@@ -1,0 +1,457 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once, through the entry point a user calls
+(``edgellm_tpu.run.main``: ``experiment: "serve"`` -> ``ServeFront`` ->
+``ContinuousBatcher`` -> paged pool -> model step), on Qwen2-0.5B at its
+published widths and full depth with seeded-random weights and the default
+dispatch, then the paper's eval sweep for a few chunks, then checks what came
+out by the repo's own means: every Pallas kernel the run dispatched against
+its XLA twin, and prefill-then-paged-decode logits against the dense fp32
+forward. With four or more devices the same serve path runs split over a
+4-stage mesh (``cuts``/``hop_codecs``: boundary hops over real ``ppermute``)
+and the split forward is held to the single-device round-trip oracle.
+
+    python3 chip_smoke.py          # on a machine with a TPU; one process
+
+It refuses to run anywhere else: no accelerator -> exit 2, nothing printed on
+stdout. Any failed phase raises, so the exit is non-zero and no result line
+is printed. On success the LAST stdout line is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``;
+the full report (device stamp, versions, dispatch, per-phase first-call vs
+steady wall times, compile-cache dir) is written to
+``chiprun_out/chip_smoke/report.json``. Times in it are wall clocks around
+work that ends in a host sync; it reports no rate and no utilization.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(ROOT, "chiprun_out", "chip_smoke")
+
+MODEL = "qwen2-0.5b"
+SEED = 0
+#: pool geometry large enough to leave toy shapes: 8 slots x 1024-token span
+BATCHING = {"page_size": 16, "pages_per_slot": 64, "max_slots": 8,
+            "num_pages": 513}
+N_REQUESTS, NEW_TOKENS = 8, 32
+#: one prompt length that is no multiple of 8, one at the sweep's window
+PROMPT_LENS = (100, 512)
+SWEEP_PARAMS = os.path.join(ROOT, "configs", "qwen_baseline_table.json")
+SWEEP_CHUNKS, SWEEP_WINDOW_BATCH = 4, 8
+SPLIT = {"cuts": [5, 11, 17],
+         "hop_codecs": ["int8_per_token", "int4_per_token", "int8_per_token"]}
+#: env switches that force a dispatch; the smoke runs the default one
+FORCING_ENV = ("EDGELLM_ATTN", "EDGELLM_PALLAS", "EDGELLM_FUSED_HOP",
+               "EDGELLM_PROBE_ALL")
+#: |system - reference| bound on logits, both sides at
+#: default_matmul_precision("highest"): fp32 rounding order through 24 layers
+#: measured 1.7e-6 on logits of std 0.6 (first v5e run); a single-pass bf16
+#: matmul anywhere (eps 2**-8) leaves >= 1e-2 — so this passes fp32 math and
+#: fails anything computed in a lower precision than the configuration states
+LOGIT_ATOL = 1e-4
+#: split NLL vs the single-device round-trip oracle (__graft_entry__'s bound)
+NLL_ATOL = 1e-3
+
+
+def serve_params(prompt_len: int, *, batching: dict = BATCHING,
+                 n_requests: int = N_REQUESTS, new_tokens: int = NEW_TOKENS,
+                 split: dict | None = None) -> dict:
+    """The inline params.json of one serve soak (``run.py`` accepts JSON)."""
+    return {"experiment": "serve",
+            "serving": {"admission": {"max_queue_depth": 64},
+                        "capacity_round": 16,
+                        "soak": {"n_requests": n_requests,
+                                 "arrival_rate": 2.0,
+                                 "prompt_len": prompt_len,
+                                 "max_new_tokens": new_tokens,
+                                 "deadline_s": 600.0}},
+            "batching": batching, **(split or {})}
+
+
+def env_phase() -> dict:
+    """Versions, device stamp, cache locations — and the conditions the rest
+    of the smoke stands on: default dispatch, compiled (not interpreted)
+    kernels."""
+    from importlib import metadata
+
+    import jax
+    import jaxlib
+
+    from edgellm_tpu.codecs import pallas_kernels, probe_cache
+    from edgellm_tpu.models import flash_attention
+    from edgellm_tpu.utils.startup import (configure_compile_cache,
+                                           device_stamp)
+
+    forced = {k: os.environ[k] for k in FORCING_ENV if k in os.environ}
+    assert not forced, f"the smoke runs the default dispatch; unset {forced}"
+    assert not flash_attention._use_interpret(), \
+        "attention kernels would run in interpret mode"
+    assert not pallas_kernels._use_interpret(), \
+        "codec kernels would run in interpret mode"
+    cache_dir = configure_compile_cache()
+    probe_path = probe_cache._cache_path()
+    return {**device_stamp(),
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": metadata.version("libtpu"),
+            "compile_cache_dir": cache_dir,
+            "compile_cache_entries_at_start": (
+                len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0),
+            "probe_cache_path": probe_path,
+            "probe_cache_has_entries": probe_cache.load_speedups() is not None,
+            "interpret": False}
+
+
+def dispatch_phase(cfg, param_dtype, *, prompt_lens=PROMPT_LENS,
+                   batching: dict = BATCHING, sweep_len: int = 512,
+                   split: dict | None = None) -> dict:
+    """Which attention plan and which codec implementation each site of the
+    run takes — the same gate functions the model code calls, at the same
+    shapes."""
+    import jax.numpy as jnp
+
+    from edgellm_tpu.models.flash_attention import decode_plan, kernel_plan
+
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    itemsize = jnp.dtype(param_dtype).itemsize
+    pages = (batching["pages_per_slot"], batching["page_size"])
+    sites = [{"site": f"serve.prefill[s={s}]", "seq": s,
+              "plan": kernel_plan(s, h, kv, hd, itemsize=itemsize)}
+             for s in prompt_lens]
+    sites.append({"site": "sweep.forward", "seq": sweep_len,
+                  "plan": kernel_plan(sweep_len, h, kv, hd,
+                                      itemsize=itemsize)})
+    sites.append({"site": "serve.decode[paged]", "seq": pages[0] * pages[1],
+                  "plan": decode_plan(pages[0] * pages[1], h, kv, hd,
+                                      itemsize=itemsize, pages=pages)})
+    out = {"param_dtype": jnp.dtype(param_dtype).name, "attention": sites}
+    if split is not None:
+        from edgellm_tpu.codecs.pallas_kernels import fused_hop_plan
+        from edgellm_tpu.parallel.split import apply_default_codec_backend
+
+        codecs = apply_default_codec_backend(list(split["hop_codecs"]))
+        out["hop_codecs"] = [
+            {"cut": cut, "asked": name, "dispatched": c.name,
+             "fused_hop_plan": fused_hop_plan(c)}
+            for cut, name, c in zip(split["cuts"], split["hop_codecs"],
+                                    codecs)]
+    return out
+
+
+#: (batch, stats kernel?) the run puts through each prefill-kernel site:
+#: serving prefills one request at a time; at 4 chunks the sweep's stats
+#: forward sees window groups of 1 and 3, and its suffix sees (4 nonzero
+#: ratios) x (group) = 4 and 12 rows through the plain kernel
+SERVE_CALLS = ((1, False),)
+SWEEP_CALLS = ((1, True), (3, True), (4, False), (12, False))
+
+
+def kernels_phase(cfg, dispatch: dict, *, hop_shapes=()) -> dict:
+    """Every Pallas kernel the dispatch selected, compiled on this backend
+    at the run's shapes, against its jnp/XLA twin. Raises on a mismatch."""
+    from edgellm_tpu.tools.attn_probe import parity_shape
+    from edgellm_tpu.tools.pallas_probe import probe_all
+
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    dtype = dispatch["param_dtype"]
+    attention = []
+    for site in dispatch["attention"]:
+        if site["plan"] is None or site["plan"][0] not in ("whole",
+                                                           "blocked"):
+            continue
+        calls = (SERVE_CALLS if site["site"].startswith("serve")
+                 else SWEEP_CALLS)
+        for b, stats in calls:
+            attention.append({"site": site["site"], **parity_shape(
+                b, h, kv, site["seq"], hd, dtype=dtype, stats=stats,
+                plan=site["plan"])})
+    # timing=False never writes the probe cache: the smoke leaves the
+    # dispatch policy as it found it
+    codecs = [probe_all(timing=False, dim=cfg.hidden_size)]
+    codecs += [probe_all(timing=False, batch=b, seq=s, dim=cfg.hidden_size)
+               for b, s in hop_shapes]
+    return {"attention": attention,
+            "codecs": [{"shape": blk["shape"], "interpret": blk["interpret"],
+                        "parity": blk["parity"],
+                        "checked": [c["codec"] for c in blk["codecs"]
+                                    if "excluded" not in c]}
+                       for blk in codecs]}
+
+
+def serve_phase(name: str, params: dict, vocab_size: int, *,
+                model: str = MODEL, out_dir: str = OUT_DIR) -> dict:
+    """One serve soak through ``run.main``. All requests must complete, with
+    tokens in ``[0, vocab)`` and no step-cache miss inside the soak."""
+    from edgellm_tpu import run
+
+    phase_dir = os.path.join(out_dir, name)
+    rc = run.main(["--model", model, "--seed", str(SEED),
+                   "--params", json.dumps(params),
+                   "--output-dir", phase_dir])
+    assert rc == 0, f"run.main serve returned {rc}"
+    with open(os.path.join(phase_dir, "serve_report.json")) as f:
+        rep = json.load(f)
+    soak = params["serving"]["soak"]
+    assert rep["outcomes"] == {"completed": soak["n_requests"]}, \
+        rep["outcomes"]
+    for toks in rep["tokens"]:
+        assert (len(toks) == soak["max_new_tokens"]
+                and all(0 <= t < vocab_size for t in toks)), toks
+    bat = rep["batcher"]
+    assert bat["jit_misses"] == 0, bat
+    return {"mode": rep["mode"], "platform": rep["platform"],
+            "outcomes": rep["outcomes"], "jit_misses": bat["jit_misses"],
+            "batched_steps": bat["steps"], "evicted": bat["evicted"],
+            # first call of every executable, compiles included
+            "first_call_s": rep["warmup_s"],
+            # the soak itself: prefill and decode steps each end in a sync
+            "steady_s": rep["drain_s"],
+            "steady_prefill_s": bat["prefill_s"],
+            "steady_decode_s": bat["decode_s"]}
+
+
+def sweep_phase(*, model: str = MODEL, params_path: str = SWEEP_PARAMS,
+                out_dir: str = OUT_DIR) -> dict:
+    """The paper's eval sweep for a few chunks, twice: the first pass compiles
+    every executable, the second is steady. PPL must be finite."""
+    import numpy as np
+
+    from edgellm_tpu import run
+
+    out = {}
+    for leg in ("first_call", "steady"):
+        leg_dir = os.path.join(out_dir, f"sweep_{leg}")
+        ckpt = os.path.join(leg_dir, "sweep_checkpoint.json")
+        if os.path.exists(ckpt):
+            os.remove(ckpt)  # a finished sweep's checkpoint would resume to a no-op
+        rc = run.main(["--model", model, "--seed", str(SEED),
+                       "--params", params_path,
+                       "--max-chunks", str(SWEEP_CHUNKS),
+                       "--window-batch", str(SWEEP_WINDOW_BATCH),
+                       "--output-dir", leg_dir])
+        assert rc == 0, f"run.main sweep returned {rc}"
+        with open(os.path.join(leg_dir, "avg_ppl_results.json")) as f:
+            res = json.load(f)
+        ppl = np.asarray(res["ppl"], np.float64)
+        assert res["chunks"] == SWEEP_CHUNKS and np.isfinite(ppl).all(), res
+        out[f"{leg}_s"] = res["wall_s"]
+        out["ppl_shape"] = list(ppl.shape)
+        out["ppl_min_max"] = [float(ppl.min()), float(ppl.max())]
+    return out
+
+
+def reference_phase(cfg, *, batching: dict = BATCHING, prompt_len: int = 100,
+                    n_steps: int = 4) -> dict:
+    """Prefill (kernel path) then teacher-forced decode through the paged
+    cache, logits against the dense fp32 forward (``stats_block=0``: the
+    full-probs formulation, no kernel, no cache) on a seeded sequence. Both
+    sides at ``default_matmul_precision("highest")`` so the bound is fp32
+    rounding, not the MXU's default single bf16 pass."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from edgellm_tpu.models import forward, init_params
+    from edgellm_tpu.models.paged_kv import PagedKVCache, paged_decode_step
+    from edgellm_tpu.serve.decode import _prefill_jit
+
+    params = init_params(cfg, jax.random.key(SEED))
+    ids = np.random.default_rng(SEED).integers(
+        1, cfg.vocab_size, size=(1, prompt_len + n_steps)).astype(np.int32)
+    span = batching["pages_per_slot"] * batching["page_size"]
+    step = jax.jit(paged_decode_step, static_argnames=("cfg",),
+                   donate_argnums=(2, 3))
+    errs = []
+    with jax.default_matmul_precision("highest"):
+        ref, _ = jax.jit(lambda p, x: forward(
+            cfg, p, x, capture_stats=True, stats_block=0))(params, ids)
+        ref = np.asarray(ref[0])  # (S + n, V)
+        assert np.isfinite(ref).all()
+        last, cache = _prefill_jit(cfg, params,
+                                   jnp.asarray(ids[:, :prompt_len]), span,
+                                   None)
+        errs.append(float(np.abs(np.asarray(last[0])
+                                 - ref[prompt_len - 1]).max()))
+        pool = PagedKVCache(cfg, num_pages=batching["num_pages"],
+                            page_size=batching["page_size"],
+                            max_slots=batching["max_slots"],
+                            pages_per_slot=batching["pages_per_slot"])
+        slot = pool.alloc_slot()
+        pool.adopt(slot, cache.k[:, 0, :prompt_len],
+                   cache.v[:, 0, :prompt_len], prompt_len)
+        for t in range(n_steps):
+            pos = prompt_len + t
+            pool.ensure(slot, pos + 1)
+            page_table, lengths = pool.device_tables()
+            feed = np.zeros((batching["max_slots"],), np.int32)
+            feed[slot] = ids[0, pos]
+            logits, k, v = step(cfg, params, pool.pool.k, pool.pool.v,
+                                page_table, lengths, jnp.asarray(feed))
+            pool.pool = type(pool.pool)(k, v)
+            # sync BEFORE touching the host tables, as the batcher does: the
+            # step may still be reading the lengths array it was handed
+            got = np.asarray(logits[slot])
+            pool.lengths[slot] = pos + 1
+            assert np.isfinite(got).all()
+            errs.append(float(np.abs(got - ref[pos]).max()))
+    assert max(errs) <= LOGIT_ATOL, \
+        f"prefill/paged-decode logits off the fp32 reference: {errs}"
+    return {"prompt_len": prompt_len, "decode_steps": n_steps,
+            "logit_std": float(ref.std()), "logit_max_abs_err": errs,
+            "atol": LOGIT_ATOL}
+
+
+def _device_bytes() -> list:
+    import jax
+
+    return [int(d.memory_stats()["peak_bytes_in_use"])
+            for d in jax.devices()]
+
+
+def split_phase(cfg, vocab_size: int, *, prompt_lens=PROMPT_LENS,
+                batching: dict = BATCHING, split: dict = SPLIT,
+                model: str = MODEL, out_dir: str = OUT_DIR) -> dict:
+    """The serve path over a 4-stage mesh, then two checks a bare "all
+    completed" would wave through: every stage device really holds its
+    weights and pool, and the split forward matches the single-device
+    forward that applies each hop codec's encode -> decode round trip at
+    its cut."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from edgellm_tpu.models import forward, init_params
+    from edgellm_tpu.models.transformer import nll_from_logits
+    from edgellm_tpu.parallel import SplitConfig, SplitRuntime, make_stage_mesh
+
+    n_stages = len(split["cuts"]) + 1
+    before = _device_bytes()
+    out = {"serve": [serve_phase(f"split_serve_{s}",
+                                 serve_params(s, batching=batching,
+                                              split=split),
+                                 vocab_size, model=model, out_dir=out_dir)
+                     for s in prompt_lens]}
+    assert all(r["mode"] == "batched_split" for r in out["serve"])
+    after = _device_bytes()
+
+    params = init_params(cfg, jax.random.key(SEED))
+    split_cfg = SplitConfig(cuts=tuple(split["cuts"]),
+                            hop_codecs=tuple(split["hop_codecs"]))
+    rt = SplitRuntime(cfg, split_cfg, make_stage_mesh(n_stages))
+    placed = rt.place_params(params)
+    pool = rt.init_paged_pool(batching["num_pages"], batching["page_size"])
+    stage_devs = [d for d in np.asarray(rt.mesh.devices).reshape(-1)]
+    # one stage's share: its layer group (every stage is padded to
+    # stage_size layers) plus its slice of the paged K/V pool
+    stage_bytes = sum(int(a.nbytes) // n_stages
+                      for a in jax.tree_util.tree_leaves(
+                          [placed["layers"], pool["k"], pool["v"]]))
+    for tree in (placed["layers"], pool["k"], pool["v"]):
+        for a in jax.tree_util.tree_leaves(tree):
+            held = {s.device for s in a.addressable_shards}
+            assert held == set(stage_devs), (a.shape, held)
+    grown = [a - b for a, b in zip(after, before)]
+    for d, g in zip(jax.devices(), grown):
+        if d in stage_devs[1:]:
+            # stages 1.. held nothing before the split serve ran
+            assert g >= stage_bytes, \
+                f"{d} grew {g} B < one stage's {stage_bytes} B"
+    assert after[0] >= stage_bytes
+
+    ids = jnp.asarray(np.random.default_rng(SEED + 1).integers(
+        0, cfg.vocab_size, (1, 128)))
+    # both sides at full matmul precision, like reference_phase: at the
+    # default single bf16 pass the sharded and unsharded graphs already
+    # round differently (3e-4 on the NLL, first four-chip run)
+    with jax.default_matmul_precision("highest"):
+        nll = float(nll_from_logits(rt.forward(placed, ids), ids))
+        ref_logits, _ = jax.jit(lambda p, x: forward(
+            cfg, p, x,
+            boundary_fn=split_cfg.roundtrip_boundary_fn()))(params, ids)
+        oracle = float(nll_from_logits(ref_logits, ids))
+    assert np.isfinite(nll) and abs(nll - oracle) < NLL_ATOL, (nll, oracle)
+    out.update({"stage_devices": [str(d) for d in stage_devs],
+                "stage_bytes_expected": stage_bytes,
+                "peak_bytes_growth_per_device": grown,
+                "hop_codecs": [c.name for c in rt.codecs],
+                "split_nll": nll, "oracle_nll": oracle,
+                "nll_abs_diff": abs(nll - oracle), "nll_atol": NLL_ATOL})
+    return out
+
+
+def smoke(report: dict, save) -> dict:
+    """Every phase in order, at full width. ``save()`` persists ``report``
+    after each phase so a failed run leaves what it learned."""
+    import jax
+
+    from edgellm_tpu.models import PRESETS, init_params
+
+    cfg = PRESETS[MODEL]
+    n_dev = len(jax.devices())
+    split = SPLIT if n_dev >= len(SPLIT["cuts"]) + 1 else None
+    param_dtype = jax.eval_shape(
+        lambda k: init_params(cfg, k), jax.random.key(SEED))["embed"].dtype
+
+    def phase(name, fn):
+        t0 = time.monotonic()
+        print(f"chip_smoke: {name} ...", file=sys.stderr, flush=True)
+        report["phases"][name] = {**fn(),
+                                  "phase_wall_s": time.monotonic() - t0}
+        save()
+
+    phase("dispatch", lambda: dispatch_phase(cfg, param_dtype, split=split))
+    hop_shapes = ([(BATCHING["max_slots"], 1)] + [(1, s) for s in PROMPT_LENS]
+                  if split else ())
+    phase("kernels", lambda: kernels_phase(
+        cfg, report["phases"]["dispatch"], hop_shapes=hop_shapes))
+    for s in PROMPT_LENS:
+        phase(f"serve_{s}", lambda s=s: serve_phase(
+            f"serve_{s}", serve_params(s), cfg.vocab_size))
+    phase("sweep", sweep_phase)
+    phase("reference", lambda: reference_phase(cfg))
+    if split is not None:
+        phase("split", lambda: split_phase(cfg, cfg.vocab_size))
+    else:
+        report["phases"]["split"] = f"not run: {n_dev} devices"
+    # built from committed files only: nothing on this path may load the
+    # mtime-keyed native extension
+    assert "edgellm_tpu.native" not in sys.modules
+    return report
+
+
+def main() -> int:
+    if not __debug__:
+        raise SystemExit("chip_smoke.py checks with assert; not under -O")
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU; JAX found platform "
+              f"{dev.platform!r} ({dev.device_kind}). Nothing was run and "
+              f"there is no fallback.", file=sys.stderr)
+        return 2
+    t0 = time.monotonic()
+    report: dict = {"ok": False, "env": env_phase(), "phases": {}}
+    os.makedirs(OUT_DIR, exist_ok=True)
+
+    def save():
+        report["wall_s"] = time.monotonic() - t0
+        with open(os.path.join(OUT_DIR, "report.json"), "w") as f:
+            json.dump(report, f, indent=1, default=str)
+
+    save()
+    smoke(report, save)
+    report["ok"] = True
+    save()
+    print(json.dumps(report, default=str))
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices())}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -23,8 +23,8 @@ def _bootstrap_jax() -> None:
             flags + " --xla_force_host_platform_device_count=8").strip()
     import jax
 
-    # sitecustomize may have imported jax already; backends are lazy, so
-    # forcing the platform here still lands before first device use
+    # backends are lazy, so forcing the platform here lands before first
+    # device use even when jax was imported earlier in the process
     jax.config.update("jax_platforms", "cpu")
 
 

@@ -26,6 +26,7 @@ from collections import Counter
 from typing import Any, Callable, Iterator, Mapping, Optional, Union
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .report import Finding
 
@@ -39,8 +40,8 @@ COLLECTIVE_PRIMS = frozenset({
 #: primitives that re-enter the host from inside a jitted graph — forbidden
 #: on every decode/forward hot path (each one is a device->host sync)
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "host_callback", "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "host_callback", "infeed", "outfeed",
 })
 
 #: dtypes the "no f64" contract rejects: double precision anywhere in a
@@ -136,9 +137,9 @@ def _sub_jaxprs(params: Mapping) -> Iterator:
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for x in vals:
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, Jaxpr):
                 yield x
 
 
@@ -146,7 +147,7 @@ def iter_eqns(jaxpr) -> Iterator:
     """Depth-first over every equation of a (Closed)Jaxpr, including all
     nested sub-jaxprs. Bodies of scan/shard_map are visited ONCE — contract
     counts are static graph counts, not runtime trip counts."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -178,7 +179,7 @@ def ppermute_traffic(jaxpr) -> list:
 
 
 def _all_avals(jaxpr) -> Iterator:
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for v in list(jaxpr.invars) + list(jaxpr.constvars) + list(jaxpr.outvars):
         yield v.aval

@@ -10,6 +10,14 @@ the opposite design point: at S <= 1024 the ENTIRE (S, S) score matrix of one
 scores -> causal mask -> softmax -> PV in one pass with zero HBM traffic for
 intermediates — no flash blocking, no online-softmax recurrence.
 
+Every rate below was taken in rounds 2-5 (2026-07) on older code and has NOT
+been re-measured since; PERF.md holds what the chip has shown since, with its
+origin. What PR 21's first run on a v5e did establish, at the shapes serving
+actually sends: float32 weights put Qwen2-0.5B on the BLOCKED kernel with
+``qb = s`` for every prompt length <= 1024 (not the whole-S bf16 kernel timed
+below), and it compiles and matches the dense fp32 formulation at
+qb in {12, 100, 512, 1024}, plain and stats (``tools/attn_probe.parity_shape``).
+
 Measured design notes (differential-scan timings on the v5e, round 4):
 
 - the big (S, hd) x (hd, S) ops are what the MXU wants: in-kernel fori flash
@@ -546,13 +554,18 @@ def decode_plan(capacity: int, h: int, kv: int, hd: int,
     each step, while the Pallas kernel scalar-prefetches the page table and
     streams each slot's pages directly (Ragged Paged Attention, PAPERS.md) —
     a genuinely new data path, not a re-tiling of one XLA already has. It
-    still dispatches only when EARNED, per the probe-cache rule: by default
-    the plan requires TPU backend AND a recorded
-    ``measured_win("paged_decode_attention")`` from ``tools/probe_kernels``;
-    ``EDGELLM_ATTN=pallas`` forces it on any backend (interpret mode off-TPU,
-    which is how tier-1 exercises the kernel); ``EDGELLM_ATTN=xla`` forces
-    the gather fallback. The ``itemsize`` scaling tracks the real
-    bytes-per-step the way the prefill gates do.
+    dispatches only when EARNED, per the probe-cache rule: by default the
+    plan requires TPU backend AND a recorded
+    ``measured_win("paged_decode_attention")`` — a key nothing writes today,
+    so THE XLA GATHER IS THE TPU PATH. ``EDGELLM_ATTN=pallas`` forces the
+    kernel on any backend (interpret mode off-TPU, which is how tier-1
+    exercises it); ``EDGELLM_ATTN=xla`` forces the gather. Neither paged
+    kernel has ever compiled for a TPU: with the q/out block shape repaired
+    (PR 21) the TPU lowering still refuses both — "Cannot store scalars to
+    VMEM" (the m/l online-softmax scratch is written one scalar per head) —
+    so forcing them on a TPU raises; nothing falls back silently. The
+    ``itemsize`` scaling tracks the real bytes-per-step the way the prefill
+    gates do.
 
     ``kv_codec`` names a quantized at-rest tier (:data:`KV_REST_TIERS`): the
     byte budget then counts the REAL per-row footprint (packed codes plus one
@@ -712,7 +725,7 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     i = pl.program_id(0)
     j = pl.program_id(1)
     kv = k_ref.shape[2] // hd
-    h = q_ref.shape[1] // hd
+    h = q_ref.shape[2] // hd
     rep = h // kv
     length = lens_ref[i]
 
@@ -730,7 +743,7 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             v = v_ref[0, :, g * hd:(g + 1) * hd]
             for r in range(rep):
                 hidx = g * rep + r
-                qh = q_ref[0, hidx * hd:(hidx + 1) * hd].reshape(1, hd)
+                qh = q_ref[0, :, hidx * hd:(hidx + 1) * hd]  # (1, hd)
                 s = jax.lax.dot_general(
                     qh, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * (1.0 / np.sqrt(hd))
@@ -750,30 +763,33 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     def _emit():
         # lengths >= 1 always (the step's own token), so l > 0
         out = acc_scr[...] / l_scr[...]
-        o_ref[...] = out.reshape(1, h * hd).astype(o_ref.dtype)
+        o_ref[0] = out.reshape(1, h * hd).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("hd", "pps", "interpret"))
 def _paged_attn(q2, kf, vf, pt_flat, lens, hd: int, pps: int,
                 interpret: bool):
-    """q2 (B, H*hd); kf/vf (num_pages, page_size, KV*hd); pt_flat (B*pps,)
-    int32; lens (B,) int32 -> (B, H*hd)."""
+    """q2 (B, 1, H*hd); kf/vf (num_pages, page_size, KV*hd); pt_flat
+    (B*pps,) int32; lens (B,) int32 -> (B, 1, H*hd). The singleton row axis
+    makes each slot's (1, 1, H*hd) q/out block equal the array's last two
+    dims — the TPU lowering refuses a (1, H*hd) row block of a (B, H*hd)
+    array."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b, dh = q2.shape
+    b, _, dh = q2.shape
     ps, kvd = kf.shape[1], kf.shape[2]
     h = dh // hd
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, pps),
         in_specs=[
-            pl.BlockSpec((1, dh), lambda i, j, pt, ln: (i, 0)),
+            pl.BlockSpec((1, 1, dh), lambda i, j, pt, ln: (i, 0, 0)),
             pl.BlockSpec((1, ps, kvd),
                          lambda i, j, pt, ln: (pt[i * pps + j], 0, 0)),
             pl.BlockSpec((1, ps, kvd),
                          lambda i, j, pt, ln: (pt[i * pps + j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, dh), lambda i, j, pt, ln: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, dh), lambda i, j, pt, ln: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -783,7 +799,7 @@ def _paged_attn(q2, kf, vf, pt_flat, lens, hd: int, pps: int,
     return pl.pallas_call(
         functools.partial(_paged_decode_kernel, hd=hd, ps=ps, pps=pps),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, dh), q2.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, dh), q2.dtype),
         interpret=interpret,
     )(pt_flat, lens, q2, kf, vf)
 
@@ -814,7 +830,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
                        itemsize=jnp.dtype(q.dtype).itemsize,
                        pages=(pps, ps))
     if plan is not None:
-        q2 = q.reshape(b, h * hd)
+        q2 = q.reshape(b, 1, h * hd)
         kf = k_pages.reshape(pn, ps, kv * hd)
         vf = v_pages.reshape(pn, ps, kv * hd)
         out = _paged_attn(q2, kf, vf, page_table.reshape(-1),
@@ -848,7 +864,7 @@ def _paged_decode_quant_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref,
     j = pl.program_id(1)
     hdc = hd // 2 if bits == 4 else hd
     kv = k_ref.shape[2] // hdc
-    h = q_ref.shape[1] // hd
+    h = q_ref.shape[2] // hd
     rep = h // kv
     length = lens_ref[i]
     inv_qmax = 1.0 / (7.0 if bits == 4 else 127.0)
@@ -881,7 +897,7 @@ def _paged_decode_quant_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref,
             v = vq.astype(jnp.float32) * vsc
             for r in range(rep):
                 hidx = g * rep + r
-                qh = q_ref[0, hidx * hd:(hidx + 1) * hd].reshape(1, hd)
+                qh = q_ref[0, :, hidx * hd:(hidx + 1) * hd]  # (1, hd)
                 s = jax.lax.dot_general(
                     qh.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * (1.0 / np.sqrt(hd))
@@ -900,7 +916,7 @@ def _paged_decode_quant_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref,
     @pl.when(j == pps - 1)
     def _emit():
         out = acc_scr[...] / l_scr[...]
-        o_ref[...] = out.reshape(1, h * hd).astype(o_ref.dtype)
+        o_ref[0] = out.reshape(1, h * hd).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -909,10 +925,10 @@ def _paged_attn_quant(q2, kf, vf, ksf, vsf, pt_flat, lens, hd: int, pps: int,
                       bits: int, interpret: bool):
     """q2 (B, H*hd); kf/vf (num_pages, page_size, KV*hdc) packed codes;
     ksf/vsf (num_pages, page_size, KV) fp32 scales; pt_flat (B*pps,) int32;
-    lens (B,) int32 -> (B, H*hd)."""
+    lens (B,) int32 -> (B, 1, H*hd) (q/out blocks as in :func:`_paged_attn`)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b, dh = q2.shape
+    b, _, dh = q2.shape
     ps, kvc = kf.shape[1], kf.shape[2]
     kv = ksf.shape[2]
     h = dh // hd
@@ -921,13 +937,13 @@ def _paged_attn_quant(q2, kf, vf, ksf, vsf, pt_flat, lens, hd: int, pps: int,
         num_scalar_prefetch=2,
         grid=(b, pps),
         in_specs=[
-            pl.BlockSpec((1, dh), lambda i, j, pt, ln: (i, 0)),
+            pl.BlockSpec((1, 1, dh), lambda i, j, pt, ln: (i, 0, 0)),
             pl.BlockSpec((1, ps, kvc), page_map),
             pl.BlockSpec((1, ps, kvc), page_map),
             pl.BlockSpec((1, ps, kv), page_map),
             pl.BlockSpec((1, ps, kv), page_map),
         ],
-        out_specs=pl.BlockSpec((1, dh), lambda i, j, pt, ln: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, dh), lambda i, j, pt, ln: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -938,7 +954,7 @@ def _paged_attn_quant(q2, kf, vf, ksf, vsf, pt_flat, lens, hd: int, pps: int,
         functools.partial(_paged_decode_quant_kernel,
                           hd=hd, ps=ps, pps=pps, bits=bits),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, dh), q2.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, dh), q2.dtype),
         interpret=interpret,
     )(pt_flat, lens, q2, kf, vf, ksf, vsf)
 
@@ -971,7 +987,7 @@ def paged_decode_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
                        pages=(pps, ps), kv_codec=kv_codec)
     if plan is not None:
         bits = 4 if kv_codec == "int4_per_channel" else 8
-        q2 = q.reshape(b, h * hd)
+        q2 = q.reshape(b, 1, h * hd)
         kf = k_pages.reshape(pn, ps, kv * hdc)
         vf = v_pages.reshape(pn, ps, kv * hdc)
         out = _paged_attn_quant(q2, kf, vf, k_scale, v_scale,

@@ -28,7 +28,6 @@ import json
 import os
 import threading
 import time
-import warnings
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from . import context as _context
@@ -173,29 +172,15 @@ def span(name: str, **attrs: Any) -> contextlib.AbstractContextManager:
 
 @contextlib.contextmanager
 def trace_capture(log_dir: Optional[str]) -> Iterator[None]:
-    """Optionally capture a ``jax.profiler.trace`` XLA profile to ``log_dir``
-    (None → no-op). Degrades to a warning when the profiler cannot start
-    (double capture, missing backend support) instead of killing the run —
-    same contract the old ``utils.profiling.trace`` stub had, which now
-    shims onto this."""
+    """Capture a ``jax.profiler.trace`` XLA profile of the enclosed block to
+    ``log_dir`` (None → no-op). A capture that was asked for and cannot start
+    (double capture, missing backend support) raises: the per-layer metrics
+    are read from these traces, so a run that silently carried on without
+    one would report a layer as measured when nothing was recorded."""
     if not log_dir:
         yield
         return
-    cm: Optional[contextlib.AbstractContextManager] = None
-    try:
-        import jax.profiler as _prof
-        cm = _prof.trace(log_dir)
-        cm.__enter__()
-    except Exception as e:  # pragma: no cover - import/env/double-capture
-        warnings.warn(f"jax profiler trace unavailable ({e}); "
-                      "continuing without XLA capture", stacklevel=2)
-        cm = None
-    try:
+    import jax.profiler
+
+    with jax.profiler.trace(log_dir):
         yield
-    finally:
-        if cm is not None:
-            try:
-                cm.__exit__(None, None, None)
-            except RuntimeError as e:  # pragma: no cover - profiler teardown
-                warnings.warn(f"jax profiler trace failed to stop ({e})",
-                              stacklevel=2)

@@ -26,9 +26,8 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jax_compat import axis_size, shard_map
 
 from ..models.configs import ModelConfig
 from ..models.transformer import (
@@ -87,7 +86,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     (the default) leaves the graph byte-identical to the uncompressed
     ring.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, h, hd = q.shape
     rep = h // k.shape[2]

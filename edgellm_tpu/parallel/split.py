@@ -32,6 +32,7 @@ from typing import Any, Optional, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.configs import ModelConfig
@@ -44,7 +45,6 @@ from ..codecs.faults import FaultConfig, FaultyLink, LinkPolicy, sum_counters
 from ..codecs.pallas_kernels import fused_hop, fused_hop_plan
 from ..lint import graph_contract
 from ..serve.recovery import StageLostError
-from ..utils.jax_compat import shard_map, pcast_varying
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -612,6 +612,24 @@ class SplitConfig:
             raise ValueError(f"cuts {self.cuts} out of range for {num_layers} layers")
         return list(zip(edges[:-1], edges[1:]))
 
+    def roundtrip_boundary_fn(self):
+        """The split's single-device oracle, as a ``boundary_fn(layer_idx,
+        hidden)`` for ``forward`` / ``prefill`` / ``decode_step``: each hop
+        codec's encode -> decode round trip (the jnp codec) applied after its
+        cut layer — mathematically what the sharded runtime computes for
+        codecs without an importance sidecar, so a wrong shard order, a
+        missed hop or a bad collective shows up as a wrong-but-finite value."""
+        wire = [c if isinstance(c, WireCodec) else get_wire_codec(c)
+                for c in self.hop_codecs]
+
+        def boundary_fn(idx, h):
+            for cut, codec in zip(self.cuts, wire):
+                h = jnp.where(idx == cut,
+                              codec.decode(codec.encode(h)).astype(h.dtype), h)
+            return h
+
+        return boundary_fn
+
     def replan(self, num_layers: int, n_stages: int,
                codec=None) -> "SplitConfig":
         """Recompute the split for a different stage count — the runtime
@@ -825,7 +843,7 @@ class SplitRuntime:
             valid = local_valid[0]  # (sz,)
             # the carry becomes stage-varying after the first scan step; promote
             # the replicated input so the vma types line up
-            hidden = pcast_varying(hidden, ("stage",))
+            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
 
             def scan_body(h, xs):
                 lp, ok = xs
@@ -1159,7 +1177,7 @@ class SplitRuntime:
             lv = {k: v[0] for k, v in local_layers.items()}  # (sz, ...)
             valid = local_valid[0]
             s = hidden.shape[1]
-            hidden = pcast_varying(hidden, ("stage",))
+            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
             zeros = jnp.zeros((sz,) + hidden.shape[:1] + (capacity,)
                               + (cfg.num_kv_heads, cfg.head_dim), hidden.dtype)
 
@@ -1188,7 +1206,7 @@ class SplitRuntime:
                        cos_t, sin_t, pos):
             lv = {k: v[0] for k, v in local_layers.items()}
             valid = local_valid[0]
-            hidden = pcast_varying(hidden, ("stage",))
+            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
 
             def scan_body(h, xs):
                 lp, ok, kl, vl = xs
@@ -1423,7 +1441,7 @@ class SplitRuntime:
                          cos_t, sin_t, pos):
             lv = {k2: v[0] for k2, v in local_layers.items()}
             valid = local_valid[0]
-            hidden = pcast_varying(hidden, ("stage",))
+            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
 
             def scan_body(h, xs):
                 lp, ok, kl, vl = xs
@@ -1734,7 +1752,7 @@ class SplitRuntime:
                              vp_loc, page_table, lengths, cos_b, sin_b):
             lv = {k: v[0] for k, v in local_layers.items()}
             valid = local_valid[0]
-            hidden = pcast_varying(hidden, ("stage",))
+            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
 
             # the deepest slot's fill level keys the fault step: distinct as
             # decoding advances, identical across same-seed replays of the
@@ -1869,7 +1887,7 @@ class SplitRuntime:
                                    lengths, cos_b, sin_b):
             lv = {k: v[0] for k, v in local_layers.items()}
             valid = local_valid[0]
-            hidden = pcast_varying(hidden, ("stage",))
+            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
             fkey = None if link is None else jax.random.fold_in(
                 jax.random.fold_in(jax.random.key(link.faults.seed), 0x57E9),
                 jnp.max(lengths))

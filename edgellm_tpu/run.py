@@ -33,6 +33,8 @@ import dataclasses
 import json
 import os
 import sys
+import time
+from typing import Optional
 
 import numpy as np
 
@@ -738,6 +740,27 @@ def _attach_front_obs(front) -> None:
         srv.health_fn = front.health_summary
 
 
+def _batcher_failure_rc(records, exc: Optional[BaseException] = None) -> int:
+    """Exit status of a batched serve soak. ``ServeFront.drain_batched``
+    turns ANY exception out of ``batcher.run`` into ``failed`` records with
+    reason ``batcher:<ExceptionType>`` and keeps serving; a soak that hit one
+    must not exit 0 — on a chip the swallowed exception is how a compiler
+    refusal would otherwise read as a table of outcomes. Policy outcomes
+    (``shed``, ``rejected``, ``timed_out``) are the front doing its job and
+    stay rc 0."""
+    broken = [r for r in records
+              if r.outcome == "failed" and r.reason.startswith("batcher:")]
+    if not broken:
+        return 0
+    if exc is not None:
+        import traceback
+
+        traceback.print_exception(exc, file=sys.stderr)
+    print(f"serve: {len(broken)} request(s) failed inside the batcher "
+          f"({broken[0].reason})", file=sys.stderr, flush=True)
+    return 1
+
+
 def _print_serve_report(report: dict) -> None:
     """Human-readable tail for ``--serve-report``: outcome counts,
     reject/shed reasons, per-breaker states, and the brownout/retry-budget
@@ -857,6 +880,9 @@ def _print_trace_report(tracer) -> None:
 
 
 def main(argv=None) -> int:
+    from .utils.startup import configure_compile_cache, device_stamp
+
+    configure_compile_cache()
     # --lint short-circuits before the parser: the graphlint gate needs no
     # params.json, and running it first means a contract violation is caught
     # before any experiment spends accelerator time (REPRODUCING §8)
@@ -1024,6 +1050,7 @@ def main(argv=None) -> int:
             print(f"chrome trace -> {args.trace_out}", flush=True)
 
     def _dispatch() -> int:
+        stamp = device_stamp()  # names the device on every result line
         experiment = params_json.get("experiment", "")
         methods = params_json.get("methods", [])
         max_length = params_json.get("max_length", cfg.max_position_embeddings)
@@ -1224,7 +1251,8 @@ def main(argv=None) -> int:
                                            clock=clock)
                     _attach_front_obs(cluster)
                     warm = ContinuousBatcher(cfg, params, bcfg, **split_kw)
-                    warm.submit(np.ones((soak.prompt_len,), np.int32), 2)
+                    warm.submit(np.ones((soak.prompt_len,), np.int32), 2,
+                                temperature=soak.temperature)
                     warm.run()
                     rng = np.random.default_rng(soak.seed)
                     gaps = rng.exponential(1.0 / soak.arrival_rate,
@@ -1262,13 +1290,14 @@ def main(argv=None) -> int:
                         "mode": (("disagg_" if dcfg is not None else "")
                                  + ("cluster_batched_split" if rt is not None
                                     else "cluster_batched")),
+                        **stamp,
                         "cluster": rep,
                         "records": [r.as_dict() for r in records]}
                     with open(out("cluster_report.json"), "w") as f:
                         json.dump(artifact, f, indent=1, default=float)
                     print(json.dumps({
                         "requests": len(records), "outcomes": outcomes,
-                        "mode": artifact["mode"],
+                        "mode": artifact["mode"], **stamp,
                         "replicas": len(rep["replicas"]),
                         "placements": rep["totals"],
                         "artifact": out("cluster_report.json")},
@@ -1278,16 +1307,20 @@ def main(argv=None) -> int:
                             f"cluster drain left {cluster.pending} accepted "
                             f"request(s) unterminated — the router lost "
                             f"work: {rep}")
-                    return 0
+                    return _batcher_failure_rc(records)
                 batcher = make_batcher()
                 front = ServeFront(cfg, params, config=front_cfg,
                                    clock=clock, batcher=batcher)
                 _attach_front_obs(front)
-                # warm the ragged step + the soak's prefill shape so compile
-                # time never lands on a request's service clock
+                # warm the ragged step, the soak's prefill shape and its
+                # sampler (greedy and sampled token 0 are different eager
+                # ops) so compile time never lands on a request's clock
+                t_warm = time.monotonic()
                 warm = ContinuousBatcher(cfg, params, bcfg, **split_kw)
-                warm.submit(np.ones((soak.prompt_len,), np.int32), 2)
+                warm.submit(np.ones((soak.prompt_len,), np.int32), 2,
+                                temperature=soak.temperature)
                 warm.run()
+                warmup_s = time.monotonic() - t_warm
                 rng = np.random.default_rng(soak.seed)
                 gaps = rng.exponential(1.0 / soak.arrival_rate,
                                        size=soak.n_requests)
@@ -1309,23 +1342,33 @@ def main(argv=None) -> int:
                         max_new_tokens=soak.max_new_tokens,
                         temperature=soak.temperature,
                         deadline_s=soak.deadline_s, rng_seed=i))
+                t_drain = time.monotonic()
                 records = front.drain_batched()
+                drain_s = time.monotonic() - t_drain
                 rep = batcher.report()
                 outcomes: dict = {}
                 for rec in records:
                     outcomes[rec.outcome] = outcomes.get(rec.outcome, 0) + 1
+                # wall clocks (the soak's own clock is virtual): warmup_s is
+                # the first call of every executable, compiles included;
+                # drain_s is the soak itself, each step ended by a host sync
                 artifact = {"requests": len(records), "outcomes": outcomes,
                             "mode": (("disagg_" if dcfg is not None else "")
                                      + ("batched_split" if rt is not None
                                         else "batched")),
+                            **stamp,
+                            "warmup_s": warmup_s, "drain_s": drain_s,
                             "batcher": rep,
-                            "records": [r.as_dict() for r in records]}
+                            "records": [r.as_dict() for r in records],
+                            "tokens": [None if r.tokens is None
+                                       else np.asarray(r.tokens)[0].tolist()
+                                       for r in records]}
                 with open(out("serve_report.json"), "w") as f:
                     json.dump(artifact, f, indent=1, default=float)
                 pf = rep.get("prefix")
                 print(json.dumps({
                     "requests": len(records), "outcomes": outcomes,
-                    "mode": artifact["mode"],
+                    "mode": artifact["mode"], **stamp,
                     "batched_steps": rep["steps"],
                     "jit_misses": rep["jit_misses"],
                     "occupancy_mean": round(rep["alloc_util_mean"], 4),
@@ -1348,7 +1391,7 @@ def main(argv=None) -> int:
                         f"prefix cache enabled with shared_prefix_len="
                         f"{soak.shared_prefix_len} but the radix index "
                         f"never hit: {pf}")
-                return 0
+                return _batcher_failure_rc(records, front.batcher_failure)
             spec = None
             if "speculative" in params_json:
                 from .serve.speculative import SpecConfig
@@ -1377,12 +1420,13 @@ def main(argv=None) -> int:
                 generate_split(rt, rt.place_params(params), warm_ids,
                                soak.max_new_tokens, speculative=spec,
                                raw_params=params, **warm_kw)
-            artifact = run_soak(front, soak, clock=clock)
+            artifact = {**run_soak(front, soak, clock=clock),
+                        **stamp}
             with open(out("serve_report.json"), "w") as f:
                 json.dump(artifact, f, indent=1, default=float)
             print(json.dumps({
                 "requests": artifact["requests"],
-                "outcomes": artifact["outcomes"],
+                "outcomes": artifact["outcomes"], **stamp,
                 "goodput_tokens_per_s": round(
                     artifact["goodput_tokens_per_s"], 3),
                 "slo_attainment": artifact["slo_attainment"],
@@ -1475,10 +1519,9 @@ def main(argv=None) -> int:
             import jax
 
             if jax.default_backend() == "tpu" and common["window_batch"] > 1:
-                # a real TPU OOM poisons the process allocator; pre-shrink the
-                # window batch by AOT memory analysis (no allocation) so big
-                # real-corpus runs degrade instead of dying (bench.py does the
-                # same)
+                # pre-shrink the window batch by AOT memory analysis (no
+                # allocation) so big real-corpus runs degrade instead of
+                # dying on the first launch (bench.py does the same)
                 from .tools.wb_preflight import preflight_token_sweep_batch
 
                 wb = preflight_token_sweep_batch(
@@ -1500,6 +1543,7 @@ def main(argv=None) -> int:
             json.dump(result.to_json(), f, indent=1)
         print(result.table())
         print(json.dumps({"chunks": result.chunks, "n_tokens": result.n_tokens,
+                          **stamp,
                           "wall_s": round(result.wall_s, 3),
                           "ppl": np.round(result.ppl(), 4).tolist()}))
         return 0

@@ -246,6 +246,10 @@ class ServeFront:
             raise ServeFrontConfigError(
                 "pass split_runtime OR split_ladder, not both")
         self.batcher = batcher   # ContinuousBatcher, for drain_batched()
+        # the last exception drain_batched turned into FAILED records: the
+        # drain keeps serving, but the caller must be able to surface WHAT
+        # broke (run.py exits non-zero and prints it)
+        self.batcher_failure: Optional[BaseException] = None
         self.model_cfg = model_cfg
         self.config = config if config is not None else ServeFrontConfig()
         self.clock = clock
@@ -556,7 +560,7 @@ class ServeFront:
             failure = None
         except Exception as e:  # noqa: BLE001 — a wedged pool / watchdog
             results = self.batcher.results
-            failure = e
+            failure = self.batcher_failure = e
         wall = self.clock() - t0
         rep = self.batcher.report()
         plan = {"mode": "batched",

@@ -25,11 +25,10 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 # cross-process CPU collectives (the ICI/DCN analogue in this test rig)
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get("EDGELLM_JAX_CACHE",
-                   os.path.join(os.path.dirname(__file__), ".jax_cache")))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from edgellm_tpu.utils.startup import configure_compile_cache
+
+configure_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def workload():

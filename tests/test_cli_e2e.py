@@ -1,6 +1,6 @@
 """End-to-end CLI runs from a real on-disk checkpoint (HF safetensors layout).
 
-This is the "real weights + real corpus readiness" contract (VERDICT missing #2):
+This is the "real weights + real corpus readiness" contract:
 the moment actual Qwen2/Pythia artifacts appear, ``run.py --weights <dir>
 --corpus <ids.npy>`` must execute the reference's experiments end to end. The
 environment has no pretrained checkpoints, so these tests synthesize a

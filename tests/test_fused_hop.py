@@ -36,7 +36,7 @@ from edgellm_tpu.codecs.wire_format import (WireFormat, flatten_bytes,
                                             verify_payload)
 from edgellm_tpu.models import init_params, tiny_config
 from edgellm_tpu.parallel import SplitConfig, SplitRuntime, make_stage_mesh
-from edgellm_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 CFG = tiny_config("qwen2", num_layers=4, hidden_size=32, num_heads=4,
                   vocab_size=128)

@@ -23,7 +23,7 @@ from edgellm_tpu.lint.contracts import (GRAPH_CONTRACTS, GraphContract,
                                         donated_input_count,
                                         graph_fingerprint, ppermute_traffic)
 from edgellm_tpu.parallel.split import make_stage_mesh
-from edgellm_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "graphlint_fixtures")
 
@@ -167,7 +167,7 @@ def test_f64_leak_caught():
         return x.astype(jnp.float64) * 2.0
 
     x = jnp.ones((4,), jnp.float32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         bad = check_traced(contract, promotes, (x,))
     assert _rules(bad) == {"GC-f64"}
     assert check_traced(contract, lambda y: y * 2.0, (x,)) == []

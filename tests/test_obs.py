@@ -291,19 +291,23 @@ def test_span_disabled_is_free_and_records_nothing():
 
 def test_trace_capture_shim_and_deprecation(tmp_path):
     """utils.profiling.trace delegates (with a DeprecationWarning) to
-    obs.tracing.trace_capture, which degrades to a warning — never a crash —
-    when the profiler can't start."""
+    obs.tracing.trace_capture, which RAISES when a capture that was asked
+    for cannot start — the layer metrics are read from these traces, so a
+    run must not carry on without one."""
     from edgellm_tpu.utils import profiling
 
     with pytest.deprecated_call():
         with profiling.trace(str(tmp_path / "xla")):
             pass
-    # double-start degrades: the second capture warns instead of raising
     from edgellm_tpu.obs.tracing import trace_capture
 
+    with trace_capture(None):  # not asked for: a no-op
+        pass
     with trace_capture(str(tmp_path / "a")):
-        with trace_capture(str(tmp_path / "b")):
-            pass
+        # double-start: the second capture cannot start, and says so
+        with pytest.raises(Exception, match="[Pp]rofile"):
+            with trace_capture(str(tmp_path / "b")):
+                pass
 
 
 # ---------------------------------------------------------------------------

@@ -14,9 +14,10 @@ accounting) and the validation surface (divisibility, batch-variant codecs,
 stage-only mesh).
 
 Also here (ISSUE satellite): >= 3-stage DECODE coverage — ``generate_split``
-and the batcher's paged decode at cuts=(1, 3) with mixed codecs, clean and
-through a retrying faulty link, token-identical to single-device
-``generate`` (forward-only 3-stage coverage lives in test_split.py).
+at cuts=(1, 3) with mixed codecs, clean and through a retrying faulty link,
+token-identical to single-device ``generate``, and the batcher's paged decode
+against the single-device round-trip oracle (forward-only 3-stage coverage
+lives in test_split.py).
 """
 import numpy as np
 import pytest
@@ -258,10 +259,39 @@ def test_three_stage_generate_split_retrying_faulty_link(params, mesh):
     np.testing.assert_array_equal(want, got)
 
 
-def test_three_stage_paged_decode_matches_generate(params, mesh):
+def _roundtrip_greedy(params, split, prompt, n_new):
+    """The split runtime's single-device oracle: a greedy KV-cached decode
+    that applies each hop codec's encode -> decode round trip after its cut
+    layer. For row-local codecs this is mathematically what the sharded
+    runtime computes for the stream, whatever else rides in the ragged step —
+    so token equality is an invariant, not a hope that a lossy hop never
+    flips an argmax against the UNQUANTIZED model."""
+    from edgellm_tpu.models.transformer import decode_step, prefill
+
+    bfn = split.roundtrip_boundary_fn()
+    logits, cache = jax.jit(
+        lambda p, x: prefill(CFG, p, x, prompt.size + n_new,
+                             boundary_fn=bfn))(params,
+                                               jnp.asarray(prompt)[None])
+    step = jax.jit(lambda p, c, t: decode_step(CFG, p, c, t,
+                                               boundary_fn=bfn))
+    toks = [int(jnp.argmax(logits[0, -1]))]
+    for _ in range(n_new - 1):
+        logits, cache = step(params, cache, jnp.asarray([toks[-1]]))
+        toks.append(int(jnp.argmax(logits[0])))
+    return np.asarray(toks, np.int32)
+
+
+def test_three_stage_paged_decode_matches_roundtrip_oracle(params, mesh):
+    """Four concurrent streams through the batcher's ragged paged decode on a
+    3-stage split with LOSSY mixed-precision hops (ternary then int4, both
+    per-token so every row's payload is its own) must equal the round-trip
+    oracle token for token."""
+    split = SplitConfig(cuts=(1, 3),
+                        hop_codecs=("ternary_per_token", "int4_per_token"))
     bcfg = BatchingConfig(max_slots=4, num_pages=20, page_size=4,
                           pages_per_slot=6)
-    rt = SplitRuntime(CFG, MIXED, mesh)
+    rt = SplitRuntime(CFG, split, mesh)
     bat = ContinuousBatcher(CFG, params, bcfg, split_runtime=rt,
                             placed_params=rt.place_params(params))
     rng = np.random.default_rng(9)
@@ -271,6 +301,5 @@ def test_three_stage_paged_decode_matches_generate(params, mesh):
         prompts[bat.submit(p, 6, rng_seed=i)] = p
     results = bat.run()
     for sid, p in prompts.items():
-        want = np.asarray(generate(CFG, params, jnp.asarray(p)[None], 6,
-                                   capacity=p.size + 6))[0]
-        np.testing.assert_array_equal(want, np.asarray(results[sid]))
+        np.testing.assert_array_equal(_roundtrip_greedy(params, split, p, 6),
+                                      np.asarray(results[sid]))

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from edgellm_tpu.models import tiny_config, init_params, forward
 from edgellm_tpu.parallel.ring import make_seq_mesh, forward_sp, ring_attention
-from edgellm_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 QWEN = tiny_config("qwen2", num_layers=4, hidden_size=32, num_heads=4, vocab_size=128)

@@ -1,6 +1,6 @@
 """Importance-guided selective hops under the stage x seq (ring) runtime.
 
-Round-4 capability composition (VERDICT r3 missing #1): the reference's
+Round-4 capability composition: the reference's
 headline codec — token-selective int4 at the boundary
 (``qwen_layer_wise.py:54-73``) — must run while the sequence is ring-sharded,
 with the attention-statistic importance captured inside ``ring_attention``'s
@@ -15,7 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from edgellm_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from edgellm_tpu.models import tiny_config, init_params

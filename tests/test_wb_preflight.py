@@ -2,6 +2,7 @@
 search lands on the largest candidate under the budget — all without touching
 device memory (compile-only)."""
 import jax.numpy as jnp
+import pytest
 
 from edgellm_tpu.models import tiny_config
 from edgellm_tpu.tools.wb_preflight import (estimate_sweep_peak_bytes,
@@ -66,3 +67,25 @@ def test_token_sweep_preflight_uses_earliest_layer():
                                        ratios=[0, 0.5], dtype=jnp.float32,
                                        hbm_bytes=1, budget_frac=1.0)
     assert tiny == 1
+
+
+def test_budget_comes_from_the_device_not_an_assumed_chip(monkeypatch):
+    """With no explicit hbm_bytes the limit is what the device reports; a
+    device that reports none is an error, never an assumed 15.75 GiB."""
+    import jax
+
+    from edgellm_tpu.tools import wb_preflight
+
+    class _Dev:
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev({"bytes_limit": 1000})])
+    assert wb_preflight._budget_bytes(None, 0.5) == 500
+    assert wb_preflight._budget_bytes(64, 0.5) == 32  # explicit wins
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev(None)])
+    with pytest.raises(RuntimeError, match="reports no memory limit"):
+        wb_preflight._budget_bytes(None, 0.5)
