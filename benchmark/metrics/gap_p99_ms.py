@@ -1,0 +1,6 @@
+"""99th percentile of the gap between tokens (see ``gap_mean_ms``)."""
+from benchmark.latency import gaps_ms, pct
+
+
+def read(record: dict):
+    return pct(gaps_ms(record), 99)

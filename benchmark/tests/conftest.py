@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU at a toy size:
+``python -m pytest benchmark/tests -q``. Set before jax is imported."""
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
