@@ -26,8 +26,9 @@ EG006  Mutation of a captured container (``append``/``update``/subscript
        the mutation happens once at trace time, not per call.
 EG007  A literal metric name (``registry.counter/gauge/histogram("...")``,
        direct ``Counter``/``Gauge``/``Histogram`` construction) or span name
-       (``span("...")``/``obs_span("...")``) that is not in the registered
-       vocabulary (``obs/names.py``) — a typo'd name silently creates a
+       (``span("...")``/``obs_span("...")``/``phase("...")``/
+       ``obs_phase("...")``) or ``named_scope("...")`` name that is not in the
+       registered vocabulary (``obs/names.py``) — a typo'd name silently creates a
        series no dashboard ever scrapes. f-string names lint as wildcard
        patterns against the registered templates; fully dynamic names (a
        variable) are out of scope.
@@ -93,7 +94,8 @@ MUTATING_METHODS = frozenset({
 #: and the span entry points whose first argument is THE name
 METRIC_FACTORY_METHODS = frozenset({"counter", "gauge", "histogram"})
 METRIC_CLASSES = frozenset({"Counter", "Gauge", "Histogram"})
-SPAN_CALLEES = frozenset({"span", "obs_span"})
+SPAN_CALLEES = frozenset({"span", "obs_span", "phase", "obs_phase"})
+SCOPE_CALLEES = frozenset({"named_scope"})
 
 _DISABLE_RE = re.compile(r"#\s*graphlint:\s*disable(?:=([A-Z0-9, ]+))?")
 
@@ -535,7 +537,8 @@ def _check_registered_names(tree: ast.Module, emit) -> None:
         is_span = ((isinstance(f, ast.Name) and f.id in SPAN_CALLEES)
                    or (isinstance(f, ast.Attribute)
                        and f.attr in SPAN_CALLEES))
-        if not (is_metric or is_span):
+        is_scope = _call_target_name(f) in SCOPE_CALLEES
+        if not (is_metric or is_span or is_scope):
             continue
         pattern = _literal_name_pattern(node.args[0])
         if pattern is None:
@@ -550,6 +553,11 @@ def _check_registered_names(tree: ast.Module, emit) -> None:
                  f"span name {pattern!r} is not in the registered "
                  f"vocabulary (obs/names.py); register it there or fix "
                  f"the typo")
+        elif is_scope and not obs_names.scope_registered(pattern):
+            emit("EG007", node.lineno,
+                 f"named_scope {pattern!r} is not in SCOPE_NAMES "
+                 f"(obs/names.py); trace readers sum device time by these "
+                 f"names — register it there or fix the typo")
 
 
 # -- driver -----------------------------------------------------------------

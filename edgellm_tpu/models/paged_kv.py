@@ -460,6 +460,7 @@ def num_pages_for_bytes(cfg: ModelConfig, pool_bytes: int, page_size: int,
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
+@jax.named_scope("paged_kv.adopt")
 def _adopt_impl(pool_k, pool_v, k_seq, v_seq, dest):
     """Scatter a contiguous (L, S, KV, hd) K/V prefix into the pool rows
     named by ``dest`` (S,) — flat indices into the (num_pages * page_size)
@@ -505,6 +506,7 @@ def _copy_pages_impl(pool_k, pool_v, src, dst):
 # moves raw codes+scales for the bit-exact checkpoint/eviction path.
 
 
+@jax.named_scope("paged_kv.adopt")
 def _flat_rows_set(arr, dest, rows):
     """Scatter (L, S, ...) rows into flat token positions ``dest`` (S,) of a
     (L, num_pages, page_size, ...) pool array."""
@@ -1476,6 +1478,7 @@ def _apply_rotary_rows(x: jnp.ndarray, cos_b: jnp.ndarray,
     return jnp.concatenate([x_rot, x_pass], axis=-1)
 
 
+@jax.named_scope("attn.decode")
 def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                             cos_b, sin_b, k_pages, v_pages,
                             page_table, lengths,
@@ -1500,13 +1503,14 @@ def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     # slot i's new token lands in its (length // page_size)-th page at offset
     # length % page_size; inactive slots (all-zero table rows) land in the
     # trash page, where duplicate scatter indices are harmless garbage
-    dest = (page_table[jnp.arange(b), lengths // ps] * ps
-            + lengths % ps)  # (B,)
-    tail = k_pages.shape[2:]
-    k_pages = k_pages.reshape(pn * ps, *tail).at[dest].set(
-        k[:, 0].astype(k_pages.dtype)).reshape(pn, ps, *tail)
-    v_pages = v_pages.reshape(pn * ps, *tail).at[dest].set(
-        v[:, 0].astype(v_pages.dtype)).reshape(pn, ps, *tail)
+    with jax.named_scope("paged_kv.write"):
+        dest = (page_table[jnp.arange(b), lengths // ps] * ps
+                + lengths % ps)  # (B,)
+        tail = k_pages.shape[2:]
+        k_pages = k_pages.reshape(pn * ps, *tail).at[dest].set(
+            k[:, 0].astype(k_pages.dtype)).reshape(pn, ps, *tail)
+        v_pages = v_pages.reshape(pn * ps, *tail).at[dest].set(
+            v[:, 0].astype(v_pages.dtype)).reshape(pn, ps, *tail)
 
     from .flash_attention import paged_decode_attention
 
@@ -1581,10 +1585,12 @@ def paged_decode_step(cfg: ModelConfig, params: dict,
 
     hidden, (k_new, v_new) = jax.lax.scan(
         body, hidden, (params["layers"], pool_k, pool_v))
-    logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
+    with jax.named_scope("unembed_sample"):
+        logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
     return logits, k_new, v_new
 
 
+@jax.named_scope("attn.decode")
 def _attention_decode_paged_quant(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                                   cos_b, sin_b, k_pages, v_pages,
                                   k_scale, v_scale, page_table, lengths,
@@ -1611,20 +1617,21 @@ def _attention_decode_paged_quant(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
 
     from .flash_attention import paged_decode_attention_quant, quantize_kv_rows
 
-    qk, sk = quantize_kv_rows(k[:, 0], kv_codec)  # (B, KV, hdc), (B, KV)
-    qv, sv = quantize_kv_rows(v[:, 0], kv_codec)
-    pn, ps = k_pages.shape[0], k_pages.shape[1]
-    dest = (page_table[jnp.arange(b), lengths // ps] * ps
-            + lengths % ps)  # (B,)
-    ctail = k_pages.shape[2:]
-    k_pages = k_pages.reshape(pn * ps, *ctail).at[dest].set(
-        qk.astype(k_pages.dtype)).reshape(pn, ps, *ctail)
-    v_pages = v_pages.reshape(pn * ps, *ctail).at[dest].set(
-        qv.astype(v_pages.dtype)).reshape(pn, ps, *ctail)
-    k_scale = k_scale.reshape(pn * ps, kv).at[dest].set(
-        sk).reshape(pn, ps, kv)
-    v_scale = v_scale.reshape(pn * ps, kv).at[dest].set(
-        sv).reshape(pn, ps, kv)
+    with jax.named_scope("paged_kv.write"):
+        qk, sk = quantize_kv_rows(k[:, 0], kv_codec)  # (B,KV,hdc), (B,KV)
+        qv, sv = quantize_kv_rows(v[:, 0], kv_codec)
+        pn, ps = k_pages.shape[0], k_pages.shape[1]
+        dest = (page_table[jnp.arange(b), lengths // ps] * ps
+                + lengths % ps)  # (B,)
+        ctail = k_pages.shape[2:]
+        k_pages = k_pages.reshape(pn * ps, *ctail).at[dest].set(
+            qk.astype(k_pages.dtype)).reshape(pn, ps, *ctail)
+        v_pages = v_pages.reshape(pn * ps, *ctail).at[dest].set(
+            qv.astype(v_pages.dtype)).reshape(pn, ps, *ctail)
+        k_scale = k_scale.reshape(pn * ps, kv).at[dest].set(
+            sk).reshape(pn, ps, kv)
+        v_scale = v_scale.reshape(pn * ps, kv).at[dest].set(
+            sv).reshape(pn, ps, kv)
 
     out = paged_decode_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
                                        page_table, lengths + 1,
@@ -1700,5 +1707,6 @@ def paged_decode_step_quant(cfg: ModelConfig, params: dict,
     hidden, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
         body, hidden, (params["layers"], pool_k, pool_v,
                        pool_k_scale, pool_v_scale))
-    logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
+    with jax.named_scope("unembed_sample"):
+        logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
     return logits, k_new, v_new, ks_new, vs_new

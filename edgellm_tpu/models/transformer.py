@@ -269,6 +269,7 @@ def attention(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos, sin,
     return project_out(out, (col_sum / s, last_row))  # stats (B, H, S) each
 
 
+@jax.named_scope("mlp")
 def mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         tp_axis: Optional[str] = None) -> jnp.ndarray:
     """MLP; with ``tp_axis`` set, the hidden (F) axis is column-split per device

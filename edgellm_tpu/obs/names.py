@@ -22,8 +22,9 @@ from fnmatch import fnmatchcase
 from typing import FrozenSet, Tuple
 
 __all__ = [
-    "METRIC_NAMES", "METRIC_TEMPLATES", "SPAN_NAMES", "SPAN_TEMPLATES",
-    "metric_registered", "span_registered",
+    "METRIC_NAMES", "METRIC_TEMPLATES", "SCOPE_NAMES", "SCOPE_TEMPLATES",
+    "SPAN_NAMES", "SPAN_TEMPLATES", "metric_registered", "scope_registered",
+    "span_registered",
 ]
 
 #: every literal metric family name in the package (registry factories and
@@ -130,8 +131,20 @@ SPAN_NAMES: FrozenSet[str] = frozenset({
     "serve.submit",
     "serve.execute",
     "batch.submit",
-    "batch.admit",
+    # ContinuousBatcher.step and its six phases (obs.tracing.phase: always
+    # on a profiler capture, clocks admit_s ... commit_s of report()), and
+    # one admission with its device dispatches and its host sync
     "batch.step",
+    "batch.step.admit",
+    "batch.step.grow",
+    "batch.step.build",
+    "batch.step.launch",
+    "batch.step.sync",
+    "batch.step.commit",
+    "batch.admit",
+    "batch.admit.prefill",
+    "batch.admit.adopt",
+    "batch.admit.tok0_sync",
     # per-cut boundary-hop attribution (decode, speculative, eval)
     "split.hop",
     # eval/split_eval.py
@@ -169,6 +182,25 @@ SPAN_NAMES: FrozenSet[str] = frozenset({
 SPAN_TEMPLATES: Tuple[str, ...] = ()
 
 
+#: every ``jax.named_scope`` the package opens inside traced code: the path
+#: segment a device operation's ``op_name`` metadata carries, so device time
+#: is summed by a name the program owns and not by ``fusion.222``
+SCOPE_NAMES: FrozenSet[str] = frozenset({
+    "paged_kv.write",     # the per-layer K/V row scatter of the ragged step
+    "paged_kv.adopt",     # a prefilled or resumed prefix scattered into pages
+    "attn.decode",        # the paged decode attention of one layer
+    "mlp",
+    "unembed_sample",     # final norm + LM head + the per-slot sampler
+    "split.stage",        # one stage iteration of the split unroll
+})
+
+#: scope templates: ``split.hop.<cut>`` is one boundary hop (encode, the
+#: collective-permute, decode), the hole the cut's index
+SCOPE_TEMPLATES: Tuple[str, ...] = (
+    "split.hop.*",
+)
+
+
 def _registered(pattern: str, names: FrozenSet[str],
                 templates: Tuple[str, ...]) -> bool:
     if "*" in pattern:
@@ -189,3 +221,8 @@ def metric_registered(name_or_pattern: str) -> bool:
 def span_registered(name_or_pattern: str) -> bool:
     """Span-name twin of :func:`metric_registered`."""
     return _registered(name_or_pattern, SPAN_NAMES, SPAN_TEMPLATES)
+
+
+def scope_registered(name_or_pattern: str) -> bool:
+    """``jax.named_scope`` twin of :func:`metric_registered`."""
+    return _registered(name_or_pattern, SCOPE_NAMES, SCOPE_TEMPLATES)

@@ -2,21 +2,37 @@
 Chrome trace-event JSON (load the file at https://ui.perfetto.dev), bridged
 into ``jax.profiler`` so host spans line up with the device timeline.
 
+Two entry points, for two kinds of call site:
+
+- :func:`span` — a span that matters only to someone who armed the tracer
+  (``obs.enable()``). Disabled it hands back ONE shared ``nullcontext``: no
+  allocation, no clock read, no lock, and nothing reaches a profiler
+  capture. Enabled it records a :class:`Span` and enters a
+  ``jax.profiler.TraceAnnotation``.
+- :class:`phase` — a phase of a hot loop that the program itself keeps a
+  clock on (``ContinuousBatcher.step``'s admit/grow/build/launch/sync/
+  commit). It ALWAYS enters a ``jax.profiler.TraceAnnotation`` — that object
+  does nothing unless a profiler session is recording, so any capture
+  (:func:`trace_capture`, a bare ``jax.profiler.start_trace``, an
+  operator's) shows the program's phases on the device trace's clock with
+  nobody arming ``obs`` — and ALWAYS adds its duration to the caller's
+  accumulator. What that costs with the tracer disabled and no capture
+  running: a ``time.monotonic`` reading or two and one annotation object,
+  about two microseconds a phase. With the tracer enabled it also records the
+  :class:`Span`, nested under its parent by the same per-thread stack.
+
 Design points:
 
 - **Thread-safe, nesting-aware.** Each thread keeps its own open-span stack
   (``threading.local``); finished spans append to one locked list. Chrome's
   viewer infers nesting from ``ts``/``dur`` on the same ``tid``, which the
   per-thread stack discipline guarantees.
-- **Disabled is near-free.** :func:`span` hands back a shared
-  ``nullcontext`` when tracing is off — no allocation, no clock read, no
-  lock. The serve loops call it unconditionally.
-- **Device bridge.** When tracing is on and jax is importable, each span
-  also enters ``jax.profiler.TraceAnnotation``, so a
-  ``jax.profiler.trace`` capture (see :func:`trace_capture`) shows host
-  spans on the TensorBoard/Perfetto device timeline. The bridge degrades
-  silently when jax or its profiler is unavailable — tracing must work in
-  a bare-stdlib process.
+- **The device bridge degrades silently** when jax or its profiler is
+  unavailable — tracing must work in a bare-stdlib process.
+- **Compile counter.** :func:`compile_totals` is the process's running count
+  and seconds of JAX backend compiles, fed by ONE ``jax.monitoring``
+  listener registered on first use; callers difference it around their own
+  work (the batcher's ``compiles``/``compile_s``).
 - **trace_capture** wraps ``jax.profiler.trace`` (the XLA-level profiler
   dump) and subsumes the old ``utils.profiling.trace`` stub, which now
   delegates here.
@@ -34,8 +50,8 @@ from . import context as _context
 from ..utils.concurrency import guarded_by
 
 __all__ = [
-    "Span", "Tracer", "configure", "get_tracer", "span", "trace_capture",
-    "tracing_enabled",
+    "Span", "Tracer", "compile_totals", "configure", "get_tracer", "phase",
+    "span", "trace_capture", "tracing_enabled",
 ]
 
 
@@ -63,10 +79,11 @@ class Span:
         return ev
 
 
-def _jax_annotation(name: str) -> contextlib.AbstractContextManager:
+def _jax_annotation(name: str, **attrs: Any
+                    ) -> contextlib.AbstractContextManager:
     try:  # bridge is best-effort: bare-stdlib processes still trace
         import jax.profiler as _prof
-        return _prof.TraceAnnotation(name)
+        return _prof.TraceAnnotation(name, **attrs)
     except Exception:
         return contextlib.nullcontext()
 
@@ -91,11 +108,8 @@ class Tracer:
     def set_sink(self, sink: Optional[Callable[[Span], None]]) -> None:
         self._sink = sink
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[Optional[Span]]:
-        if not self.enabled:
-            yield None
-            return
+    def _open(self, name: str, attrs: Dict[str, Any]) -> Span:
+        """Push a new open span on this thread's stack (tracer enabled)."""
         stack = getattr(self._stack, "open", None)
         if stack is None:
             stack = self._stack.open = []
@@ -105,20 +119,31 @@ class Tracer:
             attrs = labels
         s = Span(name, self._now_us(), threading.get_ident(), attrs)
         stack.append(s)
+        return s
+
+    def _close(self, s: Span) -> None:
+        s.dur_us = self._now_us() - s.ts_us
+        self._stack.open.pop()
+        with self._lock:
+            self._spans.append(s)
+        sink = self._sink
+        if sink is not None:
+            try:
+                sink(s)
+            except Exception:  # pragma: no cover - defensive
+                pass
+
+    @contextlib.contextmanager
+    def span(self, name: str, **attrs: Any) -> Iterator[Optional[Span]]:
+        if not self.enabled:
+            yield None
+            return
+        s = self._open(name, attrs)
         try:
             with _jax_annotation(name):
                 yield s
         finally:
-            s.dur_us = self._now_us() - s.ts_us
-            stack.pop()
-            with self._lock:
-                self._spans.append(s)
-            sink = self._sink
-            if sink is not None:
-                try:
-                    sink(s)
-                except Exception:  # pragma: no cover - defensive
-                    pass
+            self._close(s)
 
     def spans(self) -> List[Span]:
         with self._lock:
@@ -168,6 +193,108 @@ def span(name: str, **attrs: Any) -> contextlib.AbstractContextManager:
     if not _TRACER.enabled:
         return _NULL
     return _TRACER.span(name, **attrs)
+
+
+class phase:
+    """One phase of a hot loop, as a context manager::
+
+        with phase("batch.step", acc, "step_wall_s", step=n) as whole:
+            with phase("batch.step.admit", acc, "admit_s", after=whole) as ph:
+                ...
+            with phase("batch.step.launch", acc, "launch_s", after=ph):
+                ...
+
+    Always on the profiler's clock and always on the caller's: see the module
+    docstring. ``acc`` is the caller's own ``{key: seconds}`` dict, which
+    gains the duration under ``key`` (the caller takes its lock once, when
+    it folds the dict into its counters, not once a phase); without ``acc``
+    only the span is kept.
+
+    ``after`` chains the caller's clock: this phase's seconds count from
+    where ``after`` stopped — or, while ``after`` is still open (the
+    enclosing phase), from where it started — so a run of phases tiles its
+    parent with one clock reading per edge and the statements between two
+    phases belong to the later one. The spans themselves start where they
+    are entered.
+
+    ``attrs`` given here reach the profiler capture (as the event's stats)
+    and the recorded :class:`Span`; :meth:`set` adds what is known only at
+    the end (how many were admitted) to the recorded Span alone — a
+    ``TraceAnnotation``'s attributes are fixed when it is entered.
+    """
+
+    __slots__ = ("name", "attrs", "start", "end", "_acc", "_key", "_after",
+                 "_ann", "_span")
+
+    def __init__(self, name: str, acc: Optional[Dict[str, float]] = None,
+                 key: Optional[str] = None, after: Optional["phase"] = None,
+                 **attrs: Any) -> None:
+        self.name = name
+        self.attrs = attrs
+        self.end: Optional[float] = None
+        self._acc = acc
+        self._key = key
+        self._after = after
+        self._span: Optional[Span] = None
+
+    def __enter__(self) -> "phase":
+        after = self._after
+        if after is None:
+            self.start = time.monotonic()
+        else:
+            self.start = after.start if after.end is None else after.end
+        self._ann = _jax_annotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        if _TRACER.enabled:
+            self._span = _TRACER._open(self.name, self.attrs)
+        return self
+
+    def set(self, **attrs: Any) -> None:
+        if self._span is not None:
+            self._span.args.update(attrs)
+
+    def __exit__(self, *exc: Any) -> bool:
+        if self._span is not None:
+            _TRACER._close(self._span)
+        self._ann.__exit__(*exc)
+        self.end = time.monotonic()
+        if self._acc is not None:
+            self._acc[self._key] = (self._acc.get(self._key, 0.0)
+                                    + self.end - self.start)
+        return False
+
+
+_COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+_compiles = [0, 0.0]     # count, seconds; written by the one listener below
+_compile_listener_on = False
+_compile_lock = threading.Lock()
+
+
+def _on_compile_event(event: str, duration: float, **_: Any) -> None:
+    if event.endswith(_COMPILE_EVENT_SUFFIX):
+        with _compile_lock:
+            _compiles[0] += 1
+            _compiles[1] += duration
+
+
+def compile_totals() -> tuple:
+    """(count, seconds) of JAX backend compiles in this process since the
+    first call — every executable, whichever jit it belongs to, so a compile
+    per new prompt length shows where a per-function cache-size delta is
+    blind. The first call registers the process's one ``jax.monitoring``
+    listener; callers difference two readings around their own work."""
+    global _compile_listener_on
+    claimed = False
+    with _compile_lock:
+        if not _compile_listener_on:
+            _compile_listener_on = claimed = True
+        totals = (_compiles[0], _compiles[1])
+    if claimed:  # registered outside the lock: one thread wins the claim
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+    return totals
 
 
 @contextlib.contextmanager

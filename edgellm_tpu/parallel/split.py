@@ -48,6 +48,7 @@ from ..serve.recovery import StageLostError
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
+@jax.named_scope("paged_kv.adopt")
 def _adopt_paged_impl(pool_k, pool_v, k_seq, v_seq, dest):
     """Scatter one stream's (n_stages, sz, n, KV, hd) prefill K/V into the
     per-stage pools at flat token indices ``dest``. Donated in-place update;
@@ -91,6 +92,7 @@ def _gather_paged_impl(pool_k, pool_v, idx):
 # *_packed pair is the lossless checkpoint/eviction form.
 
 
+@jax.named_scope("paged_kv.adopt")
 def _paged_rows_set(arr, dest, rows):
     ns, sz, pn, ps = arr.shape[:4]
     tail = arr.shape[4:]
@@ -143,6 +145,13 @@ def _gather_paged_quant_impl(arrays, idx, kv_codec: str):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_paged_pool_impl(arrays, src, dst):
     return tuple(a.at[:, :, dst].set(a[:, :, src]) for a in arrays)
+
+
+@jax.named_scope("unembed_sample")
+def _unembed_last(cfg, placed, hidden):
+    """The ragged step's (B, V) fp32 logits, under the scope the local
+    step's unembed and the sampler share."""
+    return unembed(cfg, placed, hidden)[:, -1]
 
 
 def make_stage_mesh(n_stages: int, n_data: int = 1, n_model: int = 1,
@@ -227,9 +236,12 @@ def run_pipeline_stages(n_stages: int, codecs: list, run_stage, hidden,
     idx = jax.lax.axis_index(axis_name)
     counters = link.init_counters(n_stages - 1) if link is not None else None
     for s in range(n_stages):
-        computed = run_stage(hidden)
+        with jax.named_scope("split.stage"):
+            computed = run_stage(hidden)
         hidden = jnp.where(idx == s, computed, hidden)
-        if s < n_stages - 1:
+        if s == n_stages - 1:
+            break
+        with jax.named_scope(f"split.hop.{s}"):
             if link is not None:
                 imp = hop_imps[s] if codecs[s].needs_importance else None
                 hidden, counters = link.hop(codecs[s], hidden, s, axis_name,
@@ -269,12 +281,15 @@ def run_pipeline_stages_carry(n_stages: int, codecs: list, run_stage, hidden,
     idx = jax.lax.axis_index(axis_name)
     counters = link.init_counters(n_stages - 1) if link is not None else None
     for s in range(n_stages):
-        computed, new_carry = run_stage(hidden, carry)
+        with jax.named_scope("split.stage"):
+            computed, new_carry = run_stage(hidden, carry)
         keep = idx == s
         hidden = jnp.where(keep, computed, hidden)
         carry = jax.tree_util.tree_map(
             lambda new, old: jnp.where(keep, new, old), new_carry, carry)
-        if s < n_stages - 1:
+        if s == n_stages - 1:
+            break
+        with jax.named_scope(f"split.hop.{s}"):
             if link is not None:
                 hidden, counters = link.hop(codecs[s], hidden, s, axis_name,
                                             idx, fault_key, counters)
@@ -414,7 +429,8 @@ def run_pipeline_stages_microbatched(n_stages: int, codecs: list,
             act = jnp.where(idx == 0, micro[t], act)
         here = t - idx  # which µ-batch THIS device holds (traced)
         valid = (here >= 0) & (here < m)
-        computed = run_stage(act)
+        with jax.named_scope("split.stage"):
+            computed = run_stage(act)
         act = jnp.where(valid, computed, act)
         if 0 <= t - (n_stages - 1) < m:
             outs.append(jnp.where(idx == n_stages - 1, act,
@@ -423,24 +439,25 @@ def run_pipeline_stages_microbatched(n_stages: int, codecs: list,
             mb = t - s  # static: only in-flight (cut, µ-batch) hops trace
             if not 0 <= mb < m:
                 continue
-            if link is not None:
+            with jax.named_scope(f"split.hop.{s}"):
+                if link is not None:
+                    imp = _microbatch_imp(codecs[s], hop_imps, s, mb, mb_rows)
+                    act, counters[mb] = link.hop(
+                        codecs[s], act, s, axis_name, idx,
+                        jax.random.fold_in(fault_key, mb), counters[mb],
+                        hop_imp=imp)
+                    continue
+                if fused_plans is not None and fused_plans[s] is not None:
+                    act = fused_hop(fused_plans[s], codecs[s], act, s,
+                                    axis_name, idx, n_dev=n_stages)
+                    continue
                 imp = _microbatch_imp(codecs[s], hop_imps, s, mb, mb_rows)
-                act, counters[mb] = link.hop(
-                    codecs[s], act, s, axis_name, idx,
-                    jax.random.fold_in(fault_key, mb), counters[mb],
-                    hop_imp=imp)
-                continue
-            if fused_plans is not None and fused_plans[s] is not None:
-                act = fused_hop(fused_plans[s], codecs[s], act, s,
-                                axis_name, idx, n_dev=n_stages)
-                continue
-            imp = _microbatch_imp(codecs[s], hop_imps, s, mb, mb_rows)
-            payload = (codecs[s].encode(act, imp) if imp is not None
-                       else codecs[s].encode(act))
-            moved = jax.tree_util.tree_map(
-                lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]),
-                payload)
-            act = jnp.where(idx == s + 1, codecs[s].decode(moved), act)
+                payload = (codecs[s].encode(act, imp) if imp is not None
+                           else codecs[s].encode(act))
+                moved = jax.tree_util.tree_map(
+                    lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]),
+                    payload)
+                act = jnp.where(idx == s + 1, codecs[s].decode(moved), act)
     out = jax.lax.psum(jnp.stack(outs), axis_name)  # (M, B/M, ...)
     out = out.reshape((batch,) + out.shape[2:])
     if link is None:
@@ -486,7 +503,8 @@ def run_pipeline_stages_carry_microbatched(n_stages: int, codecs: list,
         here = t - idx
         valid = (here >= 0) & (here < m)
         b = jnp.clip(here, 0, m - 1)
-        computed, carry = run_stage(act, carry, b, valid)
+        with jax.named_scope("split.stage"):
+            computed, carry = run_stage(act, carry, b, valid)
         act = jnp.where(valid, computed, act)
         if 0 <= t - (n_stages - 1) < m:
             outs.append(jnp.where(idx == n_stages - 1, act,
@@ -495,20 +513,21 @@ def run_pipeline_stages_carry_microbatched(n_stages: int, codecs: list,
             mb = t - s
             if not 0 <= mb < m:
                 continue
-            if link is not None:
-                act, counters[mb] = link.hop(
-                    codecs[s], act, s, axis_name, idx,
-                    jax.random.fold_in(fault_key, mb), counters[mb])
-                continue
-            if fused_plans is not None and fused_plans[s] is not None:
-                act = fused_hop(fused_plans[s], codecs[s], act, s,
-                                axis_name, idx, n_dev=n_stages)
-                continue
-            payload = codecs[s].encode(act)
-            moved = jax.tree_util.tree_map(
-                lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]),
-                payload)
-            act = jnp.where(idx == s + 1, codecs[s].decode(moved), act)
+            with jax.named_scope(f"split.hop.{s}"):
+                if link is not None:
+                    act, counters[mb] = link.hop(
+                        codecs[s], act, s, axis_name, idx,
+                        jax.random.fold_in(fault_key, mb), counters[mb])
+                    continue
+                if fused_plans is not None and fused_plans[s] is not None:
+                    act = fused_hop(fused_plans[s], codecs[s], act, s,
+                                    axis_name, idx, n_dev=n_stages)
+                    continue
+                payload = codecs[s].encode(act)
+                moved = jax.tree_util.tree_map(
+                    lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]),
+                    payload)
+                act = jnp.where(idx == s + 1, codecs[s].decode(moved), act)
     out = jax.lax.psum(jnp.stack(outs), axis_name)
     out = out.reshape((batch,) + out.shape[2:])
     if link is None:
@@ -1837,7 +1856,7 @@ class SplitRuntime:
                     check_vma=False,
                 )(placed["layers"], placed["layers_valid"], hidden,
                   pool_k, pool_v, page_table, lengths, cos_b, sin_b)
-                return unembed(cfg, placed, out)[:, -1], kp, vp
+                return _unembed_last(cfg, placed, out), kp, vp
             out, kp, vp, counters = shard_map(
                 stage_step_paged, mesh=mesh,
                 in_specs=(lspecs, P("stage"), P(), P("stage"), P("stage"),
@@ -1846,7 +1865,7 @@ class SplitRuntime:
                 check_vma=False,
             )(placed["layers"], placed["layers_valid"], hidden,
               pool_k, pool_v, page_table, lengths, cos_b, sin_b)
-            return unembed(cfg, placed, out)[:, -1], kp, vp, counters
+            return _unembed_last(cfg, placed, out), kp, vp, counters
 
         self._paged_fns_cache[key] = step_paged_fn
         return step_paged_fn
@@ -1936,7 +1955,7 @@ class SplitRuntime:
                 )(placed["layers"], placed["layers_valid"], hidden,
                   pool_k, pool_v, pool_ks, pool_vs, page_table, lengths,
                   cos_b, sin_b)
-                return unembed(cfg, placed, out)[:, -1], kp, vp, ks, vs
+                return _unembed_last(cfg, placed, out), kp, vp, ks, vs
             out, kp, vp, ks, vs, counters = shard_map(
                 stage_step_paged_quant, mesh=mesh,
                 in_specs=(lspecs, P("stage"), P(), P("stage"), P("stage"),
@@ -1947,7 +1966,8 @@ class SplitRuntime:
             )(placed["layers"], placed["layers_valid"], hidden,
               pool_k, pool_v, pool_ks, pool_vs, page_table, lengths,
               cos_b, sin_b)
-            return unembed(cfg, placed, out)[:, -1], kp, vp, ks, vs, counters
+            return (_unembed_last(cfg, placed, out), kp, vp, ks, vs,
+                    counters)
 
         self._paged_fns_cache[key] = step_paged_quant_fn
         return step_paged_quant_fn

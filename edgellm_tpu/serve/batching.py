@@ -59,7 +59,7 @@ import functools
 import os
 import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -74,7 +74,8 @@ from ..models.paged_kv import OutOfPages, OutOfSlots, PagedKVCache, \
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
 from ..obs.flight import flight_dump_for
-from ..obs.tracing import span as obs_span
+from ..obs.tracing import compile_totals, phase as obs_phase, \
+    span as obs_span
 from ..utils.concurrency import guarded_by
 from .decode import _prefill_jit, _prefill_suffix_jit, _sample
 from .recovery import (CheckpointError, CheckpointTierMismatchError,
@@ -155,6 +156,7 @@ class Stream:
     resume_prefix: bool = False   # re-publish the prompt's pages on adopt
     admit_seq: int = -1           # admission order; youngest = largest
     evictions: int = 0
+    queued_t: float = 0.0         # monotonic stamp: entered the waiting queue
 
     @property
     def t(self) -> int:
@@ -167,6 +169,7 @@ class Stream:
         return jax.random.key(self.rng_seed)
 
 
+@jax.named_scope("unembed_sample")
 def _batched_sample(logits, keys, steps, temps):
     """Per-slot ``decode._sample``, vectorized bit-identically: slot i's
     token equals ``_sample(logits[i:i+1], fold_in(key_i, step_i), temp_i)``
@@ -227,6 +230,13 @@ def batched_step_cache_size() -> int:
 # sampler is the SAME vmapped _batched_sample, jitted standalone so split
 # streams keep the local path's per-slot bit-identity guarantee
 _split_sample_jit = jax.jit(_batched_sample)
+
+
+#: host-clock counters of ``stats``/``report()``, monotonic seconds: the whole
+#: of every ``step()`` call, its six phases (which tile it), and the time
+#: admitted streams spent in the waiting queue
+_CLOCKS = ("step_wall_s", "admit_s", "grow_s", "build_s", "launch_s",
+           "sync_s", "commit_s", "queue_wait_s")
 
 
 @guarded_by("_stats_lock", fields=["stats"])
@@ -305,7 +315,12 @@ class ContinuousBatcher:
                       "finished": 0, "jit_misses": 0, "emitted_tokens": 0,
                       "prefill_s": 0.0, "decode_s": 0.0,
                       "occ_sum": 0.0, "occ_max": 0.0, "slot_sum": 0.0,
-                      "alloc_sum": 0.0, "alloc_n": 0}
+                      "alloc_sum": 0.0, "alloc_n": 0,
+                      "compiles": 0, "compile_s": 0.0,
+                      **dict.fromkeys(_CLOCKS, 0.0)}
+        # the scheduler thread's clocks since its last fold into ``stats``:
+        # phases add here lock-free, step()/submit()/prefill_hold() fold once
+        self._acc: dict[str, float] = defaultdict(int)
 
     # -- submission --------------------------------------------------------
 
@@ -327,16 +342,22 @@ class ContinuousBatcher:
                 f"{need} cache positions > slot span {self.bcfg.span} "
                 f"(pages_per_slot={self.bcfg.pages_per_slot} x "
                 f"page_size={self.bcfg.page_size})")
+        c0 = compile_totals()
         sid = self._next_sid
-        self._next_sid += 1
-        self._streams[sid] = Stream(sid, prompt, int(max_new_tokens),
-                                    float(temperature), int(rng_seed))
-        self._waiting.append(sid)
-        with self._stats_lock:
-            self.stats["submitted"] += 1
         with obs_span("batch.submit", sid=sid, prompt_len=int(prompt.size),
                       max_new_tokens=int(max_new_tokens)):
-            pass
+            self._next_sid += 1
+            self._streams[sid] = Stream(
+                sid, prompt, int(max_new_tokens), float(temperature),
+                int(rng_seed), queued_t=time.monotonic())
+            self._waiting.append(sid)
+            # not through _acc: a front may submit from another thread than
+            # the one that steps
+            c1 = compile_totals()
+            with self._stats_lock:
+                self.stats["submitted"] += 1
+                self.stats["compiles"] += c1[0] - c0[0]
+                self.stats["compile_s"] += c1[1] - c0[1]
         return sid
 
     def pop_result(self, sid: int) -> np.ndarray:
@@ -415,38 +436,45 @@ class ContinuousBatcher:
         except OutOfSlots:
             return False
         resumed = st.resume is not None
-        t0 = time.monotonic()
-        try:
-            tok0 = self._admit_fill(st, slot)
-        except OutOfPages:
-            # the feasibility probe over-promised (an interior index page
-            # can be unreclaimable while a descendant is slot-held): undo
-            # cleanly — nothing was committed to the stream yet
-            self.pool.free_slot(slot)
-            return False
-        if tok0 is not None:
-            st.tokens.append(int(np.asarray(tok0)[0]))
-        with self._stats_lock:
-            self.stats["prefill_s"] += time.monotonic() - t0
-        st.status, st.slot = "running", slot
-        st.admit_seq = self._admit_seq
-        self._admit_seq += 1
-        self._slot_to_sid[slot] = sid
-        with self._stats_lock:
-            self.stats["admitted"] += 1
-        with obs_span("batch.admit", sid=sid, slot=slot,
-                      microbatch=self._microbatch_of(slot), resumed=resumed):
-            pass
-        if st.t >= st.max_new_tokens:  # max_new_tokens == 1: prefill is all
-            self._finish(st)
+        with obs_phase("batch.admit", sid=sid, slot=slot,
+                       microbatch=self._microbatch_of(slot),
+                       prompt_len=int(st.prompt.size),
+                       resumed=resumed) as ph:
+            t0 = time.monotonic()
+            try:
+                tok0, matched = self._admit_fill(st, slot)
+            except OutOfPages:
+                # the feasibility probe over-promised (an interior index
+                # page can be unreclaimable while a descendant is
+                # slot-held): undo cleanly — nothing was committed to the
+                # stream yet
+                self.pool.free_slot(slot)
+                return False
+            ph.set(matched=matched)
+            if tok0 is not None:
+                # a host sync per admission: token 0 comes back before the
+                # stream may ride the step
+                with obs_phase("batch.admit.tok0_sync", sid=sid):
+                    st.tokens.append(int(np.asarray(tok0)[0]))
+            self._acc["prefill_s"] += time.monotonic() - t0
+            self._acc["queue_wait_s"] += t0 - st.queued_t
+            self._acc["admitted"] += 1
+            st.status, st.slot = "running", slot
+            st.admit_seq = self._admit_seq
+            self._admit_seq += 1
+            self._slot_to_sid[slot] = sid
+            if st.t >= st.max_new_tokens:  # max_new_tokens == 1: prefill is all
+                self._finish(st)
         return True
 
-    def _admit_fill(self, st: Stream, slot: int) -> Optional[jax.Array]:
+    def _admit_fill(self, st: Stream, slot: int) -> tuple:
         """Land one stream's KV into ``slot``'s pages — resume payload,
         full prefill, or (on a prefix-index hit) shared pages plus a
-        suffix-only prefill. Returns the sampled token 0 for fresh admits,
-        None for resumes. Raises :class:`OutOfPages` with the slot still
-        consistent (the caller undoes via ``free_slot``)."""
+        suffix-only prefill. Returns (the sampled token 0 for fresh admits,
+        None for resumes; the prompt positions a prefix hit mapped in).
+        Raises :class:`OutOfPages` with the slot still consistent (the
+        caller undoes via ``free_slot``)."""
+        sid = st.sid
         if st.resume is not None:
             need_len = int(st.resume["length"])
             # resumes adopt privately: the payload mixes prompt and
@@ -454,26 +482,27 @@ class ContinuousBatcher:
             # Quantized tiers carry PACKED codes + scales (never fp rows),
             # so evict -> readmit round-trips the pool bytes exactly.
             packed = "k_codes" in st.resume
-            if self.rt is not None:
-                self.pool.ensure(slot, need_len)
-                dest = self.pool._flat_indices(slot, need_len)
-                if packed:
-                    self._split_pool = self.rt.adopt_paged_rows_packed(
-                        self._split_pool, st.resume["k_codes"],
-                        st.resume["v_codes"], st.resume["k_scale"],
-                        st.resume["v_scale"], dest)
+            with obs_phase("batch.admit.adopt", sid=sid):
+                if self.rt is not None:
+                    self.pool.ensure(slot, need_len)
+                    dest = self.pool._flat_indices(slot, need_len)
+                    if packed:
+                        self._split_pool = self.rt.adopt_paged_rows_packed(
+                            self._split_pool, st.resume["k_codes"],
+                            st.resume["v_codes"], st.resume["k_scale"],
+                            st.resume["v_scale"], dest)
+                    else:
+                        self._split_pool = self.rt.adopt_paged_rows(
+                            self._split_pool, st.resume["k"],
+                            st.resume["v"], dest)
+                    self.pool.lengths[slot] = need_len
+                elif packed:
+                    self.pool.adopt_packed(
+                        slot, st.resume["k_codes"], st.resume["v_codes"],
+                        st.resume["k_scale"], st.resume["v_scale"], need_len)
                 else:
-                    self._split_pool = self.rt.adopt_paged_rows(
-                        self._split_pool, st.resume["k"], st.resume["v"],
-                        dest)
-                self.pool.lengths[slot] = need_len
-            elif packed:
-                self.pool.adopt_packed(
-                    slot, st.resume["k_codes"], st.resume["v_codes"],
-                    st.resume["k_scale"], st.resume["v_scale"], need_len)
-            else:
-                self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
-                                jnp.asarray(st.resume["v"]), need_len)
+                    self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
+                                    jnp.asarray(st.resume["v"]), need_len)
             st.resume = None
             if st.resume_prefix and self.pool.prefix is not None:
                 # migration adopts opt in to re-publishing: the payload's
@@ -482,7 +511,7 @@ class ContinuousBatcher:
                 # the transfer. register_prefix walks only the prompt
                 # tokens — generated rows are never indexed.
                 self.pool.register_prefix(slot, st.prompt)
-            return None
+            return None, 0
         s = st.prompt.size
         matched = 0
         if self.pool.prefix is not None:
@@ -498,32 +527,36 @@ class ContinuousBatcher:
             # the exact generate_split() prefill: same executable, same
             # token-0 key, then the per-stage cache rows scatter into the
             # mesh pools at this slot's pages
-            logits, cache = self.rt.prefill_decode(
-                self.placed, jnp.asarray(st.prompt[None, :]),
-                self.bcfg.span)
-            tok0 = _sample(logits[:, -1], jax.random.fold_in(st.key, 0),
-                           st.temperature)
-            self.pool.ensure(slot, s)
-            dest = self.pool._flat_indices(slot, s)
-            self._split_pool = self.rt.adopt_paged(
-                self._split_pool, cache, 0, dest, s)
-            self.pool.lengths[slot] = s
+            with obs_phase("batch.admit.prefill", sid=sid):
+                logits, cache = self.rt.prefill_decode(
+                    self.placed, jnp.asarray(st.prompt[None, :]),
+                    self.bcfg.span)
+                tok0 = _sample(logits[:, -1], jax.random.fold_in(st.key, 0),
+                               st.temperature)
+            with obs_phase("batch.admit.adopt", sid=sid):
+                self.pool.ensure(slot, s)
+                dest = self.pool._flat_indices(slot, s)
+                self._split_pool = self.rt.adopt_paged(
+                    self._split_pool, cache, 0, dest, s)
+                self.pool.lengths[slot] = s
         else:
             # the exact generate() prefill: same executable, same
             # capacity semantics (KV values are capacity-invariant),
             # same token-0 key
-            last_logits, cache = _prefill_jit(
-                self.cfg, self.params, jnp.asarray(st.prompt[None, :]),
-                self.bcfg.span, self.bcfg.compute_dtype)
-            tok0 = _sample(last_logits, jax.random.fold_in(st.key, 0),
-                           st.temperature)
-            self.pool.adopt(slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s)
+            with obs_phase("batch.admit.prefill", sid=sid):
+                last_logits, cache = _prefill_jit(
+                    self.cfg, self.params, jnp.asarray(st.prompt[None, :]),
+                    self.bcfg.span, self.bcfg.compute_dtype)
+                tok0 = _sample(last_logits, jax.random.fold_in(st.key, 0),
+                               st.temperature)
+            with obs_phase("batch.admit.adopt", sid=sid):
+                self.pool.adopt(slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s)
         if self.pool.prefix is not None:
             # publish this prompt's pages (full blocks + partial tail) so
             # later admits share them; already-indexed blocks just refresh
             # their LRU stamps
             self.pool.register_prefix(slot, st.prompt)
-        return tok0
+        return tok0, matched
 
     def _prefill_suffix_local(self, st: Stream, slot: int,
                               matched: int) -> jax.Array:
@@ -535,22 +568,25 @@ class ContinuousBatcher:
         parity with it is the executed ``batching.prefix-token-identity``
         contract."""
         s = st.prompt.size
-        state = self.pool.gather_slot(slot)  # the matched prefix rows
-        cdtype = (self.bcfg.compute_dtype if self.bcfg.compute_dtype
-                  is not None else jnp.float32)
-        nl, _, kv, hd = state["k"].shape
-        kc = jnp.zeros((nl, 1, self.bcfg.span, kv, hd), cdtype)
-        vc = jnp.zeros_like(kc)
-        cache = KVCache(kc.at[:, 0, :matched].set(state["k"]),
-                        vc.at[:, 0, :matched].set(state["v"]),
-                        jnp.asarray(matched, jnp.int32))
-        logits, cache = _prefill_suffix_jit(
-            self.cfg, self.params, jnp.asarray(st.prompt[None, matched:]),
-            cache, self.bcfg.compute_dtype)
-        tok0 = _sample(logits[:, -1], jax.random.fold_in(st.key, 0),
-                       st.temperature)
-        self.pool.adopt_rows(slot, cache.k[:, 0, matched:s],
-                             cache.v[:, 0, matched:s], matched, s)
+        with obs_phase("batch.admit.prefill", sid=st.sid):
+            state = self.pool.gather_slot(slot)  # the matched prefix rows
+            cdtype = (self.bcfg.compute_dtype if self.bcfg.compute_dtype
+                      is not None else jnp.float32)
+            nl, _, kv, hd = state["k"].shape
+            kc = jnp.zeros((nl, 1, self.bcfg.span, kv, hd), cdtype)
+            vc = jnp.zeros_like(kc)
+            cache = KVCache(kc.at[:, 0, :matched].set(state["k"]),
+                            vc.at[:, 0, :matched].set(state["v"]),
+                            jnp.asarray(matched, jnp.int32))
+            logits, cache = _prefill_suffix_jit(
+                self.cfg, self.params,
+                jnp.asarray(st.prompt[None, matched:]), cache,
+                self.bcfg.compute_dtype)
+            tok0 = _sample(logits[:, -1], jax.random.fold_in(st.key, 0),
+                           st.temperature)
+        with obs_phase("batch.admit.adopt", sid=st.sid):
+            self.pool.adopt_rows(slot, cache.k[:, 0, matched:s],
+                                 cache.v[:, 0, matched:s], matched, s)
         return tok0
 
     def _prefill_suffix_split(self, st: Stream, slot: int,
@@ -561,30 +597,32 @@ class ContinuousBatcher:
         schedule) over the suffix tokens, apply the COW fork copies to the
         mesh pools, and scatter the suffix rows into this slot's pages."""
         s = st.prompt.size
-        idx = self.pool._flat_indices(slot, matched)
-        k_seq, v_seq = self.rt.gather_paged(self._split_pool, idx)
-        ns, sz = k_seq.shape[:2]
-        kv, hd = k_seq.shape[3:]
-        kc = np.zeros((ns, sz, 1, self.bcfg.span, kv, hd), k_seq.dtype)
-        vc = np.zeros_like(kc)
-        kc[:, :, 0, :matched] = k_seq
-        vc[:, :, 0, :matched] = v_seq
-        cache = {"k": jnp.asarray(kc), "v": jnp.asarray(vc),
-                 "length": jnp.asarray(matched, jnp.int32)}
-        logits, cache = self.rt.verify_step(
-            self.placed, cache, jnp.asarray(st.prompt[None, matched:]))
-        tok0 = _sample(logits[:, -1], jax.random.fold_in(st.key, 0),
-                       st.temperature)
-        pairs = self.pool.ensure_writable(slot, s)  # bookkeeping-only forks
-        if pairs:
-            self._split_pool = self.rt.copy_paged_pages(
-                self._split_pool, [o for o, _ in pairs],
-                [n for _, n in pairs])
-        dest = self.pool._flat_indices(slot, s)[matched:]
-        self._split_pool = self.rt.adopt_paged_rows(
-            self._split_pool, cache["k"][:, :, 0, matched:s],
-            cache["v"][:, :, 0, matched:s], dest)
-        self.pool.lengths[slot] = s
+        with obs_phase("batch.admit.prefill", sid=st.sid):
+            idx = self.pool._flat_indices(slot, matched)
+            k_seq, v_seq = self.rt.gather_paged(self._split_pool, idx)
+            ns, sz = k_seq.shape[:2]
+            kv, hd = k_seq.shape[3:]
+            kc = np.zeros((ns, sz, 1, self.bcfg.span, kv, hd), k_seq.dtype)
+            vc = np.zeros_like(kc)
+            kc[:, :, 0, :matched] = k_seq
+            vc[:, :, 0, :matched] = v_seq
+            cache = {"k": jnp.asarray(kc), "v": jnp.asarray(vc),
+                     "length": jnp.asarray(matched, jnp.int32)}
+            logits, cache = self.rt.verify_step(
+                self.placed, cache, jnp.asarray(st.prompt[None, matched:]))
+            tok0 = _sample(logits[:, -1], jax.random.fold_in(st.key, 0),
+                           st.temperature)
+        with obs_phase("batch.admit.adopt", sid=st.sid):
+            pairs = self.pool.ensure_writable(slot, s)  # bookkeeping forks
+            if pairs:
+                self._split_pool = self.rt.copy_paged_pages(
+                    self._split_pool, [o for o, _ in pairs],
+                    [n for _, n in pairs])
+            dest = self.pool._flat_indices(slot, s)[matched:]
+            self._split_pool = self.rt.adopt_paged_rows(
+                self._split_pool, cache["k"][:, :, 0, matched:s],
+                cache["v"][:, :, 0, matched:s], dest)
+            self.pool.lengths[slot] = s
         return tok0
 
     def _gather_state(self, slot: int) -> dict:
@@ -624,6 +662,7 @@ class ContinuousBatcher:
         del self._slot_to_sid[st.slot]
         st.status, st.slot = "waiting", -1
         st.evictions += 1
+        st.queued_t = time.monotonic()
         self._waiting.appendleft(sid)  # resumed work goes to the head
         with self._stats_lock:
             self.stats["evicted"] += 1
@@ -650,8 +689,14 @@ class ContinuousBatcher:
         st = self._streams[sid]
         if st.status != "waiting":
             raise ValueError(f"stream {sid} is not waiting")
-        if not self._try_admit(sid):
-            return None
+        c0 = compile_totals()
+        try:
+            if not self._try_admit(sid):
+                return None
+        finally:
+            # admission outside step(): prefill_s and queue_wait_s move,
+            # admit_s and step_wall_s (clocks of step() calls) do not
+            self._fold_acc(c0)
         self._waiting.remove(sid)
         if st.status == "running":
             self.pool.hold_slot(st.slot)
@@ -738,130 +783,181 @@ class ContinuousBatcher:
             return step_fn._cache_size() + _split_sample_jit._cache_size()
         return batched_step_cache_size()
 
+    def _fold_acc(self, c0: tuple) -> None:
+        """Fold the scheduler thread's clocks and counts since the last
+        fold into ``stats`` — the one ``_stats_lock`` acquisition of a
+        ``step()`` — with the backend compiles since the reading ``c0``."""
+        c1 = compile_totals()
+        acc = self._acc
+        occ_max = acc.pop("occ_max", 0.0)
+        with self._stats_lock:
+            for k, v in acc.items():
+                self.stats[k] += v
+            self.stats["occ_max"] = max(self.stats["occ_max"], occ_max)
+            self.stats["compiles"] += c1[0] - c0[0]
+            self.stats["compile_s"] += c1[1] - c0[1]
+        acc.clear()
+
     def step(self) -> int:
         """Admit what fits, run ONE compiled ragged step over every running
         slot, commit the sampled tokens. Returns the number of streams that
-        advanced (0 = nothing running and nothing admittable)."""
+        advanced (0 = nothing running and nothing admittable).
+
+        The call is the ``batch.step`` span and the ``step_wall_s`` clock,
+        whichever way it returns; its six phases (``batch.step.admit`` /
+        ``grow`` / ``build`` / ``launch`` / ``sync`` / ``commit``, clocks
+        ``admit_s`` ... ``commit_s``) tile it."""
+        c0 = compile_totals()
+        try:
+            with obs_phase("batch.step", self._acc, "step_wall_s",
+                           step=int(self.stats["steps"]),
+                           running=len(self._slot_to_sid),
+                           waiting=len(self._waiting)) as whole:
+                return self._step_phases(whole)
+        finally:
+            self._fold_acc(c0)
+
+    def _step_phases(self, whole: obs_phase) -> int:
+        acc = self._acc
+        step_no = int(self.stats["steps"])  # launches so far: this one's index
         # admit in FIFO order until a stream doesn't fit (no overtaking:
         # admission order stays deterministic)
-        while self._waiting:
-            sid = self._waiting[0]
-            if not self._try_admit(sid):
-                break
-            self._waiting.popleft()
-        running = self._running()
+        with obs_phase("batch.step.admit", acc, "admit_s", after=whole,
+                       step=step_no) as ph:
+            admitted = 0
+            while self._waiting:
+                sid = self._waiting[0]
+                if not self._try_admit(sid):
+                    break
+                self._waiting.popleft()
+                admitted += 1
+            ph.set(admitted=admitted)
+            running = self._running()
         if not running:
             return 0
         # every running slot must be able to take this step's token; evict
         # youngest streams when the pool can't cover a growth (oldest first
         # keeps them protected longest)
-        for st in sorted(running, key=lambda s: s.admit_seq):
-            if st.status != "running":
-                continue  # already evicted by a predecessor's growth
-            try:
-                self._grow_writable(st)
-            except OutOfPages as e:
-                # a growth may need a fresh page (pages_for grew) OR a COW
-                # fork page (the write position sits in a shared page) —
-                # either way at least one page must come free
-                need = max(1, self.pool.pages_for(self._cache_len(st) + 1)
-                           - len(self.pool._slot_pages[st.slot]))
-                if not self._evict_for_pages(need, {st.sid}):
-                    # unservable growth: capture the pool state post-mortem
-                    # before the scheduler unwinds (once per instance)
-                    flight_dump_for(e, sid=st.sid, slot=st.slot,
-                                    free_pages=self.pool.num_free_pages)
-                    raise
-                self._grow_writable(st)
-        running = self._running()
+        with obs_phase("batch.step.grow", acc, "grow_s", after=ph,
+                       step=step_no) as ph:
+            evicted0 = self.stats["evicted"]
+            for st in sorted(running, key=lambda s: s.admit_seq):
+                if st.status != "running":
+                    continue  # already evicted by a predecessor's growth
+                try:
+                    self._grow_writable(st)
+                except OutOfPages as e:
+                    # a growth may need a fresh page (pages_for grew) OR a
+                    # COW fork page (the write position sits in a shared
+                    # page) — either way at least one page must come free
+                    need = max(1,
+                               self.pool.pages_for(self._cache_len(st) + 1)
+                               - len(self.pool._slot_pages[st.slot]))
+                    if not self._evict_for_pages(need, {st.sid}):
+                        # unservable growth: capture the pool state
+                        # post-mortem before the scheduler unwinds (once per
+                        # instance)
+                        flight_dump_for(e, sid=st.sid, slot=st.slot,
+                                        free_pages=self.pool.num_free_pages)
+                        raise
+                    self._grow_writable(st)
+            ph.set(evicted=self.stats["evicted"] - evicted0)
+            running = self._running()
         if not running:
             return 0
 
-        if self._watchdog is not None:
-            self._watchdog.arm()
-        b = self.bcfg.max_slots
-        token_ids = np.zeros((b,), np.int32)
-        steps = np.zeros((b,), np.int32)
-        temps = np.zeros((b,), np.float32)
-        keys = [jax.random.key(0)] * b
-        for st in running:
-            token_ids[st.slot] = st.tokens[-1]
-            steps[st.slot] = st.t
-            temps[st.slot] = st.temperature
-            keys[st.slot] = st.key
-        # the pool's lengths array is the step's write/mask positions: slot
-        # i's cache holds prompt + t-1 fed tokens (== pool lengths by
-        # construction); inactive slots write the trash page
-        page_table, lengths = self.pool.device_tables()
-        misses0 = self._step_cache_size()
-        t0 = time.monotonic()
-        if self.rt is not None:
-            # one ragged split step: every cut hops ONE (max_slots, 1, D)
-            # quantized activation block, the sampler is the same vmapped
-            # _batched_sample the local step fuses in
-            logits, self._split_pool = self.rt.decode_step_paged(
-                self.placed, self._split_pool, page_table, lengths,
-                jnp.asarray(token_ids))
-            toks = _split_sample_jit(logits, jnp.stack(keys),
-                                     jnp.asarray(steps), jnp.asarray(temps))
-        elif self.bcfg.kv_codec != "fp":
-            toks, k, v, ks, vs = _batched_step_quant_jit(
-                self.cfg, self.params, self.pool.pool.k, self.pool.pool.v,
-                self.pool.pool.k_scale, self.pool.pool.v_scale,
-                page_table, lengths, jnp.asarray(token_ids),
-                jnp.stack(keys), jnp.asarray(steps), jnp.asarray(temps),
-                self.bcfg.kv_codec, self.bcfg.compute_dtype)
-            self.pool.pool = QuantPagePool(k, v, ks, vs)
-        else:
-            toks, k, v = _batched_step_jit(
-                self.cfg, self.params, self.pool.pool.k, self.pool.pool.v,
-                page_table, lengths, jnp.asarray(token_ids),
-                jnp.stack(keys), jnp.asarray(steps), jnp.asarray(temps),
-                self.bcfg.compute_dtype)
-            self.pool.pool = type(self.pool.pool)(k, v)
-        toks_host = np.asarray(toks)  # ONE host sync per step
-        step_s = time.monotonic() - t0
-        misses = self._step_cache_size() - misses0
-        with self._stats_lock:
-            self.stats["decode_s"] += step_s
-            self.stats["jit_misses"] += misses
-            self.stats["steps"] += 1
-            step_no = int(self.stats["steps"]) - 1
-        with obs_span("batch.step", step=step_no,
-                      running=len(running), step_ms=round(step_s * 1e3, 3)):
-            pass
+        with obs_phase("batch.step.build", acc, "build_s", after=ph,
+                       step=step_no) as ph:
+            if self._watchdog is not None:
+                self._watchdog.arm()
+            b = self.bcfg.max_slots
+            token_ids = np.zeros((b,), np.int32)
+            steps = np.zeros((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            keys = [jax.random.key(0)] * b
+            for st in running:
+                token_ids[st.slot] = st.tokens[-1]
+                steps[st.slot] = st.t
+                temps[st.slot] = st.temperature
+                keys[st.slot] = st.key
+            # the pool's lengths array is the step's write/mask positions:
+            # slot i's cache holds prompt + t-1 fed tokens (== pool lengths
+            # by construction); inactive slots write the trash page
+            page_table, lengths = self.pool.device_tables()
+            misses0 = self._step_cache_size()
+        with obs_phase("batch.step.launch", acc, "launch_s", after=ph,
+                       step=step_no) as ph:
+            t0 = time.monotonic()
+            if self.rt is not None:
+                # one ragged split step: every cut hops ONE (max_slots, 1, D)
+                # quantized activation block, the sampler is the same
+                # vmapped _batched_sample the local step fuses in
+                logits, self._split_pool = self.rt.decode_step_paged(
+                    self.placed, self._split_pool, page_table, lengths,
+                    jnp.asarray(token_ids))
+                toks = _split_sample_jit(
+                    logits, jnp.stack(keys), jnp.asarray(steps),
+                    jnp.asarray(temps))
+            elif self.bcfg.kv_codec != "fp":
+                toks, k, v, ks, vs = _batched_step_quant_jit(
+                    self.cfg, self.params, self.pool.pool.k,
+                    self.pool.pool.v, self.pool.pool.k_scale,
+                    self.pool.pool.v_scale, page_table, lengths,
+                    jnp.asarray(token_ids), jnp.stack(keys),
+                    jnp.asarray(steps), jnp.asarray(temps),
+                    self.bcfg.kv_codec, self.bcfg.compute_dtype)
+                self.pool.pool = QuantPagePool(k, v, ks, vs)
+            else:
+                toks, k, v = _batched_step_jit(
+                    self.cfg, self.params, self.pool.pool.k,
+                    self.pool.pool.v, page_table, lengths,
+                    jnp.asarray(token_ids), jnp.stack(keys),
+                    jnp.asarray(steps), jnp.asarray(temps),
+                    self.bcfg.compute_dtype)
+                self.pool.pool = type(self.pool.pool)(k, v)
+        with obs_phase("batch.step.sync", acc, "sync_s", after=ph,
+                       step=step_no) as ph:
+            toks_host = np.asarray(toks)  # ONE host sync per step
+            step_s = time.monotonic() - t0
 
-        advanced = 0
-        for st in running:
-            # toks_host is already on host (the single np.asarray sync
-            # above); this int() is numpy scalar unboxing, not a device sync
-            st.tokens.append(int(toks_host[st.slot]))  # graphlint: disable=EG005
-            self.pool.lengths[st.slot] = self._cache_len(st)
-            advanced += 1
-            if st.t >= st.max_new_tokens:
-                self._finish(st)
-        # unique_live_tokens counts each physical page once: with prefix
-        # sharing, summing per-slot lengths would over-count aliased pages
-        # against a reserved-capacity denominator that holds them once
-        # (identical to live_tokens when nothing is shared)
-        occ = self.pool.unique_live_tokens / self.pool.token_capacity
-        slot_util = len(self._slot_to_sid) / b
-        # live tokens per RESERVED token — the denominator is only the pages
-        # actually allocated, the paged answer to static batching's
-        # worst-case (batch x capacity) reservation
-        reserved = (self.pool.num_pages - 1
-                    - self.pool.num_free_pages) * self.pool.page_size
-        alloc_util = (self.pool.unique_live_tokens / reserved
-                      if reserved else None)
-        with self._stats_lock:
-            self.stats["occ_sum"] += occ
-            self.stats["occ_max"] = max(self.stats["occ_max"], occ)
-            self.stats["slot_sum"] += slot_util
-            if alloc_util is not None:
-                self.stats["alloc_sum"] += alloc_util
-                self.stats["alloc_n"] += 1
-        if self._watchdog is not None:
-            self._watchdog.check()
+        with obs_phase("batch.step.commit", acc, "commit_s", after=ph,
+                       step=step_no) as ph:
+            acc["decode_s"] = step_s
+            acc["jit_misses"] = self._step_cache_size() - misses0
+            acc["steps"] = 1
+            finished0 = self.stats["finished"]
+            advanced = 0
+            for st in running:
+                # toks_host is already on host (the single np.asarray sync
+                # above); this int() is numpy scalar unboxing, not a device
+                # sync
+                st.tokens.append(int(toks_host[st.slot]))  # graphlint: disable=EG005
+                self.pool.lengths[st.slot] = self._cache_len(st)
+                advanced += 1
+                if st.t >= st.max_new_tokens:
+                    self._finish(st)
+            ph.set(finished=self.stats["finished"] - finished0)
+            # unique_live_tokens counts each physical page once: with prefix
+            # sharing, summing per-slot lengths would over-count aliased
+            # pages against a reserved-capacity denominator that holds them
+            # once (identical to live_tokens when nothing is shared)
+            occ = self.pool.unique_live_tokens / self.pool.token_capacity
+            # live tokens per RESERVED token — the denominator is only the
+            # pages actually allocated, the paged answer to static
+            # batching's worst-case (batch x capacity) reservation
+            reserved = (self.pool.num_pages - 1
+                        - self.pool.num_free_pages) * self.pool.page_size
+            acc["occ_sum"] = acc["occ_max"] = occ
+            acc["slot_sum"] = len(self._slot_to_sid) / b
+            if reserved:
+                acc["alloc_sum"] = self.pool.unique_live_tokens / reserved
+                acc["alloc_n"] = 1
+            if self._watchdog is not None:
+                self._watchdog.check()
+            # drop the step's device handles on commit's clock: freeing
+            # max_slots key arrays and the step's inputs is not free, and
+            # left to the return it would be time no phase owns
+            del keys, toks, page_table, lengths
         return advanced
 
     def run(self, max_steps: int = 100_000) -> dict[int, np.ndarray]:
@@ -1010,6 +1106,14 @@ class ContinuousBatcher:
             "jit_misses": stats["jit_misses"],
             "prefill_s": stats["prefill_s"],
             "decode_s": dec,
+            # all additive, so report1 - report0 is a window's worth: every
+            # step() call entry to return, its six phases, the waiting-queue
+            # time of admitted streams, and the backend compiles (any jit's,
+            # where jit_misses sees the step executable only) that happened
+            # inside submit()/step()/prefill_hold()
+            **{k: stats[k] for k in _CLOCKS},
+            "compiles": stats["compiles"],
+            "compile_s": stats["compile_s"],
             "decode_tokens_per_s": (emitted / dec) if dec > 0 else 0.0,
             "occupancy_mean": (stats["occ_sum"] / n) if n else 0.0,
             "occupancy_max": stats["occ_max"],

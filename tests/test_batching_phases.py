@@ -1,0 +1,451 @@
+"""The batcher's phases: spans that enclose the work inside ``step()``, the
+host-clock counters they keep, and the named scopes of the device side.
+
+What is held here, on the CPU with a toy model (local pool and, over a
+2-stage CPU mesh, the split runtime): the six phase clocks tile
+``step_wall_s``; ``launch_s + sync_s`` is ``decode_s`` but for two clock
+readings a step; ``prefill_s`` lies inside ``admit_s``; ``queue_wait_s`` is
+the time a stream was held out; ``compiles`` sees a prefill's compile that
+``jit_misses`` is blind to; spans nest by step and by stream when the tracer
+is on and nothing is recorded when it is off; tokens do not depend on the
+tracer; every name is registered; the named scopes change no jaxpr.
+"""
+import ast
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from edgellm_tpu import obs
+from edgellm_tpu.lint.ast_rules import lint_source
+from edgellm_tpu.lint.contracts import graph_fingerprint
+from edgellm_tpu.models import init_params, paged_kv, tiny_config, transformer
+from edgellm_tpu.obs import names as obs_names
+from edgellm_tpu.obs.tracing import compile_totals, phase
+from edgellm_tpu.parallel import split as split_mod
+from edgellm_tpu.serve import batching
+from edgellm_tpu.serve.batching import (BatchingConfig, ContinuousBatcher,
+                                        _batched_step_jit)
+
+CFG = tiny_config("qwen2", num_layers=4, hidden_size=32, num_heads=4,
+                  vocab_size=128)
+# the geometry tests/test_batching.py uses, so the ragged step is shared
+BCFG = BatchingConfig(page_size=8, num_pages=17, max_slots=4,
+                      pages_per_slot=4)
+PHASES = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s", "commit_s")
+STEP_SPANS = tuple("batch.step." + p[:-2] for p in PHASES)
+ADMIT_SPANS = ("batch.admit.prefill", "batch.admit.adopt",
+               "batch.admit.tok0_sync")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(CFG, jax.random.key(1))
+
+
+@pytest.fixture(scope="module")
+def split_rt(params):
+    from edgellm_tpu.parallel import (SplitConfig, SplitRuntime,
+                                      make_stage_mesh)
+
+    rt = SplitRuntime(CFG, SplitConfig(cuts=(2,),
+                                       hop_codecs=("int8_per_token",)),
+                      make_stage_mesh(2))
+    return rt, rt.place_params(params)
+
+
+@pytest.fixture(params=["local", "split"])
+def make(request, params):
+    """A factory of fresh batchers of one kind over the shared geometry."""
+    if request.param == "local":
+        return lambda: ContinuousBatcher(CFG, params, BCFG)
+    rt, placed = request.getfixturevalue("split_rt")
+    return lambda: ContinuousBatcher(CFG, params, BCFG, split_runtime=rt,
+                                     placed_params=placed)
+
+
+@pytest.fixture(autouse=True)
+def _obs_off():
+    obs.disable()
+    obs.get_tracer().clear()
+    yield
+    obs.disable()
+    obs.get_tracer().clear()
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(
+        1, CFG.vocab_size, size=n).astype(np.int32)
+
+
+def _traffic(b, n=6):
+    """More streams than slots, greedy and sampled, distinct lengths."""
+    return [b.submit(_prompt(5 + i, i), 5 + i % 3, temperature=0.5 * (i % 2),
+                     rng_seed=i) for i in range(n)]
+
+
+def _run(make):
+    b = make()
+    sids = _traffic(b)
+    results = b.run()
+    return b, [results[s] for s in sids]
+
+
+# ---------------------------------------------------------------------------
+# counters
+# ---------------------------------------------------------------------------
+
+
+def test_six_phases_tile_step_wall(make):
+    _run(make)                      # compiles land in the first batcher
+    b, _ = _run(make)
+    r = b.report()
+    assert r["steps"] >= 8 and r["compiles"] == 0
+    six = sum(r[k] for k in PHASES)
+    assert all(r[k] > 0 for k in PHASES)
+    assert six <= r["step_wall_s"]
+    assert six == pytest.approx(r["step_wall_s"], rel=0.01)
+
+
+def test_launch_plus_sync_is_decode_s_and_prefill_lies_in_admit(make):
+    b, _ = _run(make)
+    r = b.report()
+    both = r["launch_s"] + r["sync_s"]
+    # decode_s starts after launch's annotation is entered and stops before
+    # sync's is left: two clock readings a step apart, never more
+    assert r["decode_s"] <= both
+    assert both - r["decode_s"] < 2e-3 * r["steps"]
+    assert 0 < r["prefill_s"] <= r["admit_s"]
+    assert r["admitted"] == 6 and r["finished"] == 6
+
+
+def test_counters_are_additive_across_report_deltas(make):
+    b = make()
+    _traffic(b)
+    b.step()
+    r0 = b.report()
+    b.run()
+    r1 = b.report()
+    steps = r1["steps"] - r0["steps"]
+    assert steps >= 1
+    for k in PHASES + ("step_wall_s", "decode_s"):
+        assert r1[k] > r0[k], k
+    six = sum(r1[k] - r0[k] for k in PHASES)
+    assert six == pytest.approx(r1["step_wall_s"] - r0["step_wall_s"],
+                                rel=0.02)
+
+
+def test_a_step_with_nothing_to_do_still_counts_its_wall(make):
+    b = make()
+    assert b.step() == 0
+    r = b.report()
+    assert r["steps"] == 0 and r["step_wall_s"] > 0
+    assert r["admit_s"] == pytest.approx(r["step_wall_s"], rel=0.5)
+    assert r["launch_s"] == r["sync_s"] == 0.0
+
+
+class _ShiftedClock:
+    """``time`` for the batcher with a monotonic clock the test can push."""
+
+    def __init__(self):
+        import time
+
+        self._time, self.offset = time, 0.0
+
+    def monotonic(self):
+        return self._time.monotonic() + self.offset
+
+
+def test_queue_wait_grows_by_the_time_a_stream_was_held_out(make,
+                                                            monkeypatch):
+    clock = _ShiftedClock()
+    monkeypatch.setattr(batching, "time", clock)
+    b = make()
+    for i in range(BCFG.max_slots):             # fill every slot
+        b.submit(_prompt(6, i), 4, rng_seed=i)
+    held = b.submit(_prompt(6, 99), 2, rng_seed=99)
+    b.step()
+    r0 = b.report()
+    assert r0["admitted"] == BCFG.max_slots and len(b._waiting) == 1
+    assert 0 <= r0["queue_wait_s"] < 5.0         # nobody waited for long
+    clock.offset += 100.0                        # the held stream waits
+    while b._streams[held].status == "waiting":
+        b.step()
+    r1 = b.report()
+    assert r1["admitted"] == BCFG.max_slots + 1
+    grown = r1["queue_wait_s"] - r0["queue_wait_s"]
+    assert 100.0 <= grown < 105.0
+
+
+def test_compiles_sees_a_prefill_at_a_new_length_where_jit_misses_is_blind(
+        params):
+    """A prompt length no test of this process has prefilled compiles
+    ``_prefill_jit`` again; the step executable is warm, so ``jit_misses``
+    stays 0 and only ``compiles`` moves."""
+    warm = ContinuousBatcher(CFG, params, BCFG)
+    warm.submit(_prompt(5), 3)
+    warm.run()
+    before = compile_totals()
+    b = ContinuousBatcher(CFG, params, BCFG)
+    b.submit(_prompt(23, 7), 3)                  # 23: used nowhere else
+    b.run()
+    r = b.report()
+    assert r["jit_misses"] == 0
+    assert r["compiles"] >= 1 and r["compile_s"] > 0
+    assert r["compiles"] == compile_totals()[0] - before[0]
+    again = ContinuousBatcher(CFG, params, BCFG)
+    again.submit(_prompt(23, 8), 3)
+    again.run()
+    assert again.report()["compiles"] == 0
+
+
+def test_prefill_hold_folds_its_clocks_without_a_step(params):
+    b = ContinuousBatcher(CFG, params, BCFG)
+    sid = b.submit(_prompt(7), 3)
+    st = b.prefill_hold(sid)
+    r = b.report()
+    assert st is not None and r["admitted"] == 1 and r["prefill_s"] > 0
+    assert r["queue_wait_s"] >= 0 and r["step_wall_s"] == 0.0
+    b.release_handoff(sid)
+
+
+# ---------------------------------------------------------------------------
+# spans
+# ---------------------------------------------------------------------------
+
+
+def _encloses(outer, inner):
+    return (outer.ts_us <= inner.ts_us
+            and inner.ts_us + inner.dur_us <= outer.ts_us + outer.dur_us + 1)
+
+
+def test_spans_nest_by_step_and_by_stream_when_the_tracer_is_on(make):
+    obs.enable(obs.ObservabilityConfig())
+    b, _ = _run(make)
+    spans = obs.get_tracer().spans()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    steps = by_name["batch.step"]
+    assert len(steps) == b.report()["steps"]    # every call launched here
+    assert [s.args["step"] for s in steps] == list(range(len(steps)))
+    for name in STEP_SPANS:
+        assert len(by_name[name]) == len(steps), name
+        for s in by_name[name]:
+            parent = steps[s.args["step"]]
+            assert _encloses(parent, s), (name, s.args)
+    admits = {s.args["sid"]: s for s in by_name["batch.admit"]}
+    assert sorted(admits) == list(range(6))
+    for s in admits.values():
+        assert {"slot", "microbatch", "prompt_len", "resumed",
+                "matched"} <= set(s.args)
+        assert any(_encloses(p, s) for p in by_name["batch.step.admit"])
+    for name in ADMIT_SPANS:
+        assert len(by_name[name]) == 6, name
+        for s in by_name[name]:
+            assert _encloses(admits[s.args["sid"]], s), (name, s.args)
+    assert sum(s.args["admitted"] for s in by_name["batch.step.admit"]) == 6
+    assert sum(s.args["finished"] for s in by_name["batch.step.commit"]) <= 6
+    assert len(by_name["batch.submit"]) == 6
+    # no span is left that encloses nothing: every one has a duration
+    assert all(s.dur_us > 0 for s in spans if s.name.startswith("batch."))
+
+
+def test_nothing_is_recorded_and_tokens_are_the_same_with_the_tracer_off(
+        make):
+    _, off = _run(make)
+    assert obs.get_tracer().spans() == []
+    obs.enable(obs.ObservabilityConfig())
+    _, on = _run(make)
+    assert obs.get_tracer().spans()
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_phase_reaches_a_profiler_capture_without_arming_obs(tmp_path,
+                                                             params):
+    """The point of ``phase``: a bare ``jax.profiler`` session sees the
+    batcher's spans with their attributes, the tracer untouched."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    b = ContinuousBatcher(CFG, params, BCFG)
+    b.submit(_prompt(5), 3)
+    b.step()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        b.submit(_prompt(6, 1), 3)
+        b.step()
+    finally:
+        jax.profiler.stop_trace()
+    assert obs.get_tracer().spans() == []
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("batch."):
+                    seen.setdefault(ev.name, []).append(dict(ev.stats))
+    assert set(seen) >= set(STEP_SPANS) | set(ADMIT_SPANS) | {"batch.step",
+                                                              "batch.admit"}
+    assert int(seen["batch.step"][0]["step"]) == 1
+    assert int(seen["batch.admit"][0]["sid"]) == 1
+
+
+def test_phase_chains_its_clock_and_keeps_late_attributes_for_the_span():
+    acc = {}
+    obs.enable(obs.ObservabilityConfig())
+    with phase("batch.step", acc, "whole", step=0) as whole:
+        with phase("batch.step.admit", acc, "a", after=whole) as ph:
+            ph.set(admitted=2)
+        with phase("batch.step.grow", acc, "b", after=ph) as ph:
+            pass
+    assert acc["a"] + acc["b"] <= acc["whole"]
+    assert acc["a"] + acc["b"] == pytest.approx(acc["whole"], abs=2e-4)
+    spans = {s.name: s for s in obs.get_tracer().spans()}
+    assert spans["batch.step.admit"].args == {"admitted": 2}
+    assert spans["batch.step"].args == {"step": 0}
+    with phase("batch.step"):                  # no accumulator: span only
+        pass
+
+
+# ---------------------------------------------------------------------------
+# names and scopes
+# ---------------------------------------------------------------------------
+
+SOURCES = ("serve/batching.py", "models/paged_kv.py", "models/transformer.py",
+           "parallel/split.py")
+
+
+def _literal_names(callees):
+    root = os.path.dirname(os.path.abspath(batching.__file__))
+    found = set()
+    for rel in SOURCES:
+        path = os.path.join(os.path.dirname(root), rel)
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and node.args and getattr(
+                    node.func, "attr", getattr(node.func, "id", "")) in callees:
+                arg = node.args[0]
+                if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
+                    found.add(arg.value)
+                elif isinstance(arg, ast.JoinedStr):
+                    found.add("".join(
+                        p.value if isinstance(p, ast.Constant) else "*"
+                        for p in arg.values))
+    return found
+
+
+def test_every_span_and_scope_name_is_registered():
+    spans = _literal_names({"obs_phase", "obs_span"})
+    assert spans >= set(STEP_SPANS) | set(ADMIT_SPANS) | {
+        "batch.step", "batch.admit", "batch.submit"}
+    assert all(obs_names.span_registered(n) for n in spans), spans
+    scopes = _literal_names({"named_scope"})
+    assert scopes == set(obs_names.SCOPE_NAMES) | set(
+        obs_names.SCOPE_TEMPLATES)
+    assert all(obs_names.scope_registered(n) for n in scopes)
+    assert not obs_names.scope_registered("paged_kv.writ")
+    assert not obs_names.span_registered("batch.step.lunch")
+
+
+def test_eg007_flags_an_unregistered_phase_or_scope_name():
+    src = ('import jax\nfrom edgellm_tpu.obs.tracing import phase\n'
+           'def f(acc):\n'
+           '    with phase("batch.step.lunch", acc, "x"):\n'
+           '        pass\n'
+           '    with jax.named_scope("paged_kv.writ"):\n'
+           '        pass\n'
+           '    with jax.named_scope(f"split.hop.{1}"):\n'
+           '        pass\n')
+    found = [f for f in lint_source(src, "x.py") if f.rule == "EG007"]
+    assert sorted(f.line for f in found) == [4, 6]
+
+
+def test_batching_holds_no_span_that_encloses_only_pass():
+    with open(batching.__file__) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.With):
+            assert not all(isinstance(s, ast.Pass) for s in node.body), (
+                f"line {node.lineno}: a with block that encloses only pass")
+
+
+def _step_args(params):
+    b = ContinuousBatcher(CFG, params, BCFG)
+    table, lengths = b.pool.device_tables()
+    n = BCFG.max_slots
+    return (params, b.pool.pool.k, b.pool.pool.v, table, lengths,
+            jnp.zeros((n,), jnp.int32), jnp.stack([jax.random.key(0)] * n),
+            jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32))
+
+
+def _step(p, k, v, table, lengths, toks, keys, steps, temps):
+    logits, k, v = paged_kv.paged_decode_step(CFG, p, k, v, table, lengths,
+                                              toks)
+    return batching._batched_sample(logits, keys, steps, temps), k, v
+
+
+def test_named_scopes_change_no_jaxpr_of_the_batched_step(params,
+                                                          monkeypatch):
+    """The obs-identity contract extended to the device side: with every
+    scope taken out (decorators unwrapped, ``jax.named_scope`` a no-op) the
+    step traces to the same jaxpr, byte for byte; only the lowered module's
+    location metadata differs."""
+    import contextlib
+
+    args = _step_args(params)
+    with_scopes = graph_fingerprint(_step, *args)
+    lowered = jax.jit(_step).lower(*args).as_text(debug_info=True)
+    for scope in ("paged_kv.write", "attn.decode", "mlp", "unembed_sample"):
+        assert scope in lowered, scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(paged_kv, "_attention_decode_paged",
+                        paged_kv._attention_decode_paged.__wrapped__)
+    monkeypatch.setattr(paged_kv, "mlp", transformer.mlp.__wrapped__)
+    monkeypatch.setattr(batching, "_batched_sample",
+                        batching._batched_sample.__wrapped__)
+
+    def bare_step(*a):          # a new function: nothing cached is reused
+        return _step(*a)
+
+    assert graph_fingerprint(bare_step, *args) == with_scopes
+    bare = jax.jit(bare_step).lower(*args).as_text(debug_info=True)
+    assert "paged_kv.write" not in bare and "attn.decode" not in bare
+
+
+def test_split_step_carries_stage_and_hop_scopes(split_rt, params):
+    rt, placed = split_rt
+    b = ContinuousBatcher(CFG, params, BCFG, split_runtime=rt,
+                          placed_params=placed)
+    fn = rt._paged_decode_fns(BCFG.num_pages, BCFG.page_size)
+    table, lengths = b.pool.device_tables()
+    text = fn.lower(placed, b._split_pool["k"], b._split_pool["v"], table,
+                    lengths, jnp.zeros((BCFG.max_slots,), jnp.int32)
+                    ).as_text(debug_info=True)
+    for scope in ("split.stage", "split.hop.0", "paged_kv.write",
+                  "unembed_sample"):
+        assert scope in text, scope
+    assert "split.hop.1" not in text            # one cut
+    pk = b._split_pool["k"]
+    rows = jnp.zeros(pk.shape[:2] + (3,) + pk.shape[4:], pk.dtype)
+    adopt = split_mod._adopt_paged_impl.lower(
+        pk, b._split_pool["v"], rows, rows,
+        jnp.arange(3)).as_text(debug_info=True)
+    assert "paged_kv.adopt" in adopt
+
+
+def test_local_step_executable_keeps_its_name():
+    """Trace readers find the step by ``program.step_module``; the scopes
+    are metadata inside it and must not rename it."""
+    assert _batched_step_jit.__name__ == "_batched_step_jit"
+    assert paged_kv._adopt_impl.__name__ == "_adopt_impl"
