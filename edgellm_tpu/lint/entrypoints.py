@@ -172,7 +172,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     ptab = jnp.zeros((MS, PPS), jnp.int32)
     plens = jnp.zeros((MS,), jnp.int32)
     ptoks = jnp.zeros((MS,), jnp.int32)
-    pkeys = jnp.stack([jax.random.key(0)] * MS)
+    pkeys = jnp.tile(batching._key_data(0), (MS, 1))
     psteps = jnp.zeros((MS,), jnp.int32)
     ptemps = jnp.zeros((MS,), jnp.float32)
     run_one("paged.decode_step",
@@ -308,7 +308,6 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     # ---- all FOUR pool buffers — codes AND scales — stay donated in the
     # ---- lowered executable) --------------------------------------------
     qpool = paged_kv.init_quant_pool(cfg, NPG, PGS, "int8_per_channel")
-    qkeys = jnp.stack([jax.random.key(0)] * MS)
     qsteps = jnp.zeros((MS,), jnp.int32)
     qtemps = jnp.zeros((MS,), jnp.float32)
     run_one("paged.decode_step_quant",
@@ -321,7 +320,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             ctx={"donate_min": 4},
             lowerable=batching._batched_step_quant_jit,
             lower_args=(cfg, params, qpool.k, qpool.v, qpool.k_scale,
-                        qpool.v_scale, ptab, plens, ptoks, qkeys, qsteps,
+                        qpool.v_scale, ptab, plens, ptoks, pkeys, qsteps,
                         qtemps, "int8_per_channel", None))
 
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state

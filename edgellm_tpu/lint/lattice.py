@@ -369,7 +369,7 @@ class _Lattice:
         return ms, pps, pgs, max(npg, 2), codec
 
     def entry_batched(self, p: dict, notes: List[str]) -> List[_Entry]:
-        jax, jnp = self.jax, self.jnp
+        jnp = self.jnp
         cfg, params = self.cfg, self.params
         ms, pps, pgs, npg, codec = self._pool_geom(p, notes)
         key = f"batched:{ms}:{pps}:{pgs}:{npg}:{codec}"
@@ -381,7 +381,7 @@ class _Lattice:
             tab = jnp.zeros((ms, pps), jnp.int32)
             lens = jnp.zeros((ms,), jnp.int32)
             toks = jnp.zeros((ms,), jnp.int32)
-            keys = jnp.stack([jax.random.key(0)] * ms)
+            keys = jnp.tile(batching._key_data(0), (ms, 1))
             steps = jnp.zeros((ms,), jnp.int32)
             temps = jnp.zeros((ms,), jnp.float32)
             if codec == "fp":

@@ -7,20 +7,24 @@ module schedules many streams through ONE jitted decode step built on
 
 - a fixed pool of ``max_slots`` slots rides through
   :func:`~edgellm_tpu.models.paged_kv.paged_decode_step` every step; the page
-  table, per-slot lengths, last tokens, RNG keys, step indices and
+  table, per-slot lengths, last tokens, RNG key data, step indices and
   temperatures are all TRACED inputs, so admitting, evicting, finishing or
   growing a stream never retraces — the steady state is jit-miss-free by
   construction and :func:`batched_step_cache_size` exposes the counter so
-  tests assert it;
+  tests assert it. The host fills one numpy row per running slot for each of
+  them, so a step costs the same handful of transfers and ONE dispatch
+  whatever ``max_slots`` is (two on the split path: step, then sampler);
 - prompts are prefetched through the SAME ``_prefill_jit`` executable
   ``generate`` uses, the first token sampled with the same ``fold_in(key, 0)``
   — then the prompt's KV is adopted into the stream's pages;
 - sampling inside the batched step reproduces ``decode._sample`` per slot
-  bitwise: ``fold_in`` and ``categorical`` are vmapped over per-slot
-  (key, step) pairs, greedy rows select the argmax lane — so every stream's
-  tokens are bit-identical to running it alone through ``generate`` (the
-  ``batching.decode-step-identity`` graphlint contract re-proves this on
-  every lint run);
+  bitwise: each slot's key is wrapped INSIDE the jit from the stream's
+  ``(2,) uint32`` key data (:func:`_key_data`, fixed when the stream is made:
+  the bits of ``jax.random.key(rng_seed)``), ``fold_in`` and ``categorical``
+  are vmapped over per-slot (key, step) pairs, greedy rows select the argmax
+  lane — so every stream's tokens are bit-identical to running it alone
+  through ``generate`` (the ``batching.decode-step-identity`` graphlint
+  contract re-proves this on every lint run);
 - when the pool runs out of pages the youngest running stream is evicted:
   its pages are gathered back to a contiguous host prefix (byte-identical to
   a contiguous cache) and the stream re-queues; re-admission adopts the
@@ -140,6 +144,18 @@ class BatchingConfig:
         return self.pages_per_slot * self.page_size
 
 
+def _key_data(seed: int) -> np.ndarray:
+    """``jax.random.key_data(jax.random.key(seed))`` as a host array, with no
+    device work where the default PRNG allows it: threefry2x32 with x64 off
+    seeds a key as ``[0, low 32 bits of the seed]`` (``threefry_seed`` shifts
+    an int32 right by 32 and masks it with 0xFFFFFFFF). Any other
+    implementation or x64 is read back from JAX once."""
+    if (jax.config.jax_default_prng_impl == "threefry2x32"
+            and not jax.config.jax_enable_x64):
+        return np.array([0, seed & 0xFFFFFFFF], np.uint32)
+    return np.asarray(jax.random.key_data(jax.random.key(seed)))
+
+
 @dataclass
 class Stream:
     """One request's host-side state across admit/evict/finish."""
@@ -157,6 +173,12 @@ class Stream:
     admit_seq: int = -1           # admission order; youngest = largest
     evictions: int = 0
     queued_t: float = 0.0         # monotonic stamp: entered the waiting queue
+    # the bits of ``key``, fixed for the stream's life: its row of the step's
+    # key table, copied per step with no device work
+    key_data: np.ndarray = field(init=False, repr=False)
+
+    def __post_init__(self):
+        self.key_data = _key_data(self.rng_seed)
 
     @property
     def t(self) -> int:
@@ -170,13 +192,17 @@ class Stream:
 
 
 @jax.named_scope("unembed_sample")
-def _batched_sample(logits, keys, steps, temps):
+def _batched_sample(logits, key_data, steps, temps):
     """Per-slot ``decode._sample``, vectorized bit-identically: slot i's
     token equals ``_sample(logits[i:i+1], fold_in(key_i, step_i), temp_i)``
-    — fold_in/categorical vmap to the same draws as their single-row calls,
-    argmax rows are batch-invariant, and the where just selects which lane
-    slot i uses (temperature stays a TRACED per-slot value, so greedy and
-    sampled streams share one executable)."""
+    with ``key_i`` the typed key over row i of ``key_data`` (the
+    ``(max_slots, 2) uint32`` table of :func:`_key_data` rows, wrapped here so
+    the host never makes a key per slot) — fold_in/categorical vmap to the
+    same draws as their single-row calls, argmax rows are batch-invariant,
+    and the where just selects which lane slot i uses (temperature stays a
+    TRACED per-slot value, so greedy and sampled streams share one
+    executable)."""
+    keys = jax.random.wrap_key_data(key_data)
     folded = jax.vmap(jax.random.fold_in)(keys, steps)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     safe = jnp.where(temps > 0.0, temps, 1.0)
@@ -189,12 +215,12 @@ def _batched_sample(logits, keys, steps, temps):
                    static_argnames=("cfg", "compute_dtype"),
                    donate_argnums=(2, 3))
 def _batched_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                      page_table, lengths, token_ids, keys, steps, temps,
+                      page_table, lengths, token_ids, key_data, steps, temps,
                       compute_dtype):
     logits, pool_k, pool_v = paged_decode_step(
         cfg, params, pool_k, pool_v, page_table, lengths, token_ids,
         compute_dtype=compute_dtype)
-    return _batched_sample(logits, keys, steps, temps), pool_k, pool_v
+    return _batched_sample(logits, key_data, steps, temps), pool_k, pool_v
 
 
 @functools.partial(jax.jit,
@@ -202,7 +228,7 @@ def _batched_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
                    donate_argnums=(2, 3, 4, 5))
 def _batched_step_quant_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
                             pool_k_scale, pool_v_scale, page_table, lengths,
-                            token_ids, keys, steps, temps, kv_codec,
+                            token_ids, key_data, steps, temps, kv_codec,
                             compute_dtype):
     """Quantized-tier twin of :func:`_batched_step_jit`: the four
     QuantPagePool arrays are donated, sampling is the same vmapped
@@ -213,7 +239,7 @@ def _batched_step_quant_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
             cfg, params, pool_k, pool_v, pool_k_scale, pool_v_scale,
             page_table, lengths, token_ids, kv_codec=kv_codec,
             compute_dtype=compute_dtype))
-    return (_batched_sample(logits, keys, steps, temps),
+    return (_batched_sample(logits, key_data, steps, temps),
             pool_k, pool_v, pool_k_scale, pool_v_scale)
 
 
@@ -321,6 +347,9 @@ class ContinuousBatcher:
         # the scheduler thread's clocks since its last fold into ``stats``:
         # phases add here lock-free, step()/submit()/prefill_hold() fold once
         self._acc: dict[str, float] = defaultdict(int)
+        # the step's key table with no stream in it: every row key 0's data,
+        # what a free slot samples (and discards) with
+        self._free_key_rows = np.tile(_key_data(0), (self.bcfg.max_slots, 1))
 
     # -- submission --------------------------------------------------------
 
@@ -874,12 +903,12 @@ class ContinuousBatcher:
             token_ids = np.zeros((b,), np.int32)
             steps = np.zeros((b,), np.int32)
             temps = np.zeros((b,), np.float32)
-            keys = [jax.random.key(0)] * b
+            key_data = self._free_key_rows.copy()
             for st in running:
                 token_ids[st.slot] = st.tokens[-1]
                 steps[st.slot] = st.t
                 temps[st.slot] = st.temperature
-                keys[st.slot] = st.key
+                key_data[st.slot] = st.key_data
             # the pool's lengths array is the step's write/mask positions:
             # slot i's cache holds prompt + t-1 fed tokens (== pool lengths
             # by construction); inactive slots write the trash page
@@ -896,14 +925,14 @@ class ContinuousBatcher:
                     self.placed, self._split_pool, page_table, lengths,
                     jnp.asarray(token_ids))
                 toks = _split_sample_jit(
-                    logits, jnp.stack(keys), jnp.asarray(steps),
+                    logits, jnp.asarray(key_data), jnp.asarray(steps),
                     jnp.asarray(temps))
             elif self.bcfg.kv_codec != "fp":
                 toks, k, v, ks, vs = _batched_step_quant_jit(
                     self.cfg, self.params, self.pool.pool.k,
                     self.pool.pool.v, self.pool.pool.k_scale,
                     self.pool.pool.v_scale, page_table, lengths,
-                    jnp.asarray(token_ids), jnp.stack(keys),
+                    jnp.asarray(token_ids), jnp.asarray(key_data),
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.kv_codec, self.bcfg.compute_dtype)
                 self.pool.pool = QuantPagePool(k, v, ks, vs)
@@ -911,7 +940,7 @@ class ContinuousBatcher:
                 toks, k, v = _batched_step_jit(
                     self.cfg, self.params, self.pool.pool.k,
                     self.pool.pool.v, page_table, lengths,
-                    jnp.asarray(token_ids), jnp.stack(keys),
+                    jnp.asarray(token_ids), jnp.asarray(key_data),
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
                 self.pool.pool = type(self.pool.pool)(k, v)
@@ -954,10 +983,9 @@ class ContinuousBatcher:
                 acc["alloc_n"] = 1
             if self._watchdog is not None:
                 self._watchdog.check()
-            # drop the step's device handles on commit's clock: freeing
-            # max_slots key arrays and the step's inputs is not free, and
-            # left to the return it would be time no phase owns
-            del keys, toks, page_table, lengths
+            # drop the step's device handles on commit's clock: left to the
+            # return it would be time no phase owns
+            del toks, page_table, lengths
         return advanced
 
     def run(self, max_steps: int = 100_000) -> dict[int, np.ndarray]:

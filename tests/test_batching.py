@@ -407,7 +407,8 @@ def test_batched_sample_matches_single_row():
     keys = jnp.stack([jax.random.key(s) for s in (7, 8, 9, 10)])
     steps = jnp.asarray([0, 3, 5, 2], jnp.int32)
     temps = jnp.asarray([0.0, 0.7, 1.3, 0.0], jnp.float32)
-    got = np.asarray(_batched_sample(logits, keys, steps, temps))
+    got = np.asarray(_batched_sample(logits, jax.random.key_data(keys),
+                                     steps, temps))
     for i in range(4):
         want = _sample(logits[i:i + 1],
                        jax.random.fold_in(keys[i], steps[i]),

@@ -384,14 +384,14 @@ def _step_args(params):
     table, lengths = b.pool.device_tables()
     n = BCFG.max_slots
     return (params, b.pool.pool.k, b.pool.pool.v, table, lengths,
-            jnp.zeros((n,), jnp.int32), jnp.stack([jax.random.key(0)] * n),
+            jnp.zeros((n,), jnp.int32), jnp.asarray(b._free_key_rows),
             jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32))
 
 
-def _step(p, k, v, table, lengths, toks, keys, steps, temps):
+def _step(p, k, v, table, lengths, toks, key_data, steps, temps):
     logits, k, v = paged_kv.paged_decode_step(CFG, p, k, v, table, lengths,
                                               toks)
-    return batching._batched_sample(logits, keys, steps, temps), k, v
+    return batching._batched_sample(logits, key_data, steps, temps), k, v
 
 
 def test_named_scopes_change_no_jaxpr_of_the_batched_step(params,
