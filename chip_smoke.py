@@ -382,6 +382,61 @@ def split_phase(cfg, vocab_size: int, *, prompt_lens=PROMPT_LENS,
     return out
 
 
+def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
+                 evict_after: int = 5) -> dict:
+    """A tiny ``granitemoehybrid`` stream (Mamba-2 and NoPE attention layers,
+    routed + shared experts, float32) admitted, stepped, evicted and
+    readmitted through ``ContinuousBatcher`` on the chip: the recurrent state
+    leaves the device with the K/V rows and comes back. The prompt is longer
+    than ``moe.DENSE_MAX_TOKENS`` so that the prefill takes the grouped expert
+    products (whose rows past the last group a TPU leaves undefined) and many
+    chunks of the scan. Its tokens equal an undisturbed stream's, and
+    ``forward`` over prompt + tokens puts each of them first, both sides at
+    ``highest``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from edgellm_tpu.models import forward, init_params
+    from edgellm_tpu.models.configs import tiny_hybrid_config
+    from edgellm_tpu.serve.batching import BatchingConfig, ContinuousBatcher
+
+    # 4 of the router's 8 experts held: half the assignments are to absent
+    # experts, the rows past the last group
+    cfg = tiny_hybrid_config(experts_held=4, expert_offset=2)
+    params = init_params(cfg, jax.random.key(SEED))
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=24)
+    prompt = np.random.default_rng(SEED).integers(
+        1, cfg.vocab_size, size=prompt_len).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        calm = ContinuousBatcher(cfg, params, bcfg)
+        sid = calm.submit(prompt, n_new, rng_seed=1)
+        want = calm.run()[sid]
+        b = ContinuousBatcher(cfg, params, bcfg)
+        b.submit(prompt[:9], n_new, rng_seed=2)           # a neighbour
+        sid = b.submit(prompt, n_new, rng_seed=1)
+        for _ in range(evict_after):
+            b.step()
+        b.evict(sid)
+        b.pool.check_invariants()
+        got = b.run()[sid]
+        report = b.report()
+        seq = np.concatenate([prompt, got])[None]
+        logits = np.asarray(jax.jit(
+            lambda p, x: forward(cfg, p, x)[0])(params, jnp.asarray(seq))[0])
+    assert np.array_equal(got, want), (got.tolist(), want.tolist())
+    rows = logits[prompt_len - 1:-1]                      # predict got[i]
+    gaps = rows.max(axis=-1) - rows[np.arange(n_new), got]
+    scale = float(np.abs(rows).max())
+    assert gaps.max() <= 1e-4 * scale, (gaps.tolist(), scale)
+    assert report["evicted"] == 1 and report["state_bytes"] > 0
+    return {"tokens": int(n_new), "evicted": report["evicted"],
+            "state_bytes": report["state_bytes"],
+            "routed_local": report["routed_local"],
+            "gap_max_over_logit_max": float(gaps.max() / scale)}
+
+
 def smoke(report: dict, save) -> dict:
     """Every phase in order, at full width. ``save()`` persists ``report``
     after each phase so a failed run leaves what it learned."""
@@ -412,6 +467,7 @@ def smoke(report: dict, save) -> dict:
             f"serve_{s}", serve_params(s), cfg.vocab_size))
     phase("sweep", sweep_phase)
     phase("reference", lambda: reference_phase(cfg))
+    phase("hybrid", hybrid_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

@@ -323,6 +323,30 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
                         qpool.v_scale, ptab, plens, ptoks, pkeys, qsteps,
                         qtemps, "int8_per_channel", None))
 
+    # ---- a stack with recurrent state (granitemoehybrid): the hybrid
+    # ---- ragged step — collective-free; the K/V pages, the per-slot state
+    # ---- store (conv, ssm) and the expert counter, FIVE buffers, stay
+    # ---- donated in the lowered executable ------------------------------
+    from ..models import hybrid
+    from ..models.configs import tiny_hybrid_config
+
+    hcfg = tiny_hybrid_config()
+    hparams = transformer.init_params(hcfg, jax.random.key(0))
+    hpool = paged_kv.init_pool(hcfg, NPG, PGS)
+    hstate = paged_kv.init_slot_state(hcfg, MS)
+    hcount = jnp.zeros((hcfg.num_layers, hcfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_hybrid",
+            lambda p, pk, pv, cv, sm, ct, pt, ln, t:
+                hybrid.paged_decode_step_hybrid(
+                    hcfg, p, pk, pv, cv, sm, ct, pt, ln, t),
+            (hparams, hpool.k, hpool.v, hstate.conv, hstate.ssm, hcount,
+             ptab, plens, ptoks),
+            ctx={"donate_min": 5},
+            lowerable=batching._batched_hybrid_step_jit,
+            lower_args=(hcfg, hparams, hpool.k, hpool.v, hstate.conv,
+                        hstate.ssm, hcount, ptab, plens, ptoks, pkeys,
+                        qsteps, qtemps, None))
+
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state
     # feeds the byte-identical ragged step graph the pre-quantization
     # batcher traces — the disabled-build jaxpr fingerprint half of the

@@ -25,6 +25,14 @@ class ModelConfig:
         QKV biases but bias-free o/gate/up/down projections, grouped-query attention.
       - ``"llama"``: identical wiring to qwen2 with no biases anywhere
         (Llama-2/3 models; beyond the reference's two families).
+      - ``"granitemoehybrid"``: two kinds of layer in one stack
+        (``layer_types``: Mamba-2 mixers and position-free GQA attention),
+        every layer's feed-forward a routed expert layer plus a shared
+        expert, four scalar multipliers (IBM Granite 4.0-H). The serving
+        path only; see ``models/hybrid.py``.
+
+    The fields after ``rope_scaling`` exist for that family and default to
+    "absent", so the three one-block families hash and trace as before.
     """
 
     family: str
@@ -43,10 +51,77 @@ class ModelConfig:
     #: ("llama3", factor, low_freq_factor, high_freq_factor,
     #: original_max_position_embeddings) — hashable for the frozen config.
     rope_scaling: Optional[tuple] = None
+    #: per-layer mixer kind, ``"mamba"`` or ``"attention"``; empty = every
+    #: layer is the family's one block
+    layer_types: tuple = ()
+    #: routed experts: ``num_experts`` is the ROUTER's width (the published
+    #: count), ``experts_held`` how many of them this chip computes, starting
+    #: at ``expert_offset`` (expert parallelism: the rest live elsewhere and
+    #: nothing here stands in for them). 0 held = all of them.
+    num_experts: int = 0
+    experts_per_tok: int = 0
+    expert_width: int = 0
+    shared_width: int = 0
+    experts_held: int = 0
+    expert_offset: int = 0
+    #: Mamba-2 mixer: heads x head width = d_inner; B/C are ``n_groups`` x
+    #: ``d_state``; prefill runs in chunks of ``mamba_chunk`` positions
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk: int = 256
+    #: Granite's scalars: h0 = embed * embedding_multiplier; every sublayer's
+    #: output * residual_multiplier; logits / logits_scaling; attention
+    #: scores * attention_multiplier (None = 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    #: no positional encoding of any kind in the attention layers
+    nope: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.family == "granitemoehybrid"
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep K/V pages: all of them, or the attention ones."""
+        if not self.layer_types:
+            return self.num_layers
+        return sum(1 for t in self.layer_types if t == "attention")
+
+    @property
+    def mamba_layers(self) -> int:
+        return sum(1 for t in self.layer_types if t == "mamba")
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Width the causal convolution runs over: x, then B and C."""
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
+
+    @property
+    def local_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def q_prescale(self) -> float:
+        """What ``q`` is multiplied by so that the attention paths' own
+        ``1/sqrt(head_dim)`` comes out as ``attention_multiplier``."""
+        if self.attention_multiplier is None:
+            return 1.0
+        return float(self.attention_multiplier) * float(self.head_dim) ** 0.5
 
     @property
     def rotary_dim(self) -> int:
@@ -57,12 +132,44 @@ class ModelConfig:
         return self.family in ("gpt_neox", "qwen2")
 
     def __post_init__(self):
-        if self.family not in ("gpt_neox", "qwen2", "llama"):
+        if self.family not in ("gpt_neox", "qwen2", "llama",
+                               "granitemoehybrid"):
             raise ValueError(f"unknown family: {self.family}")
+        if self.is_hybrid:
+            self._check_hybrid()
+        elif self.layer_types or self.num_experts or self.mamba_heads:
+            raise ValueError(
+                f"layer_types / experts / mamba fields belong to the "
+                f"granitemoehybrid family, not {self.family!r}")
         if self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_kv_heads must evenly divide num_heads")
+
+    def _check_hybrid(self):
+        if len(self.layer_types) != self.num_layers or any(
+                t not in ("mamba", "attention") for t in self.layer_types):
+            raise ValueError(
+                f"layer_types must name 'mamba' or 'attention' for each of "
+                f"the {self.num_layers} layers, got {self.layer_types!r}")
+        if not 0 < self.experts_per_tok <= self.num_experts:
+            raise ValueError("experts_per_tok must be in [1, num_experts]")
+        if not (0 <= self.expert_offset
+                and self.expert_offset + self.local_experts
+                <= self.num_experts):
+            raise ValueError(
+                f"experts held [{self.expert_offset}, "
+                f"{self.expert_offset + self.local_experts}) lie outside the "
+                f"router's {self.num_experts}")
+        if self.expert_width < 1 or self.shared_width < 1:
+            raise ValueError("expert_width and shared_width must be >= 1")
+        if self.mamba_layers and min(
+                self.mamba_heads, self.mamba_head_dim, self.mamba_d_state,
+                self.mamba_d_conv - 1, self.mamba_chunk) < 1:
+            raise ValueError("a mamba layer needs heads, head width, d_state, "
+                             "d_conv >= 2 and a chunk length")
+        if self.mamba_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_groups must evenly divide mamba_heads")
 
 
 # EleutherAI/pythia-70m — facts per SURVEY.md section 2.1 (6 layers, d=512, 8 heads,
@@ -129,11 +236,72 @@ LLAMA_3_2_1B = ModelConfig(
     rope_scaling=("llama3", 32.0, 1.0, 4.0, 8192),
 )
 
+# ibm-granite/granite-4.0-h-small (32B-A9B, 2025-10) — config.json: 40 layers
+# in a period of ten (five Mamba-2, one NoPE GQA attention, four Mamba-2), d
+# 4096, 72 routed experts of width 768 top-10 plus a shared expert of 1536 on
+# every layer, tied 100352-row table. Whole: all 72 experts held.
+_GRANITE_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+GRANITE_4_0_H_SMALL = ModelConfig(
+    family="granitemoehybrid",
+    vocab_size=100352,
+    hidden_size=4096,
+    num_layers=40,
+    num_heads=32,
+    num_kv_heads=8,
+    intermediate_size=768,
+    max_position_embeddings=131072,
+    norm_eps=1e-5,
+    tie_word_embeddings=True,
+    layer_types=_GRANITE_PERIOD * 4,
+    num_experts=72,
+    experts_per_tok=10,
+    expert_width=768,
+    shared_width=1536,
+    mamba_heads=128,
+    mamba_head_dim=64,
+    mamba_d_state=128,
+    mamba_d_conv=4,
+    mamba_n_groups=1,
+    mamba_chunk=256,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    logits_scaling=16.0,
+    attention_multiplier=0.0078125,
+    nope=True,
+)
+
+
+def tiny_hybrid_config(*, layer_types: tuple = ("mamba", "mamba", "attention",
+                                                "mamba"),
+                       hidden_size: int = 64, num_heads: int = 4,
+                       num_kv_heads: int = 2, vocab_size: int = 256,
+                       num_experts: int = 8, experts_per_tok: int = 3,
+                       experts_held: int = 0, expert_offset: int = 0,
+                       mamba_chunk: int = 8) -> ModelConfig:
+    """A small granitemoehybrid for tests: every mechanism of the published
+    model (both layer kinds, routed + shared experts, the four multipliers,
+    NoPE, a non-default attention scale) at toy widths."""
+    return ModelConfig(
+        family="granitemoehybrid", vocab_size=vocab_size,
+        hidden_size=hidden_size, num_layers=len(layer_types),
+        num_heads=num_heads, num_kv_heads=num_kv_heads,
+        intermediate_size=32, max_position_embeddings=512, norm_eps=1e-5,
+        tie_word_embeddings=True, layer_types=tuple(layer_types),
+        num_experts=num_experts, experts_per_tok=experts_per_tok,
+        expert_width=32, shared_width=48, experts_held=experts_held,
+        expert_offset=expert_offset, mamba_heads=8, mamba_head_dim=16,
+        mamba_d_state=16, mamba_d_conv=4, mamba_n_groups=1,
+        mamba_chunk=mamba_chunk, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        attention_multiplier=1.0 / 32.0, nope=True)
+
 
 def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
                 num_heads: int = 4, num_kv_heads: int | None = None,
                 vocab_size: int = 256, intermediate_size: int | None = None) -> ModelConfig:
     """Small random-init config for tests (no pretrained weights in this environment)."""
+    if family == "granitemoehybrid":
+        return tiny_hybrid_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -159,8 +327,10 @@ PRESETS = {
     "qwen2-0.5b": QWEN2_0_5B,
     "qwen2-1.5b": QWEN2_1_5B,
     "llama-3.2-1b": LLAMA_3_2_1B,
+    "granite-4.0-h-small": GRANITE_4_0_H_SMALL,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
     "tiny-llama": tiny_config("llama", num_layers=6),
+    "tiny-granite-hybrid": tiny_hybrid_config(),
 }

@@ -1,0 +1,117 @@
+"""The routed expert layer of the ``granitemoehybrid`` family, told which
+experts it holds.
+
+The router keeps its published width: logits over all ``cfg.num_experts``,
+the top ``cfg.experts_per_tok`` of them, weights the softmax over those. The
+chip computes the experts ``[cfg.expert_offset, + cfg.local_experts)`` for the
+tokens routed to them, plus the shared expert on every token. No token is
+dropped and there is no capacity factor. What the absent experts would have
+added is left out — that is their chip's part of the sum, and nothing here
+stands in for them or for the exchange (expert parallelism without its
+all-to-all: the share of one chip).
+
+Two ways through the held experts, chosen by the static token count:
+
+- up to :data:`DENSE_MAX_TOKENS` tokens (the decode step): every held expert
+  over every token, the combine weight (zero where a token was not routed to
+  an expert) applied before the down projection, which then contracts over
+  experts and width at once. At 60 tokens x 10 of 72 experts every held
+  expert is hit anyway, so all their weights are read either way and the
+  layer is bound by those bytes, not by the wasted multiplies;
+- more tokens (a prefill): assignments sorted by expert and three
+  ``jax.lax.ragged_dot`` grouped products over the held groups; the rows of
+  assignments to absent experts sort last, belong to no group and are
+  selected out of the result.
+
+Scopes: ``moe.route``, ``moe.experts``, ``moe.shared``.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .configs import ModelConfig
+
+#: token counts up to this take the dense path: where dense multiplies
+#: (tokens x held x 3DF) stop hiding under the held experts' weight bytes on
+#: a v5e (197 TFLOP/s against 819 GB/s: ~240 tokens a byte-bound pass)
+DENSE_MAX_TOKENS = 256
+
+
+def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray):
+    """u (T, D) -> (expert ids (T, k) int32 over the PUBLISHED width,
+    weights (T, k) float32: softmax over the chosen k logits)."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("td,de->te", u, router_w,
+                            preferred_element_type=jnp.float32)
+        vals, idx = jax.lax.top_k(logits, cfg.experts_per_tok)
+        return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+def _local(cfg: ModelConfig, idx):
+    """Published expert ids -> (index among the held experts, held?)."""
+    local = idx - cfg.expert_offset
+    return local, (local >= 0) & (local < cfg.local_experts)
+
+
+def _assignments(cfg: ModelConfig, local, held):
+    """Assignments per held expert (Eh,) int32 among those ``held`` marks."""
+    eh = cfg.local_experts
+    return jnp.sum(
+        jax.nn.one_hot(jnp.where(held, local, eh), eh + 1, dtype=jnp.int32),
+        axis=tuple(range(held.ndim)))[:eh]
+
+
+def _experts_dense(cfg: ModelConfig, mp: dict, u, idx, weights):
+    eh = cfg.local_experts
+    local, held = _local(cfg, idx)
+    # (T, Eh): a token's weight for each held expert, 0 where not routed
+    combine = jnp.sum(
+        jax.nn.one_hot(jnp.where(held, local, eh), eh, dtype=jnp.float32)
+        * weights[..., None], axis=1)
+    gate = jnp.einsum("td,edf->tef", u, mp["w_gate"])
+    up = jnp.einsum("td,edf->tef", u, mp["w_up"])
+    hidden = (jax.nn.silu(gate) * up
+              * combine[..., None].astype(u.dtype))          # (T, Eh, F)
+    return jnp.einsum("tef,efd->td", hidden, mp["w_down"])
+
+
+def _experts_grouped(cfg: ModelConfig, mp: dict, u, idx, weights):
+    t, k = idx.shape
+    eh = cfg.local_experts
+    local, held = _local(cfg, idx)
+    group = jnp.where(held, local, eh).reshape(-1)          # absent: last
+    order = jnp.argsort(group, stable=True)
+    sizes = _assignments(cfg, local, held)
+    rows = u[order // k]                                     # (T*k, D)
+    gate = jax.lax.ragged_dot(rows, mp["w_gate"], sizes)
+    up = jax.lax.ragged_dot(rows, mp["w_up"], sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, mp["w_down"], sizes)
+    # rows past the last held group belong to no product, and what a grouped
+    # product leaves in them is not defined (a TPU leaves NaN): they are
+    # selected out, not multiplied by a zero weight
+    kept = held.reshape(-1)[order][:, None]
+    w = weights.reshape(-1)[order][:, None]
+    out = jnp.where(kept, out.astype(jnp.float32) * w, 0.0)
+    back = jnp.argsort(order)                                # undo the sort
+    return jnp.sum(out[back].reshape(t, k, -1), axis=1).astype(u.dtype)
+
+
+def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,
+              active: jnp.ndarray | None = None):
+    """u (T, D) normalised input -> (routed part of the held experts + the
+    shared expert (T, D), assignments per held expert (Eh,) int32 counted
+    over the rows ``active`` (T,) bool marks — all rows when None)."""
+    idx, weights = route(cfg, mp["router"], u)
+    with jax.named_scope("moe.experts"):
+        experts = (_experts_dense if u.shape[0] <= DENSE_MAX_TOKENS
+                   else _experts_grouped)
+        routed = experts(cfg, mp, u, idx, weights)
+        local, held = _local(cfg, idx)
+        if active is not None:
+            held = held & active[:, None]
+        counts = _assignments(cfg, local, held)
+    with jax.named_scope("moe.shared"):
+        shared = (jax.nn.silu(u @ mp["shared_gate"])
+                  * (u @ mp["shared_up"])) @ mp["shared_down"]
+    return routed + shared, counts

@@ -324,7 +324,7 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                          f"got {num_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads,
+    shape = (cfg.kv_layers, num_pages, page_size, cfg.num_kv_heads,
              cfg.head_dim)
     return PagePool(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
@@ -499,6 +499,44 @@ def _copy_pages_impl(pool_k, pool_v, src, dst):
             pool_v.at[:, dst].set(pool_v[:, src]))
 
 
+# The per-slot state store of a hybrid stack (models/hybrid.py): row j of
+# conv (L_mamba, max_slots, d_conv-1, conv_dim) and ssm (L_mamba, max_slots,
+# H, P, N), both float32, is the convolution window and the recurrent state of
+# mamba layer j for each slot. A slot's state has a fixed size and is
+# overwritten every step, so there is nothing to page: one row a slot.
+
+
+class SlotState(NamedTuple):
+    conv: jnp.ndarray
+    ssm: jnp.ndarray
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.conv.nbytes) + int(self.ssm.nbytes)
+
+
+def init_slot_state(cfg: ModelConfig, max_slots: int) -> SlotState:
+    from .hybrid import state_shapes
+
+    conv, ssm = state_shapes(cfg, max_slots)
+    return SlotState(jnp.zeros(conv, jnp.float32),
+                     jnp.zeros(ssm, jnp.float32))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+@jax.named_scope("state.adopt")
+def _state_set_impl(conv_all, ssm_all, conv, ssm, slot):
+    """Overwrite one slot's rows with (L_mamba, ...) state: a prefill's, a
+    resumed stream's, or zeros when the slot is allocated."""
+    return (conv_all.at[:, slot].set(conv.astype(conv_all.dtype)),
+            ssm_all.at[:, slot].set(ssm.astype(ssm_all.dtype)))
+
+
+@jax.jit
+def _state_get_impl(conv_all, ssm_all, slot):
+    return conv_all[:, slot], ssm_all[:, slot]
+
+
 # Quantized-pool twins. Page moves (defrag, COW) are BYTE moves — codes and
 # scales ride the same permutation/copy untouched, so a forked page is
 # byte-identical to its original and defrag never requantizes. Only adopt
@@ -602,6 +640,18 @@ class PagedKVCache:
             raise ValueError(
                 f"pages_per_slot must be >= 1, got {pages_per_slot}")
         self.cfg = cfg
+        if cfg.is_hybrid:
+            from .hybrid import refuse_recurrent_state
+
+            if prefix_cache is not None and prefix_cache.enabled:
+                refuse_recurrent_state(cfg, "prefix sharing (PrefixIndex)")
+            if resolve_kv_codec(kv_codec).quantized:
+                refuse_recurrent_state(
+                    cfg, f"the quantized KV tier kv_codec={kv_codec!r}")
+            if not materialize:
+                refuse_recurrent_state(
+                    cfg, "a bookkeeping-only PagedKVCache (the split "
+                         "runtime's allocator)")
         # KV-at-rest tier. Every page bookkeeping path below (alloc, COW,
         # refcounts, radix index, defrag permutation) is codec-agnostic — a
         # page is a page; only the device-pool surgery dispatches on tier.
@@ -617,6 +667,11 @@ class PagedKVCache:
         else:
             self.pool = init_quant_pool(cfg, num_pages, page_size,
                                         self.kv_codec)
+        # the second kind of state: one fixed-size row a slot for every
+        # mamba layer of a hybrid stack, managed with the slot (zeroed at
+        # alloc, written at adopt, gathered at eviction, dead once freed)
+        self.state: Optional[SlotState] = (
+            init_slot_state(cfg, max_slots) if cfg.is_hybrid else None)
         self.page_size = page_size
         self.num_pages = num_pages
         self.max_slots = max_slots
@@ -675,6 +730,11 @@ class PagedKVCache:
         when nothing is shared; under prefix sharing it is the honest
         occupancy numerator (summing per-slot lengths over-counts aliased
         pages — the ``report()`` occupancy bug this property fixes)."""
+        if not self.shared_pages:
+            # every page is one holder's: the walk below would add up the
+            # slots' lengths, a page at a time, in Python, on the batcher's
+            # commit clock with the chip idle (12 ms a step at 192 slots)
+            return self.live_tokens
         cover = np.zeros((self.num_pages,), np.int64)
         for s in range(self.max_slots):
             if not self.active[s]:
@@ -714,6 +774,10 @@ class PagedKVCache:
             if not self.active[s]:
                 self.active[s] = True
                 self.lengths[s] = 0
+                if self.state is not None:
+                    # a reused slot starts from zero, not from its last
+                    # tenant's state
+                    self.adopt_state(s, 0.0, 0.0)
                 return s
         raise OutOfSlots(f"all {self.max_slots} slots active")
 
@@ -1146,6 +1210,35 @@ class PagedKVCache:
             jnp.asarray(k_scale), jnp.asarray(v_scale), dest)
         self.lengths[slot] = length
 
+    @property
+    def state_bytes(self) -> int:
+        """Device bytes of the per-slot recurrent state store (0 for a
+        family whose state is its pages)."""
+        return self.state.nbytes if self.state is not None else 0
+
+    def adopt_state(self, slot: int, conv, ssm) -> None:
+        """Overwrite ``slot``'s recurrent state with ``conv`` (L_mamba,
+        d_conv-1, conv_dim) and ``ssm`` (L_mamba, H, P, N) — a prefill's, or
+        an evicted stream's gathered rows (scalars broadcast: zeros at
+        allocation)."""
+        if self.state is None:
+            raise ValueError(f"family {self.cfg.family!r} keeps no recurrent "
+                             f"state")
+        if not self.active[slot]:
+            raise ValueError(f"slot {slot} is not active")
+        self.state = SlotState(*_state_set_impl(
+            self.state.conv, self.state.ssm, jnp.asarray(conv, jnp.float32),
+            jnp.asarray(ssm, jnp.float32), jnp.asarray(slot, jnp.int32)))
+
+    def gather_state(self, slot: int) -> dict:
+        """``slot``'s recurrent state as host arrays {"conv", "ssm"}: what
+        an eviction keeps beside :meth:`gather_slot`'s K/V rows."""
+        if self.state is None:
+            return {}
+        conv, ssm = _state_get_impl(self.state.conv, self.state.ssm,
+                                    jnp.asarray(slot, jnp.int32))
+        return {"conv": np.asarray(conv), "ssm": np.asarray(ssm)}
+
     def gather_slot(self, slot: int) -> dict:
         """Read ``slot``'s K/V back as the contiguous host state dict the
         recovery checkpoint stores: {"k": (L, length, KV, hd), "v": ...,
@@ -1307,6 +1400,9 @@ class PagedKVCache:
                       "index_holds": self._index_holds.copy()})
         if self.prefix is not None:
             state["prefix_index"] = self.prefix.to_array()
+        if self.state is not None:
+            state["state_conv"] = np.asarray(self.state.conv)
+            state["state_ssm"] = np.asarray(self.state.ssm)
         return state
 
     def load_state_dict(self, state: dict) -> None:
@@ -1340,6 +1436,13 @@ class PagedKVCache:
                                       jnp.asarray(state["v_codes"]),
                                       jnp.asarray(state["k_scale"]),
                                       jnp.asarray(state["v_scale"]))
+        if self.state is not None:
+            if state["state_ssm"].shape != self.state.ssm.shape:
+                raise ValueError(
+                    f"state store shape mismatch: checkpoint "
+                    f"{state['state_ssm'].shape} vs {self.state.ssm.shape}")
+            self.state = SlotState(jnp.asarray(state["state_conv"]),
+                                   jnp.asarray(state["state_ssm"]))
         self.page_table = np.asarray(state["page_table"], np.int32).copy()
         self.lengths = np.asarray(state["lengths"], np.int32).copy()
         self.active = np.asarray(state["active"], bool).copy()
@@ -1382,6 +1485,20 @@ class PagedKVCache:
     def check_invariants(self) -> None:
         """Raise AssertionError on any aliasing/leak/ownership/refcount
         violation — the test suite calls this after every mutation."""
+        assert (self.state is not None) == self.cfg.is_hybrid, \
+            "a state store exists exactly for a family with recurrent state"
+        if self.state is not None:
+            from .hybrid import state_shapes
+
+            want = state_shapes(self.cfg, self.max_slots)
+            assert (self.state.conv.shape, self.state.ssm.shape) == want, \
+                f"state store shapes {self.state.conv.shape}, " \
+                f"{self.state.ssm.shape} != {want}"
+            assert self.state.conv.dtype == self.state.ssm.dtype == \
+                jnp.float32, "recurrent state must stay float32"
+            if self.pool is not None:
+                assert self.pool.k.shape[0] == self.cfg.kv_layers, \
+                    "the page pool holds the attention layers only"
         assert 0 not in self._free, "trash page 0 on the free list"
         assert self._owner[0] == FREE, "trash page 0 owned by a slot"
         assert self._refcount[0] == 0, "trash page 0 referenced"
@@ -1497,8 +1614,11 @@ def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
         q = q + lp["bq"].reshape(h, hd)
         k = k + lp["bk"].reshape(kv, hd)
         v = v + lp["bv"].reshape(kv, hd)
-    q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
-    k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
+    if cfg.nope:  # position-free attention: cos_b/sin_b are not read
+        q = q * jnp.asarray(cfg.q_prescale, q.dtype)
+    else:
+        q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
+        k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
     pn, ps = k_pages.shape[0], k_pages.shape[1]
     # slot i's new token lands in its (length // page_size)-th page at offset
     # length % page_size; inactive slots (all-zero table rows) land in the

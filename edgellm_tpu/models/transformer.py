@@ -400,6 +400,14 @@ def forward(cfg: ModelConfig, params: dict, input_ids: jnp.ndarray, *,
     head -> logits; ``qwen_layer_wise.py:78-104``) as one jit-compiled function.
     """
     params = _cast_params(params, compute_dtype)
+    if cfg.is_hybrid:
+        from .hybrid import forward_hybrid, refuse_recurrent_state
+
+        if boundary_fn is not None or capture_stats or collect_hidden:
+            refuse_recurrent_state(
+                cfg, "forward() with a boundary hook, attention statistics "
+                     "or collected hiddens (the sweep drivers)")
+        return forward_hybrid(cfg, params, input_ids), {}
     hidden = embed(params, input_ids)
     hidden, aux = run_layers(cfg, params, hidden, boundary_fn=boundary_fn,
                              capture_stats=capture_stats,
@@ -500,6 +508,12 @@ def prefill(cfg: ModelConfig, params: dict, input_ids: jnp.ndarray,
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity={capacity}]")
     params = _cast_params(params, compute_dtype)
+    if cfg.is_hybrid:
+        from .hybrid import prefill_hybrid, refuse_recurrent_state
+
+        if boundary_fn is not None:
+            refuse_recurrent_state(cfg, "prefill() with a boundary hook")
+        return prefill_hybrid(cfg, params, input_ids, capacity)
     hidden = embed(params, input_ids)
     hidden, aux = run_layers(cfg, params, hidden, boundary_fn=boundary_fn,
                              collect_kv=True)
@@ -636,6 +650,12 @@ def decode_step(cfg: ModelConfig, params: dict, cache: KVCache,
     token reuses the one executable.
     """
     params = _cast_params(params, compute_dtype)
+    if cfg.is_hybrid:
+        from .hybrid import decode_step_hybrid, refuse_recurrent_state
+
+        if boundary_fn is not None:
+            refuse_recurrent_state(cfg, "decode_step() with a boundary hook")
+        return decode_step_hybrid(cfg, params, cache, token_ids)
     if token_ids.ndim == 1:
         token_ids = token_ids[:, None]
     hidden = embed(params, token_ids)  # (B, 1, D)
@@ -785,6 +805,10 @@ def _blocked_ce(cfg: ModelConfig, params: dict, hidden: jnp.ndarray,
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> dict:
     """Random init (tests/bench only — environment has no pretrained checkpoints)."""
+    if cfg.is_hybrid:
+        from .hybrid import init_params_hybrid
+
+        return init_params_hybrid(cfg, key, dtype)
     keys = iter(jax.random.split(key, 32))
     init = lambda *shape: (jax.random.normal(next(keys), shape, jnp.float32) * 0.02).astype(dtype)
     L, D, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size

@@ -192,6 +192,15 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "mlp",
     "unembed_sample",     # final norm + LM head + the per-slot sampler
     "split.stage",        # one stage iteration of the split unroll
+    # a stack with recurrent state and routed experts (models/mamba2.py,
+    # models/moe.py, the per-slot state store of models/paged_kv.py)
+    "ssm.proj",           # a Mamba-2 layer's in and out projections
+    "ssm.step",           # decode: window update, recurrence, gated norm
+    "ssm.scan",           # prefill: the convolution and the chunked scan
+    "moe.route",          # router logits, top-k, softmax over the chosen
+    "moe.experts",        # the held experts' part for the tokens routed here
+    "moe.shared",         # the shared expert on every token
+    "state.adopt",        # a slot's recurrent state overwritten (or zeroed)
 })
 
 #: scope templates: ``split.hop.<cut>`` is one boundary hop (encode, the

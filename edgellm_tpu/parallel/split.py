@@ -692,6 +692,9 @@ class SplitRuntime:
                  fec: Optional[Any] = None,
                  hedge: Optional[Any] = None,
                  pipeline: Optional[PipelineConfig] = None):
+        from ..models.hybrid import refuse_recurrent_state
+
+        refuse_recurrent_state(cfg, "the split runtime (SplitRuntime)")
         self.cfg = cfg
         self.split = split
         self.mesh = mesh

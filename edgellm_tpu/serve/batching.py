@@ -72,8 +72,9 @@ import jax
 import jax.numpy as jnp
 
 from ..models.configs import ModelConfig
+from ..models.hybrid import paged_decode_step_hybrid, refuse_recurrent_state
 from ..models.paged_kv import OutOfPages, OutOfSlots, PagedKVCache, \
-    PrefixCacheConfig, QuantPagePool, paged_decode_step, \
+    PrefixCacheConfig, QuantPagePool, SlotState, paged_decode_step, \
     paged_decode_step_quant, resolve_kv_codec
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
@@ -243,13 +244,38 @@ def _batched_step_quant_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
             pool_k, pool_v, pool_k_scale, pool_v_scale)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "compute_dtype"),
+                   donate_argnums=(2, 3, 4, 5, 6))
+def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
+                             conv, ssm, expert_tokens, page_table, lengths,
+                             token_ids, key_data, steps, temps,
+                             compute_dtype):
+    """The ragged step of a stack with recurrent state
+    (``models/hybrid.py``): the K/V pages of the attention layers, the
+    per-slot state store of the mamba layers and the per-expert assignment
+    counter are all donated and come back updated. A SEPARATE jit: the
+    one-block families keep the executable above."""
+    if compute_dtype is not None:
+        params = jax.tree_util.tree_map(
+            lambda a: a.astype(compute_dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+    logits, pool_k, pool_v, conv, ssm, expert_tokens = (
+        paged_decode_step_hybrid(cfg, params, pool_k, pool_v, conv, ssm,
+                                 expert_tokens, page_table, lengths,
+                                 token_ids))
+    return (_batched_sample(logits, key_data, steps, temps),
+            pool_k, pool_v, conv, ssm, expert_tokens)
+
+
 def batched_step_cache_size() -> int:
     """Executables compiled for the ragged step so far in this process — the
     jit-miss counter :meth:`ContinuousBatcher.step` reports deltas of.
     Counts BOTH tier executables: a steady-state serve loop must stop
     missing on whichever one its pool uses."""
     return (_batched_step_jit._cache_size()
-            + _batched_step_quant_jit._cache_size())
+            + _batched_step_quant_jit._cache_size()
+            + _batched_hybrid_step_jit._cache_size())
 
 
 # the split step returns (max_slots, V) logits from decode_step_paged; the
@@ -285,6 +311,14 @@ class ContinuousBatcher:
         self.params = params
         self.bcfg = bcfg if bcfg is not None else BatchingConfig()
         self.rt = split_runtime
+        if cfg.is_hybrid:
+            # refused here, at construction, and by name: each would need a
+            # snapshot of the recurrent state that it does not take
+            if split_runtime is not None:
+                refuse_recurrent_state(cfg, "the split runtime (SplitRuntime)")
+            if self.bcfg.checkpoint_dir is not None:
+                refuse_recurrent_state(
+                    cfg, "checkpoint_dir (checkpoint_stream/restore_stream)")
         if split_runtime is not None:
             if placed_params is None:
                 raise ValueError(
@@ -343,10 +377,20 @@ class ContinuousBatcher:
                       "occ_sum": 0.0, "occ_max": 0.0, "slot_sum": 0.0,
                       "alloc_sum": 0.0, "alloc_n": 0,
                       "compiles": 0, "compile_s": 0.0,
+                      "routed_assignments": 0,
                       **dict.fromkeys(_CLOCKS, 0.0)}
         # the scheduler thread's clocks since its last fold into ``stats``:
         # phases add here lock-free, step()/submit()/prefill_hold() fold once
         self._acc: dict[str, float] = defaultdict(int)
+        # routed-expert counters of a hybrid stack: assignments per held
+        # expert per layer, summed on the device inside the step and read by
+        # report() alone (no host sync a step); assignments made, counted
+        # here on the host from the running set
+        self._expert_tokens = self._expert_tokens_host = None
+        if cfg.is_hybrid:
+            shape = (cfg.num_layers, cfg.local_experts)
+            self._expert_tokens = jnp.zeros(shape, jnp.int32)
+            self._expert_tokens_host = np.zeros(shape, np.int64)  # last read
         # the step's key table with no stream in it: every row key 0's data,
         # what a free slot samples (and discards) with
         self._free_key_rows = np.tile(_key_data(0), (self.bcfg.max_slots, 1))
@@ -532,6 +576,9 @@ class ContinuousBatcher:
                 else:
                     self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
                                     jnp.asarray(st.resume["v"]), need_len)
+                if "ssm" in st.resume:
+                    self.pool.adopt_state(slot, st.resume["conv"],
+                                          st.resume["ssm"])
             st.resume = None
             if st.resume_prefix and self.pool.prefix is not None:
                 # migration adopts opt in to re-publishing: the payload's
@@ -580,6 +627,10 @@ class ContinuousBatcher:
                                st.temperature)
             with obs_phase("batch.admit.adopt", sid=sid):
                 self.pool.adopt(slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s)
+                if self.cfg.is_hybrid:
+                    # the other kind of state a prefill hands on
+                    self.pool.adopt_state(slot, cache.conv[:, 0],
+                                          cache.ssm[:, 0])
         if self.pool.prefix is not None:
             # publish this prompt's pages (full blocks + partial tail) so
             # later admits share them; already-indexed blocks just refresh
@@ -664,8 +715,11 @@ class ContinuousBatcher:
         round-trip is bit-exact with no requantize."""
         quant = self.bcfg.kv_codec != "fp"
         if self.rt is None:
-            return (self.pool.gather_slot_packed(slot) if quant
-                    else self.pool.gather_slot(slot))
+            # a hybrid stack's payload also carries the slot's recurrent
+            # state ({"conv", "ssm"}); other families add nothing
+            return {**(self.pool.gather_slot_packed(slot) if quant
+                       else self.pool.gather_slot(slot)),
+                    **self.pool.gather_state(slot)}
         n = int(self.pool.lengths[slot])
         idx = self.pool._flat_indices(slot, max(n, 1))
         if quant:
@@ -715,6 +769,8 @@ class ContinuousBatcher:
         cannot admit right now. A ``max_new_tokens == 1`` stream finishes
         at admission (token 0 is the whole answer) and comes back already
         ``finished`` with no held slot."""
+        refuse_recurrent_state(
+            self.cfg, "disaggregated prefill (prefill_hold / page migration)")
         st = self._streams[sid]
         if st.status != "waiting":
             raise ValueError(f"stream {sid} is not waiting")
@@ -927,6 +983,19 @@ class ContinuousBatcher:
                 toks = _split_sample_jit(
                     logits, jnp.asarray(key_data), jnp.asarray(steps),
                     jnp.asarray(temps))
+            elif self.cfg.is_hybrid:
+                state = self.pool.state
+                toks, k, v, conv, ssm, self._expert_tokens = (
+                    _batched_hybrid_step_jit(
+                        self.cfg, self.params, self.pool.pool.k,
+                        self.pool.pool.v, state.conv, state.ssm,
+                        self._expert_tokens, page_table, lengths,
+                        jnp.asarray(token_ids), jnp.asarray(key_data),
+                        jnp.asarray(steps), jnp.asarray(temps),
+                        self.bcfg.compute_dtype))
+                self.pool.pool = type(self.pool.pool)(k, v)
+                self.pool.state = SlotState(conv, ssm)
+                del state
             elif self.bcfg.kv_codec != "fp":
                 toks, k, v, ks, vs = _batched_step_quant_jit(
                     self.cfg, self.params, self.pool.pool.k,
@@ -954,6 +1023,10 @@ class ContinuousBatcher:
             acc["decode_s"] = step_s
             acc["jit_misses"] = self._step_cache_size() - misses0
             acc["steps"] = 1
+            if self.cfg.is_hybrid:
+                acc["routed_assignments"] = (
+                    len(running) * self.cfg.experts_per_tok
+                    * self.cfg.num_layers)
             finished0 = self.stats["finished"]
             advanced = 0
             for st in running:
@@ -970,7 +1043,8 @@ class ContinuousBatcher:
             # sharing, summing per-slot lengths would over-count aliased
             # pages against a reserved-capacity denominator that holds them
             # once (identical to live_tokens when nothing is shared)
-            occ = self.pool.unique_live_tokens / self.pool.token_capacity
+            live = self.pool.unique_live_tokens
+            occ = live / self.pool.token_capacity
             # live tokens per RESERVED token — the denominator is only the
             # pages actually allocated, the paged answer to static
             # batching's worst-case (batch x capacity) reservation
@@ -979,7 +1053,7 @@ class ContinuousBatcher:
             acc["occ_sum"] = acc["occ_max"] = occ
             acc["slot_sum"] = len(self._slot_to_sid) / b
             if reserved:
-                acc["alloc_sum"] = self.pool.unique_live_tokens / reserved
+                acc["alloc_sum"] = live / reserved
                 acc["alloc_n"] = 1
             if self._watchdog is not None:
                 self._watchdog.check()
@@ -1009,6 +1083,7 @@ class ContinuousBatcher:
         resume payload — as a :class:`DecodeCheckpoint`, restorable into ANY
         pool geometry whose span covers it (the payload is the contiguous
         prefix, not pages)."""
+        refuse_recurrent_state(self.cfg, "checkpoint_stream")
         st = self._streams[sid]
         if st.status == "running":
             state = self._gather_state(st.slot)
@@ -1063,6 +1138,7 @@ class ContinuousBatcher:
         """Re-queue a checkpointed stream; its remaining tokens come out
         bit-identical to the uninterrupted run (per-step keys depend only on
         the seed and the step index, the KV prefix is restored bit-exactly)."""
+        refuse_recurrent_state(self.cfg, "restore_stream")
         ckpt = DecodeCheckpoint.load(path)
         meta = ckpt.meta
         if meta.get("mode") != self._ckpt_mode():
@@ -1152,4 +1228,25 @@ class ContinuousBatcher:
             "token_capacity": self.pool.token_capacity,
             **({"prefix": self.pool.prefix_report()}
                if self.pool.prefix is not None else {}),
+            **self._hybrid_report(stats),
         }
+
+    def _hybrid_report(self, stats: dict) -> dict:
+        """What a stack with recurrent state and routed experts adds to
+        ``report()``: the bytes of the per-slot state store, and the routing
+        counters (additive, like the clocks). ``expert_tokens`` is read off
+        the device HERE and nowhere else: it waits for the step in flight."""
+        if not self.cfg.is_hybrid:
+            return {}
+        try:
+            self._expert_tokens_host = np.asarray(self._expert_tokens,
+                                                  np.int64)
+        except RuntimeError:
+            # a scrape from another thread caught the handle between the
+            # step's donation and its return: keep the last reading
+            pass
+        tokens = self._expert_tokens_host
+        return {"state_bytes": self.pool.state_bytes,
+                "expert_tokens": tokens.tolist(),
+                "routed_assignments": int(stats["routed_assignments"]),
+                "routed_local": int(tokens.sum())}

@@ -69,6 +69,12 @@ def _sample(logits: jnp.ndarray, key: jax.Array,
 def _prefill_impl(cfg: ModelConfig, params: dict, prompt_ids: jnp.ndarray,
                   capacity: int,
                   compute_dtype: Optional[Any]) -> tuple[jnp.ndarray, KVCache]:
+    if cfg.is_hybrid:
+        # the hybrid stack unembeds the last position only
+        from ..models.hybrid import prefill_hybrid
+
+        return prefill_hybrid(cfg, _cast_params(params, compute_dtype),
+                              prompt_ids, capacity, last_only=True)
     logits, cache = prefill(cfg, params, prompt_ids, capacity,
                             compute_dtype=compute_dtype)
     return logits[:, -1], cache  # only the last position seeds generation
@@ -212,6 +218,10 @@ def generate(cfg: ModelConfig, params: dict, prompt_ids: ArrayLike,
         prompt_ids, max_new_tokens, capacity, temperature, rng_key)
     b, s = prompt_ids.shape
     if recovery is not None:
+        from ..models.hybrid import refuse_recurrent_state
+
+        refuse_recurrent_state(cfg, "generate(recovery=...) (checkpoints, "
+                                    "the survivable loop)")
         rt = LocalRuntime(cfg, compute_dtype)
         return _survivable_loop(rt, params, prompt_ids, max_new_tokens,
                                 capacity, temperature, key, 0, stats,

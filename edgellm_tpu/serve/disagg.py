@@ -435,6 +435,10 @@ class DisaggServer:
                  dcfg: DisaggConfig = DisaggConfig(), *,
                  split_runtime=None, placed_params=None,
                  clock: Clock = MONOTONIC):
+        from ..models.hybrid import refuse_recurrent_state
+
+        refuse_recurrent_state(cfg, "disaggregated prefill (DisaggServer's "
+                                    "page migration)")
         self.cfg, self.params = cfg, params
         self.bcfg, self.dcfg = bcfg, dcfg
         self.clock = clock

@@ -409,6 +409,10 @@ class LocalRuntime:
     split runtime."""
 
     def __init__(self, cfg, compute_dtype=None):
+        from ..models.hybrid import refuse_recurrent_state
+
+        refuse_recurrent_state(cfg, "the recovery runtime (LocalRuntime: "
+                                    "DecodeCheckpoint, failover)")
         self.cfg = cfg
         self.compute_dtype = compute_dtype
         self.codecs: list = []

@@ -143,7 +143,10 @@ def test_a_step_with_nothing_to_do_still_counts_its_wall(make):
     assert b.step() == 0
     r = b.report()
     assert r["steps"] == 0 and r["step_wall_s"] > 0
-    assert r["admit_s"] == pytest.approx(r["step_wall_s"], rel=0.5)
+    # an empty step is admission and nothing else. Its two clocks are a few
+    # microseconds each, read apart, so under a loaded machine their ratio is
+    # anything: the ordering is what holds
+    assert 0 < r["admit_s"] <= r["step_wall_s"]
     assert r["launch_s"] == r["sync_s"] == 0.0
 
 
@@ -321,7 +324,8 @@ def test_phase_chains_its_clock_and_keeps_late_attributes_for_the_span():
 # ---------------------------------------------------------------------------
 
 SOURCES = ("serve/batching.py", "models/paged_kv.py", "models/transformer.py",
-           "parallel/split.py")
+           "parallel/split.py", "models/mamba2.py", "models/moe.py",
+           "models/hybrid.py")
 
 
 def _literal_names(callees):

@@ -147,6 +147,39 @@ def test_probe_share_cow_and_unique_tokens():
     assert cache.shared_pages >= 2
 
 
+def _unique_by_walking_pages(cache):
+    """``unique_live_tokens`` as it was before its short cut: per page, the
+    largest coverage over every slot that holds it."""
+    cover = np.zeros((cache.num_pages,), np.int64)
+    for s in range(cache.max_slots):
+        if cache.active[s]:
+            n = int(cache.lengths[s])
+            for j, p in enumerate(cache._slot_pages[s]):
+                cover[p] = max(cover[p], min(cache.page_size,
+                                             n - j * cache.page_size), 0)
+    return int(cover.sum())
+
+
+@pytest.mark.parametrize("shared", [False, True], ids=["unshared", "shared"])
+def test_unique_live_tokens_short_cut_equals_the_page_walk(shared):
+    """With no page held twice the property returns ``live_tokens`` without
+    walking the pages (the walk ran twice a step on the batcher's commit
+    clock); with a shared page it walks. Both equal the walk."""
+    cache = _donor_pool()[0] if shared else _pool(None)
+    if shared:
+        s1 = cache.alloc_slot()
+        assert cache.share_prefix(s1, PROMPT + [111, 112],
+                                  max_tokens=11) == 10
+    else:
+        for n, seed in ((10, 0), (7, 1)):
+            s = cache.alloc_slot()
+            cache.adopt(s, *_seq(n, seed), n)
+    cache.check_invariants()
+    assert bool(cache.shared_pages) == shared
+    assert cache.unique_live_tokens == _unique_by_walking_pages(cache)
+    assert (cache.unique_live_tokens == cache.live_tokens) == (not shared)
+
+
 def test_share_cap_lands_mid_partial_node():
     cache, _ = _donor_pool()
     assert cache.probe_prefix(PROMPT, max_tokens=9) == {
