@@ -550,22 +550,26 @@ def decode_plan(capacity: int, h: int, kv: int, hd: int,
     the prefill kernels).
 
     PAGED caches (``pages=(pages_per_slot, page_size)``) are different: XLA
-    sees a gather-then-attend, materializing every slot's full span in HBM
-    each step, while the Pallas kernel scalar-prefetches the page table and
-    streams each slot's pages directly (Ragged Paged Attention, PAPERS.md) —
-    a genuinely new data path, not a re-tiling of one XLA already has. It
-    dispatches only when EARNED, per the probe-cache rule: by default the
-    plan requires TPU backend AND a recorded
-    ``measured_win("paged_decode_attention")`` — a key nothing writes today,
-    so THE XLA GATHER IS THE TPU PATH. ``EDGELLM_ATTN=pallas`` forces the
-    kernel on any backend (interpret mode off-TPU, which is how tier-1
-    exercises it); ``EDGELLM_ATTN=xla`` forces the gather. Neither paged
-    kernel has ever compiled for a TPU: with the q/out block shape repaired
-    (PR 21) the TPU lowering still refuses both — "Cannot store scalars to
-    VMEM" (the m/l online-softmax scratch is written one scalar per head) —
-    so forcing them on a TPU raises; nothing falls back silently. The
-    ``itemsize`` scaling tracks the real bytes-per-step the way the prefill
-    gates do.
+    sees a gather-then-attend — every slot's full span fetched out of the
+    pool a PAGE at a time (:func:`_gather_pages`), written to HBM and read
+    again by the attend, each step — while the Pallas kernel
+    scalar-prefetches the page table and streams each slot's pages directly
+    (Ragged Paged Attention, PAPERS.md): no gathered copy. It dispatches
+    only when EARNED, per the probe-cache rule: by default the plan requires
+    TPU backend AND a recorded ``measured_win("paged_decode_attention")`` —
+    a key nothing writes today, so THE XLA PAGE GATHER IS THE TPU PATH, and
+    what a kernel could still win over it is that copy (PERF.md §5, §6
+    "PR 27"; ROADMAP S4). ``EDGELLM_ATTN=pallas`` forces the kernel on any
+    backend (interpret mode off-TPU, which is how tier-1 exercises it);
+    ``EDGELLM_ATTN=xla`` forces the gather. Neither paged kernel has ever
+    compiled for a TPU: with the q/out block shape repaired (PR 21) the TPU
+    lowering still refuses both — "Cannot store scalars to VMEM" (the m/l
+    online-softmax scratch is written one scalar per head) — so forcing
+    them on a TPU raises; nothing falls back silently. As written their
+    grid is one page a step, ``(B, pages_per_slot)``: 24,576 grid steps a
+    layer at 192 slots x 128 pages, which at a third of a microsecond a step
+    is no faster than the gather. The ``itemsize`` scaling tracks the real
+    bytes-per-step the way the prefill gates do.
 
     ``kv_codec`` names a quantized at-rest tier (:data:`KV_REST_TIERS`): the
     byte budget then counts the REAL per-row footprint (packed codes plus one
@@ -699,7 +703,7 @@ def verify_attention(q, k_cache, v_cache, length):
 
 # ---------------------------------------------------------------------------
 # Paged ragged decode attention: q_len=1 per slot against that slot's page
-# list. Pallas kernel on TPU (plan-gated), XLA gather fallback everywhere.
+# list. Pallas kernel on TPU (plan-gated), XLA page-gather fallback everywhere.
 # ---------------------------------------------------------------------------
 
 
@@ -804,6 +808,19 @@ def _paged_attn(q2, kf, vf, pt_flat, lens, hd: int, pps: int,
     )(pt_flat, lens, q2, kf, vf)
 
 
+def _gather_pages(pages, page_table):
+    """Each slot's pages out of one layer's pool, in table order: pages
+    (num_pages, page_size, KV, ...) and page_table (B, pages_per_slot) ->
+    (B, span, KV, ...). One gather slice is one whole PAGE, taken from the
+    pool viewed as (num_pages, page_size*KV, ...): on a TPU that view is a
+    bitcast of what the K/V write leaves and the 4-d pool is not (see
+    :func:`paged_decode_attention`)."""
+    pn, ps, kv, *tail = pages.shape
+    b, pps = page_table.shape
+    return pages.reshape(pn, ps * kv, *tail)[page_table].reshape(
+        b, pps * ps, kv, *tail)
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
     """Ragged single-position attention against a paged pool: q (B, 1, H, hd)
     per slot; k/v_pages (num_pages, page_size, KV, hd) — ONE layer's shared
@@ -815,9 +832,27 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
     Dispatch mirrors the prefill kernels: :func:`decode_plan` (with
     ``pages=``) earns the Pallas kernel via probe-cache win or
     ``EDGELLM_ATTN=pallas`` force; otherwise the XLA fallback gathers each
-    slot's span contiguous and reuses :func:`decode_attention` with vector
-    lengths — trash-page garbage lands only in masked positions, where
-    softmax of ``finfo.min`` contributes exactly 0."""
+    slot's span contiguous, one PAGE a gather slice, and reuses
+    :func:`decode_attention` with vector lengths — trash-page garbage lands
+    only in masked positions, where softmax of ``finfo.min`` contributes
+    exactly 0.
+
+    What the fallback costs on a v5e (PERF.md §6 "PR 27", one layer's K or V
+    at 192 slots x 128 pages of 16 rows, KV=2): gathered a ROW at a time
+    (393,216 slices of 256-512 B, the code before PR 27) 4.4-4.65 ms, 11.9
+    ns a row whatever it held; gathered a page at a time 0.85 ms at hd=64,
+    i.e. by bytes (the row-tiled pool pads 64 lanes to 128, so 200 MB read
+    and 200 written at 470 GB/s). The view matters as much as the slice:
+    ``k_pages[page_table]`` on the 4-d pool makes the TPU compiler move the
+    page axis under KV for the attend and pay two relayout copies of 0.6 ms
+    around every gather; the pool viewed as ``(num_pages, page_size*KV*hd)``
+    costs a de-padding and a re-padding reshape instead. Merging only
+    ``page_size`` and ``KV`` is a bitcast of the layout the K/V write leaves
+    (16 tiles of (2, 128) are two tiles of (8, 128)(2, 1)), the gather is
+    all there is, and the attend reads its output as it read the row
+    gather's. Same values in the same order either way: outputs are
+    bit-identical to the row gather's (tests/test_batching.py keeps it as
+    the oracle, and guards the traced step against its return)."""
     b, s1, h, hd = q.shape
     pn, ps, kv, _ = k_pages.shape
     pps = page_table.shape[1]
@@ -837,10 +872,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
                           lengths.astype(jnp.int32), hd, pps,
                           _use_interpret())
         return out.reshape(b, 1, h, hd)
-    idx = (page_table[:, :, None] * ps
-           + jnp.arange(ps)[None, None, :]).reshape(b, span)
-    kg = k_pages.reshape(pn * ps, kv, hd)[idx]
-    vg = v_pages.reshape(pn * ps, kv, hd)[idx]
+    kg = _gather_pages(k_pages, page_table)
+    vg = _gather_pages(v_pages, page_table)
     return decode_attention(q, kg, vg, lengths)
 
 
@@ -967,9 +1000,10 @@ def paged_decode_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
     per-row absmax scales, the layout quantize_kv_rows writes. Dispatch is
     the same plan gate with ``kv_codec`` (per-tier probe key); the Pallas
     path dequantizes in VMEM, and the XLA fallback gathers codes+scales by
-    page table THEN dequantizes — elementwise per row, so it is exactly
-    equal to dequantizing the whole pool first (the numerical-equivalence
-    contract the lint layer executes)."""
+    page table, a page a slice as the fp twin does (:func:`_gather_pages`),
+    THEN dequantizes — elementwise per row, so it is exactly equal to
+    dequantizing the whole pool first (the numerical-equivalence contract
+    the lint layer executes)."""
     b, s1, h, hd_q = q.shape
     pn, ps, kv, hdc = k_pages.shape
     hd = hdc * 2 if kv_codec == "int4_per_channel" else hdc
@@ -995,12 +1029,10 @@ def paged_decode_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
                                 lengths.astype(jnp.int32), hd, pps, bits,
                                 _use_interpret())
         return out.reshape(b, 1, h, hd)
-    idx = (page_table[:, :, None] * ps
-           + jnp.arange(ps)[None, None, :]).reshape(b, span)
-    kg = dequantize_kv_rows(k_pages.reshape(pn * ps, kv, hdc)[idx],
-                            k_scale.reshape(pn * ps, kv)[idx],
+    kg = dequantize_kv_rows(_gather_pages(k_pages, page_table),
+                            _gather_pages(k_scale, page_table),
                             kv_codec, q.dtype)
-    vg = dequantize_kv_rows(v_pages.reshape(pn * ps, kv, hdc)[idx],
-                            v_scale.reshape(pn * ps, kv)[idx],
+    vg = dequantize_kv_rows(_gather_pages(v_pages, page_table),
+                            _gather_pages(v_scale, page_table),
                             kv_codec, q.dtype)
     return decode_attention(q, kg, vg, lengths)

@@ -401,6 +401,121 @@ def test_paged_attention_fallback_matches_contiguous():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 
+def _flat_row_gather(pages, page_table):
+    """The fallback's fetch as it was before the page gather (one slice a
+    ROW: 393,216 of them a layer at the benchmark's geometry, 11.9 ns each on
+    a v5e whatever the row held). Kept as the oracle."""
+    pn, ps = pages.shape[:2]
+    b, pps = page_table.shape
+    idx = (page_table[:, :, None] * ps
+           + jnp.arange(ps)[None, None, :]).reshape(b, pps * ps)
+    return pages.reshape(pn * ps, *pages.shape[2:])[idx]
+
+
+def _ragged_paged_case(ps):
+    """Five slots over pages 0..8 (0 the trash page): one that ends inside a
+    page, one on a page edge, one at length 1, one whose table is all trash
+    page (an inactive slot of the step: length 0 + the step's own row), one
+    that repeats a page another slot holds (a shared prefix) and names it
+    twice itself."""
+    table = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 0, 0, 0],
+                         [0, 0, 0, 0], [1, 7, 1, 8]], jnp.int32)
+    lengths = jnp.asarray([2 * ps + 3, 2 * ps, 1, 1, 4 * ps], jnp.int32)
+    return table, lengths
+
+
+@pytest.mark.parametrize("kv,hd,ps", [(2, 64, 16), (2, 128, 16),
+                                      (8, 128, 16), (2, 64, 8)])
+def test_page_gather_equals_flat_row_gather_bitwise(kv, hd, ps, monkeypatch):
+    # the K/V handed to the attend, and what comes out of it, must be the
+    # flat-row gather's to the bit: same values, same order, trash-page rows
+    # only under the length mask
+    from edgellm_tpu.models import flash_attention as fa
+
+    monkeypatch.setenv("EDGELLM_ATTN", "xla")
+    rng = np.random.default_rng(kv * hd + ps)
+    pn, h = 11, 2 * kv
+    pt, lengths = _ragged_paged_case(ps)
+    b = pt.shape[0]
+    q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((pn, ps, kv, hd)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((pn, ps, kv, hd)), jnp.bfloat16)
+    handed = []
+    attend = fa.decode_attention
+
+    def recording_attend(q_, k_, v_, lengths_):
+        handed.append((k_, v_))
+        return attend(q_, k_, v_, lengths_)
+
+    monkeypatch.setattr(fa, "decode_attention", recording_attend)
+    out = paged_decode_attention(q, kp, vp, pt, lengths)
+    (kg, vg), = handed
+    k_old, v_old = _flat_row_gather(kp, pt), _flat_row_gather(vp, pt)
+    assert kg.shape == k_old.shape == (b, pt.shape[1] * ps, kv, hd)
+    np.testing.assert_array_equal(np.asarray(kg), np.asarray(k_old))
+    np.testing.assert_array_equal(np.asarray(vg), np.asarray(v_old))
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(attend(q, k_old, v_old, lengths)))
+    # the all-trash slot reads the trash page's row 0 and nothing else
+    np.testing.assert_array_equal(
+        np.asarray(out[3]),
+        np.asarray(attend(q[3:4], kp[None, 0], vp[None, 0], 1)[0]))
+
+
+def _gathers(jaxpr):
+    """Every ``gather`` equation of a jaxpr and of the jaxprs its equations
+    carry (the layer scan's body, closed calls), with its scope path."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield str(eqn.source_info.name_stack), eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _gathers(sub)
+
+
+@pytest.mark.parametrize("tier", ["fp", "int8_per_channel",
+                                  "int4_per_channel"])
+def test_decode_step_fetches_pool_by_page_not_by_row(params, tier):
+    # the guard against the per-row gather coming back unseen: in the traced
+    # step, every gather under attn.decode that reads a pool array takes one
+    # whole page a slice — none reads the pool flattened to rows
+    from edgellm_tpu.models.paged_kv import (paged_decode_step,
+                                             paged_decode_step_quant)
+
+    pn, ps, slots, pps = BCFG.num_pages, BCFG.page_size, BCFG.max_slots, 4
+    kv, hd = CFG.num_kv_heads, CFG.head_dim
+    table = jnp.zeros((slots, pps), jnp.int32)
+    ints = jnp.zeros((slots,), jnp.int32)
+    if tier == "fp":
+        pool = jnp.zeros((CFG.num_layers, pn, ps, kv, hd), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_step(CFG, *a))(
+            params, pool, pool, table, ints, ints)
+        want = [ps * kv * hd] * 2                  # a page of K, of V
+    else:
+        hdc = hd // 2 if tier == "int4_per_channel" else hd
+        codes = jnp.zeros((CFG.num_layers, pn, ps, kv, hdc),
+                          jnp.uint8 if hdc != hd else jnp.int8)
+        scale = jnp.zeros((CFG.num_layers, pn, ps, kv), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_step_quant(
+            CFG, *a, kv_codec=tier))(params, codes, codes, scale, scale,
+                                     table, ints, ints)
+        want = [ps * kv * hdc] * 2 + [ps * kv] * 2  # codes and scales
+    fetches = []
+    for path, eqn in _gathers(jaxpr.jaxpr):
+        shape = eqn.invars[0].aval.shape
+        if "attn.decode" not in path or "paged_kv.write" in path:
+            continue
+        # whatever view of the layer's pool is gathered, its leading axis
+        # counts PAGES and one slice is everything a page holds
+        assert shape[0] == pn, f"a pool not indexed by page: {shape}"
+        assert tuple(eqn.params["slice_sizes"]) == (1, *shape[1:]), \
+            f"a slice is not one whole page: {eqn.params['slice_sizes']}"
+        fetches.append(int(np.prod(shape[1:])))
+    assert sorted(fetches) == sorted(want)
+
+
 def test_batched_sample_matches_single_row():
     rng = np.random.default_rng(13)
     logits = jnp.asarray(rng.standard_normal((4, 128)).astype(np.float32))

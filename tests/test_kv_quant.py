@@ -199,6 +199,43 @@ def test_paged_quant_fallback_matches_dequantized_pool(tier):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
+@pytest.mark.parametrize("kv,hd,ps", [(2, 64, 16), (2, 128, 16),
+                                      (8, 128, 16), (2, 64, 8)])
+@pytest.mark.parametrize("tier", TIERS)
+def test_quant_page_gather_equals_flat_row_gather_bitwise(tier, kv, hd, ps,
+                                                          monkeypatch):
+    # the quantized twin of test_batching's page-gather case: codes and both
+    # scale pools fetched a page at a time equal the flat-row fetch (the
+    # three old lines, kept below as the oracle) to the bit — ragged
+    # lengths, an all-trash slot, a shared page named twice
+    from edgellm_tpu.models.flash_attention import decode_attention
+
+    monkeypatch.setenv("EDGELLM_ATTN", "xla")
+    rng = np.random.default_rng(kv + hd + ps)
+    pn, pps, h = 11, 4, 2 * kv
+    pt = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 0, 0, 0],
+                      [0, 0, 0, 0], [1, 7, 1, 8]], jnp.int32)
+    lens = jnp.asarray([2 * ps + 3, 2 * ps, 1, 1, pps * ps], jnp.int32)
+    b, span = pt.shape[0], pps * ps
+    rows = (pn * ps, kv, hd)
+    kq, ks = quantize_kv_rows(
+        jnp.asarray(rng.standard_normal(rows), jnp.float32), tier)
+    vq, vs = quantize_kv_rows(
+        jnp.asarray(rng.standard_normal(rows), jnp.float32), tier)
+    hdc = kq.shape[-1]
+    q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.bfloat16)
+    got = paged_decode_attention_quant(
+        q, kq.reshape(pn, ps, kv, hdc), vq.reshape(pn, ps, kv, hdc),
+        ks.reshape(pn, ps, kv), vs.reshape(pn, ps, kv), pt, lens,
+        kv_codec=tier)
+    idx = (pt[:, :, None] * ps
+           + jnp.arange(ps)[None, None, :]).reshape(b, span)
+    kg = dequantize_kv_rows(kq[idx], ks[idx], tier, q.dtype)
+    vg = dequantize_kv_rows(vq[idx], vs[idx], tier, q.dtype)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(decode_attention(q, kg, vg, lens)))
+
+
 # ---------------------------------------------------------------------------
 # quantized pool surgery: adopt / gather / COW / defrag / state_dict
 # ---------------------------------------------------------------------------
