@@ -113,7 +113,7 @@ def dispatch_phase(cfg, param_dtype, *, prompt_lens=PROMPT_LENS,
     shapes."""
     import jax.numpy as jnp
 
-    from edgellm_tpu.models.flash_attention import decode_plan, kernel_plan
+    from edgellm_tpu.models.flash_attention import kernel_plan
 
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     itemsize = jnp.dtype(param_dtype).itemsize
@@ -124,9 +124,9 @@ def dispatch_phase(cfg, param_dtype, *, prompt_lens=PROMPT_LENS,
     sites.append({"site": "sweep.forward", "seq": sweep_len,
                   "plan": kernel_plan(sweep_len, h, kv, hd,
                                       itemsize=itemsize)})
+    # one path: models.paged_kv.read_span + flash_attention.decode_attention
     sites.append({"site": "serve.decode[paged]", "seq": pages[0] * pages[1],
-                  "plan": decode_plan(pages[0] * pages[1], h, kv, hd,
-                                      itemsize=itemsize, pages=pages)})
+                  "plan": "xla page gather"})
     out = {"param_dtype": jnp.dtype(param_dtype).name, "attention": sites}
     if split is not None:
         from edgellm_tpu.codecs.pallas_kernels import fused_hop_plan
@@ -263,7 +263,7 @@ def reference_phase(cfg, *, batching: dict = BATCHING, prompt_len: int = 100,
         1, cfg.vocab_size, size=(1, prompt_len + n_steps)).astype(np.int32)
     span = batching["pages_per_slot"] * batching["page_size"]
     step = jax.jit(paged_decode_step, static_argnames=("cfg",),
-                   donate_argnums=(2, 3))
+                   donate_argnums=(2,))
     errs = []
     with jax.default_matmul_precision("highest"):
         ref, _ = jax.jit(lambda p, x: forward(
@@ -288,9 +288,8 @@ def reference_phase(cfg, *, batching: dict = BATCHING, prompt_len: int = 100,
             page_table, lengths = pool.device_tables()
             feed = np.zeros((batching["max_slots"],), np.int32)
             feed[slot] = ids[0, pos]
-            logits, k, v = step(cfg, params, pool.pool.k, pool.pool.v,
-                                page_table, lengths, jnp.asarray(feed))
-            pool.pool = type(pool.pool)(k, v)
+            logits, pool.pool = step(cfg, params, pool.pool, page_table,
+                                     lengths, jnp.asarray(feed))
             # sync BEFORE touching the host tables, as the batcher does: the
             # step may still be reading the lengths array it was handed
             got = np.asarray(logits[slot])
@@ -348,8 +347,8 @@ def split_phase(cfg, vocab_size: int, *, prompt_lens=PROMPT_LENS,
     # stage_size layers) plus its slice of the paged K/V pool
     stage_bytes = sum(int(a.nbytes) // n_stages
                       for a in jax.tree_util.tree_leaves(
-                          [placed["layers"], pool["k"], pool["v"]]))
-    for tree in (placed["layers"], pool["k"], pool["v"]):
+                          [placed["layers"], pool]))
+    for tree in (placed["layers"], pool):
         for a in jax.tree_util.tree_leaves(tree):
             held = {s.device for s in a.addressable_shards}
             assert held == set(stage_devs), (a.shape, held)

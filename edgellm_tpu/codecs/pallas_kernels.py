@@ -605,7 +605,7 @@ REMOTE_CAPABLE = frozenset({"int8_per_token"})
 
 @dataclasses.dataclass(frozen=True)
 class FusedHopPlan:
-    """One hop's fused-transport decision (mirrors ``decode_plan``: a plan
+    """One hop's fused-transport decision (like ``kernel_plan``'s: a plan
     object you can log, not a bare bool). ``base`` is the probe-cache key
     (codec name sans ``_pallas``); ``reason`` records why the gate said yes
     so bench sidecars can carry the provenance."""

@@ -736,60 +736,41 @@ def run_kv_tier_eval(
     what PAGE compression costs, with the same window/stride/masking recipe
     and the same token weighting, so the two curves are directly comparable.
     Every window is teacher-force decoded through a paged pool one position
-    at a time — the exact serving data path (quantize-on-append, in-kernel
-    dequant attention), not a whole-window forward, so the PPL delta vs the
+    at a time — the exact serving data path (quantize-on-append, gather then
+    dequantize in the attend), not a whole-window forward, so the PPL delta vs the
     ``"fp"`` tier is the delta a served stream actually experiences. One
     executable per (window_batch, window_length) group shape; full-length
     groups all share one, the short corpus tail gets its own.
     """
     from ..models.paged_kv import resolve_kv_codec as _resolve_tier
-    from ..models.paged_kv import (kv_page_bytes, paged_decode_step,
-                                   paged_decode_step_quant)
+    from ..models.paged_kv import (init_pool, init_quant_pool, kv_page_bytes,
+                                   paged_decode_step)
 
     codec = _resolve_tier(kv_codec)
-    quant = codec.quantized
-    L, KV, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
     fn_cache: dict = {}
 
     def _make_fn(w, t, pps, num_pages):
         def fn(p, pt, ids, targets):
-            if quant:
-                hdc = codec.code_lanes(hd)
-                pools = (jnp.zeros((L, num_pages, page_size, KV, hdc),
-                                   codec.code_dtype),
-                         jnp.zeros((L, num_pages, page_size, KV, hdc),
-                                   codec.code_dtype),
-                         jnp.zeros((L, num_pages, page_size, KV),
-                                   jnp.float32),
-                         jnp.zeros((L, num_pages, page_size, KV),
-                                   jnp.float32))
-            else:
-                pools = (jnp.zeros((L, num_pages, page_size, KV, hd),
-                                   jnp.float32),
-                         jnp.zeros((L, num_pages, page_size, KV, hd),
-                                   jnp.float32))
+            pool = (init_quant_pool(cfg, num_pages, page_size, codec.name)
+                    if codec.quantized
+                    else init_pool(cfg, num_pages, page_size))
 
-            def body(pools_c, xs):
+            def body(pool_c, xs):
                 tok, tgt, step = xs
                 lengths = jnp.full((w,), step, jnp.int32)
-                if quant:
-                    logits, *pools2 = paged_decode_step_quant(
-                        cfg, p, *pools_c, pt, lengths, tok,
-                        kv_codec=codec.name, compute_dtype=compute_dtype)
-                else:
-                    logits, *pools2 = paged_decode_step(
-                        cfg, p, *pools_c, pt, lengths, tok,
-                        compute_dtype=compute_dtype)
+                logits, pool2 = paged_decode_step(
+                    cfg, p, pool_c, pt, lengths, tok,
+                    compute_dtype=compute_dtype)
                 logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
                 valid = tgt != -100
                 safe = jnp.where(valid, tgt, 0)
                 nll = -jnp.take_along_axis(logp, safe[:, None], 1)[:, 0]
-                return tuple(pools2), (jnp.where(valid, nll, 0.0), valid)
+                return pool2, (jnp.where(valid, nll, 0.0), valid)
 
             # feed positions 0..t-2; the step-s logits score target s+1 —
             # the same shift nll_from_logits applies to whole-window logits
             xs = (ids[:, :-1].T, targets[:, 1:].T, jnp.arange(t - 1))
-            _, (nlls, valids) = jax.lax.scan(body, pools, xs)
+            _, (nlls, valids) = jax.lax.scan(body, pool, xs)
             return nlls.sum(0), valids.sum(0).astype(jnp.float32)
         return jax.jit(fn)
 

@@ -176,12 +176,12 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     psteps = jnp.zeros((MS,), jnp.int32)
     ptemps = jnp.zeros((MS,), jnp.float32)
     run_one("paged.decode_step",
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, ppool.k, ppool.v, ptab, plens, ptoks),
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, ppool, ptab, plens, ptoks),
             ctx={"donate_min": 2},
             lowerable=batching._batched_step_jit,
-            lower_args=(cfg, params, ppool.k, ppool.v, ptab, plens, ptoks,
+            lower_args=(cfg, params, ppool, ptab, plens, ptoks,
                         pkeys, psteps, ptemps, None))
 
     # ---- continuous batching: a single-request paged decode must emit
@@ -244,13 +244,13 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         live_toks = jnp.zeros((MS,), jnp.int32)
         ident = check_identity(
             "batching.prefix-disabled-identity",
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, pbat.pool.pool.k, pbat.pool.pool.v, live_tab,
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, pbat.pool.pool, live_tab,
              live_lens, live_toks),
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, ppool.k, ppool.v, ptab, plens, ptoks),
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, ppool, ptab, plens, ptoks),
             what="prefix-enabled batcher's ragged decode-step graph")
         (findings.extend(ident) if ident
          else checked.append("batching.prefix-disabled-identity"))
@@ -311,17 +311,13 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     qsteps = jnp.zeros((MS,), jnp.int32)
     qtemps = jnp.zeros((MS,), jnp.float32)
     run_one("paged.decode_step_quant",
-            lambda p, pk, pv, ks, vs, pt, ln, t:
-                paged_kv.paged_decode_step_quant(
-                    cfg, p, pk, pv, ks, vs, pt, ln, t,
-                    kv_codec="int8_per_channel"),
-            (params, qpool.k, qpool.v, qpool.k_scale, qpool.v_scale, ptab,
-             plens, ptoks),
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, qpool, ptab, plens, ptoks),
             ctx={"donate_min": 4},
-            lowerable=batching._batched_step_quant_jit,
-            lower_args=(cfg, params, qpool.k, qpool.v, qpool.k_scale,
-                        qpool.v_scale, ptab, plens, ptoks, pkeys, qsteps,
-                        qtemps, "int8_per_channel", None))
+            lowerable=batching._batched_step_jit,
+            lower_args=(cfg, params, qpool, ptab, plens, ptoks, pkeys,
+                        qsteps, qtemps, None))
 
     # ---- a stack with recurrent state (granitemoehybrid): the hybrid
     # ---- ragged step — collective-free; the K/V pages, the per-slot state
@@ -363,12 +359,12 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         ftoks = jnp.zeros((MS,), jnp.int32)
         ident = check_identity(
             "batching.kvq-disabled-identity",
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, fbat.pool.pool.k, fbat.pool.pool.v, ftab, flens, ftoks),
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, ppool.k, ppool.v, ptab, plens, ptoks),
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, fbat.pool.pool, ftab, flens, ftoks),
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, ppool, ptab, plens, ptoks),
             what="kv_codec=\"fp\" batcher's ragged decode-step graph")
         (findings.extend(ident) if ident
          else checked.append("batching.kvq-disabled-identity"))
@@ -428,8 +424,9 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
                 eq_rng.permutation(np.arange(1, NPG))[:MS * PPS]
                 .reshape(MS, PPS).astype(np.int32))
             elens = jnp.asarray([PGS + 3, PGS - 2], jnp.int32)
-            got = fa.paged_decode_attention_quant(
-                q, kq[0], vq[0], ks[0], vs[0], etab, elens, kv_codec=tier)
+            got = paged_kv.paged_decode_attention(
+                q, paged_kv.QuantPagePool(kq[0], vq[0], ks[0], vs[0]), etab,
+                elens)
             # reference: dequantize the WHOLE pool, then the plain fp path
             kf = fa.dequantize_kv_rows(
                 kq[0].reshape(NPG * PGS, cfg.num_kv_heads, -1),
@@ -437,9 +434,10 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             vf = fa.dequantize_kv_rows(
                 vq[0].reshape(NPG * PGS, cfg.num_kv_heads, -1),
                 vs[0].reshape(NPG * PGS, cfg.num_kv_heads), tier)
-            ref = fa.paged_decode_attention(
-                q, kf.reshape(NPG, PGS, cfg.num_kv_heads, cfg.head_dim),
-                vf.reshape(NPG, PGS, cfg.num_kv_heads, cfg.head_dim),
+            ref = paged_kv.paged_decode_attention(
+                q, paged_kv.PagePool(
+                    kf.reshape(NPG, PGS, cfg.num_kv_heads, cfg.head_dim),
+                    vf.reshape(NPG, PGS, cfg.num_kv_heads, cfg.head_dim)),
                 etab, elens)
             if not np.array_equal(np.asarray(got), np.asarray(ref)):
                 d = float(np.abs(np.asarray(got) - np.asarray(ref)).max())
@@ -477,13 +475,13 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         dtoks = jnp.zeros((MS,), jnp.int32)
         ident = check_identity(
             "disagg.disabled-identity",
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, dsrv.pool.pool.k, dsrv.pool.pool.v, dtab, dlens,
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, dsrv.pool.pool, dtab, dlens,
              dtoks),
-            lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                cfg, p, pk, pv, pt, ln, t),
-            (params, ppool.k, ppool.v, ptab, plens, ptoks),
+            lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                cfg, p, pool, pt, ln, t),
+            (params, ppool, ptab, plens, ptoks),
             what="disagg decode batcher's ragged decode-step graph (with "
                  "migrated pages live)")
         (findings.extend(ident) if ident
@@ -610,12 +608,12 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             htoks = jnp.zeros((MS,), jnp.int32)
             ident = check_identity(
                 "cluster.hedge-disabled-identity",
-                lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                    cfg, p, pk, pv, pt, ln, t),
-                (params, hpool.pool.k, hpool.pool.v, htab, hlens, htoks),
-                lambda p, pk, pv, pt, ln, t: paged_kv.paged_decode_step(
-                    cfg, p, pk, pv, pt, ln, t),
-                (params, ppool.k, ppool.v, ptab, plens, ptoks),
+                lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                    cfg, p, pool, pt, ln, t),
+                (params, hpool.pool, htab, hlens, htoks),
+                lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
+                    cfg, p, pool, pt, ln, t),
+                (params, ppool, ptab, plens, ptoks),
                 what="gray-hedged replica's ragged decode-step graph")
             (findings.extend(ident) if ident
              else checked.append("cluster.hedge-disabled-identity"))
@@ -677,9 +675,9 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         "donate_min": 2,  # the per-stage page pools update in place
     }
     run_one("split.decode_step_paged", pstep_fn,
-            (placed, spool["k"], spool["v"], ptab, plens, ptoks), paged_ctx,
+            (placed, spool, ptab, plens, ptoks), paged_ctx,
             lowerable=pstep_fn,
-            lower_args=(placed, spool["k"], spool["v"], ptab, plens, ptoks))
+            lower_args=(placed, spool, ptab, plens, ptoks))
 
     # ---- k-token verify: the speculative burst's ONE boundary round-trip —
     # ---- every cut quantizes a single (B, K, D) activation block instead of
@@ -757,10 +755,10 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         "donate_min": 2,
     }
     run_one("split.decode_step_paged.pipelined", pipe_pstep_fn,
-            (placed, spool["k"], spool["v"], ptab, plens, ptoks),
+            (placed, spool, ptab, plens, ptoks),
             pipe_paged_ctx,
             lowerable=pipe_pstep_fn,
-            lower_args=(placed, spool["k"], spool["v"], ptab, plens, ptoks))
+            lower_args=(placed, spool, ptab, plens, ptoks))
 
     # num_microbatches=1 must trace the ORIGINAL sequential schedule byte for
     # byte — the fingerprint half of the ISSUE's disabled-pipeline contract

@@ -384,17 +384,12 @@ class _Lattice:
             keys = jnp.tile(batching._key_data(0), (ms, 1))
             steps = jnp.zeros((ms,), jnp.int32)
             temps = jnp.zeros((ms,), jnp.float32)
-            if codec == "fp":
-                pool = paged_kv.init_pool(cfg, npg, pgs)
-                args = (cfg, params, pool.k, pool.v, tab, lens, toks, keys,
-                        steps, temps, None)
-                return self._result(batching._batched_step_jit.lower(*args),
-                                    batching._batched_step_jit, args, 2)
-            pool = paged_kv.init_quant_pool(cfg, npg, pgs, codec)
-            args = (cfg, params, pool.k, pool.v, pool.k_scale, pool.v_scale,
-                    tab, lens, toks, keys, steps, temps, codec, None)
-            return self._result(batching._batched_step_quant_jit.lower(*args),
-                                batching._batched_step_quant_jit, args, 4)
+            pool = (paged_kv.init_pool(cfg, npg, pgs) if codec == "fp"
+                    else paged_kv.init_quant_pool(cfg, npg, pgs, codec))
+            args = (cfg, params, pool, tab, lens, toks, keys, steps, temps,
+                    None)
+            return self._result(batching._batched_step_jit.lower(*args),
+                                batching._batched_step_jit, args, len(pool))
 
         name = "batched.step" if codec == "fp" else "batched.step_quant"
         return [_Entry(name, key, build)]
@@ -572,14 +567,8 @@ class _Lattice:
             tab = jnp.zeros((ms, pps), jnp.int32)
             lens = jnp.zeros((ms,), jnp.int32)
             toks = jnp.zeros((ms,), jnp.int32)
-            if codec == "fp":
-                args = (placed, pool["k"], pool["v"], tab, lens, toks)
-                required = 2
-            else:
-                args = (placed, pool["k"], pool["v"], pool["k_scale"],
-                        pool["v_scale"], tab, lens, toks)
-                required = 4
-            return self._result(pstep.lower(*args), pstep, args, required)
+            args = (placed, pool, tab, lens, toks)
+            return self._result(pstep.lower(*args), pstep, args, len(pool))
 
         return [_Entry("split.decode_step_paged",
                        self._split_key(p, f"paged:{ms}:{pps}:{pgs}:{npg}"),

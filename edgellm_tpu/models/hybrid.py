@@ -50,7 +50,7 @@ from .configs import ModelConfig
 from .flash_attention import causal_attention, decode_attention, kernel_plan
 from .mamba2 import mamba2_prefill, mamba2_step
 from .moe import moe_layer
-from .paged_kv import _attention_decode_paged
+from .paged_kv import PagePool, _attention_decode_paged
 from .transformer import _rmsnorm
 
 
@@ -282,9 +282,9 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
                                                j)
         else:
             lp = _row(params["attn"], j)
-            out, kp, vp = _attention_decode_paged(
+            out, (kp, vp) = _attention_decode_paged(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None], None, None,
-                pool_k[j], pool_v[j], page_table, lengths)
+                PagePool(pool_k[j], pool_v[j]), page_table, lengths)
             out = out[:, 0]
             pool_k, pool_v = pool_k.at[j].set(kp), pool_v.at[j].set(vp)
         h = h + cfg.residual_multiplier * out

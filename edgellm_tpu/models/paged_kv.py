@@ -49,6 +49,8 @@ import jax.numpy as jnp
 
 from ..lint import graph_contract
 from .configs import ModelConfig
+from .flash_attention import (_gather_pages, decode_attention,
+                              dequantize_kv_rows, quantize_kv_rows)
 from .transformer import (_cast_params, _layernorm, _rmsnorm, _rotate_half,
                           embed, mlp, precompute_rope, unembed)
 
@@ -300,7 +302,9 @@ class KVTierMismatchError(ValueError):
 class PagePool(NamedTuple):
     """Device-side page pool: post-rotary K/V at ``num_kv_heads`` width.
 
-    k, v: (L, num_pages, page_size, KV, hd). Page 0 is the reserved trash
+    k, v: (..., num_pages, page_size, KV, hd). The leading axes are (L,) on
+    one chip, (n_stages, stage_size) in the split runtime and none for the
+    one layer a scan body or a stage holds. Page 0 is the reserved trash
     page (see module docstring)."""
 
     k: jnp.ndarray
@@ -308,11 +312,11 @@ class PagePool(NamedTuple):
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[-4]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[-3]
 
 
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -389,11 +393,11 @@ def resolve_kv_codec(name: str) -> KVPageCodec:
 class QuantPagePool(NamedTuple):
     """Quantized device pool: packed int codes + per-row fp32 scales.
 
-    k, v: (L, num_pages, page_size, KV, hdc) codes — hdc = hd (int8) or
+    k, v: (..., num_pages, page_size, KV, hdc) codes — hdc = hd (int8) or
     hd/2 (packed int4, lane i paired with lane i + hd/2, the wire codecs'
-    contiguous-half pairing). k_scale, v_scale: (L, num_pages, page_size,
-    KV) fp32 absmax scales. Page axis 1 and token axis 2 match PagePool, so
-    the page-table/flat-index math is tier-agnostic."""
+    contiguous-half pairing). k_scale, v_scale: (..., num_pages, page_size,
+    KV) fp32 absmax scales. The leading axes, the page axis and the token
+    axis match PagePool, so the page-table/flat-index math is tier-agnostic."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -402,11 +406,11 @@ class QuantPagePool(NamedTuple):
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[-4]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[-3]
 
 
 def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -429,6 +433,15 @@ def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                          jnp.zeros(cshape, codec.code_dtype),
                          jnp.zeros(sshape, jnp.float32),
                          jnp.zeros(sshape, jnp.float32))
+
+
+def pool_tier(pool) -> str:
+    """The ``kv_codec`` name of a pool (whole, staged or one layer's): the
+    one place a tier is read from, its type and the width of its codes."""
+    if isinstance(pool, PagePool):
+        return "fp"
+    return next(c.name for c in KV_PAGE_CODECS.values()
+                if c.quantized and pool.k.dtype == c.code_dtype)
 
 
 def kv_page_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
@@ -454,49 +467,101 @@ def num_pages_for_bytes(cfg: ModelConfig, pool_bytes: int, page_size: int,
 
 
 # ---------------------------------------------------------------------------
-# jitted pool surgery: adopt a contiguous prefix, gather one back, permute
-# pages for defrag. All donate the pool so surgery is in-place.
+# Pool surgery: adopt a contiguous prefix, gather one back, copy pages for a
+# COW fork, permute them for defrag. Each is written once over the pool's
+# leaves; ``lead`` counts the axes before the page axis (1 for a chip's
+# (L, ...) pool, 2 for the split runtime's (n_stages, stage_size, ...)), so
+# the staged pool takes the same code. Page moves are BYTE moves — codes and
+# scales ride the same copy or permutation untouched, so a forked page is
+# byte-identical to its original and defrag never requantizes. Only adopt
+# (fp rows in) and gather (fp rows out) touch the codec; the *_packed pair
+# moves raw codes + scales for the bit-exact checkpoint/eviction path.
+# Whatever writes donates the pool, so surgery is in place.
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _flat(arr, lead: int):
+    """A pool leaf with its page and row axes merged into the token axis
+    that flat indices (:meth:`PagedKVCache._flat_indices`) name."""
+    sh = arr.shape
+    return arr.reshape(*sh[:lead], sh[lead] * sh[lead + 1], *sh[lead + 2:])
+
+
+def _at(lead: int, idx):
+    return (slice(None),) * lead + (idx,)
+
+
 @jax.named_scope("paged_kv.adopt")
-def _adopt_impl(pool_k, pool_v, k_seq, v_seq, dest):
-    """Scatter a contiguous (L, S, KV, hd) K/V prefix into the pool rows
-    named by ``dest`` (S,) — flat indices into the (num_pages * page_size)
-    token axis. S is static per call (one executable per adopted length)."""
-    l, pn, ps = pool_k.shape[:3]
-    tail = pool_k.shape[3:]
-    fk = pool_k.reshape(l, pn * ps, *tail).at[:, dest].set(
-        k_seq.astype(pool_k.dtype))
-    fv = pool_v.reshape(l, pn * ps, *tail).at[:, dest].set(
-        v_seq.astype(pool_v.dtype))
-    return fk.reshape(pool_k.shape), fv.reshape(pool_v.shape)
+def _set_rows(pool, rows, dest, lead: int):
+    """Scatter ``rows`` — one (..., S, ...) array a pool leaf, already in the
+    leaf's stored form — into the flat token positions ``dest`` (S,)."""
+    flat = [_flat(a, lead).at[_at(lead, dest)].set(r.astype(a.dtype))
+            for a, r in zip(pool, rows)]
+    return type(pool)(*(f.reshape(a.shape) for f, a in zip(flat, pool)))
 
 
-@jax.jit
-def _gather_impl(pool_k, pool_v, idx):
-    """Read the pool rows named by ``idx`` (span,) back as contiguous
-    (L, span, KV, hd) arrays — the checkpoint/eviction serialization path."""
-    l, pn, ps = pool_k.shape[:3]
-    tail = pool_k.shape[3:]
-    return (pool_k.reshape(l, pn * ps, *tail)[:, idx],
-            pool_v.reshape(l, pn * ps, *tail)[:, idx])
+def adopt_at(pool, k_seq, v_seq, dest, lead: int):
+    """Put contiguous (..., S, KV, hd) fp K/V rows at the flat token indices
+    ``dest`` (S,): stored as they are on the fp tier, quantized on append on
+    the others ('writes quantize on append', the at-rest contract). S is
+    static per call (one executable per adopted length)."""
+    tier = pool_tier(pool)
+    if tier == "fp":
+        return _set_rows(pool, (k_seq, v_seq), dest, lead)
+    qk, sk = quantize_kv_rows(k_seq, tier)
+    qv, sv = quantize_kv_rows(v_seq, tier)
+    return _set_rows(pool, (qk, qv, sk, sv), dest, lead)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _permute_impl(pool_k, pool_v, src):
-    """new_pool[p] = old_pool[src[p]] — the defrag move, one gather."""
-    return pool_k[:, src], pool_v[:, src]
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _adopt_impl(pool, k_seq, v_seq, dest):
+    return adopt_at(pool, k_seq, v_seq, dest, 1)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _copy_pages_impl(pool_k, pool_v, src, dst):
+@functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
+def _adopt_packed_impl(pool, k_codes, v_codes, k_scale, v_scale, dest,
+                       lead: int = 1):
+    """Scatter already-packed rows (a checkpoint's payload) — no requantize,
+    so restore is bit-exact by construction."""
+    return _set_rows(pool, (k_codes, v_codes, k_scale, v_scale), dest, lead)
+
+
+@functools.partial(jax.jit, static_argnames=("lead",))
+def _gather_packed_impl(pool, idx, lead: int = 1):
+    """The rows at flat token indices ``idx`` (span,) as stored, a leaf each:
+    the checkpoint/eviction form of a quantized pool — geometry-independent
+    AND codec-lossless. NOT donated: the pool stays live."""
+    return tuple(_flat(a, lead)[_at(lead, idx)] for a in pool)
+
+
+@functools.partial(jax.jit, static_argnames=("lead",))
+def _gather_impl(pool, idx, lead: int = 1):
+    """The rows at ``idx`` back as contiguous (..., span, KV, hd) K and V:
+    byte-identical to what was adopted on the fp tier, DEQUANTIZED to fp32 on
+    the others (the suffix-prefill compute path, which needs fp rows; lossy
+    by exactly the tier's quantization error)."""
+    rows = _gather_packed_impl(pool, idx, lead=lead)
+    tier = pool_tier(pool)
+    if tier == "fp":
+        return rows
+    kc, vc, ks, vs = rows
+    return (dequantize_kv_rows(kc, ks, tier),
+            dequantize_kv_rows(vc, vs, tier))
+
+
+@functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
+def _permute_impl(pool, src, lead: int = 1):
+    """new_pool[p] = old_pool[src[p]] — the defrag move, one gather a leaf."""
+    return type(pool)(*(a[_at(lead, src)] for a in pool))
+
+
+@functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
+def _copy_pages_impl(pool, src, dst, lead: int = 1):
     """COW fork: duplicate whole pages ``src`` (n,) into pages ``dst`` (n,).
     The forking slot then writes its private copy; every other holder keeps
     reading the original bytes."""
-    return (pool_k.at[:, dst].set(pool_k[:, src]),
-            pool_v.at[:, dst].set(pool_v[:, src]))
+    return type(pool)(*(a.at[_at(lead, dst)].set(a[_at(lead, src)])
+                        for a in pool))
 
 
 # The per-slot state store of a hybrid stack (models/hybrid.py): row j of
@@ -535,86 +600,6 @@ def _state_set_impl(conv_all, ssm_all, conv, ssm, slot):
 @jax.jit
 def _state_get_impl(conv_all, ssm_all, slot):
     return conv_all[:, slot], ssm_all[:, slot]
-
-
-# Quantized-pool twins. Page moves (defrag, COW) are BYTE moves — codes and
-# scales ride the same permutation/copy untouched, so a forked page is
-# byte-identical to its original and defrag never requantizes. Only adopt
-# (fp rows in) and gather (fp rows out) touch the codec; the *_packed pair
-# moves raw codes+scales for the bit-exact checkpoint/eviction path.
-
-
-@jax.named_scope("paged_kv.adopt")
-def _flat_rows_set(arr, dest, rows):
-    """Scatter (L, S, ...) rows into flat token positions ``dest`` (S,) of a
-    (L, num_pages, page_size, ...) pool array."""
-    l, pn, ps = arr.shape[:3]
-    tail = arr.shape[3:]
-    return (arr.reshape(l, pn * ps, *tail).at[:, dest]
-            .set(rows.astype(arr.dtype)).reshape(arr.shape))
-
-
-def _flat_rows_get(arr, idx):
-    l, pn, ps = arr.shape[:3]
-    tail = arr.shape[3:]
-    return arr.reshape(l, pn * ps, *tail)[:, idx]
-
-
-@functools.partial(jax.jit, static_argnames=("kv_codec",),
-                   donate_argnums=(0,))
-def _adopt_quant_impl(pool, k_seq, v_seq, dest, kv_codec: str):
-    """Quantize contiguous (L, S, KV, hd) fp K/V rows on append and scatter
-    codes + scales — 'writes quantize on append', the at-rest contract."""
-    from .flash_attention import quantize_kv_rows
-
-    qk, sk = quantize_kv_rows(k_seq, kv_codec)
-    qv, sv = quantize_kv_rows(v_seq, kv_codec)
-    return QuantPagePool(_flat_rows_set(pool.k, dest, qk),
-                         _flat_rows_set(pool.v, dest, qv),
-                         _flat_rows_set(pool.k_scale, dest, sk),
-                         _flat_rows_set(pool.v_scale, dest, sv))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _adopt_packed_impl(pool, k_codes, v_codes, k_scale, v_scale, dest):
-    """Scatter already-packed rows (a checkpoint's payload) — no requantize,
-    so restore is bit-exact by construction."""
-    return QuantPagePool(_flat_rows_set(pool.k, dest, k_codes),
-                         _flat_rows_set(pool.v, dest, v_codes),
-                         _flat_rows_set(pool.k_scale, dest, k_scale),
-                         _flat_rows_set(pool.v_scale, dest, v_scale))
-
-
-@jax.jit
-def _gather_packed_impl(pool, idx):
-    """Read rows back as packed codes + scales (checkpoint/eviction form —
-    geometry-independent AND codec-lossless)."""
-    return (_flat_rows_get(pool.k, idx), _flat_rows_get(pool.v, idx),
-            _flat_rows_get(pool.k_scale, idx),
-            _flat_rows_get(pool.v_scale, idx))
-
-
-@functools.partial(jax.jit, static_argnames=("kv_codec",))
-def _gather_quant_impl(pool, idx, kv_codec: str):
-    """Read rows back DEQUANTIZED to fp32 (the suffix-prefill compute path,
-    which needs fp rows; lossy by exactly the tier's quantization error)."""
-    from .flash_attention import dequantize_kv_rows
-
-    kc, vc, ks, vs = _gather_packed_impl(pool, idx)
-    return (dequantize_kv_rows(kc, ks, kv_codec),
-            dequantize_kv_rows(vc, vs, kv_codec))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _permute_pool_impl(arrays, src):
-    """Tier-agnostic defrag move over a tuple of pool arrays (page axis 1)."""
-    return tuple(a[:, src] for a in arrays)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _copy_pool_pages_impl(arrays, src, dst):
-    """Tier-agnostic COW page copy over a tuple of pool arrays."""
-    return tuple(a.at[:, dst].set(a[:, src]) for a in arrays)
 
 
 class PagedKVCache:
@@ -1119,14 +1104,7 @@ class PagedKVCache:
         if pairs and self.pool is not None:
             src = jnp.asarray([o for o, _ in pairs], jnp.int32)
             dst = jnp.asarray([n for _, n in pairs], jnp.int32)
-            if self.kv_codec == "fp":
-                k, v = _copy_pages_impl(self.pool.k, self.pool.v, src, dst)
-                self.pool = PagePool(k, v)
-            else:
-                # byte move: the fork copies codes AND scales untouched, so
-                # the private page is byte-identical to the shared original
-                self.pool = QuantPagePool(
-                    *_copy_pool_pages_impl(tuple(self.pool), src, dst))
+            self.pool = _copy_pages_impl(self.pool, src, dst)
         return pairs
 
     def device_tables(self) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -1158,13 +1136,8 @@ class PagedKVCache:
         self.ensure(slot, length)
         self.prepare_write(slot, length, start=0)
         dest = jnp.asarray(self._flat_indices(slot, length))
-        if self.kv_codec == "fp":
-            k, v = _adopt_impl(self.pool.k, self.pool.v, k_seq, v_seq, dest)
-            self.pool = PagePool(k, v)
-        else:
-            self.pool = _adopt_quant_impl(self.pool, jnp.asarray(k_seq),
-                                          jnp.asarray(v_seq), dest,
-                                          kv_codec=self.kv_codec)
+        self.pool = _adopt_impl(self.pool, jnp.asarray(k_seq),
+                                jnp.asarray(v_seq), dest)
         self.lengths[slot] = length
 
     def adopt_rows(self, slot: int, k_seq, v_seq,
@@ -1180,13 +1153,8 @@ class PagedKVCache:
                              f"length {int(self.lengths[slot])}")
         self.ensure_writable(slot, stop)
         dest = jnp.asarray(self._flat_indices(slot, stop)[start:])
-        if self.kv_codec == "fp":
-            k, v = _adopt_impl(self.pool.k, self.pool.v, k_seq, v_seq, dest)
-            self.pool = PagePool(k, v)
-        else:
-            self.pool = _adopt_quant_impl(self.pool, jnp.asarray(k_seq),
-                                          jnp.asarray(v_seq), dest,
-                                          kv_codec=self.kv_codec)
+        self.pool = _adopt_impl(self.pool, jnp.asarray(k_seq),
+                                jnp.asarray(v_seq), dest)
         self.lengths[slot] = stop
 
     def adopt_packed(self, slot: int, k_codes, v_codes, k_scale, v_scale,
@@ -1249,10 +1217,7 @@ class PagedKVCache:
         self._require_pool("gather_slot")
         n = int(self.lengths[slot])
         idx = jnp.asarray(self._flat_indices(slot, max(n, 1)))
-        if self.kv_codec == "fp":
-            k, v = _gather_impl(self.pool.k, self.pool.v, idx)
-        else:
-            k, v = _gather_quant_impl(self.pool, idx, kv_codec=self.kv_codec)
+        k, v = _gather_impl(self.pool, idx)
         return {"k": np.asarray(k)[:, :n], "v": np.asarray(v)[:, :n],
                 "length": np.asarray(n, np.int32)}
 
@@ -1287,10 +1252,7 @@ class PagedKVCache:
         self._require_pool("gather_slot_rows")
         self._check_row_range(slot, start, stop)
         idx = jnp.asarray(self._flat_indices(slot, stop)[start:])
-        if self.kv_codec == "fp":
-            k, v = _gather_impl(self.pool.k, self.pool.v, idx)
-        else:
-            k, v = _gather_quant_impl(self.pool, idx, kv_codec=self.kv_codec)
+        k, v = _gather_impl(self.pool, idx)
         return {"k": np.asarray(k), "v": np.asarray(v)}
 
     def gather_slot_rows_packed(self, slot: int, start: int,
@@ -1363,15 +1325,7 @@ class PagedKVCache:
         self._index_holds = self._index_holds[src].copy()
         self._free = list(range(self.num_pages - 1, nxt - 1, -1))
         if moved:
-            if self.kv_codec == "fp":
-                k, v = _permute_impl(self.pool.k, self.pool.v,
-                                     jnp.asarray(src))
-                self.pool = PagePool(k, v)
-            else:
-                # pages move as bytes: codes and scales ride the same
-                # permutation, nothing requantizes
-                self.pool = QuantPagePool(
-                    *_permute_pool_impl(tuple(self.pool), jnp.asarray(src)))
+            self.pool = _permute_impl(self.pool, jnp.asarray(src))
         return moved
 
     # -- serialization -----------------------------------------------------
@@ -1595,15 +1549,89 @@ def _apply_rotary_rows(x: jnp.ndarray, cos_b: jnp.ndarray,
     return jnp.concatenate([x_rot, x_pass], axis=-1)
 
 
+@jax.named_scope("paged_kv.write")
+def write_rows(pool, page_table, lengths, k, v):
+    """One layer's pool with a step's new K/V rows in it: k, v (B, 1, KV, hd)
+    post-rotary, slot i's row at position ``lengths[i]`` of its page list.
+    The fp tier stores the cast row; a quantized tier quantizes ON APPEND and
+    scatters codes + the row's own scales — neighbouring rows are untouched,
+    which is why scales are per row and not per page. The only code that
+    knows where in a pool a decode step's row goes."""
+    tier = pool_tier(pool)
+    if tier != "fp":
+        qk, sk = quantize_kv_rows(k[:, 0], tier)  # (B,KV,hdc), (B,KV)
+        qv, sv = quantize_kv_rows(v[:, 0], tier)
+        stored = (qk, qv, sk, sv)
+    ps = pool.page_size
+    # slot i's new token lands in its (length // page_size)-th page at offset
+    # length % page_size; inactive slots (all-zero table rows) land in the
+    # trash page, where duplicate scatter indices are harmless garbage
+    dest = (page_table[jnp.arange(k.shape[0]), lengths // ps] * ps
+            + lengths % ps)  # (B,)
+
+    def row(i):  # sliced after its leaf is flattened, as the fp step has always
+        # traced it: lint/entrypoints.py's *-identity contracts hash the order
+        return (k, v)[i][:, 0] if tier == "fp" else stored[i]
+
+    return type(pool)(*(
+        _flat(a, 0).at[dest].set(row(i).astype(a.dtype)).reshape(a.shape)
+        for i, a in enumerate(pool)))
+
+
+def read_span(pool, page_table, dtype):
+    """Each slot's whole span of K and V out of one layer's pool, in page
+    table order: ((B, span, KV, hd), same) in ``dtype``. The fp tier gathers
+    pages; a quantized tier gathers codes and scales a page a slice, THEN
+    dequantizes — elementwise per row, so exactly equal to dequantizing the
+    whole pool first (the numerical-equivalence contract the lint layer
+    executes). Trash-page rows come along under the caller's length mask."""
+    tier = pool_tier(pool)
+    if tier == "fp":
+        return (_gather_pages(pool.k, page_table),
+                _gather_pages(pool.v, page_table))
+    return (dequantize_kv_rows(_gather_pages(pool.k, page_table),
+                               _gather_pages(pool.k_scale, page_table),
+                               tier, dtype),
+            dequantize_kv_rows(_gather_pages(pool.v, page_table),
+                               _gather_pages(pool.v_scale, page_table),
+                               tier, dtype))
+
+
+def paged_decode_attention(q, pool, page_table, lengths):
+    """Ragged single-position attention against one layer's pool: q
+    (B, 1, H, hd) per slot; page_table (B, pages_per_slot) int32 names each
+    slot's pages in logical order (0 = the trash page for unallocated tails);
+    lengths (B,) int32 counts each slot's valid positions INCLUDING the one
+    this step wrote. Returns (B, 1, H, hd) in q's dtype; softmax in fp32.
+
+    One XLA page gather (:func:`read_span`) and
+    :func:`~edgellm_tpu.models.flash_attention.decode_attention` with vector
+    lengths: trash-page garbage lands only in masked positions, where
+    softmax of ``finfo.min`` contributes exactly 0."""
+    s1, h, hd = q.shape[1:]
+    kv, lanes = pool.k.shape[-2:]
+    if s1 != 1:
+        raise ValueError(f"paged decode is q_len=1 only, got q_len={s1}")
+    tier = pool_tier(pool)
+    if KV_PAGE_CODECS[tier].code_lanes(hd) != lanes:
+        raise ValueError(f"code width {lanes} does not match q head_dim "
+                         f"{hd} for tier {tier!r}")
+    if h % kv:
+        raise ValueError(f"ragged GQA: H={h}, KV={kv}")
+    kg, vg = read_span(pool, page_table, q.dtype)
+    return decode_attention(q, kg, vg, lengths)
+
+
 @jax.named_scope("attn.decode")
 def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                            cos_b, sin_b, k_pages, v_pages,
-                            page_table, lengths,
+                            cos_b, sin_b, pool, page_table, lengths,
                             tp_axis: Optional[str] = None):
     """The paged twin of ``transformer._attention_decode``: project the
-    (B, 1, D) hidden, rotate each slot at ITS position, scatter the new K/V
-    into each slot's current page, then ragged-attend against the gathered
-    pages. k/v_pages are ONE layer's (num_pages, page_size, KV, hd) pool."""
+    (B, 1, D) hidden, rotate each slot at ITS position, write the new K/V row
+    into each slot's current page, then ragged-attend against the slot's
+    pages. ``pool`` is ONE layer's (num_pages, page_size, KV, ...) pool, at
+    whichever tier; on a quantized tier the current token attends its OWN
+    quantized K/V, consistent with what every later step will read."""
     b, s1, d = x.shape
     hd = cfg.head_dim
     h, kv = lp["wq"].shape[-1] // hd, lp["wk"].shape[-1] // hd
@@ -1619,214 +1647,77 @@ def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     else:
         q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
         k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
-    pn, ps = k_pages.shape[0], k_pages.shape[1]
-    # slot i's new token lands in its (length // page_size)-th page at offset
-    # length % page_size; inactive slots (all-zero table rows) land in the
-    # trash page, where duplicate scatter indices are harmless garbage
-    with jax.named_scope("paged_kv.write"):
-        dest = (page_table[jnp.arange(b), lengths // ps] * ps
-                + lengths % ps)  # (B,)
-        tail = k_pages.shape[2:]
-        k_pages = k_pages.reshape(pn * ps, *tail).at[dest].set(
-            k[:, 0].astype(k_pages.dtype)).reshape(pn, ps, *tail)
-        v_pages = v_pages.reshape(pn * ps, *tail).at[dest].set(
-            v[:, 0].astype(v_pages.dtype)).reshape(pn, ps, *tail)
-
-    from .flash_attention import paged_decode_attention
-
-    out = paged_decode_attention(q, k_pages, v_pages, page_table, lengths + 1)
+    pool = write_rows(pool, page_table, lengths, k, v)
+    out = paged_decode_attention(q, pool, page_table, lengths + 1)
     out = out.reshape(b, s1, h * hd) @ lp["wo"]
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
     if "bo" in lp:
         out = out + lp["bo"]
-    return out, k_pages, v_pages
+    return out, pool
 
 
 def block_decode_paged(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,
-                       cos_b, sin_b, k_pages, v_pages, page_table, lengths,
+                       cos_b, sin_b, pool, page_table, lengths,
                        tp_axis: Optional[str] = None):
     """The paged twin of ``transformer.block_decode`` for one layer:
     same norm/residual/MLP structure, paged attention core."""
     if cfg.family == "gpt_neox":
         attn_in = _layernorm(hidden, lp["ln1_scale"], lp["ln1_bias"],
                              cfg.norm_eps)
-        attn_out, k_pages, v_pages = _attention_decode_paged(
-            cfg, lp, attn_in, cos_b, sin_b, k_pages, v_pages,
-            page_table, lengths, tp_axis)
+        attn_out, pool = _attention_decode_paged(
+            cfg, lp, attn_in, cos_b, sin_b, pool, page_table, lengths,
+            tp_axis)
         mlp_in = _layernorm(hidden, lp["ln2_scale"], lp["ln2_bias"],
                             cfg.norm_eps)
-        return (hidden + attn_out + mlp(cfg, lp, mlp_in, tp_axis),
-                k_pages, v_pages)
+        return hidden + attn_out + mlp(cfg, lp, mlp_in, tp_axis), pool
     attn_in = _rmsnorm(hidden, lp["ln1_scale"], cfg.norm_eps)
-    attn_out, k_pages, v_pages = _attention_decode_paged(
-        cfg, lp, attn_in, cos_b, sin_b, k_pages, v_pages,
-        page_table, lengths, tp_axis)
+    attn_out, pool = _attention_decode_paged(
+        cfg, lp, attn_in, cos_b, sin_b, pool, page_table, lengths, tp_axis)
     hidden = hidden + attn_out
     mlp_in = _rmsnorm(hidden, lp["ln2_scale"], cfg.norm_eps)
-    return hidden + mlp(cfg, lp, mlp_in, tp_axis), k_pages, v_pages
+    return hidden + mlp(cfg, lp, mlp_in, tp_axis), pool
 
 
 @graph_contract("paged.decode_step", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 2))
-def paged_decode_step(cfg: ModelConfig, params: dict,
-                      pool_k: jnp.ndarray, pool_v: jnp.ndarray,
+@graph_contract("paged.decode_step_quant", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 4))
+def paged_decode_step(cfg: ModelConfig, params: dict, pool,
                       page_table: jnp.ndarray, lengths: jnp.ndarray,
                       token_ids: jnp.ndarray, *,
                       compute_dtype: Optional[jnp.dtype] = None):
     """Append one position to EVERY slot of a paged pool in one pass.
 
-    pool_k/pool_v: (L, num_pages, page_size, KV, hd); page_table
-    (max_slots, pages_per_slot) and lengths (max_slots,) are TRACED — one
-    executable per pool geometry serves every admit/evict/fill state.
-    token_ids: (max_slots,) int32 (inactive slots pass any valid token; their
-    writes land in the trash page). Returns (logits (max_slots, V) fp32,
-    pool_k, pool_v).
+    pool: a :class:`PagePool` or :class:`QuantPagePool` with leading axis
+    (L,); page_table (max_slots, pages_per_slot) and lengths (max_slots,)
+    are TRACED — one executable per pool geometry and tier serves every
+    admit/evict/fill state. token_ids: (max_slots,) int32 (inactive slots
+    pass any valid token; their writes land in the trash page). Returns
+    (logits (max_slots, V) fp32, pool).
 
     Per-slot positions: the RoPE row, the page write offset, and the
     attention mask all index by each slot's own ``lengths[i]`` — the ragged
     generalization of ``decode_step``'s single ``cache.length``; per-slot
     math is bit-identical to the contiguous path (see module docstring).
+    The layer scan carries the pool pytree, whose leaves flatten in the
+    order k, v(, k_scale, v_scale).
     """
     params = _cast_params(params, compute_dtype)
     if token_ids.ndim == 1:
         token_ids = token_ids[:, None]
     hidden = embed(params, token_ids)  # (B, 1, D)
-    span = page_table.shape[1] * pool_k.shape[2]  # pages_per_slot * page_size
+    span = page_table.shape[1] * pool.page_size  # pages_per_slot * page_size
     cos, sin = precompute_rope(cfg, span)
     cos_b = cos[lengths]  # (B, rot) — each slot's own row
     sin_b = sin[lengths]
 
     def body(h, xs):
-        lp, kp, vp = xs
-        h, kp, vp = block_decode_paged(cfg, lp, h, cos_b, sin_b, kp, vp,
-                                       page_table, lengths)
-        return h, (kp, vp)
+        lp, layer_pool = xs
+        return block_decode_paged(cfg, lp, h, cos_b, sin_b, layer_pool,
+                                  page_table, lengths)
 
-    hidden, (k_new, v_new) = jax.lax.scan(
-        body, hidden, (params["layers"], pool_k, pool_v))
+    hidden, pool = jax.lax.scan(body, hidden, (params["layers"], pool))
     with jax.named_scope("unembed_sample"):
         logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
-    return logits, k_new, v_new
-
-
-@jax.named_scope("attn.decode")
-def _attention_decode_paged_quant(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                                  cos_b, sin_b, k_pages, v_pages,
-                                  k_scale, v_scale, page_table, lengths,
-                                  kv_codec: str,
-                                  tp_axis: Optional[str] = None):
-    """Quantized-pool twin of :func:`_attention_decode_paged`: the freshly
-    projected K/V row quantizes ON APPEND (codes + its own per-row scales
-    scatter into the pool — neighbouring rows are untouched, which is why
-    scales are per row and not per page), then the ragged attention
-    dequantizes in-kernel. The current token therefore attends its OWN
-    quantized K/V, consistent with what every later step will read."""
-    b, s1, d = x.shape
-    hd = cfg.head_dim
-    h, kv = lp["wq"].shape[-1] // hd, lp["wk"].shape[-1] // hd
-    q = (x @ lp["wq"]).reshape(b, s1, h, hd)
-    k = (x @ lp["wk"]).reshape(b, s1, kv, hd)
-    v = (x @ lp["wv"]).reshape(b, s1, kv, hd)
-    if "bq" in lp:
-        q = q + lp["bq"].reshape(h, hd)
-        k = k + lp["bk"].reshape(kv, hd)
-        v = v + lp["bv"].reshape(kv, hd)
-    q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
-    k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
-
-    from .flash_attention import paged_decode_attention_quant, quantize_kv_rows
-
-    with jax.named_scope("paged_kv.write"):
-        qk, sk = quantize_kv_rows(k[:, 0], kv_codec)  # (B,KV,hdc), (B,KV)
-        qv, sv = quantize_kv_rows(v[:, 0], kv_codec)
-        pn, ps = k_pages.shape[0], k_pages.shape[1]
-        dest = (page_table[jnp.arange(b), lengths // ps] * ps
-                + lengths % ps)  # (B,)
-        ctail = k_pages.shape[2:]
-        k_pages = k_pages.reshape(pn * ps, *ctail).at[dest].set(
-            qk.astype(k_pages.dtype)).reshape(pn, ps, *ctail)
-        v_pages = v_pages.reshape(pn * ps, *ctail).at[dest].set(
-            qv.astype(v_pages.dtype)).reshape(pn, ps, *ctail)
-        k_scale = k_scale.reshape(pn * ps, kv).at[dest].set(
-            sk).reshape(pn, ps, kv)
-        v_scale = v_scale.reshape(pn * ps, kv).at[dest].set(
-            sv).reshape(pn, ps, kv)
-
-    out = paged_decode_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
-                                       page_table, lengths + 1,
-                                       kv_codec=kv_codec)
-    out = out.astype(x.dtype).reshape(b, s1, h * hd) @ lp["wo"]
-    if tp_axis is not None:
-        out = jax.lax.psum(out, tp_axis)
-    if "bo" in lp:
-        out = out + lp["bo"]
-    return out, k_pages, v_pages, k_scale, v_scale
-
-
-def block_decode_paged_quant(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,
-                             cos_b, sin_b, k_pages, v_pages,
-                             k_scale, v_scale, page_table, lengths,
-                             kv_codec: str,
-                             tp_axis: Optional[str] = None):
-    """One layer of the quantized paged decode: same norm/residual/MLP
-    structure as :func:`block_decode_paged`, quantized attention core."""
-    if cfg.family == "gpt_neox":
-        attn_in = _layernorm(hidden, lp["ln1_scale"], lp["ln1_bias"],
-                             cfg.norm_eps)
-        attn_out, k_pages, v_pages, k_scale, v_scale = (
-            _attention_decode_paged_quant(
-                cfg, lp, attn_in, cos_b, sin_b, k_pages, v_pages,
-                k_scale, v_scale, page_table, lengths, kv_codec, tp_axis))
-        mlp_in = _layernorm(hidden, lp["ln2_scale"], lp["ln2_bias"],
-                            cfg.norm_eps)
-        return (hidden + attn_out + mlp(cfg, lp, mlp_in, tp_axis),
-                k_pages, v_pages, k_scale, v_scale)
-    attn_in = _rmsnorm(hidden, lp["ln1_scale"], cfg.norm_eps)
-    attn_out, k_pages, v_pages, k_scale, v_scale = (
-        _attention_decode_paged_quant(
-            cfg, lp, attn_in, cos_b, sin_b, k_pages, v_pages,
-            k_scale, v_scale, page_table, lengths, kv_codec, tp_axis))
-    hidden = hidden + attn_out
-    mlp_in = _rmsnorm(hidden, lp["ln2_scale"], cfg.norm_eps)
-    return (hidden + mlp(cfg, lp, mlp_in, tp_axis),
-            k_pages, v_pages, k_scale, v_scale)
-
-
-@graph_contract("paged.decode_step_quant", collectives={},
-                donate=lambda ctx: ctx.get("donate_min", 4))
-def paged_decode_step_quant(cfg: ModelConfig, params: dict,
-                            pool_k: jnp.ndarray, pool_v: jnp.ndarray,
-                            pool_k_scale: jnp.ndarray,
-                            pool_v_scale: jnp.ndarray,
-                            page_table: jnp.ndarray, lengths: jnp.ndarray,
-                            token_ids: jnp.ndarray, *, kv_codec: str,
-                            compute_dtype: Optional[jnp.dtype] = None):
-    """Quantized-pool twin of :func:`paged_decode_step`: a SEPARATE
-    entrypoint, not a branch — the fp tier keeps tracing the exact
-    pre-quantization graph (the disabled-build identity the lint layer
-    pins), and this one carries the four QuantPagePool arrays through the
-    layer scan. Returns (logits (max_slots, V) fp32, pool_k, pool_v,
-    pool_k_scale, pool_v_scale)."""
-    params = _cast_params(params, compute_dtype)
-    if token_ids.ndim == 1:
-        token_ids = token_ids[:, None]
-    hidden = embed(params, token_ids)  # (B, 1, D)
-    span = page_table.shape[1] * pool_k.shape[2]  # pages_per_slot * page_size
-    cos, sin = precompute_rope(cfg, span)
-    cos_b = cos[lengths]  # (B, rot) — each slot's own row
-    sin_b = sin[lengths]
-
-    def body(h, xs):
-        lp, kp, vp, ks, vs = xs
-        h, kp, vp, ks, vs = block_decode_paged_quant(
-            cfg, lp, h, cos_b, sin_b, kp, vp, ks, vs, page_table, lengths,
-            kv_codec)
-        return h, (kp, vp, ks, vs)
-
-    hidden, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-        body, hidden, (params["layers"], pool_k, pool_v,
-                       pool_k_scale, pool_v_scale))
-    with jax.named_scope("unembed_sample"):
-        logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
-    return logits, k_new, v_new, ks_new, vs_new
+    return logits, pool

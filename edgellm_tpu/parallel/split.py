@@ -38,8 +38,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.configs import ModelConfig
 from ..models.transformer import (block, block_decode, block_verify, embed,
                                   unembed, precompute_rope, KVCache)
+from ..models import paged_kv
 from ..models.paged_kv import KVTierMismatchError, block_decode_paged, \
-    block_decode_paged_quant, resolve_kv_codec
+    pool_tier, resolve_kv_codec
 from ..codecs.packing import get_wire_codec, WireCodec
 from ..codecs.faults import FaultConfig, FaultyLink, LinkPolicy, sum_counters
 from ..codecs.pallas_kernels import fused_hop, fused_hop_plan
@@ -47,104 +48,17 @@ from ..lint import graph_contract
 from ..serve.recovery import StageLostError
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-@jax.named_scope("paged_kv.adopt")
-def _adopt_paged_impl(pool_k, pool_v, k_seq, v_seq, dest):
-    """Scatter one stream's (n_stages, sz, n, KV, hd) prefill K/V into the
-    per-stage pools at flat token indices ``dest``. Donated in-place update;
-    elementwise along "stage", so the pool sharding propagates hop-free."""
-    ns, sz, pn, ps = pool_k.shape[:4]
-    tail = pool_k.shape[4:]
-    flat_k = pool_k.reshape(ns, sz, pn * ps, *tail)
-    flat_v = pool_v.reshape(ns, sz, pn * ps, *tail)
-    flat_k = flat_k.at[:, :, dest].set(k_seq.astype(flat_k.dtype))
-    flat_v = flat_v.at[:, :, dest].set(v_seq.astype(flat_v.dtype))
-    return (flat_k.reshape(pool_k.shape), flat_v.reshape(pool_v.shape))
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _copy_paged_impl(pool_k, pool_v, src, dst):
-    """Duplicate whole pages inside the per-stage pools: pages ``src``
-    (1-D int32) are copied to pages ``dst`` — the device half of a prefix
-    COW fork (the host allocator already repointed the forking slot's table
-    rows). Donated, elementwise along "stage"."""
-    return (pool_k.at[:, :, dst].set(pool_k[:, :, src]),
-            pool_v.at[:, :, dst].set(pool_v[:, :, src]))
-
-
-@jax.jit
-def _gather_paged_impl(pool_k, pool_v, idx):
-    """Inverse of :func:`_adopt_paged_impl` for one stream: gather the
-    (n_stages, sz, n, KV, hd) K/V rows at flat token indices ``idx`` out of
-    the per-stage pools. NOT donated — the pool stays live (eviction frees
-    pages host-side; checkpointing must not consume the pool)."""
-    ns, sz, pn, ps = pool_k.shape[:4]
-    tail = pool_k.shape[4:]
-    flat_k = pool_k.reshape(ns, sz, pn * ps, *tail)
-    flat_v = pool_v.reshape(ns, sz, pn * ps, *tail)
-    return flat_k[:, :, idx], flat_v[:, :, idx]
-
-
-# Quantized-pool twins (KV-at-rest tiers, models.paged_kv): the per-stage
-# pool becomes FOUR arrays — packed K/V codes plus per-row fp32 scales —
-# and page surgery moves them together as bytes. Only adopt (fp rows in,
-# quantize on append) and gather (dequantize out) touch the codec; the
-# *_packed pair is the lossless checkpoint/eviction form.
-
-
-@jax.named_scope("paged_kv.adopt")
-def _paged_rows_set(arr, dest, rows):
-    ns, sz, pn, ps = arr.shape[:4]
-    tail = arr.shape[4:]
-    return (arr.reshape(ns, sz, pn * ps, *tail).at[:, :, dest]
-            .set(rows.astype(arr.dtype)).reshape(arr.shape))
-
-
-def _paged_rows_get(arr, idx):
-    ns, sz, pn, ps = arr.shape[:4]
-    tail = arr.shape[4:]
-    return arr.reshape(ns, sz, pn * ps, *tail)[:, :, idx]
-
-
-@functools.partial(jax.jit, static_argnames=("kv_codec",),
-                   donate_argnums=(0,))
-def _adopt_paged_quant_impl(arrays, k_seq, v_seq, dest, kv_codec: str):
-    from ..models.flash_attention import quantize_kv_rows
-
-    pk, pv, ks, vs = arrays
-    qk, sk = quantize_kv_rows(k_seq, kv_codec)
-    qv, sv = quantize_kv_rows(v_seq, kv_codec)
-    return (_paged_rows_set(pk, dest, qk), _paged_rows_set(pv, dest, qv),
-            _paged_rows_set(ks, dest, sk), _paged_rows_set(vs, dest, sv))
+#: axes of a staged pool before its page axis: (n_stages, stage_size)
+_STAGED = 2
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _adopt_paged_packed_impl(arrays, k_codes, v_codes, k_scale, v_scale,
-                             dest):
-    pk, pv, ks, vs = arrays
-    return (_paged_rows_set(pk, dest, k_codes),
-            _paged_rows_set(pv, dest, v_codes),
-            _paged_rows_set(ks, dest, k_scale),
-            _paged_rows_set(vs, dest, v_scale))
-
-
-@jax.jit
-def _gather_paged_packed_impl(arrays, idx):
-    return tuple(_paged_rows_get(a, idx) for a in arrays)
-
-
-@functools.partial(jax.jit, static_argnames=("kv_codec",))
-def _gather_paged_quant_impl(arrays, idx, kv_codec: str):
-    from ..models.flash_attention import dequantize_kv_rows
-
-    kc, vc, ks, vs = _gather_paged_packed_impl(arrays, idx)
-    return (dequantize_kv_rows(kc, ks, kv_codec),
-            dequantize_kv_rows(vc, vs, kv_codec))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _copy_paged_pool_impl(arrays, src, dst):
-    return tuple(a.at[:, :, dst].set(a[:, :, src]) for a in arrays)
+def _adopt_paged_impl(pool, k_seq, v_seq, dest):
+    """One stream's (n_stages, sz, n, KV, hd) prefill K/V into the per-stage
+    pools at flat token indices ``dest``: ``paged_kv.adopt_at`` over the
+    staged pool. Donated in-place update; elementwise along "stage", so the
+    pool sharding propagates hop-free."""
+    return paged_kv.adopt_at(pool, k_seq, v_seq, dest, _STAGED)
 
 
 @jax.named_scope("unembed_sample")
@@ -1583,121 +1497,77 @@ class SplitRuntime:
     # cross a cut.
 
     def init_paged_pool(self, num_pages: int, page_size: int,
-                        dtype=jnp.float32, kv_codec: str = "fp") -> dict:
-        """Zeroed per-stage paged KV pools, placed sharded on "stage".
-        Page 0 is the trash page (see models.paged_kv) — host-side page
-        tables must never hand it out. Quantized ``kv_codec`` tiers return
-        FOUR arrays — packed codes {"k", "v"} plus per-row fp32 scales
-        {"k_scale", "v_scale"} — and a "kv_codec" tag the paged methods
-        dispatch on; the fp pool dict is unchanged."""
+                        dtype=jnp.float32, kv_codec: str = "fp"):
+        """Zeroed per-stage paged KV pools, placed sharded on "stage": the
+        chip's pool types (models.paged_kv) with leading axes (n_stages,
+        stage_size), a PagePool on the fp tier, a QuantPagePool — packed
+        codes plus per-row fp32 scales — on a quantized ``kv_codec``; the
+        paged methods read the tier off the pool. Page 0 is the trash page —
+        host-side page tables must never hand it out."""
         self._check_decode_supported()
         if num_pages < 2:
             raise ValueError("need num_pages >= 2 (page 0 is the trash page)")
         cfg = self.cfg
         codec = resolve_kv_codec(kv_codec)
-        sh = NamedSharding(self.mesh, P("stage"))
+        zeros = functools.partial(
+            jax.jit, static_argnums=(0, 1),
+            out_shardings=NamedSharding(self.mesh, P("stage")))(jnp.zeros)
+        rows = (self.split.n_stages, self.stage_size, num_pages, page_size,
+                cfg.num_kv_heads)
         if not codec.quantized:
-            shape = (self.split.n_stages, self.stage_size, num_pages,
-                     page_size, cfg.num_kv_heads, cfg.head_dim)
-            zeros = functools.partial(jax.jit, static_argnums=0,
-                                      out_shardings=sh)(
-                lambda s: jnp.zeros(s, dtype))
-            return {"k": zeros(shape), "v": zeros(shape)}
-        hdc = codec.code_lanes(cfg.head_dim)
-        cshape = (self.split.n_stages, self.stage_size, num_pages, page_size,
-                  cfg.num_kv_heads, hdc)
-        sshape = cshape[:-1]
-        czeros = functools.partial(jax.jit, static_argnums=0,
-                                   out_shardings=sh)(
-            lambda s: jnp.zeros(s, codec.code_dtype))
-        szeros = functools.partial(jax.jit, static_argnums=0,
-                                   out_shardings=sh)(
-            lambda s: jnp.zeros(s, jnp.float32))
-        return {"k": czeros(cshape), "v": czeros(cshape),
-                "k_scale": szeros(sshape), "v_scale": szeros(sshape),
-                "kv_codec": codec.name}
+            shape = rows + (cfg.head_dim,)
+            return paged_kv.PagePool(zeros(shape, dtype), zeros(shape, dtype))
+        codes = rows + (codec.code_lanes(cfg.head_dim),)
+        return paged_kv.QuantPagePool(
+            zeros(codes, codec.code_dtype), zeros(codes, codec.code_dtype),
+            zeros(rows, jnp.float32), zeros(rows, jnp.float32))
 
-    @staticmethod
-    def _pool_codec(pool: dict) -> str:
-        return pool.get("kv_codec", "fp") if "k_scale" in pool else "fp"
-
-    @staticmethod
-    def _pool_arrays(pool: dict) -> tuple:
-        return (pool["k"], pool["v"], pool["k_scale"], pool["v_scale"])
-
-    @staticmethod
-    def _pool_dict(arrays: tuple, kv_codec: str) -> dict:
-        pk, pv, ks, vs = arrays
-        return {"k": pk, "v": pv, "k_scale": ks, "v_scale": vs,
-                "kv_codec": kv_codec}
-
-    def adopt_paged(self, pool: dict, cache: dict, row: int,
-                    dest: np.ndarray, length: int) -> dict:
+    def adopt_paged(self, pool, cache: dict, row: int, dest: np.ndarray,
+                    length: int):
         """Move one stream's prefilled contiguous cache (``prefill_decode``
         row ``row``) into pool pages at flat token indices ``dest``
         ((length,) int32, from PagedKVCache._flat_indices). Donates the pool
         buffers — the scatter is stage-elementwise, no collectives. On a
         quantized pool the fp rows quantize on append."""
-        dest = jnp.asarray(dest, jnp.int32)
-        k_seq = cache["k"][:, :, row, :length]   # (n_stages, sz, n, KV, hd)
-        v_seq = cache["v"][:, :, row, :length]
-        codec = self._pool_codec(pool)
-        if codec != "fp":
-            return self._pool_dict(_adopt_paged_quant_impl(
-                self._pool_arrays(pool), k_seq, v_seq, dest,
-                kv_codec=codec), codec)
-        pk, pv = _adopt_paged_impl(pool["k"], pool["v"], k_seq, v_seq, dest)
-        return {"k": pk, "v": pv}
+        return _adopt_paged_impl(
+            pool, cache["k"][:, :, row, :length],  # (n_stages, sz, n, KV, hd)
+            cache["v"][:, :, row, :length], jnp.asarray(dest, jnp.int32))
 
-    def adopt_paged_rows(self, pool: dict, k_seq, v_seq,
-                         dest: np.ndarray) -> dict:
+    def adopt_paged_rows(self, pool, k_seq, v_seq, dest: np.ndarray):
         """Scatter an already-contiguous (n_stages, sz, n, KV, hd) K/V prefix
         — a :meth:`gather_paged` payload, possibly round-tripped through a
         checkpoint — into pool pages at flat token indices ``dest``. The
         re-admission half of eviction for the split batcher. Quantized pools
         requantize fp rows here; bit-exact resume uses the packed twin."""
-        dest = jnp.asarray(dest, jnp.int32)
-        codec = self._pool_codec(pool)
-        if codec != "fp":
-            return self._pool_dict(_adopt_paged_quant_impl(
-                self._pool_arrays(pool), jnp.asarray(k_seq),
-                jnp.asarray(v_seq), dest, kv_codec=codec), codec)
-        pk, pv = _adopt_paged_impl(pool["k"], pool["v"], jnp.asarray(k_seq),
-                                   jnp.asarray(v_seq), dest)
-        return {"k": pk, "v": pv}
+        return _adopt_paged_impl(pool, jnp.asarray(k_seq), jnp.asarray(v_seq),
+                                 jnp.asarray(dest, jnp.int32))
 
-    def adopt_paged_rows_packed(self, pool: dict, k_codes, v_codes,
-                                k_scale, v_scale, dest: np.ndarray) -> dict:
+    def adopt_paged_rows_packed(self, pool, k_codes, v_codes, k_scale,
+                                v_scale, dest: np.ndarray):
         """Scatter a :meth:`gather_paged_packed` payload back — raw codes +
         scales, no requantize, so evict -> readmit is bit-exact."""
-        codec = self._pool_codec(pool)
-        if codec == "fp":
+        if pool_tier(pool) == "fp":
             raise KVTierMismatchError(
-                offered="quantized", pool=codec,
+                offered="quantized", pool="fp",
                 where="adopt_paged_rows_packed",
                 detail="packed payloads need a quantized pool; fp pools "
                        "adopt fp rows via adopt_paged_rows")
-        return self._pool_dict(_adopt_paged_packed_impl(
-            self._pool_arrays(pool), jnp.asarray(k_codes),
-            jnp.asarray(v_codes), jnp.asarray(k_scale),
-            jnp.asarray(v_scale), jnp.asarray(dest, jnp.int32)), codec)
+        return paged_kv._adopt_packed_impl(
+            pool, jnp.asarray(k_codes), jnp.asarray(v_codes),
+            jnp.asarray(k_scale), jnp.asarray(v_scale),
+            jnp.asarray(dest, jnp.int32), lead=_STAGED)
 
-    def copy_paged_pages(self, pool: dict, src, dst) -> dict:
+    def copy_paged_pages(self, pool, src, dst):
         """Apply prefix-cache COW forks to the per-stage pools: duplicate
         pages ``src`` to ``dst`` (parallel 1-D index lists from
         ``PagedKVCache.ensure_writable``'s (old, new) pairs). Donates the
         pool buffers; stage-elementwise, no collectives. Quantized pools
         copy codes AND scales — a fork is a byte move, never a requantize."""
-        src = jnp.asarray(src, jnp.int32)
-        dst = jnp.asarray(dst, jnp.int32)
-        codec = self._pool_codec(pool)
-        if codec != "fp":
-            return self._pool_dict(_copy_paged_pool_impl(
-                self._pool_arrays(pool), src, dst), codec)
-        pk, pv = _copy_paged_impl(pool["k"], pool["v"], src, dst)
-        return {"k": pk, "v": pv}
+        return paged_kv._copy_pages_impl(pool, jnp.asarray(src, jnp.int32),
+                                         jnp.asarray(dst, jnp.int32),
+                                         lead=_STAGED)
 
-    def gather_paged(self, pool: dict, idx: np.ndarray) -> tuple:
+    def gather_paged(self, pool, idx: np.ndarray) -> tuple:
         """Gather one stream's (n_stages, sz, n, KV, hd) K/V prefix from pool
         pages at flat token indices ``idx`` — byte-identical to the
         contiguous cache rows :meth:`adopt_paged` scattered (the split twin
@@ -1705,50 +1575,50 @@ class SplitRuntime:
         Returns host (k_seq, v_seq) numpy arrays; the pool is NOT consumed.
         Quantized pools come back DEQUANTIZED to fp32 (the suffix-prefill
         compute form); the packed twin preserves the raw bytes."""
-        idx = jnp.asarray(idx, jnp.int32)
-        codec = self._pool_codec(pool)
-        if codec != "fp":
-            k_seq, v_seq = _gather_paged_quant_impl(
-                self._pool_arrays(pool), idx, kv_codec=codec)
-            return np.asarray(k_seq), np.asarray(v_seq)
-        k_seq, v_seq = _gather_paged_impl(pool["k"], pool["v"], idx)
+        k_seq, v_seq = paged_kv._gather_impl(
+            pool, jnp.asarray(idx, jnp.int32), lead=_STAGED)
         return np.asarray(k_seq), np.asarray(v_seq)
 
-    def gather_paged_packed(self, pool: dict, idx: np.ndarray) -> tuple:
+    def gather_paged_packed(self, pool, idx: np.ndarray) -> tuple:
         """Quantized-pool eviction/checkpoint form: host (k_codes, v_codes,
         k_scale, v_scale) numpy arrays at flat token indices ``idx`` — the
         raw pool bytes, so the adopt_paged_rows_packed round-trip is
         bit-exact by construction."""
-        if self._pool_codec(pool) == "fp":
+        if pool_tier(pool) == "fp":
             raise KVTierMismatchError(
                 offered="quantized", pool="fp",
                 where="gather_paged_packed",
                 detail="the packed gather form needs a quantized pool; fp "
                        "pools use gather_paged")
-        out = _gather_paged_packed_impl(self._pool_arrays(pool),
-                                        jnp.asarray(idx, jnp.int32))
+        out = paged_kv._gather_packed_impl(pool, jnp.asarray(idx, jnp.int32),
+                                           lead=_STAGED)
         return tuple(np.asarray(a) for a in out)
 
     def _paged_decode_fns(self, num_pages: int, page_size: int,
                           kv_codec: str = "fp"):
         """Build (or fetch) the jitted ragged step executable for one pool
-        geometry. Page table and lengths are TRACED — one executable per
-        (num_pages, page_size, max_slots, pages_per_slot) shape serves every
-        admit/evict/fill state (the jit-miss-free property batching relies
-        on). Quantized ``kv_codec`` tiers get their own executable carrying
-        four pool arrays (codes + scales) through every hop."""
-        if kv_codec != "fp":
-            return self._paged_decode_fns_quant(num_pages, page_size,
-                                                kv_codec)
-        key = ("paged", num_pages, page_size)
+        geometry and tier. Page table and lengths are TRACED — one executable
+        per (num_pages, page_size, max_slots, pages_per_slot) shape serves
+        every admit/evict/fill state (the jit-miss-free property batching
+        relies on). The pool crosses ``shard_map`` and the stage scan as one
+        pytree, so a quantized tier's codes and scales ride every hop's
+        carry beside each other. Quantized tiers are unpipelined only — the
+        µ-batch trash-page routing has not been run on them
+        (ContinuousBatcher refuses the combination up front)."""
+        key = ("paged", num_pages, page_size, kv_codec)
         if key in self._paged_fns_cache:
             return self._paged_fns_cache[key]
+        n_micro = (self.pipeline.num_microbatches if self.pipelined else 1)
+        if kv_codec != "fp" and n_micro > 1:
+            raise ValueError(
+                "quantized paged decode composes with the unpipelined split "
+                "runtime only (n_micro must be 1)")
         cfg, n_stages, sz = self.cfg, self.split.n_stages, self.stage_size
         codecs, mesh = self.codecs, self.mesh
         layer_pspec = self._layer_pspec
         link = self._link
         fused_plans = self.fused_plans
-        n_micro = (self.pipeline.num_microbatches if self.pipelined else 1)
+        tree_map = jax.tree_util.tree_map
 
         def _hop_protocol(run_stage, hidden, carry, fault_key):
             if link is None:
@@ -1770,8 +1640,25 @@ class SplitRuntime:
                 n_stages, codecs, n_micro, run_stage, hidden, carry,
                 link=link, fault_key=fault_key)
 
-        def stage_step_paged(local_layers, local_valid, hidden, kp_loc,
-                             vp_loc, page_table, lengths, cos_b, sin_b):
+        def layer_scan(lv, valid, page_table, lengths, cos_b, sin_b):
+            """``(hidden, pool) -> (hidden, pool)`` over this stage's layers
+            and its (sz, ...) pool. Built once a trace where the tables are
+            the step's own: every unroll step then scans the same body."""
+            def scan_body(h, xs):
+                lp, ok, layer_pool = xs
+                out, written = block_decode_paged(
+                    cfg, lp, h, cos_b, sin_b, layer_pool, page_table, lengths)
+                # padding layers are identity AND must not touch their
+                # pages
+                return jnp.where(ok, out, h), tree_map(
+                    lambda new, old: jnp.where(ok, new, old), written,
+                    layer_pool)
+
+            return lambda h, pool: jax.lax.scan(scan_body, h,
+                                                (lv, valid, pool))
+
+        def stage_step_paged(local_layers, local_valid, hidden, pool_loc,
+                             page_table, lengths, cos_b, sin_b):
             lv = {k: v[0] for k, v in local_layers.items()}
             valid = local_valid[0]
             hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
@@ -1783,27 +1670,13 @@ class SplitRuntime:
                 jax.random.fold_in(jax.random.key(link.faults.seed), 0x57E9),
                 jnp.max(lengths))
             if n_micro == 1:
-                def scan_body(h, xs):
-                    lp, ok, kp, vp = xs
-                    out, kp2, vp2 = block_decode_paged(
-                        cfg, lp, h, cos_b, sin_b, kp, vp, page_table, lengths)
-                    # padding layers are identity AND must not touch their
-                    # pages
-                    return jnp.where(ok, out, h), (jnp.where(ok, kp2, kp),
-                                                   jnp.where(ok, vp2, vp))
-
-                def run_stage(h, cache):
-                    kp, vp = cache
-                    h2, (kp2, vp2) = jax.lax.scan(scan_body, h,
-                                                  (lv, valid, kp, vp))
-                    return h2, (kp2, vp2)
-
-                out, (kp, vp), counters = _hop_protocol(
-                    run_stage, hidden, (kp_loc[0], vp_loc[0]), fkey)
+                out, pool, counters = _hop_protocol(
+                    layer_scan(lv, valid, page_table, lengths, cos_b, sin_b),
+                    hidden, tree_map(lambda a: a[0], pool_loc), fkey)
             else:
                 mb_rows = hidden.shape[0] // n_micro
 
-                def run_stage_mu(h_mu, cache, b, ok):
+                def run_stage_mu(h_mu, pool, b, ok):
                     # the pool is shared across slots so it is NOT sliced per
                     # µ-batch; instead each step sees only its µ-batch's slot
                     # rows of the page table, and fill/drain steps (ok False)
@@ -1819,30 +1692,21 @@ class SplitRuntime:
                                                          mb_rows, axis=0)
                     sb_mu = jax.lax.dynamic_slice_in_dim(sin_b, start,
                                                          mb_rows, axis=0)
+                    return layer_scan(lv, valid, pt_mu, ln_mu, cb_mu,
+                                      sb_mu)(h_mu, pool)
 
-                    def scan_body_mu(h, xs):
-                        lp, okl, kp, vp = xs
-                        out, kp2, vp2 = block_decode_paged(
-                            cfg, lp, h, cb_mu, sb_mu, kp, vp, pt_mu, ln_mu)
-                        return jnp.where(okl, out, h), (
-                            jnp.where(okl, kp2, kp), jnp.where(okl, vp2, vp))
-
-                    kp, vp = cache
-                    h2, (kp2, vp2) = jax.lax.scan(scan_body_mu, h_mu,
-                                                  (lv, valid, kp, vp))
-                    return h2, (kp2, vp2)
-
-                out, (kp, vp), counters = _hop_protocol_pipelined(
-                    run_stage_mu, hidden, (kp_loc[0], vp_loc[0]), fkey)
+                out, pool, counters = _hop_protocol_pipelined(
+                    run_stage_mu, hidden, tree_map(lambda a: a[0], pool_loc),
+                    fkey)
+            pool = tree_map(lambda a: a[None], pool)
             if link is None:
-                return out, kp[None], vp[None]
-            return out, kp[None], vp[None], counters
+                return out, pool
+            return out, pool, counters
 
-        # pools are donated: every ragged step scatters in place, same
+        # the pool is donated: every ragged step scatters in place, same
         # aliasing discipline the "split.decode_step_paged" contract asserts
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step_paged_fn(placed, pool_k, pool_v, page_table, lengths,
-                          token_ids):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def step_paged_fn(placed, pool, page_table, lengths, token_ids):
             hidden = embed(placed, token_ids[:, None])  # (B, 1, D)
             span = page_table.shape[1] * page_size
             cos, sin = precompute_rope(cfg, span)
@@ -1850,130 +1714,20 @@ class SplitRuntime:
             sin_b = sin[lengths]
             lspecs = {k: layer_pspec(k, v.ndim)
                       for k, v in placed["layers"].items()}
-            if link is None:
-                out, kp, vp = shard_map(
-                    stage_step_paged, mesh=mesh,
-                    in_specs=(lspecs, P("stage"), P(), P("stage"), P("stage"),
-                              P(), P(), P(), P()),
-                    out_specs=(P(), P("stage"), P("stage")),
-                    check_vma=False,
-                )(placed["layers"], placed["layers_valid"], hidden,
-                  pool_k, pool_v, page_table, lengths, cos_b, sin_b)
-                return _unembed_last(cfg, placed, out), kp, vp
-            out, kp, vp, counters = shard_map(
+            # P("stage") on the pool is a prefix of its pytree: every leaf
+            out, *rest = shard_map(
                 stage_step_paged, mesh=mesh,
-                in_specs=(lspecs, P("stage"), P(), P("stage"), P("stage"),
+                in_specs=(lspecs, P("stage"), P(), P("stage"),
                           P(), P(), P(), P()),
-                out_specs=(P(), P("stage"), P("stage"), P()),
+                out_specs=((P(), P("stage")) if link is None
+                           else (P(), P("stage"), P())),
                 check_vma=False,
             )(placed["layers"], placed["layers_valid"], hidden,
-              pool_k, pool_v, page_table, lengths, cos_b, sin_b)
-            return _unembed_last(cfg, placed, out), kp, vp, counters
+              pool, page_table, lengths, cos_b, sin_b)
+            return (_unembed_last(cfg, placed, out), *rest)
 
         self._paged_fns_cache[key] = step_paged_fn
         return step_paged_fn
-
-    def _paged_decode_fns_quant(self, num_pages: int, page_size: int,
-                                kv_codec: str):
-        """Quantized twin of :meth:`_paged_decode_fns`: the scan carries
-        packed codes AND per-row scales, every layer dequantizes in-kernel
-        (models.flash_attention.paged_decode_attention_quant), and appends
-        quantize before the scatter. Unpipelined only — the µ-batch trash
-        -page routing has no quant twin (ContinuousBatcher refuses the
-        combination up front)."""
-        key = ("paged_quant", num_pages, page_size, kv_codec)
-        if key in self._paged_fns_cache:
-            return self._paged_fns_cache[key]
-        if self.pipelined and self.pipeline.num_microbatches > 1:
-            raise ValueError(
-                "quantized paged decode composes with the unpipelined split "
-                "runtime only (n_micro must be 1)")
-        cfg, n_stages, sz = self.cfg, self.split.n_stages, self.stage_size
-        codecs, mesh = self.codecs, self.mesh
-        layer_pspec = self._layer_pspec
-        link = self._link
-        fused_plans = self.fused_plans
-
-        def _hop_protocol(run_stage, hidden, carry, fault_key):
-            if link is None:
-                out, c = run_pipeline_stages_carry(
-                    n_stages, codecs, run_stage, hidden, carry,
-                    fused_plans=fused_plans)
-                return out, c, None
-            return run_pipeline_stages_carry(
-                n_stages, codecs, run_stage, hidden, carry,
-                link=link, fault_key=fault_key)
-
-        def stage_step_paged_quant(local_layers, local_valid, hidden, kp_loc,
-                                   vp_loc, ks_loc, vs_loc, page_table,
-                                   lengths, cos_b, sin_b):
-            lv = {k: v[0] for k, v in local_layers.items()}
-            valid = local_valid[0]
-            hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
-            fkey = None if link is None else jax.random.fold_in(
-                jax.random.fold_in(jax.random.key(link.faults.seed), 0x57E9),
-                jnp.max(lengths))
-
-            def scan_body(h, xs):
-                lp, ok, kp, vp, ks, vs = xs
-                out, kp2, vp2, ks2, vs2 = block_decode_paged_quant(
-                    cfg, lp, h, cos_b, sin_b, kp, vp, ks, vs, page_table,
-                    lengths, kv_codec)
-                # padding layers are identity AND must not touch their pages
-                return jnp.where(ok, out, h), (
-                    jnp.where(ok, kp2, kp), jnp.where(ok, vp2, vp),
-                    jnp.where(ok, ks2, ks), jnp.where(ok, vs2, vs))
-
-            def run_stage(h, cache):
-                kp, vp, ks, vs = cache
-                h2, cache2 = jax.lax.scan(scan_body, h,
-                                          (lv, valid, kp, vp, ks, vs))
-                return h2, cache2
-
-            out, (kp, vp, ks, vs), counters = _hop_protocol(
-                run_stage, hidden,
-                (kp_loc[0], vp_loc[0], ks_loc[0], vs_loc[0]), fkey)
-            if link is None:
-                return out, kp[None], vp[None], ks[None], vs[None]
-            return out, kp[None], vp[None], ks[None], vs[None], counters
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def step_paged_quant_fn(placed, pool_k, pool_v, pool_ks, pool_vs,
-                                page_table, lengths, token_ids):
-            hidden = embed(placed, token_ids[:, None])  # (B, 1, D)
-            span = page_table.shape[1] * page_size
-            cos, sin = precompute_rope(cfg, span)
-            cos_b = cos[lengths]
-            sin_b = sin[lengths]
-            lspecs = {k: layer_pspec(k, v.ndim)
-                      for k, v in placed["layers"].items()}
-            if link is None:
-                out, kp, vp, ks, vs = shard_map(
-                    stage_step_paged_quant, mesh=mesh,
-                    in_specs=(lspecs, P("stage"), P(), P("stage"), P("stage"),
-                              P("stage"), P("stage"), P(), P(), P(), P()),
-                    out_specs=(P(), P("stage"), P("stage"), P("stage"),
-                               P("stage")),
-                    check_vma=False,
-                )(placed["layers"], placed["layers_valid"], hidden,
-                  pool_k, pool_v, pool_ks, pool_vs, page_table, lengths,
-                  cos_b, sin_b)
-                return _unembed_last(cfg, placed, out), kp, vp, ks, vs
-            out, kp, vp, ks, vs, counters = shard_map(
-                stage_step_paged_quant, mesh=mesh,
-                in_specs=(lspecs, P("stage"), P(), P("stage"), P("stage"),
-                          P("stage"), P("stage"), P(), P(), P(), P()),
-                out_specs=(P(), P("stage"), P("stage"), P("stage"),
-                           P("stage"), P()),
-                check_vma=False,
-            )(placed["layers"], placed["layers_valid"], hidden,
-              pool_k, pool_v, pool_ks, pool_vs, page_table, lengths,
-              cos_b, sin_b)
-            return (_unembed_last(cfg, placed, out), kp, vp, ks, vs,
-                    counters)
-
-        self._paged_fns_cache[key] = step_paged_quant_fn
-        return step_paged_quant_fn
 
     @graph_contract(
         "split.decode_step_paged",
@@ -1989,7 +1743,7 @@ class SplitRuntime:
         wire_dtypes=lambda ctx: ctx["wire_dtypes"],
         wire_bytes=lambda ctx: ctx["wire_bytes"],
         donate=lambda ctx: ctx.get("donate_min", 2))
-    def decode_step_paged(self, placed_params: dict, pool: dict,
+    def decode_step_paged(self, placed_params: dict, pool,
                           page_table: jnp.ndarray, lengths: jnp.ndarray,
                           token_ids: jnp.ndarray) -> tuple:
         """One ragged decode position across the pipeline: every active slot
@@ -2005,32 +1759,14 @@ class SplitRuntime:
         if self.pipelined:
             self.pipeline.validate_batch(int(np.shape(page_table)[0]),
                                          "paged decode slot count")
-        num_pages, page_size = pool["k"].shape[2], pool["k"].shape[3]
-        codec = self._pool_codec(pool)
-        step_fn = self._paged_decode_fns(int(num_pages), int(page_size),
-                                         kv_codec=codec)
-        page_table = jnp.asarray(page_table, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        if codec != "fp":
-            if self._link is None:
-                logits, pk, pv, ks, vs = step_fn(
-                    placed_params, pool["k"], pool["v"], pool["k_scale"],
-                    pool["v_scale"], page_table, lengths, token_ids)
-            else:
-                logits, pk, pv, ks, vs, counters = step_fn(
-                    placed_params, pool["k"], pool["v"], pool["k_scale"],
-                    pool["v_scale"], page_table, lengths, token_ids)
-                self._accum_counters(counters)
-            return logits, self._pool_dict((pk, pv, ks, vs), codec)
-        if self._link is None:
-            logits, pk, pv = step_fn(placed_params, pool["k"], pool["v"],
-                                     page_table, lengths, token_ids)
-        else:
-            logits, pk, pv, counters = step_fn(
-                placed_params, pool["k"], pool["v"], page_table, lengths,
-                token_ids)
-            self._accum_counters(counters)
-        return logits, {"k": pk, "v": pv}
+        step_fn = self._paged_decode_fns(pool.num_pages, pool.page_size,
+                                         kv_codec=pool_tier(pool))
+        logits, pool, *counters = step_fn(
+            placed_params, pool, jnp.asarray(page_table, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), token_ids)
+        if counters:
+            self._accum_counters(counters[0])
+        return logits, pool
 
     # ---------- accounting ----------
 

@@ -503,8 +503,8 @@ def _validate_params_json(p: dict) -> None:
                     "stream at a time (B == 1), leaving nothing to "
                     "micro-batch — drop one of the two blocks")
         if pc.enabled and p.get("kv_at_rest", {}).get("codec", "fp") != "fp":
-            # mirror of _paged_decode_fns_quant's refusal: the µ-batch
-            # trash-page routing has no quant twin
+            # mirror of _paged_decode_fns's refusal: the µ-batch
+            # trash-page routing has not been run on a quantized pool
             die("kv_at_rest + pipeline: quantized paged decode composes "
                 "with the unpipelined split runtime only — drop 'pipeline' "
                 "or use codec 'fp'")

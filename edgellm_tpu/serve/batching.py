@@ -74,8 +74,7 @@ import jax.numpy as jnp
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_recurrent_state
 from ..models.paged_kv import OutOfPages, OutOfSlots, PagedKVCache, \
-    PrefixCacheConfig, QuantPagePool, SlotState, paged_decode_step, \
-    paged_decode_step_quant, resolve_kv_codec
+    PrefixCacheConfig, SlotState, paged_decode_step, resolve_kv_codec
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
 from ..obs.flight import flight_dump_for
@@ -214,34 +213,17 @@ def _batched_sample(logits, key_data, steps, temps):
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "compute_dtype"),
-                   donate_argnums=(2, 3))
-def _batched_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                      page_table, lengths, token_ids, key_data, steps, temps,
+                   donate_argnums=(2,))
+def _batched_step_jit(cfg: ModelConfig, params: dict, pool, page_table,
+                      lengths, token_ids, key_data, steps, temps,
                       compute_dtype):
-    logits, pool_k, pool_v = paged_decode_step(
-        cfg, params, pool_k, pool_v, page_table, lengths, token_ids,
+    """The ragged step and its sampler for every tier: ``pool`` (a PagePool
+    or a QuantPagePool) is donated whole and comes back updated. The fp
+    tier's jaxpr is the one the kvq-disabled-identity contract pins."""
+    logits, pool = paged_decode_step(
+        cfg, params, pool, page_table, lengths, token_ids,
         compute_dtype=compute_dtype)
-    return _batched_sample(logits, key_data, steps, temps), pool_k, pool_v
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "kv_codec", "compute_dtype"),
-                   donate_argnums=(2, 3, 4, 5))
-def _batched_step_quant_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                            pool_k_scale, pool_v_scale, page_table, lengths,
-                            token_ids, key_data, steps, temps, kv_codec,
-                            compute_dtype):
-    """Quantized-tier twin of :func:`_batched_step_jit`: the four
-    QuantPagePool arrays are donated, sampling is the same vmapped
-    ``_batched_sample``. A SEPARATE jit — the fp tier keeps hitting the
-    executable above, whose jaxpr the kvq-disabled-identity contract pins."""
-    logits, pool_k, pool_v, pool_k_scale, pool_v_scale = (
-        paged_decode_step_quant(
-            cfg, params, pool_k, pool_v, pool_k_scale, pool_v_scale,
-            page_table, lengths, token_ids, kv_codec=kv_codec,
-            compute_dtype=compute_dtype))
-    return (_batched_sample(logits, key_data, steps, temps),
-            pool_k, pool_v, pool_k_scale, pool_v_scale)
+    return _batched_sample(logits, key_data, steps, temps), pool
 
 
 @functools.partial(jax.jit,
@@ -270,11 +252,8 @@ def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
 
 def batched_step_cache_size() -> int:
     """Executables compiled for the ragged step so far in this process — the
-    jit-miss counter :meth:`ContinuousBatcher.step` reports deltas of.
-    Counts BOTH tier executables: a steady-state serve loop must stop
-    missing on whichever one its pool uses."""
+    jit-miss counter :meth:`ContinuousBatcher.step` reports deltas of."""
     return (_batched_step_jit._cache_size()
-            + _batched_step_quant_jit._cache_size()
             + _batched_hybrid_step_jit._cache_size())
 
 
@@ -996,23 +975,12 @@ class ContinuousBatcher:
                 self.pool.pool = type(self.pool.pool)(k, v)
                 self.pool.state = SlotState(conv, ssm)
                 del state
-            elif self.bcfg.kv_codec != "fp":
-                toks, k, v, ks, vs = _batched_step_quant_jit(
-                    self.cfg, self.params, self.pool.pool.k,
-                    self.pool.pool.v, self.pool.pool.k_scale,
-                    self.pool.pool.v_scale, page_table, lengths,
-                    jnp.asarray(token_ids), jnp.asarray(key_data),
-                    jnp.asarray(steps), jnp.asarray(temps),
-                    self.bcfg.kv_codec, self.bcfg.compute_dtype)
-                self.pool.pool = QuantPagePool(k, v, ks, vs)
             else:
-                toks, k, v = _batched_step_jit(
-                    self.cfg, self.params, self.pool.pool.k,
-                    self.pool.pool.v, page_table, lengths,
-                    jnp.asarray(token_ids), jnp.asarray(key_data),
+                toks, self.pool.pool = _batched_step_jit(
+                    self.cfg, self.params, self.pool.pool, page_table,
+                    lengths, jnp.asarray(token_ids), jnp.asarray(key_data),
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
-                self.pool.pool = type(self.pool.pool)(k, v)
         with obs_phase("batch.step.sync", acc, "sync_s", after=ph,
                        step=step_no) as ph:
             toks_host = np.asarray(toks)  # ONE host sync per step

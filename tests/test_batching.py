@@ -14,10 +14,12 @@ import jax
 import jax.numpy as jnp
 
 from edgellm_tpu.models import init_params, tiny_config
-from edgellm_tpu.models.flash_attention import (decode_attention, decode_plan,
-                                                paged_decode_attention)
-from edgellm_tpu.models.paged_kv import (OutOfPages, OutOfSlots,
-                                         PagedKVCache)
+from edgellm_tpu.models.flash_attention import decode_attention
+from edgellm_tpu.models import paged_kv
+from edgellm_tpu.models.paged_kv import (OutOfPages, OutOfSlots, PagedKVCache,
+                                         PagePool, init_pool, init_quant_pool,
+                                         paged_decode_attention,
+                                         paged_decode_step)
 from edgellm_tpu.serve.batching import (BatchingConfig, ContinuousBatcher,
                                         _batched_sample,
                                         batched_step_cache_size)
@@ -349,30 +351,13 @@ def test_checkpoint_refuses_other_model(params, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# kernel plan gates + attention fallback
+# the paged attend: one page gather, then decode_attention
 # ---------------------------------------------------------------------------
 
 
-def test_decode_plan_paged_gates(monkeypatch):
-    # contiguous decode has no validated kernel: always None
-    assert decode_plan(256, 4, 2, 64) is None
-    # paged + forced pallas: the plan dispatches on any backend
-    monkeypatch.setenv("EDGELLM_ATTN", "pallas")
-    assert decode_plan(64, 4, 2, 64, pages=(8, 8)) == ("paged", (8, 8))
-    assert decode_plan(64, 4, 2, 64, pages=(4, 8)) is None  # pps*ps != cap
-    assert decode_plan(64, 4, 2, 64, pages=(16, 4)) is None  # ps % 8
-    assert decode_plan(64, 4, 2, 8, pages=(8, 8)) is None   # hd unvalidated
-    monkeypatch.setenv("EDGELLM_ATTN", "xla")
-    assert decode_plan(64, 4, 2, 64, pages=(8, 8)) is None
-    monkeypatch.delenv("EDGELLM_ATTN")
-    if jax.default_backend() != "tpu":
-        # default: off-TPU the paged kernel is never earned
-        assert decode_plan(64, 4, 2, 64, pages=(8, 8)) is None
-
-
-def test_paged_attention_fallback_matches_contiguous():
-    # the XLA gather fallback must agree bitwise with decode_attention over
-    # each slot's contiguous view, and be invariant to garbage beyond length
+def test_paged_attention_matches_contiguous():
+    # the page gather must agree bitwise with decode_attention over each
+    # slot's contiguous view, and be invariant to garbage beyond length
     rng = np.random.default_rng(11)
     b, h, kv, hd, pn, ps, pps = 3, 4, 2, 8, 7, 4, 2
     span = pps * ps
@@ -381,7 +366,7 @@ def test_paged_attention_fallback_matches_contiguous():
     vp = jnp.asarray(rng.standard_normal(kp.shape).astype(np.float32))
     pt = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
     lengths = jnp.asarray([3, 8, 5], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, pt, lengths)
+    out = paged_decode_attention(q, PagePool(kp, vp), pt, lengths)
     idx = (np.asarray(pt)[:, :, None] * ps
            + np.arange(ps)[None, None, :]).reshape(b, span)
     kg = jnp.asarray(np.asarray(kp).reshape(pn * ps, kv, hd)[idx])
@@ -396,8 +381,8 @@ def test_paged_attention_fallback_matches_contiguous():
             page, off = np.asarray(pt)[i, pos // ps], pos % ps
             kp2[page, off] = 1e6 * (i + 1)
             vp2[page, off] = -1e6
-    out2 = paged_decode_attention(q, jnp.asarray(kp2), jnp.asarray(vp2),
-                                  pt, lengths)
+    out2 = paged_decode_attention(
+        q, PagePool(jnp.asarray(kp2), jnp.asarray(vp2)), pt, lengths)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 
@@ -430,9 +415,6 @@ def test_page_gather_equals_flat_row_gather_bitwise(kv, hd, ps, monkeypatch):
     # the K/V handed to the attend, and what comes out of it, must be the
     # flat-row gather's to the bit: same values, same order, trash-page rows
     # only under the length mask
-    from edgellm_tpu.models import flash_attention as fa
-
-    monkeypatch.setenv("EDGELLM_ATTN", "xla")
     rng = np.random.default_rng(kv * hd + ps)
     pn, h = 11, 2 * kv
     pt, lengths = _ragged_paged_case(ps)
@@ -441,14 +423,14 @@ def test_page_gather_equals_flat_row_gather_bitwise(kv, hd, ps, monkeypatch):
     kp = jnp.asarray(rng.standard_normal((pn, ps, kv, hd)), jnp.bfloat16)
     vp = jnp.asarray(rng.standard_normal((pn, ps, kv, hd)), jnp.bfloat16)
     handed = []
-    attend = fa.decode_attention
+    attend = decode_attention
 
     def recording_attend(q_, k_, v_, lengths_):
         handed.append((k_, v_))
         return attend(q_, k_, v_, lengths_)
 
-    monkeypatch.setattr(fa, "decode_attention", recording_attend)
-    out = paged_decode_attention(q, kp, vp, pt, lengths)
+    monkeypatch.setattr(paged_kv, "decode_attention", recording_attend)
+    out = paged_decode_attention(q, PagePool(kp, vp), pt, lengths)
     (kg, vg), = handed
     k_old, v_old = _flat_row_gather(kp, pt), _flat_row_gather(vp, pt)
     assert kg.shape == k_old.shape == (b, pt.shape[1] * ps, kv, hd)
@@ -481,27 +463,19 @@ def test_decode_step_fetches_pool_by_page_not_by_row(params, tier):
     # the guard against the per-row gather coming back unseen: in the traced
     # step, every gather under attn.decode that reads a pool array takes one
     # whole page a slice — none reads the pool flattened to rows
-    from edgellm_tpu.models.paged_kv import (paged_decode_step,
-                                             paged_decode_step_quant)
-
     pn, ps, slots, pps = BCFG.num_pages, BCFG.page_size, BCFG.max_slots, 4
     kv, hd = CFG.num_kv_heads, CFG.head_dim
     table = jnp.zeros((slots, pps), jnp.int32)
     ints = jnp.zeros((slots,), jnp.int32)
     if tier == "fp":
-        pool = jnp.zeros((CFG.num_layers, pn, ps, kv, hd), jnp.float32)
-        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_step(CFG, *a))(
-            params, pool, pool, table, ints, ints)
+        pool = init_pool(CFG, pn, ps)
         want = [ps * kv * hd] * 2                  # a page of K, of V
     else:
-        hdc = hd // 2 if tier == "int4_per_channel" else hd
-        codes = jnp.zeros((CFG.num_layers, pn, ps, kv, hdc),
-                          jnp.uint8 if hdc != hd else jnp.int8)
-        scale = jnp.zeros((CFG.num_layers, pn, ps, kv), jnp.float32)
-        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_step_quant(
-            CFG, *a, kv_codec=tier))(params, codes, codes, scale, scale,
-                                     table, ints, ints)
+        pool = init_quant_pool(CFG, pn, ps, tier)
+        hdc = pool.k.shape[-1]
         want = [ps * kv * hdc] * 2 + [ps * kv] * 2  # codes and scales
+    jaxpr = jax.make_jaxpr(lambda *a: paged_decode_step(CFG, *a))(
+        params, pool, table, ints, ints)
     fetches = []
     for path, eqn in _gathers(jaxpr.jaxpr):
         shape = eqn.invars[0].aval.shape

@@ -19,8 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from edgellm_tpu.models import init_params, tiny_config
-from edgellm_tpu.models.paged_kv import (PagedKVCache, QuantPagePool,
-                                         paged_decode_step_quant)
+from edgellm_tpu.models.paged_kv import PagedKVCache, paged_decode_step
 from edgellm_tpu.serve.batching import (BatchingConfig, ContinuousBatcher,
                                         _key_data)
 from edgellm_tpu.serve.decode import (_prefill_jit, _sample, generate,
@@ -128,8 +127,7 @@ def _solo_split(params, s, request):
         rng_key=jax.random.key(s["seed"])))[0]
 
 
-_quant_step = jax.jit(paged_decode_step_quant,
-                      static_argnames=("cfg", "kv_codec"))
+_quant_step = jax.jit(paged_decode_step, static_argnames=("cfg",))
 
 
 def _solo_quant(params, s, _request):
@@ -152,11 +150,8 @@ def _solo_quant(params, s, _request):
         token_ids = np.zeros((BCFG.max_slots,), np.int32)
         token_ids[slot] = toks[-1]
         table, lengths = pool.device_tables()
-        q = pool.pool
-        logits, *packed = _quant_step(
-            CFG, params, q.k, q.v, q.k_scale, q.v_scale, table, lengths,
-            jnp.asarray(token_ids), kv_codec=CODEC)
-        pool.pool = QuantPagePool(*packed)
+        logits, pool.pool = _quant_step(CFG, params, pool.pool, table,
+                                        lengths, jnp.asarray(token_ids))
         toks.append(int(_sample(logits[slot][None],
                                 jax.random.fold_in(key, len(toks)),
                                 s["temp"])[0]))

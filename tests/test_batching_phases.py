@@ -387,15 +387,15 @@ def _step_args(params):
     b = ContinuousBatcher(CFG, params, BCFG)
     table, lengths = b.pool.device_tables()
     n = BCFG.max_slots
-    return (params, b.pool.pool.k, b.pool.pool.v, table, lengths,
+    return (params, b.pool.pool, table, lengths,
             jnp.zeros((n,), jnp.int32), jnp.asarray(b._free_key_rows),
             jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32))
 
 
-def _step(p, k, v, table, lengths, toks, key_data, steps, temps):
-    logits, k, v = paged_kv.paged_decode_step(CFG, p, k, v, table, lengths,
+def _step(p, pool, table, lengths, toks, key_data, steps, temps):
+    logits, pool = paged_kv.paged_decode_step(CFG, p, pool, table, lengths,
                                               toks)
-    return batching._batched_sample(logits, key_data, steps, temps), k, v
+    return batching._batched_sample(logits, key_data, steps, temps), pool
 
 
 def test_named_scopes_change_no_jaxpr_of_the_batched_step(params,
@@ -415,6 +415,8 @@ def test_named_scopes_change_no_jaxpr_of_the_batched_step(params,
                         lambda name: contextlib.nullcontext())
     monkeypatch.setattr(paged_kv, "_attention_decode_paged",
                         paged_kv._attention_decode_paged.__wrapped__)
+    monkeypatch.setattr(paged_kv, "write_rows",
+                        paged_kv.write_rows.__wrapped__)
     monkeypatch.setattr(paged_kv, "mlp", transformer.mlp.__wrapped__)
     monkeypatch.setattr(batching, "_batched_sample",
                         batching._batched_sample.__wrapped__)
@@ -433,18 +435,17 @@ def test_split_step_carries_stage_and_hop_scopes(split_rt, params):
                           placed_params=placed)
     fn = rt._paged_decode_fns(BCFG.num_pages, BCFG.page_size)
     table, lengths = b.pool.device_tables()
-    text = fn.lower(placed, b._split_pool["k"], b._split_pool["v"], table,
-                    lengths, jnp.zeros((BCFG.max_slots,), jnp.int32)
+    text = fn.lower(placed, b._split_pool, table, lengths,
+                    jnp.zeros((BCFG.max_slots,), jnp.int32)
                     ).as_text(debug_info=True)
     for scope in ("split.stage", "split.hop.0", "paged_kv.write",
                   "unembed_sample"):
         assert scope in text, scope
     assert "split.hop.1" not in text            # one cut
-    pk = b._split_pool["k"]
+    pk = b._split_pool.k
     rows = jnp.zeros(pk.shape[:2] + (3,) + pk.shape[4:], pk.dtype)
     adopt = split_mod._adopt_paged_impl.lower(
-        pk, b._split_pool["v"], rows, rows,
-        jnp.arange(3)).as_text(debug_info=True)
+        b._split_pool, rows, rows, jnp.arange(3)).as_text(debug_info=True)
     assert "paged_kv.adopt" in adopt
 
 

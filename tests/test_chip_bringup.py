@@ -98,3 +98,28 @@ def test_serve_exits_nonzero_when_the_batcher_raises(tmp_path, capsys,
     assert "batcher:RuntimeError" in cap.err
     rep = json.load(open(tmp_path / "out" / "serve_report.json"))
     assert rep["outcomes"] == {"failed": 3}
+
+
+def test_chip_smoke_phases_follow_the_paged_step():
+    """What of ``chip_smoke.py`` calls the paged step runs here at a toy size:
+    the decode site names its one path, and prefill -> adopt -> paged step
+    matches the dense forward."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    from edgellm_tpu.models import tiny_config
+
+    cfg = tiny_config("qwen2", num_layers=2, hidden_size=32, num_heads=4,
+                      vocab_size=128)
+    batching = {"page_size": 8, "pages_per_slot": 4, "max_slots": 2,
+                "num_pages": 9}
+    sites = chip_smoke.dispatch_phase(cfg, "float32", prompt_lens=(12,),
+                                      batching=batching, sweep_len=16)
+    assert sites["attention"][-1] == {"site": "serve.decode[paged]",
+                                      "seq": 32, "plan": "xla page gather"}
+    ref = chip_smoke.reference_phase(cfg, batching=batching, prompt_len=12,
+                                     n_steps=3)
+    assert len(ref["logit_max_abs_err"]) == 4   # the prefill's and 3 steps'
+    assert max(ref["logit_max_abs_err"]) <= chip_smoke.LOGIT_ATOL

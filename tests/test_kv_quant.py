@@ -19,13 +19,13 @@ import jax.numpy as jnp
 
 from edgellm_tpu.models import init_params, tiny_config
 from edgellm_tpu.models.flash_attention import (dequantize_kv_rows,
-                                                paged_decode_attention,
-                                                paged_decode_attention_quant,
                                                 quantize_kv_rows)
 from edgellm_tpu.models.paged_kv import (KV_PAGE_CODECS, OutOfPages,
-                                         PagedKVCache, PrefixCacheConfig,
+                                         PagedKVCache, PagePool,
+                                         PrefixCacheConfig, QuantPagePool,
                                          kv_page_bytes,
                                          num_pages_for_bytes,
+                                         paged_decode_attention,
                                          resolve_kv_codec)
 from edgellm_tpu.serve.batching import BatchingConfig, ContinuousBatcher
 from edgellm_tpu.serve.decode import generate
@@ -172,8 +172,8 @@ def test_quantize_roundtrip_error_bound_and_idempotence(tier):
 
 @pytest.mark.parametrize("tier", TIERS)
 def test_paged_quant_fallback_matches_dequantized_pool(tier):
-    # the quant decode-attention entrypoint == dequantize the WHOLE pool
-    # then the plain paged path, exactly (same contract graphlint executes)
+    # the attend over a quantized pool == dequantize the WHOLE pool then
+    # the attend over an fp pool, exactly (same contract graphlint executes)
     npg, pgs, ms, pps = 5, 8, 2, 2
     rng = np.random.default_rng(3)
     kv = (npg * pgs, CFG2.num_kv_heads, CFG2.head_dim)
@@ -187,30 +187,29 @@ def test_paged_quant_fallback_matches_dequantized_pool(tier):
     tab = jnp.asarray(rng.permutation(np.arange(1, npg))[:ms * pps]
                       .reshape(ms, pps).astype(np.int32))
     lens = jnp.asarray([pgs + 3, pgs - 2], jnp.int32)
-    got = paged_decode_attention_quant(
-        q, kq.reshape(npg, pgs, -1, hdc), vq.reshape(npg, pgs, -1, hdc),
-        ks.reshape(npg, pgs, -1), vs.reshape(npg, pgs, -1), tab, lens,
-        kv_codec=tier)
+    got = paged_decode_attention(
+        q, QuantPagePool(kq.reshape(npg, pgs, -1, hdc),
+                         vq.reshape(npg, pgs, -1, hdc),
+                         ks.reshape(npg, pgs, -1), vs.reshape(npg, pgs, -1)),
+        tab, lens)
     kf = dequantize_kv_rows(kq, ks, tier)
     vf = dequantize_kv_rows(vq, vs, tier)
     ref = paged_decode_attention(
-        q, kf.reshape(npg, pgs, -1, CFG2.head_dim),
-        vf.reshape(npg, pgs, -1, CFG2.head_dim), tab, lens)
+        q, PagePool(kf.reshape(npg, pgs, -1, CFG2.head_dim),
+                    vf.reshape(npg, pgs, -1, CFG2.head_dim)), tab, lens)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 @pytest.mark.parametrize("kv,hd,ps", [(2, 64, 16), (2, 128, 16),
                                       (8, 128, 16), (2, 64, 8)])
 @pytest.mark.parametrize("tier", TIERS)
-def test_quant_page_gather_equals_flat_row_gather_bitwise(tier, kv, hd, ps,
-                                                          monkeypatch):
+def test_quant_page_gather_equals_flat_row_gather_bitwise(tier, kv, hd, ps):
     # the quantized twin of test_batching's page-gather case: codes and both
     # scale pools fetched a page at a time equal the flat-row fetch (the
     # three old lines, kept below as the oracle) to the bit — ragged
     # lengths, an all-trash slot, a shared page named twice
     from edgellm_tpu.models.flash_attention import decode_attention
 
-    monkeypatch.setenv("EDGELLM_ATTN", "xla")
     rng = np.random.default_rng(kv + hd + ps)
     pn, pps, h = 11, 4, 2 * kv
     pt = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 0, 0, 0],
@@ -224,10 +223,11 @@ def test_quant_page_gather_equals_flat_row_gather_bitwise(tier, kv, hd, ps,
         jnp.asarray(rng.standard_normal(rows), jnp.float32), tier)
     hdc = kq.shape[-1]
     q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.bfloat16)
-    got = paged_decode_attention_quant(
-        q, kq.reshape(pn, ps, kv, hdc), vq.reshape(pn, ps, kv, hdc),
-        ks.reshape(pn, ps, kv), vs.reshape(pn, ps, kv), pt, lens,
-        kv_codec=tier)
+    got = paged_decode_attention(
+        q, QuantPagePool(kq.reshape(pn, ps, kv, hdc),
+                         vq.reshape(pn, ps, kv, hdc),
+                         ks.reshape(pn, ps, kv), vs.reshape(pn, ps, kv)),
+        pt, lens)
     idx = (pt[:, :, None] * ps
            + jnp.arange(ps)[None, None, :]).reshape(b, span)
     kg = dequantize_kv_rows(kq[idx], ks[idx], tier, q.dtype)
@@ -239,6 +239,157 @@ def test_quant_page_gather_equals_flat_row_gather_bitwise(tier, kv, hd, ps,
 # ---------------------------------------------------------------------------
 # quantized pool surgery: adopt / gather / COW / defrag / state_dict
 # ---------------------------------------------------------------------------
+
+
+ALL_TIERS = ("fp",) + TIERS
+
+
+def _zero_pool(axes, pn, ps, kv, hd, tier):
+    """An all-zero pool with leading ``axes`` ((L,) on a chip, (n_stages,
+    stage_size) staged) at ``tier``."""
+    codec = resolve_kv_codec(tier)
+    rows = tuple(axes) + (pn, ps, kv)
+    if not codec.quantized:   # a buffer each: the surgery donates them
+        return PagePool(*(jnp.zeros(rows + (hd,), jnp.float32)
+                          for _ in "kv"))
+    codes = rows + (codec.code_lanes(hd),)
+    return QuantPagePool(jnp.zeros(codes, codec.code_dtype),
+                         jnp.zeros(codes, codec.code_dtype),
+                         jnp.zeros(rows, jnp.float32),
+                         jnp.zeros(rows, jnp.float32))
+
+
+def _host(pool):
+    return [np.asarray(a) for a in pool]
+
+
+@pytest.mark.parametrize("tier", ALL_TIERS)
+@pytest.mark.parametrize("lead", [1, 2], ids=["one-chip", "staged"])
+def test_pool_surgery_is_one_body_at_every_rank_and_tier(lead, tier):
+    # the surgery of models/paged_kv.py over a chip's (L, ...) pool and over
+    # the split runtime's (n_stages, stage_size, ...): adopt -> gather
+    # returns the rows, packed gather -> packed adopt is a byte move, copy
+    # and permute move every leaf (codes AND scales) together, and a staged
+    # pool's stage s holds what the one-chip code makes of stage s's inputs
+    from edgellm_tpu.models import paged_kv as pk
+    from edgellm_tpu.parallel import split as split_mod
+
+    axes = (3,) if lead == 1 else (2, 3)
+    pn, ps, kv, hd, n = 7, 4, 2, 8, 7
+    rng = np.random.default_rng(lead * 10 + len(tier))
+    k = jnp.asarray(rng.standard_normal(axes + (n, kv, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(axes + (n, kv, hd)), jnp.float32)
+    dest = jnp.asarray(np.r_[2 * ps:3 * ps, 3 * ps:3 * ps + 3], jnp.int32)
+    adopt = pk._adopt_impl if lead == 1 else split_mod._adopt_paged_impl
+
+    def run(k_, v_, lead_, adopt_):
+        """Every surgery step once; host copies of every result."""
+        out = {}
+        pool = adopt_(_zero_pool(k_.shape[:lead_], pn, ps, kv, hd, tier),
+                      k_, v_, dest)
+        assert type(pool) is (PagePool if tier == "fp" else QuantPagePool)
+        out["adopted"] = _host(pool)
+        out["gathered"] = _host(pk._gather_impl(pool, dest, lead=lead_))
+        out["packed"] = _host(pk._gather_packed_impl(pool, dest, lead=lead_))
+        # COW fork of pages 2, 3 into 4, 5 (the pool is donated: host copies)
+        pool = pk._copy_pages_impl(pool, jnp.asarray([2, 3]),
+                                   jnp.asarray([4, 5]), lead=lead_)
+        out["copied"] = _host(pool)
+        src = jnp.asarray([0, 4, 5, 1, 2, 3, 6], jnp.int32)
+        out["permuted"] = _host(pk._permute_impl(pool, src, lead=lead_))
+        return out, np.asarray(src)
+
+    got, src = run(k, v, lead, adopt)
+    page = (slice(None),) * lead
+    # adopt -> gather returns the rows (to the tier's quantization error)
+    gk, gv = got["gathered"]
+    if tier == "fp":
+        np.testing.assert_array_equal(gk, np.asarray(k))
+        np.testing.assert_array_equal(gv, np.asarray(v))
+    else:
+        codes, scales = quantize_kv_rows(k, tier)
+        np.testing.assert_allclose(
+            gk, np.asarray(dequantize_kv_rows(codes, scales, tier)),
+            rtol=1e-6, atol=1e-7)
+        step = np.abs(np.asarray(k)).max(-1, keepdims=True) / (
+            7.0 if tier == "int4_per_channel" else 127.0)
+        assert (np.abs(gk - np.asarray(k)) <= step / 2 + 1e-6).all()
+        # packed gather -> packed adopt elsewhere -> packed gather: the bytes
+        dest2 = jnp.asarray(np.r_[5 * ps:5 * ps + n], jnp.int32)
+        fresh = _zero_pool(axes, pn, ps, kv, hd, tier)
+        moved = pk._adopt_packed_impl(
+            fresh, *(jnp.asarray(a) for a in got["packed"]), dest2, lead=lead)
+        back = pk._gather_packed_impl(moved, dest2, lead=lead)
+        for a, b in zip(back, got["packed"]):
+            assert np.asarray(a).dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), b)
+    # copy: every leaf's pages 4, 5 are its pages 2, 3; nothing else moved
+    for before, after in zip(got["adopted"], got["copied"]):
+        np.testing.assert_array_equal(after[page + ([4, 5],)],
+                                      before[page + ([2, 3],)])
+        keep = [0, 1, 2, 3, 6]
+        np.testing.assert_array_equal(after[page + (keep,)],
+                                      before[page + (keep,)])
+    # permute: new[p] = old[src[p]] for every leaf, scales with their codes
+    for before, after in zip(got["copied"], got["permuted"]):
+        np.testing.assert_array_equal(after, before[page + (src,)])
+    assert got["adopted"][0][page + (2,)].any()      # something was written
+    if lead == 2:
+        # stage by stage, the staged pool is the one-chip code's result
+        for s in range(axes[0]):
+            one, _ = run(k[s], v[s], 1, pk._adopt_impl)
+            for name, leaves in one.items():
+                for a, b in zip(leaves, got[name]):
+                    np.testing.assert_array_equal(a, b[s], err_msg=name)
+
+
+@pytest.mark.parametrize("tier", ALL_TIERS)
+def test_one_chip_and_staged_step_share_one_write_and_one_read(
+        tier, params, monkeypatch):
+    # the layout of a K/V row is known in two functions of models/paged_kv.py:
+    # the one-chip step and the split runtime's step both trace through
+    # them, each with one layer's pool of the tier's type
+    from edgellm_tpu.models import paged_kv as pk
+    from edgellm_tpu.parallel import SplitConfig, SplitRuntime, \
+        make_stage_mesh
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs >= 2 devices")
+    seen = []
+    write, read = pk.write_rows, pk.read_span
+
+    def recording_write(pool, *a):
+        seen.append(("write", type(pool), pool.k.shape))
+        return write(pool, *a)
+
+    def recording_read(pool, *a):
+        seen.append(("read", type(pool), pool.k.shape))
+        return read(pool, *a)
+
+    monkeypatch.setattr(pk, "write_rows", recording_write)
+    monkeypatch.setattr(pk, "read_span", recording_read)
+    npg, ps, slots, pps = BCFG.num_pages, BCFG.page_size, BCFG.max_slots, 4
+    table = jnp.zeros((slots, pps), jnp.int32)
+    ints = jnp.zeros((slots,), jnp.int32)
+    codec = resolve_kv_codec(tier)
+    layer = (npg, ps, CFG.num_kv_heads, codec.code_lanes(CFG.head_dim))
+    kind = QuantPagePool if codec.quantized else PagePool
+    want = [("write", kind, layer), ("read", kind, layer)]
+
+    pool = _zero_pool((CFG.num_layers,), *layer[:3], CFG.head_dim, tier)
+    jax.make_jaxpr(lambda *a: pk.paged_decode_step(CFG, *a))(
+        params, pool, table, ints, ints)
+    assert seen == want        # the layer scan traces its body once
+    del seen[:]
+
+    rt = SplitRuntime(CFG, SplitConfig(cuts=(2,), hop_codecs=("fp32",)),
+                      make_stage_mesh(2))
+    staged = rt.init_paged_pool(npg, ps, kv_codec=tier)
+    assert type(staged) is kind and pk.pool_tier(staged) == tier
+    assert staged.k.shape == (2, rt.stage_size) + layer
+    jax.make_jaxpr(rt._paged_decode_fns(npg, ps, kv_codec=tier))(
+        rt.place_params(params), staged, table, ints, ints)
+    assert seen == want        # one stage body, scanned by every stage
 
 
 def test_packed_gather_adopt_roundtrip_across_geometry():
