@@ -413,32 +413,26 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
                 eq_rng.standard_normal((cfg.num_layers, NPG * PGS,
                                         cfg.num_kv_heads, cfg.head_dim),
                                        np.float32)), tier)
-            shp = tpool.k.shape
-            kq = kq.reshape(shp)
-            vq = vq.reshape(shp)
-            ks = ks.reshape(shp[:-1])
-            vs = vs.reshape(shp[:-1])
+            # the stored form: (L, P, ps, KV*lanes) codes, (L, P, ps, KV)
+            # scales; layer 0 is what the attend is asked for
+            qpool_t = paged_kv.QuantPagePool(
+                kq.reshape(tpool.k.shape), vq.reshape(tpool.k.shape),
+                ks.reshape(tpool.k_scale.shape),
+                vs.reshape(tpool.k_scale.shape))
             q = jnp.asarray(eq_rng.standard_normal(
                 (MS, 1, cfg.num_heads, cfg.head_dim), np.float32))
             etab = jnp.asarray(
                 eq_rng.permutation(np.arange(1, NPG))[:MS * PPS]
                 .reshape(MS, PPS).astype(np.int32))
             elens = jnp.asarray([PGS + 3, PGS - 2], jnp.int32)
-            got = paged_kv.paged_decode_attention(
-                q, paged_kv.QuantPagePool(kq[0], vq[0], ks[0], vs[0]), etab,
-                elens)
+            got = paged_kv.paged_decode_attention(q, qpool_t, 0, etab, elens)
             # reference: dequantize the WHOLE pool, then the plain fp path
-            kf = fa.dequantize_kv_rows(
-                kq[0].reshape(NPG * PGS, cfg.num_kv_heads, -1),
-                ks[0].reshape(NPG * PGS, cfg.num_kv_heads), tier)
-            vf = fa.dequantize_kv_rows(
-                vq[0].reshape(NPG * PGS, cfg.num_kv_heads, -1),
-                vs[0].reshape(NPG * PGS, cfg.num_kv_heads), tier)
+            fshape = tpool.k.shape[:-1] + (cfg.num_kv_heads * cfg.head_dim,)
             ref = paged_kv.paged_decode_attention(
                 q, paged_kv.PagePool(
-                    kf.reshape(NPG, PGS, cfg.num_kv_heads, cfg.head_dim),
-                    vf.reshape(NPG, PGS, cfg.num_kv_heads, cfg.head_dim)),
-                etab, elens)
+                    fa.dequantize_kv_rows(kq, ks, tier).reshape(fshape),
+                    fa.dequantize_kv_rows(vq, vs, tier).reshape(fshape)),
+                0, etab, elens)
             if not np.array_equal(np.asarray(got), np.asarray(ref)):
                 d = float(np.abs(np.asarray(got) - np.asarray(ref)).max())
                 findings.append(Finding(

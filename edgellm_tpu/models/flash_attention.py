@@ -532,11 +532,10 @@ def dequantize_kv_rows(codes, scales, kv_codec: str, dtype=jnp.float32):
 def decode_attention(q, k_cache, v_cache, length):
     """Single-position attention against a cache: q (B, 1, H, hd) vs
     k/v_cache (B, capacity, KV, hd) of which the first ``length`` positions
-    are valid (``length`` is traced — one executable per capacity). ``length``
-    may be a scalar (one fill level for the whole batch — the contiguous
-    decode path) or a (B,) vector (per-row fill levels — the paged attend,
-    ``models.paged_kv.paged_decode_attention``); the scalar graph is
-    unchanged by the vector extension.
+    are valid (``length`` is a traced scalar — one executable per capacity,
+    one fill level for the whole batch: the contiguous decode path; the
+    paged pool's ragged twin, over rows as the pool stores them, is
+    ``models.paged_kv.attend_rows``).
     Returns (B, 1, H, hd) in q's dtype; softmax in fp32.
 
     GQA broadcasting happens here, not in the cache: the per-group einsum
@@ -560,15 +559,9 @@ def decode_attention(q, k_cache, v_cache, length):
     scores = jnp.einsum("bgrd,bcgd->bgrc", qg, k_cache,
                         preferred_element_type=jnp.float32)
     scores = scores * (1.0 / np.sqrt(hd))
-    if jnp.ndim(length):
-        # ragged: row i masks at its own lengths[i]
-        valid = jnp.arange(k_cache.shape[1])[None, :] < length[:, None]
-        scores = jnp.where(valid[:, None, None, :], scores,
-                           jnp.finfo(jnp.float32).min)
-    else:
-        valid = jnp.arange(k_cache.shape[1]) < length  # (capacity,)
-        scores = jnp.where(valid[None, None, None, :], scores,
-                           jnp.finfo(jnp.float32).min)
+    valid = jnp.arange(k_cache.shape[1]) < length  # (capacity,)
+    scores = jnp.where(valid[None, None, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bgrc,bcgd->bgrd", probs.astype(q.dtype), v_cache,
                      preferred_element_type=jnp.float32).astype(q.dtype)
@@ -606,31 +599,3 @@ def verify_attention(q, k_cache, v_cache, length):
     out = jnp.einsum("bqgrc,bcgd->bqgrd", probs.astype(q.dtype), v_cache,
                      preferred_element_type=jnp.float32).astype(q.dtype)
     return out.reshape(b, kq, h, hd)
-
-
-def _gather_pages(pages, page_table):
-    """Each slot's pages out of one layer's pool, in table order: pages
-    (num_pages, page_size, KV, ...) and page_table (B, pages_per_slot) ->
-    (B, span, KV, ...); trash-page rows of an unallocated tail come along and
-    stay under the caller's length mask.
-
-    One gather slice is one whole PAGE, taken from the pool viewed as
-    (num_pages, page_size*KV, ...). What that costs on a v5e (PERF.md §6
-    "PR 27", one layer's K or V at 192 slots x 128 pages of 16 rows, KV=2):
-    gathered a ROW at a time (393,216 slices of 256-512 B) 4.4-4.65 ms, 11.9
-    ns a row whatever it held; a page at a time 0.85 ms at hd=64, i.e. by
-    bytes (the row-tiled pool pads 64 lanes to 128, so 200 MB read and 200
-    written at 470 GB/s). The view matters as much as the slice:
-    ``pages[page_table]`` on the 4-d pool makes the TPU compiler move the
-    page axis under KV for the attend and pay two relayout copies of 0.6 ms
-    around every gather; the pool viewed as ``(num_pages, page_size*KV*hd)``
-    costs a de-padding and a re-padding reshape instead. Merging only
-    ``page_size`` and ``KV`` is a bitcast of the layout the K/V write leaves
-    (16 tiles of (2, 128) are two tiles of (8, 128)(2, 1)), so the gather is
-    all there is. Same values in the same order as the row gather
-    (tests/test_batching.py keeps it as the oracle, and guards the traced
-    step against its return)."""
-    pn, ps, kv, *tail = pages.shape
-    b, pps = page_table.shape
-    return pages.reshape(pn, ps * kv, *tail)[page_table].reshape(
-        b, pps * ps, kv, *tail)

@@ -263,7 +263,8 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
                              lengths, token_ids):
     """The ragged step for a hybrid stack: one position for EVERY slot.
 
-    pool_k/pool_v: (L_attn, num_pages, page_size, KV, hd); conv_all / ssm_all:
+    pool_k/pool_v: (L_attn, num_pages, page_size, KV * hd), addressed by the
+    static attention-layer number (paged_kv's flat index); conv_all / ssm_all:
     the per-slot state store, (L_mamba, max_slots, ...) float32; expert_tokens
     (L, Eh) int32, the running count of assignments per held expert, which
     gains this step's over the slots with ``lengths > 0`` (a free slot runs
@@ -282,11 +283,10 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
                                                j)
         else:
             lp = _row(params["attn"], j)
-            out, (kp, vp) = _attention_decode_paged(
+            out, (pool_k, pool_v) = _attention_decode_paged(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None], None, None,
-                PagePool(pool_k[j], pool_v[j]), page_table, lengths)
+                PagePool(pool_k, pool_v), j, page_table, lengths)
             out = out[:, 0]
-            pool_k, pool_v = pool_k.at[j].set(kp), pool_v.at[j].set(vp)
         h = h + cfg.residual_multiplier * out
         h, c = _ffn(cfg, params["moe"][layer], h, active)
         counts.append(c)

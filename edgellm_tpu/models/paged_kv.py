@@ -7,7 +7,7 @@ recompiles per shape — ROADMAP item 1's gap between "a compiled generate()"
 and "a service". This module replaces the monolith with the paged layout of
 *Ragged Paged Attention* (PAPERS.md): one shared pool of fixed-size pages,
 
-    k, v: (L, num_pages, page_size, KV, hd)
+    k, v: (L, num_pages, page_size, KV * hd)
 
 and a small host-side allocator that maps each stream (a *slot*) to an
 ordered list of pages. Logical position ``p`` of slot ``i`` lives at
@@ -36,6 +36,28 @@ Conventions that keep the paged step bit-identical to the contiguous one:
 Donation: the jitted step and adopt/defrag helpers donate the pool buffers,
 so the (L, num_pages, page_size) arrays update in place — the
 ``paged.decode_step`` graph contract asserts the aliasing survives lowering.
+
+Stored shape and addressing — two halves of ONE mechanism (PERF.md §6
+"PR 29"; tests/test_chip_compile.py holds the chip's compiler to it):
+
+- a token's K (or V) for ALL its KV heads is one minor vector of
+  ``KV * lanes`` (lanes = hd, or the packed code width of a quantized tier),
+  so a page of 16 bf16 rows is whole (8,128)(2,1) tiles, nothing is padded
+  and the chip keeps the array row-major. With a ``(KV, hd)`` tail of
+  (2, 64) the runtime stored the pool PAGES-minor, where nothing can scatter
+  a row or gather a page: every layer of the step relaid its slice out and
+  back, and the adopt relaid the whole pool (113 of 181 ms of device time).
+- every device-side write and read goes through one flat index computed
+  from (layer, page, row) over the pool viewed ``(L*P*ps, KV*lanes)`` (rows)
+  or ``(L*P, ps, KV*lanes)`` (pages); nothing slices a leading layer axis,
+  and the step's layer scan CARRIES the pool instead of stacking it.
+
+Neither half helps alone. The lane-dense row with ``.at[:, dest]`` still
+costs two whole-pool copies a leaf (the compiler moves L under the row
+axis), and on the staged pool, whose (2, 128) tail was already compact and
+whose adopt was in place, it would ADD two stage-pool copies a leaf; the flat
+index cannot help a pool that lives pages-minor. Callers never see the
+merge: adopts, gathers, checkpoints and migration hand over (S, KV, hd) rows.
 """
 from __future__ import annotations
 
@@ -49,8 +71,7 @@ import jax.numpy as jnp
 
 from ..lint import graph_contract
 from .configs import ModelConfig
-from .flash_attention import (_gather_pages, decode_attention,
-                              dequantize_kv_rows, quantize_kv_rows)
+from .flash_attention import dequantize_kv_rows, quantize_kv_rows
 from .transformer import (_cast_params, _layernorm, _rmsnorm, _rotate_half,
                           embed, mlp, precompute_rope, unembed)
 
@@ -302,21 +323,26 @@ class KVTierMismatchError(ValueError):
 class PagePool(NamedTuple):
     """Device-side page pool: post-rotary K/V at ``num_kv_heads`` width.
 
-    k, v: (..., num_pages, page_size, KV, hd). The leading axes are (L,) on
-    one chip, (n_stages, stage_size) in the split runtime and none for the
-    one layer a scan body or a stage holds. Page 0 is the reserved trash
-    page (see module docstring)."""
+    k, v: (..., num_pages, page_size, KV * hd): a token's row for all its KV
+    heads is ONE minor vector (head j in lanes [j*hd, (j+1)*hd)), so a page
+    is whole lane tiles and the chip stores the array row-major (module
+    docstring: with a (KV, hd) tail it lived pages-minor and was copied
+    around every write and read). The leading axes are (L,) on one chip and
+    (n_stages, stage_size) in the split runtime; the LAST of them is the
+    layer axis that every flat index folds in (:func:`write_rows`,
+    :func:`read_span`, :func:`adopt_at`), never slices. Page 0 is the
+    reserved trash page (see module docstring)."""
 
     k: jnp.ndarray
     v: jnp.ndarray
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[-4]
+        return self.k.shape[-3]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
 
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -328,8 +354,8 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                          f"got {num_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (cfg.kv_layers, num_pages, page_size, cfg.num_kv_heads,
-             cfg.head_dim)
+    shape = (cfg.kv_layers, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
     return PagePool(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -393,11 +419,14 @@ def resolve_kv_codec(name: str) -> KVPageCodec:
 class QuantPagePool(NamedTuple):
     """Quantized device pool: packed int codes + per-row fp32 scales.
 
-    k, v: (..., num_pages, page_size, KV, hdc) codes — hdc = hd (int8) or
-    hd/2 (packed int4, lane i paired with lane i + hd/2, the wire codecs'
-    contiguous-half pairing). k_scale, v_scale: (..., num_pages, page_size,
-    KV) fp32 absmax scales. The leading axes, the page axis and the token
-    axis match PagePool, so the page-table/flat-index math is tier-agnostic."""
+    k, v: (..., num_pages, page_size, KV * hdc) codes — hdc = hd (int8) or
+    hd/2 (packed int4, lane i paired with lane i + hd/2 WITHIN a head, the
+    wire codecs' contiguous-half pairing); the KV heads' codes merge into
+    one minor vector exactly as PagePool's rows do, so there is one row
+    layout. k_scale, v_scale: (..., num_pages, page_size, KV) fp32 absmax
+    scales, which also tell a quantized pool its KV. The leading axes, the
+    page axis and the token axis match PagePool, so the page-table/flat-index
+    math is tier-agnostic."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -406,11 +435,11 @@ class QuantPagePool(NamedTuple):
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[-4]
+        return self.k.shape[-3]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
 
 def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -427,8 +456,8 @@ def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
     hdc = codec.code_lanes(cfg.head_dim)
-    cshape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, hdc)
-    sshape = cshape[:-1]
+    sshape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads)
+    cshape = sshape[:-1] + (cfg.num_kv_heads * hdc,)
     return QuantPagePool(jnp.zeros(cshape, codec.code_dtype),
                          jnp.zeros(cshape, codec.code_dtype),
                          jnp.zeros(sshape, jnp.float32),
@@ -471,97 +500,210 @@ def num_pages_for_bytes(cfg: ModelConfig, pool_bytes: int, page_size: int,
 # COW fork, permute them for defrag. Each is written once over the pool's
 # leaves; ``lead`` counts the axes before the page axis (1 for a chip's
 # (L, ...) pool, 2 for the split runtime's (n_stages, stage_size, ...)), so
-# the staged pool takes the same code. Page moves are BYTE moves — codes and
-# scales ride the same copy or permutation untouched, so a forked page is
-# byte-identical to its original and defrag never requantizes. Only adopt
-# (fp rows in) and gather (fp rows out) touch the codec; the *_packed pair
-# moves raw codes + scales for the bit-exact checkpoint/eviction path.
-# Whatever writes donates the pool, so surgery is in place.
+# the staged pool takes the same code. The LAST leading axis is the layer
+# axis and is never sliced: it folds into the row (or page) axis, and the
+# index of a row of layer l is ``l * P * ps + row`` (:func:`_every_layer`).
+# The axes before it (the staged pool's sharded stage axis) stay sliced.
+# Page moves are BYTE moves — codes and scales ride the same copy or
+# permutation untouched, so a forked page is byte-identical to its original
+# and defrag never requantizes. Only adopt (fp rows in) and gather (fp rows
+# out) touch the codec; the *_packed pair moves raw codes + scales for the
+# bit-exact checkpoint/eviction path. Whatever writes donates the pool, so
+# surgery is in place.
 # ---------------------------------------------------------------------------
 
 
-def _flat(arr, lead: int):
-    """A pool leaf with its page and row axes merged into the token axis
-    that flat indices (:meth:`PagedKVCache._flat_indices`) name."""
+def _rows(arr, lead: int):
+    """A pool leaf viewed as token rows, its layer, page and row axes merged:
+    (..., L*P*ps, width). A bitcast where a page is whole tiles."""
     sh = arr.shape
-    return arr.reshape(*sh[:lead], sh[lead] * sh[lead + 1], *sh[lead + 2:])
+    return arr.reshape(*sh[:lead - 1], -1, sh[-1])
+
+
+def _pages(arr, lead: int):
+    """A pool leaf viewed as pages, its layer and page axes merged:
+    (..., L*P, ps, width)."""
+    sh = arr.shape
+    return arr.reshape(*sh[:lead - 1], -1, *sh[lead + 1:])
+
+
+def _every_layer(arr, lead: int, idx, pages: bool = False):
+    """Per-layer indices ``idx`` (n,) — flat token rows
+    (:meth:`PagedKVCache._flat_indices`; a layer holds P*ps of them) or,
+    with ``pages``, page ids (a layer holds P) — as indices into the folded
+    axis of :func:`_rows` / :func:`_pages` for EVERY layer: (L*n,),
+    layer-major."""
+    stride = arr.shape[lead] * (1 if pages else arr.shape[lead + 1])
+    layers = jnp.arange(arr.shape[lead - 1], dtype=jnp.int32) * stride
+    return (layers[:, None] + idx[None, :].astype(jnp.int32)).reshape(-1)
 
 
 def _at(lead: int, idx):
-    return (slice(None),) * lead + (idx,)
+    return (slice(None),) * (lead - 1) + (idx,)
+
+
+def _merge_heads(x):
+    """(..., KV, lanes) rows as a caller hands them over -> the stored
+    (..., KV*lanes) minor vector."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _split_heads(x, kv: int):
+    """The stored (..., KV*lanes) minor vector -> (..., KV, lanes)."""
+    return x.reshape(*x.shape[:-1], kv, -1)
 
 
 @jax.named_scope("paged_kv.adopt")
-def _set_rows(pool, rows, dest, lead: int):
-    """Scatter ``rows`` — one (..., S, ...) array a pool leaf, already in the
-    leaf's stored form — into the flat token positions ``dest`` (S,)."""
-    flat = [_flat(a, lead).at[_at(lead, dest)].set(r.astype(a.dtype))
-            for a, r in zip(pool, rows)]
-    return type(pool)(*(f.reshape(a.shape) for f, a in zip(flat, pool)))
+def _set_rows(pool, rows, dest, lead: int, head: Optional[int] = None):
+    """Scatter ``rows`` — one (..., L, S, width) array a pool leaf, already
+    in the leaf's stored form — into the flat token positions ``dest`` (S,)
+    of every layer, over the leaf viewed (..., L*P*ps, width), which the chip
+    runs in place. (``.at[:, dest]`` over (L, P*ps, width) makes its compiler
+    move L under the row axis and copy the whole pool out and back, twice a
+    leaf.)
+
+    ``head`` (static) says where ``dest`` meets its first page boundary:
+    ``dest[head:]`` starts a page and runs on in position order, as
+    :meth:`PagedKVCache._flat_indices` lays a slot out. The whole pages in it
+    then go a PAGE a scatter slice at ``l*P + page`` — whole tiles, stored by
+    bytes — and only the rows before ``head`` and after the last whole page
+    go a row a slice: a lane-dense bf16 row shares its packed sublane with
+    its neighbour, so a row scatter costs 76-86 ns a row on a v5e where a
+    (2, 128) row that was a tile of its own cost 12 (PERF.md §6 "PR 29": a
+    1024-row adopt 3.8 ms by rows, 0.3 by pages). ``None``: every row by
+    itself, whatever ``dest`` holds."""
+    n = dest.shape[0]
+    ps = pool[0].shape[lead + 1]
+    head = n if head is None else min(head, n)
+    whole = (n - head) // ps * ps               # rows that fill whole pages
+
+    def apart(x, axis):                          # rows scattered one by one
+        return jnp.concatenate(
+            [jax.lax.slice_in_dim(x, 0, head, axis=axis),
+             jax.lax.slice_in_dim(x, head + whole, n, axis=axis)], axis=axis)
+
+    out = []
+    for a, r in zip(pool, rows):
+        r = r.astype(a.dtype)
+        if whole:
+            at = _every_layer(a, lead, dest[head:head + whole:ps] // ps,
+                              pages=True)
+            body = r[..., head:head + whole, :].reshape(
+                *a.shape[:lead - 1], -1, ps, a.shape[-1])
+            a = _pages(a, lead).at[_at(lead, at)].set(body).reshape(a.shape)
+        if whole < n:
+            at = _every_layer(a, lead, apart(dest, 0))
+            a = _rows(a, lead).at[_at(lead, at)].set(
+                apart(r, r.ndim - 2).reshape(*a.shape[:lead - 1], -1,
+                                            a.shape[-1])).reshape(a.shape)
+        out.append(a)
+    return type(pool)(*out)
 
 
-def adopt_at(pool, k_seq, v_seq, dest, lead: int):
-    """Put contiguous (..., S, KV, hd) fp K/V rows at the flat token indices
-    ``dest`` (S,): stored as they are on the fp tier, quantized on append on
-    the others ('writes quantize on append', the at-rest contract). S is
-    static per call (one executable per adopted length)."""
+def page_head(dest, page_size: int) -> int:
+    """The ``head`` of :func:`_set_rows` for a HOST array of flat token
+    indices in position order: how many rows lie before the first page
+    boundary (0 for an adopt from position 0)."""
+    return int(-int(dest[0]) % page_size) if len(dest) else 0
+
+
+def adopt_at(pool, k_seq, v_seq, dest, lead: int, head: Optional[int] = None):
+    """Put contiguous (..., L, S, KV, hd) fp K/V rows at the flat token
+    indices ``dest`` (S,) of every layer: stored as they are on the fp tier
+    (the KV heads merged into the row's one minor vector, a free reshape),
+    quantized on append on the others ('writes quantize on append', the
+    at-rest contract). Row ``dest[i]`` of layer ``l`` is row ``l*P*ps +
+    dest[i]`` of the leaf viewed (..., L*P*ps, KV*lanes); with two leading
+    axes the sharded stage axis stays sliced and only ``stage_size`` folds
+    into the index. S and ``head`` (:func:`_set_rows`) are static per call
+    (one executable per adopted length)."""
     tier = pool_tier(pool)
     if tier == "fp":
-        return _set_rows(pool, (k_seq, v_seq), dest, lead)
+        return _set_rows(pool, (_merge_heads(k_seq), _merge_heads(v_seq)),
+                         dest, lead, head)
     qk, sk = quantize_kv_rows(k_seq, tier)
     qv, sv = quantize_kv_rows(v_seq, tier)
-    return _set_rows(pool, (qk, qv, sk, sv), dest, lead)
+    return _set_rows(pool, (_merge_heads(qk), _merge_heads(qv), sk, sv),
+                     dest, lead, head)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _adopt_impl(pool, k_seq, v_seq, dest):
-    return adopt_at(pool, k_seq, v_seq, dest, 1)
+@functools.partial(jax.jit, static_argnames=("head",), donate_argnums=(0,))
+def _adopt_impl(pool, k_seq, v_seq, dest, head: Optional[int] = None):
+    return adopt_at(pool, k_seq, v_seq, dest, 1, head)
 
 
-@functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
+@functools.partial(jax.jit, static_argnames=("lead", "head"),
+                   donate_argnums=(0,))
 def _adopt_packed_impl(pool, k_codes, v_codes, k_scale, v_scale, dest,
-                       lead: int = 1):
-    """Scatter already-packed rows (a checkpoint's payload) — no requantize,
-    so restore is bit-exact by construction."""
-    return _set_rows(pool, (k_codes, v_codes, k_scale, v_scale), dest, lead)
+                       lead: int = 1, head: Optional[int] = None):
+    """Scatter already-packed (..., S, KV, hdc) code rows and their scales
+    (a checkpoint's payload) — no requantize, so restore is bit-exact by
+    construction."""
+    return _set_rows(pool, (_merge_heads(k_codes), _merge_heads(v_codes),
+                            k_scale, v_scale), dest, lead, head)
+
+
+def _get_rows(pool, idx, lead: int):
+    """The rows at flat token indices ``idx`` (span,) of every layer as
+    stored, a (..., L, span, width) array a leaf: one gather a leaf at
+    ``l*P*ps + idx``."""
+    out = []
+    for a in pool:
+        at = _every_layer(a, lead, idx)
+        out.append(_rows(a, lead)[_at(lead, at)].reshape(
+            *a.shape[:lead], idx.shape[0], a.shape[-1]))
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("lead",))
 def _gather_packed_impl(pool, idx, lead: int = 1):
-    """The rows at flat token indices ``idx`` (span,) as stored, a leaf each:
-    the checkpoint/eviction form of a quantized pool — geometry-independent
-    AND codec-lossless. NOT donated: the pool stays live."""
-    return tuple(_flat(a, lead)[_at(lead, idx)] for a in pool)
+    """A quantized pool's rows at ``idx`` as stored — codes (..., L, span,
+    KV, hdc), scales (..., L, span, KV): the checkpoint/eviction form,
+    geometry-independent AND codec-lossless. NOT donated: the pool stays
+    live."""
+    kc, vc, ks, vs = _get_rows(pool, idx, lead)
+    kv = ks.shape[-1]
+    return _split_heads(kc, kv), _split_heads(vc, kv), ks, vs
 
 
-@functools.partial(jax.jit, static_argnames=("lead",))
-def _gather_impl(pool, idx, lead: int = 1):
-    """The rows at ``idx`` back as contiguous (..., span, KV, hd) K and V:
+@functools.partial(jax.jit, static_argnames=("lead", "kv"))
+def _gather_impl(pool, idx, lead: int = 1, *, kv: int):
+    """The rows at ``idx`` back as contiguous (..., L, span, KV, hd) K and V:
     byte-identical to what was adopted on the fp tier, DEQUANTIZED to fp32 on
     the others (the suffix-prefill compute path, which needs fp rows; lossy
-    by exactly the tier's quantization error)."""
-    rows = _gather_packed_impl(pool, idx, lead=lead)
+    by exactly the tier's quantization error). ``kv`` is what an fp pool's
+    merged row cannot say."""
+    k, v, *scales = _get_rows(pool, idx, lead)
+    k, v = _split_heads(k, kv), _split_heads(v, kv)
     tier = pool_tier(pool)
     if tier == "fp":
-        return rows
-    kc, vc, ks, vs = rows
-    return (dequantize_kv_rows(kc, ks, tier),
-            dequantize_kv_rows(vc, vs, tier))
+        return k, v
+    return (dequantize_kv_rows(k, scales[0], tier),
+            dequantize_kv_rows(v, scales[1], tier))
 
 
 @functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
 def _permute_impl(pool, src, lead: int = 1):
-    """new_pool[p] = old_pool[src[p]] — the defrag move, one gather a leaf."""
-    return type(pool)(*(a[_at(lead, src)] for a in pool))
+    """new_pool[l, p] = old_pool[l, src[p]] — the defrag move, one gather of
+    whole pages a leaf at ``l*P + src`` over the leaf viewed (..., L*P, ps,
+    width)."""
+    return type(pool)(*(
+        _pages(a, lead)[_at(lead, _every_layer(a, lead, src, pages=True))]
+        .reshape(a.shape) for a in pool))
 
 
 @functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
 def _copy_pages_impl(pool, src, dst, lead: int = 1):
-    """COW fork: duplicate whole pages ``src`` (n,) into pages ``dst`` (n,).
-    The forking slot then writes its private copy; every other holder keeps
-    reading the original bytes."""
-    return type(pool)(*(a.at[_at(lead, dst)].set(a[_at(lead, src)])
-                        for a in pool))
+    """COW fork: duplicate whole pages ``src`` (n,) into pages ``dst`` (n,)
+    of every layer. The forking slot then writes its private copy; every
+    other holder keeps reading the original bytes."""
+    out = []
+    for a in pool:
+        pages = _pages(a, lead)
+        at_src = _at(lead, _every_layer(a, lead, src, pages=True))
+        at_dst = _at(lead, _every_layer(a, lead, dst, pages=True))
+        out.append(pages.at[at_dst].set(pages[at_src]).reshape(a.shape))
+    return type(pool)(*out)
 
 
 # The per-slot state store of a hybrid stack (models/hybrid.py): row j of
@@ -1137,7 +1279,7 @@ class PagedKVCache:
         self.prepare_write(slot, length, start=0)
         dest = jnp.asarray(self._flat_indices(slot, length))
         self.pool = _adopt_impl(self.pool, jnp.asarray(k_seq),
-                                jnp.asarray(v_seq), dest)
+                                jnp.asarray(v_seq), dest, head=0)
         self.lengths[slot] = length
 
     def adopt_rows(self, slot: int, k_seq, v_seq,
@@ -1154,7 +1296,8 @@ class PagedKVCache:
         self.ensure_writable(slot, stop)
         dest = jnp.asarray(self._flat_indices(slot, stop)[start:])
         self.pool = _adopt_impl(self.pool, jnp.asarray(k_seq),
-                                jnp.asarray(v_seq), dest)
+                                jnp.asarray(v_seq), dest,
+                                head=-start % self.page_size)
         self.lengths[slot] = stop
 
     def adopt_packed(self, slot: int, k_codes, v_codes, k_scale, v_scale,
@@ -1175,7 +1318,7 @@ class PagedKVCache:
         dest = jnp.asarray(self._flat_indices(slot, length))
         self.pool = _adopt_packed_impl(
             self.pool, jnp.asarray(k_codes), jnp.asarray(v_codes),
-            jnp.asarray(k_scale), jnp.asarray(v_scale), dest)
+            jnp.asarray(k_scale), jnp.asarray(v_scale), dest, head=0)
         self.lengths[slot] = length
 
     @property
@@ -1217,7 +1360,7 @@ class PagedKVCache:
         self._require_pool("gather_slot")
         n = int(self.lengths[slot])
         idx = jnp.asarray(self._flat_indices(slot, max(n, 1)))
-        k, v = _gather_impl(self.pool, idx)
+        k, v = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
         return {"k": np.asarray(k)[:, :n], "v": np.asarray(v)[:, :n],
                 "length": np.asarray(n, np.int32)}
 
@@ -1252,7 +1395,7 @@ class PagedKVCache:
         self._require_pool("gather_slot_rows")
         self._check_row_range(slot, start, stop)
         idx = jnp.asarray(self._flat_indices(slot, stop)[start:])
-        k, v = _gather_impl(self.pool, idx)
+        k, v = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
         return {"k": np.asarray(k), "v": np.asarray(v)}
 
     def gather_slot_rows_packed(self, slot: int, start: int,
@@ -1335,15 +1478,17 @@ class PagedKVCache:
         (Per-slot checkpoints use :meth:`gather_slot` instead, which is
         geometry-independent.)"""
         self._require_pool("state_dict")
+        # the K/V (code) leaves in the form callers hand rows over in,
+        # (L, P, ps, KV, lanes) — what every earlier checkpoint holds; the
+        # stored row merges the last two axes, a free reshape either way
+        kv = self.cfg.num_kv_heads
+        k, v = (_split_heads(np.asarray(a), kv) for a in self.pool[:2])
         if self.kv_codec == "fp":
             # pre-quantization key set, unchanged: old checkpoints and fp
             # pools stay mutually loadable
-            state = {"k": np.asarray(self.pool.k),
-                     "v": np.asarray(self.pool.v)}
+            state = {"k": k, "v": v}
         else:
-            state = {"kv_codec": self.kv_codec,
-                     "k_codes": np.asarray(self.pool.k),
-                     "v_codes": np.asarray(self.pool.v),
+            state = {"kv_codec": self.kv_codec, "k_codes": k, "v_codes": v,
                      "k_scale": np.asarray(self.pool.k_scale),
                      "v_scale": np.asarray(self.pool.v_scale)}
         state.update({"page_table": self.page_table.copy(),
@@ -1374,22 +1519,17 @@ class PagedKVCache:
             # cache at the checkpoint's tier instead.
             raise KVTierMismatchError(offered=ck, pool=self.kv_codec,
                                       where="load_state_dict")
-        if self.kv_codec == "fp":
-            if state["k"].shape != self.pool.k.shape:
-                raise ValueError(
-                    f"pool shape mismatch: checkpoint {state['k'].shape} vs "
-                    f"cache {self.pool.k.shape}")
-            self.pool = PagePool(jnp.asarray(state["k"]),
-                                 jnp.asarray(state["v"]))
-        else:
-            if state["k_codes"].shape != self.pool.k.shape:
-                raise ValueError(
-                    f"pool shape mismatch: checkpoint "
-                    f"{state['k_codes'].shape} vs {self.pool.k.shape}")
-            self.pool = QuantPagePool(jnp.asarray(state["k_codes"]),
-                                      jnp.asarray(state["v_codes"]),
-                                      jnp.asarray(state["k_scale"]),
-                                      jnp.asarray(state["v_scale"]))
+        names = (("k", "v") if self.kv_codec == "fp"
+                 else ("k_codes", "v_codes", "k_scale", "v_scale"))
+        kv = self.cfg.num_kv_heads
+        want = self.pool.k.shape[:-1] + (kv, self.pool.k.shape[-1] // kv)
+        if state[names[0]].shape != want:
+            raise ValueError(
+                f"pool shape mismatch: checkpoint {state[names[0]].shape} "
+                f"vs cache {want}")
+        self.pool = type(self.pool)(*(
+            jnp.asarray(state[n]).reshape(a.shape)
+            for n, a in zip(names, self.pool)))
         if self.state is not None:
             if state["state_ssm"].shape != self.state.ssm.shape:
                 raise ValueError(
@@ -1550,15 +1690,23 @@ def _apply_rotary_rows(x: jnp.ndarray, cos_b: jnp.ndarray,
 
 
 @jax.named_scope("paged_kv.write")
-def write_rows(pool, page_table, lengths, k, v):
-    """One layer's pool with a step's new K/V rows in it: k, v (B, 1, KV, hd)
-    post-rotary, slot i's row at position ``lengths[i]`` of its page list.
-    The fp tier stores the cast row; a quantized tier quantizes ON APPEND and
-    scatters codes + the row's own scales — neighbouring rows are untouched,
-    which is why scales are per row and not per page. The only code that
-    knows where in a pool a decode step's row goes."""
+def write_rows(pool, layer, page_table, lengths, k, v):
+    """The pool with a step's new K/V rows in layer ``layer``: pool leaves
+    (L, P, ps, KV*lanes) WITH their layer axis, ``layer`` a traced or static
+    index, k, v (B, 1, KV, hd) post-rotary, slot i's row at position
+    ``lengths[i]`` of its page list. The fp tier stores the cast row (its KV
+    heads merged into the one minor vector); a quantized tier quantizes ON
+    APPEND and scatters codes + the row's own scales — neighbouring rows are
+    untouched, which is why scales are per row and not per page.
+
+    One scatter a leaf at the flat index ``layer*P*ps + page*ps + row`` over
+    the leaf viewed (L*P*ps, KV*lanes): the carried pool is updated where it
+    lies, no layer is sliced out of it and none is put back. The only code
+    that knows where in a pool a decode step's row goes."""
     tier = pool_tier(pool)
-    if tier != "fp":
+    if tier == "fp":
+        stored = (k[:, 0], v[:, 0])
+    else:
         qk, sk = quantize_kv_rows(k[:, 0], tier)  # (B,KV,hdc), (B,KV)
         qv, sv = quantize_kv_rows(v[:, 0], tier)
         stored = (qk, qv, sk, sv)
@@ -1566,71 +1714,128 @@ def write_rows(pool, page_table, lengths, k, v):
     # slot i's new token lands in its (length // page_size)-th page at offset
     # length % page_size; inactive slots (all-zero table rows) land in the
     # trash page, where duplicate scatter indices are harmless garbage
-    dest = (page_table[jnp.arange(k.shape[0]), lengths // ps] * ps
+    dest = (layer * (pool.num_pages * ps)
+            + page_table[jnp.arange(k.shape[0]), lengths // ps] * ps
             + lengths % ps)  # (B,)
-
-    def row(i):  # sliced after its leaf is flattened, as the fp step has always
-        # traced it: lint/entrypoints.py's *-identity contracts hash the order
-        return (k, v)[i][:, 0] if tier == "fp" else stored[i]
-
     return type(pool)(*(
-        _flat(a, 0).at[dest].set(row(i).astype(a.dtype)).reshape(a.shape)
-        for i, a in enumerate(pool)))
+        _rows(a, 1).at[dest].set(
+            r.astype(a.dtype).reshape(-1, a.shape[-1])).reshape(a.shape)
+        for a, r in zip(pool, stored)))
 
 
-def read_span(pool, page_table, dtype):
-    """Each slot's whole span of K and V out of one layer's pool, in page
-    table order: ((B, span, KV, hd), same) in ``dtype``. The fp tier gathers
-    pages; a quantized tier gathers codes and scales a page a slice, THEN
-    dequantizes — elementwise per row, so exactly equal to dequantizing the
-    whole pool first (the numerical-equivalence contract the lint layer
-    executes). Trash-page rows come along under the caller's length mask."""
+def _gather_pages(leaf, layer, page_table):
+    """Each slot's pages out of layer ``layer`` of a pool leaf (L, P, ps,
+    width), in table order: page_table (B, pages_per_slot) -> (B, span,
+    width); trash-page rows of an unallocated tail come along and stay under
+    the caller's length mask.
+
+    One gather slice is one whole PAGE at ``layer*P + page`` of the leaf
+    viewed (L*P, ps, width). What the forms cost on a v5e (PERF.md §6 "PR
+    27", "PR 29"; one layer's K or V at 192 slots x 128 pages of 16 rows):
+    a ROW a slice 4.4-4.65 ms (11.9 ns a row whatever it held); a page a
+    slice of the (KV, hd) = (2, 64) pool 0.85 ms, by PADDED bytes (a row was
+    half a lane tile) plus a relayout of the layer's slice in and out of the
+    pages-minor array around it; a page a slice of the lane-dense pool is
+    whole (8,128)(2,1) tiles, read where they lie: 0.46-0.63 ms for its true
+    200 MB (0.62-0.80 where most of the table is the trash page). Same values
+    in the same order as the row gather (tests/test_batching.py keeps it as
+    the oracle, and guards the traced step against its return)."""
+    b, pps = page_table.shape
+    at = layer * leaf.shape[1] + page_table
+    return _pages(leaf, 1)[at].reshape(b, pps * leaf.shape[2],
+                                       leaf.shape[-1])
+
+
+def read_span(pool, layer, page_table, dtype):
+    """Each slot's whole span of K and V out of layer ``layer`` of the pool,
+    in page table order, AS STORED: ((B, span, KV*hd), same) in ``dtype``,
+    head j in lanes [j*hd, (j+1)*hd). The fp tier gathers pages
+    (:func:`_gather_pages`, pages at ``layer*P + page_table``); a quantized
+    tier gathers codes and scales a page a slice, THEN dequantizes —
+    elementwise per row, so exactly equal to dequantizing the whole pool
+    first (the numerical-equivalence contract the lint layer executes).
+    Trash-page rows come along under the caller's length mask."""
     tier = pool_tier(pool)
     if tier == "fp":
-        return (_gather_pages(pool.k, page_table),
-                _gather_pages(pool.v, page_table))
-    return (dequantize_kv_rows(_gather_pages(pool.k, page_table),
-                               _gather_pages(pool.k_scale, page_table),
-                               tier, dtype),
-            dequantize_kv_rows(_gather_pages(pool.v, page_table),
-                               _gather_pages(pool.v_scale, page_table),
-                               tier, dtype))
+        return (_gather_pages(pool.k, layer, page_table),
+                _gather_pages(pool.v, layer, page_table))
+    kv = pool.k_scale.shape[-1]
+    return tuple(
+        _merge_heads(dequantize_kv_rows(
+            _split_heads(_gather_pages(codes, layer, page_table), kv),
+            _gather_pages(scales, layer, page_table), tier, dtype))
+        for codes, scales in ((pool.k, pool.k_scale),
+                              (pool.v, pool.v_scale)))
 
 
-def paged_decode_attention(q, pool, page_table, lengths):
-    """Ragged single-position attention against one layer's pool: q
-    (B, 1, H, hd) per slot; page_table (B, pages_per_slot) int32 names each
+def attend_rows(q, k_rows, v_rows, lengths):
+    """Single-position GQA attention against rows as the pool stores them:
+    q (B, 1, H, hd); k_rows, v_rows (B, span, KV*hd), head j of a row in
+    lanes [j*hd, (j+1)*hd); lengths (B,) valid positions a slot. Returns
+    (B, 1, H, hd) in q's dtype; softmax in fp32.
+
+    The rows are read AS THEY LIE: splitting the (KV*hd) lanes into
+    (KV, hd) for ``decode_attention``'s per-group einsum is a real
+    lane-splitting copy of every gathered K and V on the chip (100 MB read,
+    200 written a layer at hd 64). Instead each query head is placed in ITS
+    group's lanes of a (B, H, KV*hd) query that is zero elsewhere, so the
+    scores are one dot over the whole row — exact, the added products are
+    zeros — and PV yields every group's lanes for every head, of which a
+    head keeps its own. KV-fold the MXU work of a step that is bound by the
+    K/V read. Head j*rep+g attends KV group j, as everywhere."""
+    b, _, h, hd = q.shape
+    kv = k_rows.shape[-1] // hd
+    # (H, KV): head h's group
+    own = (jnp.arange(h)[:, None] // (h // kv)) == jnp.arange(kv)[None, :]
+    qz = jnp.where(own[None, :, :, None], q.reshape(b, h, 1, hd), 0)
+    scores = jnp.einsum("bhD,bcD->bhc", qz.reshape(b, h, kv * hd), k_rows,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (1.0 / np.sqrt(hd))
+    # ragged: row i masks at its own lengths[i]
+    valid = jnp.arange(k_rows.shape[1])[None, :] < lengths[:, None]
+    scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhc,bcD->bhD", probs.astype(q.dtype), v_rows,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.where(own[None, :, :, None], out.reshape(b, h, kv, hd), 0)
+    return out.sum(axis=2).reshape(b, 1, h, hd)
+
+
+def paged_decode_attention(q, pool, layer, page_table, lengths):
+    """Ragged single-position attention against layer ``layer`` of a pool:
+    q (B, 1, H, hd) per slot; page_table (B, pages_per_slot) int32 names each
     slot's pages in logical order (0 = the trash page for unallocated tails);
     lengths (B,) int32 counts each slot's valid positions INCLUDING the one
     this step wrote. Returns (B, 1, H, hd) in q's dtype; softmax in fp32.
 
-    One XLA page gather (:func:`read_span`) and
-    :func:`~edgellm_tpu.models.flash_attention.decode_attention` with vector
-    lengths: trash-page garbage lands only in masked positions, where
-    softmax of ``finfo.min`` contributes exactly 0."""
+    One XLA page gather (:func:`read_span`) and :func:`attend_rows` over
+    its output as it lies: trash-page garbage lands only in masked positions,
+    where softmax of ``finfo.min`` contributes exactly 0."""
     s1, h, hd = q.shape[1:]
-    kv, lanes = pool.k.shape[-2:]
     if s1 != 1:
         raise ValueError(f"paged decode is q_len=1 only, got q_len={s1}")
     tier = pool_tier(pool)
-    if KV_PAGE_CODECS[tier].code_lanes(hd) != lanes:
-        raise ValueError(f"code width {lanes} does not match q head_dim "
-                         f"{hd} for tier {tier!r}")
+    lanes = KV_PAGE_CODECS[tier].code_lanes(hd)
+    kv, rest = divmod(pool.k.shape[-1], lanes)
+    if rest or (tier != "fp" and kv != pool.k_scale.shape[-1]):
+        raise ValueError(f"row width {pool.k.shape[-1]} does not match q "
+                         f"head_dim {hd} for tier {tier!r}")
     if h % kv:
         raise ValueError(f"ragged GQA: H={h}, KV={kv}")
-    kg, vg = read_span(pool, page_table, q.dtype)
-    return decode_attention(q, kg, vg, lengths)
+    kg, vg = read_span(pool, layer, page_table, q.dtype)
+    return attend_rows(q, kg, vg, lengths)
 
 
 @jax.named_scope("attn.decode")
 def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                            cos_b, sin_b, pool, page_table, lengths,
+                            cos_b, sin_b, pool, layer, page_table, lengths,
                             tp_axis: Optional[str] = None):
     """The paged twin of ``transformer._attention_decode``: project the
     (B, 1, D) hidden, rotate each slot at ITS position, write the new K/V row
     into each slot's current page, then ragged-attend against the slot's
-    pages. ``pool`` is ONE layer's (num_pages, page_size, KV, ...) pool, at
-    whichever tier; on a quantized tier the current token attends its OWN
+    pages. ``pool`` is the WHOLE (L, num_pages, page_size, ...) pool, at
+    whichever tier, and ``layer`` the index this layer's rows and pages are
+    addressed under; on a quantized tier the current token attends its OWN
     quantized K/V, consistent with what every later step will read."""
     b, s1, d = x.shape
     hd = cfg.head_dim
@@ -1647,8 +1852,8 @@ def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     else:
         q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
         k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
-    pool = write_rows(pool, page_table, lengths, k, v)
-    out = paged_decode_attention(q, pool, page_table, lengths + 1)
+    pool = write_rows(pool, layer, page_table, lengths, k, v)
+    out = paged_decode_attention(q, pool, layer, page_table, lengths + 1)
     out = out.reshape(b, s1, h * hd) @ lp["wo"]
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
@@ -1658,22 +1863,24 @@ def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
 
 
 def block_decode_paged(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,
-                       cos_b, sin_b, pool, page_table, lengths,
+                       cos_b, sin_b, pool, layer, page_table, lengths,
                        tp_axis: Optional[str] = None):
     """The paged twin of ``transformer.block_decode`` for one layer:
-    same norm/residual/MLP structure, paged attention core."""
+    same norm/residual/MLP structure, paged attention core over layer
+    ``layer`` of the whole pool."""
     if cfg.family == "gpt_neox":
         attn_in = _layernorm(hidden, lp["ln1_scale"], lp["ln1_bias"],
                              cfg.norm_eps)
         attn_out, pool = _attention_decode_paged(
-            cfg, lp, attn_in, cos_b, sin_b, pool, page_table, lengths,
+            cfg, lp, attn_in, cos_b, sin_b, pool, layer, page_table, lengths,
             tp_axis)
         mlp_in = _layernorm(hidden, lp["ln2_scale"], lp["ln2_bias"],
                             cfg.norm_eps)
         return hidden + attn_out + mlp(cfg, lp, mlp_in, tp_axis), pool
     attn_in = _rmsnorm(hidden, lp["ln1_scale"], cfg.norm_eps)
     attn_out, pool = _attention_decode_paged(
-        cfg, lp, attn_in, cos_b, sin_b, pool, page_table, lengths, tp_axis)
+        cfg, lp, attn_in, cos_b, sin_b, pool, layer, page_table, lengths,
+        tp_axis)
     hidden = hidden + attn_out
     mlp_in = _rmsnorm(hidden, lp["ln2_scale"], cfg.norm_eps)
     return hidden + mlp(cfg, lp, mlp_in, tp_axis), pool
@@ -1700,8 +1907,11 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
     attention mask all index by each slot's own ``lengths[i]`` — the ragged
     generalization of ``decode_step``'s single ``cache.length``; per-slot
     math is bit-identical to the contiguous path (see module docstring).
-    The layer scan carries the pool pytree, whose leaves flatten in the
-    order k, v(, k_scale, v_scale).
+    The layer scan CARRIES the pool pytree (leaves in the order k, v(,
+    k_scale, v_scale)) beside the hidden state and scans the layer index:
+    the donated pool is updated where it lies, layer after layer, and is
+    never sliced into ``xs`` nor stacked back out of ``ys`` (which cost a
+    relayout of every layer's slice in and out, PERF.md §6 "PR 29").
     """
     params = _cast_params(params, compute_dtype)
     if token_ids.ndim == 1:
@@ -1712,12 +1922,14 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
     cos_b = cos[lengths]  # (B, rot) — each slot's own row
     sin_b = sin[lengths]
 
-    def body(h, xs):
-        lp, layer_pool = xs
-        return block_decode_paged(cfg, lp, h, cos_b, sin_b, layer_pool,
-                                  page_table, lengths)
+    def body(carry, xs):
+        (h, pool), (lp, layer) = carry, xs
+        return block_decode_paged(cfg, lp, h, cos_b, sin_b, pool, layer,
+                                  page_table, lengths), None
 
-    hidden, pool = jax.lax.scan(body, hidden, (params["layers"], pool))
+    layers = jnp.arange(pool.k.shape[0], dtype=jnp.int32)
+    (hidden, pool), _ = jax.lax.scan(body, (hidden, pool),
+                                     (params["layers"], layers))
     with jax.named_scope("unembed_sample"):
         logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
     return logits, pool

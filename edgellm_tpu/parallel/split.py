@@ -52,13 +52,14 @@ from ..serve.recovery import StageLostError
 _STAGED = 2
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _adopt_paged_impl(pool, k_seq, v_seq, dest):
+@functools.partial(jax.jit, static_argnames=("head",), donate_argnums=(0,))
+def _adopt_paged_impl(pool, k_seq, v_seq, dest, head=None):
     """One stream's (n_stages, sz, n, KV, hd) prefill K/V into the per-stage
     pools at flat token indices ``dest``: ``paged_kv.adopt_at`` over the
-    staged pool. Donated in-place update; elementwise along "stage", so the
-    pool sharding propagates hop-free."""
-    return paged_kv.adopt_at(pool, k_seq, v_seq, dest, _STAGED)
+    staged pool (the sharded stage axis stays sliced, ``stage_size`` folds
+    into the row index). Donated in-place update; elementwise along "stage",
+    so the pool sharding propagates hop-free."""
+    return paged_kv.adopt_at(pool, k_seq, v_seq, dest, _STAGED, head)
 
 
 @jax.named_scope("unembed_sample")
@@ -1492,7 +1493,7 @@ class SplitRuntime:
     # traced page table, trash page 0), so streams with different prompt
     # lengths and fill levels share ONE compiled ragged step per pool
     # geometry while every cut still moves its quantized (B, 1, D) boundary
-    # activation.  Pool layout: (n_stages, sz, num_pages, page_size, KV, hd)
+    # activation.  Pool layout: (n_stages, sz, num_pages, page_size, KV * hd)
     # sharded P("stage") — each stage owns its own layers' pages, pages never
     # cross a cut.
 
@@ -1512,26 +1513,28 @@ class SplitRuntime:
         zeros = functools.partial(
             jax.jit, static_argnums=(0, 1),
             out_shardings=NamedSharding(self.mesh, P("stage")))(jnp.zeros)
-        rows = (self.split.n_stages, self.stage_size, num_pages, page_size,
-                cfg.num_kv_heads)
+        rows = (self.split.n_stages, self.stage_size, num_pages, page_size)
+        kv = cfg.num_kv_heads
         if not codec.quantized:
-            shape = rows + (cfg.head_dim,)
+            shape = rows + (kv * cfg.head_dim,)
             return paged_kv.PagePool(zeros(shape, dtype), zeros(shape, dtype))
-        codes = rows + (codec.code_lanes(cfg.head_dim),)
+        codes = rows + (kv * codec.code_lanes(cfg.head_dim),)
         return paged_kv.QuantPagePool(
             zeros(codes, codec.code_dtype), zeros(codes, codec.code_dtype),
-            zeros(rows, jnp.float32), zeros(rows, jnp.float32))
+            zeros(rows + (kv,), jnp.float32), zeros(rows + (kv,), jnp.float32))
 
     def adopt_paged(self, pool, cache: dict, row: int, dest: np.ndarray,
                     length: int):
         """Move one stream's prefilled contiguous cache (``prefill_decode``
         row ``row``) into pool pages at flat token indices ``dest``
         ((length,) int32, from PagedKVCache._flat_indices). Donates the pool
-        buffers — the scatter is stage-elementwise, no collectives. On a
-        quantized pool the fp rows quantize on append."""
+        buffers — the scatter is stage-elementwise, no collectives, whole
+        pages a slice where ``dest`` (a HOST array, in position order) fills
+        them. On a quantized pool the fp rows quantize on append."""
         return _adopt_paged_impl(
             pool, cache["k"][:, :, row, :length],  # (n_stages, sz, n, KV, hd)
-            cache["v"][:, :, row, :length], jnp.asarray(dest, jnp.int32))
+            cache["v"][:, :, row, :length], jnp.asarray(dest, jnp.int32),
+            head=paged_kv.page_head(dest, pool.page_size))
 
     def adopt_paged_rows(self, pool, k_seq, v_seq, dest: np.ndarray):
         """Scatter an already-contiguous (n_stages, sz, n, KV, hd) K/V prefix
@@ -1539,8 +1542,10 @@ class SplitRuntime:
         checkpoint — into pool pages at flat token indices ``dest``. The
         re-admission half of eviction for the split batcher. Quantized pools
         requantize fp rows here; bit-exact resume uses the packed twin."""
-        return _adopt_paged_impl(pool, jnp.asarray(k_seq), jnp.asarray(v_seq),
-                                 jnp.asarray(dest, jnp.int32))
+        return _adopt_paged_impl(
+            pool, jnp.asarray(k_seq), jnp.asarray(v_seq),
+            jnp.asarray(dest, jnp.int32),
+            head=paged_kv.page_head(dest, pool.page_size))
 
     def adopt_paged_rows_packed(self, pool, k_codes, v_codes, k_scale,
                                 v_scale, dest: np.ndarray):
@@ -1555,7 +1560,8 @@ class SplitRuntime:
         return paged_kv._adopt_packed_impl(
             pool, jnp.asarray(k_codes), jnp.asarray(v_codes),
             jnp.asarray(k_scale), jnp.asarray(v_scale),
-            jnp.asarray(dest, jnp.int32), lead=_STAGED)
+            jnp.asarray(dest, jnp.int32), lead=_STAGED,
+            head=paged_kv.page_head(dest, pool.page_size))
 
     def copy_paged_pages(self, pool, src, dst):
         """Apply prefix-cache COW forks to the per-stage pools: duplicate
@@ -1576,7 +1582,8 @@ class SplitRuntime:
         Quantized pools come back DEQUANTIZED to fp32 (the suffix-prefill
         compute form); the packed twin preserves the raw bytes."""
         k_seq, v_seq = paged_kv._gather_impl(
-            pool, jnp.asarray(idx, jnp.int32), lead=_STAGED)
+            pool, jnp.asarray(idx, jnp.int32), lead=_STAGED,
+            kv=self.cfg.num_kv_heads)
         return np.asarray(k_seq), np.asarray(v_seq)
 
     def gather_paged_packed(self, pool, idx: np.ndarray) -> tuple:
@@ -1646,12 +1653,17 @@ class SplitRuntime:
             the step's own: every unroll step then scans the same body."""
             def scan_body(h, xs):
                 lp, ok, layer_pool = xs
+                # the one write and read site of paged_kv, over this
+                # iteration's slice as a pool of ONE layer (the carried pool
+                # is the next perf_opt issue's, ROADMAP S9(c))
                 out, written = block_decode_paged(
-                    cfg, lp, h, cos_b, sin_b, layer_pool, page_table, lengths)
+                    cfg, lp, h, cos_b, sin_b,
+                    tree_map(lambda a: a[None], layer_pool), 0, page_table,
+                    lengths)
                 # padding layers are identity AND must not touch their
                 # pages
                 return jnp.where(ok, out, h), tree_map(
-                    lambda new, old: jnp.where(ok, new, old), written,
+                    lambda new, old: jnp.where(ok, new[0], old), written,
                     layer_pool)
 
             return lambda h, pool: jax.lax.scan(scan_body, h,

@@ -126,6 +126,49 @@ def test_adopt_gather_roundtrip():
     assert int(back["length"]) == n
     np.testing.assert_array_equal(back["k"], k)
     np.testing.assert_array_equal(back["v"], v)
+    # the stored leaf is (L, P, ps, KV*hd); a row of layer l landed in layer
+    # l at its page and offset and NOWHERE else: the (L, P, ps, KV, hd)
+    # oracle, written with the indices the flat (layer, page, row) index
+    # replaces
+    assert pool.pool.k.shape == (CFG.num_layers, 9, 4,
+                                 CFG.num_kv_heads * CFG.head_dim)
+    assert (pool.pool.num_pages, pool.pool.page_size) == (9, 4)
+    pos = np.arange(n)
+    pages, offs = pool.page_table[slot, pos // 4], pos % 4
+    for leaf, rows in ((pool.pool.k, k), (pool.pool.v, v)):
+        want = np.zeros((CFG.num_layers, 9, 4, CFG.num_kv_heads,
+                         CFG.head_dim), np.float32)
+        want[:, pages, offs] = rows
+        np.testing.assert_array_equal(
+            np.asarray(leaf).reshape(want.shape), want)
+
+
+def test_parent_shaped_state_dict_loads():
+    # a state_dict written before the row was stored lane-dense holds
+    # (L, P, ps, KV, hd) leaves; it loads by a reshape, and state_dict()
+    # still writes that form
+    kw = dict(num_pages=9, page_size=4, max_slots=2, pages_per_slot=3)
+    src = PagedKVCache(CFG, **kw)
+    rng = np.random.default_rng(5)
+    k = rng.standard_normal((CFG.num_layers, 7, CFG.num_kv_heads,
+                             CFG.head_dim)).astype(np.float32)
+    slot = src.alloc_slot()
+    src.adopt(slot, jnp.asarray(k), jnp.asarray(-k), 7)
+    state = src.state_dict()
+    five_d = (CFG.num_layers, 9, 4, CFG.num_kv_heads, CFG.head_dim)
+    assert state["k"].shape == state["v"].shape == five_d
+    pos = np.arange(7)
+    np.testing.assert_array_equal(
+        state["k"][:, src.page_table[slot, pos // 4], pos % 4], k)
+    twin = PagedKVCache(CFG, **kw)
+    twin.load_state_dict(state)
+    twin.check_invariants()
+    assert twin.pool.k.shape == src.pool.k.shape
+    back = twin.gather_slot(slot)
+    np.testing.assert_array_equal(back["k"], k)
+    np.testing.assert_array_equal(back["v"], -k)
+    with pytest.raises(ValueError, match="shape mismatch"):
+        twin.load_state_dict({**state, "k": state["k"][:, :-1]})
 
 
 def test_defrag_preserves_content_and_compacts():
@@ -355,9 +398,22 @@ def test_checkpoint_refuses_other_model(params, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_paged_attention_matches_contiguous():
-    # the page gather must agree bitwise with decode_attention over each
-    # slot's contiguous view, and be invariant to garbage beyond length
+def _stored(pages, layer=0, layers=1):
+    """One layer's (num_pages, page_size, KV, hd) K or V as layer ``layer``
+    of a stored (layers, num_pages, page_size, KV*hd) leaf whose other
+    layers hold garbage no attend of ``layer`` may read."""
+    pn, ps = pages.shape[:2]
+    leaf = np.full((layers, pn, ps, int(np.prod(pages.shape[2:]))), 1e4,
+                   np.float32)
+    leaf[layer] = np.asarray(pages, np.float32).reshape(pn, ps, -1)
+    return jnp.asarray(leaf, pages.dtype)
+
+
+@pytest.mark.parametrize("layer,layers", [(0, 1), (1, 3), (2, 3)])
+def test_paged_attention_matches_contiguous(layer, layers):
+    # the page gather at a layer index, then the attend over the rows as
+    # they lie, must agree with decode_attention over each slot's contiguous
+    # (B, span, KV, hd) view, and be invariant to garbage beyond length
     rng = np.random.default_rng(11)
     b, h, kv, hd, pn, ps, pps = 3, 4, 2, 8, 7, 4, 2
     span = pps * ps
@@ -366,12 +422,16 @@ def test_paged_attention_matches_contiguous():
     vp = jnp.asarray(rng.standard_normal(kp.shape).astype(np.float32))
     pt = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
     lengths = jnp.asarray([3, 8, 5], jnp.int32)
-    out = paged_decode_attention(q, PagePool(kp, vp), pt, lengths)
+    out = paged_decode_attention(
+        q, PagePool(_stored(kp, layer, layers), _stored(vp, layer, layers)),
+        layer, pt, lengths)
     idx = (np.asarray(pt)[:, :, None] * ps
            + np.arange(ps)[None, None, :]).reshape(b, span)
     kg = jnp.asarray(np.asarray(kp).reshape(pn * ps, kv, hd)[idx])
     vg = jnp.asarray(np.asarray(vp).reshape(pn * ps, kv, hd)[idx])
-    ref = decode_attention(q, kg, vg, lengths)
+    ref = _ragged_decode_attention(q, kg, vg, lengths)
+    # a head's scores sum its own hd products and exact zeros for the other
+    # group's lanes; at 16 lanes the order of additions is decode_attention's
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # scribble over every position past each slot's length: masked entries
     # contribute exactly 0, so the output must not change by a single bit
@@ -382,8 +442,18 @@ def test_paged_attention_matches_contiguous():
             kp2[page, off] = 1e6 * (i + 1)
             vp2[page, off] = -1e6
     out2 = paged_decode_attention(
-        q, PagePool(jnp.asarray(kp2), jnp.asarray(vp2)), pt, lengths)
+        q, PagePool(_stored(jnp.asarray(kp2), layer, layers),
+                    _stored(jnp.asarray(vp2), layer, layers)),
+        layer, pt, lengths)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+def _ragged_decode_attention(q, k, v, lengths):
+    """The (B, span, KV, hd) oracle of the paged attend: each slot alone
+    through the contiguous path's decode_attention at its own length."""
+    return jnp.concatenate([
+        decode_attention(q[i:i + 1], k[i:i + 1], v[i:i + 1], lengths[i])
+        for i in range(q.shape[0])])
 
 
 def _flat_row_gather(pages, page_table):
@@ -423,25 +493,99 @@ def test_page_gather_equals_flat_row_gather_bitwise(kv, hd, ps, monkeypatch):
     kp = jnp.asarray(rng.standard_normal((pn, ps, kv, hd)), jnp.bfloat16)
     vp = jnp.asarray(rng.standard_normal((pn, ps, kv, hd)), jnp.bfloat16)
     handed = []
-    attend = decode_attention
+    attend = paged_kv.attend_rows
 
     def recording_attend(q_, k_, v_, lengths_):
         handed.append((k_, v_))
         return attend(q_, k_, v_, lengths_)
 
-    monkeypatch.setattr(paged_kv, "decode_attention", recording_attend)
-    out = paged_decode_attention(q, PagePool(kp, vp), pt, lengths)
+    monkeypatch.setattr(paged_kv, "attend_rows", recording_attend)
+    # layer 1 of 2: the other layer's pages are garbage under the same ids
+    out = paged_decode_attention(
+        q, PagePool(_stored(kp, 1, 2), _stored(vp, 1, 2)), 1, pt, lengths)
     (kg, vg), = handed
     k_old, v_old = _flat_row_gather(kp, pt), _flat_row_gather(vp, pt)
-    assert kg.shape == k_old.shape == (b, pt.shape[1] * ps, kv, hd)
-    np.testing.assert_array_equal(np.asarray(kg), np.asarray(k_old))
-    np.testing.assert_array_equal(np.asarray(vg), np.asarray(v_old))
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(attend(q, k_old, v_old, lengths)))
+    span = pt.shape[1] * ps
+    assert k_old.shape == (b, span, kv, hd)
+    assert kg.shape == vg.shape == (b, span, kv * hd)   # as stored
+    np.testing.assert_array_equal(np.asarray(kg).reshape(k_old.shape),
+                                  np.asarray(k_old))
+    np.testing.assert_array_equal(np.asarray(vg).reshape(v_old.shape),
+                                  np.asarray(v_old))
+    # the attend over the rows as they lie against decode_attention over
+    # the (KV, hd) view: the same products, zeros added for the other
+    # groups' lanes (bf16 out: a reordered sum's tolerance)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(_ragged_decode_attention(q, k_old, v_old, lengths),
+                   np.float32),
+        rtol=2e-2, atol=2e-2)
     # the all-trash slot reads the trash page's row 0 and nothing else
-    np.testing.assert_array_equal(
-        np.asarray(out[3]),
-        np.asarray(attend(q[3:4], kp[None, 0], vp[None, 0], 1)[0]))
+    np.testing.assert_allclose(
+        np.asarray(out[3], np.float32),
+        np.asarray(decode_attention(q[3:4], kp[None, 0], vp[None, 0], 1)[0],
+                   np.float32), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("tier", ["fp", "int8_per_channel",
+                                  "int4_per_channel"])
+def test_write_rows_then_read_span_at_a_layer_equal_the_5d_oracle(tier):
+    # a step's rows written at layer l and the span read back at layer l
+    # equal the same write and the flat-row read of the pool held as
+    # (L, P, ps, KV, lanes): the row lands in layer l only, at
+    # l*P*ps + page*ps + row, and the pages come from l*P + page
+    from edgellm_tpu.models.flash_attention import (dequantize_kv_rows,
+                                                    quantize_kv_rows)
+
+    rng = np.random.default_rng(23)
+    layers, pn, ps, kv, hd = 3, 11, 4, 2, 8
+    pt, lengths = _ragged_paged_case(ps)
+    lengths = lengths - 1                       # the row to be written
+    b = pt.shape[0]
+    k = jnp.asarray(rng.standard_normal((b, 1, kv, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, 1, kv, hd)), jnp.float32)
+    if tier == "fp":
+        pool = PagePool(*(jnp.asarray(rng.standard_normal(
+            (layers, pn, ps, kv * hd)), jnp.float32) for _ in "kv"))
+        stored = (k[:, 0], v[:, 0])
+    else:
+        pool = init_quant_pool(
+            tiny_config("qwen2", num_layers=layers, hidden_size=kv * hd * 2,
+                        num_heads=2 * kv, vocab_size=32), pn, ps, tier)
+        pool = type(pool)(*(jnp.asarray(rng.integers(
+            0, 100, a.shape), a.dtype) for a in pool))
+        (qk, sk), (qv, sv) = (quantize_kv_rows(x[:, 0], tier)
+                              for x in (k, v))
+        stored = (qk, qv, sk, sv)
+    before = [np.asarray(a) for a in pool]
+    for layer in (0, 2, jnp.asarray(1, jnp.int32)):
+        after = paged_kv.write_rows(pool, layer, pt, lengths, k, v)
+        l = int(layer)
+        page = np.asarray(pt)[np.arange(b), np.asarray(lengths) // ps]
+        off = np.asarray(lengths) % ps
+        live = page != 0        # the trash page takes duplicate writes
+        for a0, a1, rows in zip(before, after, stored):
+            want = a0.copy().reshape(layers, pn, ps, kv, -1)
+            want[l, page[live], off[live]] = np.asarray(
+                rows, a0.dtype).reshape(b, kv, -1)[live]
+            got = np.asarray(a1).reshape(want.shape)
+            np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+            np.testing.assert_array_equal(np.delete(got, l, 0)[:, 0],
+                                          np.delete(want, l, 0)[:, 0])
+        kg, vg = paged_kv.read_span(after, layer, pt, jnp.float32)
+        views = [np.asarray(a)[l].reshape(pn, ps, kv, -1) for a in after]
+        if tier == "fp":
+            k_ref, v_ref = (_flat_row_gather(jnp.asarray(x), pt)
+                            for x in views)
+        else:
+            k_ref, v_ref = (dequantize_kv_rows(
+                _flat_row_gather(jnp.asarray(c), pt),
+                _flat_row_gather(jnp.asarray(s_[..., 0]), pt), tier)
+                for c, s_ in ((views[0], views[2]), (views[1], views[3])))
+        np.testing.assert_array_equal(
+            np.asarray(kg), np.asarray(k_ref).reshape(kg.shape))
+        np.testing.assert_array_equal(
+            np.asarray(vg), np.asarray(v_ref).reshape(vg.shape))
 
 
 def _gathers(jaxpr):
@@ -472,8 +616,7 @@ def test_decode_step_fetches_pool_by_page_not_by_row(params, tier):
         want = [ps * kv * hd] * 2                  # a page of K, of V
     else:
         pool = init_quant_pool(CFG, pn, ps, tier)
-        hdc = pool.k.shape[-1]
-        want = [ps * kv * hdc] * 2 + [ps * kv] * 2  # codes and scales
+        want = [ps * pool.k.shape[-1]] * 2 + [ps * kv] * 2  # codes, scales
     jaxpr = jax.make_jaxpr(lambda *a: paged_decode_step(CFG, *a))(
         params, pool, table, ints, ints)
     fetches = []
@@ -481,9 +624,11 @@ def test_decode_step_fetches_pool_by_page_not_by_row(params, tier):
         shape = eqn.invars[0].aval.shape
         if "attn.decode" not in path or "paged_kv.write" in path:
             continue
-        # whatever view of the layer's pool is gathered, its leading axis
-        # counts PAGES and one slice is everything a page holds
-        assert shape[0] == pn, f"a pool not indexed by page: {shape}"
+        # whatever view of the pool is gathered, its leading axis counts the
+        # PAGES of every layer (the flat (layer, page) index: no layer is
+        # sliced out first) and one slice is everything a page holds
+        assert shape[0] == CFG.num_layers * pn, \
+            f"a pool not indexed by (layer, page): {shape}"
         assert tuple(eqn.params["slice_sizes"]) == (1, *shape[1:]), \
             f"a slice is not one whole page: {eqn.params['slice_sizes']}"
         fetches.append(int(np.prod(shape[1:])))

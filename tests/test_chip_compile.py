@@ -1,0 +1,179 @@
+"""The page pool stays in place: the TPU compiler's own verdict, at no chip
+time.
+
+The paged decode step, the one-chip adopt and the split runtime's staged
+adopt are compiled HERE for a described (not attached) TPU v5e at the
+benchmark cells' real shapes, and the compiled modules are held to what
+PERF.md §6 "PR 29" found: the pool is a donated buffer that row scatters
+and page gathers address in place. A pool that is copied, relaid or stacked
+back layer by layer — 113 of 181 ms of the step before that PR — shows here
+as a ``copy``, ``dynamic-update-slice`` or ``reshape`` of pool size and as
+gigabytes of temporaries. Nothing runs, so nothing here is a time.
+
+ONE file, the topology described inside a fixture (``on-chip-measurement``
+§2): only the worker that is handed this file loads the TPU's library.
+"""
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from edgellm_tpu.models import paged_kv
+from edgellm_tpu.models.configs import ModelConfig
+from edgellm_tpu.models.transformer import init_params
+from edgellm_tpu.serve import batching
+
+# benchmark/configs/qwen2-0.5b.json and qwen2-1.5b-split4.json: the widths
+# and the serving geometry of the cells (the test must not read benchmark/)
+QWEN05 = ModelConfig(
+    family="qwen2", vocab_size=151936, hidden_size=896, num_layers=24,
+    num_heads=14, num_kv_heads=2, intermediate_size=4864,
+    max_position_embeddings=131072, norm_eps=1e-6, rope_theta=1e6,
+    tie_word_embeddings=True)
+PAGES, PAGE, SLOTS, PAGES_PER_SLOT = 24577, 16, 192, 128
+SPLIT_STAGES, SPLIT_STAGE_SIZE, SPLIT_KV, SPLIT_HD = 4, 7, 2, 128
+PROMPT = 1000      # 62 whole pages and 8 rows: both scatters of an adopt
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it undescribed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it off around these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _elements(shape_text: str) -> int:
+    """The largest array an HLO result type names, in elements."""
+    return max((int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+                for dims in re.findall(r"[a-z]+[0-9]+\[([\d,]*)\]",
+                                       shape_text)), default=0)
+
+
+def _instructions(hlo: str):
+    """(opcode, name, result type, line) of every instruction of every
+    computation of a compiled module, fused computations included."""
+    for line in hlo.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(",
+                     line)
+        if m:
+            name, shape, op = m.groups()
+            yield op, name, shape, line
+
+
+def _moved(hlo: str, at_least: int, ops=("copy", "dynamic-update-slice",
+                                         "reshape", "transpose")):
+    """Instructions that materialize an array of ``at_least`` elements or
+    more by moving it. (A ``bitcast`` moves nothing; the page gather's own
+    fused computation holds the ``reshape``/``transpose`` of its OUTPUT,
+    which is the gather, so it is exempt by its shape below.)"""
+    return [(op, name, shape.split("{")[0]) for op, name, shape, _ in
+            _instructions(hlo) if op in ops and _elements(shape) >= at_least]
+
+
+def test_decode_step_updates_the_pool_where_it_lies(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(QWEN05, jax.random.key(0), dtype=jnp.bfloat16)),
+        one)
+    pool = _shapes(jax.eval_shape(
+        lambda: paged_kv.init_pool(QWEN05, PAGES, PAGE, jnp.bfloat16)), one)
+    width = QWEN05.num_kv_heads * QWEN05.head_dim
+    assert pool.k.shape == (24, PAGES, PAGE, width)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((SLOTS,), jnp.int32)
+    step = batching._batched_step_jit.lower(
+        QWEN05, params, pool, arr((SLOTS, PAGES_PER_SLOT), jnp.int32), ints,
+        ints, arr((SLOTS, 2), jnp.uint32), ints, arr((SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    layer_pool = PAGES * PAGE * width           # one layer's K or V
+    gathered = SLOTS * PAGES_PER_SLOT * PAGE * width   # a layer's span read
+    # the gather custom fusion names its own output's reshape/transpose
+    # (`bf16[24576,16,128]`, `bf16[192,128,16,128]`): the gather itself
+    own = {f"bf16[{SLOTS * PAGES_PER_SLOT},{PAGE},{width}]",
+           f"bf16[{SLOTS},{PAGES_PER_SLOT},{PAGE},{width}]"}
+    moved = [m for m in _moved(hlo, gathered)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    # no copy / dynamic-update-slice / reshape produces a pool-sized array,
+    # and no K/V-sized relayout sits between the page gather and the dots
+    assert not moved, moved
+    assert not [m for m in moved if _elements(m[2]) >= layer_pool]
+    # the two gathers a layer take whole pages at (layer, page) ...
+    flat_pages = f"bf16[{24 * PAGES},{PAGE},{width}]"
+    assert flat_pages in hlo, "the pool is not gathered as (L*P, ps, width)"
+    # ... and the row writes scatter into the pool viewed as rows
+    assert f"bf16[{24 * PAGES * PAGE},{width}]" in hlo
+    # the attend's dots read the gather's output as it lies
+    assert "bhD,bcD->bhc" in hlo and "bhc,bcD->bhD" in hlo
+    assert step.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_adopt_scatters_in_place(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    pool = _shapes(jax.eval_shape(
+        lambda: paged_kv.init_pool(QWEN05, PAGES, PAGE, jnp.bfloat16)), one)
+    rows = jax.ShapeDtypeStruct(
+        (24, PROMPT, QWEN05.num_kv_heads, QWEN05.head_dim), jnp.bfloat16,
+        sharding=one)
+    dest = jax.ShapeDtypeStruct((PROMPT,), jnp.int32, sharding=one)
+    # head=0: a prefill's adopt, whole pages a scatter slice (the cells' path)
+    adopt = paged_kv._adopt_impl.lower(pool, rows, rows, dest,
+                                       head=0).compile()
+    layer_pool = PAGES * PAGE * pool.k.shape[-1]
+    assert not _moved(adopt.as_text(), layer_pool), \
+        _moved(adopt.as_text(), layer_pool)
+    assert adopt.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def test_staged_adopt_scatters_in_place_over_four_chips(topo):
+    from edgellm_tpu.parallel import split
+
+    mesh = Mesh(np.asarray(topo.devices[:SPLIT_STAGES]), ("stage",))
+    staged = NamedSharding(mesh, P("stage"))
+    width = SPLIT_KV * SPLIT_HD
+    leaf = jax.ShapeDtypeStruct(
+        (SPLIT_STAGES, SPLIT_STAGE_SIZE, PAGES, PAGE, width), jnp.bfloat16,
+        sharding=staged)
+    rows = jax.ShapeDtypeStruct(
+        (SPLIT_STAGES, SPLIT_STAGE_SIZE, PROMPT, SPLIT_KV, SPLIT_HD),
+        jnp.float32, sharding=staged)
+    dest = jax.ShapeDtypeStruct((PROMPT,), jnp.int32,
+                                sharding=NamedSharding(mesh, P()))
+    adopt = split._adopt_paged_impl.lower(
+        paged_kv.PagePool(leaf, leaf), rows, rows, dest, head=0).compile()
+    hlo = adopt.as_text()
+    layer_pool = PAGES * PAGE * width
+    assert not _moved(hlo, layer_pool), _moved(hlo, layer_pool)
+    # stage-elementwise: the stage axis stayed sliced, nothing crosses chips
+    assert not re.search(r"all-gather|all-reduce|collective-permute|"
+                         r"all-to-all", hlo)
+    assert adopt.memory_analysis().temp_size_in_bytes < 16e6

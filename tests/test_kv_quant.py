@@ -187,16 +187,21 @@ def test_paged_quant_fallback_matches_dequantized_pool(tier):
     tab = jnp.asarray(rng.permutation(np.arange(1, npg))[:ms * pps]
                       .reshape(ms, pps).astype(np.int32))
     lens = jnp.asarray([pgs + 3, pgs - 2], jnp.int32)
+    nkv = CFG2.num_kv_heads
+    # the stored form: one layer of (P, ps, KV*lanes) codes, (P, ps, KV)
+    # scales
     got = paged_decode_attention(
-        q, QuantPagePool(kq.reshape(npg, pgs, -1, hdc),
-                         vq.reshape(npg, pgs, -1, hdc),
-                         ks.reshape(npg, pgs, -1), vs.reshape(npg, pgs, -1)),
-        tab, lens)
+        q, QuantPagePool(kq.reshape(1, npg, pgs, nkv * hdc),
+                         vq.reshape(1, npg, pgs, nkv * hdc),
+                         ks.reshape(1, npg, pgs, nkv),
+                         vs.reshape(1, npg, pgs, nkv)),
+        0, tab, lens)
     kf = dequantize_kv_rows(kq, ks, tier)
     vf = dequantize_kv_rows(vq, vs, tier)
     ref = paged_decode_attention(
-        q, PagePool(kf.reshape(npg, pgs, -1, CFG2.head_dim),
-                    vf.reshape(npg, pgs, -1, CFG2.head_dim)), tab, lens)
+        q, PagePool(kf.reshape(1, npg, pgs, nkv * CFG2.head_dim),
+                    vf.reshape(1, npg, pgs, nkv * CFG2.head_dim)),
+        0, tab, lens)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
@@ -223,17 +228,35 @@ def test_quant_page_gather_equals_flat_row_gather_bitwise(tier, kv, hd, ps):
         jnp.asarray(rng.standard_normal(rows), jnp.float32), tier)
     hdc = kq.shape[-1]
     q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.bfloat16)
-    got = paged_decode_attention(
-        q, QuantPagePool(kq.reshape(pn, ps, kv, hdc),
-                         vq.reshape(pn, ps, kv, hdc),
-                         ks.reshape(pn, ps, kv), vs.reshape(pn, ps, kv)),
-        pt, lens)
+    from edgellm_tpu.models import paged_kv as pk
+
+    # layer 1 of 2 in the stored form; layer 0 holds other bytes under the
+    # same page ids
+    def stored(x, width):
+        leaf = x.reshape(1, pn, ps, width)
+        return jnp.concatenate([leaf[:, ::-1], leaf])
+
+    pool = QuantPagePool(stored(kq, kv * hdc), stored(vq, kv * hdc),
+                         stored(ks, kv), stored(vs, kv))
     idx = (pt[:, :, None] * ps
            + jnp.arange(ps)[None, None, :]).reshape(b, span)
     kg = dequantize_kv_rows(kq[idx], ks[idx], tier, q.dtype)
     vg = dequantize_kv_rows(vq[idx], vs[idx], tier, q.dtype)
-    np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(decode_attention(q, kg, vg, lens)))
+    # the rows handed to the attend are the flat-row fetch's to the bit ...
+    got_k, got_v = pk.read_span(pool, 1, pt, q.dtype)
+    np.testing.assert_array_equal(np.asarray(got_k).reshape(kg.shape),
+                                  np.asarray(kg))
+    np.testing.assert_array_equal(np.asarray(got_v).reshape(vg.shape),
+                                  np.asarray(vg))
+    # ... and the attend over them as they lie is decode_attention over
+    # their (KV, hd) view, to a reordered bf16 sum's tolerance
+    got = paged_decode_attention(q, pool, 1, pt, lens)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(jnp.concatenate([
+            decode_attention(q[i:i + 1], kg[i:i + 1], vg[i:i + 1], lens[i])
+            for i in range(b)]), np.float32),
+        rtol=2e-2, atol=2e-2)
 
 
 # ---------------------------------------------------------------------------
@@ -248,15 +271,15 @@ def _zero_pool(axes, pn, ps, kv, hd, tier):
     """An all-zero pool with leading ``axes`` ((L,) on a chip, (n_stages,
     stage_size) staged) at ``tier``."""
     codec = resolve_kv_codec(tier)
-    rows = tuple(axes) + (pn, ps, kv)
+    rows = tuple(axes) + (pn, ps)
     if not codec.quantized:   # a buffer each: the surgery donates them
-        return PagePool(*(jnp.zeros(rows + (hd,), jnp.float32)
+        return PagePool(*(jnp.zeros(rows + (kv * hd,), jnp.float32)
                           for _ in "kv"))
-    codes = rows + (codec.code_lanes(hd),)
+    codes = rows + (kv * codec.code_lanes(hd),)
     return QuantPagePool(jnp.zeros(codes, codec.code_dtype),
                          jnp.zeros(codes, codec.code_dtype),
-                         jnp.zeros(rows, jnp.float32),
-                         jnp.zeros(rows, jnp.float32))
+                         jnp.zeros(rows + (kv,), jnp.float32),
+                         jnp.zeros(rows + (kv,), jnp.float32))
 
 
 def _host(pool):
@@ -289,8 +312,11 @@ def test_pool_surgery_is_one_body_at_every_rank_and_tier(lead, tier):
                       k_, v_, dest)
         assert type(pool) is (PagePool if tier == "fp" else QuantPagePool)
         out["adopted"] = _host(pool)
-        out["gathered"] = _host(pk._gather_impl(pool, dest, lead=lead_))
-        out["packed"] = _host(pk._gather_packed_impl(pool, dest, lead=lead_))
+        out["gathered"] = _host(pk._gather_impl(pool, dest, lead=lead_,
+                                                kv=kv))
+        if tier != "fp":     # the packed form is a quantized pool's
+            out["packed"] = _host(pk._gather_packed_impl(pool, dest,
+                                                         lead=lead_))
         # COW fork of pages 2, 3 into 4, 5 (the pool is donated: host copies)
         pool = pk._copy_pages_impl(pool, jnp.asarray([2, 3]),
                                    jnp.asarray([4, 5]), lead=lead_)
@@ -301,6 +327,16 @@ def test_pool_surgery_is_one_body_at_every_rank_and_tier(lead, tier):
 
     got, src = run(k, v, lead, adopt)
     page = (slice(None),) * lead
+    # a row of layer l (of stage s) landed in layer l (of stage s) at its
+    # page and offset and nowhere else: the (..., P, ps, KV, lanes) oracle,
+    # written with the indices the flat (layer, page, row) index replaces
+    stored_k = (np.asarray(k) if tier == "fp"
+                else np.asarray(quantize_kv_rows(k, tier)[0]))
+    want = np.zeros(axes + (pn, ps) + stored_k.shape[-2:], stored_k.dtype)
+    want[page + (np.asarray(dest) // ps, np.asarray(dest) % ps)] = stored_k
+    assert got["adopted"][0].shape == axes + (
+        pn, ps, kv * stored_k.shape[-1])                 # the stored row
+    np.testing.assert_array_equal(got["adopted"][0].reshape(want.shape), want)
     # adopt -> gather returns the rows (to the tier's quantization error)
     gk, gv = got["gathered"]
     if tier == "fp":
@@ -344,6 +380,47 @@ def test_pool_surgery_is_one_body_at_every_rank_and_tier(lead, tier):
 
 
 @pytest.mark.parametrize("tier", ALL_TIERS)
+@pytest.mark.parametrize("lead", [1, 2], ids=["one-chip", "staged"])
+@pytest.mark.parametrize("start,n", [(0, 11), (2, 7), (3, 14), (1, 2)])
+def test_adopt_by_whole_pages_equals_adopt_by_rows(start, n, lead, tier):
+    # an adopt whose rows fill whole pages scatters those a PAGE a slice
+    # (head says where the first page boundary is) and the rows before and
+    # after one by one: every leaf must equal the all-rows scatter's, over
+    # pages that are not neighbours in the pool, from a start inside a page
+    from edgellm_tpu.models import paged_kv as pk
+    from edgellm_tpu.parallel import split as split_mod
+
+    axes = (3,) if lead == 1 else (2, 3)
+    pn, ps, kv, hd = 9, 4, 2, 8
+    rng = np.random.default_rng(start * 100 + n)
+    k = jnp.asarray(rng.standard_normal(axes + (n, kv, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(axes + (n, kv, hd)), jnp.float32)
+    pos = np.arange(start, start + n)
+    dest = (np.asarray([5, 2, 7, 1, 8])[pos // ps] * ps
+            + pos % ps).astype(np.int32)
+    head = pk.page_head(dest, ps)
+    assert head == -start % ps
+    adopt = pk._adopt_impl if lead == 1 else split_mod._adopt_paged_impl
+    by_rows = _host(adopt(_zero_pool(axes, pn, ps, kv, hd, tier), k, v,
+                          jnp.asarray(dest)))
+    by_pages = _host(adopt(_zero_pool(axes, pn, ps, kv, hd, tier), k, v,
+                           jnp.asarray(dest), head=head))
+    assert by_rows[0].any()
+    for a, b in zip(by_rows, by_pages):
+        np.testing.assert_array_equal(a, b)
+    if tier != "fp":
+        packed = pk._gather_packed_impl(
+            type(_zero_pool(axes, pn, ps, kv, hd, tier))(
+                *(jnp.asarray(a) for a in by_rows)),
+            jnp.asarray(dest), lead=lead)
+        again = _host(pk._adopt_packed_impl(
+            _zero_pool(axes, pn, ps, kv, hd, tier), *packed,
+            jnp.asarray(dest), lead=lead, head=head))
+        for a, b in zip(by_rows, again):
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("tier", ALL_TIERS)
 def test_one_chip_and_staged_step_share_one_write_and_one_read(
         tier, params, monkeypatch):
     # the layout of a K/V row is known in two functions of models/paged_kv.py:
@@ -372,14 +449,18 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
     table = jnp.zeros((slots, pps), jnp.int32)
     ints = jnp.zeros((slots,), jnp.int32)
     codec = resolve_kv_codec(tier)
-    layer = (npg, ps, CFG.num_kv_heads, codec.code_lanes(CFG.head_dim))
+    layer = (npg, ps, CFG.num_kv_heads * codec.code_lanes(CFG.head_dim))
     kind = QuantPagePool if codec.quantized else PagePool
-    want = [("write", kind, layer), ("read", kind, layer)]
 
-    pool = _zero_pool((CFG.num_layers,), *layer[:3], CFG.head_dim, tier)
+    def want(layers):   # the pool WITH its layer axis, one write, one read
+        return [(op, kind, (layers,) + layer) for op in ("write", "read")]
+
+    pool = _zero_pool((CFG.num_layers,), npg, ps, CFG.num_kv_heads,
+                      CFG.head_dim, tier)
     jax.make_jaxpr(lambda *a: pk.paged_decode_step(CFG, *a))(
         params, pool, table, ints, ints)
-    assert seen == want        # the layer scan traces its body once
+    # the layer scan traces its body once, over the carried whole pool
+    assert seen == want(CFG.num_layers)
     del seen[:]
 
     rt = SplitRuntime(CFG, SplitConfig(cuts=(2,), hop_codecs=("fp32",)),
@@ -389,7 +470,8 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
     assert staged.k.shape == (2, rt.stage_size) + layer
     jax.make_jaxpr(rt._paged_decode_fns(npg, ps, kv_codec=tier))(
         rt.place_params(params), staged, table, ints, ints)
-    assert seen == want        # one stage body, scanned by every stage
+    # one stage body, scanned by every stage, over a pool of ONE layer
+    assert seen == want(1)
 
 
 def test_packed_gather_adopt_roundtrip_across_geometry():
