@@ -381,31 +381,21 @@ def split_phase(cfg, vocab_size: int, *, prompt_lens=PROMPT_LENS,
     return out
 
 
-def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
-                 evict_after: int = 5) -> dict:
-    """A tiny ``granitemoehybrid`` stream (Mamba-2 and NoPE attention layers,
-    routed + shared experts, float32) admitted, stepped, evicted and
-    readmitted through ``ContinuousBatcher`` on the chip: the recurrent state
-    leaves the device with the K/V rows and comes back. The prompt is longer
-    than ``moe.DENSE_MAX_TOKENS`` so that the prefill takes the grouped expert
-    products (whose rows past the last group a TPU leaves undefined) and many
-    chunks of the scan. Its tokens equal an undisturbed stream's, and
-    ``forward`` over prompt + tokens puts each of them first, both sides at
-    ``highest``."""
+def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
+                   evict_after: int) -> tuple:
+    """One stream admitted, stepped, evicted and readmitted through
+    ``ContinuousBatcher`` on the chip beside a short neighbour, float32 at
+    ``highest``: its tokens equal an undisturbed stream's, and ``forward``
+    over prompt + tokens puts each of them first. Returns (the batcher's
+    report, the largest gap over the largest logit)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from edgellm_tpu.models import forward, init_params
-    from edgellm_tpu.models.configs import tiny_hybrid_config
-    from edgellm_tpu.serve.batching import BatchingConfig, ContinuousBatcher
+    from edgellm_tpu.serve.batching import ContinuousBatcher
 
-    # 4 of the router's 8 experts held: half the assignments are to absent
-    # experts, the rows past the last group
-    cfg = tiny_hybrid_config(experts_held=4, expert_offset=2)
     params = init_params(cfg, jax.random.key(SEED))
-    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
-                          pages_per_slot=24)
     prompt = np.random.default_rng(SEED).integers(
         1, cfg.vocab_size, size=prompt_len).astype(np.int32)
     with jax.default_matmul_precision("highest"):
@@ -429,11 +419,56 @@ def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
     gaps = rows.max(axis=-1) - rows[np.arange(n_new), got]
     scale = float(np.abs(rows).max())
     assert gaps.max() <= 1e-4 * scale, (gaps.tolist(), scale)
-    assert report["evicted"] == 1 and report["state_bytes"] > 0
+    assert report["evicted"] == 1
+    return report, float(gaps.max() / scale)
+
+
+def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
+                 evict_after: int = 5) -> dict:
+    """A tiny ``granitemoehybrid`` stream (Mamba-2 and NoPE attention layers,
+    routed + shared experts, float32) admitted, stepped, evicted and
+    readmitted through ``ContinuousBatcher`` on the chip: the recurrent state
+    leaves the device with the K/V rows and comes back. The prompt is longer
+    than ``moe.DENSE_MAX_TOKENS`` so that the prefill takes the grouped expert
+    products (whose rows past the last group a TPU leaves undefined) and many
+    chunks of the scan."""
+    from edgellm_tpu.models.configs import tiny_hybrid_config
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    # 4 of the router's 8 experts held: half the assignments are to absent
+    # experts, the rows past the last group
+    report, gap = _evict_readmit(
+        tiny_hybrid_config(experts_held=4, expert_offset=2),
+        BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                       pages_per_slot=24), prompt_len, n_new, evict_after)
+    assert report["state_bytes"] > 0
     return {"tokens": int(n_new), "evicted": report["evicted"],
             "state_bytes": report["state_bytes"],
             "routed_local": report["routed_local"],
-            "gap_max_over_logit_max": float(gaps.max() / scale)}
+            "gap_max_over_logit_max": gap}
+
+
+def window_phase(*, prompt_len: int = 300, n_new: int = 120,
+                 evict_after: int = 70) -> dict:
+    """A tiny ``mellum`` stream (sliding-window layers of 40 keys beside full
+    ones, YaRN on the full layers only, routed experts with none shared, an
+    untied head, float32) through the same admit / step / evict / readmit: a
+    ring of 4 pages of 16 rows a slot in the window group's own pool, a
+    prompt of 4.7 ring turns adopted by its tail, 120 steps that turn the
+    ring twice more, and the ring leaving the device and coming back in
+    between (the benchmark's cell never evicts)."""
+    from edgellm_tpu.models.configs import tiny_mellum_config
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    cfg = tiny_mellum_config(sliding_window=40)
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=28)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after)
+    assert report["window_rows_capacity"] == 3 * 4 * 16
+    return {"tokens": int(n_new), "evicted": report["evicted"],
+            "window_pages": cfg.window_pages(16),
+            "routed_local": report["routed_local"],
+            "gap_max_over_logit_max": gap}
 
 
 def smoke(report: dict, save) -> dict:
@@ -467,6 +502,7 @@ def smoke(report: dict, save) -> dict:
     phase("sweep", sweep_phase)
     phase("reference", lambda: reference_phase(cfg))
     phase("hybrid", hybrid_phase)
+    phase("window", window_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

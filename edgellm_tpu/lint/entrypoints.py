@@ -343,6 +343,31 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
                         hstate.ssm, hcount, ptab, plens, ptoks, pkeys,
                         qsteps, qtemps, None))
 
+    # ---- a stack with sliding-window layers (mellum): the same walk with
+    # ---- the window group — collective-free; the full layers' pool, the
+    # ---- window layers' pool of rings (two buffers each) and the expert
+    # ---- counter, FIVE buffers, stay donated in the lowered executable ---
+    from ..models.configs import tiny_mellum_config
+
+    wcfg = tiny_mellum_config(sliding_window=2 * PGS + 2)
+    wparams = transformer.init_params(wcfg, jax.random.key(0))
+    wfull = paged_kv.init_pool(wcfg, NPG, PGS)
+    wring = paged_kv.init_pool(wcfg, MS * wcfg.window_pages(PGS) + 1, PGS,
+                               layers=wcfg.window_layers)
+    wtab = jnp.zeros((MS, wcfg.window_pages(PGS)), jnp.int32)
+    wcount = jnp.zeros((wcfg.num_layers, wcfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_window",
+            lambda p, pk, pv, wk, wv, ct, pt, wt, ln, t:
+                hybrid.paged_decode_step_hybrid(
+                    wcfg, p, pk, pv, None, None, ct, pt, ln, t,
+                    window=(wk, wv, wt)),
+            (wparams, wfull.k, wfull.v, wring.k, wring.v, wcount, ptab, wtab,
+             plens, ptoks),
+            ctx={"donate_min": 5},
+            lowerable=batching._batched_window_step_jit,
+            lower_args=(wcfg, wparams, wfull, wring, wcount, ptab, wtab,
+                        plens, ptoks, pkeys, qsteps, qtemps, None))
+
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state
     # feeds the byte-identical ragged step graph the pre-quantization
     # batcher traces — the disabled-build jaxpr fingerprint half of the

@@ -11,6 +11,7 @@ network access to pull pretrained weights).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 
@@ -30,9 +31,16 @@ class ModelConfig:
         every layer's feed-forward a routed expert layer plus a shared
         expert, four scalar multipliers (IBM Granite 4.0-H). The serving
         path only; see ``models/hybrid.py``.
+      - ``"mellum"``: the same walk over ``layer_types`` with a third kind,
+        ``"sliding_attention"`` (a banded GQA layer that keeps a ring of
+        pages) beside ``"attention"`` (full causal, HF's ``full_attention``);
+        an explicit head width (``H x hd != hidden_size``), RoPE with YaRN
+        on the full layers only, every feed-forward a routed expert layer
+        with no shared expert, an untied head (JetBrains Mellum 2).
 
-    The fields after ``rope_scaling`` exist for that family and default to
-    "absent", so the three one-block families hash and trace as before.
+    The fields after ``rope_scaling`` exist for those two families and
+    default to "absent", so the three one-block families hash and trace as
+    before.
     """
 
     family: str
@@ -47,13 +55,23 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0
     tie_word_embeddings: bool = False
-    #: llama3 RoPE frequency rescaling, or None for vanilla RoPE. Tuple form
-    #: ("llama3", factor, low_freq_factor, high_freq_factor,
-    #: original_max_position_embeddings) — hashable for the frozen config.
+    #: RoPE frequency rescaling, or None for vanilla RoPE. Tuple forms,
+    #: hashable for the frozen config: ("llama3", factor, low_freq_factor,
+    #: high_freq_factor, original_max_position_embeddings) or ("yarn",
+    #: factor, original_max_position_embeddings, beta_fast, beta_slow,
+    #: attention_factor). In a stack with window layers it is the FULL
+    #: layers' table; the window layers rotate by the plain one (Mellum's
+    #: ``rope_parameters`` by layer kind).
     rope_scaling: Optional[tuple] = None
-    #: per-layer mixer kind, ``"mamba"`` or ``"attention"``; empty = every
-    #: layer is the family's one block
+    #: per-layer mixer kind, ``"mamba"``, ``"attention"`` or
+    #: ``"sliding_attention"``; empty = every layer is the family's one block
     layer_types: tuple = ()
+    #: width of one attention head where the model states it; 0 = the
+    #: derived ``hidden_size // num_heads``
+    explicit_head_dim: int = 0
+    #: keys a ``"sliding_attention"`` layer attends, itself among them:
+    #: position i sees j with ``i - sliding_window < j <= i``
+    sliding_window: int = 0
     #: routed experts: ``num_experts`` is the ROUTER's width (the published
     #: count), ``experts_held`` how many of them this chip computes, starting
     #: at ``expert_offset`` (expert parallelism: the rest live elsewhere and
@@ -84,11 +102,32 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.explicit_head_dim or self.hidden_size // self.num_heads
 
     @property
     def is_hybrid(self) -> bool:
+        """Walked by layer kinds (``models/hybrid.py``), with params held per
+        kind and a routed expert layer after every mixer."""
+        return self.family in ("granitemoehybrid", "mellum")
+
+    @property
+    def recurrent_state(self) -> bool:
+        """Keeps a sequence's state as more than K/V rows (Mamba-2's
+        convolution window and SSM state): what
+        ``hybrid.refuse_recurrent_state`` refuses by."""
         return self.family == "granitemoehybrid"
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that keep a RING of K/V pages: the sliding ones."""
+        return sum(1 for t in self.layer_types if t == "sliding_attention")
+
+    def window_pages(self, page_size: int) -> int:
+        """Pages a slot's ring holds in each window layer: the most that
+        ``sliding_window`` consecutive positions can touch,
+        ``ceil((window - 1) / page_size) + 1`` — ``window // page_size + 1``
+        where the page size divides the window (65 at 1024 / 16)."""
+        return -(-(self.sliding_window - 1) // page_size) + 1
 
     @property
     def kv_layers(self) -> int:
@@ -133,25 +172,33 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.family not in ("gpt_neox", "qwen2", "llama",
-                               "granitemoehybrid"):
+                               "granitemoehybrid", "mellum"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
-        elif self.layer_types or self.num_experts or self.mamba_heads:
+        elif (self.layer_types or self.num_experts or self.mamba_heads
+              or self.explicit_head_dim or self.sliding_window):
             raise ValueError(
-                f"layer_types / experts / mamba fields belong to the "
-                f"granitemoehybrid family, not {self.family!r}")
-        if self.hidden_size % self.num_heads:
+                f"layer_types / experts / mamba / head width / window fields "
+                f"belong to the granitemoehybrid and mellum families, not "
+                f"{self.family!r}")
+        if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_kv_heads must evenly divide num_heads")
 
     def _check_hybrid(self):
+        kinds = (("mamba", "attention") if self.recurrent_state
+                 else ("attention", "sliding_attention"))
         if len(self.layer_types) != self.num_layers or any(
-                t not in ("mamba", "attention") for t in self.layer_types):
+                t not in kinds for t in self.layer_types):
             raise ValueError(
-                f"layer_types must name 'mamba' or 'attention' for each of "
-                f"the {self.num_layers} layers, got {self.layer_types!r}")
+                f"layer_types must name one of {kinds} for each of the "
+                f"{self.num_layers} layers of family {self.family!r}, got "
+                f"{self.layer_types!r}")
+        if self.window_layers and self.sliding_window < 1:
+            raise ValueError("a sliding_attention layer needs sliding_window "
+                             ">= 1")
         if not 0 < self.experts_per_tok <= self.num_experts:
             raise ValueError("experts_per_tok must be in [1, num_experts]")
         if not (0 <= self.expert_offset
@@ -161,8 +208,9 @@ class ModelConfig:
                 f"experts held [{self.expert_offset}, "
                 f"{self.expert_offset + self.local_experts}) lie outside the "
                 f"router's {self.num_experts}")
-        if self.expert_width < 1 or self.shared_width < 1:
-            raise ValueError("expert_width and shared_width must be >= 1")
+        if self.expert_width < 1 or self.shared_width < 0:
+            raise ValueError("expert_width must be >= 1 and shared_width "
+                             ">= 0 (0: no shared expert)")
         if self.mamba_layers and min(
                 self.mamba_heads, self.mamba_head_dim, self.mamba_d_state,
                 self.mamba_d_conv - 1, self.mamba_chunk) < 1:
@@ -271,6 +319,59 @@ GRANITE_4_0_H_SMALL = ModelConfig(
 )
 
 
+# JetBrains/Mellum2-12B-A2.5B-Instruct (2026-05) — config.json: 28 layers in
+# a period of four (three sliding-window layers of 1024 keys, one full), d
+# 2304, 32 query / 4 KV heads of 128 (H x hd = 4096), 64 routed experts of
+# width 896 top-8 with no shared expert on every layer, untied 98304-row
+# head; RoPE theta 500000, YaRN (factor 16 over 8192) on the full layers only.
+_MELLUM_PERIOD = ("sliding_attention",) * 3 + ("attention",)
+MELLUM2_12B_A2_5B = ModelConfig(
+    family="mellum",
+    vocab_size=98304,
+    hidden_size=2304,
+    num_layers=28,
+    num_heads=32,
+    num_kv_heads=4,
+    intermediate_size=7168,   # the published dense width; no layer is dense
+    max_position_embeddings=131072,
+    norm_eps=1e-6,
+    rope_theta=500000.0,
+    tie_word_embeddings=False,
+    rope_scaling=("yarn", 16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+    layer_types=_MELLUM_PERIOD * 7,
+    explicit_head_dim=128,
+    sliding_window=1024,
+    num_experts=64,
+    experts_per_tok=8,
+    expert_width=896,
+)
+
+
+def tiny_mellum_config(*, layer_types: tuple = _MELLUM_PERIOD * 2,
+                       sliding_window: int = 20, hidden_size: int = 48,
+                       num_heads: int = 4, num_kv_heads: int = 2,
+                       head_dim: int = 16, vocab_size: int = 256,
+                       num_experts: int = 8, experts_per_tok: int = 3,
+                       experts_held: int = 0, expert_offset: int = 0,
+                       max_position_embeddings: int = 512) -> ModelConfig:
+    """A small mellum for tests: every mechanism of the published model (both
+    layer kinds in the 3:1 pattern, a window shorter than the test prompts,
+    ``H x hd`` = 64 against a hidden size of 48, YaRN on the full layers only
+    with an original length the tests decode past, top-k of routed experts
+    with none shared, an untied head) at toy widths."""
+    return ModelConfig(
+        family="mellum", vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=len(layer_types), num_heads=num_heads,
+        num_kv_heads=num_kv_heads, intermediate_size=32,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-6,
+        rope_theta=10000.0, tie_word_embeddings=False,
+        rope_scaling=("yarn", 4.0, 32, 32.0, 1.0, 0.1 * math.log(4.0) + 1.0),
+        layer_types=tuple(layer_types), explicit_head_dim=head_dim,
+        sliding_window=sliding_window, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32,
+        experts_held=experts_held, expert_offset=expert_offset)
+
+
 def tiny_hybrid_config(*, layer_types: tuple = ("mamba", "mamba", "attention",
                                                 "mamba"),
                        hidden_size: int = 64, num_heads: int = 4,
@@ -302,6 +403,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
     """Small random-init config for tests (no pretrained weights in this environment)."""
     if family == "granitemoehybrid":
         return tiny_hybrid_config()
+    if family == "mellum":
+        return tiny_mellum_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -328,9 +431,11 @@ PRESETS = {
     "qwen2-1.5b": QWEN2_1_5B,
     "llama-3.2-1b": LLAMA_3_2_1B,
     "granite-4.0-h-small": GRANITE_4_0_H_SMALL,
+    "mellum2-12b-a2.5b": MELLUM2_12B_A2_5B,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
     "tiny-llama": tiny_config("llama", num_layers=6),
     "tiny-granite-hybrid": tiny_hybrid_config(),
+    "tiny-mellum": tiny_mellum_config(),
 }

@@ -529,10 +529,11 @@ def dequantize_kv_rows(codes, scales, kv_codec: str, dtype=jnp.float32):
     return (c * (scales[..., None] / qmax)).astype(dtype)
 
 
-def decode_attention(q, k_cache, v_cache, length):
+def decode_attention(q, k_cache, v_cache, length, window: int = 0):
     """Single-position attention against a cache: q (B, 1, H, hd) vs
     k/v_cache (B, capacity, KV, hd) of which the first ``length`` positions
-    are valid (``length`` is a traced scalar — one executable per capacity,
+    are valid — the last ``window`` of them where ``window`` (static) is not
+    0: a sliding layer's band (``length`` is a traced scalar — one executable per capacity,
     one fill level for the whole batch: the contiguous decode path; the
     paged pool's ragged twin, over rows as the pool stores them, is
     ``models.paged_kv.attend_rows``).
@@ -560,6 +561,8 @@ def decode_attention(q, k_cache, v_cache, length):
                         preferred_element_type=jnp.float32)
     scores = scores * (1.0 / np.sqrt(hd))
     valid = jnp.arange(k_cache.shape[1]) < length  # (capacity,)
+    if window:
+        valid &= jnp.arange(k_cache.shape[1]) >= length - window
     scores = jnp.where(valid[None, None, None, :], scores,
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)

@@ -95,6 +95,11 @@ def params_from_state_dict(cfg: ModelConfig, sd: dict) -> dict:
     """Build the framework's parameter pytree from a HF torch state_dict."""
     if cfg.family == "gpt_neox":
         return _neox_params(cfg, sd)
+    if cfg.is_hybrid:
+        raise ValueError(
+            f"no state_dict mapping for family {cfg.family!r}: its parameters "
+            f"are held per layer kind (models/hybrid.py) and only "
+            f"config_from_hf knows the family")
     return _qwen2_params(cfg, sd)
 
 
@@ -229,7 +234,9 @@ def config_from_hf(hf_config) -> ModelConfig:
         if getattr(hf_config, "rope_scaling", None):
             raise ValueError("qwen2 rope_scaling is not supported (vanilla RoPE only)")
         if getattr(hf_config, "use_sliding_window", False):
-            raise ValueError("qwen2 sliding-window attention is not supported")
+            raise ValueError("qwen2 sliding-window attention is not supported "
+                             "(use_sliding_window; the mellum family is the "
+                             "one with window layers)")
         return ModelConfig(
             family="qwen2",
             vocab_size=hf_config.vocab_size,
@@ -243,4 +250,62 @@ def config_from_hf(hf_config) -> ModelConfig:
             rope_theta=hf_config.rope_theta,
             tie_word_embeddings=hf_config.tie_word_embeddings,
         )
+    if mt == "mellum":
+        return _mellum_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
+
+
+#: the published names of Mellum's layer kinds -> ModelConfig's
+MELLUM_LAYER_KINDS = {"full_attention": "attention",
+                      "sliding_attention": "sliding_attention"}
+
+
+def _mellum_config(hf_config) -> ModelConfig:
+    """JetBrains Mellum 2 (``model_type`` ``mellum``). The keys mapped:
+    ``head_dim`` (explicit: 32 heads of 128 over a hidden size of 2304),
+    ``layer_types`` (``full_attention`` / ``sliding_attention``),
+    ``sliding_window``, ``rope_parameters`` by layer kind (YaRN on the full
+    layers, plain RoPE of the same theta on the sliding ones),
+    ``moe_intermediate_size``, ``num_experts``, ``num_experts_per_tok``;
+    every ``mlp_layer_types`` entry must be ``sparse`` and ``norm_topk_prob``
+    true (the renormalised top-k softmax the expert layer computes)."""
+    rope = hf_config.rope_parameters
+    full, sliding = rope["full_attention"], rope["sliding_attention"]
+    if (full.get("rope_type") != "yarn"
+            or sliding.get("rope_type", "default") != "default"
+            or full["rope_theta"] != sliding["rope_theta"]):
+        raise ValueError(
+            "mellum rope_parameters must be yarn on full_attention and "
+            "default on sliding_attention, at one rope_theta; got "
+            f"{rope!r}")
+    if any(t != "sparse" for t in hf_config.mlp_layer_types):
+        raise ValueError("mellum with a dense mlp layer is not supported "
+                         "(every mlp_layer_types entry must be 'sparse')")
+    if not getattr(hf_config, "norm_topk_prob", True):
+        raise ValueError("mellum with norm_topk_prob=False is not supported")
+    if getattr(hf_config, "attention_bias", False):
+        raise ValueError("mellum with attention_bias=True is not supported")
+    return ModelConfig(
+        family="mellum",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(full["rope_theta"]),
+        tie_word_embeddings=hf_config.tie_word_embeddings,
+        rope_scaling=("yarn", float(full["factor"]),
+                      int(full["original_max_position_embeddings"]),
+                      float(full["beta_fast"]), float(full["beta_slow"]),
+                      float(full["attention_factor"])),
+        layer_types=tuple(MELLUM_LAYER_KINDS[t]
+                          for t in hf_config.layer_types),
+        explicit_head_dim=int(hf_config.head_dim),
+        sliding_window=int(hf_config.sliding_window),
+        num_experts=int(hf_config.num_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+    )

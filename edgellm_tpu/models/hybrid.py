@@ -1,5 +1,5 @@
-"""A stack of two layer kinds: the ``granitemoehybrid`` family's forward,
-prefill and decode steps.
+"""A stack walked by layer kinds: the ``granitemoehybrid`` and ``mellum``
+families' forward, prefill and decode steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -12,6 +12,12 @@ so parameters are held PER KIND::
               "mamba": {... stacked along the L_mamba mamba layers},
               "attn":  {... stacked along the L_attn attention layers},
               "moe":   [{...} for each of the L layers]}
+
+A ``mellum`` stack (JetBrains Mellum 2) has no ``mamba`` entry, and beside
+``attn`` (its full causal layers, rotated by the YaRN table) a third kind,
+``window``: sliding layers stacked the same way, rotated by the plain table,
+whose K/V live in a RING of pages (``paged_kv``: the window group); its
+expert layers have no shared expert and its head (``lm_head``) is untied.
 
 (the expert weights are a list, one entry a layer, and not a stack: the
 grouped products of a prefill are a kernel call whose operands must be whole
@@ -36,7 +42,10 @@ What this module does not do, by name, because each needs a snapshot of the
 recurrent state that does not exist yet: boundary hooks and attention
 statistics (the sweep drivers), the split runtime, speculation, prefix
 sharing, quantized KV tiers, checkpoints. :func:`refuse_recurrent_state` is
-the one place the refusal is worded.
+the one place the refusal is worded. The same mechanisms read "a slot's K/V
+= every position of every layer", which a window layer's ring does not
+hold: :func:`refuse_window_ring` words that refusal, and
+:func:`refuse_beyond_kv_rows` is what a mechanism calls to make both.
 """
 from __future__ import annotations
 
@@ -47,11 +56,13 @@ import jax.numpy as jnp
 
 from ..lint import graph_contract
 from .configs import ModelConfig
-from .flash_attention import causal_attention, decode_attention, kernel_plan
+from .flash_attention import (MAX_BLOCKED_S, QBLOCK, causal_attention,
+                              decode_attention, kernel_plan)
 from .mamba2 import mamba2_prefill, mamba2_step
 from .moe import moe_layer
-from .paged_kv import PagePool, _attention_decode_paged
-from .transformer import _rmsnorm
+from .paged_kv import (PagePool, _attention_decode_paged,
+                       _attention_decode_window)
+from .transformer import _rmsnorm, apply_rotary, precompute_rope
 
 
 class RecurrentStateUnsupported(ValueError):
@@ -61,8 +72,9 @@ class RecurrentStateUnsupported(ValueError):
 
 
 def refuse_recurrent_state(cfg: ModelConfig, what: str) -> None:
-    """Raise for a hybrid config: ``what`` names the mechanism refusing."""
-    if cfg.is_hybrid:
+    """Raise for a config with recurrent state: ``what`` names the mechanism
+    refusing."""
+    if cfg.recurrent_state:
         raise RecurrentStateUnsupported(
             f"{what} does not support family {cfg.family!r}: its Mamba-2 "
             f"layers keep recurrent state (a convolution window and an SSM "
@@ -70,8 +82,34 @@ def refuse_recurrent_state(cfg: ModelConfig, what: str) -> None:
             f"snapshot of that recurrent state; there is no fallback")
 
 
+class WindowRingUnsupported(ValueError):
+    """A mechanism that reads a sequence's K/V as every position of every
+    layer was asked to serve a family whose window layers keep only a ring of
+    the newest positions."""
+
+
+def refuse_window_ring(cfg: ModelConfig, what: str) -> None:
+    """Raise for a config with sliding-window layers: ``what`` names the
+    mechanism refusing."""
+    if cfg.window_layers:
+        raise WindowRingUnsupported(
+            f"{what} does not support family {cfg.family!r}: its "
+            f"{cfg.window_layers} sliding-window layers keep a ring of the "
+            f"newest {cfg.sliding_window} positions in a page group of their "
+            f"own beside the full layers' pages, and {what} is written for "
+            f"one kind of layer whose K/V rows are every position of every "
+            f"layer; there is no fallback")
+
+
+def refuse_beyond_kv_rows(cfg: ModelConfig, what: str) -> None:
+    """What a mechanism that handles plain per-layer K/V rows alone calls:
+    both refusals above, each in its own words."""
+    refuse_recurrent_state(cfg, what)
+    refuse_window_ring(cfg, what)
+
+
 class HybridCache(NamedTuple):
-    """The contiguous decode cache of a hybrid stack.
+    """The contiguous decode cache of a stack with recurrent state.
 
     k, v: (L_attn, B, capacity, KV, hd); length: () int32;
     conv: (L_mamba, B, d_conv-1, conv_dim) float32;
@@ -82,6 +120,25 @@ class HybridCache(NamedTuple):
     length: jnp.ndarray
     conv: jnp.ndarray
     ssm: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[2]
+
+
+class WindowCache(NamedTuple):
+    """The contiguous decode cache of a stack with sliding-window layers.
+
+    k, v: (L_attn, B, capacity, KV, hd), the full layers'; length: () int32;
+    wk, wv: (L_window, B, capacity, KV, hd), EVERY position of the sliding
+    layers (the contiguous path masks the band; only the paged pool keeps a
+    ring)."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    length: jnp.ndarray
+    wk: jnp.ndarray
+    wv: jnp.ndarray
 
     @property
     def capacity(self) -> int:
@@ -106,7 +163,7 @@ def _row(tree: dict, j: int) -> dict:
 
 def _kinds(cfg: ModelConfig):
     """(layer, kind, index among its kind) down the stack."""
-    seen = {"mamba": 0, "attention": 0}
+    seen = {"mamba": 0, "attention": 0, "sliding_attention": 0}
     for layer, kind in enumerate(cfg.layer_types):
         yield layer, kind, seen[kind]
         seen[kind] += 1
@@ -123,14 +180,73 @@ def _qkv(cfg: ModelConfig, lp: dict, x):
     return q * jnp.asarray(cfg.q_prescale, q.dtype), k, v
 
 
-def _attention_full(cfg: ModelConfig, lp: dict, x):
-    """Causal attention over whole sequences -> (out (B, S, D), k, v)."""
+def _rotated(cfg: ModelConfig, qkv: tuple, rope):
+    """:func:`_qkv`'s result with q and k rotated by ``rope`` (cos, sin) (S,
+    rot); None applies no positions."""
+    if rope is None:
+        return qkv
+    q, k, v = qkv
+    return (apply_rotary(q, *rope, cfg.rotary_dim),
+            apply_rotary(k, *rope, cfg.rotary_dim), v)
+
+
+def _rope_tables(cfg: ModelConfig, n: int) -> dict:
+    """{kind: (cos, sin) (n, rot) or None}: the table each attention kind
+    rotates by — none under NoPE; the scaled one (YaRN) on full layers and
+    the plain one on sliding layers."""
+    if cfg.nope:
+        return {"attention": None, "sliding_attention": None}
+    return {"attention": precompute_rope(cfg, n),
+            "sliding_attention": (precompute_rope(cfg, n, scaled=False)
+                                  if cfg.window_layers else None)}
+
+
+def _attention_blocks(q, k, v, window: int):
+    """Causal (``window`` 0) or banded GQA attention in plain XLA, a block of
+    :data:`QBLOCK` query rows at a time against the keys that block can see:
+    no (H, S, S) score tensor exists (32 x 4096^2 float32 would be 2.1 GB a
+    layer), and a sliding layer's block reads at most ``QBLOCK + window - 1``
+    keys. Position i attends j with ``i - window < j <= i``. q (B, S, H,
+    hd), k, v (B, S, KV, hd) -> (B, S, H, hd); softmax in float32."""
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    outs = []
+    for start in range(0, s, QBLOCK):
+        stop = min(start + QBLOCK, s)
+        lo = max(0, start - window + 1) if window else 0
+        qb = q[:, start:stop].reshape(b, stop - start, kv, h // kv, hd)
+        scores = jnp.einsum("bqgrd,bcgd->bgrqc", qb, k[:, lo:stop],
+                            preferred_element_type=jnp.float32)
+        scores = scores * (1.0 / float(hd) ** 0.5)
+        qi = jnp.arange(start, stop)[:, None]
+        kj = jnp.arange(lo, stop)[None, :]
+        seen = kj <= qi
+        if window:
+            seen &= kj > qi - window
+        scores = jnp.where(seen, scores, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bgrqc,bcgd->bqgrd", probs.astype(q.dtype),
+                         v[:, lo:stop], preferred_element_type=jnp.float32)
+        outs.append(out.astype(q.dtype).reshape(b, stop - start, h, hd))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def _attention_full(cfg: ModelConfig, lp: dict, x, rope=None,
+                    window: int = 0):
+    """Attention over whole sequences, causal or (``window`` > 0) banded ->
+    (out (B, S, D), k, v). A stack with sliding layers, and any prompt past
+    the kernels' envelope, goes by query blocks in plain XLA, its full
+    layers too; otherwise the prefill kernel where it has a plan and
+    ``jax.nn.dot_product_attention`` where not."""
     b, s, _ = x.shape
-    q, k, v = _qkv(cfg, lp, x)
-    plan = kernel_plan(s, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                       itemsize=jnp.dtype(x.dtype).itemsize)
-    out = (causal_attention(q, k, v, plan=plan) if plan is not None
-           else jax.nn.dot_product_attention(q, k, v, is_causal=True))
+    q, k, v = _rotated(cfg, _qkv(cfg, lp, x), rope)
+    if cfg.window_layers or s > MAX_BLOCKED_S:
+        out = _attention_blocks(q, k, v, window)
+    else:
+        plan = kernel_plan(s, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                           itemsize=jnp.dtype(x.dtype).itemsize)
+        out = (causal_attention(q, k, v, plan=plan) if plan is not None
+               else jax.nn.dot_product_attention(q, k, v, is_causal=True))
     return out.reshape(b, s, -1) @ lp["wo"], k, v
 
 
@@ -160,8 +276,12 @@ def embed_hybrid(cfg: ModelConfig, params: dict, ids):
 
 
 def unembed_hybrid(cfg: ModelConfig, params: dict, hidden):
-    """(..., D) -> float32 logits (..., V) over the tied table."""
+    """(..., D) -> float32 logits (..., V) over the tied table, or over an
+    untied ``lm_head`` (D, V)."""
     post = _rms(cfg, hidden, params["final_norm_scale"])
+    if not cfg.tie_word_embeddings:
+        return jnp.einsum("...d,dv->...v", post, params["lm_head"],
+                          preferred_element_type=jnp.float32)
     logits = jnp.einsum("...d,vd->...v", post, params["embed"],
                         preferred_element_type=jnp.float32)
     return logits / cfg.logits_scaling
@@ -171,7 +291,8 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
     """Whole sequences through the stack. Returns (hidden (B, S, D), per-kind
     lists of what a decode cache is filled from when ``collect``)."""
     h = embed_hybrid(cfg, params, ids)
-    ks, vs, convs, ssms = [], [], [], []
+    ks, vs, convs, ssms, wks, wvs = [], [], [], [], [], []
+    rope = _rope_tables(cfg, ids.shape[1])
     for layer, kind, j in _kinds(cfg):
         if kind == "mamba":
             lp = _row(params["mamba"], j)
@@ -180,16 +301,24 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
             if collect:
                 convs.append(conv)
                 ssms.append(ssm)
+        elif kind == "sliding_attention":
+            lp = _row(params["window"], j)
+            out, k, v = _attention_full(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind],
+                cfg.sliding_window)
+            if collect:
+                wks.append(k)
+                wvs.append(v)
         else:
             lp = _row(params["attn"], j)
             out, k, v = _attention_full(
-                cfg, lp, _rms(cfg, h, lp["ln1_scale"]))
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind])
             if collect:
                 ks.append(k)
                 vs.append(v)
         h = h + cfg.residual_multiplier * out
         h, _ = _ffn(cfg, params["moe"][layer], h)
-    return h, (ks, vs, convs, ssms)
+    return h, (ks, vs, convs, ssms, wks, wvs)
 
 
 def forward_hybrid(cfg: ModelConfig, params: dict, ids):
@@ -211,21 +340,24 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
                          f"{capacity}]")
-    h, (ks, vs, convs, ssms) = _walk_full(cfg, params, ids, collect=True)
+    h, (ks, vs, convs, ssms, wks, wvs) = _walk_full(cfg, params, ids,
+                                                    collect=True)
     logits = unembed_hybrid(cfg, params, h[:, -1] if last_only else h)
     kv_shape = (0, b, s, cfg.num_kv_heads, cfg.head_dim)
-    conv_shape, ssm_shape = state_shapes(cfg, b)
     pad = ((0, 0), (0, 0), (0, capacity - s), (0, 0), (0, 0))
-    return logits, HybridCache(
-        jnp.pad(_stack(ks, kv_shape, h.dtype), pad),
-        jnp.pad(_stack(vs, kv_shape, h.dtype), pad),
-        jnp.asarray(s, jnp.int32),
-        _stack(convs, conv_shape, jnp.float32),
-        _stack(ssms, ssm_shape, jnp.float32))
+    k = jnp.pad(_stack(ks, kv_shape, h.dtype), pad)
+    v = jnp.pad(_stack(vs, kv_shape, h.dtype), pad)
+    length = jnp.asarray(s, jnp.int32)
+    if cfg.window_layers:
+        return logits, WindowCache(k, v, length, jnp.pad(jnp.stack(wks), pad),
+                                   jnp.pad(jnp.stack(wvs), pad))
+    conv_shape, ssm_shape = state_shapes(cfg, b)
+    return logits, HybridCache(k, v, length,
+                               _stack(convs, conv_shape, jnp.float32),
+                               _stack(ssms, ssm_shape, jnp.float32))
 
 
-def decode_step_hybrid(cfg: ModelConfig, params: dict, cache: HybridCache,
-                       token_ids):
+def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     """Append one position to every row of a contiguous cache: token ids (B,)
     or (B, 1) -> (logits (B, V) float32, updated cache)."""
     if token_ids.ndim == 2:
@@ -233,34 +365,51 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache: HybridCache,
     b = token_ids.shape[0]
     pos = cache.length
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
-    k_all, v_all, conv_all, ssm_all = (cache.k, cache.v, cache.conv,
-                                       cache.ssm)
+    windowed = isinstance(cache, WindowCache)
+    conv_all, ssm_all = (None, None) if windowed else (cache.conv, cache.ssm)
+    # the rows a kind's layers append to: full layers k / v, sliding wk / wv
+    rows = {"attention": [cache.k, cache.v],
+            "sliding_attention": [cache.wk, cache.wv] if windowed else None}
+    rope = {kind: t and tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1)
+                              for x in t)
+            for kind, t in _rope_tables(cfg, cache.capacity).items()}
     for layer, kind, j in _kinds(cfg):
         if kind == "mamba":
             lp = _row(params["mamba"], j)
             conv_all, ssm_all, out = _step_row(cfg, lp, h, conv_all, ssm_all,
                                                j)
         else:
-            lp = _row(params["attn"], j)
-            q, k, v = _qkv(cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None])
+            sliding = kind == "sliding_attention"
+            lp = _row(params["window" if sliding else "attn"], j)
+            k_all, v_all = rows[kind]
+            q, k, v = _rotated(
+                cfg, _qkv(cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None]),
+                rope[kind])
             kc = jax.lax.dynamic_update_slice(
                 k_all[j], k.astype(k_all.dtype), (0, pos, 0, 0))
             vc = jax.lax.dynamic_update_slice(
                 v_all[j], v.astype(v_all.dtype), (0, pos, 0, 0))
-            out = decode_attention(q, kc, vc, pos + 1).reshape(b, -1) \
+            out = decode_attention(
+                q, kc, vc, pos + 1,
+                cfg.sliding_window if sliding else 0).reshape(b, -1) \
                 @ lp["wo"]
-            k_all, v_all = k_all.at[j].set(kc), v_all.at[j].set(vc)
+            rows[kind] = [k_all.at[j].set(kc), v_all.at[j].set(vc)]
         h = h + cfg.residual_multiplier * out
         h, _ = _ffn(cfg, params["moe"][layer], h)
-    return (unembed_hybrid(cfg, params, h),
-            HybridCache(k_all, v_all, pos + 1, conv_all, ssm_all))
+    logits = unembed_hybrid(cfg, params, h)
+    if windowed:
+        return logits, WindowCache(*rows["attention"], pos + 1,
+                                   *rows["sliding_attention"])
+    return logits, HybridCache(*rows["attention"], pos + 1, conv_all, ssm_all)
 
 
 @graph_contract("paged.decode_step_hybrid", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 5))
+@graph_contract("paged.decode_step_window", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 5))
 def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
                              conv_all, ssm_all, expert_tokens, page_table,
-                             lengths, token_ids):
+                             lengths, token_ids, window=None):
     """The ragged step for a hybrid stack: one position for EVERY slot.
 
     pool_k/pool_v: (L_attn, num_pages, page_size, KV * hd), addressed by the
@@ -270,21 +419,41 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
     gains this step's over the slots with ``lengths > 0`` (a free slot runs
     token-0 math into the trash page and into its own dead state rows, and is
     not counted). Returns (logits (max_slots, V) float32, pool_k, pool_v,
-    conv_all, ssm_all, expert_tokens)."""
+    conv_all, ssm_all, expert_tokens).
+
+    A stack with sliding layers also passes ``window`` = (win_k, win_v
+    (L_window, window pool pages, page_size, KV * hd), window_table
+    (max_slots, window_pages): each slot's ring) and gets (win_k, win_v) back
+    as a seventh result; it has no mamba layer, and conv_all / ssm_all are
+    None both ways."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
     counts = []
+    # each slot's own row of the table its layer kind rotates by
+    span = page_table.shape[1] * pool_k.shape[2]
+    rope = {kind: t and (t[0][lengths], t[1][lengths])
+            for kind, t in _rope_tables(cfg, span).items()}
+    if window is not None:
+        win_k, win_v, window_table = window
     for layer, kind, j in _kinds(cfg):
         if kind == "mamba":
             lp = _row(params["mamba"], j)
             conv_all, ssm_all, out = _step_row(cfg, lp, h, conv_all, ssm_all,
                                                j)
+        elif kind == "sliding_attention":
+            lp = _row(params["window"], j)
+            out, (win_k, win_v) = _attention_decode_window(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None],
+                *rope[kind], PagePool(win_k, win_v), j, window_table,
+                lengths)
+            out = out[:, 0]
         else:
             lp = _row(params["attn"], j)
             out, (pool_k, pool_v) = _attention_decode_paged(
-                cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None], None, None,
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None],
+                *(rope[kind] or (None, None)),
                 PagePool(pool_k, pool_v), j, page_table, lengths)
             out = out[:, 0]
         h = h + cfg.residual_multiplier * out
@@ -292,8 +461,9 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
         counts.append(c)
     with jax.named_scope("unembed_sample"):
         logits = unembed_hybrid(cfg, params, h)
-    return (logits, pool_k, pool_v, conv_all, ssm_all,
-            expert_tokens + jnp.stack(counts))
+    out = (logits, pool_k, pool_v, conv_all, ssm_all,
+           expert_tokens + jnp.stack(counts))
+    return out if window is None else out + ((win_k, win_v),)
 
 
 def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
@@ -302,7 +472,9 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     one; the Mamba-2 scalars take ``mamba_ssm``'s initialisation so that the
     state matters (``A_log = log U[1, 16]``, ``dt_bias = softplus^-1(dt)``
     with ``dt`` log-uniform in [0.001, 0.1], ``D = 1``, the convolution
-    uniform in +-1/sqrt(d_conv))."""
+    uniform in +-1/sqrt(d_conv)). A kind the stack has no layer of has no
+    entry (``mamba``; ``window``), nor has an absent shared expert or a tied
+    head."""
     keys = iter(jax.random.split(key, 16 + 8 * cfg.num_layers))
 
     def init(*shape):
@@ -311,18 +483,32 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
 
     d, hd = cfg.hidden_size, cfg.head_dim
     lm, la, lt = cfg.mamba_layers, cfg.kv_layers, cfg.num_layers
-    nh, di, cd = cfg.mamba_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
     eh, f, fs = cfg.local_experts, cfg.expert_width, cfg.shared_width
-    dt = jnp.exp(jax.random.uniform(next(keys), (lm, nh), jnp.float32,
-                                    jnp.log(0.001), jnp.log(0.1)))
-    return {
+
+    def attention(n):
+        return {
+            "ln1_scale": jnp.ones((n, d), dtype),
+            "wq": init(n, d, cfg.num_heads * hd),
+            "wk": init(n, d, cfg.num_kv_heads * hd),
+            "wv": init(n, d, cfg.num_kv_heads * hd),
+            "wo": init(n, cfg.num_heads * hd, d),
+        }
+
+    if lm:  # drawn first, as before the stack had kinds without it
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (lm, cfg.mamba_heads), jnp.float32,
+            jnp.log(0.001), jnp.log(0.1)))
+    params = {
         # the tied table at 0.02 / embedding_multiplier: h0 then starts at
         # the other matrices' std and a token's own row does not win every
         # logit by embedding_multiplier * |row|^2
         "embed": (init(cfg.vocab_size, d).astype(jnp.float32)
                   / cfg.embedding_multiplier).astype(dtype),
         "final_norm_scale": jnp.ones((d,), dtype),
-        "mamba": {
+    }
+    if lm:
+        nh, di, cd = cfg.mamba_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+        params["mamba"] = {
             "ln1_scale": jnp.ones((lm, d), dtype),
             "w_in": init(lm, d, di + cd + nh),
             "conv_w": jax.random.uniform(
@@ -336,20 +522,18 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
             "D": jnp.ones((lm, nh), dtype),
             "norm_scale": jnp.ones((lm, di), dtype),
             "w_out": init(lm, di, d),
-        },
-        "attn": {
-            "ln1_scale": jnp.ones((la, d), dtype),
-            "wq": init(la, d, cfg.num_heads * hd),
-            "wk": init(la, d, cfg.num_kv_heads * hd),
-            "wv": init(la, d, cfg.num_kv_heads * hd),
-            "wo": init(la, cfg.num_heads * hd, d),
-        },
-        "moe": [{
-            "ln2_scale": jnp.ones((d,), dtype),
-            "router": init(d, cfg.num_experts),
-            "w_gate": init(eh, d, f), "w_up": init(eh, d, f),
-            "w_down": init(eh, f, d),
-            "shared_gate": init(d, fs), "shared_up": init(d, fs),
-            "shared_down": init(fs, d),
-        } for _ in range(lt)],
-    }
+        }
+    params["attn"] = attention(la)
+    params["moe"] = [{
+        "ln2_scale": jnp.ones((d,), dtype),
+        "router": init(d, cfg.num_experts),
+        "w_gate": init(eh, d, f), "w_up": init(eh, d, f),
+        "w_down": init(eh, f, d),
+        **({"shared_gate": init(d, fs), "shared_up": init(d, fs),
+            "shared_down": init(fs, d)} if fs else {}),
+    } for _ in range(lt)]
+    if cfg.window_layers:
+        params["window"] = attention(cfg.window_layers)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = init(d, cfg.vocab_size)
+    return params

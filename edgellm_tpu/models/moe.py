@@ -1,10 +1,11 @@
-"""The routed expert layer of the ``granitemoehybrid`` family, told which
-experts it holds.
+"""The routed expert layer of the ``granitemoehybrid`` and ``mellum``
+families, told which experts it holds.
 
 The router keeps its published width: logits over all ``cfg.num_experts``,
 the top ``cfg.experts_per_tok`` of them, weights the softmax over those. The
 chip computes the experts ``[cfg.expert_offset, + cfg.local_experts)`` for the
-tokens routed to them, plus the shared expert on every token. No token is
+tokens routed to them, plus the shared expert on every token where the
+family has one (``cfg.shared_width``; Mellum has none). No token is
 dropped and there is no capacity factor. What the absent experts would have
 added is left out — that is their chip's part of the sum, and nothing here
 stands in for them or for the exchange (expert parallelism without its
@@ -21,7 +22,8 @@ Two ways through the held experts, chosen by the static token count:
 - more tokens (a prefill): assignments sorted by expert and three
   ``jax.lax.ragged_dot`` grouped products over the held groups; the rows of
   assignments to absent experts sort last, belong to no group and are
-  selected out of the result.
+  selected out of the result. The tokens are padded to a multiple of 8 first
+  (:data:`GROUPED_TOKEN_MULTIPLE`: what the chip's compiler needs).
 
 Scopes: ``moe.route``, ``moe.experts``, ``moe.shared``.
 """
@@ -36,6 +38,11 @@ from .configs import ModelConfig
 #: (tokens x held x 3DF) stop hiding under the held experts' weight bytes on
 #: a v5e (197 TFLOP/s against 819 GB/s: ~240 tokens a byte-bound pass)
 DENSE_MAX_TOKENS = 256
+#: the grouped path pads its tokens to a multiple of this: on a v5e the walk
+#: compiled whole (embed to logits under one jit) comes out 0.5-5% off at a
+#: token count that is not (257, 300, 324, 420: PERF.md §6 "PR 30"; eager, or
+#: the layer compiled alone, or any multiple of 8, is exact to 2e-7)
+GROUPED_TOKEN_MULTIPLE = 8
 
 
 def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray):
@@ -78,6 +85,15 @@ def _experts_dense(cfg: ModelConfig, mp: dict, u, idx, weights):
 
 def _experts_grouped(cfg: ModelConfig, mp: dict, u, idx, weights):
     t, k = idx.shape
+    pad = -t % GROUPED_TOKEN_MULTIPLE
+    if pad:
+        # padding tokens are routed to no expert (id -1): their assignments
+        # sort last with the absent experts', belong to no group and are
+        # selected out like them
+        rows = ((0, pad), (0, 0))
+        return _experts_grouped(
+            cfg, mp, jnp.pad(u, rows), jnp.pad(idx, rows, constant_values=-1),
+            jnp.pad(weights, rows))[:t]
     eh = cfg.local_experts
     local, held = _local(cfg, idx)
     group = jnp.where(held, local, eh).reshape(-1)          # absent: last
@@ -100,7 +116,7 @@ def _experts_grouped(cfg: ModelConfig, mp: dict, u, idx, weights):
 def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,
               active: jnp.ndarray | None = None):
     """u (T, D) normalised input -> (routed part of the held experts + the
-    shared expert (T, D), assignments per held expert (Eh,) int32 counted
+    shared expert, if any, (T, D), assignments per held expert (Eh,) int32 counted
     over the rows ``active`` (T,) bool marks — all rows when None)."""
     idx, weights = route(cfg, mp["router"], u)
     with jax.named_scope("moe.experts"):
@@ -111,6 +127,8 @@ def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,
         if active is not None:
             held = held & active[:, None]
         counts = _assignments(cfg, local, held)
+    if not cfg.shared_width:
+        return routed, counts
     with jax.named_scope("moe.shared"):
         shared = (jax.nn.silu(u @ mp["shared_gate"])
                   * (u @ mp["shared_up"])) @ mp["shared_down"]

@@ -52,6 +52,19 @@ Stored shape and addressing — two halves of ONE mechanism (PERF.md §6
   or ``(L*P, ps, KV*lanes)`` (pages); nothing slices a leading layer axis,
   and the step's layer scan CARRIES the pool instead of stacking it.
 
+Two page groups (PERF.md §6 "PR 30"): a stack with sliding-window layers
+beside full ones keeps TWO pools of this shape and two tables a slot in one
+:class:`PagedKVCache`. The full layers' group is the one described above and
+grows with the stream. A window layer never needs more than its window, so
+its group gives each slot a RING of ``cfg.window_pages(page_size)`` pages in
+a pool of its own: position ``p`` lives in ring entry ``(p // page_size) %
+window_pages``, the row a step writes overwrites one that has left the
+window, and nothing is taken or freed while the stream grows. Both groups go
+through the same :func:`write_rows` (``ring=True``), :func:`_gather_pages`
+and :func:`attend_rows`; the ring's attend masks a row by the ABSOLUTE
+position it holds (:func:`ring_positions`, :func:`window_valid`), and keys are
+rotated before they are stored, so ring order does not matter to the softmax.
+
 Neither half helps alone. The lane-dense row with ``.at[:, dest]`` still
 costs two whole-pool copies a leaf (the compiler moves L under the row
 axis), and on the staged pool, whose (2, 128) tail was already compact and
@@ -346,16 +359,17 @@ class PagePool(NamedTuple):
 
 
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
-              dtype=jnp.float32) -> PagePool:
+              dtype=jnp.float32, layers: Optional[int] = None) -> PagePool:
     """An all-zero pool; ``num_pages`` INCLUDES the reserved trash page 0,
-    so ``num_pages - 1`` pages are allocatable."""
+    so ``num_pages - 1`` pages are allocatable. ``layers``: how many layers
+    it serves where that is not ``cfg.kv_layers`` (the window group's)."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is reserved), "
                          f"got {num_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (cfg.kv_layers, num_pages, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
+    shape = (cfg.kv_layers if layers is None else layers, num_pages,
+             page_size, cfg.num_kv_heads * cfg.head_dim)
     return PagePool(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -768,15 +782,15 @@ class PagedKVCache:
                 f"pages_per_slot must be >= 1, got {pages_per_slot}")
         self.cfg = cfg
         if cfg.is_hybrid:
-            from .hybrid import refuse_recurrent_state
+            from .hybrid import refuse_beyond_kv_rows
 
             if prefix_cache is not None and prefix_cache.enabled:
-                refuse_recurrent_state(cfg, "prefix sharing (PrefixIndex)")
+                refuse_beyond_kv_rows(cfg, "prefix sharing (PrefixIndex)")
             if resolve_kv_codec(kv_codec).quantized:
-                refuse_recurrent_state(
+                refuse_beyond_kv_rows(
                     cfg, f"the quantized KV tier kv_codec={kv_codec!r}")
             if not materialize:
-                refuse_recurrent_state(
+                refuse_beyond_kv_rows(
                     cfg, "a bookkeeping-only PagedKVCache (the split "
                          "runtime's allocator)")
         # KV-at-rest tier. Every page bookkeeping path below (alloc, COW,
@@ -798,7 +812,24 @@ class PagedKVCache:
         # mamba layer of a hybrid stack, managed with the slot (zeroed at
         # alloc, written at adopt, gathered at eviction, dead once freed)
         self.state: Optional[SlotState] = (
-            init_slot_state(cfg, max_slots) if cfg.is_hybrid else None)
+            init_slot_state(cfg, max_slots) if cfg.recurrent_state else None)
+        # the second page group: the sliding-window layers keep, for each
+        # slot, a RING of ``window_pages`` pages in a pool of their own —
+        # position p in entry (p // page_size) % window_pages — so they
+        # neither take nor free a page however long the stream grows. The
+        # rings are provisioned in full: slot s holds pages [1 + s * wp,
+        # 1 + (s + 1) * wp) of the window pool for as long as it is active
+        # (page 0 is that pool's trash page: a free slot's table row is 0).
+        self.window_pages = (cfg.window_pages(page_size)
+                             if cfg.window_layers else 0)
+        self.window_pool: Optional[PagePool] = None
+        self.window_table: Optional[np.ndarray] = None
+        if self.window_pages:
+            self.window_pool = init_pool(
+                cfg, max_slots * self.window_pages + 1, page_size, dtype,
+                layers=cfg.window_layers)
+            self.window_table = np.zeros((max_slots, self.window_pages),
+                                         np.int32)
         self.page_size = page_size
         self.num_pages = num_pages
         self.max_slots = max_slots
@@ -905,6 +936,8 @@ class PagedKVCache:
                     # a reused slot starts from zero, not from its last
                     # tenant's state
                     self.adopt_state(s, 0.0, 0.0)
+                if self.window_table is not None:
+                    self.window_table[s] = self._ring_of(s)
                 return s
         raise OutOfSlots(f"all {self.max_slots} slots active")
 
@@ -951,6 +984,8 @@ class PagedKVCache:
             self._release_ref(p)
         self._slot_pages[slot] = []
         self.page_table[slot] = 0
+        if self.window_table is not None:
+            self.window_table[slot] = 0  # stale ring rows stay masked
         self.lengths[slot] = 0
         self.active[slot] = False
 
@@ -1255,6 +1290,74 @@ class PagedKVCache:
         return (jnp.asarray(self.page_table),
                 jnp.asarray(self.lengths, jnp.int32))
 
+    def device_window_table(self) -> jnp.ndarray:
+        """(max_slots, window_pages) int32: each slot's ring in the window
+        pool, the third traced table of a step with sliding layers."""
+        return jnp.asarray(self.window_table)
+
+    # -- the window group's ring -------------------------------------------
+
+    def _ring_of(self, slot: int) -> np.ndarray:
+        """The window pool pages that are ``slot``'s ring, entry by entry."""
+        wp = self.window_pages
+        return 1 + slot * wp + np.arange(wp, dtype=np.int32)
+
+    def window_ring_start(self, length: int) -> int:
+        """The first position a ring still holds once ``length`` positions
+        are written: the start of the oldest of the ``window_pages`` pages
+        that end with the newest position's. (Its first rows may have left
+        the window already; the attend masks by position.)"""
+        return max(0, ((length - 1) // self.page_size - self.window_pages
+                       + 1) * self.page_size)
+
+    def _ring_indices(self, slot: int, start: int, stop: int) -> np.ndarray:
+        """Flat row indices, in the window pool, of positions [start, stop)
+        of ``slot``: position p at ring entry (p // ps) % window_pages."""
+        pos = np.arange(start, stop)
+        entry = (pos // self.page_size) % self.window_pages
+        return (self.window_table[slot, entry] * self.page_size
+                + pos % self.page_size).astype(np.int32)
+
+    @property
+    def window_rows_capacity(self) -> int:
+        """Rows the rings of all slots hold, a window layer."""
+        return self.max_slots * self.window_pages * self.page_size
+
+    @property
+    def window_rows_live(self) -> int:
+        """Ring rows inside some stream's window, a window layer."""
+        return int(np.minimum(self.lengths[self.active],
+                              self.cfg.sliding_window).sum())
+
+    def adopt_window(self, slot: int, wk_seq, wv_seq, length: int) -> None:
+        """Write the sliding layers' (L_window, n, KV, hd) post-rotary K/V of
+        positions ``[window_ring_start(length), length)`` — the tail of a
+        prefill, or an evicted stream's gathered ring — at their ring places.
+        Whole pages go a page a scatter slice, as :meth:`adopt`'s."""
+        start = self.window_ring_start(length)
+        if wk_seq.shape[1] != length - start:
+            raise ValueError(
+                f"adopt_window takes positions [{start}, {length}) of a "
+                f"{length}-position stream, got {wk_seq.shape[1]} rows")
+        dest = jnp.asarray(self._ring_indices(slot, start, length))
+        self.window_pool = _adopt_impl(self.window_pool, jnp.asarray(wk_seq),
+                                       jnp.asarray(wv_seq), dest, head=0)
+
+    def gather_window(self, slot: int) -> dict:
+        """``slot``'s ring as host arrays {"wk", "wv"}: (L_window, n, KV, hd)
+        rows of positions ``[window_ring_start(length), length)`` in position
+        order — what an eviction keeps beside :meth:`gather_slot`'s rows, and
+        what :meth:`adopt_window` takes back."""
+        if not self.window_pages:
+            return {}
+        n = int(self.lengths[slot])
+        start = self.window_ring_start(n)
+        idx = jnp.asarray(self._ring_indices(slot, start, max(n, 1)))
+        k, v = _gather_impl(self.window_pool, idx,
+                            kv=self.cfg.num_kv_heads)
+        return {"wk": np.asarray(k)[:, :n - start],
+                "wv": np.asarray(v)[:, :n - start]}
+
     # -- data movement -----------------------------------------------------
 
     def _require_pool(self, what: str) -> None:
@@ -1262,6 +1365,12 @@ class PagedKVCache:
             raise ValueError(f"{what} needs a materialized pool; this cache "
                              f"was built with materialize=False "
                              f"(bookkeeping-only)")
+
+    def _refuse_window(self, what: str) -> None:
+        if self.window_pages:
+            from .hybrid import refuse_window_ring
+
+            refuse_window_ring(self.cfg, what)
 
     def _flat_indices(self, slot: int, n: int) -> np.ndarray:
         pos = np.arange(n)
@@ -1478,6 +1587,7 @@ class PagedKVCache:
         (Per-slot checkpoints use :meth:`gather_slot` instead, which is
         geometry-independent.)"""
         self._require_pool("state_dict")
+        self._refuse_window("state_dict (the whole-cache snapshot)")
         # the K/V (code) leaves in the form callers hand rows over in,
         # (L, P, ps, KV, lanes) — what every earlier checkpoint holds; the
         # stored row merges the last two axes, a free reshape either way
@@ -1511,6 +1621,7 @@ class PagedKVCache:
         exclusive refcounts from the slot tables, so restore never
         double-frees or leaks a page either way."""
         self._require_pool("load_state_dict")
+        self._refuse_window("load_state_dict (the whole-cache snapshot)")
         ck = state.get("kv_codec", "fp")
         if ck != self.kv_codec:
             # REFUSAL, not transcode: silently requantizing (or inflating)
@@ -1579,8 +1690,31 @@ class PagedKVCache:
     def check_invariants(self) -> None:
         """Raise AssertionError on any aliasing/leak/ownership/refcount
         violation — the test suite calls this after every mutation."""
-        assert (self.state is not None) == self.cfg.is_hybrid, \
+        assert (self.state is not None) == self.cfg.recurrent_state, \
             "a state store exists exactly for a family with recurrent state"
+        assert bool(self.window_pages) == bool(self.cfg.window_layers), \
+            "a window group exists exactly for a stack with sliding layers"
+        if self.window_pages:
+            wp = self.window_pages
+            assert wp == self.cfg.window_pages(self.page_size)
+            assert wp * self.page_size >= \
+                self.cfg.sliding_window + self.page_size - 1, \
+                "a ring must hold a whole window wherever it starts in a page"
+            assert self.window_pool.k.shape[:3] == (
+                self.cfg.window_layers, self.max_slots * wp + 1,
+                self.page_size), \
+                f"window pool {self.window_pool.k.shape}: a ring of {wp} " \
+                f"pages a slot and the trash page, however long streams grow"
+            assert self.pool is None or \
+                self.pool.k.shape[0] == self.cfg.kv_layers, \
+                "the page pool holds the full attention layers only"
+            for s in range(self.max_slots):
+                want = self._ring_of(s) if self.active[s] else 0
+                assert (self.window_table[s] == want).all(), \
+                    f"slot {s}'s ring {self.window_table[s]} != {want}"
+            live = self.window_table[self.window_table > 0]
+            assert len(live) == len(set(live.tolist())), \
+                "a window page in two rings"
         if self.state is not None:
             from .hybrid import state_shapes
 
@@ -1690,7 +1824,7 @@ def _apply_rotary_rows(x: jnp.ndarray, cos_b: jnp.ndarray,
 
 
 @jax.named_scope("paged_kv.write")
-def write_rows(pool, layer, page_table, lengths, k, v):
+def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
     """The pool with a step's new K/V rows in layer ``layer``: pool leaves
     (L, P, ps, KV*lanes) WITH their layer axis, ``layer`` a traced or static
     index, k, v (B, 1, KV, hd) post-rotary, slot i's row at position
@@ -1702,7 +1836,11 @@ def write_rows(pool, layer, page_table, lengths, k, v):
     One scatter a leaf at the flat index ``layer*P*ps + page*ps + row`` over
     the leaf viewed (L*P*ps, KV*lanes): the carried pool is updated where it
     lies, no layer is sliced out of it and none is put back. The only code
-    that knows where in a pool a decode step's row goes."""
+    that knows where in a pool a decode step's row goes.
+
+    ``ring`` (static): the table is a window layer's RING of pages, and
+    position p lives in entry ``(p // ps) % entries``: the row written
+    overwrites one that has left the window."""
     tier = pool_tier(pool)
     if tier == "fp":
         stored = (k[:, 0], v[:, 0])
@@ -1714,9 +1852,12 @@ def write_rows(pool, layer, page_table, lengths, k, v):
     # slot i's new token lands in its (length // page_size)-th page at offset
     # length % page_size; inactive slots (all-zero table rows) land in the
     # trash page, where duplicate scatter indices are harmless garbage
-    dest = (layer * (pool.num_pages * ps)
-            + page_table[jnp.arange(k.shape[0]), lengths // ps] * ps
-            + lengths % ps)  # (B,)
+    base = layer * (pool.num_pages * ps)
+    slots = jnp.arange(k.shape[0])
+    entry = lengths // ps
+    if ring:
+        entry = entry % page_table.shape[1]
+    dest = base + page_table[slots, entry] * ps + lengths % ps  # (B,)
     return type(pool)(*(
         _rows(a, 1).at[dest].set(
             r.astype(a.dtype).reshape(-1, a.shape[-1])).reshape(a.shape)
@@ -1768,11 +1909,39 @@ def read_span(pool, layer, page_table, dtype):
                               (pool.v, pool.v_scale)))
 
 
-def attend_rows(q, k_rows, v_rows, lengths):
+def ring_positions(lengths, entries: int, page_size: int):
+    """The absolute position each row of a slot's ring holds, in table order:
+    lengths (B,) counts a slot's positions INCLUDING the newest, ``t =
+    lengths - 1``; ring entry j holds page ``q = T - ((T - j) mod entries)``
+    of the stream, ``T = t // page_size`` — the latest page number congruent
+    to j — and its row o position ``q * page_size + o``: (B, entries *
+    page_size) int32. Negative where the stream has not reached the entry
+    yet; past ``t`` in the newest page's rows that are not written yet (they
+    still hold the page ``entries`` back, which has left every window)."""
+    last_page = (lengths - 1) // page_size                       # (B,)
+    j = jnp.arange(entries, dtype=jnp.int32)
+    page = last_page[:, None] - (last_page[:, None] - j[None, :]) % entries
+    pos = page[:, :, None] * page_size + jnp.arange(page_size,
+                                                    dtype=jnp.int32)
+    return pos.reshape(lengths.shape[0], entries * page_size)
+
+
+def window_valid(pos, lengths, window: int):
+    """Which ring rows the newest position ``t = lengths - 1`` attends: the
+    ``window`` positions up to and including itself, by the ABSOLUTE position
+    a row holds (``t - window < pos <= t``, ``pos >= 0``), never by where in
+    the ring it lies."""
+    t = lengths[:, None] - 1
+    return (pos >= 0) & (pos <= t) & (pos > t - window)
+
+
+def attend_rows(q, k_rows, v_rows, lengths, valid=None):
     """Single-position GQA attention against rows as the pool stores them:
     q (B, 1, H, hd); k_rows, v_rows (B, span, KV*hd), head j of a row in
-    lanes [j*hd, (j+1)*hd); lengths (B,) valid positions a slot. Returns
-    (B, 1, H, hd) in q's dtype; softmax in fp32.
+    lanes [j*hd, (j+1)*hd); lengths (B,) valid positions a slot — or
+    ``valid`` (B, span) bool, the rows attended, where they are not a prefix
+    (a window layer's ring, :func:`window_valid`). Returns (B, 1, H, hd) in
+    q's dtype; softmax in fp32.
 
     The rows are read AS THEY LIE: splitting the (KV*hd) lanes into
     (KV, hd) for ``decode_attention``'s per-group einsum is a real
@@ -1791,8 +1960,9 @@ def attend_rows(q, k_rows, v_rows, lengths):
     scores = jnp.einsum("bhD,bcD->bhc", qz.reshape(b, h, kv * hd), k_rows,
                         preferred_element_type=jnp.float32)
     scores = scores * (1.0 / np.sqrt(hd))
-    # ragged: row i masks at its own lengths[i]
-    valid = jnp.arange(k_rows.shape[1])[None, :] < lengths[:, None]
+    if valid is None:
+        # ragged: row i masks at its own lengths[i]
+        valid = jnp.arange(k_rows.shape[1])[None, :] < lengths[:, None]
     scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhc,bcD->bhD", probs.astype(q.dtype), v_rows,
@@ -1801,7 +1971,8 @@ def attend_rows(q, k_rows, v_rows, lengths):
     return out.sum(axis=2).reshape(b, 1, h, hd)
 
 
-def paged_decode_attention(q, pool, layer, page_table, lengths):
+def paged_decode_attention(q, pool, layer, page_table, lengths,
+                           window: int = 0):
     """Ragged single-position attention against layer ``layer`` of a pool:
     q (B, 1, H, hd) per slot; page_table (B, pages_per_slot) int32 names each
     slot's pages in logical order (0 = the trash page for unallocated tails);
@@ -1810,7 +1981,10 @@ def paged_decode_attention(q, pool, layer, page_table, lengths):
 
     One XLA page gather (:func:`read_span`) and :func:`attend_rows` over
     its output as it lies: trash-page garbage lands only in masked positions,
-    where softmax of ``finfo.min`` contributes exactly 0."""
+    where softmax of ``finfo.min`` contributes exactly 0.
+
+    ``window`` (static, > 0): ``page_table`` is a window layer's ring
+    (:func:`write_rows`) and a row is attended by the position it holds."""
     s1, h, hd = q.shape[1:]
     if s1 != 1:
         raise ValueError(f"paged decode is q_len=1 only, got q_len={s1}")
@@ -1823,14 +1997,19 @@ def paged_decode_attention(q, pool, layer, page_table, lengths):
     if h % kv:
         raise ValueError(f"ragged GQA: H={h}, KV={kv}")
     kg, vg = read_span(pool, layer, page_table, q.dtype)
-    return attend_rows(q, kg, vg, lengths)
+    if not window:
+        return attend_rows(q, kg, vg, lengths)
+    return attend_rows(q, kg, vg, lengths, window_valid(
+        ring_positions(lengths, page_table.shape[1], pool.page_size),
+        lengths, window))
 
 
-@jax.named_scope("attn.decode")
-def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
-                            cos_b, sin_b, pool, layer, page_table, lengths,
-                            tp_axis: Optional[str] = None):
-    """The paged twin of ``transformer._attention_decode``: project the
+def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
+                  pool, layer, page_table, lengths,
+                  tp_axis: Optional[str] = None, window: int = 0):
+    """The paged twin of ``transformer._attention_decode`` (``window`` static,
+    > 0: a sliding layer over its ring; see the two scoped entries below):
+    project the
     (B, 1, D) hidden, rotate each slot at ITS position, write the new K/V row
     into each slot's current page, then ragged-attend against the slot's
     pages. ``pool`` is the WHOLE (L, num_pages, page_size, ...) pool, at
@@ -1852,14 +2031,35 @@ def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
     else:
         q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
         k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
-    pool = write_rows(pool, layer, page_table, lengths, k, v)
-    out = paged_decode_attention(q, pool, layer, page_table, lengths + 1)
+    pool = (write_rows(pool, layer, page_table, lengths, k, v, ring=True)
+            if window else write_rows(pool, layer, page_table, lengths, k, v))
+    out = paged_decode_attention(q, pool, layer, page_table, lengths + 1,
+                                 window)
     out = out.reshape(b, s1, h * hd) @ lp["wo"]
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
     if "bo" in lp:
         out = out + lp["bo"]
     return out, pool
+
+
+@jax.named_scope("attn.decode")
+def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
+                            cos_b, sin_b, pool, layer, page_table, lengths,
+                            tp_axis: Optional[str] = None):
+    """:func:`_decode_paged` for a layer whose pages hold every position."""
+    return _decode_paged(cfg, lp, x, cos_b, sin_b, pool, layer, page_table,
+                         lengths, tp_axis)
+
+
+@jax.named_scope("attn.window")
+def _attention_decode_window(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
+                             cos_b, sin_b, pool, layer, window_table,
+                             lengths):
+    """:func:`_decode_paged` for a sliding-window layer: ``pool`` and
+    ``window_table`` are the window group's, the table a ring."""
+    return _decode_paged(cfg, lp, x, cos_b, sin_b, pool, layer, window_table,
+                         lengths, window=cfg.sliding_window)
 
 
 def block_decode_paged(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,

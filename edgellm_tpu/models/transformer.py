@@ -26,6 +26,7 @@ Everything is jit-safe: no data-dependent Python control flow, static shapes.
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, NamedTuple, Optional
 
 import numpy as np
@@ -49,16 +50,57 @@ class AttnStats(NamedTuple):
     last_row: jnp.ndarray
 
 
-def precompute_rope(cfg: ModelConfig, seq_len: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """cos/sin tables, fp32, HF convention: emb = concat(freqs, freqs)."""
+def precompute_rope(cfg: ModelConfig, seq_len: int,
+                    scaled: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """cos/sin tables, fp32, HF convention: emb = concat(freqs, freqs).
+    ``scaled=False`` is the plain table whatever ``cfg.rope_scaling`` says:
+    what the window layers of a stack with two layer kinds rotate by."""
     rot = cfg.rotary_dim
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
-    if cfg.rope_scaling is not None:
-        inv_freq = _llama3_scale_freqs(inv_freq, cfg.rope_scaling)
+    factor = 1.0
+    if scaled and cfg.rope_scaling is not None:
+        if cfg.rope_scaling[0] == "yarn":
+            inv_freq, factor = _yarn_scale_freqs(inv_freq, cfg.rope_theta,
+                                                 cfg.rope_scaling)
+        else:
+            inv_freq = _llama3_scale_freqs(inv_freq, cfg.rope_scaling)
     pos = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(pos, inv_freq)  # (S, rot/2)
     emb = jnp.concatenate([freqs, freqs], axis=-1)  # (S, rot)
+    if factor != 1.0:
+        return jnp.cos(emb) * factor, jnp.sin(emb) * factor
     return jnp.cos(emb), jnp.sin(emb)
+
+
+def yarn_band(rot: int, theta: float, scaling: tuple) -> tuple[int, int]:
+    """(low, high): the rotary pairs between which YaRN ramps from kept to
+    interpolated frequencies. ``dim(r) = rot ln(orig / (2 pi r)) / (2 ln
+    theta)`` is the pair that turns ``r`` times over the original length;
+    ``low = floor(dim(beta_fast))``, ``high = ceil(dim(beta_slow))``, clipped
+    to the table (transformers' ``find_correction_range``, ``truncate``)."""
+    _, _, orig, beta_fast, beta_slow, _ = scaling
+
+    def dim(r):
+        return rot * math.log(orig / (r * 2 * math.pi)) / (2 * math.log(theta))
+
+    return (max(math.floor(dim(beta_fast)), 0),
+            min(math.ceil(dim(beta_slow)), rot - 1))
+
+
+def _yarn_scale_freqs(inv_freq: jnp.ndarray, theta: float, scaling: tuple):
+    """YaRN (transformers' ``_compute_yarn_parameters``): pair ``d`` keeps its
+    frequency below ``low``, takes ``1/factor`` of it above ``high`` and a
+    linear blend between. ``scaling`` = ("yarn", factor, original_max_position
+    _embeddings, beta_fast, beta_slow, attention_factor). Returns (scaled
+    inv_freq, attention_factor): cos and sin are both multiplied by it."""
+    _, factor, _, _, _, attention_factor = scaling
+    low, high = yarn_band(2 * inv_freq.shape[0], theta, scaling)
+    if low == high:
+        high += 0.001  # transformers: no singularity
+    ramp = jnp.clip((jnp.arange(inv_freq.shape[0], dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return (inv_freq / factor * ramp + inv_freq * (1.0 - ramp),
+            float(attention_factor))
 
 
 def _llama3_scale_freqs(inv_freq: jnp.ndarray, scaling: tuple) -> jnp.ndarray:
@@ -401,10 +443,10 @@ def forward(cfg: ModelConfig, params: dict, input_ids: jnp.ndarray, *,
     """
     params = _cast_params(params, compute_dtype)
     if cfg.is_hybrid:
-        from .hybrid import forward_hybrid, refuse_recurrent_state
+        from .hybrid import forward_hybrid, refuse_beyond_kv_rows
 
         if boundary_fn is not None or capture_stats or collect_hidden:
-            refuse_recurrent_state(
+            refuse_beyond_kv_rows(
                 cfg, "forward() with a boundary hook, attention statistics "
                      "or collected hiddens (the sweep drivers)")
         return forward_hybrid(cfg, params, input_ids), {}
@@ -509,10 +551,10 @@ def prefill(cfg: ModelConfig, params: dict, input_ids: jnp.ndarray,
         raise ValueError(f"prompt length {s} must be in [1, capacity={capacity}]")
     params = _cast_params(params, compute_dtype)
     if cfg.is_hybrid:
-        from .hybrid import prefill_hybrid, refuse_recurrent_state
+        from .hybrid import prefill_hybrid, refuse_beyond_kv_rows
 
         if boundary_fn is not None:
-            refuse_recurrent_state(cfg, "prefill() with a boundary hook")
+            refuse_beyond_kv_rows(cfg, "prefill() with a boundary hook")
         return prefill_hybrid(cfg, params, input_ids, capacity)
     hidden = embed(params, input_ids)
     hidden, aux = run_layers(cfg, params, hidden, boundary_fn=boundary_fn,
@@ -651,10 +693,10 @@ def decode_step(cfg: ModelConfig, params: dict, cache: KVCache,
     """
     params = _cast_params(params, compute_dtype)
     if cfg.is_hybrid:
-        from .hybrid import decode_step_hybrid, refuse_recurrent_state
+        from .hybrid import decode_step_hybrid, refuse_beyond_kv_rows
 
         if boundary_fn is not None:
-            refuse_recurrent_state(cfg, "decode_step() with a boundary hook")
+            refuse_beyond_kv_rows(cfg, "decode_step() with a boundary hook")
         return decode_step_hybrid(cfg, params, cache, token_ids)
     if token_ids.ndim == 1:
         token_ids = token_ids[:, None]

@@ -189,6 +189,8 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "paged_kv.write",     # the per-layer K/V row scatter of the ragged step
     "paged_kv.adopt",     # a prefilled or resumed prefix scattered into pages
     "attn.decode",        # the paged decode attention of one layer
+    "attn.window",        # ... of one sliding-window layer: its projections,
+                          # the ring's row write, page gather and attend
     "mlp",
     "unembed_sample",     # final norm + LM head + the per-slot sampler
     "split.stage",        # one stage iteration of the split unroll

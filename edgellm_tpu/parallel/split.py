@@ -607,9 +607,9 @@ class SplitRuntime:
                  fec: Optional[Any] = None,
                  hedge: Optional[Any] = None,
                  pipeline: Optional[PipelineConfig] = None):
-        from ..models.hybrid import refuse_recurrent_state
+        from ..models.hybrid import refuse_beyond_kv_rows
 
-        refuse_recurrent_state(cfg, "the split runtime (SplitRuntime)")
+        refuse_beyond_kv_rows(cfg, "the split runtime (SplitRuntime)")
         self.cfg = cfg
         self.split = split
         self.mesh = mesh

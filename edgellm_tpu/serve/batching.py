@@ -53,6 +53,14 @@ module schedules many streams through ONE jitted decode step built on
   step crosses the boundary once per cut through the quantized hop ladder —
   batched serving over a split plan, no longer local-pool-only.
 
+- a stack walked by layer kinds (``models/hybrid.py``) has a step
+  executable of its own: ``_batched_hybrid_step_jit`` with the per-slot
+  recurrent state of a ``granitemoehybrid`` stack, ``_batched_window_step_jit``
+  with the second page group of a ``mellum`` stack (each sliding-window
+  layer's ring of pages, ``PagedKVCache.window_pool`` / ``window_table``):
+  admission adopts a prompt's tail into the rings, eviction gathers them with
+  the full layers' rows, and what cannot carry them refuses by name.
+
 ``ServeFront`` integration lives in ``serve/frontend.py`` (``batcher=``):
 admission control, brownout and breakers all apply before a request reaches
 the batcher — this module is only the inner scheduler.
@@ -72,7 +80,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.configs import ModelConfig
-from ..models.hybrid import paged_decode_step_hybrid, refuse_recurrent_state
+from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
 from ..models.paged_kv import OutOfPages, OutOfSlots, PagedKVCache, \
     PrefixCacheConfig, SlotState, paged_decode_step, resolve_kv_codec
 from ..models.transformer import KVCache
@@ -250,11 +258,36 @@ def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
             pool_k, pool_v, conv, ssm, expert_tokens)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "compute_dtype"),
+                   donate_argnums=(2, 3, 4))
+def _batched_window_step_jit(cfg: ModelConfig, params: dict, pool, window_pool,
+                             expert_tokens, page_table, window_table, lengths,
+                             token_ids, key_data, steps, temps,
+                             compute_dtype):
+    """The ragged step of a stack with sliding-window layers beside full ones
+    (``models/hybrid.py``'s walk, no recurrent state): the full layers' page
+    pool, the window layers' pool of rings (each a PagePool, addressed through
+    its own table) and the per-expert assignment counter are donated and come
+    back updated. A SEPARATE jit: the other families keep their executables."""
+    if compute_dtype is not None:
+        params = jax.tree_util.tree_map(
+            lambda a: a.astype(compute_dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+    logits, k, v, _, _, expert_tokens, (wk, wv) = paged_decode_step_hybrid(
+        cfg, params, pool.k, pool.v, None, None, expert_tokens, page_table,
+        lengths, token_ids, window=(window_pool.k, window_pool.v,
+                                    window_table))
+    return (_batched_sample(logits, key_data, steps, temps),
+            type(pool)(k, v), type(window_pool)(wk, wv), expert_tokens)
+
+
 def batched_step_cache_size() -> int:
     """Executables compiled for the ragged step so far in this process — the
     jit-miss counter :meth:`ContinuousBatcher.step` reports deltas of."""
     return (_batched_step_jit._cache_size()
-            + _batched_hybrid_step_jit._cache_size())
+            + _batched_hybrid_step_jit._cache_size()
+            + _batched_window_step_jit._cache_size())
 
 
 # the split step returns (max_slots, V) logits from decode_step_paged; the
@@ -292,11 +325,12 @@ class ContinuousBatcher:
         self.rt = split_runtime
         if cfg.is_hybrid:
             # refused here, at construction, and by name: each would need a
-            # snapshot of the recurrent state that it does not take
+            # snapshot of the recurrent state, or of a window layer's ring,
+            # that it does not take
             if split_runtime is not None:
-                refuse_recurrent_state(cfg, "the split runtime (SplitRuntime)")
+                refuse_beyond_kv_rows(cfg, "the split runtime (SplitRuntime)")
             if self.bcfg.checkpoint_dir is not None:
-                refuse_recurrent_state(
+                refuse_beyond_kv_rows(
                     cfg, "checkpoint_dir (checkpoint_stream/restore_stream)")
         if split_runtime is not None:
             if placed_params is None:
@@ -558,6 +592,9 @@ class ContinuousBatcher:
                 if "ssm" in st.resume:
                     self.pool.adopt_state(slot, st.resume["conv"],
                                           st.resume["ssm"])
+                if "wk" in st.resume:
+                    self.pool.adopt_window(slot, st.resume["wk"],
+                                           st.resume["wv"], need_len)
             st.resume = None
             if st.resume_prefix and self.pool.prefix is not None:
                 # migration adopts opt in to re-publishing: the payload's
@@ -606,10 +643,15 @@ class ContinuousBatcher:
                                st.temperature)
             with obs_phase("batch.admit.adopt", sid=sid):
                 self.pool.adopt(slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s)
-                if self.cfg.is_hybrid:
+                if self.cfg.recurrent_state:
                     # the other kind of state a prefill hands on
                     self.pool.adopt_state(slot, cache.conv[:, 0],
                                           cache.ssm[:, 0])
+                if self.cfg.window_layers:
+                    # the sliding layers take the tail their rings hold
+                    r0 = self.pool.window_ring_start(s)
+                    self.pool.adopt_window(slot, cache.wk[:, 0, r0:s],
+                                           cache.wv[:, 0, r0:s], s)
         if self.pool.prefix is not None:
             # publish this prompt's pages (full blocks + partial tail) so
             # later admits share them; already-indexed blocks just refresh
@@ -695,10 +737,12 @@ class ContinuousBatcher:
         quant = self.bcfg.kv_codec != "fp"
         if self.rt is None:
             # a hybrid stack's payload also carries the slot's recurrent
-            # state ({"conv", "ssm"}); other families add nothing
+            # state ({"conv", "ssm"}) or its sliding layers' rings ({"wk",
+            # "wv"}); other families add nothing
             return {**(self.pool.gather_slot_packed(slot) if quant
                        else self.pool.gather_slot(slot)),
-                    **self.pool.gather_state(slot)}
+                    **self.pool.gather_state(slot),
+                    **self.pool.gather_window(slot)}
         n = int(self.pool.lengths[slot])
         idx = self.pool._flat_indices(slot, max(n, 1))
         if quant:
@@ -748,7 +792,7 @@ class ContinuousBatcher:
         cannot admit right now. A ``max_new_tokens == 1`` stream finishes
         at admission (token 0 is the whole answer) and comes back already
         ``finished`` with no held slot."""
-        refuse_recurrent_state(
+        refuse_beyond_kv_rows(
             self.cfg, "disaggregated prefill (prefill_hold / page migration)")
         st = self._streams[sid]
         if st.status != "waiting":
@@ -962,6 +1006,15 @@ class ContinuousBatcher:
                 toks = _split_sample_jit(
                     logits, jnp.asarray(key_data), jnp.asarray(steps),
                     jnp.asarray(temps))
+            elif self.cfg.window_layers:
+                (toks, self.pool.pool, self.pool.window_pool,
+                 self._expert_tokens) = _batched_window_step_jit(
+                    self.cfg, self.params, self.pool.pool,
+                    self.pool.window_pool, self._expert_tokens, page_table,
+                    self.pool.device_window_table(), lengths,
+                    jnp.asarray(token_ids), jnp.asarray(key_data),
+                    jnp.asarray(steps), jnp.asarray(temps),
+                    self.bcfg.compute_dtype)
             elif self.cfg.is_hybrid:
                 state = self.pool.state
                 toks, k, v, conv, ssm, self._expert_tokens = (
@@ -1051,7 +1104,7 @@ class ContinuousBatcher:
         resume payload — as a :class:`DecodeCheckpoint`, restorable into ANY
         pool geometry whose span covers it (the payload is the contiguous
         prefix, not pages)."""
-        refuse_recurrent_state(self.cfg, "checkpoint_stream")
+        refuse_beyond_kv_rows(self.cfg, "checkpoint_stream")
         st = self._streams[sid]
         if st.status == "running":
             state = self._gather_state(st.slot)
@@ -1106,7 +1159,7 @@ class ContinuousBatcher:
         """Re-queue a checkpointed stream; its remaining tokens come out
         bit-identical to the uninterrupted run (per-step keys depend only on
         the seed and the step index, the KV prefix is restored bit-exactly)."""
-        refuse_recurrent_state(self.cfg, "restore_stream")
+        refuse_beyond_kv_rows(self.cfg, "restore_stream")
         ckpt = DecodeCheckpoint.load(path)
         meta = ckpt.meta
         if meta.get("mode") != self._ckpt_mode():
@@ -1215,6 +1268,10 @@ class ContinuousBatcher:
             pass
         tokens = self._expert_tokens_host
         return {"state_bytes": self.pool.state_bytes,
+                # the sliding layers' rings, rows a window layer: those
+                # inside some stream's window now, and all the rings hold
+                "window_rows_live": self.pool.window_rows_live,
+                "window_rows_capacity": self.pool.window_rows_capacity,
                 "expert_tokens": tokens.tolist(),
                 "routed_assignments": int(stats["routed_assignments"]),
                 "routed_local": int(tokens.sum())}

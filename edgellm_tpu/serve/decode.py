@@ -218,9 +218,9 @@ def generate(cfg: ModelConfig, params: dict, prompt_ids: ArrayLike,
         prompt_ids, max_new_tokens, capacity, temperature, rng_key)
     b, s = prompt_ids.shape
     if recovery is not None:
-        from ..models.hybrid import refuse_recurrent_state
+        from ..models.hybrid import refuse_beyond_kv_rows
 
-        refuse_recurrent_state(cfg, "generate(recovery=...) (checkpoints, "
+        refuse_beyond_kv_rows(cfg, "generate(recovery=...) (checkpoints, "
                                     "the survivable loop)")
         rt = LocalRuntime(cfg, compute_dtype)
         return _survivable_loop(rt, params, prompt_ids, max_new_tokens,

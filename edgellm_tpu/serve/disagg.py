@@ -435,9 +435,9 @@ class DisaggServer:
                  dcfg: DisaggConfig = DisaggConfig(), *,
                  split_runtime=None, placed_params=None,
                  clock: Clock = MONOTONIC):
-        from ..models.hybrid import refuse_recurrent_state
+        from ..models.hybrid import refuse_beyond_kv_rows
 
-        refuse_recurrent_state(cfg, "disaggregated prefill (DisaggServer's "
+        refuse_beyond_kv_rows(cfg, "disaggregated prefill (DisaggServer's "
                                     "page migration)")
         self.cfg, self.params = cfg, params
         self.bcfg, self.dcfg = bcfg, dcfg

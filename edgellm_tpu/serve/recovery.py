@@ -409,9 +409,9 @@ class LocalRuntime:
     split runtime."""
 
     def __init__(self, cfg, compute_dtype=None):
-        from ..models.hybrid import refuse_recurrent_state
+        from ..models.hybrid import refuse_beyond_kv_rows
 
-        refuse_recurrent_state(cfg, "the recovery runtime (LocalRuntime: "
+        refuse_beyond_kv_rows(cfg, "the recovery runtime (LocalRuntime: "
                                     "DecodeCheckpoint, failover)")
         self.cfg = cfg
         self.compute_dtype = compute_dtype

@@ -127,9 +127,9 @@ def draft_from_params(cfg: ModelConfig, raw_params: dict, spec: SpecConfig,
     ``draft_layers`` so the draft never needs weights stage 0 doesn't hold.
     Returns (draft_cfg, draft_params) for ``transformer.prefill``/
     ``decode_step``."""
-    from ..models.hybrid import refuse_recurrent_state
+    from ..models.hybrid import refuse_beyond_kv_rows
 
-    refuse_recurrent_state(cfg, "speculative decoding (the draft and the "
+    refuse_beyond_kv_rows(cfg, "speculative decoding (the draft and the "
                                 "verify rollback)")
     limit = (cut + 1) if cut is not None else cfg.num_layers
     n = spec.draft_layers if spec.draft_layers is not None else limit

@@ -177,3 +177,70 @@ def test_staged_adopt_scatters_in_place_over_four_chips(topo):
     assert not re.search(r"all-gather|all-reduce|collective-permute|"
                          r"all-to-all", hlo)
     assert adopt.memory_analysis().temp_size_in_bytes < 16e6
+
+
+# benchmark/configs/mellum2-12b-a2.5b-pp4.json: the widths and the serving
+# geometry of the cell (two periods of three sliding layers and a full one)
+MELLUM = ModelConfig(
+    family="mellum", vocab_size=98304, hidden_size=2304, num_layers=8,
+    num_heads=32, num_kv_heads=4, intermediate_size=7168,
+    max_position_embeddings=131072, norm_eps=1e-6, rope_theta=500000.0,
+    rope_scaling=("yarn", 16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+    layer_types=(("sliding_attention",) * 3 + ("attention",)) * 2,
+    explicit_head_dim=128, sliding_window=1024, num_experts=64,
+    experts_per_tok=8, expert_width=896)
+M_SLOTS, M_PAGES_PER_SLOT = 96, 384
+
+
+def test_window_layers_gather_their_rings_and_both_pools_stay_in_place(topo):
+    """The step of a stack with sliding layers: a window layer's two gathers
+    take each slot's RING (65 pages), never its span (384); a full layer's
+    take the span; neither pool is copied, relaid or stacked, and the
+    gathered copies are the step's temporaries (0.78 GB beside 11.2 GB of
+    weights and pools)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(MELLUM, jax.random.key(0), dtype=jnp.bfloat16)),
+        one)
+    ring = MELLUM.window_pages(PAGE)
+    assert ring == 65
+    full = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        MELLUM, M_SLOTS * M_PAGES_PER_SLOT + 1, PAGE, jnp.bfloat16)), one)
+    window = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        MELLUM, M_SLOTS * ring + 1, PAGE, jnp.bfloat16,
+        layers=MELLUM.window_layers)), one)
+    width = MELLUM.num_kv_heads * MELLUM.head_dim
+    assert full.k.shape == (2, 36865, PAGE, width)
+    assert window.k.shape == (6, 6241, PAGE, width)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((M_SLOTS,), jnp.int32)
+    step = batching._batched_window_step_jit.lower(
+        MELLUM, params, full, window, arr((8, 64), jnp.int32),
+        arr((M_SLOTS, M_PAGES_PER_SLOT), jnp.int32),
+        arr((M_SLOTS, ring), jnp.int32), ints, ints,
+        arr((M_SLOTS, 2), jnp.uint32), ints, arr((M_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    gathered = M_SLOTS * ring * PAGE * width        # a window layer's read
+    gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+               if op == "gather" and _elements(shape) >= gathered]
+    span = f"bf16[{M_SLOTS},{M_PAGES_PER_SLOT},{PAGE},{width}]"
+    rings = f"bf16[{M_SLOTS},{ring},{PAGE},{width}]"
+    # K and V of 2 full layers, K and V of 6 window layers: static walk
+    assert sorted(gathers) == [span] * 4 + [rings] * 12, gathers
+    own = {span, rings,
+           f"bf16[{M_SLOTS * M_PAGES_PER_SLOT},{PAGE},{width}]",
+           f"bf16[{M_SLOTS * ring},{PAGE},{width}]"}
+    moved = [m for m in _moved(hlo, gathered)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    assert not moved, moved
+    # each pool is addressed flat: pages at (layer, page), rows at (l, p, r)
+    assert f"bf16[{2 * 36865},{PAGE},{width}]" in hlo
+    assert f"bf16[{6 * 6241},{PAGE},{width}]" in hlo
+    assert f"bf16[{6 * 6241 * PAGE},{width}]" in hlo
+    mem = step.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9

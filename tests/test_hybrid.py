@@ -288,30 +288,57 @@ def test_batcher_tokens_equal_generate(params):
 
 # -- the share ----------------------------------------------------------------
 
-def test_the_two_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
-    """Expert parallelism's arithmetic: each chip routes over all 8, computes
-    its own 4 for the tokens routed to them plus the shared expert; the two
-    routed parts and the shared expert counted once are the whole layer, as
-    the uncut reference computes it."""
+def _granite_shares():
     whole = tiny_hybrid_config()
+    return (whole, 4, lambda off: tiny_hybrid_config(experts_held=4,
+                                                     expert_offset=off),
+            lambda mp, u: ref._moe(dict(ref.model_key(ref_config(whole))),
+                                   mp, u, False))
+
+
+def _mellum_shares():
+    """The mellum family's layer: 64 experts top-8, 16 held a chip, none
+    shared, at toy widths."""
+    from benchmark import reference_mellum
+    from edgellm_tpu.models.configs import tiny_mellum_config
+
+    def cfg(**kw):
+        return tiny_mellum_config(num_experts=64, experts_per_tok=8, **kw)
+
+    return (cfg(), 16, lambda off: cfg(experts_held=16, expert_offset=off),
+            lambda mp, u: reference_mellum._moe(
+                {"top_k": 8, "offset": 0, "held": 64}, mp, u, False))
+
+
+@pytest.mark.parametrize("family", [_granite_shares, _mellum_shares],
+                         ids=["2x4-of-8-and-a-shared-expert",
+                              "4x16-of-64-none-shared"])
+def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        family):
+    """Expert parallelism's arithmetic: each chip routes over all the
+    experts, computes its own for the tokens routed to them plus the shared
+    expert where the family has one; the routed parts and the shared expert
+    counted once are the whole layer, as the uncut reference computes it."""
+    whole, held_n, share_cfg, want_fn = family()
     mp = make_params(whole)["moe"][1]
     u = jax.random.normal(jax.random.key(5), (37, whole.hidden_size))
-    shared = (jax.nn.silu(u @ mp["shared_gate"]) * (u @ mp["shared_up"])) \
-        @ mp["shared_down"]
+    shared = 0.0
+    if whole.shared_width:
+        shared = (jax.nn.silu(u @ mp["shared_gate"])
+                  * (u @ mp["shared_up"])) @ mp["shared_down"]
     parts, counts = [], []
     with jax.default_matmul_precision("highest"):
-        for off in (0, 4):
-            cfg = tiny_hybrid_config(experts_held=4, expert_offset=off)
-            held = {**mp, **{k: mp[k][off:off + 4]
+        for off in range(0, whole.num_experts, held_n):
+            held = {**mp, **{k: mp[k][off:off + held_n]
                              for k in ("w_gate", "w_up", "w_down")}}
-            out, cnt = moe.moe_layer(cfg, held, u)
+            out, cnt = moe.moe_layer(share_cfg(off), held, u)
             parts.append(out - shared)
             counts.append(cnt)
-        want = ref._moe(dict(ref.model_key(ref_config(whole))), mp, u, False)
-    assert rel_err(parts[0] + parts[1] + shared, np.asarray(want)) < TOL
-    # no token dropped: every one of the 37 x 3 assignments landed somewhere
-    assert int(counts[0].sum() + counts[1].sum()) == 37 * 3
-    assert float(jnp.abs(parts[0]).max()) > 0 < float(jnp.abs(parts[1]).max())
+        want = want_fn(mp, u)
+    assert rel_err(sum(parts) + shared, np.asarray(want)) < TOL
+    # no token dropped: every one of the assignments landed somewhere
+    assert int(sum(c.sum() for c in counts)) == 37 * whole.experts_per_tok
+    assert all(float(jnp.abs(p).max()) > 0 for p in parts)
 
 
 @pytest.mark.parametrize("tokens", [7, moe.DENSE_MAX_TOKENS + 44])
