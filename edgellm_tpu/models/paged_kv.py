@@ -2006,7 +2006,8 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
 
 def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
                   pool, layer, page_table, lengths,
-                  tp_axis: Optional[str] = None, window: int = 0):
+                  tp_axis: Optional[str] = None, window: int = 0,
+                  write_table=None):
     """The paged twin of ``transformer._attention_decode`` (``window`` static,
     > 0: a sliding layer over its ring; see the two scoped entries below):
     project the
@@ -2015,7 +2016,13 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
     pages. ``pool`` is the WHOLE (L, num_pages, page_size, ...) pool, at
     whichever tier, and ``layer`` the index this layer's rows and pages are
     addressed under; on a quantized tier the current token attends its OWN
-    quantized K/V, consistent with what every later step will read."""
+    quantized K/V, consistent with what every later step will read.
+
+    ``write_table`` (None: ``page_table``): the table the new row is WRITTEN
+    through, where a caller must keep some slots' rows out of their pages (the
+    split runtime's dead unroll iterations and padding layers: entries 0, the
+    trash page) while the read still gathers the real ones. A Python-level
+    default: a caller that does not pass it traces what it always traced."""
     b, s1, d = x.shape
     hd = cfg.head_dim
     h, kv = lp["wq"].shape[-1] // hd, lp["wk"].shape[-1] // hd
@@ -2031,8 +2038,9 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
     else:
         q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
         k = _apply_rotary_rows(k, cos_b, sin_b, cfg.rotary_dim)
-    pool = (write_rows(pool, layer, page_table, lengths, k, v, ring=True)
-            if window else write_rows(pool, layer, page_table, lengths, k, v))
+    write_table = page_table if write_table is None else write_table
+    pool = (write_rows(pool, layer, write_table, lengths, k, v, ring=True)
+            if window else write_rows(pool, layer, write_table, lengths, k, v))
     out = paged_decode_attention(q, pool, layer, page_table, lengths + 1,
                                  window)
     out = out.reshape(b, s1, h * hd) @ lp["wo"]
@@ -2046,10 +2054,10 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
 @jax.named_scope("attn.decode")
 def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                             cos_b, sin_b, pool, layer, page_table, lengths,
-                            tp_axis: Optional[str] = None):
+                            tp_axis: Optional[str] = None, write_table=None):
     """:func:`_decode_paged` for a layer whose pages hold every position."""
     return _decode_paged(cfg, lp, x, cos_b, sin_b, pool, layer, page_table,
-                         lengths, tp_axis)
+                         lengths, tp_axis, write_table=write_table)
 
 
 @jax.named_scope("attn.window")
@@ -2064,23 +2072,23 @@ def _attention_decode_window(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
 
 def block_decode_paged(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,
                        cos_b, sin_b, pool, layer, page_table, lengths,
-                       tp_axis: Optional[str] = None):
+                       tp_axis: Optional[str] = None, write_table=None):
     """The paged twin of ``transformer.block_decode`` for one layer:
     same norm/residual/MLP structure, paged attention core over layer
-    ``layer`` of the whole pool."""
+    ``layer`` of the whole pool (``write_table``: :func:`_decode_paged`)."""
     if cfg.family == "gpt_neox":
         attn_in = _layernorm(hidden, lp["ln1_scale"], lp["ln1_bias"],
                              cfg.norm_eps)
         attn_out, pool = _attention_decode_paged(
             cfg, lp, attn_in, cos_b, sin_b, pool, layer, page_table, lengths,
-            tp_axis)
+            tp_axis, write_table)
         mlp_in = _layernorm(hidden, lp["ln2_scale"], lp["ln2_bias"],
                             cfg.norm_eps)
         return hidden + attn_out + mlp(cfg, lp, mlp_in, tp_axis), pool
     attn_in = _rmsnorm(hidden, lp["ln1_scale"], cfg.norm_eps)
     attn_out, pool = _attention_decode_paged(
         cfg, lp, attn_in, cos_b, sin_b, pool, layer, page_table, lengths,
-        tp_axis)
+        tp_axis, write_table)
     hidden = hidden + attn_out
     mlp_in = _rmsnorm(hidden, lp["ln2_scale"], cfg.norm_eps)
     return hidden + mlp(cfg, lp, mlp_in, tp_axis), pool

@@ -182,26 +182,40 @@ def run_pipeline_stages(n_stages: int, codecs: list, run_stage, hidden,
     return out, counters
 
 
+def keep_carry(keep, new, old):
+    """``new`` where ``keep`` else ``old``, leaf by leaf: how a stage body
+    whose carry has nowhere to send an unwanted write (a contiguous KV cache)
+    drops the update of a dead unroll iteration — one select of the whole
+    carry. A paged pool has the trash page instead and never comes here."""
+    return jax.tree_util.tree_map(
+        lambda n, o: jnp.where(keep, n, o), new, old)
+
+
 def run_pipeline_stages_carry(n_stages: int, codecs: list, run_stage, hidden,
                               carry, axis_name: str = "stage",
                               link=None, fault_key=None, fused_plans=None):
     """:func:`run_pipeline_stages` for stage bodies that thread stage-local
-    state (the decode KV cache): ``run_stage(hidden, carry) -> (hidden,
-    carry)``. Each device keeps the carry produced at ITS unroll step — the
-    step where the hidden it transformed was the real pipeline activation —
-    so per-stage caches update exactly once per token, and nothing but the
-    (B, 1, D) boundary activation ever crosses a cut. Returns
-    (final hidden, carry), plus the psum-replicated fault counters when
-    ``link`` is given (see :func:`run_pipeline_stages`)."""
+    state (the decode KV cache, the page pool): ``run_stage(hidden, carry,
+    keep) -> (hidden, carry)``. Every device runs the body in each of the
+    ``n_stages`` unroll iterations; ``keep`` (traced, per device) is True in
+    the ONE iteration where the hidden it transforms is the real pipeline
+    activation. The stage body owns its carry, as under
+    :func:`run_pipeline_stages_carry_microbatched`: what it returns is taken
+    as it is, so it must leave the carry's live contents alone when ``keep``
+    is False — a contiguous cache by selecting its update away
+    (:func:`keep_carry`), a paged pool by sending the dead iteration's row
+    write to the trash page, which costs no pass over the pool. Only the
+    small hidden state is selected here. Per-stage state so updates exactly
+    once per token, and nothing but the (B, 1, D) boundary activation ever
+    crosses a cut. Returns (final hidden, carry), plus the psum-replicated
+    fault counters when ``link`` is given (see :func:`run_pipeline_stages`)."""
     idx = jax.lax.axis_index(axis_name)
     counters = link.init_counters(n_stages - 1) if link is not None else None
     for s in range(n_stages):
-        with jax.named_scope("split.stage"):
-            computed, new_carry = run_stage(hidden, carry)
         keep = idx == s
+        with jax.named_scope("split.stage"):
+            computed, carry = run_stage(hidden, carry, keep)
         hidden = jnp.where(keep, computed, hidden)
-        carry = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(keep, new, old), new_carry, carry)
         if s == n_stages - 1:
             break
         with jax.named_scope(f"split.hop.{s}"):
@@ -1124,11 +1138,12 @@ class SplitRuntime:
                                          capture_stats=False, return_kv=True)
                 return jnp.where(ok, out, h), (kl, vl)
 
-            def run_stage(h, cache):
+            def run_stage(h, cache, keep):
                 computed, (ks, vs) = jax.lax.scan(scan_body, h, (lv, valid))
                 kc, vc = cache  # (sz, B, capacity, KV, hd)
-                return computed, (kc.at[:, :, :s].set(ks),
-                                  vc.at[:, :, :s].set(vs))
+                return computed, keep_carry(
+                    keep, (kc.at[:, :, :s].set(ks), vc.at[:, :, :s].set(vs)),
+                    cache)
 
             fkey = None if link is None else jax.random.fold_in(
                 jax.random.fold_in(jax.random.key(link.faults.seed), 0x9EF1),
@@ -1153,11 +1168,11 @@ class SplitRuntime:
                 return jnp.where(ok, out, h), (jnp.where(ok, kl2, kl),
                                                jnp.where(ok, vl2, vl))
 
-            def run_stage(h, cache):
-                kc, vc = cache
-                h2, (kc2, vc2) = jax.lax.scan(scan_body, h,
-                                              (lv, valid, kc, vc))
-                return h2, (kc2, vc2)
+            def run_stage(h, cache, keep):
+                # a contiguous cache has no trash page: a dead iteration's
+                # update is selected away, whole
+                h2, written = jax.lax.scan(scan_body, h, (lv, valid, *cache))
+                return h2, keep_carry(keep, written, cache)
 
             # the cache fill level is the fault step: distinct per emitted
             # token, identical across same-seed runs, no extra traced arg
@@ -1388,11 +1403,11 @@ class SplitRuntime:
                 return jnp.where(ok, out, h), (jnp.where(ok, kl2, kl),
                                                jnp.where(ok, vl2, vl))
 
-            def run_stage(h, cache):
-                kc, vc = cache
-                h2, (kc2, vc2) = jax.lax.scan(scan_body, h,
-                                              (lv, valid, kc, vc))
-                return h2, (kc2, vc2)
+            def run_stage(h, cache, keep):
+                # a contiguous cache has no trash page: a dead iteration's
+                # update is selected away, whole
+                h2, written = jax.lax.scan(scan_body, h, (lv, valid, *cache))
+                return h2, keep_carry(keep, written, cache)
 
             # the cache fill level keys the fault step, exactly like the
             # single-token step: distinct per burst, identical across
@@ -1607,11 +1622,15 @@ class SplitRuntime:
         geometry and tier. Page table and lengths are TRACED — one executable
         per (num_pages, page_size, max_slots, pages_per_slot) shape serves
         every admit/evict/fill state (the jit-miss-free property batching
-        relies on). The pool crosses ``shard_map`` and the stage scan as one
-        pytree, so a quantized tier's codes and scales ride every hop's
-        carry beside each other. Quantized tiers are unpipelined only — the
-        µ-batch trash-page routing has not been run on them
-        (ContinuousBatcher refuses the combination up front)."""
+        relies on). The pool crosses ``shard_map`` as one pytree and is the
+        stage scan's CARRY, so a quantized tier's codes and scales ride
+        beside each other and every leaf is updated where it lies: both
+        schedules run ONE stage body (``layer_scan``), which sends a write
+        that must not happen — a dead unroll iteration's, a fill / drain
+        step's, a padding layer's — to the trash page instead of selecting
+        the pool. Quantized tiers are unpipelined only — the µ-batch
+        schedule has not been run on them (ContinuousBatcher refuses the
+        combination up front)."""
         key = ("paged", num_pages, page_size, kv_codec)
         if key in self._paged_fns_cache:
             return self._paged_fns_cache[key]
@@ -1647,33 +1666,57 @@ class SplitRuntime:
                 n_stages, codecs, n_micro, run_stage, hidden, carry,
                 link=link, fault_key=fault_key)
 
-        def layer_scan(lv, valid, page_table, lengths, cos_b, sin_b):
-            """``(hidden, pool) -> (hidden, pool)`` over this stage's layers
-            and its (sz, ...) pool. Built once a trace where the tables are
-            the step's own: every unroll step then scans the same body."""
-            def scan_body(h, xs):
-                lp, ok, layer_pool = xs
-                # the one write and read site of paged_kv, over this
-                # iteration's slice as a pool of ONE layer (the carried pool
-                # is the next perf_opt issue's, ROADMAP S9(c))
-                out, written = block_decode_paged(
-                    cfg, lp, h, cos_b, sin_b,
-                    tree_map(lambda a: a[None], layer_pool), 0, page_table,
-                    lengths)
-                # padding layers are identity AND must not touch their
-                # pages
-                return jnp.where(ok, out, h), tree_map(
-                    lambda new, old: jnp.where(ok, new[0], old), written,
-                    layer_pool)
-
-            return lambda h, pool: jax.lax.scan(scan_body, h,
-                                                (lv, valid, pool))
-
         def stage_step_paged(local_layers, local_valid, hidden, pool_loc,
                              page_table, lengths, cos_b, sin_b):
             lv = {k: v[0] for k, v in local_layers.items()}
             valid = local_valid[0]
+            layers = jnp.arange(sz, dtype=jnp.int32)
             hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
+
+            def layer_scan(table, lens, cb, sb):
+                """``(hidden, pool, live) -> (hidden, pool)``: this stage's
+                layers over its pool, the stage body of both schedules. The
+                pool's ``(sz, P, ps, width)`` leaves are CARRIED whole beside
+                the hidden state and the layer index is scanned, as
+                ``paged_kv.paged_decode_step`` does on one chip: each layer's
+                row scatter and page gathers address ``layer*P + page`` of
+                the donated buffer where it lies; nothing is sliced into
+                ``xs``, stacked out of ``ys`` or selected.
+
+                A write that must not happen goes to the trash page (page 0,
+                where idle slots' writes already go): ``live`` False is a
+                dead unroll iteration (this device is not the stage whose
+                turn it is) or a fill / drain step of the µ-batch schedule, a
+                False in ``valid`` a padding layer of an uneven cut. Only the
+                WRITE is rerouted: the gathers read the real pages (a dead
+                iteration reading through an all-zero table costs 19.5 ms of
+                a 65 ms step more, the one trash page fetched over and over:
+                PERF.md §6 "PR 31"), and what is computed from them is
+                dropped by the small selects on the hidden state. Built once
+                a trace where the tables are the step's own: every unroll
+                step then scans the same body."""
+                def scan_body(carry, xs):
+                    (h, pool), (lp, ok, write, layer) = carry, xs
+                    out, pool = block_decode_paged(
+                        cfg, lp, h, cb, sb, pool, layer, table, lens,
+                        write_table=jnp.where(write, table, 0))
+                    # a padding layer is the identity on the hidden state
+                    return (jnp.where(ok, out, h), pool), None
+
+                return lambda h, pool, live: jax.lax.scan(
+                    scan_body, (h, pool),
+                    (lv, valid, valid & live, layers))[0]
+
+            def run_stage_mu(h_mu, pool, b, ok):
+                # the pool is shared across slots so it is NOT sliced per
+                # µ-batch: the same body sees its µ-batch's slot rows of the
+                # tables, and a fill / drain step (ok False) is a dead one
+                mb_rows = h_mu.shape[0]
+                return layer_scan(*(
+                    jax.lax.dynamic_slice_in_dim(a, b * mb_rows, mb_rows,
+                                                 axis=0)
+                    for a in (page_table, lengths, cos_b, sin_b)))(
+                        h_mu, pool, ok)
 
             # the deepest slot's fill level keys the fault step: distinct as
             # decoding advances, identical across same-seed replays of the
@@ -1681,35 +1724,14 @@ class SplitRuntime:
             fkey = None if link is None else jax.random.fold_in(
                 jax.random.fold_in(jax.random.key(link.faults.seed), 0x57E9),
                 jnp.max(lengths))
+            pool = tree_map(lambda a: a[0], pool_loc)
             if n_micro == 1:
                 out, pool, counters = _hop_protocol(
-                    layer_scan(lv, valid, page_table, lengths, cos_b, sin_b),
-                    hidden, tree_map(lambda a: a[0], pool_loc), fkey)
+                    layer_scan(page_table, lengths, cos_b, sin_b), hidden,
+                    pool, fkey)
             else:
-                mb_rows = hidden.shape[0] // n_micro
-
-                def run_stage_mu(h_mu, pool, b, ok):
-                    # the pool is shared across slots so it is NOT sliced per
-                    # µ-batch; instead each step sees only its µ-batch's slot
-                    # rows of the page table, and fill/drain steps (ok False)
-                    # have their writes routed to the trash page (page 0) so
-                    # no real page is touched
-                    start = b * mb_rows
-                    pt_mu = jax.lax.dynamic_slice_in_dim(page_table, start,
-                                                         mb_rows, axis=0)
-                    pt_mu = jnp.where(ok, pt_mu, 0)
-                    ln_mu = jax.lax.dynamic_slice_in_dim(lengths, start,
-                                                         mb_rows, axis=0)
-                    cb_mu = jax.lax.dynamic_slice_in_dim(cos_b, start,
-                                                         mb_rows, axis=0)
-                    sb_mu = jax.lax.dynamic_slice_in_dim(sin_b, start,
-                                                         mb_rows, axis=0)
-                    return layer_scan(lv, valid, pt_mu, ln_mu, cb_mu,
-                                      sb_mu)(h_mu, pool)
-
                 out, pool, counters = _hop_protocol_pipelined(
-                    run_stage_mu, hidden, tree_map(lambda a: a[0], pool_loc),
-                    fkey)
+                    run_stage_mu, hidden, pool, fkey)
             pool = tree_map(lambda a: a[None], pool)
             if link is None:
                 return out, pool

@@ -763,3 +763,129 @@ def test_split_paged_decode_matches_generate_split(params):
     for v in state.values():
         np.testing.assert_array_equal(np.asarray(v["toks"], np.int32),
                                       ref[v["i"]])
+
+
+# ---------------------------------------------------------------------------
+# split runtime: the stage's pool is carried through the step, and a write
+# that must not happen (a dead unroll iteration, a fill / drain step of the
+# µ-batch schedule, a padding layer) lands in the trash page
+# ---------------------------------------------------------------------------
+
+
+def _staged_oracle(rt, placed, pool, table, lengths, toks):
+    """The step as the runtime made it before its pool was carried, kept
+    here as the oracle: every layer runs over ITS slice of the stage's pool
+    as a pool of one layer, and whatever must not be written is selected
+    away whole — a padding layer's slice after the layer, a dead unroll
+    iteration's pool after the stage. Nothing is routed anywhere, so the
+    trash page holds the idle slots' writes only. Unpipelined: the µ-batch
+    schedule must leave the same bits."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from edgellm_tpu.models.transformer import embed, precompute_rope, unembed
+    from edgellm_tpu.parallel import split
+
+    tree_map = jax.tree_util.tree_map
+
+    def stage(local_layers, local_valid, hidden, pool_loc, cos_b, sin_b):
+        lv = {k: v[0] for k, v in local_layers.items()}
+        hidden = jax.lax.pcast(hidden, ("stage",), to="varying")
+
+        def scan_body(h, xs):
+            lp, ok, layer_pool = xs
+            out, written = paged_kv.block_decode_paged(
+                CFG, lp, h, cos_b, sin_b,
+                tree_map(lambda a: a[None], layer_pool), 0, table, lengths)
+            return jnp.where(ok, out, h), tree_map(
+                lambda new, old: jnp.where(ok, new[0], old), written,
+                layer_pool)
+
+        def run_stage(h, pool, keep):
+            h2, written = jax.lax.scan(scan_body, h,
+                                       (lv, local_valid[0], pool))
+            return h2, split.keep_carry(keep, written, pool)
+
+        out, pool = split.run_pipeline_stages_carry(
+            len(rt.bounds), rt.codecs, run_stage, hidden,
+            tree_map(lambda a: a[0], pool_loc))
+        return out, tree_map(lambda a: a[None], pool)
+
+    @jax.jit
+    def step(placed, pool, toks):
+        cos, sin = precompute_rope(CFG, table.shape[1] * pool.page_size)
+        out, pool = shard_map(
+            stage, mesh=rt.mesh,
+            in_specs=({k: rt._layer_pspec(k, v.ndim)
+                       for k, v in placed["layers"].items()},
+                      P("stage"), P(), P("stage"), P(), P()),
+            out_specs=(P(), P("stage")), check_vma=False,
+        )(placed["layers"], placed["layers_valid"],
+          embed(placed, toks[:, None]), pool, cos[lengths], sin[lengths])
+        return unembed(CFG, placed, out)[:, -1], pool
+
+    table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+    return step(placed, pool, jnp.asarray(toks))
+
+
+@pytest.mark.parametrize("tier,n_micro", [("fp", 1), ("fp", 2),
+                                          ("int8_per_channel", 1)])
+def test_split_paged_step_leaves_every_real_page_to_the_live_write(
+        params, tier, n_micro):
+    if len(jax.devices()) < 3:
+        pytest.skip("needs >= 3 devices")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from edgellm_tpu.parallel import (PipelineConfig, SplitConfig,
+                                      SplitRuntime, make_stage_mesh)
+
+    # stages of 2, 1 and 1 layers: stages 1 and 2 carry a padding layer
+    rt = SplitRuntime(
+        CFG, SplitConfig(cuts=(1, 2), hop_codecs=("int8_per_token",) * 2),
+        make_stage_mesh(3), pipeline=PipelineConfig(num_microbatches=n_micro))
+    assert rt.stage_size == 2 and [b - a for a, b in rt.bounds] == [2, 1, 1]
+    placed = rt.place_params(params)
+    ps, npg, ms, pps = (BCFG.page_size, BCFG.num_pages, BCFG.max_slots,
+                        BCFG.pages_per_slot)
+    # a pool with something in EVERY row, the trash page and the padding
+    # layers' pages included: an untouched page is told from a zeroed one
+    rng = np.random.default_rng(5)
+    zero = rt.init_paged_pool(npg, ps, kv_codec=tier)
+
+    def some(a):    # codes, a quantized tier's positive scales, or fp rows
+        if a.dtype == jnp.int8:
+            return rng.integers(-127, 128, a.shape).astype(a.dtype)
+        if tier != "fp":
+            return rng.uniform(0.01, 0.05, a.shape).astype(a.dtype)
+        return rng.standard_normal(a.shape).astype(a.dtype)
+
+    start = [some(a) for a in zero]
+    staged = NamedSharding(rt.mesh, P("stage"))
+    pool = type(zero)(*(jax.device_put(a, staged) for a in start))
+    want = type(zero)(*(jax.device_put(a, staged) for a in start))
+    # three streams at their own fill levels and an idle slot (row of zeros)
+    table = np.zeros((ms, pps), np.int32)
+    table[0], table[1, :2], table[3, :3] = [1, 2, 3, 4], [5, 6], [7, 8, 9]
+    lengths = np.asarray([25, 9, 0, 16], np.int32)
+    toks = _prompt(ms, 77)
+    for _ in range(2):          # the second step reads what the first wrote
+        ref, want = _staged_oracle(rt, placed, want, table, lengths, toks)
+        logits, pool = rt.decode_step_paged(placed, pool, table, lengths,
+                                            jnp.asarray(toks))
+        live = lengths > 0
+        np.testing.assert_array_equal(np.asarray(logits)[live],
+                                      np.asarray(ref)[live])
+        for g, w, was in zip(pool, want, start):
+            g, w = np.asarray(g), np.asarray(w)
+            # every real page of every stage, padding layers' too, bit for
+            # bit: written once, by the iteration whose turn it was
+            np.testing.assert_array_equal(g[:, :, 1:], w[:, :, 1:])
+            # a padding layer touched no real page at all ...
+            np.testing.assert_array_equal(g[1:, 1, 1:], was[1:, 1, 1:])
+            # ... its write went to the trash page, like every dead
+            # iteration's (the oracle's holds the idle slot's write only)
+            assert not np.array_equal(g[1:, 1, 0], was[1:, 1, 0])
+            np.testing.assert_array_equal(w[1:, 1, 0], was[1:, 1, 0])
+            assert not np.array_equal(g[:, 0, 0], w[:, 0, 0])
+        toks = np.asarray(jnp.argmax(logits, -1), np.int32)
+        lengths = np.where(live, lengths + 1, 0).astype(np.int32)

@@ -244,3 +244,87 @@ def test_window_layers_gather_their_rings_and_both_pools_stay_in_place(topo):
     mem = step.memory_analysis()
     assert mem.temp_size_in_bytes < 1.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+
+
+# benchmark/configs/qwen2-1.5b-split4.json: the widths of the four-chip cell
+QWEN15 = ModelConfig(
+    family="qwen2", vocab_size=151936, hidden_size=1536, num_layers=28,
+    num_heads=12, num_kv_heads=2, intermediate_size=8960,
+    max_position_embeddings=131072, norm_eps=1e-6, rope_theta=1e6,
+    tie_word_embeddings=True)
+
+
+def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
+        topo):
+    """``step_paged_fn`` as the split cell runs it (four stages of 7 layers,
+    hops int8 / int4 / int8): each stage's pool is a scan carry that the row
+    scatters and page gathers address in place through all four unroll
+    iterations. On the parent's tree the same module held 8 ``select``, 8
+    ``dynamic-update-slice`` and 8 ``broadcast`` of a stage's whole
+    ``bf16[7,24577,16,256]`` leaf, 8 ``select`` of a layer's and 5.45 GB of
+    temporaries (PERF.md §6 "PR 31"): 155 of a 215 ms step."""
+    from edgellm_tpu.parallel import SplitConfig, SplitRuntime, \
+        make_stage_mesh
+
+    mesh = make_stage_mesh(SPLIT_STAGES, devices=topo.devices)
+    staged, everywhere = (NamedSharding(mesh, P("stage")),
+                          NamedSharding(mesh, P()))
+    rt = SplitRuntime(QWEN15, SplitConfig(
+        cuts=(6, 13, 20), hop_codecs=("int8_per_token", "int4_per_token",
+                                      "int8_per_token")), mesh)
+    sz = rt.stage_size
+    assert sz == SPLIT_STAGE_SIZE
+    params = jax.eval_shape(
+        lambda: init_params(QWEN15, jax.random.key(0), dtype=jnp.bfloat16))
+    placed = _shapes({k: v for k, v in params.items() if k != "layers"},
+                     everywhere)
+    placed["layers"] = {
+        k: jax.ShapeDtypeStruct((SPLIT_STAGES, sz) + v.shape[1:], v.dtype,
+                                sharding=staged)
+        for k, v in params["layers"].items()}
+    placed["layers_valid"] = jax.ShapeDtypeStruct(
+        (SPLIT_STAGES, sz), jnp.bool_, sharding=staged)
+    width = SPLIT_KV * SPLIT_HD
+    leaf = jax.ShapeDtypeStruct((SPLIT_STAGES, sz, PAGES, PAGE, width),
+                                jnp.bfloat16, sharding=staged)
+
+    def arr(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=everywhere)
+
+    step = rt._paged_decode_fns(PAGES, PAGE).lower(
+        placed, paged_kv.PagePool(leaf, leaf), arr((SLOTS, PAGES_PER_SLOT)),
+        arr((SLOTS,)), arr((SLOTS,))).compile()
+    hlo = step.as_text()
+    layer_pool = PAGES * PAGE * width
+    gathered = SLOTS * PAGES_PER_SLOT * PAGE * width
+    own = {f"bf16[{SLOTS * PAGES_PER_SLOT},{PAGE},{width}]",
+           f"bf16[{SLOTS},{PAGES_PER_SLOT},{PAGE},{width}]"}
+    moving = ("copy", "select", "dynamic-update-slice", "reshape",
+              "transpose", "broadcast", "dynamic-slice")
+    moved = [m for m in _moved(hlo, gathered, moving)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    # nothing of a layer's pool or larger is copied, selected, sliced out,
+    # put back or relaid, and nothing K/V-sized but the gather's own output
+    assert not moved, moved
+    # the stage's pool is gathered as pages at (layer, page), written as rows
+    assert f"bf16[{sz * PAGES},{PAGE},{width}]" in hlo, \
+        "the stage's pool is not gathered as (sz*P, ps, width)"
+    assert f"bf16[{sz * PAGES * PAGE},{width}]" in hlo
+    # across chips: the three hops, each to the next stage, and the one
+    # all-reduce that hands the last stage's hidden state to every chip
+    hops = {pairs for op, _, _, line in _instructions(hlo)
+            if op == "collective-permute-start"
+            for pairs in re.findall(r"source_target_pairs=\{([^}]*\})\}",
+                                    line)}
+    assert hops == {"{0,1}", "{1,2}", "{2,3}"}, hops
+    crossing = [(op, shape.split("{")[0]) for op, _, shape, _ in
+                _instructions(hlo)
+                if op in ("all-reduce", "all-reduce-start", "all-gather",
+                          "all-gather-start", "all-to-all",
+                          "reduce-scatter")]
+    assert crossing == [("all-reduce", f"f32[{SLOTS},1,1536]")], crossing
+    mem = step.memory_analysis()
+    # a chip's share: the gathered K and V of one layer at a time (2 x 201
+    # MB) and little else; the parent's stacked and selected pools were 5.45
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.alias_size_in_bytes >= 2 * sz * layer_pool * 2   # donated

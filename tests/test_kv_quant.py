@@ -425,7 +425,7 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
         tier, params, monkeypatch):
     # the layout of a K/V row is known in two functions of models/paged_kv.py:
     # the one-chip step and the split runtime's step both trace through
-    # them, each with one layer's pool of the tier's type
+    # them, each with its whole carried pool of the tier's type
     from edgellm_tpu.models import paged_kv as pk
     from edgellm_tpu.parallel import SplitConfig, SplitRuntime, \
         make_stage_mesh
@@ -470,8 +470,8 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
     assert staged.k.shape == (2, rt.stage_size) + layer
     jax.make_jaxpr(rt._paged_decode_fns(npg, ps, kv_codec=tier))(
         rt.place_params(params), staged, table, ints, ints)
-    # one stage body, scanned by every stage, over a pool of ONE layer
-    assert seen == want(1)
+    # one stage body, scanned by every stage, over the stage's carried pool
+    assert seen == want(rt.stage_size)
 
 
 def test_packed_gather_adopt_roundtrip_across_geometry():
