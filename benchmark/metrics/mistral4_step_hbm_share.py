@@ -1,0 +1,18 @@
+"""The least time a decode step's bytes need at the HBM peak (every held
+weight once, of the table a row a slot; the live latent rows once at their
+stored width; a new row a slot a layer written:
+``rooflines_mistral4.step_bytes``) as a share of the step executable's device
+time. A floor: it cannot pass 100%."""
+from benchmark.rooflines_granitemoehybrid import hbm_share, live_slots
+from benchmark.rooflines_mistral4 import latent_rows, step_bytes
+from benchmark.trace_reduce import step_runs_seconds
+
+
+def read(record: dict):
+    step = step_runs_seconds(record)
+    slots = live_slots(record)
+    rows = latent_rows(record)
+    if step is None or slots is None or rows is None:
+        return None
+    need = step_bytes(record["config"], rows[0], rows[2], slots)
+    return hbm_share(record, need, 1e3 * step[1] / step[0])

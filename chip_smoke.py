@@ -471,6 +471,31 @@ def window_phase(*, prompt_len: int = 300, n_new: int = 120,
             "gap_max_over_logit_max": gap}
 
 
+def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
+                 evict_after: int = 20) -> dict:
+    """A tiny ``mistral4`` stream (latent attention: one cached row a
+    position for all heads, interleaved rotary pairs on the rope lanes, the
+    query scale and YaRN stepping inside the prompt; routed experts, half of
+    them absent, plus a shared one; float32) through the same admit / step /
+    evict / readmit: the prefill attends EXPANDED, every step ABSORBED over
+    the one-leaf pool across two page boundaries, the rows leave the device
+    and come back in between, and ``forward`` (expanded at every position)
+    over prompt + tokens puts each served token first."""
+    from edgellm_tpu.models.configs import tiny_mistral4_config
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    cfg = tiny_mistral4_config(experts_held=4, expert_offset=2)
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=24)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after)
+    assert report["latent_rows_capacity"] == 72 * 16
+    assert report["kv_row_bytes"] == cfg.kv_row_lanes * 4
+    return {"tokens": int(n_new), "evicted": report["evicted"],
+            "kv_row_bytes": report["kv_row_bytes"],
+            "routed_local": report["routed_local"],
+            "gap_max_over_logit_max": gap}
+
+
 def smoke(report: dict, save) -> dict:
     """Every phase in order, at full width. ``save()`` persists ``report``
     after each phase so a failed run leaves what it learned."""
@@ -503,6 +528,7 @@ def smoke(report: dict, save) -> dict:
     phase("reference", lambda: reference_phase(cfg))
     phase("hybrid", hybrid_phase)
     phase("window", window_phase)
+    phase("latent", latent_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

@@ -368,6 +368,25 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             lower_args=(wcfg, wparams, wfull, wring, wcount, ptab, wtab,
                         plens, ptoks, pkeys, qsteps, qtemps, None))
 
+    # ---- a stack of latent-attention layers (mistral4): the same walk and
+    # ---- the same step executable with the pool's ONE leaf where the K
+    # ---- pages go and no V pages or state store — collective-free; the
+    # ---- leaf and the expert counter, TWO buffers, stay donated ---------
+    from ..models.configs import tiny_mistral4_config
+
+    lcfg = tiny_mistral4_config()
+    lparams = transformer.init_params(lcfg, jax.random.key(0))
+    lpool = paged_kv.init_pool(lcfg, NPG, PGS)
+    lcount = jnp.zeros((lcfg.num_layers, lcfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_latent",
+            lambda p, rows, ct, pt, ln, t: hybrid.paged_decode_step_hybrid(
+                lcfg, p, rows, None, None, None, ct, pt, ln, t),
+            (lparams, lpool.rows, lcount, ptab, plens, ptoks),
+            ctx={"donate_min": 2},
+            lowerable=batching._batched_hybrid_step_jit,
+            lower_args=(lcfg, lparams, lpool.rows, None, None, None, lcount,
+                        ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
+
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state
     # feeds the byte-identical ragged step graph the pre-quantization
     # batcher traces — the disabled-build jaxpr fingerprint half of the

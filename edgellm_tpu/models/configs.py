@@ -15,6 +15,11 @@ import math
 from typing import Optional
 
 
+#: lanes of one tile of the chip's minor dimension: what a stored latent row
+#: is padded to whole multiples of (``ModelConfig.kv_row_lanes``)
+LANE_TILE = 128
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for one causal LM.
@@ -37,8 +42,16 @@ class ModelConfig:
         an explicit head width (``H x hd != hidden_size``), RoPE with YaRN
         on the full layers only, every feed-forward a routed expert layer
         with no shared expert, an untied head (JetBrains Mellum 2).
+      - ``"mistral4"``: the same walk with a fourth kind,
+        ``"latent_attention"`` (MLA): queries through a low-rank bottleneck,
+        keys and values rebuilt per head from ONE cached latent row a
+        position (``kv_lora_rank`` normalised lanes + ``qk_rope_head_dim``
+        rotated lanes shared by all heads), interleaved rotary pairs on the
+        rope lanes only, YaRN with its softmax ``mscale`` and a per-position
+        query scale; routed experts plus a shared one on every layer, an
+        untied head (Mistral Small 4).
 
-    The fields after ``rope_scaling`` exist for those two families and
+    The fields after ``rope_scaling`` exist for those three families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -63,8 +76,9 @@ class ModelConfig:
     #: layers' table; the window layers rotate by the plain one (Mellum's
     #: ``rope_parameters`` by layer kind).
     rope_scaling: Optional[tuple] = None
-    #: per-layer mixer kind, ``"mamba"``, ``"attention"`` or
-    #: ``"sliding_attention"``; empty = every layer is the family's one block
+    #: per-layer mixer kind, ``"mamba"``, ``"attention"``,
+    #: ``"sliding_attention"`` or ``"latent_attention"``; empty = every layer
+    #: is the family's one block
     layer_types: tuple = ()
     #: width of one attention head where the model states it; 0 = the
     #: derived ``hidden_size // num_heads``
@@ -99,16 +113,53 @@ class ModelConfig:
     attention_multiplier: Optional[float] = None
     #: no positional encoding of any kind in the attention layers
     nope: bool = False
+    #: latent attention: the query bottleneck, the cached latent's width, the
+    #: rotated lanes of a head (of ``head_dim``, the rest are position-free)
+    #: and a value head's width
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: scores are multiplied by ``softmax_mscale ** 2`` (YaRN's ``0.1 *
+    #: mscale_all_dim * ln(factor) + 1``) and by ``1 + query_scale_beta *
+    #: ln(1 + floor(pos / original_max_position_embeddings))`` at query
+    #: position ``pos``
+    softmax_mscale: float = 1.0
+    query_scale_beta: float = 0.0
 
     @property
     def head_dim(self) -> int:
         return self.explicit_head_dim or self.hidden_size // self.num_heads
 
     @property
+    def qk_nope_head_dim(self) -> int:
+        """A latent layer's position-free lanes of a query / key head."""
+        return self.head_dim - self.qk_rope_head_dim
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers whose cached row is a latent (one row a position for all
+        heads), not per-head K and V."""
+        return sum(1 for t in self.layer_types if t == "latent_attention")
+
+    @property
+    def kv_row_lanes(self) -> int:
+        """Lanes of ONE stored row of a page-pool leaf, the one place the
+        pool's row width is asked: all KV heads' K (or V) of a position, or a
+        latent layer's ``[c (kv_lora_rank) | k_rope (qk_rope_head_dim)]``
+        zero-padded to whole 128-lane tiles (320 -> 384: at 320 the chip's
+        compiler keeps the pool pages-minor and copies it whole around every
+        write, as it does a 64-lane second leaf; PERF.md section 6 "PR 32")."""
+        if self.latent_layers:
+            return -(-(self.kv_lora_rank + self.qk_rope_head_dim)
+                     // LANE_TILE) * LANE_TILE
+        return self.num_kv_heads * self.head_dim
+
+    @property
     def is_hybrid(self) -> bool:
         """Walked by layer kinds (``models/hybrid.py``), with params held per
         kind and a routed expert layer after every mixer."""
-        return self.family in ("granitemoehybrid", "mellum")
+        return self.family in ("granitemoehybrid", "mellum", "mistral4")
 
     @property
     def recurrent_state(self) -> bool:
@@ -131,10 +182,12 @@ class ModelConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep K/V pages: all of them, or the attention ones."""
+        """Layers that keep pages that grow with the stream: all of them, or
+        the attention ones (per-head K/V rows, or latent rows)."""
         if not self.layer_types:
             return self.num_layers
-        return sum(1 for t in self.layer_types if t == "attention")
+        return sum(1 for t in self.layer_types
+                   if t in ("attention", "latent_attention"))
 
     @property
     def mamba_layers(self) -> int:
@@ -164,7 +217,7 @@ class ModelConfig:
 
     @property
     def rotary_dim(self) -> int:
-        return int(self.head_dim * self.rotary_pct)
+        return self.qk_rope_head_dim or int(self.head_dim * self.rotary_pct)
 
     @property
     def qkv_bias(self) -> bool:
@@ -172,24 +225,26 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.family not in ("gpt_neox", "qwen2", "llama",
-                               "granitemoehybrid", "mellum"):
+                               "granitemoehybrid", "mellum", "mistral4"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
         elif (self.layer_types or self.num_experts or self.mamba_heads
-              or self.explicit_head_dim or self.sliding_window):
+              or self.explicit_head_dim or self.sliding_window
+              or self.kv_lora_rank):
             raise ValueError(
-                f"layer_types / experts / mamba / head width / window fields "
-                f"belong to the granitemoehybrid and mellum families, not "
-                f"{self.family!r}")
+                f"layer_types / experts / mamba / head width / window / "
+                f"latent fields belong to the granitemoehybrid, mellum and "
+                f"mistral4 families, not {self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_kv_heads must evenly divide num_heads")
 
     def _check_hybrid(self):
-        kinds = (("mamba", "attention") if self.recurrent_state
-                 else ("attention", "sliding_attention"))
+        kinds = {"granitemoehybrid": ("mamba", "attention"),
+                 "mellum": ("attention", "sliding_attention"),
+                 "mistral4": ("latent_attention",)}[self.family]
         if len(self.layer_types) != self.num_layers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
@@ -199,6 +254,17 @@ class ModelConfig:
         if self.window_layers and self.sliding_window < 1:
             raise ValueError("a sliding_attention layer needs sliding_window "
                              ">= 1")
+        if bool(self.latent_layers) != bool(self.kv_lora_rank):
+            raise ValueError("latent ranks belong to latent_attention layers, "
+                             "and those need them")
+        if self.latent_layers and (
+                min(self.q_lora_rank, self.kv_lora_rank, self.v_head_dim) < 1
+                or not 0 < self.qk_rope_head_dim < self.head_dim
+                or self.qk_rope_head_dim % 2):
+            raise ValueError(
+                "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
+                "v_head_dim >= 1 and an even qk_rope_head_dim inside the "
+                "explicit head_dim (= qk_nope_head_dim + qk_rope_head_dim)")
         if not 0 < self.experts_per_tok <= self.num_experts:
             raise ValueError("experts_per_tok must be in [1, num_experts]")
         if not (0 <= self.expert_offset
@@ -347,6 +413,70 @@ MELLUM2_12B_A2_5B = ModelConfig(
 )
 
 
+# mistralai/Mistral-Small-4-119B-2603 (119B-A6.5B, 2026-03) — config.json
+# (``model_type`` ``mistral4``): 36 layers, every one latent attention (32
+# heads: queries through a 1024-wide bottleneck, a cached row of 256 latent +
+# 64 rotated lanes, value heads of 128) and 128 routed experts of width 2048
+# top-4 plus one shared expert of 2048; YaRN (factor 128 over 8192, mscale =
+# mscale_all_dim = 1: cos/sin unscaled, the softmax scale times m^2) and the
+# per-position query scale (``llama_4_scaling_beta`` 0.1); untied 131072-row
+# head.
+MISTRAL_SMALL_4_119B = ModelConfig(
+    family="mistral4",
+    vocab_size=131072,
+    hidden_size=4096,
+    num_layers=36,
+    num_heads=32,
+    num_kv_heads=32,
+    intermediate_size=12288,  # the published dense width; no layer is dense
+    max_position_embeddings=1048576,
+    norm_eps=1e-6,
+    rope_theta=10000.0,
+    tie_word_embeddings=False,
+    rope_scaling=("yarn", 128.0, 8192, 32.0, 1.0, 1.0),
+    layer_types=("latent_attention",) * 36,
+    explicit_head_dim=128,
+    num_experts=128,
+    experts_per_tok=4,
+    expert_width=2048,
+    shared_width=2048,
+    q_lora_rank=1024,
+    kv_lora_rank=256,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    softmax_mscale=0.1 * math.log(128.0) + 1.0,
+    query_scale_beta=0.1,
+)
+
+
+def tiny_mistral4_config(*, num_layers: int = 3, hidden_size: int = 48,
+                         num_heads: int = 4, vocab_size: int = 256,
+                         num_experts: int = 8, experts_per_tok: int = 3,
+                         experts_held: int = 0, expert_offset: int = 0,
+                         original_max: int = 16,
+                         max_position_embeddings: int = 512) -> ModelConfig:
+    """A small mistral4 for tests, every ratio of the published model kept:
+    rope lanes (8) < a head's 24, ``H x head`` = 96 against a hidden size of
+    48, the cached latent (16 + 8 = 24 lanes, stored padded) far under ``H x
+    (head + v)`` = 160, a query bottleneck, YaRN with ``mscale`` on the
+    softmax and the query scale both stepping inside 100 positions
+    (``original_max`` 16), top-k of routed experts plus a shared one, an
+    untied head."""
+    return ModelConfig(
+        family="mistral4", vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=num_layers, num_heads=num_heads, num_kv_heads=num_heads,
+        intermediate_size=32,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-6,
+        rope_theta=10000.0, tie_word_embeddings=False,
+        rope_scaling=("yarn", 8.0, original_max, 32.0, 1.0, 1.0),
+        layer_types=("latent_attention",) * num_layers,
+        explicit_head_dim=24, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32, shared_width=40,
+        experts_held=experts_held, expert_offset=expert_offset,
+        q_lora_rank=20, kv_lora_rank=16, qk_rope_head_dim=8, v_head_dim=16,
+        softmax_mscale=0.1 * math.log(8.0) + 1.0, query_scale_beta=0.1)
+
+
 def tiny_mellum_config(*, layer_types: tuple = _MELLUM_PERIOD * 2,
                        sliding_window: int = 20, hidden_size: int = 48,
                        num_heads: int = 4, num_kv_heads: int = 2,
@@ -405,6 +535,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_hybrid_config()
     if family == "mellum":
         return tiny_mellum_config()
+    if family == "mistral4":
+        return tiny_mistral4_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -432,10 +564,12 @@ PRESETS = {
     "llama-3.2-1b": LLAMA_3_2_1B,
     "granite-4.0-h-small": GRANITE_4_0_H_SMALL,
     "mellum2-12b-a2.5b": MELLUM2_12B_A2_5B,
+    "mistral-small-4-119b": MISTRAL_SMALL_4_119B,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
     "tiny-llama": tiny_config("llama", num_layers=6),
     "tiny-granite-hybrid": tiny_hybrid_config(),
     "tiny-mellum": tiny_mellum_config(),
+    "tiny-mistral4": tiny_mistral4_config(),
 }

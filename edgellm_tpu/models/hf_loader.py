@@ -16,6 +16,7 @@ Layout notes:
 """
 from __future__ import annotations
 
+import math
 import os
 import time
 
@@ -252,6 +253,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         )
     if mt == "mellum":
         return _mellum_config(hf_config)
+    if mt == "mistral4":
+        return _mistral4_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -308,4 +311,70 @@ def _mellum_config(hf_config) -> ModelConfig:
         num_experts=int(hf_config.num_experts),
         experts_per_tok=int(hf_config.num_experts_per_tok),
         expert_width=int(hf_config.moe_intermediate_size),
+    )
+
+
+def _mistral4_config(hf_config) -> ModelConfig:
+    """Mistral Small 4 (``model_type`` ``mistral4``). The keys mapped:
+    ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim`` (= ``qk_head_dim``, the explicit head width),
+    ``v_head_dim``, ``rope_parameters`` (YaRN; the cos/sin factor is
+    ``mscale`` over ``mscale_all_dim``'s, the softmax ``0.1 mscale_all_dim
+    ln(factor) + 1`` squared, ``llama_4_scaling_beta`` the query scale's),
+    ``n_routed_experts``, ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``n_shared_experts`` (a shared expert that many times as wide). Every
+    layer must be a routed one (``first_k_dense_replace`` 0), routing one
+    group's renormalised top-k softmax unscaled, rotary pairs interleaved."""
+    rope = hf_config.rope_parameters
+    if rope.get("rope_type", rope.get("type")) != "yarn":
+        raise ValueError(f"mistral4 rope_parameters must be yarn, got "
+                         f"{rope!r}")
+    for key, want in (("first_k_dense_replace", 0), ("n_group", 1),
+                      ("topk_group", 1), ("norm_topk_prob", True),
+                      ("routed_scaling_factor", 1), ("rope_interleave", True),
+                      ("attention_bias", False), ("mlp_bias", False),
+                      ("sliding_window", None)):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"mistral4 with {key}={getattr(hf_config, key)!r} is not "
+                f"supported (only {want!r})")
+    nope, rot = hf_config.qk_nope_head_dim, hf_config.qk_rope_head_dim
+    if getattr(hf_config, "qk_head_dim", nope + rot) != nope + rot:
+        raise ValueError("mistral4 qk_head_dim must be qk_nope_head_dim + "
+                         "qk_rope_head_dim")
+    factor = float(rope["factor"])
+
+    def mscale(m):
+        return 0.1 * float(m) * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    return ModelConfig(
+        family="mistral4",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(rope["rope_theta"]),
+        tie_word_embeddings=hf_config.tie_word_embeddings,
+        rope_scaling=("yarn", factor,
+                      int(rope["original_max_position_embeddings"]),
+                      float(rope["beta_fast"]), float(rope["beta_slow"]),
+                      mscale(rope.get("mscale", 1))
+                      / mscale(rope.get("mscale_all_dim", 0))),
+        layer_types=("latent_attention",) * hf_config.num_hidden_layers,
+        explicit_head_dim=int(nope + rot),
+        num_experts=int(hf_config.n_routed_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+        shared_width=int(hf_config.n_shared_experts
+                         * hf_config.moe_intermediate_size),
+        q_lora_rank=int(hf_config.q_lora_rank),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_rope_head_dim=int(rot),
+        v_head_dim=int(hf_config.v_head_dim),
+        softmax_mscale=mscale(rope.get("mscale_all_dim", 0)),
+        query_scale_beta=float(rope.get("llama_4_scaling_beta", 0.0)),
     )

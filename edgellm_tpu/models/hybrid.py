@@ -1,5 +1,5 @@
-"""A stack walked by layer kinds: the ``granitemoehybrid`` and ``mellum``
-families' forward, prefill and decode steps.
+"""A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum`` and
+``mistral4`` families' forward, prefill and decode steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -18,6 +18,12 @@ A ``mellum`` stack (JetBrains Mellum 2) has no ``mamba`` entry, and beside
 ``window``: sliding layers stacked the same way, rotated by the plain table,
 whose K/V live in a RING of pages (``paged_kv``: the window group); its
 expert layers have no shared expert and its head (``lm_head``) is untied.
+A ``mistral4`` stack (Mistral Small 4) has one kind, ``latent`` (``models/
+mla.py``): every layer caches ONE latent row a position in the page pool
+(``paged_kv.LatentPool``, pages that grow as full layers' do); its prefill
+attends in the EXPANDED form (keys and values rebuilt per head from the rows)
+and every decode step in the ABSORBED one (multi-query attention over the rows
+as cached); routed experts plus a shared one, an untied head.
 
 (the expert weights are a list, one entry a layer, and not a stack: the
 grouped products of a prefill are a kernel call whose operands must be whole
@@ -44,8 +50,9 @@ statistics (the sweep drivers), the split runtime, speculation, prefix
 sharing, quantized KV tiers, checkpoints. :func:`refuse_recurrent_state` is
 the one place the refusal is worded. The same mechanisms read "a slot's K/V
 = every position of every layer", which a window layer's ring does not
-hold: :func:`refuse_window_ring` words that refusal, and
-:func:`refuse_beyond_kv_rows` is what a mechanism calls to make both.
+hold: :func:`refuse_window_ring` words that refusal. A latent layer's row
+is not K and V of ``KV x hd`` lanes: :func:`refuse_latent_rows`.
+:func:`refuse_beyond_kv_rows` is what a mechanism calls to make all three.
 """
 from __future__ import annotations
 
@@ -56,12 +63,14 @@ import jax.numpy as jnp
 
 from ..lint import graph_contract
 from .configs import ModelConfig
+from . import mla
 from .flash_attention import (MAX_BLOCKED_S, QBLOCK, causal_attention,
                               decode_attention, kernel_plan)
 from .mamba2 import mamba2_prefill, mamba2_step
 from .moe import moe_layer
-from .paged_kv import (PagePool, _attention_decode_paged,
-                       _attention_decode_window)
+from .paged_kv import (LatentPool, PagePool, _attention_decode_latent,
+                       _attention_decode_paged, _attention_decode_window,
+                       attend_latent)
 from .transformer import _rmsnorm, apply_rotary, precompute_rope
 
 
@@ -101,11 +110,32 @@ def refuse_window_ring(cfg: ModelConfig, what: str) -> None:
             f"layer; there is no fallback")
 
 
+class LatentRowsUnsupported(ValueError):
+    """A mechanism that moves a sequence's cache as K and V rows of ``KV x
+    hd`` lanes was asked to serve a family whose layers cache one latent row
+    a position."""
+
+
+def refuse_latent_rows(cfg: ModelConfig, what: str) -> None:
+    """Raise for a config with latent-attention layers: ``what`` names the
+    mechanism refusing."""
+    if cfg.latent_layers:
+        raise LatentRowsUnsupported(
+            f"{what} does not support family {cfg.family!r}: its "
+            f"{cfg.latent_layers} latent-attention layers cache ONE row a "
+            f"position for all heads (a {cfg.kv_lora_rank}-lane latent and "
+            f"{cfg.qk_rope_head_dim} rotated lanes, stored "
+            f"{cfg.kv_row_lanes} wide in a one-leaf pool), and {what} is "
+            f"written for K and V rows of num_kv_heads x head_dim lanes "
+            f"each; there is no fallback")
+
+
 def refuse_beyond_kv_rows(cfg: ModelConfig, what: str) -> None:
     """What a mechanism that handles plain per-layer K/V rows alone calls:
-    both refusals above, each in its own words."""
+    the three refusals above, each in its own words."""
     refuse_recurrent_state(cfg, what)
     refuse_window_ring(cfg, what)
+    refuse_latent_rows(cfg, what)
 
 
 class HybridCache(NamedTuple):
@@ -145,6 +175,20 @@ class WindowCache(NamedTuple):
         return self.k.shape[2]
 
 
+class LatentCache(NamedTuple):
+    """The contiguous decode cache of a stack of latent-attention layers.
+
+    rows: (L, B, capacity, kv_row_lanes), a position's ``[c | k_rope | 0...]``
+    as the page pool stores it; length: () int32."""
+
+    rows: jnp.ndarray
+    length: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.rows.shape[2]
+
+
 def state_shapes(cfg: ModelConfig, rows: int) -> tuple:
     """Shapes of (conv, ssm) for ``rows`` sequences or slots."""
     return ((cfg.mamba_layers, rows, cfg.mamba_d_conv - 1,
@@ -163,7 +207,8 @@ def _row(tree: dict, j: int) -> dict:
 
 def _kinds(cfg: ModelConfig):
     """(layer, kind, index among its kind) down the stack."""
-    seen = {"mamba": 0, "attention": 0, "sliding_attention": 0}
+    seen = {"mamba": 0, "attention": 0, "sliding_attention": 0,
+            "latent_attention": 0}
     for layer, kind in enumerate(cfg.layer_types):
         yield layer, kind, seen[kind]
         seen[kind] += 1
@@ -196,6 +241,8 @@ def _rope_tables(cfg: ModelConfig, n: int) -> dict:
     the plain one on sliding layers."""
     if cfg.nope:
         return {"attention": None, "sliding_attention": None}
+    if cfg.latent_layers:  # the rope lanes' table (cfg.rotary_dim wide)
+        return {"latent_attention": precompute_rope(cfg, n)}
     return {"attention": precompute_rope(cfg, n),
             "sliding_attention": (precompute_rope(cfg, n, scaled=False)
                                   if cfg.window_layers else None)}
@@ -207,7 +254,8 @@ def _attention_blocks(q, k, v, window: int):
     no (H, S, S) score tensor exists (32 x 4096^2 float32 would be 2.1 GB a
     layer), and a sliding layer's block reads at most ``QBLOCK + window - 1``
     keys. Position i attends j with ``i - window < j <= i``. q (B, S, H,
-    hd), k, v (B, S, KV, hd) -> (B, S, H, hd); softmax in float32."""
+    hd), k (B, S, KV, hd), v (B, S, KV, vd) -> (B, S, H, vd); softmax in
+    float32."""
     b, s, h, hd = q.shape
     kv = k.shape[2]
     outs = []
@@ -227,7 +275,8 @@ def _attention_blocks(q, k, v, window: int):
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bgrqc,bcgd->bqgrd", probs.astype(q.dtype),
                          v[:, lo:stop], preferred_element_type=jnp.float32)
-        outs.append(out.astype(q.dtype).reshape(b, stop - start, h, hd))
+        outs.append(out.astype(q.dtype).reshape(b, stop - start, h,
+                                                v.shape[-1]))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
@@ -248,6 +297,44 @@ def _attention_full(cfg: ModelConfig, lp: dict, x, rope=None,
         out = (causal_attention(q, k, v, plan=plan) if plan is not None
                else jax.nn.dot_product_attention(q, k, v, is_causal=True))
     return out.reshape(b, s, -1) @ lp["wo"], k, v
+
+
+def _attention_latent_full(cfg: ModelConfig, lp: dict, x, rope):
+    """A latent layer over whole sequences, EXPANDED -> (out (B, S, D), rows
+    (B, S, kv_row_lanes) as the cache takes them): keys and values rebuilt
+    for every head from the rows (``mla.expand``), then causal attention by
+    blocks of :data:`QBLOCK` query rows. The widest tensor is the last
+    block's scores, (1, H, 1, QBLOCK, S) float32: 32 x 512 x 8192 x 4 B =
+    537 MB at a 8192-token prompt, beside the expanded K and V of (S, H, 128)
+    each, 67 MB a leaf in bf16."""
+    b, s, _ = x.shape
+    cos, sin = rope
+    q_nope, q_rope, rows = mla.project(
+        cfg, lp, x, lambda t: apply_rotary(t, cos, sin, cfg.rotary_dim),
+        jnp.broadcast_to(mla.query_scale(cfg, jnp.arange(s)), (b, s)))
+    with jax.named_scope("attn.latent.expand"):
+        k, v = mla.expand(cfg, lp, rows)
+        out = _attention_blocks(jnp.concatenate([q_nope, q_rope], axis=-1),
+                                k, v, 0)
+    return out.reshape(b, s, -1) @ lp["wo"], rows
+
+
+@jax.named_scope("attn.latent")
+def _attention_latent_step(cfg: ModelConfig, lp: dict, x, rope, rows_all,
+                           pos):
+    """A latent layer's decode against ONE layer of a contiguous cache,
+    ABSORBED: x (B, D), rope (cos, sin) (1, rot) at ``pos``, rows_all (B,
+    capacity, kv_row_lanes) -> (out (B, D), rows_all with position ``pos``
+    written)."""
+    b = x.shape[0]
+    q_nope, q_rope, row = mla.project(
+        cfg, lp, x, mla.rotate_rows(*rope),
+        mla.query_scale(cfg, jnp.broadcast_to(pos, (b,))))
+    rows_all = jax.lax.dynamic_update_slice(
+        rows_all, row[:, None].astype(rows_all.dtype), (0, pos, 0))
+    ctx = attend_latent(mla.absorb_query(cfg, lp, q_nope, q_rope), rows_all,
+                        jnp.broadcast_to(pos + 1, (b,)), cfg.head_dim)
+    return mla.unabsorb(cfg, lp, ctx), rows_all
 
 
 def _ffn(cfg: ModelConfig, mp: dict, h, active=None):
@@ -291,10 +378,16 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
     """Whole sequences through the stack. Returns (hidden (B, S, D), per-kind
     lists of what a decode cache is filled from when ``collect``)."""
     h = embed_hybrid(cfg, params, ids)
-    ks, vs, convs, ssms, wks, wvs = [], [], [], [], [], []
+    ks, vs, convs, ssms, wks, wvs, lat = [], [], [], [], [], [], []
     rope = _rope_tables(cfg, ids.shape[1])
     for layer, kind, j in _kinds(cfg):
-        if kind == "mamba":
+        if kind == "latent_attention":
+            lp = _row(params["latent"], j)
+            out, rows = _attention_latent_full(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind])
+            if collect:
+                lat.append(rows)
+        elif kind == "mamba":
             lp = _row(params["mamba"], j)
             out, conv, ssm = mamba2_prefill(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]))
@@ -318,7 +411,7 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
                 vs.append(v)
         h = h + cfg.residual_multiplier * out
         h, _ = _ffn(cfg, params["moe"][layer], h)
-    return h, (ks, vs, convs, ssms, wks, wvs)
+    return h, (ks, vs, convs, ssms, wks, wvs, lat)
 
 
 def forward_hybrid(cfg: ModelConfig, params: dict, ids):
@@ -335,14 +428,19 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
                    last_only: bool = False):
     """The prompt's forward that also fills the decode cache: (logits
     (B, S, V) float32 — (B, V) of the last position with ``last_only`` —,
-    :class:`HybridCache` with length S)."""
+    :class:`HybridCache`, :class:`WindowCache` or :class:`LatentCache` with
+    length S)."""
     b, s = ids.shape
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
                          f"{capacity}]")
-    h, (ks, vs, convs, ssms, wks, wvs) = _walk_full(cfg, params, ids,
-                                                    collect=True)
+    h, (ks, vs, convs, ssms, wks, wvs, lat) = _walk_full(cfg, params, ids,
+                                                         collect=True)
     logits = unembed_hybrid(cfg, params, h[:, -1] if last_only else h)
+    if lat:
+        return logits, LatentCache(
+            jnp.pad(jnp.stack(lat), ((0, 0), (0, 0), (0, capacity - s),
+                                     (0, 0))), jnp.asarray(s, jnp.int32))
     kv_shape = (0, b, s, cfg.num_kv_heads, cfg.head_dim)
     pad = ((0, 0), (0, 0), (0, capacity - s), (0, 0), (0, 0))
     k = jnp.pad(_stack(ks, kv_shape, h.dtype), pad)
@@ -365,6 +463,8 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     b = token_ids.shape[0]
     pos = cache.length
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
+    if isinstance(cache, LatentCache):
+        return _decode_step_latent(cfg, params, cache, h)
     windowed = isinstance(cache, WindowCache)
     conv_all, ssm_all = (None, None) if windowed else (cache.conv, cache.ssm)
     # the rows a kind's layers append to: full layers k / v, sliding wk / wv
@@ -403,6 +503,25 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     return logits, HybridCache(*rows["attention"], pos + 1, conv_all, ssm_all)
 
 
+def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
+                        h):
+    """:func:`decode_step_hybrid` for a stack of latent layers: h (B, D) the
+    embedded tokens."""
+    pos, rows = cache.length, cache.rows
+    rope = tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1) for x in
+                 _rope_tables(cfg, cache.capacity)["latent_attention"])
+    for layer, _, j in _kinds(cfg):
+        lp = _row(params["latent"], j)
+        out, rows_j = _attention_latent_step(
+            cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope, rows[j], pos)
+        rows = rows.at[j].set(rows_j)
+        h = h + cfg.residual_multiplier * out
+        h, _ = _ffn(cfg, params["moe"][layer], h)
+    return unembed_hybrid(cfg, params, h), LatentCache(rows, pos + 1)
+
+
+@graph_contract("paged.decode_step_latent", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 2))
 @graph_contract("paged.decode_step_hybrid", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 5))
 @graph_contract("paged.decode_step_window", collectives={},
@@ -425,7 +544,11 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
     (L_window, window pool pages, page_size, KV * hd), window_table
     (max_slots, window_pages): each slot's ring) and gets (win_k, win_v) back
     as a seventh result; it has no mamba layer, and conv_all / ssm_all are
-    None both ways."""
+    None both ways.
+
+    A stack of latent layers passes its pool's ONE leaf (L, num_pages,
+    page_size, kv_row_lanes) as ``pool_k``; ``pool_v``, conv_all and ssm_all
+    are None both ways."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
@@ -438,7 +561,12 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
     if window is not None:
         win_k, win_v, window_table = window
     for layer, kind, j in _kinds(cfg):
-        if kind == "mamba":
+        if kind == "latent_attention":
+            lp = _row(params["latent"], j)
+            out, (pool_k,) = _attention_decode_latent(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), *rope[kind],
+                LatentPool(pool_k), j, page_table, lengths)
+        elif kind == "mamba":
             lp = _row(params["mamba"], j)
             conv_all, ssm_all, out = _step_row(cfg, lp, h, conv_all, ssm_all,
                                                j)
@@ -473,8 +601,8 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     state matters (``A_log = log U[1, 16]``, ``dt_bias = softplus^-1(dt)``
     with ``dt`` log-uniform in [0.001, 0.1], ``D = 1``, the convolution
     uniform in +-1/sqrt(d_conv)). A kind the stack has no layer of has no
-    entry (``mamba``; ``window``), nor has an absent shared expert or a tied
-    head."""
+    entry (``mamba``; ``window``; ``attn``; ``latent``), nor has an absent
+    shared expert or a tied head."""
     keys = iter(jax.random.split(key, 16 + 8 * cfg.num_layers))
 
     def init(*shape):
@@ -523,7 +651,22 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
             "norm_scale": jnp.ones((lm, di), dtype),
             "w_out": init(lm, di, d),
         }
-    params["attn"] = attention(la)
+    if cfg.latent_layers:
+        n, h = cfg.latent_layers, cfg.num_heads
+        rank = cfg.kv_lora_rank
+        params["latent"] = {
+            "ln1_scale": jnp.ones((n, d), dtype),
+            "wq_a": init(n, d, cfg.q_lora_rank),
+            "q_norm": jnp.ones((n, cfg.q_lora_rank), dtype),
+            "wq_b": init(n, cfg.q_lora_rank, h * hd),
+            "wkv_a": init(n, d, rank + cfg.qk_rope_head_dim),
+            "kv_norm": jnp.ones((n, rank), dtype),
+            "wkv_b": init(n, rank, h * (cfg.qk_nope_head_dim
+                                        + cfg.v_head_dim)),
+            "wo": init(n, h * cfg.v_head_dim, d),
+        }
+    else:
+        params["attn"] = attention(la)
     params["moe"] = [{
         "ln2_scale": jnp.ones((d,), dtype),
         "router": init(d, cfg.num_experts),

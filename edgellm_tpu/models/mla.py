@@ -1,0 +1,117 @@
+"""Latent attention (MLA): the projections of a ``latent_attention`` layer
+and the two forms its attention takes. The walk (``models/hybrid.py``) and
+the page pool (``models/paged_kv.py``) call these; nothing here knows a
+cache.
+
+With ``x = rms(h; w1)`` and H heads of ``nope + rope`` query / key lanes and
+``vd`` value lanes::
+
+    c_q = rms(x W_qa; g_q)                  (q_lora_rank)
+    q_h = c_q W_qb = [q_nope_h | q_rope_h]  (nope | rope)
+    [c_kv | k_rope] = x W_kva               (kv_lora_rank | rope)
+    c = rms(c_kv; g_kv)
+    [k_nope_h | v_h] = c W_kvb^h            (nope | vd)
+
+``q_rope_h`` and the ONE ``k_rope`` all heads share are rotated in
+interleaved pairs (lanes 2i, 2i+1 by frequency i; stored de-interleaved,
+evens then odds, on both sides, so every dot product is the pair rotation's).
+The cached row of a position is ``[c | k_rope | 0...]`` (``cfg.kv_row_lanes``
+lanes): what both forms read.
+
+- **expanded** (:func:`expand`; forward and prefill): ``k_nope_h`` and ``v_h``
+  rebuilt for every head from ``c``; ``score_h = q_h . [k_nope_h | k_rope]``.
+- **absorbed** (:func:`absorb_query`, :func:`unabsorb`; every decode step):
+  ``W_kvb``'s K half folded into the query, ``q~_h = W_kvb^{K,h} q_nope_h``
+  (kv_lora_rank), so ``score_h = [q~_h | q_rope_h] . row`` is multi-query
+  attention of H heads over the row as it is cached, and its V half applied
+  after the weighted sum of rows: ``o_h = W_kvb^{V,h T} sum_j p_j c(j)``. No
+  per-head key or value of a cached position exists.
+
+Scores are scaled by ``(nope + rope)^-1/2`` (the attends' own), times
+``softmax_mscale^2`` and the query position's ``1 + beta ln(1 + floor(pos /
+original_max))``, both folded into the query (:func:`query_scale`).
+"""
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+from .configs import ModelConfig
+from .transformer import _rmsnorm, _rotate_half, deinterleave_pairs
+
+
+def query_scale(cfg: ModelConfig, positions):
+    """What a query at ``positions`` (any shape, int) is multiplied by beside
+    the attends' ``head_dim^-1/2``: float32, same shape."""
+    scale = jnp.full(positions.shape, cfg.softmax_mscale ** 2, jnp.float32)
+    if not cfg.query_scale_beta:
+        return scale
+    original_max = cfg.rope_scaling[2]
+    return scale * (1.0 + cfg.query_scale_beta * jnp.log1p(
+        (positions // original_max).astype(jnp.float32)))
+
+
+def rotate_rows(cos, sin):
+    """The rotation of (B, heads, rope) arrays by ONE table row a sequence:
+    cos, sin (B, rope), each slot's own position's, or (1, rope)."""
+    def rotate(t):
+        return (t * cos[:, None, :].astype(t.dtype)
+                + _rotate_half(t) * sin[:, None, :].astype(t.dtype))
+    return rotate
+
+
+def _pad_lanes(cfg: ModelConfig, x):
+    """(..., kv_lora_rank + rope) -> (..., kv_row_lanes), zeros after."""
+    pad = cfg.kv_row_lanes - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+def project(cfg: ModelConfig, lp: dict, x, rotate, scale):
+    """x (..., D) normalised -> (q_nope (..., H, nope), q_rope (..., H, rope),
+    row (..., kv_row_lanes)): the queries scaled by ``scale`` (...,) float32
+    (:func:`query_scale`) and ``q_rope`` / the row's ``k_rope`` rotated by
+    ``rotate`` (a function of (..., heads, rope) arrays, de-interleaved first
+    here), the row as it is cached."""
+    rope, rank = cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    c_q = _rmsnorm(x @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
+    q = (c_q @ lp["wq_b"]).reshape(*x.shape[:-1], cfg.num_heads, cfg.head_dim)
+    q = q * scale[..., None, None].astype(q.dtype)
+    kv = x @ lp["wkv_a"]
+    c = _rmsnorm(kv[..., :rank], lp["kv_norm"], cfg.norm_eps)
+    k_rope = rotate(deinterleave_pairs(kv[..., None, rank:]))[..., 0, :]
+    row = _pad_lanes(cfg, jnp.concatenate([c, k_rope], axis=-1))
+    return (q[..., :-rope], rotate(deinterleave_pairs(q[..., -rope:])), row)
+
+
+def _kvb(cfg: ModelConfig, lp: dict):
+    """``W_kvb`` (rank, H, nope + vd): K lanes first, then V."""
+    return lp["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads,
+                               cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def expand(cfg: ModelConfig, lp: dict, rows):
+    """Cached rows (B, S, kv_row_lanes) -> per-head (k (B, S, H, nope + rope),
+    v (B, S, H, vd)): the expanded form's keys and values."""
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    kv = jnp.einsum("bsc,chn->bshn", rows[..., :rank], _kvb(cfg, lp))
+    k_rope = jnp.broadcast_to(rows[..., None, rank:rank + rope],
+                              (*kv.shape[:3], rope))
+    nope = cfg.qk_nope_head_dim
+    return (jnp.concatenate([kv[..., :nope], k_rope], axis=-1),
+            kv[..., nope:])
+
+
+def absorb_query(cfg: ModelConfig, lp: dict, q_nope, q_rope):
+    """(B, H, nope), (B, H, rope) -> the query over a cached row, (B, H,
+    kv_row_lanes): ``[W_kvb^{K,h} q_nope_h | q_rope_h | 0...]``."""
+    wk = _kvb(cfg, lp)[..., :cfg.qk_nope_head_dim]
+    qc = jnp.einsum("bhn,chn->bhc", q_nope, wk,
+                    preferred_element_type=jnp.float32).astype(q_nope.dtype)
+    return _pad_lanes(cfg, jnp.concatenate([qc, q_rope], axis=-1))
+
+
+def unabsorb(cfg: ModelConfig, lp: dict, ctx):
+    """The weighted sums of cached rows (B, H, kv_row_lanes) -> the layer's
+    output (B, D): ``W_kvb``'s V half on the latent lanes, then ``W_o``."""
+    wv = _kvb(cfg, lp)[..., cfg.qk_nope_head_dim:]
+    out = jnp.einsum("bhc,chv->bhv", ctx[..., :cfg.kv_lora_rank], wv)
+    return out.reshape(ctx.shape[0], -1) @ lp["wo"]

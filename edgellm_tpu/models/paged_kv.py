@@ -65,6 +65,15 @@ and :func:`attend_rows`; the ring's attend masks a row by the ABSOLUTE
 position it holds (:func:`ring_positions`, :func:`window_valid`), and keys are
 rotated before they are stored, so ring order does not matter to the softmax.
 
+A latent row (PERF.md §6 "PR 32"): a stack of latent-attention layers
+(``models/mla.py``) caches ONE row a position a layer for all its heads, ``[c
+| k_rope]`` zero-padded to whole lane tiles (``cfg.kv_row_lanes``: 320 ->
+384), in a pool of ONE leaf (:class:`LatentPool`) in the same page group, by
+the same table and allocator as full layers' K/V: pages grow with the stream,
+the surgery below runs over the one leaf, :func:`write_rows` and
+:func:`_gather_pages` address it as they do K or V, and
+:func:`attend_latent` stands beside :func:`attend_rows`.
+
 Neither half helps alone. The lane-dense row with ``.at[:, dest]`` still
 costs two whole-pool copies a leaf (the compiler moves L under the row
 axis), and on the staged pool, whose (2, 128) tail was already compact and
@@ -83,7 +92,8 @@ import jax
 import jax.numpy as jnp
 
 from ..lint import graph_contract
-from .configs import ModelConfig
+from . import mla
+from .configs import LANE_TILE, ModelConfig
 from .flash_attention import dequantize_kv_rows, quantize_kv_rows
 from .transformer import (_cast_params, _layernorm, _rmsnorm, _rotate_half,
                           embed, mlp, precompute_rope, unembed)
@@ -358,18 +368,40 @@ class PagePool(NamedTuple):
         return self.k.shape[-2]
 
 
+class LatentPool(NamedTuple):
+    """Device-side page pool of a stack of latent-attention layers: ONE leaf.
+
+    rows: (L, num_pages, page_size, cfg.kv_row_lanes): a position's
+    normalised latent ``c``, then its post-rotary ``k_rope`` (shared by all
+    heads), then zeros up to whole 128-lane tiles. Pages, table, trash page
+    and flat index are :class:`PagePool`'s."""
+
+    rows: jnp.ndarray
+
+    @property
+    def num_pages(self) -> int:
+        return self.rows.shape[-3]
+
+    @property
+    def page_size(self) -> int:
+        return self.rows.shape[-2]
+
+
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
-              dtype=jnp.float32, layers: Optional[int] = None) -> PagePool:
+              dtype=jnp.float32, layers: Optional[int] = None):
     """An all-zero pool; ``num_pages`` INCLUDES the reserved trash page 0,
     so ``num_pages - 1`` pages are allocatable. ``layers``: how many layers
-    it serves where that is not ``cfg.kv_layers`` (the window group's)."""
+    it serves where that is not ``cfg.kv_layers`` (the window group's). A
+    :class:`PagePool`, or a :class:`LatentPool` for latent layers."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is reserved), "
                          f"got {num_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
     shape = (cfg.kv_layers if layers is None else layers, num_pages,
-             page_size, cfg.num_kv_heads * cfg.head_dim)
+             page_size, cfg.kv_row_lanes)
+    if cfg.latent_layers:
+        return LatentPool(jnp.zeros(shape, dtype))
     return PagePool(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -481,7 +513,7 @@ def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
 def pool_tier(pool) -> str:
     """The ``kv_codec`` name of a pool (whole, staged or one layer's): the
     one place a tier is read from, its type and the width of its codes."""
-    if isinstance(pool, PagePool):
+    if isinstance(pool, (PagePool, LatentPool)):
         return "fp"
     return next(c.name for c in KV_PAGE_CODECS.values()
                 if c.quantized and pool.k.dtype == c.code_dtype)
@@ -492,6 +524,14 @@ def kv_page_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
     """HBM bytes ONE page costs across all layers (K + V, codes + scales) —
     the honest per-tier footprint the capacity accounting below divides by."""
     codec = resolve_kv_codec(kv_codec)
+    if cfg.latent_layers:
+        if codec.quantized:
+            from .hybrid import refuse_latent_rows
+
+            refuse_latent_rows(cfg, f"the quantized KV tier kv_codec="
+                                    f"{kv_codec!r}")
+        return (cfg.kv_layers * page_size * cfg.kv_row_lanes
+                * jnp.dtype(dtype).itemsize)
     return (2 * cfg.num_layers * page_size * cfg.num_kv_heads
             * codec.row_bytes(cfg.head_dim, dtype))
 
@@ -646,6 +686,13 @@ def _adopt_impl(pool, k_seq, v_seq, dest, head: Optional[int] = None):
     return adopt_at(pool, k_seq, v_seq, dest, 1, head)
 
 
+@functools.partial(jax.jit, static_argnames=("head",), donate_argnums=(0,))
+def _adopt_latent_impl(pool, rows, dest, head: Optional[int] = None):
+    """:func:`_adopt_impl` for a :class:`LatentPool`: (L, S, kv_row_lanes)
+    rows, as stored, at the flat token indices ``dest``."""
+    return _set_rows(pool, (rows,), dest, 1, head)
+
+
 @functools.partial(jax.jit, static_argnames=("lead", "head"),
                    donate_argnums=(0,))
 def _adopt_packed_impl(pool, k_codes, v_codes, k_scale, v_scale, dest,
@@ -694,6 +741,13 @@ def _gather_impl(pool, idx, lead: int = 1, *, kv: int):
         return k, v
     return (dequantize_kv_rows(k, scales[0], tier),
             dequantize_kv_rows(v, scales[1], tier))
+
+
+@jax.jit
+def _gather_latent_impl(pool, idx):
+    """A :class:`LatentPool`'s rows at ``idx`` as stored: (L, span,
+    kv_row_lanes)."""
+    return _get_rows(pool, idx, 1)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
@@ -1324,6 +1378,25 @@ class PagedKVCache:
         return self.max_slots * self.window_pages * self.page_size
 
     @property
+    def latent_rows_capacity(self) -> int:
+        """Rows the pages can hold, a latent layer (0: no latent layer)."""
+        return self.token_capacity if self.cfg.latent_layers else 0
+
+    @property
+    def latent_rows_live(self) -> int:
+        """Latent rows of live streams, a latent layer."""
+        return self.live_tokens if self.cfg.latent_layers else 0
+
+    @property
+    def kv_row_bytes(self) -> int:
+        """Device bytes ONE position keeps in ONE layer of the page pool, as
+        stored (every leaf, codes and scales, lane padding included); 0 for
+        a bookkeeping-only cache."""
+        if self.pool is None:
+            return 0
+        return sum(a.shape[-1] * a.dtype.itemsize for a in self.pool)
+
+    @property
     def window_rows_live(self) -> int:
         """Ring rows inside some stream's window, a window layer."""
         return int(np.minimum(self.lengths[self.active],
@@ -1367,10 +1440,12 @@ class PagedKVCache:
                              f"(bookkeeping-only)")
 
     def _refuse_window(self, what: str) -> None:
-        if self.window_pages:
-            from .hybrid import refuse_window_ring
+        """Refuse a mechanism that reads the pool as K and V of every
+        position (the name is older than the latent rows)."""
+        from .hybrid import refuse_latent_rows, refuse_window_ring
 
-            refuse_window_ring(self.cfg, what)
+        refuse_window_ring(self.cfg, what)
+        refuse_latent_rows(self.cfg, what)
 
     def _flat_indices(self, slot: int, n: int) -> np.ndarray:
         pos = np.arange(n)
@@ -1389,6 +1464,18 @@ class PagedKVCache:
         dest = jnp.asarray(self._flat_indices(slot, length))
         self.pool = _adopt_impl(self.pool, jnp.asarray(k_seq),
                                 jnp.asarray(v_seq), dest, head=0)
+        self.lengths[slot] = length
+
+    def adopt_latent(self, slot: int, rows, length: int) -> None:
+        """:meth:`adopt` for a :class:`LatentPool`: a contiguous (L, length,
+        kv_row_lanes) prefix of latent rows as stored (a prefill's cache, or
+        an evicted stream's gathered rows) into ``slot``'s pages."""
+        self._require_pool("adopt_latent")
+        self.ensure(slot, length)
+        self.prepare_write(slot, length, start=0)
+        dest = jnp.asarray(self._flat_indices(slot, length))
+        self.pool = _adopt_latent_impl(self.pool, jnp.asarray(rows), dest,
+                                       head=0)
         self.lengths[slot] = length
 
     def adopt_rows(self, slot: int, k_seq, v_seq,
@@ -1465,10 +1552,16 @@ class PagedKVCache:
         "length"} — byte-identical to the contiguous cache prefix on the fp
         tier; on quantized tiers the rows come back DEQUANTIZED to fp32
         (the suffix-prefill compute path — use :meth:`gather_slot_packed`
-        when the bytes themselves must survive)."""
+        when the bytes themselves must survive). A latent stack's payload is
+        {"rows": (L, length, kv_row_lanes), "length"}, bytes as stored."""
         self._require_pool("gather_slot")
         n = int(self.lengths[slot])
         idx = jnp.asarray(self._flat_indices(slot, max(n, 1)))
+        if isinstance(self.pool, LatentPool):
+            # a latent stack's rows as stored, what adopt_latent takes back
+            return {"rows": np.asarray(_gather_latent_impl(self.pool,
+                                                           idx))[:, :n],
+                    "length": np.asarray(n, np.int32)}
         k, v = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
         return {"k": np.asarray(k)[:, :n], "v": np.asarray(v)[:, :n],
                 "length": np.asarray(n, np.int32)}
@@ -1715,6 +1808,18 @@ class PagedKVCache:
             live = self.window_table[self.window_table > 0]
             assert len(live) == len(set(live.tolist())), \
                 "a window page in two rings"
+        assert isinstance(self.pool, LatentPool) == bool(
+            self.cfg.latent_layers and self.pool is not None), \
+            "a one-leaf latent pool exists exactly for a stack of latent layers"
+        if self.cfg.latent_layers and self.pool is not None:
+            want = (self.cfg.kv_layers, self.num_pages, self.page_size,
+                    self.cfg.kv_row_lanes)
+            assert self.pool.rows.shape == want, \
+                f"latent pool {self.pool.rows.shape} != {want}"
+            assert self.cfg.kv_row_lanes % LANE_TILE == 0 and \
+                self.cfg.kv_row_lanes >= self.cfg.kv_lora_rank + \
+                self.cfg.qk_rope_head_dim, \
+                "a latent row is whole lane tiles that hold c and k_rope"
         if self.state is not None:
             from .hybrid import state_shapes
 
@@ -1838,12 +1943,15 @@ def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
     lies, no layer is sliced out of it and none is put back. The only code
     that knows where in a pool a decode step's row goes.
 
+    A :class:`LatentPool` takes its ONE row a slot as ``k`` (B, 1,
+    kv_row_lanes), as stored, and ``v`` None.
+
     ``ring`` (static): the table is a window layer's RING of pages, and
     position p lives in entry ``(p // ps) % entries``: the row written
     overwrites one that has left the window."""
     tier = pool_tier(pool)
-    if tier == "fp":
-        stored = (k[:, 0], v[:, 0])
+    if tier == "fp":            # a row a leaf: K and V, or the one latent row
+        stored = tuple(r[:, 0] for r in (k, v) if r is not None)
     else:
         qk, sk = quantize_kv_rows(k[:, 0], tier)  # (B,KV,hdc), (B,KV)
         qv, sv = quantize_kv_rows(v[:, 0], tier)
@@ -1969,6 +2077,45 @@ def attend_rows(q, k_rows, v_rows, lengths, valid=None):
                      preferred_element_type=jnp.float32).astype(q.dtype)
     out = jnp.where(own[None, :, :, None], out.reshape(b, h, kv, hd), 0)
     return out.sum(axis=2).reshape(b, 1, h, hd)
+
+
+def attend_latent(q_rows, rows, lengths, head_dim: int):
+    """Single-position multi-query attention of H heads over latent rows as
+    the pool stores them (the ABSORBED form, ``models/mla.py``): q_rows (B,
+    H, lanes) from ``mla.absorb_query``; rows (B, span, lanes), the SAME
+    array keys and values (a row's latent lanes are both); lengths (B,)
+    valid positions a slot. Returns the weighted sums of rows (B, H, lanes)
+    in q's dtype, which ``mla.unabsorb`` takes; scores times
+    ``head_dim^-1/2`` (the query / key head's width, what the expanded form
+    divides by), softmax in fp32. No (B, span, H, ...) tensor exists: the
+    widest are the (B, H, span) scores."""
+    scores = jnp.einsum("bhD,bcD->bhc", q_rows, rows,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (1.0 / np.sqrt(head_dim))
+    valid = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
+    scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhc,bcD->bhD", probs.astype(q_rows.dtype), rows,
+                      preferred_element_type=jnp.float32
+                      ).astype(q_rows.dtype)
+
+
+@jax.named_scope("attn.latent")
+def _attention_decode_latent(cfg: ModelConfig, lp: dict, x, cos_b, sin_b,
+                             pool: LatentPool, layer, page_table, lengths):
+    """A latent-attention layer of the ragged step, ABSORBED: x (B, D)
+    normalised; project and rotate each slot at ITS position, write its new
+    row into its current page (``paged_kv.write``), gather each slot's pages
+    and attend them as they lie, then the V half of ``W_kvb`` and ``W_o``.
+    Returns (out (B, D), pool)."""
+    q_nope, q_rope, row = mla.project(cfg, lp, x,
+                                      mla.rotate_rows(cos_b, sin_b),
+                                      mla.query_scale(cfg, lengths))
+    pool = write_rows(pool, layer, page_table, lengths, row[:, None], None)
+    ctx = attend_latent(mla.absorb_query(cfg, lp, q_nope, q_rope),
+                        _gather_pages(pool.rows, layer, page_table),
+                        lengths + 1, cfg.head_dim)
+    return mla.unabsorb(cfg, lp, ctx), pool
 
 
 def paged_decode_attention(q, pool, layer, page_table, lengths,

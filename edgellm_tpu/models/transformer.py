@@ -127,6 +127,15 @@ def _rotate_half(x):
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
 
 
+def deinterleave_pairs(x):
+    """(..., 2n) lanes (x0, x1, x2, ...) -> (x0, x2, ..., x1, x3, ...): after
+    it, the half-split rotation of :func:`apply_rotary` IS the rotation of
+    the interleaved pairs (2i, 2i+1) by frequency i (``rope_interleave``).
+    Applied to queries and keys alike, so their dot products are the pair
+    rotation's."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, rot: int) -> jnp.ndarray:
     """Apply rotary embedding to the first ``rot`` dims of the head dimension.
 

@@ -191,6 +191,11 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "attn.decode",        # the paged decode attention of one layer
     "attn.window",        # ... of one sliding-window layer: its projections,
                           # the ring's row write, page gather and attend
+    "attn.latent",        # ... of one latent layer, absorbed: projections,
+                          # rotation, absorption, page gather, attend, the
+                          # V up-projection and the output projection
+    "attn.latent.expand",  # a latent layer's prefill: keys and values
+                           # rebuilt per head, attention by query blocks
     "mlp",
     "unembed_sample",     # final norm + LM head + the per-slot sampler
     "split.stage",        # one stage iteration of the split unroll

@@ -59,7 +59,11 @@ module schedules many streams through ONE jitted decode step built on
   with the second page group of a ``mellum`` stack (each sliding-window
   layer's ring of pages, ``PagedKVCache.window_pool`` / ``window_table``):
   admission adopts a prompt's tail into the rings, eviction gathers them with
-  the full layers' rows, and what cannot carry them refuses by name.
+  the full layers' rows, and what cannot carry them refuses by name. A
+  ``mistral4`` stack rides ``_batched_hybrid_step_jit`` too: its pool is ONE
+  leaf of latent rows (``paged_kv.LatentPool``), handed over where the K
+  pages go, with no V pages and no state store; admission adopts the
+  prefill's rows (``adopt_latent``) and eviction gathers them as stored.
 
 ``ServeFront`` integration lives in ``serve/frontend.py`` (``batcher=``):
 admission control, brownout and breakers all apply before a request reaches
@@ -245,7 +249,9 @@ def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
     (``models/hybrid.py``): the K/V pages of the attention layers, the
     per-slot state store of the mamba layers and the per-expert assignment
     counter are all donated and come back updated. A SEPARATE jit: the
-    one-block families keep the executable above."""
+    one-block families keep the executable above. A stack of latent layers
+    takes it with its pool's one leaf as ``pool_k`` and None for ``pool_v``,
+    ``conv`` and ``ssm``, which come back None."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
@@ -586,6 +592,8 @@ class ContinuousBatcher:
                     self.pool.adopt_packed(
                         slot, st.resume["k_codes"], st.resume["v_codes"],
                         st.resume["k_scale"], st.resume["v_scale"], need_len)
+                elif "rows" in st.resume:
+                    self.pool.adopt_latent(slot, st.resume["rows"], need_len)
                 else:
                     self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
                                     jnp.asarray(st.resume["v"]), need_len)
@@ -642,7 +650,11 @@ class ContinuousBatcher:
                 tok0 = _sample(last_logits, jax.random.fold_in(st.key, 0),
                                st.temperature)
             with obs_phase("batch.admit.adopt", sid=sid):
-                self.pool.adopt(slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s)
+                if self.cfg.latent_layers:
+                    self.pool.adopt_latent(slot, cache.rows[:, 0, :s], s)
+                else:
+                    self.pool.adopt(slot, cache.k[:, 0, :s],
+                                    cache.v[:, 0, :s], s)
                 if self.cfg.recurrent_state:
                     # the other kind of state a prefill hands on
                     self.pool.adopt_state(slot, cache.conv[:, 0],
@@ -1016,18 +1028,23 @@ class ContinuousBatcher:
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
             elif self.cfg.is_hybrid:
-                state = self.pool.state
+                # K and V pages and the state store, or a latent stack's one
+                # leaf with no V pages and no state
+                pool, state = self.pool.pool, self.pool.state
+                k, v = pool if len(pool) == 2 else (pool[0], None)
+                conv, ssm = state if state is not None else (None, None)
                 toks, k, v, conv, ssm, self._expert_tokens = (
                     _batched_hybrid_step_jit(
-                        self.cfg, self.params, self.pool.pool.k,
-                        self.pool.pool.v, state.conv, state.ssm,
+                        self.cfg, self.params, k, v, conv, ssm,
                         self._expert_tokens, page_table, lengths,
                         jnp.asarray(token_ids), jnp.asarray(key_data),
                         jnp.asarray(steps), jnp.asarray(temps),
                         self.bcfg.compute_dtype))
-                self.pool.pool = type(self.pool.pool)(k, v)
-                self.pool.state = SlotState(conv, ssm)
-                del state
+                self.pool.pool = (type(pool)(k) if v is None
+                                  else type(pool)(k, v))
+                if state is not None:
+                    self.pool.state = SlotState(conv, ssm)
+                del pool, state
             else:
                 toks, self.pool.pool = _batched_step_jit(
                     self.cfg, self.params, self.pool.pool, page_table,
@@ -1272,6 +1289,11 @@ class ContinuousBatcher:
                 # inside some stream's window now, and all the rings hold
                 "window_rows_live": self.pool.window_rows_live,
                 "window_rows_capacity": self.pool.window_rows_capacity,
+                # a latent stack's rows, a latent layer: of live streams, and
+                # what the pages hold; a position's stored bytes a layer
+                "latent_rows_live": self.pool.latent_rows_live,
+                "latent_rows_capacity": self.pool.latent_rows_capacity,
+                "kv_row_bytes": self.pool.kv_row_bytes,
                 "expert_tokens": tokens.tolist(),
                 "routed_assignments": int(stats["routed_assignments"]),
                 "routed_local": int(tokens.sum())}

@@ -328,3 +328,76 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
     # MB) and little else; the parent's stacked and selected pools were 5.45
     assert mem.temp_size_in_bytes < 0.5e9
     assert mem.alias_size_in_bytes >= 2 * sz * layer_pool * 2   # donated
+
+
+# benchmark/configs/mistral-small-4-119b-ep4.json: the widths, the chip's
+# share (32 of 128 experts, a quarter of the vocabulary, 4 layers) and the
+# serving geometry of the cell
+MISTRAL4 = ModelConfig(
+    family="mistral4", vocab_size=32768, hidden_size=4096, num_layers=4,
+    num_heads=32, num_kv_heads=32, intermediate_size=12288,
+    max_position_embeddings=1048576, norm_eps=1e-6, rope_theta=10000.0,
+    rope_scaling=("yarn", 128.0, 8192, 32.0, 1.0, 1.0),
+    layer_types=("latent_attention",) * 4, explicit_head_dim=128,
+    num_experts=128, experts_per_tok=4, expert_width=2048, shared_width=2048,
+    experts_held=32, q_lora_rank=1024, kv_lora_rank=256, qk_rope_head_dim=64,
+    v_head_dim=128, softmax_mscale=1.4852030263919618, query_scale_beta=0.1)
+L_SLOTS, L_PAGES_PER_SLOT = 96, 768
+
+
+def _latent_step(one):
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(MISTRAL4, jax.random.key(0), dtype=jnp.bfloat16)),
+        one)
+    pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        MISTRAL4, L_SLOTS * L_PAGES_PER_SLOT + 1, PAGE, jnp.bfloat16)), one)
+    assert pool.rows.shape == (4, 73729, PAGE, 384)     # 320 lanes, padded
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((L_SLOTS,), jnp.int32)
+    return batching._batched_hybrid_step_jit.lower(
+        MISTRAL4, params, pool.rows, None, None, None,
+        arr((4, 32), jnp.int32), arr((L_SLOTS, L_PAGES_PER_SLOT), jnp.int32),
+        ints, ints, arr((L_SLOTS, 2), jnp.uint32), ints,
+        arr((L_SLOTS,), jnp.float32), None).compile()
+
+
+def test_latent_step_is_absorbed_and_its_one_leaf_pool_stays_in_place(topo):
+    """The step of a stack of latent layers at the cell's shapes: ONE gather
+    a layer of each slot's span of 384-lane rows out of the one-leaf pool,
+    which no copy, relayout or stacking touches; no tensor with a (96, 12288,
+    32, ...) shape exists (keys and values are never rebuilt per head: the
+    absorption is real), and the gathers' own outputs are the only span-sized
+    temporaries (0.9 GB a layer, one alive at a time, beside 11.0 GB of
+    weights and pool)."""
+    step = _latent_step(SingleDeviceSharding(topo.devices[0]))
+    hlo = step.as_text()
+    span = L_PAGES_PER_SLOT * PAGE
+    gathered = L_SLOTS * span * 384
+    gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+               if op == "gather" and _elements(shape) >= gathered]
+    rows = f"bf16[{L_SLOTS},{L_PAGES_PER_SLOT},{PAGE},384]"
+    assert gathers == [rows] * 4, gathers
+    own = {rows, f"bf16[{L_SLOTS * L_PAGES_PER_SLOT},{PAGE},384]",
+           f"bf16[{L_SLOTS},{span},384]"}
+    moved = [m for m in _moved(hlo, gathered)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    assert not moved, moved
+    # nothing per head over the span: the widest span-sized tensors beside
+    # the gathered rows are the (slots, heads, span) scores
+    per_head = re.findall(rf"\[{L_SLOTS},{span},32,\d+\]"
+                          rf"|\[{L_SLOTS},32,{span},\d+\]", hlo)
+    assert not per_head, per_head[:3]
+    big = {shape.split("{")[0] for _, _, shape, _ in _instructions(hlo)
+           if _elements(shape) >= gathered and not shape.startswith("(")}
+    assert big <= own | {f"bf16[4,73729,{PAGE},384]",
+                         f"bf16[{4 * 73729 * PAGE},384]",
+                         f"bf16[{4 * 73729},{PAGE},384]"}, big
+    # the pool is addressed flat: pages at (layer, page), rows at (l, p, r)
+    assert f"bf16[{4 * 73729},{PAGE},384]" in hlo
+    assert f"bf16[{4 * 73729 * PAGE},384]" in hlo
+    mem = step.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
