@@ -111,9 +111,12 @@ def dispatch_phase(cfg, param_dtype, *, prompt_lens=PROMPT_LENS,
     """Which attention plan and which codec implementation each site of the
     run takes — the same gate functions the model code calls, at the same
     shapes."""
+    import jax
     import jax.numpy as jnp
 
     from edgellm_tpu.models.flash_attention import kernel_plan
+    from edgellm_tpu.models.paged_kv import decode_read_path, init_pool
+    from edgellm_tpu.serve.batching import BatchingConfig
 
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     itemsize = jnp.dtype(param_dtype).itemsize
@@ -124,9 +127,13 @@ def dispatch_phase(cfg, param_dtype, *, prompt_lens=PROMPT_LENS,
     sites.append({"site": "sweep.forward", "seq": sweep_len,
                   "plan": kernel_plan(sweep_len, h, kv, hd,
                                       itemsize=itemsize)})
-    # one path: models.paged_kv.read_span + flash_attention.decode_attention
+    # the read paged_kv.decode_read_path picks for the pool the run serves
+    # from: the page walk on a TPU where a page is whole tiles
     sites.append({"site": "serve.decode[paged]", "seq": pages[0] * pages[1],
-                  "plan": "xla page gather"})
+                  "plan": decode_read_path(jax.eval_shape(
+                      lambda: init_pool(cfg, 2, pages[1],
+                                        BatchingConfig(**batching)
+                                        .cache_dtype)))})
     out = {"param_dtype": jnp.dtype(param_dtype).name, "attention": sites}
     if split is not None:
         from edgellm_tpu.codecs.pallas_kernels import fused_hop_plan

@@ -77,6 +77,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: one head's in-flight score/prob matrices must fit VMEM alongside the
 #: double-buffered blocks; S=1024 (4 MB fp32 scores) compile- and run-checked
@@ -602,3 +603,193 @@ def verify_attention(q, k_cache, v_cache, length):
     out = jnp.einsum("bqgrc,bcgd->bqgrd", probs.astype(q.dtype), v_cache,
                      preferred_element_type=jnp.float32).astype(q.dtype)
     return out.reshape(b, kq, h, hd)
+
+
+# ---------------------------------------------------------------------------
+# Paged decode attention: q_len=1 per slot against pages read where they lie.
+# ---------------------------------------------------------------------------
+
+#: rows of K (and of V) one block of the page walk holds in VMEM: the unit a
+#: slot's pages are fetched, waited for and attended in. PERF.md §6 "PR 33"
+#: has the v5e's timings from 64 to 1024 rows.
+WALK_BLOCK_ROWS = 512
+
+
+def paged_walk_pages_per_block(page_size: int, width: int,
+                               itemsize: int) -> int:
+    """Pages a block of :func:`paged_decode_walk` holds: ``WALK_BLOCK_ROWS``
+    rows, fewer where the row is wide, so that the four buffers (K and V,
+    two each) stay inside 4 MB of VMEM."""
+    rows = min(WALK_BLOCK_ROWS, (1 << 20) // (width * itemsize))
+    return max(rows // page_size, 1)
+
+
+def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       kbuf, vbuf, sem, par_ref, *, scale):
+    """Grid (B,): slot ``b`` of the step, every head. See
+    :func:`paged_decode_walk`."""
+    b = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    _, ppb, ps, w = kbuf.shape
+    rows = ppb * ps
+    leaves = ((k_hbm, kbuf, 0),) if v_hbm is None else (
+        (k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+
+    def live_pages(slot):
+        return jnp.maximum(pl.cdiv(len_ref[slot], ps), 1)
+
+    def num_pages(slot, blk):              # live pages of a slot's block
+        return jnp.minimum(live_pages(slot) - blk * ppb, ppb)
+
+    def start(slot, blk, buf):
+        """A DMA a live page a leaf. What bounds a saturated step is how
+        fast these are ISSUED (~15 ns each, PERF.md §6 "PR 33"), so the loop
+        is unrolled by two: 0.73 -> 0.63 ms a layer."""
+        cnt = num_pages(slot, blk)
+
+        def page(j):
+            at = ids_ref[slot, blk * ppb + j]
+            for hbm, dst, s in leaves:
+                pltpu.make_async_copy(hbm.at[at], dst.at[buf, j],
+                                      sem.at[s, buf]).start()
+
+        def pair(g, _):
+            page(2 * g)
+            page(2 * g + 1)
+            return 0
+
+        jax.lax.fori_loop(0, cnt // 2, pair, 0)
+
+        @pl.when(cnt % 2 == 1)
+        def _odd():
+            page(cnt - 1)
+
+    def wait(slot, blk, buf):
+        """A DMA semaphore counts bytes, so the block's pages are waited for
+        by the binary digits of their count: at most ``log2(ppb) + 1`` waits
+        a leaf in place of one a page (0.82 -> 0.73 ms a layer)."""
+        cnt = num_pages(slot, blk)
+        for bit in (1 << i for i in range(ppb.bit_length())):
+            @pl.when((cnt & bit) != 0)
+            def _wait(bit=bit):
+                for hbm, dst, s in leaves:
+                    pltpu.make_async_copy(
+                        hbm.at[pl.ds(0, bit)], dst.at[buf, pl.ds(0, bit)],
+                        sem.at[s, buf]).wait()
+
+    @pl.when(b == 0)
+    def _first():
+        start(0, 0, 0)
+
+    # which buffer this slot's first block landed in: the blocks before it,
+    # over all slots, mod 2 (scratch keeps it from one grid step to the next)
+    base = jnp.where(b == 0, 0, par_ref[0])
+    length = len_ref[b]
+    nblk = pl.cdiv(live_pages(b), ppb)
+    # a query and a pool of two dtypes meet in the wider, as the einsums of
+    # ``paged_kv.attend_rows`` promote them
+    wide = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    q = q_ref[0].astype(wide)                               # (H, W)
+    h = q.shape[0]
+
+    def body(n, carry):
+        m, l, acc = carry
+        buf = (base + n) % 2
+        more = n + 1 < nblk
+        # the next block's pages are in flight while this one is attended:
+        # this slot's, or the NEXT slot's first, so that no slot starts cold
+        nxt = jnp.where(more, b, jnp.minimum(b + 1, nslots - 1))
+
+        @pl.when(more | (b + 1 < nslots))
+        def _prefetch():
+            start(nxt, jnp.where(more, n + 1, 0), 1 - buf)
+
+        wait(b, n, buf)
+        k = kbuf[buf].reshape(rows, w).astype(wide)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        live = length - n * rows           # rows of this block a length covers
+        s = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1) < live,
+            s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        # rows no DMA filled are stale VMEM: 0 x NaN must not reach the sum
+        v = (kbuf if v_hbm is None else vbuf)[buf].reshape(rows, w)
+        v = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, w), 0) < live,
+            v, jnp.zeros_like(v))
+        pv = jax.lax.dot_general(p.astype(q_ref.dtype).astype(wide),
+                                 v.astype(wide),
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + pv)
+
+    m, l, acc = jax.lax.fori_loop(
+        0, nblk, body,
+        (jnp.full((h, 1), -1e30, jnp.float32), jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, w), jnp.float32)))
+    par_ref[0] = (base + nblk) % 2
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "pages_per_block", "interpret"))
+def paged_decode_walk(qz, k_pages, v_pages, page_ids, lengths, *,
+                      scale: float, pages_per_block: int | None = None,
+                      interpret=False):
+    """Single-position attention of every slot over ITS OWN live pages, read
+    out of the pool where they lie: ONE kernel in place of "gather every
+    slot's whole span into a copy, then two dots over the copy".
+
+    qz (B, H, W): a slot's query heads, each in the lanes of its KV group and
+    zero elsewhere (``paged_kv.attend_rows`` builds it: scores are one dot
+    over the whole lane-dense row). k_pages, v_pages (N, ps, W): a pool's
+    leaves viewed as pages, every layer's; they stay in HBM. ``v_pages``
+    None: the rows of ``k_pages`` are both key and value (a latent row), one
+    fetch a page. page_ids (B, pages_per_slot)
+    int32: slot i's pages in position order, as indices into N; lengths (B,)
+    int32: the positions slot i attends, >= 1. Returns (B, H, W) in qz's
+    dtype: ``softmax(scale * qz . rows^T) . rows`` in float32, of which a
+    head keeps its group's lanes.
+
+    Slot i fetches ``ceil(lengths[i] / ps)`` pages and no more, a block of
+    ``pages_per_block`` at a time through two VMEM buffers a leaf (a DMA a
+    page), with a running (max, sum, accumulator) softmax over the blocks.
+    The pipeline runs ACROSS slots: a slot's last block is attended while the
+    next slot's first is in flight. Rows past ``lengths[i]`` in the last
+    block are masked before the exponent and their V rows selected to zero,
+    so what the output holds does not depend on any page or row a length
+    does not cover.
+
+    Scalar prefetch puts ``page_ids`` and ``lengths`` in SMEM before the
+    body runs."""
+    b, h, w = qz.shape
+    ps = k_pages.shape[1]
+    ppb = pages_per_block or paged_walk_pages_per_block(
+        ps, w, k_pages.dtype.itemsize)
+    buf = pltpu.VMEM((2, ppb, ps, w), k_pages.dtype)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slot = pl.BlockSpec((1, h, w), lambda i, ids, lens: (i, 0, 0))
+    if v_pages is None:
+        kernel = lambda ids, lens, q, k, o, kb, sem, par: _paged_walk_kernel(
+            ids, lens, q, k, None, o, kb, None, sem, par, scale=scale)
+        leaves, bufs = (k_pages,), [buf]
+    else:
+        kernel = functools.partial(_paged_walk_kernel, scale=scale)
+        leaves, bufs = (k_pages, v_pages), [buf, buf]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[slot] + [hbm] * len(leaves), out_specs=slot,
+            scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((2, 2)),
+                                   pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, w), qz.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_walk",
+    )(page_ids, lengths, qz, *leaves)

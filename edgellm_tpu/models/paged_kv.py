@@ -92,7 +92,7 @@ import jax
 import jax.numpy as jnp
 
 from ..lint import graph_contract
-from . import mla
+from . import flash_attention, mla
 from .configs import LANE_TILE, ModelConfig
 from .flash_attention import dequantize_kv_rows, quantize_kv_rows
 from .transformer import (_cast_params, _layernorm, _rmsnorm, _rotate_half,
@@ -2043,6 +2043,25 @@ def window_valid(pos, lengths, window: int):
     return (pos >= 0) & (pos <= t) & (pos > t - window)
 
 
+def _group_lanes(q, kv: int):
+    """Each query head in ITS KV group's lanes of a row-wide query, zero
+    elsewhere: q (B, 1, H, hd) -> (own (H, KV) bool, head h's group; qz (B, H,
+    KV*hd)). Head j*rep+g attends KV group j, as everywhere."""
+    b, _, h, hd = q.shape
+    own = (jnp.arange(h)[:, None] // (h // kv)) == jnp.arange(kv)[None, :]
+    qz = jnp.where(own[None, :, :, None], q.reshape(b, h, 1, hd), 0)
+    return own, qz.reshape(b, h, kv * hd)
+
+
+def _own_lanes(out, own):
+    """What a head keeps of a row-wide weighted sum, its own group's lanes:
+    out (B, H, KV*hd) -> (B, 1, H, hd)."""
+    b, h, _ = out.shape
+    out = jnp.where(own[None, :, :, None], out.reshape(b, h, own.shape[1], -1),
+                    0)
+    return out.sum(axis=2).reshape(b, 1, h, -1)
+
+
 def attend_rows(q, k_rows, v_rows, lengths, valid=None):
     """Single-position GQA attention against rows as the pool stores them:
     q (B, 1, H, hd); k_rows, v_rows (B, span, KV*hd), head j of a row in
@@ -2055,17 +2074,14 @@ def attend_rows(q, k_rows, v_rows, lengths, valid=None):
     (KV, hd) for ``decode_attention``'s per-group einsum is a real
     lane-splitting copy of every gathered K and V on the chip (100 MB read,
     200 written a layer at hd 64). Instead each query head is placed in ITS
-    group's lanes of a (B, H, KV*hd) query that is zero elsewhere, so the
-    scores are one dot over the whole row — exact, the added products are
-    zeros — and PV yields every group's lanes for every head, of which a
-    head keeps its own. KV-fold the MXU work of a step that is bound by the
-    K/V read. Head j*rep+g attends KV group j, as everywhere."""
-    b, _, h, hd = q.shape
-    kv = k_rows.shape[-1] // hd
-    # (H, KV): head h's group
-    own = (jnp.arange(h)[:, None] // (h // kv)) == jnp.arange(kv)[None, :]
-    qz = jnp.where(own[None, :, :, None], q.reshape(b, h, 1, hd), 0)
-    scores = jnp.einsum("bhD,bcD->bhc", qz.reshape(b, h, kv * hd), k_rows,
+    group's lanes of a (B, H, KV*hd) query that is zero elsewhere
+    (:func:`_group_lanes`), so the scores are one dot over the whole row —
+    exact, the added products are zeros — and PV yields every group's lanes
+    for every head, of which a head keeps its own. KV-fold the MXU work of a
+    step that is bound by the K/V read."""
+    hd = q.shape[-1]
+    own, qz = _group_lanes(q, k_rows.shape[-1] // hd)
+    scores = jnp.einsum("bhD,bcD->bhc", qz, k_rows,
                         preferred_element_type=jnp.float32)
     scores = scores * (1.0 / np.sqrt(hd))
     if valid is None:
@@ -2075,8 +2091,54 @@ def attend_rows(q, k_rows, v_rows, lengths, valid=None):
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhc,bcD->bhD", probs.astype(q.dtype), v_rows,
                      preferred_element_type=jnp.float32).astype(q.dtype)
-    out = jnp.where(own[None, :, :, None], out.reshape(b, h, kv, hd), 0)
-    return out.sum(axis=2).reshape(b, 1, h, hd)
+    return _own_lanes(out, own)
+
+
+#: the two decode reads of a layer whose pages hold a prefix, as
+#: ``ContinuousBatcher.report()`` and ``chip_smoke.py`` name them
+PAGE_WALK, PAGE_GATHER = "pallas page walk", "xla page gather"
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def decode_read_path(pool, window: int = 0) -> str:
+    """Which read a decode step's attention layer is built with, read off
+    what it is handed: :data:`PAGE_WALK` (:func:`attend_pages`) for an fp
+    :class:`PagePool` whose pages hold a prefix (``window == 0``), on a TPU,
+    where a page is whole tiles (rows of whole lane tiles, a page of whole
+    sublane tiles: what the kernel's page DMAs need); :data:`PAGE_GATHER`
+    (:func:`read_span` + :func:`attend_rows`, the oracle) for a quantized
+    tier, which dequantizes after the gather, a window ring, the latent
+    pool and every other backend. ``pool`` may be a whole, staged or
+    one-layer pool. No width is gated out: on a v5e the walk is ahead at 128,
+    256, 512 and 1024 lanes (PERF.md §6 "PR 33")."""
+    if not isinstance(pool, PagePool) or window or not _on_tpu():
+        return PAGE_GATHER
+    sublanes = 32 // jnp.dtype(pool.k.dtype).itemsize
+    whole = pool.k.shape[-1] % LANE_TILE == 0 and pool.page_size % sublanes == 0
+    return PAGE_WALK if whole else PAGE_GATHER
+
+
+def attend_pages(q, pool: PagePool, layer, page_table, lengths):
+    """:func:`read_span` + :func:`attend_rows` without the span: q (B, 1, H,
+    hd) against each slot's LIVE pages of layer ``layer`` of an fp pool (L,
+    P, ps, KV*hd), read out of the pool where they lie by ONE kernel
+    (``flash_attention.paged_decode_walk``: a DMA a page, ``ceil(lengths[i] /
+    ps)`` pages a slot, one for an idle slot's trash page). Both leaves go in
+    whole, viewed (L*P, ps, KV*hd) — a bitcast of the carried pool — with the
+    page ids ``layer*P + page_table``; the same query in its groups' lanes,
+    float32 scores and softmax, and the same rows attended as
+    :func:`attend_rows`, in a blockwise order of the float32 sums. Returns
+    (B, 1, H, hd) in q's dtype."""
+    hd = q.shape[-1]
+    own, qz = _group_lanes(q, pool.k.shape[-1] // hd)
+    ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
+    out = flash_attention.paged_decode_walk(
+        qz, _pages(pool.k, 1), _pages(pool.v, 1), ids,
+        lengths.astype(jnp.int32), scale=float(1.0 / np.sqrt(hd)))
+    return _own_lanes(out, own)
 
 
 def attend_latent(q_rows, rows, lengths, head_dim: int):
@@ -2126,9 +2188,11 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
     lengths (B,) int32 counts each slot's valid positions INCLUDING the one
     this step wrote. Returns (B, 1, H, hd) in q's dtype; softmax in fp32.
 
-    One XLA page gather (:func:`read_span`) and :func:`attend_rows` over
-    its output as it lies: trash-page garbage lands only in masked positions,
-    where softmax of ``finfo.min`` contributes exactly 0.
+    Where :func:`decode_read_path` says so, one kernel that walks each
+    slot's live pages (:func:`attend_pages`). Otherwise one XLA page gather
+    (:func:`read_span`) and :func:`attend_rows` over its output as it lies:
+    trash-page garbage lands only in masked positions, where softmax of
+    ``finfo.min`` contributes exactly 0.
 
     ``window`` (static, > 0): ``page_table`` is a window layer's ring
     (:func:`write_rows`) and a row is attended by the position it holds."""
@@ -2143,6 +2207,8 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
                          f"head_dim {hd} for tier {tier!r}")
     if h % kv:
         raise ValueError(f"ragged GQA: H={h}, KV={kv}")
+    if decode_read_path(pool, window) == PAGE_WALK:
+        return attend_pages(q, pool, layer, page_table, lengths)
     kg, vg = read_span(pool, layer, page_table, q.dtype)
     if not window:
         return attend_rows(q, kg, vg, lengths)

@@ -85,8 +85,9 @@ import jax.numpy as jnp
 
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
-from ..models.paged_kv import OutOfPages, OutOfSlots, PagedKVCache, \
-    PrefixCacheConfig, SlotState, paged_decode_step, resolve_kv_codec
+from ..models.paged_kv import PAGE_WALK, OutOfPages, OutOfSlots, \
+    PagedKVCache, PrefixCacheConfig, SlotState, decode_read_path, \
+    paged_decode_step, resolve_kv_codec
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
 from ..obs.flight import flight_dump_for
@@ -397,7 +398,12 @@ class ContinuousBatcher:
                       "alloc_sum": 0.0, "alloc_n": 0,
                       "compiles": 0, "compile_s": 0.0,
                       "routed_assignments": 0,
+                      "attend_pages_walked": 0, "attend_pages_spanned": 0,
                       **dict.fromkeys(_CLOCKS, 0.0)}
+        # which read the step's full-attention layers are built with
+        # (paged_kv.decode_read_path, off the pool the step is handed)
+        self.decode_read = decode_read_path(
+            self._split_pool if split_runtime is not None else self.pool.pool)
         # the scheduler thread's clocks since its last fold into ``stats``:
         # phases add here lock-free, step()/submit()/prefill_hold() fold once
         self._acc: dict[str, float] = defaultdict(int)
@@ -1005,6 +1011,14 @@ class ContinuousBatcher:
             # by construction); inactive slots write the trash page
             page_table, lengths = self.pool.device_tables()
             misses0 = self._step_cache_size()
+            if self.decode_read == PAGE_WALK:
+                # what a layer's attend fetches this step, a page a DMA: the
+                # pages under each slot's length, the row this step writes
+                # included (an idle slot's one trash page), against the
+                # table entries a page gather reads whatever they hold
+                acc["attend_pages_walked"] = int(np.sum(
+                    self.pool.lengths // self.pool.page_size + 1))
+                acc["attend_pages_spanned"] = b * self.bcfg.pages_per_slot
         with obs_phase("batch.step.launch", acc, "launch_s", after=ph,
                        step=step_no) as ph:
             t0 = time.monotonic()
@@ -1264,6 +1278,12 @@ class ContinuousBatcher:
                                 if alloc_n else 0.0),
             "span": self.bcfg.span,
             "token_capacity": self.pool.token_capacity,
+            # the decode read the step was built with, and (additive; 0 on
+            # the page gather) the pages a layer's page walk fetched against
+            # the table entries a gather would have read
+            "decode_read": self.decode_read,
+            "attend_pages_walked": stats["attend_pages_walked"],
+            "attend_pages_spanned": stats["attend_pages_spanned"],
             **({"prefix": self.pool.prefix_report()}
                if self.pool.prefix is not None else {}),
             **self._hybrid_report(stats),

@@ -268,6 +268,51 @@ def test_ragged_mixed_lengths_bit_identical_to_generate(params):
     assert rep["jit_misses"] <= 1  # at most the one warmup compile
 
 
+@pytest.mark.parametrize("read", [paged_kv.PAGE_GATHER, paged_kv.PAGE_WALK])
+def test_report_counts_the_pages_a_page_walk_fetches(params, read):
+    """``report()``'s two running sums, at a toy size: before every step, the
+    pages under each slot's length with the row the step writes (an idle
+    slot's one trash page) against slots x table entries; both stay 0 where
+    the step was built on the page gather, which is what a CPU builds. The
+    counting is the host's, so the walk's is checked by naming it the read
+    of a batcher whose step still gathers."""
+    bat = ContinuousBatcher(CFG, params, BCFG)
+    assert bat.decode_read == paged_kv.PAGE_GATHER      # a cpu: the oracle
+    assert bat.report()["decode_read"] == paged_kv.PAGE_GATHER
+    bat.decode_read = read
+    bat.submit(_prompt(7, 1), 12)      # 7 -> 18 positions: pages 1 -> 3
+    bat.submit(_prompt(16, 2), 3)      # starts its third page at once
+    walked = spanned = 0
+    while True:
+        before = bat.report()
+        if not bat.step():
+            break
+        # the lengths the step was launched with: each running stream's
+        # cache before this step's token, 0 for the two idle slots
+        after = bat.report()
+        spanned += BCFG.max_slots * BCFG.pages_per_slot
+        assert (after["attend_pages_spanned"]
+                - before["attend_pages_spanned"]) == (
+                    BCFG.max_slots * BCFG.pages_per_slot
+                    if read == paged_kv.PAGE_WALK else 0)
+        walked += after["attend_pages_walked"] - before["attend_pages_walked"]
+    rep = bat.report()
+    assert rep["finished"] == 2
+    if read == paged_kv.PAGE_GATHER:
+        assert rep["attend_pages_walked"] == rep["attend_pages_spanned"] == 0
+        return
+    # stream 1 decodes at cache lengths 7..17 (11 steps: its first token is
+    # the prefill's), stream 2 at 16, 17 (2 steps); the other slots idle
+    steps = rep["steps"]
+    assert steps == 11
+    lens1 = list(range(7, 18))
+    lens2 = [16, 17] + [0] * 9          # its slot is idle once it finished
+    want = sum(n // 8 + 1 for n in lens1) + sum(n // 8 + 1 for n in lens2) \
+        + 2 * steps                      # two slots never held a stream
+    assert rep["attend_pages_walked"] == walked == want
+    assert rep["attend_pages_spanned"] == spanned == steps * 16
+
+
 def test_steady_state_is_jit_miss_free(params):
     # warm the geometry's executable...
     warm = ContinuousBatcher(CFG, params, BCFG)

@@ -10,6 +10,13 @@ back layer by layer — 113 of 181 ms of the step before that PR — shows here
 as a ``copy``, ``dynamic-update-slice`` or ``reshape`` of pool size and as
 gigabytes of temporaries. Nothing runs, so nothing here is a time.
 
+Each step is compiled on BOTH decode reads (``paged_kv.decode_read_path``):
+the page walk a TPU takes (PERF.md §6 "PR 33": one kernel fetches each slot's
+live pages, and no span-sized copy of K or V exists anywhere in the module),
+and the page gather every other backend keeps as the oracle. The test
+process's backend is the CPU, so the ``walk`` fixture answers the one
+question the choice asks of the backend as a TPU would.
+
 ONE file, the topology described inside a fixture (``on-chip-measurement``
 §2): only the worker that is handed this file loads the TPU's library.
 """
@@ -62,6 +69,37 @@ def topo():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(params=["walk", "gather"])
+def read(request, monkeypatch):
+    """The decode read a step is compiled on. ``walk``: what the program
+    picks on a TPU for an fp pool of whole tiles; ``gather``: what it picks
+    here, the XLA oracle."""
+    steps = (batching._batched_step_jit, batching._batched_window_step_jit)
+
+    def forget():       # a jit keeps its trace by arguments, not by backend
+        for step in steps:
+            step.clear_cache()
+
+    forget()
+    if request.param == "walk":
+        monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    yield request.param
+    forget()
+
+
+def _walks(hlo: str) -> int:
+    """Page-walk kernels in a compiled module."""
+    return sum(op == "custom-call" and "paged_decode_walk" in line
+               for op, _, _, line in _instructions(hlo))
+
+
+def _span_sized(hlo: str, shapes) -> list:
+    """Results of any instruction whose type names one of ``shapes``: a
+    slot's whole span of K or V, gathered, reshaped or fused."""
+    return [shape for _, _, shape, _ in _instructions(hlo)
+            if any(s in shape for s in shapes)]
+
+
 def _shapes(tree, sharding):
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
@@ -96,7 +134,7 @@ def _moved(hlo: str, at_least: int, ops=("copy", "dynamic-update-slice",
             _instructions(hlo) if op in ops and _elements(shape) >= at_least]
 
 
-def test_decode_step_updates_the_pool_where_it_lies(topo):
+def test_decode_step_updates_the_pool_where_it_lies(topo, read):
     one = SingleDeviceSharding(topo.devices[0])
     params = _shapes(jax.eval_shape(
         lambda: init_params(QWEN05, jax.random.key(0), dtype=jnp.bfloat16)),
@@ -105,6 +143,8 @@ def test_decode_step_updates_the_pool_where_it_lies(topo):
         lambda: paged_kv.init_pool(QWEN05, PAGES, PAGE, jnp.bfloat16)), one)
     width = QWEN05.num_kv_heads * QWEN05.head_dim
     assert pool.k.shape == (24, PAGES, PAGE, width)
+    assert paged_kv.decode_read_path(pool) == {
+        "walk": paged_kv.PAGE_WALK, "gather": paged_kv.PAGE_GATHER}[read]
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -126,15 +166,31 @@ def test_decode_step_updates_the_pool_where_it_lies(topo):
     # no copy / dynamic-update-slice / reshape produces a pool-sized array,
     # and no K/V-sized relayout sits between the page gather and the dots
     assert not moved, moved
-    assert not [m for m in moved if _elements(m[2]) >= layer_pool]
-    # the two gathers a layer take whole pages at (layer, page) ...
+    # both reads take whole pages at (layer, page) of the pool viewed
+    # (L*P, ps, width) ...
     flat_pages = f"bf16[{24 * PAGES},{PAGE},{width}]"
-    assert flat_pages in hlo, "the pool is not gathered as (L*P, ps, width)"
+    assert flat_pages in hlo, "the pool is not read as (L*P, ps, width)"
     # ... and the row writes scatter into the pool viewed as rows
     assert f"bf16[{24 * PAGES * PAGE},{width}]" in hlo
-    # the attend's dots read the gather's output as it lies
-    assert "bhD,bcD->bhc" in hlo and "bhc,bcD->bhD" in hlo
-    assert step.memory_analysis().temp_size_in_bytes < 0.5e9
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 24 * layer_pool * 2    # donated
+    if read == "gather":
+        # the attend's dots read the gather's output as it lies
+        assert "bhD,bcD->bhc" in hlo and "bhc,bcD->bhD" in hlo
+        assert not _walks(hlo)
+        # a gathered copy of one layer's K or V at a time: 126.5 MB
+        assert 100e6 < mem.temp_size_in_bytes < 0.5e9
+        return
+    # the walk: one kernel a layer (the scan's body), both leaves handed to
+    # it whole, and NOTHING span-sized anywhere in the module: no result of
+    # a slot's 2048 rows a slot, gathered, reshaped or fused
+    assert _walks(hlo) == 1, _walks(hlo)
+    spans = _span_sized(hlo, own | {
+        f"bf16[{SLOTS},{PAGES_PER_SLOT * PAGE},{width}]"})
+    assert not spans, spans[:3]
+    # the gathered copy was the step's temporaries: 126.5 MB on the gather,
+    # 0.7 MB here
+    assert mem.temp_size_in_bytes < 5e6, mem.temp_size_in_bytes
 
 
 def test_adopt_scatters_in_place(topo):
@@ -192,12 +248,14 @@ MELLUM = ModelConfig(
 M_SLOTS, M_PAGES_PER_SLOT = 96, 384
 
 
-def test_window_layers_gather_their_rings_and_both_pools_stay_in_place(topo):
+def test_window_layers_gather_their_rings_and_both_pools_stay_in_place(topo,
+                                                                       read):
     """The step of a stack with sliding layers: a window layer's two gathers
     take each slot's RING (65 pages), never its span (384); a full layer's
-    take the span; neither pool is copied, relaid or stacked, and the
-    gathered copies are the step's temporaries (0.78 GB beside 11.2 GB of
-    weights and pools)."""
+    take the span, or on the page walk nothing (the kernel reads the live
+    pages out of the pool); neither pool is copied, relaid or stacked, and
+    the gathered copies are the step's temporaries (0.78 GB beside 11.2 GB
+    of weights and pools; 0.16 GB on the walk)."""
     one = SingleDeviceSharding(topo.devices[0])
     params = _shapes(jax.eval_shape(
         lambda: init_params(MELLUM, jax.random.key(0), dtype=jnp.bfloat16)),
@@ -229,8 +287,12 @@ def test_window_layers_gather_their_rings_and_both_pools_stay_in_place(topo):
                if op == "gather" and _elements(shape) >= gathered]
     span = f"bf16[{M_SLOTS},{M_PAGES_PER_SLOT},{PAGE},{width}]"
     rings = f"bf16[{M_SLOTS},{ring},{PAGE},{width}]"
-    # K and V of 2 full layers, K and V of 6 window layers: static walk
-    assert sorted(gathers) == [span] * 4 + [rings] * 12, gathers
+    # K and V of 6 window layers (static walk), and of the 2 full layers
+    # where they are gathered: on the page walk a full layer's span is never
+    # materialized, while a ring stays a gather (its rows are no prefix)
+    full_reads = [span] * 4 if read == "gather" else []
+    assert sorted(gathers) == full_reads + [rings] * 12, gathers
+    assert _walks(hlo) == (2 if read == "walk" else 0)
     own = {span, rings,
            f"bf16[{M_SLOTS * M_PAGES_PER_SLOT},{PAGE},{width}]",
            f"bf16[{M_SLOTS * ring},{PAGE},{width}]"}
@@ -242,7 +304,8 @@ def test_window_layers_gather_their_rings_and_both_pools_stay_in_place(topo):
     assert f"bf16[{6 * 6241},{PAGE},{width}]" in hlo
     assert f"bf16[{6 * 6241 * PAGE},{width}]" in hlo
     mem = step.memory_analysis()
-    assert mem.temp_size_in_bytes < 1.0e9
+    # 778 MB with the full layers' gathered spans, 156 MB with the rings'
+    assert mem.temp_size_in_bytes < (1.0e9 if read == "gather" else 0.3e9)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
 
 
@@ -255,7 +318,7 @@ QWEN15 = ModelConfig(
 
 
 def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
-        topo):
+        topo, read):
     """``step_paged_fn`` as the split cell runs it (four stages of 7 layers,
     hops int8 / int4 / int8): each stage's pool is a scan carry that the row
     scatters and page gathers address in place through all four unroll
@@ -306,10 +369,17 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
     # nothing of a layer's pool or larger is copied, selected, sliced out,
     # put back or relaid, and nothing K/V-sized but the gather's own output
     assert not moved, moved
-    # the stage's pool is gathered as pages at (layer, page), written as rows
+    # the stage's pool is read as pages at (layer, page), written as rows
     assert f"bf16[{sz * PAGES},{PAGE},{width}]" in hlo, \
-        "the stage's pool is not gathered as (sz*P, ps, width)"
+        "the stage's pool is not read as (sz*P, ps, width)"
     assert f"bf16[{sz * PAGES * PAGE},{width}]" in hlo
+    if read == "walk":
+        # inside shard_map, in every unroll iteration's scan body, dead ones
+        # included: the kernel, and no span-sized K or V anywhere
+        assert _walks(hlo) >= 1, _walks(hlo)
+        assert not _span_sized(hlo, own), _span_sized(hlo, own)[:3]
+    else:
+        assert not _walks(hlo)
     # across chips: the three hops, each to the next stage, and the one
     # all-reduce that hands the last stage's hidden state to every chip
     hops = {pairs for op, _, _, line in _instructions(hlo)
@@ -324,9 +394,10 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
                           "reduce-scatter")]
     assert crossing == [("all-reduce", f"f32[{SLOTS},1,1536]")], crossing
     mem = step.memory_analysis()
-    # a chip's share: the gathered K and V of one layer at a time (2 x 201
-    # MB) and little else; the parent's stacked and selected pools were 5.45
-    assert mem.temp_size_in_bytes < 0.5e9
+    # a chip's share: the gathered K and V of one layer at a time (211.9 MB)
+    # and little else, where the stacked and selected pools before PR 31
+    # were 5.45 GB; the walk leaves 2.3 MB
+    assert mem.temp_size_in_bytes < (0.5e9 if read == "gather" else 10e6)
     assert mem.alias_size_in_bytes >= 2 * sz * layer_pool * 2   # donated
 
 

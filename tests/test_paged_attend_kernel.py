@@ -1,0 +1,205 @@
+"""The page walk against the page gather: ``flash_attention.paged_decode_walk``
+in TPU-interpret mode on the CPU, held to ``paged_kv.read_span`` +
+``paged_kv.attend_rows`` (the XLA path, which stays the oracle) at toy sizes.
+
+What a chip would do with the kernel is ``tests/test_chip_compile.py``'s (it
+compiles) and the benchmark's (it is timed); here is what it computes: the
+same rows attended, whatever the table says about where they lie, and nothing
+of a page or a row that no length covers.
+"""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+
+from edgellm_tpu.models import flash_attention, paged_kv
+from edgellm_tpu.models import tiny_config
+from edgellm_tpu.models.transformer import init_params
+
+PAGE, PAGES_PER_SLOT, LAYERS, LAYER = 16, 8, 3, 1
+SPAN = PAGE * PAGES_PER_SLOT
+
+#: (row width W = KV * hd, query heads, KV heads): the cells' three shapes
+GEOMETRIES = {"w128-h14-kv2": (128, 14, 2), "w256-h12-kv2": (256, 12, 2),
+              "w512-h32-kv4": (512, 32, 4)}
+
+#: name -> (lengths a slot, pages in scrambled pool order?, NaN in every page
+#: no slot holds?). A length of 1 is an idle slot: its table row is the trash
+#: page. Every table has a neighbour of another length beside the slot it is
+#: named for, so that the pipeline crosses a slot boundary both ways.
+TABLES = {
+    "ends-on-a-page-edge": ((48, 5, 32), False, False),
+    "ends-mid-page": ((37, 64, 3), False, False),
+    "idle-slots-on-the-trash-page": ((1, 21, 1, 1), False, False),
+    "full-span": ((SPAN, 17, SPAN), False, False),
+    "scrambled-pool-order": ((100, 33, 1, 16, 77), True, False),
+    "unfetched-pages-hold-nan": ((60, 1, 16, 90), True, True),
+}
+
+
+def _pool_and_table(width, lengths, scrambled, poisoned, dtype, seed):
+    rng = np.random.default_rng(seed)
+    slots = len(lengths)
+    pages = slots * PAGES_PER_SLOT + 1
+    k, v = (rng.standard_normal((LAYERS, pages, PAGE, width))
+            .astype(np.float32) for _ in range(2))
+    order = (rng.permutation(np.arange(1, pages)) if scrambled
+             else np.arange(1, pages))
+    table = np.zeros((slots, PAGES_PER_SLOT), np.int32)
+    held = np.zeros((pages,), bool)
+    taken = 0
+    for i, n in enumerate(lengths):
+        if n == 1:
+            continue        # idle: every entry the trash page, length 0 + 1
+        for j in range(-(-n // PAGE)):
+            table[i, j] = order[taken]
+            held[order[taken]] = True
+            taken += 1
+    held[0] = True          # the trash page is fetched, and finite
+    if poisoned:
+        k[:, ~held] = np.nan
+        v[:, ~held] = np.nan
+    pool = paged_kv.PagePool(jnp.asarray(k, dtype), jnp.asarray(v, dtype))
+    return pool, jnp.asarray(table), held
+
+
+def _interpreted(*args, **kwargs):
+    """The kernel under the TPU interpreter, WAITED FOR: its host callbacks
+    run JAX operations of their own, and deadlock against a main thread that
+    has gone on to dispatch the next one."""
+    return jax.block_until_ready(flash_attention.paged_decode_walk(
+        *args, **kwargs, interpret=pltpu.InterpretParams()))
+
+
+def _walk(q, pool, table, lengths, pages_per_block=None):
+    """``paged_kv.attend_pages`` with the kernel interpreted."""
+    hd = q.shape[-1]
+    own, qz = paged_kv._group_lanes(q, pool.k.shape[-1] // hd)
+    out = _interpreted(
+        qz, paged_kv._pages(pool.k, 1), paged_kv._pages(pool.v, 1),
+        LAYER * pool.num_pages + table, lengths, scale=float(hd ** -0.5),
+        pages_per_block=pages_per_block)
+    return paged_kv._own_lanes(out, own)
+
+
+def _gather(q, pool, table, lengths, held):
+    """The oracle, over a pool whose unheld pages are made finite: 0 x NaN
+    is what the gather's masked rows would make of them."""
+    clean = paged_kv.PagePool(*(
+        jnp.where(held[None, :, None, None], a, 0) for a in pool))
+    kg, vg = paged_kv.read_span(clean, LAYER, table, q.dtype)
+    return paged_kv.attend_rows(q, kg, vg, lengths)
+
+
+@pytest.mark.parametrize("table", TABLES)
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_walk_equals_gather_float32(geometry, table):
+    """Float32 rows: only the order of the float32 sums differs, so the two
+    agree to rounding, on every table kind, with blocks of 2 pages so that
+    most slots take several blocks and end inside one."""
+    width, heads, kv = GEOMETRIES[geometry]
+    lengths, scrambled, poisoned = TABLES[table]
+    pool, tab, held = _pool_and_table(width, lengths, scrambled, poisoned,
+                                      jnp.float32, seed=len(table))
+    q = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (len(lengths), 1, heads, width // kv)), jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    got = _walk(q, pool, tab, lens, pages_per_block=2)
+    want = _gather(q, pool, tab, lens, jnp.asarray(held))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_walk_equals_gather_bfloat16_default_block(geometry):
+    """The cells' dtype and the block :func:`paged_walk_pages_per_block`
+    picks (a whole toy span in one block): equal to bf16 rounding of the
+    probabilities, finite over a poisoned pool."""
+    width, heads, kv = GEOMETRIES[geometry]
+    lengths = (SPAN, 1, 37, 16, 90)
+    pool, tab, held = _pool_and_table(width, lengths, True, True,
+                                      jnp.bfloat16, seed=7)
+    q = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (len(lengths), 1, heads, width // kv)), jnp.bfloat16)
+    lens = jnp.asarray(lengths, jnp.int32)
+    got = _walk(q, pool, tab, lens).astype(jnp.float32)
+    want = _gather(q, pool, tab, lens, jnp.asarray(held)).astype(jnp.float32)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
+
+
+def test_one_leaf_as_keys_and_values():
+    """``v_pages`` None (a latent row is both key and value): one leaf, one
+    buffer, the weighted sum of the rows the scores were taken over."""
+    pool, tab, held = _pool_and_table(128, (40, 1, 16), True, True,
+                                      jnp.float32, seed=3)
+    lens = jnp.asarray((40, 1, 16), jnp.int32)
+    qz = jnp.asarray(np.random.default_rng(4).standard_normal((3, 5, 128)),
+                     jnp.float32)
+    pages = paged_kv._pages(pool.k, 1)
+    got = _interpreted(qz, pages, None, LAYER * pool.num_pages + tab, lens,
+                       scale=0.125, pages_per_block=2)
+    rows = paged_kv._gather_pages(
+        jnp.where(jnp.asarray(held)[None, :, None, None], pool.k, 0), LAYER,
+        tab)
+    want = paged_kv.attend_latent(qz, rows, lens, 64)   # 64 ** -0.5 = 0.125
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_read_path_is_read_off_the_pool(monkeypatch):
+    """The walk for an fp pool of whole tiles on a TPU, the gather for
+    everything else — no flag: the pool's type, its shape and the backend."""
+    cfg = tiny_config("qwen2", num_layers=2, hidden_size=256, num_heads=4,
+                      vocab_size=64)                     # KV 2 x hd 64
+    fp = paged_kv.init_pool(cfg, 9, 16, jnp.bfloat16)
+    quant = paged_kv.init_quant_pool(cfg, 9, 16, "int8_per_channel")
+    narrow = paged_kv.PagePool(fp.k[..., :64], fp.v[..., :64])
+    short = paged_kv.PagePool(fp.k[:, :, :8], fp.v[:, :, :8])
+    assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_GATHER  # on a cpu
+    monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_WALK
+    assert paged_kv.decode_read_path(
+        paged_kv.PagePool(fp.k[None], fp.v[None])) == paged_kv.PAGE_WALK
+    for pool, window in ((fp, 32), (quant, 0), (narrow, 0), (short, 0),
+                         (paged_kv.LatentPool(fp.k), 0)):
+        assert paged_kv.decode_read_path(pool, window) == paged_kv.PAGE_GATHER
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_paged_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
+                                                              compute):
+    """The whole ragged step built on the walk (the choice forced as a TPU
+    would make it, the kernel interpreted) against the same step on the
+    gather: logits and the written pool, float32 pages under either compute
+    dtype (a bf16 query meets float32 rows in float32, as the einsums do)."""
+    cfg = tiny_config("qwen2", num_layers=2, hidden_size=256, num_heads=4,
+                      vocab_size=64)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    pool = paged_kv.PagePool(*(
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for a in paged_kv.init_pool(cfg, 13, 8, jnp.float32)))
+    table = jnp.asarray([[1, 2, 3, 0], [0, 0, 0, 0], [7, 5, 0, 0]], jnp.int32)
+    lens = jnp.asarray([20, 0, 9], jnp.int32)
+    toks = jnp.asarray([3, 0, 5], jnp.int32)
+    step = functools.partial(paged_kv.paged_decode_step, cfg, params, pool,
+                             table, lens, toks,
+                             compute_dtype=jnp.dtype(compute))
+    want, want_pool = step()
+    monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        flash_attention, "paged_decode_walk",
+        functools.partial(flash_attention.paged_decode_walk,
+                          interpret=pltpu.InterpretParams()))
+    got, got_pool = jax.block_until_ready(step())
+    tol = 1e-5 if compute == "float32" else 3e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
+    np.testing.assert_allclose(np.asarray(got_pool.k),
+                               np.asarray(want_pool.k), atol=tol)
