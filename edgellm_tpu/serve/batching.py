@@ -52,7 +52,6 @@ module schedules many streams through ONE jitted decode step built on
   per-stage on the mesh (``SplitRuntime.init_paged_pool``), and every ragged
   step crosses the boundary once per cut through the quantized hop ladder —
   batched serving over a split plan, no longer local-pool-only.
-
 - a stack walked by layer kinds (``models/hybrid.py``) has a step
   executable of its own: ``_batched_hybrid_step_jit`` with the per-slot
   recurrent state of a ``granitemoehybrid`` stack, ``_batched_window_step_jit``
@@ -71,6 +70,7 @@ the batcher — this module is only the inner scheduler.
 """
 from __future__ import annotations
 
+import bisect
 import functools
 import os
 import threading
@@ -303,11 +303,11 @@ def batched_step_cache_size() -> int:
 _split_sample_jit = jax.jit(_batched_sample)
 
 
-#: host-clock counters of ``stats``/``report()``, monotonic seconds: the whole
-#: of every ``step()`` call, its six phases (which tile it), and the time
-#: admitted streams spent in the waiting queue
+#: host-clock counters of ``stats``/``report()``, monotonic seconds, additive
+#: (``report()`` says what each encloses; the last three are ``_fold_step``'s)
 _CLOCKS = ("step_wall_s", "admit_s", "grow_s", "build_s", "launch_s",
-           "sync_s", "commit_s", "queue_wait_s")
+           "sync_s", "commit_s", "queue_wait_s", "admit_step_wall_s",
+           "between_s", "tok0_hold_s")
 
 
 @guarded_by("_stats_lock", fields=["stats"])
@@ -387,26 +387,26 @@ class ContinuousBatcher:
         self.results: dict[int, np.ndarray] = {}
         self._watchdog = (Watchdog(self.bcfg.step_deadline_s)
                           if self.bcfg.step_deadline_s is not None else None)
-        # running aggregates only — a long-lived server takes millions of
-        # steps, so no per-step sample lists; the obs scrape thread reads
-        # report() mid-step, so every write holds _stats_lock
+        # running aggregates only (a server takes millions of steps: no list
+        # grows by the step); a scrape reads report() mid-step: writes lock
         self._stats_lock = threading.Lock()
         self.stats = {"steps": 0, "submitted": 0, "admitted": 0, "evicted": 0,
                       "finished": 0, "jit_misses": 0, "emitted_tokens": 0,
-                      "prefill_s": 0.0, "decode_s": 0.0,
-                      "occ_sum": 0.0, "occ_max": 0.0, "slot_sum": 0.0,
-                      "alloc_sum": 0.0, "alloc_n": 0,
-                      "compiles": 0, "compile_s": 0.0,
-                      "routed_assignments": 0,
+                      "prefill_s": 0.0, "prefill_tokens": 0, "decode_s": 0.0,
+                      "occ_sum": 0.0, "slot_sum": 0.0, "alloc_sum": 0.0,
+                      "alloc_n": 0, "compiles": 0, "compile_s": 0.0,
+                      "routed_assignments": 0, "admit_steps": 0,
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
+                      "step_wall_hist": _new_step_wall_hist(),
                       **dict.fromkeys(_CLOCKS, 0.0)}
-        # which read the step's full-attention layers are built with
-        # (paged_kv.decode_read_path, off the pool the step is handed)
+        # the read the step's full-attention layers are built with (by pool)
         self.decode_read = decode_read_path(
             self._split_pool if split_runtime is not None else self.pool.pool)
-        # the scheduler thread's clocks since its last fold into ``stats``:
-        # phases add here lock-free, step()/submit()/prefill_hold() fold once
+        # the scheduler thread's own, lock-free between folds: clocks and
+        # counts; the clock at each token 0; the last launched step's return
         self._acc: dict[str, float] = defaultdict(int)
+        self._tok0_at: list[float] = []
+        self._returned: Optional[float] = None
         # routed-expert counters of a hybrid stack: assignments per held
         # expert per layer, summed on the device inside the step and read by
         # report() alone (no host sync a step); assignments made, counted
@@ -554,7 +554,7 @@ class ContinuousBatcher:
                 # stream may ride the step
                 with obs_phase("batch.admit.tok0_sync", sid=sid):
                     st.tokens.append(int(np.asarray(tok0)[0]))
-            self._acc["prefill_s"] += time.monotonic() - t0
+            self._acc["prefill_s"] += self._admitted_at(st, tok0, matched) - t0
             self._acc["queue_wait_s"] += t0 - st.queued_t
             self._acc["admitted"] += 1
             st.status, st.slot = "running", slot
@@ -909,17 +909,16 @@ class ContinuousBatcher:
             return step_fn._cache_size() + _split_sample_jit._cache_size()
         return batched_step_cache_size()
 
-    def _fold_acc(self, c0: tuple) -> None:
+    def _fold_acc(self, c0: tuple, whole: Optional[obs_phase] = None) -> None:
         """Fold the scheduler thread's clocks and counts since the last
         fold into ``stats`` — the one ``_stats_lock`` acquisition of a
         ``step()`` — with the backend compiles since the reading ``c0``."""
         c1 = compile_totals()
         acc = self._acc
-        occ_max = acc.pop("occ_max", 0.0)
         with self._stats_lock:
+            self._fold_step(acc, whole, self.stats["step_wall_hist"])
             for k, v in acc.items():
                 self.stats[k] += v
-            self.stats["occ_max"] = max(self.stats["occ_max"], occ_max)
             self.stats["compiles"] += c1[0] - c0[0]
             self.stats["compile_s"] += c1[1] - c0[1]
         acc.clear()
@@ -934,14 +933,15 @@ class ContinuousBatcher:
         ``grow`` / ``build`` / ``launch`` / ``sync`` / ``commit``, clocks
         ``admit_s`` ... ``commit_s``) tile it."""
         c0 = compile_totals()
+        whole = obs_phase("batch.step", self._acc, "step_wall_s",
+                          step=int(self.stats["steps"]),
+                          running=len(self._slot_to_sid),
+                          waiting=len(self._waiting))
         try:
-            with obs_phase("batch.step", self._acc, "step_wall_s",
-                           step=int(self.stats["steps"]),
-                           running=len(self._slot_to_sid),
-                           waiting=len(self._waiting)) as whole:
+            with whole:
                 return self._step_phases(whole)
         finally:
-            self._fold_acc(c0)
+            self._fold_acc(c0, whole)
 
     def _step_phases(self, whole: obs_phase) -> int:
         acc = self._acc
@@ -1102,7 +1102,7 @@ class ContinuousBatcher:
             # batching's worst-case (batch x capacity) reservation
             reserved = (self.pool.num_pages - 1
                         - self.pool.num_free_pages) * self.pool.page_size
-            acc["occ_sum"] = acc["occ_max"] = occ
+            acc["occ_sum"] = occ
             acc["slot_sum"] = len(self._slot_to_sid) / b
             if reserved:
                 acc["alloc_sum"] = live / reserved
@@ -1127,6 +1127,54 @@ class ContinuousBatcher:
                                 free_pages=self.pool.num_free_pages)
                 raise exc
         return self.results
+
+    # -- what the fold keeps of one step -----------------------------------
+
+    def _admitted_at(self, st: Stream, tok0, matched: int) -> float:
+        """The clock where an admission's host work ends (what closes its
+        ``prefill_s``). For a fresh admission that is where its token 0 has
+        come to lie on the host, which ``tok0_hold_s`` counts from, and its
+        ``prompt - matched`` positions were prefilled; a resume prefills
+        nothing and holds no token."""
+        now = time.monotonic()
+        if tok0 is not None:
+            self._tok0_at.append(now)
+            self._acc["prefill_tokens"] += int(st.prompt.size - matched)
+        return now
+
+    def _fold_step(self, acc: dict, whole: Optional[obs_phase],
+                   hist: list) -> None:
+        """What a ``step()`` that launched leaves beside its sums, from the
+        readings it took anyway (``whole`` is its ``batch.step`` phase, ``acc``
+        its clocks; ``hist`` is ``stats["step_wall_hist"]`` and the caller
+        holds the lock): its row of the step-wall table, the caller's time
+        since the launched step before it, and for a step that admitted, its
+        wall and how long each token 0 lay on the host before the call
+        returned it. A call that launched nothing is in none of them, and the
+        launched step after it has no step before it; nor has the one after
+        a step that left nothing running or waiting (a caller need not poll
+        an empty batcher): an idle batcher's wait is no hand-off.
+        ``prefill_hold()`` (no ``whole``) holds no token."""
+        tok0_at = self._tok0_at
+        if whole is None or not acc.get("steps"):
+            if whole is not None:
+                self._returned = None
+            tok0_at.clear()
+            return
+        wall = acc["step_wall_s"]
+        row = hist[bisect.bisect_right(_STEP_WALL_EDGES, wall)]
+        row[0] += 1
+        for i, k in enumerate(_PHASES, 1):
+            row[i] += acc[k]
+        if self._returned is not None:
+            acc["between_s"] = whole.start - self._returned
+        idle = not self._slot_to_sid and not self._waiting
+        self._returned = None if idle else whole.end
+        if acc.get("admitted"):
+            acc["admit_steps"] = 1
+            acc["admit_step_wall_s"] = wall
+            acc["tok0_hold_s"] = sum(whole.end - t for t in tok0_at)
+            tok0_at.clear()
 
     # -- checkpoint / restore ----------------------------------------------
 
@@ -1246,6 +1294,7 @@ class ContinuousBatcher:
     def report(self) -> dict:
         with self._stats_lock:
             stats = dict(self.stats)  # one consistent snapshot for the scrape
+            hist = [row[:] for row in stats["step_wall_hist"]]
         n = stats["steps"]
         alloc_n = stats["alloc_n"]
         dec = stats["decode_s"]
@@ -1266,13 +1315,28 @@ class ContinuousBatcher:
             # step() call entry to return, its six phases, the waiting-queue
             # time of admitted streams, and the backend compiles (any jit's,
             # where jit_misses sees the step executable only) that happened
-            # inside submit()/step()/prefill_hold()
+            # inside submit()/step()/prefill_hold(). And of the step() calls
+            # that launched a step alone (_fold_step): admit_step_wall_s, the
+            # wall of those that admitted (admit_steps of them); between_s,
+            # from the return of one to the entry of the next while there was
+            # work, the caller's loop; tok0_hold_s, from a fresh admission's
+            # token 0 on the host to the return of the call that admitted it
             **{k: stats[k] for k in _CLOCKS},
+            "admit_steps": stats["admit_steps"],
+            # prompt positions admissions prefilled, in prefill_s: a prefix
+            # hit's matched positions and a resume's rows are not among them
+            "prefill_tokens": stats["prefill_tokens"],
+            # the launched steps by wall: row i the steps whose step_wall_s
+            # lay in [edge i-1, edge i) of step_wall_edges_s, row 0 under the
+            # first edge, the last row at or over the last; a row is [steps,
+            # admit_s, grow_s, build_s, launch_s, sync_s, commit_s] of them,
+            # additive entry by entry
+            "step_wall_hist": hist,
+            "step_wall_edges_s": list(_STEP_WALL_EDGES),
             "compiles": stats["compiles"],
             "compile_s": stats["compile_s"],
             "decode_tokens_per_s": (emitted / dec) if dec > 0 else 0.0,
             "occupancy_mean": (stats["occ_sum"] / n) if n else 0.0,
-            "occupancy_max": stats["occ_max"],
             "slot_util_mean": (stats["slot_sum"] / n) if n else 0.0,
             "alloc_util_mean": ((stats["alloc_sum"] / alloc_n)
                                 if alloc_n else 0.0),
@@ -1317,3 +1381,23 @@ class ContinuousBatcher:
                 "expert_tokens": tokens.tolist(),
                 "routed_assignments": int(stats["routed_assignments"]),
                 "routed_local": int(tokens.sum())}
+
+
+# -- the step-wall table ---------------------------------------------------
+# (down here because the lines above ``_admit_fill``'s call sites keep their
+# numbers: the Pallas prefill's Mosaic body carries its call stack's line
+# numbers into the compile-cache key, PERF.md section 6 "PR 32")
+
+#: the six clocks that tile ``step_wall_s``, a row's columns after its count
+_PHASES = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s", "commit_s")
+
+#: bucket edges of ``step_wall_hist``, seconds: 2**(k/4) from 2**-12 (0.244
+#: ms) to 2**4 (16 s), so that no bucket is wider than 18.93%
+_STEP_WALL_EDGES = tuple(2.0 ** (k / 4 - 12) for k in range(65))
+
+
+def _new_step_wall_hist() -> list:
+    """An empty table: a row under the first edge, one a bucket, one at or
+    over the last edge; a row is [steps, *seconds of the six phases]."""
+    return [[0] + [0.0] * len(_PHASES)
+            for _ in range(len(_STEP_WALL_EDGES) + 1)]

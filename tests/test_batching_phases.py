@@ -11,6 +11,7 @@ is on and nothing is recorded when it is off; tokens do not depend on the
 tracer; every name is registered; the named scopes change no jaxpr.
 """
 import ast
+import bisect
 import os
 
 import numpy as np
@@ -122,20 +123,67 @@ def test_launch_plus_sync_is_decode_s_and_prefill_lies_in_admit(make):
     assert r["admitted"] == 6 and r["finished"] == 6
 
 
-def test_counters_are_additive_across_report_deltas(make):
+def _two_reports(make):
     b = make()
     _traffic(b)
     b.step()
     r0 = b.report()
     b.run()
-    r1 = b.report()
-    steps = r1["steps"] - r0["steps"]
-    assert steps >= 1
+    return r0, b.report()
+
+
+def _hist_delta(r0, r1):
+    return [[y - x for x, y in zip(row0, row1)]
+            for row0, row1 in zip(r0["step_wall_hist"], r1["step_wall_hist"])]
+
+
+def _six_phases_tile_the_window(r0, r1):
     for k in PHASES + ("step_wall_s", "decode_s"):
         assert r1[k] > r0[k], k
     six = sum(r1[k] - r0[k] for k in PHASES)
     assert six == pytest.approx(r1["step_wall_s"] - r0["step_wall_s"],
                                 rel=0.02)
+
+
+def _the_table_is_the_windows_steps(r0, r1):
+    """Row by row the table is additive: a window's rows count its steps and
+    hold, column by column, its six phase clocks (every call launched)."""
+    rows = _hist_delta(r0, r1)
+    assert all(v >= 0 for row in rows for v in row)
+    assert sum(row[0] for row in rows) == r1["steps"] - r0["steps"]
+    for i, k in enumerate(PHASES, 1):
+        assert sum(row[i] for row in rows) == pytest.approx(r1[k] - r0[k],
+                                                            rel=1e-9), k
+    assert r1["step_wall_edges_s"] == r0["step_wall_edges_s"]
+
+
+def _admitting_steps_are_some_of_the_windows(r0, r1):
+    steps = r1["steps"] - r0["steps"]
+    # the first step filled the four slots; two streams are admitted later
+    assert r1["admitted"] - r0["admitted"] == 2
+    assert 1 <= r1["admit_steps"] - r0["admit_steps"] <= 2 < steps
+    wall = r1["admit_step_wall_s"] - r0["admit_step_wall_s"]
+    assert 0 < wall < r1["step_wall_s"] - r0["step_wall_s"]
+    assert r1["prefill_tokens"] - r0["prefill_tokens"] == 9 + 10
+    hold = r1["tok0_hold_s"] - r0["tok0_hold_s"]
+    assert 0 < hold < 2 * wall
+
+
+def _the_caller_took_time_between_every_two_steps(r0, r1):
+    between = r1["between_s"] - r0["between_s"]
+    # run() does nothing between two steps but test two containers
+    assert 0 < between < r1["step_wall_s"] - r0["step_wall_s"]
+
+
+@pytest.mark.parametrize("holds", [
+    _six_phases_tile_the_window, _the_table_is_the_windows_steps,
+    _admitting_steps_are_some_of_the_windows,
+    _the_caller_took_time_between_every_two_steps],
+    ids=lambda f: f.__name__.strip("_"))
+def test_counters_are_additive_across_report_deltas(make, holds):
+    r0, r1 = _two_reports(make)
+    assert r1["steps"] - r0["steps"] >= 1
+    holds(r0, r1)
 
 
 def test_a_step_with_nothing_to_do_still_counts_its_wall(make):
@@ -156,9 +204,10 @@ class _ShiftedClock:
     def __init__(self):
         import time
 
-        self._time, self.offset = time, 0.0
+        self._time, self.offset, self.reads = time, 0.0, 0
 
     def monotonic(self):
+        self.reads += 1
         return self._time.monotonic() + self.offset
 
 
@@ -213,6 +262,267 @@ def test_prefill_hold_folds_its_clocks_without_a_step(params):
     assert st is not None and r["admitted"] == 1 and r["prefill_s"] > 0
     assert r["queue_wait_s"] >= 0 and r["step_wall_s"] == 0.0
     b.release_handoff(sid)
+
+
+# ---------------------------------------------------------------------------
+# what the fold keeps of one step: the step-wall table, admitting steps apart
+# from plain ones, the caller's time between steps, a first token's hold
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """One pushable clock under the batcher's own readings and its phases'."""
+    clock = _ShiftedClock()
+    monkeypatch.setattr(batching, "time", clock)
+    monkeypatch.setattr(obs.tracing, "time", clock)
+    return clock
+
+
+def _push_in(b, method, clock, seconds):
+    """Make every later call of ``b.<method>`` take ``seconds`` more on the
+    clock; returns the dict whose ``"s"`` the test may change and whose
+    ``"pushed_s"`` adds up what was pushed."""
+    push, inner = {"s": seconds, "pushed_s": 0.0}, getattr(b, method)
+
+    def pushed(*a, **kw):
+        clock.offset += push["s"]
+        push["pushed_s"] += push["s"]
+        return inner(*a, **kw)
+
+    setattr(b, method, pushed)
+    return push
+
+
+def _row_of(seconds):
+    return bisect.bisect_right(batching._STEP_WALL_EDGES, seconds)
+
+
+def _wall_of_a_step(b):
+    r0 = b.report()
+    b.step()
+    r1 = b.report()
+    assert r1["steps"] == r0["steps"] + 1
+    return r1["step_wall_s"] - r0["step_wall_s"]
+
+
+def test_edges_are_geometric_and_no_bucket_is_wider_than_a_fifth():
+    edges = batching._STEP_WALL_EDGES
+    assert edges[0] <= 0.25e-3 and edges[-1] >= 16.0
+    assert all(1.0 < hi / lo <= 1.2 for lo, hi in zip(edges, edges[1:]))
+    hist = batching._new_step_wall_hist()
+    assert len(hist) == len(edges) + 1          # an underflow, an overflow row
+    assert all(row == [0] + [0.0] * len(PHASES) for row in hist)
+    assert _row_of(0.0) == 0 and _row_of(1e9) == len(edges)
+    assert _row_of(edges[0]) == 1               # an edge opens its bucket
+
+
+@pytest.mark.parametrize("phase_no, method", [(2, "_grow_writable"),
+                                              (1, "_try_admit")],
+                         ids=["grow", "admit"])
+def test_a_long_step_lands_in_its_row_with_its_phase_columns(
+        make, clock, phase_no, method):
+    b = make()
+    b.submit(_prompt(6), 8)
+    b.step()
+    b.submit(_prompt(7, 1), 8, rng_seed=1)      # the long step admits it
+    before = b.report()
+    push = _push_in(b, method, clock, 3.0)
+    wall = _wall_of_a_step(b)
+    push["s"], long = 0.0, push["pushed_s"]
+    # two running streams grow, one waiting stream is tried
+    assert long == (6.0 if method == "_grow_writable" else 3.0)
+    assert long <= wall < 16.0                  # under the overflow row
+    rows = _hist_delta(before, b.report())
+    row = rows[_row_of(wall)]
+    assert row[0] == 1 and sum(r[0] for r in rows) == 1
+    assert sum(row[1:]) == pytest.approx(wall, rel=1e-3)
+    # the pushed seconds lie in the pushed phase's column, in no other
+    assert long <= row[phase_no] <= wall
+    assert sum(row[1:]) - row[phase_no] <= wall - long + 1e-9
+    edges = b.report()["step_wall_edges_s"]
+    assert edges[_row_of(wall) - 1] <= wall < edges[_row_of(wall)]
+
+
+def test_table_rows_sum_to_steps_and_to_the_six_clocks(make):
+    b, _ = _run(make)
+    r = b.report()
+    rows = r["step_wall_hist"]
+    assert sum(row[0] for row in rows) == r["steps"] >= 8
+    for i, k in enumerate(PHASES, 1):
+        assert sum(row[i] for row in rows) == pytest.approx(r[k], rel=1e-9)
+    # a call that finds nothing to run is on the clocks and not in the table
+    assert b.step() == 0
+    r1 = b.report()
+    assert r1["step_wall_s"] > r["step_wall_s"] and r1["admit_s"] > r["admit_s"]
+    assert r1["step_wall_hist"] == rows
+    for k in ("admit_steps", "admit_step_wall_s", "between_s", "tok0_hold_s",
+              "prefill_tokens"):
+        assert r1[k] == r[k], k
+
+
+def test_admit_steps_split_admitting_steps_from_plain_ones(make, clock):
+    b = make()
+    _push_in(b, "_grow_writable", clock, 0.05)  # every step is long enough
+    walls = {True: [], False: []}
+    b.submit(_prompt(6), 12)
+    b.submit(_prompt(9, 1), 12, rng_seed=1)
+    for step in range(9):
+        if step in (4, 6):
+            b.submit(_prompt(5 + step, step), 3, rng_seed=step)
+        admitted = b.report()["admitted"]
+        wall = _wall_of_a_step(b)
+        walls[b.report()["admitted"] > admitted].append(wall)
+    r = b.report()
+    assert len(walls[True]) == 3 and len(walls[False]) == 6
+    assert r["admit_steps"] == 3 and r["admitted"] == 4
+    assert r["admit_step_wall_s"] == pytest.approx(sum(walls[True]),
+                                                   rel=1e-9)
+    table_wall = sum(sum(row[1:]) for row in r["step_wall_hist"])
+    table_steps = sum(row[0] for row in r["step_wall_hist"])
+    assert table_steps - r["admit_steps"] == 6
+    assert table_wall - r["admit_step_wall_s"] == pytest.approx(
+        sum(walls[False]), rel=1e-3)
+
+
+def test_between_s_is_the_callers_time_between_two_launched_steps(make,
+                                                                  clock):
+    b = make()
+    sid = b.submit(_prompt(6), 3)
+    b.step()
+    assert b.report()["between_s"] == 0.0       # no step before the first
+    clock.offset += 50.0                        # the caller dawdles
+    b.step()
+    r = b.report()
+    assert 50.0 <= r["between_s"] < 55.0
+    assert r["step_wall_s"] < 40.0              # and no step's wall holds it
+    while b._streams[sid].status != "finished":
+        b.step()
+    r0 = b.report()
+    assert b.step() == 0                        # an idle batcher...
+    clock.offset += 100.0                       # ...waits for work
+    b.submit(_prompt(6, 1), 3, rng_seed=1)
+    b.step()
+    r1 = b.report()
+    assert r1["steps"] == r0["steps"] + 1
+    assert r1["between_s"] == r0["between_s"]   # a wait is no hand-off
+    b.step()
+    assert 0 < b.report()["between_s"] - r1["between_s"] < 10.0
+    # nor is it one where the caller does not poll the batcher it emptied
+    while b._slot_to_sid:
+        b.step()
+    r2 = b.report()
+    clock.offset += 100.0
+    b.submit(_prompt(6, 2), 3, rng_seed=2)
+    b.step()
+    assert b.report()["between_s"] == r2["between_s"]
+
+
+def test_tok0_hold_is_the_steps_return_less_the_token0_reading(make, clock):
+    b = make()
+    readings, inner = [], b._admitted_at
+
+    def admitted_at(*a):
+        readings.append(inner(*a))
+        return readings[-1]
+
+    b._admitted_at = admitted_at
+    b.submit(_prompt(6), 4)
+    b.submit(_prompt(9, 1), 4, temperature=0.5, rng_seed=1)
+    _push_in(b, "_grow_writable", clock, 10.0)  # twice: two running streams
+    b.step()
+    r = b.report()
+    assert len(readings) == 2 and readings[0] < readings[1] < b._returned
+    assert r["tok0_hold_s"] == pytest.approx(
+        sum(b._returned - t for t in readings), rel=1e-12)
+    assert 40.0 <= r["tok0_hold_s"] < 45.0
+    b.step()                                    # a plain step holds no token
+    assert b.report()["tok0_hold_s"] == r["tok0_hold_s"]
+    assert b.report()["admit_steps"] == 1
+
+
+def test_prefill_hold_holds_no_token_and_counts_its_prefill(params, clock):
+    b = ContinuousBatcher(CFG, params, BCFG)
+    sid = b.submit(_prompt(7), 3)
+    st = b.prefill_hold(sid)
+    clock.offset += 50.0
+    r = b.report()
+    assert st is not None and len(st.tokens) == 1
+    assert r["tok0_hold_s"] == 0.0 and r["prefill_tokens"] == 7
+    assert r["admit_steps"] == 0 and r["between_s"] == 0.0
+    assert sum(row[0] for row in r["step_wall_hist"]) == 0
+    b.release_handoff(sid)
+    # and the reading it took is gone: a later step holds only its own
+    b.submit(_prompt(5, 1), 3, rng_seed=1)
+    b.step()
+    assert 0 < b.report()["tok0_hold_s"] < 10.0
+
+
+def test_prefill_tokens_are_the_prompt_less_the_matched_prefix(params):
+    bcfg = BatchingConfig(page_size=8, num_pages=17, max_slots=4,
+                          pages_per_slot=4,
+                          prefix_cache=paged_kv.PrefixCacheConfig())
+    b = ContinuousBatcher(CFG, params, bcfg)
+    first = _prompt(20)
+    b.submit(first, 6)
+    b.step()
+    assert b.report()["prefill_tokens"] == 20
+    second = np.concatenate([first[:16], _prompt(5, 3)])   # two pages shared
+    sid = b.submit(second, 6, rng_seed=1)
+    b.step()
+    matched = 16
+    assert b.report()["prefix"]["saved_tokens"] == matched
+    assert b.report()["prefill_tokens"] == 20 + (21 - matched)
+    # a resume adopts its rows back and prefills nothing
+    b.evict(sid)
+    b.step()
+    r = b.report()
+    assert r["evicted"] == 1 and r["admitted"] == 3
+    assert r["prefill_tokens"] == 20 + (21 - matched)
+    assert r["admit_steps"] == 3                # the resume admitted, though
+    assert r["tok0_hold_s"] > 0
+
+
+def test_report_no_longer_holds_occupancy_max(make):
+    b, _ = _run(make)
+    r = b.report()
+    assert "occupancy_max" not in r and "occ_max" not in b.stats
+    assert 0 < r["occupancy_mean"] <= 1
+
+
+class _CountedLock:
+    def __init__(self, lock):
+        self.lock, self.taken = lock, 0
+
+    def __enter__(self):
+        self.taken += 1
+        return self.lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self.lock.__exit__(*exc)
+
+
+def test_a_plain_step_takes_the_lock_once_and_reads_no_new_clock(make,
+                                                                 clock):
+    """The fold's cost is guarded by counts: a step that admits nothing and
+    finishes nothing takes ``_stats_lock`` once and reads the clock ten times,
+    as before the fold kept anything: ``batch.step`` in and out, the six
+    phases out (each starts where the last stopped), ``decode_s``'s two. An
+    admission reads it eleven times, as before: ``queued_t`` at ``submit()``,
+    ``t0``, the reading after ``tok0_sync`` (now token 0's too: reused, not
+    added), and the four ``batch.admit*`` spans in and out."""
+    b = make()
+    b.submit(_prompt(6), 8)
+    b.step()
+    b._stats_lock = lock = _CountedLock(b._stats_lock)
+    reads = clock.reads
+    assert b.step() == 1
+    assert lock.taken == 1 and clock.reads - reads == 10
+    reads = clock.reads
+    b.submit(_prompt(7, 1), 8, rng_seed=1)
+    lock.taken = 0                              # submit() took it for itself
+    assert b.step() == 2
+    assert lock.taken == 1 and clock.reads - reads == 10 + 3 + 8
 
 
 # ---------------------------------------------------------------------------

@@ -100,3 +100,141 @@ def test_cell_lists_the_new_readers(cell):
         "admit_ms_req", "compiles_in_window"}
     assert ("queue_wait_ms" in names) == (cell == CELLS[0])
     assert "kv_write_dev_ms" not in names      # PERF.md section 3 says why
+
+
+# ---------------------------------------------------------------------------
+# PR 34: what the batcher's fold keeps of a step, read over a window
+# ---------------------------------------------------------------------------
+
+FOLD_READERS = ("step_wall_p99_ms", "tail_step_wall_ms",
+                "tail_step_admit_share", "admit_step_share",
+                "admit_step_wall_ms", "plain_step_wall_ms",
+                "between_steps_ms", "prefill_tok_s")
+ALL_CELLS = CELLS + ("granite-4.0-h-small-ep2.decode-sat",
+                     "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
+                     "mistral-small-4-119b-ep4.decode-sat-deep")
+EDGES = [0.01, 0.02, 0.04, 0.08]            # five rows: under, three, over
+PHASE_KEYS = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s",
+              "commit_s")
+
+
+def _row(steps, admit, sync):
+    """A table row whose wall is ``admit + sync`` but for 1 ms a step that
+    the four small phases share."""
+    small = 0.00025 * steps
+    return [steps, admit, small, small, small, sync - 4 * small, small]
+
+
+def _fold_reports(window_rows, **window):
+    """``report0`` with a history of its own and ``report1`` = it + the
+    window: the table row by row, every other key by ``window``, the six
+    clocks by the table's columns (a closed loop: every call launched)."""
+    before = [_row(7, 0.07, 0.07) for _ in window_rows]
+    r0 = {"step_wall_hist": before, "step_wall_edges_s": EDGES, "steps": 35,
+          "admitted": 9, "admit_steps": 5, "admit_step_wall_s": 0.7,
+          "between_s": 0.1, "tok0_hold_s": 0.2, "prefill_tokens": 900,
+          "prefill_s": 0.3,
+          **{k: sum(r[i] for r in before)
+             for i, k in enumerate(PHASE_KEYS, 1)}}
+    r1 = {**r0, "step_wall_hist": [[a + b for a, b in zip(x, y)]
+                                   for x, y in zip(before, window_rows)]}
+    for i, k in enumerate(PHASE_KEYS, 1):
+        r1[k] = r0[k] + sum(r[i] for r in window_rows)
+    r1["steps"] = r0["steps"] + sum(r[0] for r in window_rows)
+    for k, v in window.items():
+        r1[k] = r0[k] + v
+    return r0, r1
+
+
+@pytest.fixture(scope="module")
+def fold_record():
+    """200 steps: 150 of 12 ms, 49 of 30 ms (a third of it admission), one
+    of 500 ms (nine tenths admission); 20 of them admitted 25 streams."""
+    rows = [_row(0, 0.0, 0.0), _row(150, 0.0, 1.8), _row(49, 0.49, 0.98),
+            _row(0, 0.0, 0.0), _row(1, 0.45, 0.05)]
+    return _record(False, *_fold_reports(
+        rows, admitted=25, admit_steps=20, admit_step_wall_s=1.1,
+        between_s=0.4, tok0_hold_s=0.25, prefill_tokens=6000,
+        prefill_s=0.5))
+
+
+@pytest.mark.parametrize("name, want", [
+    # 198 of 200 steps lie under it: 48 of the 49 in [20, 40) ms
+    ("step_wall_p99_ms", 20.0 + 20.0 * 48 / 49),
+    # the slowest two: the 500 ms step and one of the 49, pro rata
+    ("tail_step_wall_ms", (500.0 + 30.0) / 2),
+    ("tail_step_admit_share", 100.0 * (0.45 + 0.49 / 49) / 0.53),
+    ("admit_step_share", 10.0),
+    ("admit_step_wall_ms", 55.0),
+    ("plain_step_wall_ms", 1e3 * (1.8 + 1.47 + 0.5 - 1.1) / 180),
+    ("between_steps_ms", 2.0),
+    ("tok0_hold_ms", 10.0),
+    ("prefill_tok_s", 12000.0)])
+def test_fold_reader_on_a_hand_made_window(name, want, fold_record):
+    assert _read(name, fold_record) == pytest.approx(want, rel=1e-9)
+
+
+@pytest.mark.parametrize("name", FOLD_READERS + ("tok0_hold_ms",))
+def test_fold_reader_gives_nothing_on_a_program_without_its_counters(name):
+    # the parent's report(): steps, admissions and clocks, no table
+    old = _record(False, {"steps": 5, "admitted": 2, "prefill_s": 0.1},
+                  {"steps": 25, "admitted": 12, "prefill_s": 0.6})
+    assert _read(name, old) is None
+    # and a window in which nothing was launched or admitted divides by 0
+    r0, _ = _fold_reports([_row(0, 0.0, 0.0)] * 5)
+    assert _read(name, _record(False, r0, dict(r0))) is None
+
+
+def test_no_admitting_step_leaves_the_plain_ones_and_the_tail():
+    rows = [_row(0, 0.0, 0.0), _row(100, 0.0, 1.2)] + [_row(0, 0.0, 0.0)] * 3
+    rec = _record(False, *_fold_reports(rows))
+    assert _read("admit_step_share", rec) == 0.0
+    assert _read("admit_step_wall_ms", rec) is None
+    assert _read("plain_step_wall_ms", rec) == pytest.approx(12.0)
+    assert _read("tail_step_wall_ms", rec) == pytest.approx(12.0)
+    assert _read("tail_step_admit_share", rec) == 0.0
+    # one row: the percentile goes by the bucket's edges, not its mean
+    assert _read("step_wall_p99_ms", rec) == pytest.approx(19.9)
+    assert _read("prefill_tok_s", rec) is None and _read(
+        "tok0_hold_ms", rec) is None
+
+
+def test_end_rows_give_their_mean_wall_to_the_percentile():
+    over = [_row(0, 0.0, 0.0)] * 4 + [_row(10, 0.0, 200.0)]
+    assert _read("step_wall_p99_ms", _record(
+        False, *_fold_reports(over))) == pytest.approx(20000.0)
+    under = [_row(10, 0.0, 0.05)] + [_row(0, 0.0, 0.0)] * 4
+    assert _read("step_wall_p99_ms", _record(
+        False, *_fold_reports(under))) == pytest.approx(5.0)
+
+
+def test_the_two_identities_of_the_table(fold_record):
+    """The window's rows sum to ``steps`` and, column by column, to the six
+    ``host_*_ms`` x ``steps``; the admitting and the plain steps' walls,
+    weighted by their shares, are the table's wall over its steps, which in a
+    closed loop is ``step_wall_ms``."""
+    r0, r1 = fold_record["report0"], fold_record["report1"]
+    steps = r1["steps"] - r0["steps"]
+    rows = [[b - a for a, b in zip(x, y)] for x, y in zip(
+        r0["step_wall_hist"], r1["step_wall_hist"])]
+    assert sum(r[0] for r in rows) == steps == 200
+    reader_of = {key: name for name, key in COUNTER_READERS.items()}
+    for i, key in enumerate(PHASE_KEYS, 1):
+        column = sum(r[i] for r in rows)
+        assert _read(reader_of[key], fold_record) * steps == pytest.approx(
+            1e3 * column, rel=1e-3), key
+    share = _read("admit_step_share", fold_record)
+    mixed = (share * _read("admit_step_wall_ms", fold_record)
+             + (100.0 - share) * _read("plain_step_wall_ms", fold_record))
+    wall_ms = sum(1e3 * (r1[k] - r0[k]) for k in PHASE_KEYS) / steps
+    assert mixed == pytest.approx(100.0 * wall_ms, rel=1e-3)
+
+
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_cell_lists_the_fold_readers(cell):
+    per_layer = {m.name: m for m in load_cell(cell).per_layer}
+    assert set(per_layer) >= set(FOLD_READERS)
+    assert ("tok0_hold_ms" in per_layer) == (cell == CELLS[0])
+    assert all(per_layer[n].unit == ("tokens/s" if n == "prefill_tok_s" else
+                                     "%" if n.endswith("_share") else "ms")
+               for n in FOLD_READERS)
