@@ -487,8 +487,12 @@ def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
     evict / readmit: the prefill attends EXPANDED, every step ABSORBED over
     the one-leaf pool across two page boundaries, the rows leave the device
     and come back in between, and ``forward`` (expanded at every position)
-    over prompt + tokens puts each served token first."""
+    over prompt + tokens puts each served token first. The step's read is
+    the page walk (pages of 16 float32 rows of 128 lanes are whole tiles):
+    the evicted stream's rows come back into other pages and its tokens
+    reproduce through the kernel."""
     from edgellm_tpu.models.configs import tiny_mistral4_config
+    from edgellm_tpu.models.paged_kv import PAGE_WALK
     from edgellm_tpu.serve.batching import BatchingConfig
 
     cfg = tiny_mistral4_config(experts_held=4, expert_offset=2)
@@ -497,7 +501,12 @@ def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
     report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after)
     assert report["latent_rows_capacity"] == 72 * 16
     assert report["kv_row_bytes"] == cfg.kv_row_lanes * 4
+    assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
     return {"tokens": int(n_new), "evicted": report["evicted"],
+            "decode_read": report["decode_read"],
+            "attend_pages_walked": report["attend_pages_walked"],
+            "attend_pages_spanned": report["attend_pages_spanned"],
             "kv_row_bytes": report["kv_row_bytes"],
             "routed_local": report["routed_local"],
             "gap_max_over_logit_max": gap}

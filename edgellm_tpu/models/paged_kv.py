@@ -69,10 +69,10 @@ A latent row (PERF.md §6 "PR 32"): a stack of latent-attention layers
 (``models/mla.py``) caches ONE row a position a layer for all its heads, ``[c
 | k_rope]`` zero-padded to whole lane tiles (``cfg.kv_row_lanes``: 320 ->
 384), in a pool of ONE leaf (:class:`LatentPool`) in the same page group, by
-the same table and allocator as full layers' K/V: pages grow with the stream,
-the surgery below runs over the one leaf, :func:`write_rows` and
-:func:`_gather_pages` address it as they do K or V, and
-:func:`attend_latent` stands beside :func:`attend_rows`.
+the same table and allocator as full layers' K/V: the surgery below runs over
+the one leaf, :func:`write_rows` and :func:`_gather_pages` address it as K or
+V, and :func:`attend_latent` / :func:`attend_latent_pages` ("PR 35") stand
+beside :func:`attend_rows` / :func:`attend_pages`.
 
 Neither half helps alone. The lane-dense row with ``.at[:, dest]`` still
 costs two whole-pool copies a leaf (the compiler moves L under the row
@@ -2105,19 +2105,19 @@ def _on_tpu() -> bool:
 
 def decode_read_path(pool, window: int = 0) -> str:
     """Which read a decode step's attention layer is built with, read off
-    what it is handed: :data:`PAGE_WALK` (:func:`attend_pages`) for an fp
-    :class:`PagePool` whose pages hold a prefix (``window == 0``), on a TPU,
-    where a page is whole tiles (rows of whole lane tiles, a page of whole
-    sublane tiles: what the kernel's page DMAs need); :data:`PAGE_GATHER`
-    (:func:`read_span` + :func:`attend_rows`, the oracle) for a quantized
-    tier, which dequantizes after the gather, a window ring, the latent
-    pool and every other backend. ``pool`` may be a whole, staged or
-    one-layer pool. No width is gated out: on a v5e the walk is ahead at 128,
-    256, 512 and 1024 lanes (PERF.md §6 "PR 33")."""
-    if not isinstance(pool, PagePool) or window or not _on_tpu():
+    what it is handed: :data:`PAGE_WALK` (:func:`attend_pages`; a
+    :class:`LatentPool`: :func:`attend_latent_pages`) for an fp pool whose
+    pages hold a prefix (``window == 0``), on a TPU, where a page is whole
+    tiles (rows of whole lane tiles, a page of whole sublane tiles: what the
+    kernel's page DMAs need); :data:`PAGE_GATHER` (the oracle) for a
+    quantized tier, a window ring and every other backend. ``pool`` may be
+    whole, staged or one layer's. No width is gated out: on a v5e the walk
+    is ahead at 128 to 1024 lanes (PERF.md §6 "PR 33", "PR 35")."""
+    if not isinstance(pool, (PagePool, LatentPool)) or window or not _on_tpu():
         return PAGE_GATHER
-    sublanes = 32 // jnp.dtype(pool.k.dtype).itemsize
-    whole = pool.k.shape[-1] % LANE_TILE == 0 and pool.page_size % sublanes == 0
+    leaf = pool[0]                 # K, or a latent pool's one leaf
+    sublanes = 32 // jnp.dtype(leaf.dtype).itemsize
+    whole = leaf.shape[-1] % LANE_TILE == 0 and pool.page_size % sublanes == 0
     return PAGE_WALK if whole else PAGE_GATHER
 
 
@@ -2167,16 +2167,16 @@ def _attention_decode_latent(cfg: ModelConfig, lp: dict, x, cos_b, sin_b,
                              pool: LatentPool, layer, page_table, lengths):
     """A latent-attention layer of the ragged step, ABSORBED: x (B, D)
     normalised; project and rotate each slot at ITS position, write its new
-    row into its current page (``paged_kv.write``), gather each slot's pages
-    and attend them as they lie, then the V half of ``W_kvb`` and ``W_o``.
-    Returns (out (B, D), pool)."""
+    row into its current page (``paged_kv.write``), attend each slot's rows
+    as they lie in its pages (:func:`latent_decode_attention`), then the V
+    half of ``W_kvb`` and ``W_o``. Returns (out (B, D), pool)."""
     q_nope, q_rope, row = mla.project(cfg, lp, x,
                                       mla.rotate_rows(cos_b, sin_b),
                                       mla.query_scale(cfg, lengths))
     pool = write_rows(pool, layer, page_table, lengths, row[:, None], None)
-    ctx = attend_latent(mla.absorb_query(cfg, lp, q_nope, q_rope),
-                        _gather_pages(pool.rows, layer, page_table),
-                        lengths + 1, cfg.head_dim)
+    q_rows = mla.absorb_query(cfg, lp, q_nope, q_rope)
+    ctx = latent_decode_attention(q_rows, pool, layer, page_table,
+                                  lengths + 1, cfg.head_dim)
     return mla.unabsorb(cfg, lp, ctx), pool
 
 
@@ -2354,3 +2354,50 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
     with jax.named_scope("unembed_sample"):
         logits = unembed(cfg, params, hidden)[:, -1]  # (B, V) fp32
     return logits, pool
+
+
+# -- the latent layers' decode read ------------------------------------------
+# Below the K/V step, so that no line of it moves: a Pallas kernel's body
+# carries its call stack's line numbers into the compile-cache key (PERF.md
+# §6 "PR 32"), and the five cells that hold no latent row keep theirs.
+
+def attend_latent_pages(q_rows, pool: LatentPool, layer, page_table, lengths,
+                        head_dim: int):
+    """:func:`_gather_pages` + :func:`attend_latent` without the span: q_rows
+    (B, H, lanes) against each slot's LIVE pages of layer ``layer`` of the
+    one-leaf pool, read where they lie by the kernel :func:`attend_pages`
+    hands two leaves (``flash_attention.paged_decode_walk``, ``v_pages``
+    None: a fetched row is key and value both, a DMA a page). The leaf goes
+    in whole, viewed (L*P, ps, lanes) — a bitcast of the carried pool — with
+    the page ids ``layer*P + page_table``; the same rows attended, float32
+    scores and softmax, probabilities in q's dtype before the weighted sum,
+    in a blockwise order of the float32 sums. Returns (B, H, lanes).
+
+    A block holds TWICE the pages of a two-leaf walk's: as many page DMAs in
+    flight (64 at 16-row pages) and the same VMEM in two buffers as there in
+    four. On a v5e at the mistral4 cell's shape 256 / 512 / 1024 / 1536 /
+    2048 rows a block take 1.86 / 1.40 / 1.25 / 1.21 / 1.23 ms a layer (an
+    all-idle batch 0.076 at 512, 0.101 at 1024, 0.157 at 2048; PERF.md §6
+    "PR 35")."""
+    pages = _pages(pool.rows, 1)
+    ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
+    return flash_attention.paged_decode_walk(
+        q_rows, pages, None, ids, lengths.astype(jnp.int32),
+        scale=float(1.0 / np.sqrt(head_dim)),
+        pages_per_block=2 * flash_attention.paged_walk_pages_per_block(
+            pages.shape[1], pages.shape[2], pages.dtype.itemsize))
+
+
+def latent_decode_attention(q_rows, pool: LatentPool, layer, page_table,
+                            lengths, head_dim: int):
+    """:func:`paged_decode_attention` for latent rows: the absorbed query
+    ``q_rows`` (B, H, lanes) of every slot against the rows of its pages of
+    layer ``layer``, ``lengths`` (B,) counting the one this step wrote; the
+    weighted sums of rows (B, H, lanes) in q's dtype. Where
+    :func:`decode_read_path` says so, the page walk; otherwise one page
+    gather and :func:`attend_latent` over its output as it lies."""
+    if decode_read_path(pool) == PAGE_WALK:
+        return attend_latent_pages(q_rows, pool, layer, page_table, lengths,
+                                   head_dim)
+    return attend_latent(q_rows, _gather_pages(pool.rows, layer, page_table),
+                         lengths, head_dim)

@@ -74,7 +74,8 @@ def read(request, monkeypatch):
     """The decode read a step is compiled on. ``walk``: what the program
     picks on a TPU for an fp pool of whole tiles; ``gather``: what it picks
     here, the XLA oracle."""
-    steps = (batching._batched_step_jit, batching._batched_window_step_jit)
+    steps = (batching._batched_step_jit, batching._batched_window_step_jit,
+             batching._batched_hybrid_step_jit)
 
     def forget():       # a jit keeps its trace by arguments, not by backend
         for step in steps:
@@ -435,40 +436,63 @@ def _latent_step(one):
         arr((L_SLOTS,), jnp.float32), None).compile()
 
 
-def test_latent_step_is_absorbed_and_its_one_leaf_pool_stays_in_place(topo):
-    """The step of a stack of latent layers at the cell's shapes: ONE gather
-    a layer of each slot's span of 384-lane rows out of the one-leaf pool,
-    which no copy, relayout or stacking touches; no tensor with a (96, 12288,
-    32, ...) shape exists (keys and values are never rebuilt per head: the
-    absorption is real), and the gathers' own outputs are the only span-sized
-    temporaries (0.9 GB a layer, one alive at a time, beside 11.0 GB of
-    weights and pool)."""
-    step = _latent_step(SingleDeviceSharding(topo.devices[0]))
+def test_latent_step_is_absorbed_and_its_one_leaf_pool_stays_in_place(topo,
+                                                                      read):
+    """The step of a stack of latent layers at the cell's shapes. On the page
+    walk (PERF.md §6 "PR 35"): one kernel a layer reads each slot's live
+    pages out of the one-leaf pool, and NOTHING span-sized exists in the
+    module — no gather of a slot's 768 pages, no (slots, heads, span) scores:
+    the step's temporaries are 17.7 MB where the gather's are 1.08 GB. On the
+    page gather: ONE gather a layer of each slot's span of 384-lane rows,
+    the gathers' own outputs the only span-sized temporaries (0.9 GB a
+    layer, one alive at a time, beside 11.0 GB of weights and pool). Either
+    way no copy, relayout or stacking touches the pool, which is donated, and
+    no tensor with a (96, 12288, 32, ...) shape exists (keys and values are
+    never rebuilt per head: the absorption is real)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    assert paged_kv.decode_read_path(paged_kv.LatentPool(
+        jax.ShapeDtypeStruct((4, 73729, PAGE, 384), jnp.bfloat16))) == {
+            "walk": paged_kv.PAGE_WALK, "gather": paged_kv.PAGE_GATHER}[read]
+    step = _latent_step(one)
     hlo = step.as_text()
     span = L_PAGES_PER_SLOT * PAGE
     gathered = L_SLOTS * span * 384
     gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
                if op == "gather" and _elements(shape) >= gathered]
     rows = f"bf16[{L_SLOTS},{L_PAGES_PER_SLOT},{PAGE},384]"
-    assert gathers == [rows] * 4, gathers
     own = {rows, f"bf16[{L_SLOTS * L_PAGES_PER_SLOT},{PAGE},384]",
            f"bf16[{L_SLOTS},{span},384]"}
     moved = [m for m in _moved(hlo, gathered)
              if not (m[0] in ("reshape", "transpose") and m[2] in own)]
     assert not moved, moved
-    # nothing per head over the span: the widest span-sized tensors beside
-    # the gathered rows are the (slots, heads, span) scores
+    # nothing per head over the span
     per_head = re.findall(rf"\[{L_SLOTS},{span},32,\d+\]"
                           rf"|\[{L_SLOTS},32,{span},\d+\]", hlo)
     assert not per_head, per_head[:3]
+    pool_views = {f"bf16[4,73729,{PAGE},384]",
+                  f"bf16[{4 * 73729 * PAGE},384]",
+                  f"bf16[{4 * 73729},{PAGE},384]"}
     big = {shape.split("{")[0] for _, _, shape, _ in _instructions(hlo)
            if _elements(shape) >= gathered and not shape.startswith("(")}
-    assert big <= own | {f"bf16[4,73729,{PAGE},384]",
-                         f"bf16[{4 * 73729 * PAGE},384]",
-                         f"bf16[{4 * 73729},{PAGE},384]"}, big
     # the pool is addressed flat: pages at (layer, page), rows at (l, p, r)
     assert f"bf16[{4 * 73729},{PAGE},384]" in hlo
     assert f"bf16[{4 * 73729 * PAGE},384]" in hlo
     mem = step.memory_analysis()
-    assert mem.temp_size_in_bytes < 1.3e9
+    assert mem.alias_size_in_bytes >= 4 * 73729 * PAGE * 384 * 2   # donated
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+    if read == "gather":
+        assert gathers == [rows] * 4, gathers
+        assert not _walks(hlo)
+        # beside the gathered rows the widest span-sized tensors are the
+        # (slots, heads, span) scores
+        assert big <= own | pool_views, big
+        assert 0.9e9 < mem.temp_size_in_bytes < 1.3e9
+        return
+    # the walk: the static walk over four layers, a kernel each, handed the
+    # whole leaf; of span size there is the pool itself and nothing else
+    assert _walks(hlo) == 4, _walks(hlo)
+    assert not gathers, gathers
+    assert big <= pool_views, big
+    scores = _span_sized(hlo, own | {f"[{L_SLOTS},32,{span}]"})
+    assert not scores, scores[:3]
+    assert mem.temp_size_in_bytes < 50e6, mem.temp_size_in_bytes

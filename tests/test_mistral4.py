@@ -530,6 +530,39 @@ def test_the_latent_counters(cfg, live, capacity, row_bytes):
     assert rep["kv_row_bytes"] == row_bytes
 
 
+def test_a_latent_batcher_whose_read_is_the_walk_counts_its_pages(
+        monkeypatch, params):
+    """``decode_read`` is read off the pool the batcher serves, a one-leaf
+    :class:`LatentPool` here: the page walk where a page is whole tiles and
+    the backend a TPU (the one question, answered by the test), the gather
+    for this file's pages of 4 rows. With it ``report()`` counts, before
+    every step, the pages under each slot's length with the row the step
+    writes (an idle slot's one trash page) against slots x table entries, by
+    the code that counts a K/V walk's. The counting is the host's: on the
+    CPU the step itself still gathers."""
+    bcfg = BatchingConfig(page_size=8, num_pages=61, max_slots=3,
+                          pages_per_slot=20)
+    with monkeypatch.context() as m:
+        m.setattr(paged_kv, "_on_tpu", lambda: True)
+        b = ContinuousBatcher(CFG, params, bcfg)
+        assert ContinuousBatcher(CFG, params, BCFG).decode_read \
+            == paged_kv.PAGE_GATHER
+    assert isinstance(b.pool.pool, LatentPool)
+    assert b.decode_read == paged_kv.PAGE_WALK
+    assert ContinuousBatcher(CFG, params, bcfg).decode_read \
+        == paged_kv.PAGE_GATHER                        # a cpu: the oracle
+    b.submit(_ids(6, 1), 12, rng_seed=0)      # decodes at cache lengths 6..16
+    b.submit(_ids(30, 2), 4, rng_seed=1)      # 30..32, then its slot idles
+    b.run()
+    rep = b.report()
+    assert rep["decode_read"] == paged_kv.PAGE_WALK and rep["steps"] == 11
+    lens = [list(range(6, 17)), [30, 31, 32] + [0] * 8, [0] * 11]
+    assert rep["attend_pages_walked"] == sum(
+        n // 8 + 1 for slot in lens for n in slot)
+    assert rep["attend_pages_spanned"] == 11 * 3 * 20
+    assert rep["latent_rows_capacity"] == 60 * 8
+
+
 # -- the named mistakes ---------------------------------------------------------
 
 def _project_with(change):

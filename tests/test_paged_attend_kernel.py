@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from edgellm_tpu.models import flash_attention, paged_kv
+from edgellm_tpu.models import flash_attention, hybrid, paged_kv
 from edgellm_tpu.models import tiny_config
+from edgellm_tpu.models.configs import tiny_mistral4_config
 from edgellm_tpu.models.transformer import init_params
 
 PAGE, PAGES_PER_SLOT, LAYERS, LAYER = 16, 8, 3, 1
@@ -67,11 +68,14 @@ def _pool_and_table(width, lengths, scrambled, poisoned, dtype, seed):
     return pool, jnp.asarray(table), held
 
 
+_KERNEL = flash_attention.paged_decode_walk      # before any test patches it
+
+
 def _interpreted(*args, **kwargs):
     """The kernel under the TPU interpreter, WAITED FOR: its host callbacks
     run JAX operations of their own, and deadlock against a main thread that
     has gone on to dispatch the next one."""
-    return jax.block_until_ready(flash_attention.paged_decode_walk(
+    return jax.block_until_ready(_KERNEL(
         *args, **kwargs, interpret=pltpu.InterpretParams()))
 
 
@@ -159,6 +163,8 @@ def test_read_path_is_read_off_the_pool(monkeypatch):
     cfg = tiny_config("qwen2", num_layers=2, hidden_size=256, num_heads=4,
                       vocab_size=64)                     # KV 2 x hd 64
     fp = paged_kv.init_pool(cfg, 9, 16, jnp.bfloat16)
+    assert paged_kv.decode_read_path(
+        paged_kv.LatentPool(fp.k)) == paged_kv.PAGE_GATHER      # on a cpu
     quant = paged_kv.init_quant_pool(cfg, 9, 16, "int8_per_channel")
     narrow = paged_kv.PagePool(fp.k[..., :64], fp.v[..., :64])
     short = paged_kv.PagePool(fp.k[:, :, :8], fp.v[:, :, :8])
@@ -167,8 +173,12 @@ def test_read_path_is_read_off_the_pool(monkeypatch):
     assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_WALK
     assert paged_kv.decode_read_path(
         paged_kv.PagePool(fp.k[None], fp.v[None])) == paged_kv.PAGE_WALK
+    # a latent pool's one leaf is asked what K is asked
+    assert paged_kv.decode_read_path(
+        paged_kv.LatentPool(fp.k)) == paged_kv.PAGE_WALK
     for pool, window in ((fp, 32), (quant, 0), (narrow, 0), (short, 0),
-                         (paged_kv.LatentPool(fp.k), 0)):
+                         (paged_kv.LatentPool(narrow.k), 0),
+                         (paged_kv.LatentPool(short.k), 0)):
         assert paged_kv.decode_read_path(pool, window) == paged_kv.PAGE_GATHER
 
 
@@ -203,3 +213,64 @@ def test_paged_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
     np.testing.assert_allclose(np.asarray(got_pool.k),
                                np.asarray(want_pool.k), atol=tol)
+
+
+#: name -> (rows a page, parameter / query dtype, pool dtype, logit
+#: tolerance): a page is whole sublane tiles of its dtype either way, and
+#: the second is the mistral4 cell's pairing
+LATENT_STEPS = {"float32": (8, jnp.float32, jnp.float32, 1e-5),
+                "bfloat16": (16, jnp.bfloat16, jnp.bfloat16, 2e-2)}
+
+
+@pytest.mark.parametrize("poisoned", [False, True],
+                         ids=["clean", "dead-pages-hold-nan"])
+@pytest.mark.parametrize("compute", LATENT_STEPS)
+def test_latent_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
+                                                                compute,
+                                                                poisoned):
+    """The whole hybrid step of a toy ``mistral4`` stack built on the walk
+    (the choice forced as a TPU would make it, the kernel interpreted)
+    against the same step on the gather: logits and the written one-leaf
+    pool. Slot 1 is idle (length 0: its row goes to the trash page, which it
+    attends), slot 2 writes the first row of a new page, slot 3 the last row
+    of its last page. ``poisoned``: every page no table names holds NaN
+    under the walk, which must fetch none of them; nothing of a fetched
+    page's rows past a length (stale or another stream's) reaches the sum."""
+    page, dtype, pool_dtype, tol = LATENT_STEPS[compute]
+    cfg = tiny_mistral4_config(num_layers=2)
+    params = init_params(cfg, jax.random.key(0), dtype=dtype)
+    table = np.asarray([[1, 2, 3, 0], [0, 0, 0, 0], [7, 5, 0, 0],
+                        [9, 4, 8, 6]], np.int32)
+    lens = np.asarray([2 * page + 4, 0, page, 4 * page - 1], np.int32)
+    rows = paged_kv.init_pool(cfg, 13, page, pool_dtype).rows
+    assert rows.shape == (2, 13, page, 128)
+    rng = np.random.default_rng(11)
+    clean = jnp.asarray(rng.standard_normal(rows.shape), pool_dtype)
+    held = np.zeros((13,), bool)
+    held[np.unique(table)] = True               # the trash page among them
+    step = functools.partial(
+        hybrid.paged_decode_step_hybrid, cfg, params, pool_v=None,
+        conv_all=None, ssm_all=None,
+        expert_tokens=jnp.zeros((2, cfg.local_experts), jnp.int32),
+        page_table=jnp.asarray(table), lengths=jnp.asarray(lens),
+        token_ids=jnp.asarray([3, 0, 5, 7], jnp.int32))
+    want, want_rows, *_ = step(pool_k=clean)
+    monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    assert paged_kv.decode_read_path(
+        paged_kv.LatentPool(clean)) == paged_kv.PAGE_WALK
+    walks = []
+
+    def walk(qz, k_pages, v_pages, *args, **kwargs):
+        walks.append(v_pages)
+        return _interpreted(qz, k_pages, v_pages, *args, **kwargs)
+
+    monkeypatch.setattr(flash_attention, "paged_decode_walk", walk)
+    dead = jnp.asarray(~held)[None, :, None, None]
+    got, got_rows, *_ = jax.block_until_ready(step(
+        pool_k=jnp.where(dead, jnp.nan, clean) if poisoned else clean))
+    assert walks == [None, None]        # a layer a walk, the one leaf each
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
+    np.testing.assert_allclose(
+        np.asarray(jnp.where(dead, 0, got_rows), np.float32),
+        np.asarray(jnp.where(dead, 0, want_rows), np.float32), atol=tol)
