@@ -455,27 +455,51 @@ def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
             "gap_max_over_logit_max": gap}
 
 
-def window_phase(*, prompt_len: int = 300, n_new: int = 120,
-                 evict_after: int = 70) -> dict:
-    """A tiny ``mellum`` stream (sliding-window layers of 40 keys beside full
-    ones, YaRN on the full layers only, routed experts with none shared, an
-    untied head, float32) through the same admit / step / evict / readmit: a
-    ring of 4 pages of 16 rows a slot in the window group's own pool, a
-    prompt of 4.7 ring turns adopted by its tail, 120 steps that turn the
-    ring twice more, and the ring leaving the device and coming back in
-    between (the benchmark's cell never evicts)."""
-    from edgellm_tpu.models.configs import tiny_mellum_config
+def _ring_stream(cfg, prompt_len: int, n_new: int, evict_after: int) -> tuple:
+    """``_evict_readmit`` for a stack with window layers of 40 keys: a ring
+    of 4 pages of 16 rows a slot in the window group's own pool, a prompt of
+    4.7 ring turns adopted by its tail, 120 steps that turn the ring twice
+    more, and the ring leaving the device and coming back in between (the
+    benchmark's cells never evict). Returns (the report, the phase's row)."""
     from edgellm_tpu.serve.batching import BatchingConfig
 
-    cfg = tiny_mellum_config(sliding_window=40)
     bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
                           pages_per_slot=28)
     report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after)
     assert report["window_rows_capacity"] == 3 * 4 * 16
-    return {"tokens": int(n_new), "evicted": report["evicted"],
-            "window_pages": cfg.window_pages(16),
-            "routed_local": report["routed_local"],
-            "gap_max_over_logit_max": gap}
+    return report, {"tokens": int(n_new), "evicted": report["evicted"],
+                    "window_pages": cfg.window_pages(16),
+                    "routed_local": report["routed_local"],
+                    "gap_max_over_logit_max": gap}
+
+
+def window_phase(*, prompt_len: int = 300, n_new: int = 120,
+                 evict_after: int = 70) -> dict:
+    """A tiny ``mellum`` stream (sliding-window layers of 40 keys beside full
+    ones, YaRN on the full layers only, routed experts with none shared, an
+    untied head, float32) through the same admit / step / evict / readmit
+    (:func:`_ring_stream`)."""
+    from edgellm_tpu.models.configs import tiny_mellum_config
+
+    return _ring_stream(tiny_mellum_config(sliding_window=40), prompt_len,
+                        n_new, evict_after)[1]
+
+
+def afmoe_phase(*, prompt_len: int = 300, n_new: int = 120,
+                evict_after: int = 70) -> dict:
+    """A tiny ``afmoe`` stream (a leading dense layer and a period of expert
+    layers; window layers of 40 keys that rotate beside a full one that takes
+    no positions; q/k norms, the output gate, four norms a layer; sigmoid
+    routing with a nonzero selection bias and a shared expert, float32) the
+    same way: the ring group and the full group both leave the device and
+    come back. The prompt is past ``moe.DENSE_MAX_TOKENS``: the prefill takes
+    the grouped expert products."""
+    from edgellm_tpu.models.configs import tiny_afmoe_config
+
+    cfg = tiny_afmoe_config(sliding_window=40)
+    report, row = _ring_stream(cfg, prompt_len, n_new, evict_after)
+    assert len(report["expert_tokens"]) == cfg.expert_layers == 4
+    return {**row, "expert_layers": cfg.expert_layers}
 
 
 def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
@@ -545,6 +569,7 @@ def smoke(report: dict, save) -> dict:
     phase("hybrid", hybrid_phase)
     phase("window", window_phase)
     phase("latent", latent_phase)
+    phase("afmoe", afmoe_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

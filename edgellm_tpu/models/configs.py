@@ -50,8 +50,19 @@ class ModelConfig:
         rope lanes only, YaRN with its softmax ``mscale`` and a per-position
         query scale; routed experts plus a shared one on every layer, an
         untied head (Mistral Small 4).
+      - ``"afmoe"``: mellum's two kinds (``"sliding_attention"`` rings
+        beside ``"attention"``) with positions BY KIND the other way round
+        (the window layers rotate, plain RoPE; the full layers take none:
+        ``position_free``), a per-head RMSNorm on q and k ahead of the
+        rotation, a sigmoid gate on the attend's output ahead of ``W_o``,
+        a norm after each sublayer as well as before it, the first
+        ``num_dense_layers`` feed-forwards a dense SwiGLU of
+        ``intermediate_size`` and the rest routed experts plus a shared
+        one, routed by ``score_func`` ``"sigmoid"`` (a per-expert selection
+        bias, ``route_scale``), ``h0 = embed * sqrt(d)``, an untied head
+        (Arcee Trinity).
 
-    The fields after ``rope_scaling`` exist for those three families and
+    The fields after ``rope_scaling`` exist for those four families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -126,6 +137,15 @@ class ModelConfig:
     #: position ``pos``
     softmax_mscale: float = 1.0
     query_scale_beta: float = 0.0
+    #: the feed-forward kind by layer: the first ``num_dense_layers`` are a
+    #: dense SwiGLU of ``intermediate_size``, the rest routed expert layers
+    num_dense_layers: int = 0
+    #: the router's scores (``models/moe.route``): ``"softmax"`` over the
+    #: chosen logits, or ``"sigmoid"`` of every logit, the top-k taken of
+    #: score + a per-expert selection bias, the weights the chosen scores
+    #: alone, normalised to sum 1, times ``route_scale``
+    score_func: str = "softmax"
+    route_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -159,7 +179,23 @@ class ModelConfig:
     def is_hybrid(self) -> bool:
         """Walked by layer kinds (``models/hybrid.py``), with params held per
         kind and a routed expert layer after every mixer."""
-        return self.family in ("granitemoehybrid", "mellum", "mistral4")
+        return self.family in ("granitemoehybrid", "mellum", "mistral4",
+                               "afmoe")
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers whose feed-forward is routed experts: all but the leading
+        dense ones."""
+        return self.num_layers - self.num_dense_layers
+
+    @property
+    def position_free(self) -> tuple:
+        """The attention kinds that take no positions at all: every kind
+        under ``nope``; an afmoe stack's full layers, whose window layers
+        rotate."""
+        if self.nope:
+            return ("attention", "sliding_attention")
+        return ("attention",) if self.family == "afmoe" else ()
 
     @property
     def recurrent_state(self) -> bool:
@@ -225,17 +261,20 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.family not in ("gpt_neox", "qwen2", "llama",
-                               "granitemoehybrid", "mellum", "mistral4"):
+                               "granitemoehybrid", "mellum", "mistral4",
+                               "afmoe"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
         elif (self.layer_types or self.num_experts or self.mamba_heads
               or self.explicit_head_dim or self.sliding_window
-              or self.kv_lora_rank):
+              or self.kv_lora_rank or self.num_dense_layers
+              or self.score_func != "softmax"):
             raise ValueError(
                 f"layer_types / experts / mamba / head width / window / "
-                f"latent fields belong to the granitemoehybrid, mellum and "
-                f"mistral4 families, not {self.family!r}")
+                f"latent / dense-layer / routing fields belong to the "
+                f"granitemoehybrid, mellum, mistral4 and afmoe families, not "
+                f"{self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
@@ -244,7 +283,8 @@ class ModelConfig:
     def _check_hybrid(self):
         kinds = {"granitemoehybrid": ("mamba", "attention"),
                  "mellum": ("attention", "sliding_attention"),
-                 "mistral4": ("latent_attention",)}[self.family]
+                 "mistral4": ("latent_attention",),
+                 "afmoe": ("attention", "sliding_attention")}[self.family]
         if len(self.layer_types) != self.num_layers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
@@ -274,6 +314,11 @@ class ModelConfig:
                 f"experts held [{self.expert_offset}, "
                 f"{self.expert_offset + self.local_experts}) lie outside the "
                 f"router's {self.num_experts}")
+        if not 0 <= self.num_dense_layers < self.num_layers:
+            raise ValueError("num_dense_layers must leave at least one "
+                             "expert layer")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown score_func: {self.score_func!r}")
         if self.expert_width < 1 or self.shared_width < 0:
             raise ValueError("expert_width must be >= 1 and shared_width "
                              ">= 0 (0: no shared expert)")
@@ -449,6 +494,70 @@ MISTRAL_SMALL_4_119B = ModelConfig(
 )
 
 
+# arcee-ai/Trinity-Mini (26B-A3B, 2025-12) — config.json (``model_type``
+# ``afmoe``): 32 layers in a period of four (three sliding-window layers of
+# 2048 keys, rotated; one full, position-free), d 2048, 32 query / 4 KV heads
+# of 128, q and k normed per head, a sigmoid gate on the attend's output, four
+# norms a layer; two leading dense layers of width 6144, then 128 routed
+# experts of width 1024 top-8 by sigmoid scores with a selection bias (weights
+# normalised, times 2.826) plus one shared expert; ``mup_enabled``: the
+# embedding times sqrt(d); untied 200192-row head.
+TRINITY_MINI = ModelConfig(
+    family="afmoe",
+    vocab_size=200192,
+    hidden_size=2048,
+    num_layers=32,
+    num_heads=32,
+    num_kv_heads=4,
+    intermediate_size=6144,
+    max_position_embeddings=131072,
+    norm_eps=1e-5,
+    rope_theta=10000.0,
+    tie_word_embeddings=False,
+    layer_types=_MELLUM_PERIOD * 8,
+    explicit_head_dim=128,
+    sliding_window=2048,
+    num_experts=128,
+    experts_per_tok=8,
+    expert_width=1024,
+    shared_width=1024,
+    embedding_multiplier=math.sqrt(2048.0),
+    num_dense_layers=2,
+    score_func="sigmoid",
+    route_scale=2.826,
+)
+
+
+def tiny_afmoe_config(*, layer_types: tuple = (("sliding_attention",)
+                                               + _MELLUM_PERIOD),
+                      num_dense_layers: int = 1, sliding_window: int = 20,
+                      hidden_size: int = 48, num_heads: int = 4,
+                      num_kv_heads: int = 2, head_dim: int = 16,
+                      vocab_size: int = 256, num_experts: int = 8,
+                      experts_per_tok: int = 3, experts_held: int = 0,
+                      expert_offset: int = 0,
+                      max_position_embeddings: int = 512) -> ModelConfig:
+    """A small afmoe for tests: every mechanism of the published model (a
+    leading dense layer ahead of one whole period of expert layers, window
+    layers that rotate beside a full one that does not, a window shorter than
+    the test prompts, ``H x hd`` = 64 against a hidden size of 48, sigmoid
+    routing with a route scale and a shared expert, the embedding times
+    sqrt(d), an untied head) at toy widths."""
+    return ModelConfig(
+        family="afmoe", vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=len(layer_types), num_heads=num_heads,
+        num_kv_heads=num_kv_heads, intermediate_size=96,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-5,
+        rope_theta=10000.0, tie_word_embeddings=False,
+        layer_types=tuple(layer_types), explicit_head_dim=head_dim,
+        sliding_window=sliding_window, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32, shared_width=32,
+        experts_held=experts_held, expert_offset=expert_offset,
+        embedding_multiplier=math.sqrt(float(hidden_size)),
+        num_dense_layers=num_dense_layers, score_func="sigmoid",
+        route_scale=2.826)
+
+
 def tiny_mistral4_config(*, num_layers: int = 3, hidden_size: int = 48,
                          num_heads: int = 4, vocab_size: int = 256,
                          num_experts: int = 8, experts_per_tok: int = 3,
@@ -537,6 +646,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_mellum_config()
     if family == "mistral4":
         return tiny_mistral4_config()
+    if family == "afmoe":
+        return tiny_afmoe_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -565,6 +676,7 @@ PRESETS = {
     "granite-4.0-h-small": GRANITE_4_0_H_SMALL,
     "mellum2-12b-a2.5b": MELLUM2_12B_A2_5B,
     "mistral-small-4-119b": MISTRAL_SMALL_4_119B,
+    "trinity-mini": TRINITY_MINI,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
@@ -572,4 +684,5 @@ PRESETS = {
     "tiny-granite-hybrid": tiny_hybrid_config(),
     "tiny-mellum": tiny_mellum_config(),
     "tiny-mistral4": tiny_mistral4_config(),
+    "tiny-afmoe": tiny_afmoe_config(),
 }

@@ -255,6 +255,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         return _mellum_config(hf_config)
     if mt == "mistral4":
         return _mistral4_config(hf_config)
+    if mt == "afmoe":
+        return _afmoe_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -377,4 +379,53 @@ def _mistral4_config(hf_config) -> ModelConfig:
         v_head_dim=int(hf_config.v_head_dim),
         softmax_mscale=mscale(rope.get("mscale_all_dim", 0)),
         query_scale_beta=float(rope.get("llama_4_scaling_beta", 0.0)),
+    )
+
+
+def _afmoe_config(hf_config) -> ModelConfig:
+    """Arcee Trinity (``model_type`` ``afmoe``). The keys mapped:
+    ``head_dim`` (explicit), ``layer_types`` (``full_attention`` /
+    ``sliding_attention``: the sliding layers rotate by plain RoPE, the full
+    ones take no positions), ``sliding_window``, ``num_dense_layers`` (the
+    leading layers whose feed-forward is a SwiGLU of ``intermediate_size``),
+    ``num_experts``, ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``num_shared_experts`` (a shared expert that many times as wide; 0:
+    none), ``score_func`` ``sigmoid`` with ``route_norm`` and ``route_scale``,
+    ``mup_enabled`` (the embedding times ``sqrt(hidden_size)``). Refused by
+    name: routing by expert groups, a rope scaling, any other score."""
+    for key, want in (("n_group", 1), ("topk_group", 1),
+                      ("num_expert_groups", 1), ("num_limited_groups", 1),
+                      ("rope_scaling", None), ("score_func", "sigmoid"),
+                      ("route_norm", True), ("attention_bias", False)):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"afmoe with {key}={getattr(hf_config, key)!r} is not "
+                f"supported (only {want!r})")
+    d = int(hf_config.hidden_size)
+    return ModelConfig(
+        family="afmoe",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=d,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(hf_config.rope_theta),
+        tie_word_embeddings=hf_config.tie_word_embeddings,
+        layer_types=tuple(MELLUM_LAYER_KINDS[t]
+                          for t in hf_config.layer_types),
+        explicit_head_dim=int(hf_config.head_dim),
+        sliding_window=int(hf_config.sliding_window),
+        num_experts=int(hf_config.num_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+        shared_width=int(hf_config.num_shared_experts
+                         * hf_config.moe_intermediate_size),
+        embedding_multiplier=(math.sqrt(d) if getattr(
+            hf_config, "mup_enabled", False) else 1.0),
+        num_dense_layers=int(hf_config.num_dense_layers),
+        score_func="sigmoid",
+        route_scale=float(hf_config.route_scale),
     )

@@ -1,12 +1,11 @@
-"""A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum`` and
-``mistral4`` families' forward, prefill and decode steps.
+"""A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum``,
+``mistral4`` and ``afmoe`` families' forward, prefill and decode steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
-(``models/mamba2.py``: a fixed-size recurrent state and a convolution window
-per sequence) or a position-free GQA attention layer (K/V rows, the paged
-pool), each followed by the routed + shared expert layer (``models/moe.py``),
-so parameters are held PER KIND::
+(``models/mamba2.py``: a recurrent state and a convolution window a sequence)
+or a position-free GQA attention layer (K/V rows, the paged pool), each then
+the routed + shared expert layer (``models/moe.py``): params are PER KIND::
 
     params = {"embed": (V, D), "final_norm_scale": (D,),
               "mamba": {... stacked along the L_mamba mamba layers},
@@ -16,44 +15,43 @@ so parameters are held PER KIND::
 A ``mellum`` stack (JetBrains Mellum 2) has no ``mamba`` entry, and beside
 ``attn`` (its full causal layers, rotated by the YaRN table) a third kind,
 ``window``: sliding layers stacked the same way, rotated by the plain table,
-whose K/V live in a RING of pages (``paged_kv``: the window group); its
-expert layers have no shared expert and its head (``lm_head``) is untied.
-A ``mistral4`` stack (Mistral Small 4) has one kind, ``latent`` (``models/
-mla.py``): every layer caches ONE latent row a position in the page pool
-(``paged_kv.LatentPool``, pages that grow as full layers' do); its prefill
-attends in the EXPANDED form (keys and values rebuilt per head from the rows)
-and every decode step in the ABSORBED one (multi-query attention over the rows
-as cached); routed experts plus a shared one, an untied head.
+whose K/V live in a RING of pages (``paged_kv``: the window group); no shared
+expert, an untied head (``lm_head``). A ``mistral4`` stack (Mistral Small 4)
+has one kind, ``latent`` (``models/mla.py``): every layer caches ONE latent
+row a position in the page pool (``paged_kv.LatentPool``); its prefill
+attends EXPANDED (keys and values rebuilt per head from the rows) and every
+decode step ABSORBED (multi-query attention over the rows as cached);
+routed experts plus a shared one, an untied head. An ``afmoe``
+stack (Arcee Trinity) is mellum's two kinds with the positions the other way
+round (``window`` rotates, ``attn`` takes none: ``cfg.position_free``) and
+leaves no other family holds, each applied where a layer has it (``paged_kv.
+head_norms`` / ``gated`` / ``post_norm``): ``q_norm`` / ``k_norm`` per head
+ahead of the rotation, a gate ``wg`` ahead of ``W_o``, a ``post_scale`` norm
+on each sublayer's output; a leading dense layer's ``moe`` entry holds no
+router and is a SwiGLU (:func:`_feed_forward`).
 
-(the expert weights are a list, one entry a layer, and not a stack: the
-grouped products of a prefill are a kernel call whose operands must be whole
-buffers, and a row sliced from a ``(L, E, D, F)`` stack is a 226 MB copy a
-tensor a layer, all ten alive at once at the published sizes) and the stack
-is walked by a static Python loop over ``cfg.layer_types``: layer ``l`` takes
-entry ``l`` of ``moe`` and the next row of its own kind. Every
-layer is traced once per executable (ten for the benchmark's one period),
-which buys XLA a free hand with each layer's state: row ``j`` of the
-``(L_mamba, slots, ...)`` state store is read, updated and written in place,
-with no loop-carried copy of the whole store.
+(the expert weights are a list, one entry a layer, and not a stack: a row
+sliced from a ``(L, E, D, F)`` stack for a prefill's grouped products, whose
+operands must be whole buffers, is a 226 MB copy a tensor a layer) and the
+stack is walked by a static Python loop over ``cfg.layer_types``: layer ``l``
+takes entry ``l`` of ``moe`` and the next row of its own kind, so row ``j``
+of the ``(L_mamba, slots, ...)`` state store is updated in place.
 
-Every layer: ``h += residual_multiplier * mixer(rms(h; w1))`` then
-``h += residual_multiplier * (moe(u) + shared(u))``, ``u = rms(h; w2)``;
-``h0 = embed[ids] * embedding_multiplier``; logits ``= rms(h_L; w_f) @
-embed.T / logits_scaling``. Attention has no rotary of any kind and scores
-``q k^T * attention_multiplier``: the kernels and ``decode_attention`` all
-scale by ``1/sqrt(head_dim)``, so ``q`` is multiplied by
-``attention_multiplier * sqrt(head_dim)`` once, ahead of them.
+Every layer: ``h += residual_multiplier * mixer(rms(h; w1))`` then ``h +=
+residual_multiplier * (moe(u) + shared(u))``, ``u = rms(h; w2)``; ``h0 =
+embed[ids] * embedding_multiplier``; logits ``= rms(h_L; w_f) @ embed.T /
+logits_scaling``. Granite scores ``q k^T * attention_multiplier``: the
+kernels and ``decode_attention`` all scale by ``1/sqrt(head_dim)``, so ``q``
+is multiplied by ``attention_multiplier * sqrt(head_dim)`` once, ahead.
 
-What this module does not do, by name, because each needs a snapshot of the
-recurrent state that does not exist yet: boundary hooks and attention
-statistics (the sweep drivers), the split runtime, speculation, prefix
-sharing, quantized KV tiers, checkpoints. :func:`refuse_recurrent_state` is
-the one place the refusal is worded. The same mechanisms read "a slot's K/V
-= every position of every layer", which a window layer's ring does not
-hold: :func:`refuse_window_ring` words that refusal. A latent layer's row
-is not K and V of ``KV x hd`` lanes: :func:`refuse_latent_rows`.
-:func:`refuse_beyond_kv_rows` is what a mechanism calls to make all three.
-"""
+Not done, by name, because each needs a snapshot of the recurrent state that
+does not exist yet: boundary hooks and attention statistics (the sweep
+drivers), the split runtime, speculation, prefix sharing, quantized KV tiers,
+checkpoints (:func:`refuse_recurrent_state`). The same mechanisms read "a
+slot's K/V = every position of every layer", which a window layer's ring does
+not hold (:func:`refuse_window_ring`), and a latent layer's row is not K and
+V of ``KV x hd`` lanes (:func:`refuse_latent_rows`): all three are made by
+:func:`refuse_beyond_kv_rows`."""
 from __future__ import annotations
 
 from typing import NamedTuple
@@ -70,8 +68,8 @@ from .mamba2 import mamba2_prefill, mamba2_step
 from .moe import moe_layer
 from .paged_kv import (LatentPool, PagePool, _attention_decode_latent,
                        _attention_decode_paged, _attention_decode_window,
-                       attend_latent)
-from .transformer import _rmsnorm, apply_rotary, precompute_rope
+                       attend_latent, gated, head_norms, post_norm)
+from .transformer import _rmsnorm, apply_rotary, mlp, precompute_rope
 
 
 class RecurrentStateUnsupported(ValueError):
@@ -215,13 +213,14 @@ def _kinds(cfg: ModelConfig):
 
 
 def _qkv(cfg: ModelConfig, lp: dict, x):
-    """x (B, S, D) -> q (B, S, H, hd) pre-scaled, k, v (B, S, KV, hd); no
-    positions are applied."""
+    """x (B, S, D) -> q (B, S, H, hd) pre-scaled, k, v (B, S, KV, hd), q and k
+    normed per head where the layer has the norms; no positions are applied."""
     b, s, _ = x.shape
     hd = cfg.head_dim
     q = (x @ lp["wq"]).reshape(b, s, cfg.num_heads, hd)
     k = (x @ lp["wk"]).reshape(b, s, cfg.num_kv_heads, hd)
     v = (x @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
+    q, k = head_norms(cfg, lp, q, k)
     return q * jnp.asarray(cfg.q_prescale, q.dtype), k, v
 
 
@@ -237,15 +236,16 @@ def _rotated(cfg: ModelConfig, qkv: tuple, rope):
 
 def _rope_tables(cfg: ModelConfig, n: int) -> dict:
     """{kind: (cos, sin) (n, rot) or None}: the table each attention kind
-    rotates by — none under NoPE; the scaled one (YaRN) on full layers and
-    the plain one on sliding layers."""
-    if cfg.nope:
-        return {"attention": None, "sliding_attention": None}
+    rotates by — none for a kind in ``cfg.position_free``; else the scaled
+    one (YaRN) on full layers and the plain one on sliding layers."""
     if cfg.latent_layers:  # the rope lanes' table (cfg.rotary_dim wide)
         return {"latent_attention": precompute_rope(cfg, n)}
-    return {"attention": precompute_rope(cfg, n),
-            "sliding_attention": (precompute_rope(cfg, n, scaled=False)
-                                  if cfg.window_layers else None)}
+    free = cfg.position_free
+    return {"attention": (None if "attention" in free
+                          else precompute_rope(cfg, n)),
+            "sliding_attention": (
+                precompute_rope(cfg, n, scaled=False) if cfg.window_layers
+                and "sliding_attention" not in free else None)}
 
 
 def _attention_blocks(q, k, v, window: int):
@@ -296,7 +296,7 @@ def _attention_full(cfg: ModelConfig, lp: dict, x, rope=None,
                            itemsize=jnp.dtype(x.dtype).itemsize)
         out = (causal_attention(q, k, v, plan=plan) if plan is not None
                else jax.nn.dot_product_attention(q, k, v, is_causal=True))
-    return out.reshape(b, s, -1) @ lp["wo"], k, v
+    return _attn_out(cfg, lp, x, out.reshape(b, s, -1)), k, v
 
 
 def _attention_latent_full(cfg: ModelConfig, lp: dict, x, rope):
@@ -338,9 +338,9 @@ def _attention_latent_step(cfg: ModelConfig, lp: dict, x, rope, rows_all,
 
 
 def _ffn(cfg: ModelConfig, mp: dict, h, active=None):
-    """The expert sublayer on h (..., D); returns (h, counts (Eh,))."""
+    """The feed-forward sublayer on h (..., D); returns (h, counts (Eh,))."""
     u = _rms(cfg, h, mp["ln2_scale"])
-    out, counts = moe_layer(cfg, mp, u.reshape(-1, u.shape[-1]), active)
+    out, counts = _feed_forward(cfg, mp, u.reshape(-1, u.shape[-1]), active)
     return h + cfg.residual_multiplier * out.reshape(h.shape), counts
 
 
@@ -482,17 +482,17 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
             sliding = kind == "sliding_attention"
             lp = _row(params["window" if sliding else "attn"], j)
             k_all, v_all = rows[kind]
-            q, k, v = _rotated(
-                cfg, _qkv(cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None]),
-                rope[kind])
+            # x is kept: a layer with an output gate reads it a second time,
+            # after the attend (:func:`_attn_out`)
+            x = _rms(cfg, h, lp["ln1_scale"])
+            q, k, v = _rotated(cfg, _qkv(cfg, lp, x[:, None]), rope[kind])
             kc = jax.lax.dynamic_update_slice(
                 k_all[j], k.astype(k_all.dtype), (0, pos, 0, 0))
             vc = jax.lax.dynamic_update_slice(
                 v_all[j], v.astype(v_all.dtype), (0, pos, 0, 0))
-            out = decode_attention(
+            out = _attn_out(cfg, lp, x, decode_attention(
                 q, kc, vc, pos + 1,
-                cfg.sliding_window if sliding else 0).reshape(b, -1) \
-                @ lp["wo"]
+                cfg.sliding_window if sliding else 0).reshape(b, -1))
             rows[kind] = [k_all.at[j].set(kc), v_all.at[j].set(vc)]
         h = h + cfg.residual_multiplier * out
         h, _ = _ffn(cfg, params["moe"][layer], h)
@@ -534,7 +534,7 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
     pool_k/pool_v: (L_attn, num_pages, page_size, KV * hd), addressed by the
     static attention-layer number (paged_kv's flat index); conv_all / ssm_all:
     the per-slot state store, (L_mamba, max_slots, ...) float32; expert_tokens
-    (L, Eh) int32, the running count of assignments per held expert, which
+    (L expert layers, Eh) int32, the count of assignments per held expert, which
     gains this step's over the slots with ``lengths > 0`` (a free slot runs
     token-0 math into the trash page and into its own dead state rows, and is
     not counted). Returns (logits (max_slots, V) float32, pool_k, pool_v,
@@ -574,8 +574,8 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
             lp = _row(params["window"], j)
             out, (win_k, win_v) = _attention_decode_window(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None],
-                *rope[kind], PagePool(win_k, win_v), j, window_table,
-                lengths)
+                *(rope[kind] or (None, None)), PagePool(win_k, win_v), j,
+                window_table, lengths)
             out = out[:, 0]
         else:
             lp = _row(params["attn"], j)
@@ -586,7 +586,8 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
             out = out[:, 0]
         h = h + cfg.residual_multiplier * out
         h, c = _ffn(cfg, params["moe"][layer], h, active)
-        counts.append(c)
+        if c is not None:                      # a dense layer routes nothing
+            counts.append(c)
     with jax.named_scope("unembed_sample"):
         logits = unembed_hybrid(cfg, params, h)
     out = (logits, pool_k, pool_v, conv_all, ssm_all,
@@ -602,7 +603,12 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     with ``dt`` log-uniform in [0.001, 0.1], ``D = 1``, the convolution
     uniform in +-1/sqrt(d_conv)). A kind the stack has no layer of has no
     entry (``mamba``; ``window``; ``attn``; ``latent``), nor has an absent
-    shared expert or a tied head."""
+    shared expert or a tied head. An ``afmoe`` stack's attention kinds also
+    hold the per-head ``q_norm`` / ``k_norm``, the gate ``wg`` and
+    ``post_scale``; its leading dense layers' ``moe`` entries a SwiGLU of
+    ``intermediate_size`` and no router, its expert layers a float32
+    ``router_bias`` drawn NONZERO (a checkpoint's is trained; at zero a path
+    that dropped it would pass every test), each with ``post_scale``."""
     keys = iter(jax.random.split(key, 16 + 8 * cfg.num_layers))
 
     def init(*shape):
@@ -613,6 +619,8 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     lm, la, lt = cfg.mamba_layers, cfg.kv_layers, cfg.num_layers
     eh, f, fs = cfg.local_experts, cfg.expert_width, cfg.shared_width
 
+    afmoe = cfg.family == "afmoe"
+
     def attention(n):
         return {
             "ln1_scale": jnp.ones((n, d), dtype),
@@ -620,6 +628,10 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
             "wk": init(n, d, cfg.num_kv_heads * hd),
             "wv": init(n, d, cfg.num_kv_heads * hd),
             "wo": init(n, cfg.num_heads * hd, d),
+            **({"q_norm": jnp.ones((n, hd), dtype),
+                "k_norm": jnp.ones((n, hd), dtype),
+                "wg": init(n, d, cfg.num_heads * hd),
+                "post_scale": jnp.ones((n, d), dtype)} if afmoe else {}),
         }
 
     if lm:  # drawn first, as before the stack had kinds without it
@@ -667,16 +679,46 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
         }
     else:
         params["attn"] = attention(la)
+    fd = cfg.intermediate_size
+    norms = {"ln2_scale": jnp.ones((d,), dtype),
+             **({"post_scale": jnp.ones((d,), dtype)} if afmoe else {})}
     params["moe"] = [{
-        "ln2_scale": jnp.ones((d,), dtype),
+        **norms,
+        "w_gate": init(d, fd), "w_up": init(d, fd), "w_down": init(fd, d),
+    } if layer < cfg.num_dense_layers else {
+        **norms,
         "router": init(d, cfg.num_experts),
         "w_gate": init(eh, d, f), "w_up": init(eh, d, f),
         "w_down": init(eh, f, d),
         **({"shared_gate": init(d, fs), "shared_up": init(d, fs),
             "shared_down": init(fs, d)} if fs else {}),
-    } for _ in range(lt)]
+        **({"router_bias": init(cfg.num_experts).astype(jnp.float32)}
+           if cfg.score_func == "sigmoid" else {}),
+    } for layer in range(lt)]
     if cfg.window_layers:
         params["window"] = attention(cfg.window_layers)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = init(d, cfg.vocab_size)
     return params
+
+
+def _attn_out(cfg: ModelConfig, lp: dict, x, ctx):
+    """The attend's output ctx (..., H*hd) of the layer input x (..., D) ->
+    the sublayer's: gated where the layer has a gate, through ``W_o``, normed
+    where it has a post-norm."""
+    return post_norm(cfg, lp, gated(lp, x, ctx) @ lp["wo"])
+
+
+def _feed_forward(cfg: ModelConfig, mp: dict, u, active=None):
+    """One layer's feed-forward on u (T, D) normalised -> (out (T, D), counts
+    (Eh,)): the routed expert layer, or, where the entry holds no router (a
+    leading dense layer), a SwiGLU of ``intermediate_size`` under the scope
+    ``mlp`` and counts None; normed after where the entry holds
+    ``post_scale``, inside the sublayer's own scope. (Down here: the lines
+    above keep their numbers, PERF.md section 6 "PR 32".)"""
+    if "router" not in mp:
+        with jax.named_scope("mlp"):
+            return post_norm(cfg, mp, mlp(cfg, mp, u)), None
+    out, counts = moe_layer(cfg, mp, u, active)
+    with jax.named_scope("moe.experts"):
+        return post_norm(cfg, mp, out), counts

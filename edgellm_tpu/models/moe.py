@@ -1,8 +1,9 @@
-"""The routed expert layer of the ``granitemoehybrid`` and ``mellum``
-families, told which experts it holds.
+"""The routed expert layer of the families walked by layer kinds
+(``models/hybrid.py``), told which experts it holds.
 
 The router keeps its published width: logits over all ``cfg.num_experts``,
-the top ``cfg.experts_per_tok`` of them, weights the softmax over those. The
+the top ``cfg.experts_per_tok`` of them, weights the softmax over those
+(``cfg.score_func`` ``"sigmoid"``: :func:`route`). The
 chip computes the experts ``[cfg.expert_offset, + cfg.local_experts)`` for the
 tokens routed to them, plus the shared expert on every token where the
 family has one (``cfg.shared_width``; Mellum has none). No token is
@@ -45,12 +46,29 @@ DENSE_MAX_TOKENS = 256
 GROUPED_TOKEN_MULTIPLE = 8
 
 
-def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray):
+def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray,
+          bias: jnp.ndarray | None = None):
     """u (T, D) -> (expert ids (T, k) int32 over the PUBLISHED width,
-    weights (T, k) float32: softmax over the chosen k logits)."""
+    weights (T, k) float32: softmax over the chosen k logits).
+
+    ``cfg.score_func`` ``"sigmoid"``: scores ``p = sigmoid(logits)``, the
+    top k taken of ``p + bias`` ((E,) float32, a per-expert SELECTION bias
+    that no weight sees), weights ``route_scale * p_e / (sum of the chosen p
+    + 1e-20)``; all of it float32."""
     with jax.named_scope("moe.route"):
         logits = jnp.einsum("td,de->te", u, router_w,
                             preferred_element_type=jnp.float32)
+        if cfg.score_func == "sigmoid":
+            p = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(p + bias.astype(jnp.float32),
+                                   cfg.experts_per_tok)
+            # the chosen scores by a one-hot product, exact, and not by
+            # take_along_axis, whose gather leaves the scope's path behind
+            chosen = jnp.einsum("tke,te->tk", jax.nn.one_hot(
+                idx, p.shape[-1], dtype=jnp.float32), p)
+            weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
+                                + 1e-20) * cfg.route_scale
+            return idx.astype(jnp.int32), weights
         vals, idx = jax.lax.top_k(logits, cfg.experts_per_tok)
         return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
 
@@ -118,7 +136,7 @@ def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,
     """u (T, D) normalised input -> (routed part of the held experts + the
     shared expert, if any, (T, D), assignments per held expert (Eh,) int32 counted
     over the rows ``active`` (T,) bool marks — all rows when None)."""
-    idx, weights = route(cfg, mp["router"], u)
+    idx, weights = route(cfg, mp["router"], u, mp.get("router_bias"))
     with jax.named_scope("moe.experts"):
         experts = (_experts_dense if u.shape[0] <= DENSE_MAX_TOKENS
                    else _experts_grouped)

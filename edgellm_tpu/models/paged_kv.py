@@ -2223,19 +2223,18 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
                   write_table=None):
     """The paged twin of ``transformer._attention_decode`` (``window`` static,
     > 0: a sliding layer over its ring; see the two scoped entries below):
-    project the
-    (B, 1, D) hidden, rotate each slot at ITS position, write the new K/V row
+    project the (B, 1, D) hidden, rotate each slot at ITS position (``cos_b``
+    None: a position-free layer, nothing is rotated), write the new K/V row
     into each slot's current page, then ragged-attend against the slot's
     pages. ``pool`` is the WHOLE (L, num_pages, page_size, ...) pool, at
     whichever tier, and ``layer`` the index this layer's rows and pages are
     addressed under; on a quantized tier the current token attends its OWN
-    quantized K/V, consistent with what every later step will read.
-
-    ``write_table`` (None: ``page_table``): the table the new row is WRITTEN
-    through, where a caller must keep some slots' rows out of their pages (the
-    split runtime's dead unroll iterations and padding layers: entries 0, the
-    trash page) while the read still gathers the real ones. A Python-level
-    default: a caller that does not pass it traces what it always traced."""
+    quantized K/V, consistent with what every later step will read. What the
+    layer's leaves add: :func:`head_norms`, :func:`gated`, :func:`post_norm`.
+    ``write_table`` (None: ``page_table``; a Python-level default): the table
+    the new row is WRITTEN through, where a caller must keep some slots' rows
+    out of their pages (the split runtime's dead unroll iterations and padding
+    layers: the trash page) while the read still gathers the real ones."""
     b, s1, d = x.shape
     hd = cfg.head_dim
     h, kv = lp["wq"].shape[-1] // hd, lp["wk"].shape[-1] // hd
@@ -2246,7 +2245,8 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
         q = q + lp["bq"].reshape(h, hd)
         k = k + lp["bk"].reshape(kv, hd)
         v = v + lp["bv"].reshape(kv, hd)
-    if cfg.nope:  # position-free attention: cos_b/sin_b are not read
+    q, k = head_norms(cfg, lp, q, k)
+    if cos_b is None:  # a position-free layer: no table was handed in
         q = q * jnp.asarray(cfg.q_prescale, q.dtype)
     else:
         q = _apply_rotary_rows(q, cos_b, sin_b, cfg.rotary_dim)
@@ -2256,12 +2256,12 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
             if window else write_rows(pool, layer, write_table, lengths, k, v))
     out = paged_decode_attention(q, pool, layer, page_table, lengths + 1,
                                  window)
-    out = out.reshape(b, s1, h * hd) @ lp["wo"]
+    out = gated(lp, x, out.reshape(b, s1, h * hd)) @ lp["wo"]
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
     if "bo" in lp:
         out = out + lp["bo"]
-    return out, pool
+    return post_norm(cfg, lp, out), pool
 
 
 @jax.named_scope("attn.decode")
@@ -2401,3 +2401,29 @@ def latent_decode_attention(q_rows, pool: LatentPool, layer, page_table,
                                    head_dim)
     return attend_latent(q_rows, _gather_pages(pool.rows, layer, page_table),
                          lengths, head_dim)
+
+
+# -- what a layer's own leaves add around the attend (down here: the lines
+# above keep their numbers, PERF.md section 6 "PR 32") ------------------------
+
+def head_norms(cfg: ModelConfig, lp: dict, q, k):
+    """q (..., H, hd), k (..., KV, hd) normed over each head's lanes where
+    the layer holds ``q_norm`` / ``k_norm`` (hd,): before any rotation."""
+    if "q_norm" not in lp:
+        return q, k
+    return (_rmsnorm(q, lp["q_norm"], cfg.norm_eps),
+            _rmsnorm(k, lp["k_norm"], cfg.norm_eps))
+
+
+def gated(lp: dict, x, ctx):
+    """The attend's output ctx (..., H*hd) times ``sigmoid(x W_g)`` where the
+    layer holds an output gate ``wg`` (D, H*hd): ahead of ``W_o``."""
+    return ctx * jax.nn.sigmoid(x @ lp["wg"]) if "wg" in lp else ctx
+
+
+def post_norm(cfg: ModelConfig, lp: dict, out):
+    """The sublayer's output normed where the layer holds ``post_scale``
+    (D,): before it joins the residual stream."""
+    if "post_scale" not in lp:
+        return out
+    return _rmsnorm(out, lp["post_scale"], cfg.norm_eps)

@@ -188,15 +188,19 @@ SPAN_TEMPLATES: Tuple[str, ...] = ()
 SCOPE_NAMES: FrozenSet[str] = frozenset({
     "paged_kv.write",     # the per-layer K/V row scatter of the ragged step
     "paged_kv.adopt",     # a prefilled or resumed prefix scattered into pages
-    "attn.decode",        # the paged decode attention of one layer
+    "attn.decode",        # the paged decode attention of one layer (with
+                          # the per-head q/k norms, the output gate and the
+                          # post-norm of a layer that holds them)
     "attn.window",        # ... of one sliding-window layer: its projections,
                           # the ring's row write, page gather and attend
+                          # (norms, gate and post-norm as above)
     "attn.latent",        # ... of one latent layer, absorbed: projections,
                           # rotation, absorption, page gather, attend, the
                           # V up-projection and the output projection
     "attn.latent.expand",  # a latent layer's prefill: keys and values
                            # rebuilt per head, attention by query blocks
-    "mlp",
+    "mlp",                # a dense feed-forward; in a stack walked by layer
+                          # kinds, a leading dense layer's and its post-norm
     "unembed_sample",     # final norm + LM head + the per-slot sampler
     "split.stage",        # one stage iteration of the split unroll
     # a stack with recurrent state and routed experts (models/mamba2.py,
@@ -205,7 +209,11 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "ssm.step",           # decode: window update, recurrence, gated norm
     "ssm.scan",           # prefill: the convolution and the chunked scan
     "moe.route",          # router logits, top-k, softmax over the chosen
+                          # (or sigmoid scores, the selection bias, the
+                          # top-k, the normalised and scaled weights)
     "moe.experts",        # the held experts' part for the tokens routed here
+                          # (and the norm after the expert sublayer, where a
+                          # layer has one)
     "moe.shared",         # the shared expert on every token
     "state.adopt",        # a slot's recurrent state overwritten (or zeroed)
 })

@@ -413,7 +413,7 @@ class ContinuousBatcher:
         # here on the host from the running set
         self._expert_tokens = self._expert_tokens_host = None
         if cfg.is_hybrid:
-            shape = (cfg.num_layers, cfg.local_experts)
+            shape = (cfg.expert_layers, cfg.local_experts)
             self._expert_tokens = jnp.zeros(shape, jnp.int32)
             self._expert_tokens_host = np.zeros(shape, np.int64)  # last read
         # the step's key table with no stream in it: every row key 0's data,
@@ -1078,7 +1078,7 @@ class ContinuousBatcher:
             if self.cfg.is_hybrid:
                 acc["routed_assignments"] = (
                     len(running) * self.cfg.experts_per_tok
-                    * self.cfg.num_layers)
+                    * self.cfg.expert_layers)
             finished0 = self.stats["finished"]
             advanced = 0
             for st in running:

@@ -32,7 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from edgellm_tpu.models import paged_kv
-from edgellm_tpu.models.configs import ModelConfig
+from edgellm_tpu.models.configs import ModelConfig, tiny_afmoe_config
 from edgellm_tpu.models.transformer import init_params
 from edgellm_tpu.serve import batching
 
@@ -496,3 +496,84 @@ def test_latent_step_is_absorbed_and_its_one_leaf_pool_stays_in_place(topo,
     scores = _span_sized(hlo, own | {f"[{L_SLOTS},32,{span}]"})
     assert not scores, scores[:3]
     assert mem.temp_size_in_bytes < 50e6, mem.temp_size_in_bytes
+
+
+# a toy afmoe (hybrid.py's fourth family): one leading dense layer and one
+# period S S S F of expert layers, rows of one whole lane tile (2 KV heads of
+# 64), pages of 16 rows, a window of 40 keys = a ring of 4 pages
+AFMOE = tiny_afmoe_config(hidden_size=128, head_dim=64, num_kv_heads=2,
+                          sliding_window=40)
+A_SLOTS, A_PAGES_PER_SLOT = 8, 6
+#: what moves bytes or multiplies in a step: each must stand under a scope
+HEAVY = ("convolution", "dot", "gather", "scatter", "custom-call", "sort")
+
+
+def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
+                                                                       read):
+    """The afmoe step at a toy size: the full layer's pool and the four ring
+    layers' are donated and addressed in place (a ring a gather, the full
+    layer's span a gather or, on the walk, the one kernel), nothing
+    pool-sized is copied, relaid or stacked, ``expert_tokens`` has a row an
+    EXPERT layer, and every matmul, gather, scatter, sort and kernel call of
+    the module carries a registered scope: the gate, the norms and the dense
+    layer brought no unscoped work."""
+    from edgellm_tpu.obs.names import SCOPE_NAMES
+
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(AFMOE, jax.random.key(0), dtype=jnp.bfloat16)),
+        one)
+    assert "router" not in params["moe"][0] and "wg" in params["window"]
+    ring = AFMOE.window_pages(PAGE)
+    assert ring == 4 and AFMOE.expert_layers == 4
+    full = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        AFMOE, A_SLOTS * A_PAGES_PER_SLOT + 1, PAGE, jnp.bfloat16)), one)
+    window = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        AFMOE, A_SLOTS * ring + 1, PAGE, jnp.bfloat16,
+        layers=AFMOE.window_layers)), one)
+    assert full.k.shape == (1, 49, PAGE, 128)
+    assert window.k.shape == (4, 33, PAGE, 128)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((A_SLOTS,), jnp.int32)
+    step = batching._batched_window_step_jit.lower(
+        AFMOE, params, full, window, arr((4, 8), jnp.int32),
+        arr((A_SLOTS, A_PAGES_PER_SLOT), jnp.int32),
+        arr((A_SLOTS, ring), jnp.int32), ints, ints,
+        arr((A_SLOTS, 2), jnp.uint32), ints, arr((A_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    gathered = A_SLOTS * ring * PAGE * 128          # a window layer's read
+    gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+               if op == "gather" and _elements(shape) >= gathered]
+    span = f"bf16[{A_SLOTS},{A_PAGES_PER_SLOT},{PAGE},128]"
+    rings = f"bf16[{A_SLOTS},{ring},{PAGE},128]"
+    full_reads = [span] * 2 if read == "gather" else []
+    assert sorted(gathers) == sorted(full_reads + [rings] * 8), gathers
+    assert _walks(hlo) == (1 if read == "walk" else 0)
+    own = {span, rings, f"bf16[{A_SLOTS * A_PAGES_PER_SLOT},{PAGE},128]",
+           f"bf16[{A_SLOTS * ring},{PAGE},128]"}
+    moved = [m for m in _moved(hlo, gathered)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    assert not moved, moved
+    assert f"bf16[{4 * 33},{PAGE},128]" in hlo        # pages at (layer, page)
+    assert f"bf16[{4 * 33 * PAGE},128]" in hlo        # rows at (l, p, r)
+    paths = [(op, "".join(re.findall(r'op_name="([^"]*)"', line)))
+             for op, _, _, line in _instructions(hlo) if op in HEAVY]
+    # all but two row gathers every walked family makes ahead of its first
+    # layer: the embedding's (jnp.take) and each slot's row of the rope table
+    unscoped = {path for _, path in paths
+                if not any(seg in SCOPE_NAMES for seg in path.split("/"))}
+    assert unscoped == {
+        "jit(_batched_window_step_jit)/jit(_take)/gather",
+        "jit(_batched_window_step_jit)/gather"}, unscoped
+    under = {seg for _, path in paths for seg in path.split("/")
+             if seg in SCOPE_NAMES}
+    assert under >= {"attn.window", "attn.decode", "paged_kv.write", "mlp",
+                     "moe.route", "moe.experts", "moe.shared",
+                     "unembed_sample"}, under
+    # donated: both pools of both groups
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (49 + 4 * 33) * PAGE * 128 * 2

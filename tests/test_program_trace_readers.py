@@ -112,7 +112,8 @@ FOLD_READERS = ("step_wall_p99_ms", "tail_step_wall_ms",
                 "between_steps_ms", "prefill_tok_s")
 ALL_CELLS = CELLS + ("granite-4.0-h-small-ep2.decode-sat",
                      "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
-                     "mistral-small-4-119b-ep4.decode-sat-deep")
+                     "mistral-small-4-119b-ep4.decode-sat-deep",
+                     "trinity-mini-pp8.decode-sat-long")
 EDGES = [0.01, 0.02, 0.04, 0.08]            # five rows: under, three, over
 PHASE_KEYS = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s",
               "commit_s")
@@ -238,3 +239,71 @@ def test_cell_lists_the_fold_readers(cell):
     assert all(per_layer[n].unit == ("tokens/s" if n == "prefill_tok_s" else
                                      "%" if n.endswith("_share") else "ms")
                for n in FOLD_READERS)
+
+
+# ---------------------------------------------------------------------------
+# PR 36: the afmoe cell's four readers, on the recorded events and by hand
+# ---------------------------------------------------------------------------
+
+AFMOE_CELL = "trinity-mini-pp8.decode-sat-long"
+AFMOE_READERS = ("afmoe_step_hbm_share", "afmoe_experts_hbm_share",
+                 "dense_mlp_dev_ms", "unembed_sample_dev_ms")
+
+
+@pytest.fixture
+def afmoe_record(table, monkeypatch):
+    """A traced window of 100 steps of 20 ms over the recorded table, the
+    scopes the recorded Qwen2 step lacks put beside its ``mlp`` by hand."""
+    steps = pt.span_count(table, pt.STEP_SPAN)
+    scopes = {**table["scopes"], "unembed_sample": 0.0015 * steps,
+              "moe.route": 0.001 * steps, "moe.experts": 0.008 * steps,
+              "moe.shared": 0.001 * steps}
+    monkeypatch.setitem(pt._TABLES, "table", {**table, "scopes": scopes})
+    with open(os.path.join(HERE, "configs", "trinity-mini-pp8.json")) as f:
+        config = json.load(f)
+    rows = {"window_rows_live": 150_000,
+            "window_rows_capacity": 96 * 129 * 16}
+    return {"trace": {"modules": {"jit__batched_window_step_jit": {
+                "runs": 100, "seconds": 2.0}}},
+            "config": config, "device_kind": "TPU v5 lite",
+            "pool_live_share": 0.5, "token_capacity": 96 * 12288,
+            "report0": {"steps": 0, "slot_util_mean": 0.0, **rows},
+            "report1": {"steps": 100, "slot_util_mean": 1.0, **rows}}
+
+
+@pytest.mark.parametrize("name", AFMOE_READERS)
+def test_afmoe_reader_on_the_recorded_events(name, table, afmoe_record):
+    from benchmark import rooflines_afmoe as r
+
+    steps = pt.span_count(table, pt.STEP_SPAN)
+    c = afmoe_record["config"]
+    assert r.param_count(c) == 4_241_534_720       # the issue's table
+    want = {
+        "dense_mlp_dev_ms": 1e3 * table["scopes"]["mlp"] / steps,
+        "unembed_sample_dev_ms": 1.5,
+        # 4 x 811,860,096 parameters x 2 B at 819 GB/s, of 10 ms
+        "afmoe_experts_hbm_share": 100 * (4 * 811_860_096 * 2 / 819e9)
+        / 10e-3,
+        "afmoe_step_hbm_share": 100 * (r.step_bytes(
+            c, 0.5 * 96 * 12288, 150_000, 96) / 819e9) / 20e-3}[name]
+    got = _read(name, afmoe_record)
+    assert got == pytest.approx(want) and (
+        "share" not in name or 0 < got < 100)
+    # an untraced run, and a program without the scope or the counters
+    assert _read(name, {**afmoe_record, "trace": None}) is None
+    pt._TABLES["table"] = {**table, "scopes": {"attn.decode": 1.0}}
+    bare = {k: {"steps": v["steps"], "slot_util_mean": v["slot_util_mean"]}
+            for k, v in afmoe_record.items() if k.startswith("report")}
+    assert _read(name, {**afmoe_record, **bare}) is None
+
+
+def test_the_afmoe_cell_lists_its_readers_and_the_ones_it_joins():
+    names = {m.name for m in load_cell(AFMOE_CELL).per_layer}
+    assert names >= set(AFMOE_READERS) | {
+        "moe_experts_dev_ms", "attn_decode_dev_ms", "attn_window_dev_ms",
+        "attn_window_hbm_share", "attn_full_hbm_share", "window_pool_live",
+        "attend_walk_share", "step_dev_ms", "device_idle"}
+    assert not names & {"mellum_step_hbm_share", "moe_experts_hbm_share",
+                        "attn_latent_dev_ms", "ssm_step_dev_ms"}
+    assert {m.name for m in load_cell(AFMOE_CELL).end_to_end} == {
+        "gap_mean_ms", "setup_s"}
