@@ -436,22 +436,55 @@ def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
     routed + shared experts, float32) admitted, stepped, evicted and
     readmitted through ``ContinuousBatcher`` on the chip: the recurrent state
     leaves the device with the K/V rows and comes back. The prompt is longer
-    than ``moe.DENSE_MAX_TOKENS`` so that the prefill takes the grouped expert
-    products (whose rows past the last group a TPU leaves undefined) and many
-    chunks of the scan."""
+    than ``moe.DENSE_MAX_TOKENS`` and the expert layers are whole lane tiles
+    (D = F = 128), so the prefill takes the grouped expert products as the
+    kernel (``models/grouped_matmul.py``: the phase asserts
+    ``grouped_product``), whose rows past the last group hold anything, and
+    many chunks of the scan; the same prefill through ``jax.lax.ragged_dot``
+    gives the same logits."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from edgellm_tpu.models import grouped_matmul, init_params
     from edgellm_tpu.models.configs import tiny_hybrid_config
+    from edgellm_tpu.models.hybrid import prefill_hybrid
     from edgellm_tpu.serve.batching import BatchingConfig
 
     # 4 of the router's 8 experts held: half the assignments are to absent
     # experts, the rows past the last group
+    cfg = dataclasses.replace(tiny_hybrid_config(
+        hidden_size=128, experts_held=4, expert_offset=2), expert_width=128)
     report, gap = _evict_readmit(
-        tiny_hybrid_config(experts_held=4, expert_offset=2),
-        BatchingConfig(page_size=16, num_pages=73, max_slots=3,
-                       pages_per_slot=24), prompt_len, n_new, evict_after)
+        cfg, BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                            pages_per_slot=24), prompt_len, n_new, evict_after)
     assert report["state_bytes"] > 0
+    assert report["grouped_product"] == grouped_matmul.PALLAS_GROUPED, \
+        report["grouped_product"]
+    params = init_params(cfg, jax.random.key(SEED))
+    ids = jnp.asarray(np.random.default_rng(SEED).integers(
+        1, cfg.vocab_size, size=(1, prompt_len)), jnp.int32)
+
+    def logits():
+        with jax.default_matmul_precision("highest"):
+            return np.asarray(jax.jit(lambda p, x: prefill_hybrid(
+                cfg, p, x, prompt_len)[0])(params, ids)[0])
+
+    kernel, on_tpu = logits(), grouped_matmul._on_tpu
+    grouped_matmul._on_tpu = lambda: False       # the oracle's path
+    try:
+        ragged = logits()
+    finally:
+        grouped_matmul._on_tpu = on_tpu
+    paths_gap = float(np.abs(kernel - ragged).max() / np.abs(ragged).max())
+    assert paths_gap <= 1e-4, paths_gap
     return {"tokens": int(n_new), "evicted": report["evicted"],
             "state_bytes": report["state_bytes"],
             "routed_local": report["routed_local"],
+            "grouped_product": report["grouped_product"],
+            "kernel_vs_ragged_dot_over_logit_max": paths_gap,
             "gap_max_over_logit_max": gap}
 
 

@@ -20,19 +20,27 @@ Two ways through the held experts, chosen by the static token count:
   experts and width at once. At 60 tokens x 10 of 72 experts every held
   expert is hit anyway, so all their weights are read either way and the
   layer is bound by those bytes, not by the wasted multiplies;
-- more tokens (a prefill): assignments sorted by expert and three
-  ``jax.lax.ragged_dot`` grouped products over the held groups; the rows of
-  assignments to absent experts sort last, belong to no group and are
-  selected out of the result. The tokens are padded to a multiple of 8 first
-  (:data:`GROUPED_TOKEN_MULTIPLE`: what the chip's compiler needs).
+- more tokens (a prefill): assignments sorted by expert and the three
+  grouped products gate / up / down over the held groups
+  (:func:`_grouped_products`); the rows of assignments to absent experts
+  sort last, belong to no group and are selected out of the result. The
+  tokens are padded to a multiple of 8 first
+  (:data:`GROUPED_TOKEN_MULTIPLE`: what the chip's compiler needs). The
+  products are one tiled kernel on a TPU where the widths are whole lane
+  tiles (``models/grouped_matmul.py``: a row tile past the last held group
+  is skipped, gate and up share a call) and three ``jax.lax.ragged_dot``,
+  the oracle, everywhere else: :func:`grouped_product` says which, same
+  operands, same float32 accumulation, same rounding of each product.
 
-Scopes: ``moe.route``, ``moe.experts``, ``moe.shared``.
+Scopes: ``moe.route``, ``moe.experts`` (``moe.experts.grouped`` within it:
+the prefill's grouped products), ``moe.shared``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from . import grouped_matmul
 from .configs import ModelConfig
 
 #: token counts up to this take the dense path: where dense multiplies
@@ -101,6 +109,29 @@ def _experts_dense(cfg: ModelConfig, mp: dict, u, idx, weights):
     return jnp.einsum("tef,efd->td", hidden, mp["w_down"])
 
 
+def grouped_product(cfg: ModelConfig) -> str:
+    """The path a prefill's grouped products take in this process, by the
+    expert layer's widths (``ContinuousBatcher.report()`` names it)."""
+    return grouped_matmul.grouped_product_path(cfg.hidden_size,
+                                               cfg.expert_width)
+
+
+def _grouped_products(mp: dict, rows, sizes):
+    """rows (M, D) sorted by held expert, ``sizes`` (Eh,) rows a group ->
+    ``(silu(rows @ w_gate[g]) * (rows @ w_up[g])) @ w_down[g]`` (M, D). What
+    a row of no group holds is not defined on either path."""
+    d, f = mp["w_gate"].shape[1:]
+    with jax.named_scope("moe.experts.grouped"):
+        if (grouped_matmul.grouped_product_path(d, f)
+                == grouped_matmul.PALLAS_GROUPED):
+            hidden = grouped_matmul.grouped_swiglu(rows, mp["w_gate"],
+                                                   mp["w_up"], sizes)
+            return grouped_matmul.grouped_matmul(hidden, mp["w_down"], sizes)
+        gate = jax.lax.ragged_dot(rows, mp["w_gate"], sizes)
+        up = jax.lax.ragged_dot(rows, mp["w_up"], sizes)
+        return jax.lax.ragged_dot(jax.nn.silu(gate) * up, mp["w_down"], sizes)
+
+
 def _experts_grouped(cfg: ModelConfig, mp: dict, u, idx, weights):
     t, k = idx.shape
     pad = -t % GROUPED_TOKEN_MULTIPLE
@@ -117,18 +148,16 @@ def _experts_grouped(cfg: ModelConfig, mp: dict, u, idx, weights):
     group = jnp.where(held, local, eh).reshape(-1)          # absent: last
     order = jnp.argsort(group, stable=True)
     sizes = _assignments(cfg, local, held)
-    rows = u[order // k]                                     # (T*k, D)
-    gate = jax.lax.ragged_dot(rows, mp["w_gate"], sizes)
-    up = jax.lax.ragged_dot(rows, mp["w_up"], sizes)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, mp["w_down"], sizes)
+    out = _grouped_products(mp, u[order // k], sizes)        # (T*k, D)
+    # the sort undone on the products as they come (half the bytes of their
+    # float32 weighting: PERF.md section 6 "PR 37"), a token's k rows together
+    out = out[jnp.argsort(order)].reshape(t, k, -1)
     # rows past the last held group belong to no product, and what a grouped
     # product leaves in them is not defined (a TPU leaves NaN): they are
     # selected out, not multiplied by a zero weight
-    kept = held.reshape(-1)[order][:, None]
-    w = weights.reshape(-1)[order][:, None]
-    out = jnp.where(kept, out.astype(jnp.float32) * w, 0.0)
-    back = jnp.argsort(order)                                # undo the sort
-    return jnp.sum(out[back].reshape(t, k, -1), axis=1).astype(u.dtype)
+    out = jnp.where(held[..., None],
+                    out.astype(jnp.float32) * weights[..., None], 0.0)
+    return jnp.sum(out, axis=1).astype(u.dtype)
 
 
 def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,

@@ -214,6 +214,8 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "moe.experts",        # the held experts' part for the tokens routed here
                           # (and the norm after the expert sublayer, where a
                           # layer has one)
+    "moe.experts.grouped",  # a prefill's three grouped products gate / up /
+                            # down over the rows sorted by held expert
     "moe.shared",         # the shared expert on every token
     "state.adopt",        # a slot's recurrent state overwritten (or zeroed)
 })

@@ -1368,7 +1368,13 @@ class ContinuousBatcher:
             # step's donation and its return: keep the last reading
             pass
         tokens = self._expert_tokens_host
+        # imported here: a line added above would move the kernels' call
+        # sites, whose line numbers are in every step's compile-cache key
+        from ..models.moe import grouped_product
         return {"state_bytes": self.pool.state_bytes,
+                # the path a prefill past moe.DENSE_MAX_TOKENS takes through
+                # its grouped expert products in this process
+                "grouped_product": grouped_product(self.cfg),
                 # the sliding layers' rings, rows a window layer: those
                 # inside some stream's window now, and all the rings hold
                 "window_rows_live": self.pool.window_rows_live,

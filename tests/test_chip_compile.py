@@ -20,6 +20,7 @@ question the choice asks of the backend as a TPU would.
 ONE file, the topology described inside a fixture (``on-chip-measurement``
 §2): only the worker that is handed this file loads the TPU's library.
 """
+import dataclasses
 import os
 import re
 
@@ -31,8 +32,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
-from edgellm_tpu.models import paged_kv
-from edgellm_tpu.models.configs import ModelConfig, tiny_afmoe_config
+from edgellm_tpu.models import grouped_matmul, hybrid, moe, paged_kv
+from edgellm_tpu.models.configs import ModelConfig, tiny_afmoe_config, \
+    tiny_hybrid_config, tiny_mellum_config, tiny_mistral4_config
 from edgellm_tpu.models.transformer import init_params
 from edgellm_tpu.serve import batching
 
@@ -508,6 +510,29 @@ A_SLOTS, A_PAGES_PER_SLOT = 8, 6
 HEAVY = ("convolution", "dot", "gather", "scatter", "custom-call", "sort")
 
 
+def _afmoe_step(one, cfg):
+    """The afmoe window step lowered for ``one`` described chip."""
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    ring = cfg.window_pages(PAGE)
+    full = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, A_SLOTS * A_PAGES_PER_SLOT + 1, PAGE, jnp.bfloat16)), one)
+    window = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, A_SLOTS * ring + 1, PAGE, jnp.bfloat16,
+        layers=cfg.window_layers)), one)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((A_SLOTS,), jnp.int32)
+    return params, full, window, batching._batched_window_step_jit.lower(
+        cfg, params, full, window, arr((4, 8), jnp.int32),
+        arr((A_SLOTS, A_PAGES_PER_SLOT), jnp.int32),
+        arr((A_SLOTS, ring), jnp.int32), ints, ints,
+        arr((A_SLOTS, 2), jnp.uint32), ints, arr((A_SLOTS,), jnp.float32),
+        None)
+
+
 def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
                                                                        read):
     """The afmoe step at a toy size: the full layer's pool and the four ring
@@ -520,30 +545,13 @@ def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
     from edgellm_tpu.obs.names import SCOPE_NAMES
 
     one = SingleDeviceSharding(topo.devices[0])
-    params = _shapes(jax.eval_shape(
-        lambda: init_params(AFMOE, jax.random.key(0), dtype=jnp.bfloat16)),
-        one)
+    params, full, window, lowered = _afmoe_step(one, AFMOE)
     assert "router" not in params["moe"][0] and "wg" in params["window"]
     ring = AFMOE.window_pages(PAGE)
     assert ring == 4 and AFMOE.expert_layers == 4
-    full = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
-        AFMOE, A_SLOTS * A_PAGES_PER_SLOT + 1, PAGE, jnp.bfloat16)), one)
-    window = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
-        AFMOE, A_SLOTS * ring + 1, PAGE, jnp.bfloat16,
-        layers=AFMOE.window_layers)), one)
     assert full.k.shape == (1, 49, PAGE, 128)
     assert window.k.shape == (4, 33, PAGE, 128)
-
-    def arr(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    ints = arr((A_SLOTS,), jnp.int32)
-    step = batching._batched_window_step_jit.lower(
-        AFMOE, params, full, window, arr((4, 8), jnp.int32),
-        arr((A_SLOTS, A_PAGES_PER_SLOT), jnp.int32),
-        arr((A_SLOTS, ring), jnp.int32), ints, ints,
-        arr((A_SLOTS, 2), jnp.uint32), ints, arr((A_SLOTS,), jnp.float32),
-        None).compile()
+    step = lowered.compile()
     hlo = step.as_text()
     gathered = A_SLOTS * ring * PAGE * 128          # a window layer's read
     gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
@@ -577,3 +585,73 @@ def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
     # donated: both pools of both groups
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * (49 + 4 * 33) * PAGE * 128 * 2
+
+
+# the four families hybrid.py walks, at toy sizes whose expert layers are
+# whole lane tiles (D = F = 128: what the grouped-matmul kernel asks for),
+# half the routed experts held
+WALKED = {name: dataclasses.replace(
+    make(hidden_size=128, experts_held=4, expert_offset=2), expert_width=128)
+    for name, make in (("granitemoehybrid", tiny_hybrid_config),
+                       ("mellum", tiny_mellum_config),
+                       ("mistral4", tiny_mistral4_config),
+                       ("afmoe", tiny_afmoe_config))}
+PREFILL = moe.DENSE_MAX_TOKENS + 8
+
+
+@pytest.fixture(params=["kernel", "ragged-dot"])
+def products(request, monkeypatch):
+    """The path a prefill's grouped products are compiled on
+    (``grouped_matmul.grouped_product_path``). ``kernel``: what the program
+    picks on a TPU at whole lane tiles; ``ragged-dot``: what it picks here."""
+    grouped_matmul._grouped.clear_cache()  # a jit keeps no trace by backend
+    if request.param == "kernel":
+        monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    yield request.param
+    grouped_matmul._grouped.clear_cache()
+
+
+@pytest.mark.parametrize("family", sorted(WALKED))
+def test_prefill_holds_its_grouped_products_to_the_scoped_kernel(
+        topo, family, products):
+    """A toy prefill past ``moe.DENSE_MAX_TOKENS`` of each walked family:
+    on the kernel path two ``grouped_matmul`` kernel calls an expert layer
+    (gate + up, down) under ``moe.experts.grouped`` and no ragged dot
+    anywhere in the module; on the oracle's path no kernel and the ragged
+    dots the kernel replaces."""
+    cfg = WALKED[family]
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    ids = jax.ShapeDtypeStruct((1, PREFILL), jnp.int32, sharding=one)
+    hlo = jax.jit(lambda p, x: hybrid.prefill_hybrid(
+        cfg, p, x, PREFILL, last_only=True)).lower(params, ids).compile(
+        ).as_text()
+    kernels = [line for op, _, _, line in _instructions(hlo)
+               if op == "custom-call" and "grouped_matmul" in line]
+    ragged = [line for op, _, _, line in _instructions(hlo)
+              if "ragged" in op or (op == "custom-call" and "agged" in line)]
+    if products == "ragged-dot":
+        assert not kernels and ragged
+        return
+    assert len(kernels) == 2 * cfg.expert_layers and not ragged, ragged
+    assert all("moe.experts/moe.experts.grouped" in line for line in kernels)
+    assert f"bf16[{PREFILL * cfg.experts_per_tok},128]" in hlo
+
+
+def test_decode_step_lowers_to_one_text_whichever_path_the_prefill_takes(
+        topo, monkeypatch):
+    """The step (8 tokens: the dense expert path) never reaches the grouped
+    products: its lowered text is the same with the kernel chosen and
+    without, at widths where the choice differs."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = dataclasses.replace(AFMOE, expert_width=128)
+    texts = []
+    for on_tpu in (False, True):
+        monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: on_tpu)
+        assert (moe.grouped_product(cfg) == grouped_matmul.PALLAS_GROUPED) \
+            == on_tpu
+        batching._batched_window_step_jit.clear_cache()
+        texts.append(_afmoe_step(one, cfg)[-1].as_text())
+    batching._batched_window_step_jit.clear_cache()
+    assert texts[0] == texts[1] and "ragged" not in texts[0]

@@ -29,8 +29,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import reference_granitemoehybrid as ref  # noqa: E402
-from edgellm_tpu.models import (hybrid, mamba2, moe, paged_kv,  # noqa: E402
-                                transformer)
+from edgellm_tpu.models import (grouped_matmul, hybrid, mamba2,  # noqa: E402
+                                moe, paged_kv, transformer)
 from edgellm_tpu.models.configs import (GRANITE_4_0_H_SMALL,  # noqa: E402
                                         ModelConfig, tiny_config,
                                         tiny_hybrid_config)
@@ -361,18 +361,7 @@ def test_dense_and_grouped_expert_paths_agree(tokens):
     assert 0 < int(half.sum()) < int(counts.sum())
 
 
-def test_rows_past_the_held_groups_are_selected_out_not_weighted(monkeypatch):
-    """What a grouped product leaves in the rows that belong to no group is
-    not defined: the CPU leaves zeros, a TPU at the published sizes left NaN
-    (PERF.md, PR 26), and NaN times a zero weight is NaN. Poison those rows
-    the way the chip did; the layer's result must not move."""
-    cfg = tiny_hybrid_config(experts_held=4, expert_offset=2)
-    mp = make_params(tiny_hybrid_config())["moe"][0]
-    mp = {**mp, **{k: mp[k][2:6] for k in ("w_gate", "w_up", "w_down")}}
-    u = jax.random.normal(jax.random.key(9), (moe.DENSE_MAX_TOKENS + 3,
-                                              cfg.hidden_size))
-    idx, w = moe.route(cfg, mp["router"], u)
-    want = moe._experts_grouped(cfg, mp, u, idx, w)
+def _poison_ragged_dot(monkeypatch):
     real = jax.lax.ragged_dot
 
     def poisoned(lhs, rhs, sizes):
@@ -381,10 +370,61 @@ def test_rows_past_the_held_groups_are_selected_out_not_weighted(monkeypatch):
         return jnp.where(past, jnp.nan, out)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
-    got = moe._experts_grouped(cfg, mp, u, idx, w)
+
+
+def _poison_kernel(monkeypatch):
+    """The kernel path as a TPU takes it, run by the interpreter (each call
+    waited for: its host callbacks deadlock against a dispatching thread),
+    with NaN in every row past the last group, tiles it never visited and
+    the visited tile's tail alike."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def poisoned(real):
+        def call(rows, *rest):
+            *_, sizes = rest
+            out = jax.block_until_ready(
+                real(rows, *rest, interpret=pltpu.InterpretParams()))
+            past = jnp.arange(out.shape[0])[:, None] >= jnp.sum(sizes)
+            return jnp.where(past, jnp.nan, out)
+        return call
+
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    for name in ("grouped_matmul", "grouped_swiglu"):
+        monkeypatch.setattr(grouped_matmul, name,
+                            poisoned(getattr(grouped_matmul, name)))
+
+
+@pytest.mark.parametrize("widths, path, poison", [
+    (dict(), grouped_matmul.XLA_RAGGED, _poison_ragged_dot),
+    (dict(hidden_size=128), grouped_matmul.PALLAS_GROUPED, _poison_kernel)],
+    ids=["ragged-dot", "kernel"])
+def test_rows_past_the_held_groups_are_selected_out_not_weighted(
+        monkeypatch, widths, path, poison):
+    """What a grouped product leaves in the rows that belong to no group is
+    not defined: the CPU leaves zeros, a TPU at the published sizes left NaN
+    (PERF.md, PR 26), the kernel never writes them, and NaN times a zero
+    weight is NaN. Poison those rows the way the chip did, on either path;
+    the layer's result must not move off the clean ``ragged_dot``'s."""
+    # the kernel's case at whole lane tiles: D = F = 128
+    wide = dict(expert_width=widths["hidden_size"]) if widths else {}
+    cfg = dataclasses.replace(tiny_hybrid_config(
+        experts_held=4, expert_offset=2, **widths), **wide)
+    mp = make_params(dataclasses.replace(tiny_hybrid_config(**widths),
+                                         **wide))["moe"][0]
+    mp = {**mp, **{k: mp[k][2:6] for k in ("w_gate", "w_up", "w_down")}}
+    u = jax.random.normal(jax.random.key(9), (moe.DENSE_MAX_TOKENS + 3,
+                                              cfg.hidden_size))
+    with jax.default_matmul_precision("highest"):
+        idx, w = moe.route(cfg, mp["router"], u)
+        want = moe._experts_grouped(cfg, mp, u, idx, w)
+        poison(monkeypatch)
+        assert moe.grouped_product(cfg) == path
+        got = moe._experts_grouped(cfg, mp, u, idx, w)
     assert int(jnp.sum(jnp.any(idx < 2, axis=-1))) > 0     # some rows are past
     assert bool(jnp.isfinite(got).all())
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if path == grouped_matmul.XLA_RAGGED:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert rel_err(got, np.asarray(want)) < TOL
 
 
 # -- the named mistakes ---------------------------------------------------------

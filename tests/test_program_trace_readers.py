@@ -307,3 +307,51 @@ def test_the_afmoe_cell_lists_its_readers_and_the_ones_it_joins():
                         "attn_latent_dev_ms", "ssm_step_dev_ms"}
     assert {m.name for m in load_cell(AFMOE_CELL).end_to_end} == {
         "gap_mean_ms", "setup_s"}
+
+
+# ---------------------------------------------------------------------------
+# PR 37: the prefill's grouped products under their own scope
+# ---------------------------------------------------------------------------
+
+EXPERT_CELLS = ("granite-4.0-h-small-ep2.decode-sat",
+                "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
+                "mistral-small-4-119b-ep4.decode-sat-deep", AFMOE_CELL)
+
+
+def test_grouped_reader_is_the_new_scope_per_admission(table, monkeypatch):
+    """``moe_grouped_dev_ms`` on the recorded table with the scope put in by
+    hand: its device seconds over the window's ``batch.admit`` spans, apart
+    from ``moe.experts`` (an operation counts under its innermost registered
+    scope); nothing on a program without the scope (the parent) and nothing
+    untraced."""
+    from edgellm_tpu.obs.names import SCOPE_NAMES
+
+    assert "moe.experts.grouped" in SCOPE_NAMES
+    path = "jit(prefill)/moe.experts/moe.experts.grouped/grouped_matmul"
+    assert pt.innermost_scope(path, tuple(SCOPE_NAMES)) == \
+        "moe.experts.grouped"
+    admits = pt.span_count(table, pt.ADMIT_SPAN)
+    assert admits > 0
+    scopes = {**table["scopes"], "moe.experts": 0.5,
+              "moe.experts.grouped": 0.004 * admits}
+    monkeypatch.setitem(pt._TABLES, "table", {**table, "scopes": scopes})
+    record = _record(True)
+    assert _read("moe_grouped_dev_ms", record) == pytest.approx(4.0)
+    assert _read("moe_grouped_dev_ms", {**record, "trace": None}) is None
+    pt._TABLES["table"] = table                      # the parent's program
+    assert _read("moe_grouped_dev_ms", record) is None
+
+
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_the_expert_cells_and_no_other_list_the_grouped_reader(cell):
+    names = {m.name: m for m in load_cell(cell).per_layer}
+    assert ("moe_grouped_dev_ms" in names) == (cell in EXPERT_CELLS)
+    if cell in EXPERT_CELLS:
+        assert names["moe_grouped_dev_ms"].unit == "ms"
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        entry, = (m for m in json.load(f)["per_layer"]
+                  if m["name"] == "moe_grouped_dev_ms")
+    assert entry == {
+        "name": "moe_grouped_dev_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "model step",
+        "moves": "gap_mean_ms", "workloads": list(EXPERT_CELLS)}
