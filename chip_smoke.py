@@ -389,7 +389,7 @@ def split_phase(cfg, vocab_size: int, *, prompt_lens=PROMPT_LENS,
 
 
 def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
-                   evict_after: int) -> tuple:
+                   evict_after: int, tweak=lambda params: params) -> tuple:
     """One stream admitted, stepped, evicted and readmitted through
     ``ContinuousBatcher`` on the chip beside a short neighbour, float32 at
     ``highest``: its tokens equal an undisturbed stream's, and ``forward``
@@ -402,7 +402,7 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
     from edgellm_tpu.models import forward, init_params
     from edgellm_tpu.serve.batching import ContinuousBatcher
 
-    params = init_params(cfg, jax.random.key(SEED))
+    params = tweak(init_params(cfg, jax.random.key(SEED)))
     prompt = np.random.default_rng(SEED).integers(
         1, cfg.vocab_size, size=prompt_len).astype(np.int32)
     with jax.default_matmul_precision("highest"):
@@ -569,6 +569,52 @@ def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
             "gap_max_over_logit_max": gap}
 
 
+def longcat_phase(*, prompt_len: int = 300, n_new: int = 40,
+                  evict_after: int = 20) -> dict:
+    """A tiny ``longcat_flash`` stream (two latent sublayers and two dense
+    SwiGLUs a layer with the routed layer on a shortcut beside them; 8 routed
+    experts, half of them absent, and 4 identity experts chosen top-5 of 12 by
+    the whole softmax plus a selection bias; both rank scales; float32)
+    through the same admit / step / evict / readmit: the rows of all FOUR
+    sublayers go through the one-leaf pool and the page walk, leave the
+    device and come back, and ``forward`` over prompt + tokens puts each
+    served token first. The router is seeded 15x wider than ``init_params``
+    leaves it, so that the chosen set varies with the token; the prompt is
+    past ``moe.DENSE_MAX_TOKENS``: the prefill takes the grouped products
+    with the identity assignments in no group."""
+    from edgellm_tpu.models.configs import tiny_longcat_flash_config
+    from edgellm_tpu.models.paged_kv import PAGE_WALK
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    def wider_router(params):
+        return {**params, "moe": [
+            {**mp, "shortcut": {**mp["shortcut"],
+                                "router": mp["shortcut"]["router"] * 15.0}}
+            if "shortcut" in mp else mp for mp in params["moe"]]}
+
+    cfg = tiny_longcat_flash_config(experts_held=4, expert_offset=2)
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=24)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after,
+                                 wider_router)
+    assert cfg.latent_layers == 4 and len(report["expert_tokens"]) == 2
+    assert report["latent_rows_capacity"] == 72 * 16
+    assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    made = report["routed_assignments"]
+    assert 0 < report["zero_assignments"] < made
+    assert 0 < report["routed_local"] < made - report["zero_assignments"]
+    return {"tokens": int(n_new), "evicted": report["evicted"],
+            "decode_read": report["decode_read"],
+            "attend_pages_walked": report["attend_pages_walked"],
+            "attend_pages_spanned": report["attend_pages_spanned"],
+            "kv_row_bytes": report["kv_row_bytes"],
+            "routed_assignments": made,
+            "routed_local": report["routed_local"],
+            "zero_assignments": report["zero_assignments"],
+            "gap_max_over_logit_max": gap}
+
+
 def smoke(report: dict, save) -> dict:
     """Every phase in order, at full width. ``save()`` persists ``report``
     after each phase so a failed run leaves what it learned."""
@@ -603,6 +649,7 @@ def smoke(report: dict, save) -> dict:
     phase("window", window_phase)
     phase("latent", latent_phase)
     phase("afmoe", afmoe_phase)
+    phase("longcat", longcat_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

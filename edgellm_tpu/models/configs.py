@@ -61,8 +61,20 @@ class ModelConfig:
         one, routed by ``score_func`` ``"sigmoid"`` (a per-expert selection
         bias, ``route_scale``), ``h0 = embed * sqrt(d)``, an untied head
         (Arcee Trinity).
+      - ``"longcat_flash"``: a layer of two (``sublayers``) latent-attention
+        sublayers, each followed by a dense SwiGLU of ``intermediate_size``,
+        with ONE routed layer on a shortcut: it reads the first dense
+        SwiGLU's normalised input and its result joins the residual stream
+        at the layer's end (``layer_types`` lists the SUBLAYERS, two a
+        published layer). The router scores by the softmax over ALL its
+        outputs (``score_func`` ``"softmax_all"``: a selection bias, weights
+        the scores as they are times ``route_scale``), ``num_experts`` routed
+        experts and, after them, ``zero_experts`` identity experts that hold
+        no weights (``E(u) = u``); the latent query and the normalised latent
+        are multiplied by ``sqrt(hidden / rank)`` (``rank_scales``); plain
+        RoPE, an untied head (Meituan LongCat-Flash).
 
-    The fields after ``rope_scaling`` exist for those four families and
+    The fields after ``rope_scaling`` exist for those five families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -143,9 +155,20 @@ class ModelConfig:
     #: the router's scores (``models/moe.route``): ``"softmax"`` over the
     #: chosen logits, or ``"sigmoid"`` of every logit, the top-k taken of
     #: score + a per-expert selection bias, the weights the chosen scores
-    #: alone, normalised to sum 1, times ``route_scale``
+    #: alone, normalised to sum 1, times ``route_scale``; or
+    #: ``"softmax_all"``, the softmax over EVERY output, chosen the same way,
+    #: the weights the chosen scores as they are times ``route_scale``
     score_func: str = "softmax"
     route_scale: float = 1.0
+    #: identity experts: router outputs ``[num_experts, num_experts +
+    #: zero_experts)`` that hold no weights and hand their input back
+    #: (``models/moe.py``); they belong to no chip's share, so every token's
+    #: are computed where the token is
+    zero_experts: int = 0
+    #: a latent layer's whole query times ``sqrt(hidden_size / q_lora_rank)``
+    #: and its normalised latent times ``sqrt(hidden_size / kv_lora_rank)``
+    #: where the row is made (``models/mla.py``)
+    rank_scales: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -180,13 +203,47 @@ class ModelConfig:
         """Walked by layer kinds (``models/hybrid.py``), with params held per
         kind and a routed expert layer after every mixer."""
         return self.family in ("granitemoehybrid", "mellum", "mistral4",
-                               "afmoe")
+                               "afmoe", "longcat_flash")
 
     @property
     def expert_layers(self) -> int:
         """Layers whose feed-forward is routed experts: all but the leading
-        dense ones."""
+        dense ones (published layers, not sublayers)."""
         return self.num_layers - self.num_dense_layers
+
+    @property
+    def sublayers(self) -> int:
+        """Attention sublayers a published layer, each followed by a dense
+        SwiGLU: past 1 ``layer_types`` lists the sublayers and a layer's ONE
+        routed layer rides a shortcut beside them (``hybrid._shortcut``)."""
+        return 2 if self.family == "longcat_flash" else 1
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: the routed experts, then the identity
+        ones."""
+        return self.num_experts + self.zero_experts
+
+    @property
+    def counted_experts(self) -> int:
+        """Columns of the step's assignment counter a layer: one a held
+        expert, then one for all the identity experts where the router has
+        any."""
+        return self.local_experts + (1 if self.zero_experts else 0)
+
+    @property
+    def q_rank_scale(self) -> float:
+        """What a latent layer's whole query is multiplied by."""
+        if not self.rank_scales:
+            return 1.0
+        return math.sqrt(self.hidden_size / self.q_lora_rank)
+
+    @property
+    def kv_rank_scale(self) -> float:
+        """What a latent layer's normalised latent is multiplied by."""
+        if not self.rank_scales:
+            return 1.0
+        return math.sqrt(self.hidden_size / self.kv_lora_rank)
 
     @property
     def position_free(self) -> tuple:
@@ -262,19 +319,20 @@ class ModelConfig:
     def __post_init__(self):
         if self.family not in ("gpt_neox", "qwen2", "llama",
                                "granitemoehybrid", "mellum", "mistral4",
-                               "afmoe"):
+                               "afmoe", "longcat_flash"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
         elif (self.layer_types or self.num_experts or self.mamba_heads
               or self.explicit_head_dim or self.sliding_window
               or self.kv_lora_rank or self.num_dense_layers
-              or self.score_func != "softmax"):
+              or self.score_func != "softmax" or self.zero_experts
+              or self.rank_scales):
             raise ValueError(
                 f"layer_types / experts / mamba / head width / window / "
-                f"latent / dense-layer / routing fields belong to the "
-                f"granitemoehybrid, mellum, mistral4 and afmoe families, not "
-                f"{self.family!r}")
+                f"latent / dense-layer / routing fields belong to "
+                f"the granitemoehybrid, mellum, mistral4, afmoe and "
+                f"longcat_flash families, not {self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
@@ -284,13 +342,28 @@ class ModelConfig:
         kinds = {"granitemoehybrid": ("mamba", "attention"),
                  "mellum": ("attention", "sliding_attention"),
                  "mistral4": ("latent_attention",),
-                 "afmoe": ("attention", "sliding_attention")}[self.family]
-        if len(self.layer_types) != self.num_layers or any(
+                 "afmoe": ("attention", "sliding_attention"),
+                 "longcat_flash": ("latent_attention",)}[self.family]
+        if len(self.layer_types) != self.num_layers * self.sublayers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
                 f"layer_types must name one of {kinds} for each of the "
-                f"{self.num_layers} layers of family {self.family!r}, got "
-                f"{self.layer_types!r}")
+                f"{self.num_layers} layers x {self.sublayers} sublayer(s) "
+                f"of family {self.family!r}, got {self.layer_types!r}")
+        if self.sublayers > 1 and (self.num_dense_layers
+                                   or self.shared_width):
+            raise ValueError(
+                "a layer of sublayers holds a dense SwiGLU after each and "
+                "its routed layer on a shortcut: no leading dense layer, no "
+                "shared expert")
+        if self.zero_experts < 0 or (self.zero_experts
+                                     and self.score_func != "softmax_all"):
+            raise ValueError(
+                "identity experts (zero_experts) are routed by score_func "
+                "'softmax_all' (unnormalised weights: a token's compute "
+                "varies with what it chose)")
+        if self.rank_scales and not self.latent_layers:
+            raise ValueError("rank_scales belongs to latent_attention layers")
         if self.window_layers and self.sliding_window < 1:
             raise ValueError("a sliding_attention layer needs sliding_window "
                              ">= 1")
@@ -305,8 +378,9 @@ class ModelConfig:
                 "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
                 "v_head_dim >= 1 and an even qk_rope_head_dim inside the "
                 "explicit head_dim (= qk_nope_head_dim + qk_rope_head_dim)")
-        if not 0 < self.experts_per_tok <= self.num_experts:
-            raise ValueError("experts_per_tok must be in [1, num_experts]")
+        if not 0 < self.experts_per_tok <= self.router_width:
+            raise ValueError("experts_per_tok must be in [1, num_experts + "
+                             "zero_experts]")
         if not (0 <= self.expert_offset
                 and self.expert_offset + self.local_experts
                 <= self.num_experts):
@@ -317,7 +391,7 @@ class ModelConfig:
         if not 0 <= self.num_dense_layers < self.num_layers:
             raise ValueError("num_dense_layers must leave at least one "
                              "expert layer")
-        if self.score_func not in ("softmax", "sigmoid"):
+        if self.score_func not in ("softmax", "sigmoid", "softmax_all"):
             raise ValueError(f"unknown score_func: {self.score_func!r}")
         if self.expert_width < 1 or self.shared_width < 0:
             raise ValueError("expert_width must be >= 1 and shared_width "
@@ -528,6 +602,70 @@ TRINITY_MINI = ModelConfig(
 )
 
 
+# meituan-longcat/LongCat-Flash-Chat (560B, 18.6-31.3B active, 2025-09) —
+# config.json (``model_type`` ``longcat_flash``): 28 layers, each two latent
+# attention sublayers (64 heads: queries through a 1536-wide bottleneck, a
+# cached row of 512 latent + 64 rotated lanes, value heads of 128; both rank
+# scales) and two dense SwiGLUs of 12288 with one routed layer on a shortcut:
+# 512 experts of width 2048 + 256 identity experts, top-12 of the 768 by the
+# whole softmax + a selection bias, weights unnormalised times 6; plain RoPE
+# theta 1e7; untied 131072-row head.
+LONGCAT_FLASH_CHAT = ModelConfig(
+    family="longcat_flash",
+    vocab_size=131072,
+    hidden_size=6144,
+    num_layers=28,
+    num_heads=64,
+    num_kv_heads=64,
+    intermediate_size=12288,
+    max_position_embeddings=131072,
+    norm_eps=1e-5,
+    rope_theta=10000000.0,
+    tie_word_embeddings=False,
+    layer_types=("latent_attention",) * 56,
+    explicit_head_dim=192,
+    num_experts=512,
+    experts_per_tok=12,
+    expert_width=2048,
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    score_func="softmax_all",
+    route_scale=6.0,
+    zero_experts=256,
+    rank_scales=True,
+)
+
+
+def tiny_longcat_flash_config(*, num_layers: int = 2, hidden_size: int = 48,
+                              num_heads: int = 4, vocab_size: int = 256,
+                              num_experts: int = 8, zero_experts: int = 4,
+                              experts_per_tok: int = 5,
+                              experts_held: int = 0, expert_offset: int = 0,
+                              max_position_embeddings: int = 512
+                              ) -> ModelConfig:
+    """A small longcat_flash for tests, every ratio of the published model
+    kept: two latent sublayers a layer, identity experts half the routed
+    count, a top-k (5) larger than a held share of 2 or 4, rope lanes (8) <
+    a head's 24, a cached row (16 + 8 = 24 lanes) that needs padding, both
+    rank scales off 1 (sqrt(48 / 20), sqrt(48 / 16)), a dense width over the
+    experts', an untied head."""
+    return ModelConfig(
+        family="longcat_flash", vocab_size=vocab_size,
+        hidden_size=hidden_size, num_layers=num_layers, num_heads=num_heads,
+        num_kv_heads=num_heads, intermediate_size=96,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-5,
+        rope_theta=10000.0, tie_word_embeddings=False,
+        layer_types=("latent_attention",) * (2 * num_layers),
+        explicit_head_dim=24, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32,
+        experts_held=experts_held, expert_offset=expert_offset,
+        q_lora_rank=20, kv_lora_rank=16, qk_rope_head_dim=8, v_head_dim=16,
+        score_func="softmax_all", route_scale=6.0,
+        zero_experts=zero_experts, rank_scales=True)
+
+
 def tiny_afmoe_config(*, layer_types: tuple = (("sliding_attention",)
                                                + _MELLUM_PERIOD),
                       num_dense_layers: int = 1, sliding_window: int = 20,
@@ -648,6 +786,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_mistral4_config()
     if family == "afmoe":
         return tiny_afmoe_config()
+    if family == "longcat_flash":
+        return tiny_longcat_flash_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -677,6 +817,7 @@ PRESETS = {
     "mellum2-12b-a2.5b": MELLUM2_12B_A2_5B,
     "mistral-small-4-119b": MISTRAL_SMALL_4_119B,
     "trinity-mini": TRINITY_MINI,
+    "longcat-flash-chat": LONGCAT_FLASH_CHAT,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
@@ -685,4 +826,5 @@ PRESETS = {
     "tiny-mellum": tiny_mellum_config(),
     "tiny-mistral4": tiny_mistral4_config(),
     "tiny-afmoe": tiny_afmoe_config(),
+    "tiny-longcat-flash": tiny_longcat_flash_config(),
 }

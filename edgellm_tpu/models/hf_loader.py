@@ -257,6 +257,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         return _mistral4_config(hf_config)
     if mt == "afmoe":
         return _afmoe_config(hf_config)
+    if mt == "longcat_flash":
+        return _longcat_flash_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -379,6 +381,65 @@ def _mistral4_config(hf_config) -> ModelConfig:
         v_head_dim=int(hf_config.v_head_dim),
         softmax_mscale=mscale(rope.get("mscale_all_dim", 0)),
         query_scale_beta=float(rope.get("llama_4_scaling_beta", 0.0)),
+    )
+
+
+def _longcat_flash_config(hf_config) -> ModelConfig:
+    """Meituan LongCat-Flash (``model_type`` ``longcat_flash``). The keys
+    mapped, under the names the checkpoint publishes: ``num_layers`` (layers
+    of TWO attention sublayers each), ``ffn_hidden_size`` (both dense
+    SwiGLUs), ``expert_ffn_hidden_size``, ``n_routed_experts``,
+    ``zero_expert_num`` (identity experts after the routed ones),
+    ``moe_topk``, ``routed_scaling_factor``, ``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim`` + ``qk_rope_head_dim`` (the
+    explicit head width), ``v_head_dim``, ``mla_scale_q_lora`` /
+    ``mla_scale_kv_lora`` (both, or neither). A file that repeats a key under
+    the name other families use (``num_hidden_layers``, ``intermediate_size``,
+    ``num_key_value_heads``) must agree with the published one. Refused by
+    name: an attention other than MLA, identity experts of another type,
+    biases, a rope scaling, renormalised top-k weights, one rank scale
+    alone."""
+    for key, want in (("attention_method", "MLA"),
+                      ("zero_expert_type", "identity"),
+                      ("attention_bias", False), ("router_bias", False),
+                      ("rope_scaling", None), ("norm_topk_prob", False),
+                      ("num_hidden_layers", hf_config.num_layers),
+                      ("intermediate_size", hf_config.ffn_hidden_size),
+                      ("num_key_value_heads",
+                       hf_config.num_attention_heads),
+                      ("mla_scale_kv_lora",
+                       getattr(hf_config, "mla_scale_q_lora", False))):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"longcat_flash with {key}={getattr(hf_config, key)!r} is "
+                f"not supported (only {want!r})")
+    nope, rot = hf_config.qk_nope_head_dim, hf_config.qk_rope_head_dim
+    layers = int(hf_config.num_layers)
+    return ModelConfig(
+        family="longcat_flash",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_attention_heads,
+        intermediate_size=int(hf_config.ffn_hidden_size),
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(hf_config.rope_theta),
+        tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", False),
+        layer_types=("latent_attention",) * (2 * layers),
+        explicit_head_dim=int(nope + rot),
+        num_experts=int(hf_config.n_routed_experts),
+        experts_per_tok=int(hf_config.moe_topk),
+        expert_width=int(hf_config.expert_ffn_hidden_size),
+        q_lora_rank=int(hf_config.q_lora_rank),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_rope_head_dim=int(rot),
+        v_head_dim=int(hf_config.v_head_dim),
+        score_func="softmax_all",
+        route_scale=float(hf_config.routed_scaling_factor),
+        zero_experts=int(hf_config.zero_expert_num),
+        rank_scales=bool(getattr(hf_config, "mla_scale_q_lora", False)),
     )
 
 

@@ -1,5 +1,5 @@
 """A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum``,
-``mistral4`` and ``afmoe`` families' forward, prefill and decode steps.
+``mistral4``, ``afmoe`` and ``longcat_flash`` families' forward and steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -28,14 +28,16 @@ leaves no other family holds, each applied where a layer has it (``paged_kv.
 head_norms`` / ``gated`` / ``post_norm``): ``q_norm`` / ``k_norm`` per head
 ahead of the rotation, a gate ``wg`` ahead of ``W_o``, a ``post_scale`` norm
 on each sublayer's output; a leading dense layer's ``moe`` entry holds no
-router and is a SwiGLU (:func:`_feed_forward`).
+router and is a SwiGLU (:func:`_feed_forward`). A ``longcat_flash`` stack
+(LongCat-Flash) walks SUBLAYERS: two ``latent`` rows and two dense ``moe``
+entries a published layer, the first entry also the layer's routed experts
+under ``shortcut``, whose result joins after the second (:func:`_shortcut`).
 
-(the expert weights are a list, one entry a layer, and not a stack: a row
-sliced from a ``(L, E, D, F)`` stack for a prefill's grouped products, whose
-operands must be whole buffers, is a 226 MB copy a tensor a layer) and the
-stack is walked by a static Python loop over ``cfg.layer_types``: layer ``l``
-takes entry ``l`` of ``moe`` and the next row of its own kind, so row ``j``
-of the ``(L_mamba, slots, ...)`` state store is updated in place.
+(the expert weights are a list, not a stack: a row sliced from a ``(L, E, D,
+F)`` stack for a prefill's grouped products, whose operands must be whole
+buffers, is a 226 MB copy) and the stack is walked by a static Python loop
+over ``cfg.layer_types``: layer ``l`` takes entry ``l`` of ``moe`` and the
+next row of its kind, so row ``j`` of the state store is updated in place.
 
 Every layer: ``h += residual_multiplier * mixer(rms(h; w1))`` then ``h +=
 residual_multiplier * (moe(u) + shared(u))``, ``u = rms(h; w2)``; ``h0 =
@@ -44,14 +46,12 @@ logits_scaling``. Granite scores ``q k^T * attention_multiplier``: the
 kernels and ``decode_attention`` all scale by ``1/sqrt(head_dim)``, so ``q``
 is multiplied by ``attention_multiplier * sqrt(head_dim)`` once, ahead.
 
-Not done, by name, because each needs a snapshot of the recurrent state that
-does not exist yet: boundary hooks and attention statistics (the sweep
+Not done, by name: boundary hooks and attention statistics (the sweep
 drivers), the split runtime, speculation, prefix sharing, quantized KV tiers,
-checkpoints (:func:`refuse_recurrent_state`). The same mechanisms read "a
-slot's K/V = every position of every layer", which a window layer's ring does
-not hold (:func:`refuse_window_ring`), and a latent layer's row is not K and
-V of ``KV x hd`` lanes (:func:`refuse_latent_rows`): all three are made by
-:func:`refuse_beyond_kv_rows`."""
+checkpoints: each needs a snapshot of the recurrent state that does not exist
+(:func:`refuse_recurrent_state`), or reads "a slot's K/V = every position of
+every layer": not a window layer's ring (:func:`refuse_window_ring`), nor a
+latent row (:func:`refuse_latent_rows`); all: :func:`refuse_beyond_kv_rows`."""
 from __future__ import annotations
 
 from typing import NamedTuple
@@ -377,7 +377,7 @@ def unembed_hybrid(cfg: ModelConfig, params: dict, hidden):
 def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
     """Whole sequences through the stack. Returns (hidden (B, S, D), per-kind
     lists of what a decode cache is filled from when ``collect``)."""
-    h = embed_hybrid(cfg, params, ids)
+    h, term = embed_hybrid(cfg, params, ids), None   # term: _shortcut's
     ks, vs, convs, ssms, wks, wvs, lat = [], [], [], [], [], [], []
     rope = _rope_tables(cfg, ids.shape[1])
     for layer, kind, j in _kinds(cfg):
@@ -410,7 +410,8 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
                 ks.append(k)
                 vs.append(v)
         h = h + cfg.residual_multiplier * out
-        h, _ = _ffn(cfg, params["moe"][layer], h)
+        g, _ = _ffn(cfg, params["moe"][layer], h)
+        h, term, _ = _shortcut(cfg, params["moe"][layer], h, g, term)
     return h, (ks, vs, convs, ssms, wks, wvs, lat)
 
 
@@ -426,10 +427,9 @@ def _stack(items: list, shape: tuple, dtype):
 
 def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
                    last_only: bool = False):
-    """The prompt's forward that also fills the decode cache: (logits
-    (B, S, V) float32 — (B, V) of the last position with ``last_only`` —,
-    :class:`HybridCache`, :class:`WindowCache` or :class:`LatentCache` with
-    length S)."""
+    """The prompt's forward that also fills the decode cache: (logits (B, S,
+    V) float32 — (B, V) of the last position with ``last_only`` —, a
+    :class:`HybridCache`, :class:`WindowCache` or :class:`LatentCache`)."""
     b, s = ids.shape
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
@@ -507,7 +507,7 @@ def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
                         h):
     """:func:`decode_step_hybrid` for a stack of latent layers: h (B, D) the
     embedded tokens."""
-    pos, rows = cache.length, cache.rows
+    pos, rows, term = cache.length, cache.rows, None
     rope = tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1) for x in
                  _rope_tables(cfg, cache.capacity)["latent_attention"])
     for layer, _, j in _kinds(cfg):
@@ -516,7 +516,8 @@ def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
             cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope, rows[j], pos)
         rows = rows.at[j].set(rows_j)
         h = h + cfg.residual_multiplier * out
-        h, _ = _ffn(cfg, params["moe"][layer], h)
+        g, _ = _ffn(cfg, params["moe"][layer], h)
+        h, term, _ = _shortcut(cfg, params["moe"][layer], h, g, term)
     return unembed_hybrid(cfg, params, h), LatentCache(rows, pos + 1)
 
 
@@ -546,14 +547,13 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
     as a seventh result; it has no mamba layer, and conv_all / ssm_all are
     None both ways.
 
-    A stack of latent layers passes its pool's ONE leaf (L, num_pages,
-    page_size, kv_row_lanes) as ``pool_k``; ``pool_v``, conv_all and ssm_all
-    are None both ways."""
+    A stack of latent layers passes its pool's ONE leaf (L, num_pages, page,
+    kv_row_lanes) as ``pool_k``; pool_v, conv_all, ssm_all: None both ways."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
-    counts = []
+    counts, term = [], None
     # each slot's own row of the table its layer kind rotates by
     span = page_table.shape[1] * pool_k.shape[2]
     rope = {kind: t and (t[0][lengths], t[1][lengths])
@@ -585,7 +585,9 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
                 PagePool(pool_k, pool_v), j, page_table, lengths)
             out = out[:, 0]
         h = h + cfg.residual_multiplier * out
-        h, c = _ffn(cfg, params["moe"][layer], h, active)
+        g, c = _ffn(cfg, params["moe"][layer], h, active)
+        h, term, c = _shortcut(cfg, params["moe"][layer], h, g, term, c,
+                               active)
         if c is not None:                      # a dense layer routes nothing
             counts.append(c)
     with jax.named_scope("unembed_sample"):
@@ -599,17 +601,16 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
                        dtype=jnp.float32) -> dict:
     """Random init for tests and smoke runs: normal std 0.02, norm scales
     one; the Mamba-2 scalars take ``mamba_ssm``'s initialisation so that the
-    state matters (``A_log = log U[1, 16]``, ``dt_bias = softplus^-1(dt)``
-    with ``dt`` log-uniform in [0.001, 0.1], ``D = 1``, the convolution
-    uniform in +-1/sqrt(d_conv)). A kind the stack has no layer of has no
-    entry (``mamba``; ``window``; ``attn``; ``latent``), nor has an absent
-    shared expert or a tied head. An ``afmoe`` stack's attention kinds also
+    state matters (``A_log = log U[1, 16]``, ``dt_bias = softplus^-1(dt)``,
+    ``dt`` log-uniform in [0.001, 0.1], ``D = 1``, the convolution uniform in
+    +-1/sqrt(d_conv)). A kind the stack has no layer of has no entry, nor has
+    an absent shared expert or a tied head. An ``afmoe`` stack's kinds also
     hold the per-head ``q_norm`` / ``k_norm``, the gate ``wg`` and
     ``post_scale``; its leading dense layers' ``moe`` entries a SwiGLU of
     ``intermediate_size`` and no router, its expert layers a float32
     ``router_bias`` drawn NONZERO (a checkpoint's is trained; at zero a path
-    that dropped it would pass every test), each with ``post_scale``."""
-    keys = iter(jax.random.split(key, 16 + 8 * cfg.num_layers))
+    that dropped it would pass); a stack of sublayers: :func:`_sub_ffns`."""
+    keys = iter(jax.random.split(key, 16 + 8 * len(cfg.layer_types)))
 
     def init(*shape):
         return (jax.random.normal(next(keys), shape, jnp.float32)
@@ -682,7 +683,7 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     fd = cfg.intermediate_size
     norms = {"ln2_scale": jnp.ones((d,), dtype),
              **({"post_scale": jnp.ones((d,), dtype)} if afmoe else {})}
-    params["moe"] = [{
+    params["moe"] = _sub_ffns(cfg, init, norms) if cfg.sublayers > 1 else [{
         **norms,
         "w_gate": init(d, fd), "w_up": init(d, fd), "w_down": init(fd, d),
     } if layer < cfg.num_dense_layers else {
@@ -714,11 +715,56 @@ def _feed_forward(cfg: ModelConfig, mp: dict, u, active=None):
     (Eh,)): the routed expert layer, or, where the entry holds no router (a
     leading dense layer), a SwiGLU of ``intermediate_size`` under the scope
     ``mlp`` and counts None; normed after where the entry holds
-    ``post_scale``, inside the sublayer's own scope. (Down here: the lines
-    above keep their numbers, PERF.md section 6 "PR 32".)"""
+    ``post_scale``, inside the sublayer's own scope (PERF.md §6 "PR 32")."""
     if "router" not in mp:
         with jax.named_scope("mlp"):
             return post_norm(cfg, mp, mlp(cfg, mp, u)), None
     out, counts = moe_layer(cfg, mp, u, active)
     with jax.named_scope("moe.experts"):
         return post_norm(cfg, mp, out), counts
+
+
+def _shortcut(cfg: ModelConfig, mp: dict, h, g, term, counts=None,
+              active=None):
+    """What follows :func:`_ffn` in the walk: h (..., D) the stream it read,
+    g what it returned, ``term`` what the walk carries -> (h, term, counts).
+    In a stack whose layers hold sublayers (``cfg.sublayers`` 2) the entry of
+    a layer's FIRST sublayer also holds the layer's routed experts, under
+    ``shortcut``: they read the normalised input the dense SwiGLU read (h's,
+    not g's), and their result does not join here but is handed back as
+    ``term``, which the walk carries past the next attention sublayer and
+    its feed-forward; an entry without ``shortcut`` adds the ``term`` it is
+    handed, at the layer's end. Every other family's entries hold none and
+    are handed none: g goes on as it is."""
+    if "shortcut" in mp:
+        u = _rms(cfg, h, mp["ln2_scale"])   # _ffn's own u: one value compiled
+        term, counts = moe_layer(cfg, mp["shortcut"],
+                                 u.reshape(-1, u.shape[-1]), active)
+        return g, term.reshape(h.shape), counts
+    if term is not None:
+        g = g + cfg.residual_multiplier * term
+    return g, None, counts
+
+
+def _sub_ffns(cfg: ModelConfig, init, norms: dict) -> list:
+    """:func:`init_params_hybrid`'s ``moe`` list for a stack whose layers
+    hold sublayers: an entry a SUBLAYER, each a dense SwiGLU of
+    ``intermediate_size``; a layer's first also the routed layer, under
+    ``shortcut`` (a router ``cfg.router_width`` wide, a float32
+    ``router_bias`` drawn nonzero, the held experts)."""
+    d, fd = cfg.hidden_size, cfg.intermediate_size
+    eh, f = cfg.local_experts, cfg.expert_width
+
+    def entry(first: bool) -> dict:
+        dense = {**norms, "w_gate": init(d, fd), "w_up": init(d, fd),
+                 "w_down": init(fd, d)}
+        if not first:
+            return dense
+        return {**dense, "shortcut": {
+            "router": init(d, cfg.router_width),
+            "router_bias": init(cfg.router_width).astype(jnp.float32),
+            "w_gate": init(eh, d, f), "w_up": init(eh, d, f),
+            "w_down": init(eh, f, d)}}
+
+    return [entry(i % cfg.sublayers == 0)
+            for i in range(len(cfg.layer_types))]

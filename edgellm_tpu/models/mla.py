@@ -30,6 +30,12 @@ lanes): what both forms read.
 Scores are scaled by ``(nope + rope)^-1/2`` (the attends' own), times
 ``softmax_mscale^2`` and the query position's ``1 + beta ln(1 + floor(pos /
 original_max))``, both folded into the query (:func:`query_scale`).
+
+Under ``cfg.rank_scales`` the whole query is also multiplied by
+``sqrt(hidden / q_lora_rank)`` (folded into the same scale) and ``c`` by
+``sqrt(hidden / kv_lora_rank)`` AFTER its norm, where the row is made
+(:func:`project`): the cached row holds the scaled latent, so both forms read
+it and ``k_rope`` does not carry it.
 """
 from __future__ import annotations
 
@@ -42,7 +48,8 @@ from .transformer import _rmsnorm, _rotate_half, deinterleave_pairs
 def query_scale(cfg: ModelConfig, positions):
     """What a query at ``positions`` (any shape, int) is multiplied by beside
     the attends' ``head_dim^-1/2``: float32, same shape."""
-    scale = jnp.full(positions.shape, cfg.softmax_mscale ** 2, jnp.float32)
+    scale = jnp.full(positions.shape,
+                     cfg.softmax_mscale ** 2 * cfg.q_rank_scale, jnp.float32)
     if not cfg.query_scale_beta:
         return scale
     original_max = cfg.rope_scaling[2]
@@ -77,6 +84,8 @@ def project(cfg: ModelConfig, lp: dict, x, rotate, scale):
     q = q * scale[..., None, None].astype(q.dtype)
     kv = x @ lp["wkv_a"]
     c = _rmsnorm(kv[..., :rank], lp["kv_norm"], cfg.norm_eps)
+    if cfg.rank_scales:
+        c = c * jnp.asarray(cfg.kv_rank_scale, c.dtype)
     k_rope = rotate(deinterleave_pairs(kv[..., None, rank:]))[..., 0, :]
     row = _pad_lanes(cfg, jnp.concatenate([c, k_rope], axis=-1))
     return (q[..., :-rope], rotate(deinterleave_pairs(q[..., -rope:])), row)

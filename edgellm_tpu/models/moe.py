@@ -1,36 +1,33 @@
 """The routed expert layer of the families walked by layer kinds
 (``models/hybrid.py``), told which experts it holds.
 
-The router keeps its published width: logits over all ``cfg.num_experts``,
-the top ``cfg.experts_per_tok`` of them, weights the softmax over those
-(``cfg.score_func`` ``"sigmoid"``: :func:`route`). The
-chip computes the experts ``[cfg.expert_offset, + cfg.local_experts)`` for the
-tokens routed to them, plus the shared expert on every token where the
-family has one (``cfg.shared_width``; Mellum has none). No token is
-dropped and there is no capacity factor. What the absent experts would have
-added is left out — that is their chip's part of the sum, and nothing here
-stands in for them or for the exchange (expert parallelism without its
-all-to-all: the share of one chip).
+The router keeps its published width: logits over all ``cfg.router_width``
+outputs, the top ``cfg.experts_per_tok`` of them, weights by
+``cfg.score_func`` (:func:`route`). The chip computes the experts
+``[cfg.expert_offset, + cfg.local_experts)`` for the tokens routed to them,
+the shared expert on every token where the family has one, and every
+token's IDENTITY experts (ids from ``cfg.num_experts`` up: no weights, they
+belong to no chip's share; :func:`_with_identity`). No token is dropped and
+there is no capacity factor. What the absent experts would have added is
+left out: their chip's part (expert parallelism without its all-to-all).
 
 Two ways through the held experts, chosen by the static token count:
 
 - up to :data:`DENSE_MAX_TOKENS` tokens (the decode step): every held expert
   over every token, the combine weight (zero where a token was not routed to
   an expert) applied before the down projection, which then contracts over
-  experts and width at once. At 60 tokens x 10 of 72 experts every held
-  expert is hit anyway, so all their weights are read either way and the
-  layer is bound by those bytes, not by the wasted multiplies;
+  experts and width at once: all the held weights are read either way and
+  the layer is bound by those bytes, not by the wasted multiplies;
 - more tokens (a prefill): assignments sorted by expert and the three
   grouped products gate / up / down over the held groups
-  (:func:`_grouped_products`); the rows of assignments to absent experts
-  sort last, belong to no group and are selected out of the result. The
-  tokens are padded to a multiple of 8 first
-  (:data:`GROUPED_TOKEN_MULTIPLE`: what the chip's compiler needs). The
-  products are one tiled kernel on a TPU where the widths are whole lane
-  tiles (``models/grouped_matmul.py``: a row tile past the last held group
-  is skipped, gate and up share a call) and three ``jax.lax.ragged_dot``,
-  the oracle, everywhere else: :func:`grouped_product` says which, same
-  operands, same float32 accumulation, same rounding of each product.
+  (:func:`_grouped_products`); the rows of assignments to absent (and
+  identity) experts sort last, belong to no group and are selected out of
+  the result. The tokens are padded to a multiple of 8 first
+  (:data:`GROUPED_TOKEN_MULTIPLE`). The products are one tiled kernel on a
+  TPU where the widths are whole lane tiles (``models/grouped_matmul.py``)
+  and three ``jax.lax.ragged_dot``, the oracle, everywhere else:
+  :func:`grouped_product` says which, same operands, same float32
+  accumulation, same rounding of each product.
 
 Scopes: ``moe.route``, ``moe.experts`` (``moe.experts.grouped`` within it:
 the prefill's grouped products), ``moe.shared``.
@@ -62,21 +59,24 @@ def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray,
     ``cfg.score_func`` ``"sigmoid"``: scores ``p = sigmoid(logits)``, the
     top k taken of ``p + bias`` ((E,) float32, a per-expert SELECTION bias
     that no weight sees), weights ``route_scale * p_e / (sum of the chosen p
-    + 1e-20)``; all of it float32."""
+    + 1e-20)``; ``"softmax_all"``: ``p = softmax(logits)`` over every output,
+    chosen the same way, weights ``route_scale * p_e``; all of it float32."""
     with jax.named_scope("moe.route"):
         logits = jnp.einsum("td,de->te", u, router_w,
                             preferred_element_type=jnp.float32)
-        if cfg.score_func == "sigmoid":
-            p = jax.nn.sigmoid(logits)
+        if cfg.score_func != "softmax":
+            p = (jax.nn.sigmoid if cfg.score_func == "sigmoid"
+                 else jax.nn.softmax)(logits)
             _, idx = jax.lax.top_k(p + bias.astype(jnp.float32),
                                    cfg.experts_per_tok)
             # the chosen scores by a one-hot product, exact, and not by
             # take_along_axis, whose gather leaves the scope's path behind
             chosen = jnp.einsum("tke,te->tk", jax.nn.one_hot(
                 idx, p.shape[-1], dtype=jnp.float32), p)
-            weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
-                                + 1e-20) * cfg.route_scale
-            return idx.astype(jnp.int32), weights
+            if cfg.score_func == "sigmoid":
+                chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
+                                   + 1e-20)
+            return idx.astype(jnp.int32), chosen * cfg.route_scale
         vals, idx = jax.lax.top_k(logits, cfg.experts_per_tok)
         return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
 
@@ -162,9 +162,9 @@ def _experts_grouped(cfg: ModelConfig, mp: dict, u, idx, weights):
 
 def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,
               active: jnp.ndarray | None = None):
-    """u (T, D) normalised input -> (routed part of the held experts + the
-    shared expert, if any, (T, D), assignments per held expert (Eh,) int32 counted
-    over the rows ``active`` (T,) bool marks — all rows when None)."""
+    """u (T, D) normalised -> (the held experts' routed part + the shared
+    expert's, if any (+ the identity experts': :func:`_with_identity`) (T, D),
+    assignments a held expert (Eh,) int32 over the rows ``active`` marks)."""
     idx, weights = route(cfg, mp["router"], u, mp.get("router_bias"))
     with jax.named_scope("moe.experts"):
         experts = (_experts_dense if u.shape[0] <= DENSE_MAX_TOKENS
@@ -174,9 +174,31 @@ def moe_layer(cfg: ModelConfig, mp: dict, u: jnp.ndarray,
         if active is not None:
             held = held & active[:, None]
         counts = _assignments(cfg, local, held)
+        if cfg.zero_experts:
+            routed, counts = _with_identity(cfg, u, idx, weights, active,
+                                            routed, counts)
     if not cfg.shared_width:
         return routed, counts
     with jax.named_scope("moe.shared"):
         shared = (jax.nn.silu(u @ mp["shared_gate"])
                   * (u @ mp["shared_up"])) @ mp["shared_down"]
     return routed + shared, counts
+
+
+def _with_identity(cfg: ModelConfig, u, idx, weights, active, routed,
+                   counts):
+    """The identity experts' part of a routed layer: ``E_e(u) = u`` for every
+    chosen id ``e >= cfg.num_experts``, so a token gains ``u`` times the sum
+    of its weights for them, in float32, rounded once with the held experts'
+    sum. No group, no product and no "absent" selection sees them
+    (:func:`_local` calls them not held). Returns (routed (T, D), counts
+    (Eh + 1,): the assignments to identity ids over the rows ``active``
+    marks, appended)."""
+    zero = idx >= cfg.num_experts
+    share = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
+    routed = (routed.astype(jnp.float32)
+              + u.astype(jnp.float32) * share).astype(u.dtype)
+    if active is not None:
+        zero = zero & active[:, None]
+    return routed, jnp.concatenate(
+        [counts, jnp.sum(zero, dtype=jnp.int32)[None]])

@@ -200,7 +200,8 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "attn.latent.expand",  # a latent layer's prefill: keys and values
                            # rebuilt per head, attention by query blocks
     "mlp",                # a dense feed-forward; in a stack walked by layer
-                          # kinds, a leading dense layer's and its post-norm
+                          # kinds, a leading dense layer's and its post-norm,
+                          # or every sublayer's dense SwiGLU (longcat_flash)
     "unembed_sample",     # final norm + LM head + the per-slot sampler
     "split.stage",        # one stage iteration of the split unroll
     # a stack with recurrent state and routed experts (models/mamba2.py,
@@ -210,10 +211,14 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "ssm.scan",           # prefill: the convolution and the chunked scan
     "moe.route",          # router logits, top-k, softmax over the chosen
                           # (or sigmoid scores, the selection bias, the
-                          # top-k, the normalised and scaled weights)
+                          # top-k, the normalised and scaled weights; or the
+                          # whole softmax's scores, chosen and scaled alike)
     "moe.experts",        # the held experts' part for the tokens routed here
                           # (and the norm after the expert sublayer, where a
-                          # layer has one)
+                          # layer has one; the identity experts' part and the
+                          # count of their assignments, where a router has
+                          # them: two elementwise fusions the trace cannot
+                          # tell from the combine, so no scope of their own)
     "moe.experts.grouped",  # a prefill's three grouped products gate / up /
                             # down over the rows sorted by held expert
     "moe.shared",         # the shared expert on every token

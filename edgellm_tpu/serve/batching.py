@@ -413,7 +413,7 @@ class ContinuousBatcher:
         # here on the host from the running set
         self._expert_tokens = self._expert_tokens_host = None
         if cfg.is_hybrid:
-            shape = (cfg.expert_layers, cfg.local_experts)
+            shape = (cfg.expert_layers, cfg.counted_experts)
             self._expert_tokens = jnp.zeros(shape, jnp.int32)
             self._expert_tokens_host = np.zeros(shape, np.int64)  # last read
         # the step's key table with no stream in it: every row key 0's data,
@@ -1367,7 +1367,7 @@ class ContinuousBatcher:
             # a scrape from another thread caught the handle between the
             # step's donation and its return: keep the last reading
             pass
-        tokens = self._expert_tokens_host
+        tokens, held = self._expert_tokens_host, self.cfg.local_experts
         # imported here: a line added above would move the kernels' call
         # sites, whose line numbers are in every step's compile-cache key
         from ..models.moe import grouped_product
@@ -1384,9 +1384,12 @@ class ContinuousBatcher:
                 "latent_rows_live": self.pool.latent_rows_live,
                 "latent_rows_capacity": self.pool.latent_rows_capacity,
                 "kv_row_bytes": self.pool.kv_row_bytes,
-                "expert_tokens": tokens.tolist(),
+                "expert_tokens": tokens[:, :held].tolist(),
                 "routed_assignments": int(stats["routed_assignments"]),
-                "routed_local": int(tokens.sum())}
+                "routed_local": int(tokens[:, :held].sum()),
+                # assignments to identity experts (no weights, no chip's
+                # share): the counter's last column where the router has any
+                "zero_assignments": int(tokens[:, held:].sum())}
 
 
 # -- the step-wall table ---------------------------------------------------

@@ -33,8 +33,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from edgellm_tpu.models import grouped_matmul, hybrid, moe, paged_kv
-from edgellm_tpu.models.configs import ModelConfig, tiny_afmoe_config, \
-    tiny_hybrid_config, tiny_mellum_config, tiny_mistral4_config
+from edgellm_tpu.models.configs import LONGCAT_FLASH_CHAT, ModelConfig, \
+    tiny_afmoe_config, tiny_hybrid_config, tiny_longcat_flash_config, \
+    tiny_mellum_config, tiny_mistral4_config
 from edgellm_tpu.models.transformer import init_params
 from edgellm_tpu.serve import batching
 
@@ -587,7 +588,88 @@ def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
     assert mem.alias_size_in_bytes >= 2 * (49 + 4 * 33) * PAGE * 128 * 2
 
 
-# the four families hybrid.py walks, at toy sizes whose expert layers are
+# benchmark/configs/longcat-flash-chat-ep32.json: the widths, the chip's
+# share (16 of 512 routed experts, an eighth of the vocabulary, 4 layers = 8
+# sublayers) and the serving geometry of the cell
+LONGCAT = dataclasses.replace(
+    LONGCAT_FLASH_CHAT, vocab_size=16384, num_layers=4,
+    layer_types=("latent_attention",) * 8, experts_held=16)
+C_SLOTS, C_PAGES_PER_SLOT = 96, 192
+
+
+def test_longcat_step_walks_five_tile_rows_and_scopes_what_is_heavy(topo,
+                                                                    read):
+    """The step of the ``longcat_flash`` cell at its shapes: 8 latent
+    sublayers of 640-lane rows at 64 heads through the kernels the mistral4
+    cell's 384-lane rows take (a kernel a SUBLAYER on the walk: its two
+    1024-row buffers and the (64, 640) query fit VMEM, or this would not
+    compile; a gather a sublayer otherwise), the one-leaf pool donated and in
+    place, the counter a row a PUBLISHED layer with the identity column, and
+    every matmul, gather, scatter, sort and kernel call under a registered
+    scope: the shortcut, the identity part and the dense SwiGLUs brought no
+    unscoped work."""
+    from edgellm_tpu.obs.names import SCOPE_NAMES
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = LONGCAT
+    assert (cfg.kv_row_lanes, cfg.kv_layers, cfg.expert_layers,
+            cfg.counted_experts) == (640, 8, 4, 17)
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    assert [sorted(m) for m in params["moe"][:2]] == [
+        ["ln2_scale", "shortcut", "w_down", "w_gate", "w_up"],
+        ["ln2_scale", "w_down", "w_gate", "w_up"]]
+    pages = C_SLOTS * C_PAGES_PER_SLOT + 1
+    pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, pages, PAGE, jnp.bfloat16)), one)
+    assert pool.rows.shape == (8, 18433, PAGE, 640)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((C_SLOTS,), jnp.int32)
+    step = batching._batched_hybrid_step_jit.lower(
+        cfg, params, pool.rows, None, None, None, arr((4, 17), jnp.int32),
+        arr((C_SLOTS, C_PAGES_PER_SLOT), jnp.int32), ints, ints,
+        arr((C_SLOTS, 2), jnp.uint32), ints, arr((C_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    span = C_PAGES_PER_SLOT * PAGE
+    gathered = C_SLOTS * span * 640
+    gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+               if op == "gather" and _elements(shape) >= gathered]
+    rows = f"bf16[{C_SLOTS},{C_PAGES_PER_SLOT},{PAGE},640]"
+    assert _walks(hlo) == (8 if read == "walk" else 0)
+    # (a gather a sublayer; the compiler may split one in two)
+    assert set(gathers) == (set() if read == "walk" else {rows}), gathers
+    assert len(gathers) >= (0 if read == "walk" else 8)
+    per_head = re.findall(rf"\[{C_SLOTS},{span},64,\d+\]"
+                          rf"|\[{C_SLOTS},64,{span},\d+\]", hlo)
+    assert not per_head, per_head[:3]
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8 * pages * PAGE * 640 * 2   # donated
+    # weights 10.35 GB + pool 3.02 GB + the step's temporaries
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.6e9
+    if read == "walk":
+        assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    # (at this size the compiler prefetches operands into VMEM in slices and
+    # joins them with a "ConcatBitcast" call of its own: a bitcast, no path)
+    paths = [(op, "".join(re.findall(r'op_name="([^"]*)"', line)))
+             for op, _, _, line in _instructions(hlo)
+             if op in HEAVY and "ConcatBitcast" not in line]
+    unscoped = {path for _, path in paths
+                if not any(seg in SCOPE_NAMES for seg in path.split("/"))}
+    assert unscoped == {
+        "jit(_batched_hybrid_step_jit)/jit(_take)/gather",
+        "jit(_batched_hybrid_step_jit)/gather"}, unscoped
+    under = {seg for _, path in paths for seg in path.split("/")
+             if seg in SCOPE_NAMES}
+    assert under >= {"attn.latent", "paged_kv.write", "mlp", "moe.route",
+                     "moe.experts", "unembed_sample"}, under
+    assert "moe.shared" not in under
+
+
+# the five families hybrid.py walks, at toy sizes whose expert layers are
 # whole lane tiles (D = F = 128: what the grouped-matmul kernel asks for),
 # half the routed experts held
 WALKED = {name: dataclasses.replace(
@@ -595,7 +677,8 @@ WALKED = {name: dataclasses.replace(
     for name, make in (("granitemoehybrid", tiny_hybrid_config),
                        ("mellum", tiny_mellum_config),
                        ("mistral4", tiny_mistral4_config),
-                       ("afmoe", tiny_afmoe_config))}
+                       ("afmoe", tiny_afmoe_config),
+                       ("longcat_flash", tiny_longcat_flash_config))}
 PREFILL = moe.DENSE_MAX_TOKENS + 8
 
 

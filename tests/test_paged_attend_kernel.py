@@ -7,6 +7,7 @@ compiles) and the benchmark's (it is timed); here is what it computes: the
 same rows attended, whatever the table says about where they lie, and nothing
 of a page or a row that no length covers.
 """
+import dataclasses
 import functools
 
 import numpy as np
@@ -18,7 +19,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from edgellm_tpu.models import flash_attention, hybrid, paged_kv
 from edgellm_tpu.models import tiny_config
-from edgellm_tpu.models.configs import tiny_mistral4_config
+from edgellm_tpu.models.configs import (tiny_longcat_flash_config,
+                                        tiny_mistral4_config)
 from edgellm_tpu.models.transformer import init_params
 
 PAGE, PAGES_PER_SLOT, LAYERS, LAYER = 16, 8, 3, 1
@@ -274,3 +276,58 @@ def test_latent_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
     np.testing.assert_allclose(
         np.asarray(jnp.where(dead, 0, got_rows), np.float32),
         np.asarray(jnp.where(dead, 0, want_rows), np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("read", ["page-walk", "page-gather"])
+@pytest.mark.parametrize("compute", LATENT_STEPS)
+def test_a_640_lane_step_of_two_sublayers_equals_the_contiguous_step(
+        monkeypatch, compute, read):
+    """A toy ``longcat_flash`` layer whose cached row is FIVE lane tiles (520
+    latent + 8 rotated lanes stored 640 wide, the published row's width)
+    through the ragged step on either read, against the contiguous absorbed
+    step a stream at a time: both sublayers' rows written (the first row of a
+    new page, the last of the last page), the latent lanes carrying their
+    rank scale, an idle slot between the streams."""
+    page, dtype, pool_dtype, tol = LATENT_STEPS[compute]
+    cfg = dataclasses.replace(tiny_longcat_flash_config(num_layers=1),
+                              kv_lora_rank=520)
+    assert (cfg.kv_row_lanes, cfg.latent_layers) == (640, 2)
+    params = init_params(cfg, jax.random.key(0), dtype=dtype)
+    table = np.asarray([[1, 2, 3, 0], [0, 0, 0, 0], [7, 5, 0, 0],
+                        [9, 4, 8, 6]], np.int32)
+    lens = np.asarray([2 * page + 4, 0, page, 4 * page - 1], np.int32)
+    toks = np.asarray([3, 0, 5, 7], np.int32)
+    rows = np.zeros((2, 13, page, 640), np.float32)
+    want = {}
+    for slot, n in enumerate(lens):
+        if not n:
+            continue
+        ids = np.random.default_rng(slot).integers(1, 256, (1, n))
+        _, cache = hybrid.prefill_hybrid(cfg, params, jnp.asarray(ids),
+                                         4 * page)
+        logits, after = hybrid.decode_step_hybrid(
+            cfg, params, cache, jnp.asarray(toks[slot:slot + 1]))
+        want[slot] = (np.asarray(logits[0]),
+                      np.asarray(after.rows[:, 0, n], np.float32))
+        pages = table[slot, :-(-n // page)]
+        rows[:, pages] = np.asarray(cache.rows[:, 0], np.float32).reshape(
+            2, 4, page, 640)[:, :len(pages)]
+    if read == "page-walk":
+        monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+        monkeypatch.setattr(flash_attention, "paged_decode_walk",
+                            _interpreted)
+    pool = paged_kv.LatentPool(jnp.asarray(rows, pool_dtype))
+    assert paged_kv.decode_read_path(pool) == (
+        paged_kv.PAGE_WALK if read == "page-walk" else paged_kv.PAGE_GATHER)
+    got, got_rows, _, _, _, counts = jax.block_until_ready(
+        hybrid.paged_decode_step_hybrid(
+            cfg, params, pool.rows, None, None, None,
+            jnp.zeros((1, cfg.counted_experts), jnp.int32),
+            jnp.asarray(table), jnp.asarray(lens), jnp.asarray(toks)))
+    assert int(counts.sum()) == 3 * cfg.experts_per_tok    # the live slots'
+    for slot, (logits, row) in want.items():
+        n = lens[slot]
+        np.testing.assert_allclose(np.asarray(got[slot]), logits, atol=tol)
+        np.testing.assert_allclose(
+            np.asarray(got_rows[:, table[slot, n // page], n % page],
+                       np.float32), row, atol=tol)

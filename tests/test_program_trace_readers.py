@@ -113,7 +113,8 @@ FOLD_READERS = ("step_wall_p99_ms", "tail_step_wall_ms",
 ALL_CELLS = CELLS + ("granite-4.0-h-small-ep2.decode-sat",
                      "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
                      "mistral-small-4-119b-ep4.decode-sat-deep",
-                     "trinity-mini-pp8.decode-sat-long")
+                     "trinity-mini-pp8.decode-sat-long",
+                     "longcat-flash-chat-ep32.decode-sat-reason")
 EDGES = [0.01, 0.02, 0.04, 0.08]            # five rows: under, three, over
 PHASE_KEYS = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s",
               "commit_s")
@@ -315,7 +316,8 @@ def test_the_afmoe_cell_lists_its_readers_and_the_ones_it_joins():
 
 EXPERT_CELLS = ("granite-4.0-h-small-ep2.decode-sat",
                 "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
-                "mistral-small-4-119b-ep4.decode-sat-deep", AFMOE_CELL)
+                "mistral-small-4-119b-ep4.decode-sat-deep", AFMOE_CELL,
+                "longcat-flash-chat-ep32.decode-sat-reason")
 
 
 def test_grouped_reader_is_the_new_scope_per_admission(table, monkeypatch):
@@ -355,3 +357,90 @@ def test_the_expert_cells_and_no_other_list_the_grouped_reader(cell):
         "name": "moe_grouped_dev_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "model step",
         "moves": "gap_mean_ms", "workloads": list(EXPERT_CELLS)}
+
+
+# ---------------------------------------------------------------------------
+# PR 38: the longcat_flash cell's four readers, on the recorded events and by
+# hand
+# ---------------------------------------------------------------------------
+
+LONGCAT_CELL = "longcat-flash-chat-ep32.decode-sat-reason"
+LONGCAT_READERS = ("zero_expert_share", "longcat_step_hbm_share",
+                   "longcat_experts_hbm_share",
+                   "longcat_attn_latent_hbm_share")
+LONGCAT_LIVE = 0.45 * 96 * 3072
+
+
+@pytest.fixture
+def longcat_record(table, monkeypatch):
+    """A traced window of 100 steps of 20 ms over the recorded table, the
+    scopes the recorded Qwen2 step lacks put in by hand."""
+    steps = pt.span_count(table, pt.STEP_SPAN)
+    scopes = {**table["scopes"], "attn.latent": 0.005 * steps,
+              "moe.route": 0.001 * steps, "moe.experts": 0.007 * steps}
+    monkeypatch.setitem(pt._TABLES, "table", {**table, "scopes": scopes})
+    with open(os.path.join(HERE, "configs",
+                           "longcat-flash-chat-ep32.json")) as f:
+        config = json.load(f)
+    rows = {"latent_rows_live": LONGCAT_LIVE,
+            "latent_rows_capacity": 18432 * 16, "kv_row_bytes": 1280}
+    return {"trace": {"modules": {"jit__batched_hybrid_step_jit": {
+                "runs": 100, "seconds": 2.0}}},
+            "config": config, "device_kind": "TPU v5 lite",
+            "report0": {"steps": 0, "slot_util_mean": 0.0, **rows,
+                        "routed_assignments": 1000, "zero_assignments": 300},
+            "report1": {"steps": 100, "slot_util_mean": 1.0, **rows,
+                        "routed_assignments": 461_800,
+                        "zero_assignments": 153_900}}
+
+
+@pytest.mark.parametrize("name", LONGCAT_READERS)
+def test_longcat_reader_on_the_recorded_events(name, table, longcat_record):
+    from benchmark import rooflines_longcat_flash as r
+
+    c = longcat_record["config"]
+    assert r.param_count(c) == 5_172_749_312       # the issue's arithmetic
+    want = {
+        "zero_expert_share": 100 * 153_600 / 460_800,
+        # 4 x 608,699,136 parameters x 2 B at 819 GB/s, of 8 ms
+        "longcat_experts_hbm_share": 100 * (4 * 608_699_136 * 2 / 819e9)
+        / 8e-3,
+        # 8 sublayers' live rows at 1280 B at 819 GB/s, of 5 ms
+        "longcat_attn_latent_hbm_share":
+            100 * (8 * LONGCAT_LIVE * 1280 / 819e9) / 5e-3,
+        "longcat_step_hbm_share": 100 * (r.step_bytes(
+            c, LONGCAT_LIVE, 1280, 96) / 819e9) / 20e-3}[name]
+    got = _read(name, longcat_record)
+    assert got == pytest.approx(want) and 0 < got < 100
+    # a program without the scopes or the counters (another family's, or an
+    # older one), and, for the three device shares, an untraced run
+    if name != "zero_expert_share":
+        assert _read(name, {**longcat_record, "trace": None}) is None
+    pt._TABLES["table"] = {**table, "scopes": {"attn.decode": 1.0}}
+    bare = {k: {"steps": v["steps"], "slot_util_mean": v["slot_util_mean"]}
+            for k, v in longcat_record.items() if k.startswith("report")}
+    assert _read(name, {**longcat_record, **bare}) is None
+
+
+def test_the_longcat_cell_lists_its_readers_and_the_ones_it_joins():
+    names = {m.name for m in load_cell(LONGCAT_CELL).per_layer}
+    assert names >= set(LONGCAT_READERS) | {
+        "attn_latent_dev_ms", "latent_pool_live", "attend_walk_share",
+        "moe_experts_dev_ms", "moe_grouped_dev_ms", "dense_mlp_dev_ms",
+        "unembed_sample_dev_ms", "step_dev_ms", "admit_dev_ms",
+        "device_idle", "compiles_in_window"} | set(FOLD_READERS)
+    # other families' byte counts, and what moves ``out_tok_s``
+    assert not names & {"attn_latent_hbm_share", "mistral4_step_hbm_share",
+                        "moe_experts_hbm_share", "afmoe_step_hbm_share",
+                        "expert_load_skew", "routed_local_share",
+                        "slot_util", "pool_live", "evictions",
+                        "attn_decode_dev_ms", "ssm_step_dev_ms"}
+    assert {m.name for m in load_cell(LONGCAT_CELL).end_to_end} == {
+        "gap_mean_ms", "setup_s"}
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    assert [m["name"] for m in spec["per_layer"][-4:]] == list(
+        LONGCAT_READERS)
+    assert spec["workloads"][-1]["name"] == LONGCAT_CELL
+    assert len(spec["workloads"]) == 8
+    assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
