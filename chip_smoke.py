@@ -493,15 +493,26 @@ def _ring_stream(cfg, prompt_len: int, n_new: int, evict_after: int) -> tuple:
     of 4 pages of 16 rows a slot in the window group's own pool, a prompt of
     4.7 ring turns adopted by its tail, 120 steps that turn the ring twice
     more, and the ring leaving the device and coming back in between (the
-    benchmark's cells never evict). Returns (the report, the phase's row)."""
+    benchmark's cells never evict). Rows of 2 KV heads of 64 are one whole
+    lane tile, so both groups' reads are the page walk: the ring's rows are
+    masked by the position each holds inside the kernel, beside a neighbour
+    whose ring has not turned. Returns (the report, the phase's row)."""
+    from edgellm_tpu.models.paged_kv import PAGE_WALK
     from edgellm_tpu.serve.batching import BatchingConfig
 
     bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
                           pages_per_slot=28)
     report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after)
     assert report["window_rows_capacity"] == 3 * 4 * 16
+    assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert report["window_read"] == PAGE_WALK, report["window_read"]
+    assert 0 < report["window_pages_walked"] <= report["window_pages_spanned"]
     return report, {"tokens": int(n_new), "evicted": report["evicted"],
                     "window_pages": cfg.window_pages(16),
+                    "decode_read": report["decode_read"],
+                    "window_read": report["window_read"],
+                    "window_pages_walked": report["window_pages_walked"],
+                    "window_pages_spanned": report["window_pages_spanned"],
                     "routed_local": report["routed_local"],
                     "gap_max_over_logit_max": gap}
 
@@ -514,8 +525,8 @@ def window_phase(*, prompt_len: int = 300, n_new: int = 120,
     (:func:`_ring_stream`)."""
     from edgellm_tpu.models.configs import tiny_mellum_config
 
-    return _ring_stream(tiny_mellum_config(sliding_window=40), prompt_len,
-                        n_new, evict_after)[1]
+    return _ring_stream(tiny_mellum_config(sliding_window=40, head_dim=64),
+                        prompt_len, n_new, evict_after)[1]
 
 
 def afmoe_phase(*, prompt_len: int = 300, n_new: int = 120,
@@ -529,7 +540,7 @@ def afmoe_phase(*, prompt_len: int = 300, n_new: int = 120,
     the grouped expert products."""
     from edgellm_tpu.models.configs import tiny_afmoe_config
 
-    cfg = tiny_afmoe_config(sliding_window=40)
+    cfg = tiny_afmoe_config(sliding_window=40, head_dim=64)
     report, row = _ring_stream(cfg, prompt_len, n_new, evict_after)
     assert len(report["expert_tokens"]) == cfg.expert_layers == 4
     return {**row, "expert_layers": cfg.expert_layers}

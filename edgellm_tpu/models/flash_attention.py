@@ -624,19 +624,42 @@ def paged_walk_pages_per_block(page_size: int, width: int,
     return max(rows // page_size, 1)
 
 
+def ring_walk_pages_per_block(entries: int, page_size: int, width: int,
+                              itemsize: int) -> int:
+    """Pages a block of a RING walk (:func:`paged_decode_walk`, ``window``)
+    holds. A ring that has turned is fetched whole, every step, and a ring
+    is ``window / page_size + 1`` entries: one over a multiple of
+    :func:`paged_walk_pages_per_block`'s answer wherever both are powers of
+    two, which would end every slot on a block of one page and a full pair
+    of dots. So the ring is cut into EQUAL blocks, as many as blocks of
+    twice that answer give to the nearest: 129 entries two blocks of 65, 65
+    entries one (4.3 MB in the four buffers at 512-lane bf16 rows). On a v5e
+    at the two cells' shapes 33 / 43 / 65 / 86 / 129 pages a block take
+    0.75 / 0.71 / 0.69 / 0.73 / 0.66 ms a layer over rings of 129 (an
+    all-idle batch 0.24 / 0.20 / 0.22 / 0.23 / 0.25, rings half filled
+    0.42 / 0.43 / 0.38 / 0.40 / 0.47) and 33 / 43 / 65 take 0.39 / 0.41 /
+    0.36 over rings of 65 (PERF.md §6 "PR 40")."""
+    ppb = paged_walk_pages_per_block(page_size, width, itemsize)
+    return -(-entries // max((entries + ppb) // (2 * ppb), 1))
+
+
 def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                       kbuf, vbuf, sem, par_ref, *, scale):
+                       kbuf, vbuf, sem, par_ref, *, scale, window=0):
     """Grid (B,): slot ``b`` of the step, every head. See
     :func:`paged_decode_walk`."""
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
     _, ppb, ps, w = kbuf.shape
     rows = ppb * ps
+    entries = ids_ref.shape[1]              # of a ring, where ``window``
     leaves = ((k_hbm, kbuf, 0),) if v_hbm is None else (
         (k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
 
     def live_pages(slot):
-        return jnp.maximum(pl.cdiv(len_ref[slot], ps), 1)
+        pages = jnp.maximum(pl.cdiv(len_ref[slot], ps), 1)
+        # a ring that has not turned yet is a prefix of its table; one that
+        # has is fetched whole
+        return jnp.minimum(pages, entries) if window else pages
 
     def num_pages(slot, blk):              # live pages of a slot's block
         return jnp.minimum(live_pages(slot) - blk * ppb, ppb)
@@ -691,6 +714,25 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     wide = jnp.promote_types(q_ref.dtype, kbuf.dtype)
     q = q_ref[0].astype(wide)                               # (H, W)
     h = q.shape[0]
+    if window:
+        # a ring's rows by the POSITION each holds (``paged_kv.ring_positions``
+        # + ``window_valid``), from scalars of the slot off the scalar core.
+        # The newest position t lies in entry ``turn`` of the ring; ring row R
+        # holds position base + R up to that entry's last row and base + R -
+        # entries * ps past it (the lap before). So the positions (t - window,
+        # t] that exist (>= 0) are the ring rows [first, newest] and [lap,
+        # entries * ps): four compares a row, no vector division or modulo.
+        t = length - 1
+        turn = jax.lax.rem(jax.lax.div(t, ps), entries)
+        newest = turn * ps + jax.lax.rem(t, ps)         # t's ring row
+        first = newest - jnp.minimum(window - 1, t)
+        lap = jnp.maximum(first + entries * ps, (turn + 1) * ps)
+
+        def ring_rows(n, shape, axis):
+            r = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            at = n * rows
+            return (((r >= first - at) & (r <= newest - at))
+                    | ((r >= lap - at) & (r < entries * ps - at)))
 
     def body(n, carry):
         m, l, acc = carry
@@ -708,18 +750,22 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         k = kbuf[buf].reshape(rows, w).astype(wide)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        live = length - n * rows           # rows of this block a length covers
-        s = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1) < live,
-            s, -1e30)
+        if window:
+            attended = functools.partial(ring_rows, n)
+        else:
+            live = length - n * rows       # rows of this block a length covers
+
+            def attended(shape, axis):
+                return jax.lax.broadcasted_iota(jnp.int32, shape, axis) < live
+
+        s = jnp.where(attended((h, rows), 1), s, -1e30)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
-        # rows no DMA filled are stale VMEM: 0 x NaN must not reach the sum
+        # rows no DMA filled are stale VMEM, a ring's rows of the lap before
+        # whatever the stream left there: 0 x NaN must not reach the sum
         v = (kbuf if v_hbm is None else vbuf)[buf].reshape(rows, w)
-        v = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (rows, w), 0) < live,
-            v, jnp.zeros_like(v))
+        v = jnp.where(attended((rows, w), 0), v, jnp.zeros_like(v))
         pv = jax.lax.dot_general(p.astype(q_ref.dtype).astype(wide),
                                  v.astype(wide),
                                  (((1,), (0,)), ((), ())),
@@ -735,11 +781,11 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "pages_per_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "pages_per_block", "interpret", "window"))
 def paged_decode_walk(qz, k_pages, v_pages, page_ids, lengths, *,
                       scale: float, pages_per_block: int | None = None,
-                      interpret=False):
+                      interpret=False, window: int = 0):
     """Single-position attention of every slot over ITS OWN live pages, read
     out of the pool where they lie: ONE kernel in place of "gather every
     slot's whole span into a copy, then two dots over the copy".
@@ -764,21 +810,37 @@ def paged_decode_walk(qz, k_pages, v_pages, page_ids, lengths, *,
     so what the output holds does not depend on any page or row a length
     does not cover.
 
+    ``window`` (static; 0: none of the following is traced): ``page_ids`` is a
+    window layer's RING of ``E = page_ids.shape[1]`` entries (entry ``(p //
+    ps) % E`` holds position p's page, ``paged_kv.write_rows(ring=True)``)
+    and ``lengths`` still counts the positions written, the newest included.
+    Slot i fetches ``min(ceil(lengths[i] / ps), E)`` pages in table order (a
+    ring that has not turned is a prefix of its table, one that has is
+    fetched whole), ``ring_walk_pages_per_block`` a block, and attends a row
+    iff the position it holds lies in ``(t - window, t]``, ``t = lengths[i]
+    - 1``: ``paged_kv.ring_positions`` + ``window_valid``. Rows of the lap
+    before, rows not written yet and rows no DMA filled are masked before
+    the exponent and their V rows selected to zero, as above.
+
     Scalar prefetch puts ``page_ids`` and ``lengths`` in SMEM before the
     body runs."""
     b, h, w = qz.shape
     ps = k_pages.shape[1]
-    ppb = pages_per_block or paged_walk_pages_per_block(
-        ps, w, k_pages.dtype.itemsize)
+    itemsize = k_pages.dtype.itemsize
+    ppb = pages_per_block or (
+        ring_walk_pages_per_block(page_ids.shape[1], ps, w, itemsize)
+        if window else paged_walk_pages_per_block(ps, w, itemsize))
     buf = pltpu.VMEM((2, ppb, ps, w), k_pages.dtype)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     slot = pl.BlockSpec((1, h, w), lambda i, ids, lens: (i, 0, 0))
     if v_pages is None:
         kernel = lambda ids, lens, q, k, o, kb, sem, par: _paged_walk_kernel(
-            ids, lens, q, k, None, o, kb, None, sem, par, scale=scale)
+            ids, lens, q, k, None, o, kb, None, sem, par, scale=scale,
+            window=window)
         leaves, bufs = (k_pages,), [buf]
     else:
-        kernel = functools.partial(_paged_walk_kernel, scale=scale)
+        kernel = functools.partial(_paged_walk_kernel, scale=scale,
+                                   window=window)
         leaves, bufs = (k_pages, v_pages), [buf, buf]
     return pl.pallas_call(
         kernel,

@@ -50,8 +50,9 @@ Not done, by name: boundary hooks and attention statistics (the sweep
 drivers), the split runtime, speculation, prefix sharing, quantized KV tiers,
 checkpoints: each needs a snapshot of the recurrent state that does not exist
 (:func:`refuse_recurrent_state`), or reads "a slot's K/V = every position of
-every layer": not a window layer's ring (:func:`refuse_window_ring`), nor a
-latent row (:func:`refuse_latent_rows`); all: :func:`refuse_beyond_kv_rows`."""
+every layer": not a ring (:func:`refuse_window_ring`; the decode's page walk
+takes one, masked by position), nor a latent row (:func:`refuse_latent_rows`);
+all: :func:`refuse_beyond_kv_rows`."""
 from __future__ import annotations
 
 from typing import NamedTuple

@@ -2103,17 +2103,19 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def decode_read_path(pool, window: int = 0) -> str:
+def decode_read_path(pool) -> str:
     """Which read a decode step's attention layer is built with, read off
     what it is handed: :data:`PAGE_WALK` (:func:`attend_pages`; a
-    :class:`LatentPool`: :func:`attend_latent_pages`) for an fp pool whose
-    pages hold a prefix (``window == 0``), on a TPU, where a page is whole
-    tiles (rows of whole lane tiles, a page of whole sublane tiles: what the
-    kernel's page DMAs need); :data:`PAGE_GATHER` (the oracle) for a
-    quantized tier, a window ring and every other backend. ``pool`` may be
+    :class:`LatentPool`: :func:`attend_latent_pages`) for an fp pool on a
+    TPU, where a page is whole tiles (rows of whole lane tiles, a page of
+    whole sublane tiles: what the kernel's page DMAs need), whether its pages
+    hold a prefix or a window layer's ring (the walk masks a ring's rows by
+    the position each holds); :data:`PAGE_GATHER` (the oracle) for a
+    quantized tier, part tiles and every other backend. ``pool`` may be
     whole, staged or one layer's. No width is gated out: on a v5e the walk
-    is ahead at 128 to 1024 lanes (PERF.md §6 "PR 33", "PR 35")."""
-    if not isinstance(pool, (PagePool, LatentPool)) or window or not _on_tpu():
+    is ahead at 128 to 1024 lanes (PERF.md §6 "PR 33", "PR 35"; a ring of 65
+    or 129 pages: "PR 40")."""
+    if not isinstance(pool, (PagePool, LatentPool)) or not _on_tpu():
         return PAGE_GATHER
     leaf = pool[0]                 # K, or a latent pool's one leaf
     sublanes = 32 // jnp.dtype(leaf.dtype).itemsize
@@ -2121,7 +2123,8 @@ def decode_read_path(pool, window: int = 0) -> str:
     return PAGE_WALK if whole else PAGE_GATHER
 
 
-def attend_pages(q, pool: PagePool, layer, page_table, lengths):
+def attend_pages(q, pool: PagePool, layer, page_table, lengths,
+                 window: int = 0):
     """:func:`read_span` + :func:`attend_rows` without the span: q (B, 1, H,
     hd) against each slot's LIVE pages of layer ``layer`` of an fp pool (L,
     P, ps, KV*hd), read out of the pool where they lie by ONE kernel
@@ -2131,13 +2134,19 @@ def attend_pages(q, pool: PagePool, layer, page_table, lengths):
     page ids ``layer*P + page_table``; the same query in its groups' lanes,
     float32 scores and softmax, and the same rows attended as
     :func:`attend_rows`, in a blockwise order of the float32 sums. Returns
-    (B, 1, H, hd) in q's dtype."""
+    (B, 1, H, hd) in q's dtype.
+
+    ``window`` (static, > 0): ``page_table`` is a window layer's ring, of
+    which the kernel fetches the entries the stream has reached (all of them
+    once the ring has turned) and attends the rows :func:`window_valid` says
+    of :func:`ring_positions`, by two scalars a slot."""
     hd = q.shape[-1]
     own, qz = _group_lanes(q, pool.k.shape[-1] // hd)
     ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
     out = flash_attention.paged_decode_walk(
         qz, _pages(pool.k, 1), _pages(pool.v, 1), ids,
-        lengths.astype(jnp.int32), scale=float(1.0 / np.sqrt(hd)))
+        lengths.astype(jnp.int32), scale=float(1.0 / np.sqrt(hd)),
+        window=window)
     return _own_lanes(out, own)
 
 
@@ -2195,7 +2204,8 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
     ``finfo.min`` contributes exactly 0.
 
     ``window`` (static, > 0): ``page_table`` is a window layer's ring
-    (:func:`write_rows`) and a row is attended by the position it holds."""
+    (:func:`write_rows`) and a row is attended by the position it holds, on
+    either read: the walk takes the ring as it takes a prefix's table."""
     s1, h, hd = q.shape[1:]
     if s1 != 1:
         raise ValueError(f"paged decode is q_len=1 only, got q_len={s1}")
@@ -2207,8 +2217,8 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
                          f"head_dim {hd} for tier {tier!r}")
     if h % kv:
         raise ValueError(f"ragged GQA: H={h}, KV={kv}")
-    if decode_read_path(pool, window) == PAGE_WALK:
-        return attend_pages(q, pool, layer, page_table, lengths)
+    if decode_read_path(pool) == PAGE_WALK:
+        return attend_pages(q, pool, layer, page_table, lengths, window)
     kg, vg = read_span(pool, layer, page_table, q.dtype)
     if not window:
         return attend_rows(q, kg, vg, lengths)

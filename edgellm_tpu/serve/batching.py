@@ -85,9 +85,9 @@ import jax.numpy as jnp
 
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
-from ..models.paged_kv import PAGE_WALK, OutOfPages, OutOfSlots, \
-    PagedKVCache, PrefixCacheConfig, SlotState, decode_read_path, \
-    paged_decode_step, resolve_kv_codec
+from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, OutOfPages, \
+    OutOfSlots, PagedKVCache, PrefixCacheConfig, SlotState, \
+    decode_read_path, paged_decode_step, resolve_kv_codec
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
 from ..obs.flight import flight_dump_for
@@ -397,11 +397,11 @@ class ContinuousBatcher:
                       "alloc_n": 0, "compiles": 0, "compile_s": 0.0,
                       "routed_assignments": 0, "admit_steps": 0,
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
+                      "window_pages_walked": 0, "window_pages_spanned": 0,
                       "step_wall_hist": _new_step_wall_hist(),
                       **dict.fromkeys(_CLOCKS, 0.0)}
-        # the read the step's full-attention layers are built with (by pool)
-        self.decode_read = decode_read_path(
-            self._split_pool if split_runtime is not None else self.pool.pool)
+        # the reads the step's full and window layers are built with (by pool)
+        self.decode_read, self.window_read = self._read_paths()
         # the scheduler thread's own, lock-free between folds: clocks and
         # counts; the clock at each token 0; the last launched step's return
         self._acc: dict[str, float] = defaultdict(int)
@@ -1011,14 +1011,22 @@ class ContinuousBatcher:
             # by construction); inactive slots write the trash page
             page_table, lengths = self.pool.device_tables()
             misses0 = self._step_cache_size()
+            # the pages under each slot's length, the row this step writes
+            # included (an idle slot's one trash page)
+            reached = self.pool.lengths // self.pool.page_size + 1
             if self.decode_read == PAGE_WALK:
-                # what a layer's attend fetches this step, a page a DMA: the
-                # pages under each slot's length, the row this step writes
-                # included (an idle slot's one trash page), against the
-                # table entries a page gather reads whatever they hold
-                acc["attend_pages_walked"] = int(np.sum(
-                    self.pool.lengths // self.pool.page_size + 1))
+                # what a layer's attend fetches this step, a page a DMA,
+                # against the table entries a page gather reads whatever
+                # they hold
+                acc["attend_pages_walked"] = int(np.sum(reached))
                 acc["attend_pages_spanned"] = b * self.bcfg.pages_per_slot
+            if self.window_read == PAGE_WALK:
+                # the same of a window layer's ring: the entries the stream
+                # has reached, every one once the ring has turned
+                ring = self.pool.window_pages
+                acc["window_pages_walked"] = int(np.sum(
+                    np.minimum(reached, ring)))
+                acc["window_pages_spanned"] = b * ring
         with obs_phase("batch.step.launch", acc, "launch_s", after=ph,
                        step=step_no) as ph:
             t0 = time.monotonic()
@@ -1344,14 +1352,29 @@ class ContinuousBatcher:
             "token_capacity": self.pool.token_capacity,
             # the decode read the step was built with, and (additive; 0 on
             # the page gather) the pages a layer's page walk fetched against
-            # the table entries a gather would have read
+            # the table entries a gather would have read; the same of a
+            # window layer's ring (the gather, 0 and 0 where no layer slides)
             "decode_read": self.decode_read,
             "attend_pages_walked": stats["attend_pages_walked"],
             "attend_pages_spanned": stats["attend_pages_spanned"],
+            "window_read": self.window_read,
+            "window_pages_walked": stats["window_pages_walked"],
+            "window_pages_spanned": stats["window_pages_spanned"],
             **({"prefix": self.pool.prefix_report()}
                if self.pool.prefix is not None else {}),
             **self._hybrid_report(stats),
         }
+
+    def _read_paths(self) -> tuple:
+        """(``decode_read``, ``window_read``): what ``decode_read_path`` says
+        of the pool the full-attention layers read and of the window
+        layers' pool of rings (no such pool: the gather, which no step then
+        takes). Down here: a line added above would move the prefill
+        kernels' call sites, as below."""
+        full = self._split_pool if self.rt is not None else self.pool.pool
+        rings = self.pool.window_pool
+        return (decode_read_path(full),
+                decode_read_path(rings) if rings is not None else PAGE_GATHER)
 
     def _hybrid_report(self, stats: dict) -> dict:
         """What a stack with recurrent state and routed experts adds to

@@ -19,7 +19,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from edgellm_tpu.models import flash_attention, hybrid, paged_kv
 from edgellm_tpu.models import tiny_config
-from edgellm_tpu.models.configs import (tiny_longcat_flash_config,
+from edgellm_tpu.models.configs import (tiny_afmoe_config,
+                                        tiny_longcat_flash_config,
+                                        tiny_mellum_config,
                                         tiny_mistral4_config)
 from edgellm_tpu.models.transformer import init_params
 
@@ -161,7 +163,9 @@ def test_one_leaf_as_keys_and_values():
 
 def test_read_path_is_read_off_the_pool(monkeypatch):
     """The walk for an fp pool of whole tiles on a TPU, the gather for
-    everything else — no flag: the pool's type, its shape and the backend."""
+    everything else — no flag: the pool's type, its shape and the backend.
+    A window layer's pool of rings is asked what every pool is asked: its
+    table is a ring, which the walk takes (PR 40)."""
     cfg = tiny_config("qwen2", num_layers=2, hidden_size=256, num_heads=4,
                       vocab_size=64)                     # KV 2 x hd 64
     fp = paged_kv.init_pool(cfg, 9, 16, jnp.bfloat16)
@@ -170,7 +174,16 @@ def test_read_path_is_read_off_the_pool(monkeypatch):
     quant = paged_kv.init_quant_pool(cfg, 9, 16, "int8_per_channel")
     narrow = paged_kv.PagePool(fp.k[..., :64], fp.v[..., :64])
     short = paged_kv.PagePool(fp.k[:, :, :8], fp.v[:, :, :8])
+    # a window group's pools: 3 slots' rings of 4 pages and the trash page
+    sliding = tiny_mellum_config(sliding_window=40, head_dim=64)
+    rings = paged_kv.init_pool(sliding, 3 * 4 + 1, 16, jnp.bfloat16,
+                               layers=sliding.window_layers)
+    quant_rings = paged_kv.init_quant_pool(sliding, 3 * 4 + 1, 16,
+                                           "int8_per_channel")
+    part_rings = paged_kv.PagePool(rings.k[..., :64], rings.v[..., :64])
+    assert rings.k.shape == (6, 13, 16, 128)
     assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_GATHER  # on a cpu
+    assert paged_kv.decode_read_path(rings) == paged_kv.PAGE_GATHER
     monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
     assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_WALK
     assert paged_kv.decode_read_path(
@@ -178,10 +191,10 @@ def test_read_path_is_read_off_the_pool(monkeypatch):
     # a latent pool's one leaf is asked what K is asked
     assert paged_kv.decode_read_path(
         paged_kv.LatentPool(fp.k)) == paged_kv.PAGE_WALK
-    for pool, window in ((fp, 32), (quant, 0), (narrow, 0), (short, 0),
-                         (paged_kv.LatentPool(narrow.k), 0),
-                         (paged_kv.LatentPool(short.k), 0)):
-        assert paged_kv.decode_read_path(pool, window) == paged_kv.PAGE_GATHER
+    assert paged_kv.decode_read_path(rings) == paged_kv.PAGE_WALK
+    for pool in (quant, narrow, short, quant_rings, part_rings,
+                 paged_kv.LatentPool(narrow.k), paged_kv.LatentPool(short.k)):
+        assert paged_kv.decode_read_path(pool) == paged_kv.PAGE_GATHER
 
 
 @pytest.mark.parametrize("compute", ["float32", "bfloat16"])
@@ -331,3 +344,254 @@ def test_a_640_lane_step_of_two_sublayers_equals_the_contiguous_step(
         np.testing.assert_allclose(
             np.asarray(got_rows[:, table[slot, n // page], n % page],
                        np.float32), row, atol=tol)
+
+
+# -- a window layer's ring (PR 40) --------------------------------------------
+
+#: name -> (the ring's entries E, the window it serves, pages a block):
+#: ``E = ceil((window - 1) / PAGE) + 1``, the most pages that many positions
+#: touch; blocks that divide E, that leave a last block of one page, and the
+#: rule's own (``ring_walk_pages_per_block``: the whole toy ring)
+RINGS = {"block-divides-ring": (4, 40, 2), "block-leaves-one-page": (5, 64, 2),
+         "the-rule's-block": (5, 64, None)}
+
+#: name -> the named slot's length, of (E, window); it stands between a
+#: neighbour whose ring has turned and one whose ring has not
+RING_LENGTHS = {
+    "one-row": lambda e, w: 1,
+    "under-a-page": lambda e, w: 5,
+    "under-the-window": lambda e, w: w - 3,
+    "exactly-the-window": lambda e, w: w,
+    "the-window-and-one": lambda e, w: w + 1,
+    "ends-a-page": lambda e, w: (e + 2) * PAGE,
+    "starts-a-page": lambda e, w: (e + 2) * PAGE + 1,
+    "fills-the-ring-exactly": lambda e, w: e * PAGE,
+    "several-laps": lambda e, w: 3 * e * PAGE + 7,
+}
+
+
+def _ring_pool(entries, window, lengths, idle, dtype, poisoned, seed):
+    """A window group's pool (slot i's ring the pages ``1 + i*E ..``, as
+    ``PagedKVCache._ring_of`` hands them out; an idle slot's row the trash
+    page), its table, and which ring rows each slot attends by the oracle's
+    own word. ``poisoned``: every row NO slot attends (rows of the lap
+    before, rows not reached yet, rows past the newest, a whole page nobody
+    names) holds NaN in K and inf in V; the trash page's row 0 stays finite,
+    it is what an idle slot attends."""
+    rng = np.random.default_rng(seed)
+    slots = len(lengths)
+    pages = slots * entries + 1
+    k, v = (rng.standard_normal((LAYERS, pages, PAGE, 128))
+            .astype(np.float32) for _ in range(2))
+    table = np.zeros((slots, entries), np.int32)
+    for i in range(slots):
+        if i not in idle:
+            table[i] = 1 + i * entries + np.arange(entries)
+    lens = jnp.asarray(lengths, jnp.int32)
+    valid = np.asarray(paged_kv.window_valid(
+        paged_kv.ring_positions(lens, entries, PAGE), lens, window))
+    keep = np.zeros((pages, PAGE), bool)
+    # (an idle slot names the trash page in every entry: unbuffered)
+    np.logical_or.at(keep, table, valid.reshape(slots, entries, PAGE))
+    if poisoned:
+        k[:, ~keep] = np.nan
+        v[:, ~keep] = np.inf
+    pool = paged_kv.PagePool(jnp.asarray(k, dtype), jnp.asarray(v, dtype))
+    return pool, jnp.asarray(table), lens, jnp.asarray(valid), keep
+
+
+def _ring_walk(q, pool, table, lens, window, pages_per_block):
+    """``paged_kv.attend_pages`` over a ring with the kernel interpreted."""
+    hd = q.shape[-1]
+    own, qz = paged_kv._group_lanes(q, pool.k.shape[-1] // hd)
+    out = _interpreted(
+        qz, paged_kv._pages(pool.k, 1), paged_kv._pages(pool.v, 1),
+        LAYER * pool.num_pages + table, lens, scale=float(hd ** -0.5),
+        pages_per_block=pages_per_block, window=window)
+    return paged_kv._own_lanes(out, own)
+
+
+def _ring_gather(q, pool, table, lens, valid, keep):
+    """The oracle: the parent's read of a ring, over a pool whose unattended
+    rows are made finite (0 x NaN is what its masked rows would make)."""
+    clean = paged_kv.PagePool(*(
+        jnp.where(jnp.asarray(keep)[None, :, :, None], a, 0) for a in pool))
+    kg, vg = paged_kv.read_span(clean, LAYER, table, q.dtype)
+    return paged_kv.attend_rows(q, kg, vg, lens, valid)
+
+
+def _ring_case(ring, length, dtype, poisoned):
+    entries, window, ppb = RINGS[ring]
+    n = RING_LENGTHS[length](entries, window)
+    lengths = (2 * entries * PAGE + 3, n, window // 2, 1)
+    pool, table, lens, valid, keep = _ring_pool(
+        entries, window, lengths, {3}, dtype, poisoned, seed=len(length))
+    q = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (len(lengths), 1, 4, 64)), dtype)
+    got = _ring_walk(q, pool, table, lens, window, ppb)
+    want = _ring_gather(q, pool, table, lens, valid, keep)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.isfinite(got).all())
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+@pytest.mark.parametrize("length", RING_LENGTHS)
+@pytest.mark.parametrize("ring", RINGS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_ring_walk_equals_gather(dtype, ring, length):
+    """A ring's rows are attended by the POSITION each holds, as
+    ``ring_positions`` + ``window_valid`` say, wherever in the ring it lies:
+    a ring that has not turned (a prefix of its table, the pages past the
+    newest never fetched), one that has (fetched whole, the newest page part
+    new rows and part the lap before's), an idle slot on the trash page."""
+    got, want = _ring_case(ring, length, jnp.dtype(dtype), poisoned=False)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("length", RING_LENGTHS)
+@pytest.mark.parametrize("ring", RINGS)
+def test_ring_walk_reads_nothing_outside_a_window(ring, length):
+    """Every row no slot's window covers holds NaN (K) and inf (V) in the
+    pool: rows of the lap before in fetched pages, rows not written yet,
+    pages a young ring has not reached. None may reach the output, through a
+    score or through 0 x inf in the weighted sum."""
+    got, want = _ring_case(ring, length, jnp.float32, poisoned=True)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_ring_rule_cuts_the_ring_into_equal_blocks():
+    """``ring_walk_pages_per_block``: no ring ends on a block of one page.
+    The cells' rings at 512-lane bf16 rows; a toy ring is one block."""
+    rule = flash_attention.ring_walk_pages_per_block
+    assert flash_attention.paged_walk_pages_per_block(16, 512, 2) == 32
+    for entries in (129, 65, 33, 4, 1, 257, 48, 100):
+        ppb = rule(entries, 16, 512, 2)
+        blocks = -(-entries // ppb)
+        assert entries - (blocks - 1) * ppb > ppb // 2, (entries, ppb)
+    assert rule(4, 16, 128, 4) == 4
+
+
+#: sha256 of the jaxpr text of a ``window=0`` call (two leaves; one leaf) as
+#: the PARENT's kernel traced it (PR 38's tree, this jax): the six cells
+#: without a ring keep their kernel bodies. A jaxpr's text carries no source
+#: location. Another jax prints another text: then the test skips.
+PREFIX_WALK_JAXPR = {"0.9.0": (
+    "a789e5f71185c0f20e5d8dea056b22e717ace381891f3b3aaa481593c4c3b735",
+    "3b43dafb03bed5ec31b5c8538a4a9ef6ac4bd080d9dc026f4e7cf53175ea59ce")}
+
+
+def _walk_jaxpr(leaves, **kwargs):
+    shape = jax.ShapeDtypeStruct
+    q, pages = shape((3, 4, 128), jnp.bfloat16), shape((40, 16, 128),
+                                                       jnp.bfloat16)
+    return str(jax.make_jaxpr(
+        lambda q, k, v, ids, lens: flash_attention.paged_decode_walk(
+            q, k, v if leaves == 2 else None, ids, lens, scale=0.125,
+            **kwargs))(q, pages, pages, shape((3, 8), jnp.int32),
+                       shape((3,), jnp.int32)))
+
+
+def test_a_prefix_walk_traces_what_the_parent_traced():
+    import hashlib
+
+    if jax.__version__ not in PREFIX_WALK_JAXPR:
+        pytest.skip(f"no recorded jaxpr text for jax {jax.__version__}")
+    got = tuple(hashlib.sha256(_walk_jaxpr(n).encode()).hexdigest()
+                for n in (2, 1))
+    assert got == PREFIX_WALK_JAXPR[jax.__version__]
+    # and a ring's is another: the mask by position is in the body
+    assert _walk_jaxpr(2, window=40) != _walk_jaxpr(2)
+
+
+#: the toy stacks whose window layers keep rings, at rows of ONE lane tile (2
+#: KV heads of 64) and a window of 12: a ring of 3 pages of 8 float32 rows
+RING_STACKS = {
+    "mellum": lambda: tiny_mellum_config(
+        sliding_window=12, head_dim=64,
+        layer_types=("sliding_attention", "attention", "sliding_attention")),
+    "afmoe": lambda: tiny_afmoe_config(
+        sliding_window=12, head_dim=64,
+        layer_types=("sliding_attention", "sliding_attention", "attention")),
+}
+
+
+@pytest.mark.parametrize("family", RING_STACKS)
+def test_window_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
+                                                               family):
+    """The whole ``_batched_window_step_jit`` through the batcher, built on
+    the walk (the choice forced as a TPU would make it, the kernel
+    interpreted: the ring layers' walks and the full layer's) against the
+    same service on the gather: a prompt longer than the ring beside one
+    shorter than a page whose ring turns under way, an eviction and a
+    readmission through ``adopt_window``; the same tokens, the same rows in
+    both pools. ``report()`` names each read and the page counters add up."""
+    from edgellm_tpu.serve import batching
+
+    cfg = RING_STACKS[family]()
+    params = init_params(cfg, jax.random.key(0))
+    bcfg = batching.BatchingConfig(page_size=8, num_pages=41, max_slots=3,
+                                   pages_per_slot=8)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 29)]
+    step_jit = batching._batched_window_step_jit
+
+    def serve():
+        step_jit.clear_cache()
+        b = batching.ContinuousBatcher(cfg, params, bcfg)
+        ring = b.pool.window_pages
+        assert ring == 3
+        sids = [b.submit(p, 26, rng_seed=i) for i, p in enumerate(prompts)]
+        for i in range(200):
+            if i == 9:
+                b.evict(sids[1])
+                b.pool.check_invariants()
+            if not b.step():
+                break
+        assert set(b.results) == set(sids)
+        return b, [b.results[s] for s in sids]
+
+    with jax.default_matmul_precision("highest"):
+        gather, want = serve()
+        rep = gather.report()
+        assert rep["decode_read"] == rep["window_read"] == paged_kv.PAGE_GATHER
+        assert rep["window_pages_walked"] == rep["window_pages_spanned"] == 0
+        monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+        windows = []
+
+        def walk(*args, window=0, **kwargs):
+            windows.append(window)
+            return _interpreted(*args, window=window, **kwargs)
+
+        monkeypatch.setattr(flash_attention, "paged_decode_walk", walk)
+        # the interpreter's callbacks must not meet a host that dispatches on
+        fetched = []        # a step: the ring entries its lengths reach
+
+        def waited(*args):
+            fetched.append(int(np.minimum(np.asarray(args[7]) // 8 + 1,
+                                          3).sum()))
+            return jax.block_until_ready(step_jit(*args))
+
+        waited._cache_size = step_jit._cache_size     # the jit-miss counter's
+        monkeypatch.setattr(batching, "_batched_window_step_jit", waited)
+        walker, got = serve()
+    step_jit.clear_cache()
+    # one trace: a walk a layer, the ring layers' with their window
+    assert sorted(windows) == [0, 12, 12]
+    rep = walker.report()
+    assert rep["decode_read"] == rep["window_read"] == paged_kv.PAGE_WALK
+    assert rep["evicted"] == 1
+    walked, spanned = sum(fetched), len(fetched) * 3 * 3
+    assert (rep["window_pages_walked"], rep["window_pages_spanned"]) == (
+        walked, spanned)
+    assert 0 < walked < spanned            # young rings fetch less than all
+    assert 0 < rep["attend_pages_walked"] < rep["attend_pages_spanned"]
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w), (g.tolist(), w.tolist())
+    for mine, theirs in ((walker.pool.pool, gather.pool.pool),
+                         (walker.pool.window_pool, gather.pool.window_pool)):
+        for a, b in zip(mine, theirs):
+            # page 0 is the trash page: idle slots' rows, in any order
+            np.testing.assert_allclose(np.asarray(a[:, 1:]),
+                                       np.asarray(b[:, 1:]), atol=1e-5)
