@@ -188,19 +188,55 @@ def kernels_phase(cfg, dispatch: dict, *, hop_shapes=()) -> dict:
                        for blk in codecs]}
 
 
-def serve_phase(name: str, params: dict, vocab_size: int, *,
-                model: str = MODEL, out_dir: str = OUT_DIR) -> dict:
-    """One serve soak through ``run.main``. All requests must complete, with
-    tokens in ``[0, vocab)`` and no step-cache miss inside the soak."""
+def launch_ahead_share(report: dict) -> float:
+    """Percent of a batcher's launched steps that found the step before them
+    unread (``report()``'s ``steps_ahead`` over ``steps``)."""
+    return 100.0 * report["steps_ahead"] / max(report["steps"], 1)
+
+
+class _old_order:
+    """While entered, every ``ContinuousBatcher.step()`` reads its own step
+    before it returns (launch, sync, commit: the order before the batcher ran
+    a launch ahead), so a phase can serve the same requests both ways."""
+
+    def __enter__(self):
+        from edgellm_tpu.serve.batching import ContinuousBatcher
+
+        self.cls, self.step = ContinuousBatcher, ContinuousBatcher.step
+
+        def step(batcher):
+            n = self.step(batcher)
+            batcher._drain()
+            return n
+
+        ContinuousBatcher.step = step
+
+    def __exit__(self, *exc):
+        self.cls.step = self.step
+
+
+def _soak(params: dict, phase_dir: str, model: str) -> dict:
     from edgellm_tpu import run
 
-    phase_dir = os.path.join(out_dir, name)
     rc = run.main(["--model", model, "--seed", str(SEED),
                    "--params", json.dumps(params),
                    "--output-dir", phase_dir])
     assert rc == 0, f"run.main serve returned {rc}"
     with open(os.path.join(phase_dir, "serve_report.json")) as f:
-        rep = json.load(f)
+        return json.load(f)
+
+
+def serve_phase(name: str, params: dict, vocab_size: int, *,
+                model: str = MODEL, out_dir: str = OUT_DIR) -> dict:
+    """One serve soak through ``run.main``. All requests must complete, with
+    tokens in ``[0, vocab)`` and no step-cache miss inside the soak; and the
+    same soak served in the old order (each step read before the next is
+    launched) must give every request the same tokens."""
+    rep = _soak(params, os.path.join(out_dir, name), model)
+    with _old_order():
+        old = _soak(params, os.path.join(out_dir, name + "_old_order"), model)
+    assert old["batcher"]["steps_ahead"] == 0, old["batcher"]
+    assert rep["tokens"] == old["tokens"], (rep["tokens"], old["tokens"])
     soak = params["serving"]["soak"]
     assert rep["outcomes"] == {"completed": soak["n_requests"]}, \
         rep["outcomes"]
@@ -212,6 +248,9 @@ def serve_phase(name: str, params: dict, vocab_size: int, *,
     return {"mode": rep["mode"], "platform": rep["platform"],
             "outcomes": rep["outcomes"], "jit_misses": bat["jit_misses"],
             "batched_steps": bat["steps"], "evicted": bat["evicted"],
+            "launch_ahead_share": launch_ahead_share(bat),
+            "tokens_equal_old_order": True,
+            "old_order_steady_s": old["drain_s"],
             # first call of every executable, compiles included
             "first_call_s": rep["warmup_s"],
             # the soak itself: prefill and decode steps each end in a sync
@@ -297,8 +336,8 @@ def reference_phase(cfg, *, batching: dict = BATCHING, prompt_len: int = 100,
             feed[slot] = ids[0, pos]
             logits, pool.pool = step(cfg, params, pool.pool, page_table,
                                      lengths, jnp.asarray(feed))
-            # sync BEFORE touching the host tables, as the batcher does: the
-            # step may still be reading the lengths array it was handed
+            # (the tables a step was handed are copies: the host may count on
+            # while the step has yet to run, as the batcher does)
             got = np.asarray(logits[slot])
             pool.lengths[slot] = pos + 1
             assert np.isfinite(got).all()
@@ -392,9 +431,10 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
                    evict_after: int, tweak=lambda params: params) -> tuple:
     """One stream admitted, stepped, evicted and readmitted through
     ``ContinuousBatcher`` on the chip beside a short neighbour, float32 at
-    ``highest``: its tokens equal an undisturbed stream's, and ``forward``
-    over prompt + tokens puts each of them first. Returns (the batcher's
-    report, the largest gap over the largest logit)."""
+    ``highest``: its tokens equal an undisturbed stream's, served a launch
+    ahead and in the old order, and ``forward`` over prompt + tokens puts
+    each of them first. Returns (the batcher's report with its
+    ``launch_ahead_share``, the largest gap over the largest logit)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -409,6 +449,13 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
         calm = ContinuousBatcher(cfg, params, bcfg)
         sid = calm.submit(prompt, n_new, rng_seed=1)
         want = calm.run()[sid]
+        with _old_order():
+            old = ContinuousBatcher(cfg, params, bcfg)
+            sid = old.submit(prompt, n_new, rng_seed=1)
+            in_old_order = old.run()[sid]
+        assert old.report()["steps_ahead"] == 0
+        assert np.array_equal(want, in_old_order), (
+            want.tolist(), in_old_order.tolist())
         b = ContinuousBatcher(cfg, params, bcfg)
         b.submit(prompt[:9], n_new, rng_seed=2)           # a neighbour
         sid = b.submit(prompt, n_new, rng_seed=1)
@@ -427,6 +474,10 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
     scale = float(np.abs(rows).max())
     assert gaps.max() <= 1e-4 * scale, (gaps.tolist(), scale)
     assert report["evicted"] == 1
+    # the eviction's drain and the launch after it are the old order; every
+    # other launch found the step before it unread
+    report["launch_ahead_share"] = launch_ahead_share(report)
+    assert report["steps_ahead"] >= report["steps"] - 3, report
     return report, float(gaps.max() / scale)
 
 
@@ -485,6 +536,7 @@ def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
             "routed_local": report["routed_local"],
             "grouped_product": report["grouped_product"],
             "kernel_vs_ragged_dot_over_logit_max": paths_gap,
+            "launch_ahead_share": report["launch_ahead_share"],
             "gap_max_over_logit_max": gap}
 
 
@@ -514,6 +566,7 @@ def _ring_stream(cfg, prompt_len: int, n_new: int, evict_after: int) -> tuple:
                     "window_pages_walked": report["window_pages_walked"],
                     "window_pages_spanned": report["window_pages_spanned"],
                     "routed_local": report["routed_local"],
+                    "launch_ahead_share": report["launch_ahead_share"],
                     "gap_max_over_logit_max": gap}
 
 
@@ -577,6 +630,7 @@ def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
             "attend_pages_spanned": report["attend_pages_spanned"],
             "kv_row_bytes": report["kv_row_bytes"],
             "routed_local": report["routed_local"],
+            "launch_ahead_share": report["launch_ahead_share"],
             "gap_max_over_logit_max": gap}
 
 
@@ -623,6 +677,7 @@ def longcat_phase(*, prompt_len: int = 300, n_new: int = 40,
             "routed_assignments": made,
             "routed_local": report["routed_local"],
             "zero_assignments": report["zero_assignments"],
+            "launch_ahead_share": report["launch_ahead_share"],
             "gap_max_over_logit_max": gap}
 
 

@@ -1338,16 +1338,29 @@ class PagedKVCache:
             self.pool = _copy_pages_impl(self.pool, src, dst)
         return pairs
 
-    def device_tables(self) -> tuple[jnp.ndarray, jnp.ndarray]:
+    def device_tables(self, idle=()) -> tuple[jnp.ndarray, jnp.ndarray]:
         """(page_table (max_slots, pages_per_slot), lengths (max_slots,)) as
-        device int32 arrays — the traced inputs of the compiled step."""
-        return (jnp.asarray(self.page_table),
-                jnp.asarray(self.lengths, jnp.int32))
+        device int32 arrays — the traced inputs of the compiled step. The
+        ``idle`` slots (active, but riding no step) go out as a free slot
+        does: table row 0 and length 0, so the step's write for them lands
+        on the trash page and not past their last row. What is uploaded is
+        a COPY: the caller goes on growing, freeing and counting while the
+        step it launched has yet to run, and an upload may alias its host
+        array (the CPU's does) or read it later."""
+        table, lengths = self.page_table.copy(), self.lengths.copy()
+        if len(idle):
+            table[idle] = 0
+            lengths[idle] = 0
+        return jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
 
-    def device_window_table(self) -> jnp.ndarray:
+    def device_window_table(self, idle=()) -> jnp.ndarray:
         """(max_slots, window_pages) int32: each slot's ring in the window
-        pool, the third traced table of a step with sliding layers."""
-        return jnp.asarray(self.window_table)
+        pool, the third traced table of a step with sliding layers; a copy,
+        the ``idle`` slots' rows 0, as :meth:`device_tables`."""
+        table = self.window_table.copy()
+        if len(idle):
+            table[idle] = 0
+        return jnp.asarray(table)
 
     # -- the window group's ring -------------------------------------------
 

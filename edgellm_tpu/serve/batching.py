@@ -13,7 +13,10 @@ module schedules many streams through ONE jitted decode step built on
   construction and :func:`batched_step_cache_size` exposes the counter so
   tests assert it. The host fills one numpy row per running slot for each of
   them, so a step costs the same handful of transfers and ONE dispatch
-  whatever ``max_slots`` is (two on the split path: step, then sampler);
+  whatever ``max_slots`` is (two on the split path: step, then sampler),
+  after the one-select merge that hands a slot the token the step before
+  sampled for it: ``step()`` launches step N+1 before it reads step N's
+  tokens, which stay on the device until the call after;
 - prompts are prefetched through the SAME ``_prefill_jit`` executable
   ``generate`` uses, the first token sampled with the same ``fold_in(key, 0)``
   — then the prompt's KV is adopted into the stream's pages;
@@ -82,6 +85,7 @@ from typing import Any, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
@@ -186,6 +190,10 @@ class Stream:
     admit_seq: int = -1           # admission order; youngest = largest
     evictions: int = 0
     queued_t: float = 0.0         # monotonic stamp: entered the waiting queue
+    # tokens sampled by launched steps and not yet read: on the device, in
+    # no list (one between two calls of ``step()``, two from a launch to the
+    # commit of the step before it)
+    pending: int = 0
     # the bits of ``key``, fixed for the stream's life: its row of the step's
     # key table, copied per step with no device work
     key_data: np.ndarray = field(init=False, repr=False)
@@ -195,9 +203,10 @@ class Stream:
 
     @property
     def t(self) -> int:
-        """Next decode-step index == tokens sampled so far (token 0 comes
-        from the prefill, exactly as in ``generate``)."""
-        return len(self.tokens)
+        """Next decode-step index == tokens sampled so far, the ones still
+        in flight among them (token 0 comes from the prefill, exactly as in
+        ``generate``). ``tokens`` holds those the host has read."""
+        return len(self.tokens) + self.pending
 
     @property
     def key(self) -> jax.Array:
@@ -294,13 +303,40 @@ def batched_step_cache_size() -> int:
     jit-miss counter :meth:`ContinuousBatcher.step` reports deltas of."""
     return (_batched_step_jit._cache_size()
             + _batched_hybrid_step_jit._cache_size()
-            + _batched_window_step_jit._cache_size())
+            + _batched_window_step_jit._cache_size()
+            + _feed_jit._cache_size())
 
 
 # the split step returns (max_slots, V) logits from decode_step_paged; the
 # sampler is the SAME vmapped _batched_sample, jitted standalone so split
 # streams keep the local path's per-slot bit-identity guarantee
 _split_sample_jit = jax.jit(_batched_sample)
+
+
+def _fed_tokens(token_ids, prev_toks):
+    """The step's ``token_ids`` where the host knows only some: a slot whose
+    last token is still in flight carries ``IN_FLIGHT`` and takes the step
+    before's sample for it, on the device."""
+    return jnp.where(token_ids == IN_FLIGHT, prev_toks, token_ids)
+
+
+#: ``token_ids`` of a slot that rode the step in flight (no token id is < 0)
+IN_FLIGHT = -1
+
+# one tiny executable ahead of all four launch branches, which keep their
+# programs: 0.55 us a run on a v5e beside a 15.6 ms step (PERF.md "PR 42")
+_feed_jit = jax.jit(_fed_tokens)
+
+
+@dataclass
+class _Launched:
+    """A launched step whose tokens the host has not read."""
+
+    step: int                     # its index among the launches
+    toks: jax.Array               # (max_slots,) sampled ids, on the device
+    riders: list                  # the streams that rode it, each in its slot
+    t0: float                     # the clock at its launch
+    watchdog: Optional[Watchdog]  # armed at its launch
 
 
 #: host-clock counters of ``stats``/``report()``, monotonic seconds, additive
@@ -315,12 +351,23 @@ class ContinuousBatcher:
     """Admit/evict streams mid-flight into one compiled ragged decode step.
 
     Lifecycle: :meth:`submit` queues a stream; :meth:`step` admits waiting
-    streams into free slots (prefill + page adoption), runs ONE jitted step
-    for every running slot, appends each slot's sampled token, retires
-    finished streams, and — when the pool cannot cover a growth — evicts the
-    youngest running stream back to the waiting queue with its gathered KV
-    prefix. :meth:`run` loops :meth:`step` to completion. ``results[sid]``
-    holds each finished stream's (max_new_tokens,) int32 tokens.
+    streams into free slots (prefill + page adoption), launches ONE jitted
+    step for every running slot, then reads the step launched a call
+    earlier: appends each slot's sampled token and retires finished streams.
+    When the pool cannot cover a growth it evicts the youngest running
+    stream back to the waiting queue with its gathered KV prefix.
+    :meth:`run` loops :meth:`step` to completion. ``results[sid]`` holds
+    each finished stream's (max_new_tokens,) int32 tokens.
+
+    The step runs ONE launch ahead of its reads: when ``step()`` returns, at
+    most one launched step's tokens are still on the device, and the next
+    launch takes them from there (``_feed_jit``), so the device has step N+1
+    queued while the host reads and commits step N. A stream ends by count,
+    so who rides, where each writes and what each samples with are known
+    without the ids. The host counts a token in flight (``Stream.t``,
+    ``pool.lengths``); ``Stream.tokens`` holds read tokens only. Whatever
+    needs a stream's tokens on the host, or rows no step is writing, calls
+    :meth:`_drain` first: the old order, sync then commit.
     """
 
     def __init__(self, cfg: ModelConfig, params: dict,
@@ -385,8 +432,6 @@ class ContinuousBatcher:
         self._next_sid = 0
         self._admit_seq = 0
         self.results: dict[int, np.ndarray] = {}
-        self._watchdog = (Watchdog(self.bcfg.step_deadline_s)
-                          if self.bcfg.step_deadline_s is not None else None)
         # running aggregates only (a server takes millions of steps: no list
         # grows by the step); a scrape reads report() mid-step: writes lock
         self._stats_lock = threading.Lock()
@@ -396,6 +441,7 @@ class ContinuousBatcher:
                       "occ_sum": 0.0, "slot_sum": 0.0, "alloc_sum": 0.0,
                       "alloc_n": 0, "compiles": 0, "compile_s": 0.0,
                       "routed_assignments": 0, "admit_steps": 0,
+                      "steps_ahead": 0,
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
                       "window_pages_walked": 0, "window_pages_spanned": 0,
                       "step_wall_hist": _new_step_wall_hist(),
@@ -407,6 +453,16 @@ class ContinuousBatcher:
         self._acc: dict[str, float] = defaultdict(int)
         self._tok0_at: list[float] = []
         self._returned: Optional[float] = None
+        # the launched step whose tokens are still on the device, and the
+        # clock at the last read
+        self._inflight: Optional[_Launched] = None
+        self._read_at = 0.0
+        # where a step's token ids are put: on a mesh, replicated like the
+        # sampler's output they are merged with, so that a merged array and
+        # an uploaded one are one kind of argument to the step (two kinds,
+        # two compiles); on one chip wherever an upload goes
+        self._ids_on = (NamedSharding(split_runtime.mesh, PartitionSpec())
+                        if split_runtime is not None else None)
         # routed-expert counters of a hybrid stack: assignments per held
         # expert per layer, summed on the device inside the step and read by
         # report() alone (no host sync a step); assignments made, counted
@@ -481,6 +537,9 @@ class ContinuousBatcher:
         hatch: an aborted drain would otherwise leave its inflight streams
         queued forever with no caller to collect them, rerunning on the next
         drain. Frees a running stream's slot and pages."""
+        st = self._streams.get(sid)
+        if st is not None and st.status == "running":
+            self._drain()  # it may be riding the step in flight
         st = self._streams.pop(sid, None)
         self.results.pop(sid, None)
         if st is None:
@@ -499,7 +558,8 @@ class ContinuousBatcher:
 
     def _cache_len(self, st: Stream) -> int:
         """Positions st's cache holds at the top of step t: the prompt plus
-        the t-1 tokens already fed back (token t-1 is pending feed)."""
+        the t-1 tokens already fed back (token t-1 is pending feed), the row
+        a step in flight writes among them."""
         return st.prompt.size + max(st.t - 1, 0)
 
     def _microbatch_of(self, slot: int) -> int:
@@ -778,6 +838,7 @@ class ContinuousBatcher:
         pages to a contiguous prefix (byte-identical to a contiguous cache,
         so re-admission — here or after a disk round-trip — resumes
         token-identically)."""
+        self._drain()  # its tokens on the host, its rows written
         st = self._streams[sid]
         if st.status != "running":
             raise ValueError(f"stream {sid} is not running")
@@ -817,6 +878,7 @@ class ContinuousBatcher:
             raise ValueError(f"stream {sid} is not waiting")
         c0 = compile_totals()
         try:
+            self._drain()
             if not self._try_admit(sid):
                 return None
         finally:
@@ -834,6 +896,7 @@ class ContinuousBatcher:
         quantized tiers, fp rows otherwise; split mode gathers the
         per-stage layout). Concatenating every chunk along the row axis
         reproduces :meth:`_gather_state`'s arrays exactly."""
+        self._drain()
         if self.rt is None:
             if self.bcfg.kv_codec != "fp":
                 return self.pool.gather_slot_rows_packed(slot, start, stop)
@@ -853,6 +916,7 @@ class ContinuousBatcher:
         decode pool, or the handoff was abandoned). The prompt's pages
         stay in the staging prefix index, if enabled, for later shared
         prefills."""
+        self._drain()
         st = self._streams.pop(sid)
         if st.status == "running":
             self.pool.release_slot_hold(st.slot)
@@ -863,7 +927,10 @@ class ContinuousBatcher:
 
     def _evict_for_pages(self, needed: int, protect: set) -> bool:
         """Evict youngest-admitted running streams (never ``protect``) until
-        ``needed`` pages are free. Youngest-first keeps old streams' work."""
+        ``needed`` pages are free. Youngest-first keeps old streams' work.
+        The step in flight is read first: a stream it finishes frees its
+        pages and is no victim."""
+        self._drain()
         while self.pool.num_free_pages < needed:
             victims = [st for st in self._streams.values()
                        if st.status == "running" and st.sid not in protect]
@@ -886,6 +953,12 @@ class ContinuousBatcher:
     def _running(self) -> list[Stream]:
         return [self._streams[sid] for sid in self._slot_to_sid.values()]
 
+    def _riders(self) -> list[Stream]:
+        """The running streams the next launch carries: a stream whose last
+        token is in flight keeps its slot until that token is read, and
+        rides no further step."""
+        return [st for st in self._running() if st.t < st.max_new_tokens]
+
     def _grow_writable(self, st: Stream) -> None:
         """Cover this step's write position for ``st`` — allocate growth
         pages AND copy-on-write any shared page the position lands in (the
@@ -906,7 +979,8 @@ class ContinuousBatcher:
             step_fn = self.rt._paged_decode_fns(self.bcfg.num_pages,
                                                 self.bcfg.page_size,
                                                 kv_codec=self.bcfg.kv_codec)
-            return step_fn._cache_size() + _split_sample_jit._cache_size()
+            return (step_fn._cache_size() + _split_sample_jit._cache_size()
+                    + _feed_jit._cache_size())
         return batched_step_cache_size()
 
     def _fold_acc(self, c0: tuple, whole: Optional[obs_phase] = None) -> None:
@@ -924,14 +998,18 @@ class ContinuousBatcher:
         acc.clear()
 
     def step(self) -> int:
-        """Admit what fits, run ONE compiled ragged step over every running
-        slot, commit the sampled tokens. Returns the number of streams that
-        advanced (0 = nothing running and nothing admittable).
+        """Admit what fits, launch ONE compiled ragged step over every slot
+        that rides, then read and commit the step launched a call earlier.
+        Returns the number of streams whose step it launched or, when it
+        launched none, whose tokens it committed (0 = nothing running,
+        nothing in flight and nothing admittable).
 
         The call is the ``batch.step`` span and the ``step_wall_s`` clock,
         whichever way it returns; its six phases (``batch.step.admit`` /
         ``grow`` / ``build`` / ``launch`` / ``sync`` / ``commit``, clocks
-        ``admit_s`` ... ``commit_s``) tile it."""
+        ``admit_s`` ... ``commit_s``) tile it. The first four are step N+1's,
+        the launch this call makes; ``sync`` and ``commit`` are step N's and
+        carry its ``step=``."""
         c0 = compile_totals()
         whole = obs_phase("batch.step", self._acc, "step_wall_s",
                           step=int(self.stats["steps"]),
@@ -958,16 +1036,16 @@ class ContinuousBatcher:
                 self._waiting.popleft()
                 admitted += 1
             ph.set(admitted=admitted)
-            running = self._running()
-        if not running:
-            return 0
-        # every running slot must be able to take this step's token; evict
+            riders = self._riders()
+        if not riders:
+            return self._drain(acc, ph)  # nothing to launch: read what flies
+        # every riding slot must be able to take this step's token; evict
         # youngest streams when the pool can't cover a growth (oldest first
         # keeps them protected longest)
         with obs_phase("batch.step.grow", acc, "grow_s", after=ph,
                        step=step_no) as ph:
             evicted0 = self.stats["evicted"]
-            for st in sorted(running, key=lambda s: s.admit_seq):
+            for st in sorted(riders, key=lambda s: s.admit_seq):
                 if st.status != "running":
                     continue  # already evicted by a predecessor's growth
                 try:
@@ -988,32 +1066,38 @@ class ContinuousBatcher:
                         raise
                     self._grow_writable(st)
             ph.set(evicted=self.stats["evicted"] - evicted0)
-            running = self._running()
-        if not running:
-            return 0
+            riders = self._riders()
+        if not riders:
+            return self._drain(acc, ph)
 
         with obs_phase("batch.step.build", acc, "build_s", after=ph,
                        step=step_no) as ph:
-            if self._watchdog is not None:
-                self._watchdog.arm()
             b = self.bcfg.max_slots
             token_ids = np.zeros((b,), np.int32)
             steps = np.zeros((b,), np.int32)
             temps = np.zeros((b,), np.float32)
             key_data = self._free_key_rows.copy()
-            for st in running:
-                token_ids[st.slot] = st.tokens[-1]
+            for st in riders:
+                # a token in flight is counted and not known: the device
+                # has it, and feeds it
+                token_ids[st.slot] = (IN_FLIGHT if st.pending
+                                      else st.tokens[-1])
                 steps[st.slot] = st.t
                 temps[st.slot] = st.temperature
                 key_data[st.slot] = st.key_data
             # the pool's lengths array is the step's write/mask positions:
             # slot i's cache holds prompt + t-1 fed tokens (== pool lengths
-            # by construction); inactive slots write the trash page
-            page_table, lengths = self.pool.device_tables()
+            # by construction, a launch counts its row); inactive slots write
+            # the trash page, and so does a slot that keeps its stream but
+            # rides no more
+            idle = [st.slot for st in self._running()
+                    if st.t >= st.max_new_tokens]
+            page_table, lengths = self.pool.device_tables(idle)
             misses0 = self._step_cache_size()
             # the pages under each slot's length, the row this step writes
             # included (an idle slot's one trash page)
             reached = self.pool.lengths // self.pool.page_size + 1
+            reached[idle] = 1
             if self.decode_read == PAGE_WALK:
                 # what a layer's attend fetches this step, a page a DMA,
                 # against the table entries a page gather reads whatever
@@ -1029,14 +1113,22 @@ class ContinuousBatcher:
                 acc["window_pages_spanned"] = b * ring
         with obs_phase("batch.step.launch", acc, "launch_s", after=ph,
                        step=step_no) as ph:
+            prev = self._inflight
+            watchdog = None
+            if self.bcfg.step_deadline_s is not None:
+                watchdog = Watchdog(self.bcfg.step_deadline_s)
+                watchdog.arm()
             t0 = time.monotonic()
+            token_ids = jax.device_put(token_ids, self._ids_on)
+            if prev is not None:
+                token_ids = _feed_jit(token_ids, prev.toks)
             if self.rt is not None:
                 # one ragged split step: every cut hops ONE (max_slots, 1, D)
                 # quantized activation block, the sampler is the same
                 # vmapped _batched_sample the local step fuses in
                 logits, self._split_pool = self.rt.decode_step_paged(
                     self.placed, self._split_pool, page_table, lengths,
-                    jnp.asarray(token_ids))
+                    token_ids)
                 toks = _split_sample_jit(
                     logits, jnp.asarray(key_data), jnp.asarray(steps),
                     jnp.asarray(temps))
@@ -1045,8 +1137,8 @@ class ContinuousBatcher:
                  self._expert_tokens) = _batched_window_step_jit(
                     self.cfg, self.params, self.pool.pool,
                     self.pool.window_pool, self._expert_tokens, page_table,
-                    self.pool.device_window_table(), lengths,
-                    jnp.asarray(token_ids), jnp.asarray(key_data),
+                    self.pool.device_window_table(idle), lengths,
+                    token_ids, jnp.asarray(key_data),
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
             elif self.cfg.is_hybrid:
@@ -1059,7 +1151,7 @@ class ContinuousBatcher:
                     _batched_hybrid_step_jit(
                         self.cfg, self.params, k, v, conv, ssm,
                         self._expert_tokens, page_table, lengths,
-                        jnp.asarray(token_ids), jnp.asarray(key_data),
+                        token_ids, jnp.asarray(key_data),
                         jnp.asarray(steps), jnp.asarray(temps),
                         self.bcfg.compute_dtype))
                 self.pool.pool = (type(pool)(k) if v is None
@@ -1070,33 +1162,67 @@ class ContinuousBatcher:
             else:
                 toks, self.pool.pool = _batched_step_jit(
                     self.cfg, self.params, self.pool.pool, page_table,
-                    lengths, jnp.asarray(token_ids), jnp.asarray(key_data),
+                    lengths, token_ids, jnp.asarray(key_data),
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
-        with obs_phase("batch.step.sync", acc, "sync_s", after=ph,
-                       step=step_no) as ph:
-            toks_host = np.asarray(toks)  # ONE host sync per step
-            step_s = time.monotonic() - t0
-
-        with obs_phase("batch.step.commit", acc, "commit_s", after=ph,
-                       step=step_no) as ph:
-            acc["decode_s"] = step_s
-            acc["jit_misses"] = self._step_cache_size() - misses0
+            toks.copy_to_host_async()  # read a call later, already on its way
+            if step_no == 0:
+                # the merge every later launch runs, compiled by the first: a
+                # caller that warmed one step has warmed the steady state
+                _feed_jit(token_ids, toks)
+            # the host counts the token in flight: the next build's step
+            # index, write position and "ends by count" all hold it
+            for st in riders:
+                st.pending += 1
+                self.pool.lengths[st.slot] = self._cache_len(st)
+            self._inflight = _Launched(step_no, toks, riders, t0, watchdog)
             acc["steps"] = 1
+            acc["steps_ahead"] = int(prev is not None)
+            acc["jit_misses"] = self._step_cache_size() - misses0
             if self.cfg.is_hybrid:
                 acc["routed_assignments"] = (
-                    len(running) * self.cfg.experts_per_tok
+                    len(riders) * self.cfg.experts_per_tok
                     * self.cfg.expert_layers)
+            del toks, token_ids, page_table, lengths
+        self._read(prev, acc, ph)
+        return len(riders)
+
+    def _drain(self, acc: Optional[dict] = None,
+               after: Optional[obs_phase] = None) -> int:
+        """Read and commit the step in flight, if one is: today's order,
+        sync then commit. Called by whatever needs every stream's tokens on
+        the host or rows that no step is writing, and by a ``step()`` that
+        has nothing to launch (which hands over its clocks: everywhere else
+        the two spans' time is the enclosing phase's or nobody's). Returns
+        the number of streams it committed a token for."""
+        fl, self._inflight = self._inflight, None
+        return self._read(fl, acc, after)
+
+    def _read(self, fl: Optional[_Launched], acc: Optional[dict] = None,
+              after: Optional[obs_phase] = None) -> int:
+        """``batch.step.sync`` and ``batch.step.commit`` of the launched
+        step ``fl``: wait for its tokens, append each to its stream, retire
+        the streams that have all of theirs."""
+        if fl is None:
+            return 0
+        with obs_phase("batch.step.sync", acc, "sync_s", after=after,
+                       step=fl.step) as ph:
+            toks_host = np.asarray(fl.toks)  # ONE host sync per step
+            # from this step's launch, or the read before it where that came
+            # later, to its read: the steps' times do not overlap
+            now = time.monotonic()
+            self._acc["decode_s"] += now - max(fl.t0, self._read_at)
+            self._read_at = now
+        with obs_phase("batch.step.commit", acc, "commit_s", after=ph,
+                       step=fl.step) as ph:
             finished0 = self.stats["finished"]
-            advanced = 0
-            for st in running:
+            for st in fl.riders:
                 # toks_host is already on host (the single np.asarray sync
                 # above); this int() is numpy scalar unboxing, not a device
                 # sync
+                st.pending -= 1
                 st.tokens.append(int(toks_host[st.slot]))  # graphlint: disable=EG005
-                self.pool.lengths[st.slot] = self._cache_len(st)
-                advanced += 1
-                if st.t >= st.max_new_tokens:
+                if len(st.tokens) >= st.max_new_tokens:
                     self._finish(st)
             ph.set(finished=self.stats["finished"] - finished0)
             # unique_live_tokens counts each physical page once: with prefix
@@ -1110,17 +1236,15 @@ class ContinuousBatcher:
             # batching's worst-case (batch x capacity) reservation
             reserved = (self.pool.num_pages - 1
                         - self.pool.num_free_pages) * self.pool.page_size
-            acc["occ_sum"] = occ
-            acc["slot_sum"] = len(self._slot_to_sid) / b
+            self._acc["occ_sum"] += occ
+            self._acc["slot_sum"] += (len(self._slot_to_sid)
+                                      / self.bcfg.max_slots)
             if reserved:
-                acc["alloc_sum"] = live / reserved
-                acc["alloc_n"] = 1
-            if self._watchdog is not None:
-                self._watchdog.check()
-            # drop the step's device handles on commit's clock: left to the
-            # return it would be time no phase owns
-            del toks, page_table, lengths
-        return advanced
+                self._acc["alloc_sum"] += live / reserved
+                self._acc["alloc_n"] += 1
+            if fl.watchdog is not None:
+                fl.watchdog.check()
+        return len(fl.riders)
 
     def run(self, max_steps: int = 100_000) -> dict[int, np.ndarray]:
         """Drive :meth:`step` until every submitted stream finished."""
@@ -1192,6 +1316,7 @@ class ContinuousBatcher:
         pool geometry whose span covers it (the payload is the contiguous
         prefix, not pages)."""
         refuse_beyond_kv_rows(self.cfg, "checkpoint_stream")
+        self._drain()  # the snapshot's tokens and rows are the same step's
         st = self._streams[sid]
         if st.status == "running":
             state = self._gather_state(st.slot)
@@ -1247,6 +1372,7 @@ class ContinuousBatcher:
         bit-identical to the uninterrupted run (per-step keys depend only on
         the seed and the step index, the KV prefix is restored bit-exactly)."""
         refuse_beyond_kv_rows(self.cfg, "restore_stream")
+        self._drain()
         ckpt = DecodeCheckpoint.load(path)
         meta = ckpt.meta
         if meta.get("mode") != self._ckpt_mode():
@@ -1318,6 +1444,8 @@ class ContinuousBatcher:
             "evicted": stats["evicted"],
             "jit_misses": stats["jit_misses"],
             "prefill_s": stats["prefill_s"],
+            # launch to read of every step, counted from the read before it
+            # where that came later: one step's seconds never hold another's
             "decode_s": dec,
             # all additive, so report1 - report0 is a window's worth: every
             # step() call entry to return, its six phases, the waiting-queue
@@ -1331,6 +1459,11 @@ class ContinuousBatcher:
             # token 0 on the host to the return of the call that admitted it
             **{k: stats[k] for k in _CLOCKS},
             "admit_steps": stats["admit_steps"],
+            # the launched steps whose predecessor's tokens were still unread
+            # at the launch: the device had the next step before the host had
+            # this one's tokens (additive; over ``steps``, the share of
+            # launches that ran ahead)
+            "steps_ahead": stats["steps_ahead"],
             # prompt positions admissions prefilled, in prefill_s: a prefix
             # hit's matched positions and a resume's rows are not among them
             "prefill_tokens": stats["prefill_tokens"],

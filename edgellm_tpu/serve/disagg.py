@@ -877,7 +877,10 @@ class DisaggServer:
             pass
         old = self.decode
         ckpt_dir = self.bcfg.checkpoint_dir
-        # harvest finished results before the worker state is torn down
+        # harvest finished results before the worker state is torn down: the
+        # step in flight is read first, so a stream it finishes is collected
+        # and the rest are snapshot with every token they were counted for
+        old._drain()
         self._collect()
         saved, replay, fresh = {}, [], []
         for dsid, our in list(self._by_decode.items()):

@@ -265,7 +265,9 @@ def test_ragged_mixed_lengths_bit_identical_to_generate(params):
                                 s["temp"], s["seed"]))
     rep = bat.report()
     assert rep["finished"] == 3 and rep["evicted"] == 0
-    assert rep["jit_misses"] <= 1  # at most the one warmup compile
+    # at most the warm-up compiles: the step, and the merge ahead of it that
+    # feeds a slot the token still in flight
+    assert rep["jit_misses"] <= 2
 
 
 @pytest.mark.parametrize("read", [paged_kv.PAGE_GATHER, paged_kv.PAGE_WALK])
@@ -288,12 +290,14 @@ def test_report_counts_the_pages_a_page_walk_fetches(params, read):
         if not bat.step():
             break
         # the lengths the step was launched with: each running stream's
-        # cache before this step's token, 0 for the two idle slots
+        # cache before this step's token, 0 for the two idle slots. The last
+        # call launches nothing: it reads the step in flight
         after = bat.report()
-        spanned += BCFG.max_slots * BCFG.pages_per_slot
+        launched = after["steps"] - before["steps"]
+        spanned += launched * BCFG.max_slots * BCFG.pages_per_slot
         assert (after["attend_pages_spanned"]
                 - before["attend_pages_spanned"]) == (
-                    BCFG.max_slots * BCFG.pages_per_slot
+                    launched * BCFG.max_slots * BCFG.pages_per_slot
                     if read == paged_kv.PAGE_WALK else 0)
         walked += after["attend_pages_walked"] - before["attend_pages_walked"]
     rep = bat.report()
@@ -306,7 +310,9 @@ def test_report_counts_the_pages_a_page_walk_fetches(params, read):
     steps = rep["steps"]
     assert steps == 11
     lens1 = list(range(7, 18))
-    lens2 = [16, 17] + [0] * 9          # its slot is idle once it finished
+    # its slot is idle once its last token is launched: kept for one more
+    # step, until that token is read, and handed to the step as a free one
+    lens2 = [16, 17] + [0] * 9
     want = sum(n // 8 + 1 for n in lens1) + sum(n // 8 + 1 for n in lens2) \
         + 2 * steps                      # two slots never held a stream
     assert rep["attend_pages_walked"] == walked == want

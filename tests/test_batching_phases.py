@@ -3,8 +3,10 @@ host-clock counters they keep, and the named scopes of the device side.
 
 What is held here, on the CPU with a toy model (local pool and, over a
 2-stage CPU mesh, the split runtime): the six phase clocks tile
-``step_wall_s``; ``launch_s + sync_s`` is ``decode_s`` but for two clock
-readings a step; ``prefill_s`` lies inside ``admit_s``; ``queue_wait_s`` is
+``step_wall_s``, the first four of the step a call launches and the last two
+of the step it reads, launched a call earlier; ``decode_s`` is every step's
+launch to read with no second counted twice; ``prefill_s`` lies inside
+``admit_s``; ``queue_wait_s`` is
 the time a stream was held out; ``compiles`` sees a prefill's compile that
 ``jit_misses`` is blind to; spans nest by step and by stream when the tracer
 is on and nothing is recorded when it is off; tokens do not depend on the
@@ -111,25 +113,37 @@ def test_six_phases_tile_step_wall(make):
     assert six == pytest.approx(r["step_wall_s"], rel=0.01)
 
 
-def test_launch_plus_sync_is_decode_s_and_prefill_lies_in_admit(make):
+def test_decode_s_is_launch_to_read_once_and_prefill_lies_in_admit(make):
     b, _ = _run(make)
     r = b.report()
-    both = r["launch_s"] + r["sync_s"]
-    # decode_s starts after launch's annotation is entered and stops before
-    # sync's is left: two clock readings a step apart, never more
-    assert r["decode_s"] <= both
-    assert both - r["decode_s"] < 2e-3 * r["steps"]
+    # a step's seconds run from its launch, or from the read before it where
+    # that came later, to its read: every wait in sync lies inside them (but
+    # for the clock reading that closes the span), and no second is counted
+    # for two steps, so they fit in the calls and the caller's time between
+    assert r["sync_s"] - r["decode_s"] < 2e-3 * r["steps"]
+    assert r["launch_s"] < r["decode_s"]
+    assert r["decode_s"] <= r["step_wall_s"] + r["between_s"] + 2e-3 * r[
+        "steps"]
     assert 0 < r["prefill_s"] <= r["admit_s"]
     assert r["admitted"] == 6 and r["finished"] == 6
 
 
 def _two_reports(make):
+    """A window in which every call launched a step: from the first call's
+    return to where nothing is left to launch (one step is still in flight
+    there, and the call that reads it launches nothing)."""
     b = make()
     _traffic(b)
     b.step()
     r0 = b.report()
+    calls = 0
+    while b._riders() or b._waiting:
+        b.step()
+        calls += 1
+    r1 = b.report()
+    assert r1["steps"] - r0["steps"] == calls and b._inflight is not None
     b.run()
-    return r0, b.report()
+    return r0, r1
 
 
 def _hist_delta(r0, r1):
@@ -345,12 +359,30 @@ def test_a_long_step_lands_in_its_row_with_its_phase_columns(
 
 
 def test_table_rows_sum_to_steps_and_to_the_six_clocks(make):
-    b, _ = _run(make)
+    b = make()
+    _traffic(b)
+    launched = dict.fromkeys(PHASES, 0.0)
+    reads_only = 0
+    while b._waiting or b._slot_to_sid:
+        r0 = b.report()
+        assert b.step() > 0
+        r1 = b.report()
+        if r1["steps"] > r0["steps"]:
+            for k in PHASES:
+                launched[k] += r1[k] - r0[k]
+        else:
+            # a call that launched nothing and read the step in flight is on
+            # the clocks and not in the table
+            reads_only += 1
+            assert r1["sync_s"] > r0["sync_s"]
+            assert r1["step_wall_hist"] == r0["step_wall_hist"]
     r = b.report()
     rows = r["step_wall_hist"]
+    assert reads_only >= 1 and r["finished"] == 6
     assert sum(row[0] for row in rows) == r["steps"] >= 8
     for i, k in enumerate(PHASES, 1):
-        assert sum(row[i] for row in rows) == pytest.approx(r[k], rel=1e-9)
+        assert sum(row[i] for row in rows) == pytest.approx(launched[k],
+                                                            rel=1e-9)
     # a call that finds nothing to run is on the clocks and not in the table
     assert b.step() == 0
     r1 = b.report()
@@ -542,14 +574,30 @@ def test_spans_nest_by_step_and_by_stream_when_the_tracer_is_on(make):
     by_name = {}
     for s in spans:
         by_name.setdefault(s.name, []).append(s)
-    steps = by_name["batch.step"]
-    assert len(steps) == b.report()["steps"]    # every call launched here
-    assert [s.args["step"] for s in steps] == list(range(len(steps)))
+    calls = by_name["batch.step"]
+    steps = b.report()["steps"]
+    # every call but the last launched a step, and carries its index; the
+    # last one read the step in flight and launched nothing
+    assert len(calls) == steps + 1
+    assert [s.args["step"] for s in calls] == list(range(steps)) + [steps]
+
+    def call_of(span):
+        inside = [i for i, c in enumerate(calls) if _encloses(c, span)]
+        assert len(inside) == 1, (span.name, span.args)
+        return inside[0]
+
     for name in STEP_SPANS:
-        assert len(by_name[name]) == len(steps), name
+        # the admit of the last call, which found nothing to launch
+        extra = 1 if name == "batch.step.admit" else 0
+        assert len(by_name[name]) == steps + extra, name
+    for name in STEP_SPANS[:4]:                 # the launch's own phases
         for s in by_name[name]:
-            parent = steps[s.args["step"]]
-            assert _encloses(parent, s), (name, s.args)
+            assert call_of(s) == s.args["step"], (name, s.args)
+    for name in STEP_SPANS[4:]:                 # the read, a call later
+        assert sorted(s.args["step"] for s in by_name[name]) == list(
+            range(steps))
+        for s in by_name[name]:
+            assert call_of(s) == s.args["step"] + 1, (name, s.args)
     admits = {s.args["sid"]: s for s in by_name["batch.admit"]}
     assert sorted(admits) == list(range(6))
     for s in admits.values():

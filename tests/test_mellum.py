@@ -424,7 +424,7 @@ def test_window_layers_hold_a_ring_however_long_the_stream_grows(
         assert pool.pool.k.shape == (2, 121, 4, 32)
         sid = b.submit(_ids(9, 2), 100, rng_seed=1)
         seen = []
-        for _ in range(99):
+        for _ in range(100):  # 99 launches, and the read of the last one
             b.step()
             pool.check_invariants()
             seen.append((len(pool._slot_pages[0]),

@@ -439,8 +439,40 @@ def test_the_longcat_cell_lists_its_readers_and_the_ones_it_joins():
         "gap_mean_ms", "setup_s"}
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
         spec = json.load(f)
-    assert [m["name"] for m in spec["per_layer"][-4:]] == list(
-        LONGCAT_READERS)
+    names = [m["name"] for m in spec["per_layer"]]
+    at = names.index(LONGCAT_READERS[0])
+    assert names[at:at + 4] == list(LONGCAT_READERS)
     assert spec["workloads"][-1]["name"] == LONGCAT_CELL
     assert len(spec["workloads"]) == 8
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
+
+
+# ---------------------------------------------------------------------------
+# PR 42: the share of the launches that ran ahead of the host's read
+# ---------------------------------------------------------------------------
+
+
+def test_launch_ahead_share_is_the_windows_steps_ahead_over_its_steps():
+    r0 = {"steps": 40, "steps_ahead": 30}
+    r1 = {"steps": 240, "steps_ahead": 228}
+    assert _read("launch_ahead_share", _record(False, r0, r1)) == \
+        pytest.approx(99.0)
+    # a window that launched nothing divides by nothing
+    assert _read("launch_ahead_share", _record(False, r0, dict(r0))) is None
+    # the parent's report() has no such counter: nothing, and no exception
+    assert _read("launch_ahead_share", _record(
+        False, {"steps": 40}, {"steps": 240})) is None
+
+
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_every_cell_lists_launch_ahead_share(cell):
+    per_layer = {m.name: m for m in load_cell(cell).per_layer}
+    assert per_layer["launch_ahead_share"].unit == "%"
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    (entry,) = [m for m in spec["per_layer"]
+                if m["name"] == "launch_ahead_share"]
+    assert (entry["layer"], entry["source"], entry["better"],
+            entry["moves"]) == ("batcher", "program_counter", "higher",
+                                "gap_mean_ms")
+    assert cell in entry["workloads"]
