@@ -13,8 +13,8 @@ Stdout contract: the FINAL line is one compact headline JSON object
 {"metric", "value", "unit", "vs_baseline", ...} where vs_baseline > 1 means
 faster than the reference's s/chunk on its hardware, plus observability
 fields: tokens_per_s (scored tokens), model_tflops_per_s, mfu, and (on TPU)
-mfu_vs_measured/relevance anchors. Verbose blocks (pallas probe, relevance
-detail, flop accounting) are printed as a separate {"detail": ...} line
+mfu_vs_measured/relevance anchors. Verbose blocks (relevance detail,
+flop accounting) are printed as a separate {"detail": ...} line
 BEFORE it and written to BENCH_DETAIL.json (BENCH_DETAIL_PATH overrides) —
 the driver's tail capture truncates giant lines, so the headline must stay
 small and last.
@@ -25,9 +25,8 @@ anchor), BENCH_CHUNKS (default 96), BENCH_WINDOW_BATCH (default 64 — batches
 evaluation windows into one executable to feed the MXU; OOM backs off by
 halving instead of dying), BENCH_DTYPE (float32|bfloat16, default bfloat16),
 BENCH_MEASURE_PEAK (default 1 on TPU: also measure the chip's
-achievable bf16 matmul ceiling and report mfu_vs_measured), BENCH_PALLAS
-(default 1 on TPU: append the on-silicon Pallas codec parity+throughput
-block), BENCH_RELEVANCE (default 1 on TPU: append LRP head-relevance
+achievable bf16 matmul ceiling and report mfu_vs_measured),
+BENCH_RELEVANCE (default 1 on TPU: append LRP head-relevance
 extraction throughput, reference anchor 2.1 it/s), BENCH_REL_CHUNKS
 (default 24), BENCH_REL_WINDOW_BATCH (requested relevance batch, preflighted
 down to fit the device's reported memory limit, default 16). ``mfu`` is
@@ -211,18 +210,6 @@ BENCH_KVQ_TOKENS (default 8), BENCH_KVQ_SLOTS (default 6),
 BENCH_KVQ_PAGE_SIZE (default 8), BENCH_KVQ_POOL_BYTES, BENCH_KVQ_PPL_*
 (WINDOW/STRIDE/CHUNKS/BATCH), BENCH_KVQ_SEED, plus the shared BENCH_MODEL
 / BENCH_DTYPE.
-
-BENCH_WIRE=1 switches to the fused boundary-hop workload (see
-``wire_main``): every FUSED_CAPABLE codec crosses a real 2-stage boundary
-through the fused single-buffer wire hop AND the separate
-encode/ppermute/decode ladder; the receiver rows must be bit-identical,
-and on TPU the fused-vs-fallback roundtrip ratio is timed and recorded to
-the probe cache under ``fused_hop:<codec>`` (the measurement the plan gate
-requires — and the artifact asserts no codec that WOULD be substituted
-into the default path times slower than its jnp ladder, so a regressed
-kernel is demoted before serving ever reuses it). Knobs: BENCH_WIRE_BATCH
-/ BENCH_WIRE_SEQ / BENCH_WIRE_DIM (default 8x512x896), BENCH_WIRE_ITERS
-(default 20).
 
 BENCH_SPEC=1 switches to the speculative split-decode workload (see
 ``spec_main``): vanilla ``generate_split`` (one boundary hop per token) vs
@@ -2704,170 +2691,11 @@ def main():
         return prefix_main()
     if os.environ.get("BENCH_KVQ") == "1":
         return kvq_main()
-    if os.environ.get("BENCH_WIRE") == "1":
-        return wire_main()
     if os.environ.get("BENCH_SPEC") == "1":
         return spec_main()
     if os.environ.get("BENCH_PIPE") == "1":
         return pipe_main()
     return sweep_main()
-
-
-def wire_main():
-    """BENCH_WIRE=1: the fused boundary-hop workload.
-
-    For every FUSED_CAPABLE base codec, cross a real 2-stage boundary both
-    ways — the fused wire hop (encode -> seal -> ONE flat uint8 ppermute ->
-    verify -> decode, ``codecs.pallas_kernels.fused_wire_hop``) and the
-    separate encode/per-leaf-ppermute/decode ladder the pre-fusion runtime
-    traces — and assert the receiver's activations are BIT-identical
-    (``fused_equals_fallback``; the wire format adds an 8-byte seal, never a
-    different value). On TPU the roundtrips are timed (pre-warmed jits,
-    interleaved) and the fused-vs-fallback ratio lands in the probe cache
-    under ``fused_hop:<base>`` — the measurement :func:`fused_hop_plan`'s
-    default gate requires before it ever fuses a hop. Off-TPU the rows carry
-    ``timing_skipped`` (hop timing off-chip is noise) but still record the
-    parity verdict, ``default_substituted``, and the current probe-cache
-    decision, so every artifact documents WHY the default path did or did
-    not fuse. Knobs: BENCH_WIRE_BATCH/SEQ/DIM (default 8x512x896),
-    BENCH_WIRE_ITERS (default 20)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from edgellm_tpu.codecs import probe_cache
-    from edgellm_tpu.codecs.packing import get_wire_codec
-    from edgellm_tpu.codecs.pallas_kernels import (FUSED_CAPABLE,
-                                                  REMOTE_CAPABLE,
-                                                  default_substituted,
-                                                  fused_hop_plan,
-                                                  fused_wire_hop)
-    from edgellm_tpu.codecs.wire_format import WireFormat
-    from edgellm_tpu.parallel import make_stage_mesh
-    from jax import shard_map
-    from edgellm_tpu.utils.profiling import timed
-
-    backend = jax.default_backend()
-    on_tpu = backend == "tpu"
-    batch = int(os.environ.get("BENCH_WIRE_BATCH", "8"))
-    seq = int(os.environ.get("BENCH_WIRE_SEQ", "512"))
-    dim = int(os.environ.get("BENCH_WIRE_DIM", "896"))
-    iters = int(os.environ.get("BENCH_WIRE_ITERS", "20"))
-
-    if len(jax.devices()) < 2:
-        line = {"metric": "fused boundary hop", "value": None, "unit": None,
-                "vs_baseline": None, "status": "needs_2_devices",
-                "section": "wire"}
-        _emit(line, {"status": "needs_2_devices", "section": "wire"})
-        return 0
-
-    mesh = make_stage_mesh(2)
-    rng = np.random.default_rng(0)
-    hidden = jnp.asarray(rng.standard_normal((batch, seq, dim)),
-                         jnp.float32)
-    stacked = jnp.broadcast_to(hidden[None], (2,) + hidden.shape)
-
-    def hop_fns(codec):
-        """(fused, fallback) jitted 0->1 hops over the 2-stage mesh; both
-        return the stacked per-stage rows so nothing is DCE'd."""
-        def fused_body(h):
-            idx = jax.lax.axis_index("stage")
-            return fused_wire_hop(codec, h[0], 0, "stage", idx)[None]
-
-        def plain_body(h):
-            idx = jax.lax.axis_index("stage")
-            mine = h[0]
-            payload = codec.encode(mine)
-            moved = jax.tree_util.tree_map(
-                lambda a: jax.lax.ppermute(a, "stage", [(0, 1)]), payload)
-            dec = codec.decode(moved).astype(mine.dtype)
-            return jnp.where(idx == 1, dec, mine)[None]
-
-        mk = lambda body: jax.jit(shard_map(
-            body, mesh=mesh, in_specs=P("stage"), out_specs=P("stage"),
-            check_vma=False))
-        return mk(fused_body), mk(plain_body)
-
-    rows, cache_rows = [], []
-    for base in sorted(FUSED_CAPABLE):
-        codec = get_wire_codec(base)
-        wf = WireFormat.for_codec(codec, hidden.shape, hidden.dtype)
-        fused_fn, plain_fn = hop_fns(codec)
-        # pre-warm BOTH jits before any timing (the BENCH_SOAK trick: the
-        # first call pays compile, and a compile inside a timed window would
-        # gift the other side a phantom speedup)
-        out_f = np.asarray(jax.block_until_ready(fused_fn(stacked)))
-        out_p = np.asarray(jax.block_until_ready(plain_fn(stacked)))
-        row = {
-            "codec": base,
-            "backend": backend,
-            "shape": [batch, seq, dim],
-            "wire_bytes": wf.wire_nbytes,
-            "payload_bytes": wf.payload_nbytes,
-            "default_substituted": default_substituted(base),
-            "remote_capable": base in REMOTE_CAPABLE,
-            "fused_equals_fallback": bool(np.array_equal(out_f, out_p)),
-        }
-        plan = fused_hop_plan(codec)
-        row["fused_plan"] = (None if plan is None
-                             else {"mode": plan.mode, "reason": plan.reason})
-        if on_tpu:
-            sec_f, _ = timed(fused_fn, stacked, warmup=2, iters=iters)
-            sec_p, _ = timed(plain_fn, stacked, warmup=2, iters=iters)
-            ratio = sec_p / sec_f
-            row["fused_us"] = round(sec_f * 1e6, 1)
-            row["fallback_us"] = round(sec_p * 1e6, 1)
-            row["roundtrip_speedup_vs_jnp"] = round(ratio, 2)
-            # unrounded: WIN_MARGIN hysteresis must never see a rounded value
-            row["roundtrip_speedup_vs_jnp_raw"] = ratio
-            cache_rows.append({"codec": f"fused_hop:{base}",
-                               "roundtrip_speedup_vs_jnp_raw": ratio})
-        else:
-            row["timing_skipped"] = (f"backend {backend!r}: hop timing is "
-                                     "only meaningful on TPU")
-        rows.append(row)
-
-    cache_path = probe_cache.record(cache_rows) if cache_rows else None
-    for row in rows:
-        # the decision the NEXT runtime build will read for this codec: the
-        # win/loss verdict (post-record, so a fresh TPU measurement is
-        # reflected) plus the margin it was judged against
-        row["probe_decision"] = {
-            "measured_win": probe_cache.measured_win(
-                f"fused_hop:{row['codec']}"),
-            "win_margin": probe_cache.WIN_MARGIN,
-        }
-
-    n_parity = sum(r["fused_equals_fallback"] for r in rows)
-    speedups = [r["roundtrip_speedup_vs_jnp_raw"] for r in rows
-                if "roundtrip_speedup_vs_jnp_raw" in r]
-    # the kernel family must earn its keep: a codec the default path WOULD
-    # substitute (frozen win set or probed win) that times slower than its
-    # jnp ladder is a regression — demote it (drop it from the win set or
-    # let the probe cache record the loss) before serving reuses the kernel
-    slow_defaults = [r["codec"] for r in rows
-                     if r.get("default_substituted")
-                     and r.get("roundtrip_speedup_vs_jnp_raw", 1.0) < 1.0]
-    detail = {"section": "wire", "backend": backend, "codecs": rows,
-              "probe_cache_path": cache_path}
-    if speedups:
-        line = {"metric": "fused hop min speedup vs separate ladder",
-                "value": round(min(speedups), 3), "unit": "x",
-                "vs_baseline": None, "section": "wire",
-                "parity": f"{n_parity}/{len(rows)}",
-                "slow_default_codecs": slow_defaults}
-    else:
-        line = {"metric": "fused hop parity (timing skipped off-TPU)",
-                "value": n_parity, "unit": f"of {len(rows)} codecs",
-                "vs_baseline": None, "section": "wire",
-                "slow_default_codecs": slow_defaults}
-    _emit(line, detail)
-    assert n_parity == len(rows), \
-        [r["codec"] for r in rows if not r["fused_equals_fallback"]]
-    assert not slow_defaults, \
-        (f"default-substituted codec(s) timed slower than the jnp ladder: "
-         f"{slow_defaults} — demote before serving reuses the kernel")
-    return 0
 
 
 def sweep_main():
@@ -2987,7 +2815,7 @@ def sweep_main():
     }
     if peak_tflops is not None:
         line["mfu"] = round(tflops_per_s / peak_tflops, 4)
-    # verbose blocks (pallas probe, relevance detail, flop accounting) go to a
+    # verbose blocks (relevance detail, flop accounting) go to a
     # sidecar + an EARLIER stdout line: the driver's tail capture must always
     # land on the compact headline as the FINAL line (round-3's artifact lost
     # its headline to a single giant JSON line)
@@ -3031,13 +2859,6 @@ def sweep_main():
         # ITS workload shape — same guard as vs_baseline above
         if (model_name, max_length, stride) == ("qwen2-0.5b", 512, 32):
             line["relevance_vs_baseline"] = round(rel_stats["it_per_s"] / 2.1, 2)
-
-    # on-silicon proof of the Pallas codec substitution path:
-    # every *_pallas wire codec executed on the real backend, parity + GB/s
-    if on_tpu and os.environ.get("BENCH_PALLAS", "1") != "0":
-        from edgellm_tpu.tools.pallas_probe import probe_all
-
-        detail["pallas"] = probe_all()
 
     # silicon record of the attention-kernel wins at the envelope-extension
     # shapes: the reference's own Pythia window (S=2048) and

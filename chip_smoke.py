@@ -44,9 +44,6 @@ SWEEP_PARAMS = os.path.join(ROOT, "configs", "qwen_baseline_table.json")
 SWEEP_CHUNKS, SWEEP_WINDOW_BATCH = 4, 8
 SPLIT = {"cuts": [5, 11, 17],
          "hop_codecs": ["int8_per_token", "int4_per_token", "int8_per_token"]}
-#: env switches that force a dispatch; the smoke runs the default one
-FORCING_ENV = ("EDGELLM_ATTN", "EDGELLM_PALLAS", "EDGELLM_FUSED_HOP",
-               "EDGELLM_PROBE_ALL")
 #: |system - reference| bound on logits, both sides at
 #: default_matmul_precision("highest"): fp32 rounding order through 24 layers
 #: measured 1.7e-6 on logits of std 0.6 (first v5e run); a single-pass bf16
@@ -73,35 +70,29 @@ def serve_params(prompt_len: int, *, batching: dict = BATCHING,
 
 
 def env_phase() -> dict:
-    """Versions, device stamp, cache locations — and the conditions the rest
-    of the smoke stands on: default dispatch, compiled (not interpreted)
-    kernels."""
+    """Versions, device stamp, cache locations — and the condition the rest
+    of the smoke stands on: compiled (not interpreted) kernels."""
     from importlib import metadata
 
     import jax
     import jaxlib
 
-    from edgellm_tpu.codecs import pallas_kernels, probe_cache
+    from edgellm_tpu.codecs import pallas_kernels
     from edgellm_tpu.models import flash_attention
     from edgellm_tpu.utils.startup import (configure_compile_cache,
                                            device_stamp)
 
-    forced = {k: os.environ[k] for k in FORCING_ENV if k in os.environ}
-    assert not forced, f"the smoke runs the default dispatch; unset {forced}"
     assert not flash_attention._use_interpret(), \
         "attention kernels would run in interpret mode"
     assert not pallas_kernels._use_interpret(), \
         "codec kernels would run in interpret mode"
     cache_dir = configure_compile_cache()
-    probe_path = probe_cache._cache_path()
     return {**device_stamp(),
             "jax": jax.__version__, "jaxlib": jaxlib.__version__,
             "libtpu": metadata.version("libtpu"),
             "compile_cache_dir": cache_dir,
             "compile_cache_entries_at_start": (
                 len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0),
-            "probe_cache_path": probe_path,
-            "probe_cache_has_entries": probe_cache.load_speedups() is not None,
             "interpret": False}
 
 
@@ -136,13 +127,11 @@ def dispatch_phase(cfg, param_dtype, *, prompt_lens=PROMPT_LENS,
                                         .cache_dtype)))})
     out = {"param_dtype": jnp.dtype(param_dtype).name, "attention": sites}
     if split is not None:
-        from edgellm_tpu.codecs.pallas_kernels import fused_hop_plan
         from edgellm_tpu.parallel.split import apply_default_codec_backend
 
         codecs = apply_default_codec_backend(list(split["hop_codecs"]))
         out["hop_codecs"] = [
-            {"cut": cut, "asked": name, "dispatched": c.name,
-             "fused_hop_plan": fused_hop_plan(c)}
+            {"cut": cut, "asked": name, "dispatched": c.name}
             for cut, name, c in zip(split["cuts"], split["hop_codecs"],
                                     codecs)]
     return out
@@ -175,10 +164,8 @@ def kernels_phase(cfg, dispatch: dict, *, hop_shapes=()) -> dict:
             attention.append({"site": site["site"], **parity_shape(
                 b, h, kv, site["seq"], hd, dtype=dtype, stats=stats,
                 plan=site["plan"])})
-    # timing=False never writes the probe cache: the smoke leaves the
-    # dispatch policy as it found it
-    codecs = [probe_all(timing=False, dim=cfg.hidden_size)]
-    codecs += [probe_all(timing=False, batch=b, seq=s, dim=cfg.hidden_size)
+    codecs = [probe_all(dim=cfg.hidden_size)]
+    codecs += [probe_all(batch=b, seq=s, dim=cfg.hidden_size)
                for b, s in hop_shapes]
     return {"attention": attention,
             "codecs": [{"shape": blk["shape"], "interpret": blk["interpret"],

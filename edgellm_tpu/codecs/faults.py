@@ -43,9 +43,8 @@ import jax
 import jax.numpy as jnp
 
 from ..lint import graph_contract
-# the wire primitives (canary + checksum seal, byte accounting) moved to
-# wire_format.py so the fused hops share the exact byte layout; re-exported
-# here verbatim — every existing import path and traced graph is unchanged
+# the wire primitives (canary + checksum seal, byte accounting) live in
+# wire_format.py; re-exported here verbatim
 from .wire_format import (CANARY, _CRC_MULT, _leaf_crc,  # noqa: F401
                           payload_checksum, seal_payload, tree_nbytes,
                           verify_payload)

@@ -53,8 +53,8 @@ from ..utils.clock import MONOTONIC, Clock
 from ..utils.concurrency import guarded_by
 from .faults import (_CRC_MULT, _bump, inject_faults, seal_payload,
                      tree_nbytes, verify_payload)
-# the byte-stream flatten/unflatten moved to wire_format.py (the fused hops
-# cross the same flat layout); aliased to the historical private names
+# the byte-stream flatten/unflatten live in wire_format.py; aliased to the
+# historical private names
 from .wire_format import flatten_bytes as _flatten_bytes
 from .wire_format import unflatten_bytes as _unflatten_bytes
 

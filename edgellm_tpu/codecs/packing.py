@@ -394,9 +394,9 @@ def _pallas(base_name: str) -> Callable[[], WireCodec]:
     module, so the import must happen at call time)."""
 
     def factory() -> WireCodec:
-        from .pallas_kernels import pallas_variant
+        from .pallas_kernels import pallas_twin
 
-        return pallas_variant(get_wire_codec(base_name))
+        return pallas_twin(base_name)
 
     return factory
 
@@ -404,8 +404,9 @@ def _pallas(base_name: str) -> Callable[[], WireCodec]:
 def get_wire_codec(name: str) -> WireCodec:
     """Codec registry. Names map to the reference's boundary compression schemes
     (fp16 is its notional uncompressed transfer baseline, BASELINE.md). The
-    ``*_pallas`` names select the fused TPU kernel implementation explicitly;
-    on TPU the split runtime substitutes them for the jnp twins automatically."""
+    ``*_pallas`` names are the kernel twins as codecs of their own; on a TPU
+    the split runtime runs a base codec as its twin where one exists
+    (``pallas_kernels.pallas_variant``)."""
     # identity codecs, selective_int4, and the Pallas twins sanitize inside
     # their own factories (dtype-specific bounds / shared twin path); the
     # quantizing jnp codecs are wrapped here
@@ -422,8 +423,6 @@ def get_wire_codec(name: str) -> WireCodec:
         "ternary_max": lambda: _saturating(_ternary("max")),
         "ternary_per_token": lambda: _saturating(_ternary_per_token()),
         "int4_per_token_pallas": _pallas("int4_per_token"),
-        "int8_per_token_pallas": _pallas("int8_per_token"),
-        "int8_per_channel_pallas": _pallas("int8_per_channel"),
         "int4_per_channel_pallas": _pallas("int4_per_channel"),
         "ternary_mean_pallas": _pallas("ternary_mean"),
         "ternary_max_pallas": _pallas("ternary_max"),
@@ -436,6 +435,5 @@ def get_wire_codec(name: str) -> WireCodec:
 WIRE_CODECS = ("fp32", "bf16", "fp16", "int8_per_token", "int8_per_channel",
                "int4_global", "int4_per_token", "int4_per_channel",
                "ternary_mean", "ternary_max", "ternary_per_token",
-               "int4_per_token_pallas", "int8_per_token_pallas",
-               "int8_per_channel_pallas", "int4_per_channel_pallas",
+               "int4_per_token_pallas", "int4_per_channel_pallas",
                "ternary_mean_pallas", "ternary_max_pallas")

@@ -1,38 +1,29 @@
 """The boundary wire format: what bytes actually cross a cut.
 
-Before the fused-hop work, "what crosses the wire" was a property of the hop
-*implementation*: :mod:`~edgellm_tpu.codecs.faults` owned the canary/checksum
-seal, :mod:`~edgellm_tpu.codecs.fec` owned the byte-stream flattening, and a
-fused transport would have had to re-invent both. This module hoists the wire
-layout into one place so every hop implementation — the separate
-encode/``ppermute``/decode ladder, the faulty link, FEC parity framing, and
-the fused single-buffer/remote-DMA hops — moves the *same bytes* in the *same
-order*:
+The wire layout lives in one place so every hop implementation — the
+separate encode/``ppermute``/decode ladder, the faulty link, FEC parity
+framing — moves the *same bytes* in the *same order*:
 
 - :func:`seal_payload` / :func:`verify_payload` / :func:`payload_checksum`:
   the 8-byte integrity sidecar (canary word + weighted-byte checksum) sealed
   next to every payload pytree. The per-byte weights are odd
   (``(2i+1) * Knuth``), and an odd weight is invertible mod 2**32 — so any
   single corrupted byte always changes the sum; a dropped payload zeroes the
-  canary. (Moved verbatim from ``codecs.faults``, which re-exports them; the
-  traced graphs are unchanged.)
+  canary. (``codecs.faults`` re-exports them.)
 - :func:`flatten_bytes` / :func:`unflatten_bytes`: every leaf's bytes
   bitcast to uint8 and concatenated in tree-flatten order, and the inverse
   against a template tree (static slices — shapes/dtypes are trace-time
-  constants). Promoted from ``codecs.fec``'s private helpers; FEC chunking
-  and the fused flat-buffer hop now share one byte order by construction.
+  constants): the byte order FEC chunking stands on.
 - :class:`WireFormat`: the layout of one hop's flat wire buffer for a given
   (codec, activation shape): ``[canary u32][crc u32][payload leaves in
   tree-flatten order]``, with static byte accounting (``wire_nbytes ==
-  payload bytes + 8``) that the graphlint wire-byte contracts check against
-  the traced ``ppermute`` traffic.
+  payload bytes + 8``).
 
 Because the seal word, checksum, and byte order live here, fault injection
 (:func:`~edgellm_tpu.codecs.faults.inject_faults` corrupting the flat
-buffer), FEC repair (chunking the same stream), hedging, and the fused
-remote-copy kernel all interoperate: a fused hop's wire buffer round-trips
-through ``WireFormat.from_wire`` into the exact sealed tree the unfused
-ladder would have built.
+buffer), FEC repair (chunking the same stream) and hedging interoperate: a
+flat buffer round-trips through ``WireFormat.from_wire`` into the exact
+sealed tree the ladder builds.
 """
 from __future__ import annotations
 
@@ -129,9 +120,7 @@ class WireFormat:
 
     ``sealed_spec`` is the abstract sealed tree (``ShapeDtypeStruct`` leaves)
     the buffer round-trips through; every byte count is a static trace-time
-    constant, which is what lets the graphlint wire-byte contracts check the
-    fused hop's single-buffer ``ppermute`` traffic against
-    ``hop_bytes + 8`` per cut without executing anything."""
+    constant."""
 
     codec_name: str
     sealed_spec: Any

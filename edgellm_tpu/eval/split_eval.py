@@ -36,8 +36,7 @@ from ..codecs.packing import WireCodec, get_wire_codec, selective_int4
 from ..codecs.faults import FaultConfig, LinkPolicy, TierController, sum_counters
 from ..codecs.fec import FECConfig, HedgeConfig, LinkHealth, LinkHealthConfig
 from ..obs.metrics import (record_link_counters, record_link_health,
-                           record_probe_decisions, record_recovery_counters,
-                           record_wire_bytes)
+                           record_recovery_counters, record_wire_bytes)
 from ..obs.tracing import span as obs_span
 from ..obs.tracing import tracing_enabled
 from ..utils.clock import MONOTONIC
@@ -51,7 +50,7 @@ from .harness import (ResumableDriver, _emit, _iter_window_groups,
 def parse_hop_codec(spec: str, n_seq: int = 1) -> object:
     """Codec spec -> registry name or WireCodec.
 
-    Plain names pass through (``"int4_per_token"``, ``"int8_per_token_pallas"``);
+    Plain names pass through (``"int4_per_token"``, ``"int4_per_token_pallas"``);
     token-selective specs use ``"selective_int4:<ratio>[:<high>][:<mode>]"``
     (e.g. ``"selective_int4:0.25:bf16"``) or ``"selective_int4_pallas:..."``
     to pin the fused-kernel implementation explicitly.
@@ -638,12 +637,9 @@ def run_split_eval(
                 if "per_decode_hop_ms" in result else {})}
             for s in range(len(timed_cuts))]
     # mirror this sweep's totals into the global registry (no-ops when
-    # observability is off): wire bytes, fault/health/recovery counters,
-    # and the per-hop fused-probe decisions (why a hop did/didn't fuse)
+    # observability is off): wire bytes, fault/health/recovery counters
     record_wire_bytes(hop_bytes_total, kind="eval_forward")
     final_rt = runtimes[0] if recovery_on and rcounters.failovers else rt
-    if hasattr(final_rt, "wire_summary"):
-        record_probe_decisions(final_rt.wire_summary(1, seq))
     if fault_on:
         record_link_counters(result["link_counters"])
         if health is not None:

@@ -894,84 +894,6 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     (findings.extend(ident) if ident
      else checked.append("split.decode_step.zero-fault-identity"))
 
-    # ---- fused boundary hops: a forced-wire build must cross each cut as
-    # ---- ONE flat sealed uint8 buffer carrying exactly hop_bytes + the
-    # ---- 8-byte canary/crc seal; a fused-DISABLED build must trace the
-    # ---- byte-identical pre-fusion graph (the FaultyLink refactor's whole
-    # ---- point: fusion changes scheduling, never what bytes cross) --------
-    import os
-
-    saved_env = os.environ.get("EDGELLM_FUSED_HOP")
-    try:
-        # plans resolve at runtime construction, so the env must be set first
-        os.environ["EDGELLM_FUSED_HOP"] = "wire"
-        rt_fused = SplitRuntime(cfg, split, mesh)
-        os.environ["EDGELLM_FUSED_HOP"] = "0"
-        rt_unfused = SplitRuntime(cfg, split, mesh)
-    finally:
-        if saved_env is None:
-            os.environ.pop("EDGELLM_FUSED_HOP", None)
-        else:
-            os.environ["EDGELLM_FUSED_HOP"] = saved_env
-
-    if any(p is None for p in rt_fused.fused_plans):
-        findings.append(Finding(
-            layer="graph", rule="GC-driver", where="split.forward.fused",
-            line=0, message="EDGELLM_FUSED_HOP=wire build refused a fused "
-                            f"plan: {rt_fused.fused_plans}"))
-    else:
-        fused_fwd_ctx = {
-            "hop_eqns": n_hops,  # one flat buffer ppermute per cut
-            "wire_dtypes": frozenset({"uint8"}),
-            "wire_bytes": sum(rt_fused.hop_bytes(BATCH, SEQ)) + 8 * n_hops,
-        }
-        run_one("split.forward.fused", rt_fused._forward,
-                (placed, ids, imps), fused_fwd_ctx)
-
-        _, step_fn_fused = rt_fused._decode_fns(CAPACITY)
-        fused_step_ctx = {
-            "hop_eqns": n_hops,
-            "wire_dtypes": frozenset({"uint8"}),
-            "wire_bytes": sum(rt_fused.decode_hop_bytes(BATCH)) + 8 * n_hops,
-            "donate_min": 2,  # KV donation discipline survives fusion
-        }
-        run_one("split.decode_step.fused", step_fn_fused,
-                (placed, k_cache, v_cache, length, tok), fused_step_ctx,
-                lowerable=step_fn_fused,
-                lower_args=(placed, k_cache, v_cache, length, tok))
-
-        # verify-shape twin: the whole (B, K, D) burst block crosses each cut
-        # as ONE flat sealed buffer — K x hop_bytes payload + the 8-byte seal
-        verify_fn_fused = rt_fused._verify_fns(CAPACITY, K)
-        fused_verify_ctx = {
-            "hop_eqns": n_hops,
-            "wire_dtypes": frozenset({"uint8"}),
-            "wire_bytes": sum(rt_fused.verify_hop_bytes(BATCH, K))
-            + 8 * n_hops,
-            "donate_min": 2,
-        }
-        run_one("split.verify_step.fused", verify_fn_fused,
-                (placed, k_cache, v_cache, length, vtoks), fused_verify_ctx,
-                lowerable=verify_fn_fused,
-                lower_args=(placed, k_cache, v_cache, length, vtoks))
-
-    ident = check_identity(
-        "split.forward.fused-disabled-identity",
-        rt._forward, (placed, ids, imps),
-        rt_unfused._forward, (placed, ids, imps),
-        what="EDGELLM_FUSED_HOP=0 forward graph vs pre-fusion default")
-    (findings.extend(ident) if ident
-     else checked.append("split.forward.fused-disabled-identity"))
-
-    _, step_fn_unfused = rt_unfused._decode_fns(CAPACITY)
-    ident = check_identity(
-        "split.decode_step.fused-disabled-identity",
-        step_fn, (placed, k_cache, v_cache, length, tok),
-        step_fn_unfused, (placed, k_cache, v_cache, length, tok),
-        what="EDGELLM_FUSED_HOP=0 decode-step graph vs pre-fusion default")
-    (findings.extend(ident) if ident
-     else checked.append("split.decode_step.fused-disabled-identity"))
-
     # ---- observability identity: ARMING the obs stack (registry + tracer
     # ---- on, a span open on this thread) must not change a single jaxpr
     # ---- byte — every instrument is host-side, at sample boundaries, never

@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import itertools
 import json
-import os
 import re
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -70,10 +69,6 @@ MAX_TINY_PAGES = 64             # pool-page cap at lint scale (note on clamp)
 _MSG_SPEC_BATCH = (
     "speculative runs the one-stream spec loop; the batcher's ragged step "
     "verifies one token per slot — drop 'speculative' or 'batching'")
-_MSG_FUSED_LINK = (
-    "fused_hops: an active faults/fec/hedge link owns the hop protocol — "
-    "fusion is refused at runtime; set fused_hops: 'off' or drop the link "
-    "config")
 _MSG_PIPE_SPEC = (
     "pipeline + speculative: the spec loop verifies one stream at a time "
     "(B == 1), leaving nothing to micro-batch — drop one of the two blocks")
@@ -92,9 +87,6 @@ PAIR_ORACLE: Dict[Tuple[str, str], str] = {
     ("gray", "speculative"): _MSG_SPEC_BATCH,
     ("kv_at_rest", "speculative"): _MSG_SPEC_BATCH,
     ("prefix_cache", "speculative"): _MSG_SPEC_BATCH,
-    ("faults", "fused_hops"): _MSG_FUSED_LINK,
-    ("fec", "fused_hops"): _MSG_FUSED_LINK,
-    ("fused_hops", "hedge"): _MSG_FUSED_LINK,
     ("pipeline", "speculative"): _MSG_PIPE_SPEC,
     ("kv_at_rest", "pipeline"): _MSG_KVQ_PIPE,
 }
@@ -105,7 +97,6 @@ FUZZ_BLOCKS: Dict[str, dict] = {
     "faults": {"faults": {"drop_rate": 0.05, "seed": 0}},
     "fec": {"fec": {"enabled": True}},
     "hedge": {"hedge": {"routes": 2}},
-    "fused_hops": {"fused_hops": "wire"},
     "pipeline": {"pipeline": {"num_microbatches": 2}},
     "speculative": {"speculative": {"k": 4}},
     "batching": {"batching": {"page_size": 8, "num_pages": 10,
@@ -122,7 +113,7 @@ FUZZ_BLOCKS: Dict[str, dict] = {
 #: part of the pair under test)
 FUZZ_DEPS: Dict[str, Tuple[str, ...]] = {
     "fec": ("faults",), "hedge": ("faults",),
-    "pipeline": ("cuts",), "speculative": ("cuts",), "fused_hops": ("cuts",),
+    "pipeline": ("cuts",), "speculative": ("cuts",),
     "prefix_cache": ("batching",), "kv_at_rest": ("batching",),
     "cluster": ("batching",), "disagg": ("batching",),
     # dep expansion is one level deep, so gray names cluster's own
@@ -135,9 +126,9 @@ FUZZ_BASE = {"experiment": "serve", "serving": {}}
 #: params keys that count as composable features in the matrix
 FEATURE_KEYS = (
     "cuts", "faults", "link_policy", "fec", "hedge", "link_health",
-    "fused_hops", "pipeline", "speculative", "serving", "batching",
-    "prefix_cache", "kv_at_rest", "cluster", "disagg", "gray", "deadline",
-    "stage_failure", "recovery", "n_seq")
+    "pipeline", "speculative", "serving", "batching", "prefix_cache",
+    "kv_at_rest", "cluster", "disagg", "gray", "deadline", "stage_failure",
+    "recovery", "n_seq")
 
 
 def compose_combo(names: Tuple[str, ...]) -> dict:
@@ -412,12 +403,6 @@ class _Lattice:
         if p.get("n_seq", 1) > 1:
             notes.append(f"stage x seq ring (n_seq={p['n_seq']}) lowered as "
                          f"its n_seq=1 twin")
-        if p.get("fused_hops") == "remote":
-            notes.append("fused_hops 'remote' lowered as 'wire' (remote "
-                         "fusion needs the TPU backend)")
-        elif p.get("fused_hops") == "auto":
-            notes.append("fused_hops 'auto' resolved off at lint time "
-                         "(plan probes would execute)")
 
     def _split_runtime(self, p: dict):
         """Tiny-geometry :class:`SplitRuntime` mirroring the config's plan:
@@ -440,31 +425,17 @@ class _Lattice:
         n_micro = 0
         if "pipeline" in p:
             n_micro = min(int(p["pipeline"].get("num_microbatches", 2)), 2)
-        fused = p.get("fused_hops", "off")
-        saved = os.environ.get("EDGELLM_FUSED_HOP")
-        try:
-            if fused in ("wire", "remote"):
-                os.environ["EDGELLM_FUSED_HOP"] = "wire"
-            elif fused == "auto":
-                os.environ["EDGELLM_FUSED_HOP"] = "0"
-            rt = SplitRuntime(
-                self.cfg,
-                SplitConfig(cuts=cuts, hop_codecs=tuple(codecs)),
-                make_stage_mesh(len(cuts) + 1),
-                faults=(FaultConfig(**p["faults"])
-                        if "faults" in p else None),
-                policy=(LinkPolicy(**{**lp, "tiers": tuple(lp.get("tiers",
-                                                                  ()))})
-                        if lp else None),
-                fec=(self._fec(p) if "fec" in p else None),
-                hedge=(self._hedge(p) if "hedge" in p else None),
-                pipeline=(PipelineConfig(num_microbatches=n_micro)
-                          if n_micro else None))
-        finally:
-            if saved is None:
-                os.environ.pop("EDGELLM_FUSED_HOP", None)
-            else:
-                os.environ["EDGELLM_FUSED_HOP"] = saved
+        rt = SplitRuntime(
+            self.cfg,
+            SplitConfig(cuts=cuts, hop_codecs=tuple(codecs)),
+            make_stage_mesh(len(cuts) + 1),
+            faults=(FaultConfig(**p["faults"]) if "faults" in p else None),
+            policy=(LinkPolicy(**{**lp, "tiers": tuple(lp.get("tiers", ()))})
+                    if lp else None),
+            fec=(self._fec(p) if "fec" in p else None),
+            hedge=(self._hedge(p) if "hedge" in p else None),
+            pipeline=(PipelineConfig(num_microbatches=n_micro)
+                      if n_micro else None))
         return rt, n_micro
 
     def _fec(self, p: dict):
@@ -480,7 +451,7 @@ class _Lattice:
     def _split_key(self, p: dict, what: str) -> str:
         sig = {k: p[k] for k in ("cuts", "hop_codecs", "faults",
                                  "link_policy", "fec", "hedge", "pipeline",
-                                 "fused_hops", "n_seq", "batching",
+                                 "n_seq", "batching",
                                  "kv_at_rest", "speculative") if k in p}
         return f"split:{what}:{json.dumps(sig, sort_keys=True)}"
 

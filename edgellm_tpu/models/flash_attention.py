@@ -71,7 +71,6 @@ eager model instance just to get attention maps,
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 import jax
@@ -112,6 +111,10 @@ MAX_KV_BYTES = 2 * 1024 * 1024
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def _shape_plan(s: int, h: int, kv: int, hd: int, itemsize: int = 2):
@@ -156,47 +159,13 @@ def _shape_plan(s: int, h: int, kv: int, hd: int, itemsize: int = 2):
     return ("blocked", (qb, hps))
 
 
-def kernel_plan(s: int, h: int, kv: int, hd: int,
-                backend_check: bool = True, itemsize: int = 2):
-    """The kernel plan for this shape when the Pallas path should handle it
-    by default, else None (XLA fused path): TPU backend, silicon-validated
-    head_dim, head-aligned GQA, and a shape one of the two kernels covers.
-    EDGELLM_ATTN forces the kernel (=pallas) or the XLA path (=xla) on any
-    backend — the force still honors the VMEM-driven shape limits."""
-    flag = os.environ.get("EDGELLM_ATTN")
-    if flag == "xla":
-        return None
-    if hd not in VALIDATED_HD or h % kv:
-        return None
-    if flag != "pallas" and backend_check and jax.default_backend() != "tpu":
+def kernel_plan(s: int, h: int, kv: int, hd: int, itemsize: int = 2):
+    """The kernel plan for this shape where the Pallas path handles it, else
+    None (XLA fused path): a TPU, a silicon-validated head_dim, head-aligned
+    GQA, and a shape one of the two kernels covers."""
+    if not _on_tpu() or hd not in VALIDATED_HD or h % kv:
         return None
     return _shape_plan(s, h, kv, hd, itemsize)
-
-
-def kernel_eligible(seq: int, model_dim: int,
-                    backend_check: bool = True,
-                    num_heads: int | None = None,
-                    num_kv_heads: int | None = None) -> bool:
-    """True when a Pallas kernel handles this (S, H*hd) shape by default.
-
-    Callers must pass the real head layout: the historical hd=64 MHA
-    inference is DEPRECATED (ADVICE r5 #2) because it disagrees with real
-    dispatch for hd=128 and GQA presets — real dispatch is
-    :func:`kernel_plan` on (S, H, KV, hd)."""
-    if num_heads is None:
-        import warnings
-
-        warnings.warn(
-            "kernel_eligible without num_heads/num_kv_heads infers an hd=64 "
-            "MHA layout, which can disagree with real dispatch for hd=128/GQA "
-            "presets; pass the head counts or use kernel_plan directly",
-            DeprecationWarning, stacklevel=2)
-        num_heads = max(model_dim // 64, 1)
-    if num_kv_heads is None:
-        num_kv_heads = num_heads
-    hd = model_dim // num_heads
-    return kernel_plan(seq, num_heads, num_kv_heads, hd,
-                       backend_check=backend_check) is not None
 
 
 def _head_attn(q, k, v, row0=0):

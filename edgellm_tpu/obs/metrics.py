@@ -30,9 +30,7 @@ Metric name catalog (REPRODUCING §10): ``edgellm_link_<counter>_total``
 (speculative decode), ``edgellm_pipeline_microbatches`` /
 ``edgellm_pipeline_bubble_fraction[_measured]`` /
 ``edgellm_pipeline_stage_occupancy`` (µ-batch pipelined decode, label
-``stage``), ``edgellm_fused_hop_active`` /
-``edgellm_fused_hop_decision`` / ``edgellm_fused_probe_win`` (fused-hop
-probe decisions, labels ``hop``, ``codec``, ``mode``, ``reason``).
+``stage``).
 """
 from __future__ import annotations
 
@@ -49,8 +47,8 @@ __all__ = [
     "Counter", "CounterSource", "Gauge", "Histogram", "MetricsRegistry",
     "format_table", "get_registry", "record_decode_stats",
     "record_link_counters", "record_link_health", "record_pipeline_stats",
-    "record_prefix_stats", "record_probe_decisions",
-    "record_recovery_counters", "record_spec_stats", "record_wire_bytes",
+    "record_prefix_stats", "record_recovery_counters", "record_spec_stats",
+    "record_wire_bytes",
 ]
 
 LabelKey = Tuple[Tuple[str, str], ...]
@@ -556,47 +554,6 @@ def record_spec_stats(stats: Optional[Mapping[str, Any]],
                   "boundary hop rounds per emitted token, last run "
                   "(< 1.0 means speculation amortized the link)"
                   ).set(float(hpt))
-
-
-def record_probe_decisions(rows: Optional[Sequence[Mapping[str, Any]]],
-                           registry: Optional[MetricsRegistry] = None
-                           ) -> None:
-    """Absorb ``SplitRuntime.wire_summary`` rows' fused-hop plan decisions,
-    plus the probe cache's measured-win verdict per codec, so
-    ``--metrics-out`` says WHY a hop did or didn't fuse instead of that
-    living only in the BENCH_WIRE detail sidecar: ``edgellm_fused_hop_active
-    {hop, codec}`` is 1/0, ``edgellm_fused_hop_decision{hop, codec, mode,
-    reason}`` is an info-style gauge carrying the plan's reason string, and
-    ``edgellm_fused_probe_win{codec}`` is 1 for a measured win, -1 for a
-    measured loss, 0 for no probe data."""
-    reg = registry if registry is not None else _REGISTRY
-    if not reg.enabled or not rows:
-        return
-    from ..codecs import probe_cache
-
-    active = reg.gauge("edgellm_fused_hop_active",
-                       "1 when this hop crosses as one fused sealed buffer, "
-                       "0 on the unfused encode/ppermute/decode ladder")
-    decision = reg.gauge("edgellm_fused_hop_decision",
-                         "info-style record (value always 1) of each hop's "
-                         "fuse/no-fuse decision and its reason")
-    win = reg.gauge("edgellm_fused_probe_win",
-                    "probe-cache verdict per codec: 1 measured win, "
-                    "-1 measured loss, 0 no data")
-    for row in rows:
-        hop = row.get("hop", 0)
-        codec = row.get("codec", "?")
-        fused = row.get("fused")
-        active.set(1.0 if fused else 0.0, hop=hop, codec=codec)
-        if fused:
-            decision.set(1.0, hop=hop, codec=codec,
-                         mode=fused.get("mode", "?"),
-                         reason=fused.get("reason", "?"))
-        else:
-            decision.set(1.0, hop=hop, codec=codec, mode="off",
-                         reason="no fused plan (gate ladder refused)")
-        w = probe_cache.measured_win(f"fused_hop:{codec}")
-        win.set(0.0 if w is None else (1.0 if w else -1.0), codec=codec)
 
 
 def record_cluster_stats(report: Optional[Mapping[str, Any]],

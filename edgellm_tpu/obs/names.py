@@ -63,10 +63,6 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "edgellm_prefix_hit_rate",
     "edgellm_prefix_shared_pages",
     "edgellm_prefix_index_pages",
-    # fused-hop probe provenance
-    "edgellm_fused_hop_active",
-    "edgellm_fused_hop_decision",
-    "edgellm_fused_probe_win",
     # tracing plane
     "edgellm_flight_dumps_total",
     "edgellm_obs_scrapes_total",

@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any, Optional, Sequence
 
 import numpy as np
@@ -43,7 +42,7 @@ from ..models.paged_kv import KVTierMismatchError, block_decode_paged, \
     pool_tier, resolve_kv_codec
 from ..codecs.packing import get_wire_codec, WireCodec
 from ..codecs.faults import FaultConfig, FaultyLink, LinkPolicy, sum_counters
-from ..codecs.pallas_kernels import fused_hop, fused_hop_plan
+from ..codecs.pallas_kernels import pallas_variant
 from ..lint import graph_contract
 from ..serve.recovery import StageLostError
 
@@ -82,28 +81,14 @@ def make_stage_mesh(n_stages: int, n_data: int = 1, n_model: int = 1,
 
 
 def apply_default_codec_backend(codecs: list) -> list:
-    """Resolve hop-codec specs (names or ``WireCodec`` instances) to the
-    backend's default implementation. On TPU the fused Pallas kernels are the
-    default — but only where the kernel is a MEASURED on-silicon win for
-    this chip (``pallas_kernels.default_substituted``: the probe cache keyed
-    by chip fingerprint, with ``PALLAS_DEFAULT_WINS`` as the no-data
-    fallback; the probe showed int8_per_channel marginally slower than its
-    already-fused jnp twin, and the selective codec's twin was deleted
-    outright on measurement — ``SELECTIVE_EXCLUSION``). EDGELLM_PALLAS
-    forces substitution of every kernel twin (=1) or none (=0) on any
-    backend; explicit ``*_pallas`` names are always honored. Shared by every
+    """Resolve hop-codec specs (names or ``WireCodec`` instances) to the codec
+    each hop runs: a base codec's Pallas twin where
+    ``pallas_kernels.pallas_variant`` answers one (on a TPU, for the codecs
+    its table holds), the codec itself everywhere else. An explicit
+    ``*_pallas`` name is a codec like any other and is kept. Shared by every
     runtime that owns hop codecs."""
     codecs = [c if isinstance(c, WireCodec) else get_wire_codec(c) for c in codecs]
-    flag = os.environ.get("EDGELLM_PALLAS")
-    if flag == "1":
-        from ..codecs.pallas_kernels import pallas_variant
-
-        return [pallas_variant(c) or c for c in codecs]
-    if flag is None and jax.default_backend() == "tpu":
-        from ..codecs.pallas_kernels import pallas_variant
-
-        return [pallas_variant(c, measured_wins_only=True) or c for c in codecs]
-    return codecs
+    return [pallas_variant(c) or c for c in codecs]
 
 
 def regroup_layers(layers: dict, bounds: list, stage_size: int) -> tuple:
@@ -122,9 +107,29 @@ def regroup_layers(layers: dict, bounds: list, stage_size: int) -> tuple:
     return groups, valid
 
 
+def cross_cut(codec, hidden, s: int, axis_name: str, idx, imp=None,
+              link=None, fault_key=None, counters=None) -> tuple:
+    """The hop of cut ``s``, the one ladder every stage runner crosses a cut
+    with (inside shard_map on ``axis_name``): ENCODE the boundary activation
+    (with its importance side channel where the codec takes one), ``ppermute``
+    each payload leaf ``s -> s + 1``, DECODE on arrival, all under the scope
+    ``split.hop.{s}``. An active ``link`` owns the crossing instead (seal,
+    inject, verify, retry, keyed by ``fault_key``) and advances ``counters``.
+    Returns (hidden, counters)."""
+    with jax.named_scope(f"split.hop.{s}"):
+        if link is not None:
+            return link.hop(codec, hidden, s, axis_name, idx, fault_key,
+                            counters, hop_imp=imp)
+        payload = (codec.encode(hidden) if imp is None
+                   else codec.encode(hidden, imp))
+        moved = jax.tree_util.tree_map(
+            lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]), payload)
+        return jnp.where(idx == s + 1, codec.decode(moved), hidden), counters
+
+
 def run_pipeline_stages(n_stages: int, codecs: list, run_stage, hidden,
                         hop_imps=None, axis_name: str = "stage",
-                        link=None, fault_key=None, fused_plans=None):
+                        link=None, fault_key=None):
     """The pipeline-unroll + boundary-hop protocol, shared by SplitRuntime and
     the stage x seq SplitRingRuntime (must run inside shard_map on
     ``axis_name``).
@@ -140,14 +145,7 @@ def run_pipeline_stages(n_stages: int, codecs: list, run_stage, hidden,
     hop through the faulty-wire protocol — seal, inject, verify, retry — keyed
     by ``fault_key``; the return value then becomes ``(out, counters)`` with
     the per-hop counters psum-replicated over ``axis_name``. With ``link``
-    None this is byte-for-byte the original lossless path.
-
-    ``fused_plans`` (one :class:`~edgellm_tpu.codecs.pallas_kernels.
-    FusedHopPlan`-or-None per cut, resolved by ``fused_hop_plan``) routes a
-    hop through the fused quantize->transport path instead; an all-None
-    plan list leaves this function byte-for-byte the pre-fusion graph, and
-    plans are only ever resolved when ``link`` is None (the gate refuses
-    under an active link)."""
+    None this is byte-for-byte the original lossless path."""
     idx = jax.lax.axis_index(axis_name)
     counters = link.init_counters(n_stages - 1) if link is not None else None
     for s in range(n_stages):
@@ -156,24 +154,9 @@ def run_pipeline_stages(n_stages: int, codecs: list, run_stage, hidden,
         hidden = jnp.where(idx == s, computed, hidden)
         if s == n_stages - 1:
             break
-        with jax.named_scope(f"split.hop.{s}"):
-            if link is not None:
-                imp = hop_imps[s] if codecs[s].needs_importance else None
-                hidden, counters = link.hop(codecs[s], hidden, s, axis_name,
-                                            idx, fault_key, counters,
-                                            hop_imp=imp)
-                continue
-            if fused_plans is not None and fused_plans[s] is not None:
-                hidden = fused_hop(fused_plans[s], codecs[s], hidden, s,
-                                   axis_name, idx, n_dev=n_stages)
-                continue
-            if codecs[s].needs_importance:
-                payload = codecs[s].encode(hidden, hop_imps[s])
-            else:
-                payload = codecs[s].encode(hidden)
-            moved = jax.tree_util.tree_map(
-                lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]), payload)
-            hidden = jnp.where(idx == s + 1, codecs[s].decode(moved), hidden)
+        imp = hop_imps[s] if codecs[s].needs_importance else None
+        hidden, counters = cross_cut(codecs[s], hidden, s, axis_name, idx, imp,
+                                     link, fault_key, counters)
     out = jax.lax.psum(
         jnp.where(idx == n_stages - 1, hidden, jnp.zeros_like(hidden)), axis_name)
     if link is None:
@@ -193,7 +176,7 @@ def keep_carry(keep, new, old):
 
 def run_pipeline_stages_carry(n_stages: int, codecs: list, run_stage, hidden,
                               carry, axis_name: str = "stage",
-                              link=None, fault_key=None, fused_plans=None):
+                              link=None, fault_key=None):
     """:func:`run_pipeline_stages` for stage bodies that thread stage-local
     state (the decode KV cache, the page pool): ``run_stage(hidden, carry,
     keep) -> (hidden, carry)``. Every device runs the body in each of the
@@ -218,19 +201,8 @@ def run_pipeline_stages_carry(n_stages: int, codecs: list, run_stage, hidden,
         hidden = jnp.where(keep, computed, hidden)
         if s == n_stages - 1:
             break
-        with jax.named_scope(f"split.hop.{s}"):
-            if link is not None:
-                hidden, counters = link.hop(codecs[s], hidden, s, axis_name,
-                                            idx, fault_key, counters)
-                continue
-            if fused_plans is not None and fused_plans[s] is not None:
-                hidden = fused_hop(fused_plans[s], codecs[s], hidden, s,
-                                   axis_name, idx, n_dev=n_stages)
-                continue
-            payload = codecs[s].encode(hidden)
-            moved = jax.tree_util.tree_map(
-                lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]), payload)
-            hidden = jnp.where(idx == s + 1, codecs[s].decode(moved), hidden)
+        hidden, counters = cross_cut(codecs[s], hidden, s, axis_name, idx,
+                                     None, link, fault_key, counters)
     out = jax.lax.psum(
         jnp.where(idx == n_stages - 1, hidden, jnp.zeros_like(hidden)), axis_name)
     if link is None:
@@ -317,8 +289,7 @@ def _microbatch_imp(codec, hop_imps, s: int, mb: int, mb_rows: int):
 def run_pipeline_stages_microbatched(n_stages: int, codecs: list,
                                      num_microbatches: int, run_stage, hidden,
                                      hop_imps=None, axis_name: str = "stage",
-                                     link=None, fault_key=None,
-                                     fused_plans=None):
+                                     link=None, fault_key=None):
     """Micro-batch pipelined twin of :func:`run_pipeline_stages` (must run
     inside shard_map on ``axis_name``).
 
@@ -349,8 +320,8 @@ def run_pipeline_stages_microbatched(n_stages: int, codecs: list,
     mb_rows = batch // m
     micro = [jax.lax.slice_in_dim(hidden, b * mb_rows, (b + 1) * mb_rows,
                                   axis=0) for b in range(m)]
-    counters = ([link.init_counters(n_hops) for _ in range(m)]
-                if link is not None else None)
+    counters = [None if link is None else link.init_counters(n_hops)
+                for _ in range(m)]
     act = jnp.zeros_like(micro[0])
     outs = []
     for t in range(m + n_stages - 1):
@@ -368,25 +339,11 @@ def run_pipeline_stages_microbatched(n_stages: int, codecs: list,
             mb = t - s  # static: only in-flight (cut, µ-batch) hops trace
             if not 0 <= mb < m:
                 continue
-            with jax.named_scope(f"split.hop.{s}"):
-                if link is not None:
-                    imp = _microbatch_imp(codecs[s], hop_imps, s, mb, mb_rows)
-                    act, counters[mb] = link.hop(
-                        codecs[s], act, s, axis_name, idx,
-                        jax.random.fold_in(fault_key, mb), counters[mb],
-                        hop_imp=imp)
-                    continue
-                if fused_plans is not None and fused_plans[s] is not None:
-                    act = fused_hop(fused_plans[s], codecs[s], act, s,
-                                    axis_name, idx, n_dev=n_stages)
-                    continue
-                imp = _microbatch_imp(codecs[s], hop_imps, s, mb, mb_rows)
-                payload = (codecs[s].encode(act, imp) if imp is not None
-                           else codecs[s].encode(act))
-                moved = jax.tree_util.tree_map(
-                    lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]),
-                    payload)
-                act = jnp.where(idx == s + 1, codecs[s].decode(moved), act)
+            act, counters[mb] = cross_cut(
+                codecs[s], act, s, axis_name, idx,
+                _microbatch_imp(codecs[s], hop_imps, s, mb, mb_rows), link,
+                None if link is None else jax.random.fold_in(fault_key, mb),
+                counters[mb])
     out = jax.lax.psum(jnp.stack(outs), axis_name)  # (M, B/M, ...)
     out = out.reshape((batch,) + out.shape[2:])
     if link is None:
@@ -401,8 +358,7 @@ def run_pipeline_stages_carry_microbatched(n_stages: int, codecs: list,
                                            num_microbatches: int, run_stage,
                                            hidden, carry,
                                            axis_name: str = "stage",
-                                           link=None, fault_key=None,
-                                           fused_plans=None):
+                                           link=None, fault_key=None):
     """:func:`run_pipeline_stages_microbatched` for stage bodies that
     thread stage-local state (the decode KV caches): ``run_stage(h_mu,
     carry, b, valid) -> (h_mu, carry)`` where ``b`` is the device's current
@@ -422,8 +378,8 @@ def run_pipeline_stages_carry_microbatched(n_stages: int, codecs: list,
     mb_rows = batch // m
     micro = [jax.lax.slice_in_dim(hidden, b * mb_rows, (b + 1) * mb_rows,
                                   axis=0) for b in range(m)]
-    counters = ([link.init_counters(n_hops) for _ in range(m)]
-                if link is not None else None)
+    counters = [None if link is None else link.init_counters(n_hops)
+                for _ in range(m)]
     act = jnp.zeros_like(micro[0])
     outs = []
     for t in range(m + n_stages - 1):
@@ -442,21 +398,10 @@ def run_pipeline_stages_carry_microbatched(n_stages: int, codecs: list,
             mb = t - s
             if not 0 <= mb < m:
                 continue
-            with jax.named_scope(f"split.hop.{s}"):
-                if link is not None:
-                    act, counters[mb] = link.hop(
-                        codecs[s], act, s, axis_name, idx,
-                        jax.random.fold_in(fault_key, mb), counters[mb])
-                    continue
-                if fused_plans is not None and fused_plans[s] is not None:
-                    act = fused_hop(fused_plans[s], codecs[s], act, s,
-                                    axis_name, idx, n_dev=n_stages)
-                    continue
-                payload = codecs[s].encode(act)
-                moved = jax.tree_util.tree_map(
-                    lambda a: jax.lax.ppermute(a, axis_name, [(s, s + 1)]),
-                    payload)
-                act = jnp.where(idx == s + 1, codecs[s].decode(moved), act)
+            act, counters[mb] = cross_cut(
+                codecs[s], act, s, axis_name, idx, None, link,
+                None if link is None else jax.random.fold_in(fault_key, mb),
+                counters[mb])
     out = jax.lax.psum(jnp.stack(outs), axis_name)
     out = out.reshape((batch,) + out.shape[2:])
     if link is None:
@@ -644,15 +589,6 @@ class SplitRuntime:
         self.stage_size = max(stop - start for start, stop in self.bounds)
         self.codecs: list[WireCodec] = apply_default_codec_backend(
             list(split.hop_codecs))
-        # per-cut fused-transport decision, resolved ONCE at build time so
-        # the compiled graphs embed it: None = the pre-fusion ladder (an
-        # all-None list leaves every traced graph byte-identical — the
-        # "split.*.fused-disabled-identity" lint checks pin this). The gate
-        # refuses whenever the faulty link is armed: fault injection, FEC
-        # and hedging own the hop there.
-        self.fused_plans: list = [
-            fused_hop_plan(c, link_active=self._link is not None)
-            for c in self.codecs]
         n_model = mesh.shape["model"]
         if n_model > 1:
             bad = [(name, dim) for name, dim in
@@ -778,10 +714,9 @@ class SplitRuntime:
         codecs = self.codecs
         mesh = self.mesh
         link = self._link
-        fused_plans = self.fused_plans
-        # resolved once at build time, like the fused plans: the disabled /
-        # M == 1 build traces the ORIGINAL schedule functions (the
-        # pipeline-disabled-identity lint pins hold it byte-identical)
+        # resolved once at build time: the disabled / M == 1 build traces
+        # the ORIGINAL schedule functions (the pipeline-disabled-identity
+        # lint pins hold it byte-identical)
         n_micro = (self.pipeline.num_microbatches if self.pipelined else 1)
 
         tp_axis = "model" if mesh.shape["model"] > 1 else None
@@ -809,10 +744,9 @@ class SplitRuntime:
             if link is None:
                 if n_micro > 1:
                     return run_pipeline_stages_microbatched(
-                        n_stages, codecs, n_micro, run_stage, hidden,
-                        hop_imps, fused_plans=fused_plans)
+                        n_stages, codecs, n_micro, run_stage, hidden, hop_imps)
                 return run_pipeline_stages(n_stages, codecs, run_stage, hidden,
-                                           hop_imps, fused_plans=fused_plans)
+                                           hop_imps)
             # one fold per forward call keeps chunks decorrelated while two
             # same-seed runs replay the identical fault sequence
             key = jax.random.fold_in(jax.random.key(link.faults.seed),
@@ -869,15 +803,6 @@ class SplitRuntime:
         "split.forward",
         # one ppermute per payload leaf per cut, one structural psum; the
         # driver supplies the measured counts/bytes from the codec registry
-        collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
-        wire_dtypes=lambda ctx: ctx["wire_dtypes"],
-        wire_bytes=lambda ctx: ctx["wire_bytes"])
-    @graph_contract(
-        "split.forward.fused",
-        # fused wire mode: the whole sealed tree crosses each cut as ONE
-        # flat uint8 buffer (hop_eqns == n_cuts), and the bytes are exactly
-        # hop_bytes + the 8-byte canary/crc seal per cut — the driver traces
-        # a forced-fused build against this declaration
         collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
         wire_dtypes=lambda ctx: ctx["wire_dtypes"],
         wire_bytes=lambda ctx: ctx["wire_bytes"])
@@ -1000,22 +925,6 @@ class SplitRuntime:
             self._mb_counter_accum = []
         return tot
 
-    def wire_summary(self, batch: int, seq: int) -> list:
-        """Per-hop wire accounting in one shot — the shape the obs registry
-        and bench artifacts consume: codec name, whole-window forward bytes,
-        single-step decode bytes, and steady-state bytes/token."""
-        fwd = self.hop_bytes(batch, seq)
-        dec = self.decode_hop_bytes(batch)
-        per_tok = self.bytes_per_token(seq)
-        return [{"hop": i, "codec": self.codecs[i].name,
-                 "forward_bytes": int(fwd[i]),
-                 "decode_step_bytes": int(dec[i]) if i < len(dec) else 0,
-                 "bytes_per_token": float(per_tok[i]),
-                 "fused": (None if self.fused_plans[i] is None else
-                           {"mode": self.fused_plans[i].mode,
-                            "reason": self.fused_plans[i].reason})}
-                for i in range(len(self.codecs))]
-
     def hop_attribution(self, delta: Optional[dict],
                         per_hop_bytes: Optional[list] = None, *,
                         link_tier: Optional[int] = None) -> list:
@@ -1094,7 +1003,6 @@ class SplitRuntime:
         codecs, mesh = self.codecs, self.mesh
         layer_pspec = self._layer_pspec
         link = self._link
-        fused_plans = self.fused_plans
         n_micro = (self.pipeline.num_microbatches if self.pipelined else 1)
 
         def _hop_protocol(run_stage, hidden, carry, fault_key):
@@ -1102,8 +1010,7 @@ class SplitRuntime:
             the link-free branch is byte-for-byte the original call."""
             if link is None:
                 out, c = run_pipeline_stages_carry(
-                    n_stages, codecs, run_stage, hidden, carry,
-                    fused_plans=fused_plans)
+                    n_stages, codecs, run_stage, hidden, carry)
                 return out, c, None
             return run_pipeline_stages_carry(
                 n_stages, codecs, run_stage, hidden, carry,
@@ -1116,8 +1023,7 @@ class SplitRuntime:
             cache in one sequential pass either way."""
             if link is None:
                 out, c = run_pipeline_stages_carry_microbatched(
-                    n_stages, codecs, n_micro, run_stage, hidden, carry,
-                    fused_plans=fused_plans)
+                    n_stages, codecs, n_micro, run_stage, hidden, carry)
                 return out, c, None
             return run_pipeline_stages_carry_microbatched(
                 n_stages, codecs, n_micro, run_stage, hidden, carry,
@@ -1294,15 +1200,6 @@ class SplitRuntime:
         wire_bytes=lambda ctx: ctx["wire_bytes"],
         donate=lambda ctx: ctx.get("donate_min", 2))
     @graph_contract(
-        "split.decode_step.fused",
-        # decode-shape twin of split.forward.fused: one flat sealed buffer
-        # per cut at (B, 1, D), byte-checked against decode_hop_bytes + 8,
-        # with the KV donation discipline intact under fusion
-        collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
-        wire_dtypes=lambda ctx: ctx["wire_dtypes"],
-        wire_bytes=lambda ctx: ctx["wire_bytes"],
-        donate=lambda ctx: ctx.get("donate_min", 2))
-    @graph_contract(
         "split.decode_step.pipelined",
         # µ-batch twin of split.decode_step: M payloads of (B/M, 1, D) per
         # cut per step (pipelined_decode_hop_bytes), ONE stacked psum, and
@@ -1358,7 +1255,7 @@ class SplitRuntime:
     # on stage 0 and this verifies them all in ONE split pass — each cut
     # moves one quantized (B, k, D) activation block instead of k single-
     # token hops, amortizing the boundary round-trip (and the whole
-    # faulty/FEC/hedge/fused hop ladder, which is shape-generic and flows
+    # faulty/FEC/hedge hop ladder, which is shape-generic and flows
     # unchanged) k-fold per accepted run.
 
     def _verify_fns(self, capacity: int, k: int):
@@ -1377,13 +1274,11 @@ class SplitRuntime:
         codecs, mesh = self.codecs, self.mesh
         layer_pspec = self._layer_pspec
         link = self._link
-        fused_plans = self.fused_plans
 
         def _hop_protocol(run_stage, hidden, carry, fault_key):
             if link is None:
                 out, c = run_pipeline_stages_carry(
-                    n_stages, codecs, run_stage, hidden, carry,
-                    fused_plans=fused_plans)
+                    n_stages, codecs, run_stage, hidden, carry)
                 return out, c, None
             return run_pipeline_stages_carry(
                 n_stages, codecs, run_stage, hidden, carry,
@@ -1457,15 +1352,6 @@ class SplitRuntime:
 
     @graph_contract(
         "split.verify_step",
-        collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
-        wire_dtypes=lambda ctx: ctx["wire_dtypes"],
-        wire_bytes=lambda ctx: ctx["wire_bytes"],
-        donate=lambda ctx: ctx.get("donate_min", 2))
-    @graph_contract(
-        "split.verify_step.fused",
-        # verify-shape twin of split.decode_step.fused: one flat sealed
-        # buffer per cut at (B, k, D) — the ISSUE's k x hop_bytes + 8 wire
-        # contract: ONE hop per verify burst, not k single-token hops
         collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
         wire_dtypes=lambda ctx: ctx["wire_dtypes"],
         wire_bytes=lambda ctx: ctx["wire_bytes"],
@@ -1643,14 +1529,12 @@ class SplitRuntime:
         codecs, mesh = self.codecs, self.mesh
         layer_pspec = self._layer_pspec
         link = self._link
-        fused_plans = self.fused_plans
         tree_map = jax.tree_util.tree_map
 
         def _hop_protocol(run_stage, hidden, carry, fault_key):
             if link is None:
                 out, c = run_pipeline_stages_carry(
-                    n_stages, codecs, run_stage, hidden, carry,
-                    fused_plans=fused_plans)
+                    n_stages, codecs, run_stage, hidden, carry)
                 return out, c, None
             return run_pipeline_stages_carry(
                 n_stages, codecs, run_stage, hidden, carry,
@@ -1659,8 +1543,7 @@ class SplitRuntime:
         def _hop_protocol_pipelined(run_stage, hidden, carry, fault_key):
             if link is None:
                 out, c = run_pipeline_stages_carry_microbatched(
-                    n_stages, codecs, n_micro, run_stage, hidden, carry,
-                    fused_plans=fused_plans)
+                    n_stages, codecs, n_micro, run_stage, hidden, carry)
                 return out, c, None
             return run_pipeline_stages_carry_microbatched(
                 n_stages, codecs, n_micro, run_stage, hidden, carry,

@@ -108,7 +108,6 @@ _PARAM_KEYS = {
     "layers_of_interest": "initial/token/channel sweeps",
     "ratios": "initial/token sweeps",
     "cuts": "split/serve", "hop_codecs": "split/serve",
-    "fused_hops": "split/serve",
     "importance_method": "split",
     "n_seq": "split", "n_data": "split", "n_model": "split",
     "faults": "split/serve", "link_policy": "split/serve",
@@ -225,21 +224,6 @@ def _validate_params_json(p: dict) -> None:
             die(f"{k} must be a list, got {type(p[k]).__name__}")
     if exp == "serve" and ("cuts" in p) != ("hop_codecs" in p):
         die("serve: cuts and hop_codecs go together")
-    if "fused_hops" in p:
-        if exp not in ("split", "serve"):
-            die("fused_hops only applies to experiments 'split' and 'serve'")
-        if "cuts" not in p:
-            die("fused_hops needs a pipeline to fuse — add 'cuts'/'hop_codecs'")
-        fh = p["fused_hops"]
-        if fh not in ("auto", "off", "wire", "remote"):
-            die(f"fused_hops must be one of ['auto', 'off', 'wire', "
-                f"'remote'], got {fh!r}")
-        if fh != "off" and any(("faults" in p, "fec" in p, "hedge" in p)):
-            # mirror of fused_hop_plan's link_active refusal: an active
-            # FaultyLink owns the hop, so forcing fusion would silently lose
-            die("fused_hops: an active faults/fec/hedge link owns the hop "
-                "protocol — fusion is refused at runtime; set fused_hops: "
-                "'off' or drop the link config")
     if exp in ("split", "serve") and "cuts" in p:
         if not p["cuts"] or not all(
                 isinstance(c, int) and not isinstance(c, bool) and c >= 0
@@ -529,11 +513,6 @@ def _validate_params_json(p: dict) -> None:
             sc = SpecConfig(**sp)
         except (TypeError, ValueError) as e:
             die(f"speculative: {e}")
-        if sc.enabled and p.get("fused_hops") == "remote":
-            # forcing remote fusion skips the probe; the k-token verify
-            # shape has no measured win yet, so refuse until probed
-            die("speculative + fused_hops 'remote': forced remote fusion is "
-                "unprobed at the k-token verify shape — use 'auto' or 'off'")
         if sc.enabled and "batching" in p:
             die("speculative runs the one-stream spec loop; the batcher's "
                 "ragged step verifies one token per slot — drop "
@@ -1547,17 +1526,6 @@ def main(argv=None) -> int:
                           "wall_s": round(result.wall_s, 3),
                           "ppl": np.round(result.ppl(), 4).tolist()}))
         return 0
-
-    # fused_hops maps onto the EDGELLM_FUSED_HOP gate BEFORE any runtime is
-    # built (SplitRuntime resolves its fused plans at construction):
-    # "auto" leaves the measured-win default, "off" pins the pre-fusion
-    # graph, "wire"/"remote" force a mode (remote still refuses off-TPU)
-    fused_hops = params_json.get("fused_hops")
-    if fused_hops == "auto":
-        os.environ.pop("EDGELLM_FUSED_HOP", None)
-    elif fused_hops is not None:
-        os.environ["EDGELLM_FUSED_HOP"] = \
-            {"off": "0", "wire": "wire", "remote": "remote"}[fused_hops]
 
     with profile_cm:
         try:

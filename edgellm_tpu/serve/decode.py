@@ -48,8 +48,8 @@ from ..models.transformer import (KVCache, _cast_params, block_verify,
 from ..obs.latency import LatencyObserver
 from ..obs.metrics import (CounterSource, get_registry, record_decode_stats,
                            record_link_counters, record_link_health,
-                           record_pipeline_stats, record_probe_decisions,
-                           record_recovery_counters, record_wire_bytes)
+                           record_pipeline_stats, record_recovery_counters,
+                           record_wire_bytes)
 from ..obs.tracing import span as obs_span
 from ..obs.tracing import tracing_enabled
 from .recovery import (CheckpointError, DecodeCheckpoint, DecodeTimeout,
@@ -383,8 +383,6 @@ def generate_split(rt: Any, placed_params: dict, prompt_ids: ArrayLike,
                      else rt.decode_hop_bytes(b))
     if get_registry().enabled and hop_bytes is not None:
         record_wire_bytes(hop_bytes, kind="decode", steps=max_new_tokens - 1)
-        if hasattr(rt, "wire_summary"):
-            record_probe_decisions(rt.wire_summary(b, max(s, 1)))
     _emit_hop_spans(
         rt, delta,
         None if hop_bytes is None

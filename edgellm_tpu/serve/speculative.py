@@ -66,8 +66,8 @@ from ..models.transformer import (KVCache, _slice_layers,
 from ..obs.latency import LatencyObserver
 from ..obs.metrics import (CounterSource, get_registry, record_decode_stats,
                            record_link_counters, record_link_health,
-                           record_probe_decisions, record_recovery_counters,
-                           record_spec_stats, record_wire_bytes)
+                           record_recovery_counters, record_spec_stats,
+                           record_wire_bytes)
 from ..obs import context as obs_context
 from ..obs.tracing import span as obs_span
 from ..obs.tracing import tracing_enabled
@@ -455,7 +455,6 @@ def _spec_loop(rt, placed, prompt_ids, max_new_tokens: int, capacity: int,
     if get_registry().enabled and isinstance(rt, CounterSource):
         record_wire_bytes(rt.verify_hop_bytes(b, k), kind="verify",
                           steps=bursts)
-        record_probe_decisions(rt.wire_summary(b, k))
     if tracing_enabled() and hasattr(rt, "hop_attribution"):
         # one hop round per burst: the per-hop wire cost is the k-token
         # verify payload times the burst count

@@ -2,10 +2,10 @@
 XLA's fused ``jax.nn.dot_product_attention`` at the model shapes the sweeps
 actually run.
 
-Mirrors the codec probe's phase-robust estimator (``pallas_probe``): each
-variant is timed with the differential scan, measurements are taken in
-interleaved (pallas, xla) pairs, and the reported speedup is the median of
-per-pair ratios, so slow drift between measurements cancels.
+A phase-robust estimator: each variant is timed with the differential scan
+(``utils.profiling.ScanTimer``), measurements are taken in interleaved
+(pallas, xla) pairs, and the reported speedup is the median of per-pair
+ratios, so slow drift between measurements cancels.
 :func:`parity_shape` is the correctness half: one kernel plan, compiled
 (never interpreted) on the current backend, against XLA's attention.
 
@@ -20,7 +20,7 @@ from statistics import median
 
 import numpy as np
 
-from .pallas_probe import _ScanTimer
+from ..utils.profiling import ScanTimer
 
 #: (name, batch, heads, kv_heads, seq, head_dim) — the sweep shapes:
 #: pythia window-2048 (reference's own evaluation window), the flagship ring
@@ -67,11 +67,11 @@ def probe_shape(name: str, b: int, h: int, kv: int, s: int, hd: int,
 
     import math
 
-    tp = _ScanTimer(pallas_body, tree, pool)
-    tx = _ScanTimer(xla_body, tree, pool)
-    # drop pairs with an unresolved (NaN) differential, exactly like the
-    # codec probe's paired_medians — a median over NaNs is undefined and a
-    # NaN field would make the bench sidecar spec-invalid JSON
+    tp = ScanTimer(pallas_body, tree, pool)
+    tx = ScanTimer(xla_body, tree, pool)
+    # drop pairs with an unresolved (NaN) differential: a median over NaNs
+    # is undefined and a NaN field would make the bench sidecar
+    # spec-invalid JSON
     pairs = [(p, x) for p, x in
              ((tp.differential(), tx.differential()) for _ in range(reps))
              if math.isfinite(p) and math.isfinite(x)]

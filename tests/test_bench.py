@@ -15,7 +15,6 @@ def test_bench_main_headline_is_final_compact_line(monkeypatch, capsys, tmp_path
     monkeypatch.setenv("BENCH_MODEL", "tiny-qwen2")
     monkeypatch.setenv("BENCH_CHUNKS", "2")
     monkeypatch.setenv("BENCH_WINDOW_BATCH", "2")
-    monkeypatch.setenv("BENCH_PALLAS", "0")
     monkeypatch.setenv("BENCH_RELEVANCE", "0")
     monkeypatch.setenv("BENCH_MEASURE_PEAK", "0")
     bench.main()

@@ -25,6 +25,9 @@ from edgellm_tpu.codecs import fec as fec_mod
 from edgellm_tpu.codecs.fec import (FECConfig, HedgeConfig, LinkHealth,
                                     LinkHealthConfig, fec_decode, fec_encode)
 from edgellm_tpu.codecs.faults import seal_payload
+from edgellm_tpu.codecs.packing import get_wire_codec
+from edgellm_tpu.codecs.wire_format import (WireFormat, flatten_bytes,
+                                            unflatten_bytes)
 from edgellm_tpu.models import init_params, tiny_config
 from edgellm_tpu.parallel import SplitConfig, SplitRuntime, make_stage_mesh
 from edgellm_tpu.utils.clock import FakeClock
@@ -542,3 +545,81 @@ def test_fault_report_prints_counters_and_health(capsys):
     assert "edgellm_link_health_burn_rate" in out
     _print_fault_report({})
     assert "no link counters" in capsys.readouterr().out
+
+
+# ---------- the flat wire stream the seal and the FEC framing stand on ----------
+
+
+def _sealed_payload(name="int8_per_token"):
+    codec = get_wire_codec(name)
+    hidden = jnp.asarray(np.random.default_rng(1).standard_normal((1, 4, 32)),
+                         jnp.float32)
+    return codec, hidden, seal_payload(codec.encode(hidden))
+
+
+def test_wire_format_roundtrip_is_the_sealed_tree():
+    codec, hidden, sealed = _sealed_payload()
+    wf = WireFormat.for_codec(codec, hidden.shape, hidden.dtype)
+    back = wf.from_wire(wf.to_wire(sealed))
+    for a, b in zip(jax.tree_util.tree_leaves(sealed),
+                    jax.tree_util.tree_leaves(back)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert wf.wire_nbytes == wf.payload_nbytes + 8
+
+
+@pytest.mark.parametrize("name", [
+    "int8_per_token", "int8_per_channel", "int4_per_token",
+    "int4_per_channel", "ternary_mean", "ternary_max"])
+def test_wire_format_carries_every_packed_codec(name):
+    """Each packed codec's payload tree (int8 codes, nibbles, crumbs, their
+    scales and minima) through one flat uint8 buffer and back: the seal
+    verifies and the decode is the bits of a decode that never left."""
+    codec, hidden, sealed = _sealed_payload(name)
+    wf = WireFormat.for_codec(codec, hidden.shape, hidden.dtype)
+    buf = wf.to_wire(sealed)
+    assert buf.dtype == jnp.uint8 and buf.shape == (wf.wire_nbytes,)
+    assert wf.payload_nbytes == codec.payload_bytes(hidden.shape)
+    back = wf.from_wire(buf)
+    assert bool(verify_payload(back))
+    want = np.asarray(codec.decode(codec.encode(hidden)))
+    np.testing.assert_array_equal(np.asarray(codec.decode(back["p"])), want)
+    assert not np.array_equal(want, np.asarray(hidden)), \
+        "decode identical to the raw hidden: quantization never happened"
+
+
+def test_corrupted_wire_buffer_fails_verification():
+    codec, hidden, sealed = _sealed_payload()
+    wf = WireFormat.for_codec(codec, hidden.shape, hidden.dtype)
+    buf = np.asarray(wf.to_wire(sealed))
+    assert bool(verify_payload(wf.from_wire(jnp.asarray(buf))))
+    for pos in (0, 7, 8, buf.size // 2, buf.size - 1):  # seal AND payload
+        bad = buf.copy()
+        bad[pos] ^= 0x40
+        assert not bool(verify_payload(wf.from_wire(jnp.asarray(bad)))), \
+            f"flipped byte {pos} slipped through the wire format"
+
+
+def test_fec_repairs_the_flat_wire_stream():
+    _, _, sealed = _sealed_payload()
+    cfg = FECConfig(group_size=4, n_groups=4)
+    wire = fec_encode(sealed, cfg)
+    chunks = np.asarray(wire["chunks"]).copy()
+    chunks[2, 1] ^= 0xA5  # one corrupted data chunk: XOR parity territory
+    got, any_bad, repaired = fec_decode(
+        {"chunks": jnp.asarray(chunks), "words": wire["words"]}, cfg, sealed)
+    assert bool(any_bad) and bool(repaired)
+    assert bool(verify_payload(got))
+    np.testing.assert_array_equal(np.asarray(flatten_bytes(got)),
+                                  np.asarray(flatten_bytes(sealed)))
+
+
+def test_flat_stream_is_shared_by_fec_and_wire_format():
+    # the FEC chunker and the wire format must serialize the SAME byte order
+    codec, hidden, sealed = _sealed_payload()
+    wf = WireFormat.for_codec(codec, hidden.shape, hidden.dtype)
+    np.testing.assert_array_equal(np.asarray(wf.to_wire(sealed)),
+                                  np.asarray(flatten_bytes(sealed)))
+    spec = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), sealed)
+    back = unflatten_bytes(wf.to_wire(sealed), spec)
+    assert bool(verify_payload(back))

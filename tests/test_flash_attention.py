@@ -7,9 +7,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from edgellm_tpu.models import flash_attention
 from edgellm_tpu.models.flash_attention import (causal_attention,
                                                 causal_attention_stats,
-                                                kernel_eligible,
                                                 kernel_plan,
                                                 _shape_plan)
 
@@ -58,24 +58,26 @@ def test_stats_kernel_matches_full_probs(rng):
 
 
 def test_model_attention_same_under_either_backend(rng, monkeypatch):
-    """Forcing the kernel into transformer.attention (EDGELLM_ATTN=pallas,
-    interpret on CPU) reproduces the default path's block output and stats."""
+    """The kernel in transformer.attention (the chooser told it is on a TPU;
+    the kernel itself still reads the real backend and runs interpreted)
+    reproduces the XLA path's block output and stats."""
     from edgellm_tpu.models import tiny_config, init_params
     from edgellm_tpu.models.transformer import forward, run_layers_from_ids
 
-    # hd must be in VALIDATED_HD (64) or the pallas force would silently take
-    # the XLA path and this test would compare XLA against XLA
+    # hd must be in VALIDATED_HD (64) or the chooser would answer the XLA
+    # path on a TPU too and this test would compare XLA against XLA
     cfg = tiny_config("qwen2", num_layers=3, hidden_size=256, num_heads=4,
                       vocab_size=128)
     params = init_params(cfg, jax.random.key(0))
     ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 32)))
 
-    monkeypatch.setenv("EDGELLM_ATTN", "xla")
+    assert kernel_plan(32, 4, 4, 64, itemsize=4) is None
     base, _ = forward(cfg, params, ids)
     _, aux = run_layers_from_ids(cfg, params, ids, capture_stats=True)
-    jax.clear_caches()  # attention() branches on env at trace time
+    jax.clear_caches()  # attention() asks the chooser at trace time
 
-    monkeypatch.setenv("EDGELLM_ATTN", "pallas")
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    assert kernel_plan(32, 4, 4, 64, itemsize=4) == ("whole", None)
     got, _ = forward(cfg, params, ids)
     _, aux_p = run_layers_from_ids(cfg, params, ids, capture_stats=True)
     jax.clear_caches()
@@ -87,31 +89,29 @@ def test_model_attention_same_under_either_backend(rng, monkeypatch):
                                np.asarray(aux["stats"].last_row), atol=1e-5)
 
 
-def test_kernel_eligibility(monkeypatch):
-    monkeypatch.delenv("EDGELLM_ATTN", raising=False)
-    # CPU default: no kernel (interpret mode would be slow, XLA is fine)
-    with pytest.warns(DeprecationWarning, match="kernel_plan"):
-        assert not kernel_eligible(512, 896)
-    # explicit head counts: no layout inference, no warning
-    assert not kernel_eligible(512, 896, num_heads=14, num_kv_heads=2)
-    monkeypatch.setenv("EDGELLM_ATTN", "pallas")
-    assert kernel_plan(512, 14, 2, 64) == ("whole", None)   # flagship
-    assert kernel_plan(512, 12, 2, 128) == ("whole", None)  # qwen2-1.5b
+@pytest.mark.parametrize("shape,plan", [
+    ((512, 14, 2, 64), ("whole", None)),            # flagship
+    ((512, 12, 2, 128), ("whole", None)),           # qwen2-1.5b
     # S=2048 — the reference's Pythia window: query-blocked kernel
-    assert kernel_plan(2048, 8, 8, 64) == ("blocked", (512, 8))
-    assert kernel_plan(2048, 14, 2, 64) == ("blocked", (512, 14))
+    ((2048, 8, 8, 64), ("blocked", (512, 8))),
+    ((2048, 14, 2, 64), ("blocked", (512, 14))),
     # llama-1b: packed row 2048 > whole-kernel envelope -> head-group split
-    assert kernel_plan(512, 32, 8, 64) == ("blocked", (512, 16))
-    assert kernel_plan(2048, 32, 8, 64) == ("blocked", (512, 16))
+    ((512, 32, 8, 64), ("blocked", (512, 16))),
+    ((2048, 32, 8, 64), ("blocked", (512, 16))),
     # beyond the blocked envelope, unvalidated hd, ragged GQA: XLA
-    assert kernel_plan(4096, 8, 8, 64) is None
-    assert kernel_plan(512, 8, 8, 80) is None      # ADVICE r4: hd gate
-    assert kernel_plan(512, 14, 4, 64) is None     # H % KV != 0
-    assert kernel_plan(1536, 8, 8, 64) == ("blocked", (512, 8))
-    assert kernel_plan(1100, 8, 8, 64) is None     # S not qb-aligned
-    monkeypatch.setenv("EDGELLM_ATTN", "xla")
-    with pytest.warns(DeprecationWarning, match="kernel_plan"):
-        assert not kernel_eligible(512, 896)
+    ((4096, 8, 8, 64), None),
+    ((512, 8, 8, 80), None),                        # ADVICE r4: hd gate
+    ((512, 14, 4, 64), None),                       # H % KV != 0
+    ((1536, 8, 8, 64), ("blocked", (512, 8))),
+    ((1100, 8, 8, 64), None),                       # S not qb-aligned
+    ((1024, 14, 2, 64), ("whole", None)),           # the whole-S edge
+])
+def test_kernel_plan(monkeypatch, shape, plan):
+    """On a TPU the plan is the shape's; everywhere else there is none
+    (interpret mode would be slow, XLA is fine)."""
+    assert kernel_plan(*shape) is None
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    assert kernel_plan(*shape) == plan
 
 
 def test_shape_plan_scales_whole_s_by_itemsize():

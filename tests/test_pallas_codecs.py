@@ -5,10 +5,12 @@ import pytest
 
 import jax.numpy as jnp
 
-from edgellm_tpu.codecs.packing import get_wire_codec, selective_int4
+from edgellm_tpu.codecs import pallas_kernels
+from edgellm_tpu.codecs.packing import (WIRE_CODECS, get_wire_codec,
+                                        selective_int4)
 from edgellm_tpu.codecs.pallas_kernels import (
     SELECTIVE_EXCLUSION, int4_encode_pallas, int4_decode_pallas,
-    pallas_wire_codec, pallas_int8_per_token, pallas_ternary, pallas_variant,
+    pallas_wire_codec, pallas_variant,
 )
 
 
@@ -59,13 +61,12 @@ def _assert_payload_equal(got: dict, want: dict):
             np.testing.assert_allclose(g, w, rtol=1e-7, err_msg=key)
 
 
-@pytest.mark.parametrize("name", ["int8_per_token", "int8_per_channel",
-                                  "int4_per_channel", "ternary_mean",
+@pytest.mark.parametrize("name", ["int4_per_channel", "ternary_mean",
                                   "ternary_max"])
 def test_pallas_twins_bit_identical(hidden, name):
     jnp_codec = get_wire_codec(name)
-    pallas_codec = pallas_variant(jnp_codec)
-    assert pallas_codec is not None and pallas_codec.name == name + "_pallas"
+    pallas_codec = get_wire_codec(name + "_pallas")
+    assert pallas_codec.name == name + "_pallas"
     want = jnp_codec.encode(hidden)
     got = pallas_codec.encode(hidden)
     _assert_payload_equal(got, want)
@@ -73,80 +74,150 @@ def test_pallas_twins_bit_identical(hidden, name):
                                np.asarray(jnp_codec.decode(want)), atol=1e-6)
 
 
-def test_selective_has_no_kernel_twin_by_measurement():
+def test_selective_has_no_kernel_twin_by_measurement(monkeypatch):
     """The selective codec's Pallas twin was DELETED in round 5 on silicon
     measurement (gather-bound; the kernel boundary broke XLA's gather->quant
     fusion, 0.96-0.97x across rounds). The exclusion is a recorded decision:
-    pallas_variant returns None on every path and the runtimes fall back to
+    pallas_variant returns None on a TPU too and the runtimes fall back to
     the jnp codec, which IS the TPU-native implementation."""
-    import edgellm_tpu.codecs.pallas_kernels as pk
-
     jnp_codec = selective_int4(0.5, "bf16")
     assert pallas_variant(jnp_codec) is None
-    assert pallas_variant(jnp_codec, measured_wins_only=True) is None
-    assert not hasattr(pk, "pallas_selective_int4")
+    monkeypatch.setattr(pallas_kernels, "_on_tpu", lambda: True)
+    assert pallas_variant(jnp_codec) is None
+    assert not hasattr(pallas_kernels, "pallas_selective_int4")
     assert "gather-bound" in SELECTIVE_EXCLUSION
 
 
 def test_registry_exposes_pallas_names():
-    codec = get_wire_codec("int8_per_token_pallas")
-    assert codec.name == "int8_per_token_pallas"
+    """An explicit ``*_pallas`` name is a codec on any backend; the int8
+    twins, which no default ever picked, are no codec any more."""
+    for name in WIRE_CODECS:
+        assert get_wire_codec(name).name == name
+    assert sorted(n for n in WIRE_CODECS if n.endswith("_pallas")) == [
+        "int4_per_channel_pallas", "int4_per_token_pallas",
+        "ternary_max_pallas", "ternary_mean_pallas"]
+    for gone in ("int8_per_token_pallas", "int8_per_channel_pallas"):
+        with pytest.raises(ValueError, match="unknown wire codec"):
+            get_wire_codec(gone)
 
 
-def test_split_runtime_substitutes_pallas_when_forced(rng, monkeypatch):
-    """EDGELLM_PALLAS=1 swaps jnp hop codecs for their fused twins (the TPU
-    default path, exercised here on CPU interpret mode)."""
+#: every codec a hop can ask for by name, and what the hop runs on a TPU
+_TWINNED = ("int4_per_token", "int4_per_channel", "ternary_mean",
+            "ternary_max")
+_ASKED = [n for n in WIRE_CODECS if not n.endswith("_pallas")] + [
+    "selective_int4:0.5:bf16", "int4_per_token_pallas"]
+
+
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("asked", _ASKED)
+def test_hop_codec_is_chosen_from_the_table_and_the_backend(monkeypatch,
+                                                            asked, on_tpu):
+    """The one place a hop's codec is chosen: the twin's name where the table
+    holds the base codec AND the chooser sees a TPU, the codec asked for
+    everywhere else; an explicit ``*_pallas`` name is honoured on both."""
+    from edgellm_tpu.eval.split_eval import parse_hop_codec
+    from edgellm_tpu.parallel.split import apply_default_codec_backend
+
+    monkeypatch.setattr(pallas_kernels, "_on_tpu", lambda: on_tpu)
+    spec = parse_hop_codec(asked)
+    own = spec if isinstance(spec, str) else spec.name
+    (got,) = apply_default_codec_backend([spec])
+    want = own + "_pallas" if on_tpu and own in _TWINNED else own
+    assert got.name == want
+    if got.name != own:
+        assert got.batch_invariant == get_wire_codec(own).batch_invariant
+
+
+def test_split_cell_dispatch_at_a_toy_size(rng, monkeypatch):
+    """The split cell's three hops (int8 / int4 / int8 per token over four
+    stages) on a TPU: cuts 0 and 2 cross as the jnp codec, cut 1 as the int4
+    twin, and the forward is the all-jnp runtime's."""
     import jax
     from edgellm_tpu.models import tiny_config, init_params
     from edgellm_tpu.parallel import SplitConfig, SplitRuntime, make_stage_mesh
 
-    monkeypatch.setenv("EDGELLM_PALLAS", "1")
-    cfg = tiny_config("qwen2", num_layers=4, hidden_size=32, num_heads=4, vocab_size=128)
+    cfg = tiny_config("qwen2", num_layers=8, hidden_size=32, num_heads=4,
+                      vocab_size=128)
+    split = SplitConfig(cuts=(1, 3, 5), hop_codecs=(
+        "int8_per_token", "int4_per_token", "int8_per_token"))
     params = init_params(cfg, jax.random.key(1))
     ids = jnp.asarray(rng.integers(0, 128, (1, 16)))
-    rt = SplitRuntime(cfg, SplitConfig(cuts=(1,), hop_codecs=("int8_per_token",)),
-                      make_stage_mesh(2))
-    assert rt.codecs[0].name == "int8_per_token_pallas"
-    monkeypatch.setenv("EDGELLM_PALLAS", "0")
-    rt_j = SplitRuntime(cfg, SplitConfig(cuts=(1,), hop_codecs=("int8_per_token",)),
-                        make_stage_mesh(2))
-    assert rt_j.codecs[0].name == "int8_per_token"
+    rt_j = SplitRuntime(cfg, split, make_stage_mesh(4))
+    assert [c.name for c in rt_j.codecs] == list(split.hop_codecs)
+    monkeypatch.setattr(pallas_kernels, "_on_tpu", lambda: True)
+    rt = SplitRuntime(cfg, split, make_stage_mesh(4))
+    assert [c.name for c in rt.codecs] == [
+        "int8_per_token", "int4_per_token_pallas", "int8_per_token"]
     out_p = rt.forward(rt.place_params(params), ids)
     out_j = rt_j.forward(rt_j.place_params(params), ids)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_j),
                                atol=1e-6, rtol=1e-6)
 
 
-def test_default_substitution_is_gated_on_measured_wins(monkeypatch, tmp_path):
-    """The TPU default path substitutes only kernels measured as wins for
-    this chip (probe cache, frozen set as no-data fallback); int8_per_channel
-    (0.94x) stays jnp, the selective twin no longer exists at all, and
-    EDGELLM_PALLAS=1 forces every REMAINING twin. Explicit *_pallas pins are
-    always honored."""
+#: the five variables and the file that once steered a dispatch, with every
+#: value they took
+_OLD_SWITCHES = [
+    ("EDGELLM_ATTN", "pallas"), ("EDGELLM_ATTN", "xla"),
+    ("EDGELLM_PALLAS", "0"), ("EDGELLM_PALLAS", "1"),
+    ("EDGELLM_FUSED_HOP", "0"), ("EDGELLM_FUSED_HOP", "1"),
+    ("EDGELLM_FUSED_HOP", "wire"), ("EDGELLM_FUSED_HOP", "remote"),
+    ("EDGELLM_PROBE_ALL", "1"), ("EDGELLM_PROBE_CACHE", "<file>"),
+]
+
+
+@pytest.mark.parametrize("var,value", _OLD_SWITCHES)
+def test_old_dispatch_switches_are_inert(monkeypatch, tmp_path, var, value):
+    """With an old variable set, and a probe cache under ``$HOME`` that calls
+    int8_per_token a win and int4_per_token a loss, the prefill's kernel, a
+    hop's codec and the graph of a cut are what they are without them, on a
+    TPU and off it."""
+    import json
+
     import jax
-    from edgellm_tpu.codecs.packing import selective_int4
+    from edgellm_tpu.lint.contracts import graph_fingerprint
+    from edgellm_tpu.models import flash_attention, init_params, tiny_config
+    from edgellm_tpu.parallel import SplitConfig, SplitRuntime, make_stage_mesh
     from edgellm_tpu.parallel.split import apply_default_codec_backend
 
-    monkeypatch.delenv("EDGELLM_PALLAS", raising=False)
-    # point the policy at an empty cache: the frozen fallback set decides
-    monkeypatch.setenv("EDGELLM_PROBE_CACHE", str(tmp_path / "none.json"))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    out = apply_default_codec_backend(
-        ["int4_per_token", "int8_per_token", selective_int4(0.5, "bf16"),
-         "int8_per_channel_pallas"])
-    assert [c.name for c in out] == [
-        "int4_per_token_pallas",       # measured win (1.33x) -> substituted
-        "int8_per_token",              # 0.80x -> stays jnp
-        "selective_int4_r0.5_bf16",    # twin deleted on measurement
-        "int8_per_channel_pallas",     # explicit pin honored
-    ]
+    cfg = tiny_config("qwen2", num_layers=4, hidden_size=32, num_heads=4,
+                      vocab_size=128)
+    split = SplitConfig(cuts=(1,), hop_codecs=("int8_per_token",))
+    asked = ["int8_per_token", "int4_per_token", "int8_per_channel"]
+    params = init_params(cfg, jax.random.key(0))
+    ids, imps = jnp.zeros((1, 8), jnp.int32), jnp.zeros((1, 8), jnp.float32)
 
-    monkeypatch.setenv("EDGELLM_PALLAS", "1")
-    forced = apply_default_codec_backend(
-        ["int8_per_channel", selective_int4(0.5, "bf16")])
-    # even forced substitution cannot resurrect a deleted twin
-    assert [c.name for c in forced] == [
-        "int8_per_channel_pallas", "selective_int4_r0.5_bf16"]
+    def answers():
+        rt = SplitRuntime(cfg, split, make_stage_mesh(2))
+        return (flash_attention.kernel_plan(512, 14, 2, 64),
+                [c.name for c in apply_default_codec_backend(asked)],
+                graph_fingerprint(rt._forward, rt.place_params(params), ids,
+                                  imps))
+
+    def both_backends():
+        out = []
+        for on_tpu in (False, True):
+            monkeypatch.setattr(flash_attention, "_on_tpu", lambda: on_tpu)
+            monkeypatch.setattr(pallas_kernels, "_on_tpu", lambda: on_tpu)
+            out.append(answers())
+        return out
+
+    for name, _ in _OLD_SWITCHES:
+        monkeypatch.delenv(name, raising=False)
+    want = both_backends()
+    assert want[0][:2] == (None, asked)
+    assert want[1][:2] == (("whole", None), [
+        "int8_per_token", "int4_per_token_pallas", "int8_per_channel"])
+
+    cache = tmp_path / ".cache" / "edgellm_tpu" / "pallas_wins.json"
+    cache.parent.mkdir(parents=True)
+    wins = {"speedups": {"int8_per_token": 2.0, "int4_per_token": 0.5,
+                         "fused_hop:int8_per_token": 2.0}}
+    cache.write_text(json.dumps({f"{jax.default_backend()}:"
+                                 f"{jax.devices()[0].device_kind}": wins,
+                                 "tpu:TPU v5 lite": wins}))
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv(var, str(cache) if value == "<file>" else value)
+    assert both_backends() == want
 
 
 def test_pallas_codec_in_split_runtime(rng):

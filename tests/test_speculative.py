@@ -10,7 +10,7 @@ agreed. Also covered here:
   quantized (1, k, D) boundary block carries the same information as k
   single-token hops);
 - the verify wire-byte contract: one burst's hop bytes == k x the
-  single-token hop bytes (the fused +8-byte seal is graphlint's half);
+  single-token hop bytes;
 - kill-between-draft-and-verify checkpoint/resume: the resumed stream is
   token-identical to the uninterrupted run at k in {1, 4, 8} (burst
   boundaries depend only on the committed prefix);
@@ -156,9 +156,7 @@ def test_verify_step_matches_stepwise_decode(setup):
 @pytest.mark.parametrize("k", KS)
 def test_verify_hop_bytes_scale_linearly(setup, k):
     """ONE verify burst moves exactly k single-token payloads' worth of
-    bytes per hop — the amortization claim is in round-trips, not bytes
-    (the fused-mode k x hop_bytes + 8 framing is checked by graphlint's
-    split.verify_step.fused contract)."""
+    bytes per hop — the amortization claim is in round-trips, not bytes."""
     rt = setup["rt"]
     (per_burst,) = rt.verify_hop_bytes(1, k)
     (per_step,) = rt.decode_hop_bytes(1)
@@ -380,7 +378,7 @@ def test_params_validation_accepts_spec_config():
     ({"speculative": {"k": 4, "window": 2}}, "unknown field"),
     ({"speculative": {"k": 0}}, "k must be in"),
     ({"speculative": {"k": 4, "draft_source": "ngram"}}, "draft_source"),
-    ({"fused_hops": "remote"}, "unprobed"),
+    ({"fused_hops": "remote"}, "unknown key"),
     ({"batching": {"page_size": 8, "num_pages": 17, "max_slots": 4,
                    "pages_per_slot": 4}}, "drop"),
 ])
