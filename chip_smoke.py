@@ -421,7 +421,8 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
     ``highest``: its tokens equal an undisturbed stream's, served a launch
     ahead and in the old order, and ``forward`` over prompt + tokens puts
     each of them first. Returns (the batcher's report with its
-    ``launch_ahead_share``, the largest gap over the largest logit)."""
+    ``launch_ahead_share`` and the ``served`` tokens, the largest gap over
+    the largest logit)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -464,6 +465,7 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
     # the eviction's drain and the launch after it are the old order; every
     # other launch found the step before it unread
     report["launch_ahead_share"] = launch_ahead_share(report)
+    report["served"] = got.tolist()
     assert report["steps_ahead"] >= report["steps"] - 3, report
     return report, float(gaps.max() / scale)
 
@@ -668,6 +670,67 @@ def longcat_phase(*, prompt_len: int = 300, n_new: int = 40,
             "gap_max_over_logit_max": gap}
 
 
+def shortconv_phase(*, prompt_len: int = 300, n_new: int = 40,
+                    evict_after: int = 20) -> dict:
+    """A tiny ``lfm2_moe`` stream (gated short convolutions of 3 taps that
+    keep a window of two rows a slot, 5:1 beside a rotated GQA layer with
+    per-head q/k norms; two dense layers, then experts routed by sigmoid
+    scores with a nonzero selection bias and none shared; a tied head,
+    float32) through the same admit / step / evict / readmit: the windows,
+    the state store's ONE leaf, leave the device with the K/V rows and come
+    back, and ``forward`` over prompt + tokens puts each served token first.
+    The attention layer's read is the page walk (rows of 2 KV heads x 64 =
+    one lane tile), the prompt is past ``moe.DENSE_MAX_TOKENS`` and the
+    expert layers are whole lane tiles, so the prefill takes the grouped
+    products as the kernel. The matrices are seeded 3x wider than
+    ``init_params`` leaves them (0.02 x sqrt(256) shrinks a projection's
+    input threefold) and the router 15x: under a tied table a stack whose
+    layers add next to nothing repeats its last token, and such a stream
+    would pass with any window."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from edgellm_tpu.models import grouped_matmul
+    from edgellm_tpu.models.configs import tiny_lfm2_moe_config
+    from edgellm_tpu.models.paged_kv import PAGE_WALK
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    def wider(params):
+        def scale(path, a):
+            name = path[-1].key
+            if name in ("router", "router_bias"):
+                return a * 15.0
+            return a * 3.0 if name.startswith("w") else a
+        return jax.tree_util.tree_map_with_path(scale, params)
+
+    cfg = dataclasses.replace(tiny_lfm2_moe_config(
+        hidden_size=256, num_heads=4, num_kv_heads=2), expert_width=128)
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=24)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after,
+                                 wider)
+    assert cfg.conv_layers == 5 and cfg.kv_layers == 1
+    assert report["state_leaf_bytes"] == {"conv": 5 * 3 * 2 * 256 * 4}
+    assert report["state_bytes"] == 5 * 3 * 2 * 256 * 4
+    assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    assert report["grouped_product"] == grouped_matmul.PALLAS_GROUPED, \
+        report["grouped_product"]
+    assert len(report["expert_tokens"]) == cfg.expert_layers == 4
+    assert len(np.unique(report["served"])) > n_new // 4, report["served"]
+    return {"tokens": int(n_new), "distinct_tokens":
+            int(len(np.unique(report["served"]))),
+            "evicted": report["evicted"],
+            "state_leaf_bytes": report["state_leaf_bytes"],
+            "decode_read": report["decode_read"],
+            "grouped_product": report["grouped_product"],
+            "routed_local": report["routed_local"],
+            "launch_ahead_share": report["launch_ahead_share"],
+            "gap_max_over_logit_max": gap}
+
+
 def smoke(report: dict, save) -> dict:
     """Every phase in order, at full width. ``save()`` persists ``report``
     after each phase so a failed run leaves what it learned."""
@@ -703,6 +766,7 @@ def smoke(report: dict, save) -> dict:
     phase("latent", latent_phase)
     phase("afmoe", afmoe_phase)
     phase("longcat", longcat_phase)
+    phase("shortconv", shortconv_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

@@ -332,16 +332,14 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     hstate = paged_kv.init_slot_state(hcfg, MS)
     hcount = jnp.zeros((hcfg.num_layers, hcfg.local_experts), jnp.int32)
     run_one("paged.decode_step_hybrid",
-            lambda p, pk, pv, cv, sm, ct, pt, ln, t:
+            lambda p, pk, pv, st, ct, pt, ln, t:
                 hybrid.paged_decode_step_hybrid(
-                    hcfg, p, pk, pv, cv, sm, ct, pt, ln, t),
-            (hparams, hpool.k, hpool.v, hstate.conv, hstate.ssm, hcount,
-             ptab, plens, ptoks),
+                    hcfg, p, pk, pv, st, ct, pt, ln, t),
+            (hparams, hpool.k, hpool.v, hstate, hcount, ptab, plens, ptoks),
             ctx={"donate_min": 5},
             lowerable=batching._batched_hybrid_step_jit,
-            lower_args=(hcfg, hparams, hpool.k, hpool.v, hstate.conv,
-                        hstate.ssm, hcount, ptab, plens, ptoks, pkeys,
-                        qsteps, qtemps, None))
+            lower_args=(hcfg, hparams, hpool.k, hpool.v, hstate, hcount,
+                        ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # ---- a stack with sliding-window layers (mellum): the same walk with
     # ---- the window group — collective-free; the full layers' pool, the
@@ -359,7 +357,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     run_one("paged.decode_step_window",
             lambda p, pk, pv, wk, wv, ct, pt, wt, ln, t:
                 hybrid.paged_decode_step_hybrid(
-                    wcfg, p, pk, pv, None, None, ct, pt, ln, t,
+                    wcfg, p, pk, pv, None, ct, pt, ln, t,
                     window=(wk, wv, wt)),
             (wparams, wfull.k, wfull.v, wring.k, wring.v, wcount, ptab, wtab,
              plens, ptoks),
@@ -380,11 +378,32 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     lcount = jnp.zeros((lcfg.num_layers, lcfg.local_experts), jnp.int32)
     run_one("paged.decode_step_latent",
             lambda p, rows, ct, pt, ln, t: hybrid.paged_decode_step_hybrid(
-                lcfg, p, rows, None, None, None, ct, pt, ln, t),
+                lcfg, p, rows, None, None, ct, pt, ln, t),
             (lparams, lpool.rows, lcount, ptab, plens, ptoks),
             ctx={"donate_min": 2},
             lowerable=batching._batched_hybrid_step_jit,
-            lower_args=(lcfg, lparams, lpool.rows, None, None, None, lcount,
+            lower_args=(lcfg, lparams, lpool.rows, None, None, lcount,
+                        ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
+
+    # ---- a stack of short convolutions beside rotated attention layers
+    # ---- (lfm2_moe): the same executable with a state store of ONE leaf,
+    # ---- the windows — collective-free; the K/V pages, the windows and the
+    # ---- expert counter, FOUR buffers, stay donated ----------------------
+    from ..models.configs import tiny_lfm2_moe_config
+
+    ccfg = tiny_lfm2_moe_config()
+    cparams = transformer.init_params(ccfg, jax.random.key(0))
+    cpool = paged_kv.init_pool(ccfg, NPG, PGS)
+    cstate = paged_kv.init_slot_state(ccfg, MS)
+    ccount = jnp.zeros((ccfg.expert_layers, ccfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_shortconv",
+            lambda p, pk, pv, st, ct, pt, ln, t:
+                hybrid.paged_decode_step_hybrid(
+                    ccfg, p, pk, pv, st, ct, pt, ln, t),
+            (cparams, cpool.k, cpool.v, cstate, ccount, ptab, plens, ptoks),
+            ctx={"donate_min": 4},
+            lowerable=batching._batched_hybrid_step_jit,
+            lower_args=(ccfg, cparams, cpool.k, cpool.v, cstate, ccount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state

@@ -73,8 +73,17 @@ class ModelConfig:
         no weights (``E(u) = u``); the latent query and the normalised latent
         are multiplied by ``sqrt(hidden / rank)`` (``rank_scales``); plain
         RoPE, an untied head (Meituan LongCat-Flash).
+      - ``"lfm2_moe"``: granite's walk with another recurrent kind,
+        ``"conv"`` (``models/shortconv.py``: a gated depthwise convolution of
+        ``conv_window`` taps whose whole memory of a sequence is the last
+        ``conv_window - 1`` rows of a product, no matrix state), beside
+        ``"attention"`` layers that ROTATE (plain RoPE) after a per-head
+        RMSNorm on q and k; the first ``num_dense_layers`` feed-forwards a
+        dense SwiGLU, the rest routed by ``"sigmoid"`` scores with a
+        selection bias and no shared expert, the weights normalised over
+        ``+ 1e-6`` (``route_norm_eps``); a tied head (LiquidAI LFM2).
 
-    The fields after ``rope_scaling`` exist for those five families and
+    The fields after ``rope_scaling`` exist for those six families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -99,7 +108,7 @@ class ModelConfig:
     #: layers' table; the window layers rotate by the plain one (Mellum's
     #: ``rope_parameters`` by layer kind).
     rope_scaling: Optional[tuple] = None
-    #: per-layer mixer kind, ``"mamba"``, ``"attention"``,
+    #: per-layer mixer kind, ``"mamba"``, ``"conv"``, ``"attention"``,
     #: ``"sliding_attention"`` or ``"latent_attention"``; empty = every layer
     #: is the family's one block
     layer_types: tuple = ()
@@ -169,6 +178,9 @@ class ModelConfig:
     #: and its normalised latent times ``sqrt(hidden_size / kv_lora_rank)``
     #: where the row is made (``models/mla.py``)
     rank_scales: bool = False
+    #: taps of a ``"conv"`` layer's depthwise causal convolution (HF's
+    #: ``conv_L_cache``): a sequence keeps ``conv_window - 1`` rows a layer
+    conv_window: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -203,7 +215,7 @@ class ModelConfig:
         """Walked by layer kinds (``models/hybrid.py``), with params held per
         kind and a routed expert layer after every mixer."""
         return self.family in ("granitemoehybrid", "mellum", "mistral4",
-                               "afmoe", "longcat_flash")
+                               "afmoe", "longcat_flash", "lfm2_moe")
 
     @property
     def expert_layers(self) -> int:
@@ -255,11 +267,18 @@ class ModelConfig:
         return ("attention",) if self.family == "afmoe" else ()
 
     @property
+    def route_norm_eps(self) -> float:
+        """What a ``"sigmoid"`` router adds to the sum of the chosen scores
+        it normalises by: the family's own constant, not a setting."""
+        return 1e-6 if self.family == "lfm2_moe" else 1e-20
+
+    @property
     def recurrent_state(self) -> bool:
-        """Keeps a sequence's state as more than K/V rows (Mamba-2's
-        convolution window and SSM state): what
+        """Keeps a sequence's state as more than K/V rows (a Mamba-2 layer's
+        convolution window and SSM state, a short convolution's window): a
+        property of the layer kinds, which ``hybrid.state_shapes`` sizes and
         ``hybrid.refuse_recurrent_state`` refuses by."""
-        return self.family == "granitemoehybrid"
+        return any(t in ("mamba", "conv") for t in self.layer_types)
 
     @property
     def window_layers(self) -> int:
@@ -285,6 +304,11 @@ class ModelConfig:
     @property
     def mamba_layers(self) -> int:
         return sum(1 for t in self.layer_types if t == "mamba")
+
+    @property
+    def conv_layers(self) -> int:
+        """Gated short-convolution layers (``models/shortconv.py``)."""
+        return sum(1 for t in self.layer_types if t == "conv")
 
     @property
     def mamba_d_inner(self) -> int:
@@ -319,7 +343,7 @@ class ModelConfig:
     def __post_init__(self):
         if self.family not in ("gpt_neox", "qwen2", "llama",
                                "granitemoehybrid", "mellum", "mistral4",
-                               "afmoe", "longcat_flash"):
+                               "afmoe", "longcat_flash", "lfm2_moe"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
@@ -327,12 +351,12 @@ class ModelConfig:
               or self.explicit_head_dim or self.sliding_window
               or self.kv_lora_rank or self.num_dense_layers
               or self.score_func != "softmax" or self.zero_experts
-              or self.rank_scales):
+              or self.rank_scales or self.conv_window):
             raise ValueError(
                 f"layer_types / experts / mamba / head width / window / "
-                f"latent / dense-layer / routing fields belong to "
-                f"the granitemoehybrid, mellum, mistral4, afmoe and "
-                f"longcat_flash families, not {self.family!r}")
+                f"latent / dense-layer / routing / short-convolution fields "
+                f"belong to the granitemoehybrid, mellum, mistral4, afmoe, "
+                f"longcat_flash and lfm2_moe families, not {self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
@@ -343,7 +367,8 @@ class ModelConfig:
                  "mellum": ("attention", "sliding_attention"),
                  "mistral4": ("latent_attention",),
                  "afmoe": ("attention", "sliding_attention"),
-                 "longcat_flash": ("latent_attention",)}[self.family]
+                 "longcat_flash": ("latent_attention",),
+                 "lfm2_moe": ("conv", "attention")}[self.family]
         if len(self.layer_types) != self.num_layers * self.sublayers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
@@ -403,6 +428,10 @@ class ModelConfig:
                              "d_conv >= 2 and a chunk length")
         if self.mamba_heads % self.mamba_n_groups:
             raise ValueError("mamba_n_groups must evenly divide mamba_heads")
+        if bool(self.conv_layers) != bool(self.conv_window) or (
+                self.conv_layers and self.conv_window < 2):
+            raise ValueError("conv_window (>= 2 taps) belongs to conv layers, "
+                             "and those need it")
 
 
 # EleutherAI/pythia-70m — facts per SURVEY.md section 2.1 (6 layers, d=512, 8 heads,
@@ -638,6 +667,63 @@ LONGCAT_FLASH_CHAT = ModelConfig(
 )
 
 
+# LiquidAI/LFM2-8B-A1B (8.3B-A1.5B, 2025-10) — config.json (``model_type``
+# ``lfm2_moe``): 24 layers, 18 gated short convolutions of 3 taps beside 6
+# rotated GQA layers (32 query / 8 KV heads of 64, q and k normed per head),
+# d 2048; two leading dense layers of width 7168, then 32 routed experts of
+# width 1792 top-4 by sigmoid scores with a selection bias, weights
+# normalised, no shared expert; tied 65536-row table.
+_LFM2_LAYERS = tuple(
+    "attention" if layer in (2, 6, 10, 14, 18, 21) else "conv"
+    for layer in range(24))
+LFM2_8B_A1B = ModelConfig(
+    family="lfm2_moe",
+    vocab_size=65536,
+    hidden_size=2048,
+    num_layers=24,
+    num_heads=32,
+    num_kv_heads=8,
+    intermediate_size=7168,
+    max_position_embeddings=128000,
+    norm_eps=1e-5,
+    rope_theta=1000000.0,
+    tie_word_embeddings=True,
+    layer_types=_LFM2_LAYERS,
+    num_experts=32,
+    experts_per_tok=4,
+    expert_width=1792,
+    num_dense_layers=2,
+    score_func="sigmoid",
+    conv_window=3,
+)
+
+
+def tiny_lfm2_moe_config(*, layer_types: tuple = ("conv", "conv", "attention",
+                                                  "conv", "conv", "conv"),
+                         num_dense_layers: int = 2, conv_window: int = 3,
+                         hidden_size: int = 48, num_heads: int = 4,
+                         num_kv_heads: int = 2, vocab_size: int = 256,
+                         num_experts: int = 8, experts_per_tok: int = 3,
+                         experts_held: int = 0, expert_offset: int = 0,
+                         max_position_embeddings: int = 512) -> ModelConfig:
+    """A small lfm2_moe for tests: every mechanism of the published model
+    (short convolutions 3:1 beside a rotated, q/k-normed GQA layer in the
+    published order, two leading dense layers, sigmoid routing with a
+    selection bias and no shared expert, a tied head) at toy widths; the
+    taps settable."""
+    return ModelConfig(
+        family="lfm2_moe", vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=len(layer_types), num_heads=num_heads,
+        num_kv_heads=num_kv_heads, intermediate_size=96,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-5,
+        rope_theta=1000000.0, tie_word_embeddings=True,
+        layer_types=tuple(layer_types), num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32,
+        experts_held=experts_held, expert_offset=expert_offset,
+        num_dense_layers=num_dense_layers, score_func="sigmoid",
+        conv_window=conv_window)
+
+
 def tiny_longcat_flash_config(*, num_layers: int = 2, hidden_size: int = 48,
                               num_heads: int = 4, vocab_size: int = 256,
                               num_experts: int = 8, zero_experts: int = 4,
@@ -788,6 +874,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_afmoe_config()
     if family == "longcat_flash":
         return tiny_longcat_flash_config()
+    if family == "lfm2_moe":
+        return tiny_lfm2_moe_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -818,6 +906,7 @@ PRESETS = {
     "mistral-small-4-119b": MISTRAL_SMALL_4_119B,
     "trinity-mini": TRINITY_MINI,
     "longcat-flash-chat": LONGCAT_FLASH_CHAT,
+    "lfm2-8b-a1b": LFM2_8B_A1B,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
@@ -827,4 +916,5 @@ PRESETS = {
     "tiny-mistral4": tiny_mistral4_config(),
     "tiny-afmoe": tiny_afmoe_config(),
     "tiny-longcat-flash": tiny_longcat_flash_config(),
+    "tiny-lfm2-moe": tiny_lfm2_moe_config(),
 }

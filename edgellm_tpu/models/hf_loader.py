@@ -96,6 +96,8 @@ def params_from_state_dict(cfg: ModelConfig, sd: dict) -> dict:
     """Build the framework's parameter pytree from a HF torch state_dict."""
     if cfg.family == "gpt_neox":
         return _neox_params(cfg, sd)
+    if cfg.family == "lfm2_moe":
+        return _lfm2_moe_params(cfg, sd)
     if cfg.is_hybrid:
         raise ValueError(
             f"no state_dict mapping for family {cfg.family!r}: its parameters "
@@ -259,6 +261,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         return _afmoe_config(hf_config)
     if mt == "longcat_flash":
         return _longcat_flash_config(hf_config)
+    if mt == "lfm2_moe":
+        return _lfm2_moe_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -490,3 +494,119 @@ def _afmoe_config(hf_config) -> ModelConfig:
         score_func="sigmoid",
         route_scale=float(hf_config.route_scale),
     )
+
+
+#: the published names of LFM2's layer kinds -> ModelConfig's
+LFM2_LAYER_KINDS = {"conv": "conv", "full_attention": "attention"}
+
+
+def _lfm2_moe_config(hf_config) -> ModelConfig:
+    """LiquidAI LFM2 MoE (``model_type`` ``lfm2_moe``). The keys mapped:
+    ``layer_types`` (``conv`` / ``full_attention``: the attention layers
+    rotate by plain RoPE after a per-head norm on q and k), ``conv_L_cache``
+    (the short convolution's taps), ``norm_eps``, ``num_dense_layers`` (the
+    leading layers whose feed-forward is a SwiGLU of ``intermediate_size``),
+    ``num_experts``, ``num_experts_per_tok``, ``moe_intermediate_size``,
+    ``routed_scaling_factor``; sigmoid scores, ``use_expert_bias`` (the
+    selection bias) and ``norm_topk_prob`` (weights normalised over the
+    chosen, ``+ 1e-6``); the head is tied unless the file says otherwise.
+    Refused by name: a bias on the convolution or its projections, a router
+    without the selection bias, unnormalised top-k weights, a rope scaling."""
+    for key, want in (("conv_bias", False), ("use_expert_bias", True),
+                      ("norm_topk_prob", True), ("rope_scaling", None)):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"lfm2_moe with {key}={getattr(hf_config, key)!r} is not "
+                f"supported (only {want!r})")
+    return ModelConfig(
+        family="lfm2_moe",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.norm_eps,
+        rope_theta=float(hf_config.rope_theta),
+        tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", True),
+        layer_types=tuple(LFM2_LAYER_KINDS[t] for t in hf_config.layer_types),
+        num_experts=int(hf_config.num_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+        num_dense_layers=int(hf_config.num_dense_layers),
+        score_func="sigmoid",
+        route_scale=float(hf_config.routed_scaling_factor),
+        conv_window=int(hf_config.conv_L_cache),
+    )
+
+
+def _lfm2_moe_params(cfg: ModelConfig, sd: dict) -> dict:
+    """``models/hybrid.py``'s per-kind tree from an ``lfm2_moe`` state_dict,
+    all the experts held. Tensor names as the modelling code publishes them
+    (from memory of it: no checkpoint can be fetched here): ``model.layers.N.``
+    ``operator_norm`` / ``ffn_norm``; a conv layer's ``conv.in_proj`` (3D, D:
+    B, C, x in that order), ``conv.conv`` (D, 1, L) and ``conv.out_proj``; an
+    attention layer's ``self_attn.{q,k,v}_proj``, ``self_attn.out_proj``,
+    ``self_attn.q_layernorm`` / ``k_layernorm``; ``feed_forward.{w1,w3,w2}``
+    (gate, up, down) dense, or ``feed_forward.gate`` (the router),
+    ``feed_forward.expert_bias`` and ``feed_forward.experts.M.{w1,w3,w2}``;
+    ``model.embedding_norm`` is the norm ahead of the (tied) head. Refused by
+    name: a tensor the model as built does not hold (a convolution bias)."""
+    if cfg.experts_held or not cfg.tie_word_embeddings:
+        raise ValueError("the lfm2_moe state_dict mapping holds every expert "
+                         "and a tied head")
+    for name in sd:
+        if name.endswith(("conv.conv.bias", "in_proj.bias", "out_proj.bias")):
+            raise ValueError(f"lfm2_moe with a convolution bias ({name}) is "
+                             f"not supported (conv_bias false only)")
+    pre = "model.layers.{i}."
+    kinds = {kind: [i for i, t in enumerate(cfg.layer_types) if t == kind]
+             for kind in ("conv", "attention")}
+
+    def rows(kind, suffix, transform=lambda w: w.T):
+        return jnp.asarray(np.stack([
+            transform(_np(sd[pre.format(i=i) + suffix]))
+            for i in kinds[kind]]))
+
+    def ffn(i):
+        ff = pre.format(i=i) + "feed_forward."
+        out = {"ln2_scale": jnp.asarray(
+            _np(sd[pre.format(i=i) + "ffn_norm.weight"]))}
+        if i < cfg.num_dense_layers:
+            names = {"w_gate": "w1", "w_up": "w3", "w_down": "w2"}
+            return {**out, **{k: jnp.asarray(_np(sd[ff + n + ".weight"]).T)
+                              for k, n in names.items()}}
+        experts = {k: jnp.asarray(np.stack([
+            _np(sd[f"{ff}experts.{e}.{n}.weight"]).T
+            for e in range(cfg.num_experts)]))
+            for k, n in (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))}
+        return {**out, **experts,
+                "router": jnp.asarray(_np(sd[ff + "gate.weight"]).T),
+                "router_bias": jnp.asarray(_np(sd[ff + "expert_bias"]),
+                                           jnp.float32)}
+
+    return {
+        "embed": jnp.asarray(_np(sd["model.embed_tokens.weight"])),
+        "final_norm_scale": jnp.asarray(
+            _np(sd["model.embedding_norm.weight"])),
+        "conv": {
+            "ln1_scale": rows("conv", "operator_norm.weight", lambda w: w),
+            "w_in": rows("conv", "conv.in_proj.weight"),
+            "conv_w": rows("conv", "conv.conv.weight", lambda w: w[:, 0]),
+            "w_out": rows("conv", "conv.out_proj.weight"),
+        },
+        "attn": {
+            "ln1_scale": rows("attention", "operator_norm.weight",
+                              lambda w: w),
+            "wq": rows("attention", "self_attn.q_proj.weight"),
+            "wk": rows("attention", "self_attn.k_proj.weight"),
+            "wv": rows("attention", "self_attn.v_proj.weight"),
+            "wo": rows("attention", "self_attn.out_proj.weight"),
+            "q_norm": rows("attention", "self_attn.q_layernorm.weight",
+                           lambda w: w),
+            "k_norm": rows("attention", "self_attn.k_layernorm.weight",
+                           lambda w: w),
+        },
+        "moe": [ffn(i) for i in range(cfg.num_layers)],
+    }

@@ -1,5 +1,6 @@
 """A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum``,
-``mistral4``, ``afmoe`` and ``longcat_flash`` families' forward and steps.
+``mistral4``, ``afmoe``, ``longcat_flash`` and ``lfm2_moe`` families' forward
+and steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -32,6 +33,14 @@ router and is a SwiGLU (:func:`_feed_forward`). A ``longcat_flash`` stack
 (LongCat-Flash) walks SUBLAYERS: two ``latent`` rows and two dense ``moe``
 entries a published layer, the first entry also the layer's routed experts
 under ``shortcut``, whose result joins after the second (:func:`_shortcut`).
+An ``lfm2_moe`` stack (LiquidAI LFM2) is granite's shape with another
+recurrent kind, ``conv`` (``models/shortconv.py``: a gated short convolution
+whose state is a window of rows, the state store's one leaf), beside ``attn``
+layers that rotate and hold ``q_norm`` / ``k_norm``; its leading ``moe``
+entries dense, the rest routed with a ``router_bias`` and no shared expert, a
+tied head. What a stack's recurrent kinds keep for a sequence is
+:func:`state_shapes`'s to say: the contiguous cache and the paged state store
+hold those leaves and no other.
 
 (the expert weights are a list, not a stack: a row sliced from a ``(L, E, D,
 F)`` stack for a prefill's grouped products, whose operands must be whole
@@ -66,6 +75,7 @@ from . import mla
 from .flash_attention import (MAX_BLOCKED_S, QBLOCK, causal_attention,
                               decode_attention, kernel_plan)
 from .mamba2 import mamba2_prefill, mamba2_step
+from .shortconv import shortconv_prefill, shortconv_step
 from .moe import moe_layer
 from .paged_kv import (LatentPool, PagePool, _attention_decode_latent,
                        _attention_decode_paged, _attention_decode_window,
@@ -76,18 +86,22 @@ from .transformer import _rmsnorm, apply_rotary, mlp, precompute_rope
 class RecurrentStateUnsupported(ValueError):
     """A mechanism that keeps, copies or rolls back a sequence's state as K/V
     rows alone was asked to serve a family whose layers also keep recurrent
-    state (Mamba-2's convolution window and SSM state)."""
+    state (Mamba-2's convolution window and SSM state, a short convolution's
+    window)."""
 
 
 def refuse_recurrent_state(cfg: ModelConfig, what: str) -> None:
     """Raise for a config with recurrent state: ``what`` names the mechanism
     refusing."""
     if cfg.recurrent_state:
+        keeps = ("Mamba-2 layers keep recurrent state (a convolution window "
+                 "and an SSM state per sequence)" if cfg.mamba_layers else
+                 f"short-convolution layers keep recurrent state (a window "
+                 f"of the last {cfg.conv_window - 1} rows per sequence)")
         raise RecurrentStateUnsupported(
-            f"{what} does not support family {cfg.family!r}: its Mamba-2 "
-            f"layers keep recurrent state (a convolution window and an SSM "
-            f"state per sequence) beside the K/V rows, and {what} has no "
-            f"snapshot of that recurrent state; there is no fallback")
+            f"{what} does not support family {cfg.family!r}: its {keeps} "
+            f"beside the K/V rows, and {what} has no snapshot of that "
+            f"recurrent state; there is no fallback")
 
 
 class WindowRingUnsupported(ValueError):
@@ -140,15 +154,13 @@ def refuse_beyond_kv_rows(cfg: ModelConfig, what: str) -> None:
 class HybridCache(NamedTuple):
     """The contiguous decode cache of a stack with recurrent state.
 
-    k, v: (L_attn, B, capacity, KV, hd); length: () int32;
-    conv: (L_mamba, B, d_conv-1, conv_dim) float32;
-    ssm: (L_mamba, B, H, P, N) float32."""
+    k, v: (L_attn, B, capacity, KV, hd); length: () int32; state: the
+    leaves :func:`state_shapes` names for B sequences, float32."""
 
     k: jnp.ndarray
     v: jnp.ndarray
     length: jnp.ndarray
-    conv: jnp.ndarray
-    ssm: jnp.ndarray
+    state: dict
 
     @property
     def capacity(self) -> int:
@@ -188,12 +200,21 @@ class LatentCache(NamedTuple):
         return self.rows.shape[2]
 
 
-def state_shapes(cfg: ModelConfig, rows: int) -> tuple:
-    """Shapes of (conv, ssm) for ``rows`` sequences or slots."""
-    return ((cfg.mamba_layers, rows, cfg.mamba_d_conv - 1,
-             cfg.mamba_conv_dim),
-            (cfg.mamba_layers, rows, cfg.mamba_heads, cfg.mamba_head_dim,
-             cfg.mamba_d_state))
+def state_shapes(cfg: ModelConfig, rows: int) -> dict:
+    """{leaf: shape} of what the stack's recurrent kinds keep for ``rows``
+    sequences or slots, a leaf stacked along its kind's layers: Mamba-2
+    layers a convolution window ``conv`` and a matrix state ``ssm``; short
+    convolutions their window, ``conv``, alone. Empty for a stack whose
+    state is its K/V rows."""
+    if cfg.mamba_layers:
+        return {"conv": (cfg.mamba_layers, rows, cfg.mamba_d_conv - 1,
+                         cfg.mamba_conv_dim),
+                "ssm": (cfg.mamba_layers, rows, cfg.mamba_heads,
+                        cfg.mamba_head_dim, cfg.mamba_d_state)}
+    if cfg.conv_layers:
+        return {"conv": (cfg.conv_layers, rows, cfg.conv_window - 1,
+                         cfg.hidden_size)}
+    return {}
 
 
 def _rms(cfg, x, scale):
@@ -206,7 +227,7 @@ def _row(tree: dict, j: int) -> dict:
 
 def _kinds(cfg: ModelConfig):
     """(layer, kind, index among its kind) down the stack."""
-    seen = {"mamba": 0, "attention": 0, "sliding_attention": 0,
+    seen = {"mamba": 0, "conv": 0, "attention": 0, "sliding_attention": 0,
             "latent_attention": 0}
     for layer, kind in enumerate(cfg.layer_types):
         yield layer, kind, seen[kind]
@@ -345,17 +366,26 @@ def _ffn(cfg: ModelConfig, mp: dict, h, active=None):
     return h + cfg.residual_multiplier * out.reshape(h.shape), counts
 
 
-def _step_row(cfg: ModelConfig, lp: dict, h, conv_all, ssm_all, j: int):
-    """One mamba layer's decode update against row ``j`` of a state store
-    (L_mamba, rows, ...): the row is read, updated and written back in place.
-    The read and the write stand under ``ssm.step`` with the update: XLA fuses
-    them into it, and the fusion is named after the write."""
+def _step_row(cfg: ModelConfig, kind: str, lp: dict, h, state: dict, j: int):
+    """One recurrent layer's decode update against row ``j`` of a state
+    store's leaves (L_kind, rows, ...) -> (state, out): the row is read,
+    updated and written back in place. The read and the write stand under
+    the update's scope (``ssm.step``, ``shortconv.conv``): XLA fuses them
+    into it, and the fusion is named after the write."""
+    if kind == "conv":
+        with jax.named_scope("shortconv.conv"):
+            window = state["conv"][j]
+        out, window = shortconv_step(cfg, lp, _rms(cfg, h, lp["ln1_scale"]),
+                                     window)
+        with jax.named_scope("shortconv.conv"):
+            return {"conv": state["conv"].at[j].set(window)}, out
     with jax.named_scope("ssm.step"):
-        conv, ssm = conv_all[j], ssm_all[j]
+        conv, ssm = state["conv"][j], state["ssm"][j]
     out, conv, ssm = mamba2_step(cfg, lp, _rms(cfg, h, lp["ln1_scale"]), conv,
                                  ssm)
     with jax.named_scope("ssm.step"):
-        return conv_all.at[j].set(conv), ssm_all.at[j].set(ssm), out
+        return {"conv": state["conv"].at[j].set(conv),
+                "ssm": state["ssm"].at[j].set(ssm)}, out
 
 
 def embed_hybrid(cfg: ModelConfig, params: dict, ids):
@@ -379,7 +409,8 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
     """Whole sequences through the stack. Returns (hidden (B, S, D), per-kind
     lists of what a decode cache is filled from when ``collect``)."""
     h, term = embed_hybrid(cfg, params, ids), None   # term: _shortcut's
-    ks, vs, convs, ssms, wks, wvs, lat = [], [], [], [], [], [], []
+    ks, vs, wks, wvs, lat = [], [], [], [], []
+    state = {leaf: [] for leaf in state_shapes(cfg, 0)}
     rope = _rope_tables(cfg, ids.shape[1])
     for layer, kind, j in _kinds(cfg):
         if kind == "latent_attention":
@@ -393,8 +424,14 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
             out, conv, ssm = mamba2_prefill(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]))
             if collect:
-                convs.append(conv)
-                ssms.append(ssm)
+                state["conv"].append(conv)
+                state["ssm"].append(ssm)
+        elif kind == "conv":
+            lp = _row(params["conv"], j)
+            out, window = shortconv_prefill(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]))
+            if collect:
+                state["conv"].append(window)
         elif kind == "sliding_attention":
             lp = _row(params["window"], j)
             out, k, v = _attention_full(
@@ -413,7 +450,7 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
         h = h + cfg.residual_multiplier * out
         g, _ = _ffn(cfg, params["moe"][layer], h)
         h, term, _ = _shortcut(cfg, params["moe"][layer], h, g, term)
-    return h, (ks, vs, convs, ssms, wks, wvs, lat)
+    return h, (ks, vs, state, wks, wvs, lat)
 
 
 def forward_hybrid(cfg: ModelConfig, params: dict, ids):
@@ -435,8 +472,8 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
                          f"{capacity}]")
-    h, (ks, vs, convs, ssms, wks, wvs, lat) = _walk_full(cfg, params, ids,
-                                                         collect=True)
+    h, (ks, vs, state, wks, wvs, lat) = _walk_full(cfg, params, ids,
+                                                   collect=True)
     logits = unembed_hybrid(cfg, params, h[:, -1] if last_only else h)
     if lat:
         return logits, LatentCache(
@@ -450,10 +487,9 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
     if cfg.window_layers:
         return logits, WindowCache(k, v, length, jnp.pad(jnp.stack(wks), pad),
                                    jnp.pad(jnp.stack(wvs), pad))
-    conv_shape, ssm_shape = state_shapes(cfg, b)
     return logits, HybridCache(k, v, length,
-                               _stack(convs, conv_shape, jnp.float32),
-                               _stack(ssms, ssm_shape, jnp.float32))
+                               {leaf: jnp.stack(rows)
+                                for leaf, rows in state.items()})
 
 
 def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
@@ -467,7 +503,7 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     if isinstance(cache, LatentCache):
         return _decode_step_latent(cfg, params, cache, h)
     windowed = isinstance(cache, WindowCache)
-    conv_all, ssm_all = (None, None) if windowed else (cache.conv, cache.ssm)
+    state = None if windowed else cache.state
     # the rows a kind's layers append to: full layers k / v, sliding wk / wv
     rows = {"attention": [cache.k, cache.v],
             "sliding_attention": [cache.wk, cache.wv] if windowed else None}
@@ -475,10 +511,9 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
                               for x in t)
             for kind, t in _rope_tables(cfg, cache.capacity).items()}
     for layer, kind, j in _kinds(cfg):
-        if kind == "mamba":
-            lp = _row(params["mamba"], j)
-            conv_all, ssm_all, out = _step_row(cfg, lp, h, conv_all, ssm_all,
-                                               j)
+        if kind in ("mamba", "conv"):
+            state, out = _step_row(cfg, kind, _row(params[kind], j), h, state,
+                                   j)
         else:
             sliding = kind == "sliding_attention"
             lp = _row(params["window" if sliding else "attn"], j)
@@ -501,7 +536,7 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     if windowed:
         return logits, WindowCache(*rows["attention"], pos + 1,
                                    *rows["sliding_attention"])
-    return logits, HybridCache(*rows["attention"], pos + 1, conv_all, ssm_all)
+    return logits, HybridCache(*rows["attention"], pos + 1, state)
 
 
 def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
@@ -526,30 +561,32 @@ def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
                 donate=lambda ctx: ctx.get("donate_min", 2))
 @graph_contract("paged.decode_step_hybrid", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 5))
+@graph_contract("paged.decode_step_shortconv", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 4))
 @graph_contract("paged.decode_step_window", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 5))
 def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                             conv_all, ssm_all, expert_tokens, page_table,
-                             lengths, token_ids, window=None):
+                             state, expert_tokens, page_table, lengths,
+                             token_ids, window=None):
     """The ragged step for a hybrid stack: one position for EVERY slot.
 
     pool_k/pool_v: (L_attn, num_pages, page_size, KV * hd), addressed by the
-    static attention-layer number (paged_kv's flat index); conv_all / ssm_all:
-    the per-slot state store, (L_mamba, max_slots, ...) float32; expert_tokens
+    static attention-layer number (paged_kv's flat index); state: the
+    per-slot state store, :func:`state_shapes`'s leaves (L_kind, max_slots,
+    ...) float32, None for a stack that keeps none; expert_tokens
     (L expert layers, Eh) int32, the count of assignments per held expert, which
     gains this step's over the slots with ``lengths > 0`` (a free slot runs
     token-0 math into the trash page and into its own dead state rows, and is
     not counted). Returns (logits (max_slots, V) float32, pool_k, pool_v,
-    conv_all, ssm_all, expert_tokens).
+    state, expert_tokens).
 
     A stack with sliding layers also passes ``window`` = (win_k, win_v
     (L_window, window pool pages, page_size, KV * hd), window_table
     (max_slots, window_pages): each slot's ring) and gets (win_k, win_v) back
-    as a seventh result; it has no mamba layer, and conv_all / ssm_all are
-    None both ways.
+    as a sixth result; it has no recurrent layer.
 
     A stack of latent layers passes its pool's ONE leaf (L, num_pages, page,
-    kv_row_lanes) as ``pool_k``; pool_v, conv_all, ssm_all: None both ways."""
+    kv_row_lanes) as ``pool_k``; pool_v and state: None both ways."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
@@ -567,10 +604,9 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
             out, (pool_k,) = _attention_decode_latent(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]), *rope[kind],
                 LatentPool(pool_k), j, page_table, lengths)
-        elif kind == "mamba":
-            lp = _row(params["mamba"], j)
-            conv_all, ssm_all, out = _step_row(cfg, lp, h, conv_all, ssm_all,
-                                               j)
+        elif kind in ("mamba", "conv"):
+            state, out = _step_row(cfg, kind, _row(params[kind], j), h, state,
+                                   j)
         elif kind == "sliding_attention":
             lp = _row(params["window"], j)
             out, (win_k, win_v) = _attention_decode_window(
@@ -593,8 +629,7 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
             counts.append(c)
     with jax.named_scope("unembed_sample"):
         logits = unembed_hybrid(cfg, params, h)
-    out = (logits, pool_k, pool_v, conv_all, ssm_all,
-           expert_tokens + jnp.stack(counts))
+    out = (logits, pool_k, pool_v, state, expert_tokens + jnp.stack(counts))
     return out if window is None else out + ((win_k, win_v),)
 
 
@@ -610,7 +645,10 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     ``post_scale``; its leading dense layers' ``moe`` entries a SwiGLU of
     ``intermediate_size`` and no router, its expert layers a float32
     ``router_bias`` drawn NONZERO (a checkpoint's is trained; at zero a path
-    that dropped it would pass); a stack of sublayers: :func:`_sub_ffns`."""
+    that dropped it would pass); a stack of sublayers: :func:`_sub_ffns`. An
+    ``lfm2_moe`` stack's ``attn`` holds the two head norms alone; its
+    ``conv`` kind's taps are uniform in +-1/sqrt(taps), as a depthwise
+    convolution is initialised upstream, so that the window matters."""
     keys = iter(jax.random.split(key, 16 + 8 * len(cfg.layer_types)))
 
     def init(*shape):
@@ -622,6 +660,7 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     eh, f, fs = cfg.local_experts, cfg.expert_width, cfg.shared_width
 
     afmoe = cfg.family == "afmoe"
+    head_normed = afmoe or cfg.family == "lfm2_moe"
 
     def attention(n):
         return {
@@ -631,8 +670,8 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
             "wv": init(n, d, cfg.num_kv_heads * hd),
             "wo": init(n, cfg.num_heads * hd, d),
             **({"q_norm": jnp.ones((n, hd), dtype),
-                "k_norm": jnp.ones((n, hd), dtype),
-                "wg": init(n, d, cfg.num_heads * hd),
+                "k_norm": jnp.ones((n, hd), dtype)} if head_normed else {}),
+            **({"wg": init(n, d, cfg.num_heads * hd),
                 "post_scale": jnp.ones((n, d), dtype)} if afmoe else {}),
         }
 
@@ -664,6 +703,16 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
             "D": jnp.ones((lm, nh), dtype),
             "norm_scale": jnp.ones((lm, di), dtype),
             "w_out": init(lm, di, d),
+        }
+    if cfg.conv_layers:
+        lc, taps = cfg.conv_layers, cfg.conv_window
+        params["conv"] = {
+            "ln1_scale": jnp.ones((lc, d), dtype),
+            "w_in": init(lc, d, 3 * d),
+            "conv_w": jax.random.uniform(
+                next(keys), (lc, d, taps), jnp.float32, -taps ** -0.5,
+                taps ** -0.5).astype(dtype),
+            "w_out": init(lc, d, d),
         }
     if cfg.latent_layers:
         n, h = cfg.latent_layers, cfg.num_heads

@@ -59,8 +59,9 @@ def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray,
     ``cfg.score_func`` ``"sigmoid"``: scores ``p = sigmoid(logits)``, the
     top k taken of ``p + bias`` ((E,) float32, a per-expert SELECTION bias
     that no weight sees), weights ``route_scale * p_e / (sum of the chosen p
-    + 1e-20)``; ``"softmax_all"``: ``p = softmax(logits)`` over every output,
-    chosen the same way, weights ``route_scale * p_e``; all of it float32."""
+    + cfg.route_norm_eps)``; ``"softmax_all"``: ``p = softmax(logits)`` over
+    every output, chosen the same way, weights ``route_scale * p_e``; all of
+    it float32."""
     with jax.named_scope("moe.route"):
         logits = jnp.einsum("td,de->te", u, router_w,
                             preferred_element_type=jnp.float32)
@@ -75,7 +76,7 @@ def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray,
                 idx, p.shape[-1], dtype=jnp.float32), p)
             if cfg.score_func == "sigmoid":
                 chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
-                                   + 1e-20)
+                                   + cfg.route_norm_eps)
             return idx.astype(jnp.int32), chosen * cfg.route_scale
         vals, idx = jax.lax.top_k(logits, cfg.experts_per_tok)
         return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)

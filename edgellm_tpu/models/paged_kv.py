@@ -774,42 +774,35 @@ def _copy_pages_impl(pool, src, dst, lead: int = 1):
     return type(pool)(*out)
 
 
-# The per-slot state store of a hybrid stack (models/hybrid.py): row j of
-# conv (L_mamba, max_slots, d_conv-1, conv_dim) and ssm (L_mamba, max_slots,
-# H, P, N), both float32, is the convolution window and the recurrent state of
-# mamba layer j for each slot. A slot's state has a fixed size and is
-# overwritten every step, so there is nothing to page: one row a slot.
+# The per-slot state store of a hybrid stack (models/hybrid.py): a dict of
+# the leaves ``hybrid.state_shapes`` names for the stack's recurrent kinds,
+# each (L_kind, max_slots, ...) float32: a Mamba-2 stack's ``conv`` (L_mamba,
+# max_slots, d_conv-1, conv_dim) and ``ssm`` (L_mamba, max_slots, H, P, N), a
+# short-convolution stack's ``conv`` (L_conv, max_slots, taps-1, D) alone. Row
+# j of a leaf is layer j's state for each slot. A slot's state has a fixed
+# size and is overwritten every step, so there is nothing to page: one row a
+# slot.
 
 
-class SlotState(NamedTuple):
-    conv: jnp.ndarray
-    ssm: jnp.ndarray
-
-    @property
-    def nbytes(self) -> int:
-        return int(self.conv.nbytes) + int(self.ssm.nbytes)
-
-
-def init_slot_state(cfg: ModelConfig, max_slots: int) -> SlotState:
+def init_slot_state(cfg: ModelConfig, max_slots: int) -> dict:
     from .hybrid import state_shapes
 
-    conv, ssm = state_shapes(cfg, max_slots)
-    return SlotState(jnp.zeros(conv, jnp.float32),
-                     jnp.zeros(ssm, jnp.float32))
+    return {leaf: jnp.zeros(shape, jnp.float32)
+            for leaf, shape in state_shapes(cfg, max_slots).items()}
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
+@functools.partial(jax.jit, donate_argnums=(0,))
 @jax.named_scope("state.adopt")
-def _state_set_impl(conv_all, ssm_all, conv, ssm, slot):
-    """Overwrite one slot's rows with (L_mamba, ...) state: a prefill's, a
-    resumed stream's, or zeros when the slot is allocated."""
-    return (conv_all.at[:, slot].set(conv.astype(conv_all.dtype)),
-            ssm_all.at[:, slot].set(ssm.astype(ssm_all.dtype)))
+def _state_set_impl(state, rows, slot):
+    """Overwrite one slot's rows of every leaf with (L_kind, ...) state: a
+    prefill's, a resumed stream's, or zeros when the slot is allocated."""
+    return {leaf: a.at[:, slot].set(rows[leaf].astype(a.dtype))
+            for leaf, a in state.items()}
 
 
 @jax.jit
-def _state_get_impl(conv_all, ssm_all, slot):
-    return conv_all[:, slot], ssm_all[:, slot]
+def _state_get_impl(state, slot):
+    return {leaf: a[:, slot] for leaf, a in state.items()}
 
 
 class PagedKVCache:
@@ -863,9 +856,10 @@ class PagedKVCache:
             self.pool = init_quant_pool(cfg, num_pages, page_size,
                                         self.kv_codec)
         # the second kind of state: one fixed-size row a slot for every
-        # mamba layer of a hybrid stack, managed with the slot (zeroed at
-        # alloc, written at adopt, gathered at eviction, dead once freed)
-        self.state: Optional[SlotState] = (
+        # recurrent layer of a hybrid stack, the leaves its kinds keep,
+        # managed with the slot (zeroed at alloc, written at adopt, gathered
+        # at eviction, dead once freed)
+        self.state: Optional[dict] = (
             init_slot_state(cfg, max_slots) if cfg.recurrent_state else None)
         # the second page group: the sliding-window layers keep, for each
         # slot, a RING of ``window_pages`` pages in a pool of their own —
@@ -989,7 +983,7 @@ class PagedKVCache:
                 if self.state is not None:
                     # a reused slot starts from zero, not from its last
                     # tenant's state
-                    self.adopt_state(s, 0.0, 0.0)
+                    self.adopt_state(s, *(0.0 for _ in self.state))
                 if self.window_table is not None:
                     self.window_table[s] = self._ring_of(s)
                 return s
@@ -1534,30 +1528,40 @@ class PagedKVCache:
     def state_bytes(self) -> int:
         """Device bytes of the per-slot recurrent state store (0 for a
         family whose state is its pages)."""
-        return self.state.nbytes if self.state is not None else 0
+        return sum(self.state_leaf_bytes.values())
 
-    def adopt_state(self, slot: int, conv, ssm) -> None:
-        """Overwrite ``slot``'s recurrent state with ``conv`` (L_mamba,
-        d_conv-1, conv_dim) and ``ssm`` (L_mamba, H, P, N) — a prefill's, or
-        an evicted stream's gathered rows (scalars broadcast: zeros at
-        allocation)."""
+    @property
+    def state_leaf_bytes(self) -> dict:
+        """{leaf: device bytes} of the state store."""
+        return {leaf: int(a.nbytes) for leaf, a in (self.state or {}).items()}
+
+    def adopt_state(self, slot: int, *rows) -> None:
+        """Overwrite ``slot``'s recurrent state with ``rows``: (L_kind, ...)
+        for each leaf the store holds, in its order (a Mamba-2 stack's
+        ``conv``, ``ssm``; a short-convolution stack's ``conv``) — a
+        prefill's, or an evicted stream's gathered rows (scalars broadcast:
+        zeros at allocation)."""
         if self.state is None:
             raise ValueError(f"family {self.cfg.family!r} keeps no recurrent "
                              f"state")
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
-        self.state = SlotState(*_state_set_impl(
-            self.state.conv, self.state.ssm, jnp.asarray(conv, jnp.float32),
-            jnp.asarray(ssm, jnp.float32), jnp.asarray(slot, jnp.int32)))
+        if len(rows) != len(self.state):
+            raise ValueError(f"the state store holds {list(self.state)}, "
+                             f"got {len(rows)} leaves")
+        self.state = _state_set_impl(
+            self.state,
+            {leaf: jnp.asarray(a, jnp.float32)
+             for leaf, a in zip(self.state, rows)},
+            jnp.asarray(slot, jnp.int32))
 
     def gather_state(self, slot: int) -> dict:
-        """``slot``'s recurrent state as host arrays {"conv", "ssm"}: what
-        an eviction keeps beside :meth:`gather_slot`'s K/V rows."""
+        """``slot``'s recurrent state as host arrays, {leaf: (L_kind, ...)}:
+        what an eviction keeps beside :meth:`gather_slot`'s K/V rows."""
         if self.state is None:
             return {}
-        conv, ssm = _state_get_impl(self.state.conv, self.state.ssm,
-                                    jnp.asarray(slot, jnp.int32))
-        return {"conv": np.asarray(conv), "ssm": np.asarray(ssm)}
+        return {leaf: np.asarray(a) for leaf, a in _state_get_impl(
+            self.state, jnp.asarray(slot, jnp.int32)).items()}
 
     def gather_slot(self, slot: int) -> dict:
         """Read ``slot``'s K/V back as the contiguous host state dict the
@@ -1715,9 +1719,8 @@ class PagedKVCache:
                       "index_holds": self._index_holds.copy()})
         if self.prefix is not None:
             state["prefix_index"] = self.prefix.to_array()
-        if self.state is not None:
-            state["state_conv"] = np.asarray(self.state.conv)
-            state["state_ssm"] = np.asarray(self.state.ssm)
+        for leaf, a in (self.state or {}).items():
+            state["state_" + leaf] = np.asarray(a)
         return state
 
     def load_state_dict(self, state: dict) -> None:
@@ -1748,12 +1751,13 @@ class PagedKVCache:
             jnp.asarray(state[n]).reshape(a.shape)
             for n, a in zip(names, self.pool)))
         if self.state is not None:
-            if state["state_ssm"].shape != self.state.ssm.shape:
-                raise ValueError(
-                    f"state store shape mismatch: checkpoint "
-                    f"{state['state_ssm'].shape} vs {self.state.ssm.shape}")
-            self.state = SlotState(jnp.asarray(state["state_conv"]),
-                                   jnp.asarray(state["state_ssm"]))
+            for leaf, a in self.state.items():
+                if state["state_" + leaf].shape != a.shape:
+                    raise ValueError(
+                        f"state store shape mismatch: checkpoint {leaf} "
+                        f"{state['state_' + leaf].shape} vs {a.shape}")
+            self.state = {leaf: jnp.asarray(state["state_" + leaf])
+                          for leaf in self.state}
         self.page_table = np.asarray(state["page_table"], np.int32).copy()
         self.lengths = np.asarray(state["lengths"], np.int32).copy()
         self.active = np.asarray(state["active"], bool).copy()
@@ -1837,11 +1841,10 @@ class PagedKVCache:
             from .hybrid import state_shapes
 
             want = state_shapes(self.cfg, self.max_slots)
-            assert (self.state.conv.shape, self.state.ssm.shape) == want, \
-                f"state store shapes {self.state.conv.shape}, " \
-                f"{self.state.ssm.shape} != {want}"
-            assert self.state.conv.dtype == self.state.ssm.dtype == \
-                jnp.float32, "recurrent state must stay float32"
+            have = {leaf: a.shape for leaf, a in self.state.items()}
+            assert have == want, f"state store shapes {have} != {want}"
+            assert all(a.dtype == jnp.float32 for a in self.state.values()), \
+                "recurrent state must stay float32"
             if self.pool is not None:
                 assert self.pool.k.shape[0] == self.cfg.kv_layers, \
                     "the page pool holds the attention layers only"

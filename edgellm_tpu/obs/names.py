@@ -205,6 +205,9 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "ssm.proj",           # a Mamba-2 layer's in and out projections
     "ssm.step",           # decode: window update, recurrence, gated norm
     "ssm.scan",           # prefill: the convolution and the chunked scan
+    # a gated short convolution (models/shortconv.py), prefill and decode
+    "shortconv.proj",     # the in (B | C | x) and out projections
+    "shortconv.conv",     # the gates, the taps, the window's read and write
     "moe.route",          # router logits, top-k, softmax over the chosen
                           # (or sigmoid scores, the selection bias, the
                           # top-k, the normalised and scaled weights; or the

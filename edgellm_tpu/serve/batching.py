@@ -57,7 +57,9 @@ module schedules many streams through ONE jitted decode step built on
   batched serving over a split plan, no longer local-pool-only.
 - a stack walked by layer kinds (``models/hybrid.py``) has a step
   executable of its own: ``_batched_hybrid_step_jit`` with the per-slot
-  recurrent state of a ``granitemoehybrid`` stack, ``_batched_window_step_jit``
+  recurrent state of a ``granitemoehybrid`` or ``lfm2_moe`` stack (the leaves
+  ``hybrid.state_shapes`` names, one donated pytree),
+  ``_batched_window_step_jit``
   with the second page group of a ``mellum`` stack (each sliding-window
   layer's ring of pages, ``PagedKVCache.window_pool`` / ``window_table``):
   admission adopts a prompt's tail into the rings, eviction gathers them with
@@ -90,7 +92,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
 from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, OutOfPages, \
-    OutOfSlots, PagedKVCache, PrefixCacheConfig, SlotState, \
+    OutOfSlots, PagedKVCache, PrefixCacheConfig, \
     decode_read_path, paged_decode_step, resolve_kv_codec
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
@@ -250,28 +252,28 @@ def _batched_step_jit(cfg: ModelConfig, params: dict, pool, page_table,
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "compute_dtype"),
-                   donate_argnums=(2, 3, 4, 5, 6))
+                   donate_argnums=(2, 3, 4, 5))
 def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                             conv, ssm, expert_tokens, page_table, lengths,
+                             state, expert_tokens, page_table, lengths,
                              token_ids, key_data, steps, temps,
                              compute_dtype):
     """The ragged step of a stack with recurrent state
     (``models/hybrid.py``): the K/V pages of the attention layers, the
-    per-slot state store of the mamba layers and the per-expert assignment
-    counter are all donated and come back updated. A SEPARATE jit: the
-    one-block families keep the executable above. A stack of latent layers
-    takes it with its pool's one leaf as ``pool_k`` and None for ``pool_v``,
-    ``conv`` and ``ssm``, which come back None."""
+    per-slot state store of the recurrent layers (every leaf its kinds keep)
+    and the per-expert assignment counter are all donated and come back
+    updated. A SEPARATE jit: the one-block families keep the executable
+    above. A stack of latent layers takes it with its pool's one leaf as
+    ``pool_k`` and None for ``pool_v`` and ``state``, which come back None."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
-    logits, pool_k, pool_v, conv, ssm, expert_tokens = (
-        paged_decode_step_hybrid(cfg, params, pool_k, pool_v, conv, ssm,
+    logits, pool_k, pool_v, state, expert_tokens = (
+        paged_decode_step_hybrid(cfg, params, pool_k, pool_v, state,
                                  expert_tokens, page_table, lengths,
                                  token_ids))
     return (_batched_sample(logits, key_data, steps, temps),
-            pool_k, pool_v, conv, ssm, expert_tokens)
+            pool_k, pool_v, state, expert_tokens)
 
 
 @functools.partial(jax.jit,
@@ -290,8 +292,8 @@ def _batched_window_step_jit(cfg: ModelConfig, params: dict, pool, window_pool,
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
-    logits, k, v, _, _, expert_tokens, (wk, wv) = paged_decode_step_hybrid(
-        cfg, params, pool.k, pool.v, None, None, expert_tokens, page_table,
+    logits, k, v, _, expert_tokens, (wk, wv) = paged_decode_step_hybrid(
+        cfg, params, pool.k, pool.v, None, expert_tokens, page_table,
         lengths, token_ids, window=(window_pool.k, window_pool.v,
                                     window_table))
     return (_batched_sample(logits, key_data, steps, temps),
@@ -663,9 +665,9 @@ class ContinuousBatcher:
                 else:
                     self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
                                     jnp.asarray(st.resume["v"]), need_len)
-                if "ssm" in st.resume:
-                    self.pool.adopt_state(slot, st.resume["conv"],
-                                          st.resume["ssm"])
+                if self.pool.state is not None:
+                    self.pool.adopt_state(
+                        slot, *(st.resume[leaf] for leaf in self.pool.state))
                 if "wk" in st.resume:
                     self.pool.adopt_window(slot, st.resume["wk"],
                                            st.resume["wv"], need_len)
@@ -723,8 +725,9 @@ class ContinuousBatcher:
                                     cache.v[:, 0, :s], s)
                 if self.cfg.recurrent_state:
                     # the other kind of state a prefill hands on
-                    self.pool.adopt_state(slot, cache.conv[:, 0],
-                                          cache.ssm[:, 0])
+                    self.pool.adopt_state(
+                        slot, *(cache.state[leaf][:, 0]
+                                for leaf in self.pool.state))
                 if self.cfg.window_layers:
                     # the sliding layers take the tail their rings hold
                     r0 = self.pool.window_ring_start(s)
@@ -1144,21 +1147,18 @@ class ContinuousBatcher:
             elif self.cfg.is_hybrid:
                 # K and V pages and the state store, or a latent stack's one
                 # leaf with no V pages and no state
-                pool, state = self.pool.pool, self.pool.state
+                pool = self.pool.pool
                 k, v = pool if len(pool) == 2 else (pool[0], None)
-                conv, ssm = state if state is not None else (None, None)
-                toks, k, v, conv, ssm, self._expert_tokens = (
+                toks, k, v, self.pool.state, self._expert_tokens = (
                     _batched_hybrid_step_jit(
-                        self.cfg, self.params, k, v, conv, ssm,
+                        self.cfg, self.params, k, v, self.pool.state,
                         self._expert_tokens, page_table, lengths,
                         token_ids, jnp.asarray(key_data),
                         jnp.asarray(steps), jnp.asarray(temps),
                         self.bcfg.compute_dtype))
                 self.pool.pool = (type(pool)(k) if v is None
                                   else type(pool)(k, v))
-                if state is not None:
-                    self.pool.state = SlotState(conv, ssm)
-                del pool, state
+                del pool
             else:
                 toks, self.pool.pool = _batched_step_jit(
                     self.cfg, self.params, self.pool.pool, page_table,
@@ -1528,6 +1528,7 @@ class ContinuousBatcher:
         # sites, whose line numbers are in every step's compile-cache key
         from ..models.moe import grouped_product
         return {"state_bytes": self.pool.state_bytes,
+                "state_leaf_bytes": self.pool.state_leaf_bytes,
                 # the path a prefill past moe.DENSE_MAX_TOKENS takes through
                 # its grouped expert products in this process
                 "grouped_product": grouped_product(self.cfg),

@@ -265,9 +265,9 @@ class LogitTap:
         def step(params, pool, wpool, cnt, table, wtable, lengths, toks,
                  key_data, steps, temps):
             with jax.default_matmul_precision("highest"):
-                logits, k, v, _, _, cnt, (wk, wv) = (
+                logits, k, v, _, cnt, (wk, wv) = (
                     hybrid.paged_decode_step_hybrid(
-                        cfg, params, pool.k, pool.v, None, None, cnt, table,
+                        cfg, params, pool.k, pool.v, None, cnt, table,
                         lengths, toks, window=(wpool.k, wpool.v, wtable)))
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps),

@@ -33,9 +33,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from edgellm_tpu.models import grouped_matmul, hybrid, moe, paged_kv
-from edgellm_tpu.models.configs import LONGCAT_FLASH_CHAT, ModelConfig, \
-    tiny_afmoe_config, tiny_hybrid_config, tiny_longcat_flash_config, \
-    tiny_mellum_config, tiny_mistral4_config
+from edgellm_tpu.models.configs import LFM2_8B_A1B, LONGCAT_FLASH_CHAT, \
+    ModelConfig, tiny_afmoe_config, tiny_hybrid_config, \
+    tiny_lfm2_moe_config, tiny_longcat_flash_config, tiny_mellum_config, \
+    tiny_mistral4_config
 from edgellm_tpu.models.transformer import init_params
 from edgellm_tpu.serve import batching
 
@@ -441,7 +442,7 @@ def _latent_step(one):
 
     ints = arr((L_SLOTS,), jnp.int32)
     return batching._batched_hybrid_step_jit.lower(
-        MISTRAL4, params, pool.rows, None, None, None,
+        MISTRAL4, params, pool.rows, None, None,
         arr((4, 32), jnp.int32), arr((L_SLOTS, L_PAGES_PER_SLOT), jnp.int32),
         ints, ints, arr((L_SLOTS, 2), jnp.uint32), ints,
         arr((L_SLOTS,), jnp.float32), None).compile()
@@ -519,6 +520,24 @@ A_SLOTS, A_PAGES_PER_SLOT = 8, 6
 HEAVY = ("convolution", "dot", "gather", "scatter", "custom-call", "sort")
 
 
+def _scopes_of_the_heavy(hlo: str):
+    """(op paths of the module's matmuls, gathers, scatters, sorts and kernel
+    calls that stand under NO registered scope, the registered scopes the
+    others stand under). (At a cell's size the compiler prefetches operands
+    into VMEM in slices and joins them with a "ConcatBitcast" call of its
+    own: a bitcast, no path.)"""
+    from edgellm_tpu.obs.names import SCOPE_NAMES
+
+    paths = ["".join(re.findall(r'op_name="([^"]*)"', line))
+             for op, _, _, line in _instructions(hlo)
+             if op in HEAVY and "ConcatBitcast" not in line]
+    unscoped = {path for path in paths
+                if not any(seg in SCOPE_NAMES for seg in path.split("/"))}
+    under = {seg for path in paths for seg in path.split("/")
+             if seg in SCOPE_NAMES}
+    return unscoped, under
+
+
 def _afmoe_step(one, cfg):
     """The afmoe window step lowered for ``one`` described chip."""
     params = _shapes(jax.eval_shape(
@@ -551,8 +570,6 @@ def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
     ``expert_tokens`` has a row an EXPERT layer, and every matmul, gather,
     scatter, sort and kernel call of the module carries a registered scope:
     the gate, the norms and the dense layer brought no unscoped work."""
-    from edgellm_tpu.obs.names import SCOPE_NAMES
-
     one = SingleDeviceSharding(topo.devices[0])
     params, full, window, lowered = _afmoe_step(one, AFMOE)
     assert "router" not in params["moe"][0] and "wg" in params["window"]
@@ -579,17 +596,12 @@ def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
     assert not moved, moved
     assert f"bf16[{4 * 33},{PAGE},128]" in hlo        # pages at (layer, page)
     assert f"bf16[{4 * 33 * PAGE},128]" in hlo        # rows at (l, p, r)
-    paths = [(op, "".join(re.findall(r'op_name="([^"]*)"', line)))
-             for op, _, _, line in _instructions(hlo) if op in HEAVY]
     # all but two row gathers every walked family makes ahead of its first
     # layer: the embedding's (jnp.take) and each slot's row of the rope table
-    unscoped = {path for _, path in paths
-                if not any(seg in SCOPE_NAMES for seg in path.split("/"))}
+    unscoped, under = _scopes_of_the_heavy(hlo)
     assert unscoped == {
         "jit(_batched_window_step_jit)/jit(_take)/gather",
         "jit(_batched_window_step_jit)/gather"}, unscoped
-    under = {seg for _, path in paths for seg in path.split("/")
-             if seg in SCOPE_NAMES}
     assert under >= {"attn.window", "attn.decode", "paged_kv.write", "mlp",
                      "moe.route", "moe.experts", "moe.shared",
                      "unembed_sample"}, under
@@ -618,8 +630,6 @@ def test_longcat_step_walks_five_tile_rows_and_scopes_what_is_heavy(topo,
     every matmul, gather, scatter, sort and kernel call under a registered
     scope: the shortcut, the identity part and the dense SwiGLUs brought no
     unscoped work."""
-    from edgellm_tpu.obs.names import SCOPE_NAMES
-
     one = SingleDeviceSharding(topo.devices[0])
     cfg = LONGCAT
     assert (cfg.kv_row_lanes, cfg.kv_layers, cfg.expert_layers,
@@ -639,7 +649,7 @@ def test_longcat_step_walks_five_tile_rows_and_scopes_what_is_heavy(topo,
 
     ints = arr((C_SLOTS,), jnp.int32)
     step = batching._batched_hybrid_step_jit.lower(
-        cfg, params, pool.rows, None, None, None, arr((4, 17), jnp.int32),
+        cfg, params, pool.rows, None, None, arr((4, 17), jnp.int32),
         arr((C_SLOTS, C_PAGES_PER_SLOT), jnp.int32), ints, ints,
         arr((C_SLOTS, 2), jnp.uint32), ints, arr((C_SLOTS,), jnp.float32),
         None).compile()
@@ -662,24 +672,97 @@ def test_longcat_step_walks_five_tile_rows_and_scopes_what_is_heavy(topo,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.6e9
     if read == "walk":
         assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
-    # (at this size the compiler prefetches operands into VMEM in slices and
-    # joins them with a "ConcatBitcast" call of its own: a bitcast, no path)
-    paths = [(op, "".join(re.findall(r'op_name="([^"]*)"', line)))
-             for op, _, _, line in _instructions(hlo)
-             if op in HEAVY and "ConcatBitcast" not in line]
-    unscoped = {path for _, path in paths
-                if not any(seg in SCOPE_NAMES for seg in path.split("/"))}
+    unscoped, under = _scopes_of_the_heavy(hlo)
     assert unscoped == {
         "jit(_batched_hybrid_step_jit)/jit(_take)/gather",
         "jit(_batched_hybrid_step_jit)/gather"}, unscoped
-    under = {seg for _, path in paths for seg in path.split("/")
-             if seg in SCOPE_NAMES}
     assert under >= {"attn.latent", "paged_kv.write", "mlp", "moe.route",
                      "moe.experts", "unembed_sample"}, under
     assert "moe.shared" not in under
 
 
-# the five families hybrid.py walks, at toy sizes whose expert layers are
+# benchmark/configs/lfm2-8b-a1b-pp2.json: the widths, stage 0's depth (layers
+# 0-11 as published: 9 short convolutions, 3 attention layers, both dense
+# layers, 10 routed ones with all 32 experts) and the serving geometry
+LFM2 = dataclasses.replace(LFM2_8B_A1B, num_layers=12,
+                           layer_types=LFM2_8B_A1B.layer_types[:12])
+F_SLOTS, F_PAGES_PER_SLOT = 96, 288
+
+
+def test_lfm2_step_keeps_its_windows_and_its_pool_in_place(topo, read):
+    """The step of the ``lfm2_moe`` cell at its shapes: three rotated
+    attention layers on the page walk (a kernel a layer; a gather of K and
+    one of V a layer otherwise), the K/V pool AND the state store's one
+    leaf, the nine conv layers' windows, donated and addressed in place (no
+    ``copy`` of the windows' shape, nothing pool-sized copied, relaid or
+    stacked), the counter a row an EXPERT layer, and every matmul, gather,
+    scatter, sort and kernel call under a registered scope: the short
+    convolution brought no unscoped work."""
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = LFM2
+    assert (cfg.conv_layers, cfg.kv_layers, cfg.expert_layers,
+            cfg.kv_row_lanes) == (9, 3, 10, 512)
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    assert sorted(params["conv"]) == ["conv_w", "ln1_scale", "w_in", "w_out"]
+    assert ["router" in m for m in params["moe"]] == [False] * 2 + [True] * 10
+    pages = F_SLOTS * F_PAGES_PER_SLOT + 1
+    pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, pages, PAGE, jnp.bfloat16)), one)
+    assert pool.k.shape == (3, 27649, PAGE, 512)
+    state = _shapes(jax.eval_shape(
+        lambda: paged_kv.init_slot_state(cfg, F_SLOTS)), one)
+    assert {leaf: a.shape for leaf, a in state.items()} == {
+        "conv": (9, F_SLOTS, 2, 2048)}
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((F_SLOTS,), jnp.int32)
+    step = batching._batched_hybrid_step_jit.lower(
+        cfg, params, pool.k, pool.v, state, arr((10, 32), jnp.int32),
+        arr((F_SLOTS, F_PAGES_PER_SLOT), jnp.int32), ints, ints,
+        arr((F_SLOTS, 2), jnp.uint32), ints, arr((F_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    layer_pool = pages * PAGE * 512
+    gathered = F_SLOTS * F_PAGES_PER_SLOT * PAGE * 512
+    span = f"bf16[{F_SLOTS},{F_PAGES_PER_SLOT},{PAGE},512]"
+    own = {span, f"bf16[{F_SLOTS * F_PAGES_PER_SLOT},{PAGE},512]"}
+    moved = [m for m in _moved(hlo, gathered)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    assert not moved, moved
+    assert _walks(hlo) == (3 if read == "walk" else 0)
+    gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+               if op == "gather" and _elements(shape) >= gathered]
+    assert set(gathers) == (set() if read == "walk" else {span}), gathers
+    if read == "walk":
+        assert not _span_sized(hlo, own)
+    # the windows: written where they lie, a layer's rows at a time, and
+    # never copied whole
+    windows = "f32[9,96,2,2048]"
+    assert windows in hlo
+    copies = [(op, name) for op, name, shape, _ in _instructions(hlo)
+              if op == "copy" and windows in shape]
+    assert not copies, copies
+    mem = step.memory_analysis()
+    window_bytes = 9 * F_SLOTS * 2 * 2048 * 4
+    assert mem.alias_size_in_bytes >= 2 * 3 * layer_pool * 2 + window_bytes
+    # weights 7.86 GB + pool 2.72 GB + the step's temporaries
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
+    if read == "walk":
+        assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    unscoped, under = _scopes_of_the_heavy(hlo)
+    assert unscoped == {
+        "jit(_batched_hybrid_step_jit)/jit(_take)/gather",
+        "jit(_batched_hybrid_step_jit)/gather"}, unscoped
+    assert under >= {"shortconv.proj", "attn.decode", "paged_kv.write",
+                     "mlp", "moe.route", "moe.experts",
+                     "unembed_sample"}, under
+    assert not under & {"moe.shared", "ssm.step", "ssm.proj"}
+
+
+# the six families hybrid.py walks, at toy sizes whose expert layers are
 # whole lane tiles (D = F = 128: what the grouped-matmul kernel asks for),
 # half the routed experts held
 WALKED = {name: dataclasses.replace(
@@ -688,7 +771,8 @@ WALKED = {name: dataclasses.replace(
                        ("mellum", tiny_mellum_config),
                        ("mistral4", tiny_mistral4_config),
                        ("afmoe", tiny_afmoe_config),
-                       ("longcat_flash", tiny_longcat_flash_config))}
+                       ("longcat_flash", tiny_longcat_flash_config),
+                       ("lfm2_moe", tiny_lfm2_moe_config))}
 PREFILL = moe.DENSE_MAX_TOKENS + 8
 
 

@@ -148,20 +148,20 @@ class LogitTap:
         self.rows = []        # (lengths, logits) per step
 
         @jax.jit
-        def step(params, pk, pv, conv, ssm, cnt, table, lengths, toks,
+        def step(params, pk, pv, state, cnt, table, lengths, toks,
                  key_data, steps, temps):
             with jax.default_matmul_precision("highest"):
-                logits, pk, pv, conv, ssm, cnt = (
+                logits, pk, pv, state, cnt = (
                     hybrid.paged_decode_step_hybrid(
-                        cfg, params, pk, pv, conv, ssm, cnt, table, lengths,
+                        cfg, params, pk, pv, state, cnt, table, lengths,
                         toks))
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps),
-                    pk, pv, conv, ssm, cnt)
+                    pk, pv, state, cnt)
 
-        def tapped(cfg_, params, pk, pv, conv, ssm, cnt, table, lengths,
+        def tapped(cfg_, params, pk, pv, state, cnt, table, lengths,
                    toks, key_data, steps, temps, compute_dtype):
-            logits, *rest = step(params, pk, pv, conv, ssm, cnt, table,
+            logits, *rest = step(params, pk, pv, state, cnt, table,
                                  lengths, toks, key_data, steps, temps)
             # copies: on the CPU a device array made from numpy may alias the
             # pool's own table, which the batcher goes on to overwrite
@@ -177,11 +177,12 @@ class LogitTap:
                 for lengths, logits in self.rows if lengths[slot] > 0}
 
 
-def _check_stream(tap, slot, cfg, params, prompt, tokens, tol=TOL):
+def _check_stream(tap, slot, cfg, params, prompt, tokens, tol=TOL,
+                  reference=None):
     """Every decode step's logits of a stream against the reference's full
-    forward over prompt + served tokens."""
+    forward (this family's, or ``reference``) over prompt + served tokens."""
     seq = np.concatenate([prompt, tokens])
-    want = ref_logits(cfg, params, seq)
+    want = (reference or ref_logits)(cfg, params, seq)
     got = tap.of_slot(slot)
     assert len(got) >= len(tokens) - 1
     for pos, row in got.items():
@@ -211,8 +212,22 @@ def test_prefill_then_decode_through_the_batcher_matches_the_full_forward(
     assert want0[toks[0]] >= want0.max() - TOL * np.abs(want0).max()
 
 
+@pytest.fixture(params=["granitemoehybrid", "lfm2_moe"])
+def recurrent(request):
+    """(config, seeded weights, reference logits) of each family whose
+    layers keep recurrent state: what the state store does for one it does
+    for the other, whatever leaves its kinds keep."""
+    if request.param == "lfm2_moe":
+        import test_lfm2_moe as fam   # imports this module's helpers: late
+    else:
+        fam = sys.modules[__name__]
+    return fam.CFG, fam.make_params(fam.CFG), fam.ref_logits
+
+
 def test_evict_then_readmit_reproduces_the_undisturbed_stream(monkeypatch,
-                                                             params):
+                                                             recurrent):
+    CFG, params, _ = recurrent
+    leaves = sorted(hybrid.state_shapes(CFG, 1))
     prompt = _ids(11, 7)
     with jax.default_matmul_precision("highest"):
         tap0 = LogitTap(monkeypatch, CFG)
@@ -228,8 +243,10 @@ def test_evict_then_readmit_reproduces_the_undisturbed_stream(monkeypatch,
         st = b._streams[sid]
         assert st.status == "running" and st.slot == 1
         b.evict(sid)
-        assert set(st.resume) == {"k", "v", "length", "conv", "ssm"}
-        assert st.resume["ssm"].dtype == np.float32
+        assert set(st.resume) == {"k", "v", "length", *leaves}
+        assert all(st.resume[leaf].dtype == np.float32 for leaf in leaves)
+        # a stream's own state comes back: not zeros, not its neighbour's
+        assert all(np.abs(st.resume[leaf]).max() > 0 for leaf in leaves)
         b.pool.check_invariants()
         got = b.run()[sid]
         assert b.report()["evicted"] == 1 and other in b.results
@@ -241,7 +258,8 @@ def test_evict_then_readmit_reproduces_the_undisturbed_stream(monkeypatch,
 
 
 def test_adjacent_slots_do_not_read_each_others_state_and_a_reused_slot_starts_from_zero(
-        monkeypatch, params):
+        monkeypatch, recurrent):
+    CFG, params, reference = recurrent
     prompt = _ids(9, 21)
     with jax.default_matmul_precision("highest"):
         alone = ContinuousBatcher(CFG, params, BCFG)
@@ -256,18 +274,19 @@ def test_adjacent_slots_do_not_read_each_others_state_and_a_reused_slot_starts_f
             b.step()
         assert first in b.results and not b.pool.active[0]
         # the slot a stream left keeps its stale state until it is reused...
-        assert float(jnp.abs(b.pool.state.ssm[:, 0]).max()) > 0
+        assert all(float(jnp.abs(a[:, 0]).max()) > 0
+                   for a in b.pool.state.values())
         again = b.submit(prompt, 10, rng_seed=1)          # ...reuses slot 0
         res = b.run()
     np.testing.assert_array_equal(res[sid], want)
     np.testing.assert_array_equal(res[again], want)
-    _check_stream(tap, 1, CFG, params, prompt, res[sid])
+    _check_stream(tap, 1, CFG, params, prompt, res[sid], reference=reference)
     assert third in res
     # and allocation itself zeroes the slot's rows
     pool = b.pool
     slot = pool.alloc_slot()
-    assert float(jnp.abs(pool.state.ssm[:, slot]).max()) == 0.0
-    assert float(jnp.abs(pool.state.conv[:, slot]).max()) == 0.0
+    assert all(float(jnp.abs(a[:, slot]).max()) == 0.0
+               for a in pool.state.values())
     pool.free_slot(slot)
     pool.check_invariants()
 
@@ -636,26 +655,35 @@ def test_the_old_families_take_no_hybrid_field():
     pool.check_invariants()
 
 
-def test_one_manager_for_both_kinds_of_state(params):
+def test_one_manager_for_both_kinds_of_state(recurrent):
+    CFG, params, _ = recurrent
     b = ContinuousBatcher(CFG, params, BCFG)
     pool = b.pool
     assert pool.pool.k.shape[0] == CFG.kv_layers == 1
-    conv, ssm = hybrid.state_shapes(CFG, BCFG.max_slots)
-    assert pool.state.conv.shape == conv and pool.state.ssm.shape == ssm
-    assert pool.state_bytes == 4 * (np.prod(conv) + np.prod(ssm))
+    shapes = hybrid.state_shapes(CFG, BCFG.max_slots)
+    assert {leaf: a.shape for leaf, a in pool.state.items()} == shapes
+    assert pool.state_leaf_bytes == {leaf: 4 * np.prod(shape)
+                                     for leaf, shape in shapes.items()}
+    assert pool.state_bytes == sum(pool.state_leaf_bytes.values()) > 0
     sid = b.submit(_ids(9), 5)
     b.step()
     pool.check_invariants()
     snap = pool.state_dict()
-    assert snap["state_ssm"].shape == ssm
     got = pool.gather_state(b._streams[sid].slot)
-    assert got["ssm"].shape == ssm[:1] + ssm[2:] and np.abs(got["ssm"]).max() > 0
+    assert sorted(got) == sorted(shapes)
+    for leaf, shape in shapes.items():
+        assert snap["state_" + leaf].shape == shape
+        assert got[leaf].shape == shape[:1] + shape[2:]
+        assert np.abs(got[leaf]).max() > 0
     pool.load_state_dict(snap)
     pool.check_invariants()
+    zeros = [0.0] * len(shapes)
     with pytest.raises(ValueError, match="not active"):
-        pool.adopt_state(2, 0.0, 0.0)
-    pool.state = paged_kv.SlotState(pool.state.conv,
-                                    pool.state.ssm.astype(jnp.bfloat16))
+        pool.adopt_state(2, *zeros)
+    with pytest.raises(ValueError, match="the state store holds"):
+        pool.adopt_state(0, *zeros, 0.0)
+    last = sorted(shapes)[-1]
+    pool.state = {**pool.state, last: pool.state[last].astype(jnp.bfloat16)}
     with pytest.raises(AssertionError, match="float32"):
         pool.check_invariants()
 
@@ -688,8 +716,8 @@ def test_the_step_carries_the_new_scopes_and_donates_five_buffers(params):
     b = ContinuousBatcher(CFG, params, BCFG)
     table, lengths = b.pool.device_tables()
     n = BCFG.max_slots
-    args = (CFG, params, b.pool.pool.k, b.pool.pool.v, b.pool.state.conv,
-            b.pool.state.ssm, b._expert_tokens, table, lengths,
+    args = (CFG, params, b.pool.pool.k, b.pool.pool.v, b.pool.state,
+            b._expert_tokens, table, lengths,
             jnp.zeros((n,), jnp.int32), jnp.asarray(b._free_key_rows),
             jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32), None)
     lowered = batching._batched_hybrid_step_jit.lower(*args)

@@ -341,9 +341,9 @@ class LogitTap:
         def step(params, pool, wpool, cnt, table, wtable, lengths, toks,
                  key_data, steps, temps):
             with jax.default_matmul_precision("highest"):
-                logits, k, v, _, _, cnt, (wk, wv) = (
+                logits, k, v, _, cnt, (wk, wv) = (
                     hybrid.paged_decode_step_hybrid(
-                        cfg, params, pool.k, pool.v, None, None, cnt, table,
+                        cfg, params, pool.k, pool.v, None, cnt, table,
                         lengths, toks, window=(wpool.k, wpool.v, wtable)))
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps),
@@ -440,7 +440,7 @@ def test_window_layers_hold_a_ring_however_long_the_stream_grows(
     cfg = CFG
     table, lengths = pool.device_tables()
     jaxpr = jax.make_jaxpr(lambda *a: hybrid.paged_decode_step_hybrid(
-        cfg, params, a[0], a[1], None, None, jnp.zeros((8, 8), jnp.int32),
+        cfg, params, a[0], a[1], None, jnp.zeros((8, 8), jnp.int32),
         table, lengths, jnp.zeros((3,), jnp.int32),
         window=(a[2], a[3], pool.device_window_table())))(
             pool.pool.k, pool.pool.v, pool.window_pool.k, pool.window_pool.v)

@@ -265,7 +265,7 @@ def test_latent_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
     held[np.unique(table)] = True               # the trash page among them
     step = functools.partial(
         hybrid.paged_decode_step_hybrid, cfg, params, pool_v=None,
-        conv_all=None, ssm_all=None,
+        state=None,
         expert_tokens=jnp.zeros((2, cfg.local_experts), jnp.int32),
         page_table=jnp.asarray(table), lengths=jnp.asarray(lens),
         token_ids=jnp.asarray([3, 0, 5, 7], jnp.int32))
@@ -332,9 +332,9 @@ def test_a_640_lane_step_of_two_sublayers_equals_the_contiguous_step(
     pool = paged_kv.LatentPool(jnp.asarray(rows, pool_dtype))
     assert paged_kv.decode_read_path(pool) == (
         paged_kv.PAGE_WALK if read == "page-walk" else paged_kv.PAGE_GATHER)
-    got, got_rows, _, _, _, counts = jax.block_until_ready(
+    got, got_rows, _, _, counts = jax.block_until_ready(
         hybrid.paged_decode_step_hybrid(
-            cfg, params, pool.rows, None, None, None,
+            cfg, params, pool.rows, None, None,
             jnp.zeros((1, cfg.counted_experts), jnp.int32),
             jnp.asarray(table), jnp.asarray(lens), jnp.asarray(toks)))
     assert int(counts.sum()) == 3 * cfg.experts_per_tok    # the live slots'

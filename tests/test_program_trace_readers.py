@@ -114,7 +114,8 @@ ALL_CELLS = CELLS + ("granite-4.0-h-small-ep2.decode-sat",
                      "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
                      "mistral-small-4-119b-ep4.decode-sat-deep",
                      "trinity-mini-pp8.decode-sat-long",
-                     "longcat-flash-chat-ep32.decode-sat-reason")
+                     "longcat-flash-chat-ep32.decode-sat-reason",
+                     "lfm2-8b-a1b-pp2.decode-sat-docs")
 EDGES = [0.01, 0.02, 0.04, 0.08]            # five rows: under, three, over
 PHASE_KEYS = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s",
               "commit_s")
@@ -317,7 +318,8 @@ def test_the_afmoe_cell_lists_its_readers_and_the_ones_it_joins():
 EXPERT_CELLS = ("granite-4.0-h-small-ep2.decode-sat",
                 "mellum2-12b-a2.5b-pp4.decode-sat-mixed",
                 "mistral-small-4-119b-ep4.decode-sat-deep", AFMOE_CELL,
-                "longcat-flash-chat-ep32.decode-sat-reason")
+                "longcat-flash-chat-ep32.decode-sat-reason",
+                "lfm2-8b-a1b-pp2.decode-sat-docs")
 
 
 def test_grouped_reader_is_the_new_scope_per_admission(table, monkeypatch):
@@ -442,8 +444,7 @@ def test_the_longcat_cell_lists_its_readers_and_the_ones_it_joins():
     names = [m["name"] for m in spec["per_layer"]]
     at = names.index(LONGCAT_READERS[0])
     assert names[at:at + 4] == list(LONGCAT_READERS)
-    assert spec["workloads"][-1]["name"] == LONGCAT_CELL
-    assert len(spec["workloads"]) == 8
+    assert spec["workloads"][7]["name"] == LONGCAT_CELL
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
 
 
@@ -476,3 +477,104 @@ def test_every_cell_lists_launch_ahead_share(cell):
             entry["moves"]) == ("batcher", "program_counter", "higher",
                                 "gap_mean_ms")
     assert cell in entry["workloads"]
+
+
+# ---------------------------------------------------------------------------
+# PR 44: the lfm2_moe cell's four readers, on events made by hand
+# ---------------------------------------------------------------------------
+
+LFM2_CELL = "lfm2-8b-a1b-pp2.decode-sat-docs"
+LFM2_READERS = ("shortconv_dev_ms", "shortconv_hbm_share",
+                "lfm2_experts_hbm_share", "lfm2_step_hbm_share")
+
+
+@pytest.fixture
+def lfm2_record(monkeypatch):
+    """Two runs of the step executable with a prefill between them, under the
+    same scopes: a window of 1 ms on one device plane, times in ns."""
+    from benchmark import rooflines_lfm2_moe as r
+
+    step = "jit__batched_hybrid_step_jit(5)"
+    events = {"devices": {"/device:TPU:0": {
+        "modules": [[step, 0, 100_000], ["jit__prefill_jit(7)", 100_000,
+                                         500_000], [step, 600_000, 100_000]],
+        "ops": [["shortconv.proj", 10_000, 20_000],
+                ["shortconv.conv", 30_000, 10_000],
+                ["moe.experts", 40_000, 50_000],
+                ["shortconv.proj", 150_000, 200_000],     # the prefill's
+                ["moe.experts", 350_000, 100_000],        # the prefill's
+                ["shortconv.proj", 610_000, 22_000],
+                ["shortconv.conv", 632_000, 8_000],
+                ["moe.route", 640_000, 4_000],
+                ["moe.experts", 644_000, 50_000]]}},
+        "host": [["bench.window", 0, 1_000_000]]}
+    monkeypatch.setitem(r._EVENTS, "events", events)
+    with open(os.path.join(HERE, "configs", "lfm2-8b-a1b-pp2.json")) as f:
+        config = json.load(f)
+    return {"trace": {"modules": {"jit__batched_hybrid_step_jit": {
+                "runs": 100, "seconds": 1.6}}},
+            "config": config, "device_kind": "TPU v5 lite",
+            "pool_live_share": 0.55, "token_capacity": 96 * 4608,
+            "report0": {"steps": 0, "slot_util_mean": 0.0},
+            "report1": {"steps": 100, "slot_util_mean": 1.0}}
+
+
+@pytest.mark.parametrize("name", LFM2_READERS)
+def test_lfm2_reader_counts_the_steps_own_operations(name, lfm2_record,
+                                                     monkeypatch):
+    """Only what ran inside a run of the step executable counts, per run (the
+    prefills run under the same scopes); the shares are the bytes of
+    ``rooflines_lfm2_moe`` at 819 GB/s over that time; nothing untraced, and
+    nothing on a program whose trace holds no such scope (the parent)."""
+    from benchmark import rooflines_lfm2_moe as r
+
+    c = lfm2_record["config"]
+    conv_ms, moe_ms = 0.030, 0.052
+    want = {
+        "shortconv_dev_ms": conv_ms,
+        "shortconv_hbm_share": 100 * ((9 * 16_783_360 * 2
+                                       + 2 * 96 * 147_456) / 819e9)
+        / (1e-3 * conv_ms),
+        "lfm2_experts_hbm_share": 100 * (10 * 352_387_104 * 2 / 819e9)
+        / (1e-3 * moe_ms),
+        "lfm2_step_hbm_share": 100 * (r.step_bytes(
+            c, 0.55 * 96 * 4608, 96) / 819e9) / 16e-3}[name]
+    assert _read(name, lfm2_record) == pytest.approx(want)
+    assert _read(name, {**lfm2_record, "trace": None}) is None
+    if name != "lfm2_step_hbm_share":
+        bare = {"devices": {"/device:TPU:0": {
+            "modules": [["jit__batched_hybrid_step_jit(5)", 0, 100_000]],
+            "ops": [["attn.decode", 10_000, 20_000]]}},
+            "host": [["bench.window", 0, 1_000_000]]}
+        monkeypatch.setitem(r._EVENTS, "events", bare)
+        assert _read(name, lfm2_record) is None
+        monkeypatch.setitem(r._EVENTS, "events", None)   # no profile left
+        assert _read(name, lfm2_record) is None
+
+
+def test_the_lfm2_cell_lists_its_readers_and_the_ones_it_joins():
+    from edgellm_tpu.obs.names import SCOPE_NAMES
+
+    assert {"shortconv.proj", "shortconv.conv"} <= set(SCOPE_NAMES)
+    names = {m.name for m in load_cell(LFM2_CELL).per_layer}
+    assert names >= set(LFM2_READERS) | {
+        "attn_decode_dev_ms", "attend_walk_share", "moe_experts_dev_ms",
+        "moe_grouped_dev_ms", "dense_mlp_dev_ms", "unembed_sample_dev_ms",
+        "step_dev_ms", "admit_dev_ms", "device_idle", "compiles_in_window",
+        "launch_ahead_share"} | set(FOLD_READERS)
+    # other families' scopes and byte counts, and what moves ``out_tok_s``
+    assert not names & {"ssm_step_dev_ms", "ssm_step_hbm_share",
+                        "hybrid_step_hbm_share", "moe_experts_hbm_share",
+                        "afmoe_step_hbm_share", "attn_window_dev_ms",
+                        "attn_latent_dev_ms", "expert_load_skew",
+                        "routed_local_share", "slot_util", "pool_live",
+                        "evictions"}
+    assert {m.name for m in load_cell(LFM2_CELL).end_to_end} == {
+        "gap_mean_ms", "setup_s"}
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    assert [m["name"] for m in spec["per_layer"]][-4:] == list(LFM2_READERS)
+    assert all(m["workloads"] == [LFM2_CELL] for m in spec["per_layer"][-4:])
+    assert spec["workloads"][-1]["name"] == LFM2_CELL
+    assert len(spec["workloads"]) == 9
+    assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
