@@ -546,11 +546,15 @@ def _ring_stream(cfg, prompt_len: int, n_new: int, evict_after: int) -> tuple:
     report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after)
     assert report["window_rows_capacity"] == 3 * 4 * 16
     assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert report["attend_fetches_per_page"] == 1   # a page is one DMA
     assert report["window_read"] == PAGE_WALK, report["window_read"]
     assert 0 < report["window_pages_walked"] <= report["window_pages_spanned"]
     return report, {"tokens": int(n_new), "evicted": report["evicted"],
                     "window_pages": cfg.window_pages(16),
                     "decode_read": report["decode_read"],
+                    "attend_fetches_per_page":
+                        report["attend_fetches_per_page"],
+            "attend_fetches_per_page": report["attend_fetches_per_page"],
                     "window_read": report["window_read"],
                     "window_pages_walked": report["window_pages_walked"],
                     "window_pages_spanned": report["window_pages_spanned"],
@@ -612,9 +616,11 @@ def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
     assert report["latent_rows_capacity"] == 72 * 16
     assert report["kv_row_bytes"] == cfg.kv_row_lanes * 4
     assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert report["attend_fetches_per_page"] == 1   # a page is one DMA
     assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
     return {"tokens": int(n_new), "evicted": report["evicted"],
             "decode_read": report["decode_read"],
+            "attend_fetches_per_page": report["attend_fetches_per_page"],
             "attend_pages_walked": report["attend_pages_walked"],
             "attend_pages_spanned": report["attend_pages_spanned"],
             "kv_row_bytes": report["kv_row_bytes"],
@@ -654,12 +660,14 @@ def longcat_phase(*, prompt_len: int = 300, n_new: int = 40,
     assert cfg.latent_layers == 4 and len(report["expert_tokens"]) == 2
     assert report["latent_rows_capacity"] == 72 * 16
     assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert report["attend_fetches_per_page"] == 1   # a page is one DMA
     assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
     made = report["routed_assignments"]
     assert 0 < report["zero_assignments"] < made
     assert 0 < report["routed_local"] < made - report["zero_assignments"]
     return {"tokens": int(n_new), "evicted": report["evicted"],
             "decode_read": report["decode_read"],
+            "attend_fetches_per_page": report["attend_fetches_per_page"],
             "attend_pages_walked": report["attend_pages_walked"],
             "attend_pages_spanned": report["attend_pages_spanned"],
             "kv_row_bytes": report["kv_row_bytes"],
@@ -715,6 +723,7 @@ def shortconv_phase(*, prompt_len: int = 300, n_new: int = 40,
     assert report["state_leaf_bytes"] == {"conv": 5 * 3 * 2 * 256 * 4}
     assert report["state_bytes"] == 5 * 3 * 2 * 256 * 4
     assert report["decode_read"] == PAGE_WALK, report["decode_read"]
+    assert report["attend_fetches_per_page"] == 1   # a page is one DMA
     assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
     assert report["grouped_product"] == grouped_matmul.PALLAS_GROUPED, \
         report["grouped_product"]
@@ -725,6 +734,7 @@ def shortconv_phase(*, prompt_len: int = 300, n_new: int = 40,
             "evicted": report["evicted"],
             "state_leaf_bytes": report["state_leaf_bytes"],
             "decode_read": report["decode_read"],
+            "attend_fetches_per_page": report["attend_fetches_per_page"],
             "grouped_product": report["grouped_product"],
             "routed_local": report["routed_local"],
             "launch_ahead_share": report["launch_ahead_share"],

@@ -179,7 +179,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             lambda p, pool, pt, ln, t: paged_kv.paged_decode_step(
                 cfg, p, pool, pt, ln, t),
             (params, ppool, ptab, plens, ptoks),
-            ctx={"donate_min": 2},
+            ctx={"donate_min": 1},
             lowerable=batching._batched_step_jit,
             lower_args=(cfg, params, ppool, ptab, plens, ptoks,
                         pkeys, psteps, ptemps, None))
@@ -321,7 +321,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
 
     # ---- a stack with recurrent state (granitemoehybrid): the hybrid
     # ---- ragged step — collective-free; the K/V pages, the per-slot state
-    # ---- store (conv, ssm) and the expert counter, FIVE buffers, stay
+    # ---- store (conv, ssm) and the expert counter, FOUR buffers, stay
     # ---- donated in the lowered executable ------------------------------
     from ..models import hybrid
     from ..models.configs import tiny_hybrid_config
@@ -332,19 +332,19 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     hstate = paged_kv.init_slot_state(hcfg, MS)
     hcount = jnp.zeros((hcfg.num_layers, hcfg.local_experts), jnp.int32)
     run_one("paged.decode_step_hybrid",
-            lambda p, pk, pv, st, ct, pt, ln, t:
+            lambda p, kv, st, ct, pt, ln, t:
                 hybrid.paged_decode_step_hybrid(
-                    hcfg, p, pk, pv, st, ct, pt, ln, t),
-            (hparams, hpool.k, hpool.v, hstate, hcount, ptab, plens, ptoks),
-            ctx={"donate_min": 5},
+                    hcfg, p, kv, st, ct, pt, ln, t),
+            (hparams, hpool.kv, hstate, hcount, ptab, plens, ptoks),
+            ctx={"donate_min": 4},
             lowerable=batching._batched_hybrid_step_jit,
-            lower_args=(hcfg, hparams, hpool.k, hpool.v, hstate, hcount,
+            lower_args=(hcfg, hparams, hpool.kv, hstate, hcount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # ---- a stack with sliding-window layers (mellum): the same walk with
     # ---- the window group — collective-free; the full layers' pool, the
-    # ---- window layers' pool of rings (two buffers each) and the expert
-    # ---- counter, FIVE buffers, stay donated in the lowered executable ---
+    # ---- window layers' pool of rings (one buffer each) and the expert
+    # ---- counter, THREE buffers, stay donated in the lowered executable --
     from ..models.configs import tiny_mellum_config
 
     wcfg = tiny_mellum_config(sliding_window=2 * PGS + 2)
@@ -355,21 +355,19 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     wtab = jnp.zeros((MS, wcfg.window_pages(PGS)), jnp.int32)
     wcount = jnp.zeros((wcfg.num_layers, wcfg.local_experts), jnp.int32)
     run_one("paged.decode_step_window",
-            lambda p, pk, pv, wk, wv, ct, pt, wt, ln, t:
+            lambda p, kv, win, ct, pt, wt, ln, t:
                 hybrid.paged_decode_step_hybrid(
-                    wcfg, p, pk, pv, None, ct, pt, ln, t,
-                    window=(wk, wv, wt)),
-            (wparams, wfull.k, wfull.v, wring.k, wring.v, wcount, ptab, wtab,
-             plens, ptoks),
-            ctx={"donate_min": 5},
+                    wcfg, p, kv, None, ct, pt, ln, t, window=(win, wt)),
+            (wparams, wfull.kv, wring.kv, wcount, ptab, wtab, plens, ptoks),
+            ctx={"donate_min": 3},
             lowerable=batching._batched_window_step_jit,
             lower_args=(wcfg, wparams, wfull, wring, wcount, ptab, wtab,
                         plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # ---- a stack of latent-attention layers (mistral4): the same walk and
-    # ---- the same step executable with the pool's ONE leaf where the K
-    # ---- pages go and no V pages or state store — collective-free; the
-    # ---- leaf and the expert counter, TWO buffers, stay donated ---------
+    # ---- the same step executable with the pool's ONE leaf where the K/V
+    # ---- pages go and no state store — collective-free; the leaf and the
+    # ---- expert counter, TWO buffers, stay donated ----------------------
     from ..models.configs import tiny_mistral4_config
 
     lcfg = tiny_mistral4_config()
@@ -378,17 +376,17 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     lcount = jnp.zeros((lcfg.num_layers, lcfg.local_experts), jnp.int32)
     run_one("paged.decode_step_latent",
             lambda p, rows, ct, pt, ln, t: hybrid.paged_decode_step_hybrid(
-                lcfg, p, rows, None, None, ct, pt, ln, t),
+                lcfg, p, rows, None, ct, pt, ln, t),
             (lparams, lpool.rows, lcount, ptab, plens, ptoks),
             ctx={"donate_min": 2},
             lowerable=batching._batched_hybrid_step_jit,
-            lower_args=(lcfg, lparams, lpool.rows, None, None, lcount,
+            lower_args=(lcfg, lparams, lpool.rows, None, lcount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # ---- a stack of short convolutions beside rotated attention layers
     # ---- (lfm2_moe): the same executable with a state store of ONE leaf,
     # ---- the windows — collective-free; the K/V pages, the windows and the
-    # ---- expert counter, FOUR buffers, stay donated ----------------------
+    # ---- expert counter, THREE buffers, stay donated ---------------------
     from ..models.configs import tiny_lfm2_moe_config
 
     ccfg = tiny_lfm2_moe_config()
@@ -397,13 +395,13 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     cstate = paged_kv.init_slot_state(ccfg, MS)
     ccount = jnp.zeros((ccfg.expert_layers, ccfg.local_experts), jnp.int32)
     run_one("paged.decode_step_shortconv",
-            lambda p, pk, pv, st, ct, pt, ln, t:
+            lambda p, kv, st, ct, pt, ln, t:
                 hybrid.paged_decode_step_hybrid(
-                    ccfg, p, pk, pv, st, ct, pt, ln, t),
-            (cparams, cpool.k, cpool.v, cstate, ccount, ptab, plens, ptoks),
-            ctx={"donate_min": 4},
+                    ccfg, p, kv, st, ct, pt, ln, t),
+            (cparams, cpool.kv, cstate, ccount, ptab, plens, ptoks),
+            ctx={"donate_min": 3},
             lowerable=batching._batched_hybrid_step_jit,
-            lower_args=(ccfg, cparams, cpool.k, cpool.v, cstate, ccount,
+            lower_args=(ccfg, cparams, cpool.kv, cstate, ccount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state
@@ -492,9 +490,9 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             # reference: dequantize the WHOLE pool, then the plain fp path
             fshape = tpool.k.shape[:-1] + (cfg.num_kv_heads * cfg.head_dim,)
             ref = paged_kv.paged_decode_attention(
-                q, paged_kv.PagePool(
+                q, paged_kv.PagePool(paged_kv.join_kv(
                     fa.dequantize_kv_rows(kq, ks, tier).reshape(fshape),
-                    fa.dequantize_kv_rows(vq, vs, tier).reshape(fshape)),
+                    fa.dequantize_kv_rows(vq, vs, tier).reshape(fshape))),
                 0, etab, elens)
             if not np.array_equal(np.asarray(got), np.asarray(ref)):
                 d = float(np.abs(np.asarray(got) - np.asarray(ref)).max())
@@ -729,7 +727,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         "hop_eqns": n_hops * leaves_p,
         "wire_dtypes": frozenset(dtypes_p),
         "wire_bytes": sum(rt.decode_hop_bytes(MS)),
-        "donate_min": 2,  # the per-stage page pools update in place
+        "donate_min": 1,  # the per-stage page pool updates in place
     }
     run_one("split.decode_step_paged", pstep_fn,
             (placed, spool, ptab, plens, ptoks), paged_ctx,
@@ -809,7 +807,7 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
         "hop_eqns": PM * n_hops * leaves_p,
         "wire_dtypes": frozenset(dtypes_p),
         "wire_bytes": sum(rt_pipe.pipelined_decode_hop_bytes(MS)),
-        "donate_min": 2,
+        "donate_min": 1,
     }
     run_one("split.decode_step_paged.pipelined", pipe_pstep_fn,
             (placed, spool, ptab, plens, ptoks),

@@ -578,17 +578,18 @@ def verify_attention(q, k_cache, v_cache, length):
 # Paged decode attention: q_len=1 per slot against pages read where they lie.
 # ---------------------------------------------------------------------------
 
-#: rows of K (and of V) one block of the page walk holds in VMEM: the unit a
-#: slot's pages are fetched, waited for and attended in. PERF.md §6 "PR 33"
-#: has the v5e's timings from 64 to 1024 rows.
+#: rows one block of the page walk holds in VMEM: the unit a slot's pages are
+#: fetched, waited for and attended in. PERF.md §6 "PR 33" has the v5e's
+#: timings from 64 to 1024 rows.
 WALK_BLOCK_ROWS = 512
 
 
 def paged_walk_pages_per_block(page_size: int, width: int,
                                itemsize: int) -> int:
-    """Pages a block of :func:`paged_decode_walk` holds: ``WALK_BLOCK_ROWS``
-    rows, fewer where the row is wide, so that the four buffers (K and V,
-    two each) stay inside 4 MB of VMEM."""
+    """Pages a block of :func:`paged_decode_walk` holds over a K/V pool whose
+    K lanes are ``width`` wide (a row is ``2 * width``): ``WALK_BLOCK_ROWS``
+    rows, fewer where the row is wide, so that the two buffers stay inside
+    4 MB of VMEM."""
     rows = min(WALK_BLOCK_ROWS, (1 << 20) // (width * itemsize))
     return max(rows // page_size, 1)
 
@@ -602,27 +603,29 @@ def ring_walk_pages_per_block(entries: int, page_size: int, width: int,
     two, which would end every slot on a block of one page and a full pair
     of dots. So the ring is cut into EQUAL blocks, as many as blocks of
     twice that answer give to the nearest: 129 entries two blocks of 65, 65
-    entries one (4.3 MB in the four buffers at 512-lane bf16 rows). On a v5e
+    entries one (4.3 MB in the two buffers at 512 K lanes of bf16). On a v5e
     at the two cells' shapes 33 / 43 / 65 / 86 / 129 pages a block take
     0.75 / 0.71 / 0.69 / 0.73 / 0.66 ms a layer over rings of 129 (an
     all-idle batch 0.24 / 0.20 / 0.22 / 0.23 / 0.25, rings half filled
     0.42 / 0.43 / 0.38 / 0.40 / 0.47) and 33 / 43 / 65 take 0.39 / 0.41 /
-    0.36 over rings of 65 (PERF.md §6 "PR 40")."""
+    0.36 over rings of 65 (PERF.md §6 "PR 40", K and V in two leaves)."""
     ppb = paged_walk_pages_per_block(page_size, width, itemsize)
     return -(-entries // max((entries + ppb) // (2 * ppb), 1))
 
 
-def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                       kbuf, vbuf, sem, par_ref, *, scale, window=0):
+def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
+                       par_ref, *, scale, window=0):
     """Grid (B,): slot ``b`` of the step, every head. See
     :func:`paged_decode_walk`."""
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
-    _, ppb, ps, w = kbuf.shape
+    _, ppb, ps, _ = rbuf.shape
     rows = ppb * ps
+    w = q_ref.shape[-1]
     entries = ids_ref.shape[1]              # of a ring, where ``window``
-    leaves = ((k_hbm, kbuf, 0),) if v_hbm is None else (
-        (k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+    # a row of w lanes is key and value both (a latent row); one of 2 w holds
+    # the K lanes, then the V lanes
+    v_at = rbuf.shape[-1] - w
 
     def live_pages(slot):
         pages = jnp.maximum(pl.cdiv(len_ref[slot], ps), 1)
@@ -634,16 +637,14 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         return jnp.minimum(live_pages(slot) - blk * ppb, ppb)
 
     def start(slot, blk, buf):
-        """A DMA a live page a leaf. What bounds a saturated step is how
-        fast these are ISSUED (~15 ns each, PERF.md §6 "PR 33"), so the loop
-        is unrolled by two: 0.73 -> 0.63 ms a layer."""
+        """A DMA a live page. What bounds a saturated step is how fast these
+        are ISSUED (PERF.md §6 "PR 33", "PR 45"), so a page is one DMA, K and
+        V lanes together, and the loop is unrolled by two."""
         cnt = num_pages(slot, blk)
 
         def page(j):
-            at = ids_ref[slot, blk * ppb + j]
-            for hbm, dst, s in leaves:
-                pltpu.make_async_copy(hbm.at[at], dst.at[buf, j],
-                                      sem.at[s, buf]).start()
+            pltpu.make_async_copy(hbm.at[ids_ref[slot, blk * ppb + j]],
+                                  rbuf.at[buf, j], sem.at[buf]).start()
 
         def pair(g, _):
             page(2 * g)
@@ -659,15 +660,14 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     def wait(slot, blk, buf):
         """A DMA semaphore counts bytes, so the block's pages are waited for
         by the binary digits of their count: at most ``log2(ppb) + 1`` waits
-        a leaf in place of one a page (0.82 -> 0.73 ms a layer)."""
+        in place of one a page (0.82 -> 0.73 ms a layer)."""
         cnt = num_pages(slot, blk)
         for bit in (1 << i for i in range(ppb.bit_length())):
             @pl.when((cnt & bit) != 0)
             def _wait(bit=bit):
-                for hbm, dst, s in leaves:
-                    pltpu.make_async_copy(
-                        hbm.at[pl.ds(0, bit)], dst.at[buf, pl.ds(0, bit)],
-                        sem.at[s, buf]).wait()
+                pltpu.make_async_copy(
+                    hbm.at[pl.ds(0, bit)], rbuf.at[buf, pl.ds(0, bit)],
+                    sem.at[buf]).wait()
 
     @pl.when(b == 0)
     def _first():
@@ -680,7 +680,7 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     nblk = pl.cdiv(live_pages(b), ppb)
     # a query and a pool of two dtypes meet in the wider, as the einsums of
     # ``paged_kv.attend_rows`` promote them
-    wide = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    wide = jnp.promote_types(q_ref.dtype, rbuf.dtype)
     q = q_ref[0].astype(wide)                               # (H, W)
     h = q.shape[0]
     if window:
@@ -716,7 +716,7 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             start(nxt, jnp.where(more, n + 1, 0), 1 - buf)
 
         wait(b, n, buf)
-        k = kbuf[buf].reshape(rows, w).astype(wide)
+        k = rbuf[buf, :, :, pl.ds(0, w)].reshape(rows, w).astype(wide)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if window:
@@ -733,7 +733,7 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         p = jnp.exp(s - m_new)
         # rows no DMA filled are stale VMEM, a ring's rows of the lap before
         # whatever the stream left there: 0 x NaN must not reach the sum
-        v = (kbuf if v_hbm is None else vbuf)[buf].reshape(rows, w)
+        v = rbuf[buf, :, :, pl.ds(v_at, w)].reshape(rows, w)
         v = jnp.where(attended((rows, w), 0), v, jnp.zeros_like(v))
         pv = jax.lax.dot_general(p.astype(q_ref.dtype).astype(wide),
                                  v.astype(wide),
@@ -752,7 +752,7 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "scale", "pages_per_block", "interpret", "window"))
-def paged_decode_walk(qz, k_pages, v_pages, page_ids, lengths, *,
+def paged_decode_walk(qz, pages, page_ids, lengths, *,
                       scale: float, pages_per_block: int | None = None,
                       interpret=False, window: int = 0):
     """Single-position attention of every slot over ITS OWN live pages, read
@@ -761,18 +761,19 @@ def paged_decode_walk(qz, k_pages, v_pages, page_ids, lengths, *,
 
     qz (B, H, W): a slot's query heads, each in the lanes of its KV group and
     zero elsewhere (``paged_kv.attend_rows`` builds it: scores are one dot
-    over the whole lane-dense row). k_pages, v_pages (N, ps, W): a pool's
-    leaves viewed as pages, every layer's; they stay in HBM. ``v_pages``
-    None: the rows of ``k_pages`` are both key and value (a latent row), one
-    fetch a page. page_ids (B, pages_per_slot)
+    over the whole lane-dense row). pages (N, ps, R): a pool's ONE leaf viewed
+    as pages, every layer's; it stays in HBM. R = 2 W: a row is a position's
+    K lanes, then its V lanes (``paged_kv.PagePool``). R = W: the row is both
+    key and value (a latent row, ``paged_kv.LatentPool``). Either way a page
+    is one fetch. page_ids (B, pages_per_slot)
     int32: slot i's pages in position order, as indices into N; lengths (B,)
     int32: the positions slot i attends, >= 1. Returns (B, H, W) in qz's
-    dtype: ``softmax(scale * qz . rows^T) . rows`` in float32, of which a
-    head keeps its group's lanes.
+    dtype: ``softmax(scale * qz . K^T) . V`` in float32, of which a head
+    keeps its group's lanes.
 
     Slot i fetches ``ceil(lengths[i] / ps)`` pages and no more, a block of
-    ``pages_per_block`` at a time through two VMEM buffers a leaf (a DMA a
-    page), with a running (max, sum, accumulator) softmax over the blocks.
+    ``pages_per_block`` at a time through two VMEM buffers (a DMA a page),
+    with a running (max, sum, accumulator) softmax over the blocks.
     The pipeline runs ACROSS slots: a slot's last block is attended while the
     next slot's first is in flight. Rows past ``lengths[i]`` in the last
     block are masked before the exponent and their V rows selected to zero,
@@ -794,33 +795,27 @@ def paged_decode_walk(qz, k_pages, v_pages, page_ids, lengths, *,
     Scalar prefetch puts ``page_ids`` and ``lengths`` in SMEM before the
     body runs."""
     b, h, w = qz.shape
-    ps = k_pages.shape[1]
-    itemsize = k_pages.dtype.itemsize
+    _, ps, r = pages.shape
+    if r not in (w, 2 * w):
+        raise ValueError(f"a page row of {r} lanes is neither a query's {w} "
+                         f"(key and value both) nor K then V ({2 * w})")
+    itemsize = pages.dtype.itemsize
     ppb = pages_per_block or (
         ring_walk_pages_per_block(page_ids.shape[1], ps, w, itemsize)
         if window else paged_walk_pages_per_block(ps, w, itemsize))
-    buf = pltpu.VMEM((2, ppb, ps, w), k_pages.dtype)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
     slot = pl.BlockSpec((1, h, w), lambda i, ids, lens: (i, 0, 0))
-    if v_pages is None:
-        kernel = lambda ids, lens, q, k, o, kb, sem, par: _paged_walk_kernel(
-            ids, lens, q, k, None, o, kb, None, sem, par, scale=scale,
-            window=window)
-        leaves, bufs = (k_pages,), [buf]
-    else:
-        kernel = functools.partial(_paged_walk_kernel, scale=scale,
-                                   window=window)
-        leaves, bufs = (k_pages, v_pages), [buf, buf]
     return pl.pallas_call(
-        kernel,
+        functools.partial(_paged_walk_kernel, scale=scale, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[slot] + [hbm] * len(leaves), out_specs=slot,
-            scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((2, 2)),
-                                   pltpu.SMEM((1,), jnp.int32)]),
+            in_specs=[slot, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=slot,
+            scratch_shapes=[pltpu.VMEM((2, ppb, ps, r), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, w), qz.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_walk",
-    )(page_ids, lengths, qz, *leaves)
+    )(page_ids, lengths, qz, pages)

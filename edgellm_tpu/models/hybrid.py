@@ -560,66 +560,66 @@ def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
 @graph_contract("paged.decode_step_latent", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 2))
 @graph_contract("paged.decode_step_hybrid", collectives={},
-                donate=lambda ctx: ctx.get("donate_min", 5))
-@graph_contract("paged.decode_step_shortconv", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 4))
+@graph_contract("paged.decode_step_shortconv", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 3))
 @graph_contract("paged.decode_step_window", collectives={},
-                donate=lambda ctx: ctx.get("donate_min", 5))
-def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                             state, expert_tokens, page_table, lengths,
-                             token_ids, window=None):
+                donate=lambda ctx: ctx.get("donate_min", 3))
+def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
+                             expert_tokens, page_table, lengths, token_ids,
+                             window=None):
     """The ragged step for a hybrid stack: one position for EVERY slot.
 
-    pool_k/pool_v: (L_attn, num_pages, page_size, KV * hd), addressed by the
+    pool: the page pool's ONE leaf — (L_attn, num_pages, page_size, 2 * KV *
+    hd), a ``paged_kv.PagePool``'s K-then-V rows, or for a stack of latent
+    layers (``cfg.latent_layers``) a ``LatentPool``'s (L, num_pages,
+    page_size, kv_row_lanes) — addressed by the
     static attention-layer number (paged_kv's flat index); state: the
     per-slot state store, :func:`state_shapes`'s leaves (L_kind, max_slots,
     ...) float32, None for a stack that keeps none; expert_tokens
     (L expert layers, Eh) int32, the count of assignments per held expert, which
     gains this step's over the slots with ``lengths > 0`` (a free slot runs
     token-0 math into the trash page and into its own dead state rows, and is
-    not counted). Returns (logits (max_slots, V) float32, pool_k, pool_v,
-    state, expert_tokens).
+    not counted). Returns (logits (max_slots, V) float32, pool, state,
+    expert_tokens).
 
-    A stack with sliding layers also passes ``window`` = (win_k, win_v
-    (L_window, window pool pages, page_size, KV * hd), window_table
-    (max_slots, window_pages): each slot's ring) and gets (win_k, win_v) back
-    as a sixth result; it has no recurrent layer.
-
-    A stack of latent layers passes its pool's ONE leaf (L, num_pages, page,
-    kv_row_lanes) as ``pool_k``; pool_v and state: None both ways."""
+    A stack with sliding layers also passes ``window`` = (win (L_window,
+    window pool pages, page_size, 2 * KV * hd), the window group's leaf,
+    window_table (max_slots, window_pages): each slot's ring) and gets win
+    back as a fifth result; it has no recurrent layer."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
     counts, term = [], None
     # each slot's own row of the table its layer kind rotates by
-    span = page_table.shape[1] * pool_k.shape[2]
+    span = page_table.shape[1] * pool.shape[2]
     rope = {kind: t and (t[0][lengths], t[1][lengths])
             for kind, t in _rope_tables(cfg, span).items()}
     if window is not None:
-        win_k, win_v, window_table = window
+        win, window_table = window
     for layer, kind, j in _kinds(cfg):
         if kind == "latent_attention":
             lp = _row(params["latent"], j)
-            out, (pool_k,) = _attention_decode_latent(
+            out, (pool,) = _attention_decode_latent(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]), *rope[kind],
-                LatentPool(pool_k), j, page_table, lengths)
+                LatentPool(pool), j, page_table, lengths)
         elif kind in ("mamba", "conv"):
             state, out = _step_row(cfg, kind, _row(params[kind], j), h, state,
                                    j)
         elif kind == "sliding_attention":
             lp = _row(params["window"], j)
-            out, (win_k, win_v) = _attention_decode_window(
+            out, (win,) = _attention_decode_window(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None],
-                *(rope[kind] or (None, None)), PagePool(win_k, win_v), j,
+                *(rope[kind] or (None, None)), PagePool(win), j,
                 window_table, lengths)
             out = out[:, 0]
         else:
             lp = _row(params["attn"], j)
-            out, (pool_k, pool_v) = _attention_decode_paged(
+            out, (pool,) = _attention_decode_paged(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"])[:, None],
                 *(rope[kind] or (None, None)),
-                PagePool(pool_k, pool_v), j, page_table, lengths)
+                PagePool(pool), j, page_table, lengths)
             out = out[:, 0]
         h = h + cfg.residual_multiplier * out
         g, c = _ffn(cfg, params["moe"][layer], h, active)
@@ -629,8 +629,8 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool_k, pool_v,
             counts.append(c)
     with jax.named_scope("unembed_sample"):
         logits = unembed_hybrid(cfg, params, h)
-    out = (logits, pool_k, pool_v, state, expert_tokens + jnp.stack(counts))
-    return out if window is None else out + ((win_k, win_v),)
+    out = (logits, pool, state, expert_tokens + jnp.stack(counts))
+    return out if window is None else out + (win,)
 
 
 def init_params_hybrid(cfg: ModelConfig, key: jax.Array,

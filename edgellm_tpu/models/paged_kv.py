@@ -7,15 +7,17 @@ recompiles per shape — ROADMAP item 1's gap between "a compiled generate()"
 and "a service". This module replaces the monolith with the paged layout of
 *Ragged Paged Attention* (PAPERS.md): one shared pool of fixed-size pages,
 
-    k, v: (L, num_pages, page_size, KV * hd)
+    kv: (L, num_pages, page_size, 2 * KV * hd)
 
-and a small host-side allocator that maps each stream (a *slot*) to an
-ordered list of pages. Logical position ``p`` of slot ``i`` lives at
-``page_table[i, p // page_size]`` offset ``p % page_size``. The page table
-and per-slot lengths ride through the jitted step as traced int32 arrays, so
-ONE executable serves every admit/evict/fill configuration of a given pool
-geometry — the continuous-batching scheduler (``serve/batching.py``) admits
-and evicts mid-flight without a single retrace.
+(a position's K lanes, then its V lanes, in ONE row of one leaf: a page is
+one contiguous piece of HBM and the decode read fetches it with one DMA,
+PERF.md §6 "PR 45") and a small host-side allocator that maps each stream (a
+*slot*) to an ordered list of pages. Logical position ``p`` of slot ``i``
+lives at ``page_table[i, p // page_size]`` offset ``p % page_size``. The page
+table and per-slot lengths ride through the jitted step as traced int32
+arrays, so ONE executable serves every admit/evict/fill configuration of a
+given pool geometry — the continuous-batching scheduler
+(``serve/batching.py``) admits and evicts mid-flight without a single retrace.
 
 Conventions that keep the paged step bit-identical to the contiguous one:
 
@@ -41,7 +43,8 @@ Stored shape and addressing — two halves of ONE mechanism (PERF.md §6
 "PR 29"; tests/test_chip_compile.py holds the chip's compiler to it):
 
 - a token's K (or V) for ALL its KV heads is one minor vector of
-  ``KV * lanes`` (lanes = hd, or the packed code width of a quantized tier),
+  ``KV * lanes`` (lanes = hd, or the packed code width of a quantized tier;
+  on the fp tier the V vector follows the K vector in the same row),
   so a page of 16 bf16 rows is whole (8,128)(2,1) tiles, nothing is padded
   and the chip keeps the array row-major. With a ``(KV, hd)`` tail of
   (2, 64) the runtime stored the pool PAGES-minor, where nothing can scatter
@@ -70,9 +73,9 @@ A latent row (PERF.md §6 "PR 32"): a stack of latent-attention layers
 | k_rope]`` zero-padded to whole lane tiles (``cfg.kv_row_lanes``: 320 ->
 384), in a pool of ONE leaf (:class:`LatentPool`) in the same page group, by
 the same table and allocator as full layers' K/V: the surgery below runs over
-the one leaf, :func:`write_rows` and :func:`_gather_pages` address it as K or
-V, and :func:`attend_latent` / :func:`attend_latent_pages` ("PR 35") stand
-beside :func:`attend_rows` / :func:`attend_pages`.
+the one leaf, :func:`write_rows` and :func:`_gather_pages` address it as they
+do a K/V pool's, and :func:`attend_latent` / :func:`attend_latent_pages` ("PR
+35") stand beside :func:`attend_rows` / :func:`attend_pages`.
 
 Neither half helps alone. The lane-dense row with ``.at[:, dest]`` still
 costs two whole-pool copies a leaf (the compiler moves L under the row
@@ -344,28 +347,51 @@ class KVTierMismatchError(ValueError):
 
 
 class PagePool(NamedTuple):
-    """Device-side page pool: post-rotary K/V at ``num_kv_heads`` width.
+    """Device-side page pool: post-rotary K/V at ``num_kv_heads`` width, ONE
+    leaf.
 
-    k, v: (..., num_pages, page_size, KV * hd): a token's row for all its KV
-    heads is ONE minor vector (head j in lanes [j*hd, (j+1)*hd)), so a page
-    is whole lane tiles and the chip stores the array row-major (module
-    docstring: with a (KV, hd) tail it lived pages-minor and was copied
-    around every write and read). The leading axes are (L,) on one chip and
-    (n_stages, stage_size) in the split runtime; the LAST of them is the
-    layer axis that every flat index folds in (:func:`write_rows`,
-    :func:`read_span`, :func:`adopt_at`), never slices. Page 0 is the
-    reserved trash page (see module docstring)."""
+    kv: (..., num_pages, page_size, 2 * KV * hd): a position's row is its K
+    for all its KV heads as one minor vector (lanes [0, W), W = KV * hd, head
+    j in lanes [j*hd, (j+1)*hd)), then its V the same way (lanes [W, 2W)).
+    So a page is ``2 * page_size * W`` contiguous items of whole lane tiles,
+    which the chip stores row-major (module docstring: with a (KV, hd) tail
+    it lived pages-minor and was copied around every write and read), a
+    decode step writes one row a slot a layer, and the page walk fetches a
+    page, keys and values, with one DMA (as two leaves it was two, and the
+    walk is bound by how fast they are issued: PERF.md §6 "PR 45"). The
+    leading axes are (L,) on one chip and (n_stages, stage_size) in the
+    split runtime; the LAST of them is the layer axis that every flat index
+    folds in (:func:`write_rows`, :func:`read_span`, :func:`adopt_at`),
+    never slices. Page 0 is the reserved trash page (see module docstring).
+    Callers never see the join: adopts, gathers, checkpoints and migration
+    hand over K and V (:func:`join_kv`, :func:`split_kv`)."""
 
-    k: jnp.ndarray
-    v: jnp.ndarray
+    kv: jnp.ndarray
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[-3]
+        return self.kv.shape[-3]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[-2]
+        return self.kv.shape[-2]
+
+    @property
+    def k_lanes(self) -> int:
+        """W = KV * hd: where a row's V lanes start."""
+        return self.kv.shape[-1] // 2
+
+
+def join_kv(k, v):
+    """K rows (..., W) and V rows (..., W) as a :class:`PagePool` stores
+    them: (..., 2 W), K lanes then V lanes."""
+    return jnp.concatenate([k, v], axis=-1)
+
+
+def split_kv(rows):
+    """:func:`join_kv` undone: (..., 2 W) -> (K (..., W), V (..., W))."""
+    w = rows.shape[-1] // 2
+    return rows[..., :w], rows[..., w:]
 
 
 class LatentPool(NamedTuple):
@@ -398,11 +424,11 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                          f"got {num_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (cfg.kv_layers if layers is None else layers, num_pages,
-             page_size, cfg.kv_row_lanes)
+    rows = (cfg.kv_layers if layers is None else layers, num_pages,
+            page_size)
     if cfg.latent_layers:
-        return LatentPool(jnp.zeros(shape, dtype))
-    return PagePool(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        return LatentPool(jnp.zeros(rows + (cfg.kv_row_lanes,), dtype))
+    return PagePool(jnp.zeros(rows + (2 * cfg.kv_row_lanes,), dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -517,6 +543,12 @@ def pool_tier(pool) -> str:
         return "fp"
     return next(c.name for c in KV_PAGE_CODECS.values()
                 if c.quantized and pool.k.dtype == c.code_dtype)
+
+
+def _k_lanes(pool) -> int:
+    """Lanes of a K row (as many of a V row) of a K/V pool at its tier; of a
+    latent pool's whole row."""
+    return pool.k_lanes if isinstance(pool, PagePool) else pool[0].shape[-1]
 
 
 def kv_page_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
@@ -664,17 +696,18 @@ def page_head(dest, page_size: int) -> int:
 def adopt_at(pool, k_seq, v_seq, dest, lead: int, head: Optional[int] = None):
     """Put contiguous (..., L, S, KV, hd) fp K/V rows at the flat token
     indices ``dest`` (S,) of every layer: stored as they are on the fp tier
-    (the KV heads merged into the row's one minor vector, a free reshape),
-    quantized on append on the others ('writes quantize on append', the
-    at-rest contract). Row ``dest[i]`` of layer ``l`` is row ``l*P*ps +
-    dest[i]`` of the leaf viewed (..., L*P*ps, KV*lanes); with two leading
-    axes the sharded stage axis stays sliced and only ``stage_size`` folds
-    into the index. S and ``head`` (:func:`_set_rows`) are static per call
-    (one executable per adopted length)."""
+    (the KV heads merged into one minor vector, K's then V's joined into the
+    row: one scatter), quantized on append on the others ('writes quantize
+    on append', the at-rest contract). Row ``dest[i]`` of layer ``l`` is row
+    ``l*P*ps + dest[i]`` of the leaf viewed (..., L*P*ps, KV*lanes); with
+    two leading axes the sharded stage axis stays sliced and only
+    ``stage_size`` folds into the index. S and ``head`` (:func:`_set_rows`)
+    are static per call (one executable per adopted length)."""
     tier = pool_tier(pool)
     if tier == "fp":
-        return _set_rows(pool, (_merge_heads(k_seq), _merge_heads(v_seq)),
-                         dest, lead, head)
+        return _set_rows(
+            pool, (join_kv(_merge_heads(k_seq), _merge_heads(v_seq)),),
+            dest, lead, head)
     qk, sk = quantize_kv_rows(k_seq, tier)
     qv, sv = quantize_kv_rows(v_seq, tier)
     return _set_rows(pool, (_merge_heads(qk), _merge_heads(qv), sk, sv),
@@ -734,9 +767,11 @@ def _gather_impl(pool, idx, lead: int = 1, *, kv: int):
     the others (the suffix-prefill compute path, which needs fp rows; lossy
     by exactly the tier's quantization error). ``kv`` is what an fp pool's
     merged row cannot say."""
-    k, v, *scales = _get_rows(pool, idx, lead)
-    k, v = _split_heads(k, kv), _split_heads(v, kv)
     tier = pool_tier(pool)
+    rows = _get_rows(pool, idx, lead)
+    # the fp tier: one gather of the joined rows, then K | V on lanes
+    k, v, *scales = split_kv(rows[0]) if tier == "fp" else rows
+    k, v = _split_heads(k, kv), _split_heads(v, kv)
     if tier == "fp":
         return k, v
     return (dequantize_kv_rows(k, scales[0], tier),
@@ -1702,7 +1737,10 @@ class PagedKVCache:
         # (L, P, ps, KV, lanes) — what every earlier checkpoint holds; the
         # stored row merges the last two axes, a free reshape either way
         kv = self.cfg.num_kv_heads
-        k, v = (_split_heads(np.asarray(a), kv) for a in self.pool[:2])
+        # (the fp leaf is split on the host: no second pool on the device)
+        k, v = (_split_heads(a, kv) for a in (
+            split_kv(np.asarray(self.pool.kv)) if self.kv_codec == "fp"
+            else map(np.asarray, self.pool[:2])))
         if self.kv_codec == "fp":
             # pre-quantization key set, unchanged: old checkpoints and fp
             # pools stay mutually loadable
@@ -1742,14 +1780,19 @@ class PagedKVCache:
         names = (("k", "v") if self.kv_codec == "fp"
                  else ("k_codes", "v_codes", "k_scale", "v_scale"))
         kv = self.cfg.num_kv_heads
-        want = self.pool.k.shape[:-1] + (kv, self.pool.k.shape[-1] // kv)
+        lanes = _k_lanes(self.pool)
+        want = self.pool[0].shape[:-1] + (kv, lanes // kv)
         if state[names[0]].shape != want:
             raise ValueError(
                 f"pool shape mismatch: checkpoint {state[names[0]].shape} "
                 f"vs cache {want}")
-        self.pool = type(self.pool)(*(
-            jnp.asarray(state[n]).reshape(a.shape)
-            for n, a in zip(names, self.pool)))
+        if self.kv_codec == "fp":    # joined on the host, uploaded once
+            self.pool = PagePool(jnp.asarray(np.concatenate(
+                [_merge_heads(np.asarray(state[n])) for n in names], -1)))
+        else:
+            self.pool = type(self.pool)(*(
+                jnp.asarray(state[n]).reshape(a.shape)
+                for n, a in zip(names, self.pool)))
         if self.state is not None:
             for leaf, a in self.state.items():
                 if state["state_" + leaf].shape != a.shape:
@@ -1810,13 +1853,13 @@ class PagedKVCache:
             assert wp * self.page_size >= \
                 self.cfg.sliding_window + self.page_size - 1, \
                 "a ring must hold a whole window wherever it starts in a page"
-            assert self.window_pool.k.shape[:3] == (
+            assert self.window_pool.kv.shape[:3] == (
                 self.cfg.window_layers, self.max_slots * wp + 1,
                 self.page_size), \
-                f"window pool {self.window_pool.k.shape}: a ring of {wp} " \
+                f"window pool {self.window_pool.kv.shape}: a ring of {wp} " \
                 f"pages a slot and the trash page, however long streams grow"
             assert self.pool is None or \
-                self.pool.k.shape[0] == self.cfg.kv_layers, \
+                self.pool[0].shape[0] == self.cfg.kv_layers, \
                 "the page pool holds the full attention layers only"
             for s in range(self.max_slots):
                 want = self._ring_of(s) if self.active[s] else 0
@@ -1846,7 +1889,7 @@ class PagedKVCache:
             assert all(a.dtype == jnp.float32 for a in self.state.values()), \
                 "recurrent state must stay float32"
             if self.pool is not None:
-                assert self.pool.k.shape[0] == self.cfg.kv_layers, \
+                assert self.pool[0].shape[0] == self.cfg.kv_layers, \
                     "the page pool holds the attention layers only"
         assert 0 not in self._free, "trash page 0 on the free list"
         assert self._owner[0] == FREE, "trash page 0 owned by a slot"
@@ -1950,9 +1993,10 @@ def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
     (L, P, ps, KV*lanes) WITH their layer axis, ``layer`` a traced or static
     index, k, v (B, 1, KV, hd) post-rotary, slot i's row at position
     ``lengths[i]`` of its page list. The fp tier stores the cast row (its KV
-    heads merged into the one minor vector); a quantized tier quantizes ON
-    APPEND and scatters codes + the row's own scales — neighbouring rows are
-    untouched, which is why scales are per row and not per page.
+    heads merged into one minor vector, K's then V's: one row, ONE scatter a
+    layer); a quantized tier quantizes ON APPEND and scatters codes + the
+    row's own scales — neighbouring rows are untouched, which is why scales
+    are per row and not per page.
 
     One scatter a leaf at the flat index ``layer*P*ps + page*ps + row`` over
     the leaf viewed (L*P*ps, KV*lanes): the carried pool is updated where it
@@ -1966,8 +2010,9 @@ def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
     position p lives in entry ``(p // ps) % entries``: the row written
     overwrites one that has left the window."""
     tier = pool_tier(pool)
-    if tier == "fp":            # a row a leaf: K and V, or the one latent row
-        stored = tuple(r[:, 0] for r in (k, v) if r is not None)
+    if tier == "fp":     # ONE row: K lanes then V lanes, or the latent row
+        stored = (k[:, 0] if v is None else join_kv(
+            _merge_heads(k[:, 0]), _merge_heads(v[:, 0])),)
     else:
         qk, sk = quantize_kv_rows(k[:, 0], tier)  # (B,KV,hdc), (B,KV)
         qv, sv = quantize_kv_rows(v[:, 0], tier)
@@ -2014,16 +2059,16 @@ def _gather_pages(leaf, layer, page_table):
 def read_span(pool, layer, page_table, dtype):
     """Each slot's whole span of K and V out of layer ``layer`` of the pool,
     in page table order, AS STORED: ((B, span, KV*hd), same) in ``dtype``,
-    head j in lanes [j*hd, (j+1)*hd). The fp tier gathers pages
-    (:func:`_gather_pages`, pages at ``layer*P + page_table``); a quantized
+    head j in lanes [j*hd, (j+1)*hd). The fp tier gathers pages once, K and
+    V lanes together (:func:`_gather_pages`, pages at ``layer*P +
+    page_table``), and splits the copy on lanes; a quantized
     tier gathers codes and scales a page a slice, THEN dequantizes —
     elementwise per row, so exactly equal to dequantizing the whole pool
     first (the numerical-equivalence contract the lint layer executes).
     Trash-page rows come along under the caller's length mask."""
     tier = pool_tier(pool)
     if tier == "fp":
-        return (_gather_pages(pool.k, layer, page_table),
-                _gather_pages(pool.v, layer, page_table))
+        return split_kv(_gather_pages(pool.kv, layer, page_table))
     kv = pool.k_scale.shape[-1]
     return tuple(
         _merge_heads(dequantize_kv_rows(
@@ -2123,8 +2168,9 @@ def decode_read_path(pool) -> str:
     """Which read a decode step's attention layer is built with, read off
     what it is handed: :data:`PAGE_WALK` (:func:`attend_pages`; a
     :class:`LatentPool`: :func:`attend_latent_pages`) for an fp pool on a
-    TPU, where a page is whole tiles (rows of whole lane tiles, a page of
-    whole sublane tiles: what the kernel's page DMAs need), whether its pages
+    TPU, where a page is whole tiles (a row's K lanes and its V lanes each
+    whole lane tiles, a page of whole sublane tiles: what the kernel's page
+    DMAs and lane slices need), whether its pages
     hold a prefix or a window layer's ring (the walk masks a ring's rows by
     the position each holds); :data:`PAGE_GATHER` (the oracle) for a
     quantized tier, part tiles and every other backend. ``pool`` may be
@@ -2133,9 +2179,11 @@ def decode_read_path(pool) -> str:
     or 129 pages: "PR 40")."""
     if not isinstance(pool, (PagePool, LatentPool)) or not _on_tpu():
         return PAGE_GATHER
-    leaf = pool[0]                 # K, or a latent pool's one leaf
-    sublanes = 32 // jnp.dtype(leaf.dtype).itemsize
-    whole = leaf.shape[-1] % LANE_TILE == 0 and pool.page_size % sublanes == 0
+    # what the kernel slices on lanes (a row's K part, its V part as wide,
+    # or the whole latent row) and on sublanes (a page)
+    sublanes = 32 // jnp.dtype(pool[0].dtype).itemsize
+    whole = (_k_lanes(pool) % LANE_TILE == 0
+             and pool.page_size % sublanes == 0)
     return PAGE_WALK if whole else PAGE_GATHER
 
 
@@ -2143,26 +2191,25 @@ def attend_pages(q, pool: PagePool, layer, page_table, lengths,
                  window: int = 0):
     """:func:`read_span` + :func:`attend_rows` without the span: q (B, 1, H,
     hd) against each slot's LIVE pages of layer ``layer`` of an fp pool (L,
-    P, ps, KV*hd), read out of the pool where they lie by ONE kernel
-    (``flash_attention.paged_decode_walk``: a DMA a page, ``ceil(lengths[i] /
-    ps)`` pages a slot, one for an idle slot's trash page). Both leaves go in
-    whole, viewed (L*P, ps, KV*hd) — a bitcast of the carried pool — with the
-    page ids ``layer*P + page_table``; the same query in its groups' lanes,
-    float32 scores and softmax, and the same rows attended as
-    :func:`attend_rows`, in a blockwise order of the float32 sums. Returns
-    (B, 1, H, hd) in q's dtype.
+    P, ps, 2*KV*hd), read out of the pool where they lie by ONE kernel
+    (``flash_attention.paged_decode_walk``: a DMA a page, keys and values
+    together, ``ceil(lengths[i] / ps)`` pages a slot, one for an idle slot's
+    trash page). The leaf goes in whole, viewed (L*P, ps, 2*KV*hd) — a
+    bitcast of the carried pool — with the page ids ``layer*P +
+    page_table``; the same query in its groups' lanes, float32 scores and
+    softmax, and the same rows attended as :func:`attend_rows`, in a
+    blockwise order of the float32 sums. Returns (B, 1, H, hd) in q's dtype.
 
     ``window`` (static, > 0): ``page_table`` is a window layer's ring, of
     which the kernel fetches the entries the stream has reached (all of them
     once the ring has turned) and attends the rows :func:`window_valid` says
     of :func:`ring_positions`, by two scalars a slot."""
     hd = q.shape[-1]
-    own, qz = _group_lanes(q, pool.k.shape[-1] // hd)
+    own, qz = _group_lanes(q, pool.k_lanes // hd)
     ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
     out = flash_attention.paged_decode_walk(
-        qz, _pages(pool.k, 1), _pages(pool.v, 1), ids,
-        lengths.astype(jnp.int32), scale=float(1.0 / np.sqrt(hd)),
-        window=window)
+        qz, _pages(pool.kv, 1), ids, lengths.astype(jnp.int32),
+        scale=float(1.0 / np.sqrt(hd)), window=window)
     return _own_lanes(out, own)
 
 
@@ -2227,9 +2274,9 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
         raise ValueError(f"paged decode is q_len=1 only, got q_len={s1}")
     tier = pool_tier(pool)
     lanes = KV_PAGE_CODECS[tier].code_lanes(hd)
-    kv, rest = divmod(pool.k.shape[-1], lanes)
+    kv, rest = divmod(_k_lanes(pool), lanes)
     if rest or (tier != "fp" and kv != pool.k_scale.shape[-1]):
-        raise ValueError(f"row width {pool.k.shape[-1]} does not match q "
+        raise ValueError(f"row width {_k_lanes(pool)} does not match q "
                          f"head_dim {hd} for tier {tier!r}")
     if h % kv:
         raise ValueError(f"ragged GQA: H={h}, KV={kv}")
@@ -2334,7 +2381,7 @@ def block_decode_paged(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,
 
 
 @graph_contract("paged.decode_step", collectives={},
-                donate=lambda ctx: ctx.get("donate_min", 2))
+                donate=lambda ctx: ctx.get("donate_min", 1))
 @graph_contract("paged.decode_step_quant", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 4))
 def paged_decode_step(cfg: ModelConfig, params: dict, pool,
@@ -2354,8 +2401,9 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
     attention mask all index by each slot's own ``lengths[i]`` — the ragged
     generalization of ``decode_step``'s single ``cache.length``; per-slot
     math is bit-identical to the contiguous path (see module docstring).
-    The layer scan CARRIES the pool pytree (leaves in the order k, v(,
-    k_scale, v_scale)) beside the hidden state and scans the layer index:
+    The layer scan CARRIES the pool pytree (the fp tier's one leaf kv; a
+    quantized tier's k, v, k_scale, v_scale) beside the hidden state and
+    scans the layer index:
     the donated pool is updated where it lies, layer after layer, and is
     never sliced into ``xs`` nor stacked back out of ``ys`` (which cost a
     relayout of every layer's slice in and out, PERF.md §6 "PR 29").
@@ -2374,7 +2422,7 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
         return block_decode_paged(cfg, lp, h, cos_b, sin_b, pool, layer,
                                   page_table, lengths), None
 
-    layers = jnp.arange(pool.k.shape[0], dtype=jnp.int32)
+    layers = jnp.arange(pool[0].shape[0], dtype=jnp.int32)
     (hidden, pool), _ = jax.lax.scan(body, (hidden, pool),
                                      (params["layers"], layers))
     with jax.named_scope("unembed_sample"):
@@ -2392,23 +2440,23 @@ def attend_latent_pages(q_rows, pool: LatentPool, layer, page_table, lengths,
     """:func:`_gather_pages` + :func:`attend_latent` without the span: q_rows
     (B, H, lanes) against each slot's LIVE pages of layer ``layer`` of the
     one-leaf pool, read where they lie by the kernel :func:`attend_pages`
-    hands two leaves (``flash_attention.paged_decode_walk``, ``v_pages``
-    None: a fetched row is key and value both, a DMA a page). The leaf goes
+    hands a K/V pool's leaf (``flash_attention.paged_decode_walk``; a row as
+    wide as the query is key and value both, a DMA a page). The leaf goes
     in whole, viewed (L*P, ps, lanes) — a bitcast of the carried pool — with
     the page ids ``layer*P + page_table``; the same rows attended, float32
     scores and softmax, probabilities in q's dtype before the weighted sum,
     in a blockwise order of the float32 sums. Returns (B, H, lanes).
 
-    A block holds TWICE the pages of a two-leaf walk's: as many page DMAs in
-    flight (64 at 16-row pages) and the same VMEM in two buffers as there in
-    four. On a v5e at the mistral4 cell's shape 256 / 512 / 1024 / 1536 /
-    2048 rows a block take 1.86 / 1.40 / 1.25 / 1.21 / 1.23 ms a layer (an
-    all-idle batch 0.076 at 512, 0.101 at 1024, 0.157 at 2048; PERF.md §6
-    "PR 35")."""
+    A block holds TWICE the pages of a K/V walk's, whose row of the same
+    lanes is twice as wide: the same VMEM in the two buffers, 64 page DMAs in
+    flight at 16-row pages. On a v5e at the mistral4 cell's shape 256 / 512 /
+    1024 / 1536 / 2048 rows a block take 1.86 / 1.40 / 1.25 / 1.21 / 1.23
+    ms a layer (an all-idle batch 0.076 at 512, 0.101 at 1024, 0.157 at
+    2048; PERF.md §6 "PR 35")."""
     pages = _pages(pool.rows, 1)
     ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
     return flash_attention.paged_decode_walk(
-        q_rows, pages, None, ids, lengths.astype(jnp.int32),
+        q_rows, pages, ids, lengths.astype(jnp.int32),
         scale=float(1.0 / np.sqrt(head_dim)),
         pages_per_block=2 * flash_attention.paged_walk_pages_per_block(
             pages.shape[1], pages.shape[2], pages.dtype.itemsize))

@@ -1394,9 +1394,9 @@ class SplitRuntime:
     # traced page table, trash page 0), so streams with different prompt
     # lengths and fill levels share ONE compiled ragged step per pool
     # geometry while every cut still moves its quantized (B, 1, D) boundary
-    # activation.  Pool layout: (n_stages, sz, num_pages, page_size, KV * hd)
-    # sharded P("stage") — each stage owns its own layers' pages, pages never
-    # cross a cut.
+    # activation.  Pool layout: (n_stages, sz, num_pages, page_size, 2 * KV *
+    # hd) (paged_kv.PagePool's K-then-V row) sharded P("stage") — each stage
+    # owns its own layers' pages, pages never cross a cut.
 
     def init_paged_pool(self, num_pages: int, page_size: int,
                         dtype=jnp.float32, kv_codec: str = "fp"):
@@ -1417,8 +1417,8 @@ class SplitRuntime:
         rows = (self.split.n_stages, self.stage_size, num_pages, page_size)
         kv = cfg.num_kv_heads
         if not codec.quantized:
-            shape = rows + (kv * cfg.head_dim,)
-            return paged_kv.PagePool(zeros(shape, dtype), zeros(shape, dtype))
+            return paged_kv.PagePool(
+                zeros(rows + (2 * kv * cfg.head_dim,), dtype))
         codes = rows + (kv * codec.code_lanes(cfg.head_dim),)
         return paged_kv.QuantPagePool(
             zeros(codes, codec.code_dtype), zeros(codes, codec.code_dtype),
@@ -1651,7 +1651,7 @@ class SplitRuntime:
         collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
         wire_dtypes=lambda ctx: ctx["wire_dtypes"],
         wire_bytes=lambda ctx: ctx["wire_bytes"],
-        donate=lambda ctx: ctx.get("donate_min", 2))
+        donate=lambda ctx: ctx.get("donate_min", 1))
     @graph_contract(
         "split.decode_step_paged.pipelined",
         # the ragged twin under the µ-batch schedule: M payloads of
@@ -1659,7 +1659,7 @@ class SplitRuntime:
         collectives=lambda ctx: {"ppermute": ctx["hop_eqns"], "psum": 1},
         wire_dtypes=lambda ctx: ctx["wire_dtypes"],
         wire_bytes=lambda ctx: ctx["wire_bytes"],
-        donate=lambda ctx: ctx.get("donate_min", 2))
+        donate=lambda ctx: ctx.get("donate_min", 1))
     def decode_step_paged(self, placed_params: dict, pool,
                           page_table: jnp.ndarray, lengths: jnp.ndarray,
                           token_ids: jnp.ndarray) -> tuple:

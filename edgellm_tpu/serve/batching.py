@@ -65,8 +65,8 @@ module schedules many streams through ONE jitted decode step built on
   admission adopts a prompt's tail into the rings, eviction gathers them with
   the full layers' rows, and what cannot carry them refuses by name. A
   ``mistral4`` stack rides ``_batched_hybrid_step_jit`` too: its pool is ONE
-  leaf of latent rows (``paged_kv.LatentPool``), handed over where the K
-  pages go, with no V pages and no state store; admission adopts the
+  leaf of latent rows (``paged_kv.LatentPool``), handed over where a K/V
+  pool's one leaf goes, with no state store; admission adopts the
   prefill's rows (``adopt_latent``) and eviction gathers them as stored.
 
 ``ServeFront`` integration lives in ``serve/frontend.py`` (``batcher=``):
@@ -252,9 +252,9 @@ def _batched_step_jit(cfg: ModelConfig, params: dict, pool, page_table,
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "compute_dtype"),
-                   donate_argnums=(2, 3, 4, 5))
-def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
-                             state, expert_tokens, page_table, lengths,
+                   donate_argnums=(2, 3, 4))
+def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool, state,
+                             expert_tokens, page_table, lengths,
                              token_ids, key_data, steps, temps,
                              compute_dtype):
     """The ragged step of a stack with recurrent state
@@ -262,18 +262,18 @@ def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool_k, pool_v,
     per-slot state store of the recurrent layers (every leaf its kinds keep)
     and the per-expert assignment counter are all donated and come back
     updated. A SEPARATE jit: the one-block families keep the executable
-    above. A stack of latent layers takes it with its pool's one leaf as
-    ``pool_k`` and None for ``pool_v`` and ``state``, which come back None."""
+    above. ``pool`` is the page pool's ONE leaf, K-then-V rows or a stack of
+    latent layers' rows; ``state`` None (and back) where the stack keeps
+    none."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
-    logits, pool_k, pool_v, state, expert_tokens = (
-        paged_decode_step_hybrid(cfg, params, pool_k, pool_v, state,
-                                 expert_tokens, page_table, lengths,
-                                 token_ids))
+    logits, pool, state, expert_tokens = paged_decode_step_hybrid(
+        cfg, params, pool, state, expert_tokens, page_table, lengths,
+        token_ids)
     return (_batched_sample(logits, key_data, steps, temps),
-            pool_k, pool_v, state, expert_tokens)
+            pool, state, expert_tokens)
 
 
 @functools.partial(jax.jit,
@@ -292,12 +292,11 @@ def _batched_window_step_jit(cfg: ModelConfig, params: dict, pool, window_pool,
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
-    logits, k, v, _, expert_tokens, (wk, wv) = paged_decode_step_hybrid(
-        cfg, params, pool.k, pool.v, None, expert_tokens, page_table,
-        lengths, token_ids, window=(window_pool.k, window_pool.v,
-                                    window_table))
+    logits, kv, _, expert_tokens, win = paged_decode_step_hybrid(
+        cfg, params, pool.kv, None, expert_tokens, page_table, lengths,
+        token_ids, window=(window_pool.kv, window_table))
     return (_batched_sample(logits, key_data, steps, temps),
-            type(pool)(k, v), type(window_pool)(wk, wv), expert_tokens)
+            type(pool)(kv), type(window_pool)(win), expert_tokens)
 
 
 def batched_step_cache_size() -> int:
@@ -449,7 +448,8 @@ class ContinuousBatcher:
                       "step_wall_hist": _new_step_wall_hist(),
                       **dict.fromkeys(_CLOCKS, 0.0)}
         # the reads the step's full and window layers are built with (by pool)
-        self.decode_read, self.window_read = self._read_paths()
+        (self.decode_read, self.window_read,
+         self.attend_fetches_per_page) = self._read_paths()
         # the scheduler thread's own, lock-free between folds: clocks and
         # counts; the clock at each token 0; the last launched step's return
         self._acc: dict[str, float] = defaultdict(int)
@@ -1145,20 +1145,17 @@ class ContinuousBatcher:
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
             elif self.cfg.is_hybrid:
-                # K and V pages and the state store, or a latent stack's one
-                # leaf with no V pages and no state
-                pool = self.pool.pool
-                k, v = pool if len(pool) == 2 else (pool[0], None)
-                toks, k, v, self.pool.state, self._expert_tokens = (
+                # the pool's one leaf (K-then-V rows, or a latent stack's
+                # rows) and the state store, where the stack keeps one
+                kind, (leaf,) = type(self.pool.pool), self.pool.pool
+                toks, leaf, self.pool.state, self._expert_tokens = (
                     _batched_hybrid_step_jit(
-                        self.cfg, self.params, k, v, self.pool.state,
+                        self.cfg, self.params, leaf, self.pool.state,
                         self._expert_tokens, page_table, lengths,
                         token_ids, jnp.asarray(key_data),
                         jnp.asarray(steps), jnp.asarray(temps),
                         self.bcfg.compute_dtype))
-                self.pool.pool = (type(pool)(k) if v is None
-                                  else type(pool)(k, v))
-                del pool
+                self.pool.pool = kind(leaf)
             else:
                 toks, self.pool.pool = _batched_step_jit(
                     self.cfg, self.params, self.pool.pool, page_table,
@@ -1488,6 +1485,7 @@ class ContinuousBatcher:
             # the table entries a gather would have read; the same of a
             # window layer's ring (the gather, 0 and 0 where no layer slides)
             "decode_read": self.decode_read,
+            "attend_fetches_per_page": self.attend_fetches_per_page,
             "attend_pages_walked": stats["attend_pages_walked"],
             "attend_pages_spanned": stats["attend_pages_spanned"],
             "window_read": self.window_read,
@@ -1499,15 +1497,19 @@ class ContinuousBatcher:
         }
 
     def _read_paths(self) -> tuple:
-        """(``decode_read``, ``window_read``): what ``decode_read_path`` says
-        of the pool the full-attention layers read and of the window
-        layers' pool of rings (no such pool: the gather, which no step then
-        takes). Down here: a line added above would move the prefill
-        kernels' call sites, as below."""
+        """(``decode_read``, ``window_read``, ``attend_fetches_per_page``):
+        what ``decode_read_path`` says of the pool the full-attention layers
+        read and of the window layers' pool of rings (no such pool: the
+        gather, which no step then takes), and the DMAs the walk starts for
+        a page, one a leaf of the pool it walks (0: the read is the gather).
+        Down here: a line added above would move the prefill kernels' call
+        sites, as below."""
         full = self._split_pool if self.rt is not None else self.pool.pool
         rings = self.pool.window_pool
-        return (decode_read_path(full),
-                decode_read_path(rings) if rings is not None else PAGE_GATHER)
+        read = decode_read_path(full)
+        return (read,
+                decode_read_path(rings) if rings is not None else PAGE_GATHER,
+                len(full) if read == PAGE_WALK else 0)
 
     def _hybrid_report(self, stats: dict) -> dict:
         """What a stack with recurrent state and routed experts adds to

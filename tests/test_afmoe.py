@@ -265,13 +265,13 @@ class LogitTap:
         def step(params, pool, wpool, cnt, table, wtable, lengths, toks,
                  key_data, steps, temps):
             with jax.default_matmul_precision("highest"):
-                logits, k, v, _, cnt, (wk, wv) = (
+                logits, kv, _, cnt, win = (
                     hybrid.paged_decode_step_hybrid(
-                        cfg, params, pool.k, pool.v, None, cnt, table,
-                        lengths, toks, window=(wpool.k, wpool.v, wtable)))
+                        cfg, params, pool.kv, None, cnt, table,
+                        lengths, toks, window=(wpool.kv, wtable)))
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps),
-                    type(pool)(k, v), type(wpool)(wk, wv), cnt)
+                    type(pool)(kv), type(wpool)(win), cnt)
 
         def tapped(cfg_, params, pool, wpool, cnt, table, wtable, lengths,
                    toks, key_data, steps, temps, compute_dtype):

@@ -126,16 +126,18 @@ def test_adopt_gather_roundtrip():
     assert int(back["length"]) == n
     np.testing.assert_array_equal(back["k"], k)
     np.testing.assert_array_equal(back["v"], v)
-    # the stored leaf is (L, P, ps, KV*hd); a row of layer l landed in layer
-    # l at its page and offset and NOWHERE else: the (L, P, ps, KV, hd)
-    # oracle, written with the indices the flat (layer, page, row) index
-    # replaces
-    assert pool.pool.k.shape == (CFG.num_layers, 9, 4,
-                                 CFG.num_kv_heads * CFG.head_dim)
+    # the stored leaf is (L, P, ps, 2*KV*hd), a row its K lanes then its V
+    # lanes; a row of layer l landed in layer l at its page and offset and
+    # NOWHERE else: the (L, P, ps, KV, hd) oracle, written with the indices
+    # the flat (layer, page, row) index replaces
+    assert len(pool.pool) == 1
+    assert pool.pool.kv.shape == (CFG.num_layers, 9, 4,
+                                  2 * CFG.num_kv_heads * CFG.head_dim)
     assert (pool.pool.num_pages, pool.pool.page_size) == (9, 4)
+    assert pool.pool.k_lanes == CFG.num_kv_heads * CFG.head_dim
     pos = np.arange(n)
     pages, offs = pool.page_table[slot, pos // 4], pos % 4
-    for leaf, rows in ((pool.pool.k, k), (pool.pool.v, v)):
+    for leaf, rows in zip(paged_kv.split_kv(pool.pool.kv), (k, v)):
         want = np.zeros((CFG.num_layers, 9, 4, CFG.num_kv_heads,
                          CFG.head_dim), np.float32)
         want[:, pages, offs] = rows
@@ -163,7 +165,9 @@ def test_parent_shaped_state_dict_loads():
     twin = PagedKVCache(CFG, **kw)
     twin.load_state_dict(state)
     twin.check_invariants()
-    assert twin.pool.k.shape == src.pool.k.shape
+    assert twin.pool.kv.shape == src.pool.kv.shape
+    np.testing.assert_array_equal(np.asarray(twin.pool.kv),
+                                  np.asarray(src.pool.kv))
     back = twin.gather_slot(slot)
     np.testing.assert_array_equal(back["k"], k)
     np.testing.assert_array_equal(back["v"], -k)
@@ -404,8 +408,7 @@ def test_trash_page_stays_finite(params):
     bat = ContinuousBatcher(CFG, params, BCFG)
     bat.submit(_prompt(5), 6)     # slots 1-3 inactive: they write page 0
     bat.run()
-    assert np.isfinite(np.asarray(bat.pool.pool.k[:, 0])).all()
-    assert np.isfinite(np.asarray(bat.pool.pool.v[:, 0])).all()
+    assert np.isfinite(np.asarray(bat.pool.pool.kv[:, 0])).all()
 
 
 # ---------------------------------------------------------------------------
@@ -460,6 +463,12 @@ def _stored(pages, layer=0, layers=1):
     return jnp.asarray(leaf, pages.dtype)
 
 
+def _kv_pool(k_leaf, v_leaf):
+    """The fp pool of :func:`_stored` K and V leaves: a row its K lanes, then
+    its V lanes, in the one leaf."""
+    return PagePool(paged_kv.join_kv(k_leaf, v_leaf))
+
+
 @pytest.mark.parametrize("layer,layers", [(0, 1), (1, 3), (2, 3)])
 def test_paged_attention_matches_contiguous(layer, layers):
     # the page gather at a layer index, then the attend over the rows as
@@ -474,7 +483,7 @@ def test_paged_attention_matches_contiguous(layer, layers):
     pt = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
     lengths = jnp.asarray([3, 8, 5], jnp.int32)
     out = paged_decode_attention(
-        q, PagePool(_stored(kp, layer, layers), _stored(vp, layer, layers)),
+        q, _kv_pool(_stored(kp, layer, layers), _stored(vp, layer, layers)),
         layer, pt, lengths)
     idx = (np.asarray(pt)[:, :, None] * ps
            + np.arange(ps)[None, None, :]).reshape(b, span)
@@ -493,7 +502,7 @@ def test_paged_attention_matches_contiguous(layer, layers):
             kp2[page, off] = 1e6 * (i + 1)
             vp2[page, off] = -1e6
     out2 = paged_decode_attention(
-        q, PagePool(_stored(jnp.asarray(kp2), layer, layers),
+        q, _kv_pool(_stored(jnp.asarray(kp2), layer, layers),
                     _stored(jnp.asarray(vp2), layer, layers)),
         layer, pt, lengths)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
@@ -553,7 +562,7 @@ def test_page_gather_equals_flat_row_gather_bitwise(kv, hd, ps, monkeypatch):
     monkeypatch.setattr(paged_kv, "attend_rows", recording_attend)
     # layer 1 of 2: the other layer's pages are garbage under the same ids
     out = paged_decode_attention(
-        q, PagePool(_stored(kp, 1, 2), _stored(vp, 1, 2)), 1, pt, lengths)
+        q, _kv_pool(_stored(kp, 1, 2), _stored(vp, 1, 2)), 1, pt, lengths)
     (kg, vg), = handed
     k_old, v_old = _flat_row_gather(kp, pt), _flat_row_gather(vp, pt)
     span = pt.shape[1] * ps
@@ -596,9 +605,11 @@ def test_write_rows_then_read_span_at_a_layer_equal_the_5d_oracle(tier):
     k = jnp.asarray(rng.standard_normal((b, 1, kv, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, 1, kv, hd)), jnp.float32)
     if tier == "fp":
-        pool = PagePool(*(jnp.asarray(rng.standard_normal(
-            (layers, pn, ps, kv * hd)), jnp.float32) for _ in "kv"))
-        stored = (k[:, 0], v[:, 0])
+        pool = PagePool(jnp.asarray(rng.standard_normal(
+            (layers, pn, ps, 2 * kv * hd)), jnp.float32))
+        # ONE stored row a position: its K heads' lanes, then its V heads'
+        stored = (jnp.concatenate([k[:, 0].reshape(b, -1),
+                                   v[:, 0].reshape(b, -1)], -1),)
     else:
         pool = init_quant_pool(
             tiny_config("qwen2", num_layers=layers, hidden_size=kv * hd * 2,
@@ -615,16 +626,18 @@ def test_write_rows_then_read_span_at_a_layer_equal_the_5d_oracle(tier):
         page = np.asarray(pt)[np.arange(b), np.asarray(lengths) // ps]
         off = np.asarray(lengths) % ps
         live = page != 0        # the trash page takes duplicate writes
+        assert len(after) == len(before) == len(stored)
         for a0, a1, rows in zip(before, after, stored):
-            want = a0.copy().reshape(layers, pn, ps, kv, -1)
+            want = a0.copy()
             want[l, page[live], off[live]] = np.asarray(
-                rows, a0.dtype).reshape(b, kv, -1)[live]
-            got = np.asarray(a1).reshape(want.shape)
+                rows, a0.dtype).reshape(b, -1)[live]
+            got = np.asarray(a1)
             np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
             np.testing.assert_array_equal(np.delete(got, l, 0)[:, 0],
                                           np.delete(want, l, 0)[:, 0])
         kg, vg = paged_kv.read_span(after, layer, pt, jnp.float32)
-        views = [np.asarray(a)[l].reshape(pn, ps, kv, -1) for a in after]
+        views = [np.asarray(a)[l].reshape(pn, ps, kv, -1) for a in (
+            paged_kv.split_kv(after.kv) if tier == "fp" else after)]
         if tier == "fp":
             k_ref, v_ref = (_flat_row_gather(jnp.asarray(x), pt)
                             for x in views)
@@ -664,7 +677,7 @@ def test_decode_step_fetches_pool_by_page_not_by_row(params, tier):
     ints = jnp.zeros((slots,), jnp.int32)
     if tier == "fp":
         pool = init_pool(CFG, pn, ps)
-        want = [ps * kv * hd] * 2                  # a page of K, of V
+        want = [ps * 2 * kv * hd]         # a page, its K and V lanes: ONE
     else:
         pool = init_quant_pool(CFG, pn, ps, tier)
         want = [ps * pool.k.shape[-1]] * 2 + [ps * kv] * 2  # codes, scales

@@ -800,8 +800,9 @@ def test_split_step_carries_stage_and_hop_scopes(split_rt, params):
                   "unembed_sample"):
         assert scope in text, scope
     assert "split.hop.1" not in text            # one cut
-    pk = b._split_pool.k
-    rows = jnp.zeros(pk.shape[:2] + (3,) + pk.shape[4:], pk.dtype)
+    kv = b._split_pool.kv
+    # three positions' K (and V) rows, as one head as wide as the K lanes
+    rows = jnp.zeros(kv.shape[:2] + (3, 1, kv.shape[-1] // 2), kv.dtype)
     adopt = split_mod._adopt_paged_impl.lower(
         b._split_pool, rows, rows, jnp.arange(3)).as_text(debug_info=True)
     assert "paged_kv.adopt" in adopt

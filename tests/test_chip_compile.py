@@ -98,6 +98,34 @@ def _walks(hlo: str) -> int:
                for op, _, _, line in _instructions(hlo))
 
 
+def _walk_operands(hlo: str) -> list:
+    """What each page-walk kernel of a compiled module is handed: the result
+    types of its operands, in order (page ids, lengths, query, then what it
+    reads out of HBM)."""
+    return [re.findall(r"([a-z]+[0-9]+\[[\d,]*\])", re.search(
+                r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=", line)[1])
+            for op, _, _, line in _instructions(hlo)
+            if op == "custom-call" and "paged_decode_walk" in line]
+
+
+def _row_writes(hlo: str) -> int:
+    """Fusions that scatter under ``paged_kv.write``: a step's new rows, a
+    layer. (Not a ``.remat`` one: where memory is tight the compiler may run
+    a layer's 96-row scatter a second time, in place again, rather than keep
+    its result alive across the layers between: the mellum step's first full
+    layer.)"""
+    writers, computation = set(), None
+    for line in hlo.splitlines():
+        header = re.match(r"^%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if header:
+            computation = header[1]
+        elif " scatter(" in line and "paged_kv.write" in line:
+            writers.add(computation)
+    return sum(op == "fusion" and "remat" not in name
+               and re.search(r"calls=%?([\w.\-]+)", line)[1] in writers
+               for op, name, _, line in _instructions(hlo))
+
+
 def _span_sized(hlo: str, shapes) -> list:
     """Results of any instruction whose type names one of ``shapes``: a
     slot's whole span of K or V, gathered, reshaped or fused."""
@@ -146,8 +174,10 @@ def test_decode_step_updates_the_pool_where_it_lies(topo, read):
         one)
     pool = _shapes(jax.eval_shape(
         lambda: paged_kv.init_pool(QWEN05, PAGES, PAGE, jnp.bfloat16)), one)
-    width = QWEN05.num_kv_heads * QWEN05.head_dim
-    assert pool.k.shape == (24, PAGES, PAGE, width)
+    # a row: 128 K lanes (2 KV heads of 64), then 128 V lanes, in ONE leaf
+    width = 2 * QWEN05.num_kv_heads * QWEN05.head_dim
+    (leaf,) = pool
+    assert leaf.shape == (24, PAGES, PAGE, width) and width == 256
     assert paged_kv.decode_read_path(pool) == {
         "walk": paged_kv.PAGE_WALK, "gather": paged_kv.PAGE_GATHER}[read]
 
@@ -160,10 +190,10 @@ def test_decode_step_updates_the_pool_where_it_lies(topo, read):
         ints, arr((SLOTS, 2), jnp.uint32), ints, arr((SLOTS,), jnp.float32),
         None).compile()
     hlo = step.as_text()
-    layer_pool = PAGES * PAGE * width           # one layer's K or V
+    layer_pool = PAGES * PAGE * width           # one layer's K and V
     gathered = SLOTS * PAGES_PER_SLOT * PAGE * width   # a layer's span read
     # the gather custom fusion names its own output's reshape/transpose
-    # (`bf16[24576,16,128]`, `bf16[192,128,16,128]`): the gather itself
+    # (`bf16[24576,16,256]`, `bf16[192,128,16,256]`): the gather itself
     own = {f"bf16[{SLOTS * PAGES_PER_SLOT},{PAGE},{width}]",
            f"bf16[{SLOTS},{PAGES_PER_SLOT},{PAGE},{width}]"}
     moved = [m for m in _moved(hlo, gathered)
@@ -178,18 +208,24 @@ def test_decode_step_updates_the_pool_where_it_lies(topo, read):
     # ... and the row writes scatter into the pool viewed as rows
     assert f"bf16[{24 * PAGES * PAGE},{width}]" in hlo
     mem = step.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * 24 * layer_pool * 2    # donated
+    assert mem.alias_size_in_bytes >= 24 * layer_pool * 2    # donated
+    # a step's new rows: ONE scatter a layer (the scan's body), K and V lanes
+    assert _row_writes(hlo) == 1, _row_writes(hlo)
     if read == "gather":
         # the attend's dots read the gather's output as it lies
         assert "bhD,bcD->bhc" in hlo and "bhc,bcD->bhD" in hlo
         assert not _walks(hlo)
-        # a gathered copy of one layer's K or V at a time: 126.5 MB
+        # a gathered copy of one layer's K and V at a time
         assert 100e6 < mem.temp_size_in_bytes < 0.5e9
         return
-    # the walk: one kernel a layer (the scan's body), both leaves handed to
-    # it whole, and NOTHING span-sized anywhere in the module: no result of
-    # a slot's 2048 rows a slot, gathered, reshaped or fused
+    # the walk: one kernel a layer (the scan's body), the ONE leaf handed to
+    # it whole as its one operand in HBM, and NOTHING span-sized anywhere in
+    # the module: no result of a slot's 2048 rows a slot, gathered, reshaped
+    # or fused
     assert _walks(hlo) == 1, _walks(hlo)
+    (operands,) = _walk_operands(hlo)
+    assert operands[2:] == [
+        f"bf16[{SLOTS},{QWEN05.num_heads},{width // 2}]", flat_pages], operands
     spans = _span_sized(hlo, own | {
         f"bf16[{SLOTS},{PAGES_PER_SLOT * PAGE},{width}]"})
     assert not spans, spans[:3]
@@ -209,7 +245,7 @@ def test_adopt_scatters_in_place(topo):
     # head=0: a prefill's adopt, whole pages a scatter slice (the cells' path)
     adopt = paged_kv._adopt_impl.lower(pool, rows, rows, dest,
                                        head=0).compile()
-    layer_pool = PAGES * PAGE * pool.k.shape[-1]
+    layer_pool = PAGES * PAGE * pool.kv.shape[-1]
     assert not _moved(adopt.as_text(), layer_pool), \
         _moved(adopt.as_text(), layer_pool)
     assert adopt.memory_analysis().temp_size_in_bytes < 16e6
@@ -220,7 +256,7 @@ def test_staged_adopt_scatters_in_place_over_four_chips(topo):
 
     mesh = Mesh(np.asarray(topo.devices[:SPLIT_STAGES]), ("stage",))
     staged = NamedSharding(mesh, P("stage"))
-    width = SPLIT_KV * SPLIT_HD
+    width = 2 * SPLIT_KV * SPLIT_HD         # a row: K lanes, then V lanes
     leaf = jax.ShapeDtypeStruct(
         (SPLIT_STAGES, SPLIT_STAGE_SIZE, PAGES, PAGE, width), jnp.bfloat16,
         sharding=staged)
@@ -230,7 +266,7 @@ def test_staged_adopt_scatters_in_place_over_four_chips(topo):
     dest = jax.ShapeDtypeStruct((PROMPT,), jnp.int32,
                                 sharding=NamedSharding(mesh, P()))
     adopt = split._adopt_paged_impl.lower(
-        paged_kv.PagePool(leaf, leaf), rows, rows, dest, head=0).compile()
+        paged_kv.PagePool(leaf), rows, rows, dest, head=0).compile()
     hlo = adopt.as_text()
     layer_pool = PAGES * PAGE * width
     assert not _moved(hlo, layer_pool), _moved(hlo, layer_pool)
@@ -260,7 +296,7 @@ def test_window_layers_walk_their_rings_and_both_pools_stay_in_place(topo,
     lie: a window layer's over each slot's RING (65 entries, masked by the
     position a row holds; PERF.md §6 "PR 40"), a full layer's over its live
     pages, and no ring- or span-sized copy of K or V exists anywhere in the
-    module. On the gather (the oracle) a window layer's two gathers take the
+    module. On the gather (the oracle) a window layer's gather takes the
     ring, never the span (384), and a full layer's the span. Either way
     neither pool is copied, relaid or stacked, and what is gathered is the
     step's temporaries (0.78 GB beside 11.2 GB of weights and pools; 0.15 GB
@@ -277,9 +313,10 @@ def test_window_layers_walk_their_rings_and_both_pools_stay_in_place(topo,
     window = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
         MELLUM, M_SLOTS * ring + 1, PAGE, jnp.bfloat16,
         layers=MELLUM.window_layers)), one)
-    width = MELLUM.num_kv_heads * MELLUM.head_dim
-    assert full.k.shape == (2, 36865, PAGE, width)
-    assert window.k.shape == (6, 6241, PAGE, width)
+    # a row of either pool: 512 K lanes (4 KV heads of 128), then 512 V lanes
+    width = 2 * MELLUM.num_kv_heads * MELLUM.head_dim
+    assert [a.shape for a in full] == [(2, 36865, PAGE, width)]
+    assert [a.shape for a in window] == [(6, 6241, PAGE, width)]
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -297,14 +334,21 @@ def test_window_layers_walk_their_rings_and_both_pools_stay_in_place(topo,
                if op == "gather" and _elements(shape) >= gathered]
     span = f"bf16[{M_SLOTS},{M_PAGES_PER_SLOT},{PAGE},{width}]"
     rings = f"bf16[{M_SLOTS},{ring},{PAGE},{width}]"
-    # K and V of the 6 window layers (static walk) and of the 2 full layers
-    # where they are gathered; on the page walk neither a span nor a ring is
-    # ever materialized: a kernel a layer, 2 full and 6 window
-    assert sorted(gathers) == ([span] * 4 + [rings] * 12
+    # the rows (K and V lanes in one) of the 6 window layers (static walk)
+    # and of the 2 full layers where they are gathered; on the page walk
+    # neither a span nor a ring is ever materialized: a kernel a layer, 2
+    # full and 6 window, each handed its pool's ONE leaf as pages
+    assert sorted(gathers) == ([span] * 2 + [rings] * 6
                                if read == "gather" else []), gathers
     assert _walks(hlo) == (8 if read == "walk" else 0)
+    # a step's new rows: one scatter a layer
+    assert _row_writes(hlo) == 8, _row_writes(hlo)
     if read == "walk":
         assert not _span_sized(hlo, (rings, span))
+        query = f"bf16[{M_SLOTS},{MELLUM.num_heads},{width // 2}]"
+        assert sorted(tuple(ops[2:]) for ops in _walk_operands(hlo)) == sorted(
+            [(query, f"bf16[{2 * 36865},{PAGE},{width}]")] * 2
+            + [(query, f"bf16[{6 * 6241},{PAGE},{width}]")] * 6)
     own = {span, rings,
            f"bf16[{M_SLOTS * M_PAGES_PER_SLOT},{PAGE},{width}]",
            f"bf16[{M_SLOTS * ring},{PAGE},{width}]"}
@@ -316,10 +360,13 @@ def test_window_layers_walk_their_rings_and_both_pools_stay_in_place(topo,
     assert f"bf16[{6 * 6241},{PAGE},{width}]" in hlo
     assert f"bf16[{6 * 6241 * PAGE},{width}]" in hlo
     mem = step.memory_analysis()
-    # 778 MB with the full layers' gathered spans and the rings'; on the
-    # walk 152 MB, the logits and the sampler's bits over the vocabulary
-    assert mem.temp_size_in_bytes < (1.0e9 if read == "gather" else 0.2e9)
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+    # 1.32 GB with the full layers' gathered spans and the rings' (the
+    # oracle splits each gathered copy on lanes into K and V: 778 MB as two
+    # leaves); on the walk 152 MB, the logits and the sampler's bits over
+    # the vocabulary
+    assert mem.temp_size_in_bytes < (1.5e9 if read == "gather" else 0.2e9)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < (
+        12.6e9 if read == "gather" else 12.5e9)
 
 
 # benchmark/configs/qwen2-1.5b-split4.json: the widths of the four-chip cell
@@ -360,7 +407,7 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
         for k, v in params["layers"].items()}
     placed["layers_valid"] = jax.ShapeDtypeStruct(
         (SPLIT_STAGES, sz), jnp.bool_, sharding=staged)
-    width = SPLIT_KV * SPLIT_HD
+    width = 2 * SPLIT_KV * SPLIT_HD         # a row: K lanes, then V lanes
     leaf = jax.ShapeDtypeStruct((SPLIT_STAGES, sz, PAGES, PAGE, width),
                                 jnp.bfloat16, sharding=staged)
 
@@ -368,7 +415,7 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=everywhere)
 
     step = rt._paged_decode_fns(PAGES, PAGE).lower(
-        placed, paged_kv.PagePool(leaf, leaf), arr((SLOTS, PAGES_PER_SLOT)),
+        placed, paged_kv.PagePool(leaf), arr((SLOTS, PAGES_PER_SLOT)),
         arr((SLOTS,)), arr((SLOTS,))).compile()
     hlo = step.as_text()
     layer_pool = PAGES * PAGE * width
@@ -391,6 +438,9 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
         # included: the kernel, and no span-sized K or V anywhere
         assert _walks(hlo) >= 1, _walks(hlo)
         assert not _span_sized(hlo, own), _span_sized(hlo, own)[:3]
+        # every one of them reads ONE operand out of HBM: the stage's leaf
+        for ops in _walk_operands(hlo):
+            assert ops[3:] == [f"bf16[{sz * PAGES},{PAGE},{width}]"], ops
     else:
         assert not _walks(hlo)
     # across chips: the three hops, each to the next stage, and the one
@@ -411,7 +461,7 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
     # and little else, where the stacked and selected pools before PR 31
     # were 5.45 GB; the walk leaves 2.3 MB
     assert mem.temp_size_in_bytes < (0.5e9 if read == "gather" else 10e6)
-    assert mem.alias_size_in_bytes >= 2 * sz * layer_pool * 2   # donated
+    assert mem.alias_size_in_bytes >= sz * layer_pool * 2       # donated
 
 
 # benchmark/configs/mistral-small-4-119b-ep4.json: the widths, the chip's
@@ -442,7 +492,7 @@ def _latent_step(one):
 
     ints = arr((L_SLOTS,), jnp.int32)
     return batching._batched_hybrid_step_jit.lower(
-        MISTRAL4, params, pool.rows, None, None,
+        MISTRAL4, params, pool.rows, None,
         arr((4, 32), jnp.int32), arr((L_SLOTS, L_PAGES_PER_SLOT), jnp.int32),
         ints, ints, arr((L_SLOTS, 2), jnp.uint32), ints,
         arr((L_SLOTS,), jnp.float32), None).compile()
@@ -575,27 +625,27 @@ def test_afmoe_step_keeps_both_pools_in_place_and_scopes_what_is_heavy(topo,
     assert "router" not in params["moe"][0] and "wg" in params["window"]
     ring = AFMOE.window_pages(PAGE)
     assert ring == 4 and AFMOE.expert_layers == 4
-    assert full.k.shape == (1, 49, PAGE, 128)
-    assert window.k.shape == (4, 33, PAGE, 128)
+    assert [a.shape for a in full] == [(1, 49, PAGE, 256)]   # K | V lanes
+    assert [a.shape for a in window] == [(4, 33, PAGE, 256)]
     step = lowered.compile()
     hlo = step.as_text()
-    gathered = A_SLOTS * ring * PAGE * 128          # a window layer's read
+    gathered = A_SLOTS * ring * PAGE * 256          # a window layer's read
     gathers = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
                if op == "gather" and _elements(shape) >= gathered]
-    span = f"bf16[{A_SLOTS},{A_PAGES_PER_SLOT},{PAGE},128]"
-    rings = f"bf16[{A_SLOTS},{ring},{PAGE},128]"
-    assert sorted(gathers) == (sorted([span] * 2 + [rings] * 8)
+    span = f"bf16[{A_SLOTS},{A_PAGES_PER_SLOT},{PAGE},256]"
+    rings = f"bf16[{A_SLOTS},{ring},{PAGE},256]"
+    assert sorted(gathers) == (sorted([span] + [rings] * 4)
                                if read == "gather" else []), gathers
     assert _walks(hlo) == (5 if read == "walk" else 0)
     if read == "walk":
         assert not _span_sized(hlo, (rings, span))
-    own = {span, rings, f"bf16[{A_SLOTS * A_PAGES_PER_SLOT},{PAGE},128]",
-           f"bf16[{A_SLOTS * ring},{PAGE},128]"}
+    own = {span, rings, f"bf16[{A_SLOTS * A_PAGES_PER_SLOT},{PAGE},256]",
+           f"bf16[{A_SLOTS * ring},{PAGE},256]"}
     moved = [m for m in _moved(hlo, gathered)
              if not (m[0] in ("reshape", "transpose") and m[2] in own)]
     assert not moved, moved
-    assert f"bf16[{4 * 33},{PAGE},128]" in hlo        # pages at (layer, page)
-    assert f"bf16[{4 * 33 * PAGE},128]" in hlo        # rows at (l, p, r)
+    assert f"bf16[{4 * 33},{PAGE},256]" in hlo        # pages at (layer, page)
+    assert f"bf16[{4 * 33 * PAGE},256]" in hlo        # rows at (l, p, r)
     # all but two row gathers every walked family makes ahead of its first
     # layer: the embedding's (jnp.take) and each slot's row of the rope table
     unscoped, under = _scopes_of_the_heavy(hlo)
@@ -649,7 +699,7 @@ def test_longcat_step_walks_five_tile_rows_and_scopes_what_is_heavy(topo,
 
     ints = arr((C_SLOTS,), jnp.int32)
     step = batching._batched_hybrid_step_jit.lower(
-        cfg, params, pool.rows, None, None, arr((4, 17), jnp.int32),
+        cfg, params, pool.rows, None, arr((4, 17), jnp.int32),
         arr((C_SLOTS, C_PAGES_PER_SLOT), jnp.int32), ints, ints,
         arr((C_SLOTS, 2), jnp.uint32), ints, arr((C_SLOTS,), jnp.float32),
         None).compile()
@@ -709,7 +759,7 @@ def test_lfm2_step_keeps_its_windows_and_its_pool_in_place(topo, read):
     pages = F_SLOTS * F_PAGES_PER_SLOT + 1
     pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
         cfg, pages, PAGE, jnp.bfloat16)), one)
-    assert pool.k.shape == (3, 27649, PAGE, 512)
+    assert [a.shape for a in pool] == [(3, 27649, PAGE, 1024)]  # K | V
     state = _shapes(jax.eval_shape(
         lambda: paged_kv.init_slot_state(cfg, F_SLOTS)), one)
     assert {leaf: a.shape for leaf, a in state.items()} == {
@@ -720,15 +770,15 @@ def test_lfm2_step_keeps_its_windows_and_its_pool_in_place(topo, read):
 
     ints = arr((F_SLOTS,), jnp.int32)
     step = batching._batched_hybrid_step_jit.lower(
-        cfg, params, pool.k, pool.v, state, arr((10, 32), jnp.int32),
+        cfg, params, pool.kv, state, arr((10, 32), jnp.int32),
         arr((F_SLOTS, F_PAGES_PER_SLOT), jnp.int32), ints, ints,
         arr((F_SLOTS, 2), jnp.uint32), ints, arr((F_SLOTS,), jnp.float32),
         None).compile()
     hlo = step.as_text()
-    layer_pool = pages * PAGE * 512
-    gathered = F_SLOTS * F_PAGES_PER_SLOT * PAGE * 512
-    span = f"bf16[{F_SLOTS},{F_PAGES_PER_SLOT},{PAGE},512]"
-    own = {span, f"bf16[{F_SLOTS * F_PAGES_PER_SLOT},{PAGE},512]"}
+    layer_pool = pages * PAGE * 1024
+    gathered = F_SLOTS * F_PAGES_PER_SLOT * PAGE * 1024
+    span = f"bf16[{F_SLOTS},{F_PAGES_PER_SLOT},{PAGE},1024]"
+    own = {span, f"bf16[{F_SLOTS * F_PAGES_PER_SLOT},{PAGE},1024]"}
     moved = [m for m in _moved(hlo, gathered)
              if not (m[0] in ("reshape", "transpose") and m[2] in own)]
     assert not moved, moved
@@ -747,9 +797,10 @@ def test_lfm2_step_keeps_its_windows_and_its_pool_in_place(topo, read):
     assert not copies, copies
     mem = step.memory_analysis()
     window_bytes = 9 * F_SLOTS * 2 * 2048 * 4
-    assert mem.alias_size_in_bytes >= 2 * 3 * layer_pool * 2 + window_bytes
-    # weights 7.86 GB + pool 2.72 GB + the step's temporaries
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
+    assert mem.alias_size_in_bytes >= 3 * layer_pool * 2 + window_bytes
+    # weights 7.86 GB + pool 2.72 GB + the step's temporaries (0.93 GB on
+    # the gather, whose copy is split on lanes into K and V)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.6e9
     if read == "walk":
         assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
     unscoped, under = _scopes_of_the_heavy(hlo)

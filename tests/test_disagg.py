@@ -420,8 +420,7 @@ def test_split_packed_adopt_refusals_are_typed():
     from edgellm_tpu.parallel.split import SplitRuntime
     rt = SplitRuntime.__new__(SplitRuntime)
     from edgellm_tpu.models.paged_kv import PagePool
-    fake_pool = PagePool(np.zeros((2, 3, 4, 2, 2), np.float32),
-                         np.zeros((2, 3, 4, 2, 2), np.float32))
+    fake_pool = PagePool(np.zeros((2, 3, 4, 2, 2 * 2), np.float32))
     with pytest.raises(KVTierMismatchError) as ei:
         rt.gather_paged_packed(fake_pool, np.zeros(2, np.int32))
     assert ei.value.where == "gather_paged_packed"

@@ -148,20 +148,20 @@ class LogitTap:
         self.rows = []        # (lengths, logits) per step
 
         @jax.jit
-        def step(params, pk, pv, state, cnt, table, lengths, toks,
+        def step(params, kv, state, cnt, table, lengths, toks,
                  key_data, steps, temps):
             with jax.default_matmul_precision("highest"):
-                logits, pk, pv, state, cnt = (
+                logits, kv, state, cnt = (
                     hybrid.paged_decode_step_hybrid(
-                        cfg, params, pk, pv, state, cnt, table, lengths,
+                        cfg, params, kv, state, cnt, table, lengths,
                         toks))
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps),
-                    pk, pv, state, cnt)
+                    kv, state, cnt)
 
-        def tapped(cfg_, params, pk, pv, state, cnt, table, lengths,
+        def tapped(cfg_, params, kv, state, cnt, table, lengths,
                    toks, key_data, steps, temps, compute_dtype):
-            logits, *rest = step(params, pk, pv, state, cnt, table,
+            logits, *rest = step(params, kv, state, cnt, table,
                                  lengths, toks, key_data, steps, temps)
             # copies: on the CPU a device array made from numpy may alias the
             # pool's own table, which the batcher goes on to overwrite
@@ -659,7 +659,7 @@ def test_one_manager_for_both_kinds_of_state(recurrent):
     CFG, params, _ = recurrent
     b = ContinuousBatcher(CFG, params, BCFG)
     pool = b.pool
-    assert pool.pool.k.shape[0] == CFG.kv_layers == 1
+    assert pool.pool.kv.shape[0] == CFG.kv_layers == 1
     shapes = hybrid.state_shapes(CFG, BCFG.max_slots)
     assert {leaf: a.shape for leaf, a in pool.state.items()} == shapes
     assert pool.state_leaf_bytes == {leaf: 4 * np.prod(shape)
@@ -712,11 +712,11 @@ def test_report_counts_routing_on_the_device_and_reads_it_only_when_asked():
     assert not {"state_bytes", "expert_tokens", "routed_local"} & set(plain)
 
 
-def test_the_step_carries_the_new_scopes_and_donates_five_buffers(params):
+def test_the_step_carries_the_new_scopes_and_donates_four_buffers(params):
     b = ContinuousBatcher(CFG, params, BCFG)
     table, lengths = b.pool.device_tables()
     n = BCFG.max_slots
-    args = (CFG, params, b.pool.pool.k, b.pool.pool.v, b.pool.state,
+    args = (CFG, params, b.pool.pool.kv, b.pool.state,
             b._expert_tokens, table, lengths,
             jnp.zeros((n,), jnp.int32), jnp.asarray(b._free_key_rows),
             jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32), None)
@@ -727,7 +727,8 @@ def test_the_step_carries_the_new_scopes_and_donates_five_buffers(params):
                   "unembed_sample"):
         assert scope in text, scope
     assert "ssm.scan" not in text
-    assert text.count("tf.aliasing_output") == 5
+    # the pool's one leaf, conv, ssm, the expert counter
+    assert text.count("tf.aliasing_output") == 4
     from edgellm_tpu.serve.decode import _prefill_jit
 
     pre = _prefill_jit.lower(CFG, params, jnp.zeros((1, 16), jnp.int32),

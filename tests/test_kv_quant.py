@@ -23,7 +23,7 @@ from edgellm_tpu.models.flash_attention import (dequantize_kv_rows,
 from edgellm_tpu.models.paged_kv import (KV_PAGE_CODECS, OutOfPages,
                                          PagedKVCache, PagePool,
                                          PrefixCacheConfig, QuantPagePool,
-                                         kv_page_bytes,
+                                         join_kv, kv_page_bytes,
                                          num_pages_for_bytes,
                                          paged_decode_attention,
                                          resolve_kv_codec)
@@ -199,8 +199,8 @@ def test_paged_quant_fallback_matches_dequantized_pool(tier):
     kf = dequantize_kv_rows(kq, ks, tier)
     vf = dequantize_kv_rows(vq, vs, tier)
     ref = paged_decode_attention(
-        q, PagePool(kf.reshape(1, npg, pgs, nkv * CFG2.head_dim),
-                    vf.reshape(1, npg, pgs, nkv * CFG2.head_dim)),
+        q, PagePool(join_kv(kf.reshape(1, npg, pgs, nkv * CFG2.head_dim),
+                            vf.reshape(1, npg, pgs, nkv * CFG2.head_dim))),
         0, tab, lens)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -272,9 +272,8 @@ def _zero_pool(axes, pn, ps, kv, hd, tier):
     stage_size) staged) at ``tier``."""
     codec = resolve_kv_codec(tier)
     rows = tuple(axes) + (pn, ps)
-    if not codec.quantized:   # a buffer each: the surgery donates them
-        return PagePool(*(jnp.zeros(rows + (kv * hd,), jnp.float32)
-                          for _ in "kv"))
+    if not codec.quantized:   # one leaf: a row's K lanes, then its V lanes
+        return PagePool(jnp.zeros(rows + (2 * kv * hd,), jnp.float32))
     codes = rows + (kv * codec.code_lanes(hd),)
     return QuantPagePool(jnp.zeros(codes, codec.code_dtype),
                          jnp.zeros(codes, codec.code_dtype),
@@ -334,9 +333,18 @@ def test_pool_surgery_is_one_body_at_every_rank_and_tier(lead, tier):
                 else np.asarray(quantize_kv_rows(k, tier)[0]))
     want = np.zeros(axes + (pn, ps) + stored_k.shape[-2:], stored_k.dtype)
     want[page + (np.asarray(dest) // ps, np.asarray(dest) % ps)] = stored_k
-    assert got["adopted"][0].shape == axes + (
+    if tier == "fp":          # the one leaf: K lanes [0, W), V lanes [W, 2W)
+        (joined,) = got["adopted"]
+        assert joined.shape == axes + (pn, ps, 2 * kv * hd)
+        adopted_k, adopted_v = joined[..., :kv * hd], joined[..., kv * hd:]
+        want_v = np.zeros_like(want)
+        want_v[page + (np.asarray(dest) // ps, np.asarray(dest) % ps)] = v
+        np.testing.assert_array_equal(adopted_v.reshape(want.shape), want_v)
+    else:
+        adopted_k = got["adopted"][0]
+    assert adopted_k.shape == axes + (
         pn, ps, kv * stored_k.shape[-1])                 # the stored row
-    np.testing.assert_array_equal(got["adopted"][0].reshape(want.shape), want)
+    np.testing.assert_array_equal(adopted_k.reshape(want.shape), want)
     # adopt -> gather returns the rows (to the tier's quantization error)
     gk, gv = got["gathered"]
     if tier == "fp":
@@ -436,11 +444,11 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
     write, read = pk.write_rows, pk.read_span
 
     def recording_write(pool, *a):
-        seen.append(("write", type(pool), pool.k.shape))
+        seen.append(("write", type(pool), pool[0].shape))
         return write(pool, *a)
 
     def recording_read(pool, *a):
-        seen.append(("read", type(pool), pool.k.shape))
+        seen.append(("read", type(pool), pool[0].shape))
         return read(pool, *a)
 
     monkeypatch.setattr(pk, "write_rows", recording_write)
@@ -449,7 +457,9 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
     table = jnp.zeros((slots, pps), jnp.int32)
     ints = jnp.zeros((slots,), jnp.int32)
     codec = resolve_kv_codec(tier)
-    layer = (npg, ps, CFG.num_kv_heads * codec.code_lanes(CFG.head_dim))
+    # a page of the pool's first leaf: K codes, or the fp tier's K | V rows
+    layer = (npg, ps, CFG.num_kv_heads * codec.code_lanes(CFG.head_dim)
+             * (1 if codec.quantized else 2))
     kind = QuantPagePool if codec.quantized else PagePool
 
     def want(layers):   # the pool WITH its layer axis, one write, one read
@@ -467,7 +477,7 @@ def test_one_chip_and_staged_step_share_one_write_and_one_read(
                       make_stage_mesh(2))
     staged = rt.init_paged_pool(npg, ps, kv_codec=tier)
     assert type(staged) is kind and pk.pool_tier(staged) == tier
-    assert staged.k.shape == (2, rt.stage_size) + layer
+    assert staged[0].shape == (2, rt.stage_size) + layer
     jax.make_jaxpr(rt._paged_decode_fns(npg, ps, kv_codec=tier))(
         rt.place_params(params), staged, table, ints, ints)
     # one stage body, scanned by every stage, over the stage's carried pool

@@ -425,11 +425,11 @@ def test_what_needs_a_snapshot_refuses_the_family_in_the_windows_words():
             make()
 
 
-def test_the_step_carries_the_new_scopes_and_donates_four_buffers(params):
+def test_the_step_carries_the_new_scopes_and_donates_three_buffers(params):
     b = ContinuousBatcher(CFG, params, BCFG)
     table, lengths = b.pool.device_tables()
     n = BCFG.max_slots
-    args = (CFG, params, b.pool.pool.k, b.pool.pool.v, b.pool.state,
+    args = (CFG, params, b.pool.pool.kv, b.pool.state,
             b._expert_tokens, table, lengths,
             jnp.zeros((n,), jnp.int32), jnp.asarray(b._free_key_rows),
             jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32), None)
@@ -440,8 +440,8 @@ def test_the_step_carries_the_new_scopes_and_donates_four_buffers(params):
                   "unembed_sample"):
         assert scope in text, scope
     assert "ssm." not in text and "moe.shared" not in text
-    # K pages, V pages, the windows, the expert counter
-    assert text.count("tf.aliasing_output") == 4
+    # the pages' one leaf, the windows, the expert counter
+    assert text.count("tf.aliasing_output") == 3
     from edgellm_tpu.lint.contracts import GRAPH_CONTRACTS
     from edgellm_tpu.obs.names import SCOPE_NAMES
     from edgellm_tpu.serve.decode import _prefill_jit

@@ -346,18 +346,18 @@ class LogitTap:
         def step(params, rows, cnt, table, lengths, toks, key_data, steps,
                  temps):
             with jax.default_matmul_precision("highest"):
-                logits, rows, _, _, cnt = hybrid.paged_decode_step_hybrid(
-                    cfg, params, rows, None, None, cnt, table, lengths, toks)
+                logits, rows, _, cnt = hybrid.paged_decode_step_hybrid(
+                    cfg, params, rows, None, cnt, table, lengths, toks)
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps), rows, cnt)
 
-        def tapped(cfg_, params, rows, v, state, cnt, table, lengths,
+        def tapped(cfg_, params, rows, state, cnt, table, lengths,
                    toks, key_data, steps, temps, compute_dtype):
-            assert v is None and state is None
+            assert state is None
             logits, toks, rows, cnt = step(params, rows, cnt, table, lengths,
                                            toks, key_data, steps, temps)
             self.rows.append((np.array(lengths), np.array(logits)))
-            return toks, rows, None, None, cnt
+            return toks, rows, None, cnt
 
         tapped._cache_size = lambda: 0
         monkeypatch.setattr(batching, "_batched_hybrid_step_jit", tapped)

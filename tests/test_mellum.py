@@ -341,13 +341,13 @@ class LogitTap:
         def step(params, pool, wpool, cnt, table, wtable, lengths, toks,
                  key_data, steps, temps):
             with jax.default_matmul_precision("highest"):
-                logits, k, v, _, cnt, (wk, wv) = (
+                logits, kv, _, cnt, win = (
                     hybrid.paged_decode_step_hybrid(
-                        cfg, params, pool.k, pool.v, None, cnt, table,
-                        lengths, toks, window=(wpool.k, wpool.v, wtable)))
+                        cfg, params, pool.kv, None, cnt, table,
+                        lengths, toks, window=(wpool.kv, wtable)))
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps),
-                    type(pool)(k, v), type(wpool)(wk, wv), cnt)
+                    type(pool)(kv), type(wpool)(win), cnt)
 
         def tapped(cfg_, params, pool, wpool, cnt, table, wtable, lengths,
                    toks, key_data, steps, temps, compute_dtype):
@@ -420,8 +420,8 @@ def test_window_layers_hold_a_ring_however_long_the_stream_grows(
     with jax.default_matmul_precision("highest"):
         b = ContinuousBatcher(CFG, params, BCFG)
         pool = b.pool
-        assert pool.window_pool.k.shape == (6, 3 * 4 + 1, 4, 32)
-        assert pool.pool.k.shape == (2, 121, 4, 32)
+        assert pool.window_pool.kv.shape == (6, 3 * 4 + 1, 4, 2 * 32)
+        assert pool.pool.kv.shape == (2, 121, 4, 2 * 32)
         sid = b.submit(_ids(9, 2), 100, rng_seed=1)
         seen = []
         for _ in range(100):  # 99 launches, and the read of the last one
@@ -440,10 +440,10 @@ def test_window_layers_hold_a_ring_however_long_the_stream_grows(
     cfg = CFG
     table, lengths = pool.device_tables()
     jaxpr = jax.make_jaxpr(lambda *a: hybrid.paged_decode_step_hybrid(
-        cfg, params, a[0], a[1], None, jnp.zeros((8, 8), jnp.int32),
+        cfg, params, a[0], None, jnp.zeros((8, 8), jnp.int32),
         table, lengths, jnp.zeros((3,), jnp.int32),
-        window=(a[2], a[3], pool.device_window_table())))(
-            pool.pool.k, pool.pool.v, pool.window_pool.k, pool.window_pool.v)
+        window=(a[1], pool.device_window_table())))(
+            pool.pool.kv, pool.window_pool.kv)
     spans = set()
     for eqn in jaxpr.jaxpr.eqns:
         if eqn.primitive.name == "gather" and \

@@ -162,12 +162,14 @@ def test_the_preset_holds_the_published_numbers():
 ])
 def test_the_pools_row_width_is_asked_of_the_config(cfg, lanes):
     """``kv_row_lanes`` is the one place: a latent row is ``[c | k_rope]``
-    padded to whole 128-lane tiles, every other family's ``KV x hd``."""
+    padded to whole 128-lane tiles, every other family's ``KV x hd``: of K,
+    and as many of V behind them in the same row. Either pool is ONE leaf."""
     assert cfg.kv_row_lanes == lanes
     pool = jax.eval_shape(lambda: paged_kv.init_pool(cfg, 9, 4))
-    assert all(a.shape == (cfg.kv_layers, 9, 4, lanes) for a in pool)
+    (leaf,) = pool
+    assert leaf.shape == (cfg.kv_layers, 9, 4,
+                          lanes * (1 if cfg.latent_layers else 2))
     assert isinstance(pool, LatentPool) == bool(cfg.latent_layers)
-    assert len(pool) == (1 if cfg.latent_layers else 2)
 
 
 def test_a_latent_row_is_a_twentieth_of_per_head_rows():
@@ -321,18 +323,18 @@ class LogitTap:
         def step(params, rows, cnt, table, lengths, toks, key_data, steps,
                  temps):
             with jax.default_matmul_precision("highest"):
-                logits, rows, _, _, cnt = hybrid.paged_decode_step_hybrid(
-                    cfg, params, rows, None, None, cnt, table, lengths, toks)
+                logits, rows, _, cnt = hybrid.paged_decode_step_hybrid(
+                    cfg, params, rows, None, cnt, table, lengths, toks)
             return (logits, batching._batched_sample(logits, key_data, steps,
                                                      temps), rows, cnt)
 
-        def tapped(cfg_, params, rows, v, state, cnt, table, lengths,
+        def tapped(cfg_, params, rows, state, cnt, table, lengths,
                    toks, key_data, steps, temps, compute_dtype):
-            assert v is None and state is None
+            assert state is None
             logits, toks, rows, cnt = step(params, rows, cnt, table, lengths,
                                            toks, key_data, steps, temps)
             self.rows.append((np.array(lengths), np.array(logits)))
-            return toks, rows, None, None, cnt
+            return toks, rows, None, cnt
 
         tapped._cache_size = lambda: 0
         monkeypatch.setattr(batching, "_batched_hybrid_step_jit", tapped)
@@ -391,7 +393,7 @@ def test_the_step_never_holds_a_per_head_key_or_value_of_the_span(params):
     table, lengths = b.pool.device_tables()
     slots, span, heads = 3, 160, CFG.num_heads
     jaxpr = jax.make_jaxpr(lambda rows: hybrid.paged_decode_step_hybrid(
-        CFG, params, rows, None, None, jnp.zeros((3, 8), jnp.int32),
+        CFG, params, rows, None, jnp.zeros((3, 8), jnp.int32),
         table, lengths, jnp.zeros((3,), jnp.int32)))(b.pool.pool.rows)
     gathers, shapes = set(), set()
 

@@ -20,6 +20,8 @@ from jax.experimental.pallas import tpu as pltpu
 from edgellm_tpu.models import flash_attention, hybrid, paged_kv
 from edgellm_tpu.models import tiny_config
 from edgellm_tpu.models.configs import (tiny_afmoe_config,
+                                        tiny_hybrid_config,
+                                        tiny_lfm2_moe_config,
                                         tiny_longcat_flash_config,
                                         tiny_mellum_config,
                                         tiny_mistral4_config)
@@ -28,7 +30,8 @@ from edgellm_tpu.models.transformer import init_params
 PAGE, PAGES_PER_SLOT, LAYERS, LAYER = 16, 8, 3, 1
 SPAN = PAGE * PAGES_PER_SLOT
 
-#: (row width W = KV * hd, query heads, KV heads): the cells' three shapes
+#: (K lanes W = KV * hd of a row of 2 W, query heads, KV heads): the cells'
+#: three shapes
 GEOMETRIES = {"w128-h14-kv2": (128, 14, 2), "w256-h12-kv2": (256, 12, 2),
               "w512-h32-kv4": (512, 32, 4)}
 
@@ -68,7 +71,8 @@ def _pool_and_table(width, lengths, scrambled, poisoned, dtype, seed):
     if poisoned:
         k[:, ~held] = np.nan
         v[:, ~held] = np.nan
-    pool = paged_kv.PagePool(jnp.asarray(k, dtype), jnp.asarray(v, dtype))
+    pool = paged_kv.PagePool(paged_kv.join_kv(jnp.asarray(k, dtype),
+                                              jnp.asarray(v, dtype)))
     return pool, jnp.asarray(table), held
 
 
@@ -86,9 +90,9 @@ def _interpreted(*args, **kwargs):
 def _walk(q, pool, table, lengths, pages_per_block=None):
     """``paged_kv.attend_pages`` with the kernel interpreted."""
     hd = q.shape[-1]
-    own, qz = paged_kv._group_lanes(q, pool.k.shape[-1] // hd)
+    own, qz = paged_kv._group_lanes(q, pool.k_lanes // hd)
     out = _interpreted(
-        qz, paged_kv._pages(pool.k, 1), paged_kv._pages(pool.v, 1),
+        qz, paged_kv._pages(pool.kv, 1),
         LAYER * pool.num_pages + table, lengths, scale=float(hd ** -0.5),
         pages_per_block=pages_per_block)
     return paged_kv._own_lanes(out, own)
@@ -142,23 +146,50 @@ def test_walk_equals_gather_bfloat16_default_block(geometry):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
 
 
-def test_one_leaf_as_keys_and_values():
-    """``v_pages`` None (a latent row is both key and value): one leaf, one
-    buffer, the weighted sum of the rows the scores were taken over."""
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_float32_query_over_a_bfloat16_pool(geometry):
+    """The split runtime's late stages: a float32 query meets bf16 rows in
+    float32, in the kernel as in ``attend_rows``' einsums, so the two agree
+    to the order of the float32 sums; a scrambled, NaN-poisoned pool, blocks
+    of 2 pages and of the rule's size."""
+    width, heads, kv = GEOMETRIES[geometry]
+    lengths = (SPAN, 1, 37, 16, 90)
+    pool, tab, held = _pool_and_table(width, lengths, True, True,
+                                      jnp.bfloat16, seed=9)
+    q = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (len(lengths), 1, heads, width // kv)), jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    want = _gather(q, pool, tab, lens, jnp.asarray(held))
+    for ppb in (2, None):
+        got = _walk(q, pool, tab, lens, pages_per_block=ppb)
+        assert got.dtype == want.dtype == jnp.float32
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_a_row_as_wide_as_the_query_is_keys_and_values():
+    """A row of the query's width (a latent row is both key and value): the
+    weighted sum of the rows the scores were taken over, where a row twice
+    as wide gives its second half."""
     pool, tab, held = _pool_and_table(128, (40, 1, 16), True, True,
                                       jnp.float32, seed=3)
     lens = jnp.asarray((40, 1, 16), jnp.int32)
     qz = jnp.asarray(np.random.default_rng(4).standard_normal((3, 5, 128)),
                      jnp.float32)
-    pages = paged_kv._pages(pool.k, 1)
-    got = _interpreted(qz, pages, None, LAYER * pool.num_pages + tab, lens,
+    latent = pool.kv[..., :128]
+    pages = paged_kv._pages(latent, 1)
+    got = _interpreted(qz, pages, LAYER * pool.num_pages + tab, lens,
                        scale=0.125, pages_per_block=2)
     rows = paged_kv._gather_pages(
-        jnp.where(jnp.asarray(held)[None, :, None, None], pool.k, 0), LAYER,
+        jnp.where(jnp.asarray(held)[None, :, None, None], latent, 0), LAYER,
         tab)
     want = paged_kv.attend_latent(qz, rows, lens, 64)   # 64 ** -0.5 = 0.125
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="neither a query's 128"):
+        _KERNEL(qz, paged_kv._pages(pool.kv[..., :192], 1), tab, lens,
+                scale=0.125)
 
 
 def test_read_path_is_read_off_the_pool(monkeypatch):
@@ -169,31 +200,36 @@ def test_read_path_is_read_off_the_pool(monkeypatch):
     cfg = tiny_config("qwen2", num_layers=2, hidden_size=256, num_heads=4,
                       vocab_size=64)                     # KV 2 x hd 64
     fp = paged_kv.init_pool(cfg, 9, 16, jnp.bfloat16)
+    assert len(fp) == 1 and fp.kv.shape == (2, 9, 16, 2 * 128)
+    k_lanes = fp.kv[..., :128]
     assert paged_kv.decode_read_path(
-        paged_kv.LatentPool(fp.k)) == paged_kv.PAGE_GATHER      # on a cpu
+        paged_kv.LatentPool(k_lanes)) == paged_kv.PAGE_GATHER      # on a cpu
     quant = paged_kv.init_quant_pool(cfg, 9, 16, "int8_per_channel")
-    narrow = paged_kv.PagePool(fp.k[..., :64], fp.v[..., :64])
-    short = paged_kv.PagePool(fp.k[:, :, :8], fp.v[:, :, :8])
+    # a row of 64 K lanes and 64 V lanes is one whole lane tile, of which
+    # the kernel would slice half: whole tiles are asked of W, not of 2 W
+    narrow = paged_kv.PagePool(k_lanes)
+    short = paged_kv.PagePool(fp.kv[:, :, :8])
     # a window group's pools: 3 slots' rings of 4 pages and the trash page
     sliding = tiny_mellum_config(sliding_window=40, head_dim=64)
     rings = paged_kv.init_pool(sliding, 3 * 4 + 1, 16, jnp.bfloat16,
                                layers=sliding.window_layers)
     quant_rings = paged_kv.init_quant_pool(sliding, 3 * 4 + 1, 16,
                                            "int8_per_channel")
-    part_rings = paged_kv.PagePool(rings.k[..., :64], rings.v[..., :64])
-    assert rings.k.shape == (6, 13, 16, 128)
+    part_rings = paged_kv.PagePool(rings.kv[..., :128])
+    assert rings.kv.shape == (6, 13, 16, 2 * 128)
     assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_GATHER  # on a cpu
     assert paged_kv.decode_read_path(rings) == paged_kv.PAGE_GATHER
     monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
     assert paged_kv.decode_read_path(fp) == paged_kv.PAGE_WALK
     assert paged_kv.decode_read_path(
-        paged_kv.PagePool(fp.k[None], fp.v[None])) == paged_kv.PAGE_WALK
-    # a latent pool's one leaf is asked what K is asked
+        paged_kv.PagePool(fp.kv[None])) == paged_kv.PAGE_WALK
+    # a latent pool's one leaf is asked what a row's K lanes are asked
     assert paged_kv.decode_read_path(
-        paged_kv.LatentPool(fp.k)) == paged_kv.PAGE_WALK
+        paged_kv.LatentPool(k_lanes)) == paged_kv.PAGE_WALK
     assert paged_kv.decode_read_path(rings) == paged_kv.PAGE_WALK
     for pool in (quant, narrow, short, quant_rings, part_rings,
-                 paged_kv.LatentPool(narrow.k), paged_kv.LatentPool(short.k)):
+                 paged_kv.LatentPool(k_lanes[..., :64]),
+                 paged_kv.LatentPool(short.kv)):
         assert paged_kv.decode_read_path(pool) == paged_kv.PAGE_GATHER
 
 
@@ -226,8 +262,64 @@ def test_paged_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
     got, got_pool = jax.block_until_ready(step())
     tol = 1e-5 if compute == "float32" else 3e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
-    np.testing.assert_allclose(np.asarray(got_pool.k),
-                               np.asarray(want_pool.k), atol=tol)
+    np.testing.assert_allclose(np.asarray(got_pool.kv),
+                               np.asarray(want_pool.kv), atol=tol)
+
+
+#: the toy stacks that keep recurrent state beside ONE K/V layer of one
+#: whole lane tile of K lanes (2 KV heads of 64): the granite and lfm2 cells'
+STATE_STACKS = {
+    "granitemoehybrid": lambda: tiny_hybrid_config(hidden_size=256),
+    "lfm2_moe": lambda: tiny_lfm2_moe_config(hidden_size=256),
+}
+
+
+@pytest.mark.parametrize("family", STATE_STACKS)
+def test_hybrid_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
+                                                               family):
+    """``paged_decode_step_hybrid`` of a stack with recurrent state, its K/V
+    pool's one leaf handed over as a latent stack's is: the step built on the
+    walk (the kernel interpreted) against the step on the gather, logits,
+    the written pool and the state. Slot 1 is idle, slot 2 writes the first
+    row of a new page; every page no table names holds NaN under the walk."""
+    cfg = STATE_STACKS[family]()
+    assert cfg.kv_row_lanes == 128 and cfg.kv_layers == 1
+    params = init_params(cfg, jax.random.key(0))
+    page = 8
+    table = np.asarray([[1, 2, 3, 0], [0, 0, 0, 0], [7, 5, 0, 0]], np.int32)
+    lens = np.asarray([2 * page + 4, 0, page], np.int32)
+    rng = np.random.default_rng(13)
+    pool = paged_kv.init_pool(cfg, 9, page)
+    assert pool.kv.shape == (1, 9, page, 256)
+    clean = jnp.asarray(rng.standard_normal(pool.kv.shape), jnp.float32)
+    state = {leaf: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+             for leaf, a in paged_kv.init_slot_state(cfg, 3).items()}
+    held = np.zeros((9,), bool)
+    held[np.unique(table)] = True
+    dead = jnp.asarray(~held)[None, :, None, None]
+    step = functools.partial(
+        hybrid.paged_decode_step_hybrid, cfg, params, state=state,
+        expert_tokens=jnp.zeros((cfg.expert_layers, cfg.local_experts),
+                                jnp.int32),
+        page_table=jnp.asarray(table), lengths=jnp.asarray(lens),
+        token_ids=jnp.asarray([3, 0, 5], jnp.int32))
+    with jax.default_matmul_precision("highest"):
+        want, want_kv, want_state, want_cnt = step(pool=clean)
+        monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+        assert paged_kv.decode_read_path(pool) == paged_kv.PAGE_WALK
+        monkeypatch.setattr(flash_attention, "paged_decode_walk",
+                            _interpreted)
+        got, got_kv, got_state, got_cnt = jax.block_until_ready(
+            step(pool=jnp.where(dead, jnp.nan, clean)))
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got_cnt), np.asarray(want_cnt))
+    np.testing.assert_allclose(np.asarray(jnp.where(dead, 0, got_kv)),
+                               np.asarray(jnp.where(dead, 0, want_kv)),
+                               atol=1e-5)
+    for leaf in want_state:
+        np.testing.assert_allclose(np.asarray(got_state[leaf]),
+                                   np.asarray(want_state[leaf]), atol=1e-5)
 
 
 #: name -> (rows a page, parameter / query dtype, pool dtype, logit
@@ -264,26 +356,26 @@ def test_latent_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
     held = np.zeros((13,), bool)
     held[np.unique(table)] = True               # the trash page among them
     step = functools.partial(
-        hybrid.paged_decode_step_hybrid, cfg, params, pool_v=None,
-        state=None,
+        hybrid.paged_decode_step_hybrid, cfg, params, state=None,
         expert_tokens=jnp.zeros((2, cfg.local_experts), jnp.int32),
         page_table=jnp.asarray(table), lengths=jnp.asarray(lens),
         token_ids=jnp.asarray([3, 0, 5, 7], jnp.int32))
-    want, want_rows, *_ = step(pool_k=clean)
+    want, want_rows, *_ = step(pool=clean)
     monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
     assert paged_kv.decode_read_path(
         paged_kv.LatentPool(clean)) == paged_kv.PAGE_WALK
     walks = []
 
-    def walk(qz, k_pages, v_pages, *args, **kwargs):
-        walks.append(v_pages)
-        return _interpreted(qz, k_pages, v_pages, *args, **kwargs)
+    def walk(qz, pages, *args, **kwargs):
+        walks.append((qz.shape[-1], pages.shape[-1]))
+        return _interpreted(qz, pages, *args, **kwargs)
 
     monkeypatch.setattr(flash_attention, "paged_decode_walk", walk)
     dead = jnp.asarray(~held)[None, :, None, None]
     got, got_rows, *_ = jax.block_until_ready(step(
-        pool_k=jnp.where(dead, jnp.nan, clean) if poisoned else clean))
-    assert walks == [None, None]        # a layer a walk, the one leaf each
+        pool=jnp.where(dead, jnp.nan, clean) if poisoned else clean))
+    # a layer a walk, the row key and value both (as wide as the query)
+    assert walks == [(128, 128)] * 2
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
     np.testing.assert_allclose(
@@ -332,9 +424,9 @@ def test_a_640_lane_step_of_two_sublayers_equals_the_contiguous_step(
     pool = paged_kv.LatentPool(jnp.asarray(rows, pool_dtype))
     assert paged_kv.decode_read_path(pool) == (
         paged_kv.PAGE_WALK if read == "page-walk" else paged_kv.PAGE_GATHER)
-    got, got_rows, _, _, counts = jax.block_until_ready(
+    got, got_rows, _, counts = jax.block_until_ready(
         hybrid.paged_decode_step_hybrid(
-            cfg, params, pool.rows, None, None,
+            cfg, params, pool.rows, None,
             jnp.zeros((1, cfg.counted_experts), jnp.int32),
             jnp.asarray(table), jnp.asarray(lens), jnp.asarray(toks)))
     assert int(counts.sum()) == 3 * cfg.experts_per_tok    # the live slots'
@@ -370,7 +462,8 @@ RING_LENGTHS = {
 }
 
 
-def _ring_pool(entries, window, lengths, idle, dtype, poisoned, seed):
+def _ring_pool(entries, window, lengths, idle, dtype, poisoned, seed,
+               width=128):
     """A window group's pool (slot i's ring the pages ``1 + i*E ..``, as
     ``PagedKVCache._ring_of`` hands them out; an idle slot's row the trash
     page), its table, and which ring rows each slot attends by the oracle's
@@ -381,7 +474,7 @@ def _ring_pool(entries, window, lengths, idle, dtype, poisoned, seed):
     rng = np.random.default_rng(seed)
     slots = len(lengths)
     pages = slots * entries + 1
-    k, v = (rng.standard_normal((LAYERS, pages, PAGE, 128))
+    k, v = (rng.standard_normal((LAYERS, pages, PAGE, width))
             .astype(np.float32) for _ in range(2))
     table = np.zeros((slots, entries), np.int32)
     for i in range(slots):
@@ -396,16 +489,17 @@ def _ring_pool(entries, window, lengths, idle, dtype, poisoned, seed):
     if poisoned:
         k[:, ~keep] = np.nan
         v[:, ~keep] = np.inf
-    pool = paged_kv.PagePool(jnp.asarray(k, dtype), jnp.asarray(v, dtype))
+    pool = paged_kv.PagePool(paged_kv.join_kv(jnp.asarray(k, dtype),
+                                              jnp.asarray(v, dtype)))
     return pool, jnp.asarray(table), lens, jnp.asarray(valid), keep
 
 
 def _ring_walk(q, pool, table, lens, window, pages_per_block):
     """``paged_kv.attend_pages`` over a ring with the kernel interpreted."""
     hd = q.shape[-1]
-    own, qz = paged_kv._group_lanes(q, pool.k.shape[-1] // hd)
+    own, qz = paged_kv._group_lanes(q, pool.k_lanes // hd)
     out = _interpreted(
-        qz, paged_kv._pages(pool.k, 1), paged_kv._pages(pool.v, 1),
+        qz, paged_kv._pages(pool.kv, 1),
         LAYER * pool.num_pages + table, lens, scale=float(hd ** -0.5),
         pages_per_block=pages_per_block, window=window)
     return paged_kv._own_lanes(out, own)
@@ -460,6 +554,30 @@ def test_ring_walk_reads_nothing_outside_a_window(ring, length):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("state", ["turned", "not-turned"])
+@pytest.mark.parametrize("entries,window", [(129, 2048), (65, 1024)])
+def test_the_cells_rings_at_their_width(entries, window, state):
+    """The mellum / trinity cells' rings as they are: 129 and 65 entries of
+    16 bf16 rows of 512 K lanes (4 KV heads of 128) and 32 query heads, the
+    rule's own block (65 pages: two blocks, one), every row outside a
+    window NaN / inf. ``turned``: the named slot has lapped its ring and is
+    fetched whole; ``not-turned``: it is a prefix of its table."""
+    n = (2 * entries * PAGE + 5 if state == "turned"
+         else (entries // 2) * PAGE + 3)
+    lengths = (n, 1, window + 1)
+    pool, table, lens, valid, keep = _ring_pool(
+        entries, window, lengths, {1}, jnp.bfloat16, True, seed=entries,
+        width=512)
+    assert pool.kv.shape == (LAYERS, 3 * entries + 1, PAGE, 2 * 512)
+    q = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (len(lengths), 1, 32, 128)), jnp.bfloat16)
+    got = _ring_walk(q, pool, table, lens, window, None)
+    want = _ring_gather(q, pool, table, lens, valid, keep)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
 def test_ring_rule_cuts_the_ring_into_equal_blocks():
     """``ring_walk_pages_per_block``: no ring ends on a block of one page.
     The cells' rings at 512-lane bf16 rows; a toy ring is one block."""
@@ -472,36 +590,41 @@ def test_ring_rule_cuts_the_ring_into_equal_blocks():
     assert rule(4, 16, 128, 4) == 4
 
 
-#: sha256 of the jaxpr text of a ``window=0`` call (two leaves; one leaf) as
-#: the PARENT's kernel traced it (PR 38's tree, this jax): the six cells
-#: without a ring keep their kernel bodies. A jaxpr's text carries no source
-#: location. Another jax prints another text: then the test skips.
-PREFIX_WALK_JAXPR = {"0.9.0": (
-    "a789e5f71185c0f20e5d8dea056b22e717ace381891f3b3aaa481593c4c3b735",
-    "3b43dafb03bed5ec31b5c8538a4a9ef6ac4bd080d9dc026f4e7cf53175ea59ce")}
-
-
-def _walk_jaxpr(leaves, **kwargs):
+def _walk_jaxpr(row_lanes, **kwargs):
+    """The text of a walk's jaxpr over pages of ``row_lanes``-lane bf16 rows
+    and a 128-lane query: (all of it, the kernel's own arguments)."""
     shape = jax.ShapeDtypeStruct
-    q, pages = shape((3, 4, 128), jnp.bfloat16), shape((40, 16, 128),
-                                                       jnp.bfloat16)
-    return str(jax.make_jaxpr(
-        lambda q, k, v, ids, lens: flash_attention.paged_decode_walk(
-            q, k, v if leaves == 2 else None, ids, lens, scale=0.125,
-            **kwargs))(q, pages, pages, shape((3, 8), jnp.int32),
-                       shape((3,), jnp.int32)))
+    text = str(jax.make_jaxpr(
+        lambda q, pages, ids, lens: flash_attention.paged_decode_walk(
+            q, pages, ids, lens, scale=0.125, **kwargs))(
+                shape((3, 4, 128), jnp.bfloat16),
+                shape((40, 16, row_lanes), jnp.bfloat16),
+                shape((3, 8), jnp.int32), shape((3,), jnp.int32)))
+    kernel = text[text.index("pallas_call["):]
+    kernel = kernel[kernel.index("jaxpr={ lambda ;"):]
+    return text, kernel[:kernel.index(". let")]
 
 
-def test_a_prefix_walk_traces_what_the_parent_traced():
-    import hashlib
-
-    if jax.__version__ not in PREFIX_WALK_JAXPR:
-        pytest.skip(f"no recorded jaxpr text for jax {jax.__version__}")
-    got = tuple(hashlib.sha256(_walk_jaxpr(n).encode()).hexdigest()
-                for n in (2, 1))
-    assert got == PREFIX_WALK_JAXPR[jax.__version__]
-    # and a ring's is another: the mask by position is in the body
-    assert _walk_jaxpr(2, window=40) != _walk_jaxpr(2)
+@pytest.mark.parametrize("row_lanes,window", [(256, 0), (256, 40), (128, 0)],
+                         ids=["k-then-v", "k-then-v-ring", "latent"])
+def test_a_page_is_one_dma_whatever_its_row_holds(row_lanes, window):
+    """The kernel holds ONE operand in HBM, one pair of VMEM buffers a row
+    wide and one pair of DMA semaphores, and starts and waits for as many
+    DMAs where a row is K then V as where it is key and value both: a page
+    is one fetch (as two leaves it was two of each)."""
+    text, args = _walk_jaxpr(row_lanes, window=window)
+    ppb = (flash_attention.ring_walk_pages_per_block(8, 16, 128, 2) if window
+           else flash_attention.paged_walk_pages_per_block(16, 128, 2))
+    assert args.count("Ref<any>") == 1
+    assert f"Ref<any>{{bf16[40,16,{row_lanes}]}}" in args
+    assert args.count("Ref<vmem>") == 1
+    assert f"Ref<vmem>{{bf16[2,{ppb},16,{row_lanes}]}}" in args
+    assert args.count("dma_sem") == 1 and "dma_sem[2]" in args
+    latent, _ = _walk_jaxpr(128, window=window)
+    assert text.count("dma_start") == latent.count("dma_start") > 0
+    assert text.count("dma_wait") == latent.count("dma_wait") > 0
+    # a ring's body is another: the mask by position is in it
+    assert (text == _walk_jaxpr(row_lanes)[0]) == (window == 0)
 
 
 #: the toy stacks whose window layers keep rings, at rows of ONE lane tile (2
