@@ -181,6 +181,14 @@ def launch_ahead_share(report: dict) -> float:
     return 100.0 * report["steps_ahead"] / max(report["steps"], 1)
 
 
+def attend_run_share(report: dict) -> float:
+    """Percent of the pages a batcher's page walks fetched that went as part
+    of a run of adjacent pages, one DMA a run (``report()``'s
+    ``attend_pages_in_runs`` over ``attend_pages_walked``)."""
+    return (100.0 * report["attend_pages_in_runs"]
+            / max(report["attend_pages_walked"], 1))
+
+
 class _old_order:
     """While entered, every ``ContinuousBatcher.step()`` reads its own step
     before it returns (launch, sync, commit: the order before the batcher ran
@@ -236,6 +244,7 @@ def serve_phase(name: str, params: dict, vocab_size: int, *,
             "outcomes": rep["outcomes"], "jit_misses": bat["jit_misses"],
             "batched_steps": bat["steps"], "evicted": bat["evicted"],
             "launch_ahead_share": launch_ahead_share(bat),
+            "attend_run_share": attend_run_share(bat),
             "tokens_equal_old_order": True,
             "old_order_steady_s": old["drain_s"],
             # first call of every executable, compiles included
@@ -465,6 +474,11 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
     # the eviction's drain and the launch after it are the old order; every
     # other launch found the step before it unread
     report["launch_ahead_share"] = launch_ahead_share(report)
+    report["attend_run_share"] = attend_run_share(report)
+    print(f"[chip_smoke] {cfg.family}: attend_run_share "
+          f"{report['attend_run_share']:.1f}% of "
+          f"{report['attend_pages_walked']} walked pages in "
+          f"{report['attend_dmas']} DMAs", flush=True)
     report["served"] = got.tolist()
     assert report["steps_ahead"] >= report["steps"] - 3, report
     return report, float(gaps.max() / scale)
@@ -554,7 +568,7 @@ def _ring_stream(cfg, prompt_len: int, n_new: int, evict_after: int) -> tuple:
                     "decode_read": report["decode_read"],
                     "attend_fetches_per_page":
                         report["attend_fetches_per_page"],
-            "attend_fetches_per_page": report["attend_fetches_per_page"],
+                    "attend_run_share": report["attend_run_share"],
                     "window_read": report["window_read"],
                     "window_pages_walked": report["window_pages_walked"],
                     "window_pages_spanned": report["window_pages_spanned"],
@@ -621,6 +635,7 @@ def latent_phase(*, prompt_len: int = 300, n_new: int = 40,
     return {"tokens": int(n_new), "evicted": report["evicted"],
             "decode_read": report["decode_read"],
             "attend_fetches_per_page": report["attend_fetches_per_page"],
+            "attend_run_share": report["attend_run_share"],
             "attend_pages_walked": report["attend_pages_walked"],
             "attend_pages_spanned": report["attend_pages_spanned"],
             "kv_row_bytes": report["kv_row_bytes"],
@@ -668,6 +683,7 @@ def longcat_phase(*, prompt_len: int = 300, n_new: int = 40,
     return {"tokens": int(n_new), "evicted": report["evicted"],
             "decode_read": report["decode_read"],
             "attend_fetches_per_page": report["attend_fetches_per_page"],
+            "attend_run_share": report["attend_run_share"],
             "attend_pages_walked": report["attend_pages_walked"],
             "attend_pages_spanned": report["attend_pages_spanned"],
             "kv_row_bytes": report["kv_row_bytes"],
@@ -735,6 +751,7 @@ def shortconv_phase(*, prompt_len: int = 300, n_new: int = 40,
             "state_leaf_bytes": report["state_leaf_bytes"],
             "decode_read": report["decode_read"],
             "attend_fetches_per_page": report["attend_fetches_per_page"],
+            "attend_run_share": report["attend_run_share"],
             "grouped_product": report["grouped_product"],
             "routed_local": report["routed_local"],
             "launch_ahead_share": report["launch_ahead_share"],

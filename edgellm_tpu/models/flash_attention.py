@@ -71,6 +71,7 @@ eager model instance just to get attention maps,
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 import jax
@@ -613,8 +614,65 @@ def ring_walk_pages_per_block(entries: int, page_size: int, width: int,
     return -(-entries // max((entries + ppb) // (2 * ppb), 1))
 
 
+#: A page of this many bytes is a fetch by itself: its one DMA moves at
+#: 83-87% of a v5e's HBM peak, and a run of two such pages was timed 2.5%
+#: BEHIND. Smaller pages go as many together as make ``WALK_RUN_BYTES``, at
+#: most ``WALK_RUN_MAX_PAGES``: at 8 KB a page moves at 27% of the peak
+#: alone, 38% in runs of four and 41% in runs of eight; at 16 KB 49 / 55 /
+#: 60% alone, in twos and in fours (PERF.md §6 "PR 45", "PR 46").
+WALK_PAGE_ALONE_BYTES = 32 * 1024
+WALK_RUN_BYTES = 64 * 1024
+WALK_RUN_MAX_PAGES = 8
+
+
+def walk_run_pages(page_bytes: int, pages_per_slot: int) -> int:
+    """Pages of a RUN: what ``PagedKVCache`` hands out and takes back
+    adjacent, and what :func:`paged_decode_walk` fetches with one DMA where
+    the groups that lead a block of a slot's table name adjacent pages. Read
+    off the bytes of ONE page of one layer, by nobody's choice: 1 for a page
+    of ``WALK_PAGE_ALONE_BYTES`` or more (the allocator and the kernel then
+    do what they did before runs); else the smallest power of two that makes
+    a fetch of ``WALK_RUN_BYTES``, at most ``WALK_RUN_MAX_PAGES`` and no more
+    than a slot's table holds: 8 and 12 KB pages go eight together, 16 and
+    20 KB ones four."""
+    run = 1
+    while (page_bytes < WALK_PAGE_ALONE_BYTES
+           and run * page_bytes < WALK_RUN_BYTES
+           and run < WALK_RUN_MAX_PAGES and 2 * run <= pages_per_slot):
+        run *= 2
+    return run
+
+
+def page_runs(page_ids, run: int):
+    """(B, E // run) bool: which table-aligned groups of ``run`` entries of
+    ``page_ids`` (B, E) name adjacent pages, ``ids[j + i] == ids[j] + i``
+    (numpy or jax arrays in, the same out); a group of trash-page entries is
+    no run."""
+    b, e = page_ids.shape
+    grp = page_ids[:, :e // run * run].reshape(b, e // run, run)
+    return (grp[..., 1:] == grp[..., :-1] + 1).all(-1)
+
+
+def leading_runs(page_ids, run: int, pages_per_block: int):
+    """(B, blocks) int32: of each block of ``pages_per_block`` entries of
+    ``page_ids`` (B, E), how many of its groups of ``run`` entries, from the
+    first on, are runs (:func:`page_runs`). What the kernel is handed behind
+    its lengths, and what the host counts the pages that went in runs by: of
+    a block, ``min(this, live pages // run)`` groups go a DMA each."""
+    runs = page_runs(page_ids, run)
+    b, groups = runs.shape
+    per = pages_per_block // run
+    # a block a ``pages_per_block`` entries, the last one short or not
+    pad = -(-page_ids.shape[1] // pages_per_block) * per - groups
+    if pad:
+        runs = (jnp if isinstance(runs, jax.Array) else np).concatenate(
+            [runs, np.zeros((b, pad), bool)], axis=1)
+    blocks = runs.reshape(b, -1, per).astype(np.int32)
+    return blocks.cumprod(-1).sum(-1).astype(np.int32)
+
+
 def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
-                       par_ref, *, scale, window=0):
+                       par_ref, *, scale, window=0, run=1):
     """Grid (B,): slot ``b`` of the step, every head. See
     :func:`paged_decode_walk`."""
     b = pl.program_id(0)
@@ -639,23 +697,45 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
     def start(slot, blk, buf):
         """A DMA a live page. What bounds a saturated step is how fast these
         are ISSUED (PERF.md §6 "PR 33", "PR 45"), so a page is one DMA, K and
-        V lanes together, and the loop is unrolled by two."""
+        V lanes together, and the loop is unrolled by two. Where pages go in
+        runs (``run`` > 1), the block's LEADING groups of ``run`` live entries
+        that name adjacent pages are ONE DMA each: adjacent in HBM, they land
+        adjacent in the buffer, so the bytes in VMEM are the same. How many
+        lead is read off a table, not tested a group: a conditional a group
+        costs the scalar core more than the DMAs it saves (PERF.md §6 "PR
+        46"). The pages after them go one by one."""
         cnt = num_pages(slot, blk)
 
         def page(j):
             pltpu.make_async_copy(hbm.at[ids_ref[slot, blk * ppb + j]],
                                   rbuf.at[buf, j], sem.at[buf]).start()
 
-        def pair(g, _):
-            page(2 * g)
-            page(2 * g + 1)
+        def pages(n, at=lambda j: j):       # n of them, from position at(0)
+            def pair(g, _):
+                page(at(2 * g))
+                page(at(2 * g + 1))
+                return 0
+
+            jax.lax.fori_loop(0, n // 2, pair, 0)
+
+            @pl.when(n % 2 == 1)
+            def _odd():
+                page(at(n - 1))
+
+        if run == 1:
+            return pages(cnt)
+        # (the table of leading runs lies behind the lengths, a row a slot)
+        lead = jnp.minimum(
+            len_ref[nslots + slot * -(-entries // ppb) + blk], cnt // run)
+
+        def group(g, _):
+            pltpu.make_async_copy(
+                hbm.at[pl.ds(ids_ref[slot, blk * ppb + g * run], run)],
+                rbuf.at[buf, pl.ds(g * run, run)], sem.at[buf]).start()
             return 0
 
-        jax.lax.fori_loop(0, cnt // 2, pair, 0)
-
-        @pl.when(cnt % 2 == 1)
-        def _odd():
-            page(cnt - 1)
+        jax.lax.fori_loop(0, lead, group, 0)
+        pages(cnt - lead * run, lambda j: lead * run + j)
 
     def wait(slot, blk, buf):
         """A DMA semaphore counts bytes, so the block's pages are waited for
@@ -751,10 +831,11 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "pages_per_block", "interpret", "window"))
+    "scale", "pages_per_block", "interpret", "window", "run_pages"))
 def paged_decode_walk(qz, pages, page_ids, lengths, *,
                       scale: float, pages_per_block: int | None = None,
-                      interpret=False, window: int = 0):
+                      interpret=False, window: int = 0,
+                      run_pages: int | None = None, lead=None):
     """Single-position attention of every slot over ITS OWN live pages, read
     out of the pool where they lie: ONE kernel in place of "gather every
     slot's whole span into a copy, then two dots over the copy".
@@ -792,8 +873,21 @@ def paged_decode_walk(qz, pages, page_ids, lengths, *,
     before, rows not written yet and rows no DMA filled are masked before
     the exponent and their V rows selected to zero, as above.
 
-    Scalar prefetch puts ``page_ids`` and ``lengths`` in SMEM before the
-    body runs."""
+    ``run_pages`` (static; None: :func:`walk_run_pages` of a page's bytes and
+    the table's width, cut to what divides a block): where it is more than 1,
+    the table-aligned groups of that many LIVE entries that LEAD a block and
+    name adjacent pages (:func:`leading_runs`, taken of ``page_ids`` here, on
+    the device) are fetched with ONE DMA each; the block's pages from its
+    first group that is no run, and the live pages past a slot's last whole
+    group, a DMA a page. The bytes that reach the buffer, and so the output,
+    are the same bit for bit. At 1 the body is the one above. ``lead``
+    (None: made here, of ``page_ids``): that table, where the caller has
+    made it already, of the page TABLE its ids come from: adjacency does not
+    change with a layer's offset, so a step's layers can share one
+    (``paged_kv.attend_pages``).
+
+    Scalar prefetch puts ``page_ids`` and ``lengths`` (with, behind them,
+    the table of each block's leading runs) in SMEM before the body runs."""
     b, h, w = qz.shape
     _, ps, r = pages.shape
     if r not in (w, 2 * w):
@@ -803,9 +897,17 @@ def paged_decode_walk(qz, pages, page_ids, lengths, *,
     ppb = pages_per_block or (
         ring_walk_pages_per_block(page_ids.shape[1], ps, w, itemsize)
         if window else paged_walk_pages_per_block(ps, w, itemsize))
+    run = math.gcd(ppb, walk_run_pages(ps * r * itemsize, page_ids.shape[1])
+                   if run_pages is None else run_pages)
+    if run > 1:
+        # behind the lengths, in the one array: a third table in SMEM costs
+        # every launch 2 us, a fifth of an idle batch's layer
+        lead = leading_runs(page_ids, run, ppb) if lead is None else lead
+        lengths = jnp.concatenate([lengths, lead.reshape(-1)])
     slot = pl.BlockSpec((1, h, w), lambda i, ids, lens: (i, 0, 0))
     return pl.pallas_call(
-        functools.partial(_paged_walk_kernel, scale=scale, window=window),
+        functools.partial(_paged_walk_kernel, scale=scale, window=window,
+                          run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
             in_specs=[slot, pl.BlockSpec(memory_space=pl.ANY)],

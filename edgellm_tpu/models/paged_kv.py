@@ -87,6 +87,7 @@ merge: adopts, gathers, checkpoints and migration hand over (S, KV, hd) rows.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Iterator, NamedTuple, Optional
 
@@ -581,6 +582,16 @@ def num_pages_for_bytes(cfg: ModelConfig, pool_bytes: int, page_size: int,
     return pages
 
 
+def page_leaf_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
+                    dtype=jnp.float32) -> int:
+    """HBM bytes of ONE page of one layer (a position's K and V rows, or its
+    latent row, ``page_size`` times): what a DMA of the page walk moves, and
+    what the length of a run is read off
+    (``flash_attention.walk_run_pages``)."""
+    layers = cfg.kv_layers if cfg.latent_layers else cfg.num_layers
+    return kv_page_bytes(cfg, page_size, kv_codec, dtype) // layers
+
+
 # ---------------------------------------------------------------------------
 # Pool surgery: adopt a contiguous prefix, gather one back, copy pages for a
 # COW fork, permute them for defrag. Each is written once over the pool's
@@ -850,6 +861,21 @@ class PagedKVCache:
     the traced int32 inputs of the compiled step. All mutating methods keep
     :meth:`check_invariants` true: no page owned twice, no page leaked, the
     trash page never allocated.
+
+    Pages go out and come back in RUNS of ``run_pages`` adjacent pages (as
+    many as make a fetch of 64 KB where a page is under 32 KB, else one:
+    ``flash_attention.walk_run_pages``), each
+    placed at a table-aligned position, so that the page walk can take a
+    group of a slot's entries with one DMA: a prompt's pages are runs by
+    construction, a slot that grows into a new group takes a whole free run
+    and holds the rest AHEAD (free pages to everyone who asks: counted by
+    :attr:`num_free_pages`, taken back before :class:`OutOfPages`), and a run
+    whose pages have all come back is whole again. A page stays the unit of
+    sharing, forking and eviction; a shared or forked page that breaks a run
+    costs that group its run (and the groups after it in its block of the
+    walk theirs), which the kernel sees in the table. Where
+    a page is a fetch by itself a run is one page, and this is the LIFO
+    stack of single pages it was before runs.
     """
 
     def __init__(self, cfg: ModelConfig, *, num_pages: int, page_size: int,
@@ -920,8 +946,23 @@ class PagedKVCache:
         self.page_table = np.zeros((max_slots, pages_per_slot), np.int32)
         self.lengths = np.zeros((max_slots,), np.int32)
         self.active = np.zeros((max_slots,), bool)
-        # LIFO free list, low pages first out — deterministic layouts
-        self._free = list(range(num_pages - 1, 0, -1))
+        # pages are handed out and taken back in RUNS of ``run_pages``
+        # adjacent pages (run c: pages 1 + c*G .. c*G + G; page 0 stays the
+        # trash page), the unit the page walk fetches with one DMA; G is
+        # read off a page's bytes and is 1 where a page is a fetch by itself
+        self.run_pages = flash_attention.walk_run_pages(
+            page_leaf_bytes(cfg, page_size, self.kv_codec, dtype),
+            pages_per_slot)
+        # the free pages of each run; LIFO stacks of the runs that are WHOLE
+        # (low pages first out — deterministic layouts) and, in the order
+        # they broke, of those that are not; the pages a slot holds AHEAD of
+        # its last page, the rest of the run it grew into (next page last):
+        # nobody's yet, and free to whoever asks (num_free_pages counts them)
+        self._runs: list[list[int]] = []
+        self._whole: list[int] = []
+        self._broken: dict[int, None] = {}
+        self._ahead: dict[int, list[int]] = {}
+        self._reset_free(range(num_pages - 1, 0, -1))
         self._slot_pages: list[list[int]] = [[] for _ in range(max_slots)]
         # page -> exclusive slot, or SHARED (>1 holder / index-held), or FREE
         self._owner = np.full((num_pages,), FREE, np.int32)
@@ -953,7 +994,9 @@ class PagedKVCache:
 
     @property
     def num_free_pages(self) -> int:
-        return len(self._free)
+        """Pages an :meth:`ensure` can get without evicting anything: no
+        slot's and not the index's, those held ahead for a slot included."""
+        return self._n_free
 
     @property
     def token_capacity(self) -> int:
@@ -1026,7 +1069,9 @@ class PagedKVCache:
 
     def ensure(self, slot: int, new_length: int) -> None:
         """Grow ``slot``'s page list to cover ``new_length`` positions,
-        allocating pages from the free list. Under pool pressure, pages held
+        allocating pages from the free list: whole runs at the table's
+        group boundaries, the rest of the last one held ahead for the slot's
+        next growths. Under pool pressure, pages held
         ONLY by the prefix index (refcount would drop to 0) are reclaimed
         LRU-first before giving up. Raises :class:`OutOfPages` (allocating
         nothing) when the pool still cannot cover the growth."""
@@ -1035,27 +1080,103 @@ class PagedKVCache:
         if new_length > self.span:
             raise ValueError(f"length {new_length} exceeds slot span "
                              f"{self.span}")
-        need = self.pages_for(new_length) - len(self._slot_pages[slot])
+        pages = self._slot_pages[slot]
+        need = self.pages_for(new_length) - len(pages)
         if need <= 0:
             return
-        if need > len(self._free):
-            self._reclaim_index_pages(need - len(self._free))
-        if need > len(self._free):
+        if need > self._n_free:
+            self._reclaim_index_pages(need - self._n_free)
+        if need > self._n_free:
             raise OutOfPages(
-                f"slot {slot} needs {need} page(s), {len(self._free)} free")
+                f"slot {slot} needs {need} page(s), {self._n_free} free")
+        # a group of the table that starts here takes a whole run, and what
+        # the growth does not cover of it is held ahead for the next ones;
+        # where no run is whole, single pages of broken ones
+        held = self._ahead.pop(slot, [])
         for _ in range(need):
-            p = self._free.pop()
+            if held:
+                p = held.pop()
+            elif len(pages) % self.run_pages == 0 and self._whole:
+                c = self._whole.pop()
+                held, self._runs[c] = self._runs[c], []
+                held.sort(reverse=True)
+                p = held.pop()
+            else:
+                p = self._take()
             self._owner[p] = slot
             self._refcount[p] = 1
-            self.page_table[slot, len(self._slot_pages[slot])] = p
-            self._slot_pages[slot].append(p)
+            self.page_table[slot, len(pages)] = p
+            pages.append(p)
+        self._n_free -= need
+        if held:
+            self._ahead[slot] = held
+
+    # -- the free pages, by run ----------------------------------------------
+
+    def _reset_free(self, pages) -> None:
+        """Every page of ``pages`` free, in that order; nothing held ahead."""
+        self._runs = [[] for _ in range(
+            -(-(self.num_pages - 1) // self.run_pages))]
+        self._whole, self._broken, self._ahead = [], {}, {}
+        for p in pages:
+            self._put(int(p))
+        self._n_free = sum(map(len, self._runs))
+
+    def _put(self, p: int) -> None:
+        """Page ``p`` is free again; its run is whole again where it was the
+        last one out (a count a run: nothing is sorted or searched)."""
+        c = (p - 1) // self.run_pages
+        run = self._runs[c]
+        run.append(p)
+        if len(run) == self.run_pages:
+            self._broken.pop(c, None)
+            self._whole.append(c)
+        elif len(run) == 1:
+            self._broken[c] = None
+
+    def _take(self) -> int:
+        """ONE free page: of the run that broke last, else off a whole run
+        (its lowest), else the farthest page another slot holds ahead. The
+        caller has seen that ``_n_free`` allows it, and counts it."""
+        if self._broken:
+            c = next(reversed(self._broken))
+            run = self._runs[c]
+            p = run.pop()
+            if not run:
+                del self._broken[c]
+            return p
+        if self._whole:
+            c = self._whole.pop()
+            run = self._runs[c]
+            run.sort(reverse=True)
+            if len(run) > 1:
+                self._broken[c] = None
+            return run.pop()
+        slot = next(iter(self._ahead))
+        held = self._ahead[slot]
+        p = held.pop(0)
+        if not held:
+            del self._ahead[slot]
+        return p
+
+    def _drop_ahead(self, slot: int) -> None:
+        """What ``slot`` holds ahead goes back to its run."""
+        for p in self._ahead.pop(slot, ()):
+            self._put(p)
+
+    def _free_pages(self) -> list:
+        """The free pages no slot holds ahead, in an order that
+        :meth:`_reset_free` turns back into the same runs and stacks: the
+        broken runs as they broke, then the whole ones as they stack."""
+        return [p for c in (*self._broken, *self._whole)
+                for p in self._runs[c]]
 
     def free_slot(self, slot: int) -> None:
         """Release a slot; each of its pages drops one reference and returns
         to the free list only at refcount 0 (reverse allocation order, so the
-        free list stays LIFO-deterministic). Shared pages survive for their
-        other holders. The page contents are left stale — masked attention
-        never reads past a slot's length."""
+        free list stays LIFO-deterministic), after what it held ahead.
+        Shared pages survive for their other holders. The page contents are
+        left stale — masked attention never reads past a slot's length."""
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
         if self._slot_holds[slot]:
@@ -1063,6 +1184,7 @@ class PagedKVCache:
                 f"slot {slot} is held for an in-flight migration "
                 f"({int(self._slot_holds[slot])} hold(s)); release the hold "
                 f"before freeing")
+        self._drop_ahead(slot)
         for p in reversed(self._slot_pages[slot]):
             self._release_ref(p)
         self._slot_pages[slot] = []
@@ -1103,7 +1225,8 @@ class PagedKVCache:
         self._refcount[p] -= 1
         if self._refcount[p] == 0:
             self._owner[p] = FREE
-            self._free.append(p)
+            self._put(p)
+            self._n_free += 1
         else:
             self._recompute_owner(p)
 
@@ -1316,12 +1439,16 @@ class PagedKVCache:
         old = self._slot_pages[slot][page_index]
         assert self._refcount[old] > 1, \
             f"fork_page on exclusively-owned page {old}"
-        if not self._free:
+        if not self._n_free:
             self._reclaim_index_pages(1)
-        if not self._free:
+        if not self._n_free:
             raise OutOfPages(
                 f"COW fork for slot {slot} needs a free page, 0 free")
-        new = self._free.pop()
+        if page_index == len(self._slot_pages[slot]) - 1:
+            # what is held ahead follows the page that is left behind
+            self._drop_ahead(slot)
+        new = self._take()
+        self._n_free -= 1
         self._refcount[new] = 1
         self._owner[new] = slot
         self._refcount[old] -= 1
@@ -1345,12 +1472,12 @@ class PagedKVCache:
                  if self._refcount[self._slot_pages[slot][j]] > 1]
         # all-or-nothing: a fork that fails MID-loop would leave earlier
         # forks' table rows pointing at pages whose device copy never ran
-        if len(forks) > len(self._free):
-            self._reclaim_index_pages(len(forks) - len(self._free))
-        if len(forks) > len(self._free):
+        if len(forks) > self._n_free:
+            self._reclaim_index_pages(len(forks) - self._n_free)
+        if len(forks) > self._n_free:
             raise OutOfPages(
                 f"slot {slot} needs {len(forks)} COW fork(s), "
-                f"{len(self._free)} page(s) free")
+                f"{self._n_free} page(s) free")
         return [self.fork_page(slot, j) for j in forks]
 
     def ensure_writable(self, slot: int, new_length: int) -> list:
@@ -1720,7 +1847,7 @@ class PagedKVCache:
         self._owner = self._owner[src].copy()
         self._refcount = self._refcount[src].copy()
         self._index_holds = self._index_holds[src].copy()
-        self._free = list(range(self.num_pages - 1, nxt - 1, -1))
+        self._reset_free(range(self.num_pages - 1, nxt - 1, -1))
         if moved:
             self.pool = _permute_impl(self.pool, jnp.asarray(src))
         return moved
@@ -1752,9 +1879,15 @@ class PagedKVCache:
         state.update({"page_table": self.page_table.copy(),
                       "lengths": self.lengths.copy(),
                       "active": self.active.copy(),
-                      "free": np.asarray(self._free, np.int32),
+                      "free": np.asarray(self._free_pages(), np.int32),
                       "refcount": self._refcount.copy(),
                       "index_holds": self._index_holds.copy()})
+        if self.run_pages > 1:
+            # a row a slot, the next page first, 0 where nothing is held
+            ahead = np.zeros((self.max_slots, self.run_pages - 1), np.int32)
+            for s, held in self._ahead.items():
+                ahead[s, :len(held)] = held[::-1]
+            state["ahead"] = ahead
         if self.prefix is not None:
             state["prefix_index"] = self.prefix.to_array()
         for leaf, a in (self.state or {}).items():
@@ -1804,7 +1937,10 @@ class PagedKVCache:
         self.page_table = np.asarray(state["page_table"], np.int32).copy()
         self.lengths = np.asarray(state["lengths"], np.int32).copy()
         self.active = np.asarray(state["active"], bool).copy()
-        self._free = [int(p) for p in state["free"]]
+        self._reset_free(state["free"])
+        for s, held in enumerate(state.get("ahead", ())):
+            if held[0]:
+                self._ahead[s] = [int(p) for p in held[held > 0][::-1]]
         self._slot_pages = [[] for _ in range(self.max_slots)]
         for s in range(self.max_slots):
             if not self.active[s]:
@@ -1832,7 +1968,8 @@ class PagedKVCache:
                 self._refcount[p] -= self._index_holds[p]
                 self._index_holds[p] = 0
                 if self._refcount[p] == 0:
-                    self._free.append(int(p))
+                    self._put(int(p))
+        self._n_free = self.num_pages - 1 - int(np.sum(self._refcount > 0))
         self._owner = np.full((self.num_pages,), FREE, np.int32)
         for p in range(1, self.num_pages):
             if self._refcount[p] > 0:
@@ -1891,7 +2028,8 @@ class PagedKVCache:
             if self.pool is not None:
                 assert self.pool[0].shape[0] == self.cfg.kv_layers, \
                     "the page pool holds the attention layers only"
-        assert 0 not in self._free, "trash page 0 on the free list"
+        free = self._free_pages()
+        assert 0 not in free, "trash page 0 on the free list"
         assert self._owner[0] == FREE, "trash page 0 owned by a slot"
         assert self._refcount[0] == 0, "trash page 0 referenced"
         # ground truth: refcount == slot-table references + index holds.
@@ -1921,9 +2059,49 @@ class PagedKVCache:
         assert (self._refcount == expect).all(), \
             f"refcounts drifted: {self._refcount} vs {expect}"
         referenced = set(int(p) for p in np.nonzero(expect)[0])
-        assert not (referenced & set(self._free)), \
+        assert len(free) == len(set(free)), "page twice on the free list"
+        assert not (referenced & set(free)), \
             "page both referenced and free"
-        assert referenced | set(self._free) == \
+        # the runs: every free page in its own run's list, a run among the
+        # whole ones exactly when all its pages are free, among the broken
+        # ones exactly when some are
+        g = self.run_pages
+        if self.pool is not None and self.kv_codec == "fp":
+            leaf = self.pool[0]
+            assert g == flash_attention.walk_run_pages(
+                leaf.shape[-2] * leaf.shape[-1] * leaf.dtype.itemsize,
+                self.pages_per_slot), \
+                "a run is as long as the walk's rule makes it for this leaf"
+        assert sum(map(len, self._runs)) == len(free), \
+            "a run with free pages is neither whole nor broken"
+        whole = set(self._whole)
+        assert len(self._whole) == len(whole), "a run stacked twice"
+        for c, run in enumerate(self._runs):
+            assert all((p - 1) // g == c for p in run), \
+                f"run {c} lists another run's page: {run}"
+            assert (c in whole) == (len(run) == g), \
+                f"run {c} has {len(run)} of {g} pages free, whole or not"
+            assert (c in self._broken) == (0 < len(run) < g), \
+                f"run {c} has {len(run)} of {g} pages free, broken or not"
+        # held ahead: the pages that follow a slot's last one in its run,
+        # nobody's (free to whoever asks) and on no free list
+        ahead = [p for held in self._ahead.values() for p in held]
+        assert len(ahead) == len(set(ahead)), "page held ahead twice"
+        assert not (set(ahead) & (referenced | set(free))), \
+            "page both held ahead and owned or free"
+        for s, held in self._ahead.items():
+            pages = self._slot_pages[s]
+            assert self.active[s] and pages and 0 < len(held) < g, \
+                f"slot {s} holds {held} ahead of {pages}"
+            assert held == list(range(pages[-1] + len(held), pages[-1], -1)) \
+                and (held[0] - 1) // g == (pages[-1] - 1) // g, \
+                f"slot {s} holds {held} ahead: not what follows {pages[-1]} " \
+                f"in its run"
+            assert (len(pages) - 1) % g + 1 + len(held) <= g, \
+                f"slot {s} holds {held} ahead past its table's group"
+        assert self._n_free == len(free) + len(ahead), \
+            f"num_free_pages {self._n_free} != {len(free)} + {len(ahead)}"
+        assert referenced | set(free) | set(ahead) == \
             set(range(1, self.num_pages)), \
             "page leaked (neither referenced nor free)"
         for p in range(1, self.num_pages):
@@ -2187,8 +2365,58 @@ def decode_read_path(pool) -> str:
     return PAGE_WALK if whole else PAGE_GATHER
 
 
+def walk_geometry(pool, pages_per_slot: int) -> tuple:
+    """(pages a block, pages a run) of the walk over a prefix's table of
+    ``pages_per_slot`` entries into ``pool`` (whole, staged or one layer's).
+    The block: the kernel's own rule for a K/V row, and TWICE that for a
+    latent row, which is half as wide at the same lanes: the same VMEM in the
+    two buffers. The run, the pages the kernel takes with ONE DMA where the
+    groups that lead a block name adjacent pages: the rule on a page's bytes
+    (``flash_attention.walk_run_pages``, the length of the runs
+    :class:`PagedKVCache` hands out) cut to what divides a block."""
+    leaf = pool[0]
+    ppb = flash_attention.paged_walk_pages_per_block(
+        leaf.shape[-2], _k_lanes(pool), leaf.dtype.itemsize)
+    if isinstance(pool, LatentPool):
+        ppb *= 2
+    return ppb, math.gcd(ppb, flash_attention.walk_run_pages(
+        leaf.shape[-2] * leaf.shape[-1] * leaf.dtype.itemsize,
+        pages_per_slot))
+
+
+def _walk_runs(pool, page_table, lead=None) -> dict:
+    """What ``flash_attention.paged_decode_walk`` is told of a prefix's
+    ``page_table`` into ``pool``: :func:`walk_geometry`'s block and run (the
+    ONE reading of the rule off this leaf: the kernel, its table of leading
+    runs and the host's counters of it all take it from there) and, where
+    pages go in runs, that table (``flash_attention.leading_runs``), made
+    here of the table's ids unless the caller brings it (``lead``)."""
+    ppb, run = walk_geometry(pool, page_table.shape[1])
+    if run == 1:
+        lead = None
+    elif lead is None:
+        lead = flash_attention.leading_runs(page_table.astype(jnp.int32),
+                                            run, ppb)
+    return {"pages_per_block": ppb, "run_pages": run, "lead": lead}
+
+
+def walk_lead(pool, page_table):
+    """The page walk's table of leading runs over a prefix's ``page_table``
+    into ``pool`` (None where a page goes alone or the read is the gather):
+    made of the TABLE, which every layer of a step shares, and not of a
+    layer's page ids. A step that SCANS its layers makes it once, before the
+    scan, and hands it down (``lead``): made in the scan's body it is made
+    again every layer, 6 µs a layer on a v5e, 0.15 ms of a 24-layer step (the
+    compiler sinks it into the loop, it does not hoist it: PERF.md §6 "PR
+    46"). A step that walks its layers one by one leaves it to the attends,
+    whose equal expressions the compiler merges."""
+    if decode_read_path(pool) != PAGE_WALK:
+        return None
+    return _walk_runs(pool, page_table)["lead"]
+
+
 def attend_pages(q, pool: PagePool, layer, page_table, lengths,
-                 window: int = 0):
+                 window: int = 0, lead=None):
     """:func:`read_span` + :func:`attend_rows` without the span: q (B, 1, H,
     hd) against each slot's LIVE pages of layer ``layer`` of an fp pool (L,
     P, ps, 2*KV*hd), read out of the pool where they lie by ONE kernel
@@ -2209,7 +2437,10 @@ def attend_pages(q, pool: PagePool, layer, page_table, lengths,
     ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
     out = flash_attention.paged_decode_walk(
         qz, _pages(pool.kv, 1), ids, lengths.astype(jnp.int32),
-        scale=float(1.0 / np.sqrt(hd)), window=window)
+        scale=float(1.0 / np.sqrt(hd)), window=window,
+        # (a ring's pages are a fetch each in every configuration that has
+        # rings; the kernel reads the rule off the operand for itself)
+        **({} if window else _walk_runs(pool, page_table, lead)))
     return _own_lanes(out, own)
 
 
@@ -2253,7 +2484,7 @@ def _attention_decode_latent(cfg: ModelConfig, lp: dict, x, cos_b, sin_b,
 
 
 def paged_decode_attention(q, pool, layer, page_table, lengths,
-                           window: int = 0):
+                           window: int = 0, lead=None):
     """Ragged single-position attention against layer ``layer`` of a pool:
     q (B, 1, H, hd) per slot; page_table (B, pages_per_slot) int32 names each
     slot's pages in logical order (0 = the trash page for unallocated tails);
@@ -2268,7 +2499,9 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
 
     ``window`` (static, > 0): ``page_table`` is a window layer's ring
     (:func:`write_rows`) and a row is attended by the position it holds, on
-    either read: the walk takes the ring as it takes a prefix's table."""
+    either read: the walk takes the ring as it takes a prefix's table.
+    ``lead``: :func:`walk_lead` of the pool and the table, where the caller
+    has made it already (a step that scans its layers)."""
     s1, h, hd = q.shape[1:]
     if s1 != 1:
         raise ValueError(f"paged decode is q_len=1 only, got q_len={s1}")
@@ -2281,7 +2514,8 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
     if h % kv:
         raise ValueError(f"ragged GQA: H={h}, KV={kv}")
     if decode_read_path(pool) == PAGE_WALK:
-        return attend_pages(q, pool, layer, page_table, lengths, window)
+        return attend_pages(q, pool, layer, page_table, lengths, window,
+                            lead)
     kg, vg = read_span(pool, layer, page_table, q.dtype)
     if not window:
         return attend_rows(q, kg, vg, lengths)
@@ -2293,7 +2527,7 @@ def paged_decode_attention(q, pool, layer, page_table, lengths,
 def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
                   pool, layer, page_table, lengths,
                   tp_axis: Optional[str] = None, window: int = 0,
-                  write_table=None):
+                  write_table=None, lead=None):
     """The paged twin of ``transformer._attention_decode`` (``window`` static,
     > 0: a sliding layer over its ring; see the two scoped entries below):
     project the (B, 1, D) hidden, rotate each slot at ITS position (``cos_b``
@@ -2307,7 +2541,8 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
     ``write_table`` (None: ``page_table``; a Python-level default): the table
     the new row is WRITTEN through, where a caller must keep some slots' rows
     out of their pages (the split runtime's dead unroll iterations and padding
-    layers: the trash page) while the read still gathers the real ones."""
+    layers: the trash page) while the read still gathers the real ones.
+    ``lead``: :func:`paged_decode_attention`'s."""
     b, s1, d = x.shape
     hd = cfg.head_dim
     h, kv = lp["wq"].shape[-1] // hd, lp["wk"].shape[-1] // hd
@@ -2328,7 +2563,7 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
     pool = (write_rows(pool, layer, write_table, lengths, k, v, ring=True)
             if window else write_rows(pool, layer, write_table, lengths, k, v))
     out = paged_decode_attention(q, pool, layer, page_table, lengths + 1,
-                                 window)
+                                 window, lead)
     out = gated(lp, x, out.reshape(b, s1, h * hd)) @ lp["wo"]
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
@@ -2340,10 +2575,11 @@ def _decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray, cos_b, sin_b,
 @jax.named_scope("attn.decode")
 def _attention_decode_paged(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
                             cos_b, sin_b, pool, layer, page_table, lengths,
-                            tp_axis: Optional[str] = None, write_table=None):
+                            tp_axis: Optional[str] = None, write_table=None,
+                            lead=None):
     """:func:`_decode_paged` for a layer whose pages hold every position."""
     return _decode_paged(cfg, lp, x, cos_b, sin_b, pool, layer, page_table,
-                         lengths, tp_axis, write_table=write_table)
+                         lengths, tp_axis, write_table=write_table, lead=lead)
 
 
 @jax.named_scope("attn.window")
@@ -2358,23 +2594,25 @@ def _attention_decode_window(cfg: ModelConfig, lp: dict, x: jnp.ndarray,
 
 def block_decode_paged(cfg: ModelConfig, lp: dict, hidden: jnp.ndarray,
                        cos_b, sin_b, pool, layer, page_table, lengths,
-                       tp_axis: Optional[str] = None, write_table=None):
+                       tp_axis: Optional[str] = None, write_table=None,
+                       lead=None):
     """The paged twin of ``transformer.block_decode`` for one layer:
     same norm/residual/MLP structure, paged attention core over layer
-    ``layer`` of the whole pool (``write_table``: :func:`_decode_paged`)."""
+    ``layer`` of the whole pool (``write_table``, ``lead``:
+    :func:`_decode_paged`)."""
     if cfg.family == "gpt_neox":
         attn_in = _layernorm(hidden, lp["ln1_scale"], lp["ln1_bias"],
                              cfg.norm_eps)
         attn_out, pool = _attention_decode_paged(
             cfg, lp, attn_in, cos_b, sin_b, pool, layer, page_table, lengths,
-            tp_axis, write_table)
+            tp_axis, write_table, lead)
         mlp_in = _layernorm(hidden, lp["ln2_scale"], lp["ln2_bias"],
                             cfg.norm_eps)
         return hidden + attn_out + mlp(cfg, lp, mlp_in, tp_axis), pool
     attn_in = _rmsnorm(hidden, lp["ln1_scale"], cfg.norm_eps)
     attn_out, pool = _attention_decode_paged(
         cfg, lp, attn_in, cos_b, sin_b, pool, layer, page_table, lengths,
-        tp_axis, write_table)
+        tp_axis, write_table, lead)
     hidden = hidden + attn_out
     mlp_in = _rmsnorm(hidden, lp["ln2_scale"], cfg.norm_eps)
     return hidden + mlp(cfg, lp, mlp_in, tp_axis), pool
@@ -2417,10 +2655,12 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
     cos_b = cos[lengths]  # (B, rot) — each slot's own row
     sin_b = sin[lengths]
 
+    lead = walk_lead(pool, page_table)    # once a step, not once a layer
+
     def body(carry, xs):
         (h, pool), (lp, layer) = carry, xs
         return block_decode_paged(cfg, lp, h, cos_b, sin_b, pool, layer,
-                                  page_table, lengths), None
+                                  page_table, lengths, lead=lead), None
 
     layers = jnp.arange(pool[0].shape[0], dtype=jnp.int32)
     (hidden, pool), _ = jax.lax.scan(body, (hidden, pool),
@@ -2458,8 +2698,7 @@ def attend_latent_pages(q_rows, pool: LatentPool, layer, page_table, lengths,
     return flash_attention.paged_decode_walk(
         q_rows, pages, ids, lengths.astype(jnp.int32),
         scale=float(1.0 / np.sqrt(head_dim)),
-        pages_per_block=2 * flash_attention.paged_walk_pages_per_block(
-            pages.shape[1], pages.shape[2], pages.dtype.itemsize))
+        **_walk_runs(pool, page_table))
 
 
 def latent_decode_attention(q_rows, pool: LatentPool, layer, page_table,

@@ -1578,11 +1578,15 @@ class SplitRuntime:
                 dropped by the small selects on the hidden state. Built once
                 a trace where the tables are the step's own: every unroll
                 step then scans the same body."""
+                # the walk's table of leading runs is the page table's, not
+                # a layer's: made before the scan, once (``walk_lead``)
+                lead = paged_kv.walk_lead(pool_loc, table)
+
                 def scan_body(carry, xs):
                     (h, pool), (lp, ok, write, layer) = carry, xs
                     out, pool = block_decode_paged(
                         cfg, lp, h, cb, sb, pool, layer, table, lens,
-                        write_table=jnp.where(write, table, 0))
+                        write_table=jnp.where(write, table, 0), lead=lead)
                     # a padding layer is the identity on the hidden state
                     return (jnp.where(ok, out, h), pool), None
 

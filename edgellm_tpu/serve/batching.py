@@ -93,7 +93,8 @@ from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
 from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, OutOfPages, \
     OutOfSlots, PagedKVCache, PrefixCacheConfig, \
-    decode_read_path, paged_decode_step, resolve_kv_codec
+    decode_read_path, paged_decode_step, resolve_kv_codec, walk_geometry
+from ..models.flash_attention import leading_runs
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
 from ..obs.flight import flight_dump_for
@@ -444,12 +445,13 @@ class ContinuousBatcher:
                       "routed_assignments": 0, "admit_steps": 0,
                       "steps_ahead": 0,
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
+                      "attend_pages_in_runs": 0,
                       "window_pages_walked": 0, "window_pages_spanned": 0,
                       "step_wall_hist": _new_step_wall_hist(),
                       **dict.fromkeys(_CLOCKS, 0.0)}
         # the reads the step's full and window layers are built with (by pool)
-        (self.decode_read, self.window_read,
-         self.attend_fetches_per_page) = self._read_paths()
+        (self.decode_read, self.window_read, self.attend_fetches_per_page,
+         self.attend_walk) = self._read_paths()
         # the scheduler thread's own, lock-free between folds: clocks and
         # counts; the clock at each token 0; the last launched step's return
         self._acc: dict[str, float] = defaultdict(int)
@@ -1163,6 +1165,9 @@ class ContinuousBatcher:
                     jnp.asarray(steps), jnp.asarray(temps),
                     self.bcfg.compute_dtype)
             toks.copy_to_host_async()  # read a call later, already on its way
+            # (counted here, behind the launch: the chip has its step, and
+            # a step that follows an admission is not held up by the count)
+            acc["attend_pages_in_runs"] = self._pages_in_runs(reached)
             if step_no == 0:
                 # the merge every later launch runs, compiled by the first: a
                 # caller that warmed one step has warmed the steady state
@@ -1488,6 +1493,12 @@ class ContinuousBatcher:
             "attend_fetches_per_page": self.attend_fetches_per_page,
             "attend_pages_walked": stats["attend_pages_walked"],
             "attend_pages_spanned": stats["attend_pages_spanned"],
+            # of the walked pages, those fetched as part of a run of adjacent
+            # pages, and the copies a layer's walk started for all of them
+            "attend_pages_in_runs": stats["attend_pages_in_runs"],
+            "attend_dmas": (
+                stats["attend_pages_walked"] - stats["attend_pages_in_runs"]
+                // self.attend_walk[1] * (self.attend_walk[1] - 1)),
             "window_read": self.window_read,
             "window_pages_walked": stats["window_pages_walked"],
             "window_pages_spanned": stats["window_pages_spanned"],
@@ -1497,19 +1508,48 @@ class ContinuousBatcher:
         }
 
     def _read_paths(self) -> tuple:
-        """(``decode_read``, ``window_read``, ``attend_fetches_per_page``):
-        what ``decode_read_path`` says of the pool the full-attention layers
-        read and of the window layers' pool of rings (no such pool: the
-        gather, which no step then takes), and the DMAs the walk starts for
-        a page, one a leaf of the pool it walks (0: the read is the gather).
+        """(``decode_read``, ``window_read``, ``attend_fetches_per_page``,
+        ``attend_walk``): what ``decode_read_path`` says of the pool the
+        full-attention layers read and of the window layers' pool of rings
+        (no such pool: the gather, which no step then takes), the DMAs the
+        walk starts for a page that goes alone, one a leaf of the pool it
+        walks (0: the read is the gather), and the walk's (pages a block,
+        pages it takes with one DMA where the groups that lead a block name
+        adjacent ones) (``paged_kv.walk_geometry``; a run of 1: every page
+        goes alone).
         Down here: a line added above would move the prefill kernels' call
         sites, as below."""
         full = self._split_pool if self.rt is not None else self.pool.pool
         rings = self.pool.window_pool
         read = decode_read_path(full)
+        ppb, run = (walk_geometry(full, self.bcfg.pages_per_slot)
+                    if read == PAGE_WALK else (1, 1))
+        # the allocator read the rule off the configuration (it holds no
+        # pages in split mode), the kernel's caller reads it off the leaf
+        assert read != PAGE_WALK or run == np.gcd(ppb, self.pool.run_pages), \
+            f"the pool hands out runs of {self.pool.run_pages} pages, the " \
+            f"walk takes {run} of a block of {ppb} with one DMA"
         return (read,
                 decode_read_path(rings) if rings is not None else PAGE_GATHER,
-                len(full) if read == PAGE_WALK else 0)
+                len(full) if read == PAGE_WALK else 0, (ppb, run))
+
+    def _pages_in_runs(self, reached) -> int:
+        """Of the pages a layer's attend walks this step (``reached`` a slot,
+        an idle slot's 1), those that go as part of a run: the groups that
+        lead a block of the host's table as adjacent pages and are live
+        whole, which is the table the kernel is handed
+        (``flash_attention.leading_runs``, the same function). Of the slots
+        that reach a whole group and the blocks they reach: an almost idle
+        batch pays for its two streams, not for 192 rows."""
+        ppb, run = self.attend_walk
+        rows = np.flatnonzero(reached >= run) if run > 1 else ()
+        if not len(rows):
+            return 0
+        live = reached[rows, None]
+        lead = leading_runs(self.pool.page_table[
+            rows, :-(-int(live.max()) // ppb) * ppb], run, ppb)
+        live = np.clip(live - ppb * np.arange(lead.shape[1]), 0, ppb) // run
+        return run * int(np.sum(np.minimum(lead, live)))
 
     def _hybrid_report(self, stats: dict) -> dict:
         """What a stack with recurrent state and routed experts adds to

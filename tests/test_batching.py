@@ -204,12 +204,16 @@ def test_defrag_preserves_content_and_compacts():
 def test_defrag_churn_page_moves_up_past_free_page():
     # regression: alloc/grow/free churn can leave an owned page whose
     # compacted destination is a HIGHER id currently on the free list
-    # (here slot pages [[4], [2, 1]] with page 3 free: page 1's destination
-    # is 3). The old->new map is then not invertible, and a naive inversion
+    # (here slot pages [[3], [1, 4]] with page 2 free: page 1's destination
+    # is 2). The old->new map is then not invertible, and a naive inversion
     # gathered the free page's garbage into the destination — silently,
     # since check_invariants() only sees bookkeeping.
+    # (Pages of this size go out in runs of two, [1, 2] [3, 4] and a short
+    # [5], the rest of a run held ahead for the slot that broke it and taken
+    # back, the oldest holder's first, once no other page is free.)
     pool = PagedKVCache(CFG, num_pages=6, page_size=4, max_slots=3,
                         pages_per_slot=2)
+    assert pool.run_pages == 2
     rng = np.random.default_rng(11)
 
     def kv(n):
@@ -222,19 +226,16 @@ def test_defrag_churn_page_moves_up_past_free_page():
         pool.adopt(slot, jnp.asarray(k), jnp.asarray(v), n)
         return k, v
 
-    s0 = pool.alloc_slot()
-    fill(s0, 4)                       # page [1]
-    s1 = pool.alloc_slot()
-    fill(s1, 4)                       # page [2]
-    s2 = pool.alloc_slot()
-    fill(s2, 4)                       # page [3]
-    pool.free_slot(s0)                # free: [5, 4, 1]
-    k1, v1 = fill(s1, 8)              # grows into page 1 -> [2, 1]
-    s0 = pool.alloc_slot()
-    k0, v0 = fill(s0, 4)              # pops page 4 -> [4]
-    pool.free_slot(s2)                # free: [5, 3]
-    assert pool._slot_pages[s0] == [4]
-    assert pool._slot_pages[s1] == [2, 1]
+    s0, s1, s2 = (pool.alloc_slot() for _ in range(3))
+    fill(s1, 4)                       # run [1, 2]: page [1], 2 held ahead
+    k0, v0 = fill(s0, 4)              # run [3, 4]: page [3], 4 held ahead
+    fill(s2, 4)                       # no whole run left: page [5]
+    fill(s2, 8)                       # nothing free but what is held: 2
+    k1, v1 = fill(s1, 8)              # its own 2 is gone: s0's 4 -> [1, 4]
+    assert pool._slot_pages[s2] == [5, 2]
+    pool.free_slot(s2)                # free: 2, 5
+    assert pool._slot_pages[s0] == [3]
+    assert pool._slot_pages[s1] == [1, 4]
     pool.check_invariants()
 
     moved = pool.defrag()

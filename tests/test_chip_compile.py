@@ -223,9 +223,18 @@ def test_decode_step_updates_the_pool_where_it_lies(topo, read):
     # the module: no result of a slot's 2048 rows a slot, gathered, reshaped
     # or fused
     assert _walks(hlo) == 1, _walks(hlo)
+    # (8 KB pages go eight to a run: behind the lengths, in the one array,
+    # a count a block of 32 entries of how many runs lead it)
     (operands,) = _walk_operands(hlo)
-    assert operands[2:] == [
+    assert operands[1:] == [
+        f"s32[{SLOTS * (1 + PAGES_PER_SLOT // 32)}]",
         f"bf16[{SLOTS},{QWEN05.num_heads},{width // 2}]", flat_pages], operands
+    # that table is the page table's, not a layer's: made ONCE a step, ahead
+    # of the scan over the layers (in the scan's body the compiler makes it
+    # again every layer: 6 us a layer on the chip, PERF.md section 6 "PR 46")
+    made = [line for _, _, shape, line in _instructions(hlo)
+            if shape.startswith(f"s32[{SLOTS},{PAGES_PER_SLOT // 32}]")]
+    assert made and not [line for line in made if "/while/" in line], made
     spans = _span_sized(hlo, own | {
         f"bf16[{SLOTS},{PAGES_PER_SLOT * PAGE},{width}]"})
     assert not spans, spans[:3]
@@ -439,8 +448,15 @@ def test_staged_decode_step_updates_the_pool_where_it_lies_over_four_chips(
         assert _walks(hlo) >= 1, _walks(hlo)
         assert not _span_sized(hlo, own), _span_sized(hlo, own)[:3]
         # every one of them reads ONE operand out of HBM: the stage's leaf
+        # (16 KB pages go four to a run: behind the lengths a count a block
+        # of 32 entries of how many runs lead it; then the stage's query)
         for ops in _walk_operands(hlo):
+            assert ops[1] == f"s32[{SLOTS * (1 + PAGES_PER_SLOT // 32)}]", ops
             assert ops[3:] == [f"bf16[{sz * PAGES},{PAGE},{width}]"], ops
+        # the table of leading runs: once a step, outside the layers' scans
+        made = [line for _, _, shape, line in _instructions(hlo)
+                if shape.startswith(f"s32[{SLOTS},{PAGES_PER_SLOT // 32}]")]
+        assert made and not [line for line in made if "/while/" in line], made
     else:
         assert not _walks(hlo)
     # across chips: the three hops, each to the next stage, and the one

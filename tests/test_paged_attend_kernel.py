@@ -9,6 +9,7 @@ of a page or a row that no length covers.
 """
 import dataclasses
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -718,3 +719,209 @@ def test_window_step_on_the_walk_equals_the_step_on_the_gather(monkeypatch,
             # page 0 is the trash page: idle slots' rows, in any order
             np.testing.assert_allclose(np.asarray(a[:, 1:]),
                                        np.asarray(b[:, 1:]), atol=1e-5)
+
+
+# -- runs: a group of adjacent pages is one DMA (PERF.md §6 "PR 46") ---------
+
+def _run_pool(tables, lengths, lanes, dtype, seed, entries=16):
+    """A pool of 16-row pages of ``lanes`` lanes a row, NaN in every page no
+    slot's LIVE entries name (the trash page is finite: an idle slot fetches
+    it), and the (slots, ``entries``) table that ``tables`` spells out."""
+    rng = np.random.default_rng(seed)
+    table = np.zeros((len(tables), entries), np.int32)
+    for i, ids in enumerate(tables):
+        table[i, :len(ids)] = ids
+    pages = int(table.max()) + 6
+    rows = rng.standard_normal(
+        (LAYERS * pages, PAGE, lanes)).astype(np.float32)
+    held = np.zeros((pages,), bool)
+    held[0] = True
+    for i, n in enumerate(lengths):
+        held[table[i, :max(-(-n // PAGE), 1)]] = True
+    rows.reshape(LAYERS, pages, PAGE, lanes)[:, ~held] = np.nan
+    return (jnp.asarray(rows, dtype), jnp.asarray(LAYER * pages + table),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _seq(first, n):
+    return list(range(first, first + n))
+
+
+#: name -> (tables, lengths): each slot's entries as page ids of the pool,
+#: and the positions it attends. Runs of four are [1..4], [5..8], ...
+RUN_TABLES = {
+    "every-group-a-run": (
+        [_seq(1, 12), _seq(21, 7), _seq(33, 16)], (12 * 16, 100, 256)),
+    "no-group-a-run": (
+        [[9, 3, 12, 7, 1, 14, 5, 2], [30, 28, 26, 24, 22], [40, 42, 41, 43]],
+        (8 * 16 - 3, 70, 64)),
+    "mixed": (
+        [_seq(1, 4) + [9, 8, 7, 6] + _seq(11, 4) + [20, 21, 23, 22],
+         [31, 32, 34, 33] + _seq(41, 8)], (256, 190)),
+    # the table goes on adjacent, the length stops: the pages past it hold
+    # NaN and must not be fetched with the run's first ones
+    "a-run-ends-mid-group-at-the-last-live-page": (
+        [_seq(1, 16), _seq(21, 16), _seq(41, 16)], (5 * 16 + 1, 16 * 6, 23)),
+    "a-last-block-of-fewer-pages-than-a-run": (
+        [_seq(1, 16), _seq(21, 16)], (9 * 16, 8 * 16 + 40)),
+    "lengths-that-are-whole-runs": (
+        [_seq(1, 16), _seq(21, 16), _seq(41, 16)], (64, 128, 256)),
+    "an-idle-slot-on-the-trash-page": (
+        [[], _seq(1, 8), [], []], (1, 128, 1, 1)),
+}
+
+
+@pytest.mark.parametrize("run", [2, 4, 8])
+@pytest.mark.parametrize("table", RUN_TABLES)
+def test_a_run_of_pages_is_fetched_to_the_same_bytes(table, run):
+    """Runs of 2, 4 and 8 against the page-a-DMA body (``run_pages=1``), BIT
+    FOR BIT: adjacent pages in HBM land adjacent in the buffer, so the dots
+    see the same bytes in the same order, whatever the table says about
+    which groups are runs and which of them lead their block; blocks of 8
+    pages, so that slots take several."""
+    tables, lengths = RUN_TABLES[table]
+    pages, ids, lens = _run_pool(tables, lengths, 256, jnp.bfloat16, seed=run)
+    qz = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (len(lengths), 6, 128)), jnp.bfloat16)
+    want = _interpreted(qz, pages, ids, lens, scale=0.125, pages_per_block=8,
+                        run_pages=1)
+    got = _interpreted(qz, pages, ids, lens, scale=0.125, pages_per_block=8,
+                       run_pages=run)
+    assert bool(jnp.isfinite(want.astype(jnp.float32)).all())
+    assert np.array_equal(np.asarray(got.astype(jnp.float32)),
+                          np.asarray(want.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("query", ["bfloat16", "float32"])
+@pytest.mark.parametrize("row", ["k-then-v", "latent"])
+def test_runs_under_either_row_and_either_query(row, query):
+    """A row of 2 W lanes (K then V) and one of W (a latent row, key and
+    value both), a bf16 query and a float32 one over bf16 pages (the split
+    runtime's late stages): the run rule's own choice for the page (8 KB and
+    4 KB: eight) against the page-a-DMA body bit for bit, and against
+    ``attend_rows`` / ``attend_latent`` over the gathered rows to rounding."""
+    tables, lengths = RUN_TABLES["mixed"]
+    lanes = 256 if row == "k-then-v" else 128
+    pages, ids, lens = _run_pool(tables, lengths, lanes, jnp.bfloat16, seed=3)
+    qz = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (len(lengths), 6, 128)), jnp.dtype(query))
+    assert flash_attention.walk_run_pages(PAGE * lanes * 2, 16) == 8
+    want = _interpreted(qz, pages, ids, lens, scale=0.125, run_pages=1)
+    got = _interpreted(qz, pages, ids, lens, scale=0.125)
+    assert got.dtype == jnp.dtype(query)
+    assert np.array_equal(np.asarray(got.astype(jnp.float32)),
+                          np.asarray(want.astype(jnp.float32)))
+    clean = jnp.nan_to_num(pages.astype(jnp.float32)).astype(pages.dtype)
+    gathered = clean[ids].reshape(len(lengths), -1, lanes)
+    if row == "latent":
+        oracle = paged_kv.attend_latent(qz, gathered, lens, 64)
+    else:
+        scores = jnp.einsum("bhD,bcD->bhc", qz, gathered[..., :128],
+                            preferred_element_type=jnp.float32) * 0.125
+        valid = jnp.arange(gathered.shape[1])[None, :] < lens[:, None]
+        probs = jax.nn.softmax(jnp.where(valid[:, None], scores,
+                                         jnp.finfo(jnp.float32).min), -1)
+        oracle = jnp.einsum("bhc,bcD->bhD", probs.astype(qz.dtype),
+                            gathered[..., 128:],
+                            preferred_element_type=jnp.float32
+                            ).astype(qz.dtype)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(oracle, np.float32), atol=3e-2)
+
+
+def test_the_hosts_count_of_runs_is_the_kernels_table():
+    """``leading_runs`` is the table the kernel reads behind its lengths (made
+    on the device, of the ids it is handed) and what the batcher counts
+    ``attend_pages_in_runs`` by (in numpy, of the host's table): the same
+    function, the same answer on a random table with runs planted in it: of
+    each block, the groups from its first on that name adjacent pages, no
+    further than its first group that does not. And a kernel handed that
+    table fetches what the page-a-DMA body fetches, with NaN in every page a
+    wrongly taken run would drag in."""
+    rng = np.random.default_rng(8)
+    slots, entries, run = 6, 16, 4
+    table = rng.permutation(np.arange(1, 1 + slots * entries)).reshape(
+        slots, entries).astype(np.int32)
+    planted = rng.random((slots, entries // run)) < 0.6
+    for s, g in zip(*np.nonzero(planted)):
+        first = 200 + 8 * (s * entries + g)          # adjacent, and free
+        table[s, g * run:(g + 1) * run] = np.arange(first, first + run)
+    # near misses: adjacent but for the last, and adjacent going down
+    table[0, :4], table[1, :4] = [900, 901, 902, 904], [913, 912, 911, 910]
+    planted[0, 0] = planted[1, 0] = False
+    host = flash_attention.page_runs(table, run)
+    assert host.dtype == bool and (host == planted).all()
+    for g, ppb in ((2, 8), (4, 8), (4, 16), (8, 8), (4, 12)):
+        want = np.zeros((slots, -(-entries // ppb)), np.int32)
+        runs = flash_attention.page_runs(table, g)
+        for s in range(slots):
+            for blk in range(want.shape[1]):
+                for grp in runs[s, blk * (ppb // g):(blk + 1) * (ppb // g)]:
+                    if not grp:
+                        break
+                    want[s, blk] += 1
+        got = flash_attention.leading_runs(table, g, ppb)
+        assert got.dtype == np.int32 and (got == want).all(), (g, ppb)
+        on_device = flash_attention.leading_runs(jnp.asarray(table), g, ppb)
+        assert on_device.dtype == jnp.int32
+        assert (np.asarray(on_device) == want).all()
+    # a count a block the KERNEL walks: a ring of 5 entries in blocks of 2
+    # has a third block, of one entry and no whole group
+    short = flash_attention.leading_runs(table[:, :5], 2, 2)
+    assert short.shape == (slots, 3) and not short[:, 2].any()
+    lengths = rng.integers(1, entries * PAGE, slots)
+    pages, ids, lens = _run_pool(table.tolist(), lengths.tolist(), 256,
+                                 jnp.bfloat16, seed=9)
+    qz = jnp.asarray(rng.standard_normal((slots, 4, 128)), jnp.bfloat16)
+    want = _interpreted(qz, pages, ids, lens, scale=0.125, run_pages=1)
+    for ppb in (8, 16):
+        got = _interpreted(qz, pages, ids, lens, scale=0.125, run_pages=run,
+                           pages_per_block=ppb)
+        same = _interpreted(qz, pages, ids, lens, scale=0.125, run_pages=1,
+                            pages_per_block=ppb)
+        assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(same, np.float32))
+    assert want.shape == got.shape
+
+
+def _kernel_jaxpr(lanes, run_pages=None, window=0):
+    """The kernel's own jaxpr (the ``pallas_call``'s body) over 16-row bf16
+    pages of ``lanes`` lanes and a query half as wide."""
+    shape = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(
+        lambda q, pages, ids, lens: flash_attention.paged_decode_walk(
+            q, pages, ids, lens, scale=0.125, window=window,
+            run_pages=run_pages))(
+                shape((3, 4, lanes // 2), jnp.bfloat16),
+                shape((40, 16, lanes), jnp.bfloat16),
+                shape((3, 8), jnp.int32), shape((3,), jnp.int32)))
+    kernel = text[text.index("pallas_call["):]
+    return kernel[kernel.index("jaxpr={ lambda ;"):]
+
+
+@pytest.mark.parametrize("window", [0, 40], ids=["prefix", "ring"])
+def test_a_page_of_32_kb_traces_the_page_a_dma_body(window):
+    """Where a page is a fetch by itself (32 KB: mellum's, trinity's and
+    lfm2's full layers and every ring of the cells; granite's are 64 KB) the
+    rule makes a run ONE page long and the kernel traces the body it traced
+    before runs: the lengths alone in their array, a DMA a page, the issue
+    loop unrolled by two. The same body as an 8 KB page's told ``run_pages=1``, and
+    another than the one the rule gives that page."""
+    assert flash_attention.walk_run_pages(16 * 1024 * 2, 8) == 1
+    wide = _kernel_jaxpr(1024, window=window)
+    narrow = _kernel_jaxpr(256, window=window)
+    alone = _kernel_jaxpr(256, run_pages=1, window=window)
+
+    def lengths(kernel):    # in SMEM: the page ids, the lengths, a parity
+        args = kernel[:kernel.index(". let")]
+        assert args.count("Ref<smem>") == 3
+        return re.findall(r"Ref<smem>\{i32\[(\d+)\]\}", args)[0]
+
+    # behind 3 slots' lengths, a count a block of each slot's 8 entries
+    assert (lengths(wide), lengths(alone), lengths(narrow)) == ("3", "3", "6")
+    assert wide == _kernel_jaxpr(1024, run_pages=1, window=window)
+    # a page's DMA in the unrolled pair and in the odd one out, as before
+    # runs; with them, a run's and its pages' a group and the tail's
+    starts = [k.count("dma_start") for k in (wide, alone, narrow)]
+    assert starts[0] == starts[1] < starts[2], starts

@@ -573,8 +573,11 @@ def test_the_lfm2_cell_lists_its_readers_and_the_ones_it_joins():
         "gap_mean_ms", "setup_s"}
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
         spec = json.load(f)
-    assert [m["name"] for m in spec["per_layer"]][-4:] == list(LFM2_READERS)
-    assert all(m["workloads"] == [LFM2_CELL] for m in spec["per_layer"][-4:])
+    # (the cell's own four, then PR 46's ``attend_run_share``, which it joins)
+    assert [m["name"] for m in spec["per_layer"]][-5:] == [
+        *LFM2_READERS, "attend_run_share"]
+    assert all(m["workloads"] == [LFM2_CELL] for m in spec["per_layer"][-5:-1])
+    assert LFM2_CELL in spec["per_layer"][-1]["workloads"]
     assert spec["workloads"][-1]["name"] == LFM2_CELL
     assert len(spec["workloads"]) == 9
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
