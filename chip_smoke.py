@@ -758,6 +758,172 @@ def shortconv_phase(*, prompt_len: int = 300, n_new: int = 40,
             "gap_max_over_logit_max": gap}
 
 
+def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
+                 evict_after: int = 20) -> dict:
+    """A tiny ``keye_vl2`` stream (sparse-attention layers: rotated,
+    q/k-normed GQA whose query attends the 64 positions its indexer scores
+    highest of the 300-340 it holds, an index key a position in the pool's
+    second leaf; experts routed by the softmax over the chosen, none shared;
+    an untied head, float32) through the same admit / step / evict /
+    readmit: the index keys leave the device with the K/V rows and come
+    back, and ``forward`` (the block-masked prefill form) over prompt +
+    tokens puts each served token first, on the masked page walk a TPU's
+    pool takes. ``wv`` is seeded 9x wider and the other matrices 3x, so that
+    which rows are attended moves the logits. Then
+    :func:`selection_on_the_chip`."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from edgellm_tpu.models import grouped_matmul, sparse_attn
+    from edgellm_tpu.models.configs import tiny_keye_vl2_config
+    from edgellm_tpu.models.paged_kv import PAGE_WALK
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    def wider(params):
+        def scale(path, a):
+            name = path[-1].key
+            if name == "router":
+                return a * 15.0
+            return a * (9.0 if name == "wv" else 3.0) \
+                if name.startswith("w") else a
+        return jax.tree_util.tree_map_with_path(scale, params)
+
+    cfg = dataclasses.replace(tiny_keye_vl2_config(
+        hidden_size=256, num_heads=4, num_kv_heads=2, head_dim=64,
+        index_heads=4, index_head_dim=64, index_topk=64), expert_width=128)
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=24)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after,
+                                 wider)
+    # rows of 2 x 128 lanes on a TPU: the page walk of a GQA layer with the
+    # selection as a mask (the row gather is every other backend's read, and
+    # tests/test_keye_vl2.py holds it to the same reference on the CPU)
+    assert report["sparse_read"] == sparse_attn.MASKED_WALK, report
+    assert report["decode_read"] == PAGE_WALK
+    assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    assert report["kv_row_bytes"] == (2 * 128 + 128) * 4
+    assert 0 < report["sparse_rows_attended"] < report["sparse_rows_live"]
+    assert report["index_rows_scored"] == report["sparse_rows_live"]
+    assert report["grouped_product"] == grouped_matmul.PALLAS_GROUPED, \
+        report["grouped_product"]
+    assert len(np.unique(report["served"])) > n_new // 4, report["served"]
+    return {"tokens": int(n_new), "distinct_tokens":
+            int(len(np.unique(report["served"]))),
+            "evicted": report["evicted"],
+            "sparse_read": report["sparse_read"],
+            "sparse_selected_share": 100.0 * report["sparse_rows_attended"]
+            / report["sparse_rows_live"],
+            "kv_row_bytes": report["kv_row_bytes"],
+            "grouped_product": report["grouped_product"],
+            "launch_ahead_share": report["launch_ahead_share"],
+            "gap_max_over_logit_max": gap,
+            "selection": selection_on_the_chip()}
+
+
+def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
+    """The selection at the benchmark cell's widths and depths: one
+    published-width sparse layer in bfloat16, a slot a depth whose index keys
+    lie in the pages of a pool of the cell's geometry; the row ids a decode
+    step's own code chooses (index keys through the page gather, bf16
+    operands into float32 scores, ``select``, the flat ids) against
+    ``jax.lax.top_k`` of float32 scores at ``highest`` from the SAME weights
+    and cache. A row near the 2048th place may flip under bf16 operands; a
+    wrong selection (the newest 2048 positions, the cheap answer) shares a
+    fifth of the set. The benchmark cell's ``correct`` sees a wrong
+    selection through the logits (the newest 2048 in place of the chosen
+    reads ``gap_mean`` 0.31-0.34 against a limit of 0.029: PERF.md §6 "PR
+    47"); this line says HOW MANY rows the stated precision itself flips,
+    which is most of that cell's sound ``gap_mean``."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from edgellm_tpu.models import init_params, paged_kv, sparse_attn
+    from edgellm_tpu.models.configs import KEYE_VL_2_0_30B_A3B
+
+    cfg = dataclasses.replace(
+        KEYE_VL_2_0_30B_A3B, num_layers=1,
+        layer_types=("sparse_attention",), experts_held=1, vocab_size=256)
+    k, ps, pps = cfg.index_topk, 16, 1280
+    params = init_params(cfg, jax.random.key(SEED), dtype=jnp.bfloat16)
+    lp = {name: a[0] for name, a in params["sparse"].items()}
+    # the head weights at the benchmark's std (logits of std 1)
+    lp["w_index"] = lp["w_index"] * (1.0 / (0.02 * cfg.hidden_size ** 0.5))
+    span = ps * pps
+    cache = paged_kv.PagedKVCache(
+        cfg, num_pages=len(depths) * pps + 1, page_size=ps,
+        max_slots=len(depths), pages_per_slot=pps, dtype=jnp.bfloat16)
+    rope = sparse_attn.index_rope(cfg, span)
+
+    @jax.jit
+    def keys_of(x):           # a sequence's index keys, as cached
+        return sparse_attn.project_index(
+            cfg, lp, x, sparse_attn.rotate_rows(*rope))[1]
+
+    xs = []
+    for slot, depth in enumerate(depths):
+        x = jax.random.normal(jax.random.key(SEED + slot),
+                              (span, cfg.hidden_size), jnp.bfloat16)
+        xs.append(x[depth])                    # the query's layer input
+        assert cache.alloc_slot() == slot
+        zeros = jnp.zeros((1, depth, cfg.num_kv_heads, cfg.head_dim),
+                          jnp.bfloat16)
+        cache.adopt(slot, zeros, zeros, depth,
+                    index=keys_of(x)[None, :depth])
+    table, lengths = cache.device_tables()
+    x = jnp.stack(xs)
+    at = (rope[0][lengths], rope[1][lengths])
+
+    @jax.jit
+    def chosen(pool, x):       # the step's own path, bf16 operands
+        qi, ik, wi = sparse_attn.project_index(
+            cfg, lp, x, sparse_attn.rotate_rows(*at))
+        pool = paged_kv.write_rows(
+            pool, 0, table, lengths,
+            jnp.zeros((len(depths), 1, cfg.num_kv_heads, cfg.head_dim),
+                      jnp.bfloat16),
+            jnp.zeros((len(depths), 1, cfg.num_kv_heads, cfg.head_dim),
+                      jnp.bfloat16), index=ik)
+        rows = paged_kv._gather_pages(pool.ik, 0, table)
+        scores = sparse_attn.index_scores(qi, wi, rows)
+        # (the row gather's ids; the masked walk's mask is the same set:
+        # asserted below)
+        idx, _ = sparse_attn.select(scores, lengths + 1, k)
+        live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]
+        keep = sparse_attn.selection_mask(scores, live, k)
+        # the same weights and cache in float32 at ``highest``
+        with jax.default_matmul_precision("highest"):
+            f32 = {n: a.astype(jnp.float32) for n, a in lp.items()}
+            lq, _, lw = sparse_attn.project_index(
+                cfg, f32, x.astype(jnp.float32),
+                sparse_attn.rotate_rows(*at))
+            exact = sparse_attn.index_scores(lq, lw,
+                                             rows.astype(jnp.float32))
+        want, _ = sparse_attn.select(exact, lengths + 1, k)
+        return idx, want, keep, pool
+
+    idx, want, keep, cache.pool = chosen(cache.pool, x)
+    idx, want, keep = np.asarray(idx), np.asarray(want), np.asarray(keep)
+    for slot in range(len(depths)):
+        assert set(np.flatnonzero(keep[slot])) == set(idx[slot].tolist())
+    out = {}
+    for slot, depth in enumerate(depths):
+        exact = set(want[slot].tolist())
+        overlap = len(exact & set(idx[slot].tolist())) / k
+        newest = len(exact & set(range(depth + 1 - k, depth + 1))) / k
+        out[str(depth)] = {"overlap": overlap, "newest_2048": newest}
+        print(f"[chip_smoke] keye_vl2 selection at depth {depth}: "
+              f"{100 * overlap:.2f}% of the float32 top-{k} set, the "
+              f"newest {k} positions would share {100 * newest:.1f}%",
+              flush=True)
+        assert overlap > 0.98 and newest < 0.5, out
+    return out
+
+
 def smoke(report: dict, save) -> dict:
     """Every phase in order, at full width. ``save()`` persists ``report``
     after each phase so a failed run leaves what it learned."""
@@ -794,6 +960,7 @@ def smoke(report: dict, save) -> dict:
     phase("afmoe", afmoe_phase)
     phase("longcat", longcat_phase)
     phase("shortconv", shortconv_phase)
+    phase("sparse", sparse_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

@@ -404,6 +404,29 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             lower_args=(ccfg, cparams, cpool.kv, cstate, ccount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
+    # ---- a stack of sparse-attention layers (keye_vl2): the same
+    # ---- executable with a pool of TWO leaves handed over whole, K/V rows
+    # ---- and index keys under one table (a span of 16 past the toy's topk
+    # ---- of 8: the indexer, the selection and the row gather are in the
+    # ---- graph) — collective-free; both leaves and the expert counter,
+    # ---- THREE buffers, stay donated ------------------------------------
+    from ..models.configs import tiny_keye_vl2_config
+
+    kcfg = tiny_keye_vl2_config()
+    kparams = transformer.init_params(kcfg, jax.random.key(0))
+    kpool = paged_kv.init_pool(kcfg, NPG, PGS)
+    kcount = jnp.zeros((kcfg.expert_layers, kcfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_sparse",
+            lambda p, kv, ik, ct, pt, ln, t:
+                hybrid.paged_decode_step_hybrid(
+                    kcfg, p, paged_kv.IndexedPagePool(kv, ik), None, ct, pt,
+                    ln, t),
+            (kparams, kpool.kv, kpool.ik, kcount, ptab, plens, ptoks),
+            ctx={"donate_min": 3},
+            lowerable=batching._batched_hybrid_step_jit,
+            lower_args=(kcfg, kparams, kpool, None, kcount,
+                        ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
+
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state
     # feeds the byte-identical ragged step graph the pre-quantization
     # batcher traces — the disabled-build jaxpr fingerprint half of the

@@ -82,8 +82,17 @@ class ModelConfig:
         dense SwiGLU, the rest routed by ``"sigmoid"`` scores with a
         selection bias and no shared expert, the weights normalised over
         ``+ 1e-6`` (``route_norm_eps``); a tied head (LiquidAI LFM2).
+      - ``"keye_vl2"``: one kind, ``"sparse_attention"``
+        (``models/sparse_attn.py``): a rotated, q/k-normed GQA layer whose
+        query attends the ``index_topk`` positions a learned INDEXER scores
+        highest (``index_heads`` query heads of ``index_head_dim`` against
+        ONE index key a position, which the page pool keeps in a second leaf
+        beside the K/V rows), every position while there are no more than
+        that; every feed-forward routed by the softmax over the chosen
+        logits, no shared expert, an untied head (Kwai Keye-VL 2.0's
+        language model).
 
-    The fields after ``rope_scaling`` exist for those six families and
+    The fields after ``rope_scaling`` exist for those seven families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -109,8 +118,8 @@ class ModelConfig:
     #: ``rope_parameters`` by layer kind).
     rope_scaling: Optional[tuple] = None
     #: per-layer mixer kind, ``"mamba"``, ``"conv"``, ``"attention"``,
-    #: ``"sliding_attention"`` or ``"latent_attention"``; empty = every layer
-    #: is the family's one block
+    #: ``"sliding_attention"``, ``"latent_attention"`` or
+    #: ``"sparse_attention"``; empty = every layer is the family's one block
     layer_types: tuple = ()
     #: width of one attention head where the model states it; 0 = the
     #: derived ``hidden_size // num_heads``
@@ -181,6 +190,17 @@ class ModelConfig:
     #: taps of a ``"conv"`` layer's depthwise causal convolution (HF's
     #: ``conv_L_cache``): a sequence keeps ``conv_window - 1`` rows a layer
     conv_window: int = 0
+    #: a ``"sparse_attention"`` layer's indexer: ``index_heads`` query heads
+    #: of ``index_head_dim`` lanes score ONE index key a position, and the
+    #: layer's query attends the ``index_topk`` highest (HF's ``sa_config``)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    #: how the published rotation splits its ``rotary_dim / 2`` frequencies
+    #: among three position streams (HF's ``rope_scaling.mrope_section``);
+    #: text positions are equal in the three, so the program rotates by the
+    #: plain table and only checks the sum
+    mrope_section: tuple = ()
 
     @property
     def head_dim(self) -> int:
@@ -196,6 +216,26 @@ class ModelConfig:
         """Layers whose cached row is a latent (one row a position for all
         heads), not per-head K and V."""
         return sum(1 for t in self.layer_types if t == "latent_attention")
+
+    @property
+    def sparse_layers(self) -> int:
+        """Layers whose query attends the positions an indexer selects, and
+        which keep an index key a position beside the K/V row."""
+        return sum(1 for t in self.layer_types if t == "sparse_attention")
+
+    @property
+    def index_row_lanes(self) -> int:
+        """Lanes of ONE stored row of the page pool's index-key leaf (0: no
+        such leaf): a position's ``index_head_dim`` key lanes zero-padded to
+        whole 128-lane tiles (64 -> 128). At 64 lanes a page of 16 bf16 rows
+        is half an (16, 128) tile: the chip's compiler pads it to 128 lanes
+        anyway or keeps the leaf pages-minor and copies it around every row
+        write (``kv_row_lanes``' reason); two positions a row would halve
+        the bytes but make a step's row write a read-modify-write of its
+        neighbour's lanes."""
+        if not self.sparse_layers:
+            return 0
+        return -(-self.index_head_dim // LANE_TILE) * LANE_TILE
 
     @property
     def kv_row_lanes(self) -> int:
@@ -215,7 +255,8 @@ class ModelConfig:
         """Walked by layer kinds (``models/hybrid.py``), with params held per
         kind and a routed expert layer after every mixer."""
         return self.family in ("granitemoehybrid", "mellum", "mistral4",
-                               "afmoe", "longcat_flash", "lfm2_moe")
+                               "afmoe", "longcat_flash", "lfm2_moe",
+                               "keye_vl2")
 
     @property
     def expert_layers(self) -> int:
@@ -299,7 +340,8 @@ class ModelConfig:
         if not self.layer_types:
             return self.num_layers
         return sum(1 for t in self.layer_types
-                   if t in ("attention", "latent_attention"))
+                   if t in ("attention", "latent_attention",
+                            "sparse_attention"))
 
     @property
     def mamba_layers(self) -> int:
@@ -343,7 +385,8 @@ class ModelConfig:
     def __post_init__(self):
         if self.family not in ("gpt_neox", "qwen2", "llama",
                                "granitemoehybrid", "mellum", "mistral4",
-                               "afmoe", "longcat_flash", "lfm2_moe"):
+                               "afmoe", "longcat_flash", "lfm2_moe",
+                               "keye_vl2"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
@@ -351,12 +394,15 @@ class ModelConfig:
               or self.explicit_head_dim or self.sliding_window
               or self.kv_lora_rank or self.num_dense_layers
               or self.score_func != "softmax" or self.zero_experts
-              or self.rank_scales or self.conv_window):
+              or self.rank_scales or self.conv_window or self.index_topk
+              or self.index_heads or self.index_head_dim
+              or self.mrope_section):
             raise ValueError(
                 f"layer_types / experts / mamba / head width / window / "
-                f"latent / dense-layer / routing / short-convolution fields "
-                f"belong to the granitemoehybrid, mellum, mistral4, afmoe, "
-                f"longcat_flash and lfm2_moe families, not {self.family!r}")
+                f"latent / dense-layer / routing / short-convolution / "
+                f"indexer fields belong to the granitemoehybrid, mellum, "
+                f"mistral4, afmoe, longcat_flash, lfm2_moe and keye_vl2 "
+                f"families, not {self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
@@ -368,7 +414,8 @@ class ModelConfig:
                  "mistral4": ("latent_attention",),
                  "afmoe": ("attention", "sliding_attention"),
                  "longcat_flash": ("latent_attention",),
-                 "lfm2_moe": ("conv", "attention")}[self.family]
+                 "lfm2_moe": ("conv", "attention"),
+                 "keye_vl2": ("sparse_attention",)}[self.family]
         if len(self.layer_types) != self.num_layers * self.sublayers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
@@ -432,6 +479,21 @@ class ModelConfig:
                 self.conv_layers and self.conv_window < 2):
             raise ValueError("conv_window (>= 2 taps) belongs to conv layers, "
                              "and those need it")
+        indexer = (self.index_heads, self.index_head_dim, self.index_topk)
+        if bool(self.sparse_layers) != bool(any(indexer)) or (
+                self.sparse_layers and (min(indexer) < 1
+                                        or self.index_head_dim % 2)):
+            raise ValueError(
+                "index_heads, index_head_dim (even) and index_topk (all >= "
+                "1) belong to sparse_attention layers, and those need them")
+        if self.mrope_section and (
+                not self.sparse_layers
+                or sum(self.mrope_section) * 2 != self.rotary_dim):
+            raise ValueError(
+                f"mrope_section {self.mrope_section!r} must split the "
+                f"{self.rotary_dim // 2} rotary frequencies of a "
+                f"sparse_attention stack's head (it sums to "
+                f"{sum(self.mrope_section)})")
 
 
 # EleutherAI/pythia-70m — facts per SURVEY.md section 2.1 (6 layers, d=512, 8 heads,
@@ -698,6 +760,68 @@ LFM2_8B_A1B = ModelConfig(
 )
 
 
+# Kwai-Keye/Keye-VL-2.0-30B-A3B (30B-A3B, 2026-06) — config.json
+# (``model_type`` ``KeyeVL2``), the language model: 48 layers, every one a
+# sparse-attention layer (32 query / 4 KV heads of 128, q and k normed per
+# head, rotated over all 128 lanes by theta 1e7 in three ``mrope_section``
+# streams that text makes equal; an indexer of 16 heads of 64 lanes against
+# one index key a position, the query attends the top 2048) and 128 routed
+# experts of width 768 top-8 by the softmax over the chosen, none shared;
+# untied 151936-row head. The vision tower is not built.
+KEYE_VL_2_0_30B_A3B = ModelConfig(
+    family="keye_vl2",
+    vocab_size=151936,
+    hidden_size=2048,
+    num_layers=48,
+    num_heads=32,
+    num_kv_heads=4,
+    intermediate_size=6144,  # the published dense width; no layer is dense
+    max_position_embeddings=262144,
+    norm_eps=1e-6,
+    rope_theta=10000000.0,
+    tie_word_embeddings=False,
+    layer_types=("sparse_attention",) * 48,
+    explicit_head_dim=128,
+    num_experts=128,
+    experts_per_tok=8,
+    expert_width=768,
+    index_heads=16,
+    index_head_dim=64,
+    index_topk=2048,
+    mrope_section=(16, 24, 24),
+)
+
+
+def tiny_keye_vl2_config(*, num_layers: int = 2, index_topk: int = 8,
+                         hidden_size: int = 48, num_heads: int = 4,
+                         num_kv_heads: int = 2, head_dim: int = 16,
+                         index_heads: int = 3, index_head_dim: int = 8,
+                         vocab_size: int = 256, num_experts: int = 8,
+                         experts_per_tok: int = 3, experts_held: int = 0,
+                         expert_offset: int = 0,
+                         max_position_embeddings: int = 512) -> ModelConfig:
+    """A small keye_vl2 for tests: every mechanism of the published language
+    model (sparse-attention layers alone, ``H x hd`` = 64 against a hidden
+    size of 48, q/k norms, a three-stream ``mrope_section`` over the head's 8
+    frequencies, an indexer of fewer and narrower heads than the attention's
+    whose ``index_topk`` the test prompts pass, top-k routing by the softmax
+    over the chosen with none shared, an untied head) at toy widths."""
+    return ModelConfig(
+        family="keye_vl2", vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=num_layers, num_heads=num_heads,
+        num_kv_heads=num_kv_heads, intermediate_size=96,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-6,
+        rope_theta=10000000.0, tie_word_embeddings=False,
+        layer_types=("sparse_attention",) * num_layers,
+        explicit_head_dim=head_dim, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32,
+        experts_held=experts_held, expert_offset=expert_offset,
+        index_heads=index_heads, index_head_dim=index_head_dim,
+        index_topk=index_topk,
+        mrope_section=(head_dim // 8, head_dim // 8 + head_dim // 16,
+                       head_dim // 2 - 2 * (head_dim // 8) - head_dim // 16))
+
+
 def tiny_lfm2_moe_config(*, layer_types: tuple = ("conv", "conv", "attention",
                                                   "conv", "conv", "conv"),
                          num_dense_layers: int = 2, conv_window: int = 3,
@@ -876,6 +1000,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_longcat_flash_config()
     if family == "lfm2_moe":
         return tiny_lfm2_moe_config()
+    if family == "keye_vl2":
+        return tiny_keye_vl2_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -907,6 +1033,7 @@ PRESETS = {
     "trinity-mini": TRINITY_MINI,
     "longcat-flash-chat": LONGCAT_FLASH_CHAT,
     "lfm2-8b-a1b": LFM2_8B_A1B,
+    "keye-vl-2.0-30b-a3b": KEYE_VL_2_0_30B_A3B,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
@@ -917,4 +1044,5 @@ PRESETS = {
     "tiny-afmoe": tiny_afmoe_config(),
     "tiny-longcat-flash": tiny_longcat_flash_config(),
     "tiny-lfm2-moe": tiny_lfm2_moe_config(),
+    "tiny-keye-vl2": tiny_keye_vl2_config(),
 }

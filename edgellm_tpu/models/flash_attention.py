@@ -672,7 +672,7 @@ def leading_runs(page_ids, run: int, pages_per_block: int):
 
 
 def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
-                       par_ref, *, scale, window=0, run=1):
+                       par_ref, *, scale, window=0, run=1, keep_ref=None):
     """Grid (B,): slot ``b`` of the step, every head. See
     :func:`paged_decode_walk`."""
     b = pl.program_id(0)
@@ -807,10 +807,22 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
             def attended(shape, axis):
                 return jax.lax.broadcasted_iota(jnp.int32, shape, axis) < live
 
-        s = jnp.where(attended((h, rows), 1), s, -1e30)
+        if keep_ref is None:
+            s = jnp.where(attended((h, rows), 1), s, -1e30)
+        else:
+            # a SELECTION of the live rows (``keep``): a row it leaves out is
+            # fetched with its page and takes no part; a block may hold none
+            # of it, so a row's weight is selected to zero, not left to the
+            # exponent of a maximum no row of the block has raised
+            chosen = attended((h, rows), 1) & (
+                keep_ref[0, :, pl.ds(pl.multiple_of(n * rows, rows), rows)]
+                != 0)
+            s = jnp.where(chosen, s, -1e30)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
+        if keep_ref is not None:
+            p = jnp.where(chosen, p, 0.0)
         # rows no DMA filled are stale VMEM, a ring's rows of the lap before
         # whatever the stream left there: 0 x NaN must not reach the sum
         v = rbuf[buf, :, :, pl.ds(v_at, w)].reshape(rows, w)
@@ -835,7 +847,7 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
 def paged_decode_walk(qz, pages, page_ids, lengths, *,
                       scale: float, pages_per_block: int | None = None,
                       interpret=False, window: int = 0,
-                      run_pages: int | None = None, lead=None):
+                      run_pages: int | None = None, lead=None, keep=None):
     """Single-position attention of every slot over ITS OWN live pages, read
     out of the pool where they lie: ONE kernel in place of "gather every
     slot's whole span into a copy, then two dots over the copy".
@@ -886,6 +898,13 @@ def paged_decode_walk(qz, pages, page_ids, lengths, *,
     change with a layer's offset, so a step's layers can share one
     (``paged_kv.attend_pages``).
 
+    ``keep`` (None: nothing of the following is traced; no ``window``): (B,
+    E * ps) int32, nonzero at the positions of a slot's table, in table
+    order, that its query ATTENDS (a sparse-attention layer's selection,
+    ``models/sparse_attn.py``): the walk fetches every live page as before
+    and a live row the selection leaves out takes no part in the softmax.
+    The mask rides in VMEM a slot at a time, a row of ``E * ps`` lanes.
+
     Scalar prefetch puts ``page_ids`` and ``lengths`` (with, behind them,
     the table of each block's leading runs) in SMEM before the body runs."""
     b, h, w = qz.shape
@@ -905,12 +924,31 @@ def paged_decode_walk(qz, pages, page_ids, lengths, *,
         lead = leading_runs(page_ids, run, ppb) if lead is None else lead
         lengths = jnp.concatenate([lengths, lead.reshape(-1)])
     slot = pl.BlockSpec((1, h, w), lambda i, ids, lens: (i, 0, 0))
+    kernel = functools.partial(_paged_walk_kernel, scale=scale, window=window,
+                               run=run)
+    masked = ()
+    if keep is not None:
+        if window or keep.shape != (b, page_ids.shape[1] * ps):
+            raise ValueError(
+                f"keep {keep.shape} must mark every position of a prefix's "
+                f"table ({b}, {page_ids.shape[1] * ps}); a ring takes none")
+        # padded to whole blocks: the last block's slice stays inside
+        span = -(-keep.shape[1] // (ppb * ps)) * ppb * ps
+        masked = (jnp.pad(keep.astype(jnp.int32),
+                          ((0, 0), (0, span - keep.shape[1])))[:, None],)
+        inner = kernel
+
+        def kernel(ids_ref, len_ref, q_ref, hbm, keep_ref, *rest):
+            return inner(ids_ref, len_ref, q_ref, hbm, *rest,
+                         keep_ref=keep_ref)
+
     return pl.pallas_call(
-        functools.partial(_paged_walk_kernel, scale=scale, window=window,
-                          run=run),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[slot, pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[slot, pl.BlockSpec(memory_space=pl.ANY)] + [
+                pl.BlockSpec((1, 1, a.shape[-1]),
+                             lambda i, ids, lens: (i, 0, 0)) for a in masked],
             out_specs=slot,
             scratch_shapes=[pltpu.VMEM((2, ppb, ps, r), pages.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
@@ -920,4 +958,4 @@ def paged_decode_walk(qz, pages, page_ids, lengths, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_walk",
-    )(page_ids, lengths, qz, pages)
+    )(page_ids, lengths, qz, pages, *masked)

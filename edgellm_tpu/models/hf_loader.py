@@ -98,6 +98,8 @@ def params_from_state_dict(cfg: ModelConfig, sd: dict) -> dict:
         return _neox_params(cfg, sd)
     if cfg.family == "lfm2_moe":
         return _lfm2_moe_params(cfg, sd)
+    if cfg.family == "keye_vl2":
+        return _keye_vl2_params(cfg, sd)
     if cfg.is_hybrid:
         raise ValueError(
             f"no state_dict mapping for family {cfg.family!r}: its parameters "
@@ -263,6 +265,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         return _longcat_flash_config(hf_config)
     if mt == "lfm2_moe":
         return _lfm2_moe_config(hf_config)
+    if mt == "KeyeVL2":
+        return _keye_vl2_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -609,4 +613,138 @@ def _lfm2_moe_params(cfg: ModelConfig, sd: dict) -> dict:
                            lambda w: w),
         },
         "moe": [ffn(i) for i in range(cfg.num_layers)],
+    }
+
+
+def _keye_vl2_config(hf_config) -> ModelConfig:
+    """Kwai Keye-VL 2.0 (``model_type`` ``KeyeVL2``), the language model. The
+    keys mapped: ``head_dim`` (explicit), ``rope_theta``,
+    ``rope_scaling.mrope_section`` (kept and checked: it must split the
+    head's ``head_dim / 2`` frequencies; text positions are equal in the
+    three streams, so the program rotates by the plain table),
+    ``moe_intermediate_size``, ``num_experts``, ``num_experts_per_tok``, and
+    ``sa_config``'s six: ``indexer_num_heads``, ``indexer_head_dim``,
+    ``topk``; ``indexer_num_kv_heads`` (1 alone: one index key a position);
+    ``q_chunk_size`` / ``kv_chunk_size`` (the tiles the published prefill
+    scores in, which change no sum: positive, and otherwise unused; the
+    program's own block is ``flash_attention.QBLOCK`` query rows). Refused
+    by name: ``use_sliding_window`` true, a ``rope_scaling`` of another type
+    than ``default``, ``decoder_sparse_step`` other than 1, a non-empty
+    ``mlp_only_layers`` (either would make a dense layer of
+    ``intermediate_size``), ``norm_topk_prob`` false, ``attention_bias``
+    true, a tied head. The vision tower's keys are not read: text alone."""
+    for key, want in (("use_sliding_window", False), ("norm_topk_prob", True),
+                      ("decoder_sparse_step", 1), ("attention_bias", False),
+                      ("tie_word_embeddings", False)):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"KeyeVL2 with {key}={getattr(hf_config, key)!r} is not "
+                f"supported (only {want!r})")
+    if list(getattr(hf_config, "mlp_only_layers", None) or ()):
+        raise ValueError(
+            f"KeyeVL2 with mlp_only_layers={hf_config.mlp_only_layers!r} is "
+            f"not supported (every layer routed: only [])")
+    rope = dict(hf_config.rope_scaling)
+    if rope.get("rope_type", rope.get("type", "default")) != "default":
+        raise ValueError(f"KeyeVL2 with rope_scaling={rope!r} is not "
+                         f"supported (only rope_type 'default')")
+    section = tuple(int(x) for x in rope["mrope_section"])
+    if 2 * sum(section) != int(hf_config.head_dim):
+        raise ValueError(
+            f"KeyeVL2 mrope_section {list(section)} sums to {sum(section)}, "
+            f"not to head_dim / 2 = {int(hf_config.head_dim) // 2}")
+    sa = dict(hf_config.sa_config)
+    if int(sa["indexer_num_kv_heads"]) != 1:
+        raise ValueError(
+            f"KeyeVL2 with sa_config.indexer_num_kv_heads="
+            f"{sa['indexer_num_kv_heads']!r} is not supported (only 1: one "
+            f"index key a position)")
+    for key in ("q_chunk_size", "kv_chunk_size"):
+        if int(sa[key]) < 1:
+            raise ValueError(f"KeyeVL2 sa_config.{key} must be >= 1, got "
+                             f"{sa[key]!r}")
+    return ModelConfig(
+        family="keye_vl2",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(hf_config.rope_theta),
+        tie_word_embeddings=False,
+        layer_types=("sparse_attention",) * hf_config.num_hidden_layers,
+        explicit_head_dim=int(hf_config.head_dim),
+        num_experts=int(hf_config.num_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+        index_heads=int(sa["indexer_num_heads"]),
+        index_head_dim=int(sa["indexer_head_dim"]),
+        index_topk=int(sa["topk"]),
+        mrope_section=section,
+    )
+
+
+def _keye_vl2_params(cfg: ModelConfig, sd: dict) -> dict:
+    """``models/hybrid.py``'s per-kind tree from a ``KeyeVL2`` state_dict's
+    language model, all the experts held. Tensor names ASSUMED (no checkpoint
+    can be fetched here): the Qwen3-MoE lineage's for what the config shares
+    with it, ``model.layers.N.`` ``input_layernorm`` /
+    ``post_attention_layernorm``, ``self_attn.{q,k,v,o}_proj``,
+    ``self_attn.q_norm`` / ``k_norm``, ``mlp.gate`` (the router),
+    ``mlp.experts.M.{gate,up,down}_proj``, ``model.norm``, ``lm_head``; the
+    indexer's after the DeepSeek sparse-attention lineage,
+    ``self_attn.indexer.{wq,wk}`` (no query bottleneck: the config has no
+    rank for one), ``self_attn.indexer.k_norm`` (``weight`` and ``bias``) and
+    ``self_attn.indexer.weights_proj``. Refused by name: a bias on a
+    projection."""
+    if cfg.experts_held:
+        raise ValueError("the KeyeVL2 state_dict mapping holds every expert")
+    for name in sd:
+        if name.endswith("_proj.bias"):
+            raise ValueError(f"KeyeVL2 with a projection bias ({name}) is "
+                             f"not supported (attention_bias false only)")
+    pre = "model.layers.{i}."
+    n = cfg.num_layers
+
+    def rows(suffix, transform=lambda w: w.T):
+        return jnp.asarray(np.stack([
+            transform(_np(sd[pre.format(i=i) + suffix])) for i in range(n)]))
+
+    def keep(w):
+        return w
+
+    def ffn(i):
+        ff = pre.format(i=i) + "mlp."
+        experts = {k: jnp.asarray(np.stack([
+            _np(sd[f"{ff}experts.{e}.{name}.weight"]).T
+            for e in range(cfg.num_experts)]))
+            for k, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                            ("w_down", "down_proj"))}
+        return {"ln2_scale": jnp.asarray(_np(
+                    sd[pre.format(i=i) + "post_attention_layernorm.weight"])),
+                "router": jnp.asarray(_np(sd[ff + "gate.weight"]).T),
+                **experts}
+
+    return {
+        "embed": jnp.asarray(_np(sd["model.embed_tokens.weight"])),
+        "final_norm_scale": jnp.asarray(_np(sd["model.norm.weight"])),
+        "lm_head": jnp.asarray(_np(sd["lm_head.weight"]).T),
+        "sparse": {
+            "ln1_scale": rows("input_layernorm.weight", keep),
+            "wq": rows("self_attn.q_proj.weight"),
+            "wk": rows("self_attn.k_proj.weight"),
+            "wv": rows("self_attn.v_proj.weight"),
+            "wo": rows("self_attn.o_proj.weight"),
+            "q_norm": rows("self_attn.q_norm.weight", keep),
+            "k_norm": rows("self_attn.k_norm.weight", keep),
+            "wq_index": rows("self_attn.indexer.wq.weight"),
+            "wk_index": rows("self_attn.indexer.wk.weight"),
+            "index_norm_scale": rows("self_attn.indexer.k_norm.weight", keep),
+            "index_norm_bias": rows("self_attn.indexer.k_norm.bias", keep),
+            "w_index": rows("self_attn.indexer.weights_proj.weight"),
+        },
+        "moe": [ffn(i) for i in range(n)],
     }

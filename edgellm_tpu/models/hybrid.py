@@ -1,6 +1,6 @@
 """A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum``,
-``mistral4``, ``afmoe``, ``longcat_flash`` and ``lfm2_moe`` families' forward
-and steps.
+``mistral4``, ``afmoe``, ``longcat_flash``, ``lfm2_moe`` and ``keye_vl2``
+families' forward and steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -40,7 +40,12 @@ layers that rotate and hold ``q_norm`` / ``k_norm``; its leading ``moe``
 entries dense, the rest routed with a ``router_bias`` and no shared expert, a
 tied head. What a stack's recurrent kinds keep for a sequence is
 :func:`state_shapes`'s to say: the contiguous cache and the paged state store
-hold those leaves and no other.
+hold those leaves and no other. A ``keye_vl2`` stack (Kwai Keye-VL 2.0's
+language model) has one kind, ``sparse`` (``models/sparse_attn.py``): a
+rotated, q/k-normed GQA layer that also caches an INDEX KEY a position (the
+page pool's second leaf, ``paged_kv.IndexedPagePool``) and whose query
+attends the ``index_topk`` positions its indexer scores highest; routed
+experts alone, an untied head.
 
 (the expert weights are a list, not a stack: a row sliced from a ``(L, E, D,
 F)`` stack for a prefill's grouped products, whose operands must be whole
@@ -60,7 +65,8 @@ drivers), the split runtime, speculation, prefix sharing, quantized KV tiers,
 checkpoints: each needs a snapshot of the recurrent state that does not exist
 (:func:`refuse_recurrent_state`), or reads "a slot's K/V = every position of
 every layer": not a ring (:func:`refuse_window_ring`; the decode's page walk
-takes one, masked by position), nor a latent row (:func:`refuse_latent_rows`);
+takes one, masked by position), nor a latent row (:func:`refuse_latent_rows`), nor rows with an index key
+beside them that a query reads a selection of (:func:`refuse_index_keys`);
 all: :func:`refuse_beyond_kv_rows`."""
 from __future__ import annotations
 
@@ -71,13 +77,14 @@ import jax.numpy as jnp
 
 from ..lint import graph_contract
 from .configs import ModelConfig
-from . import mla
+from . import mla, sparse_attn
 from .flash_attention import (MAX_BLOCKED_S, QBLOCK, causal_attention,
                               decode_attention, kernel_plan)
 from .mamba2 import mamba2_prefill, mamba2_step
 from .shortconv import shortconv_prefill, shortconv_step
 from .moe import moe_layer
-from .paged_kv import (LatentPool, PagePool, _attention_decode_latent,
+from .paged_kv import (IndexedPagePool, LatentPool, PagePool,
+                       _attention_decode_latent,
                        _attention_decode_paged, _attention_decode_window,
                        attend_latent, gated, head_norms, post_norm)
 from .transformer import _rmsnorm, apply_rotary, mlp, precompute_rope
@@ -143,12 +150,33 @@ def refuse_latent_rows(cfg: ModelConfig, what: str) -> None:
             f"each; there is no fallback")
 
 
+class IndexKeysUnsupported(ValueError):
+    """A mechanism that moves or reads a sequence's cache as K and V rows
+    alone was asked to serve a family whose layers also keep an index key a
+    position and attend the positions it selects."""
+
+
+def refuse_index_keys(cfg: ModelConfig, what: str) -> None:
+    """Raise for a config with sparse-attention layers: ``what`` names the
+    mechanism refusing."""
+    if cfg.sparse_layers:
+        raise IndexKeysUnsupported(
+            f"{what} does not support family {cfg.family!r}: its "
+            f"{cfg.sparse_layers} sparse-attention layers keep an index key "
+            f"a position ({cfg.index_head_dim} lanes, stored "
+            f"{cfg.index_row_lanes} wide) in a second leaf of the page pool "
+            f"beside the K/V rows and attend the {cfg.index_topk} positions "
+            f"it selects, and {what} is written for a cache of K and V rows "
+            f"alone that every query reads whole; there is no fallback")
+
+
 def refuse_beyond_kv_rows(cfg: ModelConfig, what: str) -> None:
     """What a mechanism that handles plain per-layer K/V rows alone calls:
-    the three refusals above, each in its own words."""
+    the four refusals above, each in its own words."""
     refuse_recurrent_state(cfg, what)
     refuse_window_ring(cfg, what)
     refuse_latent_rows(cfg, what)
+    refuse_index_keys(cfg, what)
 
 
 class HybridCache(NamedTuple):
@@ -180,6 +208,22 @@ class WindowCache(NamedTuple):
     length: jnp.ndarray
     wk: jnp.ndarray
     wv: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[2]
+
+
+class SparseCache(NamedTuple):
+    """The contiguous decode cache of a stack of sparse-attention layers.
+
+    k, v: (L, B, capacity, KV, hd); length: () int32; index: (L, B, capacity,
+    index_row_lanes), a position's index key as the page pool stores it."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    length: jnp.ndarray
+    index: jnp.ndarray
 
     @property
     def capacity(self) -> int:
@@ -228,7 +272,7 @@ def _row(tree: dict, j: int) -> dict:
 def _kinds(cfg: ModelConfig):
     """(layer, kind, index among its kind) down the stack."""
     seen = {"mamba": 0, "conv": 0, "attention": 0, "sliding_attention": 0,
-            "latent_attention": 0}
+            "latent_attention": 0, "sparse_attention": 0}
     for layer, kind in enumerate(cfg.layer_types):
         yield layer, kind, seen[kind]
         seen[kind] += 1
@@ -262,6 +306,9 @@ def _rope_tables(cfg: ModelConfig, n: int) -> dict:
     one (YaRN) on full layers and the plain one on sliding layers."""
     if cfg.latent_layers:  # the rope lanes' table (cfg.rotary_dim wide)
         return {"latent_attention": precompute_rope(cfg, n)}
+    if cfg.sparse_layers:  # the heads' table, and the indexer's narrower one
+        return {"sparse_attention": precompute_rope(cfg, n),
+                "sparse_index": sparse_attn.index_rope(cfg, n)}
     free = cfg.position_free
     return {"attention": (None if "attention" in free
                           else precompute_rope(cfg, n)),
@@ -409,7 +456,7 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
     """Whole sequences through the stack. Returns (hidden (B, S, D), per-kind
     lists of what a decode cache is filled from when ``collect``)."""
     h, term = embed_hybrid(cfg, params, ids), None   # term: _shortcut's
-    ks, vs, wks, wvs, lat = [], [], [], [], []
+    ks, vs, wks, wvs, lat, iks = [], [], [], [], [], []
     state = {leaf: [] for leaf in state_shapes(cfg, 0)}
     rope = _rope_tables(cfg, ids.shape[1])
     for layer, kind, j in _kinds(cfg):
@@ -419,6 +466,15 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind])
             if collect:
                 lat.append(rows)
+        elif kind == "sparse_attention":
+            lp = _row(params["sparse"], j)
+            out, k, v, ik = sparse_attn.attention_full(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind],
+                rope["sparse_index"])
+            if collect:
+                ks.append(k)
+                vs.append(v)
+                iks.append(ik)
         elif kind == "mamba":
             lp = _row(params["mamba"], j)
             out, conv, ssm = mamba2_prefill(
@@ -450,7 +506,7 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
         h = h + cfg.residual_multiplier * out
         g, _ = _ffn(cfg, params["moe"][layer], h)
         h, term, _ = _shortcut(cfg, params["moe"][layer], h, g, term)
-    return h, (ks, vs, state, wks, wvs, lat)
+    return h, (ks, vs, state, wks, wvs, lat, iks)
 
 
 def forward_hybrid(cfg: ModelConfig, params: dict, ids):
@@ -467,13 +523,14 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
                    last_only: bool = False):
     """The prompt's forward that also fills the decode cache: (logits (B, S,
     V) float32 — (B, V) of the last position with ``last_only`` —, a
-    :class:`HybridCache`, :class:`WindowCache` or :class:`LatentCache`)."""
+    :class:`HybridCache`, :class:`WindowCache`, :class:`LatentCache` or
+    :class:`SparseCache`)."""
     b, s = ids.shape
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
                          f"{capacity}]")
-    h, (ks, vs, state, wks, wvs, lat) = _walk_full(cfg, params, ids,
-                                                   collect=True)
+    h, (ks, vs, state, wks, wvs, lat, iks) = _walk_full(cfg, params, ids,
+                                                        collect=True)
     logits = unembed_hybrid(cfg, params, h[:, -1] if last_only else h)
     if lat:
         return logits, LatentCache(
@@ -487,6 +544,9 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
     if cfg.window_layers:
         return logits, WindowCache(k, v, length, jnp.pad(jnp.stack(wks), pad),
                                    jnp.pad(jnp.stack(wvs), pad))
+    if iks:
+        return logits, SparseCache(k, v, length,
+                                   jnp.pad(jnp.stack(iks), pad[:-1]))
     return logits, HybridCache(k, v, length,
                                {leaf: jnp.stack(rows)
                                 for leaf, rows in state.items()})
@@ -502,6 +562,8 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
     if isinstance(cache, LatentCache):
         return _decode_step_latent(cfg, params, cache, h)
+    if isinstance(cache, SparseCache):
+        return _decode_step_sparse(cfg, params, cache, h)
     windowed = isinstance(cache, WindowCache)
     state = None if windowed else cache.state
     # the rows a kind's layers append to: full layers k / v, sliding wk / wv
@@ -557,6 +619,27 @@ def _decode_step_latent(cfg: ModelConfig, params: dict, cache: LatentCache,
     return unembed_hybrid(cfg, params, h), LatentCache(rows, pos + 1)
 
 
+def _decode_step_sparse(cfg: ModelConfig, params: dict, cache: SparseCache,
+                        h):
+    """:func:`decode_step_hybrid` for a stack of sparse-attention layers: h
+    (B, D) the embedded tokens."""
+    pos, (k, v, _, index) = cache.length, cache
+    rope = {kind: tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1) for x in t)
+            for kind, t in _rope_tables(cfg, cache.capacity).items()}
+    for layer, kind, j in _kinds(cfg):
+        lp = _row(params["sparse"], j)
+        out, k_j, v_j, index_j = sparse_attn.attention_decode_rows(
+            cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind],
+            rope["sparse_index"], k[j], v[j], index[j], pos)
+        k, v, index = (a.at[j].set(r) for a, r in
+                       ((k, k_j), (v, v_j), (index, index_j)))
+        h = h + cfg.residual_multiplier * out
+        h, _ = _ffn(cfg, params["moe"][layer], h)
+    return unembed_hybrid(cfg, params, h), SparseCache(k, v, pos + 1, index)
+
+
+@graph_contract("paged.decode_step_sparse", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 3))
 @graph_contract("paged.decode_step_latent", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 2))
 @graph_contract("paged.decode_step_hybrid", collectives={},
@@ -586,14 +669,18 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
     A stack with sliding layers also passes ``window`` = (win (L_window,
     window pool pages, page_size, 2 * KV * hd), the window group's leaf,
     window_table (max_slots, window_pages): each slot's ring) and gets win
-    back as a fifth result; it has no recurrent layer."""
+    back as a fifth result; it has no recurrent layer. A stack of
+    sparse-attention layers hands over its ``paged_kv.IndexedPagePool``
+    WHOLE as ``pool`` (both leaves: the K/V rows and the index keys) and gets
+    it back so."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
     counts, term = [], None
     # each slot's own row of the table its layer kind rotates by
-    span = page_table.shape[1] * pool.shape[2]
+    span = page_table.shape[1] * (
+        pool.page_size if isinstance(pool, IndexedPagePool) else pool.shape[2])
     rope = {kind: t and (t[0][lengths], t[1][lengths])
             for kind, t in _rope_tables(cfg, span).items()}
     if window is not None:
@@ -604,6 +691,11 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
             out, (pool,) = _attention_decode_latent(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]), *rope[kind],
                 LatentPool(pool), j, page_table, lengths)
+        elif kind == "sparse_attention":
+            lp = _row(params["sparse"], j)
+            out, pool = sparse_attn.attention_decode_paged(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind],
+                rope["sparse_index"], pool, j, page_table, lengths)
         elif kind in ("mamba", "conv"):
             state, out = _step_row(cfg, kind, _row(params[kind], j), h, state,
                                    j)
@@ -648,7 +740,11 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     that dropped it would pass); a stack of sublayers: :func:`_sub_ffns`. An
     ``lfm2_moe`` stack's ``attn`` holds the two head norms alone; its
     ``conv`` kind's taps are uniform in +-1/sqrt(taps), as a depthwise
-    convolution is initialised upstream, so that the window matters."""
+    convolution is initialised upstream, so that the window matters. A
+    ``keye_vl2`` stack's ``sparse`` kind holds an attention layer's leaves
+    with the two head norms, and the indexer's: ``wq_index``, ``wk_index``,
+    the index key's LayerNorm (``index_norm_scale`` one, ``index_norm_bias``
+    zero) and ``w_index``."""
     keys = iter(jax.random.split(key, 16 + 8 * len(cfg.layer_types)))
 
     def init(*shape):
@@ -660,7 +756,7 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     eh, f, fs = cfg.local_experts, cfg.expert_width, cfg.shared_width
 
     afmoe = cfg.family == "afmoe"
-    head_normed = afmoe or cfg.family == "lfm2_moe"
+    head_normed = afmoe or cfg.family in ("lfm2_moe", "keye_vl2")
 
     def attention(n):
         return {
@@ -727,6 +823,15 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
             "wkv_b": init(n, rank, h * (cfg.qk_nope_head_dim
                                         + cfg.v_head_dim)),
             "wo": init(n, h * cfg.v_head_dim, d),
+        }
+    elif cfg.sparse_layers:
+        n, hi, di = cfg.sparse_layers, cfg.index_heads, cfg.index_head_dim
+        params["sparse"] = {
+            **attention(n),
+            "wq_index": init(n, d, hi * di), "wk_index": init(n, d, di),
+            "index_norm_scale": jnp.ones((n, di), dtype),
+            "index_norm_bias": jnp.zeros((n, di), dtype),
+            "w_index": init(n, d, hi),
         }
     else:
         params["attn"] = attention(la)

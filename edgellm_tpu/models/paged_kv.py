@@ -414,12 +414,42 @@ class LatentPool(NamedTuple):
         return self.rows.shape[-2]
 
 
+class IndexedPagePool(NamedTuple):
+    """Device-side page pool of a stack of sparse-attention layers
+    (``models/sparse_attn.py``): TWO leaves that share one page table.
+
+    kv: a :class:`PagePool`'s leaf, a position's K then V lanes; ik: (...,
+    num_pages, page_size, cfg.index_row_lanes), the position's INDEX KEY (the
+    one key the layer's indexer scores it by: ``index_head_dim`` lanes, then
+    zeros up to whole 128-lane tiles). Row r of page p of layer l is one
+    position in both, so pages, table, trash page, flat index and every piece
+    of surgery below (written once over a pool's leaves) are
+    :class:`PagePool`'s: an adopt, a gather, a fork or a defrag moves a
+    position's K/V row and its index key together."""
+
+    kv: jnp.ndarray
+    ik: jnp.ndarray
+
+    @property
+    def num_pages(self) -> int:
+        return self.kv.shape[-3]
+
+    @property
+    def page_size(self) -> int:
+        return self.kv.shape[-2]
+
+    @property
+    def k_lanes(self) -> int:
+        return self.kv.shape[-1] // 2
+
+
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
               dtype=jnp.float32, layers: Optional[int] = None):
     """An all-zero pool; ``num_pages`` INCLUDES the reserved trash page 0,
     so ``num_pages - 1`` pages are allocatable. ``layers``: how many layers
     it serves where that is not ``cfg.kv_layers`` (the window group's). A
-    :class:`PagePool`, or a :class:`LatentPool` for latent layers."""
+    :class:`PagePool`, a :class:`LatentPool` for latent layers, an
+    :class:`IndexedPagePool` for sparse-attention layers."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is reserved), "
                          f"got {num_pages}")
@@ -429,7 +459,11 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
             page_size)
     if cfg.latent_layers:
         return LatentPool(jnp.zeros(rows + (cfg.kv_row_lanes,), dtype))
-    return PagePool(jnp.zeros(rows + (2 * cfg.kv_row_lanes,), dtype))
+    kv = jnp.zeros(rows + (2 * cfg.kv_row_lanes,), dtype)
+    if cfg.sparse_layers:
+        return IndexedPagePool(
+            kv, jnp.zeros(rows + (cfg.index_row_lanes,), dtype))
+    return PagePool(kv)
 
 
 # ---------------------------------------------------------------------------
@@ -540,7 +574,7 @@ def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
 def pool_tier(pool) -> str:
     """The ``kv_codec`` name of a pool (whole, staged or one layer's): the
     one place a tier is read from, its type and the width of its codes."""
-    if isinstance(pool, (PagePool, LatentPool)):
+    if isinstance(pool, (PagePool, LatentPool, IndexedPagePool)):
         return "fp"
     return next(c.name for c in KV_PAGE_CODECS.values()
                 if c.quantized and pool.k.dtype == c.code_dtype)
@@ -549,14 +583,25 @@ def pool_tier(pool) -> str:
 def _k_lanes(pool) -> int:
     """Lanes of a K row (as many of a V row) of a K/V pool at its tier; of a
     latent pool's whole row."""
-    return pool.k_lanes if isinstance(pool, PagePool) else pool[0].shape[-1]
+    return (pool.k_lanes if isinstance(pool, (PagePool, IndexedPagePool))
+            else pool[0].shape[-1])
 
 
 def kv_page_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
                   dtype=jnp.float32) -> int:
-    """HBM bytes ONE page costs across all layers (K + V, codes + scales) —
-    the honest per-tier footprint the capacity accounting below divides by."""
+    """HBM bytes ONE page costs across all layers (K + V, codes + scales; a
+    sparse-attention stack's index keys, as stored, with them) — the honest
+    per-tier footprint the capacity accounting below divides by."""
     codec = resolve_kv_codec(kv_codec)
+    if cfg.sparse_layers:
+        if codec.quantized:
+            from .hybrid import refuse_index_keys
+
+            refuse_index_keys(cfg, f"the quantized KV tier kv_codec="
+                                   f"{kv_codec!r}")
+        return (cfg.kv_layers * page_size
+                * (2 * cfg.kv_row_lanes + cfg.index_row_lanes)
+                * jnp.dtype(dtype).itemsize)
     if cfg.latent_layers:
         if codec.quantized:
             from .hybrid import refuse_latent_rows
@@ -587,9 +632,14 @@ def page_leaf_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
     """HBM bytes of ONE page of one layer (a position's K and V rows, or its
     latent row, ``page_size`` times): what a DMA of the page walk moves, and
     what the length of a run is read off
-    (``flash_attention.walk_run_pages``)."""
+    (``flash_attention.walk_run_pages``). Of an :class:`IndexedPagePool` the
+    K/V leaf's page, which is the one a walk fetches: the index keys' page is
+    :func:`kv_page_bytes`' to count, and no walk's to move."""
     layers = cfg.kv_layers if cfg.latent_layers else cfg.num_layers
-    return kv_page_bytes(cfg, page_size, kv_codec, dtype) // layers
+    page = kv_page_bytes(cfg, page_size, kv_codec, dtype) // layers
+    if cfg.sparse_layers:
+        page -= page_size * cfg.index_row_lanes * jnp.dtype(dtype).itemsize
+    return page
 
 
 # ---------------------------------------------------------------------------
@@ -704,7 +754,8 @@ def page_head(dest, page_size: int) -> int:
     return int(-int(dest[0]) % page_size) if len(dest) else 0
 
 
-def adopt_at(pool, k_seq, v_seq, dest, lead: int, head: Optional[int] = None):
+def adopt_at(pool, k_seq, v_seq, dest, lead: int, head: Optional[int] = None,
+             index=None):
     """Put contiguous (..., L, S, KV, hd) fp K/V rows at the flat token
     indices ``dest`` (S,) of every layer: stored as they are on the fp tier
     (the KV heads merged into one minor vector, K's then V's joined into the
@@ -713,12 +764,19 @@ def adopt_at(pool, k_seq, v_seq, dest, lead: int, head: Optional[int] = None):
     ``l*P*ps + dest[i]`` of the leaf viewed (..., L*P*ps, KV*lanes); with
     two leading axes the sharded stage axis stays sliced and only
     ``stage_size`` folds into the index. S and ``head`` (:func:`_set_rows`)
-    are static per call (one executable per adopted length)."""
+    are static per call (one executable per adopted length). ``index``: the
+    (..., L, S, index_row_lanes) index keys of the same positions as stored,
+    which an :class:`IndexedPagePool` takes into its second leaf by the same
+    scatter (and no other pool takes)."""
     tier = pool_tier(pool)
+    if isinstance(pool, IndexedPagePool) != (index is not None):
+        raise ValueError(
+            f"a {type(pool).__name__} adopts K/V rows "
+            f"{'WITH' if index is None else 'without'} index keys")
     if tier == "fp":
         return _set_rows(
-            pool, (join_kv(_merge_heads(k_seq), _merge_heads(v_seq)),),
-            dest, lead, head)
+            pool, (join_kv(_merge_heads(k_seq), _merge_heads(v_seq)),)
+            + (() if index is None else (index,)), dest, lead, head)
     qk, sk = quantize_kv_rows(k_seq, tier)
     qv, sv = quantize_kv_rows(v_seq, tier)
     return _set_rows(pool, (_merge_heads(qk), _merge_heads(qv), sk, sv),
@@ -726,8 +784,9 @@ def adopt_at(pool, k_seq, v_seq, dest, lead: int, head: Optional[int] = None):
 
 
 @functools.partial(jax.jit, static_argnames=("head",), donate_argnums=(0,))
-def _adopt_impl(pool, k_seq, v_seq, dest, head: Optional[int] = None):
-    return adopt_at(pool, k_seq, v_seq, dest, 1, head)
+def _adopt_impl(pool, k_seq, v_seq, dest, head: Optional[int] = None,
+                index=None):
+    return adopt_at(pool, k_seq, v_seq, dest, 1, head, index)
 
 
 @functools.partial(jax.jit, static_argnames=("head",), donate_argnums=(0,))
@@ -777,14 +836,15 @@ def _gather_impl(pool, idx, lead: int = 1, *, kv: int):
     byte-identical to what was adopted on the fp tier, DEQUANTIZED to fp32 on
     the others (the suffix-prefill compute path, which needs fp rows; lossy
     by exactly the tier's quantization error). ``kv`` is what an fp pool's
-    merged row cannot say."""
+    merged row cannot say. An :class:`IndexedPagePool` hands back a third
+    array, the rows' index keys as stored (..., L, span, index_row_lanes)."""
     tier = pool_tier(pool)
     rows = _get_rows(pool, idx, lead)
     # the fp tier: one gather of the joined rows, then K | V on lanes
     k, v, *scales = split_kv(rows[0]) if tier == "fp" else rows
     k, v = _split_heads(k, kv), _split_heads(v, kv)
     if tier == "fp":
-        return k, v
+        return (k, v, *rows[1:])
     return (dequantize_kv_rows(k, scales[0], tier),
             dequantize_kv_rows(v, scales[1], tier))
 
@@ -1611,28 +1671,41 @@ class PagedKVCache:
     def _refuse_window(self, what: str) -> None:
         """Refuse a mechanism that reads the pool as K and V of every
         position (the name is older than the latent rows)."""
-        from .hybrid import refuse_latent_rows, refuse_window_ring
+        from .hybrid import (refuse_index_keys, refuse_latent_rows,
+                             refuse_window_ring)
 
         refuse_window_ring(self.cfg, what)
         refuse_latent_rows(self.cfg, what)
+        refuse_index_keys(self.cfg, what)
+
+    def _refuse_index(self, what: str) -> None:
+        """Refuse a mechanism that moves a range of a slot's K/V rows and
+        knows no index key."""
+        from .hybrid import refuse_index_keys
+
+        refuse_index_keys(self.cfg, what)
 
     def _flat_indices(self, slot: int, n: int) -> np.ndarray:
         pos = np.arange(n)
         return (self.page_table[slot, pos // self.page_size]
                 * self.page_size + pos % self.page_size).astype(np.int32)
 
-    def adopt(self, slot: int, k_seq, v_seq, length: int) -> None:
+    def adopt(self, slot: int, k_seq, v_seq, length: int,
+              index=None) -> None:
         """Write a contiguous (L, length, KV, hd) post-rotary K/V prefix
         (a prefill's cache, or a restored checkpoint) into ``slot``'s pages
         and set its length. Allocates pages as needed; any shared page in
         the range is COW-forked first (no device copy — every row the fork
-        exposes is overwritten here, and rows past ``length`` stay masked)."""
+        exposes is overwritten here, and rows past ``length`` stay masked).
+        ``index``: the positions' (L, length, index_row_lanes) index keys, as
+        stored, where the pool keeps them (:class:`IndexedPagePool`)."""
         self._require_pool("adopt")
         self.ensure(slot, length)
         self.prepare_write(slot, length, start=0)
         dest = jnp.asarray(self._flat_indices(slot, length))
-        self.pool = _adopt_impl(self.pool, jnp.asarray(k_seq),
-                                jnp.asarray(v_seq), dest, head=0)
+        self.pool = _adopt_impl(
+            self.pool, jnp.asarray(k_seq), jnp.asarray(v_seq), dest, head=0,
+            index=None if index is None else jnp.asarray(index))
         self.lengths[slot] = length
 
     def adopt_latent(self, slot: int, rows, length: int) -> None:
@@ -1655,6 +1728,7 @@ class PagedKVCache:
         shared rows below ``start`` stay aliased. ``start`` must equal the
         slot's current length (the shared-prefix claim)."""
         self._require_pool("adopt_rows")
+        self._refuse_index("adopt_rows (a prefix hit's suffix)")
         if start != int(self.lengths[slot]):
             raise ValueError(f"adopt_rows start {start} != slot {slot} "
                              f"length {int(self.lengths[slot])}")
@@ -1732,7 +1806,9 @@ class PagedKVCache:
         tier; on quantized tiers the rows come back DEQUANTIZED to fp32
         (the suffix-prefill compute path — use :meth:`gather_slot_packed`
         when the bytes themselves must survive). A latent stack's payload is
-        {"rows": (L, length, kv_row_lanes), "length"}, bytes as stored."""
+        {"rows": (L, length, kv_row_lanes), "length"}, bytes as stored; a
+        sparse-attention stack's adds "index": (L, length, index_row_lanes),
+        what :meth:`adopt` takes back as ``index``."""
         self._require_pool("gather_slot")
         n = int(self.lengths[slot])
         idx = jnp.asarray(self._flat_indices(slot, max(n, 1)))
@@ -1741,8 +1817,10 @@ class PagedKVCache:
             return {"rows": np.asarray(_gather_latent_impl(self.pool,
                                                            idx))[:, :n],
                     "length": np.asarray(n, np.int32)}
-        k, v = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
+        k, v, *index = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
         return {"k": np.asarray(k)[:, :n], "v": np.asarray(v)[:, :n],
+                # a sparse-attention stack's index keys, as stored
+                **{"index": np.asarray(a)[:, :n] for a in index},
                 "length": np.asarray(n, np.int32)}
 
     def gather_slot_packed(self, slot: int) -> dict:
@@ -1774,6 +1852,7 @@ class PagedKVCache:
         time; under :meth:`hold_slot` the flat indices stay stable across
         the whole ranged walk)."""
         self._require_pool("gather_slot_rows")
+        self._refuse_index("gather_slot_rows (a migration's page chunk)")
         self._check_row_range(slot, start, stop)
         idx = jnp.asarray(self._flat_indices(slot, stop)[start:])
         k, v = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
@@ -2166,7 +2245,8 @@ def _apply_rotary_rows(x: jnp.ndarray, cos_b: jnp.ndarray,
 
 
 @jax.named_scope("paged_kv.write")
-def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
+def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False,
+               index=None):
     """The pool with a step's new K/V rows in layer ``layer``: pool leaves
     (L, P, ps, KV*lanes) WITH their layer axis, ``layer`` a traced or static
     index, k, v (B, 1, KV, hd) post-rotary, slot i's row at position
@@ -2182,7 +2262,9 @@ def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
     that knows where in a pool a decode step's row goes.
 
     A :class:`LatentPool` takes its ONE row a slot as ``k`` (B, 1,
-    kv_row_lanes), as stored, and ``v`` None.
+    kv_row_lanes), as stored, and ``v`` None. An :class:`IndexedPagePool`
+    also takes the position's index key, ``index`` (B, index_row_lanes) as
+    stored, into its second leaf at the same flat index.
 
     ``ring`` (static): the table is a window layer's RING of pages, and
     position p lives in entry ``(p // ps) % entries``: the row written
@@ -2191,6 +2273,8 @@ def write_rows(pool, layer, page_table, lengths, k, v, ring: bool = False):
     if tier == "fp":     # ONE row: K lanes then V lanes, or the latent row
         stored = (k[:, 0] if v is None else join_kv(
             _merge_heads(k[:, 0]), _merge_heads(v[:, 0])),)
+        if index is not None:
+            stored += (index,)
     else:
         qk, sk = quantize_kv_rows(k[:, 0], tier)  # (B,KV,hdc), (B,KV)
         qv, sv = quantize_kv_rows(v[:, 0], tier)
@@ -2355,7 +2439,9 @@ def decode_read_path(pool) -> str:
     whole, staged or one layer's. No width is gated out: on a v5e the walk
     is ahead at 128 to 1024 lanes (PERF.md §6 "PR 33", "PR 35"; a ring of 65
     or 129 pages: "PR 40")."""
-    if not isinstance(pool, (PagePool, LatentPool)) or not _on_tpu():
+    # (an IndexedPagePool: of its K/V leaf, the one a walk would fetch)
+    if (not isinstance(pool, (PagePool, LatentPool, IndexedPagePool))
+            or not _on_tpu()):
         return PAGE_GATHER
     # what the kernel slices on lanes (a row's K part, its V part as wide,
     # or the whole latent row) and on sublanes (a page)
@@ -2416,7 +2502,7 @@ def walk_lead(pool, page_table):
 
 
 def attend_pages(q, pool: PagePool, layer, page_table, lengths,
-                 window: int = 0, lead=None):
+                 window: int = 0, lead=None, keep=None):
     """:func:`read_span` + :func:`attend_rows` without the span: q (B, 1, H,
     hd) against each slot's LIVE pages of layer ``layer`` of an fp pool (L,
     P, ps, 2*KV*hd), read out of the pool where they lie by ONE kernel
@@ -2431,7 +2517,11 @@ def attend_pages(q, pool: PagePool, layer, page_table, lengths,
     ``window`` (static, > 0): ``page_table`` is a window layer's ring, of
     which the kernel fetches the entries the stream has reached (all of them
     once the ring has turned) and attends the rows :func:`window_valid` says
-    of :func:`ring_positions`, by two scalars a slot."""
+    of :func:`ring_positions`, by two scalars a slot.
+
+    ``keep`` (B, span) bool: of a slot's live positions, those the query
+    attends (a sparse-attention layer's selection): the walk fetches every
+    live page, and a row ``keep`` leaves out takes no part."""
     hd = q.shape[-1]
     own, qz = _group_lanes(q, pool.k_lanes // hd)
     ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
@@ -2440,7 +2530,8 @@ def attend_pages(q, pool: PagePool, layer, page_table, lengths,
         scale=float(1.0 / np.sqrt(hd)), window=window,
         # (a ring's pages are a fetch each in every configuration that has
         # rings; the kernel reads the rule off the operand for itself)
-        **({} if window else _walk_runs(pool, page_table, lead)))
+        **({} if window else _walk_runs(pool, page_table, lead)),
+        **({} if keep is None else {"keep": keep}))
     return _own_lanes(out, own)
 
 

@@ -195,6 +195,16 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
                           # V up-projection and the output projection
     "attn.latent.expand",  # a latent layer's prefill: keys and values
                            # rebuilt per head, attention by query blocks
+    # a sparse-attention layer (models/sparse_attn.py)
+    "attn.sparse",        # ... its decode: projections, norms, rotation, both
+                          # row writes, the indexer, the selection, the
+                          # selected rows' read and attend, W_o
+    "attn.sparse.index",  # the indexer's projections and its score pass over
+                          # the live index keys (decode: through the pages;
+                          # prefill: a block of query rows at a time)
+    "attn.sparse.select",  # the top-k and what turns it into row ids (decode)
+                           # or a mask on a block's scores (prefill)
+    "attn.sparse.prefill",  # a sparse layer over a whole prompt, by blocks
     "mlp",                # a dense feed-forward; in a stack walked by layer
                           # kinds, a leading dense layer's and its post-norm,
                           # or every sublayer's dense SwiGLU (longcat_flash)

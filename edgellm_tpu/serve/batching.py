@@ -67,7 +67,10 @@ module schedules many streams through ONE jitted decode step built on
   ``mistral4`` stack rides ``_batched_hybrid_step_jit`` too: its pool is ONE
   leaf of latent rows (``paged_kv.LatentPool``), handed over where a K/V
   pool's one leaf goes, with no state store; admission adopts the
-  prefill's rows (``adopt_latent``) and eviction gathers them as stored.
+  prefill's rows (``adopt_latent``) and eviction gathers them as stored. A
+  ``keye_vl2`` stack rides it with its pool of TWO leaves handed over whole
+  (``paged_kv.IndexedPagePool``: K/V rows and index keys under one table);
+  ``adopt`` and ``gather_slot`` move both.
 
 ``ServeFront`` integration lives in ``serve/frontend.py`` (``batcher=``):
 admission control, brownout and breakers all apply before a request reaches
@@ -91,7 +94,9 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
-from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, OutOfPages, \
+from ..models.sparse_attn import EVERY_ROW, ROW_GATHER, sparse_read_path
+from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, IndexedPagePool, \
+    PagePool, OutOfPages, \
     OutOfSlots, PagedKVCache, PrefixCacheConfig, \
     decode_read_path, paged_decode_step, resolve_kv_codec, walk_geometry
 from ..models.flash_attention import leading_runs
@@ -264,8 +269,9 @@ def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool, state,
     and the per-expert assignment counter are all donated and come back
     updated. A SEPARATE jit: the one-block families keep the executable
     above. ``pool`` is the page pool's ONE leaf, K-then-V rows or a stack of
-    latent layers' rows; ``state`` None (and back) where the stack keeps
-    none."""
+    latent layers' rows (a stack of sparse-attention layers: its
+    ``IndexedPagePool`` whole, both leaves donated); ``state`` None (and
+    back) where the stack keeps none."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
@@ -447,8 +453,15 @@ class ContinuousBatcher:
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
                       "attend_pages_in_runs": 0,
                       "window_pages_walked": 0, "window_pages_spanned": 0,
+                      "sparse_rows_live": 0, "sparse_rows_attended": 0,
+                      "index_rows_scored": 0,
                       "step_wall_hist": _new_step_wall_hist(),
                       **dict.fromkeys(_CLOCKS, 0.0)}
+        # the read a sparse-attention layer's decode is built with (None: the
+        # stack has no such layer), by the positions a slot can hold
+        self.sparse_read = (
+            sparse_read_path(cfg, self.bcfg.span, self.pool.pool)
+            if cfg.sparse_layers else None)
         # the reads the step's full and window layers are built with (by pool)
         (self.decode_read, self.window_read, self.attend_fetches_per_page,
          self.attend_walk) = self._read_paths()
@@ -666,7 +679,8 @@ class ContinuousBatcher:
                     self.pool.adopt_latent(slot, st.resume["rows"], need_len)
                 else:
                     self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
-                                    jnp.asarray(st.resume["v"]), need_len)
+                                    jnp.asarray(st.resume["v"]), need_len,
+                                    index=st.resume.get("index"))
                 if self.pool.state is not None:
                     self.pool.adopt_state(
                         slot, *(st.resume[leaf] for leaf in self.pool.state))
@@ -723,8 +737,10 @@ class ContinuousBatcher:
                 if self.cfg.latent_layers:
                     self.pool.adopt_latent(slot, cache.rows[:, 0, :s], s)
                 else:
-                    self.pool.adopt(slot, cache.k[:, 0, :s],
-                                    cache.v[:, 0, :s], s)
+                    self.pool.adopt(
+                        slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s,
+                        index=(cache.index[:, 0, :s]
+                               if self.cfg.sparse_layers else None))
                 if self.cfg.recurrent_state:
                     # the other kind of state a prefill hands on
                     self.pool.adopt_state(
@@ -1116,6 +1132,16 @@ class ContinuousBatcher:
                 acc["window_pages_walked"] = int(np.sum(
                     np.minimum(reached, ring)))
                 acc["window_pages_spanned"] = b * ring
+            if self.sparse_read is not None:
+                # a sparse layer's rows this step, the one it writes among
+                # them: a rider's live rows, those its query attends, and
+                # those its indexer scores (none where no slot can select)
+                live = self.pool.lengths[[st.slot for st in riders]] + 1
+                acc["sparse_rows_live"] = int(live.sum())
+                acc["sparse_rows_attended"] = int(
+                    np.minimum(live, self.cfg.index_topk).sum())
+                acc["index_rows_scored"] = (
+                    0 if self.sparse_read == EVERY_ROW else int(live.sum()))
         with obs_phase("batch.step.launch", acc, "launch_s", after=ph,
                        step=step_no) as ph:
             prev = self._inflight
@@ -1149,7 +1175,10 @@ class ContinuousBatcher:
             elif self.cfg.is_hybrid:
                 # the pool's one leaf (K-then-V rows, or a latent stack's
                 # rows) and the state store, where the stack keeps one
-                kind, (leaf,) = type(self.pool.pool), self.pool.pool
+                # (a pool of two leaves goes and comes back whole)
+                kind = type(self.pool.pool)
+                leaf = (self.pool.pool if kind is IndexedPagePool
+                        else self.pool.pool[0])
                 toks, leaf, self.pool.state, self._expert_tokens = (
                     _batched_hybrid_step_jit(
                         self.cfg, self.params, leaf, self.pool.state,
@@ -1157,7 +1186,8 @@ class ContinuousBatcher:
                         token_ids, jnp.asarray(key_data),
                         jnp.asarray(steps), jnp.asarray(temps),
                         self.bcfg.compute_dtype))
-                self.pool.pool = kind(leaf)
+                self.pool.pool = (leaf if kind is IndexedPagePool
+                                  else kind(leaf))
             else:
                 toks, self.pool.pool = _batched_step_jit(
                     self.cfg, self.params, self.pool.pool, page_table,
@@ -1502,6 +1532,7 @@ class ContinuousBatcher:
             "window_read": self.window_read,
             "window_pages_walked": stats["window_pages_walked"],
             "window_pages_spanned": stats["window_pages_spanned"],
+            **self._sparse_report(stats),
             **({"prefix": self.pool.prefix_report()}
                if self.pool.prefix is not None else {}),
             **self._hybrid_report(stats),
@@ -1521,7 +1552,13 @@ class ContinuousBatcher:
         sites, as below."""
         full = self._split_pool if self.rt is not None else self.pool.pool
         rings = self.pool.window_pool
-        read = decode_read_path(full)
+        if isinstance(full, IndexedPagePool):
+            # what a sparse layer reads as a full layer does: its K/V leaf
+            full = PagePool(full.kv)
+        # (a step that gathers its chosen rows one by one walks no page: its
+        # index keys come by the page gather; the masked walk is the walk)
+        read = (PAGE_GATHER if self.sparse_read == ROW_GATHER
+                else decode_read_path(full))
         ppb, run = (walk_geometry(full, self.bcfg.pages_per_slot)
                     if read == PAGE_WALK else (1, 1))
         # the allocator read the rule off the configuration (it holds no
@@ -1550,6 +1587,19 @@ class ContinuousBatcher:
             rows, :-(-int(live.max()) // ppb) * ppb], run, ppb)
         live = np.clip(live - ppb * np.arange(lead.shape[1]), 0, ppb) // run
         return run * int(np.sum(np.minimum(lead, live)))
+
+    def _sparse_report(self, stats: dict) -> dict:
+        """What a stack of sparse-attention layers adds to ``report()``: the
+        read its decode is built with, and three additive counters of rows a
+        sparse layer, counted on the host from the riders' lengths: live,
+        attended (``min(length, index_topk)`` a rider) and scored by the
+        indexer."""
+        if self.sparse_read is None:
+            return {}
+        return {"sparse_read": self.sparse_read,
+                **{k: int(stats[k]) for k in (
+                    "sparse_rows_live", "sparse_rows_attended",
+                    "index_rows_scored")}}
 
     def _hybrid_report(self, stats: dict) -> dict:
         """What a stack with recurrent state and routed experts adds to

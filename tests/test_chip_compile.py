@@ -33,8 +33,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from edgellm_tpu.models import grouped_matmul, hybrid, moe, paged_kv
-from edgellm_tpu.models.configs import LFM2_8B_A1B, LONGCAT_FLASH_CHAT, \
-    ModelConfig, tiny_afmoe_config, tiny_hybrid_config, \
+from edgellm_tpu.models.configs import KEYE_VL_2_0_30B_A3B, LFM2_8B_A1B, \
+    LONGCAT_FLASH_CHAT, ModelConfig, tiny_afmoe_config, tiny_hybrid_config, \
     tiny_lfm2_moe_config, tiny_longcat_flash_config, tiny_mellum_config, \
     tiny_mistral4_config
 from edgellm_tpu.models.transformer import init_params
@@ -827,6 +827,113 @@ def test_lfm2_step_keeps_its_windows_and_its_pool_in_place(topo, read):
                      "mlp", "moe.route", "moe.experts",
                      "unembed_sample"}, under
     assert not under & {"moe.shared", "ssm.step", "ssm.proj"}
+
+
+# benchmark/configs/keye-vl-2.0-30b-a3b-ep4.json: the widths, the share (32
+# of 128 experts, 37,984 rows of the table) and the serving geometry, ONE of
+# the cell's six layers (the compile of six takes 100 s)
+KEYE = dataclasses.replace(
+    KEYE_VL_2_0_30B_A3B, num_layers=1, layer_types=("sparse_attention",),
+    experts_held=32, vocab_size=37984)
+K_SLOTS, K_PAGES_PER_SLOT = 32, 1280
+
+
+def test_keye_step_selects_under_scopes_and_keeps_both_leaves_in_place(
+        topo, read):
+    """The step of the ``keye_vl2`` cell at its shapes, one layer: the pool's
+    TWO leaves (K/V rows of 1024 lanes, index keys of 128) donated and
+    written where they lie, a row scatter each; the index keys read by one
+    page gather of the 128-lane leaf; then, on a TPU's choice, the page walk
+    with the selection as a mask (one kernel, no span-sized copy of K or V),
+    and on the other the row gather of 32 x 2048 chosen rows. Every matmul, gather,
+    scatter, sort and kernel call under a registered scope."""
+    from edgellm_tpu.models import sparse_attn
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = KEYE
+    assert (cfg.sparse_layers, cfg.kv_row_lanes, cfg.index_row_lanes) == (
+        1, 512, 128)
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    assert "w_index" in params["sparse"] and "attn" not in params
+    pages = K_SLOTS * K_PAGES_PER_SLOT + 1
+    pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, pages, PAGE, jnp.bfloat16)), one)
+    assert [a.shape for a in pool] == [(1, pages, PAGE, 1024),
+                                       (1, pages, PAGE, 128)]
+    span = K_PAGES_PER_SLOT * PAGE
+    assert sparse_attn.sparse_read_path(cfg, span, pool) == (
+        sparse_attn.MASKED_WALK if read == "walk" else sparse_attn.ROW_GATHER)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((K_SLOTS,), jnp.int32)
+    step = batching._batched_hybrid_step_jit.lower(
+        cfg, params, pool, None, arr((1, 32), jnp.int32),
+        arr((K_SLOTS, K_PAGES_PER_SLOT), jnp.int32), ints, ints,
+        arr((K_SLOTS, 2), jnp.uint32), ints, arr((K_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    kv_leaf, ik_leaf = pages * PAGE * 1024, pages * PAGE * 128
+    # nothing the size of either leaf is copied, relaid or stacked
+    keys = f"bf16[{K_SLOTS},{K_PAGES_PER_SLOT},{PAGE},128]"
+    own = {keys, f"bf16[{K_SLOTS * K_PAGES_PER_SLOT},{PAGE},128]",
+           f"bf16[{K_SLOTS},{span},128]"}
+    moved = [m for m in _moved(hlo, ik_leaf)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    assert not moved, moved
+    assert _walks(hlo) == (1 if read == "walk" else 0)
+    big = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+           if op == "gather" and _elements(shape) >= K_SLOTS * 2048 * 1024]
+    # (the chosen rows: 32 x 2048 of them, gathered a K or V half at a time)
+    chosen = {shape for shape in big if shape != keys}
+    assert keys in big and chosen == (
+        set() if read == "walk" else {f"bf16[{K_SLOTS},2048,1024]"}), big
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (kv_leaf + ik_leaf)
+    # the index keys' gathered copy 168 MB and the scores; the row gather's
+    # chosen rows 268 MB more
+    assert mem.temp_size_in_bytes < (250e6 if read == "walk" else 700e6), \
+        mem.temp_size_in_bytes
+    unscoped, under = _scopes_of_the_heavy(hlo)
+    # ("": the compiler's own "AllocateBuffer" calls for the carried k-th
+    # value of the selection's counting loop, 32 words each: no operation)
+    assert unscoped <= {"jit(_batched_hybrid_step_jit)/jit(_take)/gather",
+                        "jit(_batched_hybrid_step_jit)/gather", ""}, unscoped
+    # (the walk's selection is counting passes, none of them heavy; the
+    # gather's is a top-k and the gather of its page ids)
+    assert under >= {"attn.sparse", "attn.sparse.index", "paged_kv.write",
+                     "moe.route", "moe.experts", "unembed_sample"} | (
+        set() if read == "walk" else {"attn.sparse.select"}), under
+    assert not under & {"attn.decode", "moe.shared", "mlp"}
+
+
+def test_keye_prefill_of_a_whole_prompt_builds_no_square_tensor(topo):
+    """The 16384-token prefill of the cell, one layer: 32 blocks of 512
+    query rows, each with its index dot, its selection (no sort: a k-th
+    value by counting passes) and its masked softmax. No (S, S) tensor
+    exists, and what the compiler holds at once stays under 2.5 GB beside
+    the 11.4 GB the cell keeps."""
+    from edgellm_tpu.serve import decode
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg, s = KEYE, 16384
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    prefill = decode._prefill_jit.lower(
+        cfg, params, jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one),
+        K_PAGES_PER_SLOT * PAGE, None).compile()
+    hlo = prefill.as_text()
+    square = [shape for _, _, shape, _ in _instructions(hlo)
+              if sum(int(d) >= s for d in re.findall(
+                  r"\d+", shape.split("{")[0].split("[")[-1])) >= 2]
+    assert not square, square[:3]
+    assert not [name for op, name, _, line in _instructions(hlo)
+                if op == "sort" and "attn.sparse" in line]
+    mem = prefill.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+    assert "attn.sparse.prefill" in hlo and "attn.sparse.select" in hlo
 
 
 # the six families hybrid.py walks, at toy sizes whose expert layers are

@@ -115,7 +115,8 @@ ALL_CELLS = CELLS + ("granite-4.0-h-small-ep2.decode-sat",
                      "mistral-small-4-119b-ep4.decode-sat-deep",
                      "trinity-mini-pp8.decode-sat-long",
                      "longcat-flash-chat-ep32.decode-sat-reason",
-                     "lfm2-8b-a1b-pp2.decode-sat-docs")
+                     "lfm2-8b-a1b-pp2.decode-sat-docs",
+                     "keye-vl-2.0-30b-a3b-ep4.decode-sat-context")
 EDGES = [0.01, 0.02, 0.04, 0.08]            # five rows: under, three, over
 PHASE_KEYS = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s",
               "commit_s")
@@ -320,6 +321,8 @@ EXPERT_CELLS = ("granite-4.0-h-small-ep2.decode-sat",
                 "mistral-small-4-119b-ep4.decode-sat-deep", AFMOE_CELL,
                 "longcat-flash-chat-ep32.decode-sat-reason",
                 "lfm2-8b-a1b-pp2.decode-sat-docs")
+# (the keye_vl2 cell routes experts too, but admits in a few bursts a window:
+# a traced 6 s often holds no admission, so it does not list the reader)
 
 
 def test_grouped_reader_is_the_new_scope_per_admission(table, monkeypatch):
@@ -573,11 +576,12 @@ def test_the_lfm2_cell_lists_its_readers_and_the_ones_it_joins():
         "gap_mean_ms", "setup_s"}
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
         spec = json.load(f)
-    # (the cell's own four, then PR 46's ``attend_run_share``, which it joins)
-    assert [m["name"] for m in spec["per_layer"]][-5:] == [
-        *LFM2_READERS, "attend_run_share"]
-    assert all(m["workloads"] == [LFM2_CELL] for m in spec["per_layer"][-5:-1])
-    assert LFM2_CELL in spec["per_layer"][-1]["workloads"]
-    assert spec["workloads"][-1]["name"] == LFM2_CELL
-    assert len(spec["workloads"]) == 9
+    # (found by name: later PRs append their own entries after these)
+    by_name = {m["name"]: m for m in spec["per_layer"]}
+    assert all(by_name[n]["workloads"] == [LFM2_CELL] for n in LFM2_READERS)
+    assert LFM2_CELL in by_name["attend_run_share"]["workloads"]
+    order = [m["name"] for m in spec["per_layer"]]
+    at = order.index(LFM2_READERS[0])
+    assert order[at:at + 5] == [*LFM2_READERS, "attend_run_share"]
+    assert LFM2_CELL in [w["name"] for w in spec["workloads"]]
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
