@@ -129,7 +129,9 @@ SPAN_NAMES: FrozenSet[str] = frozenset({
     "batch.submit",
     # ContinuousBatcher.step and its six phases (obs.tracing.phase: always
     # on a profiler capture, clocks admit_s ... commit_s of report()), and
-    # one admission with its device dispatches and its host sync
+    # one admission with its device dispatches, and the host sync on its
+    # token 0 (which stands behind the device work after the admission: in
+    # the loop's next batch.admit, or in batch.step.sync behind the launch)
     "batch.step",
     "batch.step.admit",
     "batch.step.grow",

@@ -19,7 +19,9 @@ module schedules many streams through ONE jitted decode step built on
   tokens, which stay on the device until the call after;
 - prompts are prefetched through the SAME ``_prefill_jit`` executable
   ``generate`` uses, the first token sampled with the same ``fold_in(key, 0)``
-  — then the prompt's KV is adopted into the stream's pages;
+  — then the prompt's KV is adopted into the stream's pages; that token 0
+  too stays on the device until the device work after it is dispatched (the
+  next admission's prefill, or the step's launch, which feeds it);
 - sampling inside the batched step reproduces ``decode._sample`` per slot
   bitwise: each slot's key is wrapped INSIDE the jit from the stream's
   ``(2,) uint32`` key data (:func:`_key_data`, fixed when the stream is made:
@@ -198,9 +200,10 @@ class Stream:
     admit_seq: int = -1           # admission order; youngest = largest
     evictions: int = 0
     queued_t: float = 0.0         # monotonic stamp: entered the waiting queue
-    # tokens sampled by launched steps and not yet read: on the device, in
-    # no list (one between two calls of ``step()``, two from a launch to the
-    # commit of the step before it)
+    # tokens sampled and not yet read: on the device, in no list. A launched
+    # step's (one between two calls of ``step()``, two from a launch to the
+    # commit of the step before it) and, from a fresh admission to the read
+    # of its token 0 (inside the same call), that token
     pending: int = 0
     # the bits of ``key``, fixed for the stream's life: its row of the step's
     # key table, copied per step with no device work
@@ -336,6 +339,18 @@ IN_FLIGHT = -1
 _feed_jit = jax.jit(_fed_tokens)
 
 
+def _with_tok0(toks, slot, tok0):
+    """The ids a launch feeds from the device (``_fed_tokens``' second
+    argument) with an admission's unread token 0 at its slot."""
+    return toks.at[slot].set(tok0[0])
+
+
+# the slot is traced: one executable for every slot, warmed by the first
+# launch behind an admission (an admission's executable, like its prefill:
+# ``compiles`` sees it, ``jit_misses`` counts the step's own)
+_tok0_feed_jit = jax.jit(_with_tok0)
+
+
 @dataclass
 class _Launched:
     """A launched step whose tokens the host has not read."""
@@ -376,6 +391,16 @@ class ContinuousBatcher:
     ``pool.lengths``); ``Stream.tokens`` holds read tokens only. Whatever
     needs a stream's tokens on the host, or rows no step is writing, calls
     :meth:`_drain` first: the old order, sync then commit.
+
+    An admission's token 0 is a token in flight like any other: it is read
+    once the device work after it is dispatched. Inside the admit loop that
+    is the next admission's prefill and adopt (the read runs ONE admission
+    behind the dispatch, so at most two prefills' outputs are live); for the
+    loop's last admission it is the step's launch, which takes the id from
+    the device (``_tok0_feed_jit``), and the read stands behind it in the
+    same call, in ``batch.step.sync``. A call of ``step()`` returns with
+    every token 0 it admitted in ``Stream.tokens``; ``_tok0s`` holds the
+    unread ones in between, and :meth:`_drain` reads them too.
     """
 
     def __init__(self, cfg: ModelConfig, params: dict,
@@ -449,7 +474,7 @@ class ContinuousBatcher:
                       "occ_sum": 0.0, "slot_sum": 0.0, "alloc_sum": 0.0,
                       "alloc_n": 0, "compiles": 0, "compile_s": 0.0,
                       "routed_assignments": 0, "admit_steps": 0,
-                      "steps_ahead": 0,
+                      "steps_ahead": 0, "admits_ahead": 0,
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
                       "attend_pages_in_runs": 0,
                       "window_pages_walked": 0, "window_pages_spanned": 0,
@@ -474,12 +499,20 @@ class ContinuousBatcher:
         # clock at the last read
         self._inflight: Optional[_Launched] = None
         self._read_at = 0.0
+        # the admissions whose token 0 is still on the device, oldest first:
+        # (stream, token 0, prompt positions matched, the admission's start);
+        # and the clock at the last admission's end
+        self._tok0s: deque[tuple] = deque()
+        self._admit_end = 0.0
         # where a step's token ids are put: on a mesh, replicated like the
         # sampler's output they are merged with, so that a merged array and
         # an uploaded one are one kind of argument to the step (two kinds,
         # two compiles); on one chip wherever an upload goes
         self._ids_on = (NamedSharding(split_runtime.mesh, PartitionSpec())
                         if split_runtime is not None else None)
+        # what a launch with no step in flight sets an unread token 0 into
+        self._no_toks = jax.device_put(
+            np.zeros((self.bcfg.max_slots,), np.int32), self._ids_on)
         # routed-expert counters of a hybrid stack: assignments per held
         # expert per layer, summed on the device inside the step and read by
         # report() alone (no host sync a step); assignments made, counted
@@ -589,6 +622,11 @@ class ContinuousBatcher:
         return int(slot // (self.bcfg.max_slots // m)) if m > 1 else 0
 
     def _try_admit(self, sid: int) -> bool:
+        """Admit waiting stream ``sid`` if a slot and its pages are free:
+        dispatch its prefill, token-0 sample and adopt (a resume: its adopt),
+        then read the token 0s of the admissions before it. Its own stays on
+        the device (``_tok0s``, ``Stream.pending`` 1) until the next device
+        work is dispatched: the next admission's, or the step's launch."""
         st = self._streams[sid]
         need_len = (int(st.resume["length"]) if st.resume is not None
                     else st.prompt.size)
@@ -626,21 +664,37 @@ class ContinuousBatcher:
                 self.pool.free_slot(slot)
                 return False
             ph.set(matched=matched)
-            if tok0 is not None:
-                # a host sync per admission: token 0 comes back before the
-                # stream may ride the step
-                with obs_phase("batch.admit.tok0_sync", sid=sid):
-                    st.tokens.append(int(np.asarray(tok0)[0]))
-            self._acc["prefill_s"] += self._admitted_at(st, tok0, matched) - t0
             self._acc["queue_wait_s"] += t0 - st.queued_t
             self._acc["admitted"] += 1
             st.status, st.slot = "running", slot
             st.admit_seq = self._admit_seq
             self._admit_seq += 1
             self._slot_to_sid[slot] = sid
-            if st.t >= st.max_new_tokens:  # max_new_tokens == 1: prefill is all
-                self._finish(st)
+            # the device has this admission's work: the token 0s before it
+            # are read now, ONE admission behind the dispatch
+            self._acc["admits_ahead"] += len(self._tok0s)
+            self._read_tok0s()
+            if tok0 is None:
+                self._admitted_at(st, None, matched, t0)
+            else:
+                # in flight: it rides the step unread, the launch feeds it
+                st.pending = 1
+                self._tok0s.append((st, tok0, matched, t0))
         return True
+
+    def _read_tok0s(self) -> None:
+        """Read every unread token 0, oldest first (the device runs their
+        prefills in that order): a host sync each, ``batch.admit.tok0_sync``
+        wherever it stands. A stream of one token ends here, by count."""
+        while self._tok0s:
+            st, tok0, matched, t0 = self._tok0s.popleft()
+            with obs_phase("batch.admit.tok0_sync", sid=st.sid):
+                tok = int(np.asarray(tok0)[0])
+            self._admitted_at(st, tok0, matched, t0)
+            st.pending -= 1
+            st.tokens.append(tok)
+            if len(st.tokens) >= st.max_new_tokens:
+                self._finish(st)
 
     def _admit_fill(self, st: Stream, slot: int) -> tuple:
         """Land one stream's KV into ``slot``'s pages — resume payload,
@@ -902,6 +956,7 @@ class ContinuousBatcher:
             self._drain()
             if not self._try_admit(sid):
                 return None
+            self._drain()  # its token 0 on the host: nothing follows it
         finally:
             # admission outside step(): prefill_s and queue_wait_s move,
             # admit_s and step_wall_s (clocks of step() calls) do not
@@ -1030,7 +1085,10 @@ class ContinuousBatcher:
         ``grow`` / ``build`` / ``launch`` / ``sync`` / ``commit``, clocks
         ``admit_s`` ... ``commit_s``) tile it. The first four are step N+1's,
         the launch this call makes; ``sync`` and ``commit`` are step N's and
-        carry its ``step=``."""
+        carry its ``step=``. The admit loop reads each admission's token 0
+        once the next one is dispatched (``admit_s`` holds those waits); the
+        last one's is read in ``sync``, behind the launch and step N's tokens
+        (``sync_s`` holds that wait)."""
         c0 = compile_totals()
         whole = obs_phase("batch.step", self._acc, "step_wall_s",
                           step=int(self.stats["steps"]),
@@ -1150,9 +1208,16 @@ class ContinuousBatcher:
                 watchdog = Watchdog(self.bcfg.step_deadline_s)
                 watchdog.arm()
             t0 = time.monotonic()
-            token_ids = jax.device_put(token_ids, self._ids_on)
-            if prev is not None:
-                token_ids = _feed_jit(token_ids, prev.toks)
+            uploaded = token_ids = jax.device_put(token_ids, self._ids_on)
+            # what the device feeds: the step in flight's tokens and the
+            # token 0 this call's last admission left unread, at its slot
+            feed = prev.toks if prev is not None else None
+            for st, tok0, _, _ in self._tok0s:
+                feed = _tok0_feed_jit(
+                    self._no_toks if feed is None else feed, st.slot, tok0)
+            acc["admits_ahead"] += len(self._tok0s)
+            if feed is not None:
+                token_ids = _feed_jit(token_ids, feed)
             if self.rt is not None:
                 # one ragged split step: every cut hops ONE (max_slots, 1, D)
                 # quantized activation block, the sampler is the same
@@ -1199,9 +1264,11 @@ class ContinuousBatcher:
             # a step that follows an admission is not held up by the count)
             acc["attend_pages_in_runs"] = self._pages_in_runs(reached)
             if step_no == 0:
-                # the merge every later launch runs, compiled by the first: a
+                # the merges every later launch runs, compiled by the first: a
                 # caller that warmed one step has warmed the steady state
-                _feed_jit(token_ids, toks)
+                _feed_jit(uploaded, toks)
+                for st, tok0, _, _ in self._tok0s:
+                    _tok0_feed_jit(toks, st.slot, tok0)
             # the host counts the token in flight: the next build's step
             # index, write position and "ends by count" all hold it
             for st in riders:
@@ -1215,18 +1282,19 @@ class ContinuousBatcher:
                 acc["routed_assignments"] = (
                     len(riders) * self.cfg.experts_per_tok
                     * self.cfg.expert_layers)
-            del toks, token_ids, page_table, lengths
+            del toks, token_ids, uploaded, feed, page_table, lengths
         self._read(prev, acc, ph)
         return len(riders)
 
     def _drain(self, acc: Optional[dict] = None,
                after: Optional[obs_phase] = None) -> int:
-        """Read and commit the step in flight, if one is: today's order,
-        sync then commit. Called by whatever needs every stream's tokens on
-        the host or rows that no step is writing, and by a ``step()`` that
-        has nothing to launch (which hands over its clocks: everywhere else
-        the two spans' time is the enclosing phase's or nobody's). Returns
-        the number of streams it committed a token for."""
+        """Read and commit the step in flight, if one is, and read the
+        token 0s still on the device: the old order, sync then commit. Called
+        by whatever needs every stream's tokens on the host or rows that no
+        step is writing, and by a ``step()`` that has nothing to launch
+        (which hands over its clocks: everywhere else the two spans' time is
+        the enclosing phase's or nobody's). Returns the number of streams it
+        committed a step's token for."""
         fl, self._inflight = self._inflight, None
         return self._read(fl, acc, after)
 
@@ -1234,17 +1302,23 @@ class ContinuousBatcher:
               after: Optional[obs_phase] = None) -> int:
         """``batch.step.sync`` and ``batch.step.commit`` of the launched
         step ``fl``: wait for its tokens, append each to its stream, retire
-        the streams that have all of theirs."""
-        if fl is None:
+        the streams that have all of theirs. The sync holds the reads of the
+        unread token 0s too, behind the step's (the device ran it first);
+        with no step to read it carries no ``step=`` and nothing commits."""
+        if fl is None and not self._tok0s:
             return 0
         with obs_phase("batch.step.sync", acc, "sync_s", after=after,
-                       step=fl.step) as ph:
-            toks_host = np.asarray(fl.toks)  # ONE host sync per step
-            # from this step's launch, or the read before it where that came
-            # later, to its read: the steps' times do not overlap
-            now = time.monotonic()
-            self._acc["decode_s"] += now - max(fl.t0, self._read_at)
-            self._read_at = now
+                       **({} if fl is None else {"step": fl.step})) as ph:
+            if fl is not None:
+                toks_host = np.asarray(fl.toks)  # ONE host sync per step
+                # from this step's launch, or the read before it where that
+                # came later, to its read: the steps' times do not overlap
+                now = time.monotonic()
+                self._acc["decode_s"] += now - max(fl.t0, self._read_at)
+                self._read_at = now
+            self._read_tok0s()
+        if fl is None:
+            return 0
         with obs_phase("batch.step.commit", acc, "commit_s", after=ph,
                        step=fl.step) as ph:
             finished0 = self.stats["finished"]
@@ -1294,13 +1368,19 @@ class ContinuousBatcher:
 
     # -- what the fold keeps of one step -----------------------------------
 
-    def _admitted_at(self, st: Stream, tok0, matched: int) -> float:
-        """The clock where an admission's host work ends (what closes its
-        ``prefill_s``). For a fresh admission that is where its token 0 has
-        come to lie on the host, which ``tok0_hold_s`` counts from, and its
-        ``prompt - matched`` positions were prefilled; a resume prefills
-        nothing and holds no token."""
+    def _admitted_at(self, st: Stream, tok0, matched: int,
+                     t0: float) -> float:
+        """The clock where the admission begun at ``t0`` ends, which closes
+        its ``prefill_s``: from ``t0``, or the end of the admission before it
+        where that came later, so that no second is counted for two. For a
+        fresh admission the end is where its token 0 has come to lie on the
+        host, which ``tok0_hold_s`` counts from: behind the next admission's
+        dispatch or the step's launch, not in its own ``batch.admit``; its
+        ``prompt - matched`` positions were prefilled. A resume ends where
+        its adopt is dispatched: it prefills nothing and holds no token."""
         now = time.monotonic()
+        self._acc["prefill_s"] += now - max(t0, self._admit_end)
+        self._admit_end = now
         if tok0 is not None:
             self._tok0_at.append(now)
             self._acc["prefill_tokens"] += int(st.prompt.size - matched)
@@ -1479,6 +1559,9 @@ class ContinuousBatcher:
             # launch to read of every step, counted from the read before it
             # where that came later: one step's seconds never hold another's
             "decode_s": dec,
+            # (prefill_s above: every admission from its start, or the end of
+            # the one before it where that came later, to its token 0 on the
+            # host, a resume's to its adopt's dispatch: no second twice)
             # all additive, so report1 - report0 is a window's worth: every
             # step() call entry to return, its six phases, the waiting-queue
             # time of admitted streams, and the backend compiles (any jit's,
@@ -1496,6 +1579,14 @@ class ContinuousBatcher:
             # this one's tokens (additive; over ``steps``, the share of
             # launches that ran ahead)
             "steps_ahead": stats["steps_ahead"],
+            # the fresh admissions whose token 0 was still unread when the
+            # device work after them was dispatched, the next admission's or
+            # the step's launch: the device had that work before the host had
+            # the token (additive; over ``admitted``, the share that ran
+            # ahead). One drained early is not among them (an eviction in the
+            # call that admitted it, prefill_hold(), a call that launched
+            # nothing), nor is a resume, which has no token 0
+            "admits_ahead": stats["admits_ahead"],
             # prompt positions admissions prefilled, in prefill_s: a prefix
             # hit's matched positions and a resume's rows are not among them
             "prefill_tokens": stats["prefill_tokens"],

@@ -482,6 +482,32 @@ def test_every_cell_lists_launch_ahead_share(cell):
     assert cell in entry["workloads"]
 
 
+def test_admit_ahead_share_is_the_windows_admits_ahead_over_its_admitted():
+    r0 = {"admitted": 192, "admits_ahead": 190}
+    r1 = {"admitted": 392, "admits_ahead": 388}
+    assert _read("admit_ahead_share", _record(False, r0, r1)) == \
+        pytest.approx(99.0)
+    # a window that admitted nothing divides by nothing
+    assert _read("admit_ahead_share", _record(False, r0, dict(r0))) is None
+    # the parent's report() has no such counter: nothing, and no exception
+    assert _read("admit_ahead_share", _record(
+        False, {"admitted": 192}, {"admitted": 392})) is None
+
+
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_every_cell_lists_admit_ahead_share(cell):
+    per_layer = {m.name: m for m in load_cell(cell).per_layer}
+    assert per_layer["admit_ahead_share"].unit == "%"
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    (entry,) = [m for m in spec["per_layer"]
+                if m["name"] == "admit_ahead_share"]
+    assert (entry["layer"], entry["source"], entry["better"],
+            entry["moves"]) == ("batcher", "program_counter", "higher",
+                                "gap_mean_ms")
+    assert cell in entry["workloads"]
+
+
 # ---------------------------------------------------------------------------
 # PR 44: the lfm2_moe cell's four readers, on events made by hand
 # ---------------------------------------------------------------------------
