@@ -20,9 +20,10 @@ The three pillars here:
   ``jax.profiler.TraceAnnotation`` — so ANY profiler capture shows the
   program's phases on the device timeline with no one calling
   :func:`enable` — and always feeds the caller's host-clock counters;
-  ``compile_totals`` is the process's one backend-compile counter;
-  :func:`~edgellm_tpu.obs.tracing.trace_capture` subsumes the old
-  ``utils.profiling.trace`` stub.
+  ``compile_totals`` is the process's one backend-compile counter,
+  ``thread_usage`` and ``host_counters`` what the calling thread and the
+  machine have paid so far (the batcher reads them where a step stalls);
+  :func:`~edgellm_tpu.obs.tracing.trace_capture` wraps the XLA-level capture.
 - :mod:`~edgellm_tpu.obs.latency` — TTFT + per-token latency histograms for
   the decode loops, measured at *sample boundaries* (one host sync per
   sampled token, never per-op) so observation does not serialize dispatch.

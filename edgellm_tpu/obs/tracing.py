@@ -19,7 +19,10 @@ Two entry points, for two kinds of call site:
   accumulator. What that costs with the tracer disabled and no capture
   running: a ``time.monotonic`` reading or two and one annotation object,
   about two microseconds a phase. With the tracer enabled it also records the
-  :class:`Span`, nested under its parent by the same per-thread stack.
+  :class:`Span`, nested under its parent by the same per-thread stack. Where
+  its caller names a second key (``cpu_key``) it keeps the calling thread's
+  CPU seconds (``time.thread_time``) over the same interval beside the wall:
+  what the thread computed, as against what it waited or was descheduled.
 
 Design points:
 
@@ -33,9 +36,13 @@ Design points:
   and seconds of JAX backend compiles, fed by ONE ``jax.monitoring``
   listener registered on first use; callers difference it around their own
   work (the batcher's ``compiles``/``compile_s``).
+- **What the thread and the host paid.** :func:`thread_usage` is the calling
+  thread's ``getrusage`` (CPU seconds user and system, context switches,
+  page faults) and :func:`host_counters` the machine's own monotone counters
+  (the container's CPU throttling, the pressure totals): running totals like
+  ``compile_totals``, which a caller differences around its own work.
 - **trace_capture** wraps ``jax.profiler.trace`` (the XLA-level profiler
-  dump) and subsumes the old ``utils.profiling.trace`` stub, which now
-  delegates here.
+  dump).
 """
 from __future__ import annotations
 
@@ -50,8 +57,9 @@ from . import context as _context
 from ..utils.concurrency import guarded_by
 
 __all__ = [
-    "Span", "Tracer", "compile_totals", "configure", "get_tracer", "phase",
-    "span", "trace_capture", "tracing_enabled",
+    "THREAD_USAGE", "Span", "Tracer", "compile_totals", "configure",
+    "get_tracer", "host_counters", "phase", "span", "thread_usage",
+    "trace_capture", "tracing_enabled",
 ]
 
 
@@ -217,23 +225,33 @@ class phase:
     phases belong to the later one. The spans themselves start where they
     are entered.
 
+    ``cpu_key`` names a second key of ``acc`` that gains the calling
+    thread's CPU seconds (``time.thread_time``) between the same two edges:
+    read inside the wall's readings where the phase reads its own, taken
+    from ``after`` where the wall is (``cpu_start`` / ``cpu_end``, beside
+    ``start`` / ``end``). A clock reading or two more where it is named,
+    none where it is not.
+
     ``attrs`` given here reach the profiler capture (as the event's stats)
     and the recorded :class:`Span`; :meth:`set` adds what is known only at
     the end (how many were admitted) to the recorded Span alone — a
     ``TraceAnnotation``'s attributes are fixed when it is entered.
     """
 
-    __slots__ = ("name", "attrs", "start", "end", "_acc", "_key", "_after",
-                 "_ann", "_span")
+    __slots__ = ("name", "attrs", "start", "end", "cpu_start", "cpu_end",
+                 "_acc", "_key", "_cpu_key", "_after", "_ann", "_span")
 
     def __init__(self, name: str, acc: Optional[Dict[str, float]] = None,
                  key: Optional[str] = None, after: Optional["phase"] = None,
-                 **attrs: Any) -> None:
+                 cpu_key: Optional[str] = None, **attrs: Any) -> None:
         self.name = name
         self.attrs = attrs
         self.end: Optional[float] = None
+        self.cpu_start: Optional[float] = None
+        self.cpu_end: Optional[float] = None
         self._acc = acc
         self._key = key
+        self._cpu_key = cpu_key
         self._after = after
         self._span: Optional[Span] = None
 
@@ -243,6 +261,14 @@ class phase:
             self.start = time.monotonic()
         else:
             self.start = after.start if after.end is None else after.end
+        if self._cpu_key is not None:
+            # the thread's clock at the edge the wall counts from: the
+            # reading ``after`` took there, where it took one
+            if after is not None:
+                self.cpu_start = (after.cpu_start if after.end is None
+                                  else after.cpu_end)
+            if self.cpu_start is None:
+                self.cpu_start = time.thread_time()
         self._ann = _jax_annotation(self.name, **self.attrs)
         self._ann.__enter__()
         if _TRACER.enabled:
@@ -257,10 +283,15 @@ class phase:
         if self._span is not None:
             _TRACER._close(self._span)
         self._ann.__exit__(*exc)
+        if self._cpu_key is not None:
+            self.cpu_end = time.thread_time()
         self.end = time.monotonic()
-        if self._acc is not None:
-            self._acc[self._key] = (self._acc.get(self._key, 0.0)
-                                    + self.end - self.start)
+        acc = self._acc
+        if acc is not None:
+            acc[self._key] = acc.get(self._key, 0.0) + self.end - self.start
+            if self._cpu_key is not None:
+                acc[self._cpu_key] = (acc.get(self._cpu_key, 0.0)
+                                      + self.cpu_end - self.cpu_start)
         return False
 
 
@@ -295,6 +326,89 @@ def compile_totals() -> tuple:
         jax.monitoring.register_event_duration_secs_listener(
             _on_compile_event)
     return totals
+
+
+try:
+    import resource as _resource
+    _RUSAGE_THREAD = _resource.RUSAGE_THREAD
+except (ImportError, AttributeError):  # no per-thread accounting here
+    _resource = _RUSAGE_THREAD = None
+
+
+#: what :func:`thread_usage` returns, field by field
+THREAD_USAGE = ("cpu_user_s", "cpu_sys_s", "nvcsw", "nivcsw", "minflt",
+                "majflt")
+
+
+def thread_usage() -> Optional[tuple]:
+    """The calling thread's running totals since it started, or None where
+    the platform keeps none a thread (``RUSAGE_THREAD``: Linux): (user CPU
+    seconds, system CPU seconds, voluntary context switches: it blocked;
+    involuntary ones: it was preempted; minor page faults; major ones),
+    named by :data:`THREAD_USAGE`. One system call; a caller differences
+    two readings around its own work."""
+    if _RUSAGE_THREAD is None:
+        return None
+    ru = _resource.getrusage(_RUSAGE_THREAD)
+    return (ru.ru_utime, ru.ru_stime, ru.ru_nvcsw, ru.ru_nivcsw,
+            ru.ru_minflt, ru.ru_majflt)
+
+
+#: where :func:`host_counters` reads: the container's ``cpu.stat`` (cgroup
+#: v2, then v1: the first that opens) and the directory of pressure files
+HOST_COUNTER_PATHS = {
+    "cpu_stat": ("/sys/fs/cgroup/cpu.stat", "/sys/fs/cgroup/cpu/cpu.stat"),
+    "pressure": "/proc/pressure",
+}
+_PRESSURES = ("cpu", "memory", "io")
+
+
+def _read_text(path: str) -> Optional[str]:
+    try:
+        with open(path) as f:
+            return f.read()
+    except OSError:
+        return None
+
+
+def host_counters() -> Dict[str, Optional[float]]:
+    """The machine's own monotone counters, each None where its file is
+    absent, unreadable or lacks the line: ``nr_throttled`` and
+    ``cpu_throttled_s``, the periods in which the container ran into its CPU
+    quota and the seconds its threads were held off for it (``cpu.stat``:
+    cgroup v2 ``throttled_usec``, v1 ``throttled_time`` in ns), and
+    ``pressure_cpu_s`` / ``pressure_memory_s`` / ``pressure_io_s``, the
+    seconds in which some task of the machine waited for that resource (the
+    ``some`` line's ``total`` of ``/proc/pressure/<resource>``, in us).
+    Running totals of the kernel's, so a caller differences two readings;
+    four small files, read where a caller asks and never on a hot path."""
+    out: Dict[str, Optional[float]] = dict.fromkeys(
+        ("nr_throttled", "cpu_throttled_s",
+         *(f"pressure_{r}_s" for r in _PRESSURES)))
+    for path in HOST_COUNTER_PATHS["cpu_stat"]:
+        text = _read_text(path)
+        if text is None:
+            continue
+        fields = dict(pair for pair in map(str.split, text.splitlines())
+                      if len(pair) == 2)
+        try:
+            if "nr_throttled" in fields:
+                out["nr_throttled"] = int(fields["nr_throttled"])
+            if "throttled_usec" in fields:
+                out["cpu_throttled_s"] = int(fields["throttled_usec"]) * 1e-6
+            elif "throttled_time" in fields:
+                out["cpu_throttled_s"] = int(fields["throttled_time"]) * 1e-9
+        except ValueError:
+            pass
+        break
+    for res in _PRESSURES:
+        text = _read_text(os.path.join(HOST_COUNTER_PATHS["pressure"], res))
+        for line in (text or "").splitlines():
+            if line.startswith("some "):
+                total = line.rpartition("total=")[2]
+                if total.isdigit():
+                    out[f"pressure_{res}_s"] = int(total) * 1e-6
+    return out
 
 
 @contextlib.contextmanager

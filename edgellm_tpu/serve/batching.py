@@ -57,18 +57,17 @@ module schedules many streams through ONE jitted decode step built on
   per-stage on the mesh (``SplitRuntime.init_paged_pool``), and every ragged
   step crosses the boundary once per cut through the quantized hop ladder —
   batched serving over a split plan, no longer local-pool-only.
-- a stack walked by layer kinds (``models/hybrid.py``) has a step
-  executable of its own: ``_batched_hybrid_step_jit`` with the per-slot
-  recurrent state of a ``granitemoehybrid`` or ``lfm2_moe`` stack (the leaves
+- a stack walked by layer kinds (``models/hybrid.py``) has a step executable of
+  its own: ``_batched_hybrid_step_jit`` with the per-slot recurrent state of a
+  ``granitemoehybrid`` or ``lfm2_moe`` stack (the leaves
   ``hybrid.state_shapes`` names, one donated pytree),
-  ``_batched_window_step_jit``
-  with the second page group of a ``mellum`` stack (each sliding-window
-  layer's ring of pages, ``PagedKVCache.window_pool`` / ``window_table``):
-  admission adopts a prompt's tail into the rings, eviction gathers them with
-  the full layers' rows, and what cannot carry them refuses by name. A
-  ``mistral4`` stack rides ``_batched_hybrid_step_jit`` too: its pool is ONE
-  leaf of latent rows (``paged_kv.LatentPool``), handed over where a K/V
-  pool's one leaf goes, with no state store; admission adopts the
+  ``_batched_window_step_jit`` with the second page group of a ``mellum`` stack
+  (each sliding-window layer's ring of pages, ``PagedKVCache.window_pool`` /
+  ``window_table``): admission adopts a prompt's tail into the rings, eviction
+  gathers them with the full layers' rows, and what cannot carry them refuses
+  by name. A ``mistral4`` stack rides ``_batched_hybrid_step_jit`` too: its
+  pool is ONE leaf of latent rows (``paged_kv.LatentPool``), handed over where
+  a K/V pool's one leaf goes, with no state store; admission adopts the
   prefill's rows (``adopt_latent``) and eviction gathers them as stored. A
   ``keye_vl2`` stack rides it with its pool of TWO leaves handed over whole
   (``paged_kv.IndexedPagePool``: K/V rows and index keys under one table);
@@ -82,10 +81,12 @@ from __future__ import annotations
 
 import bisect
 import functools
+import gc
+import logging
 import os
 import threading
 import time
-from collections import defaultdict, deque
+from collections import OrderedDict, defaultdict, deque
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -98,15 +99,14 @@ from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
 from ..models.sparse_attn import EVERY_ROW, ROW_GATHER, sparse_read_path
 from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, IndexedPagePool, \
-    PagePool, OutOfPages, \
-    OutOfSlots, PagedKVCache, PrefixCacheConfig, \
+    PagePool, OutOfPages, OutOfSlots, PagedKVCache, PrefixCacheConfig, \
     decode_read_path, paged_decode_step, resolve_kv_codec, walk_geometry
 from ..models.flash_attention import leading_runs
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
 from ..obs.flight import flight_dump_for
-from ..obs.tracing import compile_totals, phase as obs_phase, \
-    span as obs_span
+from ..obs.tracing import THREAD_USAGE, compile_totals, host_counters, \
+    phase as obs_phase, span as obs_span, thread_usage
 from ..utils.concurrency import guarded_by
 from .decode import _prefill_jit, _prefill_suffix_jit, _sample
 from .recovery import (CheckpointError, CheckpointTierMismatchError,
@@ -362,11 +362,11 @@ class _Launched:
     watchdog: Optional[Watchdog]  # armed at its launch
 
 
-#: host-clock counters of ``stats``/``report()``, monotonic seconds, additive
-#: (``report()`` says what each encloses; the last three are ``_fold_step``'s)
+#: clocks of ``stats``/``report()``, seconds, additive (what each is: there)
 _CLOCKS = ("step_wall_s", "admit_s", "grow_s", "build_s", "launch_s",
            "sync_s", "commit_s", "queue_wait_s", "admit_step_wall_s",
-           "between_s", "tok0_hold_s")
+           "between_s", "tok0_hold_s", "step_cpu_s", "admit_cpu_s",
+           "stall_excess_s", "stall_off_cpu_s")
 
 
 @guarded_by("_stats_lock", fields=["stats"])
@@ -474,9 +474,9 @@ class ContinuousBatcher:
                       "occ_sum": 0.0, "slot_sum": 0.0, "alloc_sum": 0.0,
                       "alloc_n": 0, "compiles": 0, "compile_s": 0.0,
                       "routed_assignments": 0, "admit_steps": 0,
-                      "steps_ahead": 0, "admits_ahead": 0,
+                      "steps_ahead": 0, "admits_ahead": 0, "stalls": 0,
                       "attend_pages_walked": 0, "attend_pages_spanned": 0,
-                      "attend_pages_in_runs": 0,
+                      "attend_pages_in_runs": 0, "steps_judged": 0,
                       "window_pages_walked": 0, "window_pages_spanned": 0,
                       "sparse_rows_live": 0, "sparse_rows_attended": 0,
                       "index_rows_scored": 0,
@@ -495,10 +495,10 @@ class ContinuousBatcher:
         self._acc: dict[str, float] = defaultdict(int)
         self._tok0_at: list[float] = []
         self._returned: Optional[float] = None
-        # the launched step whose tokens are still on the device, and the
-        # clock at the last read
+        # the step whose tokens are still on the device; the last read's clock
         self._inflight: Optional[_Launched] = None
         self._read_at = 0.0
+        self._judge = _StepJudge()  # what a launched step is judged against
         # the admissions whose token 0 is still on the device, oldest first:
         # (stream, token 0, prompt positions matched, the admission's start);
         # and the clock at the last admission's end
@@ -1091,7 +1091,7 @@ class ContinuousBatcher:
         (``sync_s`` holds that wait)."""
         c0 = compile_totals()
         whole = obs_phase("batch.step", self._acc, "step_wall_s",
-                          step=int(self.stats["steps"]),
+                          cpu_key="step_cpu_s", step=int(self.stats["steps"]),
                           running=len(self._slot_to_sid),
                           waiting=len(self._waiting))
         try:
@@ -1106,7 +1106,7 @@ class ContinuousBatcher:
         # admit in FIFO order until a stream doesn't fit (no overtaking:
         # admission order stays deterministic)
         with obs_phase("batch.step.admit", acc, "admit_s", after=whole,
-                       step=step_no) as ph:
+                       cpu_key="admit_cpu_s", step=step_no) as ph:
             admitted = 0
             while self._waiting:
                 sid = self._waiting[0]
@@ -1398,8 +1398,19 @@ class ContinuousBatcher:
         launched step after it has no step before it; nor has the one after
         a step that left nothing running or waiting (a caller need not poll
         an empty batcher): an idle batcher's wait is no hand-off.
-        ``prefill_hold()`` (no ``whole``) holds no token."""
+        ``prefill_hold()`` (no ``whole``) holds no token.
+
+        And its verdict (``_StepJudge``): the step's wall against the median
+        of its kind's, the caller's time before it against
+        ``STALL_EXCESS_S``. A stall goes to the ring, to ``stalls`` /
+        ``stall_excess_s`` / ``stall_off_cpu_s`` and to the log; a step that
+        had an expectation to be held against counts in ``steps_judged``."""
         tok0_at = self._tok0_at
+        judge = self._judge
+        if whole is not None:
+            # what the thread and the process have spent, at every call's end:
+            # a stall's record differences the last two
+            judge.read(self.stats["evicted"])
         if whole is None or not acc.get("steps"):
             if whole is not None:
                 self._returned = None
@@ -1410,15 +1421,27 @@ class ContinuousBatcher:
         row[0] += 1
         for i, k in enumerate(_PHASES, 1):
             row[i] += acc[k]
+        stalls = []
         if self._returned is not None:
-            acc["between_s"] = whole.start - self._returned
+            acc["between_s"] = between = whole.start - self._returned
+            if between >= STALL_EXCESS_S:
+                stalls.append(judge.between(acc, whole, self._returned))
         idle = not self._slot_to_sid and not self._waiting
         self._returned = None if idle else whole.end
+        judge.cpu_returned = whole.cpu_end
         if acc.get("admitted"):
             acc["admit_steps"] = 1
             acc["admit_step_wall_s"] = wall
             acc["tok0_hold_s"] = sum(whole.end - t for t in tok0_at)
             tok0_at.clear()
+        judged, stall = judge.step(acc, whole)
+        acc["steps_judged"] = int(judged)
+        if stall is not None:
+            stalls.append(stall)
+        if stalls:
+            acc["stalls"] = len(stalls)
+            acc["stall_excess_s"] = sum(s["excess_s"] for s in stalls)
+            acc["stall_off_cpu_s"] = sum(s["off_cpu_s"] for s in stalls)
 
     # -- checkpoint / restore ----------------------------------------------
 
@@ -1538,9 +1561,12 @@ class ContinuousBatcher:
     # -- reporting ---------------------------------------------------------
 
     def report(self) -> dict:
+        host = host_counters()  # four small files: read outside the lock
         with self._stats_lock:
             stats = dict(self.stats)  # one consistent snapshot for the scrape
             hist = [row[:] for row in stats["step_wall_hist"]]
+            stall_log = list(self._judge.log)
+            self._judge.seen(host)
         n = stats["steps"]
         alloc_n = stats["alloc_n"]
         dec = stats["decode_s"]
@@ -1571,9 +1597,28 @@ class ContinuousBatcher:
             # wall of those that admitted (admit_steps of them); between_s,
             # from the return of one to the entry of the next while there was
             # work, the caller's loop; tok0_hold_s, from a fresh admission's
-            # token 0 on the host to the return of the call that admitted it
+            # token 0 on the host to the return of the call that admitted it.
+            # step_cpu_s / admit_cpu_s: the scheduler thread's CPU seconds
+            # (time.thread_time) over what step_wall_s / admit_s enclose: the
+            # wall less these is what the thread waited or was descheduled
             **{k: stats[k] for k in _CLOCKS},
             "admit_steps": stats["admit_steps"],
+            # the launched steps that were judged against their kind (the
+            # launched steps with the same admissions, prefill tokens and
+            # "did it evict": _StepJudge), and the stalls: a judged step whose
+            # wall lay STALL_EXCESS_S over the median of its kind's last
+            # walls, or the caller's time between two steps of that much.
+            # stall_excess_s (above): the seconds over, stall_off_cpu_s: those
+            # of them the thread was not on a CPU beyond its kind's habit.
+            # All additive. stall_log: the last stalls, oldest first, one
+            # record each (_StepJudge.between / .step say what is in one)
+            "steps_judged": stats["steps_judged"],
+            "stalls": stats["stalls"],
+            "stall_log": stall_log,
+            # the machine's own running totals now (obs.tracing.host_counters:
+            # CPU throttling of the container, pressure), None where the
+            # machine shows none: report1 - report0 is a window's worth
+            "host": host,
             # the launched steps whose predecessor's tokens were still unread
             # at the launch: the device had the next step before the host had
             # this one's tokens (additive; over ``steps``, the share of
@@ -1750,3 +1795,153 @@ def _new_step_wall_hist() -> list:
     over the last edge; a row is [steps, *seconds of the six phases]."""
     return [[0] + [0.0] * len(_PHASES)
             for _ in range(len(_STEP_WALL_EDGES) + 1)]
+
+
+# -- the stall record --------------------------------------------------------
+# (down here for the same reason)
+
+#: a launched step is a stall when its wall lies this far over the median of
+#: its kind's, and the caller's time between two steps when it is this long:
+#: half the 103-124 ms PERF.md section 6 "PR 49" saw, five plain steps' wall
+STALL_EXCESS_S = 0.05
+#: a kind is judged by the median of its last _KIND_WALLS steps once it has
+#: _KIND_MIN; the table keeps the _KINDS_MAX kinds seen last, the ring the
+#: last _STALL_LOG_LEN stalls
+_KIND_WALLS, _KIND_MIN, _KINDS_MAX, _STALL_LOG_LEN = 9, 5, 128, 64
+_LOG = logging.getLogger(__name__)
+
+
+def _median(values) -> float:
+    s = sorted(values)
+    return (s[(len(s) - 1) // 2] + s[len(s) // 2]) / 2
+
+
+def _gc_collections() -> list:
+    return [g["collections"] for g in gc.get_stats()]
+
+
+class _StepJudge:
+    """What ``ContinuousBatcher._fold_step`` holds a launched step against,
+    and the ring of those that failed; written by the scheduler thread under
+    the fold's lock, which ``report()`` copies the ring under.
+
+    A step's KIND is (streams it admitted, prompt positions it prefilled,
+    whether it evicted): the same device work to first order, and a closed
+    loop has a handful. A kind keeps its last ``_KIND_WALLS`` steps as rows
+    of [wall, the six phases, wall - the thread's CPU seconds]; with
+    ``_KIND_MIN`` of them the median wall is what the next step of the kind
+    is expected to take. On a sound step's path: one ``thread_usage`` and
+    one ``time.process_time`` a call, one lookup, a median of nine, one
+    append. Everything else is read at a stall."""
+
+    def __init__(self):
+        self.kinds: OrderedDict = OrderedDict()  # kind -> its last rows
+        self.log: deque = deque(maxlen=_STALL_LOG_LEN)
+        # (thread_usage(), time.process_time(), stats["evicted"]) at the end
+        # of the last step() call and of the one before it
+        self.now = self.before = (thread_usage(), time.process_time(), 0)
+        # the thread's clock where ``_returned`` was read
+        self.cpu_returned = 0.0
+        # what a stall differences against: the collections a generation and
+        # the host's counters (with the clock) at the last stall or report()
+        self.gc_seen = _gc_collections()
+        self.host_seen = (host_counters(), time.monotonic())
+
+    def read(self, evicted: int) -> None:
+        self.before, self.now = self.now, (
+            thread_usage(), time.process_time(), evicted)
+
+    def seen(self, host: dict) -> None:
+        """``host`` is the host's counters now (a stall's, ``report()``'s):
+        the next stall's deltas, these and the collections, count from
+        here. Under the fold's lock, like the ring."""
+        self.gc_seen, self.host_seen = _gc_collections(), (
+            host, time.monotonic())
+
+    def between(self, acc: dict, whole: obs_phase, returned: float) -> dict:
+        """The record of a caller that took ``STALL_EXCESS_S`` or more from
+        the last launched step's return (``returned``) to the entry of
+        ``whole``: all of it is excess, its CPU seconds are the thread's
+        between the two calls."""
+        wall = whole.start - returned
+        cpu = whole.cpu_start - self.cpu_returned
+        return self._stall("between", acc, whole, returned, wall, 0.0,
+                           dict.fromkeys(_PHASES, 0.0), cpu, wall - cpu)
+
+    def step(self, acc: dict, whole: obs_phase) -> tuple:
+        """(whether the launched step of ``whole`` had an expectation, its
+        stall record or None), and the step joins its kind."""
+        wall, cpu = acc["step_wall_s"], acc["step_cpu_s"]
+        kind = (acc.get("admitted", 0), acc.get("prefill_tokens", 0),
+                self.now[2] != self.before[2])
+        rows = self.kinds.get(kind)
+        if rows is None:
+            rows = self.kinds[kind] = deque(maxlen=_KIND_WALLS)
+            if len(self.kinds) > _KINDS_MAX:
+                self.kinds.popitem(last=False)
+        else:
+            self.kinds.move_to_end(kind)
+        row = (wall, *[acc[k] for k in _PHASES], wall - cpu)
+        judged, stall = len(rows) >= _KIND_MIN, None
+        if judged:
+            expected = _median([r[0] for r in rows])
+            if wall - expected > STALL_EXCESS_S:
+                meds = [_median(col) for col in zip(*rows)]
+                over = {k: row[i] - meds[i] for i, k in enumerate(_PHASES, 1)}
+                stall = self._stall(
+                    max(over, key=over.get)[:-2], acc, whole, whole.start,
+                    wall, expected, dict(zip(_PHASES, row[1:])), cpu,
+                    row[-1] - meds[-1])
+        rows.append(row)
+        return judged, stall
+
+    def _stall(self, where: str, acc: dict, whole: obs_phase, t_s: float,
+               wall: float, expected: float, phases: dict, cpu: float,
+               off_cpu: float) -> dict:
+        """One record, appended to the ring and logged. ``step`` is the
+        ``step=`` of the ``batch.step`` span (a capture's key), ``t_s`` the
+        stall's start on ``time.monotonic``; ``where`` the phase with the
+        largest excess over its kind's median, or ``between``. ``cpu_s`` is
+        the thread's in ``wall_s``, ``off_cpu_s`` the wall less it, less the
+        kind's median of the same. Counted from the end of the call before,
+        so with the caller's time in them: ``cpu_user_s`` / ``cpu_sys_s``,
+        ``nvcsw`` (it blocked) / ``nivcsw`` (it was preempted), ``minflt`` /
+        ``majflt`` (None where the platform keeps none a thread) and
+        ``proc_cpu_s``, all the process's threads. ``gc`` is the collections
+        a generation and ``host_delta`` the host's counters since the
+        reading ``host_age_s`` ago: the last stall or ``report()``."""
+        (u0, p0, _), (u1, p1, _) = self.before, self.now
+        usage = (dict.fromkeys(THREAD_USAGE) if u0 is None or u1 is None else
+                 {k: b - a for k, a, b in zip(THREAD_USAGE, u0, u1)})
+        gc0, (host0, at0), host = self.gc_seen, self.host_seen, host_counters()
+        self.seen(host)
+        rec = {
+            "step": whole.attrs.get("step"), "t_s": t_s, "where": where,
+            "wall_s": wall, "expected_s": expected,
+            "excess_s": wall - expected,
+            "admitted": acc.get("admitted", 0),
+            "prefill_tokens": acc.get("prefill_tokens", 0),
+            "running": whole.attrs.get("running"),
+            "waiting": whole.attrs.get("waiting"),
+            **phases, "cpu_s": cpu, "off_cpu_s": off_cpu, **usage,
+            "proc_cpu_s": p1 - p0,
+            "gc": [b - a for a, b in zip(gc0, self.gc_seen)],
+            "host": host,
+            "host_delta": {k: None if None in (host0[k], v) else v - host0[k]
+                           for k, v in host.items()},
+            "host_age_s": self.host_seen[1] - at0,
+        }
+        self.log.append(rec)
+        throttled = rec["host_delta"]["cpu_throttled_s"]
+        _LOG.warning(
+            "batch.step %s stalled in %s: %.1f ms over its kind's %.1f ms, "
+            "%.0f%% of it off the CPU (blocked %s times, preempted %s); "
+            "the container %s in the last %.1f s",
+            rec["step"], where, 1e3 * rec["excess_s"], 1e3 * expected,
+            100.0 * off_cpu / rec["excess_s"], usage["nvcsw"],
+            usage["nivcsw"],
+            "shows no cpu.stat" if throttled is None else
+            "was throttled %.1f ms (%s periods)" % (
+                1e3 * throttled, rec["host_delta"]["nr_throttled"]),
+            rec["host_age_s"])
+        return rec

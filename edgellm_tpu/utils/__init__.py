@@ -1,6 +1,6 @@
-"""Shared utilities: profiling/tracing helpers, the host-side clock protocol."""
+"""Shared utilities: timing helpers, the host-side clock protocol."""
 from .clock import MONOTONIC, Clock, FakeClock, sequence_clock
-from .profiling import trace, timed, throughput
+from .profiling import timed, throughput
 
-__all__ = ["trace", "timed", "throughput",
+__all__ = ["timed", "throughput",
            "Clock", "MONOTONIC", "FakeClock", "sequence_clock"]

@@ -1,35 +1,15 @@
-"""Tracing and timing helpers (SURVEY.md section 5: the reference's only
-observability is tqdm progress bars; here: real XLA traces + wall-clock helpers).
+"""Timing helpers (SURVEY.md section 5: the reference's only observability is
+tqdm progress bars; here: wall-clock helpers; XLA traces are
+``obs.tracing.trace_capture``).
 
-``trace("/tmp/trace")`` wraps ``jax.profiler.trace`` — view the result with
-TensorBoard or Perfetto to see per-op device time, including the ``ppermute``
-boundary transfers and Pallas codec kernels. ``timed``/``throughput`` give
-honest wall-clock numbers by blocking on device completion.
+``timed``/``throughput`` give honest wall-clock numbers by blocking on device
+completion.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Deprecated shim over :func:`edgellm_tpu.obs.tracing.trace_capture`
-    (same contract: capture an XLA profiler trace for the enclosed block,
-    raise when the profiler cannot start). New code should
-    use ``obs.tracing.trace_capture`` directly — it composes with the host
-    span tracer and the ``--trace-out`` Chrome trace export."""
-    import warnings
-
-    from ..obs.tracing import trace_capture
-
-    warnings.warn("utils.profiling.trace is deprecated; use "
-                  "edgellm_tpu.obs.tracing.trace_capture",
-                  DeprecationWarning, stacklevel=3)
-    with trace_capture(log_dir):
-        yield
 
 
 def _block(x):

@@ -414,6 +414,9 @@ class _ShiftedClock:
     def monotonic(self):
         return self._time.monotonic() + self.offset
 
+    def __getattr__(self, name):        # the CPU clocks are the machine's
+        return getattr(self._time, name)
+
 
 @pytest.fixture
 def clock(monkeypatch):

@@ -289,17 +289,16 @@ def test_span_disabled_is_free_and_records_nothing():
     assert tracer.spans() == []
 
 
-def test_trace_capture_shim_and_deprecation(tmp_path):
-    """utils.profiling.trace delegates (with a DeprecationWarning) to
-    obs.tracing.trace_capture, which RAISES when a capture that was asked
-    for cannot start — the layer metrics are read from these traces, so a
-    run must not carry on without one."""
-    from edgellm_tpu.utils import profiling
-
-    with pytest.deprecated_call():
-        with profiling.trace(str(tmp_path / "xla")):
-            pass
+def test_trace_capture_raises_where_a_capture_cannot_start(tmp_path):
+    """obs.tracing.trace_capture RAISES when a capture that was asked for
+    cannot start — the layer metrics are read from these traces, so a run
+    must not carry on without one. (The ``utils.profiling.trace`` shim over
+    it is gone, and nothing else answers to the name.)"""
+    from edgellm_tpu import utils
     from edgellm_tpu.obs.tracing import trace_capture
+
+    assert not hasattr(utils.profiling, "trace")
+    assert not hasattr(utils, "trace")
 
     with trace_capture(None):  # not asked for: a no-op
         pass

@@ -611,3 +611,71 @@ def test_the_lfm2_cell_lists_its_readers_and_the_ones_it_joins():
     assert order[at:at + 5] == [*LFM2_READERS, "attend_run_share"]
     assert LFM2_CELL in [w["name"] for w in spec["workloads"]]
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
+
+
+# ---------------------------------------------------------------------------
+# PR 50: the stall count, the CPU clocks and the host's counters over a window
+# ---------------------------------------------------------------------------
+
+STALL_READERS = ("stalls_in_window", "stall_ms_in_window",
+                 "stall_off_cpu_ms", "steps_judged_share",
+                 "host_step_cpu_ms", "host_admit_cpu_ms")
+
+
+def _stall_report(steps, judged, stalls, excess, off, step_cpu, admit_cpu):
+    return {"steps": steps, "steps_judged": judged, "stalls": stalls,
+            "stall_excess_s": excess, "stall_off_cpu_s": off,
+            "step_cpu_s": step_cpu, "admit_cpu_s": admit_cpu,
+            "host": {"nr_throttled": None, "cpu_throttled_s": None}}
+
+
+#: two windows on one report0: three stalls of 0.36 s, 0.27 of it off the
+#: CPU; and a window with no stall. The machine shows no ``cpu.stat``, as the
+#: benchmark's do
+_R0 = _stall_report(100, 90, 1, 0.1, 0.1, 2.0, 0.5)
+_STALLED = _stall_report(300, 280, 4, 0.46, 0.37, 2.8, 0.9)
+_SOUND = _stall_report(300, 290, 1, 0.1, 0.1, 3.0, 0.5)
+
+
+@pytest.mark.parametrize("name, stalled, sound", [
+    ("stalls_in_window", 3, 0),
+    ("stall_ms_in_window", 360.0, 0.0),
+    ("stall_off_cpu_ms", 270.0, 0.0),
+    ("steps_judged_share", 95.0, 100.0),
+    ("host_step_cpu_ms", 4.0, 5.0),
+    ("host_admit_cpu_ms", 2.0, 0.0)])
+def test_stall_reader_on_two_hand_made_windows(name, stalled, sound):
+    assert _read(name, _record(False, _R0, _STALLED)) == pytest.approx(stalled)
+    assert _read(name, _record(False, _R0, _SOUND)) == pytest.approx(sound)
+
+
+@pytest.mark.parametrize("window", ["stalled", "sound"])
+@pytest.mark.parametrize("name", STALL_READERS)
+def test_stall_reader_speaks_in_every_window_of_a_program_that_counts(
+        name, window):
+    # a cell that lists a reader has to print it in every traced run: with a
+    # stall or with none, and on a machine that shows no cpu.stat
+    r1 = _STALLED if window == "stalled" else _SOUND
+    assert isinstance(_read(name, _record(False, _R0, r1)), (int, float))
+
+
+@pytest.mark.parametrize("name", STALL_READERS)
+def test_stall_reader_gives_nothing_on_a_program_without_its_counters(name):
+    # the parent's report(): steps and the older clocks, no stall count, no
+    # CPU clock, no "host"
+    old = _record(False, {"steps": 5, "step_wall_s": 1.0, "admit_s": 0.2},
+                  {"steps": 25, "step_wall_s": 3.0, "admit_s": 0.4})
+    assert _read(name, old) is None
+
+
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_every_cell_lists_the_stall_readers(cell):
+    names = {m.name for m in load_cell(cell).per_layer}
+    assert set(STALL_READERS) <= names
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in STALL_READERS:
+        m = entries[name]
+        assert (m["source"], m["layer"], m["moves"]) == (
+            "program_counter", "batcher", "gap_mean_ms")
+        assert m["workloads"] == list(ALL_CELLS)
