@@ -718,7 +718,7 @@ def shortconv_phase(*, prompt_len: int = 300, n_new: int = 40,
 
     from edgellm_tpu.models import grouped_matmul
     from edgellm_tpu.models.configs import tiny_lfm2_moe_config
-    from edgellm_tpu.models.paged_kv import PAGE_WALK
+    from edgellm_tpu.models.paged_kv import INDEX_WALK, PAGE_WALK
     from edgellm_tpu.serve.batching import BatchingConfig
 
     def wider(params):
@@ -778,7 +778,7 @@ def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
 
     from edgellm_tpu.models import grouped_matmul, sparse_attn
     from edgellm_tpu.models.configs import tiny_keye_vl2_config
-    from edgellm_tpu.models.paged_kv import PAGE_WALK
+    from edgellm_tpu.models.paged_kv import INDEX_WALK, PAGE_WALK
     from edgellm_tpu.serve.batching import BatchingConfig
 
     def wider(params):
@@ -803,6 +803,11 @@ def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
     assert report["sparse_read"] == sparse_attn.MASKED_WALK, report
     assert report["decode_read"] == PAGE_WALK
     assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    # the index keys (pages of 16 x 128 float32 lanes, eight to a run) are
+    # scored where they lie, the prompt's whole groups as runs
+    assert report["index_read"] == INDEX_WALK, report
+    assert 0 < report["index_pages_in_runs"] <= \
+        report["index_pages_walked"] == report["attend_pages_walked"]
     assert report["kv_row_bytes"] == (2 * 128 + 128) * 4
     assert 0 < report["sparse_rows_attended"] < report["sparse_rows_live"]
     assert report["index_rows_scored"] == report["sparse_rows_live"]
@@ -813,6 +818,9 @@ def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
             int(len(np.unique(report["served"]))),
             "evicted": report["evicted"],
             "sparse_read": report["sparse_read"],
+            "index_read": report["index_read"],
+            "index_run_share": 100.0 * report["index_pages_in_runs"]
+            / report["index_pages_walked"],
             "sparse_selected_share": 100.0 * report["sparse_rows_attended"]
             / report["sparse_rows_live"],
             "kv_row_bytes": report["kv_row_bytes"],
@@ -826,7 +834,8 @@ def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
     """The selection at the benchmark cell's widths and depths: one
     published-width sparse layer in bfloat16, a slot a depth whose index keys
     lie in the pages of a pool of the cell's geometry; the row ids a decode
-    step's own code chooses (index keys through the page gather, bf16
+    step's own code chooses (index keys scored where they lie by the index
+    walk, held here to the page gather's scores on every live row; bf16
     operands into float32 scores, ``select``, the flat ids) against
     ``jax.lax.top_k`` of float32 scores at ``highest`` from the SAME weights
     and cache. A row near the 2048th place may flip under bf16 operands; a
@@ -888,12 +897,15 @@ def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
                       jnp.bfloat16),
             jnp.zeros((len(depths), 1, cfg.num_kv_heads, cfg.head_dim),
                       jnp.bfloat16), index=ik)
+        scores = sparse_attn.index_scores_paged(qi, wi, pool, 0, table,
+                                                lengths + 1)
         rows = paged_kv._gather_pages(pool.ik, 0, table)
-        scores = sparse_attn.index_scores(qi, wi, rows)
+        live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]
+        off = jnp.max(jnp.where(live, jnp.abs(
+            scores - sparse_attn.index_scores(qi, wi, rows)), 0.0))
         # (the row gather's ids; the masked walk's mask is the same set:
         # asserted below)
         idx, _ = sparse_attn.select(scores, lengths + 1, k)
-        live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]
         keep = sparse_attn.selection_mask(scores, live, k)
         # the same weights and cache in float32 at ``highest``
         with jax.default_matmul_precision("highest"):
@@ -904,9 +916,12 @@ def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
             exact = sparse_attn.index_scores(lq, lw,
                                              rows.astype(jnp.float32))
         want, _ = sparse_attn.select(exact, lengths + 1, k)
-        return idx, want, keep, pool
+        return idx, want, keep, pool, off, jnp.max(jnp.abs(exact))
 
-    idx, want, keep, cache.pool = chosen(cache.pool, x)
+    assert paged_kv.index_read_path(cache.pool) == paged_kv.INDEX_WALK
+    idx, want, keep, cache.pool, off, largest = chosen(cache.pool, x)
+    # float32 sums of the same bf16 products, in another order
+    assert float(off) <= 1e-5 * float(largest), (float(off), float(largest))
     idx, want, keep = np.asarray(idx), np.asarray(want), np.asarray(keep)
     for slot in range(len(depths)):
         assert set(np.flatnonzero(keep[slot])) == set(idx[slot].tolist())

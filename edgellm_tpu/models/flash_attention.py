@@ -671,19 +671,18 @@ def leading_runs(page_ids, run: int, pages_per_block: int):
     return blocks.cumprod(-1).sum(-1).astype(np.int32)
 
 
-def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
-                       par_ref, *, scale, window=0, run=1, keep_ref=None):
-    """Grid (B,): slot ``b`` of the step, every head. See
-    :func:`paged_decode_walk`."""
-    b = pl.program_id(0)
-    nslots = pl.num_programs(0)
+def _walk_fetch(ids_ref, len_ref, hbm, rbuf, sem, nslots, *, window=0,
+                run=1):
+    """The FETCH half of a page walk, which every kernel that walks a slot's
+    pages inlines: (``live_pages(slot)``, ``start(slot, blk, buf)``,
+    ``wait(slot, blk, buf)``) over the scalar-prefetched table ``ids_ref``
+    (B, entries) and lengths ``len_ref`` (behind them, where ``run`` > 1, the
+    table of each block's leading runs), the leaf ``hbm`` viewed as pages and
+    the two buffers ``rbuf`` (2, pages a block, ps, lanes) with a DMA
+    semaphore each. What is done with a block that has landed is the
+    kernel's own."""
     _, ppb, ps, _ = rbuf.shape
-    rows = ppb * ps
-    w = q_ref.shape[-1]
     entries = ids_ref.shape[1]              # of a ring, where ``window``
-    # a row of w lanes is key and value both (a latent row); one of 2 w holds
-    # the K lanes, then the V lanes
-    v_at = rbuf.shape[-1] - w
 
     def live_pages(slot):
         pages = jnp.maximum(pl.cdiv(len_ref[slot], ps), 1)
@@ -749,6 +748,42 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
                     hbm.at[pl.ds(0, bit)], rbuf.at[buf, pl.ds(0, bit)],
                     sem.at[buf]).wait()
 
+    return live_pages, start, wait
+
+
+def _walk_block(b, n, nblk, base, nslots, start, wait):
+    """The buffer that holds block ``n`` of slot ``b``, landed: the next
+    block's pages are put in flight first (this slot's, or the NEXT slot's
+    first, so that no slot starts cold), then this one's are waited for.
+    ``base``: the buffer the slot's first block landed in."""
+    buf = (base + n) % 2
+    more = n + 1 < nblk
+    nxt = jnp.where(more, b, jnp.minimum(b + 1, nslots - 1))
+
+    @pl.when(more | (b + 1 < nslots))
+    def _prefetch():
+        start(nxt, jnp.where(more, n + 1, 0), 1 - buf)
+
+    wait(b, n, buf)
+    return buf
+
+
+def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
+                       par_ref, *, scale, window=0, run=1, keep_ref=None):
+    """Grid (B,): slot ``b`` of the step, every head. See
+    :func:`paged_decode_walk`."""
+    b = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    _, ppb, ps, _ = rbuf.shape
+    rows = ppb * ps
+    w = q_ref.shape[-1]
+    entries = ids_ref.shape[1]              # of a ring, where ``window``
+    # a row of w lanes is key and value both (a latent row); one of 2 w holds
+    # the K lanes, then the V lanes
+    v_at = rbuf.shape[-1] - w
+    live_pages, start, wait = _walk_fetch(ids_ref, len_ref, hbm, rbuf, sem,
+                                          nslots, window=window, run=run)
+
     @pl.when(b == 0)
     def _first():
         start(0, 0, 0)
@@ -785,17 +820,8 @@ def _paged_walk_kernel(ids_ref, len_ref, q_ref, hbm, o_ref, rbuf, sem,
 
     def body(n, carry):
         m, l, acc = carry
-        buf = (base + n) % 2
-        more = n + 1 < nblk
-        # the next block's pages are in flight while this one is attended:
-        # this slot's, or the NEXT slot's first, so that no slot starts cold
-        nxt = jnp.where(more, b, jnp.minimum(b + 1, nslots - 1))
-
-        @pl.when(more | (b + 1 < nslots))
-        def _prefetch():
-            start(nxt, jnp.where(more, n + 1, 0), 1 - buf)
-
-        wait(b, n, buf)
+        # (the next block's pages are in flight while this one is attended)
+        buf = _walk_block(b, n, nblk, base, nslots, start, wait)
         k = rbuf[buf, :, :, pl.ds(0, w)].reshape(rows, w).astype(wide)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -959,3 +985,135 @@ def paged_decode_walk(qz, pages, page_ids, lengths, *,
         interpret=interpret,
         name="paged_decode_walk",
     )(page_ids, lengths, qz, pages, *masked)
+
+
+# ---------------------------------------------------------------------------
+# The index walk: a sparse-attention layer's index keys scored where they lie.
+# ---------------------------------------------------------------------------
+
+#: rows one block of the index walk holds in VMEM. An index key is a narrow
+#: row (128 lanes), so a block is many more rows than ``WALK_BLOCK_ROWS``
+#: inside the same vector memory; PERF.md §6 "PR 51" has the v5e's timings
+#: from 512 to 4096 rows.
+INDEX_WALK_BLOCK_ROWS = 2048
+
+
+def index_walk_pages_per_block(page_size: int, lanes: int,
+                               itemsize: int) -> int:
+    """Pages a block of :func:`paged_index_walk` holds over a leaf of rows
+    ``lanes`` wide: ``INDEX_WALK_BLOCK_ROWS`` rows, fewer where the row is
+    wide, so that the two buffers stay inside 2 MB of VMEM."""
+    rows = min(INDEX_WALK_BLOCK_ROWS, (1 << 20) // (lanes * itemsize))
+    return max(rows // page_size, 1)
+
+
+def _index_walk_kernel(ids_ref, len_ref, q_ref, w_ref, hbm, o_ref, rbuf, sem,
+                       par_ref, *, run=1):
+    """Grid (B,): slot ``b`` of the step, every indexer head. The fetch is
+    the page walk's (:func:`_walk_fetch`, :func:`_walk_block`); a block that
+    has landed is SCORED. See :func:`paged_index_walk`."""
+    b = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    _, ppb, ps, lanes = rbuf.shape
+    rows = ppb * ps
+    live_pages, start, wait = _walk_fetch(ids_ref, len_ref, hbm, rbuf, sem,
+                                          nslots, run=run)
+
+    @pl.when(b == 0)
+    def _first():
+        start(0, 0, 0)
+
+    base = jnp.where(b == 0, 0, par_ref[0])
+    length = len_ref[b]
+    nblk = pl.cdiv(live_pages(b), ppb)
+    wide = jnp.promote_types(q_ref.dtype, rbuf.dtype)
+    q = q_ref[0].astype(wide)                               # (Hi, lanes)
+    w = w_ref[0]                                            # (Hi, 1) float32
+    # the blocks no live page reaches are no DMA's and no dot's: zeros
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def body(n, _):
+        buf = _walk_block(b, n, nblk, base, nslots, start, wait)
+        keys = rbuf[buf].reshape(rows, lanes).astype(wide)
+        dots = jax.lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        s = jnp.sum(jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
+        # rows past the length are stale VMEM (whatever the stream left
+        # there, NaN too): selected to zero, as a score of -0.0 is
+        covered = jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1) < length - n * rows
+        o_ref[0, :, pl.ds(pl.multiple_of(n * rows, rows), rows)] = jnp.where(
+            covered & (s != 0.0), s, 0.0)
+        return 0
+
+    jax.lax.fori_loop(0, nblk, body, 0)
+    par_ref[0] = (base + nblk) % 2
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "pages_per_block", "interpret", "run_pages"))
+def paged_index_walk(qi, wi, pages, page_ids, lengths, *,
+                     pages_per_block: int | None = None, interpret=False,
+                     run_pages: int | None = None, lead=None):
+    """A sparse-attention layer's index scores of every slot over ITS OWN
+    live index keys, read out of the pool's index-key leaf where they lie:
+    ONE kernel in place of "gather every slot's whole span of index keys
+    into a copy, then a dot over the copy" (``sparse_attn.index_scores`` of
+    ``paged_kv._gather_pages``, the oracle).
+
+    qi (B, Hi, lanes): a slot's indexer queries, zeros after their own
+    lanes; wi (B, Hi) float32: the heads' weights; pages (N, ps, lanes): the
+    leaf viewed as pages, every layer's; it stays in HBM. page_ids (B,
+    pages_per_slot) int32: slot i's pages in position order, as indices into
+    N; lengths (B,) int32: the positions slot i scores, >= 1. Returns (B,
+    pages_per_slot * ps) float32: at position p < ``lengths[i]`` of slot i,
+    ``sum_j wi[i, j] relu(qi[i, j] . row p)``, float32 sums of the operands'
+    products, a score of -0.0 made +0.0; at every other position 0.0,
+    whatever the pages and rows no length covers hold.
+
+    The fetch is :func:`paged_decode_walk`'s: slot i fetches ``ceil(
+    lengths[i] / ps)`` pages and no more, a block of ``pages_per_block``
+    (None: :func:`index_walk_pages_per_block`) at a time through two VMEM
+    buffers, the pipeline running ACROSS slots; where ``run_pages`` (None:
+    :func:`walk_run_pages` of a page's bytes and the table's width, cut to
+    what divides a block) is more than 1, the table-aligned groups of that
+    many live entries that LEAD a block and name adjacent pages
+    (:func:`leading_runs`; ``lead``: that table where the caller has made it
+    of the page table) are ONE DMA each, every other page a DMA of its own.
+    An index page is a few KB, and a walk is bound by how fast its DMAs are
+    issued: a page a DMA this is no faster than the gather (PERF.md §6 "PR
+    51")."""
+    b, hi, lanes = qi.shape
+    _, ps, r = pages.shape
+    if r != lanes or wi.shape != (b, hi):
+        raise ValueError(f"queries {qi.shape} and weights {wi.shape} against "
+                         f"index rows of {r} lanes")
+    entries = page_ids.shape[1]
+    ppb = pages_per_block or index_walk_pages_per_block(
+        ps, lanes, pages.dtype.itemsize)
+    run = math.gcd(ppb, walk_run_pages(ps * r * pages.dtype.itemsize, entries)
+                   if run_pages is None else run_pages)
+    if run > 1:
+        lead = leading_runs(page_ids, run, ppb) if lead is None else lead
+        lengths = jnp.concatenate([lengths, lead.reshape(-1)])
+    span = -(-entries // ppb) * ppb * ps        # whole blocks
+    scores = pl.pallas_call(
+        functools.partial(_index_walk_kernel, run=run),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[pl.BlockSpec((1, hi, lanes),
+                                   lambda i, ids, lens: (i, 0, 0)),
+                      pl.BlockSpec((1, hi, 1), lambda i, ids, lens: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, span),
+                                   lambda i, ids, lens: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, ppb, ps, r), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, 1, span), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_index_walk",
+    )(page_ids, lengths, qi, wi.astype(jnp.float32)[:, :, None], pages)
+    return scores[:, 0, :entries * ps]

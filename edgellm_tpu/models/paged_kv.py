@@ -425,7 +425,11 @@ class IndexedPagePool(NamedTuple):
     position in both, so pages, table, trash page, flat index and every piece
     of surgery below (written once over a pool's leaves) are
     :class:`PagePool`'s: an adopt, a gather, a fork or a defrag moves a
-    position's K/V row and its index key together."""
+    position's K/V row and its index key together. A decode step reads each
+    leaf by a walk of its own where it lies (:func:`attend_pages` the K/V
+    rows, ``sparse_attn.index_scores_paged`` the index keys), and the index
+    keys' page is the smaller: the one the length of the pool's runs is read
+    off (:func:`page_leaf_bytes`)."""
 
     kv: jnp.ndarray
     ik: jnp.ndarray
@@ -629,16 +633,18 @@ def num_pages_for_bytes(cfg: ModelConfig, pool_bytes: int, page_size: int,
 
 def page_leaf_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
                     dtype=jnp.float32) -> int:
-    """HBM bytes of ONE page of one layer (a position's K and V rows, or its
-    latent row, ``page_size`` times): what a DMA of the page walk moves, and
-    what the length of a run is read off
-    (``flash_attention.walk_run_pages``). Of an :class:`IndexedPagePool` the
-    K/V leaf's page, which is the one a walk fetches: the index keys' page is
-    :func:`kv_page_bytes`' to count, and no walk's to move."""
+    """HBM bytes of the SMALLEST page of one layer that a walk of the pool
+    fetches (a position's K and V rows, or its latent row, ``page_size``
+    times): what the length of the pool's runs is read off
+    (``flash_attention.walk_run_pages``). Of an :class:`IndexedPagePool`
+    the smaller of its two leaves' pages, each of which a walk of its own
+    fetches: at the published widths the index keys' (4 KB of 16 bf16 rows,
+    so eight go together) beside a K/V page that is a fetch by itself."""
     layers = cfg.kv_layers if cfg.latent_layers else cfg.num_layers
     page = kv_page_bytes(cfg, page_size, kv_codec, dtype) // layers
     if cfg.sparse_layers:
-        page -= page_size * cfg.index_row_lanes * jnp.dtype(dtype).itemsize
+        index = page_size * cfg.index_row_lanes * jnp.dtype(dtype).itemsize
+        page = min(page - index, index)
     return page
 
 
@@ -1009,7 +1015,8 @@ class PagedKVCache:
         # pages are handed out and taken back in RUNS of ``run_pages``
         # adjacent pages (run c: pages 1 + c*G .. c*G + G; page 0 stays the
         # trash page), the unit the page walk fetches with one DMA; G is
-        # read off a page's bytes and is 1 where a page is a fetch by itself
+        # read off a page's bytes (the smaller leaf's, of a pool of two) and
+        # is 1 where a page is a fetch by itself
         self.run_pages = flash_attention.walk_run_pages(
             page_leaf_bytes(cfg, page_size, self.kv_codec, dtype),
             pages_per_slot)
@@ -2146,11 +2153,9 @@ class PagedKVCache:
         # ones exactly when some are
         g = self.run_pages
         if self.pool is not None and self.kv_codec == "fp":
-            leaf = self.pool[0]
-            assert g == flash_attention.walk_run_pages(
-                leaf.shape[-2] * leaf.shape[-1] * leaf.dtype.itemsize,
-                self.pages_per_slot), \
-                "a run is as long as the walk's rule makes it for this leaf"
+            assert g == pool_run_pages(self.pool, self.pages_per_slot), \
+                "a run is as long as the walk's rule makes it for the " \
+                "pool's smallest page"
         assert sum(map(len, self._runs)) == len(free), \
             "a run with free pages is neither whole nor broken"
         whole = set(self._whole)
@@ -2445,10 +2450,15 @@ def decode_read_path(pool) -> str:
         return PAGE_GATHER
     # what the kernel slices on lanes (a row's K part, its V part as wide,
     # or the whole latent row) and on sublanes (a page)
-    sublanes = 32 // jnp.dtype(pool[0].dtype).itemsize
-    whole = (_k_lanes(pool) % LANE_TILE == 0
-             and pool.page_size % sublanes == 0)
-    return PAGE_WALK if whole else PAGE_GATHER
+    return (PAGE_WALK if _whole_tiles(pool[0], _k_lanes(pool))
+            else PAGE_GATHER)
+
+
+def _whole_tiles(leaf, lanes: int) -> bool:
+    """Whether a walk's kernel can slice ``leaf``: ``lanes`` whole lane
+    tiles, a page whole sublane tiles of the leaf's dtype."""
+    sublanes = 32 // jnp.dtype(leaf.dtype).itemsize
+    return lanes % LANE_TILE == 0 and leaf.shape[-2] % sublanes == 0
 
 
 def walk_geometry(pool, pages_per_slot: int) -> tuple:
@@ -2465,9 +2475,56 @@ def walk_geometry(pool, pages_per_slot: int) -> tuple:
         leaf.shape[-2], _k_lanes(pool), leaf.dtype.itemsize)
     if isinstance(pool, LatentPool):
         ppb *= 2
-    return ppb, math.gcd(ppb, flash_attention.walk_run_pages(
-        leaf.shape[-2] * leaf.shape[-1] * leaf.dtype.itemsize,
-        pages_per_slot))
+    return ppb, math.gcd(ppb, leaf_run_pages(leaf, pages_per_slot))
+
+
+def leaf_run_pages(leaf, pages_per_slot: int) -> int:
+    """``flash_attention.walk_run_pages`` of ONE leaf (whole, staged or one
+    layer's): the pages of it a walk would take with one DMA, by its own
+    page's bytes."""
+    return flash_attention.walk_run_pages(
+        leaf.shape[-2] * leaf.shape[-1] * leaf.dtype.itemsize, pages_per_slot)
+
+
+def pool_run_pages(pool, pages_per_slot: int) -> int:
+    """The runs an fp pool's pages have to lie in for every walk of it: the
+    longest any of its leaves asks for, which is its smallest page's
+    (:func:`page_leaf_bytes` says the same of a configuration). A walk takes
+    of them what ITS leaf's rule says (:func:`walk_geometry`,
+    :func:`index_walk_geometry`): a run is table-aligned and a power of two,
+    so a shorter one lies inside it."""
+    return max(leaf_run_pages(leaf, pages_per_slot) for leaf in pool)
+
+
+#: the read of a sparse layer's index keys that scores them where they lie,
+#: as ``ContinuousBatcher.report()`` names it (``index_read``; the other is
+#: :data:`PAGE_GATHER`)
+INDEX_WALK = "pallas index page walk"
+
+
+def index_read_path(pool) -> str:
+    """Which read a sparse layer's decode takes of its slots' index keys,
+    read off what it is handed as :func:`decode_read_path` reads the K/V
+    read: :data:`INDEX_WALK` (``flash_attention.paged_index_walk``) for an
+    fp :class:`IndexedPagePool` on a TPU whose index-key pages are whole
+    tiles; :data:`PAGE_GATHER` (:func:`_gather_pages` +
+    ``sparse_attn.index_scores``, the oracle) for part tiles and every other
+    backend."""
+    if not isinstance(pool, IndexedPagePool) or not _on_tpu():
+        return PAGE_GATHER
+    return (INDEX_WALK if _whole_tiles(pool.ik, pool.ik.shape[-1])
+            else PAGE_GATHER)
+
+
+def index_walk_geometry(pool: IndexedPagePool, pages_per_slot: int) -> tuple:
+    """(pages a block, pages a run) of the index walk over a table of
+    ``pages_per_slot`` entries, read off the index-key leaf as
+    :func:`walk_geometry` reads the K/V walk's off its leaf: the ONE reading
+    for the kernel's caller, its table of leading runs and the host's count."""
+    ik = pool.ik
+    ppb = flash_attention.index_walk_pages_per_block(
+        ik.shape[-2], ik.shape[-1], ik.dtype.itemsize)
+    return ppb, math.gcd(ppb, leaf_run_pages(ik, pages_per_slot))
 
 
 def _walk_runs(pool, page_table, lead=None) -> dict:

@@ -27,9 +27,15 @@ A position's index key is cached beside its K/V row, ``[kI | 0...]`` of
   one traced body (:data:`BLOCKS_PER_BODY`): the compile of a 16k prompt is
   a quarter of the unrolled form's.
 - **decode** (:func:`attention_decode_paged`): one query a slot. The slot's
-  live index keys are read through its pages (one XLA page gather of the
-  128-lane leaf) and scored; the ``topk`` best are chosen; then the read
-  the pool's K/V leaf takes (:func:`sparse_read_path`, split as
+  live index keys are scored (:func:`index_scores_paged`): on a TPU where
+  they lie, by a page walk of the pool's index-key leaf that fetches a run
+  of adjacent 4 KB pages with one DMA and scores a block while it is in
+  vector memory (``flash_attention.paged_index_walk``; 0.21 ms a layer at 32
+  slots of 8k-20k rows where the XLA page gather of every slot's whole span
+  and the dot over the copy took 1.03, and a page a DMA 0.68: PERF.md §6
+  "PR 51"); elsewhere by that gather and :func:`index_scores`, the kernel's
+  oracle (``paged_kv.index_read_path``). The ``topk`` best are chosen; then
+  the read the pool's K/V leaf takes (:func:`sparse_read_path`, split as
   ``paged_kv.PAGE_WALK`` and ``PAGE_GATHER`` are): on a TPU the MASKED WALK,
   the page walk of a GQA layer over every live page with the selection as a
   mask on its rows (``flash_attention.paged_decode_walk(keep=)``); elsewhere
@@ -54,12 +60,14 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import flash_attention
 from .configs import ModelConfig
 from .flash_attention import QBLOCK
 from .mla import rotate_rows  # (B, heads, lanes) by ONE table row a sequence
-from .paged_kv import (PAGE_WALK, IndexedPagePool, PagePool,
-                       _apply_rotary_rows, _gather_pages, _rows, attend_pages,
-                       attend_rows, decode_read_path, head_norms,
+from .paged_kv import (INDEX_WALK, PAGE_WALK, IndexedPagePool, PagePool,
+                       _apply_rotary_rows, _gather_pages, _pages, _rows,
+                       attend_pages, attend_rows, decode_read_path,
+                       head_norms, index_read_path, index_walk_geometry,
                        paged_decode_attention, split_kv, write_rows)
 from .transformer import _layernorm, apply_rotary
 
@@ -133,6 +141,29 @@ def index_scores(qi, wi, ik_rows):
     dots = jnp.einsum("bhd,bcd->bhc", _pad_query(qi, ik_rows.shape[-1]),
                       ik_rows, preferred_element_type=jnp.float32)
     return _weighted(dots, wi[:, :, None])
+
+
+def index_scores_paged(qi, wi, pool: IndexedPagePool, layer, page_table,
+                       lengths):
+    """:func:`index_scores` of each slot's index keys in layer ``layer`` of
+    the pool, by the read the pool takes (``paged_kv.index_read_path``): qi
+    (B, Hi, di), wi (B, Hi) float32, lengths (B,) the positions a slot
+    scores -> (B, span) float32. On the index walk the keys are scored where
+    they lie and a position past a slot's length reads 0.0; on the page
+    gather every table entry is gathered and scored, and what lies past a
+    length is whatever its page held. Either way the caller masks by
+    length."""
+    if index_read_path(pool) != INDEX_WALK:
+        return index_scores(qi, wi, _gather_pages(pool.ik, layer, page_table))
+    ppb, run = index_walk_geometry(pool, page_table.shape[1])
+    table = page_table.astype(jnp.int32)
+    # (of the TABLE: adjacency does not change with a layer's offset, so the
+    # compiler merges the layers' equal expressions)
+    lead = flash_attention.leading_runs(table, run, ppb) if run > 1 else None
+    return flash_attention.paged_index_walk(
+        _pad_query(qi, pool.ik.shape[-1]), wi, _pages(pool.ik, 1),
+        layer * pool.num_pages + table, lengths.astype(jnp.int32),
+        pages_per_block=ppb, run_pages=run, lead=lead)
 
 
 def select(scores, lengths, k: int):
@@ -310,8 +341,9 @@ def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope, rope_index,
     rope_index (cos, sin) (B, hd) / (B, di), each slot's own position's.
     Project, norm and rotate; write the slot's K/V row and its index key into
     its current page (``paged_kv.write``, one scatter a leaf); score the
-    slot's index keys, choose, gather the chosen K/V rows and attend them;
-    ``W_o``. Returns (out (B, D), pool)."""
+    slot's index keys (:func:`index_scores_paged`), choose, and attend the
+    chosen K/V rows by the pool's read (:func:`sparse_read_path`); ``W_o``.
+    Returns (out (B, D), pool)."""
     b = x.shape[0]
     q, k, v = (t[:, None] for t in _qkv(cfg, lp, x))           # (B, 1, ., hd)
     q = _apply_rotary_rows(q, *rope, cfg.rotary_dim)
@@ -326,8 +358,8 @@ def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope, rope_index,
                                      lengths + 1)
         return out.reshape(b, -1) @ lp["wo"], pool
     with jax.named_scope("attn.sparse.index"):
-        scores = index_scores(qi, wi, _gather_pages(pool.ik, layer,
-                                                    page_table))
+        scores = index_scores_paged(qi, wi, pool, layer, page_table,
+                                    lengths + 1)
     if read == MASKED_WALK:
         with jax.named_scope("attn.sparse.select"):
             live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]

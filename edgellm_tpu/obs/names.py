@@ -202,8 +202,9 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
                           # row writes, the indexer, the selection, the
                           # selected rows' read and attend, W_o
     "attn.sparse.index",  # the indexer's projections and its score pass over
-                          # the live index keys (decode: through the pages;
-                          # prefill: a block of query rows at a time)
+                          # the live index keys (decode: the index walk, or
+                          # the page gather and a dot; prefill: a block of
+                          # query rows at a time)
     "attn.sparse.select",  # the top-k and what turns it into row ids (decode)
                            # or a mask on a block's scores (prefill)
     "attn.sparse.prefill",  # a sparse layer over a whole prompt, by blocks

@@ -98,9 +98,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
 from ..models.sparse_attn import EVERY_ROW, ROW_GATHER, sparse_read_path
-from ..models.paged_kv import PAGE_GATHER, PAGE_WALK, IndexedPagePool, \
-    PagePool, OutOfPages, OutOfSlots, PagedKVCache, PrefixCacheConfig, \
-    decode_read_path, paged_decode_step, resolve_kv_codec, walk_geometry
+from ..models.paged_kv import INDEX_WALK, PAGE_GATHER, PAGE_WALK, \
+    IndexedPagePool, PagePool, OutOfPages, OutOfSlots, PagedKVCache, \
+    PrefixCacheConfig, decode_read_path, index_read_path, \
+    index_walk_geometry, paged_decode_step, pool_run_pages, \
+    resolve_kv_codec, walk_geometry
 from ..models.flash_attention import leading_runs
 from ..models.transformer import KVCache
 from ..obs import context as obs_context
@@ -479,7 +481,8 @@ class ContinuousBatcher:
                       "attend_pages_in_runs": 0, "steps_judged": 0,
                       "window_pages_walked": 0, "window_pages_spanned": 0,
                       "sparse_rows_live": 0, "sparse_rows_attended": 0,
-                      "index_rows_scored": 0,
+                      "index_rows_scored": 0, "index_pages_walked": 0,
+                      "index_pages_in_runs": 0,
                       "step_wall_hist": _new_step_wall_hist(),
                       **dict.fromkeys(_CLOCKS, 0.0)}
         # the read a sparse-attention layer's decode is built with (None: the
@@ -487,9 +490,11 @@ class ContinuousBatcher:
         self.sparse_read = (
             sparse_read_path(cfg, self.bcfg.span, self.pool.pool)
             if cfg.sparse_layers else None)
-        # the reads the step's full and window layers are built with (by pool)
+        # the reads the step's full and window layers are built with (by
+        # pool), and the read of a sparse layer's index keys
         (self.decode_read, self.window_read, self.attend_fetches_per_page,
-         self.attend_walk) = self._read_paths()
+         self.attend_walk, self.index_read, self.index_walk) = \
+            self._read_paths()
         # the scheduler thread's own, lock-free between folds: clocks and
         # counts; the clock at each token 0; the last launched step's return
         self._acc: dict[str, float] = defaultdict(int)
@@ -1200,6 +1205,10 @@ class ContinuousBatcher:
                     np.minimum(live, self.cfg.index_topk).sum())
                 acc["index_rows_scored"] = (
                     0 if self.sparse_read == EVERY_ROW else int(live.sum()))
+            if self.index_read == INDEX_WALK:
+                # what a layer's index walk fetches this step: the same
+                # pages, of the pool's other leaf
+                acc["index_pages_walked"] = int(np.sum(reached))
         with obs_phase("batch.step.launch", acc, "launch_s", after=ph,
                        step=step_no) as ph:
             prev = self._inflight
@@ -1262,7 +1271,11 @@ class ContinuousBatcher:
             toks.copy_to_host_async()  # read a call later, already on its way
             # (counted here, behind the launch: the chip has its step, and
             # a step that follows an admission is not held up by the count)
-            acc["attend_pages_in_runs"] = self._pages_in_runs(reached)
+            acc["attend_pages_in_runs"] = self._pages_in_runs(
+                reached, self.attend_walk)
+            if self.index_read == INDEX_WALK:
+                acc["index_pages_in_runs"] = self._pages_in_runs(
+                    reached, self.index_walk)
             if step_no == 0:
                 # the merges every later launch runs, compiled by the first: a
                 # caller that warmed one step has warmed the steady state
@@ -1676,45 +1689,58 @@ class ContinuousBatcher:
 
     def _read_paths(self) -> tuple:
         """(``decode_read``, ``window_read``, ``attend_fetches_per_page``,
-        ``attend_walk``): what ``decode_read_path`` says of the pool the
+        ``attend_walk``, ``index_read``, ``index_walk``): what
+        ``decode_read_path`` says of the pool the
         full-attention layers read and of the window layers' pool of rings
         (no such pool: the gather, which no step then takes), the DMAs the
         walk starts for a page that goes alone, one a leaf of the pool it
         walks (0: the read is the gather), and the walk's (pages a block,
         pages it takes with one DMA where the groups that lead a block name
         adjacent ones) (``paged_kv.walk_geometry``; a run of 1: every page
-        goes alone).
+        goes alone); then what ``index_read_path`` says of a sparse stack's
+        index keys (None: no step scores one) and that walk's block and run
+        (``paged_kv.index_walk_geometry``).
         Down here: a line added above would move the prefill kernels' call
         sites, as below."""
-        full = self._split_pool if self.rt is not None else self.pool.pool
+        whole = self._split_pool if self.rt is not None else self.pool.pool
         rings = self.pool.window_pool
-        if isinstance(full, IndexedPagePool):
-            # what a sparse layer reads as a full layer does: its K/V leaf
-            full = PagePool(full.kv)
-        # (a step that gathers its chosen rows one by one walks no page: its
-        # index keys come by the page gather; the masked walk is the walk)
+        # what a sparse layer reads as a full layer does: its K/V leaf
+        full = (PagePool(whole.kv) if isinstance(whole, IndexedPagePool)
+                else whole)
+        # (a step that gathers its chosen rows one by one walks no K/V page;
+        # the masked walk is the walk)
         read = (PAGE_GATHER if self.sparse_read == ROW_GATHER
                 else decode_read_path(full))
-        ppb, run = (walk_geometry(full, self.bcfg.pages_per_slot)
-                    if read == PAGE_WALK else (1, 1))
+        pps = self.bcfg.pages_per_slot
+        ppb, run = (walk_geometry(full, pps) if read == PAGE_WALK
+                    else (1, 1))
+        index_read = (index_read_path(whole)
+                      if self.sparse_read not in (None, EVERY_ROW) else None)
+        index_walk = (index_walk_geometry(whole, pps)
+                      if index_read == INDEX_WALK else (1, 1))
         # the allocator read the rule off the configuration (it holds no
-        # pages in split mode), the kernel's caller reads it off the leaf
-        assert read != PAGE_WALK or run == np.gcd(ppb, self.pool.run_pages), \
+        # pages in split mode), a walk's caller reads it off ITS leaf: the
+        # pool's runs are the longest any leaf's walk takes
+        walked = read == PAGE_WALK or index_read == INDEX_WALK
+        runs = pool_run_pages(whole, pps) if walked else self.pool.run_pages
+        assert runs == self.pool.run_pages, \
             f"the pool hands out runs of {self.pool.run_pages} pages, the " \
-            f"walk takes {run} of a block of {ppb} with one DMA"
+            f"walks of its leaves take {runs} with one DMA"
         return (read,
                 decode_read_path(rings) if rings is not None else PAGE_GATHER,
-                len(full) if read == PAGE_WALK else 0, (ppb, run))
+                len(full) if read == PAGE_WALK else 0, (ppb, run),
+                index_read, index_walk)
 
-    def _pages_in_runs(self, reached) -> int:
-        """Of the pages a layer's attend walks this step (``reached`` a slot,
-        an idle slot's 1), those that go as part of a run: the groups that
+    def _pages_in_runs(self, reached, walk: tuple) -> int:
+        """Of the pages a layer's walk fetches this step (``reached`` a slot,
+        an idle slot's 1; ``walk``: its (block, run), ``attend_walk`` or
+        ``index_walk``), those that go as part of a run: the groups that
         lead a block of the host's table as adjacent pages and are live
         whole, which is the table the kernel is handed
         (``flash_attention.leading_runs``, the same function). Of the slots
         that reach a whole group and the blocks they reach: an almost idle
         batch pays for its two streams, not for 192 rows."""
-        ppb, run = self.attend_walk
+        ppb, run = walk
         rows = np.flatnonzero(reached >= run) if run > 1 else ()
         if not len(rows):
             return 0
@@ -1726,16 +1752,21 @@ class ContinuousBatcher:
 
     def _sparse_report(self, stats: dict) -> dict:
         """What a stack of sparse-attention layers adds to ``report()``: the
-        read its decode is built with, and three additive counters of rows a
-        sparse layer, counted on the host from the riders' lengths: live,
-        attended (``min(length, index_topk)`` a rider) and scored by the
-        indexer."""
+        reads its decode is built with (of the chosen K/V rows, and of the
+        index keys), three additive counters of rows a sparse layer, counted
+        on the host from the riders' lengths: live, attended (``min(length,
+        index_topk)`` a rider) and scored by the indexer; and, as
+        ``attend_pages_walked`` / ``_in_runs`` are of the K/V walk, the pages
+        a layer's index walk fetched and those of them that went as part of
+        a run (0 and 0 on the page gather)."""
         if self.sparse_read is None:
             return {}
         return {"sparse_read": self.sparse_read,
+                "index_read": self.index_read,
                 **{k: int(stats[k]) for k in (
                     "sparse_rows_live", "sparse_rows_attended",
-                    "index_rows_scored")}}
+                    "index_rows_scored", "index_pages_walked",
+                    "index_pages_in_runs")}}
 
     def _hybrid_report(self, stats: dict) -> dict:
         """What a stack with recurrent state and routed experts adds to

@@ -842,11 +842,14 @@ def test_keye_step_selects_under_scopes_and_keeps_both_leaves_in_place(
         topo, read):
     """The step of the ``keye_vl2`` cell at its shapes, one layer: the pool's
     TWO leaves (K/V rows of 1024 lanes, index keys of 128) donated and
-    written where they lie, a row scatter each; the index keys read by one
-    page gather of the 128-lane leaf; then, on a TPU's choice, the page walk
-    with the selection as a mask (one kernel, no span-sized copy of K or V),
-    and on the other the row gather of 32 x 2048 chosen rows. Every matmul, gather,
-    scatter, sort and kernel call under a registered scope."""
+    written where they lie, a row scatter each. On a TPU's choice both are
+    read where they lie: the index keys scored by the index walk (one kernel
+    under ``attn.sparse.index``; no gathered copy of a slot's span of keys is
+    left, and the step's temporaries fall by its 168 MB), then the page walk
+    with the selection as a mask (one kernel, no span-sized copy of K or V);
+    on the other the index keys by one page gather of the 128-lane leaf and
+    the row gather of 32 x 2048 chosen rows. Every matmul, gather, scatter,
+    sort and kernel call under a registered scope."""
     from edgellm_tpu.models import sparse_attn
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -864,6 +867,8 @@ def test_keye_step_selects_under_scopes_and_keeps_both_leaves_in_place(
     span = K_PAGES_PER_SLOT * PAGE
     assert sparse_attn.sparse_read_path(cfg, span, pool) == (
         sparse_attn.MASKED_WALK if read == "walk" else sparse_attn.ROW_GATHER)
+    assert paged_kv.index_read_path(pool) == (
+        paged_kv.INDEX_WALK if read == "walk" else paged_kv.PAGE_GATHER)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -884,17 +889,22 @@ def test_keye_step_selects_under_scopes_and_keeps_both_leaves_in_place(
              if not (m[0] in ("reshape", "transpose") and m[2] in own)]
     assert not moved, moved
     assert _walks(hlo) == (1 if read == "walk" else 0)
+    index_walks = [line for op, _, _, line in _instructions(hlo)
+                   if op == "custom-call" and "paged_index_walk" in line]
+    assert len(index_walks) == (1 if read == "walk" else 0)
+    assert all("attn.sparse.index" in line for line in index_walks)
     big = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
            if op == "gather" and _elements(shape) >= K_SLOTS * 2048 * 1024]
-    # (the chosen rows: 32 x 2048 of them, gathered a K or V half at a time)
-    chosen = {shape for shape in big if shape != keys}
-    assert keys in big and chosen == (
-        set() if read == "walk" else {f"bf16[{K_SLOTS},2048,1024]"}), big
+    # (the index keys: every slot's span of them, by the gather alone; the
+    # chosen rows: 32 x 2048 of them, gathered a K or V half at a time)
+    assert set(big) == (set() if read == "walk" else
+                        {keys, f"bf16[{K_SLOTS},2048,1024]"}), big
+    assert read != "walk" or not _span_sized(hlo, own)
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * (kv_leaf + ik_leaf)
-    # the index keys' gathered copy 168 MB and the scores; the row gather's
-    # chosen rows 268 MB more
-    assert mem.temp_size_in_bytes < (250e6 if read == "walk" else 700e6), \
+    # on the walks the scores and the mask; on the gathers the index keys'
+    # copy, 168 MB, and the chosen rows, 268 MB more
+    assert mem.temp_size_in_bytes < (100e6 if read == "walk" else 700e6), \
         mem.temp_size_in_bytes
     unscoped, under = _scopes_of_the_heavy(hlo)
     # ("": the compiler's own "AllocateBuffer" calls for the carried k-th

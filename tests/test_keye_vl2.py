@@ -410,6 +410,9 @@ def test_prefill_then_paged_decode_through_the_batcher_matches_the_full_forward(
     assert want0[toks[0]] >= want0.max() - TOL * np.abs(want0).max()
     rep = b.report()
     assert rep["sparse_read"] == sparse_attn.ROW_GATHER
+    # this backend is no TPU: the index keys come by the page gather
+    assert rep["index_read"] == paged_kv.PAGE_GATHER
+    assert rep["index_pages_walked"] == rep["index_pages_in_runs"] == 0
     live = sum(range(plen + 1, plen + 31))
     assert rep["sparse_rows_live"] == rep["index_rows_scored"] == live
     assert rep["sparse_rows_attended"] == sum(
@@ -429,7 +432,7 @@ def test_a_pool_no_slot_of_which_can_pass_topk_skips_the_selection(
     assert _worst(tap, 0, CFG, params, prompt, toks) < TOL
     rep = b.report()
     assert rep["sparse_read"] == sparse_attn.EVERY_ROW
-    assert rep["index_rows_scored"] == 0
+    assert rep["index_read"] is None and rep["index_rows_scored"] == 0
     assert rep["sparse_rows_attended"] == rep["sparse_rows_live"] > 0
     assert float(jnp.abs(b.pool.pool.ik).max()) > 0
 
@@ -568,18 +571,30 @@ def test_the_paged_steps_row_ids_are_the_references_set(params):
 WIDE = tiny_keye_vl2_config(num_kv_heads=2, head_dim=64)   # rows of 2 x 128
 
 
-def _interpreted(*args, **kwargs):
-    """The page-walk kernel under the TPU interpreter, WAITED FOR (its host
-    callbacks deadlock against a main thread that keeps dispatching)."""
+def _interpreted(*args, kernel=None, **kwargs):
+    """The page-walk kernel (or ``kernel``) under the TPU interpreter, WAITED
+    FOR (its host callbacks deadlock against a main thread that keeps
+    dispatching)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return jax.block_until_ready(_KERNEL(
+    return jax.block_until_ready((kernel or _KERNEL)(
         *args, **kwargs, interpret=pltpu.InterpretParams()))
 
 
 from edgellm_tpu.models import flash_attention  # noqa: E402
 
 _KERNEL = flash_attention.paged_decode_walk      # before any test patches it
+_INDEX_KERNEL = flash_attention.paged_index_walk
+
+
+def _a_tpus_reads(monkeypatch):
+    """The choices forced as a TPU would make them, both kernels interpreted."""
+    import functools
+
+    monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash_attention, "paged_decode_walk", _interpreted)
+    monkeypatch.setattr(flash_attention, "paged_index_walk",
+                        functools.partial(_interpreted, kernel=_INDEX_KERNEL))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -662,15 +677,26 @@ def test_the_read_is_read_off_the_pool_and_the_span(monkeypatch):
         layer_types=("sparse_attention",)), 3, 16, jnp.bfloat16)
     assert sparse_attn.sparse_read_path(KEYE_VL_2_0_30B_A3B, 20480, big) == \
         sparse_attn.MASKED_WALK
+    # the index keys' read, off the OTHER leaf: an index key is 128 lanes
+    # whatever the K/V rows are, so a page of whole sublane tiles walks
+    assert paged_kv.index_read_path(pool) == paged_kv.index_read_path(
+        narrow) == paged_kv.index_read_path(big) == paged_kv.INDEX_WALK
+    assert paged_kv.index_read_path(paged_kv.init_pool(WIDE, 9, 4)) == \
+        paged_kv.index_read_path(paged_kv.PagePool(pool.kv)) == \
+        paged_kv.PAGE_GATHER
+    assert paged_kv.index_walk_geometry(big, 1280) == (128, 8)
+    assert paged_kv.walk_geometry(paged_kv.PagePool(big.kv), 1280) == (32, 1)
+    monkeypatch.undo()
+    assert paged_kv.index_read_path(big) == paged_kv.PAGE_GATHER
 
 
 def test_the_step_on_the_masked_walk_equals_the_step_on_the_row_gather(
         monkeypatch):
-    """``paged_decode_step_hybrid`` of a sparse stack built on the masked
-    walk (the choice forced as a TPU would make it, the kernel interpreted)
-    against the step on the row gather: logits, both written leaves, the
-    counter. Slot 1 is idle; every page no table names holds NaN under the
-    walk."""
+    """``paged_decode_step_hybrid`` of a sparse stack built on the index
+    walk and the masked walk (the choices forced as a TPU would make them,
+    the kernels interpreted) against the step on the page gather and the row
+    gather: logits, both written leaves, the counter. Slot 1 is idle; every
+    page no table names holds NaN in BOTH leaves under the walks."""
     import functools
 
     cfg = WIDE
@@ -695,16 +721,14 @@ def test_the_step_on_the_masked_walk_equals_the_step_on_the_row_gather(
         token_ids=jnp.asarray([3, 0, 5], jnp.int32))
     with jax.default_matmul_precision("highest"):
         want, want_pool, _, want_cnt = step(pool=clean)
-        monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+        _a_tpus_reads(monkeypatch)
         assert sparse_attn.sparse_read_path(cfg, 64, pool) == \
             sparse_attn.MASKED_WALK
-        monkeypatch.setattr(flash_attention, "paged_decode_walk",
-                            _interpreted)
-        # the index keys' page gather reads dead pages under its mask: they
-        # stay finite; the K/V leaf's dead pages hold NaN
+        assert paged_kv.index_read_path(pool) == paged_kv.INDEX_WALK
+        # both leaves are read where they lie: the dead pages of both hold NaN
         got, got_pool, _, got_cnt = jax.block_until_ready(step(
             pool=paged_kv.IndexedPagePool(
-                jnp.where(dead, jnp.nan, clean.kv), clean.ik)))
+                *(jnp.where(dead, jnp.nan, a) for a in clean))))
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     np.testing.assert_array_equal(np.asarray(got_cnt), np.asarray(want_cnt))
@@ -712,6 +736,60 @@ def test_the_step_on_the_masked_walk_equals_the_step_on_the_row_gather(
         np.testing.assert_allclose(np.asarray(jnp.where(dead, 0, a)),
                                    np.asarray(jnp.where(dead, 0, b)),
                                    atol=1e-5)
+
+
+def test_the_batcher_on_a_tpus_reads_serves_the_contiguous_references_tokens(
+        monkeypatch):
+    """Through the batcher, built on the index walk and the masked walk (the
+    choices forced, the kernels interpreted): two streams' tokens equal
+    ``generate``'s over a contiguous cache and the same service's on this
+    backend's reads; ``report()`` names the index read and counts its pages
+    (every live page a layer a step, those of whole groups of a slot's
+    table in runs: the pool deals runs of eight 4 KB index pages), and the
+    counters of rows a step are what they were."""
+    cfg = WIDE
+    params = make_params(cfg)
+    bcfg = BatchingConfig(page_size=8, num_pages=41, max_slots=2,
+                          pages_per_slot=20)
+    prompts, new = [_ids(70, 1), _ids(13, 2)], 12
+    step_jit = batching._batched_hybrid_step_jit
+
+    def serve():
+        step_jit.clear_cache()
+        b = ContinuousBatcher(cfg, params, bcfg)
+        sids = [b.submit(p, new, rng_seed=i) for i, p in enumerate(prompts)]
+        res = b.run()
+        b.pool.check_invariants()
+        return b.report(), [res[s] for s in sids]
+
+    with jax.default_matmul_precision("highest"):
+        oracle, want = serve()
+        _a_tpus_reads(monkeypatch)
+
+        def waited(*args):      # the interpreter's callbacks dispatch too
+            return jax.block_until_ready(step_jit(*args))
+
+        waited._cache_size = step_jit._cache_size
+        monkeypatch.setattr(batching, "_batched_hybrid_step_jit", waited)
+        rep, got = serve()
+        for i, p in enumerate(prompts):
+            np.testing.assert_array_equal(want[i], np.asarray(generate(
+                cfg, params, p[None], new, rng_key=jax.random.key(i)))[0])
+    step_jit.clear_cache()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert (oracle["sparse_read"], oracle["index_read"]) == (
+        sparse_attn.ROW_GATHER, paged_kv.PAGE_GATHER)
+    assert (rep["sparse_read"], rep["index_read"], rep["decode_read"]) == (
+        sparse_attn.MASKED_WALK, paged_kv.INDEX_WALK, paged_kv.PAGE_WALK)
+    for name in ("sparse_rows_live", "sparse_rows_attended",
+                 "index_rows_scored", "steps"):
+        assert rep[name] == oracle[name] > 0, name
+    # the same pages as the K/V walk's, of the other leaf; runs of eight
+    assert rep["index_pages_walked"] == rep["attend_pages_walked"] > 0
+    assert 0 < rep["index_pages_in_runs"] < rep["index_pages_walked"]
+    assert rep["index_pages_in_runs"] % 8 == 0
+    assert oracle["index_pages_walked"] == oracle["index_pages_in_runs"] == 0
 
 
 def test_the_mask_is_the_row_ids_set():
@@ -862,14 +940,17 @@ def test_the_pool_is_two_leaves_under_one_table_and_counts_both():
     assert paged_kv.kv_page_bytes(CFG, 4) == page
     assert cache.kv_row_bytes == (64 + 128) * 4
     assert paged_kv.num_pages_for_bytes(CFG, 10 * page + 5, 4) == 10
-    # what a walk would fetch: the K/V leaf's page alone
+    # the smallest page a walk would fetch: here the K/V leaf's (64 lanes
+    # beside the index keys' 128)
     assert paged_kv.page_leaf_bytes(CFG, 4) == 4 * 64 * 4
     assert cache.token_capacity == 32 * 4
     # at the cell's sizes: 216 KiB a page, 9.06 GB
     big = dataclasses.replace(KEYE_VL_2_0_30B_A3B, num_layers=6,
                               layer_types=("sparse_attention",) * 6)
     assert paged_kv.kv_page_bytes(big, 16, dtype=jnp.bfloat16) == 216 * 1024
-    assert paged_kv.page_leaf_bytes(big, 16, dtype=jnp.bfloat16) == 32 * 1024
+    # (the K/V leaf's page 32 KB, a fetch by itself; the index keys' 4 KB,
+    # which is what the pool's runs are read off: eight to a run)
+    assert paged_kv.page_leaf_bytes(big, 16, dtype=jnp.bfloat16) == 4 * 1024
 
 
 def test_a_family_without_an_indexer_builds_the_one_leaf_pool_it_built():

@@ -445,8 +445,128 @@ def test_the_batchers_count_of_pages_in_runs_is_a_recount_of_its_table(
                 if not (np.diff(ids) == 1).all():
                     break               # the block's later groups go alone
                 want += run
-    assert b._pages_in_runs(reached) == want > 0
-    assert b._pages_in_runs(np.ones(4, np.int64)) == 0      # all idle
+    assert b._pages_in_runs(reached, b.attend_walk) == want > 0
+    assert b._pages_in_runs(np.ones(4, np.int64), b.attend_walk) == 0  # idle
     b.pool.run_pages = 4
     with pytest.raises(AssertionError, match="hands out runs of 4"):
         b._read_paths()
+
+
+#: the benchmark's nine configurations (``benchmark/configs/*.json``; a test
+#: must not read them): the lanes of a stored row of each leaf of the pool, a
+#: slot's table, and the run. Every page is 16 rows of bfloat16. Eight are
+#: what they were before the index walk; keye's was 1, read off its K/V leaf
+#: alone (PERF.md §6 "PR 51").
+CELL_RUNS = {
+    "qwen2-0.5b": ((2 * 2 * 64,), 128, 8),
+    "qwen2-1.5b-split4": ((2 * 2 * 128,), 128, 4),
+    "granite-4.0-h-small-ep2": ((2 * 8 * 128,), 128, 1),
+    "mellum2-12b-a2.5b-pp4": ((2 * 4 * 128,), 384, 1),
+    "mistral-small-4-119b-ep4": ((384,), 768, 8),
+    "trinity-mini-pp8": ((2 * 4 * 128,), 768, 1),
+    "longcat-flash-chat-ep32": ((640,), 192, 4),
+    "lfm2-8b-a1b-pp2": ((2 * 8 * 64,), 288, 1),
+    "keye-vl-2.0-30b-a3b-ep4": ((2 * 4 * 128, 128), 1280, 8),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_RUNS))
+def test_the_run_of_each_cells_pool_is_its_smallest_pages(cell):
+    """``paged_kv.pool_run_pages``: the longest run any leaf's walk takes,
+    which is the rule on the pool's smallest page; a leaf's own walk takes
+    what ITS page says."""
+    lanes, pps, want = CELL_RUNS[cell]
+    pool = tuple(jax.ShapeDtypeStruct((1, 3, PAGE, n), jnp.bfloat16)
+                 for n in lanes)
+    assert paged_kv.pool_run_pages(pool, pps) == want
+    assert want == flash_attention.walk_run_pages(
+        PAGE * min(lanes) * 2, pps)
+    own = [paged_kv.leaf_run_pages(leaf, pps) for leaf in pool]
+    assert own == ([want] if len(lanes) == 1 else [1, 8])
+
+
+def test_a_two_leaf_pool_at_the_keye_geometry_deals_runs_of_eight(
+        monkeypatch):
+    """An ``IndexedPagePool`` at the keye cell's geometry (32 slots x 1280
+    pages of 16 rows, published widths, bfloat16; the leaves are shapes
+    here, which is all an allocator reads): its runs are read off the
+    index-key leaf (4 KB a page, eight to a run) where the K/V leaf's page
+    (32 KB) is a fetch by itself. Prompts of 512 and 1024 pages are whole
+    runs; slots that grow in lockstep take a run each and hold seven pages
+    ahead, so that their pages do not interleave; the pool that fits 32 full
+    slots of single pages fits them in runs; an evicted slot gives back its
+    runs and what it held ahead, whole; the whole-cache snapshot stays
+    refused by name."""
+    import dataclasses
+
+    from edgellm_tpu.models.configs import KEYE_VL_2_0_30B_A3B
+    from edgellm_tpu.models.hybrid import IndexKeysUnsupported
+
+    cfg = dataclasses.replace(KEYE_VL_2_0_30B_A3B, num_layers=1,
+                              layer_types=("sparse_attention",))
+    init_pool = paged_kv.init_pool
+    monkeypatch.setattr(paged_kv, "init_pool", lambda *a, **k: jax.eval_shape(
+        lambda: init_pool(*a, **k)))
+    slots, pps = 32, 1280
+    cache = PagedKVCache(cfg, num_pages=slots * pps + 1, page_size=PAGE,
+                         max_slots=slots, pages_per_slot=pps,
+                         dtype=jnp.bfloat16)
+    pool = cache.pool
+    assert isinstance(pool, paged_kv.IndexedPagePool)
+    assert [a.shape[-1] for a in pool] == [1024, 128]
+    assert paged_kv.page_leaf_bytes(cfg, PAGE, dtype=jnp.bfloat16) == 4096
+    assert cache.run_pages == paged_kv.pool_run_pages(pool, pps) == 8
+    assert paged_kv.walk_geometry(paged_kv.PagePool(pool.kv), pps) == (32, 1)
+    assert paged_kv.index_walk_geometry(pool, pps) == (128, 8)
+
+    def whole_groups(slot):
+        return _runs(cache, slot).all()
+
+    # admission: the cell's two prompts, 8192 and 16384 tokens
+    for s in range(slots):
+        assert cache.alloc_slot() == s
+        tokens = (8192, 16384)[s % 2]
+        cache.ensure(s, tokens)
+        cache.lengths[s] = tokens
+        assert whole_groups(s) and len(cache._slot_pages[s]) == tokens // PAGE
+    assert not cache._ahead                     # whole runs: nothing held
+    cache.check_invariants()
+    # growth, every slot a page in lockstep: a run each, seven held ahead
+    for s in range(slots):
+        cache.lengths[s] += 1
+        cache.ensure(s, int(cache.lengths[s]))
+    assert all(len(cache._ahead[s]) == 7 for s in range(slots))
+    heads = sorted(cache._slot_pages[s][-1] for s in range(slots))
+    assert all((p - 1) % 8 == 0 for p in heads) and len(set(heads)) == slots
+    free = cache.num_free_pages
+    for step in range(1, 8):                    # the next seven are their own
+        for s in range(slots):
+            cache.lengths[s] += PAGE
+            cache.ensure(s, int(cache.lengths[s]))
+        assert cache.num_free_pages == free - step * slots
+    assert not cache._ahead and all(whole_groups(s) for s in range(slots))
+    cache.check_invariants()
+    # an eviction mid-group gives the run back whole, what was held too
+    cache.lengths[3] += PAGE
+    cache.ensure(3, int(cache.lengths[3]))
+    assert len(cache._ahead[3]) == 7 and not cache._broken
+    held, whole = cache.num_free_pages, len(cache._whole)
+    pages = len(cache._slot_pages[3])
+    cache.free_slot(3)
+    assert not cache._ahead and not cache._broken
+    assert cache.num_free_pages == held + pages
+    assert len(cache._whole) == whole + -(-pages // 8)
+    # every slot to its full span: 32 x 160 runs are the pool's 5120
+    assert cache.alloc_slot() == 3
+    for s in range(slots):
+        cache.ensure(s, pps * PAGE)
+        cache.lengths[s] = pps * PAGE
+        assert whole_groups(s)
+    assert cache.num_free_pages == 0 and not cache._whole
+    cache.check_invariants()
+    for s in range(slots):
+        cache.free_slot(s)
+    assert len(cache._whole) == slots * pps // 8 and not cache._broken
+    cache.check_invariants()
+    with pytest.raises(IndexKeysUnsupported, match="state_dict"):
+        cache.state_dict()

@@ -679,3 +679,39 @@ def test_every_cell_lists_the_stall_readers(cell):
         assert (m["source"], m["layer"], m["moves"]) == (
             "program_counter", "batcher", "gap_mean_ms")
         assert m["workloads"] == list(ALL_CELLS)
+
+
+# ---------------------------------------------------------------------------
+# PR 51: the share of a sparse layer's index pages that went as part of a run
+# ---------------------------------------------------------------------------
+
+KEYE_CELL = "keye-vl-2.0-30b-a3b-ep4.decode-sat-context"
+
+
+def _index_report(walked, in_runs):
+    return {"index_pages_walked": walked, "index_pages_in_runs": in_runs}
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("r0, r1, want", [
+    (_index_report(10, 8), _index_report(1010, 968), 96.0),   # most in runs
+    (_index_report(0, 0), _index_report(500, 0), 0.0),        # every page alone
+    (_index_report(7, 0), _index_report(7, 0), None),         # nothing walked
+    ({"index_rows_scored": 5}, {"index_rows_scored": 9}, None)])   # the parent
+def test_index_run_share_is_the_window_difference_of_the_two_counters(
+        traced, r0, r1, want):
+    # a program counter: the same number traced or not; None where the
+    # program keeps no such counter or scores its index keys by the gather
+    got = _read("index_run_share", _record(traced, r0, r1))
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_the_keye_cell_alone_lists_index_run_share():
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
+    # (found by name: later PRs append their own entries after it)
+    assert entries["index_run_share"] == {"name": "index_run_share", "unit": "%", "better": "higher",
+                 "source": "program_counter", "layer": "cache",
+                 "moves": "gap_mean_ms", "workloads": [KEYE_CELL]}
+    assert "index_run_share" in {
+        m.name for m in load_cell(KEYE_CELL).per_layer}
