@@ -830,6 +830,31 @@ def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
             "selection": selection_on_the_chip()}
 
 
+def _selection_overlap(family: str, depths, k: int, idx, want, keep,
+                       at_least: float) -> dict:
+    """What both selection checks end on: the masked walk's mask is the row
+    ids' set, and a slot a depth the share of the float32 ``top_k`` set the
+    step's own ids hold (asserted above ``at_least``) beside what the newest
+    ``k`` positions would share."""
+    import numpy as np
+
+    idx, want, keep = np.asarray(idx), np.asarray(want), np.asarray(keep)
+    for slot in range(len(depths)):
+        assert set(np.flatnonzero(keep[slot])) == set(idx[slot].tolist())
+    out = {}
+    for slot, depth in enumerate(depths):
+        exact = set(want[slot].tolist())
+        overlap = len(exact & set(idx[slot].tolist())) / k
+        newest = len(exact & set(range(depth + 1 - k, depth + 1))) / k
+        out[str(depth)] = {"overlap": overlap, "newest_2048": newest}
+        print(f"[chip_smoke] {family} selection at depth {depth}: "
+              f"{100 * overlap:.2f}% of the float32 top-{k} set, the "
+              f"newest {k} positions would share {100 * newest:.1f}%",
+              flush=True)
+        assert overlap > at_least and newest < 0.5, out
+    return out
+
+
 def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
     """The selection at the benchmark cell's widths and depths: one
     published-width sparse layer in bfloat16, a slot a depth whose index keys
@@ -849,7 +874,6 @@ def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from edgellm_tpu.models import init_params, paged_kv, sparse_attn
     from edgellm_tpu.models.configs import KEYE_VL_2_0_30B_A3B
@@ -922,21 +946,171 @@ def selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
     idx, want, keep, cache.pool, off, largest = chosen(cache.pool, x)
     # float32 sums of the same bf16 products, in another order
     assert float(off) <= 1e-5 * float(largest), (float(off), float(largest))
-    idx, want, keep = np.asarray(idx), np.asarray(want), np.asarray(keep)
-    for slot in range(len(depths)):
-        assert set(np.flatnonzero(keep[slot])) == set(idx[slot].tolist())
-    out = {}
+    return _selection_overlap("keye_vl2", depths, k, idx, want, keep, 0.98)
+
+
+def sparse_latent_phase(*, prompt_len: int = 300, n_new: int = 40,
+                        evict_after: int = 20) -> dict:
+    """A tiny ``deepseek_v32`` stream (sparse latent layers: latent attention
+    whose query attends the 64 positions its indexer scores highest of the
+    300-340 it holds, the indexer's query from the q latent and its first 32
+    lanes rotated; a latent row and an index key a position in the two
+    leaves of one pool; a leading dense layer, then sigmoid routing over 4
+    groups of 4 of which 2 are kept, a shared expert; float32) through the
+    same admit / step / evict / readmit: BOTH leaves leave the device and
+    come back, and ``forward`` (the absorbed block form) over prompt +
+    tokens puts each served token first, on the index walk and the masked
+    walk of the latent rows a TPU's pool takes. ``wkv_b`` is seeded 9x wider
+    and the other matrices 3x, so that which rows are attended moves the
+    logits. Then :func:`latent_selection_on_the_chip`."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from edgellm_tpu.models import grouped_matmul, sparse_attn
+    from edgellm_tpu.models.configs import tiny_deepseek_v32_config
+    from edgellm_tpu.models.paged_kv import INDEX_WALK, PAGE_WALK
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    def wider(params):
+        def scale(path, a):
+            name = path[-1].key
+            if name in ("router", "router_bias"):
+                return a * 15.0
+            return a * (9.0 if name == "wkv_b" else 3.0) \
+                if name.startswith("w") or name.startswith("shared") else a
+        return jax.tree_util.tree_map_with_path(scale, params)
+
+    # rows of [c 96 | k_rope 32] = 128 lanes and index keys of 128: whole
+    # tiles, so that both walks are what the step is built on
+    cfg = dataclasses.replace(tiny_deepseek_v32_config(
+        hidden_size=256, num_heads=4, index_heads=4, index_head_dim=128,
+        index_topk=64), explicit_head_dim=64, qk_rope_head_dim=32,
+        kv_lora_rank=96, v_head_dim=32, q_lora_rank=64, expert_width=128,
+        shared_width=128)
+    bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
+                          pages_per_slot=24)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after,
+                                 wider)
+    assert report["sparse_read"] == sparse_attn.MASKED_WALK, report
+    assert report["decode_read"] == PAGE_WALK
+    assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    assert report["index_read"] == INDEX_WALK, report
+    assert 0 < report["index_pages_in_runs"] <= \
+        report["index_pages_walked"] == report["attend_pages_walked"]
+    assert report["kv_row_bytes"] == (128 + 128) * 4
+    assert report["latent_rows_capacity"] == 72 * 16
+    assert 0 < report["sparse_rows_attended"] < report["sparse_rows_live"]
+    assert report["index_rows_scored"] == report["sparse_rows_live"]
+    assert report["grouped_product"] == grouped_matmul.PALLAS_GROUPED, \
+        report["grouped_product"]
+    assert len(np.unique(report["served"])) > n_new // 4, report["served"]
+    return {"tokens": int(n_new), "distinct_tokens":
+            int(len(np.unique(report["served"]))),
+            "evicted": report["evicted"],
+            "sparse_read": report["sparse_read"],
+            "index_read": report["index_read"],
+            "index_run_share": 100.0 * report["index_pages_in_runs"]
+            / report["index_pages_walked"],
+            "sparse_selected_share": 100.0 * report["sparse_rows_attended"]
+            / report["sparse_rows_live"],
+            "kv_row_bytes": report["kv_row_bytes"],
+            "grouped_product": report["grouped_product"],
+            "launch_ahead_share": report["launch_ahead_share"],
+            "gap_max_over_logit_max": gap,
+            "selection": latent_selection_on_the_chip()}
+
+
+def latent_selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
+    """:func:`selection_on_the_chip` for the ``deepseek_v32`` cell's indexer:
+    one published-width sparse latent layer in bfloat16 (64 index heads of
+    128 lanes, the query from the q latent, the first 64 lanes rotated by
+    the YaRN table), a slot a depth whose index keys lie in the second leaf
+    of a pool of the cell's geometry; the row ids the step's own code
+    chooses (the index walk's scores, held to the page gather's on every live
+    row; ``select`` and the masked walk's mask, one set) against
+    ``jax.lax.top_k`` of float32 scores at ``highest`` from the SAME weights
+    and cache."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from edgellm_tpu.models import (init_params, mla, paged_kv, sparse_attn,
+                                    sparse_mla)
+    from edgellm_tpu.models.configs import DEEPSEEK_V3_2_EXP
+    from edgellm_tpu.models.transformer import precompute_rope
+
+    cfg = dataclasses.replace(
+        DEEPSEEK_V3_2_EXP, num_layers=2, num_dense_layers=1,
+        layer_types=("sparse_latent_attention",) * 2, experts_held=1,
+        expert_width=128, shared_width=128, intermediate_size=128,
+        vocab_size=256)
+    k, ps, pps = cfg.index_topk, 16, 1280
+    params = init_params(cfg, jax.random.key(SEED), dtype=jnp.bfloat16)
+    lp = {name: a[0] for name, a in params["sparse_latent"].items()}
+    # the head weights at the benchmark's std (logits of std 1)
+    lp["w_index"] = lp["w_index"] * (1.0 / (0.02 * cfg.hidden_size ** 0.5))
+    span = ps * pps
+    cache = paged_kv.PagedKVCache(
+        cfg, num_pages=len(depths) * pps + 1, page_size=ps,
+        max_slots=len(depths), pages_per_slot=pps, dtype=jnp.bfloat16)
+    cos, sin = precompute_rope(cfg, span)
+
+    def project(weights, x, cos, sin):
+        return sparse_attn.project_index(
+            cfg, weights, x, sparse_mla.index_rotation_rows(cfg, cos, sin),
+            query=mla.query_latent(cfg, weights, x))
+
+    @jax.jit
+    def keys_of(x):           # a sequence's index keys, as cached
+        return project(lp, x, cos, sin)[1]
+
+    xs = []
     for slot, depth in enumerate(depths):
-        exact = set(want[slot].tolist())
-        overlap = len(exact & set(idx[slot].tolist())) / k
-        newest = len(exact & set(range(depth + 1 - k, depth + 1))) / k
-        out[str(depth)] = {"overlap": overlap, "newest_2048": newest}
-        print(f"[chip_smoke] keye_vl2 selection at depth {depth}: "
-              f"{100 * overlap:.2f}% of the float32 top-{k} set, the "
-              f"newest {k} positions would share {100 * newest:.1f}%",
-              flush=True)
-        assert overlap > 0.98 and newest < 0.5, out
-    return out
+        x = jax.random.normal(jax.random.key(SEED + slot),
+                              (span, cfg.hidden_size), jnp.bfloat16)
+        xs.append(x[depth])                    # the query's layer input
+        assert cache.alloc_slot() == slot
+        cache.adopt_latent(
+            slot, jnp.zeros((2, depth, cfg.kv_row_lanes), jnp.bfloat16),
+            depth, index=jnp.broadcast_to(keys_of(x)[None, :depth],
+                                          (2, depth, cfg.index_row_lanes)))
+    table, lengths = cache.device_tables()
+    x = jnp.stack(xs)
+    at = (cos[lengths], sin[lengths])
+
+    @jax.jit
+    def chosen(pool, x):       # the step's own path, bf16 operands
+        qi, ik, wi = project(lp, x, *at)
+        pool = paged_kv.write_rows(
+            pool, 0, table, lengths,
+            jnp.zeros((len(depths), 1, cfg.kv_row_lanes), jnp.bfloat16),
+            None, index=ik)
+        scores = sparse_attn.index_scores_paged(qi, wi, pool, 0, table,
+                                                lengths + 1)
+        rows = paged_kv._gather_pages(pool.ik, 0, table)
+        live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]
+        off = jnp.max(jnp.where(live, jnp.abs(
+            scores - sparse_attn.index_scores(qi, wi, rows)), 0.0))
+        idx, _ = sparse_attn.select(scores, lengths + 1, k)
+        keep = sparse_attn.selection_mask(scores, live, k)
+        # the same weights and cache in float32 at ``highest``
+        with jax.default_matmul_precision("highest"):
+            f32 = {n: a.astype(jnp.float32) for n, a in lp.items()}
+            lq, _, lw = project(f32, x.astype(jnp.float32), *at)
+            exact = sparse_attn.index_scores(lq, lw,
+                                             rows.astype(jnp.float32))
+        want, _ = sparse_attn.select(exact, lengths + 1, k)
+        return idx, want, keep, pool, off, jnp.max(jnp.abs(exact))
+
+    assert paged_kv.index_read_path(cache.pool) == paged_kv.INDEX_WALK
+    idx, want, keep, cache.pool, off, largest = chosen(cache.pool, x)
+    # float32 sums of the same bf16 products, in another order
+    assert float(off) <= 1e-5 * float(largest), (float(off), float(largest))
+    return _selection_overlap("deepseek_v32", depths, k, idx, want, keep,
+                              0.97)
 
 
 def smoke(report: dict, save) -> dict:
@@ -976,6 +1150,7 @@ def smoke(report: dict, save) -> dict:
     phase("longcat", longcat_phase)
     phase("shortconv", shortconv_phase)
     phase("sparse", sparse_phase)
+    phase("sparse_latent", sparse_latent_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

@@ -410,7 +410,8 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
     # ---- of 8: the indexer, the selection and the row gather are in the
     # ---- graph) — collective-free; both leaves and the expert counter,
     # ---- THREE buffers, stay donated ------------------------------------
-    from ..models.configs import tiny_keye_vl2_config
+    from ..models.configs import (tiny_deepseek_v32_config,
+                                  tiny_keye_vl2_config)
 
     kcfg = tiny_keye_vl2_config()
     kparams = transformer.init_params(kcfg, jax.random.key(0))
@@ -425,6 +426,23 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             ctx={"donate_min": 3},
             lowerable=batching._batched_hybrid_step_jit,
             lower_args=(kcfg, kparams, kpool, None, kcount,
+                        ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
+
+    # the ragged step of a stack of sparse LATENT layers: its pool's two
+    # leaves (latent rows, index keys) and the assignment counter donated
+    dcfg = tiny_deepseek_v32_config()
+    dparams = transformer.init_params(dcfg, jax.random.key(0))
+    dpool = paged_kv.init_pool(dcfg, NPG, PGS)
+    dcount = jnp.zeros((dcfg.expert_layers, dcfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_sparse_latent",
+            lambda p, rows, ik, ct, pt, ln, t:
+                hybrid.paged_decode_step_hybrid(
+                    dcfg, p, paged_kv.IndexedLatentPool(rows, ik), None, ct,
+                    pt, ln, t),
+            (dparams, dpool.rows, dpool.ik, dcount, ptab, plens, ptoks),
+            ctx={"donate_min": 3},
+            lowerable=batching._batched_hybrid_step_jit,
+            lower_args=(dcfg, dparams, dpool, None, dcount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state

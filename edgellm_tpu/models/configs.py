@@ -91,8 +91,21 @@ class ModelConfig:
         that; every feed-forward routed by the softmax over the chosen
         logits, no shared expert, an untied head (Kwai Keye-VL 2.0's
         language model).
+      - ``"deepseek_v32"``: one kind, ``"sparse_latent_attention"``
+        (``models/sparse_mla.py``): mistral4's latent layer (one cached row
+        a position, interleaved rotary pairs on the rope lanes, YaRN with
+        its softmax ``mscale``) whose query attends the ``index_topk``
+        positions keye's INDEXER selects: here the indexer's query comes
+        from the q latent ``c_q`` and only the first ``qk_rope_head_dim``
+        lanes of its heads and key rotate, by the attention's table; the
+        pool keeps the latent row and the index key in two leaves under one
+        table. The first ``num_dense_layers`` feed-forwards a dense SwiGLU,
+        the rest routed by ``"sigmoid"`` scores with a selection bias, the
+        experts in ``route_groups`` groups of which a token may choose
+        within the ``route_groups_kept`` best, plus a shared expert; an
+        untied head (DeepSeek-V3.2-Exp).
 
-    The fields after ``rope_scaling`` exist for those seven families and
+    The fields after ``rope_scaling`` exist for those eight families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -118,8 +131,9 @@ class ModelConfig:
     #: ``rope_parameters`` by layer kind).
     rope_scaling: Optional[tuple] = None
     #: per-layer mixer kind, ``"mamba"``, ``"conv"``, ``"attention"``,
-    #: ``"sliding_attention"``, ``"latent_attention"`` or
-    #: ``"sparse_attention"``; empty = every layer is the family's one block
+    #: ``"sliding_attention"``, ``"latent_attention"``, ``"sparse_attention"``
+    #: or ``"sparse_latent_attention"``; empty = every layer is the family's
+    #: one block
     layer_types: tuple = ()
     #: width of one attention head where the model states it; 0 = the
     #: derived ``hidden_size // num_heads``
@@ -201,6 +215,13 @@ class ModelConfig:
     #: text positions are equal in the three, so the program rotates by the
     #: plain table and only checks the sum
     mrope_section: tuple = ()
+    #: group-limited routing (``models/moe.route``): the router's outputs in
+    #: ``route_groups`` equal groups, ranked by the sum of their two largest
+    #: biased scores; a token's experts are the top k within the
+    #: ``route_groups_kept`` best groups (HF's ``n_group`` / ``topk_group``).
+    #: 1 and 1: every expert stands, and nothing of it is traced
+    route_groups: int = 1
+    route_groups_kept: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -215,13 +236,24 @@ class ModelConfig:
     def latent_layers(self) -> int:
         """Layers whose cached row is a latent (one row a position for all
         heads), not per-head K and V."""
-        return sum(1 for t in self.layer_types if t == "latent_attention")
+        return sum(1 for t in self.layer_types
+                   if t in ("latent_attention", "sparse_latent_attention"))
 
     @property
     def sparse_layers(self) -> int:
         """Layers whose query attends the positions an indexer selects, and
-        which keep an index key a position beside the K/V row."""
-        return sum(1 for t in self.layer_types if t == "sparse_attention")
+        which keep an index key a position beside the K/V (or latent) row."""
+        return sum(1 for t in self.layer_types
+                   if t in ("sparse_attention", "sparse_latent_attention"))
+
+    @property
+    def index_rope_lanes(self) -> int:
+        """The LEADING lanes of an indexer head (and of the index key) that
+        rotate: all of them by a table of their own
+        (``sparse_attn.index_rope``), or, where the layer is latent, the
+        first ``qk_rope_head_dim`` by the attention's table."""
+        return (self.qk_rope_head_dim if self.latent_layers
+                else self.index_head_dim)
 
     @property
     def index_row_lanes(self) -> int:
@@ -256,7 +288,7 @@ class ModelConfig:
         kind and a routed expert layer after every mixer."""
         return self.family in ("granitemoehybrid", "mellum", "mistral4",
                                "afmoe", "longcat_flash", "lfm2_moe",
-                               "keye_vl2")
+                               "keye_vl2", "deepseek_v32")
 
     @property
     def expert_layers(self) -> int:
@@ -341,7 +373,7 @@ class ModelConfig:
             return self.num_layers
         return sum(1 for t in self.layer_types
                    if t in ("attention", "latent_attention",
-                            "sparse_attention"))
+                            "sparse_attention", "sparse_latent_attention"))
 
     @property
     def mamba_layers(self) -> int:
@@ -386,7 +418,7 @@ class ModelConfig:
         if self.family not in ("gpt_neox", "qwen2", "llama",
                                "granitemoehybrid", "mellum", "mistral4",
                                "afmoe", "longcat_flash", "lfm2_moe",
-                               "keye_vl2"):
+                               "keye_vl2", "deepseek_v32"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
@@ -396,13 +428,14 @@ class ModelConfig:
               or self.score_func != "softmax" or self.zero_experts
               or self.rank_scales or self.conv_window or self.index_topk
               or self.index_heads or self.index_head_dim
-              or self.mrope_section):
+              or self.mrope_section or self.route_groups != 1
+              or self.route_groups_kept != 1):
             raise ValueError(
                 f"layer_types / experts / mamba / head width / window / "
                 f"latent / dense-layer / routing / short-convolution / "
                 f"indexer fields belong to the granitemoehybrid, mellum, "
-                f"mistral4, afmoe, longcat_flash, lfm2_moe and keye_vl2 "
-                f"families, not {self.family!r}")
+                f"mistral4, afmoe, longcat_flash, lfm2_moe, keye_vl2 and "
+                f"deepseek_v32 families, not {self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
@@ -415,7 +448,8 @@ class ModelConfig:
                  "afmoe": ("attention", "sliding_attention"),
                  "longcat_flash": ("latent_attention",),
                  "lfm2_moe": ("conv", "attention"),
-                 "keye_vl2": ("sparse_attention",)}[self.family]
+                 "keye_vl2": ("sparse_attention",),
+                 "deepseek_v32": ("sparse_latent_attention",)}[self.family]
         if len(self.layer_types) != self.num_layers * self.sublayers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
@@ -486,6 +520,25 @@ class ModelConfig:
             raise ValueError(
                 "index_heads, index_head_dim (even) and index_topk (all >= "
                 "1) belong to sparse_attention layers, and those need them")
+        if self.sparse_layers and self.latent_layers \
+                and self.index_head_dim <= self.qk_rope_head_dim:
+            raise ValueError(
+                "a sparse_latent_attention layer's indexer rotates the first "
+                "qk_rope_head_dim lanes of an index_head_dim that is wider")
+        groups, kept = self.route_groups, self.route_groups_kept
+        if (groups, kept) != (1, 1) and (
+                self.score_func == "softmax" or not 1 <= kept <= groups
+                or self.router_width % groups
+                or self.router_width // groups < 2
+                or kept * (self.router_width // groups)
+                < self.experts_per_tok):
+            raise ValueError(
+                f"route_groups {groups} / route_groups_kept {kept}: the "
+                f"router's {self.router_width} outputs must split into "
+                f"equal groups of at least two (a group is ranked by its "
+                f"two best biased scores), the kept groups must hold "
+                f"experts_per_tok {self.experts_per_tok}, and the scores "
+                f"are 'sigmoid' or 'softmax_all'")
         if self.mrope_section and (
                 not self.sparse_layers
                 or sum(self.mrope_section) * 2 != self.rotary_dim):
@@ -792,6 +845,89 @@ KEYE_VL_2_0_30B_A3B = ModelConfig(
 )
 
 
+# deepseek-ai/DeepSeek-V3.2-Exp (671B-A37B, 2025-09) — config.json
+# (``model_type`` ``deepseek_v32``): 61 layers, every one latent attention
+# (128 heads: queries through a 1536-wide bottleneck, a cached row of 512
+# latent + 64 rotated lanes, value heads of 128; YaRN factor 40 over 4096,
+# mscale = mscale_all_dim = 1) whose query attends the 2048 positions an
+# indexer of 64 heads of 128 lanes selects (its query from the q latent, its
+# first 64 lanes rotated); three leading dense layers of width 18432, then 256
+# routed experts of width 2048 top-8 by sigmoid scores with a selection bias,
+# chosen within the 4 best of 8 groups, weights normalised times 2.5, plus one
+# shared expert; untied 129280-row head. The multi-token-prediction module is
+# not built.
+DEEPSEEK_V3_2_EXP = ModelConfig(
+    family="deepseek_v32",
+    vocab_size=129280,
+    hidden_size=7168,
+    num_layers=61,
+    num_heads=128,
+    num_kv_heads=128,
+    intermediate_size=18432,
+    max_position_embeddings=163840,
+    norm_eps=1e-6,
+    rope_theta=10000.0,
+    tie_word_embeddings=False,
+    rope_scaling=("yarn", 40.0, 4096, 32.0, 1.0, 1.0),
+    layer_types=("sparse_latent_attention",) * 61,
+    explicit_head_dim=192,
+    num_experts=256,
+    experts_per_tok=8,
+    expert_width=2048,
+    shared_width=2048,
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    softmax_mscale=0.1 * math.log(40.0) + 1.0,
+    num_dense_layers=3,
+    score_func="sigmoid",
+    route_scale=2.5,
+    index_heads=64,
+    index_head_dim=128,
+    index_topk=2048,
+    route_groups=8,
+    route_groups_kept=4,
+)
+
+
+def tiny_deepseek_v32_config(*, num_layers: int = 3, index_topk: int = 8,
+                             num_dense_layers: int = 1,
+                             hidden_size: int = 48, num_heads: int = 4,
+                             index_heads: int = 3, index_head_dim: int = 16,
+                             vocab_size: int = 256, num_experts: int = 16,
+                             experts_per_tok: int = 3, route_groups: int = 4,
+                             route_groups_kept: int = 2,
+                             experts_held: int = 0, expert_offset: int = 0,
+                             original_max: int = 16,
+                             max_position_embeddings: int = 512
+                             ) -> ModelConfig:
+    """A small deepseek_v32 for tests: every mechanism of the published
+    model (a leading dense layer, latent rows of 16 + 8 = 24 lanes stored
+    padded beside index keys of 16 lanes whose first 8 rotate, an indexer fed
+    by the 20-wide q latent whose ``index_topk`` the test prompts pass, YaRN
+    with ``mscale`` on the softmax stepping inside 100 positions, sigmoid
+    routing over 4 groups of 4 of which 2 are kept, a route scale, a shared
+    expert, an untied head) at toy widths."""
+    return ModelConfig(
+        family="deepseek_v32", vocab_size=vocab_size,
+        hidden_size=hidden_size, num_layers=num_layers, num_heads=num_heads,
+        num_kv_heads=num_heads, intermediate_size=96,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-6,
+        rope_theta=10000.0, tie_word_embeddings=False,
+        rope_scaling=("yarn", 8.0, original_max, 32.0, 1.0, 1.0),
+        layer_types=("sparse_latent_attention",) * num_layers,
+        explicit_head_dim=24, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32, shared_width=40,
+        experts_held=experts_held, expert_offset=expert_offset,
+        q_lora_rank=20, kv_lora_rank=16, qk_rope_head_dim=8, v_head_dim=16,
+        softmax_mscale=0.1 * math.log(8.0) + 1.0,
+        num_dense_layers=num_dense_layers, score_func="sigmoid",
+        route_scale=2.5, index_heads=index_heads,
+        index_head_dim=index_head_dim, index_topk=index_topk,
+        route_groups=route_groups, route_groups_kept=route_groups_kept)
+
+
 def tiny_keye_vl2_config(*, num_layers: int = 2, index_topk: int = 8,
                          hidden_size: int = 48, num_heads: int = 4,
                          num_kv_heads: int = 2, head_dim: int = 16,
@@ -1002,6 +1138,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_lfm2_moe_config()
     if family == "keye_vl2":
         return tiny_keye_vl2_config()
+    if family == "deepseek_v32":
+        return tiny_deepseek_v32_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -1034,6 +1172,7 @@ PRESETS = {
     "longcat-flash-chat": LONGCAT_FLASH_CHAT,
     "lfm2-8b-a1b": LFM2_8B_A1B,
     "keye-vl-2.0-30b-a3b": KEYE_VL_2_0_30B_A3B,
+    "deepseek-v3.2-exp": DEEPSEEK_V3_2_EXP,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
@@ -1045,4 +1184,5 @@ PRESETS = {
     "tiny-longcat-flash": tiny_longcat_flash_config(),
     "tiny-lfm2-moe": tiny_lfm2_moe_config(),
     "tiny-keye-vl2": tiny_keye_vl2_config(),
+    "tiny-deepseek-v32": tiny_deepseek_v32_config(),
 }

@@ -16,6 +16,7 @@ Layout notes:
 """
 from __future__ import annotations
 
+import logging
 import math
 import os
 import time
@@ -100,6 +101,8 @@ def params_from_state_dict(cfg: ModelConfig, sd: dict) -> dict:
         return _lfm2_moe_params(cfg, sd)
     if cfg.family == "keye_vl2":
         return _keye_vl2_params(cfg, sd)
+    if cfg.family == "deepseek_v32":
+        return _deepseek_v32_params(cfg, sd)
     if cfg.is_hybrid:
         raise ValueError(
             f"no state_dict mapping for family {cfg.family!r}: its parameters "
@@ -267,6 +270,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         return _lfm2_moe_config(hf_config)
     if mt == "KeyeVL2":
         return _keye_vl2_config(hf_config)
+    if mt == "deepseek_v32":
+        return _deepseek_v32_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -745,6 +750,171 @@ def _keye_vl2_params(cfg: ModelConfig, sd: dict) -> dict:
             "index_norm_scale": rows("self_attn.indexer.k_norm.weight", keep),
             "index_norm_bias": rows("self_attn.indexer.k_norm.bias", keep),
             "w_index": rows("self_attn.indexer.weights_proj.weight"),
+        },
+        "moe": [ffn(i) for i in range(n)],
+    }
+
+
+def _deepseek_v32_config(hf_config) -> ModelConfig:
+    """DeepSeek-V3.2-Exp (``model_type`` ``deepseek_v32``). The keys mapped:
+    mistral4's latent-attention keys (``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim`` + ``qk_rope_head_dim``, ``v_head_dim``), ``rope_theta``
+    and ``rope_scaling`` (YaRN; the softmax scale times ``(0.1 mscale_all_dim
+    ln(factor) + 1)^2``), the indexer's ``index_n_heads``, ``index_head_dim``,
+    ``index_topk``, ``first_k_dense_replace`` (the leading layers whose
+    feed-forward is a SwiGLU of ``intermediate_size``), ``n_routed_experts``,
+    ``num_experts_per_tok``, ``moe_intermediate_size``, ``n_shared_experts``
+    (1: one shared expert of that width), ``n_group`` / ``topk_group`` (the
+    group-limited selection), ``routed_scaling_factor``.
+    ``num_nextn_predict_layers`` is accepted and its multi-token-prediction
+    module NOT built (a draft source the serving forward pass does not run):
+    said once on the log. Refused by name: a ``quantization_config`` (the
+    checkpoint's FP8 storage: bf16 weights only), a ``rope_scaling`` type
+    other than ``yarn``, ``mscale`` != ``mscale_all_dim`` (the cos/sin would
+    carry a factor), ``attention_bias``, ``n_shared_experts`` other than 1,
+    ``scoring_func`` other than ``sigmoid``, ``topk_method`` other than
+    ``noaux_tc``, ``moe_layer_freq`` other than 1, ``norm_topk_prob`` false,
+    a tied head."""
+    if getattr(hf_config, "quantization_config", None):
+        raise ValueError(
+            "deepseek_v32 with a quantization_config is not supported (the "
+            "checkpoint's FP8 weights and index keys: bf16 only)")
+    rope = dict(getattr(hf_config, "rope_scaling", None) or {})
+    if rope.get("rope_type", rope.get("type")) != "yarn":
+        raise ValueError(f"deepseek_v32 rope_scaling must be yarn, got "
+                         f"{rope!r}")
+    if float(rope.get("mscale", 1)) != float(rope.get("mscale_all_dim", 0)):
+        raise ValueError(
+            f"deepseek_v32 with rope_scaling mscale={rope.get('mscale')!r} "
+            f"!= mscale_all_dim={rope.get('mscale_all_dim')!r} is not "
+            f"supported (equal only: cos/sin carry no factor)")
+    for key, want in (("attention_bias", False), ("n_shared_experts", 1),
+                      ("scoring_func", "sigmoid"),
+                      ("topk_method", "noaux_tc"), ("moe_layer_freq", 1),
+                      ("norm_topk_prob", True),
+                      ("tie_word_embeddings", False)):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"deepseek_v32 with {key}={getattr(hf_config, key)!r} is "
+                f"not supported (only {want!r})")
+    if getattr(hf_config, "num_nextn_predict_layers", 0):
+        logging.getLogger(__name__).info(
+            "deepseek_v32: num_nextn_predict_layers=%s: the multi-token-"
+            "prediction module is not built and its weights are not loaded",
+            hf_config.num_nextn_predict_layers)
+    nope, rot = hf_config.qk_nope_head_dim, hf_config.qk_rope_head_dim
+    factor = float(rope["factor"])
+    n = int(hf_config.num_hidden_layers)
+    return ModelConfig(
+        family="deepseek_v32",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=n,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(hf_config.rope_theta),
+        tie_word_embeddings=False,
+        rope_scaling=("yarn", factor,
+                      int(rope["original_max_position_embeddings"]),
+                      float(rope["beta_fast"]), float(rope["beta_slow"]),
+                      1.0),
+        layer_types=("sparse_latent_attention",) * n,
+        explicit_head_dim=int(nope + rot),
+        num_experts=int(hf_config.n_routed_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+        shared_width=int(hf_config.moe_intermediate_size),
+        q_lora_rank=int(hf_config.q_lora_rank),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_rope_head_dim=int(rot),
+        v_head_dim=int(hf_config.v_head_dim),
+        softmax_mscale=(0.1 * float(rope.get("mscale_all_dim", 0))
+                        * math.log(factor) + 1.0 if factor > 1 else 1.0),
+        num_dense_layers=int(hf_config.first_k_dense_replace),
+        score_func="sigmoid",
+        route_scale=float(hf_config.routed_scaling_factor),
+        index_heads=int(hf_config.index_n_heads),
+        index_head_dim=int(hf_config.index_head_dim),
+        index_topk=int(hf_config.index_topk),
+        route_groups=int(hf_config.n_group),
+        route_groups_kept=int(hf_config.topk_group),
+    )
+
+
+def _deepseek_v32_params(cfg: ModelConfig, sd: dict) -> dict:
+    """``models/hybrid.py``'s per-kind tree from a ``deepseek_v32``
+    state_dict, all the experts held. Tensor names ASSUMED (no checkpoint can
+    be fetched here), the DeepSeek-V3 lineage's: ``model.layers.N.``
+    ``input_layernorm`` / ``post_attention_layernorm``, ``self_attn.``
+    ``q_a_proj`` / ``q_a_layernorm`` / ``q_b_proj`` / ``kv_a_proj_with_mqa``
+    / ``kv_a_layernorm`` / ``kv_b_proj`` / ``o_proj``, the indexer's
+    ``self_attn.indexer.{wq_b,wk}``, ``self_attn.indexer.k_norm`` (``weight``
+    and ``bias``) and ``self_attn.indexer.weights_proj``; a dense layer's
+    ``mlp.{gate,up,down}_proj``; an expert layer's ``mlp.gate`` (``weight``,
+    ``e_score_correction_bias``), ``mlp.experts.M.{gate,up,down}_proj`` and
+    ``mlp.shared_experts.{gate,up,down}_proj``; ``model.norm``, ``lm_head``.
+    ``kv_b_proj``'s rows are a head's K lanes then its V lanes, as
+    ``mla._kvb`` reads them. Layers past ``num_hidden_layers`` (the
+    multi-token-prediction module's) are not read."""
+    if cfg.experts_held:
+        raise ValueError("the deepseek_v32 state_dict mapping holds every "
+                         "expert")
+    pre = "model.layers.{i}."
+    n = cfg.num_layers
+
+    def rows(suffix, transform=lambda w: w.T):
+        return jnp.asarray(np.stack([
+            transform(_np(sd[pre.format(i=i) + suffix])) for i in range(n)]))
+
+    def keep(w):
+        return w
+
+    def swiglu(prefix, names=("w_gate", "w_up", "w_down")):
+        return {k: jnp.asarray(_np(sd[f"{prefix}{name}.weight"]).T)
+                for k, name in zip(names, ("gate_proj", "up_proj",
+                                           "down_proj"))}
+
+    def ffn(i):
+        ff = pre.format(i=i) + "mlp."
+        norm = {"ln2_scale": jnp.asarray(_np(
+            sd[pre.format(i=i) + "post_attention_layernorm.weight"]))}
+        if i < cfg.num_dense_layers:
+            return {**norm, **swiglu(ff)}
+        experts = {k: jnp.asarray(np.stack([
+            _np(sd[f"{ff}experts.{e}.{name}.weight"]).T
+            for e in range(cfg.num_experts)]))
+            for k, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                            ("w_down", "down_proj"))}
+        return {**norm, **experts,
+                "router": jnp.asarray(_np(sd[ff + "gate.weight"]).T),
+                "router_bias": jnp.asarray(
+                    _np(sd[ff + "gate.e_score_correction_bias"]),
+                    jnp.float32),
+                **swiglu(ff + "shared_experts.",
+                         ("shared_gate", "shared_up", "shared_down"))}
+
+    at = "self_attn."
+    return {
+        "embed": jnp.asarray(_np(sd["model.embed_tokens.weight"])),
+        "final_norm_scale": jnp.asarray(_np(sd["model.norm.weight"])),
+        "lm_head": jnp.asarray(_np(sd["lm_head.weight"]).T),
+        "sparse_latent": {
+            "ln1_scale": rows("input_layernorm.weight", keep),
+            "wq_a": rows(at + "q_a_proj.weight"),
+            "q_norm": rows(at + "q_a_layernorm.weight", keep),
+            "wq_b": rows(at + "q_b_proj.weight"),
+            "wkv_a": rows(at + "kv_a_proj_with_mqa.weight"),
+            "kv_norm": rows(at + "kv_a_layernorm.weight", keep),
+            "wkv_b": rows(at + "kv_b_proj.weight"),
+            "wo": rows(at + "o_proj.weight"),
+            "wq_index": rows(at + "indexer.wq_b.weight"),
+            "wk_index": rows(at + "indexer.wk.weight"),
+            "index_norm_scale": rows(at + "indexer.k_norm.weight", keep),
+            "index_norm_bias": rows(at + "indexer.k_norm.bias", keep),
+            "w_index": rows(at + "indexer.weights_proj.weight"),
         },
         "moe": [ffn(i) for i in range(n)],
     }

@@ -1,6 +1,6 @@
 """A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum``,
-``mistral4``, ``afmoe``, ``longcat_flash``, ``lfm2_moe`` and ``keye_vl2``
-families' forward and steps.
+``mistral4``, ``afmoe``, ``longcat_flash``, ``lfm2_moe``, ``keye_vl2`` and
+``deepseek_v32`` families' forward and steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -45,7 +45,12 @@ language model) has one kind, ``sparse`` (``models/sparse_attn.py``): a
 rotated, q/k-normed GQA layer that also caches an INDEX KEY a position (the
 page pool's second leaf, ``paged_kv.IndexedPagePool``) and whose query
 attends the ``index_topk`` positions its indexer scores highest; routed
-experts alone, an untied head.
+experts alone, an untied head. A ``deepseek_v32`` stack (DeepSeek-V3.2-Exp)
+has one kind, ``sparse_latent`` (``models/sparse_mla.py``): mistral4's latent
+layer whose query attends the positions keye's indexer selects, its rows and
+index keys the two leaves of a ``paged_kv.IndexedLatentPool``; afmoe's leading
+dense entries, then routed experts chosen within the best expert groups
+(``moe.route``) plus a shared one, an untied head.
 
 (the expert weights are a list, not a stack: a row sliced from a ``(L, E, D,
 F)`` stack for a prefill's grouped products, whose operands must be whole
@@ -77,13 +82,13 @@ import jax.numpy as jnp
 
 from ..lint import graph_contract
 from .configs import ModelConfig
-from . import mla, sparse_attn
+from . import mla, sparse_attn, sparse_mla
 from .flash_attention import (MAX_BLOCKED_S, QBLOCK, causal_attention,
                               decode_attention, kernel_plan)
 from .mamba2 import mamba2_prefill, mamba2_step
 from .shortconv import shortconv_prefill, shortconv_step
 from .moe import moe_layer
-from .paged_kv import (IndexedPagePool, LatentPool, PagePool,
+from .paged_kv import (INDEXED_POOLS, LatentPool, PagePool,
                        _attention_decode_latent,
                        _attention_decode_paged, _attention_decode_window,
                        attend_latent, gated, head_norms, post_norm)
@@ -230,6 +235,21 @@ class SparseCache(NamedTuple):
         return self.k.shape[2]
 
 
+class SparseLatentCache(NamedTuple):
+    """The contiguous decode cache of a stack of sparse latent layers.
+
+    rows: (L, B, capacity, kv_row_lanes), :class:`LatentCache`'s; length: ()
+    int32; index: (L, B, capacity, index_row_lanes), :class:`SparseCache`'s."""
+
+    rows: jnp.ndarray
+    length: jnp.ndarray
+    index: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.rows.shape[2]
+
+
 class LatentCache(NamedTuple):
     """The contiguous decode cache of a stack of latent-attention layers.
 
@@ -272,7 +292,8 @@ def _row(tree: dict, j: int) -> dict:
 def _kinds(cfg: ModelConfig):
     """(layer, kind, index among its kind) down the stack."""
     seen = {"mamba": 0, "conv": 0, "attention": 0, "sliding_attention": 0,
-            "latent_attention": 0, "sparse_attention": 0}
+            "latent_attention": 0, "sparse_attention": 0,
+            "sparse_latent_attention": 0}
     for layer, kind in enumerate(cfg.layer_types):
         yield layer, kind, seen[kind]
         seen[kind] += 1
@@ -305,7 +326,9 @@ def _rope_tables(cfg: ModelConfig, n: int) -> dict:
     rotates by — none for a kind in ``cfg.position_free``; else the scaled
     one (YaRN) on full layers and the plain one on sliding layers."""
     if cfg.latent_layers:  # the rope lanes' table (cfg.rotary_dim wide)
-        return {"latent_attention": precompute_rope(cfg, n)}
+        # (a sparse latent layer's indexer rotates by it too)
+        return {"sparse_latent_attention" if cfg.sparse_layers
+                else "latent_attention": precompute_rope(cfg, n)}
     if cfg.sparse_layers:  # the heads' table, and the indexer's narrower one
         return {"sparse_attention": precompute_rope(cfg, n),
                 "sparse_index": sparse_attn.index_rope(cfg, n)}
@@ -406,11 +429,40 @@ def _attention_latent_step(cfg: ModelConfig, lp: dict, x, rope, rows_all,
     return mla.unabsorb(cfg, lp, ctx), rows_all
 
 
+#: a prefill whose routed layer would gather more than FFN_ROWS_MAX bytes of
+#: token rows (tokens x experts_per_tok x hidden: 1.9 GB at 16384 x 8 x 7168
+#: in bf16, beside as much again in products) takes its feed-forwards, the
+#: dense ones too, FFN_CHUNK_ROWS bytes of such rows at a time; nothing at
+#: or under it is touched (the widest before this rule: 512 MiB, 16384 x 8 x
+#: 2048)
+FFN_ROWS_MAX = 768 << 20
+FFN_CHUNK_ROWS = 256 << 20
+
+
+def _ffn_chunk(cfg: ModelConfig, u) -> int:
+    """Tokens of u (T, D) a feed-forward takes at once (0: all of them), a
+    power of two; see :data:`FFN_ROWS_MAX`."""
+    row = cfg.experts_per_tok * u.shape[-1] * u.dtype.itemsize
+    if u.shape[0] * row <= FFN_ROWS_MAX:
+        return 0
+    return 1 << (FFN_CHUNK_ROWS // row).bit_length() - 1
+
+
 def _ffn(cfg: ModelConfig, mp: dict, h, active=None):
-    """The feed-forward sublayer on h (..., D); returns (h, counts (Eh,))."""
+    """The feed-forward sublayer on h (..., D); returns (h, counts (Eh,)),
+    counts None where the tokens went in chunks (a prefill's, which nobody
+    reads)."""
     u = _rms(cfg, h, mp["ln2_scale"])
-    out, counts = _feed_forward(cfg, mp, u.reshape(-1, u.shape[-1]), active)
-    return h + cfg.residual_multiplier * out.reshape(h.shape), counts
+    u = u.reshape(-1, u.shape[-1])
+    chunk = _ffn_chunk(cfg, u)
+    if not chunk:
+        out, counts = _feed_forward(cfg, mp, u, active)
+        return h + cfg.residual_multiplier * out.reshape(h.shape), counts
+    pad = -u.shape[0] % chunk
+    chunks = jnp.pad(u, ((0, pad), (0, 0))).reshape(-1, chunk, u.shape[-1])
+    out = jax.lax.map(lambda c: _feed_forward(cfg, mp, c)[0], chunks)
+    out = out.reshape(-1, u.shape[-1])[:u.shape[0]]
+    return h + cfg.residual_multiplier * out.reshape(h.shape), None
 
 
 def _step_row(cfg: ModelConfig, kind: str, lp: dict, h, state: dict, j: int):
@@ -466,6 +518,13 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind])
             if collect:
                 lat.append(rows)
+        elif kind == "sparse_latent_attention":
+            lp = _row(params["sparse_latent"], j)
+            out, rows, ik = sparse_mla.attention_full(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind])
+            if collect:
+                lat.append(rows)
+                iks.append(ik)
         elif kind == "sparse_attention":
             lp = _row(params["sparse"], j)
             out, k, v, ik = sparse_attn.attention_full(
@@ -523,8 +582,8 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
                    last_only: bool = False):
     """The prompt's forward that also fills the decode cache: (logits (B, S,
     V) float32 — (B, V) of the last position with ``last_only`` —, a
-    :class:`HybridCache`, :class:`WindowCache`, :class:`LatentCache` or
-    :class:`SparseCache`)."""
+    :class:`HybridCache`, :class:`WindowCache`, :class:`LatentCache`,
+    :class:`SparseCache` or :class:`SparseLatentCache`)."""
     b, s = ids.shape
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
@@ -533,9 +592,12 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
                                                         collect=True)
     logits = unembed_hybrid(cfg, params, h[:, -1] if last_only else h)
     if lat:
-        return logits, LatentCache(
-            jnp.pad(jnp.stack(lat), ((0, 0), (0, 0), (0, capacity - s),
-                                     (0, 0))), jnp.asarray(s, jnp.int32))
+        grow = ((0, 0), (0, 0), (0, capacity - s), (0, 0))
+        rows, length = jnp.pad(jnp.stack(lat), grow), jnp.asarray(s, jnp.int32)
+        if iks:
+            return logits, SparseLatentCache(
+                rows, length, jnp.pad(jnp.stack(iks), grow))
+        return logits, LatentCache(rows, length)
     kv_shape = (0, b, s, cfg.num_kv_heads, cfg.head_dim)
     pad = ((0, 0), (0, 0), (0, capacity - s), (0, 0), (0, 0))
     k = jnp.pad(_stack(ks, kv_shape, h.dtype), pad)
@@ -560,6 +622,8 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     b = token_ids.shape[0]
     pos = cache.length
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
+    if isinstance(cache, SparseLatentCache):
+        return _decode_step_sparse_latent(cfg, params, cache, h)
     if isinstance(cache, LatentCache):
         return _decode_step_latent(cfg, params, cache, h)
     if isinstance(cache, SparseCache):
@@ -638,6 +702,27 @@ def _decode_step_sparse(cfg: ModelConfig, params: dict, cache: SparseCache,
     return unembed_hybrid(cfg, params, h), SparseCache(k, v, pos + 1, index)
 
 
+def _decode_step_sparse_latent(cfg: ModelConfig, params: dict,
+                               cache: SparseLatentCache, h):
+    """:func:`decode_step_hybrid` for a stack of sparse latent layers: h (B,
+    D) the embedded tokens."""
+    pos, (rows, _, index) = cache.length, cache
+    rope = tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1) for x in
+                 _rope_tables(cfg, cache.capacity)["sparse_latent_attention"])
+    for layer, _, j in _kinds(cfg):
+        lp = _row(params["sparse_latent"], j)
+        out, rows_j, index_j = sparse_mla.attention_decode_rows(
+            cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope, rows[j], index[j],
+            pos)
+        rows, index = rows.at[j].set(rows_j), index.at[j].set(index_j)
+        h = h + cfg.residual_multiplier * out
+        h, _ = _ffn(cfg, params["moe"][layer], h)
+    return unembed_hybrid(cfg, params, h), SparseLatentCache(rows, pos + 1,
+                                                             index)
+
+
+@graph_contract("paged.decode_step_sparse_latent", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 3))
 @graph_contract("paged.decode_step_sparse", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 3))
 @graph_contract("paged.decode_step_latent", collectives={},
@@ -672,7 +757,8 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
     back as a fifth result; it has no recurrent layer. A stack of
     sparse-attention layers hands over its ``paged_kv.IndexedPagePool``
     WHOLE as ``pool`` (both leaves: the K/V rows and the index keys) and gets
-    it back so."""
+    it back so, as a stack of sparse latent layers does its
+    ``paged_kv.IndexedLatentPool``."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
@@ -680,7 +766,7 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
     counts, term = [], None
     # each slot's own row of the table its layer kind rotates by
     span = page_table.shape[1] * (
-        pool.page_size if isinstance(pool, IndexedPagePool) else pool.shape[2])
+        pool.page_size if isinstance(pool, INDEXED_POOLS) else pool.shape[2])
     rope = {kind: t and (t[0][lengths], t[1][lengths])
             for kind, t in _rope_tables(cfg, span).items()}
     if window is not None:
@@ -691,6 +777,11 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
             out, (pool,) = _attention_decode_latent(
                 cfg, lp, _rms(cfg, h, lp["ln1_scale"]), *rope[kind],
                 LatentPool(pool), j, page_table, lengths)
+        elif kind == "sparse_latent_attention":
+            lp = _row(params["sparse_latent"], j)
+            out, pool = sparse_mla.attention_decode_paged(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind], pool, j,
+                page_table, lengths)
         elif kind == "sparse_attention":
             lp = _row(params["sparse"], j)
             out, pool = sparse_attn.attention_decode_paged(
@@ -744,7 +835,9 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     ``keye_vl2`` stack's ``sparse`` kind holds an attention layer's leaves
     with the two head norms, and the indexer's: ``wq_index``, ``wk_index``,
     the index key's LayerNorm (``index_norm_scale`` one, ``index_norm_bias``
-    zero) and ``w_index``."""
+    zero) and ``w_index``. A ``deepseek_v32`` stack's ``sparse_latent`` kind
+    holds a latent layer's leaves and the same indexer's, ``wq_index`` off
+    the q latent (q_lora_rank rows)."""
     keys = iter(jax.random.split(key, 16 + 8 * len(cfg.layer_types)))
 
     def init(*shape):
@@ -813,7 +906,7 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     if cfg.latent_layers:
         n, h = cfg.latent_layers, cfg.num_heads
         rank = cfg.kv_lora_rank
-        params["latent"] = {
+        latent = {
             "ln1_scale": jnp.ones((n, d), dtype),
             "wq_a": init(n, d, cfg.q_lora_rank),
             "q_norm": jnp.ones((n, cfg.q_lora_rank), dtype),
@@ -824,6 +917,18 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
                                         + cfg.v_head_dim)),
             "wo": init(n, h * cfg.v_head_dim, d),
         }
+        if cfg.sparse_layers:  # the indexer's leaves, its query's from c_q
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            params["sparse_latent"] = {
+                **latent,
+                "wq_index": init(n, cfg.q_lora_rank, hi * di),
+                "wk_index": init(n, d, di),
+                "index_norm_scale": jnp.ones((n, di), dtype),
+                "index_norm_bias": jnp.zeros((n, di), dtype),
+                "w_index": init(n, d, hi),
+            }
+        else:
+            params["latent"] = latent
     elif cfg.sparse_layers:
         n, hi, di = cfg.sparse_layers, cfg.index_heads, cfg.index_head_dim
         params["sparse"] = {

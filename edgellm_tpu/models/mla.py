@@ -72,23 +72,47 @@ def _pad_lanes(cfg: ModelConfig, x):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
 
 
-def project(cfg: ModelConfig, lp: dict, x, rotate, scale):
+def query_latent(cfg: ModelConfig, lp: dict, x):
+    """x (..., D) normalised -> ``c_q`` (..., q_lora_rank), the normalised
+    query latent: what :func:`project` makes the heads' queries from and a
+    sparse latent layer's indexer its own (``models/sparse_mla.py``)."""
+    return _rmsnorm(x @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
+
+
+def project(cfg: ModelConfig, lp: dict, x, rotate, scale, c_q=None):
     """x (..., D) normalised -> (q_nope (..., H, nope), q_rope (..., H, rope),
     row (..., kv_row_lanes)): the queries scaled by ``scale`` (...,) float32
     (:func:`query_scale`) and ``q_rope`` / the row's ``k_rope`` rotated by
     ``rotate`` (a function of (..., heads, rope) arrays, de-interleaved first
-    here), the row as it is cached."""
-    rope, rank = cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    c_q = _rmsnorm(x @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
-    q = (c_q @ lp["wq_b"]).reshape(*x.shape[:-1], cfg.num_heads, cfg.head_dim)
-    q = q * scale[..., None, None].astype(q.dtype)
+    here), the row as it is cached. ``c_q``: :func:`query_latent` of ``x``
+    where the caller has made it already."""
+    rope = cfg.qk_rope_head_dim
+    if c_q is None:
+        c_q = query_latent(cfg, lp, x)
+    q = head_queries(cfg, lp, c_q, scale)
+    row = latent_row(cfg, lp, x, rotate)
+    return (q[..., :-rope], rotate(deinterleave_pairs(q[..., -rope:])), row)
+
+
+def head_queries(cfg: ModelConfig, lp: dict, c_q, scale):
+    """``c_q`` (..., q_lora_rank) -> the heads' queries (..., H, nope + rope)
+    times ``scale`` (...,) float32, nothing rotated yet."""
+    q = (c_q @ lp["wq_b"]).reshape(*c_q.shape[:-1], cfg.num_heads,
+                                   cfg.head_dim)
+    return q * scale[..., None, None].astype(q.dtype)
+
+
+def latent_row(cfg: ModelConfig, lp: dict, x, rotate):
+    """x (..., D) normalised -> the position's row as it is cached (...,
+    kv_row_lanes): ``[c | k_rope | 0...]``, ``k_rope`` de-interleaved and
+    rotated by ``rotate``."""
+    rank = cfg.kv_lora_rank
     kv = x @ lp["wkv_a"]
     c = _rmsnorm(kv[..., :rank], lp["kv_norm"], cfg.norm_eps)
     if cfg.rank_scales:
         c = c * jnp.asarray(cfg.kv_rank_scale, c.dtype)
     k_rope = rotate(deinterleave_pairs(kv[..., None, rank:]))[..., 0, :]
-    row = _pad_lanes(cfg, jnp.concatenate([c, k_rope], axis=-1))
-    return (q[..., :-rope], rotate(deinterleave_pairs(q[..., -rope:])), row)
+    return _pad_lanes(cfg, jnp.concatenate([c, k_rope], axis=-1))
 
 
 def _kvb(cfg: ModelConfig, lp: dict):

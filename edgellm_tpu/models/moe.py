@@ -61,15 +61,18 @@ def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray,
     that no weight sees), weights ``route_scale * p_e / (sum of the chosen p
     + cfg.route_norm_eps)``; ``"softmax_all"``: ``p = softmax(logits)`` over
     every output, chosen the same way, weights ``route_scale * p_e``; all of
-    it float32."""
+    it float32. With ``cfg.route_groups`` > 1 the top k are taken within the
+    ``cfg.route_groups_kept`` best groups (:func:`_group_limited`)."""
     with jax.named_scope("moe.route"):
         logits = jnp.einsum("td,de->te", u, router_w,
                             preferred_element_type=jnp.float32)
         if cfg.score_func != "softmax":
             p = (jax.nn.sigmoid if cfg.score_func == "sigmoid"
                  else jax.nn.softmax)(logits)
-            _, idx = jax.lax.top_k(p + bias.astype(jnp.float32),
-                                   cfg.experts_per_tok)
+            biased = p + bias.astype(jnp.float32)
+            if cfg.route_groups > 1:
+                biased = _group_limited(cfg, biased)
+            _, idx = jax.lax.top_k(biased, cfg.experts_per_tok)
             # the chosen scores by a one-hot product, exact, and not by
             # take_along_axis, whose gather leaves the scope's path behind
             chosen = jnp.einsum("tke,te->tk", jax.nn.one_hot(
@@ -80,6 +83,22 @@ def route(cfg: ModelConfig, router_w: jnp.ndarray, u: jnp.ndarray,
             return idx.astype(jnp.int32), chosen * cfg.route_scale
         vals, idx = jax.lax.top_k(logits, cfg.experts_per_tok)
         return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+def _group_limited(cfg: ModelConfig, biased):
+    """Group-limited selection: biased scores (T, E) float32, the E outputs
+    in ``cfg.route_groups`` equal groups in order -> the same with every
+    output outside the ``cfg.route_groups_kept`` best groups at -inf. A
+    group's rank is the sum of its two largest biased scores, a tie to the
+    earlier group; the kept groups hold at least ``experts_per_tok``
+    outputs, so the top k that follows never takes a masked one."""
+    t, e = biased.shape
+    groups = biased.reshape(t, cfg.route_groups, e // cfg.route_groups)
+    rank = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)        # (T, G)
+    _, best = jax.lax.top_k(rank, cfg.route_groups_kept)
+    kept = jnp.any(best[:, :, None] == jnp.arange(cfg.route_groups),
+                   axis=1)                                      # (T, G)
+    return jnp.where(kept[:, :, None], groups, -jnp.inf).reshape(t, e)
 
 
 def _local(cfg: ModelConfig, idx):

@@ -447,13 +447,46 @@ class IndexedPagePool(NamedTuple):
         return self.kv.shape[-1] // 2
 
 
+class IndexedLatentPool(NamedTuple):
+    """Device-side page pool of a stack of sparse LATENT layers
+    (``models/sparse_mla.py``): a :class:`LatentPool`'s leaf and an
+    :class:`IndexedPagePool`'s second one under one page table.
+
+    rows: (L, num_pages, page_size, cfg.kv_row_lanes), a position's ``[c |
+    k_rope | 0...]``; ik: (L, num_pages, page_size, cfg.index_row_lanes), its
+    index key. Row r of page p of layer l is one position in both, and the
+    surgery written over a pool's leaves moves the two together. The walks
+    are the two other pools': the latent rows' (key and value both, a block
+    of twice a K/V walk's pages) and the index keys', whose page is the
+    smaller and sets the pool's runs."""
+
+    rows: jnp.ndarray
+    ik: jnp.ndarray
+
+    @property
+    def num_pages(self) -> int:
+        return self.rows.shape[-3]
+
+    @property
+    def page_size(self) -> int:
+        return self.rows.shape[-2]
+
+
+#: the pools whose first leaf is a latent row / that hold index keys
+LATENT_POOLS = (LatentPool, IndexedLatentPool)
+INDEXED_POOLS = (IndexedPagePool, IndexedLatentPool)
+#: every pool of the fp tier (rows in the pool's own dtype, no scales)
+FP_POOLS = (PagePool, IndexedPagePool, *LATENT_POOLS)
+
+
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
               dtype=jnp.float32, layers: Optional[int] = None):
     """An all-zero pool; ``num_pages`` INCLUDES the reserved trash page 0,
     so ``num_pages - 1`` pages are allocatable. ``layers``: how many layers
     it serves where that is not ``cfg.kv_layers`` (the window group's). A
     :class:`PagePool`, a :class:`LatentPool` for latent layers, an
-    :class:`IndexedPagePool` for sparse-attention layers."""
+    :class:`IndexedPagePool` for sparse-attention layers, an
+    :class:`IndexedLatentPool` for layers that are both."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is reserved), "
                          f"got {num_pages}")
@@ -461,6 +494,10 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
         raise ValueError(f"page_size must be >= 1, got {page_size}")
     rows = (cfg.kv_layers if layers is None else layers, num_pages,
             page_size)
+    if cfg.latent_layers and cfg.sparse_layers:
+        return IndexedLatentPool(
+            jnp.zeros(rows + (cfg.kv_row_lanes,), dtype),
+            jnp.zeros(rows + (cfg.index_row_lanes,), dtype))
     if cfg.latent_layers:
         return LatentPool(jnp.zeros(rows + (cfg.kv_row_lanes,), dtype))
     kv = jnp.zeros(rows + (2 * cfg.kv_row_lanes,), dtype)
@@ -578,7 +615,7 @@ def init_quant_pool(cfg: ModelConfig, num_pages: int, page_size: int,
 def pool_tier(pool) -> str:
     """The ``kv_codec`` name of a pool (whole, staged or one layer's): the
     one place a tier is read from, its type and the width of its codes."""
-    if isinstance(pool, (PagePool, LatentPool, IndexedPagePool)):
+    if isinstance(pool, FP_POOLS):
         return "fp"
     return next(c.name for c in KV_PAGE_CODECS.values()
                 if c.quantized and pool.k.dtype == c.code_dtype)
@@ -603,8 +640,10 @@ def kv_page_bytes(cfg: ModelConfig, page_size: int, kv_codec: str = "fp",
 
             refuse_index_keys(cfg, f"the quantized KV tier kv_codec="
                                    f"{kv_codec!r}")
+        # (a latent row is key and value both: one row, not K's then V's)
         return (cfg.kv_layers * page_size
-                * (2 * cfg.kv_row_lanes + cfg.index_row_lanes)
+                * ((1 if cfg.latent_layers else 2) * cfg.kv_row_lanes
+                   + cfg.index_row_lanes)
                 * jnp.dtype(dtype).itemsize)
     if cfg.latent_layers:
         if codec.quantized:
@@ -796,10 +835,18 @@ def _adopt_impl(pool, k_seq, v_seq, dest, head: Optional[int] = None,
 
 
 @functools.partial(jax.jit, static_argnames=("head",), donate_argnums=(0,))
-def _adopt_latent_impl(pool, rows, dest, head: Optional[int] = None):
+def _adopt_latent_impl(pool, rows, dest, head: Optional[int] = None,
+                       index=None):
     """:func:`_adopt_impl` for a :class:`LatentPool`: (L, S, kv_row_lanes)
-    rows, as stored, at the flat token indices ``dest``."""
-    return _set_rows(pool, (rows,), dest, 1, head)
+    rows, as stored, at the flat token indices ``dest``; with them, into an
+    :class:`IndexedLatentPool`'s second leaf, the positions' index keys
+    ``index`` (L, S, index_row_lanes)."""
+    if isinstance(pool, IndexedLatentPool) != (index is not None):
+        raise ValueError(
+            f"a {type(pool).__name__} adopts latent rows "
+            f"{'WITH' if index is None else 'without'} index keys")
+    return _set_rows(pool, (rows,) + (() if index is None else (index,)),
+                     dest, 1, head)
 
 
 @functools.partial(jax.jit, static_argnames=("lead", "head"),
@@ -857,9 +904,10 @@ def _gather_impl(pool, idx, lead: int = 1, *, kv: int):
 
 @jax.jit
 def _gather_latent_impl(pool, idx):
-    """A :class:`LatentPool`'s rows at ``idx`` as stored: (L, span,
-    kv_row_lanes)."""
-    return _get_rows(pool, idx, 1)[0]
+    """A latent pool's rows at ``idx`` as stored, an array a leaf: [(L, span,
+    kv_row_lanes)], and an :class:`IndexedLatentPool`'s index keys (L, span,
+    index_row_lanes) after."""
+    return _get_rows(pool, idx, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("lead",), donate_argnums=(0,))
@@ -1715,16 +1763,20 @@ class PagedKVCache:
             index=None if index is None else jnp.asarray(index))
         self.lengths[slot] = length
 
-    def adopt_latent(self, slot: int, rows, length: int) -> None:
+    def adopt_latent(self, slot: int, rows, length: int,
+                     index=None) -> None:
         """:meth:`adopt` for a :class:`LatentPool`: a contiguous (L, length,
         kv_row_lanes) prefix of latent rows as stored (a prefill's cache, or
-        an evicted stream's gathered rows) into ``slot``'s pages."""
+        an evicted stream's gathered rows) into ``slot``'s pages; ``index``:
+        the positions' index keys where the pool keeps them
+        (:class:`IndexedLatentPool`), as :meth:`adopt`'s."""
         self._require_pool("adopt_latent")
         self.ensure(slot, length)
         self.prepare_write(slot, length, start=0)
         dest = jnp.asarray(self._flat_indices(slot, length))
-        self.pool = _adopt_latent_impl(self.pool, jnp.asarray(rows), dest,
-                                       head=0)
+        self.pool = _adopt_latent_impl(
+            self.pool, jnp.asarray(rows), dest, head=0,
+            index=None if index is None else jnp.asarray(index))
         self.lengths[slot] = length
 
     def adopt_rows(self, slot: int, k_seq, v_seq,
@@ -1819,10 +1871,12 @@ class PagedKVCache:
         self._require_pool("gather_slot")
         n = int(self.lengths[slot])
         idx = jnp.asarray(self._flat_indices(slot, max(n, 1)))
-        if isinstance(self.pool, LatentPool):
+        if isinstance(self.pool, LATENT_POOLS):
             # a latent stack's rows as stored, what adopt_latent takes back
-            return {"rows": np.asarray(_gather_latent_impl(self.pool,
-                                                           idx))[:, :n],
+            # (its index keys with them, where the pool keeps any)
+            rows, *index = _gather_latent_impl(self.pool, idx)
+            return {"rows": np.asarray(rows)[:, :n],
+                    **{"index": np.asarray(a)[:, :n] for a in index},
                     "length": np.asarray(n, np.int32)}
         k, v, *index = _gather_impl(self.pool, idx, kv=self.cfg.num_kv_heads)
         return {"k": np.asarray(k)[:, :n], "v": np.asarray(v)[:, :n],
@@ -2091,9 +2145,12 @@ class PagedKVCache:
             live = self.window_table[self.window_table > 0]
             assert len(live) == len(set(live.tolist())), \
                 "a window page in two rings"
-        assert isinstance(self.pool, LatentPool) == bool(
+        assert isinstance(self.pool, LATENT_POOLS) == bool(
             self.cfg.latent_layers and self.pool is not None), \
-            "a one-leaf latent pool exists exactly for a stack of latent layers"
+            "a pool of latent rows exists exactly for a stack of latent layers"
+        assert isinstance(self.pool, INDEXED_POOLS) == bool(
+            self.cfg.sparse_layers and self.pool is not None), \
+            "a pool holds index keys exactly for a stack of sparse layers"
         if self.cfg.latent_layers and self.pool is not None:
             want = (self.cfg.kv_layers, self.num_pages, self.page_size,
                     self.cfg.kv_row_lanes)
@@ -2445,8 +2502,7 @@ def decode_read_path(pool) -> str:
     is ahead at 128 to 1024 lanes (PERF.md §6 "PR 33", "PR 35"; a ring of 65
     or 129 pages: "PR 40")."""
     # (an IndexedPagePool: of its K/V leaf, the one a walk would fetch)
-    if (not isinstance(pool, (PagePool, LatentPool, IndexedPagePool))
-            or not _on_tpu()):
+    if not isinstance(pool, FP_POOLS) or not _on_tpu():
         return PAGE_GATHER
     # what the kernel slices on lanes (a row's K part, its V part as wide,
     # or the whole latent row) and on sublanes (a page)
@@ -2473,7 +2529,7 @@ def walk_geometry(pool, pages_per_slot: int) -> tuple:
     leaf = pool[0]
     ppb = flash_attention.paged_walk_pages_per_block(
         leaf.shape[-2], _k_lanes(pool), leaf.dtype.itemsize)
-    if isinstance(pool, LatentPool):
+    if isinstance(pool, LATENT_POOLS):
         ppb *= 2
     return ppb, math.gcd(ppb, leaf_run_pages(leaf, pages_per_slot))
 
@@ -2510,7 +2566,7 @@ def index_read_path(pool) -> str:
     tiles; :data:`PAGE_GATHER` (:func:`_gather_pages` +
     ``sparse_attn.index_scores``, the oracle) for part tiles and every other
     backend."""
-    if not isinstance(pool, IndexedPagePool) or not _on_tpu():
+    if not isinstance(pool, INDEXED_POOLS) or not _on_tpu():
         return PAGE_GATHER
     return (INDEX_WALK if _whole_tiles(pool.ik, pool.ik.shape[-1])
             else PAGE_GATHER)
@@ -2824,7 +2880,7 @@ def paged_decode_step(cfg: ModelConfig, params: dict, pool,
 # §6 "PR 32"), and the five cells that hold no latent row keep theirs.
 
 def attend_latent_pages(q_rows, pool: LatentPool, layer, page_table, lengths,
-                        head_dim: int):
+                        head_dim: int, keep=None):
     """:func:`_gather_pages` + :func:`attend_latent` without the span: q_rows
     (B, H, lanes) against each slot's LIVE pages of layer ``layer`` of the
     one-leaf pool, read where they lie by the kernel :func:`attend_pages`
@@ -2840,13 +2896,15 @@ def attend_latent_pages(q_rows, pool: LatentPool, layer, page_table, lengths,
     flight at 16-row pages. On a v5e at the mistral4 cell's shape 256 / 512 /
     1024 / 1536 / 2048 rows a block take 1.86 / 1.40 / 1.25 / 1.21 / 1.23
     ms a layer (an all-idle batch 0.076 at 512, 0.101 at 1024, 0.157 at
-    2048; PERF.md §6 "PR 35")."""
+    2048; PERF.md §6 "PR 35"). ``keep``: :func:`attend_pages`'s, a sparse
+    latent layer's selection as a mask on the walk's rows."""
     pages = _pages(pool.rows, 1)
     ids = (layer * pool.num_pages + page_table).astype(jnp.int32)
     return flash_attention.paged_decode_walk(
         q_rows, pages, ids, lengths.astype(jnp.int32),
         scale=float(1.0 / np.sqrt(head_dim)),
-        **_walk_runs(pool, page_table))
+        **_walk_runs(pool, page_table),
+        **({} if keep is None else {"keep": keep}))
 
 
 def latent_decode_attention(q_rows, pool: LatentPool, layer, page_table,

@@ -104,21 +104,29 @@ def index_rope(cfg: ModelConfig, n: int):
     return jnp.cos(emb), jnp.sin(emb)
 
 
-def project_index(cfg: ModelConfig, lp: dict, x, rotate):
+def project_index(cfg: ModelConfig, lp: dict, x, rotate, query=None):
     """x (..., D) normalised -> (qI (..., Hi, di) rotated; kI (...,
     index_row_lanes), the position's index key as it is cached: layer-normed,
     rotated, zeros after; wI (..., Hi) float32, the heads' weights times
     ``Hi^-1/2 di^-1/2``). ``rotate``: a function of (..., heads, di)
-    arrays."""
+    arrays. ``query`` (None: ``x``): what ``W_qI`` projects, where that is
+    not the layer's input (a sparse latent layer's q latent ``c_q``)."""
     hi, di = cfg.index_heads, cfg.index_head_dim
-    qi = rotate((x @ lp["wq_index"]).reshape(*x.shape[:-1], hi, di))
+    qi = rotate(((x if query is None else query)
+                 @ lp["wq_index"]).reshape(*x.shape[:-1], hi, di))
+    ki = index_key(cfg, lp, x, rotate)
+    wi = (x @ lp["w_index"]).astype(jnp.float32) * (hi * di) ** -0.5
+    return qi, ki, wi
+
+
+def index_key(cfg: ModelConfig, lp: dict, x, rotate):
+    """:func:`project_index`'s kI alone: x (..., D) normalised -> (...,
+    index_row_lanes), the position's index key as it is cached."""
     ki = _layernorm(x @ lp["wk_index"], lp["index_norm_scale"],
                     lp["index_norm_bias"], cfg.norm_eps)
     ki = rotate(ki[..., None, :])[..., 0, :]
-    ki = jnp.pad(ki, ((0, 0),) * (ki.ndim - 1)
-                 + ((0, cfg.index_row_lanes - di),))
-    wi = (x @ lp["w_index"]).astype(jnp.float32) * (hi * di) ** -0.5
-    return qi, ki, wi
+    return jnp.pad(ki, ((0, 0),) * (ki.ndim - 1)
+                   + ((0, cfg.index_row_lanes - cfg.index_head_dim),))
 
 
 def _pad_query(qi, lanes: int):
@@ -334,6 +342,35 @@ def selected_rows(scores, lengths, k: int, pool: IndexedPagePool, layer,
     return count, layer * (pool.num_pages * ps) + page * ps + idx % ps
 
 
+def read_selected(cfg: ModelConfig, qi, wi, pool, layer, page_table, lengths,
+                  *, every, walk, rows):
+    """The decode of a sparse layer kind after its row is written: index ->
+    select -> read, the read the pool takes (:func:`sparse_read_path`). qi (B,
+    Hi, di), wi (B, Hi) from :func:`project_index`; ``pool`` holds the index
+    keys as ``ik``; lengths (B,) each slot's positions BEFORE the one just
+    written, as the layer was handed them. The layer kind brings its three
+    reads: ``every()`` of every live row where no slot can pass
+    ``index_topk``; ``walk(keep)`` the page walk under the selection, keep
+    (B, span) bool; ``rows(chosen, count)`` of the rows gathered by the flat
+    ids chosen (B, k), count (B,) of them live (:func:`selected_rows`)."""
+    span = page_table.shape[1] * pool.page_size
+    read = sparse_read_path(cfg, span, pool)
+    if read == EVERY_ROW:
+        return every()
+    with jax.named_scope("attn.sparse.index"):
+        scores = index_scores_paged(qi, wi, pool, layer, page_table,
+                                    lengths + 1)
+    if read == MASKED_WALK:
+        with jax.named_scope("attn.sparse.select"):
+            live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]
+            keep = selection_mask(scores, live, cfg.index_topk)
+        return walk(keep)
+    with jax.named_scope("attn.sparse.select"):
+        count, chosen = selected_rows(scores, lengths + 1, cfg.index_topk,
+                                      pool, layer, page_table)
+    return rows(chosen, count)
+
+
 @jax.named_scope("attn.sparse")
 def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope, rope_index,
                            pool: IndexedPagePool, layer, page_table, lengths):
@@ -341,8 +378,8 @@ def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope, rope_index,
     rope_index (cos, sin) (B, hd) / (B, di), each slot's own position's.
     Project, norm and rotate; write the slot's K/V row and its index key into
     its current page (``paged_kv.write``, one scatter a leaf); score the
-    slot's index keys (:func:`index_scores_paged`), choose, and attend the
-    chosen K/V rows by the pool's read (:func:`sparse_read_path`); ``W_o``.
+    slot's index keys, choose, and attend the chosen K/V rows by the pool's
+    read (:func:`read_selected`); ``W_o``.
     Returns (out (B, D), pool)."""
     b = x.shape[0]
     q, k, v = (t[:, None] for t in _qkv(cfg, lp, x))           # (B, 1, ., hd)
@@ -351,26 +388,14 @@ def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope, rope_index,
     with jax.named_scope("attn.sparse.index"):
         qi, ik, wi = project_index(cfg, lp, x, rotate_rows(*rope_index))
     pool = write_rows(pool, layer, page_table, lengths, k, v, index=ik)
-    span = page_table.shape[1] * pool.page_size
-    read = sparse_read_path(cfg, span, pool)
-    if read == EVERY_ROW:
-        out = paged_decode_attention(q, PagePool(pool.kv), layer, page_table,
-                                     lengths + 1)
-        return out.reshape(b, -1) @ lp["wo"], pool
-    with jax.named_scope("attn.sparse.index"):
-        scores = index_scores_paged(qi, wi, pool, layer, page_table,
-                                    lengths + 1)
-    if read == MASKED_WALK:
-        with jax.named_scope("attn.sparse.select"):
-            live = jnp.arange(span)[None, :] < (lengths + 1)[:, None]
-            keep = selection_mask(scores, live, cfg.index_topk)
-        out = attend_pages(q, PagePool(pool.kv), layer, page_table,
-                           lengths + 1, keep=keep)
-    else:
-        with jax.named_scope("attn.sparse.select"):
-            count, rows = selected_rows(scores, lengths + 1, cfg.index_topk,
-                                        pool, layer, page_table)
-        out = attend_rows(q, *split_kv(_rows(pool.kv, 1)[rows]), count)
+    out = read_selected(
+        cfg, qi, wi, pool, layer, page_table, lengths,
+        every=lambda: paged_decode_attention(
+            q, PagePool(pool.kv), layer, page_table, lengths + 1),
+        walk=lambda keep: attend_pages(
+            q, PagePool(pool.kv), layer, page_table, lengths + 1, keep=keep),
+        rows=lambda chosen, count: attend_rows(
+            q, *split_kv(_rows(pool.kv, 1)[chosen]), count))
     return out.reshape(b, -1) @ lp["wo"], pool
 
 

@@ -208,6 +208,15 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "attn.sparse.select",  # the top-k and what turns it into row ids (decode)
                            # or a mask on a block's scores (prefill)
     "attn.sparse.prefill",  # a sparse layer over a whole prompt, by blocks
+    # a sparse LATENT layer (models/sparse_mla.py): the two scopes above for
+    # its indexer and its selection, and an outer scope of its own a form, so
+    # that a reader tells it from attn.sparse and attn.latent
+    "attn.sparse_latent",  # ... its decode: projections, norms, rotations,
+                           # both row writes, the indexer, the selection,
+                           # the absorbed read of the chosen rows, W_kvb's V
+                           # half and W_o
+    "attn.sparse_latent.prefill",  # ... over a whole prompt, absorbed, by
+                                   # blocks of query rows
     "mlp",                # a dense feed-forward; in a stack walked by layer
                           # kinds, a leading dense layer's and its post-norm,
                           # or every sublayer's dense SwiGLU (longcat_flash)
@@ -224,7 +233,9 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "moe.route",          # router logits, top-k, softmax over the chosen
                           # (or sigmoid scores, the selection bias, the
                           # top-k, the normalised and scaled weights; or the
-                          # whole softmax's scores, chosen and scaled alike)
+                          # whole softmax's scores, chosen and scaled alike;
+                          # the group-limited selection's mask where the
+                          # router's outputs lie in groups)
     "moe.experts",        # the held experts' part for the tokens routed here
                           # (and the norm after the expert sublayer, where a
                           # layer has one; the identity experts' part and the

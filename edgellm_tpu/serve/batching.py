@@ -71,7 +71,9 @@ module schedules many streams through ONE jitted decode step built on
   prefill's rows (``adopt_latent``) and eviction gathers them as stored. A
   ``keye_vl2`` stack rides it with its pool of TWO leaves handed over whole
   (``paged_kv.IndexedPagePool``: K/V rows and index keys under one table);
-  ``adopt`` and ``gather_slot`` move both.
+  ``adopt`` and ``gather_slot`` move both. A ``deepseek_v32`` stack the same
+  (``paged_kv.IndexedLatentPool``: latent rows and index keys; ``adopt_latent``
+  takes both).
 
 ``ServeFront`` integration lives in ``serve/frontend.py`` (``batcher=``):
 admission control, brownout and breakers all apply before a request reaches
@@ -98,8 +100,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..models.configs import ModelConfig
 from ..models.hybrid import paged_decode_step_hybrid, refuse_beyond_kv_rows
 from ..models.sparse_attn import EVERY_ROW, ROW_GATHER, sparse_read_path
-from ..models.paged_kv import INDEX_WALK, PAGE_GATHER, PAGE_WALK, \
-    IndexedPagePool, PagePool, OutOfPages, OutOfSlots, PagedKVCache, \
+from ..models.paged_kv import INDEX_WALK, INDEXED_POOLS, LATENT_POOLS, \
+    PAGE_GATHER, PAGE_WALK, LatentPool, PagePool, OutOfPages, OutOfSlots, PagedKVCache, \
     PrefixCacheConfig, decode_read_path, index_read_path, \
     index_walk_geometry, paged_decode_step, pool_run_pages, \
     resolve_kv_codec, walk_geometry
@@ -275,7 +277,8 @@ def _batched_hybrid_step_jit(cfg: ModelConfig, params: dict, pool, state,
     updated. A SEPARATE jit: the one-block families keep the executable
     above. ``pool`` is the page pool's ONE leaf, K-then-V rows or a stack of
     latent layers' rows (a stack of sparse-attention layers: its
-    ``IndexedPagePool`` whole, both leaves donated); ``state`` None (and
+    ``IndexedPagePool`` / ``IndexedLatentPool`` whole, both leaves donated);
+    ``state`` None (and
     back) where the stack keeps none."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
@@ -735,7 +738,8 @@ class ContinuousBatcher:
                         slot, st.resume["k_codes"], st.resume["v_codes"],
                         st.resume["k_scale"], st.resume["v_scale"], need_len)
                 elif "rows" in st.resume:
-                    self.pool.adopt_latent(slot, st.resume["rows"], need_len)
+                    self.pool.adopt_latent(slot, st.resume["rows"], need_len,
+                                           index=st.resume.get("index"))
                 else:
                     self.pool.adopt(slot, jnp.asarray(st.resume["k"]),
                                     jnp.asarray(st.resume["v"]), need_len,
@@ -794,7 +798,10 @@ class ContinuousBatcher:
                                st.temperature)
             with obs_phase("batch.admit.adopt", sid=sid):
                 if self.cfg.latent_layers:
-                    self.pool.adopt_latent(slot, cache.rows[:, 0, :s], s)
+                    self.pool.adopt_latent(
+                        slot, cache.rows[:, 0, :s], s,
+                        index=(cache.index[:, 0, :s]
+                               if self.cfg.sparse_layers else None))
                 else:
                     self.pool.adopt(
                         slot, cache.k[:, 0, :s], cache.v[:, 0, :s], s,
@@ -1251,7 +1258,7 @@ class ContinuousBatcher:
                 # rows) and the state store, where the stack keeps one
                 # (a pool of two leaves goes and comes back whole)
                 kind = type(self.pool.pool)
-                leaf = (self.pool.pool if kind is IndexedPagePool
+                leaf = (self.pool.pool if kind in INDEXED_POOLS
                         else self.pool.pool[0])
                 toks, leaf, self.pool.state, self._expert_tokens = (
                     _batched_hybrid_step_jit(
@@ -1260,7 +1267,7 @@ class ContinuousBatcher:
                         token_ids, jnp.asarray(key_data),
                         jnp.asarray(steps), jnp.asarray(temps),
                         self.bcfg.compute_dtype))
-                self.pool.pool = (leaf if kind is IndexedPagePool
+                self.pool.pool = (leaf if kind in INDEXED_POOLS
                                   else kind(leaf))
             else:
                 toks, self.pool.pool = _batched_step_jit(
@@ -1704,9 +1711,12 @@ class ContinuousBatcher:
         sites, as below."""
         whole = self._split_pool if self.rt is not None else self.pool.pool
         rings = self.pool.window_pool
-        # what a sparse layer reads as a full layer does: its K/V leaf
-        full = (PagePool(whole.kv) if isinstance(whole, IndexedPagePool)
-                else whole)
+        # what a sparse layer reads as a full layer does: its K/V leaf (or
+        # its leaf of latent rows)
+        full = whole
+        if isinstance(whole, INDEXED_POOLS):
+            full = (LatentPool(whole.rows) if isinstance(whole, LATENT_POOLS)
+                    else PagePool(whole.kv))
         # (a step that gathers its chosen rows one by one walks no K/V page;
         # the masked walk is the walk)
         read = (PAGE_GATHER if self.sparse_read == ROW_GATHER

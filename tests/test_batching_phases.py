@@ -787,7 +787,7 @@ def test_phase_chains_its_clock_and_keeps_late_attributes_for_the_span():
 SOURCES = ("serve/batching.py", "models/paged_kv.py", "models/transformer.py",
            "parallel/split.py", "models/mamba2.py", "models/moe.py",
            "models/hybrid.py", "models/shortconv.py",
-           "models/sparse_attn.py")
+           "models/sparse_attn.py", "models/sparse_mla.py")
 
 
 def _literal_names(callees):

@@ -33,7 +33,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from edgellm_tpu.models import grouped_matmul, hybrid, moe, paged_kv
-from edgellm_tpu.models.configs import KEYE_VL_2_0_30B_A3B, LFM2_8B_A1B, \
+from edgellm_tpu.models.configs import DEEPSEEK_V3_2_EXP, \
+    KEYE_VL_2_0_30B_A3B, LFM2_8B_A1B, \
     LONGCAT_FLASH_CHAT, ModelConfig, tiny_afmoe_config, tiny_hybrid_config, \
     tiny_lfm2_moe_config, tiny_longcat_flash_config, tiny_mellum_config, \
     tiny_mistral4_config
@@ -944,6 +945,148 @@ def test_keye_prefill_of_a_whole_prompt_builds_no_square_tensor(topo):
     mem = prefill.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
     assert "attn.sparse.prefill" in hlo and "attn.sparse.select" in hlo
+
+
+# the deepseek cell (benchmark/configs/deepseek-v3.2-exp-ep16.json): one
+# leading dense layer and one expert layer at the published widths, 16 of the
+# 256 routed experts held, an eighth of the vocabulary; 16 slots of 1280
+# pages of 16 rows in a pool of two leaves (latent rows 640 lanes, index keys
+# 128)
+DSV32 = dataclasses.replace(
+    DEEPSEEK_V3_2_EXP, num_layers=2, num_dense_layers=1,
+    layer_types=("sparse_latent_attention",) * 2, experts_held=16,
+    vocab_size=16160)
+D_SLOTS, D_PAGES_PER_SLOT = 16, 1280
+
+
+def test_deepseek_step_walks_once_a_layer_and_keeps_both_leaves_in_place(
+        topo, read):
+    """The step of the ``deepseek_v32`` cell at its shapes, a dense layer and
+    an expert layer: the pool's TWO leaves (latent rows of 640 lanes, index
+    keys of 128) donated and written where they lie. On a TPU's choice each
+    layer holds ONE index walk (64 heads x 128 lanes, under
+    ``attn.sparse.index``) and ONE read of the chosen rows, the page walk of
+    the latent leaf at 128 heads x 640 lanes with the selection as a mask;
+    no gather of a slot's whole span of either leaf exists, and nothing per
+    head over the span (the absorption is real). On the other read the index
+    keys come by one page gather a layer and the chosen rows by a row gather
+    of 16 x 2048 rows. Every matmul, gather, scatter, sort and kernel call
+    under a registered scope, the new kind's under its own."""
+    from edgellm_tpu.models import sparse_attn
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = DSV32
+    assert (cfg.sparse_layers, cfg.latent_layers, cfg.kv_row_lanes,
+            cfg.index_row_lanes) == (2, 2, 640, 128)
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    assert "w_index" in params["sparse_latent"] and "latent" not in params
+    assert params["sparse_latent"]["wq_index"].shape == (2, 1536, 64 * 128)
+    assert "router" not in params["moe"][0]
+    assert params["moe"][1]["w_gate"].shape == (16, 7168, 2048)
+    pages = D_SLOTS * D_PAGES_PER_SLOT + 1
+    pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, pages, PAGE, jnp.bfloat16)), one)
+    assert type(pool) is paged_kv.IndexedLatentPool
+    assert [a.shape for a in pool] == [(2, pages, PAGE, 640),
+                                       (2, pages, PAGE, 128)]
+    span = D_PAGES_PER_SLOT * PAGE
+    assert sparse_attn.sparse_read_path(cfg, span, pool) == (
+        sparse_attn.MASKED_WALK if read == "walk" else sparse_attn.ROW_GATHER)
+    assert paged_kv.index_read_path(pool) == (
+        paged_kv.INDEX_WALK if read == "walk" else paged_kv.PAGE_GATHER)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((D_SLOTS,), jnp.int32)
+    step = batching._batched_hybrid_step_jit.lower(
+        cfg, params, pool, None, arr((1, 16), jnp.int32),
+        arr((D_SLOTS, D_PAGES_PER_SLOT), jnp.int32), ints, ints,
+        arr((D_SLOTS, 2), jnp.uint32), ints, arr((D_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    rows_leaf, ik_leaf = 2 * pages * PAGE * 640, 2 * pages * PAGE * 128
+    keys = f"bf16[{D_SLOTS},{D_PAGES_PER_SLOT},{PAGE},128]"
+    own = {keys, f"bf16[{D_SLOTS * D_PAGES_PER_SLOT},{PAGE},128]",
+           f"bf16[{D_SLOTS},{span},128]"}
+    moved = [m for m in _moved(hlo, ik_leaf // 2)
+             if not (m[0] in ("reshape", "transpose") and m[2] in own)]
+    assert not moved, moved
+    # one read of the chosen rows and one index walk a layer
+    assert _walks(hlo) == (2 if read == "walk" else 0)
+    walked = _walk_operands(hlo)
+    assert all(f"bf16[{D_SLOTS},128,640]" in ops
+               and f"bf16[{2 * pages},{PAGE},640]" in ops for ops in walked)
+    index_walks = [line for op, _, _, line in _instructions(hlo)
+                   if op == "custom-call" and "paged_index_walk" in line]
+    assert len(index_walks) == (2 if read == "walk" else 0)
+    assert all("attn.sparse.index" in line for line in index_walks)
+    big = [shape.split("{")[0] for op, _, shape, _ in _instructions(hlo)
+           if op == "gather" and _elements(shape) >= D_SLOTS * 2048 * 640]
+    # (the gather's: every slot's span of index keys, a layer, and the
+    # chosen latent rows, 16 x 2048 of them a layer; no gather anywhere of a
+    # slot's whole span of LATENT rows)
+    assert sorted(set(big)) == ([] if read == "walk" else sorted(
+        {keys, f"bf16[{D_SLOTS},2048,640]"})), big
+    assert not _span_sized(hlo, {f"bf16[{D_SLOTS},{span},640]",
+                                 f"bf16[{D_SLOTS},{D_PAGES_PER_SLOT},"
+                                 f"{PAGE},640]"})
+    assert read != "walk" or not _span_sized(hlo, own)
+    # nothing per head over the span: keys and values are never rebuilt
+    assert not re.findall(rf"\[{D_SLOTS},{span},128,\d+\]", hlo)
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (rows_leaf + ik_leaf)
+    assert mem.temp_size_in_bytes < (300e6 if read == "walk" else 700e6), \
+        mem.temp_size_in_bytes
+    unscoped, under = _scopes_of_the_heavy(hlo)
+    # ("gather": the compiler's own split of the chosen rows' gather, which
+    # keeps no path; the gather read alone)
+    assert unscoped <= {"jit(_batched_hybrid_step_jit)/jit(_take)/gather",
+                        "jit(_batched_hybrid_step_jit)/gather", ""} | (
+        set() if read == "walk" else {"gather"}), unscoped
+    assert under >= {"attn.sparse_latent", "attn.sparse.index",
+                     "paged_kv.write", "mlp", "moe.route", "moe.experts",
+                     "moe.shared", "unembed_sample"} | (
+        set() if read == "walk" else {"attn.sparse.select"}), under
+    assert not under & {"attn.decode", "attn.sparse", "attn.latent"}
+
+
+def test_deepseek_prefill_of_a_whole_prompt_rebuilds_no_key_per_head(topo):
+    """The 16384-token prefill of the cell, a dense layer and an expert
+    layer: blocks of 64 query rows, each with its own queries made from the q
+    latent, its index dot, its selection (no sort: a k-th value by counting
+    passes) and its absorbed attend. No (S, S) tensor exists, no query, key
+    or value of (S, 128 heads, lanes) either, the feed-forwards go 2048
+    tokens at a time, and what the compiler holds at once stays under 3 GB
+    beside the 11.8 GB the cell keeps."""
+    from edgellm_tpu.serve import decode
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg, s = DSV32, 16384
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    prefill = decode._prefill_jit.lower(
+        cfg, params, jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one),
+        D_PAGES_PER_SLOT * PAGE, None).compile()
+    hlo = prefill.as_text()
+    square = [shape for _, _, shape, _ in _instructions(hlo)
+              if sum(int(d) >= s for d in re.findall(
+                  r"\d+", shape.split("{")[0].split("[")[-1])) >= 2]
+    assert not square, square[:3]
+    per_head = [shape for _, _, shape, _ in _instructions(hlo)
+                if re.search(rf"\[(1,)?{s},128,\d\d+\]|\[(1,)?128,{s},\d\d+\]",
+                             shape.split("{")[0])]
+    assert not per_head, per_head[:3]
+    assert not [name for op, name, _, line in _instructions(hlo)
+                if op == "sort" and "attn.sparse" in line]
+    # the gathered token rows of a routed layer: 2048 tokens x 8 at a time
+    assert f"bf16[{2048 * 8},7168]" in hlo
+    assert f"bf16[{s * 8},7168]" not in hlo
+    mem = prefill.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+    assert "attn.sparse_latent.prefill" in hlo
+    assert "attn.sparse.select" in hlo and "attn.latent.expand" not in hlo
 
 
 # the six families hybrid.py walks, at toy sizes whose expert layers are

@@ -467,6 +467,9 @@ CELL_RUNS = {
     "longcat-flash-chat-ep32": ((640,), 192, 4),
     "lfm2-8b-a1b-pp2": ((2 * 8 * 64,), 288, 1),
     "keye-vl-2.0-30b-a3b-ep4": ((2 * 4 * 128, 128), 1280, 8),
+    # 16 x 1280: a latent leaf (20 KB a page: four to a run by itself) and
+    # the index keys' (4 KB: eight), which sets the pool's runs
+    "deepseek-v3.2-exp-ep16": ((640, 128), 1280, 8),
 }
 
 
@@ -482,7 +485,8 @@ def test_the_run_of_each_cells_pool_is_its_smallest_pages(cell):
     assert want == flash_attention.walk_run_pages(
         PAGE * min(lanes) * 2, pps)
     own = [paged_kv.leaf_run_pages(leaf, pps) for leaf in pool]
-    assert own == ([want] if len(lanes) == 1 else [1, 8])
+    assert own == ([want] if len(lanes) == 1
+                   else [{2 * 4 * 128: 1, 640: 4}[lanes[0]], 8])
 
 
 def test_a_two_leaf_pool_at_the_keye_geometry_deals_runs_of_eight(
