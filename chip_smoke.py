@@ -484,6 +484,27 @@ def _evict_readmit(cfg, bcfg, prompt_len: int, n_new: int,
     return report, float(gaps.max() / scale)
 
 
+def _prefill_kernels(cfg, bcfg, prompt_len: int, tweak=lambda p: p) -> list:
+    """The Pallas kernels of the prefill an admission of ``prompt_len``
+    tokens runs: the names of the custom calls in ``_prefill_jit`` as
+    ``ContinuousBatcher._admit_fill`` calls it, lowered for this backend
+    (traced, not compiled: what ``report()`` says of a path is a rule's
+    answer, this is the program's)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from edgellm_tpu.models import init_params
+    from edgellm_tpu.serve.decode import _prefill_jit
+
+    params = tweak(init_params(cfg, jax.random.key(SEED)))
+    text = _prefill_jit.lower(
+        cfg, params, jnp.zeros((1, prompt_len), jnp.int32), bcfg.span,
+        bcfg.compute_dtype).as_text()
+    return sorted(set(re.findall(r'kernel_name\s*=\s*"(\w+)"', text)))
+
+
 def hybrid_phase(*, prompt_len: int = 300, n_new: int = 24,
                  evict_after: int = 5) -> dict:
     """A tiny ``granitemoehybrid`` stream (Mamba-2 and NoPE attention layers,
@@ -803,6 +824,12 @@ def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
     assert report["sparse_read"] == sparse_attn.MASKED_WALK, report
     assert report["decode_read"] == PAGE_WALK
     assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    # the prefill's blocks (heads of 64 lanes, 300 rows padded to 320) attend
+    # in the masked kernel, and so does ``forward`` on the other side: what
+    # the report names, and what the admission's program holds
+    assert report["sparse_prefill"] == sparse_attn.MASKED_KERNEL, report
+    kernels = _prefill_kernels(cfg, bcfg, prompt_len, wider)
+    assert "masked_attention" in kernels, kernels
     # the index keys (pages of 16 x 128 float32 lanes, eight to a run) are
     # scored where they lie, the prompt's whole groups as runs
     assert report["index_read"] == INDEX_WALK, report
@@ -819,6 +846,8 @@ def sparse_phase(*, prompt_len: int = 300, n_new: int = 40,
             "evicted": report["evicted"],
             "sparse_read": report["sparse_read"],
             "index_read": report["index_read"],
+            "sparse_prefill": report["sparse_prefill"],
+            "prefill_kernels": kernels,
             "index_run_share": 100.0 * report["index_pages_in_runs"]
             / report["index_pages_walked"],
             "sparse_selected_share": 100.0 * report["sparse_rows_attended"]
@@ -958,11 +987,13 @@ def sparse_latent_phase(*, prompt_len: int = 300, n_new: int = 40,
     leaves of one pool; a leading dense layer, then sigmoid routing over 4
     groups of 4 of which 2 are kept, a shared expert; float32) through the
     same admit / step / evict / readmit: BOTH leaves leave the device and
-    come back, and ``forward`` (the absorbed block form) over prompt +
-    tokens puts each served token first, on the index walk and the masked
-    walk of the latent rows a TPU's pool takes. ``wkv_b`` is seeded 9x wider
-    and the other matrices 3x, so that which rows are attended moves the
-    logits. Then :func:`latent_selection_on_the_chip`."""
+    come back, and ``forward`` (the masked kernel's expanded form) over
+    prompt + tokens puts each served token first, on the index walk and the
+    masked walk of the latent rows a TPU's pool takes. ``wkv_b`` is seeded
+    4.5x wider and the other matrices 3x, so that which rows are attended
+    moves the logits and the stream is no repeat of one token (at 9x the
+    values' 128 lanes drown the rest: token 70 thirty times of forty). Then
+    :func:`latent_selection_on_the_chip`."""
     import dataclasses
 
     import jax
@@ -978,16 +1009,19 @@ def sparse_latent_phase(*, prompt_len: int = 300, n_new: int = 40,
             name = path[-1].key
             if name in ("router", "router_bias"):
                 return a * 15.0
-            return a * (9.0 if name == "wkv_b" else 3.0) \
+            return a * (4.5 if name == "wkv_b" else 3.0) \
                 if name.startswith("w") or name.startswith("shared") else a
         return jax.tree_util.tree_map_with_path(scale, params)
 
     # rows of [c 96 | k_rope 32] = 128 lanes and index keys of 128: whole
-    # tiles, so that both walks are what the step is built on
+    # tiles, so that both walks are what the step is built on; heads of 160 +
+    # 32 key lanes and 128 value lanes, a lane tile and a half and one as
+    # the published 192 and 128 are, so that the prefill is the cell's: the
+    # masked kernel over keys and values rebuilt a group of heads at a time
     cfg = dataclasses.replace(tiny_deepseek_v32_config(
         hidden_size=256, num_heads=4, index_heads=4, index_head_dim=128,
-        index_topk=64), explicit_head_dim=64, qk_rope_head_dim=32,
-        kv_lora_rank=96, v_head_dim=32, q_lora_rank=64, expert_width=128,
+        index_topk=64), explicit_head_dim=192, qk_rope_head_dim=32,
+        kv_lora_rank=96, v_head_dim=128, q_lora_rank=64, expert_width=128,
         shared_width=128)
     bcfg = BatchingConfig(page_size=16, num_pages=73, max_slots=3,
                           pages_per_slot=24)
@@ -996,6 +1030,11 @@ def sparse_latent_phase(*, prompt_len: int = 300, n_new: int = 40,
     assert report["sparse_read"] == sparse_attn.MASKED_WALK, report
     assert report["decode_read"] == PAGE_WALK
     assert 0 < report["attend_pages_walked"] < report["attend_pages_spanned"]
+    # (the body's 300 rows expanded, four heads a group: what the report
+    # names, and what the admission's program holds)
+    assert report["sparse_prefill"] == sparse_attn.MASKED_KERNEL, report
+    kernels = _prefill_kernels(cfg, bcfg, prompt_len, wider)
+    assert "masked_attention" in kernels, kernels
     assert report["index_read"] == INDEX_WALK, report
     assert 0 < report["index_pages_in_runs"] <= \
         report["index_pages_walked"] == report["attend_pages_walked"]
@@ -1011,6 +1050,8 @@ def sparse_latent_phase(*, prompt_len: int = 300, n_new: int = 40,
             "evicted": report["evicted"],
             "sparse_read": report["sparse_read"],
             "index_read": report["index_read"],
+            "sparse_prefill": report["sparse_prefill"],
+            "prefill_kernels": kernels,
             "index_run_share": 100.0 * report["index_pages_in_runs"]
             / report["index_pages_walked"],
             "sparse_selected_share": 100.0 * report["sparse_rows_attended"]

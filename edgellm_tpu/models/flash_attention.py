@@ -1117,3 +1117,165 @@ def paged_index_walk(qi, wi, pages, page_ids, lengths, *,
         name="paged_index_walk",
     )(page_ids, lengths, qi, wi.astype(jnp.float32)[:, :, None], pages)
     return scores[:, 0, :entries * ps]
+
+
+# ---------------------------------------------------------------------------
+# Masked prefill attention: a block of query rows under a selection's mask.
+# ---------------------------------------------------------------------------
+
+#: keys a grid step of :func:`masked_attention` holds. A row's maximum and sum
+#: are reduced across lanes and its accumulator rescaled ONCE A KEY BLOCK,
+#: whatever the block's width, and at a key's 128 + 128 or 192 + 128 lanes
+#: that fixed part is most of a narrow block's step: the vector units bound
+#: the kernel, not the MXU (PERF.md section 6 "PR 53" has the sweep)
+MASKED_KEY_BLOCK = 2048
+#: what a step's blocks, scratch and score tiles may take of VMEM (a v5e has
+#: 128 MiB): the row tile is the largest the plan finds under it
+MASKED_VMEM_BYTES = 24 << 20
+MASKED_VMEM_LIMIT_BYTES = 64 << 20
+#: a mask is int8: whole (32, 128) tiles of it
+MASK_SUBLANES = 32
+
+
+def masked_attention_plan(rep: int, rows: int, keys: int, dk: int, dv: int,
+                          itemsize: int):
+    """(row tile, key block) of :func:`masked_attention` for ``rep`` copies
+    of ``rows`` query rows (a multiple of :data:`MASK_SUBLANES`) against
+    ``keys`` keys: the key block is :data:`MASKED_KEY_BLOCK` (a short key set
+    one block of whole lane tiles); the row tile is the largest of whole
+    copies of the rows (a divisor of ``rep``), or of whole mask tiles that
+    divide them, whose step fits :data:`MASKED_VMEM_BYTES`: q, k, v, mask
+    and output blocks twice (the pipeline's two buffers), the float32
+    accumulator, maximum and sum, and three (row tile, key block) float32
+    score tiles (scores, exponents, the cast)."""
+    tc = min(MASKED_KEY_BLOCK, -(-keys // 128) * 128)
+
+    def step_bytes(tr):
+        blocks = (tr * dk + tc * dk + tc * dv + tr * dv) * itemsize \
+            + min(tr, rows) * tc
+        return 2 * blocks + tr * (dv + 2 * 128) * 4 + 3 * tr * tc * 4
+
+    tiles = [rows * d for d in range(1, rep + 1) if rep % d == 0] + [
+        t for t in range(MASK_SUBLANES, rows, MASK_SUBLANES) if rows % t == 0]
+    fits = [t for t in tiles if step_bytes(t) <= MASKED_VMEM_BYTES]
+    return (max(fits) if fits else min(tiles)), tc
+
+
+def _masked_attn_kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
+                        m_ref, l_ref, acc_ref, *, scale, rows):
+    """Grid (group, row tile, key block), the key block innermost. See
+    :func:`masked_attention`."""
+    t, j = pl.program_id(1), pl.program_id(2)
+    tr, tc = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _first():
+        # a maximum every real score lies above and a masked one below: a
+        # key block none of whose keys a row attends leaves the row's sum
+        # and accumulator as they were (exp(-1e30 + 1e29) is 0)
+        m_ref[...] = jnp.full_like(m_ref, -1e29)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * tc <= start_ref[0] + _tile_last(t, tr, rows))
+    def _attend():
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        keep = mask_ref[0].astype(jnp.int32) != 0
+        if tr > rows:       # whole copies of the rows: one mask tile for all
+            s = jnp.where(keep[None], s.reshape(tr // rows, rows, tc),
+                          -1e30).reshape(tr, tc)
+        else:
+            s = jnp.where(keep, s, -1e30)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(q_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _last():
+        # (a row the mask leaves nothing, a padded one, divides by one)
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def _tile_last(t, tr: int, rows: int):
+    """The last of a block's ``rows`` query rows that row tile ``t`` of
+    ``tr`` rows holds: a tile of whole copies holds them all, a smaller one
+    the rows ``[(t mod rows/tr) tr, + tr)``."""
+    if tr >= rows:
+        return rows - 1
+    return jax.lax.rem(t, rows // tr) * tr + tr - 1
+
+
+def masked_attention(q, k, v, mask, start, *, scale: float, interpret=False):
+    """Attention of a BLOCK of query rows over keys under a mask, key block
+    by key block with a running maximum, sum and accumulator (float32): no
+    (heads, rows, keys) score or probability tensor leaves vector memory.
+
+    q (G, rep, Q, dk): ``rep`` query heads a group, the SAME ``Q`` positions
+    each; k (G, C, dk); v (G, C, dv); mask (M, Q, C) bool or int8, M = 1 or a
+    divisor of G (group ``g`` reads mask ``g // (G / M)``): query row ``i``
+    of every head attends key ``c`` iff ``mask[., i, c]``, which holds the
+    row's visibility too (``c <= start + i``); start () int32, the block's
+    first position, traced or not. Returns (G, rep, Q, dv) in q's dtype:
+    ``softmax(scale * q . k^T) . v`` over the kept keys, scores float32, the
+    probabilities cast to q's dtype before the second dot.
+
+    A group's queries ride as ONE (rep * Q, dk) matrix, row ``r`` reading
+    mask row ``r mod Q``: a row tile of whole copies of the Q rows takes one
+    (Q, keys) mask tile for all of them (a broadcast over the leading axis,
+    no row of the mask repeated in VMEM), and every tile ends at the block's
+    last position. A key block that lies wholly past a row tile's last
+    position is neither fetched (its block index is clamped to the last one
+    the tile reads, which the pipeline sees as a repeat) nor multiplied.
+    Tiles: :func:`masked_attention_plan`. Q is padded to whole mask tiles and
+    C to whole key blocks here; a padded row attends nothing and is cut."""
+    g, rep, n, dk = q.shape
+    c, dv = k.shape[1], v.shape[-1]
+    rows = -(-n // MASK_SUBLANES) * MASK_SUBLANES
+    tr, tc = masked_attention_plan(rep, rows, c, dk, dv, q.dtype.itemsize)
+    span = -(-c // tc) * tc
+    mask = jnp.pad(mask.astype(jnp.int8),
+                   ((0, 0), (0, rows - n), (0, span - c)))
+    if rows != n:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, rows - n), (0, 0)))
+    if span != c:
+        k, v = (jnp.pad(a, ((0, 0), (0, span - c), (0, 0))) for a in (k, v))
+    per = g // mask.shape[0]
+
+    def key_block(t, j, at):        # the last block a tile reads, repeated
+        return jnp.minimum(j, (at[0] + _tile_last(t, tr, rows)) // tc)
+
+    keys = [pl.BlockSpec((1, tc, lanes),
+                         lambda i, t, j, at: (i, key_block(t, j, at), 0))
+            for lanes in (dk, dv)]
+    out = pl.pallas_call(
+        functools.partial(_masked_attn_kernel, scale=scale, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(g, rep * rows // tr, span // tc),
+            in_specs=[pl.BlockSpec((1, tr, dk), lambda i, t, j, at: (i, t, 0)),
+                      *keys,
+                      pl.BlockSpec(
+                          (1, min(tr, rows), tc), lambda i, t, j, at: (
+                              i // per, jax.lax.rem(t, max(rows // tr, 1)),
+                              key_block(t, j, at)))],
+            out_specs=pl.BlockSpec((1, tr, dv), lambda i, t, j, at: (i, t, 0)),
+            scratch_shapes=[pltpu.VMEM((tr, 1), jnp.float32),
+                            pltpu.VMEM((tr, 1), jnp.float32),
+                            pltpu.VMEM((tr, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((g, rep * rows, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=MASKED_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="masked_attention",
+    )(jnp.reshape(start, (1,)).astype(jnp.int32),
+      q.reshape(g, rep * rows, dk), k, v, mask)
+    return out.reshape(g, rep, rows, dv)[:, :, :n]

@@ -39,6 +39,7 @@ it and ``k_rope`` does not carry it.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .configs import ModelConfig
@@ -94,11 +95,12 @@ def project(cfg: ModelConfig, lp: dict, x, rotate, scale, c_q=None):
     return (q[..., :-rope], rotate(deinterleave_pairs(q[..., -rope:])), row)
 
 
-def head_queries(cfg: ModelConfig, lp: dict, c_q, scale):
+def head_queries(cfg: ModelConfig, lp: dict, c_q, scale, heads=None):
     """``c_q`` (..., q_lora_rank) -> the heads' queries (..., H, nope + rope)
-    times ``scale`` (...,) float32, nothing rotated yet."""
-    q = (c_q @ lp["wq_b"]).reshape(*c_q.shape[:-1], cfg.num_heads,
-                                   cfg.head_dim)
+    times ``scale`` (...,) float32, nothing rotated yet. ``heads`` (first,
+    count): those heads alone (:func:`_of_heads`)."""
+    wq, h = _of_heads(lp["wq_b"], cfg.num_heads, heads)
+    q = (c_q @ wq).reshape(*c_q.shape[:-1], h, cfg.head_dim)
     return q * scale[..., None, None].astype(q.dtype)
 
 
@@ -115,17 +117,32 @@ def latent_row(cfg: ModelConfig, lp: dict, x, rotate):
     return _pad_lanes(cfg, jnp.concatenate([c, k_rope], axis=-1))
 
 
-def _kvb(cfg: ModelConfig, lp: dict):
-    """``W_kvb`` (rank, H, nope + vd): K lanes first, then V."""
-    return lp["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads,
-                               cfg.qk_nope_head_dim + cfg.v_head_dim)
+def _of_heads(w, h: int, heads):
+    """A matrix whose columns are ``h`` heads' lanes, head-major (rank, h x
+    lanes) -> (the columns of heads ``first .. first + count``, count) for
+    ``heads`` = (first, count), ``first`` traced or not (a group of heads a
+    turn of a ``lax.map``); (w, h) for None."""
+    if heads is None:
+        return w, h
+    lanes = w.shape[1] // h
+    return jax.lax.dynamic_slice_in_dim(
+        w, heads[0] * lanes, heads[1] * lanes, axis=1), heads[1]
 
 
-def expand(cfg: ModelConfig, lp: dict, rows):
+def _kvb(cfg: ModelConfig, lp: dict, heads=None):
+    """``W_kvb`` (rank, H, nope + vd): K lanes first, then V. ``heads``: as
+    :func:`_of_heads`."""
+    w, h = _of_heads(lp["wkv_b"], cfg.num_heads, heads)
+    return w.reshape(cfg.kv_lora_rank, h,
+                     cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def expand(cfg: ModelConfig, lp: dict, rows, heads=None):
     """Cached rows (B, S, kv_row_lanes) -> per-head (k (B, S, H, nope + rope),
-    v (B, S, H, vd)): the expanded form's keys and values."""
+    v (B, S, H, vd)): the expanded form's keys and values. ``heads`` (first,
+    count): those heads' alone (:func:`_of_heads`)."""
     rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    kv = jnp.einsum("bsc,chn->bshn", rows[..., :rank], _kvb(cfg, lp))
+    kv = jnp.einsum("bsc,chn->bshn", rows[..., :rank], _kvb(cfg, lp, heads))
     k_rope = jnp.broadcast_to(rows[..., None, rank:rank + rope],
                               (*kv.shape[:3], rope))
     nope = cfg.qk_nope_head_dim

@@ -25,7 +25,12 @@ A position's index key is cached beside its K/V row, ``[kI | 0...]`` of
   block scores the index keys it is handed, turns its rows' selections into
   a MASK and attends under it: no (S, S) tensor exists. Eight blocks share
   one traced body (:data:`BLOCKS_PER_BODY`): the compile of a 16k prompt is
-  a quarter of the unrolled form's.
+  a quarter of the unrolled form's. The attend is the one
+  :func:`sparse_prefill_path` names: on a TPU ONE kernel that walks the keys
+  a block at a time under the mask with a running maximum and sum
+  (``flash_attention.masked_attention``), so that a block's (H, QBLOCK, S)
+  float32 scores never leave vector memory; everywhere else the XLA einsums
+  that carry them through HBM, the kernel's oracle (PERF.md §6 "PR 53").
 - **decode** (:func:`attention_decode_paged`): one query a slot. The slot's
   live index keys are scored (:func:`index_scores_paged`): on a TPU where
   they lie, by a page walk of the pool's index-key leaf that fetches a run
@@ -61,7 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from . import flash_attention
-from .configs import ModelConfig
+from .configs import LANE_TILE, ModelConfig
 from .flash_attention import QBLOCK
 from .mla import rotate_rows  # (B, heads, lanes) by ONE table row a sequence
 from .paged_kv import (INDEX_WALK, PAGE_WALK, IndexedPagePool, PagePool,
@@ -76,6 +81,28 @@ from .transformer import _layernorm, apply_rotary
 MASKED_WALK = "pallas page walk, the selection a mask on its rows"
 ROW_GATHER = "xla row gather of the selected rows"
 EVERY_ROW = "every live row (no slot can pass index_topk)"
+#: the attends of a sparse layer's PREFILL blocks (``sparse_prefill``)
+MASKED_KERNEL = "pallas masked attention, a key block at a time"
+XLA_BLOCKS = "xla blocks, float32 scores through HBM"
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def sparse_prefill_path(cfg: ModelConfig, dtype) -> str:
+    """Which attend a sparse layer's prefill blocks are built with, read off
+    what it is handed: :data:`MASKED_KERNEL`
+    (``flash_attention.masked_attention``) on a TPU where the operands are
+    bfloat16 or float32 and a head's key and value lanes are whole or half
+    lane tiles (a latent layer's: ``mla.expand``'s, ``nope + rope`` and
+    ``v_head_dim``); :data:`XLA_BLOCKS`, the kernel's oracle, everywhere
+    else."""
+    dv = cfg.v_head_dim if cfg.latent_layers else cfg.head_dim
+    whole = cfg.head_dim % (LANE_TILE // 2) == 0 and dv % (LANE_TILE // 2) == 0
+    known = jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                 jnp.dtype(jnp.float32))
+    return MASKED_KERNEL if whole and known and _on_tpu() else XLA_BLOCKS
 
 
 def sparse_read_path(cfg: ModelConfig, span: int, pool=None) -> str:
@@ -231,29 +258,43 @@ def selection_mask(scores, visible, k: int):
 BLOCKS_PER_BODY = 8
 
 
+def _by_group(a):
+    """(B, n, KV, hd) -> (B KV, n, hd): the keys, or one query head a group,
+    as :func:`flash_attention.masked_attention` takes them."""
+    b, n, kv, hd = a.shape
+    return jnp.swapaxes(a, 1, 2).reshape(b * kv, n, hd)
+
+
 def _attend_block(cfg: ModelConfig, start, qb, k, v, qib, ik, wib,
-                  select: bool):
+                  select: bool, kernel: bool):
     """One block of query rows at positions ``start ..`` (``start`` may be
     traced) against EVERY key handed over: qb (B, Q, H, hd), k, v (B, C, KV,
-    hd), qib (B, Q, Hi, lanes) padded, ik (B, C, lanes), wib (B, Q, Hi) ->
-    (B, Q, H, hd). Keys past a row's own position are masked; with
-    ``select`` the row's selection besides."""
+    hd), or with ``kernel`` :func:`_by_group` (B KV, C, hd); qib (B, Q, Hi,
+    lanes) padded, ik (B, C, lanes), wib (B, Q, Hi) -> (B, Q, H, hd). Keys
+    past a row's own position are masked; with ``select`` the row's
+    selection besides. ``kernel``: the attend is the masked kernel, else the
+    XLA einsums over float32 scores (B, H, Q, C), its oracle."""
     b, n, h, hd = qb.shape
-    kv = k.shape[2]
-    seen = (jnp.arange(k.shape[1])[None, :]
-            <= start + jnp.arange(n)[:, None])                 # (Q, C)
+    kv = cfg.num_kv_heads
+    seen = (jnp.arange(ik.shape[1])[None, :]
+            <= start + jnp.arange(n)[:, None])[None]           # (1, Q, C)
     if select:
         with jax.named_scope("attn.sparse.index"):
             dots = jnp.einsum("bqhd,bcd->bhqc", qib, ik,
                               preferred_element_type=jnp.float32)
             index = _weighted(dots, jnp.moveaxis(wib, -1, 1)[..., None])
         with jax.named_scope("attn.sparse.select"):
-            seen = selection_mask(index, seen,
-                                  cfg.index_topk)[:, None, None]  # (B,1,1,Q,C)
-    scores = jnp.einsum("bqgrd,bcgd->bgrqc",
-                        qb.reshape(b, n, kv, h // kv, hd), k,
+            seen = selection_mask(index, seen, cfg.index_topk)  # (B, Q, C)
+    qg = qb.reshape(b, n, kv, h // kv, hd)
+    if kernel:
+        out = flash_attention.masked_attention(
+            jnp.moveaxis(qg, 1, 3).reshape(b * kv, h // kv, n, hd), k, v,
+            seen, start, scale=1.0 / math.sqrt(hd))
+        return jnp.moveaxis(out.reshape(b, kv, h // kv, n, hd), 3, 1
+                            ).reshape(b, n, h, hd)
+    scores = jnp.einsum("bqgrd,bcgd->bgrqc", qg, k,
                         preferred_element_type=jnp.float32)
-    scores = jnp.where(seen, scores * (1.0 / math.sqrt(hd)),
+    scores = jnp.where(seen[:, None, None], scores * (1.0 / math.sqrt(hd)),
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bgrqc,bcgd->bqgrd", probs.astype(qb.dtype), v,
@@ -272,23 +313,27 @@ def attend_blocks(cfg: ModelConfig, q, k, v, qi, ik, wi):
     block of their own; a body whose keys all lie inside the first
     ``index_topk`` positions selects nothing (every visible position is
     attended), and a row that sees no more than ``index_topk`` selects them
-    all. No (S, S) tensor exists: the widest are one block's index dots (B,
-    Hi, QBLOCK, S) and attention scores (B, H, QBLOCK, S), float32."""
+    all. No (S, S) tensor exists: the widest is one block's index dots (B,
+    Hi, QBLOCK, S), float32; its attention scores (B, H, QBLOCK, S) float32,
+    1 GB at 32 heads and 16384 positions, exist on the XLA path alone
+    (:func:`sparse_prefill_path`: the kernel takes its keys by KV group)."""
     s = q.shape[1]
     qi = _pad_query(qi, ik.shape[-1])
+    kernel = sparse_prefill_path(cfg, q.dtype) == MASKED_KERNEL
     body = QBLOCK * BLOCKS_PER_BODY
     outs = []
     for start in range(0, s, body):
         stop = min(start + body, s)
         whole = (stop - start) // QBLOCK * QBLOCK
-        keys = (k[:, :stop], v[:, :stop])
+        keys = tuple(_by_group(a[:, :stop]) if kernel else a[:, :stop]
+                     for a in (k, v))
         select = stop > cfg.index_topk
 
         def block(at, rows, n):
             cut = [jax.lax.dynamic_slice_in_dim(a, rows, n, axis=1)
                    for a in (q, qi, wi)]
             return _attend_block(cfg, at, cut[0], *keys, cut[1],
-                                 ik[:, :stop], cut[2], select)
+                                 ik[:, :stop], cut[2], select, kernel)
 
         if whole > QBLOCK:
             firsts = start + QBLOCK * jnp.arange(whole // QBLOCK)

@@ -18,20 +18,29 @@ are; ``I[t, s]``, the selection ``S_t`` and its ties as ``sparse_attn.py``
 has them. Position t attends ``S_t`` alone: up to ``index_topk`` positions
 that is plain causal latent attention.
 
-Both forms are ABSORBED (multi-query attention of H heads over the rows as
-cached): a row is key and value both, no per-head key or value of a cached
-position exists, and a prefill of S positions at 128 heads never holds the
-(S, H, nope + vd) it would rebuild.
+Every decode step is ABSORBED (multi-query attention of H heads over the
+rows as cached): a row is key and value both and no per-head key or value of
+a cached position exists. A prefill never holds a (S, H, nope + vd) tensor
+for more than :data:`EXPANDED_HEADS` heads.
 
 - **prefill** (:func:`attention_full`): blocks of :data:`QUERY_ROWS` query
   rows, ``sparse_attn.BLOCKS_PER_BODY x QBLOCK`` rows a traced body against
-  the rows the last of them can see. A block makes its own heads' queries
-  and indexer queries from ``c_q`` (no (S, H, hd) query exists either),
-  scores the index keys, turns its rows' selections into a mask
-  (``sparse_attn.selection_mask``) and attends under it; ``W_kvb``'s V half
-  and ``W_o`` close the block. The widest tensors are a block's scores (B,
-  H, QUERY_ROWS, S) and index dots (B, Hi, QUERY_ROWS, S), float32: 537 and
-  268 MB at 128 / 64 heads and 16384 positions.
+  the rows the last of them can see. A block makes its indexer queries from
+  ``c_q``, scores the index keys and turns its rows' selections into a mask
+  (``sparse_attn.selection_mask``, :func:`_block_mask`). The attend under
+  the mask is the one ``sparse_attn.sparse_prefill_path`` names. On a TPU it
+  is ``flash_attention.masked_attention``, which walks the keys a block at a
+  time with a running maximum and sum, so that no (H, rows, S) float32
+  score leaves vector memory, EXPANDED (:func:`_attend_expanded`): a body's
+  rows in one call a group of heads under the blocks' masks, the group's
+  keys and values rebuilt from the rows (``mla.expand``), ``W_o`` once a
+  body: with the scores in vector memory the dots are what is left, and the
+  expanded ones are 3.4x fewer. Everywhere else the XLA einsums over a
+  block's float32 scores (B, H, QUERY_ROWS, S), ABSORBED as a decode step is
+  (:func:`_attend_block`): the kernel's oracle. The widest tensor left on the
+  kernel's path is a block's index dots (B, Hi, QUERY_ROWS, S) float32,
+  which XLA fuses into their weighted sum (PERF.md section 6 "PR 53" has
+  the timings of both).
 - **decode** (:func:`attention_decode_paged`): one query a slot. The row and
   the index key are written (one scatter a leaf), the slot's live index keys
   scored where they lie (``sparse_attn.index_scores_paged``), the ``topk``
@@ -47,24 +56,27 @@ Scopes: ``attn.sparse_latent`` (a layer's decode) and
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from . import mla
+from . import flash_attention, mla
 from .configs import ModelConfig
 from .flash_attention import QBLOCK
 from .paged_kv import (IndexedLatentPool, _apply_rotary_rows, _rows,
                        attend_latent, attend_latent_pages,
                        latent_decode_attention, write_rows)
-from .sparse_attn import (BLOCKS_PER_BODY, _pad_query, _weighted, index_key,
-                          index_scores, project_index, read_selected, select,
-                          selection_mask)
+from .sparse_attn import (BLOCKS_PER_BODY, MASKED_KERNEL, _by_group,
+                          _pad_query, _weighted, index_key, index_scores,
+                          project_index, read_selected, select,
+                          selection_mask, sparse_prefill_path)
 from .transformer import apply_rotary, deinterleave_pairs
 
-#: query rows a prefill block attends at once: every head's queries of a row
-#: share the rows they read (multi-query), so a block's dots have ``H x
-#: QUERY_ROWS`` rows whatever this is, and it is set by the float32 scores a
-#: block holds: (H, QUERY_ROWS, S) is 537 MB at 128 heads and 16384 positions
+#: query rows a prefill block selects for (and, on the XLA path, attends) at
+#: once. Set by the float32 index dots a block holds, (Hi, QUERY_ROWS, S):
+#: 268 MB at 64 index heads and 16384 positions (the attention scores (H,
+#: QUERY_ROWS, S), 537 MB at 128 heads, exist on the XLA path alone)
 QUERY_ROWS = 64
 
 
@@ -80,9 +92,9 @@ def index_rotation_rows(cfg: ModelConfig, cos, sin):
 
 
 def _attend(cfg: ModelConfig, lp: dict, q_rows, rows, seen):
-    """Absorbed attention of queries over rows: q_rows (B, Q, H, lanes) from
-    ``mla.absorb_query``, rows (B, C, lanes), seen (B or 1, Q, C) bool ->
-    the layer's output (B, Q, D)."""
+    """Absorbed attention of queries over rows (the XLA path): q_rows (B, Q,
+    H, lanes) from ``mla.absorb_query``, rows (B, C, lanes), seen (B or 1,
+    Q, C) bool -> the layer's output (B, Q, D)."""
     b, n, h, lanes = q_rows.shape
     scores = jnp.einsum("bqhD,bcD->bhqc", q_rows, rows,
                         preferred_element_type=jnp.float32)
@@ -95,38 +107,94 @@ def _attend(cfg: ModelConfig, lp: dict, q_rows, rows, seen):
         b, n, -1)
 
 
-def _attend_block(cfg: ModelConfig, lp: dict, start, c_q, x, rope, rows, ik,
-                  select: bool):
-    """One block of query rows at positions ``start ..`` (``start`` may be
-    traced) against EVERY row handed over: c_q (B, Q, q_lora_rank), x (B, Q,
-    D), rope (cos, sin) (Q, rope) the block's own rows of the table, rows
-    (B, C, kv_row_lanes), ik (B, C, index_row_lanes) -> (B, Q, D). Rows past
-    a query's own position are masked; with ``select`` its selection
+def _block_mask(cfg: ModelConfig, lp: dict, start, c_q, x, rope, ik,
+                select: bool):
+    """What one block of query rows at positions ``start ..`` (``start`` may
+    be traced) attends of EVERY position handed over: c_q (B, Q,
+    q_lora_rank), x (B, Q, D), rope (cos, sin) (Q, rope) the block's own
+    rows of the table, ik (B, C, index_row_lanes) -> (B or 1, Q, C) bool.
+    Positions past a query's own are masked; with ``select`` its selection
     besides."""
+    at = start + jnp.arange(c_q.shape[1])
+    seen = (jnp.arange(ik.shape[1])[None, :] <= at[:, None])[None]
+    if not select:
+        return seen
+    with jax.named_scope("attn.sparse.index"):
+        qi, _, wi = project_index(
+            cfg, lp, x,
+            lambda t: apply_rotary(t, *rope, cfg.index_rope_lanes),
+            query=c_q)
+        dots = jnp.einsum("bqhd,bcd->bhqc", _pad_query(qi, ik.shape[-1]),
+                          ik, preferred_element_type=jnp.float32)
+        index = _weighted(dots, jnp.moveaxis(wi, -1, 1)[..., None])
+    with jax.named_scope("attn.sparse.select"):
+        return selection_mask(index, seen, cfg.index_topk)
+
+
+def _head_queries(cfg: ModelConfig, lp: dict, start, c_q, rope, heads=None):
+    """The heads' queries of rows at positions ``start ..``: c_q (B, Q,
+    q_lora_rank) -> (q_nope (B, Q, H, nope), q_rope (B, Q, H, rope) rotated
+    by the rows' own table rows), scaled as ``mla.project`` scales them;
+    ``heads`` (first, count): those heads' alone."""
     b, n, _ = c_q.shape
-    at = start + jnp.arange(n)
-    seen = (jnp.arange(rows.shape[1])[None, :] <= at[:, None])[None]
-    if select:
-        with jax.named_scope("attn.sparse.index"):
-            qi, _, wi = project_index(
-                cfg, lp, x,
-                lambda t: apply_rotary(t, *rope, cfg.index_rope_lanes),
-                query=c_q)
-            dots = jnp.einsum("bqhd,bcd->bhqc", _pad_query(qi, ik.shape[-1]),
-                              ik, preferred_element_type=jnp.float32)
-            index = _weighted(dots, jnp.moveaxis(wi, -1, 1)[..., None])
-        with jax.named_scope("attn.sparse.select"):
-            seen = selection_mask(index, seen, cfg.index_topk)
-    q = mla.head_queries(
-        cfg, lp, c_q, jnp.broadcast_to(mla.query_scale(cfg, at), (b, n)))
+    q = mla.head_queries(cfg, lp, c_q, jnp.broadcast_to(
+        mla.query_scale(cfg, start + jnp.arange(n)), (b, n)), heads)
     nope = cfg.qk_nope_head_dim
-    q_rope = apply_rotary(deinterleave_pairs(q[..., nope:]), *rope,
-                          cfg.rotary_dim)
+    return q[..., :nope], apply_rotary(deinterleave_pairs(q[..., nope:]),
+                                       *rope, cfg.rotary_dim)
+
+
+def _attend_block(cfg: ModelConfig, lp: dict, start, c_q, rope, rows, seen):
+    """One block of query rows ABSORBED against every row handed over (the
+    XLA path): the heads' queries made from c_q (B, Q, q_lora_rank) and
+    folded through ``W_kvb``'s K half, rows (B, C, kv_row_lanes), seen (B or
+    1, Q, C) from :func:`_block_mask` -> (B, Q, D)."""
+    b, n, _ = c_q.shape
+    q_nope, q_rope = _head_queries(cfg, lp, start, c_q, rope)
     q_rows = mla.absorb_query(
-        cfg, lp, q[..., :nope].reshape(b * n, cfg.num_heads, nope),
+        cfg, lp, q_nope.reshape(b * n, cfg.num_heads, -1),
         q_rope.reshape(b * n, cfg.num_heads, -1))
     return _attend(cfg, lp, q_rows.reshape(b, n, cfg.num_heads, -1), rows,
                    seen)
+
+
+#: heads whose K and V :func:`_attend_expanded` rebuilds at a time: 16 heads'
+#: (S, 192 + 128) are 168 MB at 16384 positions where all 128 would be 1.3 GB
+EXPANDED_HEADS = 16
+
+
+def _attend_expanded(cfg: ModelConfig, lp: dict, start: int, c_q, rope, rows,
+                     seen):
+    """A BODY's query rows EXPANDED against every row handed over, in the
+    masked kernel: c_q (B, Q, q_lora_rank), rope the rows' own table rows,
+    rows (B, C, kv_row_lanes), seen (B or 1, Q, C) -> (B, Q, D).
+    :data:`EXPANDED_HEADS` heads at a time (a ``lax.map``: one group's
+    queries, keys and values live at once): their queries made from c_q,
+    their keys and values rebuilt from the rows (``mla.expand``), a head a
+    group of the kernel, one call over the body's rows."""
+    b, n, _ = c_q.shape
+    h = cfg.num_heads
+    hg = math.gcd(h, EXPANDED_HEADS)
+    seen = seen.astype(jnp.int8)        # once, not once a group of heads
+
+    def group(first):
+        q = jnp.concatenate(_head_queries(cfg, lp, start, c_q, rope,
+                                          (first, hg)), axis=-1)
+        k, v = mla.expand(cfg, lp, rows, (first, hg))
+        out = flash_attention.masked_attention(
+            _by_group(q)[:, None], _by_group(k), _by_group(v), seen, start,
+            scale=cfg.head_dim ** -0.5)
+        return out.reshape(b, hg, n, -1)
+
+    outs = jax.lax.map(group, jnp.arange(0, h, hg))
+    # (groups, B, hg, Q, vd) -> (B, Q, H vd)
+    ctx = jnp.transpose(outs, (1, 3, 0, 2, 4)).reshape(b, n, -1)
+    return ctx @ lp["wo"]
+
+
+def _joined(parts: list):
+    """Blocks of rows (B, n_i, ...) as one (B, sum n_i, ...)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 @jax.named_scope("attn.sparse_latent.prefill")
@@ -139,7 +207,9 @@ def attention_full(cfg: ModelConfig, lp: dict, x, rope):
     of them can see, the rows left over as a block of their own; a body
     whose rows all lie inside the first ``index_topk`` positions selects
     nothing, and a query that sees no more than ``index_topk`` selects them
-    all."""
+    all. On the XLA path a block makes its mask and attends ABSORBED; where
+    the masked kernel runs, the blocks make their masks alone and the body
+    attends EXPANDED under them in one go."""
     b, s, _ = x.shape
     cos, sin = rope
     c_q = mla.query_latent(cfg, lp, x)
@@ -149,6 +219,7 @@ def attention_full(cfg: ModelConfig, lp: dict, x, rope):
         # (every position's index key; a block makes its own queries)
         ik = index_key(cfg, lp, x, lambda t: apply_rotary(
             t, cos, sin, cfg.index_rope_lanes))
+    kernel = sparse_prefill_path(cfg, x.dtype) == MASKED_KERNEL
     body = QBLOCK * BLOCKS_PER_BODY
     outs = []
     for start in range(0, s, body):
@@ -161,19 +232,31 @@ def attention_full(cfg: ModelConfig, lp: dict, x, rope):
                    for a in (c_q, x)]
             table = tuple(jax.lax.dynamic_slice_in_dim(t, at, n)
                           for t in rope)
-            return _attend_block(cfg, lp, at, *cut, table, rows[:, :stop],
-                                 ik[:, :stop], select)
+            seen = _block_mask(cfg, lp, at, *cut, table, ik[:, :stop],
+                               select)
+            if kernel:
+                return seen
+            return _attend_block(cfg, lp, at, cut[0], table, rows[:, :stop],
+                                 seen)
 
+        parts = []
         if whole > QUERY_ROWS:
             firsts = start + QUERY_ROWS * jnp.arange(whole // QUERY_ROWS)
             out = jax.lax.map(lambda at: block(at, QUERY_ROWS), firsts)
-            outs.append(jnp.moveaxis(out, 0, 1).reshape(b, whole, -1))
+            parts.append(jnp.moveaxis(out, 0, 1).reshape(
+                out.shape[1], whole, -1))
         elif whole:
-            outs.append(block(start, QUERY_ROWS))
+            parts.append(block(start, QUERY_ROWS))
         if start + whole < stop:
-            outs.append(block(start + whole, stop - start - whole))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    return out, rows, ik
+            parts.append(block(start + whole, stop - start - whole))
+        if kernel:
+            outs.append(_attend_expanded(
+                cfg, lp, start, c_q[:, start:stop],
+                (cos[start:stop], sin[start:stop]), rows[:, :stop],
+                _joined(parts)))
+        else:
+            outs.extend(parts)
+    return _joined(outs), rows, ik
 
 
 @jax.named_scope("attn.sparse_latent")

@@ -215,8 +215,9 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
                            # both row writes, the indexer, the selection,
                            # the absorbed read of the chosen rows, W_kvb's V
                            # half and W_o
-    "attn.sparse_latent.prefill",  # ... over a whole prompt, absorbed, by
-                                   # blocks of query rows
+    "attn.sparse_latent.prefill",  # ... over a whole prompt, by blocks of
+                                   # query rows (absorbed; on the masked
+                                   # kernel expanded, a body at a time)
     "mlp",                # a dense feed-forward; in a stack walked by layer
                           # kinds, a leading dense layer's and its post-norm,
                           # or every sublayer's dense SwiGLU (longcat_flash)

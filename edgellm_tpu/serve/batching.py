@@ -1763,7 +1763,9 @@ class ContinuousBatcher:
     def _sparse_report(self, stats: dict) -> dict:
         """What a stack of sparse-attention layers adds to ``report()``: the
         reads its decode is built with (of the chosen K/V rows, and of the
-        index keys), three additive counters of rows a sparse layer, counted
+        index keys) and the attend of its prefill's blocks
+        (``sparse_attn.sparse_prefill_path`` of the dtype a prefill computes
+        in), three additive counters of rows a sparse layer, counted
         on the host from the riders' lengths: live, attended (``min(length,
         index_topk)`` a rider) and scored by the indexer; and, as
         ``attend_pages_walked`` / ``_in_runs`` are of the K/V walk, the pages
@@ -1771,8 +1773,13 @@ class ContinuousBatcher:
         a run (0 and 0 on the page gather)."""
         if self.sparse_read is None:
             return {}
+        from ..models.sparse_attn import sparse_prefill_path  # (down here:
+        # a line added to the imports would move the kernels' call sites)
         return {"sparse_read": self.sparse_read,
                 "index_read": self.index_read,
+                "sparse_prefill": sparse_prefill_path(
+                    self.cfg, self.bcfg.compute_dtype
+                    or self.params["embed"].dtype),
                 **{k: int(stats[k]) for k in (
                     "sparse_rows_live", "sparse_rows_attended",
                     "index_rows_scored", "index_pages_walked",

@@ -920,12 +920,44 @@ def test_keye_step_selects_under_scopes_and_keeps_both_leaves_in_place(
     assert not under & {"attn.decode", "moe.shared", "mlp"}
 
 
-def test_keye_prefill_of_a_whole_prompt_builds_no_square_tensor(topo):
+@pytest.fixture(params=["kernel", "xla-blocks"])
+def attend(request, monkeypatch):
+    """The attend a sparse prefill's blocks are compiled on
+    (``sparse_attn.sparse_prefill_path``). ``kernel``: what the program
+    picks on a TPU, ``flash_attention.masked_attention``; ``xla-blocks``:
+    what this process's backend, the CPU, picks, the kernel's oracle."""
+    from edgellm_tpu.models import sparse_attn
+
+    if request.param == "kernel":
+        monkeypatch.setattr(sparse_attn, "_on_tpu", lambda: True)
+    jax.clear_caches()      # the prefill's jit keeps its trace by arguments
+    yield request.param
+    jax.clear_caches()
+
+
+def _scores_of_a_block(hlo: str, s: int, rows: int) -> list:
+    """float32 tensors of ``rows`` (a block's heads x query rows) by S keys
+    or more: a block's attention scores or probabilities where they pass
+    through HBM (the index dots' (index heads x query rows, S) are fewer
+    rows and stay)."""
+    found = []
+    for _, _, shape, _ in _instructions(hlo):
+        dims = [int(d) for d in re.findall(
+            r"\d+", shape.split("{")[0].split("[")[-1])]
+        if shape.startswith("f32") and dims and dims[-1] >= s \
+                and int(np.prod(dims[:-1])) >= rows:
+            found.append(shape)
+    return found
+
+
+def test_keye_prefill_of_a_whole_prompt_builds_no_square_tensor(topo,
+                                                                attend):
     """The 16384-token prefill of the cell, one layer: 32 blocks of 512
     query rows, each with its index dot, its selection (no sort: a k-th
     value by counting passes) and its masked softmax. No (S, S) tensor
     exists, and what the compiler holds at once stays under 2.5 GB beside
-    the 11.4 GB the cell keeps."""
+    the 11.4 GB the cell keeps; on the kernel no (32 heads, 512, S) float32
+    scores exist either, and it holds under 1.5 GB."""
     from edgellm_tpu.serve import decode
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -945,6 +977,13 @@ def test_keye_prefill_of_a_whole_prompt_builds_no_square_tensor(topo):
     mem = prefill.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
     assert "attn.sparse.prefill" in hlo and "attn.sparse.select" in hlo
+    scores = _scores_of_a_block(hlo, s, cfg.num_heads * 512)
+    if attend == "kernel":
+        assert not scores, scores[:3]
+        assert "masked_attention" in hlo
+        assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+    else:
+        assert scores and "masked_attention" not in hlo
 
 
 # the deepseek cell (benchmark/configs/deepseek-v3.2-exp-ep16.json): one
@@ -1052,14 +1091,39 @@ def test_deepseek_step_walks_once_a_layer_and_keeps_both_leaves_in_place(
     assert not under & {"attn.decode", "attn.sparse", "attn.latent"}
 
 
-def test_deepseek_prefill_of_a_whole_prompt_rebuilds_no_key_per_head(topo):
+def _per_head(hlo: str, s: int) -> list:
+    """(heads, shape) of every bfloat16 tensor of (S, heads, lanes) or
+    (heads, S, lanes), dimensions of 1 aside, of ten lanes or more and fewer
+    than a thousand: keys or values of every position, a head apart (float32
+    are a block's scores and index dots, keys first; the rows of the stack's
+    two layers, (2, S, lanes), are no heads)."""
+    found = []
+    for _, _, shape, _ in _instructions(hlo):
+        dims = [int(d) for d in re.findall(
+            r"\d+", shape.split("{")[0].split("[")[-1]) if int(d) != 1]
+        if "bf16[" not in shape[:6] or len(dims) != 3 \
+                or s not in dims[:2] or not 10 <= dims[2] < 1000:
+            continue
+        heads = dims[1] if dims[0] == s else dims[0]
+        if heads > 2:
+            found.append((heads, shape))
+    return found
+
+
+def test_deepseek_prefill_of_a_whole_prompt_rebuilds_no_key_per_head(topo,
+                                                                     attend):
     """The 16384-token prefill of the cell, a dense layer and an expert
-    layer: blocks of 64 query rows, each with its own queries made from the q
-    latent, its index dot, its selection (no sort: a k-th value by counting
-    passes) and its absorbed attend. No (S, S) tensor exists, no query, key
-    or value of (S, 128 heads, lanes) either, the feed-forwards go 2048
-    tokens at a time, and what the compiler holds at once stays under 3 GB
-    beside the 11.8 GB the cell keeps."""
+    layer: blocks of 64 query rows, each with its own index dot and its
+    selection (no sort: a k-th value by counting passes). No (S, S) tensor
+    exists, no query, key or value of (S, 128 heads, lanes) either, the
+    feed-forwards go 2048 tokens at a time, and what the compiler holds at
+    once stays under 3 GB beside the 11.8 GB the cell keeps. On the XLA
+    blocks a block attends ABSORBED, its queries made from the q latent: no
+    key or value a head exists at all, and a block's (128 heads, 64, S)
+    float32 scores do. On the kernel a body attends EXPANDED and no such
+    scores exist: the keys and values of every position are rebuilt
+    ``sparse_mla.EXPANDED_HEADS`` heads at a time and never for more."""
+    from edgellm_tpu.models import sparse_mla
     from edgellm_tpu.serve import decode
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -1074,10 +1138,7 @@ def test_deepseek_prefill_of_a_whole_prompt_rebuilds_no_key_per_head(topo):
               if sum(int(d) >= s for d in re.findall(
                   r"\d+", shape.split("{")[0].split("[")[-1])) >= 2]
     assert not square, square[:3]
-    per_head = [shape for _, _, shape, _ in _instructions(hlo)
-                if re.search(rf"\[(1,)?{s},128,\d\d+\]|\[(1,)?128,{s},\d\d+\]",
-                             shape.split("{")[0])]
-    assert not per_head, per_head[:3]
+    per_head = _per_head(hlo, s)
     assert not [name for op, name, _, line in _instructions(hlo)
                 if op == "sort" and "attn.sparse" in line]
     # the gathered token rows of a routed layer: 2048 tokens x 8 at a time
@@ -1087,6 +1148,15 @@ def test_deepseek_prefill_of_a_whole_prompt_rebuilds_no_key_per_head(topo):
     assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
     assert "attn.sparse_latent.prefill" in hlo
     assert "attn.sparse.select" in hlo and "attn.latent.expand" not in hlo
+    scores = _scores_of_a_block(hlo, s, cfg.num_heads * 64)
+    if attend == "kernel":
+        assert not scores, scores[:3]
+        assert "masked_attention" in hlo
+        assert per_head and max(h for h, _ in per_head) == \
+            sparse_mla.EXPANDED_HEADS < cfg.num_heads, per_head[:5]
+    else:
+        assert scores and "masked_attention" not in hlo
+        assert not per_head, per_head[:3]
 
 
 # the six families hybrid.py walks, at toy sizes whose expert layers are

@@ -489,6 +489,7 @@ def test_prefill_then_paged_decode_through_the_batcher_matches_the_full_forward(
     assert want0[toks[0]] >= want0.max() - TOL * np.abs(want0).max()
     rep = b.report()
     assert rep["sparse_read"] == sparse_attn.ROW_GATHER
+    assert rep["sparse_prefill"] == sparse_attn.XLA_BLOCKS
     # this backend is no TPU: every read is a gather
     assert rep["index_read"] == rep["decode_read"] == paged_kv.PAGE_GATHER
     assert rep["index_pages_walked"] == rep["index_pages_in_runs"] == 0
@@ -633,6 +634,108 @@ def test_the_read_is_read_off_the_pool_and_the_span(monkeypatch):
     assert paged_kv.walk_geometry(big, 1280) == (64, 4)
     assert paged_kv.index_walk_geometry(big, 1280) == (128, 8)
     assert paged_kv.pool_run_pages(big, 1280) == 8
+
+
+_MASKED = flash_attention.masked_attention
+
+
+def _a_tpus_prefill(monkeypatch):
+    """The prefill's attend chosen as a TPU would choose it, the kernel
+    interpreted (traced into a body's ``lax.map``: the caller jits the whole
+    prefill and waits for what it hands back)."""
+    import functools
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(sparse_attn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash_attention, "masked_attention", functools.partial(
+        _MASKED, interpret=pltpu.InterpretParams()))
+
+
+#: the toy's heads widened to half a lane tile (56 + 8 key lanes, 64 value
+#: lanes): what the masked kernel takes, as the published 192 and 128 are
+WIDE = dataclasses.replace(CFG, explicit_head_dim=64, v_head_dim=64)
+
+
+def test_the_prefill_attend_is_read_off_the_backend_and_the_lanes(
+        monkeypatch):
+    path = sparse_attn.sparse_prefill_path
+    assert path(WIDE, jnp.float32) == path(
+        DEEPSEEK_V3_2_EXP, jnp.bfloat16) == sparse_attn.XLA_BLOCKS
+    monkeypatch.setattr(sparse_attn, "_on_tpu", lambda: True)  # (no TPU here)
+    # a head's 64 + 64 (192 + 128) key and value lanes, as ``mla.expand``
+    # rebuilds them: the cached row's lanes are not what the kernel reads
+    assert path(WIDE, jnp.float32) == path(
+        DEEPSEEK_V3_2_EXP, jnp.bfloat16) == sparse_attn.MASKED_KERNEL
+    # heads of 24 + 16 lanes are no half of a lane tile; float16 and int8
+    # are no operands the kernel was built for
+    assert path(CFG, jnp.float32) == path(WIDE, jnp.float16) == \
+        path(WIDE, jnp.int8) == sparse_attn.XLA_BLOCKS
+
+
+@pytest.mark.parametrize("heads", [2, 4])
+def test_the_prefill_on_the_masked_kernel_equals_the_xla_blocks(
+        monkeypatch, heads):
+    """``attention_full`` built on the masked kernel (the choice forced as a
+    TPU makes it, the kernel interpreted: EXPANDED, a body at a time under
+    the blocks' masks, ``heads`` of the four heads' keys and values rebuilt
+    at once) against the XLA blocks (ABSORBED, a block at a time), two
+    sequences of 150 positions past ``index_topk`` in bodies of 64 rows and
+    blocks of 4 with two rows left over: the rows and the index keys a cache
+    is filled from bit for bit (nothing of the kernel reaches them), the
+    outputs to a float32 running softmax's reordering and the absorption's
+    reassociation; then the whole forward on the kernel against the
+    reference."""
+    cfg = WIDE
+    monkeypatch.setattr(sparse_mla, "QBLOCK", 8)
+    monkeypatch.setattr(sparse_mla, "QUERY_ROWS", 4)
+    monkeypatch.setattr(sparse_mla, "EXPANDED_HEADS", heads)
+    params = make_params(cfg)
+    lp = hybrid._row(params["sparse_latent"], 1)
+    x = jax.random.normal(jax.random.key(5), (2, 150, cfg.hidden_size))
+    rope = hybrid._rope_tables(cfg, 150)["sparse_latent_attention"]
+    full = jax.jit(lambda x: sparse_mla.attention_full(cfg, lp, x, rope))
+    ids = _ids(150, 3)
+    with jax.default_matmul_precision("highest"):
+        want = full(x)
+        _a_tpus_prefill(monkeypatch)
+        jax.clear_caches()
+        assert sparse_attn.sparse_prefill_path(cfg, x.dtype) == \
+            sparse_attn.MASKED_KERNEL
+        on_kernel = jax.jit(
+            lambda x: sparse_mla.attention_full(cfg, lp, x, rope))
+        assert "masked_attention" in str(jax.make_jaxpr(on_kernel)(x))
+        got = jax.block_until_ready(on_kernel(x))
+        logits = jax.block_until_ready(_forward(cfg, params, ids))
+    jax.clear_caches()
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert rel_err(got[0], want[0]) < 2e-6
+    assert rel_err(logits, ref_logits(cfg, params, _pad(ids))[:150]) < TOL
+
+
+def test_a_head_slice_of_the_latent_projections_is_those_heads_alone():
+    """``mla.head_queries`` and ``mla.expand`` of heads (first, count), the
+    first traced as a prefill's ``lax.map`` over groups hands it over,
+    against the same heads cut from the whole layer's, bit for bit."""
+    cfg = WIDE
+    lp = hybrid._row(make_params(cfg)["sparse_latent"], 1)
+    c_q = jax.random.normal(jax.random.key(1), (2, 9, cfg.q_lora_rank))
+    rows = jax.random.normal(jax.random.key(2), (2, 9, cfg.kv_row_lanes))
+    scale = jnp.full((2, 9), 0.7, jnp.float32)
+    q = mla.head_queries(cfg, lp, c_q, scale)
+    k, v = mla.expand(cfg, lp, rows)
+    assert q.shape[2] == k.shape[2] == v.shape[2] == cfg.num_heads
+
+    @jax.jit
+    def some(first):
+        return (mla.head_queries(cfg, lp, c_q, scale, (first, 2)),
+                *mla.expand(cfg, lp, rows, (first, 2)))
+
+    for first in (0, 2):
+        for part, whole in zip(some(first), (q, k, v)):
+            np.testing.assert_array_equal(
+                np.asarray(part), np.asarray(whole[:, :, first:first + 2]))
 
 
 def test_the_step_on_the_walks_equals_the_step_on_the_gathers(monkeypatch):

@@ -162,3 +162,119 @@ def test_blocked_plan_is_auto_resolved(rng):
     got = causal_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                            interpret=True)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+# ---------------------------------------------------------------------------
+# The masked prefill kernel (flash_attention.masked_attention) against the
+# XLA attend it replaces: float32 scores over every key, a `where`, a two-pass
+# softmax, the probabilities cast to the operands' dtype, a second einsum.
+# ---------------------------------------------------------------------------
+
+#: |kernel - oracle| <= tol x max |oracle|, by dtype: a running softmax orders
+#: its float32 sums differently (a few roundings of 2^-24 a key block), and a
+#: bfloat16 output is rounded once more on either side (half an ulp of 2^-8
+#: each, and a probability rounded to bfloat16 before the second dot may fall
+#: on the other side of a rounding boundary)
+MASKED_TOL = {jnp.float32: 2e-6, jnp.bfloat16: 1.2e-2}
+
+#: (G, rep, Q, C, dk, dv): the layouts the sparse families send (a latent
+#: layer's heads expanded, a head a group; sparse_attn's KV groups), and one
+#: group of many heads
+MASKED_LAYOUTS = {
+    "multi_query": (1, 8, 32, 640, 256, 128),
+    "expanded": (3, 1, 64, 640, 192, 128),
+    "gqa": (4, 8, 32, 640, 128, 128),
+}
+
+
+def _xla_attend(q, k, v, mask, scale):
+    groups = q.shape[0] // mask.shape[0]
+    s = jnp.einsum("grqd,gcd->grqc", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(jnp.repeat(mask, groups, axis=0)[:, None], s,
+                  jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("grqc,gcd->grqd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _interpreted(*args, **kw):
+    from jax.experimental.pallas import tpu as pltpu
+    return jax.block_until_ready(flash_attention.masked_attention(
+        *args, **kw, interpret=pltpu.InterpretParams()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["causal", "ties", "traced_start",
+                                  "skipped_blocks", "leftover_rows"])
+@pytest.mark.parametrize("layout", list(MASKED_LAYOUTS))
+def test_masked_attention_matches_the_xla_blocks(rng, monkeypatch, layout,
+                                                 case, dtype):
+    from edgellm_tpu.models import sparse_attn
+
+    g, rep, n, c, dk, dv = MASKED_LAYOUTS[layout]
+    start, topk = 500, 48
+    if case == "leftover_rows":     # rows that fill no mask tile, keys no block
+        n, c, start = 20, 300, 270
+    if case == "skipped_blocks":
+        # a budget that leaves row tiles of 32 of the block's 64 rows, three
+        # key blocks of 512: the third lies past every row, the second past
+        # the rows of a head's first tile (positions 480-511) alone
+        n, c, start = 64, 1536, 480
+        monkeypatch.setattr(flash_attention, "MASKED_VMEM_BYTES", 1 << 19)
+        monkeypatch.setattr(flash_attention, "MASKED_KEY_BLOCK", 512)
+        assert flash_attention.masked_attention_plan(
+            rep, n, c, dk, dv, jnp.dtype(dtype).itemsize) == (32, 512)
+    q = jnp.asarray(rng.normal(size=(g, rep, n, dk)), dtype)
+    k = jnp.asarray(rng.normal(size=(g, c, dk)), dtype)
+    v = jnp.asarray(rng.normal(size=(g, c, dv)), dtype)
+    at = start + jnp.arange(n)
+    visible = (jnp.arange(c)[None, :] <= at[:, None])[None]       # (1, Q, C)
+    mask = visible
+    if case != "causal":
+        # index scores in eighths: a row's k-th largest visible score is
+        # shared by many positions, of which the earliest fill the selection
+        scores = jnp.asarray(rng.integers(0, 8, (2 if g % 2 == 0 else 1, n,
+                                                 c)) / 8.0, jnp.float32)
+        mask = sparse_attn.selection_mask(scores, visible, topk)
+        assert (np.asarray(mask.sum(-1)) == np.minimum(
+            np.asarray(at) + 1, topk)).all()
+    scale = dk ** -0.5
+    want = _xla_attend(q, k, v, mask, scale)
+    if case == "skipped_blocks":
+        # a skipped block is neither fetched nor multiplied: what it holds
+        # (NaN here) cannot reach the output, where a block that was
+        # multiplied under the mask would carry 0 x NaN into the sums
+        last = start + n - 1
+        poison = jnp.arange(c)[None, :, None] >= -(-(last + 1) // 512) * 512
+        k, v = jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v)
+    if case == "traced_start":
+        # as a prefill's body hands it over: under ``lax.map``, a tracer
+        firsts = jnp.asarray([start, start - 64], jnp.int32)
+        masks = jnp.stack([mask, jnp.roll(mask, -64, axis=-1)
+                           & (jnp.arange(c)[None, :] <= at[:, None] - 64)])
+        got = jax.block_until_ready(jax.jit(lambda *a: jax.lax.map(
+            lambda xs: _interpreted(q, k, v, xs[1], xs[0], scale=scale),
+            a))(firsts, masks))
+        want = jnp.stack([want, _xla_attend(q, k, v, masks[1], scale)])
+    else:
+        got = _interpreted(q, k, v, mask, jnp.int32(start), scale=scale)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    want = np.asarray(want, np.float32)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert np.max(np.abs(np.asarray(got, np.float32) - want)) <= \
+        MASKED_TOL[dtype] * np.max(np.abs(want))
+
+
+def test_masked_attention_plan_reads_the_tiles_off_the_shapes():
+    """The key block is 2048 keys, a short key set one block of whole lane
+    tiles; the row tile is whole copies of the block's rows, or whole mask
+    tiles that divide them, the largest whose step fits the budget."""
+    plan = flash_attention.masked_attention_plan
+    # keye's block: 8 heads a group x 512 rows of 128 lanes
+    assert plan(8, 512, 16384, 128, 128, 2) == (512, 2048)
+    # deepseek's body, one head a group, 4096 rows: a divisor of the rows in
+    # whole mask tiles
+    assert plan(1, 4096, 16384, 192, 128, 2) == (512, 2048)
+    # whole copies of a short block's rows, its keys in one block
+    assert plan(4, 32, 300, 128, 128, 4) == (128, 384)

@@ -410,6 +410,7 @@ def test_prefill_then_paged_decode_through_the_batcher_matches_the_full_forward(
     assert want0[toks[0]] >= want0.max() - TOL * np.abs(want0).max()
     rep = b.report()
     assert rep["sparse_read"] == sparse_attn.ROW_GATHER
+    assert rep["sparse_prefill"] == sparse_attn.XLA_BLOCKS
     # this backend is no TPU: the index keys come by the page gather
     assert rep["index_read"] == paged_kv.PAGE_GATHER
     assert rep["index_pages_walked"] == rep["index_pages_in_runs"] == 0
@@ -790,6 +791,82 @@ def test_the_batcher_on_a_tpus_reads_serves_the_contiguous_references_tokens(
     assert 0 < rep["index_pages_in_runs"] < rep["index_pages_walked"]
     assert rep["index_pages_in_runs"] % 8 == 0
     assert oracle["index_pages_walked"] == oracle["index_pages_in_runs"] == 0
+
+
+_MASKED = flash_attention.masked_attention
+
+
+def _a_tpus_prefill(monkeypatch):
+    """The prefill's attend chosen as a TPU would choose it, the kernel
+    interpreted (traced into a body's ``lax.map``: the caller jits the whole
+    prefill and waits for what it hands back)."""
+    import functools
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(sparse_attn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash_attention, "masked_attention", functools.partial(
+        _MASKED, interpret=pltpu.InterpretParams()))
+
+
+def test_the_prefill_attend_is_read_off_the_backend_and_the_lanes(
+        monkeypatch):
+    path = sparse_attn.sparse_prefill_path
+    assert path(WIDE, jnp.float32) == path(
+        KEYE_VL_2_0_30B_A3B, jnp.bfloat16) == sparse_attn.XLA_BLOCKS
+    monkeypatch.setattr(sparse_attn, "_on_tpu", lambda: True)
+    assert path(WIDE, jnp.float32) == path(
+        KEYE_VL_2_0_30B_A3B, jnp.bfloat16) == sparse_attn.MASKED_KERNEL
+    # heads of 16 lanes are no half of a lane tile; float16 is no operand
+    # the kernel was built for
+    assert path(CFG, jnp.float32) == path(WIDE, jnp.float16) == \
+        sparse_attn.XLA_BLOCKS
+
+
+def test_the_prefill_on_the_masked_kernel_equals_the_xla_blocks(monkeypatch):
+    """``attention_full`` built on the masked kernel (the choice forced as a
+    TPU makes it, the kernel interpreted) against the XLA blocks, two
+    sequences of 150 positions past ``index_topk`` in blocks of 16 rows
+    (a body of 128 and 22 rows of a second, six of them left over): K, V
+    and the index keys a cache is filled from bit for bit, the outputs to a
+    float32 running softmax's reordering; then the whole forward on the
+    kernel against the reference."""
+    cfg, params = WIDE, make_params(WIDE)
+    monkeypatch.setattr(sparse_attn, "QBLOCK", 16)
+    lp = hybrid._row(params["sparse"], 1)
+    x = jax.random.normal(jax.random.key(5), (2, 150, cfg.hidden_size))
+    tables = hybrid._rope_tables(cfg, 150)
+
+    def full(x):
+        return sparse_attn.attention_full(
+            cfg, lp, x, tables["sparse_attention"], tables["sparse_index"])
+
+    ids = _ids(150, 3)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(full)(x)
+        _a_tpus_prefill(monkeypatch)
+        jax.clear_caches()
+        assert sparse_attn.sparse_prefill_path(cfg, x.dtype) == \
+            sparse_attn.MASKED_KERNEL
+        got = jax.block_until_ready(jax.jit(full)(x))
+        logits = jax.block_until_ready(_forward(cfg, params, ids))
+    jax.clear_caches()
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert rel_err(got[0], want[0]) < 2e-6
+    assert rel_err(logits, ref_logits(cfg, params, _pad(ids))[:150]) < TOL
+
+
+def test_only_a_sparse_stack_reports_a_sparse_prefill():
+    """``report()["sparse_prefill"]`` stands beside ``sparse_read`` for a
+    stack of sparse layers (the XLA blocks on this backend) and for no
+    other."""
+    b = ContinuousBatcher(CFG, make_params(CFG), BCFG)
+    assert b.report()["sparse_prefill"] == sparse_attn.XLA_BLOCKS
+    plain = tiny_config("qwen2", num_layers=1)
+    rep = ContinuousBatcher(plain, transformer.init_params(
+        plain, jax.random.key(0)), BCFG).report()
+    assert "sparse_prefill" not in rep and "sparse_read" not in rep
 
 
 def test_the_mask_is_the_row_ids_set():
