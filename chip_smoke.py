@@ -1154,6 +1154,86 @@ def latent_selection_on_the_chip(depths=(8192, 12288, 16384, 20479)) -> dict:
                               0.97)
 
 
+def window_latent_phase(*, prompt_len: int = 300, n_new: int = 120,
+                        evict_after: int = 70) -> dict:
+    """A tiny ``dots3_note`` stream (a full sparse latent layer with a dense
+    feed-forward, a second one and three WINDOW latent layers at sizes of
+    their own: 2 heads of 96 + 32 key lanes over rows of [c 224 | k_rope 32]
+    = 256 lanes, a band of 40 keys, a ring of 4 pages a slot turned six
+    times; both rank factors, a gate lane a head on both kinds; float32)
+    through the same admit / step / evict / readmit: ALL THREE leaves (the
+    full layers' latent rows and index keys, the ring's latent rows) leave
+    the device and come back, and ``forward`` over prompt + tokens puts each
+    served token first, on the reads a TPU's pools take: the index walk, the
+    masked walk of the full layers' rows, and the RING WALK of latent rows
+    (a row key and value both, masked by position inside the kernel)."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from edgellm_tpu.models import sparse_attn
+    from edgellm_tpu.models.configs import (LatentGeometry,
+                                            tiny_dots3_note_config)
+    from edgellm_tpu.models.paged_kv import INDEX_WALK, PAGE_WALK
+    from edgellm_tpu.serve.batching import BatchingConfig
+
+    def wider(params):
+        def scale(path, a):
+            name = path[-1].key
+            if name in ("router", "router_bias"):
+                return a * 15.0
+            if name == "wg":
+                return a * 30.0
+            return a * (4.5 if name == "wkv_b" else 3.0) \
+                if name.startswith("w") or name.startswith("shared") else a
+        return jax.tree_util.tree_map_with_path(scale, params)
+
+    # the full kind as the sparse_latent phase's (rows and index keys of one
+    # lane tile, heads of 160 + 32 and 128); the window kind's rows two lane
+    # tiles, its heads 96 + 32 and 64
+    cfg = dataclasses.replace(tiny_dots3_note_config(
+        hidden_size=256, index_topk=64, sliding_window=40,
+        window_latent=LatentGeometry(
+            num_heads=2, q_lora_rank=64, kv_lora_rank=224,
+            qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=64,
+            rope_theta=500.0)),
+        explicit_head_dim=192, qk_rope_head_dim=32, kv_lora_rank=96,
+        v_head_dim=128, q_lora_rank=64, expert_width=128, shared_width=128,
+        index_heads=4, index_head_dim=128)
+    bcfg = BatchingConfig(page_size=16, num_pages=97, max_slots=3,
+                          pages_per_slot=32)
+    assert (cfg.kv_row_lanes, cfg.window_row_lanes,
+            cfg.window_pages(16)) == (128, 256, 4)
+    report, gap = _evict_readmit(cfg, bcfg, prompt_len, n_new, evict_after,
+                                 wider)
+    assert report["sparse_read"] == sparse_attn.MASKED_WALK, report
+    assert report["decode_read"] == PAGE_WALK
+    assert report["window_read"] == PAGE_WALK, report
+    assert report["index_read"] == INDEX_WALK, report
+    assert 0 < report["window_pages_walked"] <= \
+        report["window_pages_spanned"]
+    assert report["window_rows_capacity"] == 3 * 4 * 16
+    kernels = _prefill_kernels(cfg, bcfg, prompt_len, wider)
+    # (the full layers' blocks take the masked kernel; the band's are XLA)
+    assert report["sparse_prefill"] == sparse_attn.MASKED_KERNEL, report
+    assert "masked_attention" in kernels, kernels
+    assert 0 < report["sparse_rows_attended"] < report["sparse_rows_live"]
+    assert len(np.unique(report["served"])) > n_new // 6, report["served"]
+    return {"tokens": int(n_new), "distinct_tokens":
+            int(len(np.unique(report["served"]))),
+            "evicted": report["evicted"],
+            "sparse_read": report["sparse_read"],
+            "window_read": report["window_read"],
+            "sparse_prefill": report["sparse_prefill"],
+            "prefill_kernels": kernels,
+            "window_pages_walked": report["window_pages_walked"],
+            "window_pages_spanned": report["window_pages_spanned"],
+            "kv_row_bytes": report["kv_row_bytes"],
+            "launch_ahead_share": report["launch_ahead_share"],
+            "gap_max_over_logit_max": gap}
+
+
 def smoke(report: dict, save) -> dict:
     """Every phase in order, at full width. ``save()`` persists ``report``
     after each phase so a failed run leaves what it learned."""
@@ -1192,6 +1272,7 @@ def smoke(report: dict, save) -> dict:
     phase("shortconv", shortconv_phase)
     phase("sparse", sparse_phase)
     phase("sparse_latent", sparse_latent_phase)
+    phase("window_latent", window_latent_phase)
     if split is not None:
         phase("split", lambda: split_phase(cfg, cfg.vocab_size))
     else:

@@ -445,6 +445,32 @@ def run_graph_checks() -> Tuple[List[Finding], List[str], List[str]]:
             lower_args=(dcfg, dparams, dpool, None, dcount,
                         ptab, plens, ptoks, pkeys, qsteps, qtemps, None))
 
+    # the ragged step of sparse latent layers beside WINDOW latent layers
+    # (dots3_note): the window step's executable with an IndexedLatentPool
+    # handed over whole and a ring group of latent rows: both leaves, the
+    # ring's leaf and the assignment counter, FOUR buffers, stay donated
+    from ..models.configs import tiny_dots3_note_config
+
+    ncfg = tiny_dots3_note_config(sliding_window=2 * PGS + 2)
+    nparams = transformer.init_params(ncfg, jax.random.key(0))
+    npool = paged_kv.init_pool(ncfg, NPG, PGS)
+    nring = paged_kv.init_pool(ncfg, MS * ncfg.window_pages(PGS) + 1, PGS,
+                               layers=ncfg.window_layers,
+                               lanes=ncfg.window_row_lanes)
+    ntab = jnp.zeros((MS, ncfg.window_pages(PGS)), jnp.int32)
+    ncount = jnp.zeros((ncfg.expert_layers, ncfg.local_experts), jnp.int32)
+    run_one("paged.decode_step_window_latent",
+            lambda p, rows, ik, win, ct, pt, wt, ln, t:
+                hybrid.paged_decode_step_hybrid(
+                    ncfg, p, paged_kv.IndexedLatentPool(rows, ik), None, ct,
+                    pt, ln, t, window=(win, wt)),
+            (nparams, npool.rows, npool.ik, nring.rows, ncount, ptab, ntab,
+             plens, ptoks),
+            ctx={"donate_min": 4},
+            lowerable=batching._batched_window_step_jit,
+            lower_args=(ncfg, nparams, npool, nring, ncount, ptab, ntab,
+                        plens, ptoks, pkeys, qsteps, qtemps, None))
+
     # the fp tier must be a NO-OP: a kv_codec="fp" batcher with live state
     # feeds the byte-identical ragged step graph the pre-quantization
     # batcher traces — the disabled-build jaxpr fingerprint half of the

@@ -21,6 +21,34 @@ LANE_TILE = 128
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentGeometry:
+    """The sizes of ONE kind of latent-attention layer, what ``models/mla.py``
+    is handed for the kind it serves (``ModelConfig.latent_geometry``): a
+    stack may hold two kinds of latent layer at different sizes. The names
+    are ``ModelConfig``'s flat fields', which stay the full kind's."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+
+    @property
+    def head_dim(self) -> int:
+        """A query / key head's lanes: what the scores are scaled by."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def kv_row_lanes(self) -> int:
+        """Lanes of the kind's ONE stored row a position, ``[c | k_rope]``
+        zero-padded to whole 128-lane tiles (``ModelConfig.kv_row_lanes``)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim)
+                 // LANE_TILE) * LANE_TILE
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for one causal LM.
 
@@ -104,8 +132,22 @@ class ModelConfig:
         experts in ``route_groups`` groups of which a token may choose
         within the ``route_groups_kept`` best, plus a shared expert; an
         untied head (DeepSeek-V3.2-Exp).
+      - ``"dots3_note"``: two kinds of latent layer in one stack.
+        ``"sparse_latent_attention"`` layers as deepseek_v32's (the flat
+        latent fields, plain RoPE by ``rope_theta``) beside
+        ``"sliding_latent_attention"`` layers: plain latent attention at
+        sizes OF THEIR OWN (``window_latent``: heads, ranks, head widths and
+        a theta that all differ), no indexer, banded over
+        ``sliding_window`` keys, whose cached latent rows live in a RING of
+        pages (the window group, ``window_row_lanes`` wide). Both kinds
+        multiply their latents by the rank factors (``rank_scales``, each at
+        its own ranks) and gate every head's output by ``sigmoid(x W_g)``
+        ahead of ``W_o`` (``head_gate``: one gate lane a head). A leading
+        dense layer, then ``"sigmoid"`` routing with a selection bias in one
+        group plus a shared expert; an untied head (dots3-note-prev's
+        language model).
 
-    The fields after ``rope_scaling`` exist for those eight families and
+    The fields after ``rope_scaling`` exist for those nine families and
     default to "absent", so the three one-block families hash and trace as
     before.
     """
@@ -131,14 +173,15 @@ class ModelConfig:
     #: ``rope_parameters`` by layer kind).
     rope_scaling: Optional[tuple] = None
     #: per-layer mixer kind, ``"mamba"``, ``"conv"``, ``"attention"``,
-    #: ``"sliding_attention"``, ``"latent_attention"``, ``"sparse_attention"``
-    #: or ``"sparse_latent_attention"``; empty = every layer is the family's
-    #: one block
+    #: ``"sliding_attention"``, ``"latent_attention"``, ``"sparse_attention"``,
+    #: ``"sparse_latent_attention"`` or ``"sliding_latent_attention"``; empty
+    #: = every layer is the family's one block
     layer_types: tuple = ()
     #: width of one attention head where the model states it; 0 = the
     #: derived ``hidden_size // num_heads``
     explicit_head_dim: int = 0
-    #: keys a ``"sliding_attention"`` layer attends, itself among them:
+    #: keys a ``"sliding_attention"`` (or ``"sliding_latent_attention"``)
+    #: layer attends, itself among them:
     #: position i sees j with ``i - sliding_window < j <= i``
     sliding_window: int = 0
     #: routed experts: ``num_experts`` is the ROUTER's width (the published
@@ -222,6 +265,13 @@ class ModelConfig:
     #: 1 and 1: every expert stands, and nothing of it is traced
     route_groups: int = 1
     route_groups_kept: int = 1
+    #: the sizes of the ``"sliding_latent_attention"`` layers, which are not
+    #: the flat latent fields' (those stay the full kind's); None: no such
+    #: layer
+    window_latent: Optional[LatentGeometry] = None
+    #: every latent layer holds an output gate ``wg`` (D, heads): a head's
+    #: attend output times ``sigmoid(x W_g)_h`` ahead of ``W_o``
+    head_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -235,9 +285,34 @@ class ModelConfig:
     @property
     def latent_layers(self) -> int:
         """Layers whose cached row is a latent (one row a position for all
-        heads), not per-head K and V."""
+        heads), not per-head K and V, IN THE POOL THAT GROWS with the
+        stream: what says the main page group's pool is a latent one. The
+        layers whose latent rows live in a ring: ``window_latent_layers``."""
         return sum(1 for t in self.layer_types
                    if t in ("latent_attention", "sparse_latent_attention"))
+
+    @property
+    def window_latent_layers(self) -> int:
+        """Layers whose cached row is a latent kept in a RING of pages (the
+        window group's): counted by ``window_layers`` too."""
+        return sum(1 for t in self.layer_types
+                   if t == "sliding_latent_attention")
+
+    def latent_geometry(self, kind: str) -> LatentGeometry:
+        """The sizes of the latent layers of ``kind``: what ``models/mla.py``
+        asks in place of the flat fields. The window kind's are
+        ``window_latent``; every other latent kind's the flat fields."""
+        if kind == "sliding_latent_attention":
+            return self.window_latent
+        return LatentGeometry(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta)
+
+    def rank_scale(self, rank: int) -> float:
+        """What a latent of ``rank`` lanes is multiplied by under
+        ``rank_scales``: ``sqrt(hidden_size / rank)``; else 1."""
+        return math.sqrt(self.hidden_size / rank) if self.rank_scales else 1.0
 
     @property
     def sparse_layers(self) -> int:
@@ -283,12 +358,23 @@ class ModelConfig:
         return self.num_kv_heads * self.head_dim
 
     @property
+    def window_row_lanes(self) -> int:
+        """Lanes of ONE stored row of the WINDOW group's pool where its
+        layers cache latent rows (0: they keep K/V rows, ``2 *
+        kv_row_lanes`` wide, or there is no window group): the window kind's
+        ``[c | k_rope]`` padded as ``kv_row_lanes`` pads the full kind's
+        (1088 -> 1152)."""
+        if not self.window_latent_layers:
+            return 0
+        return self.window_latent.kv_row_lanes
+
+    @property
     def is_hybrid(self) -> bool:
         """Walked by layer kinds (``models/hybrid.py``), with params held per
         kind and a routed expert layer after every mixer."""
         return self.family in ("granitemoehybrid", "mellum", "mistral4",
                                "afmoe", "longcat_flash", "lfm2_moe",
-                               "keye_vl2", "deepseek_v32")
+                               "keye_vl2", "deepseek_v32", "dots3_note")
 
     @property
     def expert_layers(self) -> int:
@@ -318,17 +404,13 @@ class ModelConfig:
 
     @property
     def q_rank_scale(self) -> float:
-        """What a latent layer's whole query is multiplied by."""
-        if not self.rank_scales:
-            return 1.0
-        return math.sqrt(self.hidden_size / self.q_lora_rank)
+        """What a (full) latent layer's whole query is multiplied by."""
+        return self.rank_scale(self.q_lora_rank)
 
     @property
     def kv_rank_scale(self) -> float:
-        """What a latent layer's normalised latent is multiplied by."""
-        if not self.rank_scales:
-            return 1.0
-        return math.sqrt(self.hidden_size / self.kv_lora_rank)
+        """What a (full) latent layer's normalised latent is multiplied by."""
+        return self.rank_scale(self.kv_lora_rank)
 
     @property
     def position_free(self) -> tuple:
@@ -355,8 +437,10 @@ class ModelConfig:
 
     @property
     def window_layers(self) -> int:
-        """Layers that keep a RING of K/V pages: the sliding ones."""
-        return sum(1 for t in self.layer_types if t == "sliding_attention")
+        """Layers that keep a RING of pages: the sliding ones, whether their
+        rows are K/V or a latent (``window_latent_layers``)."""
+        return sum(1 for t in self.layer_types
+                   if t in ("sliding_attention", "sliding_latent_attention"))
 
     def window_pages(self, page_size: int) -> int:
         """Pages a slot's ring holds in each window layer: the most that
@@ -418,7 +502,7 @@ class ModelConfig:
         if self.family not in ("gpt_neox", "qwen2", "llama",
                                "granitemoehybrid", "mellum", "mistral4",
                                "afmoe", "longcat_flash", "lfm2_moe",
-                               "keye_vl2", "deepseek_v32"):
+                               "keye_vl2", "deepseek_v32", "dots3_note"):
             raise ValueError(f"unknown family: {self.family}")
         if self.is_hybrid:
             self._check_hybrid()
@@ -429,13 +513,14 @@ class ModelConfig:
               or self.rank_scales or self.conv_window or self.index_topk
               or self.index_heads or self.index_head_dim
               or self.mrope_section or self.route_groups != 1
-              or self.route_groups_kept != 1):
+              or self.route_groups_kept != 1 or self.window_latent
+              or self.head_gate):
             raise ValueError(
                 f"layer_types / experts / mamba / head width / window / "
                 f"latent / dense-layer / routing / short-convolution / "
-                f"indexer fields belong to the granitemoehybrid, mellum, "
-                f"mistral4, afmoe, longcat_flash, lfm2_moe, keye_vl2 and "
-                f"deepseek_v32 families, not {self.family!r}")
+                f"indexer / head-gate fields belong to the granitemoehybrid, "
+                f"mellum, mistral4, afmoe, longcat_flash, lfm2_moe, keye_vl2, "
+                f"deepseek_v32 and dots3_note families, not {self.family!r}")
         if not self.explicit_head_dim and self.hidden_size % self.num_heads:
             raise ValueError("num_heads must evenly divide hidden_size")
         if self.num_heads % self.num_kv_heads:
@@ -449,7 +534,9 @@ class ModelConfig:
                  "longcat_flash": ("latent_attention",),
                  "lfm2_moe": ("conv", "attention"),
                  "keye_vl2": ("sparse_attention",),
-                 "deepseek_v32": ("sparse_latent_attention",)}[self.family]
+                 "deepseek_v32": ("sparse_latent_attention",),
+                 "dots3_note": ("sparse_latent_attention",
+                                "sliding_latent_attention")}[self.family]
         if len(self.layer_types) != self.num_layers * self.sublayers or any(
                 t not in kinds for t in self.layer_types):
             raise ValueError(
@@ -473,6 +560,19 @@ class ModelConfig:
         if self.window_layers and self.sliding_window < 1:
             raise ValueError("a sliding_attention layer needs sliding_window "
                              ">= 1")
+        w = self.window_latent
+        if bool(self.window_latent_layers) != (w is not None) or (
+                w is not None and (
+                    min(w.num_heads, w.q_lora_rank, w.kv_lora_rank,
+                        w.qk_nope_head_dim, w.v_head_dim) < 1
+                    or w.qk_rope_head_dim < 2 or w.qk_rope_head_dim % 2)):
+            raise ValueError(
+                "window_latent (heads, ranks, nope, an even rope, a value "
+                "width, all >= 1) belongs to sliding_latent_attention "
+                "layers, and those need it")
+        if self.head_gate and not (self.latent_layers
+                                   or self.window_latent_layers):
+            raise ValueError("head_gate belongs to latent layers")
         if bool(self.latent_layers) != bool(self.kv_lora_rank):
             raise ValueError("latent ranks belong to latent_attention layers, "
                              "and those need them")
@@ -891,6 +991,100 @@ DEEPSEEK_V3_2_EXP = ModelConfig(
 )
 
 
+# dots-studio/dots3-note-prev (288B-A17B, 2026-08) — config.json
+# (``model_type`` ``dots3_note``), the language model: 46 layers, 13 full
+# (deepseek_v32's sparse latent layer: 128 heads, queries through a 1024-wide
+# bottleneck, a cached row of 512 latent + 64 rotated lanes, value heads of
+# 128, plain RoPE theta 8e7, an indexer of 64 heads of 128 lanes choosing
+# 2048) and 33 sliding (plain latent attention at sizes of its own: 64 heads,
+# both ranks 1024, 192 + 64 lanes a head, theta 5e4, a band of 513 keys, the
+# rows in a ring); both rank factors and a head-wise output gate on every
+# layer; one leading dense layer of width 13824, then 256 routed experts of
+# width 1536 top-8 by sigmoid scores with a selection bias (one group), plus
+# a shared expert; untied 152064-row head. The towers and the
+# multi-token-prediction module are not built.
+_DOTS3_LAYERS = ("sparse_latent_attention",) * 2 + (
+    ("sliding_latent_attention",) * 3 + ("sparse_latent_attention",)) * 11
+DOTS3_NOTE_PREV = ModelConfig(
+    family="dots3_note",
+    vocab_size=152064,
+    hidden_size=5120,
+    num_layers=46,
+    num_heads=128,
+    num_kv_heads=128,
+    intermediate_size=13824,
+    max_position_embeddings=524288,
+    norm_eps=1e-5,
+    rope_theta=80000000.0,
+    tie_word_embeddings=False,
+    layer_types=_DOTS3_LAYERS,
+    explicit_head_dim=192,
+    sliding_window=513,
+    num_experts=256,
+    experts_per_tok=8,
+    expert_width=1536,
+    shared_width=1536,
+    q_lora_rank=1024,
+    kv_lora_rank=512,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    num_dense_layers=1,
+    score_func="sigmoid",
+    route_scale=1.0,
+    rank_scales=True,
+    index_heads=64,
+    index_head_dim=128,
+    index_topk=2048,
+    window_latent=LatentGeometry(
+        num_heads=64, q_lora_rank=1024, kv_lora_rank=1024,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=128,
+        rope_theta=50000.0),
+    head_gate=True,
+)
+
+
+def tiny_dots3_note_config(*, layer_types: tuple = (
+        "sparse_latent_attention", "sparse_latent_attention",
+        "sliding_latent_attention", "sliding_latent_attention",
+        "sliding_latent_attention"),
+                           index_topk: int = 8, sliding_window: int = 21,
+                           num_dense_layers: int = 1, hidden_size: int = 48,
+                           vocab_size: int = 256, num_experts: int = 16,
+                           experts_per_tok: int = 3, experts_held: int = 0,
+                           expert_offset: int = 0, rank_scales: bool = True,
+                           window_latent: LatentGeometry = LatentGeometry(
+                               num_heads=2, q_lora_rank=20, kv_lora_rank=136,
+                               qk_nope_head_dim=24, qk_rope_head_dim=8,
+                               v_head_dim=16, rope_theta=500.0),
+                           max_position_embeddings: int = 512
+                           ) -> ModelConfig:
+    """A small dots3_note for tests: every mechanism of the published
+    model's first stage (the leading dense layer, two full sparse latent
+    layers of 4 heads with rows of 16 + 8 = 24 lanes (stored 128) beside
+    index keys of 16 lanes, then three window layers of 2 heads with rows of
+    136 + 8 = 144 lanes (stored 256: the two groups' rows differ in width as
+    the published 640 and 1152 do), head widths, ranks and a theta of their
+    own, a band the test
+    prompts pass and a ring they turn; both rank factors off 1, a gate lane
+    a head on both kinds, sigmoid routing in one group with a shared expert,
+    an untied head) at toy widths."""
+    return ModelConfig(
+        family="dots3_note", vocab_size=vocab_size, hidden_size=hidden_size,
+        num_layers=len(layer_types), num_heads=4, num_kv_heads=4,
+        intermediate_size=96,
+        max_position_embeddings=max_position_embeddings, norm_eps=1e-5,
+        rope_theta=10000.0, tie_word_embeddings=False,
+        layer_types=tuple(layer_types), explicit_head_dim=24,
+        sliding_window=sliding_window, num_experts=num_experts,
+        experts_per_tok=experts_per_tok, expert_width=32, shared_width=40,
+        experts_held=experts_held, expert_offset=expert_offset,
+        q_lora_rank=20, kv_lora_rank=16, qk_rope_head_dim=8, v_head_dim=16,
+        num_dense_layers=num_dense_layers, score_func="sigmoid",
+        route_scale=1.0, rank_scales=rank_scales, index_heads=3,
+        index_head_dim=16, index_topk=index_topk,
+        window_latent=window_latent, head_gate=True)
+
+
 def tiny_deepseek_v32_config(*, num_layers: int = 3, index_topk: int = 8,
                              num_dense_layers: int = 1,
                              hidden_size: int = 48, num_heads: int = 4,
@@ -1140,6 +1334,8 @@ def tiny_config(family: str, *, num_layers: int = 4, hidden_size: int = 64,
         return tiny_keye_vl2_config()
     if family == "deepseek_v32":
         return tiny_deepseek_v32_config()
+    if family == "dots3_note":
+        return tiny_dots3_note_config()
     if num_kv_heads is None:
         num_kv_heads = 2 if family in ("qwen2", "llama") else num_heads
     if intermediate_size is None:
@@ -1173,6 +1369,7 @@ PRESETS = {
     "lfm2-8b-a1b": LFM2_8B_A1B,
     "keye-vl-2.0-30b-a3b": KEYE_VL_2_0_30B_A3B,
     "deepseek-v3.2-exp": DEEPSEEK_V3_2_EXP,
+    "dots3-note-prev": DOTS3_NOTE_PREV,
     # CI/smoke-scale variants (random init, no pretrained weights needed)
     "tiny-neox": tiny_config("gpt_neox"),
     "tiny-qwen2": tiny_config("qwen2", num_layers=6),
@@ -1185,4 +1382,5 @@ PRESETS = {
     "tiny-lfm2-moe": tiny_lfm2_moe_config(),
     "tiny-keye-vl2": tiny_keye_vl2_config(),
     "tiny-deepseek-v32": tiny_deepseek_v32_config(),
+    "tiny-dots3-note": tiny_dots3_note_config(),
 }

@@ -103,6 +103,8 @@ def params_from_state_dict(cfg: ModelConfig, sd: dict) -> dict:
         return _keye_vl2_params(cfg, sd)
     if cfg.family == "deepseek_v32":
         return _deepseek_v32_params(cfg, sd)
+    if cfg.family == "dots3_note":
+        return _dots3_note_params(cfg, sd)
     if cfg.is_hybrid:
         raise ValueError(
             f"no state_dict mapping for family {cfg.family!r}: its parameters "
@@ -272,6 +274,8 @@ def config_from_hf(hf_config) -> ModelConfig:
         return _keye_vl2_config(hf_config)
     if mt == "deepseek_v32":
         return _deepseek_v32_config(hf_config)
+    if mt == "dots3_note":
+        return _dots3_note_config(hf_config)
     raise ValueError(f"unsupported model_type: {mt}")
 
 
@@ -844,6 +848,38 @@ def _deepseek_v32_config(hf_config) -> ModelConfig:
     )
 
 
+def _v3_lineage_ffn(cfg: ModelConfig, sd: dict, i: int) -> dict:
+    """Layer ``i``'s ``moe`` entry from a state dict under the DeepSeek-V3
+    lineage's names (the deepseek_v32 and dots3_note mappings): a leading
+    dense layer's ``mlp.{gate,up,down}_proj``, or an expert layer's
+    ``mlp.gate`` (``weight``, ``e_score_correction_bias``),
+    ``mlp.experts.M.*`` and ``mlp.shared_experts.*``, with the layer's
+    ``post_attention_layernorm``."""
+    pre = f"model.layers.{i}."
+    ff = pre + "mlp."
+
+    def swiglu(prefix, names=("w_gate", "w_up", "w_down")):
+        return {k: jnp.asarray(_np(sd[f"{prefix}{name}.weight"]).T)
+                for k, name in zip(names, ("gate_proj", "up_proj",
+                                           "down_proj"))}
+
+    norm = {"ln2_scale": jnp.asarray(_np(
+        sd[pre + "post_attention_layernorm.weight"]))}
+    if i < cfg.num_dense_layers:
+        return {**norm, **swiglu(ff)}
+    experts = {k: jnp.asarray(np.stack([
+        _np(sd[f"{ff}experts.{e}.{name}.weight"]).T
+        for e in range(cfg.num_experts)]))
+        for k, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                        ("w_down", "down_proj"))}
+    return {**norm, **experts,
+            "router": jnp.asarray(_np(sd[ff + "gate.weight"]).T),
+            "router_bias": jnp.asarray(
+                _np(sd[ff + "gate.e_score_correction_bias"]), jnp.float32),
+            **swiglu(ff + "shared_experts.",
+                     ("shared_gate", "shared_up", "shared_down"))}
+
+
 def _deepseek_v32_params(cfg: ModelConfig, sd: dict) -> dict:
     """``models/hybrid.py``'s per-kind tree from a ``deepseek_v32``
     state_dict, all the experts held. Tensor names ASSUMED (no checkpoint can
@@ -872,30 +908,6 @@ def _deepseek_v32_params(cfg: ModelConfig, sd: dict) -> dict:
     def keep(w):
         return w
 
-    def swiglu(prefix, names=("w_gate", "w_up", "w_down")):
-        return {k: jnp.asarray(_np(sd[f"{prefix}{name}.weight"]).T)
-                for k, name in zip(names, ("gate_proj", "up_proj",
-                                           "down_proj"))}
-
-    def ffn(i):
-        ff = pre.format(i=i) + "mlp."
-        norm = {"ln2_scale": jnp.asarray(_np(
-            sd[pre.format(i=i) + "post_attention_layernorm.weight"]))}
-        if i < cfg.num_dense_layers:
-            return {**norm, **swiglu(ff)}
-        experts = {k: jnp.asarray(np.stack([
-            _np(sd[f"{ff}experts.{e}.{name}.weight"]).T
-            for e in range(cfg.num_experts)]))
-            for k, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
-                            ("w_down", "down_proj"))}
-        return {**norm, **experts,
-                "router": jnp.asarray(_np(sd[ff + "gate.weight"]).T),
-                "router_bias": jnp.asarray(
-                    _np(sd[ff + "gate.e_score_correction_bias"]),
-                    jnp.float32),
-                **swiglu(ff + "shared_experts.",
-                         ("shared_gate", "shared_up", "shared_down"))}
-
     at = "self_attn."
     return {
         "embed": jnp.asarray(_np(sd["model.embed_tokens.weight"])),
@@ -916,5 +928,174 @@ def _deepseek_v32_params(cfg: ModelConfig, sd: dict) -> dict:
             "index_norm_bias": rows(at + "indexer.k_norm.bias", keep),
             "w_index": rows(at + "indexer.weights_proj.weight"),
         },
-        "moe": [ffn(i) for i in range(n)],
+        "moe": [_v3_lineage_ffn(cfg, sd, i) for i in range(n)],
     }
+
+
+#: the published names of dots3_note's layer kinds -> ModelConfig's
+DOTS3_LAYER_KINDS = {"full_attention": "sparse_latent_attention",
+                     "sliding_attention": "sliding_latent_attention"}
+
+
+def _dots3_note_config(hf_config) -> ModelConfig:
+    """dots3-note-prev's language model (``model_type`` ``dots3_note``). The
+    keys mapped: ``layer_types`` (``full_attention`` a sparse latent layer at
+    the flat latent keys' sizes, deepseek_v32's, with its ``index_*`` keys;
+    ``sliding_attention`` a window latent layer at the ``swa_*`` keys' sizes
+    over ``sliding_window_size`` keys: ``ModelConfig.window_latent``),
+    ``rope_theta`` / ``swa_rope_theta`` (plain RoPE, a table a kind),
+    ``apply_mla_qkv_lora_rescale`` (``rank_scales``, at each kind's own
+    ranks; false: off), ``attention_gate_type`` / ``swa_attention_gate_type``
+    (``head_gate``), ``first_k_dense_replace``, ``n_routed_experts``,
+    ``num_experts_per_tok``, ``moe_intermediate_size``, ``n_shared_experts``
+    (1), ``routed_scaling_factor``; one routing group (the config has no
+    ``n_group``). Refused by name: a ``quantization_config``, a non-null
+    ``rope_scaling``, a gate type other than ``"headwise"`` on either kind,
+    ``attention_bias``, ``n_shared_experts`` other than 1, ``scoring_func``
+    other than ``sigmoid``, ``topk_method`` other than ``noaux_tc``,
+    ``moe_layer_freq`` other than 1, ``norm_topk_prob`` false, a
+    ``layer_types`` entry outside the two, a tied head. The vision tower, the
+    audio encoder and the multi-token-prediction module are not built."""
+    if getattr(hf_config, "quantization_config", None):
+        raise ValueError(
+            "dots3_note with a quantization_config is not supported (bf16 "
+            "weights only)")
+    if getattr(hf_config, "rope_scaling", None):
+        raise ValueError(
+            f"dots3_note with rope_scaling={hf_config.rope_scaling!r} is "
+            f"not supported (null only: plain RoPE on both kinds)")
+    for key, want in (("attention_gate_type", "headwise"),
+                      ("swa_attention_gate_type", "headwise"),
+                      ("attention_bias", False), ("n_shared_experts", 1),
+                      ("scoring_func", "sigmoid"),
+                      ("topk_method", "noaux_tc"), ("moe_layer_freq", 1),
+                      ("norm_topk_prob", True),
+                      ("tie_word_embeddings", False)):
+        if getattr(hf_config, key, want) != want:
+            raise ValueError(
+                f"dots3_note with {key}={getattr(hf_config, key)!r} is not "
+                f"supported (only {want!r})")
+    kinds = list(hf_config.layer_types)
+    unknown = sorted(set(kinds) - set(DOTS3_LAYER_KINDS))
+    if unknown or len(kinds) != int(hf_config.num_hidden_layers):
+        raise ValueError(
+            f"dots3_note layer_types must name one of "
+            f"{sorted(DOTS3_LAYER_KINDS)} for each of the "
+            f"{hf_config.num_hidden_layers} layers, got {unknown or kinds!r}")
+    from .configs import LatentGeometry
+
+    nope, rot = hf_config.qk_nope_head_dim, hf_config.qk_rope_head_dim
+    sliding = "sliding_attention" in kinds
+    return ModelConfig(
+        family="dots3_note",
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        num_layers=len(kinds),
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        intermediate_size=hf_config.intermediate_size,
+        max_position_embeddings=hf_config.max_position_embeddings,
+        norm_eps=hf_config.rms_norm_eps,
+        rope_theta=float(hf_config.rope_theta),
+        tie_word_embeddings=False,
+        layer_types=tuple(DOTS3_LAYER_KINDS[t] for t in kinds),
+        explicit_head_dim=int(nope + rot),
+        sliding_window=(int(hf_config.sliding_window_size) if sliding
+                        else 0),
+        num_experts=int(hf_config.n_routed_experts),
+        experts_per_tok=int(hf_config.num_experts_per_tok),
+        expert_width=int(hf_config.moe_intermediate_size),
+        shared_width=int(hf_config.moe_intermediate_size),
+        q_lora_rank=int(hf_config.q_lora_rank),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_rope_head_dim=int(rot),
+        v_head_dim=int(hf_config.v_head_dim),
+        num_dense_layers=int(hf_config.first_k_dense_replace),
+        score_func="sigmoid",
+        route_scale=float(hf_config.routed_scaling_factor),
+        rank_scales=bool(getattr(hf_config, "apply_mla_qkv_lora_rescale",
+                                 False)),
+        index_heads=int(hf_config.index_n_heads),
+        index_head_dim=int(hf_config.index_head_dim),
+        index_topk=int(hf_config.index_topk),
+        window_latent=LatentGeometry(
+            num_heads=int(hf_config.swa_num_attention_heads),
+            q_lora_rank=int(hf_config.swa_q_lora_rank),
+            kv_lora_rank=int(hf_config.swa_kv_lora_rank),
+            qk_nope_head_dim=int(hf_config.swa_qk_nope_head_dim),
+            qk_rope_head_dim=int(hf_config.swa_qk_rope_head_dim),
+            v_head_dim=int(hf_config.swa_v_head_dim),
+            rope_theta=float(hf_config.swa_rope_theta)) if sliding else None,
+        head_gate=True,
+    )
+
+
+#: a state dict's names that belong to what is not built: said once, skipped
+DOTS3_TOWER_PREFIXES = ("vision_tower.", "audio_tower.", "audio_encoder.",
+                        "visual.", "model.mtp.")
+
+
+def _dots3_note_params(cfg: ModelConfig, sd: dict) -> dict:
+    """``models/hybrid.py``'s per-kind tree from a ``dots3_note`` state_dict,
+    all the experts held. Tensor names ASSUMED (no checkpoint can be fetched
+    here), the DeepSeek-V3 lineage's as ``_deepseek_v32_params`` reads them,
+    for BOTH kinds of layer (a window layer's ``self_attn`` holds the same
+    names at its own sizes and no ``indexer``), plus the gate,
+    ``self_attn.gate_proj`` (H, D). Tower and multi-token-prediction weights
+    in the state dict (:data:`DOTS3_TOWER_PREFIXES`) are said once on the log
+    and not loaded."""
+    if cfg.experts_held:
+        raise ValueError("the dots3_note state_dict mapping holds every "
+                         "expert")
+    towers = sorted({k.split(".")[0] for k in sd
+                     if k.startswith(DOTS3_TOWER_PREFIXES)})
+    if towers:
+        logging.getLogger(__name__).info(
+            "dots3_note: %s weights are in the state dict: the towers and "
+            "the multi-token-prediction module are not built and their "
+            "weights are not loaded", ", ".join(towers))
+    pre = "model.layers.{i}."
+    at = "self_attn."
+
+    def rows(layers, suffix, transform=lambda w: w.T):
+        return jnp.asarray(np.stack([
+            transform(_np(sd[pre.format(i=i) + suffix])) for i in layers]))
+
+    def keep(w):
+        return w
+
+    def latent(layers):
+        return {
+            "ln1_scale": rows(layers, "input_layernorm.weight", keep),
+            "wq_a": rows(layers, at + "q_a_proj.weight"),
+            "q_norm": rows(layers, at + "q_a_layernorm.weight", keep),
+            "wq_b": rows(layers, at + "q_b_proj.weight"),
+            "wkv_a": rows(layers, at + "kv_a_proj_with_mqa.weight"),
+            "kv_norm": rows(layers, at + "kv_a_layernorm.weight", keep),
+            "wkv_b": rows(layers, at + "kv_b_proj.weight"),
+            "wo": rows(layers, at + "o_proj.weight"),
+            "wg": rows(layers, at + "gate_proj.weight"),
+        }
+
+    full = [i for i, t in enumerate(cfg.layer_types)
+            if t == "sparse_latent_attention"]
+    sliding = [i for i, t in enumerate(cfg.layer_types)
+               if t == "sliding_latent_attention"]
+    out = {
+        "embed": jnp.asarray(_np(sd["model.embed_tokens.weight"])),
+        "final_norm_scale": jnp.asarray(_np(sd["model.norm.weight"])),
+        "lm_head": jnp.asarray(_np(sd["lm_head.weight"]).T),
+        "sparse_latent": {
+            **latent(full),
+            "wq_index": rows(full, at + "indexer.wq_b.weight"),
+            "wk_index": rows(full, at + "indexer.wk.weight"),
+            "index_norm_scale": rows(full, at + "indexer.k_norm.weight",
+                                     keep),
+            "index_norm_bias": rows(full, at + "indexer.k_norm.bias", keep),
+            "w_index": rows(full, at + "indexer.weights_proj.weight"),
+        },
+        "moe": [_v3_lineage_ffn(cfg, sd, i) for i in range(cfg.num_layers)],
+    }
+    if sliding:
+        out["window_latent"] = latent(sliding)
+    return out

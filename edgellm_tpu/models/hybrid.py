@@ -1,6 +1,6 @@
 """A stack walked by layer kinds: the ``granitemoehybrid``, ``mellum``,
-``mistral4``, ``afmoe``, ``longcat_flash``, ``lfm2_moe``, ``keye_vl2`` and
-``deepseek_v32`` families' forward and steps.
+``mistral4``, ``afmoe``, ``longcat_flash``, ``lfm2_moe``, ``keye_vl2``,
+``deepseek_v32`` and ``dots3_note`` families' forward and steps.
 
 The one-block families ride one ``lax.scan`` over a pytree stacked along the
 layer axis with K/V as the scanned state. Here a layer is a Mamba-2 mixer
@@ -50,7 +50,15 @@ has one kind, ``sparse_latent`` (``models/sparse_mla.py``): mistral4's latent
 layer whose query attends the positions keye's indexer selects, its rows and
 index keys the two leaves of a ``paged_kv.IndexedLatentPool``; afmoe's leading
 dense entries, then routed experts chosen within the best expert groups
-(``moe.route``) plus a shared one, an untied head.
+(``moe.route``) plus a shared one, an untied head. A ``dots3_note`` stack
+(dots3-note-prev's language model) holds TWO latent kinds at sizes of their
+own (``cfg.latent_geometry``): deepseek's ``sparse_latent`` (plain RoPE, one
+routing group) and ``window_latent``, plain latent attention with no indexer
+over a band of ``sliding_window`` keys, whose rows live in the window group's
+RING (a one-leaf latent pool as wide as ITS row) and rotate by a table of its
+own theta; both multiply their latents by the rank factors and gate every
+head's output (``wg`` (D, H): ``mla.head_gate``) ahead of ``W_o``. Its ragged
+step hands over an ``IndexedLatentPool`` and a ``window=`` group at once.
 
 (the expert weights are a list, not a stack: a row sliced from a ``(L, E, D,
 F)`` stack for a prefill's grouped products, whose operands must be whole
@@ -75,6 +83,7 @@ beside them that a query reads a selection of (:func:`refuse_index_keys`);
 all: :func:`refuse_beyond_kv_rows`."""
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -91,8 +100,10 @@ from .moe import moe_layer
 from .paged_kv import (INDEXED_POOLS, LatentPool, PagePool,
                        _attention_decode_latent,
                        _attention_decode_paged, _attention_decode_window,
+                       _attention_decode_window_latent,
                        attend_latent, gated, head_norms, post_norm)
-from .transformer import _rmsnorm, apply_rotary, mlp, precompute_rope
+from .transformer import (_rmsnorm, apply_rotary, deinterleave_pairs, mlp,
+                          precompute_rope)
 
 
 class RecurrentStateUnsupported(ValueError):
@@ -144,10 +155,11 @@ class LatentRowsUnsupported(ValueError):
 def refuse_latent_rows(cfg: ModelConfig, what: str) -> None:
     """Raise for a config with latent-attention layers: ``what`` names the
     mechanism refusing."""
-    if cfg.latent_layers:
+    if cfg.latent_layers or cfg.window_latent_layers:
         raise LatentRowsUnsupported(
             f"{what} does not support family {cfg.family!r}: its "
-            f"{cfg.latent_layers} latent-attention layers cache ONE row a "
+            f"{cfg.latent_layers + cfg.window_latent_layers} "
+            f"latent-attention layers cache ONE row a "
             f"position for all heads (a {cfg.kv_lora_rank}-lane latent and "
             f"{cfg.qk_rope_head_dim} rotated lanes, stored "
             f"{cfg.kv_row_lanes} wide in a one-leaf pool), and {what} is "
@@ -250,6 +262,25 @@ class SparseLatentCache(NamedTuple):
         return self.rows.shape[2]
 
 
+class SparseLatentWindowCache(NamedTuple):
+    """The contiguous decode cache of a stack of sparse latent layers beside
+    window latent layers (``dots3_note``).
+
+    rows, length, index: :class:`SparseLatentCache`'s, the full layers';
+    wrows: (L_window, B, capacity, window_row_lanes), EVERY position of the
+    window layers' latent rows (the contiguous path masks the band; only the
+    paged pool keeps a ring)."""
+
+    rows: jnp.ndarray
+    length: jnp.ndarray
+    index: jnp.ndarray
+    wrows: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.rows.shape[2]
+
+
 class LatentCache(NamedTuple):
     """The contiguous decode cache of a stack of latent-attention layers.
 
@@ -293,7 +324,7 @@ def _kinds(cfg: ModelConfig):
     """(layer, kind, index among its kind) down the stack."""
     seen = {"mamba": 0, "conv": 0, "attention": 0, "sliding_attention": 0,
             "latent_attention": 0, "sparse_attention": 0,
-            "sparse_latent_attention": 0}
+            "sparse_latent_attention": 0, "sliding_latent_attention": 0}
     for layer, kind in enumerate(cfg.layer_types):
         yield layer, kind, seen[kind]
         seen[kind] += 1
@@ -327,8 +358,12 @@ def _rope_tables(cfg: ModelConfig, n: int) -> dict:
     one (YaRN) on full layers and the plain one on sliding layers."""
     if cfg.latent_layers:  # the rope lanes' table (cfg.rotary_dim wide)
         # (a sparse latent layer's indexer rotates by it too)
-        return {"sparse_latent_attention" if cfg.sparse_layers
-                else "latent_attention": precompute_rope(cfg, n)}
+        tables = {"sparse_latent_attention" if cfg.sparse_layers
+                  else "latent_attention": precompute_rope(cfg, n)}
+        if cfg.window_latent_layers:    # a table a kind: its own theta
+            tables["sliding_latent_attention"] = mla.plain_rope(
+                cfg.window_latent, n)
+        return tables
     if cfg.sparse_layers:  # the heads' table, and the indexer's narrower one
         return {"sparse_attention": precompute_rope(cfg, n),
                 "sparse_index": sparse_attn.index_rope(cfg, n)}
@@ -401,11 +436,12 @@ def _attention_latent_full(cfg: ModelConfig, lp: dict, x, rope):
     each, 67 MB a leaf in bf16."""
     b, s, _ = x.shape
     cos, sin = rope
+    geo = cfg.latent_geometry("latent_attention")
     q_nope, q_rope, rows = mla.project(
-        cfg, lp, x, lambda t: apply_rotary(t, cos, sin, cfg.rotary_dim),
-        jnp.broadcast_to(mla.query_scale(cfg, jnp.arange(s)), (b, s)))
+        cfg, geo, lp, x, lambda t: apply_rotary(t, cos, sin, cfg.rotary_dim),
+        jnp.broadcast_to(mla.query_scale(cfg, geo, jnp.arange(s)), (b, s)))
     with jax.named_scope("attn.latent.expand"):
-        k, v = mla.expand(cfg, lp, rows)
+        k, v = mla.expand(geo, lp, rows)
         out = _attention_blocks(jnp.concatenate([q_nope, q_rope], axis=-1),
                                 k, v, 0)
     return out.reshape(b, s, -1) @ lp["wo"], rows
@@ -419,14 +455,63 @@ def _attention_latent_step(cfg: ModelConfig, lp: dict, x, rope, rows_all,
     capacity, kv_row_lanes) -> (out (B, D), rows_all with position ``pos``
     written)."""
     b = x.shape[0]
+    geo = cfg.latent_geometry("latent_attention")
     q_nope, q_rope, row = mla.project(
-        cfg, lp, x, mla.rotate_rows(*rope),
-        mla.query_scale(cfg, jnp.broadcast_to(pos, (b,))))
+        cfg, geo, lp, x, mla.rotate_rows(*rope),
+        mla.query_scale(cfg, geo, jnp.broadcast_to(pos, (b,))))
     rows_all = jax.lax.dynamic_update_slice(
         rows_all, row[:, None].astype(rows_all.dtype), (0, pos, 0))
-    ctx = attend_latent(mla.absorb_query(cfg, lp, q_nope, q_rope), rows_all,
+    ctx = attend_latent(mla.absorb_query(geo, lp, q_nope, q_rope), rows_all,
                         jnp.broadcast_to(pos + 1, (b,)), cfg.head_dim)
-    return mla.unabsorb(cfg, lp, ctx), rows_all
+    return mla.unabsorb(geo, lp, ctx), rows_all
+
+
+#: heads whose queries, keys and values a window latent layer's prefill
+#: rebuilds at a time: 16 heads' (S, 256 + 256 + 128) are 336 MB at 16384
+#: positions where all 64 would be 1.3 GB
+BAND_HEADS = 16
+
+
+@jax.named_scope("attn.window_latent.prefill")
+def _attention_window_latent_full(cfg: ModelConfig, lp: dict, x, rope):
+    """A window latent layer over whole sequences, EXPANDED under the band ->
+    (out (B, S, D), rows (B, S, window_row_lanes) as the ring takes them):
+    position t attends ``s`` with ``t - sliding_window < s <= t``.
+    :data:`BAND_HEADS` heads at a time (a ``lax.map``: one group's queries,
+    keys and values live at once), their queries made from ``c_q`` and their
+    keys and values rebuilt from the rows (``mla.expand``), attended by
+    blocks of :data:`QBLOCK` query rows against the ``QBLOCK + window - 1``
+    keys the band lets them see (:func:`_attention_blocks`, plain XLA: timed
+    alone on a v5e at the cell's widths against
+    ``flash_attention.masked_attention`` under the band's mask, the blocks
+    were ahead over the cell's two prompt lengths together and are the one
+    attend kept: PERF.md section 6 "PR 54"); then the heads' gate and ``W_o``
+    once."""
+    b, s, _ = x.shape
+    cos, sin = rope
+    geo = cfg.latent_geometry("sliding_latent_attention")
+    rot, nope = geo.qk_rope_head_dim, geo.qk_nope_head_dim
+
+    def rotate(t):
+        return apply_rotary(t, cos, sin, rot)
+
+    c_q = mla.query_latent(cfg, lp, x)
+    rows = mla.latent_row(cfg, geo, lp, x, rotate)
+    scale = jnp.broadcast_to(mla.query_scale(cfg, geo, jnp.arange(s)), (b, s))
+    hg = math.gcd(geo.num_heads, BAND_HEADS)
+
+    def group(first):
+        q = mla.head_queries(geo, lp, c_q, scale, (first, hg))
+        q = jnp.concatenate(
+            [q[..., :nope], rotate(deinterleave_pairs(q[..., nope:]))],
+            axis=-1)
+        k, v = mla.expand(geo, lp, rows, (first, hg))
+        return _attention_blocks(q, k, v, cfg.sliding_window)
+
+    outs = jax.lax.map(group, jnp.arange(0, geo.num_heads, hg))
+    # (groups, B, S, hg, vd) -> (B, S, H vd)
+    ctx = jnp.moveaxis(outs, 0, 2).reshape(b, s, -1)
+    return gated(lp, x, ctx) @ lp["wo"], rows
 
 
 #: a prefill whose routed layer would gather more than FFN_ROWS_MAX bytes of
@@ -509,6 +594,7 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
     lists of what a decode cache is filled from when ``collect``)."""
     h, term = embed_hybrid(cfg, params, ids), None   # term: _shortcut's
     ks, vs, wks, wvs, lat, iks = [], [], [], [], [], []
+    # (a window latent layer's rows ride in ``wks``, and ``wvs`` stays empty)
     state = {leaf: [] for leaf in state_shapes(cfg, 0)}
     rope = _rope_tables(cfg, ids.shape[1])
     for layer, kind, j in _kinds(cfg):
@@ -525,6 +611,12 @@ def _walk_full(cfg: ModelConfig, params: dict, ids, collect: bool):
             if collect:
                 lat.append(rows)
                 iks.append(ik)
+        elif kind == "sliding_latent_attention":
+            lp = _row(params["window_latent"], j)
+            out, rows = _attention_window_latent_full(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind])
+            if collect:
+                wks.append(rows)
         elif kind == "sparse_attention":
             lp = _row(params["sparse"], j)
             out, k, v, ik = sparse_attn.attention_full(
@@ -583,7 +675,8 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
     """The prompt's forward that also fills the decode cache: (logits (B, S,
     V) float32 — (B, V) of the last position with ``last_only`` —, a
     :class:`HybridCache`, :class:`WindowCache`, :class:`LatentCache`,
-    :class:`SparseCache` or :class:`SparseLatentCache`)."""
+    :class:`SparseCache`, :class:`SparseLatentCache` or
+    :class:`SparseLatentWindowCache`)."""
     b, s = ids.shape
     if not 0 < s <= capacity:
         raise ValueError(f"prompt length {s} must be in [1, capacity="
@@ -594,6 +687,10 @@ def prefill_hybrid(cfg: ModelConfig, params: dict, ids, capacity: int,
     if lat:
         grow = ((0, 0), (0, 0), (0, capacity - s), (0, 0))
         rows, length = jnp.pad(jnp.stack(lat), grow), jnp.asarray(s, jnp.int32)
+        if wks:
+            return logits, SparseLatentWindowCache(
+                rows, length, jnp.pad(jnp.stack(iks), grow),
+                jnp.pad(jnp.stack(wks), grow))
         if iks:
             return logits, SparseLatentCache(
                 rows, length, jnp.pad(jnp.stack(iks), grow))
@@ -622,7 +719,7 @@ def decode_step_hybrid(cfg: ModelConfig, params: dict, cache, token_ids):
     b = token_ids.shape[0]
     pos = cache.length
     h = embed_hybrid(cfg, params, token_ids)                  # (B, D)
-    if isinstance(cache, SparseLatentCache):
+    if isinstance(cache, (SparseLatentCache, SparseLatentWindowCache)):
         return _decode_step_sparse_latent(cfg, params, cache, h)
     if isinstance(cache, LatentCache):
         return _decode_step_latent(cfg, params, cache, h)
@@ -702,25 +799,59 @@ def _decode_step_sparse(cfg: ModelConfig, params: dict, cache: SparseCache,
     return unembed_hybrid(cfg, params, h), SparseCache(k, v, pos + 1, index)
 
 
-def _decode_step_sparse_latent(cfg: ModelConfig, params: dict,
-                               cache: SparseLatentCache, h):
-    """:func:`decode_step_hybrid` for a stack of sparse latent layers: h (B,
-    D) the embedded tokens."""
-    pos, (rows, _, index) = cache.length, cache
-    rope = tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1) for x in
-                 _rope_tables(cfg, cache.capacity)["sparse_latent_attention"])
-    for layer, _, j in _kinds(cfg):
-        lp = _row(params["sparse_latent"], j)
-        out, rows_j, index_j = sparse_mla.attention_decode_rows(
-            cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope, rows[j], index[j],
-            pos)
-        rows, index = rows.at[j].set(rows_j), index.at[j].set(index_j)
+def _decode_step_sparse_latent(cfg: ModelConfig, params: dict, cache, h):
+    """:func:`decode_step_hybrid` for a stack of sparse latent layers (a
+    :class:`SparseLatentCache`), or of those beside window latent layers (a
+    :class:`SparseLatentWindowCache`): h (B, D) the embedded tokens."""
+    pos, rows, index = cache.length, cache.rows, cache.index
+    wrows = getattr(cache, "wrows", None)
+    rope = {kind: tuple(jax.lax.dynamic_slice_in_dim(x, pos, 1) for x in t)
+            for kind, t in _rope_tables(cfg, cache.capacity).items()}
+    for layer, kind, j in _kinds(cfg):
+        if kind == "sliding_latent_attention":
+            lp = _row(params["window_latent"], j)
+            out, wrows_j = _attention_window_latent_step(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind], wrows[j],
+                pos)
+            wrows = wrows.at[j].set(wrows_j)
+        else:
+            lp = _row(params["sparse_latent"], j)
+            out, rows_j, index_j = sparse_mla.attention_decode_rows(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), rope[kind], rows[j],
+                index[j], pos)
+            rows, index = rows.at[j].set(rows_j), index.at[j].set(index_j)
         h = h + cfg.residual_multiplier * out
         h, _ = _ffn(cfg, params["moe"][layer], h)
-    return unembed_hybrid(cfg, params, h), SparseLatentCache(rows, pos + 1,
-                                                             index)
+    logits = unembed_hybrid(cfg, params, h)
+    if wrows is None:
+        return logits, SparseLatentCache(rows, pos + 1, index)
+    return logits, SparseLatentWindowCache(rows, pos + 1, index, wrows)
 
 
+@jax.named_scope("attn.window_latent")
+def _attention_window_latent_step(cfg: ModelConfig, lp: dict, x, rope,
+                                  rows_all, pos):
+    """A window latent layer's decode against ONE layer of a contiguous
+    cache, ABSORBED: x (B, D), rope (cos, sin) (1, rot) at ``pos``, rows_all
+    (B, capacity, window_row_lanes) -> (out (B, D), rows_all with position
+    ``pos`` written). The band is a mask over every cached position."""
+    b = x.shape[0]
+    geo = cfg.latent_geometry("sliding_latent_attention")
+    q_nope, q_rope, row = mla.project(
+        cfg, geo, lp, x, mla.rotate_rows(*rope),
+        mla.query_scale(cfg, geo, jnp.broadcast_to(pos, (b,))))
+    rows_all = jax.lax.dynamic_update_slice(
+        rows_all, row[:, None].astype(rows_all.dtype), (0, pos, 0))
+    at = jnp.arange(rows_all.shape[1])
+    seen = (at <= pos) & (at > pos - cfg.sliding_window)
+    ctx = attend_latent(mla.absorb_query(geo, lp, q_nope, q_rope), rows_all,
+                        None, geo.head_dim, jnp.broadcast_to(seen, (
+                            b, rows_all.shape[1])))
+    return mla.unabsorb(geo, lp, ctx, mla.head_gate(lp, x)), rows_all
+
+
+@graph_contract("paged.decode_step_window_latent", collectives={},
+                donate=lambda ctx: ctx.get("donate_min", 4))
 @graph_contract("paged.decode_step_sparse_latent", collectives={},
                 donate=lambda ctx: ctx.get("donate_min", 3))
 @graph_contract("paged.decode_step_sparse", collectives={},
@@ -758,7 +889,10 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
     sparse-attention layers hands over its ``paged_kv.IndexedPagePool``
     WHOLE as ``pool`` (both leaves: the K/V rows and the index keys) and gets
     it back so, as a stack of sparse latent layers does its
-    ``paged_kv.IndexedLatentPool``."""
+    ``paged_kv.IndexedLatentPool``. A ``dots3_note`` stack does both at
+    once: its ``IndexedLatentPool`` whole as ``pool`` and ``window`` = (the
+    ring group's ONE leaf of latent rows (L_window, pages, page_size,
+    window_row_lanes), its table)."""
     if token_ids.ndim == 2:
         token_ids = token_ids[:, 0]
     active = lengths > 0
@@ -790,6 +924,11 @@ def paged_decode_step_hybrid(cfg: ModelConfig, params: dict, pool, state,
         elif kind in ("mamba", "conv"):
             state, out = _step_row(cfg, kind, _row(params[kind], j), h, state,
                                    j)
+        elif kind == "sliding_latent_attention":
+            lp = _row(params["window_latent"], j)
+            out, (win,) = _attention_decode_window_latent(
+                cfg, lp, _rms(cfg, h, lp["ln1_scale"]), *rope[kind],
+                LatentPool(win), j, window_table, lengths)
         elif kind == "sliding_attention":
             lp = _row(params["window"], j)
             out, (win,) = _attention_decode_window(
@@ -837,7 +976,10 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
     the index key's LayerNorm (``index_norm_scale`` one, ``index_norm_bias``
     zero) and ``w_index``. A ``deepseek_v32`` stack's ``sparse_latent`` kind
     holds a latent layer's leaves and the same indexer's, ``wq_index`` off
-    the q latent (q_lora_rank rows)."""
+    the q latent (q_lora_rank rows). A ``dots3_note`` stack's
+    ``sparse_latent`` kind also holds the heads' gate ``wg`` (D, H), and its
+    ``window_latent`` kind a latent layer's leaves at the window kind's own
+    sizes (``cfg.window_latent``) with a gate of its own heads."""
     keys = iter(jax.random.split(key, 16 + 8 * len(cfg.layer_types)))
 
     def init(*shape):
@@ -917,6 +1059,8 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
                                         + cfg.v_head_dim)),
             "wo": init(n, h * cfg.v_head_dim, d),
         }
+        if cfg.head_gate:   # a gate lane a head
+            latent["wg"] = init(n, d, h)
         if cfg.sparse_layers:  # the indexer's leaves, its query's from c_q
             hi, di = cfg.index_heads, cfg.index_head_dim
             params["sparse_latent"] = {
@@ -956,7 +1100,21 @@ def init_params_hybrid(cfg: ModelConfig, key: jax.Array,
         **({"router_bias": init(cfg.num_experts).astype(jnp.float32)}
            if cfg.score_func == "sigmoid" else {}),
     } for layer in range(lt)]
-    if cfg.window_layers:
+    if cfg.window_latent_layers:
+        n, g = cfg.window_latent_layers, cfg.window_latent
+        params["window_latent"] = {
+            "ln1_scale": jnp.ones((n, d), dtype),
+            "wq_a": init(n, d, g.q_lora_rank),
+            "q_norm": jnp.ones((n, g.q_lora_rank), dtype),
+            "wq_b": init(n, g.q_lora_rank, g.num_heads * g.head_dim),
+            "wkv_a": init(n, d, g.kv_lora_rank + g.qk_rope_head_dim),
+            "kv_norm": jnp.ones((n, g.kv_lora_rank), dtype),
+            "wkv_b": init(n, g.kv_lora_rank, g.num_heads * (
+                g.qk_nope_head_dim + g.v_head_dim)),
+            "wo": init(n, g.num_heads * g.v_head_dim, d),
+            **({"wg": init(n, d, g.num_heads)} if cfg.head_gate else {}),
+        }
+    elif cfg.window_layers:
         params["window"] = attention(cfg.window_layers)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = init(d, cfg.vocab_size)

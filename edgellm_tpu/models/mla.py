@@ -42,15 +42,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .configs import ModelConfig
+from .configs import LatentGeometry, ModelConfig
 from .transformer import _rmsnorm, _rotate_half, deinterleave_pairs
 
 
-def query_scale(cfg: ModelConfig, positions):
+def query_scale(cfg: ModelConfig, geo: LatentGeometry, positions):
     """What a query at ``positions`` (any shape, int) is multiplied by beside
     the attends' ``head_dim^-1/2``: float32, same shape."""
     scale = jnp.full(positions.shape,
-                     cfg.softmax_mscale ** 2 * cfg.q_rank_scale, jnp.float32)
+                     cfg.softmax_mscale ** 2
+                     * cfg.rank_scale(geo.q_lora_rank), jnp.float32)
     if not cfg.query_scale_beta:
         return scale
     original_max = cfg.rope_scaling[2]
@@ -67,9 +68,22 @@ def rotate_rows(cos, sin):
     return rotate
 
 
-def _pad_lanes(cfg: ModelConfig, x):
+def plain_rope(geo: LatentGeometry, n: int):
+    """(cos, sin) (n, rope) float32 of plain RoPE by the KIND's own theta
+    over its rope lanes, ``transformer.precompute_rope``'s layout (emb =
+    concat(freqs, freqs)): the table of a latent kind whose theta is not the
+    stack's ``rope_theta`` (a window kind's)."""
+    rot = geo.qk_rope_head_dim
+    inv_freq = 1.0 / (geo.rope_theta
+                      ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    freqs = jnp.outer(jnp.arange(n, dtype=jnp.float32), inv_freq)
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return jnp.cos(emb), jnp.sin(emb)
+
+
+def _pad_lanes(geo: LatentGeometry, x):
     """(..., kv_lora_rank + rope) -> (..., kv_row_lanes), zeros after."""
-    pad = cfg.kv_row_lanes - x.shape[-1]
+    pad = geo.kv_row_lanes - x.shape[-1]
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
 
 
@@ -80,41 +94,42 @@ def query_latent(cfg: ModelConfig, lp: dict, x):
     return _rmsnorm(x @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
 
 
-def project(cfg: ModelConfig, lp: dict, x, rotate, scale, c_q=None):
+def project(cfg: ModelConfig, geo: LatentGeometry, lp: dict, x, rotate,
+            scale, c_q=None):
     """x (..., D) normalised -> (q_nope (..., H, nope), q_rope (..., H, rope),
     row (..., kv_row_lanes)): the queries scaled by ``scale`` (...,) float32
     (:func:`query_scale`) and ``q_rope`` / the row's ``k_rope`` rotated by
     ``rotate`` (a function of (..., heads, rope) arrays, de-interleaved first
     here), the row as it is cached. ``c_q``: :func:`query_latent` of ``x``
     where the caller has made it already."""
-    rope = cfg.qk_rope_head_dim
+    rope = geo.qk_rope_head_dim
     if c_q is None:
         c_q = query_latent(cfg, lp, x)
-    q = head_queries(cfg, lp, c_q, scale)
-    row = latent_row(cfg, lp, x, rotate)
+    q = head_queries(geo, lp, c_q, scale)
+    row = latent_row(cfg, geo, lp, x, rotate)
     return (q[..., :-rope], rotate(deinterleave_pairs(q[..., -rope:])), row)
 
 
-def head_queries(cfg: ModelConfig, lp: dict, c_q, scale, heads=None):
+def head_queries(geo: LatentGeometry, lp: dict, c_q, scale, heads=None):
     """``c_q`` (..., q_lora_rank) -> the heads' queries (..., H, nope + rope)
     times ``scale`` (...,) float32, nothing rotated yet. ``heads`` (first,
     count): those heads alone (:func:`_of_heads`)."""
-    wq, h = _of_heads(lp["wq_b"], cfg.num_heads, heads)
-    q = (c_q @ wq).reshape(*c_q.shape[:-1], h, cfg.head_dim)
+    wq, h = _of_heads(lp["wq_b"], geo.num_heads, heads)
+    q = (c_q @ wq).reshape(*c_q.shape[:-1], h, geo.head_dim)
     return q * scale[..., None, None].astype(q.dtype)
 
 
-def latent_row(cfg: ModelConfig, lp: dict, x, rotate):
+def latent_row(cfg: ModelConfig, geo: LatentGeometry, lp: dict, x, rotate):
     """x (..., D) normalised -> the position's row as it is cached (...,
     kv_row_lanes): ``[c | k_rope | 0...]``, ``k_rope`` de-interleaved and
     rotated by ``rotate``."""
-    rank = cfg.kv_lora_rank
+    rank = geo.kv_lora_rank
     kv = x @ lp["wkv_a"]
     c = _rmsnorm(kv[..., :rank], lp["kv_norm"], cfg.norm_eps)
     if cfg.rank_scales:
-        c = c * jnp.asarray(cfg.kv_rank_scale, c.dtype)
+        c = c * jnp.asarray(cfg.rank_scale(rank), c.dtype)
     k_rope = rotate(deinterleave_pairs(kv[..., None, rank:]))[..., 0, :]
-    return _pad_lanes(cfg, jnp.concatenate([c, k_rope], axis=-1))
+    return _pad_lanes(geo, jnp.concatenate([c, k_rope], axis=-1))
 
 
 def _of_heads(w, h: int, heads):
@@ -129,39 +144,51 @@ def _of_heads(w, h: int, heads):
         w, heads[0] * lanes, heads[1] * lanes, axis=1), heads[1]
 
 
-def _kvb(cfg: ModelConfig, lp: dict, heads=None):
+def _kvb(geo: LatentGeometry, lp: dict, heads=None):
     """``W_kvb`` (rank, H, nope + vd): K lanes first, then V. ``heads``: as
     :func:`_of_heads`."""
-    w, h = _of_heads(lp["wkv_b"], cfg.num_heads, heads)
-    return w.reshape(cfg.kv_lora_rank, h,
-                     cfg.qk_nope_head_dim + cfg.v_head_dim)
+    w, h = _of_heads(lp["wkv_b"], geo.num_heads, heads)
+    return w.reshape(geo.kv_lora_rank, h,
+                     geo.qk_nope_head_dim + geo.v_head_dim)
 
 
-def expand(cfg: ModelConfig, lp: dict, rows, heads=None):
+def expand(geo: LatentGeometry, lp: dict, rows, heads=None):
     """Cached rows (B, S, kv_row_lanes) -> per-head (k (B, S, H, nope + rope),
     v (B, S, H, vd)): the expanded form's keys and values. ``heads`` (first,
     count): those heads' alone (:func:`_of_heads`)."""
-    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    kv = jnp.einsum("bsc,chn->bshn", rows[..., :rank], _kvb(cfg, lp, heads))
+    rank, rope = geo.kv_lora_rank, geo.qk_rope_head_dim
+    kv = jnp.einsum("bsc,chn->bshn", rows[..., :rank], _kvb(geo, lp, heads))
     k_rope = jnp.broadcast_to(rows[..., None, rank:rank + rope],
                               (*kv.shape[:3], rope))
-    nope = cfg.qk_nope_head_dim
+    nope = geo.qk_nope_head_dim
     return (jnp.concatenate([kv[..., :nope], k_rope], axis=-1),
             kv[..., nope:])
 
 
-def absorb_query(cfg: ModelConfig, lp: dict, q_nope, q_rope):
+def absorb_query(geo: LatentGeometry, lp: dict, q_nope, q_rope):
     """(B, H, nope), (B, H, rope) -> the query over a cached row, (B, H,
     kv_row_lanes): ``[W_kvb^{K,h} q_nope_h | q_rope_h | 0...]``."""
-    wk = _kvb(cfg, lp)[..., :cfg.qk_nope_head_dim]
+    wk = _kvb(geo, lp)[..., :geo.qk_nope_head_dim]
     qc = jnp.einsum("bhn,chn->bhc", q_nope, wk,
                     preferred_element_type=jnp.float32).astype(q_nope.dtype)
-    return _pad_lanes(cfg, jnp.concatenate([qc, q_rope], axis=-1))
+    return _pad_lanes(geo, jnp.concatenate([qc, q_rope], axis=-1))
 
 
-def unabsorb(cfg: ModelConfig, lp: dict, ctx):
+def head_gate(lp: dict, x):
+    """``sigmoid(x W_g)`` (..., H), one gate a head, of the layer's
+    normalised input x (..., D); None where the layer holds no gate."""
+    return jax.nn.sigmoid(x @ lp["wg"]) if "wg" in lp else None
+
+
+def unabsorb(geo: LatentGeometry, lp: dict, ctx, gate=None):
     """The weighted sums of cached rows (B, H, kv_row_lanes) -> the layer's
-    output (B, D): ``W_kvb``'s V half on the latent lanes, then ``W_o``."""
-    wv = _kvb(cfg, lp)[..., cfg.qk_nope_head_dim:]
-    out = jnp.einsum("bhc,chv->bhv", ctx[..., :cfg.kv_lora_rank], wv)
+    output (B, D): ``W_kvb``'s V half on the latent lanes, then ``W_o``.
+    ``gate`` (B, H) (:func:`head_gate`; None: no gate): a head's output times
+    its gate ahead of ``W_o``. A head's scalar commutes with its V half, so
+    it multiplies the ``v_head_dim`` lanes that come out, not the
+    ``kv_lora_rank`` that go in."""
+    wv = _kvb(geo, lp)[..., geo.qk_nope_head_dim:]
+    out = jnp.einsum("bhc,chv->bhv", ctx[..., :geo.kv_lora_rank], wv)
+    if gate is not None:
+        out = out * gate[..., None].astype(out.dtype)
     return out.reshape(ctx.shape[0], -1) @ lp["wo"]

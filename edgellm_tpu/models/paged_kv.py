@@ -480,13 +480,18 @@ FP_POOLS = (PagePool, IndexedPagePool, *LATENT_POOLS)
 
 
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
-              dtype=jnp.float32, layers: Optional[int] = None):
+              dtype=jnp.float32, layers: Optional[int] = None,
+              lanes: int = 0):
     """An all-zero pool; ``num_pages`` INCLUDES the reserved trash page 0,
     so ``num_pages - 1`` pages are allocatable. ``layers``: how many layers
     it serves where that is not ``cfg.kv_layers`` (the window group's). A
     :class:`PagePool`, a :class:`LatentPool` for latent layers, an
     :class:`IndexedPagePool` for sparse-attention layers, an
-    :class:`IndexedLatentPool` for layers that are both."""
+    :class:`IndexedLatentPool` for layers that are both. ``lanes`` (> 0): a
+    group whose rows are latent at a width of their own, whatever the main
+    group holds (the window group of a stack whose window layers cache
+    latent rows, ``cfg.window_row_lanes``): a one-leaf :class:`LatentPool` of
+    rows that wide."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is reserved), "
                          f"got {num_pages}")
@@ -494,6 +499,8 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
         raise ValueError(f"page_size must be >= 1, got {page_size}")
     rows = (cfg.kv_layers if layers is None else layers, num_pages,
             page_size)
+    if lanes:
+        return LatentPool(jnp.zeros(rows + (lanes,), dtype))
     if cfg.latent_layers and cfg.sparse_layers:
         return IndexedLatentPool(
             jnp.zeros(rows + (cfg.kv_row_lanes,), dtype),
@@ -1045,12 +1052,14 @@ class PagedKVCache:
         # (page 0 is that pool's trash page: a free slot's table row is 0).
         self.window_pages = (cfg.window_pages(page_size)
                              if cfg.window_layers else 0)
-        self.window_pool: Optional[PagePool] = None
+        # (a PagePool of K/V rows, or a LatentPool where the window layers
+        # cache latent rows: told apart by the row's width alone)
+        self.window_pool = None
         self.window_table: Optional[np.ndarray] = None
         if self.window_pages:
             self.window_pool = init_pool(
                 cfg, max_slots * self.window_pages + 1, page_size, dtype,
-                layers=cfg.window_layers)
+                layers=cfg.window_layers, lanes=cfg.window_row_lanes)
             self.window_table = np.zeros((max_slots, self.window_pages),
                                          np.int32)
         self.page_size = page_size
@@ -1690,13 +1699,23 @@ class PagedKVCache:
         """Write the sliding layers' (L_window, n, KV, hd) post-rotary K/V of
         positions ``[window_ring_start(length), length)`` — the tail of a
         prefill, or an evicted stream's gathered ring — at their ring places.
-        Whole pages go a page a scatter slice, as :meth:`adopt`'s."""
+        Whole pages go a page a scatter slice, as :meth:`adopt`'s. A ring of
+        LATENT rows takes them as ``wk_seq`` (L_window, n, window_row_lanes),
+        as stored, and ``wv_seq`` None (:func:`write_rows`' convention)."""
         start = self.window_ring_start(length)
         if wk_seq.shape[1] != length - start:
             raise ValueError(
                 f"adopt_window takes positions [{start}, {length}) of a "
                 f"{length}-position stream, got {wk_seq.shape[1]} rows")
+        if isinstance(self.window_pool, LatentPool) != (wv_seq is None):
+            raise ValueError(
+                f"a ring of {type(self.window_pool).__name__} rows adopts "
+                f"{'latent rows alone' if wv_seq is not None else 'K and V'}")
         dest = jnp.asarray(self._ring_indices(slot, start, length))
+        if wv_seq is None:
+            self.window_pool = _adopt_latent_impl(
+                self.window_pool, jnp.asarray(wk_seq), dest, head=0)
+            return
         self.window_pool = _adopt_impl(self.window_pool, jnp.asarray(wk_seq),
                                        jnp.asarray(wv_seq), dest, head=0)
 
@@ -1704,12 +1723,17 @@ class PagedKVCache:
         """``slot``'s ring as host arrays {"wk", "wv"}: (L_window, n, KV, hd)
         rows of positions ``[window_ring_start(length), length)`` in position
         order — what an eviction keeps beside :meth:`gather_slot`'s rows, and
-        what :meth:`adopt_window` takes back."""
+        what :meth:`adopt_window` takes back. A ring of latent rows:
+        {"wrows": (L_window, n, window_row_lanes)}, bytes as stored (the
+        main group's payload already holds a "rows")."""
         if not self.window_pages:
             return {}
         n = int(self.lengths[slot])
         start = self.window_ring_start(n)
         idx = jnp.asarray(self._ring_indices(slot, start, max(n, 1)))
+        if isinstance(self.window_pool, LatentPool):
+            (rows,) = _gather_latent_impl(self.window_pool, idx)
+            return {"wrows": np.asarray(rows)[:, :n - start]}
         k, v = _gather_impl(self.window_pool, idx,
                             kv=self.cfg.num_kv_heads)
         return {"wk": np.asarray(k)[:, :n - start],
@@ -2130,11 +2154,18 @@ class PagedKVCache:
             assert wp * self.page_size >= \
                 self.cfg.sliding_window + self.page_size - 1, \
                 "a ring must hold a whole window wherever it starts in a page"
-            assert self.window_pool.kv.shape[:3] == (
+            assert self.window_pool[0].shape[:3] == (
                 self.cfg.window_layers, self.max_slots * wp + 1,
                 self.page_size), \
-                f"window pool {self.window_pool.kv.shape}: a ring of {wp} " \
+                f"window pool {self.window_pool[0].shape}: a ring of {wp} " \
                 f"pages a slot and the trash page, however long streams grow"
+            assert isinstance(self.window_pool, LatentPool) == bool(
+                self.cfg.window_latent_layers) and (
+                not self.cfg.window_latent_layers
+                or self.window_pool.rows.shape[-1]
+                == self.cfg.window_row_lanes), \
+                "a ring holds latent rows (at the window kind's own width) " \
+                "exactly for window layers that cache them"
             assert self.pool is None or \
                 self.pool[0].shape[0] == self.cfg.kv_layers, \
                 "the page pool holds the full attention layers only"
@@ -2648,12 +2679,14 @@ def attend_pages(q, pool: PagePool, layer, page_table, lengths,
     return _own_lanes(out, own)
 
 
-def attend_latent(q_rows, rows, lengths, head_dim: int):
+def attend_latent(q_rows, rows, lengths, head_dim: int, valid=None):
     """Single-position multi-query attention of H heads over latent rows as
     the pool stores them (the ABSORBED form, ``models/mla.py``): q_rows (B,
     H, lanes) from ``mla.absorb_query``; rows (B, span, lanes), the SAME
     array keys and values (a row's latent lanes are both); lengths (B,)
-    valid positions a slot. Returns the weighted sums of rows (B, H, lanes)
+    valid positions a slot, or ``valid`` (B, span) bool, the rows attended,
+    where they are not a prefix (a window layer's ring or band). Returns the
+    weighted sums of rows (B, H, lanes)
     in q's dtype, which ``mla.unabsorb`` takes; scores times
     ``head_dim^-1/2`` (the query / key head's width, what the expanded form
     divides by), softmax in fp32. No (B, span, H, ...) tensor exists: the
@@ -2661,7 +2694,8 @@ def attend_latent(q_rows, rows, lengths, head_dim: int):
     scores = jnp.einsum("bhD,bcD->bhc", q_rows, rows,
                         preferred_element_type=jnp.float32)
     scores = scores * (1.0 / np.sqrt(head_dim))
-    valid = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
+    if valid is None:
+        valid = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
     scores = jnp.where(valid[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhc,bcD->bhD", probs.astype(q_rows.dtype), rows,
@@ -2677,14 +2711,15 @@ def _attention_decode_latent(cfg: ModelConfig, lp: dict, x, cos_b, sin_b,
     row into its current page (``paged_kv.write``), attend each slot's rows
     as they lie in its pages (:func:`latent_decode_attention`), then the V
     half of ``W_kvb`` and ``W_o``. Returns (out (B, D), pool)."""
-    q_nope, q_rope, row = mla.project(cfg, lp, x,
+    geo = cfg.latent_geometry("latent_attention")
+    q_nope, q_rope, row = mla.project(cfg, geo, lp, x,
                                       mla.rotate_rows(cos_b, sin_b),
-                                      mla.query_scale(cfg, lengths))
+                                      mla.query_scale(cfg, geo, lengths))
     pool = write_rows(pool, layer, page_table, lengths, row[:, None], None)
-    q_rows = mla.absorb_query(cfg, lp, q_nope, q_rope)
+    q_rows = mla.absorb_query(geo, lp, q_nope, q_rope)
     ctx = latent_decode_attention(q_rows, pool, layer, page_table,
                                   lengths + 1, cfg.head_dim)
-    return mla.unabsorb(cfg, lp, ctx), pool
+    return mla.unabsorb(geo, lp, ctx), pool
 
 
 def paged_decode_attention(q, pool, layer, page_table, lengths,
@@ -2936,8 +2971,16 @@ def head_norms(cfg: ModelConfig, lp: dict, q, k):
 
 def gated(lp: dict, x, ctx):
     """The attend's output ctx (..., H*hd) times ``sigmoid(x W_g)`` where the
-    layer holds an output gate ``wg`` (D, H*hd): ahead of ``W_o``."""
-    return ctx * jax.nn.sigmoid(x @ lp["wg"]) if "wg" in lp else ctx
+    layer holds an output gate ``wg``: ahead of ``W_o``. ``wg`` (D, H*hd): a
+    gate a lane; (D, H): a gate a HEAD, broadcast over the head's lanes."""
+    if "wg" not in lp:
+        return ctx
+    gate = jax.nn.sigmoid(x @ lp["wg"])
+    if gate.shape[-1] != ctx.shape[-1]:
+        heads = gate.shape[-1]
+        return (ctx.reshape(*ctx.shape[:-1], heads, -1)
+                * gate[..., None]).reshape(ctx.shape)
+    return ctx * gate
 
 
 def post_norm(cfg: ModelConfig, lp: dict, out):
@@ -2946,3 +2989,56 @@ def post_norm(cfg: ModelConfig, lp: dict, out):
     if "post_scale" not in lp:
         return out
     return _rmsnorm(out, lp["post_scale"], cfg.norm_eps)
+
+
+# -- a window layer whose ring holds LATENT rows (down here: the lines above
+# keep their numbers) ---------------------------------------------------------
+
+def latent_ring_attention(q_rows, pool: LatentPool, layer, window_table,
+                          lengths, head_dim: int, window: int):
+    """:func:`latent_decode_attention` for a RING of latent rows: the
+    absorbed query ``q_rows`` (B, H, lanes) of every slot against the rows of
+    its ring of layer ``layer`` that hold positions ``(t - window, t]``, ``t
+    = lengths - 1`` (``lengths`` counts the one this step wrote); the
+    weighted sums of rows (B, H, lanes). Where :func:`decode_read_path` says
+    so of the ring's pool, the page walk under ``window=`` (a row is key and
+    value both, masked by the position it holds inside the kernel: the two
+    things ``flash_attention.paged_decode_walk`` took one at a time before);
+    otherwise one page gather of the rings and :func:`attend_latent` under
+    :func:`window_valid`."""
+    if decode_read_path(pool) == PAGE_WALK:
+        ids = (layer * pool.num_pages + window_table).astype(jnp.int32)
+        return flash_attention.paged_decode_walk(
+            q_rows, _pages(pool.rows, 1), ids, lengths.astype(jnp.int32),
+            scale=float(1.0 / np.sqrt(head_dim)), window=window)
+    return attend_latent(
+        q_rows, _gather_pages(pool.rows, layer, window_table), lengths,
+        head_dim, window_valid(
+            ring_positions(lengths, window_table.shape[1], pool.page_size),
+            lengths, window))
+
+
+@jax.named_scope("attn.window_latent")
+def _attention_decode_window_latent(cfg: ModelConfig, lp: dict, x, cos_b,
+                                    sin_b, pool: LatentPool, layer,
+                                    window_table, lengths):
+    """A window layer of latent rows in the ragged step, ABSORBED: x (B, D)
+    normalised; project and rotate each slot at ITS position by the window
+    kind's own sizes and table (``cfg.latent_geometry``), write its new row
+    into its ring (the scope ``attn.window_latent.write``: the ring's write
+    is this layer's own, not the growing pool's ``paged_kv.write``), attend
+    the ring's rows inside the band (:func:`latent_ring_attention`), then the
+    V half of ``W_kvb``, the heads' gate and ``W_o``. Returns (out (B, D),
+    pool)."""
+    geo = cfg.latent_geometry("sliding_latent_attention")
+    q_nope, q_rope, row = mla.project(cfg, geo, lp, x,
+                                      mla.rotate_rows(cos_b, sin_b),
+                                      mla.query_scale(cfg, geo, lengths))
+    with jax.named_scope("attn.window_latent.write"):
+        pool = write_rows.__wrapped__(pool, layer, window_table, lengths,
+                                      row[:, None], None, ring=True)
+    q_rows = mla.absorb_query(geo, lp, q_nope, q_rope)
+    ctx = latent_ring_attention(q_rows, pool, layer, window_table,
+                                lengths + 1, geo.head_dim,
+                                cfg.sliding_window)
+    return mla.unabsorb(geo, lp, ctx, mla.head_gate(lp, x)), pool

@@ -65,7 +65,7 @@ from . import flash_attention, mla
 from .configs import ModelConfig
 from .flash_attention import QBLOCK
 from .paged_kv import (IndexedLatentPool, _apply_rotary_rows, _rows,
-                       attend_latent, attend_latent_pages,
+                       attend_latent, attend_latent_pages, gated,
                        latent_decode_attention, write_rows)
 from .sparse_attn import (BLOCKS_PER_BODY, MASKED_KERNEL, _by_group,
                           _pad_query, _weighted, index_key, index_scores,
@@ -91,10 +91,16 @@ def index_rotation_rows(cfg: ModelConfig, cos, sin):
     return rotate
 
 
-def _attend(cfg: ModelConfig, lp: dict, q_rows, rows, seen):
+def _geo(cfg: ModelConfig):
+    """The sizes ``mla.py`` is handed here: the sparse latent kind's."""
+    return cfg.latent_geometry("sparse_latent_attention")
+
+
+def _attend(cfg: ModelConfig, lp: dict, q_rows, rows, seen, gate=None):
     """Absorbed attention of queries over rows (the XLA path): q_rows (B, Q,
     H, lanes) from ``mla.absorb_query``, rows (B, C, lanes), seen (B or 1,
-    Q, C) bool -> the layer's output (B, Q, D)."""
+    Q, C) bool, gate (B, Q, H) or None (``mla.head_gate``) -> the layer's
+    output (B, Q, D)."""
     b, n, h, lanes = q_rows.shape
     scores = jnp.einsum("bqhD,bcD->bhqc", q_rows, rows,
                         preferred_element_type=jnp.float32)
@@ -103,8 +109,9 @@ def _attend(cfg: ModelConfig, lp: dict, q_rows, rows, seen):
     probs = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhqc,bcD->bqhD", probs.astype(q_rows.dtype), rows,
                      preferred_element_type=jnp.float32).astype(q_rows.dtype)
-    return mla.unabsorb(cfg, lp, ctx.reshape(b * n, h, lanes)).reshape(
-        b, n, -1)
+    return mla.unabsorb(
+        _geo(cfg), lp, ctx.reshape(b * n, h, lanes),
+        None if gate is None else gate.reshape(b * n, h)).reshape(b, n, -1)
 
 
 def _block_mask(cfg: ModelConfig, lp: dict, start, c_q, x, rope, ik,
@@ -137,25 +144,28 @@ def _head_queries(cfg: ModelConfig, lp: dict, start, c_q, rope, heads=None):
     by the rows' own table rows), scaled as ``mla.project`` scales them;
     ``heads`` (first, count): those heads' alone."""
     b, n, _ = c_q.shape
-    q = mla.head_queries(cfg, lp, c_q, jnp.broadcast_to(
-        mla.query_scale(cfg, start + jnp.arange(n)), (b, n)), heads)
+    q = mla.head_queries(_geo(cfg), lp, c_q, jnp.broadcast_to(
+        mla.query_scale(cfg, _geo(cfg), start + jnp.arange(n)), (b, n)),
+        heads)
     nope = cfg.qk_nope_head_dim
     return q[..., :nope], apply_rotary(deinterleave_pairs(q[..., nope:]),
                                        *rope, cfg.rotary_dim)
 
 
-def _attend_block(cfg: ModelConfig, lp: dict, start, c_q, rope, rows, seen):
+def _attend_block(cfg: ModelConfig, lp: dict, start, c_q, x, rope, rows,
+                  seen):
     """One block of query rows ABSORBED against every row handed over (the
     XLA path): the heads' queries made from c_q (B, Q, q_lora_rank) and
     folded through ``W_kvb``'s K half, rows (B, C, kv_row_lanes), seen (B or
-    1, Q, C) from :func:`_block_mask` -> (B, Q, D)."""
+    1, Q, C) from :func:`_block_mask`, x (B, Q, D) the rows' normalised
+    input (what a gate reads) -> (B, Q, D)."""
     b, n, _ = c_q.shape
     q_nope, q_rope = _head_queries(cfg, lp, start, c_q, rope)
     q_rows = mla.absorb_query(
-        cfg, lp, q_nope.reshape(b * n, cfg.num_heads, -1),
+        _geo(cfg), lp, q_nope.reshape(b * n, cfg.num_heads, -1),
         q_rope.reshape(b * n, cfg.num_heads, -1))
     return _attend(cfg, lp, q_rows.reshape(b, n, cfg.num_heads, -1), rows,
-                   seen)
+                   seen, mla.head_gate(lp, x))
 
 
 #: heads whose K and V :func:`_attend_expanded` rebuilds at a time: 16 heads'
@@ -163,11 +173,12 @@ def _attend_block(cfg: ModelConfig, lp: dict, start, c_q, rope, rows, seen):
 EXPANDED_HEADS = 16
 
 
-def _attend_expanded(cfg: ModelConfig, lp: dict, start: int, c_q, rope, rows,
-                     seen):
+def _attend_expanded(cfg: ModelConfig, lp: dict, start: int, c_q, x, rope,
+                     rows, seen):
     """A BODY's query rows EXPANDED against every row handed over, in the
-    masked kernel: c_q (B, Q, q_lora_rank), rope the rows' own table rows,
-    rows (B, C, kv_row_lanes), seen (B or 1, Q, C) -> (B, Q, D).
+    masked kernel: c_q (B, Q, q_lora_rank), x (B, Q, D) the rows' normalised
+    input (what a gate reads), rope the rows' own table rows, rows (B, C,
+    kv_row_lanes), seen (B or 1, Q, C) -> (B, Q, D).
     :data:`EXPANDED_HEADS` heads at a time (a ``lax.map``: one group's
     queries, keys and values live at once): their queries made from c_q,
     their keys and values rebuilt from the rows (``mla.expand``), a head a
@@ -180,7 +191,7 @@ def _attend_expanded(cfg: ModelConfig, lp: dict, start: int, c_q, rope, rows,
     def group(first):
         q = jnp.concatenate(_head_queries(cfg, lp, start, c_q, rope,
                                           (first, hg)), axis=-1)
-        k, v = mla.expand(cfg, lp, rows, (first, hg))
+        k, v = mla.expand(_geo(cfg), lp, rows, (first, hg))
         out = flash_attention.masked_attention(
             _by_group(q)[:, None], _by_group(k), _by_group(v), seen, start,
             scale=cfg.head_dim ** -0.5)
@@ -189,7 +200,7 @@ def _attend_expanded(cfg: ModelConfig, lp: dict, start: int, c_q, rope, rows,
     outs = jax.lax.map(group, jnp.arange(0, h, hg))
     # (groups, B, hg, Q, vd) -> (B, Q, H vd)
     ctx = jnp.transpose(outs, (1, 3, 0, 2, 4)).reshape(b, n, -1)
-    return ctx @ lp["wo"]
+    return gated(lp, x, ctx) @ lp["wo"]
 
 
 def _joined(parts: list):
@@ -214,7 +225,8 @@ def attention_full(cfg: ModelConfig, lp: dict, x, rope):
     cos, sin = rope
     c_q = mla.query_latent(cfg, lp, x)
     rows = mla.latent_row(
-        cfg, lp, x, lambda t: apply_rotary(t, cos, sin, cfg.rotary_dim))
+        cfg, _geo(cfg), lp, x,
+        lambda t: apply_rotary(t, cos, sin, cfg.rotary_dim))
     with jax.named_scope("attn.sparse.index"):
         # (every position's index key; a block makes its own queries)
         ik = index_key(cfg, lp, x, lambda t: apply_rotary(
@@ -236,7 +248,7 @@ def attention_full(cfg: ModelConfig, lp: dict, x, rope):
                                select)
             if kernel:
                 return seen
-            return _attend_block(cfg, lp, at, cut[0], table, rows[:, :stop],
+            return _attend_block(cfg, lp, at, *cut, table, rows[:, :stop],
                                  seen)
 
         parts = []
@@ -251,7 +263,7 @@ def attention_full(cfg: ModelConfig, lp: dict, x, rope):
             parts.append(block(start + whole, stop - start - whole))
         if kernel:
             outs.append(_attend_expanded(
-                cfg, lp, start, c_q[:, start:stop],
+                cfg, lp, start, c_q[:, start:stop], x[:, start:stop],
                 (cos[start:stop], sin[start:stop]), rows[:, :stop],
                 _joined(parts)))
         else:
@@ -271,15 +283,16 @@ def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope,
     (``sparse_attn.read_selected``, the skeleton both sparse kinds share);
     ``W_kvb``'s V half and ``W_o``. Returns (out (B, D), pool)."""
     c_q = mla.query_latent(cfg, lp, x)
+    geo = _geo(cfg)
     q_nope, q_rope, row = mla.project(
-        cfg, lp, x, mla.rotate_rows(*rope), mla.query_scale(cfg, lengths),
-        c_q)
+        cfg, geo, lp, x, mla.rotate_rows(*rope),
+        mla.query_scale(cfg, geo, lengths), c_q)
     with jax.named_scope("attn.sparse.index"):
         qi, ik, wi = project_index(
             cfg, lp, x, index_rotation_rows(cfg, *rope), query=c_q)
     pool = write_rows(pool, layer, page_table, lengths, row[:, None], None,
                       index=ik)
-    q_rows = mla.absorb_query(cfg, lp, q_nope, q_rope)
+    q_rows = mla.absorb_query(geo, lp, q_nope, q_rope)
     ctx = read_selected(
         cfg, qi, wi, pool, layer, page_table, lengths,
         every=lambda: latent_decode_attention(
@@ -289,7 +302,7 @@ def attention_decode_paged(cfg: ModelConfig, lp: dict, x, rope,
             keep=keep),
         rows=lambda chosen, count: attend_latent(
             q_rows, _rows(pool.rows, 1)[chosen], count, cfg.head_dim))
-    return mla.unabsorb(cfg, lp, ctx), pool
+    return mla.unabsorb(geo, lp, ctx, mla.head_gate(lp, x)), pool
 
 
 @jax.named_scope("attn.sparse_latent")
@@ -301,9 +314,10 @@ def attention_decode_rows(cfg: ModelConfig, lp: dict, x, rope, rows_all,
     position ``pos`` written)."""
     b = x.shape[0]
     c_q = mla.query_latent(cfg, lp, x)
+    geo = _geo(cfg)
     q_nope, q_rope, row = mla.project(
-        cfg, lp, x, mla.rotate_rows(*rope),
-        mla.query_scale(cfg, jnp.broadcast_to(pos, (b,))), c_q)
+        cfg, geo, lp, x, mla.rotate_rows(*rope),
+        mla.query_scale(cfg, geo, jnp.broadcast_to(pos, (b,))), c_q)
     qi, ik, wi = project_index(cfg, lp, x, index_rotation_rows(cfg, *rope),
                                query=c_q)
     rows_all = jax.lax.dynamic_update_slice(
@@ -316,6 +330,7 @@ def attention_decode_rows(cfg: ModelConfig, lp: dict, x, rope, rows_all,
         idx, lengths = select(index_scores(qi, wi, ik_all), lengths,
                               cfg.index_topk)
         attended = jnp.take_along_axis(rows_all, idx[:, :, None], axis=1)
-    ctx = attend_latent(mla.absorb_query(cfg, lp, q_nope, q_rope), attended,
+    ctx = attend_latent(mla.absorb_query(geo, lp, q_nope, q_rope), attended,
                         lengths, cfg.head_dim)
-    return mla.unabsorb(cfg, lp, ctx), rows_all, ik_all
+    return (mla.unabsorb(geo, lp, ctx, mla.head_gate(lp, x)), rows_all,
+            ik_all)

@@ -218,6 +218,16 @@ SCOPE_NAMES: FrozenSet[str] = frozenset({
     "attn.sparse_latent.prefill",  # ... over a whole prompt, by blocks of
                                    # query rows (absorbed; on the masked
                                    # kernel expanded, a body at a time)
+    # a window layer whose ring holds LATENT rows (dots3_note)
+    "attn.window_latent",  # ... its decode: projections, norms, rotation, the
+                           # ring's row write, the walk (or gather) of the
+                           # ring under the band, W_kvb's V half, the heads'
+                           # gate and W_o
+    "attn.window_latent.write",  # the ring's row write within it: not the
+                                 # growing pool's paged_kv.write, so a reader
+                                 # of the full layers' scopes reads them alone
+    "attn.window_latent.prefill",  # ... over a whole prompt: expanded, by
+                                   # blocks of query rows under the band
     "mlp",                # a dense feed-forward; in a stack walked by layer
                           # kinds, a leading dense layer's and its post-norm,
                           # or every sublayer's dense SwiGLU (longcat_flash)

@@ -302,16 +302,22 @@ def _batched_window_step_jit(cfg: ModelConfig, params: dict, pool, window_pool,
     (``models/hybrid.py``'s walk, no recurrent state): the full layers' page
     pool, the window layers' pool of rings (each a PagePool, addressed through
     its own table) and the per-expert assignment counter are donated and come
-    back updated. A SEPARATE jit: the other families keep their executables."""
+    back updated. A SEPARATE jit: the other families keep their executables.
+    A ``dots3_note`` stack's two groups are an ``IndexedLatentPool`` (both
+    leaves donated) and a ``LatentPool`` of rings."""
     if compute_dtype is not None:
         params = jax.tree_util.tree_map(
             lambda a: a.astype(compute_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
-    logits, kv, _, expert_tokens, win = paged_decode_step_hybrid(
-        cfg, params, pool.kv, None, expert_tokens, page_table, lengths,
-        token_ids, window=(window_pool.kv, window_table))
+    # (a pool of two leaves goes and comes back whole, as the step above
+    # hands it over; a ring's pool is one leaf, K/V rows or latent rows)
+    whole = isinstance(pool, INDEXED_POOLS)
+    logits, main, _, expert_tokens, win = paged_decode_step_hybrid(
+        cfg, params, pool if whole else pool[0], None, expert_tokens,
+        page_table, lengths, token_ids, window=(window_pool[0], window_table))
     return (_batched_sample(logits, key_data, steps, temps),
-            type(pool)(kv), type(window_pool)(win), expert_tokens)
+            main if whole else type(pool)(main), type(window_pool)(win),
+            expert_tokens)
 
 
 def batched_step_cache_size() -> int:
@@ -750,6 +756,9 @@ class ContinuousBatcher:
                 if "wk" in st.resume:
                     self.pool.adopt_window(slot, st.resume["wk"],
                                            st.resume["wv"], need_len)
+                elif "wrows" in st.resume:   # a ring of latent rows
+                    self.pool.adopt_window(slot, st.resume["wrows"], None,
+                                           need_len)
             st.resume = None
             if st.resume_prefix and self.pool.prefix is not None:
                 # migration adopts opt in to re-publishing: the payload's
@@ -812,7 +821,12 @@ class ContinuousBatcher:
                     self.pool.adopt_state(
                         slot, *(cache.state[leaf][:, 0]
                                 for leaf in self.pool.state))
-                if self.cfg.window_layers:
+                if self.cfg.window_latent_layers:
+                    # the window layers' latent rows, the tail a ring holds
+                    r0 = self.pool.window_ring_start(s)
+                    self.pool.adopt_window(slot, cache.wrows[:, 0, r0:s],
+                                           None, s)
+                elif self.cfg.window_layers:
                     # the sliding layers take the tail their rings hold
                     r0 = self.pool.window_ring_start(s)
                     self.pool.adopt_window(slot, cache.wk[:, 0, r0:s],

@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 
 from edgellm_tpu.models import grouped_matmul, hybrid, moe, paged_kv
 from edgellm_tpu.models.configs import DEEPSEEK_V3_2_EXP, \
+    DOTS3_NOTE_PREV, \
     KEYE_VL_2_0_30B_A3B, LFM2_8B_A1B, \
     LONGCAT_FLASH_CHAT, ModelConfig, tiny_afmoe_config, tiny_hybrid_config, \
     tiny_lfm2_moe_config, tiny_longcat_flash_config, tiny_mellum_config, \
@@ -1089,6 +1090,106 @@ def test_deepseek_step_walks_once_a_layer_and_keeps_both_leaves_in_place(
                      "moe.shared", "unembed_sample"} | (
         set() if read == "walk" else {"attn.sparse.select"}), under
     assert not under & {"attn.decode", "attn.sparse", "attn.latent"}
+
+
+DOTS3 = dataclasses.replace(
+    DOTS3_NOTE_PREV, num_layers=2, num_dense_layers=1,
+    layer_types=("sparse_latent_attention", "sliding_latent_attention"),
+    experts_held=32, vocab_size=19008)
+N_SLOTS, N_PAGES_PER_SLOT = 32, 1280
+
+
+def test_dots3_step_walks_a_ring_of_latent_rows_beside_the_selected_ones(
+        topo, read):
+    """The step of the ``dots3_note`` cell at its shapes, a full (dense)
+    layer and a window (expert) layer: THREE leaves in two page groups (the
+    full layers' latent rows of 640 lanes and index keys of 128 under one
+    table, the window layers' ring of latent rows of 1152 lanes under
+    another) donated and written where they lie. On a TPU's choice the full
+    layer holds one index walk and one masked walk of its latent leaf (128
+    heads x 640 lanes), and the window layer ONE ring walk at 64 heads x 1152
+    lanes over its 33 entries, a row key and value both; no gather of a
+    slot's span or of its ring exists. On the other read the ring comes by
+    one page gather. Every heavy operation under a registered scope, the
+    window kind's under ``attn.window_latent`` and its ring write under
+    ``attn.window_latent.write``, never ``paged_kv.write``."""
+    from edgellm_tpu.models import sparse_attn
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = DOTS3
+    ring = cfg.window_pages(PAGE)
+    assert (cfg.sparse_layers, cfg.latent_layers, cfg.window_latent_layers,
+            cfg.kv_row_lanes, cfg.window_row_lanes, ring) == (
+        1, 1, 1, 640, 1152, 33)
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)), one)
+    assert params["sparse_latent"]["wg"].shape == (1, 5120, 128)
+    assert params["window_latent"]["wg"].shape == (1, 5120, 64)
+    assert params["window_latent"]["wkv_b"].shape == (1, 1024, 64 * 320)
+    assert "wq_index" not in params["window_latent"]
+    assert params["moe"][1]["w_gate"].shape == (32, 5120, 1536)
+    pages = N_SLOTS * N_PAGES_PER_SLOT + 1
+    pool = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, pages, PAGE, jnp.bfloat16)), one)
+    rings = _shapes(jax.eval_shape(lambda: paged_kv.init_pool(
+        cfg, N_SLOTS * ring + 1, PAGE, jnp.bfloat16,
+        layers=cfg.window_layers, lanes=cfg.window_row_lanes)), one)
+    assert type(pool) is paged_kv.IndexedLatentPool
+    assert type(rings) is paged_kv.LatentPool
+    assert rings.rows.shape == (1, N_SLOTS * ring + 1, PAGE, 1152)
+    assert paged_kv.decode_read_path(rings) == (
+        paged_kv.PAGE_WALK if read == "walk" else paged_kv.PAGE_GATHER)
+    assert sparse_attn.sparse_read_path(cfg, N_PAGES_PER_SLOT * PAGE, pool) \
+        == (sparse_attn.MASKED_WALK if read == "walk"
+            else sparse_attn.ROW_GATHER)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ints = arr((N_SLOTS,), jnp.int32)
+    step = batching._batched_window_step_jit.lower(
+        cfg, params, pool, rings, arr((1, 32), jnp.int32),
+        arr((N_SLOTS, N_PAGES_PER_SLOT), jnp.int32),
+        arr((N_SLOTS, ring), jnp.int32), ints, ints,
+        arr((N_SLOTS, 2), jnp.uint32), ints, arr((N_SLOTS,), jnp.float32),
+        None).compile()
+    hlo = step.as_text()
+    # the full layer's masked walk and the window layer's ring walk
+    assert _walks(hlo) == (2 if read == "walk" else 0)
+    walked = _walk_operands(hlo)
+    if read == "walk":
+        (ringed,) = [ops for ops in walked
+                     if f"bf16[{N_SLOTS},64,1152]" in ops]
+        assert f"bf16[{N_SLOTS * ring + 1},{PAGE},1152]" in ringed
+        assert f"s32[{N_SLOTS},{ring}]" in ringed
+        (chosen,) = [ops for ops in walked
+                     if f"bf16[{N_SLOTS},128,640]" in ops]
+        assert f"bf16[{pages},{PAGE},640]" in chosen
+    index_walks = [line for op, _, _, line in _instructions(hlo)
+                   if op == "custom-call" and "paged_index_walk" in line]
+    assert len(index_walks) == (1 if read == "walk" else 0)
+    ring_shapes = {f"bf16[{N_SLOTS},{ring},{PAGE},1152]",
+                   f"bf16[{N_SLOTS},{ring * PAGE},1152]"}
+    span = N_PAGES_PER_SLOT * PAGE
+    assert not _span_sized(hlo, {f"bf16[{N_SLOTS},{span},640]",
+                                 f"bf16[{N_SLOTS},{N_PAGES_PER_SLOT},"
+                                 f"{PAGE},640]"})
+    assert bool(_span_sized(hlo, ring_shapes)) == (read != "walk")
+    # nothing per head over the ring: the window kind's absorption is real
+    assert not re.findall(rf"\[{N_SLOTS},{ring * PAGE},64,\d+\]", hlo)
+    mem = step.memory_analysis()
+    leaves = sum(int(np.prod(a.shape)) * 2 for a in (*pool, *rings))
+    assert mem.alias_size_in_bytes >= leaves
+    unscoped, under = _scopes_of_the_heavy(hlo)
+    assert unscoped <= {"jit(_batched_window_step_jit)/jit(_take)/gather",
+                        "jit(_batched_window_step_jit)/gather", ""} | (
+        set() if read == "walk" else {"gather"}), unscoped
+    assert under >= {"attn.sparse_latent", "attn.sparse.index",
+                     "attn.window_latent", "attn.window_latent.write",
+                     "paged_kv.write", "mlp", "moe.route", "moe.experts",
+                     "moe.shared", "unembed_sample"}, under
+    assert not under & {"attn.decode", "attn.window", "attn.latent"}
+    assert "attn.window_latent/paged_kv.write" not in hlo
 
 
 def _per_head(hlo: str, s: int) -> list:

@@ -842,7 +842,7 @@ MISTAKES = {
     "the softmax mscale dropped":
         lambda mp: mp.setattr(
             mla, "query_scale",
-            lambda cfg, pos: jnp.ones(pos.shape, jnp.float32)),
+            lambda cfg, geo, pos: jnp.ones(pos.shape, jnp.float32)),
 }
 
 

@@ -563,8 +563,8 @@ def _bias_dropped(real, cfg, router_w, u, bias):
 def _latent_cached_without_its_rank_scale(monkeypatch):
     real = mla.project
 
-    def project(cfg, lp, x, rotate, scale):
-        q_nope, q_rope, row = real(cfg, lp, x, rotate, scale)
+    def project(cfg, geo, lp, x, rotate, scale):
+        q_nope, q_rope, row = real(cfg, geo, lp, x, rotate, scale)
         rank = cfg.kv_lora_rank
         return q_nope, q_rope, row.at[..., :rank].divide(cfg.kv_rank_scale)
 
@@ -573,8 +573,9 @@ def _latent_cached_without_its_rank_scale(monkeypatch):
 
 
 def _query_rank_scale_left_out(monkeypatch):
-    monkeypatch.setattr(mla, "query_scale", lambda cfg, positions: jnp.ones(
-        positions.shape, jnp.float32))
+    monkeypatch.setattr(
+        mla, "query_scale",
+        lambda cfg, geo, positions: jnp.ones(positions.shape, jnp.float32))
     return CFG
 
 

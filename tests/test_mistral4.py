@@ -221,7 +221,9 @@ def test_yarn_band_and_the_two_scales_at_the_published_numbers():
     want = [1.0, 1.0, 1 + 0.1 * math.log(2), 1 + 0.1 * math.log(2),
             1 + 0.1 * math.log(3), 1 + 0.1 * math.log(17)]
     np.testing.assert_allclose(
-        np.asarray(mla.query_scale(c, pos)), np.asarray(want) * m * m,
+        np.asarray(mla.query_scale(
+            c, c.latent_geometry("latent_attention"), pos)),
+        np.asarray(want) * m * m,
         rtol=1e-6)
     np.testing.assert_allclose(np.asarray(ref.query_scale(k, 16385))[
         [0, 8191, 8192, 16383, 16384]], want[:5], rtol=1e-6)
@@ -572,8 +574,8 @@ def _project_with(change):
     def make(monkeypatch):
         real = mla.project
 
-        def project(cfg, lp, x, rotate, scale):
-            q_nope, q_rope, row = real(cfg, lp, x, rotate, scale)
+        def project(cfg, geo, lp, x, rotate, scale):
+            q_nope, q_rope, row = real(cfg, geo, lp, x, rotate, scale)
             return q_nope, q_rope, change(cfg, lp, x, rotate, row)
 
         monkeypatch.setattr(mla, "project", project)

@@ -470,6 +470,11 @@ CELL_RUNS = {
     # 16 x 1280: a latent leaf (20 KB a page: four to a run by itself) and
     # the index keys' (4 KB: eight), which sets the pool's runs
     "deepseek-v3.2-exp-ep16": ((640, 128), 1280, 8),
+    # the full layers' group, deepseek's two leaves at 32 x 1280 ...
+    "dots3-note-prev-ep8": ((640, 128), 1280, 8),
+    # ... and the window layers' ring group: ONE leaf of 1152-lane latent
+    # rows, a page of 36 KB a fetch by itself, 33 entries a ring
+    "dots3-note-prev-ep8.rings": ((1152,), 33, 1),
 }
 
 

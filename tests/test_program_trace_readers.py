@@ -117,7 +117,8 @@ ALL_CELLS = CELLS + ("granite-4.0-h-small-ep2.decode-sat",
                      "longcat-flash-chat-ep32.decode-sat-reason",
                      "lfm2-8b-a1b-pp2.decode-sat-docs",
                      "keye-vl-2.0-30b-a3b-ep4.decode-sat-context",
-                     "deepseek-v3.2-exp-ep16.decode-sat-context")
+                     "deepseek-v3.2-exp-ep16.decode-sat-context",
+                     "dots3-note-prev-ep8.decode-sat-context")
 EDGES = [0.01, 0.02, 0.04, 0.08]            # five rows: under, three, over
 PHASE_KEYS = ("admit_s", "grow_s", "build_s", "launch_s", "sync_s",
               "commit_s")
@@ -711,8 +712,9 @@ def test_the_cells_that_walk_index_keys_alone_list_index_run_share():
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
         entries = {m["name"]: m for m in json.load(f)["per_layer"]}
     # (found by name: later PRs append their own entries after it, and a
-    # later cell whose pool holds index keys its name to the list: PR 52)
-    sparse = [KEYE_CELL, ALL_CELLS[-1]]
+    # later cell whose pool holds index keys its name to the list: PR 52,
+    # PR 54)
+    sparse = [KEYE_CELL, *ALL_CELLS[-2:]]
     assert entries["index_run_share"] == {"name": "index_run_share", "unit": "%", "better": "higher",
                  "source": "program_counter", "layer": "cache",
                  "moves": "gap_mean_ms", "workloads": sparse}
